@@ -172,6 +172,11 @@ BITWISE_PINS: Dict[str, BitwisePin] = {
             ("all-reduce:add:f32:axes=data|expert",
              "fused data+expert grad reduce for shared params — same "
              "class as axes=expert, same dynamic pin"),
+            ("all-reduce:add:f32:axes=expert|model",
+             "the installed XLA fuses the shared-param expert reduce "
+             "with the TP partial-sum reduce into one all-reduce — "
+             "same class as axes=expert, same dynamic pin (also "
+             "scripts/ds_moe.py ep_layout_training_invariant)"),
         ),
     ),
     "train_step_pipe3d": BitwisePin(

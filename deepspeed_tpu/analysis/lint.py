@@ -541,7 +541,7 @@ _R008_DRAW_FNS = ("uniform", "normal", "truncated_normal", "bernoulli",
 # a module that never touches shardings cannot lay the draw out across
 # a mesh axis — R008 half 1 only looks at modules referencing these
 _R008_MESH_MARKERS = ("with_sharding_constraint", "shard_map",
-                      "use_mesh", "Mesh", "NamedSharding")
+                      "set_mesh", "Mesh", "NamedSharding")
 
 
 def _r008_pinned_nodes(tree: ast.Module) -> Set[int]:

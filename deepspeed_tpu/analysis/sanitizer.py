@@ -287,7 +287,7 @@ _PY_SCALARS = (bool, int, float, complex)
 def _leaf_sig(leaf: Any) -> Tuple:
     """(shape, dtype, weak_type, is_python_scalar) of one call leaf."""
     if isinstance(leaf, _PY_SCALARS):
-        aval = jax.core.get_aval(leaf)
+        aval = jax.typeof(leaf)
         return (tuple(aval.shape), str(aval.dtype), True, True)
     aval = getattr(leaf, "aval", None)
     if aval is not None:

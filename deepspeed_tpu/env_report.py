@@ -10,7 +10,6 @@ Usage: python -m deepspeed_tpu.env_report
 """
 
 import importlib
-import os
 import shutil
 import sys
 
@@ -29,9 +28,7 @@ def _version(mod: str) -> str:
 def op_report(backend: str = None) -> list:
     """(op name, buildable/compatible, status detail) rows
     (ref: env_report.py op_report:30). `backend` is the platform name
-    discovered by main()'s watchdogged device probe — op_report itself
-    must never call jax.default_backend(): that would re-enter the very
-    backend init the watchdog exists to survive."""
+    main() discovered, or None when backend init failed."""
     rows = []
     have_gxx = shutil.which("g++") is not None
     # native aio (csrc/aio)
@@ -44,18 +41,17 @@ def op_report(backend: str = None) -> list:
     except Exception as e:
         rows.append(("async_io (csrc/aio)", False, f"error: {e}"))
     rows.append(("toolchain g++", have_gxx, shutil.which("g++") or "missing"))
-    # pallas kernel lanes compile on-demand; report platform readiness.
-    # No backend = the device probe failed or timed out — the kernels
-    # CANNOT be called, so they are NOT okay (the pre-watchdog code had
-    # the same failure row via its try/except)
-    if backend:
-        rows.append(("pallas flash attention", True,
-                     f"mosaic on tpu / interpret on {backend}"))
-        rows.append(("pallas paged attention", True,
-                     f"mosaic on tpu / interpret on {backend}"))
+    # pallas kernels compile on demand with Mosaic, on a TPU only;
+    # anywhere else they run solely under an explicit interpret request
+    # (ops/pallas.interpret_kernels), which is a debugging aid
+    if backend == "tpu":
+        how = "mosaic"
+    elif backend:
+        how = f"no compiled path on {backend}; interpret on request"
     else:
-        rows.append(("pallas kernels", False,
-                     "backend unavailable (device probe failed/timed out)"))
+        how = "backend unavailable"
+    rows.append(("pallas flash attention", backend == "tpu", how))
+    rows.append(("pallas paged attention", backend == "tpu", how))
     return rows
 
 
@@ -74,22 +70,15 @@ def main():
     print(f"  {'python':<18} {sys.version.split()[0]}")
     print("-" * 64)
     print("devices:")
-    # backend init can HANG (not fail) when an accelerator runtime or
-    # its tunnel is wedged — a diagnostics tool must report that state,
-    # not inherit it. Device discovery runs under the shared watchdog
-    # (platform/accelerator.probe_devices); on timeout the report says
-    # so and the op-compatibility section (pure host-side) still
-    # prints. ref: ds_report's device block, which has the same job
-    # when CUDA is broken.
-    from .platform.accelerator import probe_devices, probe_timeout_from_env
-
-    devs, probe_err, timed_out = probe_devices(probe_timeout_from_env())
+    # a diagnostics tool reports a broken backend instead of dying on
+    # it: the op-compatibility section (pure host-side) still prints.
+    # ref: ds_report's device block, which has the same job when CUDA
+    # is broken.
     backend_snap = None
-    if timed_out:
-        print("  device backend init TIMED OUT (accelerator runtime or "
-              "tunnel unresponsive)")
-    elif probe_err is not None:
-        print(f"  jax init failed: {probe_err}")
+    try:
+        devs = jax.devices()
+    except RuntimeError as e:
+        print(f"  jax init failed: {type(e).__name__}: {e}")
     else:
         backend_snap = jax.default_backend()
         print(f"  backend            {backend_snap}")
@@ -97,20 +86,16 @@ def main():
               f"({jax.process_count()} process(es))")
         kinds = sorted({d.device_kind for d in devs})
         print(f"  device kind        {', '.join(kinds)}")
-        from .platform.accelerator import get_accelerator
+        if backend_snap == "tpu":
+            from .platform.accelerator import get_accelerator
 
-        acc = get_accelerator()
-        print(f"  peak bf16 flops    {acc.peak_flops():.2e}/chip")
+            print("  peak bf16 flops    "
+                  f"{get_accelerator().peak_flops():.2e}/chip")
     print("-" * 64)
     print("op compatibility:")
     for name, ok, detail in op_report(backend_snap):
         print(f"  {name:<28} {GREEN_OK if ok else RED_NO}  {detail}")
     print("-" * 64)
-    # a hung backend-init C call can block interpreter teardown even
-    # with the probe on a daemon thread; the report is complete, leave
-    if timed_out:
-        sys.stdout.flush()
-        os._exit(0)
 
 
 if __name__ == "__main__":

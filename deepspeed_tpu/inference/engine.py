@@ -41,6 +41,7 @@ from ..resilience.integrity import (
     payload_digest,
 )
 from ..models import transformer as T
+from ..ops.pallas import kernels_runnable
 from ..utils.logging import log_dist
 from ..utils.sync import serving_readback
 from . import model as M
@@ -69,10 +70,14 @@ class InferenceConfig(ConfigModel):
     # ~4x (f32) more resident tokens per HBM byte, and export/spill
     # payloads shrink by the same factor
     kv_cache_dtype: str = "auto"
-    # decode attention implementation: 'auto' = Pallas kernels on TPU,
-    # the XLA gather oracle elsewhere; 'pallas' forces the fused
-    # kernels (interpret mode off-TPU — the CPU test/gate lane);
-    # 'xla' forces the oracle
+    # serving attention + KV-write implementation: 'auto' = the Pallas
+    # kernels wherever they run (ops/pallas.kernels_runnable: a TPU, or
+    # an explicit interpret request), the jnp oracle elsewhere — the
+    # RESOLVED choice is InferenceEngine.resolved_impl, logged at init
+    # and surfaced as the scheduler's `decode_kernel` metric; 'pallas'
+    # forces the kernels (off-TPU that needs ops/pallas.
+    # interpret_kernels, else pallas_call raises); 'xla' forces the
+    # oracle (no Pallas program anywhere in the engine)
     decode_impl: str = "auto"
     # MoE expert-utilization census: every compiled decode/prefill
     # application streams its per-expert routed-token counts to the
@@ -125,15 +130,8 @@ def _leaf_sharding(pspec, leaf, mesh: Mesh, memory_kind: str = "device"):
     pairing is not worth the bookkeeping)."""
     from .quantization import ChannelQuantWeight, QuantizedWeight
 
-    try:
-        mk = NamedSharding(mesh, pspec, memory_kind=memory_kind)
-        repl = NamedSharding(mesh, P(), memory_kind=memory_kind)
-    except ValueError:
-        # backend without distinct memory spaces (CPU, jax 0.4.x): the
-        # default memory already IS host memory, so the tier placement
-        # collapses to a plain sharding
-        mk = NamedSharding(mesh, pspec)
-        repl = NamedSharding(mesh, P())
+    mk = NamedSharding(mesh, pspec, memory_kind=memory_kind)
+    repl = NamedSharding(mesh, P(), memory_kind=memory_kind)
     if isinstance(leaf, QuantizedWeight):
         return QuantizedWeight(q=mk, scale=repl, bits=leaf.bits,
                                dtype_name=leaf.dtype_name)
@@ -255,6 +253,23 @@ class InferenceEngine:
                     f"tp_size {tp} (ref AutoTP requires head divisibility, "
                     "module_inject/auto_tp.py)"
                 )
+        if self.config.decode_impl not in ("auto", "pallas", "xla"):
+            raise ValueError(
+                f"decode_impl must be 'auto', 'pallas' or 'xla' "
+                f"(got {self.config.decode_impl!r})")
+        # under TP the kernels run per head-shard, which needs Q and KV
+        # heads to split evenly over 'model'; a layout that cannot is
+        # the oracle's — said here, not discovered per call
+        tp = M._tp_size(self.mesh)
+        kernel_layout = tp <= 1 or M._heads_shardable(self.mesh, model_config)
+        if self.config.decode_impl == "pallas" and not kernel_layout:
+            raise ValueError(
+                f"decode_impl='pallas' needs n_heads {model_config.n_heads} "
+                f"and kv_heads {model_config.kv_heads} divisible by tp_size "
+                f"{tp}; use decode_impl='auto' or 'xla'")
+        self._use_kernel = kernel_layout and (
+            self.config.decode_impl == "pallas"
+            or (self.config.decode_impl == "auto" and kernels_runnable()))
         if model_config.attention_impl == "sparse":
             # sparse-trained models serve with the train-time block layout
             # reproduced exactly (inference/model.py _sparsity). Decode
@@ -262,7 +277,7 @@ class InferenceEngine:
             # cache blocks nest inside layout blocks (and no TP mesh);
             # otherwise the XLA paged path carries the per-position mask.
             kernel_ok = (
-                jax.default_backend() == "tpu"
+                self._use_kernel
                 and model_config.sparse_block % self.config.kv_block_size == 0
                 and self.mesh is None
             )
@@ -372,20 +387,12 @@ class InferenceEngine:
             raise ValueError(
                 f"kv_cache_dtype must be 'auto' or 'int8' "
                 f"(got {self.config.kv_cache_dtype!r})")
-        if self.config.decode_impl not in ("auto", "pallas", "xla"):
-            raise ValueError(
-                f"decode_impl must be 'auto', 'pallas' or 'xla' "
-                f"(got {self.config.decode_impl!r})")
         self.kv_quant = self.config.kv_cache_dtype == "int8"
         self.cache = M.init_cache(
             model_config, self.config.num_kv_blocks + 1,
             self.config.kv_block_size, dtype, mesh=self.mesh,
             kv_quant=self.kv_quant,
         )
-        self._use_kernel = (
-            self.config.decode_impl == "pallas"
-            or (self.config.decode_impl == "auto"
-                and jax.default_backend() == "tpu"))
         self._prefill_batch_fns: Dict[Tuple[int, int], Any] = {}
         # keyed (batch_width, unique_rows)
         self._decode_fns: Dict[Tuple[int, bool], Any] = {}
@@ -417,9 +424,16 @@ class InferenceEngine:
             f"inference engine: {self.config.num_kv_blocks} KV blocks x "
             f"{self.config.kv_block_size} tokens ({kv_bytes/2**30:.2f} GiB "
             f"{'int8' if self.kv_quant else str(dtype.__name__ if hasattr(dtype, '__name__') else dtype)} cache), "
-            f"max_batch {self.config.max_batch_size}",
+            f"max_batch {self.config.max_batch_size}, "
+            f"decode_impl {self.resolved_impl}",
             ranks=[0],
         )
+
+    @property
+    def resolved_impl(self) -> str:
+        """The RESOLVED serving implementation ('pallas' | 'xla') —
+        what config.decode_impl='auto' picked on this backend."""
+        return "pallas" if self._use_kernel else "xla"
 
     def refresh_params(self, params: Any) -> None:
         """(Re)point the served weight tree — the hybrid-engine shared-
@@ -496,13 +510,8 @@ class InferenceEngine:
                 "per_channel int8 (streams codes, scales on output)"
             )
         nvme = self._offload["device"] == "nvme"
-        try:
-            host = jax.sharding.SingleDeviceSharding(
-                jax.devices()[0], memory_kind="pinned_host")
-        except ValueError:
-            # backend without a pinned_host space (CPU, jax 0.4.x): the
-            # default memory already IS host memory
-            host = jax.sharding.SingleDeviceSharding(jax.devices()[0])
+        host = jax.sharding.SingleDeviceSharding(
+            jax.devices()[0], memory_kind="pinned_host")
 
         from .quantization import ChannelQuantWeight
 
@@ -647,15 +656,8 @@ class InferenceEngine:
 
             return fetch
 
-        try:
-            dev_s = jax.sharding.SingleDeviceSharding(
-                jax.devices()[0], memory_kind="device")
-        except ValueError:
-            # backend without distinct memory spaces (CPU, jax 0.4.x):
-            # the default memory IS the only tier, so the in-jit fetch
-            # collapses to a plain placement (same fallback as
-            # _leaf_sharding / _refresh_offload)
-            dev_s = jax.sharding.SingleDeviceSharding(jax.devices()[0])
+        dev_s = jax.sharding.SingleDeviceSharding(
+            jax.devices()[0], memory_kind="device")
 
         def fetch(lp, dep=None, idx=None):
             lp = barrier(lp, dep)
@@ -1442,7 +1444,6 @@ class InferenceEngine:
         Logs a one-line compile-time summary and returns
         {programs, seconds, widths, chunks, hbm_per_bucket}."""
         import time as _time
-        import warnings as _warnings
 
         from ..analysis.costmodel import build_cost_report
         from .sampling import SamplingConfig
@@ -1505,13 +1506,7 @@ class InferenceEngine:
                 _, _, self.cache, _ = fn(*args)
                 n += 1
             if footprint:
-                # the donated-cache warning is S001 business, not ours
-                with _warnings.catch_warnings():
-                    _warnings.simplefilter("ignore")
-                    compiled = self._decode_fn(w, True).lower(
-                        self.params, self.cache, self._dev(toks),
-                        self._dev(tables), self._dev(ctx)).compile()
-                rep = build_cost_report(compiled,
+                rep = build_cost_report(self.compiled_decode(w),
                                         label=f"serving_decode[w{w}]")
                 if rep is not None:
                     self.warmup_footprints[w] = {
@@ -1542,6 +1537,36 @@ class InferenceEngine:
                 "chunks": [int(c) for c in decode_chunks],
                 "hbm_per_bucket": {
                     w: f["peak_hbm_bytes"] for w, f in sorted(fp.items())}}
+
+    def compiled_decode(self, width: int, unique_rows: bool = True):
+        """AOT-compiled decode program serving dispatches at this bucket
+        width (unique_rows=False: the shared-table variant mixed prefill
+        chunks run), lowered over inert padding rows — the inspectable
+        artifact behind warmup's footprints and chip_smoke's
+        which-kernels-compiled check. A separate compilation from the
+        jit call cache (the persistent compile cache dedupes them)."""
+        toks = np.zeros((width,), np.int32)
+        tables = np.full((width, self.config.blocks_per_seq),
+                         self.pad_block, np.int32)
+        return self._aot(self._decode_fn(width, unique_rows),
+                         self._dev(toks), self._dev(tables), self._dev(toks))
+
+    def compiled_prefill(self, bp: int, tp: int):
+        """AOT-compiled whole-prompt prefill wave for batch bucket bp x
+        token bucket tp (compiled_decode's twin)."""
+        return self._aot(
+            self._prefill_batch_fn(bp, tp),
+            self._dev(np.zeros((bp, tp), np.int32)),
+            self._dev(np.zeros((bp,), np.int32)),
+            self._dev(np.zeros((bp, self.config.blocks_per_seq), np.int32)))
+
+    def _aot(self, fn, *operands):
+        import warnings
+
+        # the donated-cache warning is S001 business, not ours
+        with warnings.catch_warnings():
+            warnings.simplefilter("ignore")
+            return fn.lower(self.params, self.cache, *operands).compile()
 
     def sanitize_numerics(self, widths: Optional[Sequence[int]] = None):
         """Numerics sanitizer (analysis/numerics.py) over the serving
@@ -1656,9 +1681,10 @@ class InferenceEngine:
         forward and accepts the longest greedy-consistent prefix — so a
         run of k accepted tokens streams the weights ONCE instead of k
         times. For full-offload serving the step cost IS the weight
-        stream (docs/PROFILE_r04.md: 88% of the host-link roofline), so
-        effective tok/s scales with the mean accepted length — the
-        policy lever the r4 profile names for bigger-than-HBM models.
+        stream (88% of the host-link estimate, measured on an earlier
+        setup; not re-measured), so effective tok/s scales with the
+        mean accepted length — the policy lever for bigger-than-HBM
+        models.
         Exact: the output equals plain greedy decoding token for token
         (worst case accepts 1 token/step = standard decode).
         ref: the reference ecosystem's prompt-lookup/self-speculative

@@ -291,12 +291,9 @@ def cache_pspec(mesh: Optional[Mesh], kv_heads: int) -> P:
 
 
 def _shard_map_kernel(fn, mesh: Mesh, in_specs, out_specs):
-    # fully-manual map (every mesh axis), via the version-portable shim
-    from ..platform.mesh import shard_map_partial
-
-    return shard_map_partial(fn, mesh, in_specs=in_specs,
-                             out_specs=out_specs,
-                             manual_axes=mesh.axis_names)
+    # fully-manual map (every mesh axis)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 class PagedCache(NamedTuple):
@@ -386,12 +383,18 @@ def _flat_slot_index(positions, block_table, block_size):
     return block_table[positions // block_size] * block_size + positions % block_size
 
 
-def _write_kv(cache_k, cache_v, k_new, v_new, flat_idx, mesh=None):
+def _write_kv(cache_k, cache_v, k_new, v_new, flat_idx, mesh=None,
+              use_kernel: bool = True):
     """Write [T, KV, D] new KV into [NBLK, bs, KV, D] caches at flat
-    slots [T] via the Pallas RMW kernel — XLA scatter costs a fixed ~3ms
-    per call on TPU (docs/PROFILE_r02.md), which at 2/layer dominated
-    the decode step. Under a TP mesh with the KV dim sharded, each device
-    RMWs its own KV slice (shard_map; slots are replicated)."""
+    slots [T] via the Pallas RMW kernel — XLA scatter cost a fixed ~3ms
+    per call on TPU (measured on an earlier setup; not re-measured),
+    which at 2/layer dominated the decode step. Under a TP mesh with
+    the KV dim sharded, each device RMWs its own KV slice (shard_map;
+    slots are replicated). use_kernel=False (decode_impl='xla') takes
+    the jnp scatter oracle, so the oracle engine shares no Pallas
+    program with the engine it checks."""
+    if not use_kernel:
+        return _write_kv_xla(cache_k, cache_v, k_new, v_new, flat_idx)
     KV = cache_k.shape[2]
     tp = _tp_size(mesh)
     if tp > 1 and KV % tp == 0:
@@ -436,17 +439,19 @@ def _write_scales_xla(k_scale, v_scale, ks_new, vs_new, flat_idx):
 
 
 def _write_kv_quant(cache_k, cache_v, k_scale, v_scale, k_new, v_new,
-                    flat_idx, mesh=None):
+                    flat_idx, mesh=None, use_kernel: bool = True):
     """Quantize [T, KV, D] new rows (quantize_kv_rows — THE rounding
     authority, shared with the fused kernel) and write codes + scale
     rows into the int8 pools. Codes ride the same Pallas RMW path as
     bf16 (_write_kv is dtype-generic); scales ride paged_scale_write
     (or the XLA scatter on the degenerate TP layout)."""
     qk, ks, qv, vs = quantize_kv_rows(k_new, v_new)
-    ck, cv = _write_kv(cache_k, cache_v, qk, qv, flat_idx, mesh)
+    ck, cv = _write_kv(cache_k, cache_v, qk, qv, flat_idx, mesh, use_kernel)
     KV = cache_k.shape[2]
     tp = _tp_size(mesh)
-    if tp > 1 and KV % tp == 0:
+    if not use_kernel or (tp > 1 and KV % tp != 0):
+        cks, cvs = _write_scales_xla(k_scale, v_scale, ks, vs, flat_idx)
+    elif tp > 1:
         sp = P(None, None, "model")
         new = P(None, "model")
         cks, cvs = _shard_map_kernel(
@@ -454,8 +459,6 @@ def _write_kv_quant(cache_k, cache_v, k_scale, v_scale, k_new, v_new,
             in_specs=(sp, sp, new, new, P(None)),
             out_specs=(sp, sp),
         )(k_scale, v_scale, ks, vs, flat_idx)
-    elif tp > 1:
-        cks, cvs = _write_scales_xla(k_scale, v_scale, ks, vs, flat_idx)
     else:
         cks, cvs = paged_scale_write(k_scale, v_scale, ks, vs, flat_idx)
     return ck, cv, cks, cvs
@@ -900,11 +903,12 @@ def decode_step(
             if quant:
                 ck, cv, cks, cvs = _write_kv_quant(
                     ck_in, cv_in, cache.k_scale[li_c], cache.v_scale[li_c],
-                    k, v, flat_idx, mesh)
+                    k, v, flat_idx, mesh, use_kernel)
                 cks = _cons(cks, mesh, None, None, "model")
                 cvs = _cons(cvs, mesh, None, None, "model")
             else:
-                ck, cv = _write_kv(ck_in, cv_in, k, v, flat_idx, mesh)
+                ck, cv = _write_kv(ck_in, cv_in, k, v, flat_idx, mesh,
+                                   use_kernel)
             ck = _cons(ck, mesh, None, None, "model", None)
             cv = _cons(cv, mesh, None, None, "model", None)
             att = _decode_attention(q, ck, cv, tables, ctx_lens, use_kernel,
@@ -1108,13 +1112,14 @@ def prefill_batch(
             ck, cv, cks, cvs = _write_kv_quant(
                 cache.k[l], cache.v[l], cache.k_scale[l], cache.v_scale[l],
                 k.reshape(B * Tp, KVh, Dh),
-                v.reshape(B * Tp, KVh, Dh), flat_idx, mesh)
+                v.reshape(B * Tp, KVh, Dh), flat_idx, mesh, use_kernel)
             new_ks.append(_cons(cks, mesh, None, None, "model"))
             new_vs.append(_cons(cvs, mesh, None, None, "model"))
         else:
             ck, cv = _write_kv(cache.k[l], cache.v[l],
                                k.reshape(B * Tp, KVh, Dh),
-                               v.reshape(B * Tp, KVh, Dh), flat_idx, mesh)
+                               v.reshape(B * Tp, KVh, Dh), flat_idx, mesh,
+                               use_kernel)
         ck = _cons(ck, mesh, None, None, "model", None)
         cv = _cons(cv, mesh, None, None, "model", None)
         new_k.append(ck)

@@ -33,8 +33,8 @@ Design notes (XLA-first):
   draw.
 - repetition penalty needs the seen-token set; a [S, vocab] presence
   bitmap rides the decode scan and is updated with max(presence,
-  one_hot(token)) — no scatter (XLA scatter carries a fixed multi-ms
-  cost on TPU, docs/PROFILE_r02.md).
+  one_hot(token)) — no scatter (XLA scatter carried a fixed multi-ms
+  cost on TPU — measured on an earlier setup; not re-measured).
 - per-sequence PRNG streams: key_i = fold_in(base, slot_i), step t uses
   fold_in(key_i, t) — batch composition never changes a sequence's
   stream (the host sampler had the same property via per-uid

@@ -1338,6 +1338,10 @@ class ServingScheduler:
             "kv_bytes_per_token": float(self.engine.kv_bytes_per_token()),
             "kv_pool_quantized": (
                 1.0 if self.engine.cache.quantized else 0.0),
+            # 1.0 = the Pallas serving kernels, 0.0 = the jnp oracle
+            # (what decode_impl='auto' resolved to on this backend)
+            "decode_kernel": (
+                1.0 if self.engine.resolved_impl == "pallas" else 0.0),
         }
         # warmup-measured static footprint per decode bucket (costmodel)
         fps = getattr(self.engine, "warmup_footprints", {})

@@ -9,9 +9,12 @@ TPU runtime already starts one process per host with coordinator env set
 pod job is just running the script on every host (gcloud ... --worker=all).
 
 What remains useful — and is implemented here — is the LOCAL spawner:
-run N controller processes on one machine (each with a slice of fake or
-real devices) for multi-process testing and single-host multi-chip
-setups. It assigns a free coordinator port, sets MASTER_ADDR/PORT +
+run N controller processes on one machine, each with a slice of fake
+CPU devices, for the multi-process tests. It is NOT how a TPU host is
+driven: ONE process drives all of a host's chips (a chip belongs to one
+process at a time, and this spawner gives ranks no per-rank chip
+visibility, so on a TPU host every rank would try to open every chip).
+It assigns a free coordinator port, sets MASTER_ADDR/PORT +
 RANK/WORLD_SIZE per rank (the env contract init_distributed consumes),
 prefixes each rank's output, and kills the whole tree if any rank dies
 (the launch.py sigkill semantics).

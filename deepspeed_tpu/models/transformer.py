@@ -28,7 +28,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..ops.attention import causal_attention
+from ..ops.attention import causal_attention, uses_flash
 
 DP = ("data", "zero", "expert")
 
@@ -83,7 +83,10 @@ class TransformerConfig:
     # save_attn_qkv | save_attn_mlp | save_attn_dots (save_attn* keep the
     # flash residuals so the backward skips the attention re-forward)
     remat: str = "none"
-    use_flash: bool = True  # pallas flash attention on TPU, XLA fallback elsewhere
+    # Pallas flash attention wherever kernels run (a TPU, or an explicit
+    # interpret request — ops/pallas.kernels_runnable) and S >= 256;
+    # the jnp reference otherwise
+    use_flash: bool = True
     # flash tiling (1024x1024 fastest at S=2048/D=128; 512x1024 at S=16k)
     flash_block_q: int = 512
     flash_block_k: int = 1024
@@ -594,28 +597,19 @@ def _rope(q, k, cfg: TransformerConfig, offset: int = 0, positions=None):
     return rot(q), rot(k)
 
 
-def _ambient_mesh():
-    """Version-portable ambient mesh (platform.mesh.ambient_mesh)."""
-    from ..platform.mesh import ambient_mesh
-
-    return ambient_mesh()
-
-
 def _shard(x, *spec):
     """Sharding constraint against the ambient mesh (set by the engine via
-    platform.mesh.use_mesh). Outside any mesh context — e.g. a plain
+    jax.sharding.set_mesh). Outside any mesh context — e.g. a plain
     single-device forward — constraints are skipped explicitly; inside a
     mesh context a bad spec raises rather than silently degrading.
 
     Inside a partial-manual shard_map (the per-worker gradient path for
     1-bit/qgZ compression), axes the caller already mapped over are
     dropped from the spec — constraints may only name Auto axes there."""
-    mesh = _ambient_mesh()
-    if mesh is None or mesh.empty:
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
         return x
-    from ..platform.mesh import manual_axes_of
-
-    manual = set(manual_axes_of(mesh))
+    manual = set(mesh.manual_axes)
     if manual:
         def strip(entry):
             if entry is None:
@@ -628,6 +622,46 @@ def _shard(x, *spec):
 
         spec = tuple(strip(e) for e in spec)
     return jax.lax.with_sharding_constraint(x, P(*spec))
+
+
+def _causal_attention(q, k, v, use_flash, alibi=None, **kw):
+    """ops.attention.causal_attention, with the flash kernel run PER
+    SHARD under a multi-device mesh. Mosaic refuses to partition a
+    pallas_call on its own ("Mosaic kernels cannot be automatically
+    partitioned. Please wrap the call in a shard_map") and accepts one
+    only where EVERY mesh axis is manual — so the call is mapped over
+    all axes not already manual: batch rows over the DP axes, heads
+    over ('model', 'seq') — the layout the caller just constrained
+    q/k/v to — and replicated over the rest. Attention needs nothing
+    from another device's rows or heads. The jnp reference path
+    partitions by itself and is left alone."""
+    mesh = jax.sharding.get_abstract_mesh()
+    free = [a for a in mesh.axis_names if a not in mesh.manual_axes]
+    if mesh.empty or mesh.size == 1 or not free \
+            or not uses_flash(q, use_flash):
+        return causal_attention(q, k, v, use_flash=use_flash, alibi=alibi,
+                                **kw)
+
+    def fit(axes, *dims):
+        got, n = [], 1
+        for a in axes:
+            if a in free and all(d % (n * mesh.shape[a]) == 0 for d in dims):
+                got.append(a)
+                n *= mesh.shape[a]
+        return tuple(got) or None
+
+    heads = fit(("model", "seq"), q.shape[2], k.shape[2])
+    spec = P(fit(DP, q.shape[0]), None, heads, None)
+    if alibi is None:
+        fn = lambda q_, k_, v_: causal_attention(
+            q_, k_, v_, use_flash=use_flash, **kw)
+        args, specs = (q, k, v), (spec, spec, spec)
+    else:  # slopes shard with the heads
+        fn = lambda q_, k_, v_, ab_: causal_attention(
+            q_, k_, v_, use_flash=use_flash, alibi=ab_, **kw)
+        args, specs = (q, k, v, alibi), (spec, spec, spec, P(heads))
+    return jax.shard_map(fn, in_specs=specs, out_specs=spec,
+                         axis_names=set(free), check_vma=False)(*args)
 
 
 def _layer_prefetch(cfg: TransformerConfig):
@@ -645,12 +679,8 @@ def _layer_prefetch(cfg: TransformerConfig):
     if (plan is None or plan.layer_store_specs is None
             or plan.prefetch_depth < 1):
         return None
-    mesh = _ambient_mesh()
-    if mesh is None or mesh.empty:
-        return None
-    from ..platform.mesh import manual_axes_of
-
-    if manual_axes_of(mesh):
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or mesh.manual_axes:
         return None  # partial-manual shard_map traces keep per-use gathers
     return (make_prefetch_gather(plan.layer_store_specs,
                                  plan.layer_tp_specs, plan.mesh),
@@ -741,11 +771,10 @@ def _attention_delta(h, lp, cfg: TransformerConfig, rng=None, positions=None,
         slopes = None
         if cfg.alibi:
             slopes = jnp.asarray(model_alibi_slopes(cfg))
-        out = causal_attention(q, k, v, use_flash=cfg.use_flash,
-                               window=window,
-                               block_q=cfg.flash_block_q,
-                               block_k=cfg.flash_block_k,
-                               alibi=slopes)  # [B,S,H,D]
+        out = _causal_attention(q, k, v, cfg.use_flash, alibi=slopes,
+                                window=window,
+                                block_q=cfg.flash_block_q,
+                                block_k=cfg.flash_block_k)  # [B,S,H,D]
 
     out = _shard(out, DP, "seq", "model", None)
     out = jnp.einsum("bshd,hde->bse", out, lp["wo"].astype(x.dtype))
@@ -829,9 +858,7 @@ def _moe_mlp_delta(h, lp, cfg: TransformerConfig, rng=None):
     if cfg.moe_dropless:
         from ..moe.dropless import dropless_moe_ffn
 
-        mesh = _ambient_mesh()
-        ep = 1 if mesh is None or mesh.empty else \
-            int(mesh.shape.get("expert", 1))
+        ep = int(jax.sharding.get_abstract_mesh().shape.get("expert", 1))
         res = dropless_moe_ffn(
             tokens,
             lp["w_router"],

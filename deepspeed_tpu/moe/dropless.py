@@ -44,8 +44,6 @@ import jax.numpy as jnp
 
 from .sharded_moe import _apply_noise, _load_balance_loss, _one_hot
 
-_HAS_RAGGED_DOT = hasattr(jax.lax, "ragged_dot")
-
 
 @dataclasses.dataclass(frozen=True)
 class DroplessOut:
@@ -131,16 +129,13 @@ def sort_by_expert(expert_idx) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     return order, order // K, flat[order]
 
 
-def grouped_mm(xs, w, counts, impl: str = "auto"):
+def grouped_mm(xs, w, counts, impl: str = "ragged"):
     """Grouped (ragged) GEMM: rows of `xs` [A, E] are expert-contiguous
     segments sized by `counts` [X]; each segment contracts with its own
     expert weight from `w` [X, E, F] -> [A, F].
 
-    impl: 'auto' = lax.ragged_dot when this jax has it, else the
-    masked-scan oracle; 'ragged' / 'dense' force a path ('dense' is the
-    X-pass masked scan — the correctness oracle and the fallback)."""
-    if impl == "auto":
-        impl = "ragged" if _HAS_RAGGED_DOT else "dense"
+    impl: 'ragged' = lax.ragged_dot; 'dense' = the X-pass masked scan
+    (the correctness oracle)."""
     if impl == "ragged":
         return jax.lax.ragged_dot(xs, w.astype(xs.dtype),
                                   counts.astype(jnp.int32))
@@ -241,7 +236,7 @@ def _a2a_wire(tokens, idx, weights, ep_size, w_in, w_out, w_gate,
 
 def dropless_apply(
     tokens, expert_idx, weights, counts, w_in, w_out, w_gate=None,
-    b_in=None, b_out=None, *, act, impl: str = "auto",
+    b_in=None, b_out=None, *, act, impl: str = "ragged",
 ):
     """The ragged wire on PRE-COMPUTED routing decisions — the serving
     entry point (inference/model.py _mlp): the scheduler's mixed
@@ -267,7 +262,7 @@ def dropless_moe_ffn(
     noisy_gate_policy: Optional[str] = None,
     shard=None,      # fn(x, *mesh axis names) sharding constraint
     ep_size: int = 1,
-    impl: str = "auto",
+    impl: str = "ragged",
 ) -> DroplessOut:
     """Dropless dispatch -> grouped expert MLP -> combine.
 

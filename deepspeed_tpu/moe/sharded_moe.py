@@ -61,10 +61,8 @@ def _replicated_draw(draw_fn):
     noise tensor is [T, X]-small, so the cost is nil and EP=1 == EP=N
     stays bitwise."""
     x = draw_fn()
-    from ..platform.mesh import ambient_mesh, manual_axes_of
-
-    mesh = ambient_mesh()
-    if mesh is None or mesh.empty or manual_axes_of(mesh):
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or mesh.manual_axes:
         return x
     from jax.sharding import PartitionSpec as P
 

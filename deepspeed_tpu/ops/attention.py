@@ -4,12 +4,14 @@ TPU-native analog of the reference's fused attention kernels
 (ref: csrc/transformer/ softmax/transform kernels for training,
 csrc/transformer/inference/csrc/softmax.cu for decode). Two paths:
 
-- `_xla_attention`: pure-jnp reference, used on CPU (the fake-mesh test
-  platform) and as the numerics oracle in tests — the analog of the
-  reference's torch-reference checks in tests/unit/ops.
+- `_xla_attention`: pure-jnp reference — the numerics oracle in tests
+  (the analog of the reference's torch-reference checks in
+  tests/unit/ops), and what runs where no kernel can (CPU without an
+  interpret request) or is wanted (`use_flash=False`, S < 256).
 - Pallas flash attention (ops/pallas/flash_attention.py): the TPU hot
-  path, flash-style tiling in VMEM; selected when running on TPU and
-  `use_flash=True`.
+  path, flash-style tiling in VMEM; selected by `use_flash=True`
+  wherever kernels run (ops/pallas.kernels_runnable: a TPU, or an
+  explicit interpret request).
 
 Layout is [batch, seq, heads, head_dim]. GQA: the flash kernel consumes
 KV heads in place via BlockSpec index maps — callers must NOT pre-repeat
@@ -22,6 +24,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from .pallas import kernels_runnable
 
 _NEG_INF = -1e30
 
@@ -75,27 +79,11 @@ def _xla_attention(q, k, v, causal: bool = True, window: int = 0,
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
-def _load_flash():
-    """Resolve the Pallas flash kernel once; returns None (with a visible
-    warning) when unavailable so fallback is explicit, never silent."""
-    global _flash_fn, _flash_resolved
-    if _flash_resolved:
-        return _flash_fn
-    _flash_resolved = True
-    try:
-        from .pallas.flash_attention import flash_attention
-
-        _flash_fn = flash_attention
-    except ImportError as e:
-        from ..utils.logging import warning_once
-
-        warning_once(f"Pallas flash attention unavailable ({e}); using XLA attention")
-        _flash_fn = None
-    return _flash_fn
-
-
-_flash_fn = None
-_flash_resolved = False
+def uses_flash(q, use_flash: bool) -> bool:
+    """THE flash-vs-reference selection, from what the call can observe:
+    the caller's flag, the sequence length (short sequences do not fill
+    a tile), and whether kernels run here at all."""
+    return use_flash and q.shape[1] >= 256 and kernels_runnable()
 
 
 def causal_attention(q, k, v, use_flash: bool = True, window: int = 0,
@@ -115,19 +103,14 @@ def causal_attention(q, k, v, use_flash: bool = True, window: int = 0,
 
     block_q/block_k tune the flash tiling (TransformerConfig
     flash_block_q/k — 1024x1024 measured fastest at S=2048/D=128,
-    512x1024 at S=16384; docs/PROFILE_r03.md)."""
-    if use_flash and q.shape[1] >= 256 and _on_tpu():
-        flash = _load_flash()
-        if flash is not None:
-            return flash(q, k, v, causal=True, window=window,
-                         block_q=block_q, block_k=block_k, alibi=alibi)
+    512x1024 at S=16384 on an earlier setup; not re-measured)."""
+    if uses_flash(q, use_flash):
+        # imported where it runs: jax.experimental.pallas costs ~1.5 s
+        # that a training-only or CPU process should not pay at import
+        from .pallas.flash_attention import flash_attention
+
+        return flash_attention(q, k, v, causal=True, window=window,
+                               block_q=block_q, block_k=block_k, alibi=alibi)
     n_rep = q.shape[2] // k.shape[2]
     return _xla_attention(q, _repeat_kv(k, n_rep), _repeat_kv(v, n_rep),
                           causal=True, window=window, alibi=alibi)
-
-
-def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform in ("tpu",)
-    except Exception:
-        return False

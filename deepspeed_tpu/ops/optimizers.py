@@ -6,9 +6,9 @@ multi_tensor_adam_cuda:128, csrc/lamb/fused_lamb_cuda_kernel.cu,
 csrc/lion/multi_tensor_lion.cu, ops/adagrad). The reference needs
 hand-written multi-tensor CUDA kernels to fuse the elementwise update;
 on TPU one `tree.map` under jit gives XLA the whole update to fuse onto
-the VPU, so the update is bandwidth-bound by construction (the bench
-step spends ~27ms on update+norm for 350M params ≈ 2.2x the raw HBM
-read/write time of the state it touches — docs/PROFILE_r02.md).
+the VPU, so the update is bandwidth-bound by construction (~27ms on
+update+norm for 350M params ≈ 2.2x the raw HBM read/write time of the
+state it touches — measured on an earlier setup; not re-measured).
 
 API shape: functional `init(params) -> state`, `update(grads, state,
 params, lr, step) -> (new_params, new_state)` pairs, fp32 throughout —

@@ -44,7 +44,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .flash_attention import NEG_INF, _dot, _interpret
+from . import interpret
+from .flash_attention import NEG_INF, _dot
 
 
 def _evo_kernel(
@@ -160,7 +161,7 @@ def evoformer_flash_fwd(q, k, v, bias1=None, bias2=None,
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
         ],
-        interpret=_interpret(),
+        interpret=interpret(),
     )(qf, kf, vf, b1, b2)
     o = jnp.moveaxis(out.reshape(B, S, H, N, D), 2, 3)
     if with_lse:
@@ -324,7 +325,7 @@ def evoformer_flash_bwd(q, k, v, bias1, bias2, o, lse, do,
         out_specs=pl.BlockSpec((1, bq, D), q_idx),
         out_shape=jax.ShapeDtypeStruct((G, N, D), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
-        interpret=_interpret(),
+        interpret=interpret(),
     )(qf, kf, vf, b1, b2, dof, lse3, delta3)
 
     # dk/dv: query-sequential; swap the roles of the inner grid dims
@@ -373,7 +374,7 @@ def evoformer_flash_bwd(q, k, v, bias1, bias2, o, lse, do,
             pltpu.VMEM((bk, D), jnp.float32),
             pltpu.VMEM((bk, 1), jnp.float32),
         ],
-        interpret=_interpret(),
+        interpret=interpret(),
     )(qf, kf, vf, b1, b2, dof, lse3, delta3)
 
     db1 = None
@@ -411,7 +412,7 @@ def evoformer_flash_bwd(q, k, v, bias1, bias2, o, lse, do,
                                    lambda bh, iq, j, s: (bh, iq, j)),
             out_shape=jax.ShapeDtypeStruct((BH, N, N), bias2.dtype),
             scratch_shapes=[pltpu.VMEM((bq, bk), jnp.float32)],
-            interpret=_interpret(),
+            interpret=interpret(),
         )(qf, kf, vf, b1, b2, dof, lse3, delta3)
         db2 = db2_f.reshape(B, 1, H, N, N)
 

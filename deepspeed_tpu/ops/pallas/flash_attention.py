@@ -34,22 +34,24 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import interpret
+
 NEG_INF = -1e30
 
 
-def _interpret() -> bool:
-    """Run kernels through the Pallas interpreter off-TPU so the CPU test
-    lane exercises the real kernel math (ref: tests/unit/ops runs CUDA
-    kernels only on GPU; the interpreter removes that gap here)."""
-    return jax.default_backend() != "tpu"
-
-
 def _dot(a, b, trans_a=False, trans_b=False):
-    """MXU matmul with f32 accumulation, keeping input dtype (bf16 ok)."""
+    """MXU matmul with f32 accumulation, keeping input dtype (bf16 ok).
+
+    bf16 operands take the MXU's native single pass whatever
+    `jax_default_matmul_precision` says — Mosaic rejects a higher
+    contract precision on bf16 operands ("Bad lhs type"); f32 operands
+    follow the config."""
     ca = 0 if trans_a else 1
     cb = 1 if trans_b else 0
     return jax.lax.dot_general(
-        a, b, (((ca,), (cb,)), ((), ())), preferred_element_type=jnp.float32
+        a, b, (((ca,), (cb,)), ((), ())), preferred_element_type=jnp.float32,
+        precision=(jax.lax.Precision.DEFAULT if a.dtype == jnp.bfloat16
+                   else None),
     )
 
 
@@ -249,7 +251,8 @@ def _flash_fwd(q, k, v, slopes, causal, block_q, block_k, H, KV, window=0,
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
         ],
-        interpret=_interpret(),
+        interpret=interpret(),
+        name="flash_fwd",
     )(*inputs)
     return o[:, :S], lse[:, 0, :S]
 
@@ -445,7 +448,8 @@ def _flash_bwd(q, k, v, slopes, o, lse, do, causal, block_q, block_k, H, KV,
         out_specs=pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, Sp, D), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
-        interpret=_interpret(),
+        interpret=interpret(),
+        name="flash_bwd_dq",
     )(*dq_inputs)
 
     # q-head index for the dk/dv grid: (b_kv, g) → q head row in [B*H)
@@ -484,7 +488,8 @@ def _flash_bwd(q, k, v, slopes, o, lse, do, causal, block_q, block_k, H, KV,
             pltpu.VMEM((bk, D), jnp.float32),
             pltpu.VMEM((bk, D), jnp.float32),
         ],
-        interpret=_interpret(),
+        interpret=interpret(),
+        name="flash_bwd_dkv",
     )(*dkv_inputs)
 
     return dq[:, :S], dk[:, :S], dv[:, :S]

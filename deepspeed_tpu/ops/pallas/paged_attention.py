@@ -31,7 +31,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .flash_attention import NEG_INF, _dot, _interpret
+from . import interpret
+from .flash_attention import NEG_INF, _dot
 
 
 def _arena_block(idx, n_blocks: int):
@@ -271,8 +272,14 @@ def _decode_kernel(
                 ck_out[0] = jnp.where(wmask, qkn[None], kb)
                 cv_out[0] = jnp.where(wmask, qvn[None], vb)
                 # the scale tile RMWs alongside its code block (same
-                # target index map, (bs, KV) row mask)
-                smask = jnp.logical_and(slot >= 0, rowm[:, :, 0])
+                # target index map, (bs, KV) row mask). The mask is
+                # built from its own 2-D iota: slicing rowm[:, :, 0]
+                # (a rank change on an i1 vector) trips an internal LLO
+                # check in libtpu 0.0.34's Mosaic.
+                smask = jnp.logical_and(
+                    slot >= 0,
+                    jax.lax.broadcasted_iota(jnp.int32, (block_size, 1), 0)
+                    == jnp.maximum(slot, 0) % block_size)
                 cks_out[0] = jnp.where(smask, skn[None], ks_ref[0])
                 cvs_out[0] = jnp.where(smask, svn[None], vs_ref[0])
             else:
@@ -449,7 +456,8 @@ def paged_decode_attention(q, k_cache, v_cache, block_table, ctx_lens,
         grid_spec=grid_spec,
         out_shape=out_shape,
         input_output_aliases=aliases,
-        interpret=_interpret(),
+        interpret=interpret(),
+        name="paged_decode_grid",
     )
     sc = (k_scale, v_scale) if quant else ()
     tail = (ab,) if alibi else ()
@@ -777,13 +785,13 @@ def paged_decode_fused(q, k_cache, v_cache, block_table, ctx_lens,
         grid=(S,),
         in_specs=[
             vmem(), vmem(), vmem(),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ] + ([vmem()] if alibi else []),
         out_specs=[
             vmem(),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         scratch_shapes=[
             pltpu.VMEM((2, 2, bs, KV, D), k_cache.dtype),
@@ -805,7 +813,8 @@ def paged_decode_fused(q, k_cache, v_cache, block_table, ctx_lens,
         ],
         # args: 4 scalar prefetch, q, kn, vn, k_cache, v_cache [, ab]
         input_output_aliases={7: 1, 8: 2},
-        interpret=_interpret(),
+        interpret=interpret(),
+        name="paged_decode_fused",
     )(block_table, ctx_lens, slots.astype(jnp.int32), allow, qg,
       k_new, v_new, k_cache, v_cache, *ab)
     return out[:, :, :G, :].reshape(S, H, D), ck, cv
@@ -821,13 +830,14 @@ def _kv_write_kernel(
 ):
     """Read-modify-write one token row into its cache block.
 
-    XLA's scatter lowering costs ~3ms per call on TPU regardless of size
-    (measured, docs/PROFILE_r02.md); at 2 scatters x n_layers per decode
-    step that dominated the engine. This kernel instead RMWs whole cache
-    blocks through VMEM: tokens are pre-sorted by slot so consecutive
-    grid steps hitting the same block keep it resident, and the block is
-    copied from the aliased input only on first visit (a later copy
-    would erase rows written by earlier same-block steps)."""
+    XLA's scatter lowering cost ~3ms per call on TPU regardless of size
+    (measured on an earlier setup; not re-measured); at 2 scatters x
+    n_layers per decode step that dominated the engine. This kernel
+    instead RMWs whole cache blocks through VMEM: tokens are pre-sorted
+    by slot so consecutive grid steps hitting the same block keep it
+    resident, and the block is copied from the aliased input only on
+    first visit (a later copy would erase rows written by earlier
+    same-block steps)."""
     t = pl.program_id(0)
     slot = slots_ref[t]
 
@@ -895,7 +905,8 @@ def paged_kv_write(cache_k, cache_v, k_new, v_new, flat_slots):
         ],
         # alias caches through: in-place RMW, no copy of the arena
         input_output_aliases={3: 0, 4: 1},
-        interpret=_interpret(),
+        interpret=interpret(),
+        name="paged_kv_write",
     )(slots, kn, vn, cache_k, cache_v)
 
 

@@ -24,11 +24,10 @@ from functools import partial
 from typing import Tuple
 
 import jax
-from ..platform.mesh import ambient_mesh
 import jax.numpy as jnp
 import numpy as np
 
-from ..ops.attention import _on_tpu
+from ..ops.pallas import kernels_runnable
 
 
 def _merge_partials(out, lse, o_hop, lse_hop):
@@ -200,10 +199,9 @@ def _ring_bwd(q, k, v, out, lse, do, axis_name: str,
 
 
 def _ring_smap(impl, mesh, in_specs, out_specs):
-    from ..platform.mesh import shard_map_partial
-
-    return shard_map_partial(impl, mesh, in_specs=in_specs,
-                             out_specs=out_specs, manual_axes={"seq"})
+    return jax.shard_map(impl, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, axis_names={"seq"},
+                         check_vma=False)
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
@@ -248,7 +246,6 @@ _ring_flash_global.defvjp(lambda q, k, v, mesh, bq, bk:
 def ring_causal_attention(
     q: jax.Array, k: jax.Array, v: jax.Array, mesh=None,
     use_flash: bool = False, block_q: int = 512, block_k: int = 1024,
-    force_kernel: bool = False,
 ) -> jax.Array:
     """SPMD entry: q/k/v [B, S, H|KV, D] sequence-sharded over 'seq';
     runs ring_attention under shard_map with every other axis auto.
@@ -256,31 +253,24 @@ def ring_causal_attention(
     forward's hop partials merge by logsumexp, and the backward is its
     own ring (_ring_bwd) wired through a global-level custom_vjp.
 
-    The kernel route engages on TPU only (the same gate
-    causal_attention applies — off-TPU the interpreter would run every
-    hop orders of magnitude slower, and the custom_vjp route needs
-    jit); force_kernel=True overrides for the interpret-mode kernel
-    test lane."""
+    The kernel route engages wherever kernels run (the same
+    ops/pallas.kernels_runnable gate causal_attention applies: a TPU,
+    or an explicit interpret request — the interpreter runs every hop
+    orders of magnitude slower, and the custom_vjp route needs jit)."""
     if mesh is None:
-        mesh = ambient_mesh()
-    if mesh is None or mesh.empty or mesh.shape.get("seq", 1) <= 1:
+        mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or mesh.shape.get("seq", 1) <= 1:
         # no ring: plain causal attention (honoring the flash setting)
         from ..ops.attention import causal_attention
 
         return causal_attention(q, k, v, use_flash=use_flash)
-    if use_flash and (force_kernel or _on_tpu()):
+    if use_flash and kernels_runnable():
         return _ring_flash_global(q, k, v, mesh, block_q, block_k)
     from jax.sharding import PartitionSpec as P
 
-    from ..platform.mesh import shard_map_partial
-
     spec = P(None, "seq", None, None)
-    fn = shard_map_partial(
+    fn = _ring_smap(
         partial(ring_attention, axis_name="seq", use_flash=False,
                 block_q=block_q, block_k=block_k),
-        mesh,
-        in_specs=(spec, spec, spec),
-        out_specs=spec,
-        manual_axes={"seq"},
-    )
+        mesh, in_specs=(spec, spec, spec), out_specs=spec)
     return fn(q, k, v)

@@ -14,7 +14,6 @@ from typing import Dict, List, Optional, Sequence, Tuple, Union
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..platform.mesh import shard_map_partial  # noqa: F401  (re-export)
 
 MeshAxes = Union[None, str, Tuple[str, ...]]
 

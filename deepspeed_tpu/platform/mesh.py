@@ -25,13 +25,11 @@ stage boundaries may cross DCN; 'zero' sits inside 'data' so sub-group
 gathers ride shorter paths than cross-group traffic.
 """
 
-from typing import Dict, List, Optional, Sequence
+from typing import Dict, Optional, Sequence
 
 import jax
 import numpy as np
 from jax.sharding import Mesh
-
-from ..utils.logging import logger
 
 MESH_AXES = ("pipe", "data", "zero", "expert", "seq", "model")
 
@@ -71,95 +69,21 @@ def build_mesh(
     """Build the global Mesh.
 
     On real TPU slices this uses `mesh_utils.create_device_mesh` so axis
-    adjacency maps onto the physical ICI torus; on CPU/fake platforms a
-    plain reshape of the device list is used.
+    adjacency maps onto the physical ICI torus — a shape it cannot lay
+    out RAISES (an enumeration-order reshape would quietly change which
+    links TP rides); on CPU a plain reshape of the device list is used.
     """
     if devices is None:
         devices = jax.devices()
     sizes = resolve_axis_sizes(axis_sizes or {}, n_devices=len(devices))
     shape = tuple(sizes[ax] for ax in MESH_AXES)
-    if devices[0].platform in ("tpu",) and len(devices) > 1:
-        try:
-            from jax.experimental import mesh_utils
+    if devices[0].platform == "tpu" and len(devices) > 1:
+        from jax.experimental import mesh_utils
 
-            dev_array = mesh_utils.create_device_mesh(shape, devices=list(devices))
-            return Mesh(dev_array, MESH_AXES)
-        except Exception as e:  # pragma: no cover - topology-dependent
-            logger.warning(f"mesh_utils.create_device_mesh failed ({e}); using reshape order")
-    dev_array = np.array(list(devices)).reshape(shape)
+        dev_array = mesh_utils.create_device_mesh(shape, devices=list(devices))
+    else:
+        dev_array = np.array(list(devices)).reshape(shape)
     return Mesh(dev_array, MESH_AXES)
-
-
-def use_mesh(mesh: Mesh):
-    """Ambient-mesh context, version-portable: `jax.sharding.set_mesh`
-    where it exists (newer jax), else the legacy Mesh context manager —
-    both make bare-PartitionSpec constraints inside jitted bodies resolve
-    against `mesh`. Every engine dispatch path routes through this one
-    helper instead of calling set_mesh directly."""
-    set_mesh = getattr(jax.sharding, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    return mesh  # Mesh is itself a context manager on older jax
-
-
-def ambient_mesh():
-    """The mesh bare-P constraints resolve against: the abstract mesh on
-    newer jax (set via use_mesh -> set_mesh), else the legacy context
-    mesh (`with mesh:`, what use_mesh enters on jax 0.4.x). Returns an
-    EMPTY mesh (`.empty` is True) when no context is active — callers
-    test `mesh is None or mesh.empty`."""
-    get_abs = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_abs is not None:
-        return get_abs()
-    from jax._src import mesh as _mesh_lib
-
-    return _mesh_lib.thread_resources.env.physical_mesh
-
-
-# Manual-axes bookkeeping for the LEGACY shard_map path: new jax exposes
-# the mapped axes on the ambient abstract mesh (mesh.manual_axes); old
-# jax has no equivalent, so shard_map_partial records them around the
-# body's trace and current_manual_axes() surfaces them to the in-model
-# constraint helpers (_shard / _constraint_auto_only).
-_LEGACY_MANUAL_AXES: List[frozenset] = []
-
-
-def current_manual_axes() -> frozenset:
-    """Mesh axes the innermost shard_map already maps over (legacy-jax
-    bookkeeping; on new jax prefer the ambient mesh's manual_axes)."""
-    return _LEGACY_MANUAL_AXES[-1] if _LEGACY_MANUAL_AXES else frozenset()
-
-
-def manual_axes_of(mesh) -> frozenset:
-    """Manual axes visible right now: the ambient mesh's own annotation
-    (new jax) unioned with the legacy shard_map bookkeeping."""
-    own = frozenset(getattr(mesh, "manual_axes", ()) or ())
-    return own | current_manual_axes()
-
-
-def shard_map_partial(f, mesh: Mesh, in_specs, out_specs, manual_axes,
-                      check: bool = False):
-    """Partial-manual shard_map, version-portable: the new `jax.shard_map`
-    (axis_names = the MANUAL axes) where it exists, else the legacy
-    experimental API (auto = every OTHER mesh axis). check maps to
-    check_vma/check_rep respectively."""
-    manual = set(manual_axes)
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  axis_names=manual, check_vma=check)
-    from jax.experimental.shard_map import shard_map as legacy
-
-    def f_recording(*a, **k):
-        _LEGACY_MANUAL_AXES.append(frozenset(manual))
-        try:
-            return f(*a, **k)
-        finally:
-            _LEGACY_MANUAL_AXES.pop()
-
-    return legacy(f_recording, mesh=mesh, in_specs=in_specs,
-                  out_specs=out_specs, check_rep=check,
-                  auto=frozenset(mesh.axis_names) - manual)
 
 
 def single_device_mesh() -> Mesh:

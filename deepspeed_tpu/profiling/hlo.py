@@ -684,8 +684,8 @@ def collective_volumes(compiled) -> Dict[str, Dict[str, float]]:
 # pre-opt CPU form this tree compiles — `call(...)` into named rng
 # computations (`_uniform.103`, `_threefry_fold_in.256`). Shardings ride
 # either the instruction itself or a `Sharding` custom-call consumer;
-# shard_map bodies show up as computations called through
-# `SPMDFullToShardShape` operands with `sharding={manual}`.
+# shard_map bodies show up as `xla.sdy.manual_computation_body*`
+# computations.
 
 # rng computation names jax stamps on the lowered helpers, leading
 # underscore stripped and trailing `.N` suffix removed. split/fold_in/
@@ -745,22 +745,10 @@ def classify_sharding(sharding: Optional[str]) -> str:
 
 def _manual_computations(comps: Dict[str, List[Dict]]) -> set:
     """Names of computations that execute inside a shard_map manual
-    context: called with an operand whose def carries
-    `sharding={manual}` / SPMDFullToShardShape (plus jax's
-    `shmap_body*` naming), closed transitively over the call graph."""
-    manual = {name for name in comps if name.startswith("shmap_body")}
-    for name, instrs in comps.items():
-        defs = {i["name"]: i for i in instrs}
-        for ins in instrs:
-            if not ins["called"]:
-                continue
-            for op in ins["operands"]:
-                d = defs.get(op)
-                if d is not None and (
-                        "sharding={manual}" in d["attrs"]
-                        or "SPMDFullToShardShape" in d["attrs"]):
-                    manual.update(ins["called"])
-                    break
+    context: the `xla.sdy.manual_computation_body*` computations a
+    shard_map lowers to, closed transitively over the call graph."""
+    manual = {name for name in comps
+              if name.startswith("xla.sdy.manual_computation_body")}
     # a call inside a manual computation is manual too
     changed = True
     while changed:

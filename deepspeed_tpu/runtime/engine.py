@@ -36,7 +36,7 @@ from ..comm.logger import comms_logger
 from ..monitor.monitor import MonitorMaster
 from ..ops.optimizers import Optimizer, build_optimizer
 from ..parallel import sharding as shd
-from ..platform.mesh import build_mesh, data_parallel_size, describe, use_mesh
+from ..platform.mesh import build_mesh, data_parallel_size, describe
 from ..resilience.faults import fault_point
 from ..utils.logging import log_dist, logger
 from ..utils.timers import BATCH_TIMER, STEP_TIMER, SynchronizedWallClockTimer, ThroughputTimer
@@ -513,13 +513,7 @@ class DeepSpeedTPUEngine:
         s = NamedSharding(self.mesh, spec)
         if not self._offload_param:
             return s
-        try:
-            return s.with_memory_kind("pinned_host")
-        except ValueError:
-            # backend without a pinned_host space (CPU, jax 0.4.x): the
-            # default memory IS host memory there, so the tier placement
-            # is already what offload_param asks for
-            return s
+        return s.with_memory_kind("pinned_host")
 
     def _make_param_fetch(self):
         """Returns an inside-jit H2D fetch of the host-parked param tree
@@ -628,7 +622,7 @@ class DeepSpeedTPUEngine:
             ),
         )
         arg = init_rng if param_init_fn is not None else params
-        with jax.transfer_guard("allow"), use_mesh(mesh):
+        with jax.transfer_guard("allow"), jax.sharding.set_mesh(mesh):
             state = jax.jit(make, out_shardings=out_shardings)(arg)
         # park the freshly initialized params in the host tier (no-op
         # unless offload_param; steady-state parking happens the same way
@@ -1080,12 +1074,13 @@ class DeepSpeedTPUEngine:
         # batch leaves [gas|M, batch, ...] sharded on the batch dim (the
         # pipelined whole-batch layout [M, mb, S] shares the shape
         # convention), worker_delta leaves worker-major on dim 0
-        wrapped = shd.shard_map_partial(
+        wrapped = jax.shard_map(
             body,
-            mesh,
+            mesh=mesh,
             in_specs=(P(), P(manual), P(None, manual), P()),
             out_specs=(P(manual), P(manual)),
-            manual_axes=manual,
+            axis_names=set(manual),
+            check_vma=False,
         )
         if with_delta:
             return wrapped
@@ -1226,7 +1221,7 @@ class DeepSpeedTPUEngine:
                     jax.tree.map(jnp.zeros_like, es))
 
         shd_of = lambda tr: jax.tree.map(lambda x: x.sharding, tr)
-        with use_mesh(self.mesh):
+        with jax.sharding.set_mesh(self.mesh):
             wmu2, ew, es = jax.jit(
                 t,
                 out_shardings=(shd_of(opt["worker_mu"]), shd_of(opt["error_w"]),
@@ -1248,7 +1243,7 @@ class DeepSpeedTPUEngine:
             step_fn = self._zo_programs[kind] = self._build_zoadam_step(kind)
         batch = self._reshape_gas(batch)
         batch = self.shard_batch(batch, leading_accum_dim=True)
-        with use_mesh(self.mesh):
+        with jax.sharding.set_mesh(self.mesh):
             self.state, metrics = step_fn(self.state, batch)
         self._zo_sched.advance(s)
         return metrics
@@ -1397,7 +1392,7 @@ class DeepSpeedTPUEngine:
                 fn = self._zo_programs["onebit"] = \
                     self._build_zoadam_step("onebit")
             label, block = "train_step[zoadam]", None
-        with warnings.catch_warnings(), use_mesh(self.mesh):
+        with warnings.catch_warnings(), jax.sharding.set_mesh(self.mesh):
             warnings.simplefilter("ignore")
             lowered = fn.lower(self.state, batch)
             compiled = lowered.compile()
@@ -1507,7 +1502,7 @@ class DeepSpeedTPUEngine:
                 # footprint story (grads + params resident together)
                 if self._grad_step_fn is None:
                     self._grad_step_fn = self._build_grad_step()
-                with warnings.catch_warnings(), use_mesh(self.mesh):
+                with warnings.catch_warnings(), jax.sharding.set_mesh(self.mesh):
                     warnings.simplefilter("ignore")
                     lowered_g = self._grad_step_fn.lower(
                         self._materialized_params(), self.state.step, batch
@@ -1612,7 +1607,7 @@ class DeepSpeedTPUEngine:
             self._grad_step_fn = self._build_grad_step()
         batch = self._reshape_gas(batch)
         batch = self.shard_batch(batch, leading_accum_dim=True)
-        with use_mesh(self.mesh):
+        with jax.sharding.set_mesh(self.mesh):
             grads, loss, grad_norm = self._grad_step_fn(
                 self._materialized_params(), self.state.step, batch
             )
@@ -1845,7 +1840,7 @@ class DeepSpeedTPUEngine:
             (jax.tree_util.keystr(p), tuple(l.shape), str(l.dtype))
             for p, l in jax.tree_util.tree_flatten_with_path(batch)[0]
         )
-        with use_mesh(self.mesh):
+        with jax.sharding.set_mesh(self.mesh):
             compiled = self._train_compiled_cache.get(shape_key)
             if compiled is None:
                 # AOT compile (per batch-shape signature, matching jit's
@@ -1989,7 +1984,7 @@ class DeepSpeedTPUEngine:
 
             batch = jax.tree.map(add_micro_dim, batch)
         batch = self.shard_batch(batch, leading_accum_dim=self.pipelined)
-        with use_mesh(self.mesh):
+        with jax.sharding.set_mesh(self.mesh):
             return float(self._eval_step_fn(self._materialized_params(), batch))
 
     # ------------------------------------------------------------------

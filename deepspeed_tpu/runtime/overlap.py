@@ -38,7 +38,7 @@ The engine activates the layer by entering `overlap_scope` around the
 loss trace (`zero_optimization.overlap_comm`, knobs `prefetch_depth` /
 `bucket_mb`); models and the pipeline runtime read the ambient plan at
 trace time — the same ambient-context discipline as
-platform.mesh.use_mesh.
+jax.sharding.set_mesh.
 """
 
 import contextlib

@@ -36,7 +36,6 @@ a leading microbatch dim.
 from typing import Any, Callable, Optional
 
 import jax
-from ..platform.mesh import ambient_mesh
 from .overlap import barrier as _overlap_barrier, current_plan
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
@@ -51,10 +50,7 @@ def _constraint_auto_only(t, spec):
     spec — inside the per-worker gradient shard_map (1-bit/0-1/qgZ x
     pipeline), the data axes are already mapped over and constraints may
     only name Auto axes (same rule as models/transformer._shard)."""
-    mesh = ambient_mesh()
-    from ..platform.mesh import manual_axes_of
-
-    manual = set(manual_axes_of(mesh)) if mesh else set()
+    manual = set(jax.sharding.get_abstract_mesh().manual_axes)
     if manual:
         def strip(entry):
             if entry is None:
@@ -187,10 +183,8 @@ def pipeline_apply(
 
     # Outside a pipe>1 mesh (pure-function tests, pipe folded away) run as
     # a plain vmap with no sharding annotations.
-    mesh = ambient_mesh()
-    has_pipe = (
-        mesh is not None and not mesh.empty and mesh.shape.get("pipe", 1) > 1
-    )
+    mesh = jax.sharding.get_abstract_mesh()
+    has_pipe = not mesh.empty and mesh.shape.get("pipe", 1) > 1
     vstage = jax.vmap(
         stage_fn,
         in_axes=(0, 0, 0, 0),
@@ -378,10 +372,8 @@ def pipeline_apply_circular(
     key_state = jnp.zeros((n_stage,) + mb_keys.shape[1:], mb_keys.dtype)
     stage_ids = jnp.arange(n_stage)
 
-    mesh = ambient_mesh()
-    has_pipe = (
-        mesh is not None and not mesh.empty and mesh.shape.get("pipe", 1) > 1
-    )
+    mesh = jax.sharding.get_abstract_mesh()
+    has_pipe = not mesh.empty and mesh.shape.get("pipe", 1) > 1
     vstage = jax.vmap(
         stage_fn,
         in_axes=(1, 0, 0, 0, 0),  # params [v, P, ...] batch over dim 1

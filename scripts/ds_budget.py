@@ -190,21 +190,13 @@ def build_reports():
 
     from deepspeed_tpu.inference import init_inference
     import jax.numpy as jnp
-    import warnings
 
     params = T.init(mcfg, jax.random.PRNGKey(0))
     icfg = dict(max_seq_len=32, kv_block_size=8, num_kv_blocks=32,
                 min_prefill_bucket=8, max_batch_size=8)
     eng = init_inference(params, mcfg, dict(icfg), dtype=jnp.float32)
-    toks = np.zeros((8,), np.int32)
-    ctx = np.zeros((8,), np.int32)
-    tables = np.full((8, eng.config.blocks_per_seq), eng.pad_block, np.int32)
-    with warnings.catch_warnings():
-        warnings.simplefilter("ignore")
-        compiled = eng._decode_fn(8, True).lower(
-            eng.params, eng.cache, eng._dev(toks), eng._dev(tables),
-            eng._dev(ctx)).compile()
-    decode_cost = build_cost_report(compiled, label="serving_decode[w8]")
+    decode_cost = build_cost_report(eng.compiled_decode(8),
+                                    label="serving_decode[w8]")
 
     # the int8-quantized FUSED decode program (kv_cache_dtype='int8',
     # decode_impl='pallas' — the Pallas kernel in interpret mode, so
@@ -217,15 +209,14 @@ def build_reports():
     from deepspeed_tpu.platform.accelerator import chip_roofline
     from deepspeed_tpu.profiling.hlo import max_gather_bytes
 
+    from deepspeed_tpu.ops.pallas import interpret_kernels
+
     eng_q = init_inference(
         params, mcfg, dict(icfg, kv_cache_dtype="int8",
                            decode_impl="pallas"),
         dtype=jnp.float32)
-    with warnings.catch_warnings():
-        warnings.simplefilter("ignore")
-        compiled_q = eng_q._decode_fn(8, True).lower(
-            eng_q.params, eng_q.cache, eng_q._dev(toks),
-            eng_q._dev(tables), eng_q._dev(ctx)).compile()
+    with interpret_kernels():  # a Pallas program named on the CPU
+        compiled_q = eng_q.compiled_decode(8)
     quant_cost = build_cost_report(compiled_q,
                                    label="serving_decode[w8,int8kv]")
     if quant_cost is not None:

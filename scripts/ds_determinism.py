@@ -271,12 +271,11 @@ def _selftest():
     # D002: a real fp additive psum over an axis the pin declares
     # layout-varying, no waiver
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
 
     def body(x):
         return jax.lax.psum(x, "expert")
 
-    reduced = jax.jit(shard_map(
+    reduced = jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=P("expert", None),
         out_specs=P(None, None)))
     txt = reduced.lower(jnp.ones((8, 8), jnp.float32)).compile().as_text()

@@ -270,14 +270,17 @@ def build_programs(only=None):
         ctx = np.zeros((8,), np.int32)
         tables = np.full((8, qeng.config.blocks_per_seq), qeng.pad_block,
                          np.int32)
-        with warnings.catch_warnings():
+        from deepspeed_tpu.ops.pallas import interpret_kernels
+
+        # a Pallas program named on the CPU: interpret mode by request
+        with warnings.catch_warnings(), interpret_kernels():
             warnings.simplefilter("ignore")
             ldq = qeng._decode_fn(8, True).lower(
                 qeng.params, qeng.cache, qeng._dev(toks),
                 qeng._dev(tables), qeng._dev(ctx))
             cdq = ldq.compile()
-        record("serving_decode_w8_int8", cdq, ldq,
-               qeng.sanitize_numerics(widths=[8]))
+            findings = qeng.sanitize_numerics(widths=[8])
+        record("serving_decode_w8_int8", cdq, ldq, findings)
     return out
 
 

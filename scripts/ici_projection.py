@@ -11,7 +11,8 @@ v5p-256 mesh shapes from the ring-collective model:
   by the payload ratio of the real model vs the slice.
 
 Run under JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8.
-Writes the 'ici_projection' block of SCALING_r04.json.
+Prints the projection as one JSON line. A projection from compiled
+bytes and the platform/accelerator.LINKS constant, not a measurement.
 """
 
 import json
@@ -26,7 +27,6 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 def main():
     import jax
 
-    jax.config.update("jax_platforms", "cpu")
     import deepspeed_tpu as ds
     from deepspeed_tpu.models import transformer as T
     from deepspeed_tpu.profiling.hlo import collective_volumes
@@ -90,13 +90,6 @@ def main():
         "projected_70b_gb_per_step_upper": round(proj_bytes / 1e9, 1),
         "ici_seconds_at_100GBps": round(proj_bytes / ici_gbps, 3),
     }
-    path = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "SCALING_r04.json")
-    doc = {}
-    if os.path.exists(path):
-        doc = json.load(open(path))
-    doc["ici_projection"] = out
-    json.dump(doc, open(path, "w"), indent=1, sort_keys=True)
     print(json.dumps(out))
 
 

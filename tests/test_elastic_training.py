@@ -352,9 +352,13 @@ class TestElasticTrainerJourney:
         assert sorted(chaos_hist) == list(range(1, T_STEPS + 1))
         assert json.dumps(sorted(clean.ledger.items())) \
             == json.dumps(sorted(chaos.ledger.items()))
-        # bitwise before the kill; reassociation-only drift after
-        assert all(clean_hist[s] == chaos_hist[s] for s in (1, 2, 3))
-        for s in range(4, T_STEPS + 1):
+        # bitwise up to the mirror the recovery rolled back to (step 2);
+        # reassociation-only drift after. Step 3 was committed on world
+        # 2, rolled back, and REPLAYED on the shrunk world (dp 1 x gas
+        # 2) — another compiled program, so its loss may differ in the
+        # last ulp (it does under the installed XLA).
+        assert all(clean_hist[s] == chaos_hist[s] for s in (1, 2))
+        for s in range(3, T_STEPS + 1):
             assert abs(clean_hist[s] - chaos_hist[s]) \
                 <= 1e-3 * abs(clean_hist[s])
 

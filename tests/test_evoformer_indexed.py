@@ -13,7 +13,8 @@ from deepspeed_tpu.runtime.indexed_dataset import (
 )
 
 # interpreter-/compile-heavy: excluded from the fast lane (-m 'not slow')
-pytestmark = pytest.mark.slow
+pytestmark = [pytest.mark.slow,
+              pytest.mark.usefixtures("pallas_interpret_module")]
 
 
 def dense_oracle(q, k, v, biases):

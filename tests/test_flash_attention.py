@@ -24,7 +24,8 @@ from deepspeed_tpu.ops.pallas.flash_attention import (
 )
 
 # interpreter-/compile-heavy: excluded from the fast lane (-m 'not slow')
-pytestmark = pytest.mark.slow
+pytestmark = [pytest.mark.slow,
+              pytest.mark.usefixtures("pallas_interpret_module")]
 
 
 def make_qkv(rng, B=2, S=128, H=2, KV=None, D=64, dtype=jnp.float32):

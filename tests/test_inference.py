@@ -89,6 +89,7 @@ class TestStateManager:
         assert set(tbl[0, 2:]) == {0}
 
 
+@pytest.mark.usefixtures("pallas_interpret")
 class TestPagedDecodeKernel:
     @pytest.mark.parametrize("window", [0, 20, 48])
     def test_windowed_matches_oracle(self, rng, window):
@@ -130,15 +131,16 @@ class TestPagedDecodeKernel:
         np.testing.assert_allclose(out, ref, rtol=2e-3, atol=2e-3)
 
     def test_sparse_engine_decode_kernel_path(self, rng):
-        """End-to-end: a sparse-trained model served with use_kernel
-        forced on (Pallas interpret off-TPU) matches the XLA-path
+        """End-to-end: a sparse-trained model served with the Pallas
+        kernels (decode_impl='pallas') matches the XLA-path
         engine — the allowed_slots kernel routing is exact."""
         cfg, params = small_model(
             attention_impl="sparse", sparse_mode="fixed", sparse_block=16,
             sparse_num_local_blocks=2, sparse_num_global_blocks=1)
-        xla_eng = engine_for(cfg, params, kv_block_size=8)
-        ker_eng = engine_for(cfg, params, kv_block_size=8)
-        ker_eng._use_kernel = True   # Pallas interpret path on CPU
+        xla_eng = engine_for(cfg, params, kv_block_size=8,
+                             decode_impl="xla")
+        ker_eng = engine_for(cfg, params, kv_block_size=8,
+                             decode_impl="pallas")
         prompt = np.asarray(rng.integers(0, 128, 18), np.int32)
         l_x = xla_eng.put([0], [prompt.copy()])
         l_k = ker_eng.put([0], [prompt.copy()])
@@ -164,6 +166,7 @@ class TestPagedDecodeKernel:
         np.testing.assert_allclose(out, ref, rtol=2e-3, atol=2e-3)
 
 
+@pytest.mark.usefixtures("pallas_interpret")
 class TestFusedWriteAttend:
     """Fused write+attend decode kernel (paged_decode_attention with
     k_new/v_new/slots): one launch replaces paged_kv_write + attention.
@@ -299,13 +302,14 @@ class TestFusedWriteAttend:
         np.testing.assert_allclose(ck, rk, rtol=1e-6, atol=1e-6)
 
     def test_engine_fused_path_matches_xla_engine(self, rng):
-        """End-to-end: engine with the kernel forced on (Pallas
-        interpret off-TPU) takes the fused write+attend path for
+        """End-to-end: engine with decode_impl='pallas' takes the
+        fused write+attend path for
         single-token decode batches and matches the XLA engine."""
         cfg, params = small_model()
-        xla_eng = engine_for(cfg, params, kv_block_size=8)
-        ker_eng = engine_for(cfg, params, kv_block_size=8)
-        ker_eng._use_kernel = True
+        xla_eng = engine_for(cfg, params, kv_block_size=8,
+                             decode_impl="xla")
+        ker_eng = engine_for(cfg, params, kv_block_size=8,
+                             decode_impl="pallas")
         prompts = [np.asarray(rng.integers(0, 128, n), np.int32)
                    for n in (9, 4, 13)]
         uids = [0, 1, 2]
@@ -1248,6 +1252,7 @@ def test_empty_token_array_raises(rng):
         eng.put([0], [np.asarray([], np.int32)])
 
 
+@pytest.mark.usefixtures("pallas_interpret")
 class TestAlibiServing:
     """ALiBi (Bloom/falcon-rw class) through every decode path: the
     (S, NB)-grid kernel, the fused write+attend mode, the per-sequence
@@ -1334,9 +1339,8 @@ class TestAlibiServing:
         model_alibi_slopes, neither shares attention code)."""
         cfg, params = small_model(variant="gpt2", alibi=True,
                                   embedding_layernorm=True)
-        eng = engine_for(cfg, params, kv_block_size=8)
-        if use_kernel:
-            eng._use_kernel = True  # Pallas interpret path on CPU
+        eng = engine_for(cfg, params, kv_block_size=8,
+                         decode_impl="pallas" if use_kernel else "xla")
         prompt = list(np.asarray(rng.integers(0, 128, 11), np.int32))
         logits = eng.put([0], [np.asarray(prompt, np.int32)])
         ref = oracle_next_logits(params, cfg, prompt)

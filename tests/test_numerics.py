@@ -522,7 +522,6 @@ class TestErrorFeedbackResiduals:
             compressed_mean,
             padded_cols,
         )
-        from deepspeed_tpu.platform.mesh import use_mesh
 
         mesh = self._mesh()
         dp, n = 8, 64
@@ -530,7 +529,7 @@ class TestErrorFeedbackResiduals:
             jax.random.PRNGKey(0), (dp, n)).astype(jnp.bfloat16)
         ew = jnp.zeros((dp, padded_cols(n, dp)), jnp.float32)
         es = jnp.zeros((dp, padded_cols(n, dp) // dp), jnp.float32)
-        with use_mesh(mesh):
+        with jax.sharding.set_mesh(mesh):
             out, ew2, es2 = jax.jit(
                 lambda p, a, b: compressed_mean(
                     p.astype(jnp.float32), a, b, mesh))(grads_bf16, ew, es)
@@ -548,7 +547,6 @@ class TestErrorFeedbackResiduals:
             compressed_mean,
             padded_cols,
         )
-        from deepspeed_tpu.platform.mesh import use_mesh
 
         mesh = self._mesh()
         dp, n = 8, 64
@@ -557,7 +555,7 @@ class TestErrorFeedbackResiduals:
         es = jnp.zeros((dp, padded_cols(n, dp) // dp), jnp.float32)
         total_true = jnp.zeros((n,), jnp.float32)
         total_comp = jnp.zeros((n,), jnp.float32)
-        with use_mesh(mesh):
+        with jax.sharding.set_mesh(mesh):
             f = jax.jit(lambda p, a, b: compressed_mean(
                 p.astype(jnp.float32), a, b, mesh))
             for t in range(20):

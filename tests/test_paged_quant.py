@@ -47,6 +47,10 @@ from deepspeed_tpu.resilience.integrity import HandoffIntegrityError
 
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
+# every engine here names decode_impl explicitly; the Pallas ones run
+# interpreted on the CPU lane, Mosaic-compiled on the hardware lane
+pytestmark = pytest.mark.usefixtures("pallas_interpret_module")
+
 # PINNED tolerances (docs/paged_attention.md): kernel-vs-oracle on the
 # SAME int8 pool differs only by f32 reassociation; int8-vs-full-
 # precision differs by the quantization error itself (per-(slot, head)
@@ -69,7 +73,7 @@ def engine_for(model, **over):
     # table slots) — the fast lane budget pays per traced grid step
     cfg, params = model
     kw = dict(max_seq_len=32, kv_block_size=8, num_kv_blocks=32,
-              min_prefill_bucket=8, max_batch_size=8)
+              min_prefill_bucket=8, max_batch_size=8, decode_impl="xla")
     kw.update(over)
     return init_inference(params, cfg, kw, dtype=jnp.float32)
 

@@ -330,54 +330,3 @@ class TestSpecStatsPlumbing:
         _, stats = eng.generate_speculative(
             prompts, max_new_tokens=6, draft_len=4, return_stats=True)
         assert stats["draft_collapsed_steps"] == stats["steps"] > 0
-
-
-class TestProbeRetry:
-    def test_retry_succeeds_after_flaky_attempts(self, monkeypatch):
-        from deepspeed_tpu.platform import accelerator as acc
-
-        calls = []
-
-        def flaky(timeout):
-            calls.append(timeout)
-            if len(calls) < 3:
-                return None, None, True  # timeout: the flake class
-            return ["dev0"], None, False
-
-        sleeps = []
-        monkeypatch.setattr(acc, "probe_devices", flaky)
-        monkeypatch.setattr(time, "sleep", lambda s: sleeps.append(s))
-        devs, err, timed, attempts = acc.probe_devices_with_retry(
-            1.0, retries=3, backoff_s=2.0)
-        assert devs == ["dev0"] and attempts == 3 and not timed
-        assert sleeps == [2.0, 4.0]  # exponential backoff
-
-    def test_guard_marks_timeout_as_infra_flake(self, monkeypatch,
-                                                capsys):
-        import json
-
-        from deepspeed_tpu.platform import accelerator as acc
-
-        monkeypatch.setattr(acc, "probe_devices",
-                            lambda t: (None, None, True))
-        monkeypatch.setattr(time, "sleep", lambda s: None)
-        rc = acc.bench_device_guard("some_metric")
-        doc = json.loads(capsys.readouterr().out.strip())
-        assert rc == 0  # flake: the driver retries, not bisects
-        assert doc["infra_flake"] is True
-        assert doc["metric"] == "some_metric"
-        assert doc["probe_attempts"] == 3
-
-    def test_guard_keeps_real_errors_fatal(self, monkeypatch, capsys):
-        import json
-
-        from deepspeed_tpu.platform import accelerator as acc
-
-        monkeypatch.setattr(acc, "probe_devices",
-                            lambda t: (None, "InitError: boom", False))
-        monkeypatch.setattr(time, "sleep", lambda s: None)
-        rc = acc.bench_device_guard("some_metric")
-        doc = json.loads(capsys.readouterr().out.strip())
-        assert rc == 1
-        assert doc["infra_flake"] is False
-        assert "boom" in doc["error"]

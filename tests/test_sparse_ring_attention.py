@@ -21,7 +21,8 @@ from deepspeed_tpu.ops.sparse_attention import (
 )
 
 # interpreter-/compile-heavy: excluded from the fast lane (-m 'not slow')
-pytestmark = pytest.mark.slow
+pytestmark = [pytest.mark.slow,
+              pytest.mark.usefixtures("pallas_interpret_module")]
 
 VOCAB = 128
 

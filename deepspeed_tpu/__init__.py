@@ -44,28 +44,36 @@ def initialize(
     Returns the engine; optimizer and lr scheduler are owned by the
     engine and built from the config's optimizer/scheduler blocks.
     """
-    cfg = parse_config(config)
-    comm.init_distributed()
-    if params is None:
-        if param_init_fn is None:
-            raise ValueError("initialize() needs `params` or `param_init_fn`")
-        import jax
+    from .utils import profiler
 
-        rng = init_rng if init_rng is not None else jax.random.PRNGKey(cfg.seed)
-        params = jax.eval_shape(param_init_fn, rng)
-    return DeepSpeedTPUEngine(
-        cfg,
-        loss_fn,
-        params,
-        param_logical_specs=param_logical_specs,
-        mesh=mesh,
-        rules=rules,
-        has_aux=has_aux,
-        param_init_fn=param_init_fn,
-        init_rng=init_rng,
-        pipelined=pipelined,
-        pipeline_virtual_stages=pipeline_virtual_stages,
-    )
+    # always-kept set-up spans (docs/tracing.md): train.init, and under
+    # it train.init.shapes here and train.init.state in the engine
+    with profiler.span("train.init", always=True):
+        cfg = parse_config(config)
+        comm.init_distributed()
+        if params is None:
+            if param_init_fn is None:
+                raise ValueError(
+                    "initialize() needs `params` or `param_init_fn`")
+            import jax
+
+            rng = (init_rng if init_rng is not None
+                   else jax.random.PRNGKey(cfg.seed))
+            with profiler.span("train.init.shapes", always=True):
+                params = jax.eval_shape(param_init_fn, rng)
+        return DeepSpeedTPUEngine(
+            cfg,
+            loss_fn,
+            params,
+            param_logical_specs=param_logical_specs,
+            mesh=mesh,
+            rules=rules,
+            has_aux=has_aux,
+            param_init_fn=param_init_fn,
+            init_rng=init_rng,
+            pipelined=pipelined,
+            pipeline_virtual_stages=pipeline_virtual_stages,
+        )
 
 
 def init_inference(*args, **kwargs):

@@ -42,8 +42,9 @@ from ..resilience.integrity import (
 )
 from ..models import transformer as T
 from ..ops.pallas import kernels_runnable
+from ..utils import profiler
 from ..utils.logging import log_dist
-from ..utils.sync import serving_readback
+from ..utils.sync import host_sync, serving_readback
 from . import model as M
 from .ragged import StateManager
 
@@ -364,7 +365,11 @@ class InferenceEngine:
         self._prepare_fn = None
         self._layer_xform = None
         self._top_xform = None
-        self.refresh_params(params)
+        # awaited, so each set-up phase's span carries its own time and
+        # the device memory it left behind (docs/tracing.md)
+        with profiler.span("init.transform", always=True):
+            self.refresh_params(params)
+            host_sync(self.params)
         self.state = StateManager(
             num_blocks=self.config.num_kv_blocks,
             block_size=self.config.kv_block_size,
@@ -388,11 +393,12 @@ class InferenceEngine:
                 f"kv_cache_dtype must be 'auto' or 'int8' "
                 f"(got {self.config.kv_cache_dtype!r})")
         self.kv_quant = self.config.kv_cache_dtype == "int8"
-        self.cache = M.init_cache(
-            model_config, self.config.num_kv_blocks + 1,
-            self.config.kv_block_size, dtype, mesh=self.mesh,
-            kv_quant=self.kv_quant,
-        )
+        with profiler.span("init.pool", always=True):
+            self.cache = host_sync(M.init_cache(
+                model_config, self.config.num_kv_blocks + 1,
+                self.config.kv_block_size, dtype, mesh=self.mesh,
+                kv_quant=self.kv_quant,
+            ))
         self._prefill_batch_fns: Dict[Tuple[int, int], Any] = {}
         # keyed (batch_width, unique_rows)
         self._decode_fns: Dict[Tuple[int, bool], Any] = {}
@@ -1441,8 +1447,17 @@ class InferenceEngine:
         the serving scheduler validates its admission config against
         and feeds to the monitor.
 
-        Logs a one-line compile-time summary and returns
-        {programs, seconds, widths, chunks, hbm_per_bucket}."""
+        Every program is AWAITED here (so its execution is charged to
+        it, not to whatever runs next) and leaves an always-kept span
+        `warmup.program` (ids kind, width, unique) with children
+        `warmup.trace` / `warmup.lower` / `warmup.compile` (the stages
+        jax.monitoring reports) and `warmup.execute` (docs/tracing.md).
+
+        Logs a one-line summary and one line per program, and returns
+        {programs, seconds, widths, chunks, hbm_per_bucket, split,
+        per_program}: `split` divides `seconds` into trace_s, lower_s,
+        compile_s, execute_s and other_s (host work that is none of
+        them), `per_program` does the same for each program."""
         import time as _time
 
         from ..analysis.costmodel import build_cost_report
@@ -1457,7 +1472,30 @@ class InferenceEngine:
                 w *= 2
         widths = [int(w) for w in widths]
         t0 = _time.perf_counter()
-        n = 0
+        per_program: List[Dict[str, Any]] = []
+        stage_names = ("trace", "lower", "compile")
+
+        def warm(kind: str, w: int, call, **ids):
+            """Run one program, await it, and account for its time."""
+            with profiler.span("warmup.program", always=True, kind=kind,
+                               width=w, **ids) as sp, \
+                    profiler.compile_spans("warmup") as stages:
+                out = call()
+                t_run = _time.perf_counter_ns()
+                host_sync(out)
+                t_done = _time.perf_counter_ns()
+                profiler.record("warmup.execute", t_run, t_done,
+                                always=True)
+            split = profiler.split_ns(sp.t0_ns, sp.t1_ns, [
+                (st, [(r.t0_ns, r.t1_ns) for r in stages
+                      if r.name == f"warmup.{st}"])
+                for st in stage_names] + [("execute", [(t_run, t_done)])])
+            per_program.append(dict(
+                {"kind": kind, "width": w, **ids,
+                 "seconds": (sp.t1_ns - sp.t0_ns) * 1e-9},
+                **{f"{k}_s": v * 1e-9 for k, v in split.items()}))
+            return out
+
         rt = self.recompile_tracker
         use_sampler = not (scfg.greedy and not scfg.needs_presence)
         with_pres = bool(presence and scfg.needs_presence)
@@ -1473,20 +1511,20 @@ class InferenceEngine:
             for uniq in ((True, False) if chunked else (True,)):
                 rt.record(f"serving_decode[w{w},u{int(uniq)}]",
                           (toks, tables, ctx))
-                logits, self.cache = self._decode_fn(w, uniq)(
-                    self.params, self.cache, self._dev(toks),
-                    self._dev(tables), self._dev(ctx))
-                n += 1
+                logits, self.cache = warm(
+                    "decode", w, lambda: self._decode_fn(w, uniq)(
+                        self.params, self.cache, self._dev(toks),
+                        self._dev(tables), self._dev(ctx)),
+                    unique=int(uniq))
             if with_pres:
                 pres = np.zeros((w, V), np.uint8)
                 rt.record(f"serving_sample[w{w}]", (steps, pres))
-                self._sample_fn(scfg, True)(
-                    logits, keys, self._dev(steps), self._dev(pres))
+                warm("sample", w, lambda: self._sample_fn(scfg, True)(
+                    logits, keys, self._dev(steps), self._dev(pres)))
             else:
                 rt.record(f"serving_sample[w{w}]", (steps,))
-                self._sample_fn(scfg, False)(logits, keys,
-                                             self._dev(steps))
-            n += 1
+                warm("sample", w, lambda: self._sample_fn(scfg, False)(
+                    logits, keys, self._dev(steps)))
             for C in decode_chunks:
                 C = int(C)
                 if C < 1:
@@ -1503,11 +1541,12 @@ class InferenceEngine:
                     args.append(self._dev(steps))
                     if with_pres:
                         args.append(self._dev(np.zeros((w, V), np.uint8)))
-                _, _, self.cache, _ = fn(*args)
-                n += 1
+                _, _, self.cache, _ = warm("fused", w, lambda: fn(*args),
+                                           chunk=C)
             if footprint:
-                rep = build_cost_report(self.compiled_decode(w),
-                                        label=f"serving_decode[w{w}]")
+                with profiler.span("warmup.footprint", always=True, width=w):
+                    rep = build_cost_report(self.compiled_decode(w),
+                                            label=f"serving_decode[w{w}]")
                 if rep is not None:
                     self.warmup_footprints[w] = {
                         "peak_hbm_bytes": float(rep.peak_hbm_bytes),
@@ -1522,6 +1561,10 @@ class InferenceEngine:
                             rep.exposed_comm_s * 1e6),
                     }
         dt = _time.perf_counter() - t0
+        n = len(per_program)
+        split = {f"{k}_s": sum(pp[f"{k}_s"] for pp in per_program)
+                 for k in stage_names + ("execute",)}
+        split["other_s"] = dt - sum(split.values())
         fp = self.warmup_footprints
         fp_note = (f", peak {max(f['peak_hbm_bytes'] for f in fp.values()) / 2**20:.0f} MiB"
                    if fp else "")
@@ -1530,13 +1573,19 @@ class InferenceEngine:
             f"{widths}{' +chunked' if chunked else ''}, fused depths "
             f"{[int(c) for c in decode_chunks]}, "
             f"sampling={'on' if use_sampler else 'greedy'}) in {dt:.1f}s"
-            f"{fp_note}",
+            f"{fp_note}: " + " ".join(
+                f"{k} {v:.2f}" for k, v in split.items()),
             ranks=[0],
         )
+        for pp in per_program:
+            log_dist("serving warmup program: " + " ".join(
+                f"{k} {v:.3f}" if isinstance(v, float) else f"{k} {v}"
+                for k, v in pp.items()), ranks=[0])
         return {"programs": n, "seconds": dt, "widths": widths,
                 "chunks": [int(c) for c in decode_chunks],
                 "hbm_per_bucket": {
-                    w: f["peak_hbm_bytes"] for w, f in sorted(fp.items())}}
+                    w: f["peak_hbm_bytes"] for w, f in sorted(fp.items())},
+                "split": split, "per_program": per_program}
 
     def compiled_decode(self, width: int, unique_rows: bool = True):
         """AOT-compiled decode program serving dispatches at this bucket
@@ -1945,9 +1994,10 @@ def init_inference(
             raise ValueError("conflicting offload in config and kwarg")
         offload = off
     icfg = InferenceConfig(**cfg)
-    return InferenceEngine(model_config, params, icfg, dtype,
-                           quantization=quantization, mesh=mesh,
-                           offload=offload)
+    with profiler.span("init.inference", always=True):
+        return InferenceEngine(model_config, params, icfg, dtype,
+                               quantization=quantization, mesh=mesh,
+                               offload=offload)
 
 
 def init_inference_from_hf(

@@ -62,6 +62,7 @@ import numpy as np
 from ..config.config import ServingSchedulerConfig
 from ..resilience.faults import fault_point
 from ..resilience.integrity import HandoffIntegrityError
+from ..utils import profiler
 from ..utils.logging import log_dist
 from ..utils.sync import serving_readback
 from .engine import InferenceEngine, _bucket
@@ -80,6 +81,20 @@ SchedulerConfig = ServingSchedulerConfig
 WAITING, PREFILL, RUNNING, FINISHED, HANDOFF = (
     "waiting", "prefill", "running", "finished", "handoff")
 
+# The phases that tile one scheduling iteration (docs/tracing.md): each
+# feeds its counter (seconds) always and a `sched.<phase>` span when
+# tracing is active. `readback` is the host waiting for the device;
+# the other seven are host work the device may or may not overlap.
+PHASES = {"tick": "tick_s", "admit": "admit_s", "select": "select_s",
+          "build": "build_s", "launch": "launch_s", "commit": "commit_s",
+          "readback": "readback_wait_s", "accept": "accept_s"}
+# an iteration whose time outside `readback` exceeds this is counted in
+# counters["slow_iterations"] and leaves an always-kept span: a stalled
+# host loop names itself even with tracing off
+SLOW_ITERATION_NS = 50_000_000
+# finished requests whose TTFT/TPOT metrics() percentiles are taken over
+LATENCY_WINDOW = 4096
+
 
 @dataclasses.dataclass
 class Request:
@@ -91,6 +106,7 @@ class Request:
     eos_token_id: Optional[int]
     stream: int                      # sampling stream id (defaults to rid)
     arrival: float                   # perf_counter() at submit
+    admit_t: Optional[float] = None  # perf_counter() at FIRST admission
     state: str = WAITING
     uid: Optional[int] = None        # engine uid while admitted
     fed: int = 0                     # base tokens already in the KV cache
@@ -183,7 +199,7 @@ class ServingScheduler:
         # decode replica (router.pump() drains this; disaggregated mode)
         self.handoff_ready: "deque[Request]" = deque()
         self._next_rid = 0
-        self.counters: Dict[str, int] = {
+        self.counters: Dict[str, float] = {
             "steps": 0, "admitted": 0, "finished": 0, "preemptions": 0,
             "batched_tokens": 0, "fused_steps": 0, "chained_steps": 0,
             "wave_prefills": 0, "handoffs": 0, "adopted": 0,
@@ -191,7 +207,19 @@ class ServingScheduler:
             "spill_rejects": 0, "spill_integrity_failures": 0,
             "spill_releases": 0, "chain_fallbacks": 0,
             "deadline_rejections": 0, "starvation_protected": 0,
+            # seconds of step()/run() spent in each phase (PHASES):
+            # together they are the loop's wall time
+            "tick_s": 0.0, "admit_s": 0.0, "select_s": 0.0,
+            "build_s": 0.0, "launch_s": 0.0, "commit_s": 0.0,
+            "readback_wait_s": 0.0, "accept_s": 0.0,
+            # sum over first admissions of admit_t - arrival
+            "queue_wait_s": 0.0,
+            "slow_iterations": 0,
         }
+        self._phases = profiler.Phases("sched", "iteration", PHASES,
+                                       sums=self.counters)
+        self._iteration = 0
+        self._it_rows, self._it_kind, self._it_delay0 = 0, "idle", 0.0
         self.spec_stats: Dict[str, float] = {
             "steps": 0, "verified_chunks": 0, "draft_tokens": 0,
             "accepted_tokens": 0, "draft_collapsed_steps": 0,
@@ -201,8 +229,11 @@ class ServingScheduler:
         # premium-impact signal (inference/autoscaler.py) needs to know
         # WHOSE deadlines the fleet is failing, not just how many
         self.slo_rejections: Dict[str, int] = {}
-        self._ttft: List[float] = []
-        self._tpot: List[float] = []
+        # the last LATENCY_WINDOW finished requests (bounded: a
+        # long-lived server must not grow them, and its percentiles
+        # should describe recent traffic)
+        self._ttft: "deque[float]" = deque(maxlen=LATENCY_WINDOW)
+        self._tpot: "deque[float]" = deque(maxlen=LATENCY_WINDOW)
         # set by ServingRouter (fault-point ctx + health identity);
         # standalone schedulers leave it None
         self.replica_index: Optional[int] = None
@@ -442,8 +473,14 @@ class ServingScheduler:
         req.handoff = False
         req.fed = seen
         self.active.append(req)
+        self._stamp_admission(req)
         self.counters["adopted"] += 1
         self.counters["admitted"] += 1
+
+    def _stamp_admission(self, req: Request) -> None:
+        if req.admit_t is None:
+            req.admit_t = time.perf_counter()
+            self.counters["queue_wait_s"] += req.admit_t - req.arrival
 
     @property
     def has_work(self) -> bool:
@@ -590,6 +627,29 @@ class ServingScheduler:
             if len(req.output) > 1:
                 self._tpot.append((req.finish_t - req.first_token_t)
                                   / (len(req.output) - 1))
+        if profiler.active():
+            self._record_request(req)
+
+    @staticmethod
+    def _record_request(req: Request) -> None:
+        """The finished request's life as spans under its rid, from the
+        stamps it carries (perf_counter seconds, the spans' clock)."""
+        def ns(t):
+            return int(t * 1e9)
+
+        root = profiler.record(
+            "request", ns(req.arrival), ns(req.finish_t), parent=0,
+            rid=req.rid, reason=req.finish_reason, tokens=len(req.output),
+            preemptions=req.preemptions)
+        if req.admit_t is None:
+            return
+        profiler.record("request.queue", ns(req.arrival), ns(req.admit_t),
+                        parent=root, rid=req.rid)
+        if req.first_token_t is not None:
+            profiler.record("request.prefill", ns(req.admit_t),
+                            ns(req.first_token_t), parent=root, rid=req.rid)
+            profiler.record("request.decode", ns(req.first_token_t),
+                            ns(req.finish_t), parent=root, rid=req.rid)
 
     # -- admission -------------------------------------------------------
     def _resume_from_spill(self, req: Request) -> str:
@@ -655,6 +715,7 @@ class ServingScheduler:
             req.pending = None
             req.state = PREFILL
         self.active.append(req)
+        self._stamp_admission(req)
         self.counters["admitted"] += 1
         self.counters["spill_resumes"] += 1
         return "resumed"
@@ -737,6 +798,7 @@ class ServingScheduler:
             req.n_cached += match.n_cached
             req.state = PREFILL
             self.active.append(req)
+            self._stamp_admission(req)
             self.counters["admitted"] += 1
             admitted_now += 1
         for req in reversed(scanned):  # preserve arrival order
@@ -749,6 +811,8 @@ class ServingScheduler:
         bucket width). Returns the device token array — NOT read back
         here; the caller decides when the readback lands."""
         eng, scfg = self.engine, self.scfg
+        ph = self._phases
+        ph.mark("build")
         streams = np.zeros((bucket,), np.uint32)
         steps = np.zeros((bucket,), np.int32)
         for req, row in sample_rows:
@@ -756,7 +820,6 @@ class ServingScheduler:
             # draw counter = the sampled token's POSITION = seen_tokens
             # after this dispatch's commit (put()/generate() contract)
             steps[row] = eng.state.get(req.uid).seen_tokens
-        keys = eng._row_keys(self.seed, streams)
         if scfg.needs_presence:
             V = self.engine.cfg.vocab_size
             pres = np.zeros((bucket, V), np.uint8)
@@ -764,11 +827,14 @@ class ServingScheduler:
                 pres[row] = req.presence
             eng.recompile_tracker.record(
                 f"serving_sample[w{bucket}]", (steps, pres))
+            ph.mark("launch", kind="sample", rows=bucket)
             return eng._sample_fn(scfg, True)(
-                logits_dev, keys, eng._dev(steps), eng._dev(pres))
+                logits_dev, eng._row_keys(self.seed, streams),
+                eng._dev(steps), eng._dev(pres))
         eng.recompile_tracker.record(f"serving_sample[w{bucket}]", (steps,))
-        return eng._sample_fn(scfg, False)(logits_dev, keys,
-                                           eng._dev(steps))
+        ph.mark("launch", kind="sample", rows=bucket)
+        return eng._sample_fn(scfg, False)(
+            logits_dev, eng._row_keys(self.seed, streams), eng._dev(steps))
 
     def _dispatch_wave(self, reqs: List[Request]) -> List[_Part]:
         """Whole-prompt prefill waves (put()'s grouped compiled waves):
@@ -776,6 +842,9 @@ class ServingScheduler:
         a (batch-bucket, token-bucket) and samples its last-token rows
         on device."""
         eng = self.engine
+        ph = self._phases
+        ph.mark("build")
+        self._it_kind = "wave"
         reqs = sorted(reqs, key=lambda r: len(r.base))
         groups: Dict[int, List[Request]] = {}
         for r in reqs:
@@ -787,6 +856,7 @@ class ServingScheduler:
                  for w0 in range(0, len(g), cap)]
         parts: List[_Part] = []
         for wave in waves:
+            ph.mark("build")
             tp = _bucket(max(len(r.base) for r in wave),
                          eng.config.min_prefill_bucket)
             bp = _bucket(len(wave), 1)
@@ -801,9 +871,11 @@ class ServingScheduler:
                     [r.uid], eng.config.blocks_per_seq)[0]
             eng.recompile_tracker.record(
                 f"serving_prefill[b{bp},t{tp}]", (toks_b, n_real, tables))
+            ph.mark("launch", kind="wave", rows=int(n_real.sum()))
             logits, eng.cache = eng._prefill_batch_fn(bp, tp)(
                 eng.params, eng.cache, eng._dev(toks_b),
                 eng._dev(n_real), eng._dev(tables))
+            ph.mark("commit")
             sample_rows = []
             for row, r in enumerate(wave):
                 eng.state.commit(r.uid, len(r.base), token_ids=r.base)
@@ -811,9 +883,11 @@ class ServingScheduler:
                 r.state = RUNNING  # pending arrives at finalize
                 sample_rows.append((r, row))
             tok_dev = self._sample_part(logits, sample_rows, bp)
+            ph.mark("commit")
             parts.append(_Part("wave", sample_rows, tok_dev))
             self.counters["wave_prefills"] += len(wave)
             self.counters["batched_tokens"] += int(n_real.sum())
+            self._it_rows += int(n_real.sum())
         return parts
 
     def _dispatch_mixed(self, rows) -> Optional[_Part]:
@@ -821,9 +895,14 @@ class ServingScheduler:
         1-token decode rows + multi-token prefill chunk rows (the
         Sarathi piggyback). rows: [(req, chunk, sample)]."""
         eng = self.engine
+        ph = self._phases
+        ph.mark("build")
         n_rows = sum(len(c) for _, c, _ in rows)
         if n_rows == 0:
             return None
+        if self._it_kind == "idle":
+            self._it_kind = "mixed"
+        self._it_rows += n_rows
         sp = _bucket(n_rows, 8)
         toks = np.zeros((sp,), np.int32)
         ctx = np.zeros((sp,), np.int32)  # pad rows: ctx 0 = inert
@@ -846,10 +925,12 @@ class ServingScheduler:
         unique = all(len(c) == 1 for _, c, _ in rows)
         eng.recompile_tracker.record(
             f"serving_decode[w{sp},u{int(unique)}]", (toks, tables, ctx))
+        ph.mark("launch", kind="mixed", rows=n_rows)
         logits, eng.cache = eng._decode_fn(sp, unique)(
             eng.params, eng.cache, eng._dev(toks), eng._dev(tables),
             eng._dev(ctx))
         # host bookkeeping overlaps the in-flight device program
+        ph.mark("commit")
         for req, chunk, sample in rows:
             eng.state.commit(req.uid, len(chunk),
                              token_ids=[int(t) for t in chunk])
@@ -860,6 +941,7 @@ class ServingScheduler:
         # mid-prompt chunks produce no token: skip the sample epilogue
         tok_dev = (self._sample_part(logits, sample_rows, sp)
                    if sample_rows else None)
+        ph.mark("commit")
         self.counters["batched_tokens"] += n_rows
         return _Part("mixed", sample_rows, tok_dev)
 
@@ -868,6 +950,10 @@ class ServingScheduler:
         (model.decode_multi) — sampled tokens never leave the device
         between the C steps; one [C, width] readback per chunk."""
         eng, scfg = self.engine, self.scfg
+        ph = self._phases
+        ph.mark("build")
+        self._it_kind = "fused"
+        self._it_rows += len(running) * C
         width = _bucket(len(running), 8)
         toks = np.zeros((width,), np.int32)
         ctx = np.zeros((width,), np.int32)
@@ -896,6 +982,7 @@ class ServingScheduler:
             eng.pad_block)
         eng.recompile_tracker.record(
             f"serving_fused[w{width},c{C}]", (toks, tables, ctx, steps))
+        ph.mark("launch", kind="fused", rows=len(running) * C)
         fn = eng.decode_multi_fn(
             width, C, sampling=scfg if use_sampler else None,
             with_presence=pres_rows is not None)
@@ -907,6 +994,7 @@ class ServingScheduler:
             if pres_rows is not None:
                 args.append(eng._dev(pres_rows))
         gen, _, eng.cache, _ = fn(*args)
+        ph.mark("commit")
         for req in running:
             eng.state.commit(req.uid, C)
         self.counters["batched_tokens"] += len(running) * C
@@ -946,12 +1034,15 @@ class ServingScheduler:
         governor (when enabled) updates FIRST — its level steers this
         iteration's admission cap, victim policy, and brownout
         degradations."""
+        ph = self._phases
+        ph.mark("admit")
         if self.governor is not None:
             self.governor.update()
         self._admit()
         if not self.active:
             return None
         self.counters["steps"] += 1
+        ph.mark("select")
         if self._spec and not self._brownout():
             # BROWNOUT degrades speculation to plain decode: draft rows
             # burn batch capacity the pool no longer has, and greedy
@@ -969,6 +1060,7 @@ class ServingScheduler:
             wave = [r for r in prefill if r.fed == 0]
             if wave:
                 parts.extend(self._dispatch_wave(wave))
+                ph.mark("select")
                 prefill = [r for r in prefill if r.state == PREFILL]
         budget = self.cfg.max_num_batched_tokens
         row_budget = self.engine.config.max_batch_size
@@ -1045,7 +1137,9 @@ class ServingScheduler:
         for part in step.parts:
             if part.tok_dev is None:
                 continue  # mid-prompt prefill chunks: nothing sampled
+            self._phases.mark("readback")
             toks = serving_readback(part.tok_dev)
+            self._phases.mark("accept")
             now = time.perf_counter()
             if part.kind == "fused":
                 # gen [C, width]: distribute each row's chunk in order,
@@ -1072,6 +1166,7 @@ class ServingScheduler:
         accepts the greedy-consistent prefix. Synchronous per step (the
         verification IS a host decision), so no _Part machinery."""
         eng = self.engine
+        ph = self._phases
         prefill = [r for r in self.active if r.state == PREFILL]
         if prefill:
             # whole prompts through compiled waves; prefix-cache-hit
@@ -1080,6 +1175,7 @@ class ServingScheduler:
             wave = [r for r in prefill if r.fed == 0]
             if wave:
                 parts.extend(self._dispatch_wave(wave))
+                ph.mark("select")
             rows = []
             row_budget = eng.config.max_batch_size
             for req in prefill:
@@ -1143,8 +1239,14 @@ class ServingScheduler:
         st["steps"] += 1
         st["verified_chunks"] += len(chunks)
         st["draft_tokens"] += sum(len(c) - 1 for _, c in chunks)
+        self._it_kind = "spec"
+        self._it_rows += sum(len(c) for _, c in chunks)
+        # the verification is launch AND wait: the engine hands back
+        # host logits, so the device time of a spec step reads as launch
+        ph.mark("launch", kind="spec", rows=self._it_rows)
         all_logits = eng._verify_chunks([r.uid for r, _ in chunks],
                                         [c for _, c in chunks])
+        ph.mark("accept")
         now = time.perf_counter()
         for (req, chunk), lg in zip(chunks, all_logits):
             accepted = 1
@@ -1178,14 +1280,40 @@ class ServingScheduler:
         'scheduler.step' fires BEFORE dispatch: an injected replica
         death raises with no state half-mutated (requeue is safe), an
         injected straggler delay accrues to fault_delay_s."""
-        act = fault_point("scheduler.step", replica=self.replica_index)
-        if act is not None and act.kind == "delay":
-            self.fault_delay_s += act.value
-        st = self._dispatch()
-        if st is None:
-            return False
-        self._finalize(st)
-        return True
+        self._begin_iteration("admit")
+        try:
+            act = fault_point("scheduler.step", replica=self.replica_index)
+            if act is not None and act.kind == "delay":
+                self.fault_delay_s += act.value
+            st = self._dispatch()
+            if st is None:
+                return False
+            self._finalize(st)
+            return True
+        finally:
+            self._end_iteration()
+
+    def _begin_iteration(self, phase: str) -> None:
+        self._iteration += 1
+        self._it_rows, self._it_kind = 0, "idle"
+        self._it_delay0 = self.fault_delay_s
+        self._phases.begin(phase, iteration=self._iteration)
+
+    def _end_iteration(self) -> None:
+        """Close the iteration's phases; count and keep it when the
+        host held the loop (everything but the readback wait, plus any
+        injected straggler time) for over SLOW_ITERATION_NS."""
+        ph = self._phases
+        total = ph.end(rows=self._it_rows, kind=self._it_kind)
+        delay = self.fault_delay_s - self._it_delay0
+        if total - ph.ns["readback"] + int(delay * 1e9) > SLOW_ITERATION_NS:
+            self.counters["slow_iterations"] += 1
+            t1 = time.perf_counter_ns()
+            profiler.record(
+                "sched.slow_iteration", t1 - total, t1, always=True,
+                iteration=self._iteration, rows=self._it_rows,
+                kind=self._it_kind, fault_delay_s=delay,
+                **{f"{p}_ms": ns * 1e-6 for p, ns in ph.ns.items()})
 
     def _can_chain(self, step: _Step) -> bool:
         """May the NEXT iteration consume this step's device-resident
@@ -1233,6 +1361,7 @@ class ServingScheduler:
         None when a row's block reservation forced a composition change
         (caller falls back to finalize-then-dispatch)."""
         eng = self.engine
+        ph = self._phases
         part = prev.parts[0]
         rows = [req for req, _ in part.sample_rows]
         sp = part.tok_dev.shape[0]
@@ -1247,6 +1376,9 @@ class ServingScheduler:
                 # of silently absorbed (L004)
                 self.counters["chain_fallbacks"] += 1
                 return None
+        ph.mark("build")
+        self._it_kind = "chained"
+        self._it_rows += len(rows)
         ctx = np.zeros((sp,), np.int32)
         tables = np.full((sp, eng.config.blocks_per_seq),
                          eng.pad_block, np.int32)
@@ -1260,12 +1392,15 @@ class ServingScheduler:
         eng.recompile_tracker.record(
             f"serving_decode[w{sp},u1]",
             (np.zeros((sp,), np.int32), tables, ctx))
+        ph.mark("launch", kind="chained", rows=len(rows))
         logits, eng.cache = eng._decode_fn(sp, True)(
             eng.params, eng.cache, part.tok_dev, eng._dev(tables),
             eng._dev(ctx))
+        ph.mark("commit")
         for req in rows:
             eng.state.commit(req.uid, 1)  # token device-resident: no ids
         tok_dev = self._sample_part(logits, sample_rows, sp)
+        ph.mark("commit")
         self.counters["steps"] += 1
         self.counters["batched_tokens"] += len(rows)
         self.counters["chained_steps"] += 1
@@ -1280,34 +1415,41 @@ class ServingScheduler:
         prev: Optional[_Step] = None
         stalls = 0
         while True:
-            if tick is not None:
-                tick(self)
-            if prev is not None and not self.waiting \
-                    and self._can_chain(prev):
-                nxt = self._dispatch_chained(prev)
-                self._finalize(prev)  # readback overlaps nxt's compute
-                prev = nxt
-                continue
-            if prev is not None:
-                self._finalize(prev)
-                prev = None
-            st = self._dispatch()
-            if st is None:
-                if not self.has_work:
-                    break
-                # every active sequence was preempted/finished this
-                # iteration: the next _admit makes progress (freed
-                # blocks) or capacity-finishes — a third idle pass
-                # with work pending is a scheduler bug, not pressure
-                stalls += 1
-                if stalls > 2:
-                    raise RuntimeError(
-                        "serving scheduler stalled with work pending "
-                        f"({len(self.waiting)} waiting)")
-                continue
-            stalls = 0
-            if st.parts:
-                prev = st
+            # every statement of the loop body sits in a phase; the
+            # finally closes the iteration when the tick raises, too
+            self._begin_iteration("tick")
+            try:
+                if tick is not None:
+                    tick(self)
+                self._phases.mark("select")
+                if prev is not None and not self.waiting \
+                        and self._can_chain(prev):
+                    nxt = self._dispatch_chained(prev)
+                    self._finalize(prev)  # readback overlaps nxt's compute
+                    prev = nxt
+                    continue
+                if prev is not None:
+                    self._finalize(prev)
+                    prev = None
+                st = self._dispatch()
+                if st is None:
+                    if not self.has_work:
+                        break
+                    # every active sequence was preempted/finished this
+                    # iteration: the next _admit makes progress (freed
+                    # blocks) or capacity-finishes — a third idle pass
+                    # with work pending is a scheduler bug, not pressure
+                    stalls += 1
+                    if stalls > 2:
+                        raise RuntimeError(
+                            "serving scheduler stalled with work pending "
+                            f"({len(self.waiting)} waiting)")
+                    continue
+                stalls = 0
+                if st.parts:
+                    prev = st
+            finally:
+                self._end_iteration()
         if prev is not None:
             self._finalize(prev)
 
@@ -1315,8 +1457,13 @@ class ServingScheduler:
     def metrics(self) -> Dict[str, float]:
         """Flat float counters for the monitor sinks
         (monitor.serving_events): TTFT/TPOT percentiles (ms, host wall
-        time over finished requests), queue depth, preemptions, and the
-        engine recompile count."""
+        time over the last LATENCY_WINDOW finished requests, **timed
+        from `submit()`**, not from when a client meant to send: a
+        stalled loop that delays its own submissions hides that wait),
+        queue depth, preemptions, the per-phase time sums of the loop
+        (`tick_s` ... `accept_s`, `readback_wait_s`, `queue_wait_s`,
+        `slow_iterations`: docs/tracing.md), and the engine recompile
+        count."""
         def pct(xs, q):
             return float(np.percentile(np.asarray(xs), q) * 1e3) if xs \
                 else 0.0

@@ -38,8 +38,10 @@ from ..ops.optimizers import Optimizer, build_optimizer
 from ..parallel import sharding as shd
 from ..platform.mesh import build_mesh, data_parallel_size, describe
 from ..resilience.faults import fault_point
+from ..utils import profiler
 from ..utils.logging import log_dist, logger
-from ..utils.timers import BATCH_TIMER, STEP_TIMER, SynchronizedWallClockTimer, ThroughputTimer
+from ..utils.sync import host_sync
+from ..utils.timers import BATCH_TIMER, SynchronizedWallClockTimer, ThroughputTimer
 from . import overlap, zero
 from .checkpoint import CheckpointEngine
 from .lr_schedules import build_schedule
@@ -376,7 +378,13 @@ class DeepSpeedTPUEngine:
         self._rng_seed = config.seed
         if param_init_fn is not None and init_rng is None:
             init_rng = jax.random.PRNGKey(config.seed)
-        self.state = self._init_state(params, param_init_fn, init_rng)
+        # one jitted program makes the float32 init, its compute-dtype
+        # copy, the master and the optimizer state, already sharded;
+        # awaited so the span carries its time and the memory it left
+        with profiler.span("train.init.state", always=True), \
+                profiler.compile_spans("train.init.state"):
+            self.state = host_sync(
+                self._init_state(params, param_init_fn, init_rng))
 
         # --- compiled step cache -----------------------------------------
         self._train_step_fn = None
@@ -407,6 +415,10 @@ class DeepSpeedTPUEngine:
 
         self.timers = SynchronizedWallClockTimer()
         self.tput = ThroughputTimer(batch_size=config.train_batch_size)
+        # one train_batch call, tiled: train.batch > train.prepare /
+        # launch / readback / post (docs/tracing.md)
+        self._phases = profiler.Phases(
+            "train", "batch", ("prepare", "launch", "readback", "post"))
         self.monitor = MonitorMaster(config.monitor)
         self.global_steps = 0
         self._metrics_host: Dict[str, float] = {}
@@ -1809,9 +1821,12 @@ class DeepSpeedTPUEngine:
         return out
 
     def _dispatch_step_inner(self, batch) -> Dict[str, Any]:
+        ph = self._phases
         if self._offload:
+            ph.mark("launch")  # host optimizer and device work interleave
             return self._dispatch_offload_step(batch)
         if self._zoadam:
+            ph.mark("launch")
             return self._dispatch_zoadam_step(batch)
         # 1-bit Adam: switch to the compressed-momentum program once the
         # warmup window ends (one extra compile at the phase boundary)
@@ -1848,10 +1863,14 @@ class DeepSpeedTPUEngine:
                 # flops/comm accounting reads the program actually executed.
                 from ..profiling.hlo import collective_volumes
 
-                compiled = step_fn.lower(self.state, batch).compile()
+                with profiler.span("train.compile", always=True,
+                                   step=self.global_steps + 1), \
+                        profiler.compile_spans("train.compile"):
+                    compiled = step_fn.lower(self.state, batch).compile()
                 self._train_compiled_cache[shape_key] = compiled
                 comms_logger.record_compiled(collective_volumes(compiled))
             self._train_compiled = compiled
+            ph.mark("launch")
             self.state, metrics = compiled(self.state, batch)
         self.state = self._park_params(self.state)
         return metrics
@@ -1906,6 +1925,15 @@ class DeepSpeedTPUEngine:
         Accepts host arrays shaped [train_batch_size, ...] or
         [gas, train_batch_size/gas, ...]; returns host metrics (synced).
         """
+        ph = self._phases
+        ph.begin("prepare", step=self.global_steps + 1)
+        try:
+            return self._train_batch(batch)
+        finally:
+            ph.end()
+
+    def _train_batch(self, batch) -> Dict[str, float]:
+        ph = self._phases
         if self._health_monitor is not None:
             # refuse to enter a collective against a dead peer — raises
             # WorldDegradedError for the elastic supervisor to handle
@@ -1916,14 +1944,18 @@ class DeepSpeedTPUEngine:
             seqlen = self.curriculum.update_difficulty(self.global_steps + 1)
             batch = truncate_to_seqlen(batch, seqlen)
         self.tput.start()
-        self.timers(BATCH_TIMER).start()
         metrics = self._dispatch_step(batch)
+        ph.mark("readback")
         # single host transfer for all metrics (device sync point) — per-key
         # float() would pay one device round trip per metric; the sync-free
         # path is train_batch_async
         metrics = {k: float(v) for k, v in jax.device_get(metrics).items()}  # ds-lint: ok R002 the one deliberate per-step sync
-        self.timers(BATCH_TIMER).stop(sync=False)
-        step_time = self.timers(BATCH_TIMER).elapsed(reset=True)
+        ph.mark("post")
+        # the step's time is the phases' own stamps: prepare + launch +
+        # readback (BATCH_TIMER and the spans share one clock reading)
+        step_time = (ph.ns["prepare"] + ph.ns["launch"]
+                     + ph.ns["readback"]) * 1e-9
+        self.timers(BATCH_TIMER).add(step_time)
         self.tput.stop()
         self.global_steps += 1
         if self._heartbeat is not None:

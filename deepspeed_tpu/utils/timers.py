@@ -14,9 +14,6 @@ import jax
 
 from .logging import logger
 
-FORWARD_TIMER = "forward"
-BACKWARD_TIMER = "backward"
-STEP_TIMER = "step"
 BATCH_TIMER = "train_batch"
 
 
@@ -50,6 +47,12 @@ class _Timer:
         if record:
             self._record.append(dt)
         self.started = False
+
+    def add(self, seconds: float) -> None:
+        """Book an interval the caller measured itself (the train
+        step's phase stamps: one clock reading serves both)."""
+        self._elapsed += seconds
+        self._record.append(seconds)
 
     def elapsed(self, reset: bool = True) -> float:
         out = self._elapsed
@@ -94,13 +97,11 @@ class SynchronizedWallClockTimer:
 class ThroughputTimer:
     """Samples/sec + TFLOPs estimator (ref: deepspeed/utils/timer.py:198)."""
 
-    def __init__(self, batch_size: int, start_step: int = 2, monitor_memory: bool = False):
+    def __init__(self, batch_size: int, start_step: int = 2):
         self.batch_size = max(batch_size, 1)
         self.start_step = start_step
-        self.epoch_count = 0
         self.global_step_count = 0
         self.total_elapsed_time = 0.0
-        self.step_elapsed_time = 0.0
         self._start_time = 0.0
         self.started = False
 
@@ -117,7 +118,6 @@ class ThroughputTimer:
             self.global_step_count += 1
             if self.global_step_count > self.start_step:
                 self.total_elapsed_time += duration
-                self.step_elapsed_time += duration
 
     @property
     def avg_samples_per_sec(self) -> float:

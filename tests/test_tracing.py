@@ -1,0 +1,385 @@
+"""The program's tracing facility (utils/profiler.py, docs/tracing.md):
+spans, phase clocks and the always-on time sums they feed in the
+serving loop, the warm-up and the train step."""
+
+import glob
+import os
+import threading
+import time
+
+import jax
+import numpy as np
+import pytest
+
+from deepspeed_tpu.inference import ServingScheduler, init_inference
+from deepspeed_tpu.inference.scheduler import PHASES
+from deepspeed_tpu.models import transformer as T
+from deepspeed_tpu.resilience.faults import FaultPlan, armed
+from deepspeed_tpu.utils import profiler
+
+
+@pytest.fixture(autouse=True)
+def clean_buffer():
+    profiler.disable()
+    profiler.clear()
+    yield
+    profiler.disable()
+    profiler.clear()
+
+
+def names(records):
+    return [r.name for r in records]
+
+
+class TestSpans:
+    def test_inactive_span_records_nothing(self):
+        with profiler.span("quiet", rid=1) as sp:
+            pass
+        assert sp.sid == 0
+        assert profiler.spans() == []
+        assert profiler.record("late", 0, 1) == 0
+        assert not profiler.active()
+
+    def test_enable_records_parent_child_and_ids(self):
+        profiler.enable()
+        with profiler.span("outer", rid=7) as outer:
+            with profiler.span("inner") as inner:
+                inner.set(rows=3)
+        profiler.disable()
+        recs = {r.name: r for r in profiler.spans()}
+        assert recs["inner"].parent == recs["outer"].sid == outer.sid
+        assert recs["outer"].parent == 0
+        assert recs["outer"].ids == {"rid": 7}
+        assert recs["inner"].ids == {"rows": 3}
+        assert recs["outer"].t0_ns <= recs["inner"].t0_ns \
+            <= recs["inner"].t1_ns <= recs["outer"].t1_ns
+
+    def test_always_records_while_inactive_with_its_own_bound(self):
+        with profiler.span("setup.phase", always=True, width=8):
+            pass
+        profiler.enable()
+        for i in range(profiler.HOT_SPANS + 10):
+            profiler.record("hot", i, i + 1)
+        profiler.disable()
+        recs = profiler.spans()
+        # the buffer bound holds, and hot spans never evict a kept one
+        assert len(recs) == profiler.HOT_SPANS + 1
+        kept = [r for r in recs if r.name == "setup.phase"]
+        assert len(kept) == 1 and kept[0].ids["width"] == 8
+
+    def test_self_time_and_split(self):
+        R = profiler.SpanRecord
+        recs = [R("p", 0, 100, 1, 0, {}), R("a", 10, 30, 2, 1, {}),
+                R("b", 20, 50, 3, 1, {}),   # overlaps a: counted once
+                R("c", 90, 120, 4, 1, {})]  # clipped to the parent
+        own = profiler.self_ns(recs)
+        assert own[1] == 100 - (40 + 10)
+        assert own[2] == 20 and own[3] == 30
+        split = profiler.split_ns(0, 100, [("x", [(10, 30)]),
+                                           ("y", [(20, 50), (90, 120)])])
+        assert split == {"x": 20, "y": 30, "other": 50}
+        assert sum(split.values()) == 100
+
+    def test_parent_stacks_are_per_thread(self):
+        profiler.enable()
+        gate = threading.Barrier(2, timeout=10)
+
+        def work(tag):
+            with profiler.span(f"{tag}.outer"):
+                gate.wait()  # both outers are open at once
+                with profiler.span(f"{tag}.inner"):
+                    gate.wait()
+
+        ts = [threading.Thread(target=work, args=(t,)) for t in ("a", "b")]
+        for t in ts:
+            t.start()
+        for t in ts:
+            t.join(timeout=20)
+            assert not t.is_alive()
+        profiler.disable()
+        recs = {r.name: r for r in profiler.spans()}
+        for tag in ("a", "b"):
+            assert recs[f"{tag}.inner"].parent == recs[f"{tag}.outer"].sid
+            assert recs[f"{tag}.outer"].parent == 0
+
+    def test_annotate_is_the_decorator_form(self):
+        @profiler.annotate("my_region", rid=3)
+        def f(x):
+            return x + 1
+
+        assert f(1) == 2 and profiler.spans() == []
+        profiler.enable()
+        assert f(2) == 3
+        profiler.disable()
+        (rec,) = profiler.spans()
+        assert rec.name == "my_region" and rec.ids == {"rid": 3}
+
+    def test_phases_tile_the_iteration_and_feed_the_sums(self):
+        sums = {"a_s": 0.0, "b_wait_s": 0.0}
+        ph = profiler.Phases("loop", "pass", {"a": "a_s", "b": "b_wait_s"},
+                             sums=sums)
+        ph.mark("b")  # outside an iteration: nothing
+        assert sums == {"a_s": 0.0, "b_wait_s": 0.0}
+        profiler.enable()
+        ph.begin("a", n=1)
+        time.sleep(0.002)
+        ph.mark("b", rows=4)
+        time.sleep(0.001)
+        ph.mark("a")
+        total = ph.end(kind="x")
+        profiler.disable()
+        assert total == ph.ns["a"] + ph.ns["b"]
+        assert sums["a_s"] == pytest.approx(ph.ns["a"] * 1e-9)
+        assert sums["b_wait_s"] == pytest.approx(ph.ns["b"] * 1e-9)
+        recs = profiler.spans()
+        assert names(recs) == ["loop.pass", "loop.a", "loop.b", "loop.a"]
+        parent = recs[0]
+        assert parent.ids == {"n": 1, "kind": "x"}
+        assert recs[2].ids == {"rows": 4}
+        assert all(r.parent == parent.sid for r in recs[1:])
+        # children tile the parent: no time between or around them
+        assert recs[1].t0_ns == parent.t0_ns and recs[3].t1_ns == parent.t1_ns
+        assert recs[1].t1_ns == recs[2].t0_ns and recs[2].t1_ns == recs[3].t0_ns
+        assert profiler.self_ns(recs)[parent.sid] == 0
+        # tracing off: the sums still run, the buffer stays as it was
+        ph.begin("a")
+        ph.mark("b")
+        ph.end()
+        assert len(profiler.spans()) == 4 and sums["a_s"] > ph.ns["a"] * 1e-9
+
+    def test_compile_spans_merge_nested_and_adjacent_reports(self):
+        trace = "/jax/core/compile/jaxpr_trace_duration"
+        lower = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+        with profiler.span("warmup.program", always=True) as sp, \
+                profiler.compile_spans("warmup") as got:
+            for _ in range(3):  # back to back: one span
+                time.sleep(0.002)
+                jax.monitoring.record_event_duration_secs(trace, 0.002)
+            time.sleep(0.005)
+            jax.monitoring.record_event_duration_secs(trace, 0.001)  # nested
+            jax.monitoring.record_event_duration_secs(lower, 0.004)
+            jax.monitoring.record_event_duration_secs("/other/event", 1.0)
+        assert names(got) == ["warmup.trace", "warmup.lower"]
+        assert all(r.parent == sp.sid for r in got)
+        assert got[0].t1_ns - got[0].t0_ns >= 5e6
+        assert got[0].t1_ns <= got[1].t0_ns
+        assert [r for r in profiler.spans() if r.parent == sp.sid] == got
+        # the listener is gone: a later report leaves nothing
+        jax.monitoring.record_event_duration_secs(trace, 0.5)
+        assert len(profiler.spans()) == 3
+
+    def test_dump_writes_chrome_trace(self, tmp_path):
+        import json
+
+        profiler.enable()
+        with profiler.span("sched.iteration", iteration=1):
+            pass
+        profiler.disable()
+        path = profiler.dump(str(tmp_path / "out" / "spans.json"))
+        (ev,) = json.load(open(path))["traceEvents"]
+        assert ev["name"] == "sched.iteration" and ev["ph"] == "X"
+        assert ev["args"]["iteration"] == 1 and ev["tid"] == "sched"
+
+    def test_profiler_session_alone_activates_spans(self, tmp_path):
+        with profiler.span("before"):
+            pass
+        with profiler.trace(str(tmp_path)):
+            assert profiler.active()
+            with profiler.span("sched.launch", rows=5, kind="mixed"):
+                time.sleep(0.001)
+        assert not profiler.active()
+        with profiler.span("after"):
+            pass
+        assert names(profiler.spans()) == ["sched.launch"]
+        (path,) = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
+                            recursive=True)
+        found = [e for plane in jax.profiler.ProfileData.from_file(path).planes
+                 if plane.name.startswith("/host:")
+                 for line in plane.lines for e in line.events
+                 if e.name == "ds.sched.launch"]
+        assert len(found) == 1
+        assert dict(found[0].stats) == {"rows": 5, "kind": "mixed"}
+        assert found[0].duration_ns >= 1e6
+
+
+# -- the hot paths ---------------------------------------------------------------
+
+MCFG = T.TransformerConfig(vocab_size=256, n_layers=2, n_heads=4, d_model=64,
+                           max_seq=128, variant="llama", use_flash=False)
+
+
+@pytest.fixture(scope="module")
+def engine():
+    params = T.init(MCFG, jax.random.PRNGKey(0))
+    eng = init_inference(params, MCFG, {
+        "max_batch_size": 8, "num_kv_blocks": 64, "kv_block_size": 8,
+        "max_seq_len": 128})
+    eng.warmup_report = eng.warmup(widths=[8], footprint=False)
+    return eng
+
+
+def make_sched(engine, **cfg):
+    return ServingScheduler(
+        engine, dict({"warmup": False, "max_num_batched_tokens": 32,
+                      "prefill_chunk": 8}, **cfg))
+
+
+def submit_some(sched, n=6, new=12):
+    return [sched.submit(list(range(1, 20 + i)), max_new_tokens=new)
+            for i in range(n)]
+
+
+class TestServingLoop:
+    def test_time_sums_add_up_to_the_loops_wall_time(self, engine):
+        sched = make_sched(engine)
+        submit_some(sched)
+        sched.run()  # every shape compiled: the timed pass below is steady
+        base = dict(sched.counters)
+        submit_some(sched, new=24)
+        ticks = []
+        t0 = time.perf_counter()
+        sched.run(tick=lambda s: ticks.append(time.sleep(0.0005)))
+        wall = time.perf_counter() - t0
+        d = {k: sched.counters[k] - base[k] for k in base}
+        phases = sum(d[k] for k in PHASES.values())
+        assert phases == pytest.approx(wall, rel=0.02)
+        assert d["tick_s"] >= 0.0005 * len(ticks)
+        assert d["readback_wait_s"] > 0 and d["launch_s"] > 0
+        assert d["queue_wait_s"] > 0 and d["admitted"] == 6
+        m = sched.metrics()
+        for k in list(PHASES.values()) + ["queue_wait_s", "slow_iterations"]:
+            assert m[k] == float(sched.counters[k])
+        # tracing was off all along: at most a stall left its name
+        assert {r.name for r in profiler.spans()} <= {"sched.slow_iteration"}
+
+    def test_spans_of_an_iteration_and_of_a_request(self, engine):
+        sched = make_sched(engine)
+        rids = submit_some(sched, n=3)
+        profiler.enable()
+        sched.run()
+        profiler.disable()
+        recs = profiler.spans()
+        its = [r for r in recs if r.name == "sched.iteration"]
+        assert [r.ids["iteration"] for r in its] == list(range(1, len(its) + 1))
+        assert {r.ids["kind"] for r in its} <= {"mixed", "chained", "idle"}
+        assert its[0].ids["rows"] > 0
+        by_parent = {}
+        for r in recs:
+            by_parent.setdefault(r.parent, []).append(r)
+        kids = by_parent[its[0].sid]
+        assert {k.name for k in kids} <= {f"sched.{p}" for p in PHASES}
+        assert {"sched.admit", "sched.select", "sched.build",
+                "sched.launch", "sched.commit"} <= {k.name for k in kids}
+        assert profiler.self_ns(recs)[its[0].sid] == 0
+        launch = [k for k in kids if k.name == "sched.launch"][0]
+        assert launch.ids["kind"] == "mixed" and launch.ids["rows"] > 0
+        for rid in rids:
+            mine = [r for r in recs if r.ids.get("rid") == rid]
+            assert sorted(names(mine)) == [
+                "request", "request.decode", "request.prefill",
+                "request.queue"]
+            root = [r for r in mine if r.name == "request"][0]
+            req = sched.finished[rid]
+            assert root.t0_ns == int(req.arrival * 1e9)
+            assert root.t1_ns == int(req.finish_t * 1e9)
+            assert req.arrival <= req.admit_t <= req.first_token_t
+
+    def test_slow_iteration_is_counted_and_kept_with_tracing_off(self, engine):
+        sched = make_sched(engine)
+        submit_some(sched, n=2, new=4)
+        with armed(FaultPlan([{"point": "scheduler.step", "kind": "delay",
+                               "value": 0.2, "at": 2}])):
+            while sched.has_work:
+                sched.step()
+        assert sched.counters["slow_iterations"] >= 1
+        slow = [r for r in profiler.spans()
+                if r.name == "sched.slow_iteration"
+                and r.ids["fault_delay_s"] == pytest.approx(0.2)]
+        assert len(slow) == 1
+        assert {f"{p}_ms" for p in PHASES} <= set(slow[0].ids)
+
+    def test_latency_lists_are_bounded(self, engine):
+        from deepspeed_tpu.inference.scheduler import LATENCY_WINDOW
+
+        sched = make_sched(engine)
+        assert sched._ttft.maxlen == sched._tpot.maxlen == LATENCY_WINDOW
+        submit_some(sched, n=2, new=3)
+        sched.run()
+        assert len(sched._ttft) == 2 and sched.metrics()["ttft_p50_ms"] > 0
+
+
+class TestWarmupAndInit:
+    def test_warmup_split_sums_to_its_seconds(self, engine):
+        rep = engine.warmup_report
+        assert rep["programs"] == len(rep["per_program"]) == 3
+        assert set(rep["split"]) == {"trace_s", "lower_s", "compile_s",
+                                     "execute_s", "other_s"}
+        assert sum(rep["split"].values()) == pytest.approx(rep["seconds"])
+        assert all(v >= 0 for v in rep["split"].values())
+        for pp in rep["per_program"]:
+            parts = sum(pp[f"{k}_s"] for k in
+                        ("trace", "lower", "compile", "execute", "other"))
+            assert parts == pytest.approx(pp["seconds"])
+        kinds = [(pp["kind"], pp.get("unique")) for pp in rep["per_program"]]
+        assert kinds == [("decode", 1), ("decode", 0), ("sample", None)]
+
+    def test_setup_spans_are_kept_with_tracing_off(self):
+        params = T.init(MCFG, jax.random.PRNGKey(1))
+        eng = init_inference(params, MCFG, {
+            "max_batch_size": 8, "num_kv_blocks": 16, "kv_block_size": 8,
+            "max_seq_len": 64})
+        eng.warmup(widths=[8], chunked=False, footprint=False)
+        recs = profiler.spans()
+        by_name = {}
+        for r in recs:
+            by_name.setdefault(r.name, []).append(r)
+        (root,) = by_name["init.inference"]
+        assert by_name["init.transform"][0].parent == root.sid
+        assert by_name["init.pool"][0].parent == root.sid
+        progs = by_name["warmup.program"]
+        assert [(p.ids["kind"], p.ids["width"]) for p in progs] == [
+            ("decode", 8), ("sample", 8)]
+        for p in progs:
+            kids = {r.name for r in recs if r.parent == p.sid}
+            assert "warmup.execute" in kids
+            assert kids <= {"warmup.trace", "warmup.lower", "warmup.compile",
+                            "warmup.execute"}
+        assert "warmup.compile" in {r.name for r in recs
+                                    if r.parent == progs[0].sid}
+
+
+class TestTrainStep:
+    def test_train_batch_spans_and_setup_spans(self):
+        import deepspeed_tpu as ds
+
+        mcfg = T.TransformerConfig(vocab_size=128, n_layers=1, n_heads=2,
+                                   d_model=32, max_seq=16, variant="llama",
+                                   use_flash=False)
+        engine = ds.initialize(
+            {"train_micro_batch_size_per_gpu": 2,
+             "optimizer": {"type": "adamw", "params": {"lr": 1e-3}},
+             "steps_per_print": 10**9},
+            loss_fn=T.make_loss_fn(mcfg),
+            param_init_fn=lambda k: T.init(mcfg, k),
+            param_logical_specs=T.logical_specs(mcfg))
+        batch = {"tokens": np.random.default_rng(0).integers(
+            0, 128, (engine.config.train_batch_size, 17)).astype(np.int32)}
+        engine.train_batch(batch)  # compiles: train.compile, tracing off
+        kept = {r.name: r for r in profiler.spans()}
+        assert {"train.init", "train.init.shapes", "train.init.state",
+                "train.compile", "train.compile.compile"} <= set(kept)
+        assert kept["train.init.state"].parent == kept["train.init"].sid
+        assert "train.batch" not in kept
+        profiler.enable()
+        engine.train_batch(batch)
+        profiler.disable()
+        recs = [r for r in profiler.spans() if r.name.startswith("train.")
+                and r.name not in kept]
+        assert names(recs) == ["train.batch", "train.prepare", "train.launch",
+                               "train.readback", "train.post"]
+        assert recs[0].ids == {"step": 2}
+        assert all(r.parent == recs[0].sid for r in recs[1:])
+        # BATCH_TIMER books the phases' own stamps: prepare+launch+readback
+        booked = engine.timers.timers["train_batch"]._record[-1]
+        assert booked == pytest.approx(
+            sum(r.t1_ns - r.t0_ns for r in recs[1:4]) * 1e-9)

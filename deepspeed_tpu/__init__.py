@@ -47,20 +47,13 @@ def initialize(
     from .utils import profiler
 
     # always-kept set-up spans (docs/tracing.md): train.init, and under
-    # it train.init.shapes here and train.init.state in the engine
+    # it train.init.shapes and train.init.state in the engine
     with profiler.span("train.init", always=True):
         cfg = parse_config(config)
         comm.init_distributed()
-        if params is None:
-            if param_init_fn is None:
-                raise ValueError(
-                    "initialize() needs `params` or `param_init_fn`")
-            import jax
-
-            rng = (init_rng if init_rng is not None
-                   else jax.random.PRNGKey(cfg.seed))
-            with profiler.span("train.init.shapes", always=True):
-                params = jax.eval_shape(param_init_fn, rng)
+        if params is None and param_init_fn is None:
+            raise ValueError(
+                "initialize() needs `params` or `param_init_fn`")
         return DeepSpeedTPUEngine(
             cfg,
             loss_fn,

@@ -193,6 +193,13 @@ BITWISE_PINS: Dict[str, BitwisePin] = {
             ("all-reduce:add:f32:axes=pipe|model",
              "fused pipe+model reduce of the scalar loss/z-stat term "
              "— same class as axes=pipe, same dynamic pin"),
+            ("all-reduce:add:f32:axes=pipe|data|model",
+             "the global grad-norm's scalar sum of squares, one fused "
+             "reduce over every axis since ZeRO's tile rule stacks "
+             "'data' on the vocab dim of the embedding, already over "
+             "(model, pipe) there (PR 24): a reported metric on this "
+             "unclipped program, not an input of the loss — same "
+             "class as axes=pipe, same dynamic pin"),
         ),
     ),
     "serving_decode_w8": BitwisePin(

@@ -1271,7 +1271,11 @@ def make_loss_fn(cfg: TransformerConfig, loss_chunks: int = 8):
         )
         n = _ce_chunk_count(inputs.shape[1], loss_chunks)
         with jax.named_scope("lm_head"):
-            loss = _token_mean_ce(x, _lm_head(params, cfg), targets,
+            # the head in its gathered (TP) layout BEFORE the chunk scan:
+            # under ZeRO-3 the partitioner otherwise re-gathers it in
+            # every chunk, forward and backward (PERF.md §6, PR 24)
+            head = _shard(_lm_head(params, cfg), None, "model")
+            loss = _token_mean_ce(x, head, targets,
                                   _shift_mask(batch, targets), n,
                                   head_b=params.get("lm_head_b"))
         if cfg.n_experts > 0:

@@ -92,7 +92,8 @@ class DeepSpeedTPUEngine:
         pipeline_virtual_stages: Optional[int] = None,
     ):
         """`params` is either a concrete pytree, or (with `param_init_fn`)
-        a pytree of ShapeDtypeStructs — then params are materialized
+        a pytree of ShapeDtypeStructs or None (then `eval_shape` of
+        `param_init_fn` gives them) — then params are materialized
         *directly sharded* by running init under jit with out_shardings,
         the functional zero.Init (ref: partition_parameters.py Init:780).
 
@@ -226,22 +227,35 @@ class DeepSpeedTPUEngine:
 
         # --- sharding derivation (the ZeRO core; pipeline x ZeRO x TP
         # compose through one emitter, parallel/sharding.pipe3d_specs) --
-        shapes = jax.tree.map(lambda p: tuple(p.shape), params)
         zcfg = config.zero_optimization
-        if param_logical_specs is None:
-            tp_specs = jax.tree.map(lambda p: P(), params)
-            combined = {
-                "tp": tp_specs,
-                "storage": zero.derive_param_storage_specs(
-                    tp_specs, shapes, self.mesh, zcfg),
-                "opt": zero.derive_optimizer_specs(
-                    tp_specs, shapes, self.mesh, zcfg),
-            }
-            combined["grads"] = zero.derive_grad_specs(
-                combined["storage"], combined["opt"], zcfg)
-        else:
-            combined = shd.pipe3d_specs(
-                param_logical_specs, shapes, self.mesh, zcfg, rules)
+        with profiler.span("train.init.shapes", always=True) as shapes_span:
+            if params is None:
+                params = jax.eval_shape(
+                    param_init_fn,
+                    init_rng if init_rng is not None
+                    else jax.random.PRNGKey(config.seed))
+            shapes = jax.tree.map(lambda p: tuple(p.shape), params)
+            if param_logical_specs is None:
+                tp_specs = jax.tree.map(lambda p: P(), params)
+                combined = {
+                    "tp": tp_specs,
+                    "storage": zero.derive_param_storage_specs(
+                        tp_specs, shapes, self.mesh, zcfg),
+                    "opt": zero.derive_optimizer_specs(
+                        tp_specs, shapes, self.mesh, zcfg),
+                }
+                combined["grads"] = zero.derive_grad_specs(
+                    combined["storage"], combined["opt"], zcfg)
+            else:
+                combined = shd.pipe3d_specs(
+                    param_logical_specs, shapes, self.mesh, zcfg, rules)
+            # what the tile rule did (docs/overlap.md "Which dimension
+            # ZeRO shards"); bytes at the compute copy's width
+            self.zero_layout = zero.zero_layout_report(
+                combined["tp"], combined["opt"], shapes, self.mesh,
+                jnp.dtype(self.compute_dtype).itemsize)
+            shapes_span.set(**self.zero_layout)
+        log_dist(f"engine: zero layout {self.zero_layout}", ranks=[0])
         self.tp_specs = combined["tp"]
         self.param_specs = combined["storage"]
         self.opt_specs = combined["opt"]

@@ -67,6 +67,62 @@ def _axes_of(entry):
     return tuple(entry)
 
 
+# The TPU's memory tile over the two minor-most dims of an array (JAX
+# docs, "Pallas TPU": min tile (8, 128) for 32-bit, (16, 128) for bf16):
+# 128 lanes on the minor dim; on the second-minor dim 8 sublanes of 32
+# bits, so a bf16 tile packs 16 rows. A ZeRO leaf is held as the bf16
+# compute copy AND the fp32 master/moments under one dim, so the sublane
+# constant is bf16's 16: a multiple of it is whole tiles in both. A
+# shard boundary inside a tile makes the leaf's all-gather a relayout
+# (gather and transpose in one instruction) and pads its gradient's
+# reduction: 6% of one head gather on four v5e chips (PERF.md §6, PR 24).
+TILE_LANES = 128
+TILE_SUBLANES = 16
+
+
+def _keeps_tile(dim: int, rank: int, shard: int) -> bool:
+    """Whether a shard of `shard` elements along `dim` is whole TPU
+    tiles. Dims outside the two minor-most are never tiled."""
+    if dim == rank - 1:
+        return shard % TILE_LANES == 0
+    if dim == rank - 2:
+        return shard % TILE_SUBLANES == 0
+    return True
+
+
+def _live_axes(mesh: Mesh, axes: Optional[Tuple[str, ...]]):
+    if axes is None:
+        axes = zero_axes(mesh)
+    live = tuple(a for a in axes if mesh.shape.get(a, 1) > 1)
+    return live, int(np.prod([mesh.shape[a] for a in live])) if live else 1
+
+
+def _shard_candidates(dims, shape, mesh: Mesh, axis_n: int):
+    """[(dim, shard extent)] of the dims the ZeRO axes could take: the
+    local extent (after the spec's existing sharding) divides by them."""
+    out = []
+    for i, d in enumerate(shape):
+        existing = int(np.prod([mesh.shape[a] for a in _axes_of(dims[i])])) if dims[i] else 1
+        local = d // existing
+        if local % axis_n == 0:
+            out.append((i, local // axis_n))
+    return out
+
+
+def _largest(cands):
+    """The first of the largest candidates (None when there is none)."""
+    return max(cands, key=lambda c: c[1], default=None)
+
+
+def _choose(cands, rank: int) -> Optional[int]:
+    """The dim of the largest candidate whose shard keeps the TPU tile;
+    of the largest of all when none does, so no leaf that could be
+    sharded stays replicated; None without candidates."""
+    kept = [c for c in cands if _keeps_tile(c[0], rank, c[1])]
+    best = _largest(kept or cands)
+    return None if best is None else best[0]
+
+
 def zero_shard_spec(
     spec: P,
     shape,
@@ -76,33 +132,26 @@ def zero_shard_spec(
 ) -> P:
     """Add the ZeRO axes to the best dimension of one leaf's PartitionSpec.
 
-    Picks the largest dim that (a) is not already sharded, (b) is
-    divisible by the axes' total size after accounting for existing
-    sharding. Leaves smaller than `min_size` elements stay untouched (the
+    Candidates are the dims that (a) are not already sharded over the
+    ZeRO axes, (b) are divisible by the axes' total size after
+    accounting for existing sharding. Of those, the largest whose shard
+    is whole TPU tiles (`_keeps_tile`: decided from the shape and the
+    spec alone); when no candidate keeps the tile, the largest. Leaves
+    smaller than `min_size` elements stay untouched (the
     persistence-threshold analog). Returns the original spec when no dim
     qualifies — those leaves stay replicated over the data axes, which is
     exactly the reference's persistent-param behavior.
     """
-    if axes is None:
-        axes = zero_axes(mesh)
-    live = tuple(a for a in axes if mesh.shape.get(a, 1) > 1)
+    live, axis_n = _live_axes(mesh, axes)
     if not live:
         return spec
-    axis_n = int(np.prod([mesh.shape[a] for a in live]))
     size = int(np.prod(shape)) if len(shape) else 1
     if size < max(min_size, axis_n) or len(shape) == 0:
         return spec
     dims = _spec_dims(spec, len(shape))
     if any(set(live) & set(_axes_of(d)) for d in dims):
         return spec  # already zero-sharded
-    best, best_len = None, 0
-    for i, d in enumerate(shape):
-        existing = int(np.prod([mesh.shape[a] for a in _axes_of(dims[i])])) if dims[i] else 1
-        local = d // existing
-        if local % axis_n != 0:
-            continue
-        if local > best_len:
-            best, best_len = i, local
+    best = _choose(_shard_candidates(dims, shape, mesh, axis_n), len(shape))
     if best is None:
         return spec
     cur = _axes_of(dims[best])
@@ -158,6 +207,38 @@ def derive_grad_specs(param_specs, opt_specs, zero_config: ZeroConfig):
     stage < 2:  the param layout → plain all-reduce semantics.
     """
     return opt_specs if zero_config.stage >= 2 else param_specs
+
+
+def zero_layout_report(gathered_specs, zero_specs, shapes, mesh: Mesh,
+                       itemsize: int):
+    """What the tile rule did to this layout, for the engine's log and
+    the `train.init.shapes` span: `zero_leaves_moved` counts the
+    zero-sharded leaves whose dim is not the largest candidate (the
+    choice before the rule), `zero_leaves_off_tile` those whose shard
+    still breaks a tile because no candidate kept it; `*_bytes` sums
+    each group's leaves at `itemsize` bytes an element."""
+    out = {"zero_leaves_moved": 0, "zero_bytes_moved": 0,
+           "zero_leaves_off_tile": 0, "zero_bytes_off_tile": 0}
+    _, axis_n = _live_axes(mesh, None)
+
+    def leaf(gathered, sharded, shape):
+        k = _zero_sharded_dim(sharded, gathered, len(shape), mesh)
+        if k is None:
+            return
+        cands = _shard_candidates(
+            _spec_dims(gathered, len(shape)), shape, mesh, axis_n)
+        shard = dict(cands)[k]
+        nbytes = int(np.prod(shape)) * itemsize
+        if k != _largest(cands)[0]:
+            out["zero_leaves_moved"] += 1
+            out["zero_bytes_moved"] += nbytes
+        if not _keeps_tile(k, len(shape), shard):
+            out["zero_leaves_off_tile"] += 1
+            out["zero_bytes_off_tile"] += nbytes
+
+    jax.tree.map(leaf, gathered_specs, zero_specs, shapes,
+                 is_leaf=lambda x: isinstance(x, P))
+    return out
 
 
 def _zero_sharded_dim(store_spec: P, gathered_spec: P, rank: int, mesh: Mesh):

@@ -153,8 +153,13 @@ class TestSpans:
         with profiler.span("warmup.program", always=True) as sp, \
                 profiler.compile_spans("warmup") as got:
             for _ in range(3):  # back to back: one span
+                t = time.perf_counter()
                 time.sleep(0.002)
-                jax.monitoring.record_event_duration_secs(trace, 0.002)
+                # the duration the stage really took, so the next report
+                # starts where this one ends however late sleep() wakes
+                # (a loaded machine overshoots by more than STAGE_GAP_NS)
+                jax.monitoring.record_event_duration_secs(
+                    trace, time.perf_counter() - t)
             time.sleep(0.005)
             jax.monitoring.record_event_duration_secs(trace, 0.001)  # nested
             jax.monitoring.record_event_duration_secs(lower, 0.004)

@@ -6,25 +6,66 @@ matmul; XLA's cost analysis counts recomputed operations).
 All functions take the published config.json keys (`hf`).
 """
 
+import re
 from typing import Any, Dict
+
+# keys that change what a block holds; one this file does not know is
+# an error, never a dense count
+_BLOCK_KEY = re.compile(r"expert|^moe_|_lora_rank$|_head_dim$|^ssm_|^mamba_"
+                        r"|^conv_|dense_replace|^layer_types$|_bias$")
+_EXPERT_COUNT = ("num_local_experts", "num_experts")
+_KNOWN_BLOCK_KEYS = _EXPERT_COUNT + ("num_experts_per_tok",)
+
+
+def _experts(hf: Dict[str, Any]):
+    """(experts a layer holds, experts a token is routed to), or (0, 0)
+    for a dense block. Refuses block keys it cannot count."""
+    unknown = sorted(k for k, v in hf.items() if _BLOCK_KEY.search(k)
+                     and k not in _KNOWN_BLOCK_KEYS
+                     and not (k.endswith("_bias") and not v))
+    if unknown:
+        raise ValueError(
+            f"kernels/shapes.py cannot count a block with the keys {unknown}: "
+            f"a dense count would be wrong; add the arithmetic (a new "
+            f"function beside these) with the configuration")
+    held = [hf[k] for k in _EXPERT_COUNT if hf.get(k)]
+    if not held:
+        if hf.get("num_experts_per_tok"):
+            raise ValueError("num_experts_per_tok without a count of experts")
+        return 0, 0
+    if len(held) > 1 or not hf.get("num_experts_per_tok"):
+        raise ValueError("a routed block states one count of experts "
+                         f"({_EXPERT_COUNT}) and num_experts_per_tok")
+    return int(held[0]), int(hf["num_experts_per_tok"])
 
 
 def layer_params(hf: Dict[str, Any]) -> int:
-    """Parameters of one decoder layer, norms included.
-    Mistral-7B: 218,112,000."""
+    """Parameters one decoder layer HOLDS, its two norms included (a
+    family's further norms, as a QK-norm's 2 x H x D, are not in
+    config.json and not here). Mistral-7B: 218,112,000."""
     return layer_matmul_params(hf) + 2 * hf["hidden_size"]
 
 
-def layer_matmul_params(hf: Dict[str, Any]) -> int:
+def layer_matmul_params(hf: Dict[str, Any], active: bool = False) -> int:
+    """Matrix parameters of one decoder layer: those it HOLDS, or with
+    `active` those one token is MULTIPLIED by. They differ in a routed
+    block only: N experts of 3 x E x F held (`intermediate_size` is one
+    expert's width, as the catalog reads it), k of them and the E x N
+    router multiplied by. OLMoE-1B-7B: 419,561,472 held, 67,239,936
+    active."""
     E, F = hf["hidden_size"], hf["intermediate_size"]
     D = hf.get("head_dim") or E // hf["num_attention_heads"]
     H, KV = hf["num_attention_heads"], hf["num_key_value_heads"]
-    return E * H * D + 2 * E * KV * D + H * D * E + 3 * E * F
+    attention = E * H * D + 2 * E * KV * D + H * D * E
+    n_experts, top_k = _experts(hf)
+    if not n_experts:
+        return attention + 3 * E * F
+    return attention + E * n_experts + (top_k if active else n_experts) * 3 * E * F
 
 
 def model_params(hf: Dict[str, Any], n_layers: int = None) -> int:
-    """All parameters: layers, embedding, final norm, untied head.
-    Mistral-7B whole: 7,241,732,096."""
+    """All parameters HELD: layers, embedding, final norm, untied head.
+    Mistral-7B whole: 7,241,732,096; OLMoE-1B-7B: 6,919,096,320."""
     L = hf["num_hidden_layers"] if n_layers is None else n_layers
     E, V = hf["hidden_size"], hf["vocab_size"]
     head = 0 if hf.get("tie_word_embeddings") else E * V
@@ -33,10 +74,13 @@ def model_params(hf: Dict[str, Any], n_layers: int = None) -> int:
 
 def matmul_params(hf: Dict[str, Any], n_layers: int = None) -> int:
     """Parameters a token is MULTIPLIED by: the layers' matrices and
-    the output head. The embedding is a gather and the norms are
-    elementwise: neither is a matmul."""
+    the output head (of a routed block, the router and the k experts a
+    token reaches). The embedding is a gather and the norms are
+    elementwise: neither is a matmul. OLMoE-1B-7B: 1,178,861,568 (its
+    model card's 1.3 B counts the embedding gather too)."""
     L = hf["num_hidden_layers"] if n_layers is None else n_layers
-    return L * layer_matmul_params(hf) + hf["hidden_size"] * hf["vocab_size"]
+    return L * layer_matmul_params(hf, active=True) \
+        + hf["hidden_size"] * hf["vocab_size"]
 
 
 def train_flops_per_token(hf: Dict[str, Any], seq_len: int,

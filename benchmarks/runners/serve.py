@@ -43,6 +43,9 @@ TRACE_SECONDS = 2.0
 # an error of the order of the logits' own spread (25% of the maximum
 # and more). It does NOT tell bf16 from a coarser KV type: PR 21 saw
 # int8 KV (0.035) about level with bf16 (0.032) on the smaller model.
+# That argument is this family's sixteen dense layers: a traffic file's
+# `logits_check` may state its own `rtol` with `rtol_why` (a routed
+# model can flip a near-tied expert under bf16).
 LOGITS_RTOL = 0.08
 
 
@@ -132,6 +135,7 @@ def logits_check(cell, eng, mcfg, host_params, seed, log) -> Dict[str, Any]:
     chk = cell.traffic["logits_check"]
     rng = np.random.default_rng([seed, 0xC4EC])
     n_dec, k = int(chk["decode_steps"]), int(chk["chunk"])
+    rtol = float(chk.get("rtol", LOGITS_RTOL))
     lens = [int(n) for n in chk["prompt_lens"]]
     full = [rng.integers(0, mcfg.vocab_size, n + n_dec).astype(np.int32)
             for n in lens]
@@ -167,9 +171,10 @@ def logits_check(cell, eng, mcfg, host_params, seed, log) -> Dict[str, Any]:
     finite = all(np.isfinite(g).all() for g in got)
     log(f"[bench] logits vs float32 reference: max |err| by step "
         f"{err.max(axis=0).round(5).tolist()} on logits of max |{ref_max:.3f}| "
-        f"(allowed {LOGITS_RTOL} of that)")
-    return {"ok": bool(finite and err.max() <= LOGITS_RTOL * ref_max),
-            "max_abs_err": float(err.max()), "ref_max_abs": ref_max}
+        f"(allowed {rtol} of that)")
+    return {"ok": bool(finite and err.max() <= rtol * ref_max),
+            "max_abs_err": float(err.max()), "ref_max_abs": ref_max,
+            "rtol": rtol}
 
 
 def setup(ctx: harness.RunContext):

@@ -1,89 +1,123 @@
 """The command's contract and the data-driven rule: BENCHMARK.json is
-well-formed, the command refuses to run without a TPU, and a later PR
-adds a configuration, a cell and a per-layer metric as files only."""
+well-formed, every configuration keeps its own published widths, every
+reference is rehearsed, the command refuses to run without a TPU, and a
+later PR adds a configuration, a cell and a per-layer metric as files
+only. The checks themselves are in helpers.py, each taking a root."""
 
 import json
 import os
 import pathlib
-import re
 import subprocess
 import sys
 
 import pytest
 
 from benchmarks import harness
+from benchmarks.tests import helpers
 
 ROOT = pathlib.Path(__file__).resolve().parents[2]
 BENCH = json.loads((ROOT / "BENCHMARK.json").read_text())
-NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
-UNIT = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
-SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
 CELLS = [w["name"] for w in BENCH["workloads"]]
 
 
 def test_benchmark_json_keeps_the_contract():
-    assert set(BENCH) == {"command", "paths", "run_seconds", "configs",
-                          "workloads", "end_to_end", "per_layer"}
-    assert 1 <= BENCH["run_seconds"] <= 51
-    e2e = {m["name"]: m for m in BENCH["end_to_end"]}
-    assert "setup_s" in e2e and e2e["setup_s"]["bound"] <= 0.1
-    names = [m["name"] for m in BENCH["end_to_end"] + BENCH["per_layer"]]
-    assert len(names) == len(set(names))
-    for m in BENCH["end_to_end"]:
-        assert set(m) <= {"name", "unit", "better", "bound", "source", "workloads"}
-        assert 0.01 <= m["bound"] <= 0.1
-        assert m["source"] in ("host_clock", "device_trace")
-    for m in BENCH["per_layer"]:
-        assert set(m) <= {"name", "unit", "better", "source", "layer",
-                          "moves", "workloads"}
-        assert m["moves"] in e2e and m["source"] in SOURCES
-    for m in BENCH["end_to_end"] + BENCH["per_layer"]:
-        assert NAME.match(m["name"]) and UNIT.match(m["unit"])
-        assert m["better"] in ("lower", "higher")
-        assert set(m.get("workloads", CELLS)) <= set(CELLS)
-    four = [w for w in BENCH["workloads"] if w["chips"] == 4]
-    assert len(four) <= max(1, len(CELLS) // 4)
-    pairs = [(w["config"], w["traffic"]) for w in BENCH["workloads"]]
-    assert len(pairs) == len(set(pairs))
-    for w in BENCH["workloads"]:
-        assert set(w) == {"name", "config", "traffic", "chips", "why"}
-        assert NAME.match(w["name"]) and NAME.match(w["traffic"])
-        assert len(w["why"]) <= 200 and w["chips"] in (1, 4)
-    for c in BENCH["configs"]:
-        assert set(c) == {"name", "source", "file", "reduced", "why"}
-        assert len(c["source"]) <= 200 and len(c["why"]) <= 200
-        assert any(w["config"] == c["name"] for w in BENCH["workloads"])
-        assert c["file"].startswith(BENCH["paths"][0] + "/")
+    helpers.check_contract(BENCH)
 
 
 @pytest.mark.parametrize("name", CELLS)
 def test_every_cell_finds_its_files_and_reports_enough(name):
-    cell = harness.load_cell(name)
-    e2e = {m["name"] for m in cell.end_to_end}
-    assert "setup_s" in e2e and len(e2e) >= 2
-    assert len(cell.per_layer) >= 1
-    assert all(m["moves"] in e2e for m in cell.per_layer)
-    assert (cell.bench_dir / "runners" / f"{cell.traffic['runner']}.py").is_file()
-    assert (cell.bench_dir / "reference" / f"{cell.config['reference']}.py").is_file()
-    for m in cell.per_layer:
-        assert (cell.bench_dir / "metrics" / f"{m['name']}.py").is_file(), m["name"]
-    # every key the cut changed from the source is listed
-    entry = {c["name"]: c for c in BENCH["configs"]}[cell.config_name]
-    assert sorted(cell.config["reduced"]) == sorted(entry["reduced"])
-    for key in ("source", "assumed", "stands_for"):
-        assert cell.config[key]
-    # no width is cut
-    for key, want in {"hidden_size": 4096, "intermediate_size": 14336,
-                      "num_attention_heads": 32, "num_key_value_heads": 8,
-                      "vocab_size": 32000, "sliding_window": 4096}.items():
-        assert cell.config[key] == want
+    helpers.check_cell(ROOT, name)
+
+
+def test_every_reference_is_rehearsed_through_its_runner():
+    helpers.check_references_rehearsed(ROOT)
+
+
+def _config(**changes):
+    """The first configuration with `changes` applied (a key set to
+    None is left out), as check_published_widths takes it."""
+    cfg = harness.load_json(ROOT / BENCH["configs"][0]["file"])
+    cfg.update(changes)
+    return {k: v for k, v in cfg.items() if v is not None}
+
+
+def _published():
+    return {k: v for k, v in harness.load_json(
+        ROOT / BENCH["paths"][0] / "configs" / "published"
+        / f"{_config()['published']}.json").items() if not k.startswith("_")}
+
+
+def _first(pattern, also=lambda k, v: True):
+    return next(k for k, v in _published().items()
+                if pattern.search(k) and also(k, v))
+
+
+def test_a_width_may_not_be_cut_and_a_count_only_as_a_stated_share():
+    bench, pub = ROOT / BENCH["paths"][0], _published()
+    helpers.check_published_widths(_config(), bench)
+    is_int = lambda k, v: isinstance(v, int) and not isinstance(v, bool)  # noqa: E731
+    width = _first(helpers.WIDTH_KEY, is_int)
+    count = _first(helpers.COUNT_KEY, is_int)
+    plain = next(k for k in _config()["reduced"])
+
+    def cut(key):
+        here = pub[key] // 2
+        return {key: here, "reduced": dict(
+            _config()["reduced"], **{key: {"published": pub[key], "here": here}})}
+
+    with pytest.raises(AssertionError):       # a width, even when listed
+        helpers.check_published_widths(_config(**cut(width)), bench)
+    with pytest.raises(AssertionError, match="share_of"):
+        helpers.check_published_widths(_config(**cut(count)), bench)
+    helpers.check_published_widths(
+        _config(share_of="one of two tensor-parallel chips", **cut(count)), bench)
+    with pytest.raises(AssertionError, match="not in `reduced`"):
+        helpers.check_published_widths(
+            _config(**{plain: pub[plain], "reduced": {}, count: pub[count] // 2}),
+            bench)
+    with pytest.raises(AssertionError, match="left out"):
+        helpers.check_published_widths(_config(**{count: None}), bench)
+    with pytest.raises(AssertionError):       # the stated published value is wrong
+        helpers.check_published_widths(_config(reduced={
+            plain: {"published": pub[plain] + 1, "here": _config()[plain]}}), bench)
+    # a width inside a nested group may not change either
+    assert helpers.width_changes({"g": {"head_dim": 128, "n": 1}},
+                                 {"g": {"head_dim": 64, "n": 2}}) == ["g.head_dim"]
+
+
+@pytest.mark.parametrize("key,is_width,is_count", [
+    ("hidden_size", 1, 0), ("intermediate_size", 1, 0), ("head_dim", 1, 0),
+    ("moe_intermediate_size", 1, 0), ("kv_lora_rank", 1, 0),
+    ("qk_rope_head_dim", 1, 0), ("num_experts_per_tok", 1, 0),
+    ("sliding_window", 1, 0), ("ssm_state_size", 1, 0), ("expand", 1, 0),
+    ("num_attention_heads", 0, 1), ("num_key_value_heads", 0, 1),
+    ("num_experts", 0, 1), ("num_local_experts", 0, 1),
+    ("n_routed_experts", 0, 1), ("vocab_size", 0, 1),
+    ("num_hidden_layers", 0, 0), ("max_position_embeddings", 0, 0),
+    ("rope_theta", 0, 0)])
+def test_which_keys_are_widths_and_which_are_counts(key, is_width, is_count):
+    assert bool(helpers.WIDTH_KEY.search(key)) == bool(is_width)
+    assert bool(not is_width and helpers.COUNT_KEY.search(key)) == bool(is_count)
+
+
+@pytest.mark.parametrize("own,ok", [
+    ({}, True), ({"rtol": 0.12}, False), ({"rtol_why": "x"}, False),
+    ({"rtol": 0.12, "rtol_why": " "}, False),
+    ({"rtol": 0.12, "rtol_why": "a routed model can flip a near-tied "
+      "expert under bf16"}, True)])
+def test_a_logits_limit_of_its_own_needs_its_reason(own, ok):
+    if ok:
+        helpers.check_logits_limit({"logits_check": own})
+    else:
+        with pytest.raises((AssertionError, KeyError)):
+            helpers.check_logits_limit({"logits_check": own})
 
 
 def test_the_command_refuses_to_run_without_a_tpu():
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     p = subprocess.run(
-        [sys.executable, str(ROOT / "benchmarks" / "run.py"), "--workload",
-         "train-seq4k", "--seed", "0", "--seconds", "1", "--trace", "0"],
+        [sys.executable, str(ROOT / BENCH["command"][1]), "--workload",
+         CELLS[0], "--seed", "0", "--seconds", "1", "--trace", "0"],
         cwd=ROOT, env=env, capture_output=True, text=True, timeout=300)
     assert p.returncode not in (0, 2), p.stderr[-2000:]
     assert "needs a TPU" in p.stderr
@@ -98,8 +132,14 @@ def test_a_configuration_a_cell_and_a_metric_are_added_as_files(tiny_root):
     for rel in real_files:   # nothing that is there was edited
         assert (tiny_root / "benchmarks" / rel).read_bytes() == \
             (ROOT / "benchmarks" / rel).read_bytes()
-    cell = harness.load_cell("tiny-serve", tiny_root)
-    assert cell.config["hidden_size"] == 256
+    # the rehearsal cell that has no real twin: its own end-to-end
+    # metric and reader, added as files and entries
+    rc = next(rc for rc in helpers.rehearsal_cells() if rc.get("adds"))
+    cell = harness.load_cell(rc["name"], tiny_root)
+    tiny = harness.load_json(
+        helpers.data_dir() / "configs" / f"{rc['config']}.json")
+    assert cell.config == tiny and cell.config["hidden_size"] != \
+        harness.load_cell(CELLS[0]).config["hidden_size"]
     assert "dummy_answer" in {m["name"] for m in cell.per_layer}
     logs = []
     got = harness.read_per_layer(
@@ -112,3 +152,28 @@ def test_a_configuration_a_cell_and_a_metric_are_added_as_files(tiny_root):
     assert any("mixed_program_ms" in line for line in logs)
     with pytest.raises(KeyError):
         harness.load_cell("no-such-cell", tiny_root)
+
+
+def test_a_real_cell_that_no_rehearsal_names_is_skipped(tmp_path):
+    """A cell added to BENCHMARK.json with no rehearsal cell of its own
+    does not stop the tiny root from being built."""
+    src = tmp_path / "src"
+    helpers.copy_checkout(src)
+    bench = json.loads((src / "BENCHMARK.json").read_text())
+    w = dict(bench["workloads"][0], name="a-cell-nobody-rehearses",
+             traffic=bench["workloads"][-1]["traffic"])
+    bench["workloads"].append(w)
+    for m in bench["end_to_end"] + bench["per_layer"]:
+        if bench["workloads"][0]["name"] in m.get("workloads", ()):
+            m["workloads"].append(w["name"])
+    (src / "BENCHMARK.json").write_text(json.dumps(bench))
+    tiny = helpers.make_tiny_root(tmp_path / "tiny", root=src)
+    for rc in helpers.rehearsal_cells(src):
+        assert harness.load_cell(rc["name"], tiny).name == rc["name"]
+    # a rehearsal that names a cell which is not there says so
+    cells = helpers.data_dir(src) / "cells"
+    rc = json.loads(next(iter(sorted(cells.glob("*.json")))).read_text())
+    rc.update(name="tiny-orphan", reports_as="no-such-cell")
+    (cells / "tiny-orphan.json").write_text(json.dumps(rc))
+    with pytest.raises(ValueError, match="no-such-cell"):
+        helpers.make_tiny_root(tmp_path / "tiny2", root=src)

@@ -18,7 +18,7 @@ from deepspeed_tpu.ops.pallas import interpret_kernels
 from deepspeed_tpu.utils.hf_checkpoint import config_from_hf
 
 DATA = pathlib.Path(__file__).resolve().parent / "data"
-HF = dict(json.loads((DATA / "tiny-mistral.json").read_text()),
+HF = dict(json.loads((DATA / "configs" / "tiny-mistral.json").read_text()),
           sliding_window=48)
 
 # float32 on both sides; the system reassociates (fused QKV, flash

@@ -53,7 +53,8 @@ class Capture:
 
     def _keep(self, td: R.TraceData) -> None:
         def rows(evs):
-            return [[e.name, e.start, e.dur] for e in evs[:KEEP_EVENTS]]
+            return [[e.name, e.start, e.dur] + ([e.scope] if e.scope else [])
+                    for e in evs[:KEEP_EVENTS]]
 
         with open(self.out_dir / "trace_events.json", "w") as f:
             json.dump({
@@ -72,8 +73,8 @@ def load_recorded(path) -> R.TraceData:
     with open(path) as f:
         d = json.load(f)
 
-    def evs(rows):
-        return [R.Event(n, s, t) for n, s, t in rows]
+    def evs(rows):   # [name, start, duration] and, since PR 26, the scope
+        return [R.Event(*row) for row in rows]
 
     td = R.from_events({int(k): evs(v) for k, v in d["ops"].items()},
                        {int(k): evs(v) for k, v in d["modules"].items()},

@@ -21,6 +21,18 @@ flow (`while.124`) as events that CONTAIN their bodies' events; line
 executions. The host plane `/host:CPU` holds one line per thread; the
 benchmark's `jax.profiler.TraceAnnotation` spans (`bench.*`) are events
 on the `python3` line, on the same clock as the device lines.
+
+Where an instruction's named scope lives (looked at by hand, PR 26): an
+event carries only its device offset and duration; its METADATA (one
+entry per distinct instruction of a plane) carries the stats
+`hlo_category`, `flops`, `bytes_accessed`, `source` and `tf_op`, the
+last being the HLO `op_name`: the `jax.named_scope` path,
+`jit(step_fn)/while/body/closed_call/jvp()/while/body/closed_call/mlp/
+bsf,fe->bse/dot_general:`, with the HLO proto off. 522 of the 711
+instructions of a train step have one; copies and other instructions
+XLA inserts have none. `jax.profiler.ProfileData` does not hand
+metadata stats out, so `metadata_scopes` reads them from the file's
+protobuf wire format with nothing but the standard library.
 """
 
 import dataclasses
@@ -44,6 +56,7 @@ class Event:
     name: str
     start: float  # seconds
     dur: float    # seconds
+    scope: str = ""  # the instruction's `op_name`: its named-scope path
 
     @property
     def end(self) -> float:
@@ -89,10 +102,81 @@ def split_instruction(text: str) -> Tuple[str, str]:
     return name.lstrip("%"), rhs[:72]
 
 
+def _varint(buf, i):
+    x = shift = 0
+    while True:
+        c = buf[i]
+        i += 1
+        x |= (c & 0x7F) << shift
+        if c < 0x80:
+            return x, i
+        shift += 7
+
+
+def _fields(buf):
+    """(field number, value) pairs of one protobuf message: an int for
+    a varint, a memoryview for a length-delimited or fixed field."""
+    i, n = 0, len(buf)
+    while i < n:
+        key, i = _varint(buf, i)
+        kind = key & 7
+        if kind == 0:
+            value, i = _varint(buf, i)
+        else:
+            if kind == 2:
+                size, i = _varint(buf, i)
+            elif kind in (1, 5):
+                size = 8 if kind == 1 else 4
+            else:
+                raise ValueError(f"protobuf wire type {kind} in an xplane file")
+            value = buf[i:i + size]
+            i += size
+        yield key >> 3, value
+
+
+def metadata_scopes(path: str, stat: str = "tf_op") -> Dict[str, Dict[str, str]]:
+    """plane name -> {event name -> the `tf_op` stat of its metadata}.
+    The schema (tsl/profiler/protobuf/xplane.proto): XSpace.planes = 1;
+    XPlane.name = 2, .event_metadata = 4, .stat_metadata = 5 (maps:
+    key 1, value 2); XEventMetadata.name = 2, .stats = 5;
+    XStatMetadata.name = 2; XStat.metadata_id = 1, .str_value = 5,
+    .ref_value = 7 (the text is then the NAME of that stat metadata)."""
+    with open(path, "rb") as f:
+        space = memoryview(f.read())
+    out: Dict[str, Dict[str, str]] = {}
+    for num, plane in _fields(space):
+        if num != 1:
+            continue
+        name, events, stat_names = "", [], {}
+        for num, value in _fields(plane):
+            if num == 2:
+                name = bytes(value).decode()
+            elif num in (4, 5):
+                entry = dict(_fields(value))
+                msg = list(_fields(entry[2]))
+                text = next((bytes(v).decode(errors="replace")
+                             for n, v in msg if n == 2), "")
+                if num == 5:
+                    stat_names[entry[1]] = text
+                else:
+                    events.append((text, [dict(_fields(v)) for n, v in msg if n == 5]))
+        want = {i for i, n in stat_names.items() if n == stat}
+        scopes = {}
+        for text, stats in events:
+            for st in stats:
+                if st.get(1) in want:
+                    scopes[text] = (bytes(st[5]).decode(errors="replace") if 5 in st
+                                    else stat_names.get(st.get(7), ""))
+        if scopes:
+            out[name] = scopes
+    return out
+
+
 def load_xplane(path: str) -> TraceData:
     import jax
 
     pd = jax.profiler.ProfileData.from_file(path)
+    scopes = metadata_scopes(path)
     lines: Dict[str, Dict[int, List[Event]]] = {
         OPS_LINE: {}, ASYNC_LINE: {}, MODULES_LINE: {}}
     details: Dict[str, str] = {}
@@ -102,12 +186,14 @@ def load_xplane(path: str) -> TraceData:
         for line in plane.lines:
             if m and line.name in lines:
                 evs = []
+                scope_of = scopes.get(plane.name, {})
                 for e in line.events:
                     name, what = split_instruction(e.name)
                     if what:
                         details.setdefault(name, what)
                     evs.append(Event(name, e.start_ns * 1e-9,
-                                     e.duration_ns * 1e-9))
+                                     e.duration_ns * 1e-9,
+                                     scope_of.get(e.name, "")))
                 lines[line.name][int(m.group(1))] = sorted(
                     evs, key=lambda e: e.start)
             elif plane.name.startswith("/host:"):
@@ -205,6 +291,62 @@ def kernel_seconds(td: TraceData, names: Sequence[str], device: int = 0) -> Opti
     return sum(e.dur for e in evs) if evs else None
 
 
+_WRAPPERS = re.compile(r"^(?:\w+\()+|\)+$")
+# path components that say how the program is built, not what it computes
+_STRUCTURAL = {"", "while", "body", "cond", "closed_call", "checkpoint",
+               "rematted_computation", "branch", "remat", "custom_jvp_call",
+               "custom_vjp_call"}
+
+
+def scope_components(path: str) -> List[str]:
+    """`jit(f)/while/body/transpose(jvp(lm_head))/dot_general:` ->
+    [`f`, `while`, `body`, `lm_head`, `dot_general`]: autodiff wraps
+    the scope it was entered under (`jvp(mlp)`, `transpose(jvp(mlp))`)
+    or nothing (`jvp()`); the wrappers go, the name stays."""
+    return [_WRAPPERS.sub("", c) for c in path.rstrip(":").split("/")]
+
+
+def scope_of(path: str, names: Sequence[str]) -> Optional[str]:
+    """The first (outermost) component of the path that is one of
+    `names`: a scope entered inside another counts for the outer one."""
+    return next((c for c in scope_components(path) if c in names), None)
+
+
+def short_scope(path: str) -> str:
+    """The outermost scope and the operation, `lm_head/reduce_sum`: the
+    path without its wrappers, the enclosing `jit(...)`s, the control
+    flow and what lies between the two."""
+    kept = [c for raw, c in zip(path.rstrip(":").split("/"), scope_components(path))
+            if c not in _STRUCTURAL and not raw.startswith(("jit(", "pjit("))]
+    return "/".join(kept if len(kept) < 3 else (kept[0], kept[-1]))
+
+
+def scope_events(td: TraceData, names: Sequence[str], device: int = 0) -> List[Event]:
+    """The work events (leaves only: a `while` CONTAINS its body) inside
+    the traced window whose scope path holds one of `names`. An event
+    is one execution of one instruction, so each is counted once. A
+    fusion carries the scope of its root instruction: what XLA fused
+    across a scope's boundary is booked to one side."""
+    return [e for e in leaves(in_window(td.ops.get(device, []), td.window))
+            if e.scope and scope_of(e.scope, names) is not None]
+
+
+def scope_seconds(td: TraceData, names: Sequence[str], device: int = 0) -> Optional[float]:
+    """Sum of device durations of the events inside the named scopes
+    `names`, inside the traced window. None when none ran (a trace
+    without scopes, a program without these names)."""
+    evs = scope_events(td, names, device)
+    return sum(e.dur for e in evs) if evs else None
+
+
+def scope_ms_per_step(obs, names: Sequence[str]) -> Optional[float]:
+    """What a per-layer reader of one scope returns: milliseconds per
+    traced step on device 0, or None without a trace or the scope."""
+    td = obs.get("trace")
+    s = scope_seconds(td, names) if td is not None else None
+    return None if s is None else 1e3 * s / obs["traced_steps"]
+
+
 def is_kernel(event_name: str, names: Sequence[str]) -> bool:
     """A Pallas kernel's event carries the kernel's `name=` inside the
     instruction name; autodiff wraps it (`jvp_flash_fwd_.1`,
@@ -277,13 +419,21 @@ def idle_gaps(td: TraceData, device: int = 0) -> List[Tuple[str, float]]:
 
 def breakdown(td: TraceData, device: int = 0) -> Dict[str, list]:
     """The contract's `breakdown`: the ten device operations with most
-    time (work events summed per instruction, under the name the trace
-    prints and, since `fusion.335` says little, what it computes) and
-    the longest idle gaps by covering span."""
+    time, work events summed per instruction, and the longest idle gaps
+    by covering span. A name reads `<n>x <instruction> <scope> <what it
+    computes>`: n is how often the instruction ran in the window (three
+    PRs read 16 executions as one slow one), the scope is its named
+    scope where the trace has one, and `fusion.335` alone says little."""
     total: Dict[str, float] = {}
+    count: Dict[str, int] = {}
+    scope: Dict[str, str] = {}
     for e in leaves(in_window(td.ops.get(device, []), td.window)):
         total[e.name] = total.get(e.name, 0.0) + e.dur
+        count[e.name] = count.get(e.name, 0) + 1
+        if e.scope:
+            scope.setdefault(e.name, short_scope(e.scope))
     top = sorted(total.items(), key=lambda kv: -kv[1])[:10]
-    return {"device_ops": [[f"{k} {td.details.get(k, '')}".strip(), v]
-                           for k, v in top],
-            "idle_gaps": [[k, v] for k, v in idle_gaps(td, device)[:5]]}
+    return {"device_ops": [[" ".join(filter(None, (
+        f"{count[k]}x", k, scope.get(k), td.details.get(k)))), v]
+        for k, v in top],
+        "idle_gaps": [[k, v] for k, v in idle_gaps(td, device)[:5]]}

@@ -1994,7 +1994,8 @@ def init_inference(
             raise ValueError("conflicting offload in config and kwarg")
         offload = off
     icfg = InferenceConfig(**cfg)
-    with profiler.span("init.inference", always=True):
+    with profiler.span("init.inference", always=True,
+                       **M.moe_span_ids(model_config, icfg.max_batch_size)):
         return InferenceEngine(model_config, params, icfg, dtype,
                                quantization=quantization, mesh=mesh,
                                offload=offload)

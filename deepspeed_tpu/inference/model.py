@@ -132,6 +132,8 @@ _SERVING_SPECS = {
     "ln1_bias": (None, ("embed",)),
     "ln2_scale": (None, ("embed",)),
     "ln2_bias": (None, ("embed",)),
+    "q_norm_scale": (None, ("heads", "head_dim")),
+    "k_norm_scale": (None, ("heads", "head_dim")),
     # MoE expert stacks (never per-channel-quantized; X leading dim)
     "w_router": (None, ("embed", None)),
     # PR-MoE residual dense expert + mixing coefficient
@@ -532,8 +534,42 @@ def _sparse_decode_allowed_slots(scfg, positions, n_blocks: int,
     return rows[:, slot_sparse]
 
 
+# Rows an expert sees (T x k / X, static in a compiled program) between
+# which serving scans over ALL experts; at or outside these it takes the
+# ragged wire. Measured on a TPU v5e at OLMoE-1B-7B's widths (64 experts
+# of 2048 x 1024, top-8, bf16, 8 layers; PERF.md section 6, PR 27), ms
+# for the routed block of 8 layers, scan / ragged: T 8: 11.3 / 6.2,
+# 16: 11.4 / 9.3, 64: 12.1 / 20.7, 128: 14.4 / 21.4, 256: 16.4 / 22.9,
+# 512: 24.7 / 25.6, 1024: 40.0 / 32.9. The scan streams every expert's
+# weights once and multiplies every token by every expert (X / k times
+# the needed operations): best while the stream binds. The ragged wire
+# does the needed operations only, at a fixed cost of its sort, gather
+# and `lax.ragged_dot` (flat at ~2.6 ms a layer from 8 to 32 rows an
+# expert), and skips experts no token reached, so it wins at both ends.
+_SCAN_ROWS_PER_EXPERT = (2, 128)
+
+
+def expert_path(n_tokens: int, cfg: T.TransformerConfig) -> str:
+    """Which expert path a compiled serving program over `n_tokens`
+    rows takes: 'scan' or 'ragged'. A function of the call's static
+    shape alone (no flag selects it)."""
+    rows = n_tokens * cfg.moe_top_k / cfg.n_experts
+    lo, hi = _SCAN_ROWS_PER_EXPERT
+    return "scan" if lo < rows < hi else "ragged"
+
+
+def moe_span_ids(cfg: T.TransformerConfig, width: int) -> Dict[str, Any]:
+    """What `init.inference` says of a routed model: its experts, its
+    top-k, and the expert path of the decode program `width` rows wide
+    (nothing for a dense one)."""
+    if cfg.n_experts == 0:
+        return {}
+    return {"n_experts": cfg.n_experts, "moe_top_k": cfg.moe_top_k,
+            "moe_expert_path": expert_path(width, cfg)}
+
+
 def _mlp(h, lp, cfg: T.TransformerConfig, census_cb=None):
-    """FFN over [T, E] tokens — dense or MoE (Mixtral-class serving).
+    """FFN over [T, E] tokens — dense or MoE (Mixtral / OLMoE serving).
 
     Dense llama uses the fused [E, 2F] gate|up GEMM when the prepared
     layout carries it (see prepare()).
@@ -543,18 +579,26 @@ def _mlp(h, lp, cfg: T.TransformerConfig, census_cb=None):
     a training-throughput artifact; ref: sharded_moe.py topk_gating
     keeps the drops only because the fixed [X, C] buffers feed the
     all-to-all). Gate weights reproduce the training combine weights
-    exactly (top-1: the softmax gate; k>=2: renormalized), so serving
-    matches the training forward wherever training dropped nothing.
+    exactly (cfg.moe_norm_topk_prob; unset: top-1 the softmax gate,
+    k>=2 renormalized), so serving matches the training forward
+    wherever training dropped nothing.
 
     Two expert paths share the gating authority
-    (moe.dropless.dropless_topk_gating):
-    - cfg.moe_dropless: per-expert token batching — the ragged batch's
-      rows stable-sort by expert id and run as ONE grouped (ragged)
-      GEMM per projection inside this same compiled program
+    (moe.dropless.dropless_topk_gating); expert_path() picks one from
+    the static rows an expert sees (T x k / X):
+    - 'ragged': per-expert token batching — the ragged batch's rows
+      stable-sort by expert id and run as ONE grouped (ragged) GEMM per
+      projection inside this same compiled program
       (moe/dropless.py dropless_apply), FLOPs proportional to T*k.
-    - default: a `lax.scan` over the stacked expert weights with a
-      per-expert combine column — X-times the dense FFN FLOPs, no
-      [T,X,C] dispatch tensor; fine for decode widths.
+    - 'scan': a `lax.scan` over the stacked expert weights with a
+      per-expert combine column — X/k times the needed FLOPs, no
+      [T,X,C] dispatch tensor, every weight streamed once.
+
+    Device time is told apart by scope: `moe_route` (router matmul,
+    softmax, top-k, and the sort or the weight matrix), `moe_experts`
+    (the expert matmuls and activation; on the scan path the combine
+    column too, which XLA fuses into the output matmul), `moe_combine`
+    (the ragged wire's weighting and segment-sum).
 
     Expert stacks may arrive as groupwise-int8 QuantizedWeight (the
     N004 machinery; quantize_layer): codes dequantize transiently here,
@@ -597,17 +641,26 @@ def _mlp(h, lp, cfg: T.TransformerConfig, census_cb=None):
 
     X = cfg.n_experts
     T_ = h.shape[0]
-    logits = h.astype(jnp.float32) @ lp["w_router"].astype(jnp.float32)
-    # eval gate: no noise; one authority with the training paths
-    idx, wts, _, _ = dropless_topk_gating(logits, cfg.moe_top_k)
-    if census_cb is not None:
-        jax.debug.callback(census_cb, expert_counts(idx, X))
+    path = expert_path(T_, cfg)
+    with jax.named_scope("moe_route"):
+        logits = h.astype(jnp.float32) @ lp["w_router"].astype(jnp.float32)
+        # eval gate: no noise; one authority with the training paths
+        idx, wts, _, _ = dropless_topk_gating(
+            logits, cfg.moe_top_k, renormalize=cfg.moe_norm_topk_prob)
+        if census_cb is not None:
+            jax.debug.callback(census_cb, expert_counts(idx, X))
+        if path == "scan":
+            # combine-weight matrix [T, X] from the top-k decisions
+            weights = jnp.zeros((T_, X), jnp.float32).at[
+                jnp.arange(T_)[:, None], idx].add(wts)
+            wcols = weights.T.astype(h.dtype)
 
     has_gate = cfg.is_gated
     has_bias = "b_in" in lp
-    if cfg.moe_dropless:
+    if path == "ragged":
         # per-expert token batching across the ragged batch: ONE
         # grouped GEMM per projection in this same compiled program
+        # (its three scopes are dropless_apply's own)
         out = dropless_apply(
             h, idx, wts, expert_counts(idx, X),
             deq(lp["w_in"]), deq(lp["w_out"]),
@@ -615,10 +668,7 @@ def _mlp(h, lp, cfg: T.TransformerConfig, census_cb=None):
             b_in=lp.get("b_in"), b_out=lp.get("b_out"), act=act)
         return _moe_residual(out, h, lp, cfg, act)
 
-    # combine-weight matrix [T, X] from the top-k decisions
-    weights = jnp.zeros((T_, X), jnp.float32).at[
-        jnp.arange(T_)[:, None], idx].add(wts)
-    xs = [deq(lp["w_in"]), deq(lp["w_out"]), weights.T.astype(h.dtype)]
+    xs = [deq(lp["w_in"]), deq(lp["w_out"]), wcols]
     if has_gate:
         xs.append(deq(lp["w_gate"]))
     if has_bias:
@@ -642,8 +692,30 @@ def _mlp(h, lp, cfg: T.TransformerConfig, census_cb=None):
                 y = y + b_out.astype(h.dtype)
         return acc + wcol[:, None] * y, None
 
-    out, _ = jax.lax.scan(expert, jnp.zeros_like(h), tuple(xs))
+    with jax.named_scope("moe_experts"):
+        out, _ = jax.lax.scan(expert, jnp.zeros_like(h), tuple(xs))
     return _moe_residual(out, h, lp, cfg, act)
+
+
+def _ffn_residual(x, attn_out, h1, lp, cfg: T.TransformerConfig,
+                  census_cb=None):
+    """The tail of one layer over [..., E] activations, for both serving
+    sites: the attention residual, norm2 and the FFN (sequential, or
+    the Falcon/Phi parallel form where the FFN reads ln2(x) or the
+    shared ln1 output h1), under the scopes the training forward
+    names (`norm2`, `mlp`)."""
+    if not cfg.parallel_residual:
+        x = x + attn_out
+    if cfg.parallel_residual and cfg.shared_ln:
+        h2 = h1
+    else:
+        with jax.named_scope("norm2"):
+            h2 = T._act_quant(
+                T._norm(x, lp["ln2_scale"], lp.get("ln2_bias"), cfg), cfg)
+    with jax.named_scope("mlp"):
+        y = _mlp(h2.reshape(-1, h2.shape[-1]), lp, cfg,
+                 census_cb=census_cb).reshape(x.shape)
+    return x + attn_out + y if cfg.parallel_residual else x + y
 
 
 def _moe_residual(out, h, lp, cfg: T.TransformerConfig, act):
@@ -825,12 +897,13 @@ def decode_step(
         else:
             allowed = _sparse_decode_allowed(
                 scfg, positions, tables.shape[1] * cache.block_size)
-    x = _embed_rows(params["embed"], tokens)  # [S, E]
-    if cfg.use_learned_pos:
-        x = x + params["pos_embed"][positions].astype(x.dtype)
-    if cfg.embedding_layernorm:
-        x = T._norm(x, params["embed_ln_scale"],
-                    params.get("embed_ln_bias"), cfg)
+    with jax.named_scope("embed"):
+        x = _embed_rows(params["embed"], tokens)  # [S, E]
+        if cfg.use_learned_pos:
+            x = x + params["pos_embed"][positions].astype(x.dtype)
+        if cfg.embedding_layernorm:
+            x = T._norm(x, params["embed_ln_scale"],
+                        params.get("embed_ln_bias"), cfg)
     alibi = (jnp.asarray(T.model_alibi_slopes(cfg)) if cfg.alibi
              else None)
 
@@ -857,89 +930,85 @@ def decode_step(
         if fetch_layer is not None:
             lp = fetch_layer(lp, x_hist[-2] if len(x_hist) >= 2 else None,
                              li)
-        h1 = T._act_quant(T._norm(x, lp["ln1_scale"], lp.get("ln1_bias"), cfg), cfg)
-        if "w_qkv" in lp:
-            qkv = _wmm("se,ehd->shd", h1, lp["w_qkv"])
-            if "b_qkv" in lp:
-                qkv = qkv + lp["b_qkv"].astype(x.dtype)
-            q, k, v = jnp.split(qkv, [H, H + KV], axis=1)
-        else:
-            q = _wmm("se,ehd->shd", h1, lp["wq"])
-            k = _wmm("se,ehd->shd", h1, lp["wk"])
-            v = _wmm("se,ehd->shd", h1, lp["wv"])
-            if "bq" in lp:
-                q = q + lp["bq"].astype(x.dtype)
-                k = k + lp["bk"].astype(x.dtype)
-                v = v + lp["bv"].astype(x.dtype)
-        if cfg.use_rope:
-            q = _rope_at(q, positions, cfg)
-            k = _rope_at(k, positions, cfg)
-        q = _cons(q, mesh, None, "model", None)
-        k = _cons(k, mesh, None, "model", None)
-        v = _cons(v, mesh, None, "model", None)
-
-        li_c = len(new_k)
-        ck_in, cv_in = cache.k[li_c], cache.v[li_c]
-        cks = cvs = None
-        if fuse_write:
-            if quant:
-                att, ck, cv, cks, cvs = _decode_attention(
-                    q, ck_in, cv_in, tables, ctx_lens, use_kernel,
-                    allowed_slots=allowed_slots,
-                    window=cfg.window_for_layer(li),
-                    mesh=mesh, k_new=k, v_new=v, slots=flat_idx,
-                    alibi=alibi, k_scale=cache.k_scale[li_c],
-                    v_scale=cache.v_scale[li_c],
-                )
+        with jax.named_scope("norm1"):
+            h1 = T._act_quant(
+                T._norm(x, lp["ln1_scale"], lp.get("ln1_bias"), cfg), cfg)
+        with jax.named_scope("attention"):
+            if "w_qkv" in lp:
+                qkv = _wmm("se,ehd->shd", h1, lp["w_qkv"])
+                if "b_qkv" in lp:
+                    qkv = qkv + lp["b_qkv"].astype(x.dtype)
+                q, k, v = jnp.split(qkv, [H, H + KV], axis=1)
             else:
-                att, ck, cv = _decode_attention(
-                    q, ck_in, cv_in, tables, ctx_lens, use_kernel,
-                    allowed_slots=allowed_slots,
-                    window=cfg.window_for_layer(li),
-                    mesh=mesh, k_new=k, v_new=v, slots=flat_idx,
-                    alibi=alibi,
-                )
-        else:
-            if quant:
-                ck, cv, cks, cvs = _write_kv_quant(
-                    ck_in, cv_in, cache.k_scale[li_c], cache.v_scale[li_c],
-                    k, v, flat_idx, mesh, use_kernel)
-                cks = _cons(cks, mesh, None, None, "model")
-                cvs = _cons(cvs, mesh, None, None, "model")
-            else:
-                ck, cv = _write_kv(ck_in, cv_in, k, v, flat_idx, mesh,
-                                   use_kernel)
-            ck = _cons(ck, mesh, None, None, "model", None)
-            cv = _cons(cv, mesh, None, None, "model", None)
-            att = _decode_attention(q, ck, cv, tables, ctx_lens, use_kernel,
-                                    allowed=allowed,
-                                    allowed_slots=allowed_slots,
-                                    window=cfg.window_for_layer(li),
-                                    mesh=mesh, alibi=alibi,
-                                    k_scale=cks, v_scale=cvs)
-        new_k.append(ck)
-        new_v.append(cv)
-        if quant:
-            new_ks.append(cks)
-            new_vs.append(cvs)
-        out = _wmm("shd,hde->se", att, lp["wo"])
-        if "bo" in lp:
-            out = out + lp["bo"].astype(x.dtype)
+                q = _wmm("se,ehd->shd", h1, lp["wq"])
+                k = _wmm("se,ehd->shd", h1, lp["wk"])
+                v = _wmm("se,ehd->shd", h1, lp["wv"])
+                if "bq" in lp:
+                    q = q + lp["bq"].astype(x.dtype)
+                    k = k + lp["bk"].astype(x.dtype)
+                    v = v + lp["bv"].astype(x.dtype)
+            q, k = T.qk_norm(q, k, lp, cfg)
+            if cfg.use_rope:
+                q = _rope_at(q, positions, cfg)
+                k = _rope_at(k, positions, cfg)
+            q = _cons(q, mesh, None, "model", None)
+            k = _cons(k, mesh, None, "model", None)
+            v = _cons(v, mesh, None, "model", None)
 
-        if cfg.parallel_residual:
-            h2 = h1 if cfg.shared_ln else T._act_quant(
-                T._norm(x, lp["ln2_scale"], lp.get("ln2_bias"), cfg), cfg)
-            x = x + out + _mlp(h2, lp, cfg, census_cb=census_cb)
-        else:
-            x = x + out
-            h2 = T._act_quant(
-                T._norm(x, lp["ln2_scale"], lp.get("ln2_bias"), cfg), cfg)
-            x = x + _mlp(h2, lp, cfg, census_cb=census_cb)
+            li_c = len(new_k)
+            ck_in, cv_in = cache.k[li_c], cache.v[li_c]
+            cks = cvs = None
+            if fuse_write:
+                if quant:
+                    att, ck, cv, cks, cvs = _decode_attention(
+                        q, ck_in, cv_in, tables, ctx_lens, use_kernel,
+                        allowed_slots=allowed_slots,
+                        window=cfg.window_for_layer(li),
+                        mesh=mesh, k_new=k, v_new=v, slots=flat_idx,
+                        alibi=alibi, k_scale=cache.k_scale[li_c],
+                        v_scale=cache.v_scale[li_c],
+                    )
+                else:
+                    att, ck, cv = _decode_attention(
+                        q, ck_in, cv_in, tables, ctx_lens, use_kernel,
+                        allowed_slots=allowed_slots,
+                        window=cfg.window_for_layer(li),
+                        mesh=mesh, k_new=k, v_new=v, slots=flat_idx,
+                        alibi=alibi,
+                    )
+            else:
+                if quant:
+                    ck, cv, cks, cvs = _write_kv_quant(
+                        ck_in, cv_in, cache.k_scale[li_c], cache.v_scale[li_c],
+                        k, v, flat_idx, mesh, use_kernel)
+                    cks = _cons(cks, mesh, None, None, "model")
+                    cvs = _cons(cvs, mesh, None, None, "model")
+                else:
+                    ck, cv = _write_kv(ck_in, cv_in, k, v, flat_idx, mesh,
+                                       use_kernel)
+                ck = _cons(ck, mesh, None, None, "model", None)
+                cv = _cons(cv, mesh, None, None, "model", None)
+                att = _decode_attention(
+                    q, ck, cv, tables, ctx_lens, use_kernel, allowed=allowed,
+                    allowed_slots=allowed_slots,
+                    window=cfg.window_for_layer(li), mesh=mesh, alibi=alibi,
+                    k_scale=cks, v_scale=cvs)
+            new_k.append(ck)
+            new_v.append(cv)
+            if quant:
+                new_ks.append(cks)
+                new_vs.append(cvs)
+            out = _wmm("shd,hde->se", att, lp["wo"])
+            if "bo" in lp:
+                out = out + lp["bo"].astype(x.dtype)
+
+        x = _ffn_residual(x, out, h1, lp, cfg, census_cb)
         x_hist.append(x)
 
-    x = T._norm(x, params["ln_f_scale"], params.get("ln_f_bias"), cfg)
-    logits = _lm_logits(x, params, cfg)
-    logits = _cons(logits, mesh, None, None)
+    with jax.named_scope("lm_head"):
+        x = T._norm(x, params["ln_f_scale"], params.get("ln_f_bias"), cfg)
+        logits = _lm_logits(x, params, cfg)
+        logits = _cons(logits, mesh, None, None)
     if quant:
         return logits, PagedCache(k=new_k, v=new_v,
                                   k_scale=new_ks, v_scale=new_vs)
@@ -1055,12 +1124,13 @@ def prefill_batch(
         _sparse_prefill_mask(scfg, Tp)
         if scfg is not None and Tp % scfg.block != 0 else None
     )
-    x = _embed_rows(params["embed"], tokens)  # [B, Tp, E]
-    if cfg.use_learned_pos:
-        x = x + params["pos_embed"][:Tp].astype(x.dtype)[None]
-    if cfg.embedding_layernorm:
-        x = T._norm(x, params["embed_ln_scale"],
-                    params.get("embed_ln_bias"), cfg)
+    with jax.named_scope("embed"):
+        x = _embed_rows(params["embed"], tokens)  # [B, Tp, E]
+        if cfg.use_learned_pos:
+            x = x + params["pos_embed"][:Tp].astype(x.dtype)[None]
+        if cfg.embedding_layernorm:
+            x = T._norm(x, params["embed_ln_scale"],
+                        params.get("embed_ln_bias"), cfg)
     alibi = (jnp.asarray(T.model_alibi_slopes(cfg)) if cfg.alibi
              else None)
 
@@ -1081,112 +1151,109 @@ def prefill_batch(
         if fetch_layer is not None:
             lp = fetch_layer(lp, x_hist[-2] if len(x_hist) >= 2 else None,
                              li)
-        h1 = T._act_quant(T._norm(x, lp["ln1_scale"], lp.get("ln1_bias"), cfg), cfg)
-        if "w_qkv" in lp:
-            qkv = _wmm("bse,ehd->bshd", h1, lp["w_qkv"])
-            if "b_qkv" in lp:
-                qkv = qkv + lp["b_qkv"].astype(x.dtype)
-            q, k, v = jnp.split(qkv, [H, H + KV], axis=2)
-        else:
-            q = _wmm("bse,ehd->bshd", h1, lp["wq"])
-            k = _wmm("bse,ehd->bshd", h1, lp["wk"])
-            v = _wmm("bse,ehd->bshd", h1, lp["wv"])
-            if "bq" in lp:
-                q = q + lp["bq"].astype(x.dtype)
-                k = k + lp["bk"].astype(x.dtype)
-                v = v + lp["bv"].astype(x.dtype)
-        if cfg.use_rope:
-            rot = jax.vmap(_rope_at, in_axes=(0, None, None))
-            q = rot(q, positions, cfg)
-            k = rot(k, positions, cfg)
-        q = _cons(q, mesh, None, None, "model", None)
-        k = _cons(k, mesh, None, None, "model", None)
-        v = _cons(v, mesh, None, None, "model", None)
-
-        KVh, Dh = k.shape[2], k.shape[3]
-        l = len(new_k)
-        if quant:
-            # the prompt's in-flight attention below stays full
-            # precision (it never reads the cache); only the RESIDENT
-            # copy quantizes — later decode steps read these codes
-            ck, cv, cks, cvs = _write_kv_quant(
-                cache.k[l], cache.v[l], cache.k_scale[l], cache.v_scale[l],
-                k.reshape(B * Tp, KVh, Dh),
-                v.reshape(B * Tp, KVh, Dh), flat_idx, mesh, use_kernel)
-            new_ks.append(_cons(cks, mesh, None, None, "model"))
-            new_vs.append(_cons(cvs, mesh, None, None, "model"))
-        else:
-            ck, cv = _write_kv(cache.k[l], cache.v[l],
-                               k.reshape(B * Tp, KVh, Dh),
-                               v.reshape(B * Tp, KVh, Dh), flat_idx, mesh,
-                               use_kernel)
-        ck = _cons(ck, mesh, None, None, "model", None)
-        cv = _cons(cv, mesh, None, None, "model", None)
-        new_k.append(ck)
-        new_v.append(cv)
-
-        if scfg is not None and Tp % scfg.block == 0:
-            # block-gather path: FLOPs/memory scale with layout density,
-            # not Tp^2 (same computation the training forward runs)
-            from ..ops.attention import _repeat_kv
-            from ..ops.sparse_attention import sparse_causal_attention
-
-            rep = q.shape[2] // k.shape[2]  # GQA repeat, as in training
-            att = sparse_causal_attention(
-                q, _repeat_kv(k, rep), _repeat_kv(v, rep), scfg
-            )
-        elif sparse_mask is not None:
-            # bucket shorter than a layout block: dense-with-mask fallback
-            att = _masked_causal_attention(q, k, v, sparse_mask)
-        elif _heads_shardable(mesh, cfg):
-            # flash kernel per head-shard; GQA grouping stays device-local
-            hs = P(None, None, "model", None)
-            if alibi is not None:
-                att = _shard_map_kernel(
-                    lambda q_, k_, v_, ab_: causal_attention(
-                        q_, k_, v_, use_flash=use_kernel and cfg.use_flash,
-                        window=cfg.window_for_layer(li), alibi=ab_),
-                    mesh, in_specs=(hs, hs, hs, P("model")), out_specs=hs,
-                )(q, k, v, alibi)
+        with jax.named_scope("norm1"):
+            h1 = T._act_quant(
+                T._norm(x, lp["ln1_scale"], lp.get("ln1_bias"), cfg), cfg)
+        with jax.named_scope("attention"):
+            if "w_qkv" in lp:
+                qkv = _wmm("bse,ehd->bshd", h1, lp["w_qkv"])
+                if "b_qkv" in lp:
+                    qkv = qkv + lp["b_qkv"].astype(x.dtype)
+                q, k, v = jnp.split(qkv, [H, H + KV], axis=2)
             else:
-                att = _shard_map_kernel(
-                    partial(causal_attention,
-                            use_flash=use_kernel and cfg.use_flash,
-                            window=cfg.window_for_layer(li)),
-                    mesh, in_specs=(hs, hs, hs), out_specs=hs,
-                )(q, k, v)
-        else:
-            att = causal_attention(
-                q, k, v,
-                # a raw pallas_call cannot consume TP-sharded operands
-                use_flash=use_kernel and cfg.use_flash and _tp_size(mesh) <= 1,
-                window=cfg.window_for_layer(li), alibi=alibi)
-        out = _wmm("bshd,hde->bse", att, lp["wo"])
-        if "bo" in lp:
-            out = out + lp["bo"].astype(x.dtype)
+                q = _wmm("bse,ehd->bshd", h1, lp["wq"])
+                k = _wmm("bse,ehd->bshd", h1, lp["wk"])
+                v = _wmm("bse,ehd->bshd", h1, lp["wv"])
+                if "bq" in lp:
+                    q = q + lp["bq"].astype(x.dtype)
+                    k = k + lp["bk"].astype(x.dtype)
+                    v = v + lp["bv"].astype(x.dtype)
+            q, k = T.qk_norm(q, k, lp, cfg)
+            if cfg.use_rope:
+                rot = jax.vmap(_rope_at, in_axes=(0, None, None))
+                q = rot(q, positions, cfg)
+                k = rot(k, positions, cfg)
+            q = _cons(q, mesh, None, None, "model", None)
+            k = _cons(k, mesh, None, None, "model", None)
+            v = _cons(v, mesh, None, None, "model", None)
 
-        E = x.shape[-1]
-        if cfg.parallel_residual:
-            h2 = h1 if cfg.shared_ln else T._act_quant(
-                T._norm(x, lp["ln2_scale"], lp.get("ln2_bias"), cfg), cfg)
-            x = x + out + _mlp(h2.reshape(B * Tp, E), lp, cfg,
-                               census_cb=census_cb).reshape(B, Tp, E)
-        else:
-            x = x + out
-            h2 = T._act_quant(
-                T._norm(x, lp["ln2_scale"], lp.get("ln2_bias"), cfg), cfg)
-            x = x + _mlp(h2.reshape(B * Tp, E), lp, cfg,
-                         census_cb=census_cb).reshape(B, Tp, E)
+            KVh, Dh = k.shape[2], k.shape[3]
+            l = len(new_k)
+            if quant:
+                # the prompt's in-flight attention below stays full
+                # precision (it never reads the cache); only the RESIDENT
+                # copy quantizes — later decode steps read these codes
+                ck, cv, cks, cvs = _write_kv_quant(
+                    cache.k[l], cache.v[l], cache.k_scale[l], cache.v_scale[l],
+                    k.reshape(B * Tp, KVh, Dh),
+                    v.reshape(B * Tp, KVh, Dh), flat_idx, mesh, use_kernel)
+                new_ks.append(_cons(cks, mesh, None, None, "model"))
+                new_vs.append(_cons(cvs, mesh, None, None, "model"))
+            else:
+                ck, cv = _write_kv(cache.k[l], cache.v[l],
+                                   k.reshape(B * Tp, KVh, Dh),
+                                   v.reshape(B * Tp, KVh, Dh), flat_idx, mesh,
+                                   use_kernel)
+            ck = _cons(ck, mesh, None, None, "model", None)
+            cv = _cons(cv, mesh, None, None, "model", None)
+            new_k.append(ck)
+            new_v.append(cv)
+
+            if scfg is not None and Tp % scfg.block == 0:
+                # block-gather path: FLOPs/memory scale with layout density,
+                # not Tp^2 (same computation the training forward runs)
+                from ..ops.attention import _repeat_kv
+                from ..ops.sparse_attention import sparse_causal_attention
+
+                rep = q.shape[2] // k.shape[2]  # GQA repeat, as in training
+                att = sparse_causal_attention(
+                    q, _repeat_kv(k, rep), _repeat_kv(v, rep), scfg
+                )
+            elif sparse_mask is not None:
+                # bucket shorter than a layout block: dense-with-mask fallback
+                att = _masked_causal_attention(q, k, v, sparse_mask)
+            elif _heads_shardable(mesh, cfg):
+                # flash kernel per head-shard; GQA grouping stays device-local
+                hs = P(None, None, "model", None)
+                if alibi is not None:
+                    att = _shard_map_kernel(
+                        lambda q_, k_, v_, ab_: causal_attention(
+                            q_, k_, v_, use_flash=use_kernel and cfg.use_flash,
+                            window=cfg.window_for_layer(li), alibi=ab_),
+                        mesh, in_specs=(hs, hs, hs, P("model")), out_specs=hs,
+                    )(q, k, v, alibi)
+                else:
+                    att = _shard_map_kernel(
+                        partial(causal_attention,
+                                use_flash=use_kernel and cfg.use_flash,
+                                window=cfg.window_for_layer(li)),
+                        mesh, in_specs=(hs, hs, hs), out_specs=hs,
+                    )(q, k, v)
+            else:
+                att = causal_attention(
+                    q, k, v,
+                    # a raw pallas_call cannot consume TP-sharded operands
+                    use_flash=(use_kernel and cfg.use_flash
+                               and _tp_size(mesh) <= 1),
+                    window=cfg.window_for_layer(li), alibi=alibi)
+            out = _wmm("bshd,hde->bse", att, lp["wo"])
+            if "bo" in lp:
+                out = out + lp["bo"].astype(x.dtype)
+
+        x = _ffn_residual(x, out, h1, lp, cfg, census_cb)
         x_hist.append(x)
 
     # logits for each prompt's last REAL token only (logits_gather):
     # gather before the vocab matmul so the head runs on B tokens, not B*Tp
-    last = jnp.maximum(n_real - 1, 0)  # [B]; padding rows read pos 0
-    x_last = jnp.take_along_axis(x, last[:, None, None].astype(jnp.int32)
-                                 .repeat(x.shape[-1], axis=2), axis=1)[:, 0]
-    x_last = T._norm(x_last, params["ln_f_scale"], params.get("ln_f_bias"), cfg)
-    logits = _lm_logits(x_last, params, cfg)
-    logits = _cons(logits, mesh, None, None)
+    with jax.named_scope("lm_head"):
+        last = jnp.maximum(n_real - 1, 0)  # [B]; padding rows read pos 0
+        x_last = jnp.take_along_axis(
+            x, last[:, None, None].astype(jnp.int32).repeat(x.shape[-1], axis=2),
+            axis=1)[:, 0]
+        x_last = T._norm(x_last, params["ln_f_scale"],
+                         params.get("ln_f_bias"), cfg)
+        logits = _lm_logits(x_last, params, cfg)
+        logits = _cons(logits, mesh, None, None)
     if quant:
         return logits, PagedCache(k=new_k, v=new_v,
                                   k_scale=new_ks, v_scale=new_vs)

@@ -215,6 +215,10 @@ class ServingScheduler:
             # sum over first admissions of admit_t - arrival
             "queue_wait_s": 0.0,
             "slow_iterations": 0,
+            # (token, expert) assignments the batched tokens made in ONE
+            # routed layer: batched tokens x top-k (0 for a dense model);
+            # over steps and experts, the rows an expert sees a step
+            "moe_token_expert_pairs": 0,
         }
         self._phases = profiler.Phases("sched", "iteration", PHASES,
                                        sums=self.counters)
@@ -886,9 +890,17 @@ class ServingScheduler:
             ph.mark("commit")
             parts.append(_Part("wave", sample_rows, tok_dev))
             self.counters["wave_prefills"] += len(wave)
-            self.counters["batched_tokens"] += int(n_real.sum())
+            self._count_tokens(int(n_real.sum()))
             self._it_rows += int(n_real.sum())
         return parts
+
+    def _count_tokens(self, n: int) -> None:
+        """Tokens a dispatched program batched, and the token-expert
+        pairs they make in a routed layer."""
+        self.counters["batched_tokens"] += n
+        cfg = self.engine.cfg
+        if cfg.n_experts > 0:
+            self.counters["moe_token_expert_pairs"] += n * cfg.moe_top_k
 
     def _dispatch_mixed(self, rows) -> Optional[_Part]:
         """One compiled decode program over the iteration's ragged rows:
@@ -942,7 +954,7 @@ class ServingScheduler:
         tok_dev = (self._sample_part(logits, sample_rows, sp)
                    if sample_rows else None)
         ph.mark("commit")
-        self.counters["batched_tokens"] += n_rows
+        self._count_tokens(n_rows)
         return _Part("mixed", sample_rows, tok_dev)
 
     def _dispatch_fused(self, running: List[Request], C: int) -> _Part:
@@ -997,7 +1009,7 @@ class ServingScheduler:
         ph.mark("commit")
         for req in running:
             eng.state.commit(req.uid, C)
-        self.counters["batched_tokens"] += len(running) * C
+        self._count_tokens(len(running) * C)
         self.counters["fused_steps"] += 1
         return _Part("fused", sample_rows, gen, n_steps=C)
 
@@ -1264,7 +1276,7 @@ class ServingScheduler:
                 self._accept(req, t, now)
                 if req.done:
                     break
-        self.counters["batched_tokens"] += sum(len(c) for _, c in chunks)
+        self._count_tokens(sum(len(c) for _, c in chunks))
         return _Step([], 0)  # already finalized (host verification)
 
     # -- public driving --------------------------------------------------
@@ -1402,7 +1414,7 @@ class ServingScheduler:
         tok_dev = self._sample_part(logits, sample_rows, sp)
         ph.mark("commit")
         self.counters["steps"] += 1
-        self.counters["batched_tokens"] += len(rows)
+        self._count_tokens(len(rows))
         self.counters["chained_steps"] += 1
         return _Step([_Part("mixed", sample_rows, tok_dev)], len(rows))
 

@@ -102,9 +102,16 @@ class TransformerConfig:
     # MegaBlocks-style): sort-by-expert grouped batching at EP=1, the
     # explicit dispatch/combine all-to-all frame under an 'expert' mesh
     # axis. No token is ever dropped; moe_capacity_factor/min_capacity
-    # are ignored. Serving follows the same flag (per-expert token
-    # batching across the ragged batch instead of the X-pass scan).
+    # are ignored. A TRAINING flag: serving is capacity-free always and
+    # picks its expert path from the call's shape
+    # (inference/model.py expert_path).
     moe_dropless: bool = False
+    # Whether the top-k combine weights are renormalised to sum to 1 (HF
+    # norm_topk_prob). None = the rule every path had: k > 1
+    # renormalises (GShard / Mixtral), k = 1 keeps the raw softmax mass
+    # (Switch). False = the raw softmax mass of the chosen experts
+    # whatever k (OLMoE: top-8 of 64 sums to well under 1).
+    moe_norm_topk_prob: Optional[bool] = None
     # Router z-loss coefficient (ST-MoE): penalizes large router logits
     # so the fp32 gate softmax stays numerically sharp. 0 disables.
     moe_z_loss_coef: float = 0.0
@@ -170,6 +177,10 @@ class TransformerConfig:
     rope_interleaved: bool = False
     # Bloom: LayerNorm over the embedding output before the first block
     embedding_layernorm: bool = False
+    # QK-norm (OLMoE / OLMo-2): an RMSNorm over the WHOLE projected q
+    # and k vectors (all heads together, n_heads * head_dim values) with
+    # a learned scale of that length, before the head split's rope.
+    qk_norm: bool = False
 
     def __post_init__(self):
         if self.rope_scaling_type not in ("none", "linear", "llama3"):
@@ -378,6 +389,11 @@ def _layer_shapes(cfg: TransformerConfig) -> Dict[str, Tuple[Tuple[int, ...], Tu
     }
     if not cfg.shared_ln:
         shapes["ln2_scale"] = ((E,), ("embed",))
+    if cfg.qk_norm:
+        # one scale per projected value, kept [heads, head_dim] so the
+        # head sharding of wq / wk applies to it as it stands
+        shapes["q_norm_scale"] = ((H, D), ("heads", "head_dim"))
+        shapes["k_norm_scale"] = ((KV, D), ("heads", "head_dim"))
     X = cfg.n_experts
     if X > 0:
         # Expert-stacked FFN weights: leading experts dim shards over the
@@ -458,7 +474,7 @@ def init(cfg: TransformerConfig, rng) -> Dict[str, Any]:
     lkeys = jax.random.split(keys[3], len(_layer_shapes(cfg)))
     for i, (name, (shape, _)) in enumerate(sorted(_layer_shapes(cfg).items())):
         full = (L,) + shape
-        if "ln" in name:
+        if "ln" in name or name.endswith("_norm_scale"):
             layers[name] = jnp.broadcast_to(norm_init(shape, name), full).copy()
         elif name.startswith("b"):
             layers[name] = jnp.zeros(full, jnp.float32)
@@ -520,6 +536,35 @@ def _norm(x, scale, bias, cfg: TransformerConfig):
         var = jnp.var(x32, axis=-1, keepdims=True)
         out = (x32 - mean) * jax.lax.rsqrt(var + cfg.norm_eps) * scale + bias
     return out.astype(x.dtype)
+
+
+def qk_norm(q, k, lp, cfg: TransformerConfig):
+    """QK-norm over projected q [..., H, D] and k [..., KV, D]: RMSNorm
+    whose statistic runs over ALL heads of a token (the whole projected
+    vector, as HF's OlmoeRMSNorm(num_heads * head_dim) sees it), in
+    float32, times the learned [heads, head_dim] scale. The one place
+    it is written: training's attention and both serving sites call it,
+    between the projection and rope. No-op unless cfg.qk_norm.
+
+    Under jit with sharding constraints the mean is over the global
+    array, whatever the head sharding. Inside a shard_map region that
+    is manual over 'model' (the axis heads shard on) a shard would see
+    its own heads only: refused."""
+    if not cfg.qk_norm:
+        return q, k
+    if "model" in jax.sharding.get_abstract_mesh().manual_axes:
+        raise NotImplementedError(
+            "qk_norm inside a shard_map region over 'model': the RMS "
+            "statistic spans all heads of a token, a shard holds only "
+            "its own; apply it before entering the manual region")
+
+    def norm(x, scale):
+        x32 = x.astype(jnp.float32)
+        ms = jnp.mean(jnp.square(x32), axis=(-2, -1), keepdims=True)
+        return (x32 * jax.lax.rsqrt(ms + cfg.norm_eps)
+                * scale.astype(jnp.float32)).astype(x.dtype)
+
+    return norm(q, lp["q_norm_scale"]), norm(k, lp["k_norm_scale"])
 
 
 def model_alibi_slopes(cfg: TransformerConfig):
@@ -734,6 +779,7 @@ def _attention_delta(h, lp, cfg: TransformerConfig, rng=None, positions=None,
         q = q + lp["bq"].astype(x.dtype)
         k = k + lp["bk"].astype(x.dtype)
         v = v + lp["bv"].astype(x.dtype)
+    q, k = qk_norm(q, k, lp, cfg)
     if cfg.use_rope:
         q, k = _rope(q, k, cfg, positions=positions)
     from jax.ad_checkpoint import checkpoint_name
@@ -869,6 +915,7 @@ def _moe_mlp_delta(h, lp, cfg: TransformerConfig, rng=None):
             b_out=lp.get("b_out"),
             act=act,
             top_k=cfg.moe_top_k,
+            renormalize=cfg.moe_norm_topk_prob,
             rng=gate_rng,
             noisy_gate_policy=cfg.moe_noisy_gate_policy,
             shard=shard,
@@ -883,6 +930,7 @@ def _moe_mlp_delta(h, lp, cfg: TransformerConfig, rng=None):
             top_k=cfg.moe_top_k,
             capacity_factor=cfg.moe_capacity_factor,
             min_capacity=cfg.moe_min_capacity,
+            renormalize=cfg.moe_norm_topk_prob,
             rng=gate_rng,
             noisy_gate_policy=cfg.moe_noisy_gate_policy,
             shard=shard,

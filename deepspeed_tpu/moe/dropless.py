@@ -31,7 +31,9 @@ casts at TopKGate.forward) and generalizes to any top_k <= n_experts:
 selection by `lax.top_k` over the (optionally noised) logits, combine
 weights renormalized for k > 1 (the GShard top-2 convention) and raw
 softmax mass for k = 1 (the Switch convention) — bit-matching the
-capacity-factor paths wherever those would not drop. The router
+capacity-factor paths wherever those would not drop; `renormalize`
+(TransformerConfig.moe_norm_topk_prob, HF norm_topk_prob) overrides
+the rule, False keeping the raw mass at any k (OLMoE). The router
 z-loss (ST-MoE, arXiv 2202.08906) and the load-balance aux loss ride
 the return value so the training loss can thread both.
 """
@@ -176,12 +178,17 @@ def _ragged_wire(tokens, idx, weights, counts, w_in, w_out, w_gate,
                  b_in, b_out, act, impl):
     """EP=1 / serving wire: sort -> grouped GEMM -> segment-sum."""
     T = tokens.shape[0]
-    order, src, sorted_experts = sort_by_expert(idx)
-    xs = tokens[src]  # [A, E] expert-contiguous
-    ys = _expert_mlp_sorted(xs, sorted_experts, counts, w_in, w_out,
-                            w_gate, b_in, b_out, act, impl)
-    wf = weights.reshape(-1)[order].astype(tokens.dtype)
-    return jax.ops.segment_sum(ys * wf[:, None], src, num_segments=T)
+    # the scopes a trace tells the wire's three parts by (serving reads
+    # them; under training they nest inside `mlp`)
+    with jax.named_scope("moe_route"):
+        order, src, sorted_experts = sort_by_expert(idx)
+        xs = tokens[src]  # [A, E] expert-contiguous
+    with jax.named_scope("moe_experts"):
+        ys = _expert_mlp_sorted(xs, sorted_experts, counts, w_in, w_out,
+                                w_gate, b_in, b_out, act, impl)
+    with jax.named_scope("moe_combine"):
+        wf = weights.reshape(-1)[order].astype(tokens.dtype)
+        return jax.ops.segment_sum(ys * wf[:, None], src, num_segments=T)
 
 
 def _a2a_wire(tokens, idx, weights, ep_size, w_in, w_out, w_gate,
@@ -258,6 +265,7 @@ def dropless_moe_ffn(
     *,
     act,
     top_k: int = 1,
+    renormalize: Optional[bool] = None,  # None = (top_k > 1)
     rng=None,
     noisy_gate_policy: Optional[str] = None,
     shard=None,      # fn(x, *mesh axis names) sharding constraint
@@ -275,7 +283,8 @@ def dropless_moe_ffn(
     """
     logits = tokens.astype(jnp.float32) @ router_w.astype(jnp.float32)
     idx, weights, l_aux, z_loss = dropless_topk_gating(
-        logits, top_k, rng=rng, noisy_gate_policy=noisy_gate_policy)
+        logits, top_k, rng=rng, noisy_gate_policy=noisy_gate_policy,
+        renormalize=renormalize)
     counts = expert_counts(idx, w_in.shape[0])
     if ep_size > 1 and tokens.shape[0] % ep_size == 0:
         out = _a2a_wire(tokens, idx, weights, ep_size, w_in, w_out,

@@ -92,6 +92,7 @@ def topk_gating(
     min_capacity: int = 4,
     rng=None,
     noisy_gate_policy: Optional[str] = None,
+    renormalize: Optional[bool] = None,
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Generic capacity-factor top-k gating (Switch at k=1, GShard at
     k=2 — ref: sharded_moe.py top1gating:180 / top2gating:278 — and the
@@ -106,7 +107,9 @@ def topk_gating(
 
     Returns (combine [T,X,C] fp32, dispatch [T,X,C] bool, l_aux). k=1
     combines with the raw softmax mass (Switch); k>=2 renormalizes the
-    kept choices to sum to 1 (GShard).
+    kept choices to sum to 1 (GShard). `renormalize` overrides that rule
+    (None keeps it; False = raw softmax mass at any k, HF
+    norm_topk_prob=false).
     """
     T, X = logits.shape
     if not 1 <= top_k <= X:
@@ -135,7 +138,7 @@ def topk_gating(
         ds.append(
             (mask_j[:, :, None] * _one_hot(pos_j, C)[:, None, :])
             * keep_j[:, None, None])
-    if top_k > 1:
+    if top_k > 1 if renormalize is None else renormalize:
         denom = jnp.maximum(sum(gs), jnp.finfo(jnp.float32).eps)
         gs = [g / denom for g in gs]
     combine = sum(d * g[:, None, None] for d, g in zip(ds, gs))
@@ -162,6 +165,7 @@ def moe_ffn(
     top_k: int = 1,
     capacity_factor: float = 1.0,
     min_capacity: int = 4,
+    renormalize: Optional[bool] = None,
     rng=None,
     noisy_gate_policy: Optional[str] = None,
     shard=None,  # fn(x, *logical_spec) applying a sharding constraint
@@ -183,6 +187,7 @@ def moe_ffn(
         min_capacity=min_capacity,
         rng=rng,
         noisy_gate_policy=noisy_gate_policy,
+        renormalize=renormalize,
     )
     x = jnp.einsum("txc,te->xce", dispatch.astype(dtype), tokens)
     if shard is not None:

@@ -19,7 +19,9 @@ inference/engine.py:331-499). Differences driven by the TPU design:
   param_init_fn path shards by ZeRO/TP specs at jit boundaries.
 
 Supported architectures: LlamaForCausalLM, MistralForCausalLM,
-MixtralForCausalLM, GPT2LMHeadModel, OPTForCausalLM,
+MixtralForCausalLM, OlmoeForCausalLM (also by `model_type: olmoe`
+alone: QK-norm over the whole projected q / k, 64 routed experts with
+the raw top-k softmax mass as weights), GPT2LMHeadModel, OPTForCausalLM,
 FalconForCausalLM (7B multi-query, 40B new-decoder, and alibi rw
 forms), PhiForCausalLM, QWenLMHeadModel, Qwen2ForCausalLM — the
 reference's v2 serving families (blogs/deepspeed-fastgen/README.md
@@ -138,7 +140,10 @@ class _CheckpointReader:
 # ---------------------------------------------------------------------------
 
 _LLAMA_FAMILY = {"LlamaForCausalLM", "MistralForCausalLM",
-                 "MixtralForCausalLM", "Qwen2ForCausalLM"}
+                 "MixtralForCausalLM", "Qwen2ForCausalLM",
+                 "OlmoeForCausalLM"}
+# a config.json without `architectures` is told by its model_type
+_ARCH_OF_MODEL_TYPE = {"olmoe": "OlmoeForCausalLM"}
 SUPPORTED_ARCHITECTURES = sorted(_LLAMA_FAMILY | {
     "GPT2LMHeadModel", "OPTForCausalLM", "FalconForCausalLM",
     "RWForCausalLM",  # falcon's pre-rename arch string
@@ -148,11 +153,16 @@ SUPPORTED_ARCHITECTURES = sorted(_LLAMA_FAMILY | {
 })
 
 
+def _arch_of(hf: Dict[str, Any]) -> str:
+    archs = hf.get("architectures") or []
+    arch = archs[0] if archs else hf.get("model_type", "?")
+    return _ARCH_OF_MODEL_TYPE.get(arch, arch)
+
+
 def config_from_hf(hf: Dict[str, Any], **overrides) -> TransformerConfig:
     """HF config.json dict → TransformerConfig. overrides win (e.g.
     use_flash=False for CPU tests, attention_impl for long-context)."""
-    archs = hf.get("architectures") or []
-    arch = archs[0] if archs else hf.get("model_type", "?")
+    arch = _arch_of(hf)
     if arch in _LLAMA_FAMILY:
         kw = dict(
             vocab_size=hf["vocab_size"],
@@ -194,6 +204,21 @@ def config_from_hf(hf: Dict[str, Any], **overrides) -> TransformerConfig:
         if arch == "MixtralForCausalLM":
             kw.update(n_experts=hf["num_local_experts"],
                       moe_top_k=hf["num_experts_per_tok"])
+        if arch == "OlmoeForCausalLM":
+            # llama geometry + QK-norm over the whole projected q / k,
+            # every MLP routed (no shared expert, no capacity: dropless),
+            # top-k weights the raw softmax mass unless norm_topk_prob
+            if hf.get("clip_qkv") is not None or hf.get("attention_bias"):
+                raise ValueError(
+                    "OLMoE with clip_qkv or attention_bias is unsupported "
+                    f"(clip_qkv={hf.get('clip_qkv')!r}, attention_bias="
+                    f"{hf.get('attention_bias')!r}); refusing a "
+                    "silently-wrong import")
+            kw.update(n_experts=hf["num_experts"],
+                      moe_top_k=hf["num_experts_per_tok"],
+                      moe_norm_topk_prob=bool(hf.get("norm_topk_prob",
+                                                     False)),
+                      moe_dropless=True, qk_norm=True)
         if arch == "Qwen2ForCausalLM":
             # ref: inference/v2/model_implementations/qwen_v2/model.py —
             # llama geometry + biases on q/k/v only
@@ -448,17 +473,28 @@ def _map_llama_layer(r: _CheckpointReader, i: int,
         out["bq"] = r.get(p + "self_attn.q_proj.bias").reshape(H, D)
         out["bk"] = r.get(p + "self_attn.k_proj.bias").reshape(KV, D)
         out["bv"] = r.get(p + "self_attn.v_proj.bias").reshape(KV, D)
+    if cfg.qk_norm:  # OLMoE: one scale per projected value
+        out["q_norm_scale"] = r.get(
+            p + "self_attn.q_norm.weight").reshape(H, D)
+        out["k_norm_scale"] = r.get(
+            p + "self_attn.k_norm.weight").reshape(KV, D)
     if cfg.n_experts > 0:
-        X, F = cfg.n_experts, cfg.ff_dim
+        X = cfg.n_experts
+        # expert MLP down(silu(gate x) * up x). Mixtral names it
+        # block_sparse_moe.experts.N.w1 / w3 / w2, OLMoE
+        # mlp.experts.N.gate_proj / up_proj / down_proj
         m = p + "block_sparse_moe."
+        gate, up, down = "w1", "w3", "w2"
+        if m + "gate.weight" not in r:
+            m = p + "mlp."
+            gate, up, down = "gate_proj", "up_proj", "down_proj"
         out["w_router"] = r.get(m + "gate.weight").T  # [E, X]
-        # Mixtral expert MLP: w2(silu(w1 x) * w3 x) — w1=gate, w3=up, w2=down
         out["w_gate"] = np.stack(
-            [r.get(m + f"experts.{x}.w1.weight").T for x in range(X)])
+            [r.get(m + f"experts.{x}.{gate}.weight").T for x in range(X)])
         out["w_in"] = np.stack(
-            [r.get(m + f"experts.{x}.w3.weight").T for x in range(X)])
+            [r.get(m + f"experts.{x}.{up}.weight").T for x in range(X)])
         out["w_out"] = np.stack(
-            [r.get(m + f"experts.{x}.w2.weight").T for x in range(X)])
+            [r.get(m + f"experts.{x}.{down}.weight").T for x in range(X)])
     else:
         out["w_gate"] = r.get(p + "mlp.gate_proj.weight").T  # [E, F]
         out["w_in"] = r.get(p + "mlp.up_proj.weight").T      # [E, F]
@@ -762,8 +798,7 @@ def import_external(
     else:
         cast = lambda a: a
 
-    archs = hf.get("architectures") or []
-    arch = archs[0] if archs else hf.get("model_type", "?")
+    arch = _arch_of(hf)
     params: Dict[str, Any]
     if arch == "GPT2LMHeadModel":
         top = _gpt2_top(r)

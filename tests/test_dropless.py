@@ -314,39 +314,51 @@ class TestServingUnits:
         base.update(kw)
         return T.TransformerConfig(**base)
 
-    def test_dropless_mlp_equals_scan_mlp(self):
-        from deepspeed_tpu.inference.model import _mlp
+    # serving picks its expert path from the call's shape
+    # (inference/model.py expert_path); these force one or the other
+    SCAN, RAGGED = (0.0, float("inf")), (float("inf"),) * 2
 
-        cfg_d = self._cfg(moe_dropless=True)
-        cfg_s = self._cfg(moe_dropless=False)
-        lp = self._layer(cfg_d)
+    def test_dropless_mlp_equals_scan_mlp(self, monkeypatch):
+        from deepspeed_tpu.inference import model as M
+
+        cfg = self._cfg()
+        lp = self._layer(cfg)
         h = jnp.asarray(np.random.default_rng(2).normal(size=(16, 32)),
                         jnp.float32)
-        np.testing.assert_allclose(
-            np.asarray(_mlp(h, lp, cfg_d)), np.asarray(_mlp(h, lp, cfg_s)),
-            atol=1e-5)
+        monkeypatch.setattr(M, "_SCAN_ROWS_PER_EXPERT", self.RAGGED)
+        ragged = np.asarray(M._mlp(h, lp, cfg))
+        monkeypatch.setattr(M, "_SCAN_ROWS_PER_EXPERT", self.SCAN)
+        np.testing.assert_allclose(ragged, np.asarray(M._mlp(h, lp, cfg)),
+                                   atol=1e-5)
+        # the training flag does not reach serving
+        np.testing.assert_array_equal(
+            np.asarray(M._mlp(h, lp, self._cfg(moe_dropless=True))),
+            np.asarray(M._mlp(h, lp, cfg)))
 
-    def test_census_counts_assignments(self):
-        from deepspeed_tpu.inference.model import _mlp
+    @pytest.mark.parametrize("rows", [SCAN, RAGGED], ids=["scan", "ragged"])
+    def test_census_counts_assignments(self, monkeypatch, rows):
+        from deepspeed_tpu.inference import model as M
 
-        cfg = self._cfg(moe_dropless=True)
+        monkeypatch.setattr(M, "_SCAN_ROWS_PER_EXPERT", rows)
+        cfg = self._cfg()
         lp = self._layer(cfg)
         h = jnp.asarray(np.random.default_rng(2).normal(size=(16, 32)),
                         jnp.float32)
         seen = []
-        jax.block_until_ready(_mlp(h, lp, cfg, census_cb=seen.append))  # ds-lint: ok R002 test asserts the callback landed
+        jax.block_until_ready(M._mlp(h, lp, cfg, census_cb=seen.append))  # ds-lint: ok R002 test asserts the callback landed
         assert len(seen) == 1
         counts = np.asarray(seen[0])
         assert counts.shape == (4,)
         assert int(counts.sum()) == 16 * 2  # every assignment counted
 
-    def test_expert_stacks_quantize_groupwise(self):
-        from deepspeed_tpu.inference.model import _mlp, quantize_layer
+    def test_expert_stacks_quantize_groupwise(self, monkeypatch):
+        from deepspeed_tpu.inference import model as M
         from deepspeed_tpu.inference.quantization import QuantizedWeight
 
-        cfg = self._cfg(moe_dropless=True)
+        monkeypatch.setattr(M, "_SCAN_ROWS_PER_EXPERT", self.RAGGED)
+        cfg = self._cfg()
         lp = self._layer(cfg)
-        qlp = quantize_layer(dict(lp), cfg)
+        qlp = M.quantize_layer(dict(lp), cfg)
         for name in ("w_in", "w_gate", "w_out"):
             assert isinstance(qlp[name], QuantizedWeight), name
             assert qlp[name].q.dtype == jnp.int8
@@ -354,14 +366,13 @@ class TestServingUnits:
         h = jnp.asarray(np.random.default_rng(2).normal(size=(16, 32)),
                         jnp.float32)
         # int8 grouped codes reproduce the fp experts within PTQ error
-        np.testing.assert_allclose(
-            np.asarray(_mlp(h, qlp, cfg)), np.asarray(_mlp(h, lp, cfg)),
-            atol=0.05)
+        ragged = np.asarray(M._mlp(h, qlp, cfg))
+        np.testing.assert_allclose(ragged, np.asarray(M._mlp(h, lp, cfg)),
+                                   atol=0.05)
         # the scan path consumes the same quantized stacks
-        cfg_s = self._cfg(moe_dropless=False)
-        np.testing.assert_allclose(
-            np.asarray(_mlp(h, qlp, cfg_s)), np.asarray(_mlp(h, qlp, cfg)),
-            atol=1e-5)
+        monkeypatch.setattr(M, "_SCAN_ROWS_PER_EXPERT", self.SCAN)
+        np.testing.assert_allclose(np.asarray(M._mlp(h, qlp, cfg)), ragged,
+                                   atol=1e-5)
 
 
 @pytest.mark.slow

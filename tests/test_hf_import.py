@@ -153,6 +153,37 @@ class TestMistralMixtralImport:
         ref = _torch_logits(m, toks)[-1]
         np.testing.assert_allclose(out[0], ref, rtol=2e-3, atol=2e-3)
 
+    def test_olmoe_matches_hf_in_training_and_serving(self, rng, tmp_path):
+        """OLMoE import (QK-norm over the whole projected q / k, top-3
+        of 8 experts with the raw softmax mass as weights): the training
+        forward and the serving engine == HF torch logits."""
+        torch.manual_seed(7)
+        hf_cfg = transformers.OlmoeConfig(
+            vocab_size=128, hidden_size=64, intermediate_size=32,
+            num_hidden_layers=2, num_attention_heads=4, num_key_value_heads=4,
+            num_experts=8, num_experts_per_tok=3, norm_topk_prob=False,
+            max_position_embeddings=64, tie_word_embeddings=False)
+        m = transformers.OlmoeForCausalLM(hf_cfg).eval()
+        with torch.no_grad():  # HF inits the QK-norm scales to ones
+            for name, p in m.named_parameters():
+                if "q_norm" in name or "k_norm" in name:
+                    p.add_(0.3 * torch.randn_like(p))
+        path = _save(m, tmp_path)
+        cfg, params = import_external(path, use_flash=False)
+        assert cfg.qk_norm and cfg.moe_norm_topk_prob is False
+        assert (cfg.n_experts, cfg.moe_top_k) == (8, 3)
+        toks = list(rng.integers(0, 128, 12))
+        ref = _torch_logits(m, toks)
+        with jax.default_matmul_precision("highest"):
+            got = np.asarray(T.forward(params, jnp.asarray([toks]), cfg)[0])
+        np.testing.assert_allclose(got, ref, rtol=2e-4, atol=2e-5)
+        eng = init_inference_from_hf(
+            path, dict(max_seq_len=32, kv_block_size=8, num_kv_blocks=16,
+                       min_prefill_bucket=8, max_batch_size=4),
+            dtype=jnp.float32, use_flash=False)
+        out = eng.put([0], [np.asarray(toks, np.int32)])
+        np.testing.assert_allclose(out[0], ref[-1], rtol=2e-4, atol=2e-5)
+
     def test_sharded_checkpoint(self, rng, tmp_path):
         """index.json + multiple safetensors shards load identically."""
         torch.manual_seed(6)

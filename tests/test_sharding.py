@@ -174,7 +174,15 @@ def _bench_model(path):
 
 @pytest.mark.parametrize("path", BENCH_CONFIGS, ids=lambda p: p.stem)
 class TestBenchmarkLayouts:
-    """The rule at the widths the benchmark runs (Mistral-7B)."""
+    """The rule at the widths the benchmark runs (Mistral-7B; OLMoE at
+    8 layers)."""
+
+    # leaves the tile rule moves off their last dim, and their bf16
+    # bytes: the head alone (32000 / 8 and 50304 / 8 lanes break the
+    # tile), and for OLMoE the two [8, 16, 128] QK-norm scales besides
+    # (128 lanes cannot be split: they shard on the layer dim)
+    MOVED = {"mistral": (1, 4096 * 32000 * 2),
+             "olmoe": (3, 2048 * 50304 * 2 + 2 * 8 * 16 * 128 * 2)}
 
     def test_storage_dim_is_optimizer_dim(self, path):
         from deepspeed_tpu.parallel.sharding import pipe3d_specs
@@ -192,8 +200,9 @@ class TestBenchmarkLayouts:
             assert s["storage"]["lm_head"] == s["opt"]["lm_head"] == (
                 P("data", "model") if "model" in axes else P("data"))
             rep = zero_layout_report(s["tp"], s["opt"], shapes, mesh, 2)
-            assert rep["zero_leaves_moved"] == 1
-            assert rep["zero_bytes_moved"] == 4096 * 32000 * 2
+            moved, nbytes = self.MOVED[json.loads(path.read_text())["model_type"]]
+            assert rep["zero_leaves_moved"] == moved
+            assert rep["zero_bytes_moved"] == nbytes
             assert rep["zero_leaves_off_tile"] == 0
 
     def test_data1_leaves_every_spec_alone(self, path):

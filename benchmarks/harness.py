@@ -18,6 +18,7 @@ import dataclasses
 import importlib.util
 import json
 import pathlib
+import sys
 import time
 from typing import Any, Callable, Dict, List, Optional
 
@@ -287,6 +288,11 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     with open(out_dir / f"seed{seed}_trace{int(trace)}.json", "w") as f:
         json.dump({"line": json.loads(line), "notes": outcome.notes,
                    "end_to_end_all": outcome.end_to_end}, f, default=str)
+    # each number compared beside its limit, as the last lines of the
+    # standard error too: of a run that is not correct the driver's
+    # record keeps the end of that
+    for msg in outcome.notes.get("compared", ()):
+        print(msg, file=sys.stderr, flush=True)
     return line
 
 

@@ -89,8 +89,22 @@ def routed_mlp(n, lw, hf, mutate: Optional[str] = None):
     return jnp.einsum("...x,...xe->...e", weights, each)
 
 
+def router_margin(n, lw, hf):
+    """How far the router is from choosing another set of experts on
+    normed activations n [..., E]: the gap between the smallest chosen
+    probability and the largest one left out, as a share of the former.
+    A margin under the rounding of the system compared (bf16: 2^-8) is
+    an expert that system may swap, which moves that token's logits by
+    one expert's output and is no fault."""
+    k = hf["num_experts_per_tok"]
+    logits = jnp.einsum("...e,ex->...x", n, lw["w_router"].astype(F32))
+    p, _ = jax.lax.top_k(jax.nn.softmax(logits.astype(F32), axis=-1), k + 1)
+    return (p[..., k - 1] - p[..., k]) / p[..., k - 1]
+
+
 def _layer(x, lw, hf, mutate: Optional[str] = None):
-    """One decoder layer on x [B, S, E] float32."""
+    """One decoder layer on x [B, S, E] float32, and its router's
+    margin [B, S]."""
     eps, theta = hf["rms_norm_eps"], float(hf["rope_theta"])
     n = _rms(x, lw["ln1_scale"], eps)
     q = jnp.einsum("bse,ehd->bshd", n, lw["wq"].astype(F32))
@@ -112,7 +126,8 @@ def _layer(x, lw, hf, mutate: Optional[str] = None):
     s = jnp.where(mask[None, None], s, -jnp.inf)
     a = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, axis=-1), v)
     h = x + jnp.einsum("bshd,hde->bse", a, lw["wo"].astype(F32))
-    return h + routed_mlp(_rms(h, lw["ln2_scale"], eps), lw, hf, mutate)
+    n2 = _rms(h, lw["ln2_scale"], eps)
+    return h + routed_mlp(n2, lw, hf, mutate), router_margin(n2, lw, hf)
 
 
 def forward_logits(top: Dict[str, Any], layer_weights: Callable[[int], Dict],
@@ -123,14 +138,28 @@ def forward_logits(top: Dict[str, Any], layer_weights: Callable[[int], Dict],
     twice). `mutate` is None or one of MUTANTS."""
     if mutate is not None and mutate not in MUTANTS:
         raise ValueError(f"unknown mutant {mutate!r}; there are {MUTANTS}")
+    return _forward(top, layer_weights, tokens, hf, mutate)[0]
+
+
+def _forward(top, layer_weights, tokens, hf, mutate):
     layer = jax.jit(lambda x, lw: _layer(x, lw, hf, mutate))
+    margins = []
     with jax.default_matmul_precision("highest"):
         x = jnp.asarray(top["embed"])[jnp.asarray(tokens)].astype(F32)
         for l in range(hf["num_hidden_layers"]):
-            x = layer(x, layer_weights(l))
+            x, margin = layer(x, layer_weights(l))
+            margins.append(margin)
         x = _rms(x, jnp.asarray(top["ln_f_scale"]), hf["rms_norm_eps"])
         return jnp.einsum("bse,ev->bsv", x,
-                          jnp.asarray(top["lm_head"]).astype(F32))
+                          jnp.asarray(top["lm_head"]).astype(F32)), \
+            jnp.stack(margins)
+
+
+def router_margins(top, layer_weights, tokens, hf):
+    """[layers, B, S]: `router_margin` of every layer at every token of
+    the model as published (what `benchmarks/logits_audit.py` sets
+    beside the served logits' errors)."""
+    return _forward(top, layer_weights, tokens, hf, None)[1]
 
 
 def loss(top, layer_weights, tokens, hf, mutate: Optional[str] = None) -> float:

@@ -124,18 +124,36 @@ def latency_stats(reqs: List[Any], due_abs: np.ndarray, w0: float, w1: float,
     return {"ttft_s": ttft, "ttft_missing": len(missing_due), "tpot_s": tpot}
 
 
-def logits_check(cell, eng, mcfg, host_params, seed, log) -> Dict[str, Any]:
-    """Teacher-forced engine.put() logits (whole-prompt prefill, a
-    continuation chunk, single-token decode steps through the cache)
-    against the reference's full forward pass on the same tokens."""
+def reference_inputs(host_params, cast=None):
+    """What a reference's `forward_logits` takes: the top-level leaves
+    and a function handing out one layer's weights at a time (so the
+    float32 model never sits on the device whole). `cast` is applied to
+    every leaf on its way (the audit's lower-precision control)."""
     import jax.numpy as jnp
 
+    cast = cast or (lambda a: a)
+    top = {k: cast(jnp.asarray(v)) for k, v in host_params.items()
+           if k != "layers"}
+
+    def layer(l):
+        return {k: cast(jnp.asarray(v[l]))
+                for k, v in host_params["layers"].items()}
+
+    return top, layer
+
+
+def logits_errors(cell, eng, mcfg, host_params, seed,
+                  decode_steps=None) -> Dict[str, Any]:
+    """Teacher-forced engine.put() logits (whole-prompt prefill, a
+    continuation chunk, single-token decode steps through the cache)
+    against the reference's full forward pass on the same tokens:
+    max |difference| at every checked position, [prompts, steps]."""
     ref = harness.load_module(
         cell.bench_dir / "reference" / f"{cell.config['reference']}.py")
     chk = cell.traffic["logits_check"]
     rng = np.random.default_rng([seed, 0xC4EC])
-    n_dec, k = int(chk["decode_steps"]), int(chk["chunk"])
-    rtol = float(chk.get("rtol", LOGITS_RTOL))
+    n_dec = int(chk["decode_steps"] if decode_steps is None else decode_steps)
+    k = int(chk["chunk"])
     lens = [int(n) for n in chk["prompt_lens"]]
     full = [rng.integers(0, mcfg.vocab_size, n + n_dec).astype(np.int32)
             for n in lens]
@@ -147,34 +165,83 @@ def logits_check(cell, eng, mcfg, host_params, seed, log) -> Dict[str, Any]:
     got = [np.asarray(eng.put(uids, toks), np.float32) for toks in feeds]
     for u in uids:
         eng.flush(u)
-    # positions whose next-token logits put() returned: the last fed
-    # token of each call
-    top = {k2: host_params[k2] for k2 in host_params if k2 != "layers"}
-
-    def layer(l):
-        return {k2: jnp.asarray(v[l]) for k2, v in host_params["layers"].items()}
-
     # one pass over both prompts, padded at the END to one length: under
     # a causal mask the padding cannot reach the positions compared
     padded = np.zeros((len(full), max(len(f) for f in full)), np.int32)
     for i, f in enumerate(full):
         padded[i, :len(f)] = f
+    top, layer = reference_inputs(host_params)
     want_all = np.asarray(ref.forward_logits(top, layer, padded, cell.config))
-    errs, ref_max = [], 0.0
-    for i, n in enumerate(lens):
-        want = want_all[i]
-        pos = [n - k - 1, n - 1] + [n + j for j in range(n_dec)]
-        ref_max = max(ref_max, float(np.abs(want[pos]).max()))
-        errs.append([float(np.abs(got[s][i] - want[p]).max())
-                     for s, p in enumerate(pos)])
-    err = np.asarray(errs)
-    finite = all(np.isfinite(g).all() for g in got)
+    # positions whose next-token logits put() returned: the last fed
+    # token of each call
+    pos = np.asarray([[n - k - 1, n - 1] + [n + j for j in range(n_dec)]
+                      for n in lens])
+    want = np.stack([want_all[i, p] for i, p in enumerate(pos)])
+    got = np.stack(got, axis=1)                      # [prompts, steps, V]
+    return {"err": np.abs(got - want).max(axis=-1),
+            "ref_max": float(np.abs(want).max()),
+            "finite": bool(np.isfinite(got).all()),
+            "got": got, "want": want, "tokens": padded, "pos": pos}
+
+
+def step_name(s: int) -> str:
+    return ("prefill", "chunk")[s] if s < 2 else f"decode {s - 2}"
+
+
+def logits_verdict(chk: Dict[str, Any], err, ref_max: float,
+                   finite: bool = True) -> Dict[str, Any]:
+    """The traffic file's rule on the per-position errors `err`
+    [prompts, steps], each taken as a share of the largest |reference
+    logit| `ref_max`. `rtol` (absent: LOGITS_RTOL) is the CEILING: no
+    position may read above it. `typical_rtol`, where the file states
+    it, is a limit on the MEDIAN over the positions: a model that is
+    wrong everywhere moves every position, one flipped near-tied expert
+    moves one. A file that states neither gets the rule the benchmark
+    started with: the largest error against LOGITS_RTOL."""
+    share = np.asarray(err, np.float64) / ref_max
+    rtol = float(chk.get("rtol", LOGITS_RTOL))
+    i, s = np.unravel_index(int(np.argmax(share)), share.shape)
+    broken = []
+    if not finite:
+        broken.append("a logit is not finite")
+    if not share.max() <= rtol:
+        broken.append(
+            f"prompt {i} {step_name(s)}: max |err| {share.max():.5f} of the "
+            f"largest |reference logit| {ref_max:.3f} is above rtol {rtol}")
+    out = {"max_abs_err": float(np.max(err)), "ref_max_abs": ref_max,
+           "rtol": rtol, "max_share": float(share.max()),
+           "median_share": float(np.median(share))}
+    if "typical_rtol" in chk:
+        out["typical_rtol"] = typical = float(chk["typical_rtol"])
+        if not out["median_share"] <= typical:
+            broken.append(
+                f"the median over {share.size} positions, "
+                f"{out['median_share']:.5f} of {ref_max:.3f}, is above "
+                f"typical_rtol {typical}")
+    return dict(out, ok=not broken, broken=broken)
+
+
+def logits_check(cell, eng, mcfg, host_params, seed, log) -> Dict[str, Any]:
+    """`logits_errors` held to the traffic file's `logits_check` rule."""
+    e = logits_errors(cell, eng, mcfg, host_params, seed)
+    v = logits_verdict(cell.traffic["logits_check"], e["err"], e["ref_max"],
+                       e["finite"])
     log(f"[bench] logits vs float32 reference: max |err| by step "
-        f"{err.max(axis=0).round(5).tolist()} on logits of max |{ref_max:.3f}| "
-        f"(allowed {rtol} of that)")
-    return {"ok": bool(finite and err.max() <= rtol * ref_max),
-            "max_abs_err": float(err.max()), "ref_max_abs": ref_max,
-            "rtol": rtol}
+        f"{e['err'].max(axis=0).round(5).tolist()} on logits of max "
+        f"|{e['ref_max']:.3f}|: largest {v['max_share']:.5f} of that "
+        f"(allowed {v['rtol']}), median over the positions "
+        f"{v['median_share']:.5f} (allowed {v.get('typical_rtol', 'any')})")
+    return v
+
+
+def report_false_checks(checks, readings, lc, log):
+    """One line naming the checks that are false and what each read, so
+    that a refused run can be understood from its output alone."""
+    false = [k for k, ok in checks.items() if not ok]
+    if not false:
+        return
+    why = dict(readings, matches_reference="; ".join(lc["broken"]))
+    log("[bench] FALSE: " + " | ".join(f"{k}: {why[k]}" for k in false))
 
 
 def setup(ctx: harness.RunContext):
@@ -196,10 +263,19 @@ def setup(ctx: harness.RunContext):
 def run(ctx: harness.RunContext) -> harness.Outcome:
     eng, mcfg, host_params, phases = setup(ctx)
     m = measure(ctx, eng, mcfg, ctx.seed)
-    lc = logits_check(ctx.cell, eng, mcfg, host_params, ctx.seed, ctx.log)
+    compared = []           # every number compared, beside its limit
+
+    def say(msg):
+        compared.append(msg)
+        ctx.log(msg)
+
+    lc = logits_check(ctx.cell, eng, mcfg, host_params, ctx.seed, say)
     m["checks"]["matches_reference"] = lc["ok"]
-    ctx.log(f"[bench] checks {m['checks']}")
+    say("[bench] compared: " + "; ".join(m["readings"].values()))
+    say(f"[bench] checks {m['checks']}")
+    report_false_checks(m["checks"], m["readings"], lc, say)
     m["notes"].update(logits=lc, setup_phases=phases, checks=m["checks"],
+                      compared=compared,
                       compile_s_total=ctx.compiles.seconds,
                       programs_compiled=ctx.compiles.n)
     return harness.Outcome(
@@ -347,6 +423,18 @@ def measure(ctx: harness.RunContext, eng, mcfg, seed: int,
         "some_finished": len(finished_in) > 0,
     }
     log(f"[bench] compiles in window {compiles_in_window}, recompiles {recompiles}")
+    # what each check read, beside what it allows
+    readings = {
+        "pallas": f"decode_impl resolved {eng.resolved_impl!r} "
+        f"(pallas on a TPU)",
+        "no_compile_in_window": f"{compiles_in_window} programs compiled in "
+        f"the window, {recompiles} recompile findings (allowed 0)",
+        "finished_as_asked": f"{len(bad_finish)} finished with another reason "
+        f"than `length`, another count than asked or a token outside the "
+        f"vocabulary (allowed 0){sorted(bad_finish)[:8] or ''}",
+        "some_finished": f"{len(finished_in)} finished inside the window "
+        f"(at least 1)",
+    }
 
     d = {k: snap1.counters[k] - snap0.counters[k] for k in snap1.counters}
     e2e = {
@@ -383,5 +471,6 @@ def measure(ctx: harness.RunContext, eng, mcfg, seed: int,
         "gen_lateness_ms_p99": 1e3 * percentile(late_in, 99) if late_in else None,
         "bytes_in_use_after": hbm_in_use,
     }
-    return {"checks": checks, "attempted": attempted, "failed": failed,
+    return {"checks": checks, "readings": readings,
+            "attempted": attempted, "failed": failed,
             "end_to_end": e2e, "obs": obs, "notes": notes}

@@ -1,12 +1,10 @@
 """CPU rehearsals of the benchmark harness (never a device number).
 
 Run with `python -m pytest benchmarks/tests -q`: they guard the
-yardstick itself. The driver's tier-1 command names `tests/` and so
-does not collect them yet; the route that makes it do so (a symlink
-`tests/benchmark_suite -> ../benchmarks/tests`, which then runs them
-under tests/conftest.py's eight CPU devices beside this file's four)
-is a file outside the benchmark's directories, which a `benchmark` PR
-may not add (PERF.md §7).
+yardstick itself. The driver's tier-1 command names `tests/` and
+collects them through the symlink `tests/benchmark_suite ->
+../benchmarks/tests` (PR 27), under tests/conftest.py's eight CPU
+devices beside this file's four.
 """
 
 import os

@@ -177,11 +177,22 @@ def check_cell(root, name):
 
 
 def check_logits_limit(traffic):
-    """A mix that states its own `rtol` for the logits check says why."""
+    """A mix that states limits of its own for the logits check (`rtol`,
+    the ceiling on the largest error; `typical_rtol`, the limit on the
+    median over the positions; any key ending in `rtol`) says why, each
+    in `<key>_why`."""
     chk = traffic.get("logits_check", {})
-    if "rtol" in chk or "rtol_why" in chk:
-        assert 0 < chk["rtol"] < 1 and chk["rtol_why"].strip(), \
-            "a traffic file that states its own rtol says why"
+    limits = {k for k in chk if k.endswith("rtol")}
+    reasons = {k[:-len("_why")] for k in chk if k.endswith("rtol_why")}
+    assert limits == reasons, \
+        f"a traffic file that states a limit of its own says why: " \
+        f"{sorted(limits ^ reasons)}"
+    for k in limits:
+        assert 0 < chk[k] < 1 and chk[f"{k}_why"].strip(), \
+            f"a traffic file that states its own {k} says why"
+    if "typical_rtol" in chk:
+        assert chk["typical_rtol"] < chk.get("rtol", 1), \
+            "the limit on the median lies under the ceiling"
 
 
 def width_changes(published, here, path=""):

@@ -104,7 +104,20 @@ def test_which_keys_are_widths_and_which_are_counts(key, is_width, is_count):
     ({}, True), ({"rtol": 0.12}, False), ({"rtol_why": "x"}, False),
     ({"rtol": 0.12, "rtol_why": " "}, False),
     ({"rtol": 0.12, "rtol_why": "a routed model can flip a near-tied "
-      "expert under bf16"}, True)])
+      "expert under bf16"}, True),
+    # PR 29: every limit a file states, not `rtol` alone
+    ({"typical_rtol": 0.01}, False),
+    ({"typical_rtol_why": "the median parts a wrong model from a flip"}, False),
+    ({"rtol": 0.05, "rtol_why": "a flip costs a percent",
+      "typical_rtol": 0.01}, False),
+    ({"rtol": 0.05, "rtol_why": "a flip costs a percent",
+      "typical_rtol": 0.01, "typical_rtol_why": "\t"}, False),
+    ({"rtol": 0.05, "rtol_why": "a flip costs a percent",
+      "typical_rtol": 0.06, "typical_rtol_why": "above the ceiling"}, False),
+    ({"rtol": 0.05, "rtol_why": "a flip costs a percent", "typical_rtol": 0.01,
+      "typical_rtol_why": "the median parts a wrong model from a flip"}, True),
+    ({"typical_rtol": 0.01,
+      "typical_rtol_why": "under the runner's own ceiling"}, True)])
 def test_a_logits_limit_of_its_own_needs_its_reason(own, ok):
     if ok:
         helpers.check_logits_limit({"logits_check": own})

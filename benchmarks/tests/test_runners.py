@@ -74,6 +74,98 @@ def test_a_traffic_file_may_state_its_own_logits_limit(tiny_root):
     assert strict["max_abs_err"] > 0
 
 
+def _olmoe_rule():
+    return harness.load_json(
+        helpers.ROOT / "benchmarks" / "traffic" / "chat-saturated-olmoe.json"
+    )["logits_check"]
+
+
+def _errors(typical, **at):
+    """Made-up per-position errors [2 prompts, steps] of a model whose
+    largest |reference logit| is 1: `typical` everywhere (a little
+    uneven, as rounding noise is), and `at` = {"p,s": value} elsewhere."""
+    import numpy as np
+
+    steps = 2 + _olmoe_rule()["decode_steps"]
+    err = typical * (1 + 0.1 * np.sin(np.arange(2 * steps))).reshape(2, steps)
+    for where, value in at.items():
+        err[tuple(int(i) for i in where.split(","))] = value
+    return err
+
+
+# what the chip read of the routed cell (PERF.md §6, PR 29), as shares of
+# the largest reference logit: the median position of a sound seed, the
+# largest position of any of 48 (a flipped expert) and a little more,
+# the SMALLEST median of the reference with one expert fewer, a wrong block
+NOISE, FLIP, K_MINUS_1, WRONG_BLOCK = 0.0063, 0.02, 0.0153, 0.25
+
+
+@pytest.mark.parametrize("case,err,ok", [
+    ("sound", _errors(NOISE), True),
+    ("one flipped expert", _errors(NOISE, **{"1,4": FLIP}), True),
+    ("two flipped experts", _errors(NOISE, **{"0,0": FLIP, "1,5": FLIP}), True),
+    ("one expert fewer everywhere", _errors(K_MINUS_1), False),
+    ("one wrong block", _errors(NOISE, **{"0,1": WRONG_BLOCK}), False),
+    ("float8 weights", _errors(0.2), False),
+    ("not a number", _errors(NOISE, **{"0,2": float("nan")}), False)])
+def test_the_routed_cells_rule_on_made_up_errors(case, err, ok):
+    """The two limits of chat-saturated-olmoe.json: a ceiling on the
+    largest position and a limit on the median over the positions. One
+    outlying position passes, every position shifted by what a missing
+    expert costs fails, one gross position fails."""
+    from benchmarks.runners import serve
+
+    rule = _olmoe_rule()
+    assert {"rtol", "typical_rtol"} <= set(rule)
+    v = serve.logits_verdict(rule, err, 1.0)
+    assert v["ok"] == ok, (case, v)
+    assert bool(v["broken"]) != ok
+    logs = []
+    serve.report_false_checks(
+        {"pallas": True, "matches_reference": v["ok"]},
+        {"pallas": "decode_impl resolved 'pallas'"}, v, logs.append)
+    assert len(logs) == (0 if ok else 1)
+    if not ok:    # the line names the check, and what broke which limit
+        assert logs[0].startswith("[bench] FALSE: matches_reference: ")
+        assert "rtol" in logs[0] and "pallas" not in logs[0]
+
+
+@pytest.mark.parametrize("err,ok", [
+    (_errors(0.05), True), (_errors(NOISE, **{"0,3": 0.0801}), False),
+    (_errors(0.07), True), (_errors(0.07, **{"1,1": 0.0799}), True)])
+def test_a_file_without_limits_of_its_own_gets_the_old_rule(err, ok):
+    """No `rtol`, no `typical_rtol`: the largest position against the
+    runner's constant and nothing else, as before PR 29."""
+    from benchmarks.runners import serve
+
+    v = serve.logits_verdict({"decode_steps": 4}, err, 1.0)
+    assert v["ok"] == ok == bool(err.max() <= serve.LOGITS_RTOL)
+    assert v["rtol"] == serve.LOGITS_RTOL and "typical_rtol" not in v
+
+
+@of_kind("serve")
+def test_a_broken_served_path_comes_out_not_correct(tiny_root, rc, monkeypatch):
+    """The rest of a run with the logits altered where the engine
+    produces them (every vocabulary entry moved by one): `correct` is
+    false, and the log says which check and which limit."""
+    from deepspeed_tpu.inference.engine import InferenceEngine
+
+    put = InferenceEngine.put
+    monkeypatch.setattr(
+        InferenceEngine, "put",
+        lambda self, *a, **k: jax.numpy.roll(put(self, *a, **k), 1, axis=-1))
+    cell = harness.load_cell(rc["name"], tiny_root)
+    logs = []
+    with interpret_kernels():
+        line = json.loads(harness.run_cell(
+            cell, seed=4, seconds=rc["seconds"], trace=False,
+            devices=jax.devices()[:1], t_process_start=harness.now(),
+            log=logs.append, out_root=tiny_root / "out"))
+    assert line["correct"] is False and line["failed"] == 0
+    false = [m for m in logs if m.startswith("[bench] FALSE: ")]
+    assert len(false) == 1 and "matches_reference" in false[0], logs[-4:]
+
+
 def test_a_request_without_a_first_token_counts_as_the_largest_ttft():
     """Real waits are all kept; each missing request counts as the
     largest of them or as long as it has waited already, whichever is

@@ -79,16 +79,112 @@ def test_token_batches_are_seeded_zipf(name):
     assert counts.max() / a1["tokens"].size > 0.02
 
 
-def test_every_seed_offers_the_same_load_and_lengths():
+def test_the_training_stream_is_pinned():
+    """PR 29 changed how serving lengths are dealt; the training cells'
+    batches are byte for byte what they were (seed 101, three steps of
+    four sequences: the digest of the parent commit's stream)."""
+    import hashlib
+
+    it = generate.token_batches(_mix("seq4k-zipf"), 101, 32000, 4)
+    h = hashlib.sha256()
+    for _ in range(3):
+        h.update(next(it)["tokens"].tobytes())
+    assert h.hexdigest().startswith("bf617dda2f57f04d")
+
+
+@pytest.mark.parametrize("burst,rate,horizon", [
+    (0, 2.0, 400.0), (128, 11.0, 59.0), (128, 12.0, 59.0)])
+def test_every_seed_offers_the_same_load_and_lengths(burst, rate, horizon):
     """Stratified draws: the seed changes which request comes when, not
-    how many there are or how long."""
-    mix = dict(_mix(SERVE_MIXES[0]), burst_at_start=0)
-    a = generate.serve_schedule(mix, 1, 400.0, 1000, rate_rps=2.0)
-    b = generate.serve_schedule(mix, 2, 400.0, 1000, rate_rps=2.0)
+    how many there are or how long. Where two seeds offer the same
+    count, they offer the same multiset of prompts and of answers, in
+    another order."""
+    mix = dict(_mix(SERVE_MIXES[0]), burst_at_start=burst)
+    a = generate.serve_schedule(mix, 1, horizon, 1000, rate_rps=rate)
+    b = generate.serve_schedule(mix, 2, horizon, 1000, rate_rps=rate)
     assert abs(len(a.due_s) - len(b.due_s)) <= 0.02 * len(a.due_s)
     n = min(len(a.due_s), len(b.due_s))
     assert abs(np.sort(a.prompt_len)[:n].sum() - np.sort(b.prompt_len)[:n].sum()) \
         <= 0.02 * a.prompt_len.sum()
+    if len(a.due_s) == len(b.due_s):
+        assert np.array_equal(np.sort(a.prompt_len), np.sort(b.prompt_len))
+        assert np.array_equal(np.sort(a.answer_len), np.sort(b.answer_len))
+    assert not np.array_equal(a.prompt_len[:n], b.prompt_len[:n])
+    assert not np.array_equal(a.answer_len[:n], b.answer_len[:n])
+
+
+def _old_lengths(mix, seed, horizon, rate):
+    """The generator as it was until PR 29, kept here as the loop it
+    was: ONE permutation of the n evenly spaced quantiles over all the
+    requests the schedule offers."""
+    from scipy.special import ndtri
+
+    rng = np.random.default_rng([int(seed), 0x5E57E])
+    n_gaps = int(round(rate * horizon))
+    t = np.cumsum(-np.log1p(-rng.permutation(
+        (np.arange(n_gaps) + 0.5) / n_gaps)) / rate)
+    n = int(mix.get("burst_at_start", 0)) + int((t < horizon).sum())
+    out = []
+    for spec in (mix["prompt_len"], mix["answer_len"]):
+        x = np.exp(np.log(spec["median"]) + spec["sigma"] * ndtri(
+            rng.permutation((np.arange(n) + 0.5) / n)))
+        out.append(np.clip(np.rint(x), spec["min"], spec["max"]).astype(np.int64))
+    return out
+
+
+def _prefix_drift(lengths, admitted):
+    return abs(lengths[:admitted].mean() / lengths.mean() - 1)
+
+
+@pytest.mark.parametrize("rate,offered", [(7.0, 541), (11.0, 777)])
+def test_an_admitted_prefix_carries_the_mix_whatever_the_seed(rate, offered):
+    """A saturated chat cell admits, first come first served, about 458
+    of what it offers (128 + 5.6/s x 59 s). Their mean prompt and mean
+    answer are the mix's to within 1% on twenty seeds; under the old
+    generator some seed's are off by over 1.5% (at 777 offered, 4.8%)."""
+    mix = _mix("chat-saturated")
+    new, old = [], []
+    for seed in range(20):
+        s = generate.serve_schedule(mix, seed, 59.0, 32000, rate_rps=rate)
+        assert len(s.due_s) == offered
+        was = _old_lengths(mix, seed, 59.0, rate)
+        # the same multiset as before, dealt in another order
+        assert np.array_equal(np.sort(was[0]), np.sort(s.prompt_len))
+        assert np.array_equal(np.sort(was[1]), np.sort(s.answer_len))
+        new.append(max(_prefix_drift(s.prompt_len, 458),
+                       _prefix_drift(s.answer_len, 458)))
+        old.append(max(_prefix_drift(was[0], 458), _prefix_drift(was[1], 458)))
+    assert max(new) < 0.01, new
+    assert max(old) > 0.015, old
+
+
+@pytest.mark.parametrize("name", SERVE_MIXES)
+@pytest.mark.parametrize("seed", [4, 2147483747])
+def test_any_block_of_arrivals_holds_every_stratum(name, seed):
+    """Any LENGTH_BLOCK consecutive requests, the burst included, hold
+    one prompt and one answer from each of LENGTH_BLOCK strata of the
+    schedule's lengths, the strata equal to within one value."""
+    s = generate.serve_schedule(_mix(name), seed, 59.0, 32000)
+    n, B = len(s.due_s), generate.LENGTH_BLOCK
+    assert n > 4 * B
+    edges = np.arange(B + 1) * n // B
+    lo, hi = edges[:-1], edges[1:] - 1
+    for lengths in (s.prompt_len, s.answer_len):
+        whole = np.sort(lengths)
+        for i in range(n - B + 1):
+            block = np.sort(lengths[i:i + B])
+            assert np.all(whole[lo] <= block) and np.all(block <= whole[hi]), i
+    # prompts and answers are dealt independently, and of the deals
+    # tried the one is kept that pairs long with long least: decoding's
+    # KV reads grow with prompt x answer
+    assert abs(np.corrcoef(s.prompt_len, s.answer_len)[0, 1]) < 0.04
+    assert abs(np.corrcoef(s.prompt_len[:458], s.answer_len[:458])[0, 1]) < 0.05
+
+
+@pytest.mark.parametrize("n", [0, 1, 31, 32, 33, 541, 777])
+def test_arrival_order_is_a_permutation(n):
+    ranks = generate._arrival_ranks(np.random.default_rng(n), n)
+    assert np.array_equal(np.sort(ranks), np.arange(n))
 
 
 def test_unknown_distribution_is_an_error():
@@ -98,13 +194,16 @@ def test_unknown_distribution_is_an_error():
 
 def test_the_measured_chat_schedule_is_pinned():
     """PERF.md's chip runs of serve-chat-saturated drew these requests
-    (seed 101, 8 s ramp + 51 s window: 541 of them, as the run's notes
-    say). A change to the generator that moves them moves the yardstick."""
+    (seed 101, 8 s ramp + 51 s window: 777 of them at 11 requests/s, as
+    the run's notes say; 541 at 7 requests/s and another digest until
+    PR 29, which raised the rate and dealt the lengths in arrival
+    order). A change to the generator that moves them moves the
+    yardstick."""
     import hashlib
 
     s = generate.serve_schedule(_mix("chat-saturated"), 101, 59.0, 32000)
-    assert len(s.due_s) == 541
+    assert len(s.due_s) == 777
     digest = hashlib.sha256(
         s.due_s.tobytes() + s.prompt_len.tobytes() + s.answer_len.tobytes()
         + b"".join(t.tobytes() for t in s.prompts)).hexdigest()
-    assert digest.startswith("85da8355b571e6e6")
+    assert digest.startswith("723db017b799eba3")

@@ -748,7 +748,14 @@ def _decode_attention(q, ck, cv, table, ctx, use_kernel: bool, allowed=None,
                       k_scale=None, v_scale=None):
     """k_new/v_new/slots non-None selects the FUSED write+attend kernel
     (single-token decode rows; ck/cv are the PRE-write arenas and the
-    returned (att, ck, cv) includes the in-kernel RMW).
+    returned (att, ck, cv) includes the in-kernel RMW). Without them —
+    the shared-table program, whose rows were written by paged_kv_write
+    first — paged_decode_attention attends only, and picks its kernel
+    from what it is handed: bf16/f32 pools at head dims that are a
+    multiple of 128 take the per-row live-block walk (each row reads
+    its live blocks, nothing else; also per shard under a 'model'
+    mesh), int8 pools and other block shapes keep the (S, NB) grid.
+    Both are `paged_decode_grid` in a trace.
 
     alibi: optional [H] per-head slopes (Bloom-class) — every path below
     biases scores by slope_h * key_pos (exact per single query row).
@@ -762,9 +769,10 @@ def _decode_attention(q, ck, cv, table, ctx, use_kernel: bool, allowed=None,
     quant = k_scale is not None
     if allowed_slots is not None and use_kernel and _tp_size(mesh) <= 1:
         # block-sparse serving on the Pallas kernels: the layout rides
-        # in as a per-slot bitmap. Fused+v2 skips pruned slots' DMA
-        # entirely; the (S, NB)-grid kernel clamps them to a resident
-        # tile (still no fresh DMA, but a grid step each).
+        # in as a per-slot bitmap. The per-row walk (fused v2, and the
+        # unfused attention) never issues a pruned slot's DMA; the
+        # (S, NB)-grid kernel clamps it to a resident tile (still no
+        # fresh DMA, but a grid step each).
         if fused and not quant and supports_fused_v2(q.shape[-1]):
             return paged_decode_fused(q, ck, cv, table, ctx,
                                       k_new, v_new, slots, window=window,

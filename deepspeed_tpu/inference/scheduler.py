@@ -219,6 +219,11 @@ class ServingScheduler:
             # routed layer: batched tokens x top-k (0 for a dense model);
             # over steps and experts, the rows an expert sees a step
             "moe_token_expert_pairs": 0,
+            # KV cache blocks the decode rows of the dispatched programs
+            # had to read: sum over rows (and fused steps) of
+            # ceil(ctx / kv_block_size); over steps, what the paged
+            # attention kernel's time should follow
+            "kv_live_blocks": 0,
         }
         self._phases = profiler.Phases("sched", "iteration", PHASES,
                                        sums=self.counters)
@@ -894,13 +899,20 @@ class ServingScheduler:
             self._it_rows += int(n_real.sum())
         return parts
 
-    def _count_tokens(self, n: int) -> None:
-        """Tokens a dispatched program batched, and the token-expert
-        pairs they make in a routed layer."""
+    def _count_tokens(self, n: int, ctx=None, steps: int = 1) -> None:
+        """Tokens a dispatched program batched, the token-expert pairs
+        they make in a routed layer and, where the program reads the
+        paged cache, the live KV blocks of its rows: ctx is the host
+        array of context lengths it was launched with (0 = pad row),
+        each row one token longer in every further fused step."""
         self.counters["batched_tokens"] += n
         cfg = self.engine.cfg
         if cfg.n_experts > 0:
             self.counters["moe_token_expert_pairs"] += n * cfg.moe_top_k
+        if ctx is not None:
+            live = ctx[ctx > 0][:, None] + np.arange(steps)
+            bs = self.engine.config.kv_block_size
+            self.counters["kv_live_blocks"] += int(np.sum(-(-live // bs)))
 
     def _dispatch_mixed(self, rows) -> Optional[_Part]:
         """One compiled decode program over the iteration's ragged rows:
@@ -954,7 +966,7 @@ class ServingScheduler:
         tok_dev = (self._sample_part(logits, sample_rows, sp)
                    if sample_rows else None)
         ph.mark("commit")
-        self._count_tokens(n_rows)
+        self._count_tokens(n_rows, ctx)
         return _Part("mixed", sample_rows, tok_dev)
 
     def _dispatch_fused(self, running: List[Request], C: int) -> _Part:
@@ -1009,7 +1021,7 @@ class ServingScheduler:
         ph.mark("commit")
         for req in running:
             eng.state.commit(req.uid, C)
-        self._count_tokens(len(running) * C)
+        self._count_tokens(len(running) * C, ctx, steps=C)
         self.counters["fused_steps"] += 1
         return _Part("fused", sample_rows, gen, n_steps=C)
 
@@ -1414,7 +1426,7 @@ class ServingScheduler:
         tok_dev = self._sample_part(logits, sample_rows, sp)
         ph.mark("commit")
         self.counters["steps"] += 1
-        self._count_tokens(len(rows))
+        self._count_tokens(len(rows), ctx)
         self.counters["chained_steps"] += 1
         return _Step([_Part("mixed", sample_rows, tok_dev)], len(rows))
 

@@ -9,14 +9,29 @@ descriptors).
 
 Cache layout: [num_blocks, block_size, KV_heads, head_dim].
 One cache block is a CONTIGUOUS (block_size, KV, D) tile — a single
-256KB-class DMA fetches every head's slice of a page, so the decode grid
-is (seqs, table_slots) with a static head loop inside (measured 8x fewer
-grid steps and much higher effective bandwidth than a per-head grid).
-The trailing (KV, D) dims satisfy TPU (8,128) tiling; TP shards the KV
-dim. "Block i of sequence s" lives at cache[table[s, i]]; pages beyond a
-sequence's context are never streamed — the index map clamps the slot to
-the last needed block so pruned steps revisit a resident tile (no DMA),
-mirroring the causal clamp in flash_attention.py.
+256KB-class DMA fetches every head's slice of a page, with a static
+head loop over it (measured 8x fewer steps and much higher effective
+bandwidth than a per-head grid). The trailing (KV, D) dims satisfy TPU
+(8,128) tiling; TP shards the KV dim. "Block i of sequence s" lives at
+cache[table[s, i]]; pages beyond a sequence's context are never
+streamed.
+
+Two ways to page, one per case (paged_decode_attention picks from the
+dtype and shape it is handed, nothing else):
+
+- the per-row live-block walk (_walk_live_blocks): grid (seqs,), the
+  arenas left in HBM, a fori_loop over the row's live blocks with
+  manual DMA a few blocks ahead. Dead table slots cost nothing. Takes
+  the fused single-token write+attend (paged_decode_fused) and the
+  unfused, unquantised attention the shared-table program runs;
+- the (seqs, table_slots) BlockSpec grid (_decode_kernel): the block
+  table is a scalar-prefetch argument and index maps do the paging; a
+  slot beyond the context clamps to the last needed block, so a pruned
+  step revisits a resident tile (no DMA, no compute, but a grid step:
+  ~0.34 us each on a v5e). Keeps what the walk cannot take: int8 KV
+  (scale tiles ride the index maps), the fused write at head dims that
+  are not a multiple of 128, and block shapes Mosaic refuses as a
+  manual DMA (_walks_live_blocks).
 
 int8 per-block KV quantization (docs/paged_attention.md): pools may
 hold int8 codes with a per-block [block_size, KV] f32 scale tile
@@ -297,11 +312,43 @@ def _decode_kernel(
         )
 
 
+def _group_queries(q, n_kv: int, alibi_slopes=None):
+    """[S, H, D] queries -> [S, KV, Gp, D] with each KV head's group
+    sublane-padded to Gp = max(G, 8) rows, and the ALiBi slopes (or
+    None) as the matching [KV, Gp] f32 table. Returns (qg, ab, G, Gp)."""
+    S, H, D = q.shape
+    G = H // n_kv
+    Gp = max(G, 8)  # sublane-pad tiny query blocks
+    qg = q.reshape(S, n_kv, G, D)
+    if Gp != G:
+        qg = jnp.pad(qg, ((0, 0), (0, 0), (0, Gp - G), (0, 0)))
+    ab = None
+    if alibi_slopes is not None:
+        ab = jnp.asarray(alibi_slopes, jnp.float32).reshape(n_kv, G)
+        if Gp != G:
+            ab = jnp.pad(ab, ((0, 0), (0, Gp - G)))
+    return qg, ab, G, Gp
+
+
 def paged_decode_attention(q, k_cache, v_cache, block_table, ctx_lens,
                            window: int = 0, allowed_slots=None,
                            k_new=None, v_new=None, slots=None,
                            alibi_slopes=None, k_scale=None, v_scale=None):
     """One-token-per-sequence attention over the paged KV cache.
+
+    Which kernel runs is read from the arguments, never from a flag.
+    Unfused and unquantised (what the shared-table program calls after
+    paged_kv_write, rows of one prefill chunk sharing a table): the
+    per-row live-block walk, grid (S,) — each row reads the live blocks
+    of its table and nothing else, so the time follows the contexts and
+    not the table's width. On the (S, NB) BlockSpec grid stay int8 KV
+    (k_scale given: the scale tiles ride the index maps), the fused
+    write+attend below (head dims the v2 kernel cannot take), and
+    block shapes Mosaic refuses as a manual DMA (_walks_live_blocks:
+    D % 128 != 0; 16-bit pools whose KV count is not 2, 4 or a multiple
+    of 8). Either way the pallas_call is named `paged_decode_grid`: in
+    a trace that name means "the shared-table decode attention",
+    whatever its grid.
 
     q: [S, H, D] (the new token's queries)
     k_cache/v_cache: [num_blocks, block_size, KV, D]
@@ -314,15 +361,16 @@ def paged_decode_attention(q, k_cache, v_cache, block_table, ctx_lens,
       (out, k_cache, v_cache, k_scale, v_scale)).
     block_table: [S, NB] int32 — cache block ids per sequence
     ctx_lens: [S] int32 — context length INCLUDING the new token; rows
-      with 0 are batch padding (output is garbage, sliced by the caller)
-    window > 0: token-exact sliding window (Mistral-class serving) — the
-      slot grid shrinks to ~window/block_size steps per sequence
+      with 0 are batch padding (the walk stores zeros, the grid garbage;
+      sliced by the caller either way)
+    window > 0: token-exact sliding window (Mistral-class serving) —
+      only the ~window/block_size slots inside it are visited
     allowed_slots: optional [S, NB] int32/bool — block-sparse serving:
       slot j of sequence s participates only when nonzero (the layout
       row at cache-block granularity; requires the sparse block size to
       be a multiple of the cache block size so each cache block falls in
-      ONE layout block). Skipped slots cost no compute and their DMA is
-      clamped to a resident tile.
+      ONE layout block). Skipped slots cost no compute; the walk never
+      issues their load, the grid clamps their DMA to a resident tile.
     k_new/v_new [S, KV, D] + slots [S]: FUSED write+attend — the new
       token's KV is folded into its target block in VMEM (attention sees
       it) and the block is RMW'd back to the arena, replacing the
@@ -339,26 +387,20 @@ def paged_decode_attention(q, k_cache, v_cache, block_table, ctx_lens,
     S, H, D = q.shape
     NBLK, bs, KV, _ = k_cache.shape
     NB = block_table.shape[1]
-    G = H // KV
-    Gp = max(G, 8)  # sublane-pad tiny query blocks
     scale = 1.0 / (D**0.5)
     sparse = allowed_slots is not None
     fused = k_new is not None
     alibi = alibi_slopes is not None
     quant = k_scale is not None
+    qg, ab, G, Gp = _group_queries(q, KV, alibi_slopes)
+    if not fused and not quant and _walks_live_blocks(qg, k_cache):
+        out = _attend_live_blocks(qg, ab, k_cache, v_cache, block_table,
+                                  ctx_lens, window, allowed_slots, scale)
+        return out[:, :, :G, :].reshape(S, H, D)
     allow = (allowed_slots.astype(jnp.int32) if sparse
              else jnp.ones((S, NB), jnp.int32))
     slots_arr = (slots.astype(jnp.int32) if fused
                  else jnp.full((S,), -1, jnp.int32))
-
-    qg = q.reshape(S, KV, G, D)
-    if Gp != G:
-        qg = jnp.pad(qg, ((0, 0), (0, 0), (0, Gp - G), (0, 0)))
-    ab = None
-    if alibi:
-        ab = jnp.asarray(alibi_slopes, jnp.float32).reshape(KV, G)
-        if Gp != G:
-            ab = jnp.pad(ab, ((0, 0), (0, Gp - G)))
 
     def kv_block_of(s, j, tbl_ref, ctx_ref, allow_ref, slot_ref):
         last = jnp.maximum(ctx_ref[s] - 1, 0) // bs
@@ -525,124 +567,137 @@ def paged_decode_attention_xla(q, k_cache, v_cache, block_table, ctx_lens,
 
 
 # ---------------------------------------------------------------------------
-# fused decode v2: per-sequence grid, manual-DMA block loop
+# per-row live-block walk: one grid step a row, manual-DMA block loop
+# (the fused single-token kernel and the shared-table attention)
 # ---------------------------------------------------------------------------
 
-def _decode_fused_kernel(
-    tbl_ref, ctx_ref, slot_ref, allow_ref,          # scalar prefetch
-    q_ref, kn_ref, vn_ref, k_any, v_any,            # inputs (caches in HBM)
-    *rest,                                          # [ab], outs, scratch
+# VMEM block buffers per row parity, each with its DMA semaphores: a
+# block is being computed while the next _RING - 1 are in flight. At
+# the serving cells' shapes 3 read 5% under 2 and 4 no better than 3
+# (chip, PR 30: PERF.md section 6)
+_RING = 3
+
+
+def _walk_live_blocks(
+    s, tbl_ref, ctx_ref, allow_ref, q_ref, k_any, v_any, ab_ref,
+    bufk, bufv, lsem, *,
     n_seqs: int, block_size: int, scale: float, n_kv: int, gp: int,
-    window: int, sparse: bool, alibi: bool,
+    window: int, sparse: bool, new_col: bool,
 ):
-    if alibi:  # [KV, Gp] ALiBi slope table rides as the LAST input
-        ab_ref, o_ref, ck_any, cv_any, bufk, bufv, wsem, lsem = rest
-    else:
-        o_ref, ck_any, cv_any, bufk, bufv, wsem, lsem = rest
-        ab_ref = None
-    """One grid step per SEQUENCE (compile size O(1) in batch — an
-    earlier all-sequences-unrolled variant ran ~8us/call faster at S=8
-    but its Mosaic compile exploded at S=64). The KV arenas stay in HBM
-    (memory_space=ANY); a fori_loop walks ONLY the live blocks of this
-    sequence's table, double-buffering block DMAs. Dead table slots cost
-    nothing, the new token's row is DMA'd straight into its cache slot
-    (2 KB, vs RMW-ing whole 256 KB blocks through the output pipeline),
-    and its attention contribution enters as one extra online-softmax
-    column from VMEM. Scratch persists across grid steps, so each step
-    prefetches the NEXT sequence's first block (buffer sets alternate by
-    sequence parity) — the common short-context case never stalls.
+    """Row `s`'s online softmax over the LIVE blocks of its table and
+    nothing else: a fori_loop from the sliding window's first slot to
+    the last block that holds a cached column, each block DMA'd from
+    the HBM arenas into a ring of VMEM buffers `bufk`/`bufv`
+    [2, ring, bs, KV, D] ring - 1 iterations ahead of its use. Scratch
+    persists across grid steps, so the step of row s also issues the
+    first ring - 1 blocks of row s+1 (buffer sets alternate by row
+    parity) —
+    the common short-context case never stalls. Dead table slots cost
+    nothing: no grid step, no DMA, no compare.
+
+    new_col: the row's newest token (position ctx-1) is NOT in the
+    cache — the caller folds it in as its own column (fused
+    write+attend) — so only columns < ctx-1 are live. Otherwise the
+    row was written before the call and columns < ctx are.
 
     sparse: block-sparse layouts ride in as the allow_ref bitmap — a
     disallowed slot's load is never ISSUED (its iteration neither waits
-    nor computes; block j+1's load is issued by iteration j regardless
-    of j's own allow bit, so pipelining is preserved across gaps). The
-    (S, NB)-grid kernel could only clamp a pruned slot's DMA to a
-    resident tile; here pruned slots are genuinely free."""
+    nor computes; later blocks' loads are issued regardless of the gap,
+    so pipelining is preserved across it).
+
+    Returns the per-head (running max, sum, accumulator) tuples of
+    (Gp, 1), (Gp, 1), (Gp, D) f32; a row with no live block (ctx 0:
+    batch padding) returns the initial carry and issues no load."""
     bs = block_size
     D = q_ref.shape[-1]
-    s = pl.program_id(0)
-
-    def jbase_of(ctx):
-        return (jnp.maximum(ctx - window, 0) // bs) if window > 0 else 0
-
-    def nblk_of(ctx):
-        return pl.cdiv(jnp.maximum(ctx - 1, 0), bs)
-
-    def allowed(sq, j):
-        if not sparse:
-            return True
-        return allow_ref[sq, j] != 0
-
+    ring = bufk.shape[1]
     # every HBM index is CLAMPED to the arena: a violated block-table
     # contract (caller bug) must produce wrong-but-contained results,
     # never a wild DMA — an out-of-bounds manual DMA doesn't just crash
     # the program, it can wedge the TPU runtime for every later client
     n_blk = k_any.shape[0]
+    n_slots = tbl_ref.shape[1]
 
-    def load(sq, bufset, j, buf_slot):
+    def cached_of(ctx):
+        return jnp.maximum(ctx - 1, 0) if new_col else ctx
+
+    def jbase_of(ctx):
+        return _win_jbase_decode(ctx, window, bs) if window > 0 else 0
+
+    def nblk_of(ctx):
+        return pl.cdiv(cached_of(ctx), bs)
+
+    def wanted(sq, j, nblk):
+        # block j of row sq is read: inside the live range and, under a
+        # layout, allowed (the bitmap is read inside its bounds only)
+        ok = j < nblk
+        if sparse:
+            ok = jnp.logical_and(
+                ok, allow_ref[sq, jnp.minimum(j, n_slots - 1)] != 0)
+        return ok
+
+    def load(sq, j):
         blk = _arena_block(tbl_ref[sq, j], n_blk)
-        pltpu.make_async_copy(k_any.at[blk], bufk.at[bufset, buf_slot],
-                              lsem.at[bufset, buf_slot, 0]).start()
-        pltpu.make_async_copy(v_any.at[blk], bufv.at[bufset, buf_slot],
-                              lsem.at[bufset, buf_slot, 1]).start()
+        pltpu.make_async_copy(k_any.at[blk], bufk.at[sq % 2, j % ring],
+                              lsem.at[sq % 2, j % ring, 0]).start()
+        pltpu.make_async_copy(v_any.at[blk], bufv.at[sq % 2, j % ring],
+                              lsem.at[sq % 2, j % ring, 1]).start()
 
     def prefetch_first(sq):
         ctx = ctx_ref[sq]
-        jb = jbase_of(ctx)
-
-        @pl.when(jnp.logical_and(jb < nblk_of(ctx), allowed(sq, jb)))
-        def _():
-            load(sq, sq % 2, jb, jb % 2)
+        jb, nb = jbase_of(ctx), nblk_of(ctx)
+        for j in range(ring - 1):
+            pl.when(wanted(sq, jb + j, nb))(
+                functools.partial(load, sq, jb + j))
 
     @pl.when(s == 0)
     def _prefetch_self():
         prefetch_first(0)
 
     @pl.when(s + 1 < n_seqs)
-    def _prefetch_next_seq():
+    def _prefetch_next_row():
         prefetch_first(s + 1)
 
     ctx = ctx_ref[s]
-    slot = slot_ref[s]
-    L = jnp.maximum(ctx - 1, 0)      # old tokens in the cache
+    cached = cached_of(ctx)
+    nblk = nblk_of(ctx)
     bufset = s % 2
 
     def body(j, carry):
         ms, ls, accs = carry  # per-head tuples: (Gp,1),(Gp,1),(Gp,D)
-        bslot = j % 2
+        bslot = j % ring
 
-        @pl.when(jnp.logical_and(j + 1 < nblk_of(ctx), allowed(s, j + 1)))
-        def _prefetch_next():
-            load(s, bufset, j + 1, (j + 1) % 2)
+        @pl.when(wanted(s, j + ring - 1, nblk))
+        def _prefetch_ahead():
+            load(s, j + ring - 1)
 
-        ok = allowed(s, j)
+        def wait():
+            pltpu.make_async_copy(k_any.at[0], bufk.at[bufset, bslot],
+                                  lsem.at[bufset, bslot, 0]).wait()
+            pltpu.make_async_copy(v_any.at[0], bufv.at[bufset, bslot],
+                                  lsem.at[bufset, bslot, 1]).wait()
+
         cols = j * bs + jax.lax.broadcasted_iota(jnp.int32, (gp, bs), 1)
-        live = cols < L
+        live = cols < cached
         if window > 0:
             live = jnp.logical_and(live, cols >= ctx - window)
         if sparse:
             # a disallowed block has no in-flight DMA: don't wait, and
             # mask every column so the accumulators pass through
+            ok = allow_ref[s, j] != 0
             live = jnp.logical_and(live, ok)
-
-            @pl.when(ok)
-            def _wait_allowed():
-                pltpu.make_async_copy(k_any.at[0], bufk.at[bufset, bslot],
-                                      lsem.at[bufset, bslot, 0]).wait()
-                pltpu.make_async_copy(v_any.at[0], bufv.at[bufset, bslot],
-                                      lsem.at[bufset, bslot, 1]).wait()
+            pl.when(ok)(wait)
         else:
-            pltpu.make_async_copy(k_any.at[0], bufk.at[bufset, bslot],
-                                  lsem.at[bufset, bslot, 0]).wait()
-            pltpu.make_async_copy(v_any.at[0], bufv.at[bufset, bslot],
-                                  lsem.at[bufset, bslot, 1]).wait()
+            wait()
         kb = bufk[bufset, bslot]  # (bs, KV, D)
         vb = bufv[bufset, bslot]
         ms2, ls2, accs2 = [], [], []
         for h in range(n_kv):
             q = q_ref[s, h]  # (Gp, D)
             st = _dot(q, kb[:, h, :], trans_b=True) * scale  # (Gp, bs)
-            if alibi:
+            if ab_ref is not None:
+                # bias slope_h * key_pos: exact up to the per-row shift
+                # softmax cancels (single query at position ctx-1)
                 st = st + ab_ref[h, :][:, None] * cols.astype(jnp.float32)
             st = jnp.where(live, st, NEG_INF)
             m_new = jnp.maximum(ms[h], jnp.max(st, axis=1, keepdims=True))
@@ -668,8 +723,68 @@ def _decode_fused_kernel(
         tuple(jnp.zeros((gp, 1), jnp.float32) for _ in range(n_kv)),
         tuple(jnp.zeros((gp, D), jnp.float32) for _ in range(n_kv)),
     )
-    ms, ls, accs = jax.lax.fori_loop(jbase_of(ctx), nblk_of(ctx),
-                                     body, init)
+    return jax.lax.fori_loop(jbase_of(ctx), nblk, body, init)
+
+
+def _store_row(o_ref, s, ls, accs):
+    """Normalise row s's accumulators into o_ref[s]; a row that saw no
+    column (sum 0: batch padding) stores zeros."""
+    for h in range(len(ls)):
+        l_safe = jnp.where(ls[h] == 0.0, 1.0, ls[h])
+        o_ref[s, h] = (accs[h] / l_safe).astype(o_ref.dtype)
+
+
+def _decode_rows_kernel(
+    tbl_ref, ctx_ref, allow_ref,                    # scalar prefetch
+    q_ref, k_any, v_any,                            # inputs (caches in HBM)
+    *rest,                                          # [ab], out, scratch
+    alibi: bool, **walk,
+):
+    """The shared-table decode attention: attend only. Every row is
+    already in the cache (paged_kv_write ran first), so rows that share
+    one table — a prefill chunk's rows, ctx rising by one — read the
+    same blocks without racing a write."""
+    if alibi:  # [KV, Gp] ALiBi slope table rides as the LAST input
+        ab_ref, o_ref, bufk, bufv, lsem = rest
+    else:
+        o_ref, bufk, bufv, lsem = rest
+        ab_ref = None
+    s = pl.program_id(0)
+    _, ls, accs = _walk_live_blocks(
+        s, tbl_ref, ctx_ref, allow_ref, q_ref, k_any, v_any, ab_ref,
+        bufk, bufv, lsem, new_col=False, **walk)
+    _store_row(o_ref, s, ls, accs)
+
+
+def _decode_fused_kernel(
+    tbl_ref, ctx_ref, slot_ref, allow_ref,          # scalar prefetch
+    q_ref, kn_ref, vn_ref, k_any, v_any,            # inputs (caches in HBM)
+    *rest,                                          # [ab], outs, scratch
+    alibi: bool, **walk,
+):
+    """One grid step per SEQUENCE (compile size O(1) in batch — an
+    earlier all-sequences-unrolled variant ran ~8us/call faster at S=8
+    but its Mosaic compile exploded at S=64). The KV arenas stay in HBM
+    (memory_space=ANY) and _walk_live_blocks reads ONLY the live blocks
+    of this sequence's table; the new token's row is DMA'd straight
+    into its cache slot (2 KB, vs RMW-ing whole 256 KB blocks through
+    the output pipeline), and its attention contribution enters as one
+    extra online-softmax column from VMEM."""
+    if alibi:  # [KV, Gp] ALiBi slope table rides as the LAST input
+        ab_ref, o_ref, ck_any, cv_any, bufk, bufv, wsem, lsem = rest
+    else:
+        o_ref, ck_any, cv_any, bufk, bufv, wsem, lsem = rest
+        ab_ref = None
+    n_seqs, bs = walk["n_seqs"], walk["block_size"]
+    scale, n_kv = walk["scale"], walk["n_kv"]
+    n_blk = k_any.shape[0]
+    s = pl.program_id(0)
+    ctx = ctx_ref[s]
+    slot = slot_ref[s]
+
+    ms, ls, accs = _walk_live_blocks(
+        s, tbl_ref, ctx_ref, allow_ref, q_ref, k_any, v_any, ab_ref,
+        bufk, bufv, lsem, new_col=True, **walk)
 
     if alibi:
         # fold the new token's ALiBi bias into its online-softmax column
@@ -712,10 +827,7 @@ def _decode_fused_kernel(
 
     ms, ls, accs = jax.lax.cond(slot >= 0, newcol, lambda c: c,
                                 (ms, ls, accs))
-
-    for h in range(n_kv):
-        l_safe = jnp.where(ls[h] == 0.0, 1.0, ls[h])
-        o_ref[s, h] = (accs[h] / l_safe).astype(o_ref.dtype)
+    _store_row(o_ref, s, ls, accs)
 
     @pl.when(s == n_seqs - 1)
     def _wait_rows():
@@ -728,6 +840,79 @@ def _decode_fused_kernel(
                                       wsem.at[sq, 0]).wait()
                 pltpu.make_async_copy(vn_ref.at[sq], cv_any.at[blk, off],
                                       wsem.at[sq, 1]).wait()
+
+
+# scoped VMEM the per-row walk may ask Mosaic for (the default, 16 MiB,
+# is met by 128 rows of 16 KV heads: 8 MiB of whole-array q + out and
+# 4 MiB of block buffers), and what its buffers may take of that
+_WALK_VMEM_LIMIT = 64 << 20
+_WALK_VMEM_BUDGET = 48 << 20
+
+
+def _walks_live_blocks(qg, k_cache) -> bool:
+    """Whether the per-row live-block walk can take these shapes; what
+    it cannot stays on the (S, NB) BlockSpec grid. The walk DMAs one
+    whole cache block (bs, KV, D) out of the HBM arena by hand, and
+    Mosaic takes such a slice only if its trailing dims fill whole
+    tiles (AOT compiles for v5e, libtpu 0.0.34: "Slice shape along
+    dimension 3 must be aligned to tiling (128)" at D = 64; "dimension
+    2 ... tiling (2) / (4) / (8)" for 16-bit pools of 1, 3, 5-7 or 12
+    KV heads, whose sublane tile is the power of two covering KV, at
+    most 8; 32-bit pools pass at any KV). BlockSpec tiles are padded by
+    the pipeline instead, so the grid takes every shape."""
+    _, bs, KV, D = k_cache.shape
+    itemsize = k_cache.dtype.itemsize
+    if D % 128 or itemsize not in (2, 4):
+        return False
+    if itemsize == 2 and KV not in (2, 4) and KV % 8:
+        return False
+    need = (4 * _RING * bs * KV * D * itemsize      # k, v x row parity
+            + 2 * qg.size * qg.dtype.itemsize)      # whole-array q, out
+    return need <= _WALK_VMEM_BUDGET
+
+
+def _attend_live_blocks(qg, ab, k_cache, v_cache, block_table, ctx_lens,
+                        window: int, allowed_slots, scale: float):
+    """paged_decode_attention's unfused, unquantised case on the per-row
+    walk: qg [S, KV, Gp, D] grouped queries, ab the [KV, Gp] ALiBi table
+    or None -> [S, KV, Gp, D]."""
+    S, KV, Gp, D = qg.shape
+    bs = k_cache.shape[1]
+    sparse = allowed_slots is not None
+    # dense: the bitmap is never read; a (1, 1) stand-in keeps SMEM free
+    allow = (allowed_slots.astype(jnp.int32) if sparse
+             else jnp.zeros((1, 1), jnp.int32))
+    vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(S,),
+        in_specs=[vmem, hbm, hbm] + ([vmem] if ab is not None else []),
+        out_specs=vmem,
+        scratch_shapes=[
+            pltpu.VMEM((2, _RING, bs, KV, D), k_cache.dtype),
+            pltpu.VMEM((2, _RING, bs, KV, D), v_cache.dtype),
+            pltpu.SemaphoreType.DMA((2, _RING, 2)),
+        ],
+    )
+    return pl.pallas_call(
+        functools.partial(
+            _decode_rows_kernel, n_seqs=S, block_size=bs, scale=scale,
+            n_kv=KV, gp=Gp, window=window, sparse=sparse,
+            alibi=ab is not None,
+        ),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(qg.shape, qg.dtype),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_WALK_VMEM_LIMIT),
+        interpret=interpret(),
+        # the trace name of THE SHARED-TABLE DECODE ATTENTION, whatever
+        # its grid: the benchmark's readers, chip_smoke.py and the AOT
+        # tests find the program by it (the int8 and fused-write cases,
+        # still on the (S, NB) grid, carry the same name)
+        name="paged_decode_grid",
+    )(block_table, ctx_lens, allow, qg, k_cache, v_cache,
+      *(() if ab is None else (ab,)))
 
 
 def supports_fused_v2(head_dim: int) -> bool:
@@ -761,23 +946,13 @@ def paged_decode_fused(q, k_cache, v_cache, block_table, ctx_lens,
     S, H, D = q.shape
     NBLK, bs, KV, _ = k_cache.shape
     NB = block_table.shape[1]
-    G = H // KV
-    Gp = max(G, 8)
     scale = 1.0 / (D**0.5)
     sparse = allowed_slots is not None
     alibi = alibi_slopes is not None
     allow = (allowed_slots.astype(jnp.int32) if sparse
              else jnp.zeros((S, NB), jnp.int32))
-
-    qg = q.reshape(S, KV, G, D)
-    if Gp != G:
-        qg = jnp.pad(qg, ((0, 0), (0, 0), (0, Gp - G), (0, 0)))
-    ab = ()
-    if alibi:
-        ab_arr = jnp.asarray(alibi_slopes, jnp.float32).reshape(KV, G)
-        if Gp != G:
-            ab_arr = jnp.pad(ab_arr, ((0, 0), (0, Gp - G)))
-        ab = (ab_arr,)
+    qg, ab, G, Gp = _group_queries(q, KV, alibi_slopes)
+    ab = (ab,) if alibi else ()
 
     vmem = lambda: pl.BlockSpec(memory_space=pltpu.VMEM)
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -794,10 +969,10 @@ def paged_decode_fused(q, k_cache, v_cache, block_table, ctx_lens,
             pl.BlockSpec(memory_space=pl.ANY),
         ],
         scratch_shapes=[
-            pltpu.VMEM((2, 2, bs, KV, D), k_cache.dtype),
-            pltpu.VMEM((2, 2, bs, KV, D), v_cache.dtype),
+            pltpu.VMEM((2, _RING, bs, KV, D), k_cache.dtype),
+            pltpu.VMEM((2, _RING, bs, KV, D), v_cache.dtype),
             pltpu.SemaphoreType.DMA((S, 2)),
-            pltpu.SemaphoreType.DMA((2, 2, 2)),
+            pltpu.SemaphoreType.DMA((2, _RING, 2)),
         ],
     )
     out, ck, cv = pl.pallas_call(
@@ -813,6 +988,8 @@ def paged_decode_fused(q, k_cache, v_cache, block_table, ctx_lens,
         ],
         # args: 4 scalar prefetch, q, kn, vn, k_cache, v_cache [, ab]
         input_output_aliases={7: 1, 8: 2},
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_WALK_VMEM_LIMIT),
         interpret=interpret(),
         name="paged_decode_fused",
     )(block_table, ctx_lens, slots.astype(jnp.int32), allow, qg,

@@ -1005,10 +1005,9 @@ def _compat_filter(config: Dict[str, Any]) -> Dict[str, Any]:
     config.pop("hybrid_engine", None)
     if "sparse_attention" in config and _enabled(config.get("sparse_attention")):
         raise NotImplementedError(
-            "the sparse_attention config block has no engine-level consumer "
-            "(models are functional here); enable it on the model instead: "
-            "TransformerConfig(attention_impl='sparse', sparse_mode=..., "
-            "sparse_block=...)"
+            "the sparse_attention config block is not carried: block-sparse "
+            "attention layouts are not implemented here (sliding windows "
+            "are TransformerConfig(sliding_window=...))"
         )
     config.pop("sparse_attention", None)
     present = [b for b in _UNIMPLEMENTED_BLOCKS

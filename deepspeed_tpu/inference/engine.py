@@ -271,24 +271,6 @@ class InferenceEngine:
         self._use_kernel = kernel_layout and (
             self.config.decode_impl == "pallas"
             or (self.config.decode_impl == "auto" and kernels_runnable()))
-        if model_config.attention_impl == "sparse":
-            # sparse-trained models serve with the train-time block layout
-            # reproduced exactly (inference/model.py _sparsity). Decode
-            # runs the Pallas kernel with a per-slot layout bitmap when
-            # cache blocks nest inside layout blocks (and no TP mesh);
-            # otherwise the XLA paged path carries the per-position mask.
-            kernel_ok = (
-                self._use_kernel
-                and model_config.sparse_block % self.config.kv_block_size == 0
-                and self.mesh is None
-            )
-            log_dist(
-                "serving block-sparse attention "
-                f"(mode={model_config.sparse_mode}); decode uses the "
-                f"{'Pallas layout-masked' if kernel_ok else 'XLA'} paged "
-                "path",
-                ranks=[0],
-            )
         if model_config.use_learned_pos:
             # prefill pads prompts up to a power-of-two bucket, and every
             # padded position indexes the learned position table — so the

@@ -46,11 +46,9 @@ from ..ops.attention import causal_attention
 from ..ops.pallas.paged_attention import (
     paged_decode_attention,
     paged_decode_attention_xla,
-    paged_decode_fused,
     paged_kv_write,
     paged_scale_write,
     quantize_kv_rows,
-    supports_fused_v2,
 )
 from .quantization import ChannelQuantWeight, channel_quantize
 
@@ -354,8 +352,9 @@ def init_cache(
 
 
 def _rope_at(x, positions, cfg: T.TransformerConfig):
-    """Rotary embedding at per-token positions [T] (decode needs a
-    different position per row, unlike training's contiguous offset).
+    """Rotary embedding of x [..., T, H, D] at per-token positions [T]
+    (decode needs a different position per row, unlike training's
+    contiguous offset; prefill's prompts share one [Tp]).
     Frequencies come from T.rope_inv_freq so long-context scaling
     (linear / llama3) and partial rotary (Phi) match the training
     forward exactly."""
@@ -466,72 +465,28 @@ def _write_kv_quant(cache_k, cache_v, k_scale, v_scale, k_new, v_new,
     return ck, cv, cks, cvs
 
 
-def _sparsity(cfg: T.TransformerConfig):
-    """SparsityConfig for a sparse-trained model, else None. Layouts are
-    deterministic (seeded), so serving reproduces the train-time block
-    mask exactly — including bigbird/variable random blocks."""
-    if cfg.attention_impl != "sparse":
-        return None
-    return cfg.sparsity_config()
+def _layer_pools(cache: PagedCache, li: int) -> tuple:
+    """One layer's pools in PagedCache's field order: (k, v), and
+    (k, v, k_scale, v_scale) of a quantised cache. What `attend` hands
+    back per layer and _forward zips into the new PagedCache."""
+    return tuple(pool[li] for pool in cache if pool is not None)
 
 
-def _sparse_prefill_mask(scfg, Tp: int) -> jnp.ndarray:
-    """Static [Tp, Tp] bool token mask from the block layout (causality
-    included). Tp is a compiled-shape constant, so this is trace-time
-    numpy, not device work."""
-    import numpy as np
-
-    nb = -(-Tp // scfg.block)
-    lay = scfg.layout(nb * scfg.block)  # [nb, nb]
-    blk = np.arange(Tp) // scfg.block
-    mask = lay[np.ix_(blk, blk)] & (np.arange(Tp)[None, :] <= np.arange(Tp)[:, None])
-    return jnp.asarray(mask)
-
-
-def _masked_causal_attention(q, k, v, mask):
-    """[B,S,H,D] attention under an explicit [S,S] token mask — the
-    serving path for sparse-trained models (same masked-softmax math as
-    ops/sparse_attention.sparse_causal_attention, without the gather)."""
-    from ..ops.attention import _repeat_kv
-
-    B, S, H, D = q.shape
-    rep = q.shape[2] // k.shape[2]  # GQA
-    k = _repeat_kv(k, rep)
-    v = _repeat_kv(v, rep)
-    logits = jnp.einsum("bqhd,bkhd->bhqk", q, k) / (D**0.5)
-    logits = jnp.where(mask[None, None], logits.astype(jnp.float32), -jnp.inf)
-    p = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
-    return jnp.einsum("bhqk,bkhd->bqhd", p, v)
-
-
-def _sparse_decode_allowed(scfg, positions, n_slots: int) -> jnp.ndarray:
-    """[S, n_slots] bool: which absolute kv positions each decode row may
-    attend to under its layout row (block of the row's own position).
-    Layout rows are prefix-stable, so the table built for the cache span
-    matches the train-time layout of any shorter sequence."""
-    import numpy as np
-
-    sblk = scfg.block
-    nb = -(-n_slots // sblk)
-    lay = jnp.asarray(scfg.layout(nb * sblk))  # [nb, nb] (trace-time numpy)
-    q_blk = positions // sblk  # [S] traced
-    rows = lay[q_blk]  # [S, nb]
-    kv_blk = jnp.arange(n_slots) // sblk  # [n_slots]
-    return rows[:, kv_blk]
-
-
-def _sparse_decode_allowed_slots(scfg, positions, n_blocks: int,
-                                 bs: int) -> jnp.ndarray:
-    """[S, NB] bool at CACHE-BLOCK granularity for the Pallas decode
-    kernel's layout mask (scalar prefetch). Valid only when
-    scfg.block % bs == 0 — then every cache block lies inside exactly
-    one layout block, so the block-granular skip is exact."""
-    sblk = scfg.block
-    nb_sparse = -(-(n_blocks * bs) // sblk)
-    lay = jnp.asarray(scfg.layout(nb_sparse * sblk))
-    rows = lay[positions // sblk]  # [S, nb_sparse]
-    slot_sparse = (jnp.arange(n_blocks) * bs) // sblk  # [NB]
-    return rows[:, slot_sparse]
+def _write_pools(pools: tuple, k_new, v_new, flat_idx, mesh=None,
+                 use_kernel: bool = True) -> tuple:
+    """One layer's pools (_layer_pools) with [T, KV, D] new rows written
+    at flat slots [T], every pool constrained to its KV-head sharding.
+    Both serving sites write through here (decode unless it fuses the
+    write into the attention call)."""
+    if len(pools) == 4:
+        ck, cv, *scales = _write_kv_quant(*pools, k_new, v_new, flat_idx,
+                                          mesh, use_kernel)
+        scales = [_cons(sc, mesh, None, None, "model") for sc in scales]
+    else:
+        ck, cv = _write_kv(*pools, k_new, v_new, flat_idx, mesh, use_kernel)
+        scales = []
+    return (_cons(ck, mesh, None, None, "model", None),
+            _cons(cv, mesh, None, None, "model", None), *scales)
 
 
 # Rows an expert sees (T x k / X, static in a compiled program) between
@@ -699,8 +654,8 @@ def _mlp(h, lp, cfg: T.TransformerConfig, census_cb=None):
 
 def _ffn_residual(x, attn_out, h1, lp, cfg: T.TransformerConfig,
                   census_cb=None):
-    """The tail of one layer over [..., E] activations, for both serving
-    sites: the attention residual, norm2 and the FFN (sequential, or
+    """The tail of one layer over [..., E] activations: the attention
+    residual, norm2 and the FFN (sequential, or
     the Falcon/Phi parallel form where the FFN reads ln2(x) or the
     shared ln1 output h1), under the scopes the training forward
     names (`norm2`, `mlp`)."""
@@ -742,118 +697,142 @@ def _moe_residual(out, h, lp, cfg: T.TransformerConfig, act):
             + dense * coef[:, 1:2].astype(h.dtype))
 
 
-def _decode_attention(q, ck, cv, table, ctx, use_kernel: bool, allowed=None,
-                      allowed_slots=None, window: int = 0, mesh=None,
-                      k_new=None, v_new=None, slots=None, alibi=None,
-                      k_scale=None, v_scale=None):
-    """k_new/v_new/slots non-None selects the FUSED write+attend kernel
-    (single-token decode rows; ck/cv are the PRE-write arenas and the
-    returned (att, ck, cv) includes the in-kernel RMW). Without them —
-    the shared-table program, whose rows were written by paged_kv_write
-    first — paged_decode_attention attends only, and picks its kernel
-    from what it is handed: bf16/f32 pools at head dims that are a
-    multiple of 128 take the per-row live-block walk (each row reads
-    its live blocks, nothing else; also per shard under a 'model'
-    mesh), int8 pools and other block shapes keep the (S, NB) grid.
-    Both are `paged_decode_grid` in a trace.
+def _decode_attention(q, pools: tuple, table, ctx, use_kernel: bool,
+                      window: int = 0, mesh=None, alibi=None,
+                      k_new=None, v_new=None, slots=None):
+    """WHERE one decode attention call over a layer's pools
+    (_layer_pools) runs. Which Pallas kernel serves it is
+    paged_decode_attention's business, read there from the same
+    arguments (k_new/v_new/slots: the write fused into the call, the
+    pools PRE-write and the result (att, *updated pools); scale pools:
+    int8 KV; window; alibi, the [H] per-head slopes). One of three
+    places:
 
-    alibi: optional [H] per-head slopes (Bloom-class) — every path below
-    biases scores by slope_h * key_pos (exact per single query row).
-
-    k_scale/v_scale non-None selects the int8-KV paths: ck/cv hold int8
-    codes, the per-block scale tiles ride every branch next to their
-    code pools, and fused mode additionally returns the updated scale
-    pools (att, ck, cv, cks, cvs). The quantized fused path runs the
-    (S, NB)-grid kernel — the v2 manual-DMA kernel stays bf16-only."""
-    fused = k_new is not None
-    quant = k_scale is not None
-    if allowed_slots is not None and use_kernel and _tp_size(mesh) <= 1:
-        # block-sparse serving on the Pallas kernels: the layout rides
-        # in as a per-slot bitmap. The per-row walk (fused v2, and the
-        # unfused attention) never issues a pruned slot's DMA; the
-        # (S, NB)-grid kernel clamps it to a resident tile (still no
-        # fresh DMA, but a grid step each).
-        if fused and not quant and supports_fused_v2(q.shape[-1]):
-            return paged_decode_fused(q, ck, cv, table, ctx,
-                                      k_new, v_new, slots, window=window,
-                                      allowed_slots=allowed_slots,
-                                      alibi_slopes=alibi)
-        return paged_decode_attention(q, ck, cv, table, ctx, window=window,
-                                      allowed_slots=allowed_slots,
-                                      k_new=k_new, v_new=v_new, slots=slots,
-                                      alibi_slopes=alibi,
-                                      k_scale=k_scale, v_scale=v_scale)
-    if allowed is not None:
-        # layout finer than the cache blocks (or TP mesh): XLA path with
-        # the per-position mask. (window is passed through for
-        # completeness — the config forbids sparse+sliding_window, so
-        # both masks never actually combine today.)
-        assert not fused
-        return paged_decode_attention_xla(q, ck, cv, table, ctx,
-                                          allowed=allowed, window=window,
-                                          alibi_slopes=alibi,
-                                          k_scale=k_scale, v_scale=v_scale)
+    - one device (use_kernel, no 'model' axis): the kernel entry as it
+      is, the only place a fused write can run;
+    - per shard under a 'model' mesh whose Q and KV heads both divide:
+      ONE shard_map over the head dims (scale tiles and slopes shard
+      with their heads) around the kernel entry, or around the oracle
+      when use_kernel is false;
+    - the XLA oracle: use_kernel false, or heads that do not divide (a
+      raw pallas_call cannot consume sharded operands; SPMD partitions
+      the gather freely)."""
+    ck, cv, *scales = pools
+    opt = dict(zip(("k_scale", "v_scale"), scales))  # what is present
+    if alibi is not None:
+        opt["alibi_slopes"] = jnp.asarray(alibi, jnp.float32)
     tp = _tp_size(mesh)
-    H, KV = q.shape[1], ck.shape[2]
-    if tp > 1 and H % tp == 0 and KV % tp == 0:
-        # heads are device-local: run the kernel (or its oracle) per shard
-        assert not fused
-        fn = partial(paged_decode_attention if use_kernel
-                     else paged_decode_attention_xla, window=window)
+    if use_kernel and tp <= 1:
+        return paged_decode_attention(q, ck, cv, table, ctx, window=window,
+                                      k_new=k_new, v_new=v_new, slots=slots,
+                                      **opt)
+    assert k_new is None, "the fused write runs on one device only"
+    fn = partial(paged_decode_attention if use_kernel
+                 else paged_decode_attention_xla, window=window)
+    if tp > 1 and q.shape[1] % tp == 0 and ck.shape[2] % tp == 0:
         qs = P(None, "model", None)
         kv = P(None, None, "model", None)
-        sp = P(None, None, "model")  # scale tiles shard with the heads
-        if quant:
-            if alibi is not None:
-                wrapped = (lambda q_, k_, v_, t_, c_, ks_, vs_, ab_:
-                           fn(q_, k_, v_, t_, c_, k_scale=ks_, v_scale=vs_,
-                              alibi_slopes=ab_))
-                return _shard_map_kernel(
-                    wrapped, mesh,
-                    in_specs=(qs, kv, kv, P(None, None), P(None), sp, sp,
-                              P("model")),
-                    out_specs=qs,
-                )(q, ck, cv, table, ctx, k_scale, v_scale,
-                  jnp.asarray(alibi, jnp.float32))
-            wrapped = (lambda q_, k_, v_, t_, c_, ks_, vs_:
-                       fn(q_, k_, v_, t_, c_, k_scale=ks_, v_scale=vs_))
-            return _shard_map_kernel(
-                wrapped, mesh,
-                in_specs=(qs, kv, kv, P(None, None), P(None), sp, sp),
-                out_specs=qs,
-            )(q, ck, cv, table, ctx, k_scale, v_scale)
-        if alibi is not None:
-            # slopes shard with the heads (each device biases its own)
-            wrapped = (lambda q_, k_, v_, t_, c_, ab_:
-                       fn(q_, k_, v_, t_, c_, alibi_slopes=ab_))
-            return _shard_map_kernel(
-                wrapped, mesh,
-                in_specs=(qs, kv, kv, P(None, None), P(None), P("model")),
-                out_specs=qs,
-            )(q, ck, cv, table, ctx, jnp.asarray(alibi, jnp.float32))
+        specs = dict(k_scale=P(None, None, "model"),
+                     v_scale=P(None, None, "model"),
+                     alibi_slopes=P("model"))
         return _shard_map_kernel(
-            fn, mesh,
-            in_specs=(qs, kv, kv, P(None, None), P(None)),
+            lambda q_, k_, v_, t_, c_, *rest: fn(
+                q_, k_, v_, t_, c_, **dict(zip(opt, rest))),
+            mesh,
+            in_specs=(qs, kv, kv, P(None, None), P(None),
+                      *(specs[n] for n in opt)),
             out_specs=qs,
-        )(q, ck, cv, table, ctx)
-    if use_kernel and tp <= 1:
-        if fused and not quant and supports_fused_v2(q.shape[-1]):
-            # per-sequence grid + manual block DMA: the dense decode hot
-            # path (live blocks only, 2KB row writes instead of 256KB
-            # block RMW through the output pipeline)
-            return paged_decode_fused(q, ck, cv, table, ctx,
-                                      k_new, v_new, slots, window=window,
-                                      alibi_slopes=alibi)
-        return paged_decode_attention(q, ck, cv, table, ctx, window=window,
-                                      k_new=k_new, v_new=v_new, slots=slots,
-                                      alibi_slopes=alibi,
-                                      k_scale=k_scale, v_scale=v_scale)
-    # under a TP mesh with non-divisible heads, the XLA path lets SPMD
-    # partition freely (a raw pallas_call over sharded operands cannot)
-    assert not fused
+        )(q, ck, cv, table, ctx, *opt.values())
     return paged_decode_attention_xla(q, ck, cv, table, ctx, window=window,
-                                      alibi_slopes=alibi,
-                                      k_scale=k_scale, v_scale=v_scale)
+                                      **opt)
+
+
+# ---------------------------------------------------------------------------
+# the serving forward: one layer body, one prologue, one epilogue
+# ---------------------------------------------------------------------------
+
+def _layer(x, lp, li: int, positions, cfg: T.TransformerConfig, mesh, attend,
+           alibi, census_cb=None):
+    """One serving layer over [..., E] activations (decode rows [S, E],
+    prefill prompts [B, Tp, E]): norm1, the QKV projection (fused w_qkv
+    or split, bias or none), QK-norm, rope at `positions` (the
+    second-to-last axis of q/k: [S] or [Tp]), the head constraints,
+    `attend(q, k, v, li, alibi) -> (att, layer_cache)` handed in by the
+    caller (the ONE thing the two sites differ in: what attention runs
+    and how the new rows reach the cache), the output projection and
+    the FFN tail. Returns (x, layer_cache)."""
+    H, KV = cfg.n_heads, cfg.kv_heads
+    with jax.named_scope("norm1"):
+        h1 = T._act_quant(
+            T._norm(x, lp["ln1_scale"], lp.get("ln1_bias"), cfg), cfg)
+    with jax.named_scope("attention"):
+        if "w_qkv" in lp:
+            qkv = _wmm("...e,ehd->...hd", h1, lp["w_qkv"])
+            if "b_qkv" in lp:
+                qkv = qkv + lp["b_qkv"].astype(x.dtype)
+            q, k, v = jnp.split(qkv, [H, H + KV], axis=-2)
+        else:
+            q = _wmm("...e,ehd->...hd", h1, lp["wq"])
+            k = _wmm("...e,ehd->...hd", h1, lp["wk"])
+            v = _wmm("...e,ehd->...hd", h1, lp["wv"])
+            if "bq" in lp:
+                q = q + lp["bq"].astype(x.dtype)
+                k = k + lp["bk"].astype(x.dtype)
+                v = v + lp["bv"].astype(x.dtype)
+        q, k = T.qk_norm(q, k, lp, cfg)
+        if cfg.use_rope:
+            q = _rope_at(q, positions, cfg)
+            k = _rope_at(k, positions, cfg)
+        heads = (None,) * (q.ndim - 2) + ("model", None)
+        q = _cons(q, mesh, *heads)
+        k = _cons(k, mesh, *heads)
+        v = _cons(v, mesh, *heads)
+        att, layer_cache = attend(q, k, v, li, alibi)
+        out = _wmm("...hd,hde->...e", att, lp["wo"])
+        if "bo" in lp:
+            out = out + lp["bo"].astype(x.dtype)
+    return _ffn_residual(x, out, h1, lp, cfg, census_cb), layer_cache
+
+
+def _forward(params, tokens, positions, cfg: T.TransformerConfig, mesh,
+             attend, fetch_layer=None, census_cb=None, head_rows=None):
+    """The serving forward both sites run: tokens [...] int32 at
+    `positions` (their last axis) -> (f32 logits, the PagedCache the
+    layers' `attend` calls returned). Prologue (embedding, learned
+    positions, embedding norm, ALiBi slopes), _layer per layer (weights
+    through fetch_layer when offloaded), epilogue (head_rows picks the
+    rows the head runs on — prefill's last real tokens — then the final
+    norm and the head)."""
+    if not is_prepared(params):
+        params = prepare(params, cfg, fuse=mesh is None)
+    with jax.named_scope("embed"):
+        x = _embed_rows(params["embed"], tokens)
+        if cfg.use_learned_pos:
+            x = x + params["pos_embed"][positions].astype(x.dtype)
+        if cfg.embedding_layernorm:
+            x = T._norm(x, params["embed_ln_scale"],
+                        params.get("embed_ln_bias"), cfg)
+    alibi = (jnp.asarray(T.model_alibi_slopes(cfg)) if cfg.alibi
+             else None)
+
+    pools = []  # per layer, as _layer_pools
+    x_hist = []  # layer outputs; fetch l is barriered on output l-2
+    for li, lp in enumerate(params["layers"]):
+        if fetch_layer is not None:
+            lp = fetch_layer(lp, x_hist[-2] if len(x_hist) >= 2 else None,
+                             li)
+        x, layer_cache = _layer(x, lp, li, positions, cfg, mesh, attend,
+                                alibi, census_cb)
+        pools.append(layer_cache)
+        x_hist.append(x)
+
+    with jax.named_scope("lm_head"):
+        if head_rows is not None:
+            x = head_rows(x)
+        x = T._norm(x, params["ln_f_scale"], params.get("ln_f_bias"), cfg)
+        logits = _lm_logits(x, params, cfg)
+        logits = _cons(logits, mesh, None, None)
+    return logits, PagedCache(*(list(pool) for pool in zip(*pools)))
 
 
 # ---------------------------------------------------------------------------
@@ -885,44 +864,11 @@ def decode_step(
     are consumed, so HBM holds O(one layer) of weights instead of the
     model (ref: docs/_posts/2022-09-10-zero-inference.md full-offload
     mode; the engine builds it)."""
-    S = tokens.shape[0]
-    if not is_prepared(params):
-        params = prepare(params, cfg, fuse=mesh is None)
-    H, KV, D, bs = cfg.n_heads, cfg.kv_heads, cfg.head_dim, cache.block_size
+    bs = cache.block_size
     # rows with ctx_lens == 0 are batch padding: their KV write is dropped
     # and their (garbage) logits are sliced off by the engine
     valid = ctx_lens > 0
     positions = jnp.maximum(ctx_lens - 1, 0)  # [S] this token's position
-    scfg = _sparsity(cfg)
-    allowed = allowed_slots = None
-    if scfg is not None:
-        if (use_kernel and _tp_size(mesh) <= 1
-                and scfg.block % cache.block_size == 0):
-            # cache blocks nest inside layout blocks → exact block-
-            # granular skip inside the Pallas kernel
-            allowed_slots = _sparse_decode_allowed_slots(
-                scfg, positions, tables.shape[1], cache.block_size)
-        else:
-            allowed = _sparse_decode_allowed(
-                scfg, positions, tables.shape[1] * cache.block_size)
-    with jax.named_scope("embed"):
-        x = _embed_rows(params["embed"], tokens)  # [S, E]
-        if cfg.use_learned_pos:
-            x = x + params["pos_embed"][positions].astype(x.dtype)
-        if cfg.embedding_layernorm:
-            x = T._norm(x, params["embed_ln_scale"],
-                        params.get("embed_ln_bias"), cfg)
-    alibi = (jnp.asarray(T.model_alibi_slopes(cfg)) if cfg.alibi
-             else None)
-
-    # fused write+attend only on the single-device kernel path (the
-    # shard_map TP path and the XLA fallbacks keep the separate write)
-    fuse_write = (
-        unique_rows and use_kernel and _tp_size(mesh) <= 1
-        and allowed is None
-    )
-    quant = cache.quantized
-
     # per-row flat slot: each row has its own table; padding rows
     # scatter to -1 which mode="drop" discards
     flat_idx = (
@@ -930,97 +876,24 @@ def decode_step(
         * bs + positions % bs
     )
     flat_idx = jnp.where(valid, flat_idx, jnp.int32(-1))
+    # the write fuses into the attention call only on the single-device
+    # kernel path (the shard_map TP path and the XLA oracle keep the
+    # separate write)
+    fuse_write = unique_rows and use_kernel and _tp_size(mesh) <= 1
 
-    new_k, new_v = [], []
-    new_ks, new_vs = [], []  # quantized caches: per-block scale pools
-    x_hist = []  # layer outputs; fetch l is barriered on output l-2
-    for li, lp in enumerate(params["layers"]):
-        if fetch_layer is not None:
-            lp = fetch_layer(lp, x_hist[-2] if len(x_hist) >= 2 else None,
-                             li)
-        with jax.named_scope("norm1"):
-            h1 = T._act_quant(
-                T._norm(x, lp["ln1_scale"], lp.get("ln1_bias"), cfg), cfg)
-        with jax.named_scope("attention"):
-            if "w_qkv" in lp:
-                qkv = _wmm("se,ehd->shd", h1, lp["w_qkv"])
-                if "b_qkv" in lp:
-                    qkv = qkv + lp["b_qkv"].astype(x.dtype)
-                q, k, v = jnp.split(qkv, [H, H + KV], axis=1)
-            else:
-                q = _wmm("se,ehd->shd", h1, lp["wq"])
-                k = _wmm("se,ehd->shd", h1, lp["wk"])
-                v = _wmm("se,ehd->shd", h1, lp["wv"])
-                if "bq" in lp:
-                    q = q + lp["bq"].astype(x.dtype)
-                    k = k + lp["bk"].astype(x.dtype)
-                    v = v + lp["bv"].astype(x.dtype)
-            q, k = T.qk_norm(q, k, lp, cfg)
-            if cfg.use_rope:
-                q = _rope_at(q, positions, cfg)
-                k = _rope_at(k, positions, cfg)
-            q = _cons(q, mesh, None, "model", None)
-            k = _cons(k, mesh, None, "model", None)
-            v = _cons(v, mesh, None, "model", None)
+    def attend(q, k, v, li, alibi):
+        where = (tables, ctx_lens, use_kernel, cfg.window_for_layer(li),
+                 mesh, alibi)
+        pools = _layer_pools(cache, li)
+        if fuse_write:
+            att, *pools = _decode_attention(q, pools, *where, k_new=k,
+                                            v_new=v, slots=flat_idx)
+            return att, pools
+        pools = _write_pools(pools, k, v, flat_idx, mesh, use_kernel)
+        return _decode_attention(q, pools, *where), pools
 
-            li_c = len(new_k)
-            ck_in, cv_in = cache.k[li_c], cache.v[li_c]
-            cks = cvs = None
-            if fuse_write:
-                if quant:
-                    att, ck, cv, cks, cvs = _decode_attention(
-                        q, ck_in, cv_in, tables, ctx_lens, use_kernel,
-                        allowed_slots=allowed_slots,
-                        window=cfg.window_for_layer(li),
-                        mesh=mesh, k_new=k, v_new=v, slots=flat_idx,
-                        alibi=alibi, k_scale=cache.k_scale[li_c],
-                        v_scale=cache.v_scale[li_c],
-                    )
-                else:
-                    att, ck, cv = _decode_attention(
-                        q, ck_in, cv_in, tables, ctx_lens, use_kernel,
-                        allowed_slots=allowed_slots,
-                        window=cfg.window_for_layer(li),
-                        mesh=mesh, k_new=k, v_new=v, slots=flat_idx,
-                        alibi=alibi,
-                    )
-            else:
-                if quant:
-                    ck, cv, cks, cvs = _write_kv_quant(
-                        ck_in, cv_in, cache.k_scale[li_c], cache.v_scale[li_c],
-                        k, v, flat_idx, mesh, use_kernel)
-                    cks = _cons(cks, mesh, None, None, "model")
-                    cvs = _cons(cvs, mesh, None, None, "model")
-                else:
-                    ck, cv = _write_kv(ck_in, cv_in, k, v, flat_idx, mesh,
-                                       use_kernel)
-                ck = _cons(ck, mesh, None, None, "model", None)
-                cv = _cons(cv, mesh, None, None, "model", None)
-                att = _decode_attention(
-                    q, ck, cv, tables, ctx_lens, use_kernel, allowed=allowed,
-                    allowed_slots=allowed_slots,
-                    window=cfg.window_for_layer(li), mesh=mesh, alibi=alibi,
-                    k_scale=cks, v_scale=cvs)
-            new_k.append(ck)
-            new_v.append(cv)
-            if quant:
-                new_ks.append(cks)
-                new_vs.append(cvs)
-            out = _wmm("shd,hde->se", att, lp["wo"])
-            if "bo" in lp:
-                out = out + lp["bo"].astype(x.dtype)
-
-        x = _ffn_residual(x, out, h1, lp, cfg, census_cb)
-        x_hist.append(x)
-
-    with jax.named_scope("lm_head"):
-        x = T._norm(x, params["ln_f_scale"], params.get("ln_f_bias"), cfg)
-        logits = _lm_logits(x, params, cfg)
-        logits = _cons(logits, mesh, None, None)
-    if quant:
-        return logits, PagedCache(k=new_k, v=new_v,
-                                  k_scale=new_ks, v_scale=new_vs)
-    return logits, PagedCache(k=new_k, v=new_v)
+    return _forward(params, tokens, positions, cfg, mesh, attend,
+                    fetch_layer, census_cb)
 
 
 def decode_multi(
@@ -1122,26 +995,8 @@ def prefill_batch(
     call. Rows with n_real == 0 are batch padding (garbage logits,
     sliced by the caller; their KV writes drop)."""
     B, Tp = tokens.shape
-    if not is_prepared(params):
-        params = prepare(params, cfg, fuse=mesh is None)
-    H, KV = cfg.n_heads, cfg.kv_heads
     bs = cache.block_size
     positions = jnp.arange(Tp, dtype=jnp.int32)
-    scfg = _sparsity(cfg)
-    sparse_mask = (
-        _sparse_prefill_mask(scfg, Tp)
-        if scfg is not None and Tp % scfg.block != 0 else None
-    )
-    with jax.named_scope("embed"):
-        x = _embed_rows(params["embed"], tokens)  # [B, Tp, E]
-        if cfg.use_learned_pos:
-            x = x + params["pos_embed"][:Tp].astype(x.dtype)[None]
-        if cfg.embedding_layernorm:
-            x = T._norm(x, params["embed_ln_scale"],
-                        params.get("embed_ln_bias"), cfg)
-    alibi = (jnp.asarray(T.model_alibi_slopes(cfg)) if cfg.alibi
-             else None)
-
     # per-row flat cache slots for the real tokens; -1 rows drop
     flat_idx = jnp.where(
         positions[None, :] < n_real[:, None],
@@ -1151,118 +1006,43 @@ def prefill_batch(
         jnp.int32(-1),
     ).reshape(B * Tp)
 
-    quant = cache.quantized
-    new_k, new_v = [], []
-    new_ks, new_vs = [], []  # quantized caches: per-block scale pools
-    x_hist = []  # layer outputs; fetch l is barriered on output l-2
-    for li, lp in enumerate(params["layers"]):
-        if fetch_layer is not None:
-            lp = fetch_layer(lp, x_hist[-2] if len(x_hist) >= 2 else None,
-                             li)
-        with jax.named_scope("norm1"):
-            h1 = T._act_quant(
-                T._norm(x, lp["ln1_scale"], lp.get("ln1_bias"), cfg), cfg)
-        with jax.named_scope("attention"):
-            if "w_qkv" in lp:
-                qkv = _wmm("bse,ehd->bshd", h1, lp["w_qkv"])
-                if "b_qkv" in lp:
-                    qkv = qkv + lp["b_qkv"].astype(x.dtype)
-                q, k, v = jnp.split(qkv, [H, H + KV], axis=2)
-            else:
-                q = _wmm("bse,ehd->bshd", h1, lp["wq"])
-                k = _wmm("bse,ehd->bshd", h1, lp["wk"])
-                v = _wmm("bse,ehd->bshd", h1, lp["wv"])
-                if "bq" in lp:
-                    q = q + lp["bq"].astype(x.dtype)
-                    k = k + lp["bk"].astype(x.dtype)
-                    v = v + lp["bv"].astype(x.dtype)
-            q, k = T.qk_norm(q, k, lp, cfg)
-            if cfg.use_rope:
-                rot = jax.vmap(_rope_at, in_axes=(0, None, None))
-                q = rot(q, positions, cfg)
-                k = rot(k, positions, cfg)
-            q = _cons(q, mesh, None, None, "model", None)
-            k = _cons(k, mesh, None, None, "model", None)
-            v = _cons(v, mesh, None, None, "model", None)
+    def attend(q, k, v, li, alibi):
+        # the prompt's in-flight attention stays full precision (it
+        # never reads the cache); only the RESIDENT copy quantizes —
+        # later decode steps read these codes
+        pools = _write_pools(
+            _layer_pools(cache, li), k.reshape(B * Tp, *k.shape[2:]),
+            v.reshape(B * Tp, *v.shape[2:]), flat_idx, mesh, use_kernel)
+        flash = partial(causal_attention, window=cfg.window_for_layer(li))
+        if _heads_shardable(mesh, cfg):
+            # flash kernel per head-shard; GQA grouping stays
+            # device-local, slopes shard with their heads
+            hs = P(None, None, "model", None)
+            ab = () if alibi is None else (alibi,)
+            att = _shard_map_kernel(
+                lambda q_, k_, v_, *ab_: flash(
+                    q_, k_, v_, use_flash=use_kernel and cfg.use_flash,
+                    alibi=ab_[0] if ab_ else None),
+                mesh, in_specs=(hs, hs, hs) + (P("model"),) * len(ab),
+                out_specs=hs,
+            )(q, k, v, *ab)
+        else:
+            att = flash(
+                q, k, v,
+                # a raw pallas_call cannot consume TP-sharded operands
+                use_flash=(use_kernel and cfg.use_flash
+                           and _tp_size(mesh) <= 1),
+                alibi=alibi)
+        return att, pools
 
-            KVh, Dh = k.shape[2], k.shape[3]
-            l = len(new_k)
-            if quant:
-                # the prompt's in-flight attention below stays full
-                # precision (it never reads the cache); only the RESIDENT
-                # copy quantizes — later decode steps read these codes
-                ck, cv, cks, cvs = _write_kv_quant(
-                    cache.k[l], cache.v[l], cache.k_scale[l], cache.v_scale[l],
-                    k.reshape(B * Tp, KVh, Dh),
-                    v.reshape(B * Tp, KVh, Dh), flat_idx, mesh, use_kernel)
-                new_ks.append(_cons(cks, mesh, None, None, "model"))
-                new_vs.append(_cons(cvs, mesh, None, None, "model"))
-            else:
-                ck, cv = _write_kv(cache.k[l], cache.v[l],
-                                   k.reshape(B * Tp, KVh, Dh),
-                                   v.reshape(B * Tp, KVh, Dh), flat_idx, mesh,
-                                   use_kernel)
-            ck = _cons(ck, mesh, None, None, "model", None)
-            cv = _cons(cv, mesh, None, None, "model", None)
-            new_k.append(ck)
-            new_v.append(cv)
-
-            if scfg is not None and Tp % scfg.block == 0:
-                # block-gather path: FLOPs/memory scale with layout density,
-                # not Tp^2 (same computation the training forward runs)
-                from ..ops.attention import _repeat_kv
-                from ..ops.sparse_attention import sparse_causal_attention
-
-                rep = q.shape[2] // k.shape[2]  # GQA repeat, as in training
-                att = sparse_causal_attention(
-                    q, _repeat_kv(k, rep), _repeat_kv(v, rep), scfg
-                )
-            elif sparse_mask is not None:
-                # bucket shorter than a layout block: dense-with-mask fallback
-                att = _masked_causal_attention(q, k, v, sparse_mask)
-            elif _heads_shardable(mesh, cfg):
-                # flash kernel per head-shard; GQA grouping stays device-local
-                hs = P(None, None, "model", None)
-                if alibi is not None:
-                    att = _shard_map_kernel(
-                        lambda q_, k_, v_, ab_: causal_attention(
-                            q_, k_, v_, use_flash=use_kernel and cfg.use_flash,
-                            window=cfg.window_for_layer(li), alibi=ab_),
-                        mesh, in_specs=(hs, hs, hs, P("model")), out_specs=hs,
-                    )(q, k, v, alibi)
-                else:
-                    att = _shard_map_kernel(
-                        partial(causal_attention,
-                                use_flash=use_kernel and cfg.use_flash,
-                                window=cfg.window_for_layer(li)),
-                        mesh, in_specs=(hs, hs, hs), out_specs=hs,
-                    )(q, k, v)
-            else:
-                att = causal_attention(
-                    q, k, v,
-                    # a raw pallas_call cannot consume TP-sharded operands
-                    use_flash=(use_kernel and cfg.use_flash
-                               and _tp_size(mesh) <= 1),
-                    window=cfg.window_for_layer(li), alibi=alibi)
-            out = _wmm("bshd,hde->bse", att, lp["wo"])
-            if "bo" in lp:
-                out = out + lp["bo"].astype(x.dtype)
-
-        x = _ffn_residual(x, out, h1, lp, cfg, census_cb)
-        x_hist.append(x)
-
-    # logits for each prompt's last REAL token only (logits_gather):
-    # gather before the vocab matmul so the head runs on B tokens, not B*Tp
-    with jax.named_scope("lm_head"):
+    def last_real(x):
+        # logits for each prompt's last REAL token only (logits_gather):
+        # gather before the vocab matmul so the head runs on B tokens,
+        # not B*Tp
         last = jnp.maximum(n_real - 1, 0)  # [B]; padding rows read pos 0
-        x_last = jnp.take_along_axis(
+        return jnp.take_along_axis(
             x, last[:, None, None].astype(jnp.int32).repeat(x.shape[-1], axis=2),
             axis=1)[:, 0]
-        x_last = T._norm(x_last, params["ln_f_scale"],
-                         params.get("ln_f_bias"), cfg)
-        logits = _lm_logits(x_last, params, cfg)
-        logits = _cons(logits, mesh, None, None)
-    if quant:
-        return logits, PagedCache(k=new_k, v=new_v,
-                                  k_scale=new_ks, v_scale=new_vs)
-    return logits, PagedCache(k=new_k, v=new_v)
+
+    return _forward(params, tokens, positions, cfg, mesh, attend,
+                    fetch_layer, census_cb, head_rows=last_real)

@@ -46,9 +46,7 @@ class TransformerConfig:
     # "ulysses": seq↔head all-to-all resharding around local attention
     # (deepspeed/sequence/layer.py); "ring": KV rotation over the 'seq'
     # ring with online softmax (parallel/ring_attention.py) — better for
-    # very long sequences or heads < seq-parallel degree; "sparse":
-    # block-sparse layouts (ops/sparse_attention.py, ref
-    # ops/sparse_attention/sparsity_config.py) via the sparse_* knobs.
+    # very long sequences or heads < seq-parallel degree.
     attention_impl: str = "ulysses"
     # Token-exact sliding-window attention (Mistral-class; Mixtral = this
     # + n_experts). 0 disables. Applies to the ulysses impl; serving
@@ -59,16 +57,6 @@ class TransformerConfig:
     # are global. Overrides sliding_window; the pattern length must
     # divide n_layers (the scan groups layers by one pattern period).
     attention_window_pattern: Optional[Tuple[int, ...]] = None
-    sparse_block: int = 64
-    sparse_mode: str = "fixed"  # fixed | longformer | bigbird | dense | variable
-    sparse_num_local_blocks: int = 4
-    sparse_num_global_blocks: int = 1
-    sparse_num_random_blocks: int = 2
-    # variable-mode layout (ref: VariableSparsityConfig): per-window
-    # local sizes (last repeats) + explicit global block indices/ranges
-    sparse_local_window_blocks: Tuple[int, ...] = (4,)
-    sparse_global_block_indices: Tuple[int, ...] = (0,)
-    sparse_global_block_end_indices: Optional[Tuple[int, ...]] = None
     dropout: float = 0.0
     # QAT activation quantization (ref: compression/basic_layer.py
     # LinearLayer_Compress activation_quantization — there a forward hook
@@ -196,16 +184,15 @@ class TransformerConfig:
             raise ValueError(
                 f"unknown remat '{self.remat}' (expected one of {REMAT_MODES})"
             )
-        if self.attention_impl not in ("ulysses", "ring", "sparse"):
+        if self.attention_impl not in ("ulysses", "ring"):
             raise ValueError(
                 f"unknown attention_impl '{self.attention_impl}' "
-                "(expected ulysses|ring|sparse)"
+                "(expected ulysses|ring)"
             )
         if self.sliding_window > 0 and self.attention_impl != "ulysses":
             raise ValueError(
                 "sliding_window requires attention_impl='ulysses' (ring "
-                "rotates full KV; sparse expresses locality via its own "
-                "block layout)"
+                "rotates full KV)"
             )
         if self.variant not in ("llama", "gpt2"):
             raise ValueError(f"unknown variant '{self.variant}'")
@@ -257,8 +244,7 @@ class TransformerConfig:
         if self.alibi and self.attention_impl != "ulysses":
             raise ValueError(
                 "alibi requires attention_impl='ulysses' (ring rotates KV "
-                "without absolute-position bookkeeping for the bias; "
-                "sparse layouts express position via blocks)"
+                "without absolute-position bookkeeping for the bias)"
             )
         if self.alibi and self.rotary_pct < 1.0:
             raise ValueError("alibi replaces rotary embeddings entirely")
@@ -338,25 +324,6 @@ class TransformerConfig:
             d = int(self.d_model * 8 / 3)
             return ((d + 127) // 128) * 128
         return 4 * self.d_model
-
-    def sparsity_config(self):
-        """SparsityConfig assembled from the sparse_* knobs (one place —
-        the training forward and the serving engine must reproduce the
-        SAME layout)."""
-        from ..ops.sparse_attention import SparsityConfig
-
-        return SparsityConfig(
-            block=self.sparse_block, mode=self.sparse_mode,
-            num_local_blocks=self.sparse_num_local_blocks,
-            num_global_blocks=self.sparse_num_global_blocks,
-            num_random_blocks=self.sparse_num_random_blocks,
-            local_window_blocks=tuple(self.sparse_local_window_blocks),
-            global_block_indices=tuple(self.sparse_global_block_indices),
-            global_block_end_indices=(
-                tuple(self.sparse_global_block_end_indices)
-                if self.sparse_global_block_end_indices is not None else None
-            ),
-        )
 
     def flops_per_token(self, seq_len: Optional[int] = None) -> float:
         """Train-step matmul FLOPs per token for MFU accounting:
@@ -798,15 +765,6 @@ def _attention_delta(h, lp, cfg: TransformerConfig, rng=None, positions=None,
         k = _shard(k, DP, "seq", None, None)
         v = _shard(v, DP, "seq", None, None)
         out = ring_causal_attention(q, k, v, use_flash=cfg.use_flash)
-    elif cfg.attention_impl == "sparse":
-        from ..ops.sparse_attention import sparse_causal_attention
-
-        scfg = cfg.sparsity_config()
-        if q.shape[2] != k.shape[2]:  # GQA: repeat KV for the oracle path
-            rep = q.shape[2] // k.shape[2]
-            k = jnp.repeat(k, rep, axis=2)
-            v = jnp.repeat(v, rep, axis=2)
-        out = sparse_causal_attention(q, k, v, scfg)
     else:
         # Ulysses: re-shard seq→heads around attention; XLA emits the
         # all-to-all pair (ref: sequence/layer.py single_all_to_all:15).

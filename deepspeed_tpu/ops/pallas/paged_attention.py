@@ -16,8 +16,9 @@ bandwidth than a per-head grid). The trailing (KV, D) dims satisfy TPU
 cache[table[s, i]]; pages beyond a sequence's context are never
 streamed.
 
-Two ways to page, one per case (paged_decode_attention picks from the
-dtype and shape it is handed, nothing else):
+paged_decode_attention is the one entry for "attend these rows over
+this paged cache". Two ways to page, one per case (the entry picks from
+the arguments, dtype and shape it is handed, nothing else):
 
 - the per-row live-block walk (_walk_live_blocks): grid (seqs,), the
   arenas left in HBM, a fori_loop over the row's live blocks with
@@ -125,13 +126,12 @@ def _win_jbase_decode(ctx, window: int, block_size: int):
 
 
 def _decode_kernel(
-    tbl_ref, ctx_ref, allow_ref, slot_ref,  # scalar prefetch: [S, NB]
-    # block table, [S] ctx lens, [S, NB] allowed-slot bitmap (block-
-    # sparse; all-ones sentinel when dense), [S] write slots (fused
-    # write+attend; all -1 sentinel when not fused)
+    tbl_ref, ctx_ref, slot_ref,  # scalar prefetch: [S, NB] block table,
+    # [S] ctx lens, [S] write slots (fused write+attend; all -1
+    # sentinel when not fused)
     q_ref, *rest,
     block_size: int, scale: float, n_kv: int, gp: int, window: int,
-    sparse: bool, fused: bool, alibi: bool, quant: bool,
+    fused: bool, alibi: bool, quant: bool,
 ):
     # positional ref layout (mirrors paged_decode_attention's arg
     # order): q, [kn, vn], k, v, [ks, vs], [ab] | o, [ck, cv,
@@ -185,10 +185,6 @@ def _decode_kernel(
     else:
         j_abs = j
         needed = j * block_size < eff_ctx
-    if sparse:
-        # block-sparse layout row: slots outside the layout are skipped
-        # entirely (compute AND their DMA is clamped to a resident tile)
-        needed = jnp.logical_and(needed, allow_ref[s, j_abs] != 0)
 
     @pl.when(needed)
     def _compute():
@@ -331,24 +327,32 @@ def _group_queries(q, n_kv: int, alibi_slopes=None):
 
 
 def paged_decode_attention(q, k_cache, v_cache, block_table, ctx_lens,
-                           window: int = 0, allowed_slots=None,
+                           window: int = 0,
                            k_new=None, v_new=None, slots=None,
                            alibi_slopes=None, k_scale=None, v_scale=None):
-    """One-token-per-sequence attention over the paged KV cache.
+    """One-token-per-sequence attention over the paged KV cache: THE
+    entry for "attend these rows over this paged cache". Which kernel
+    runs is read from the arguments here and nowhere else, never from a
+    flag:
 
-    Which kernel runs is read from the arguments, never from a flag.
-    Unfused and unquantised (what the shared-table program calls after
-    paged_kv_write, rows of one prefill chunk sharing a table): the
-    per-row live-block walk, grid (S,) — each row reads the live blocks
-    of its table and nothing else, so the time follows the contexts and
-    not the table's width. On the (S, NB) BlockSpec grid stay int8 KV
-    (k_scale given: the scale tiles ride the index maps), the fused
-    write+attend below (head dims the v2 kernel cannot take), and
-    block shapes Mosaic refuses as a manual DMA (_walks_live_blocks:
-    D % 128 != 0; 16-bit pools whose KV count is not 2, 4 or a multiple
-    of 8). Either way the pallas_call is named `paged_decode_grid`: in
-    a trace that name means "the shared-table decode attention",
-    whatever its grid.
+    - k_new/v_new/slots given, unquantised, head dim a multiple of 128
+      (supports_fused_v2): paged_decode_fused, the per-row live-block
+      walk with the new row DMA'd into its slot;
+    - attend only, unquantised, a block shape Mosaic takes as a manual
+      DMA (_walks_live_blocks): the per-row live-block walk, grid (S,)
+      — each row reads the live blocks of its table and nothing else,
+      so the time follows the contexts and not the table's width. This
+      is what the shared-table program calls after paged_kv_write, rows
+      of one prefill chunk sharing a table;
+    - everything else on the (S, NB) BlockSpec grid: int8 KV (k_scale
+      given: the scale tiles ride the index maps), the fused write at
+      other head dims, and block shapes the walk cannot take
+      (D % 128 != 0; 16-bit pools whose KV count is not 2, 4 or a
+      multiple of 8).
+
+    The attend-only pallas_call is named `paged_decode_grid` whatever
+    its grid: in a trace that name means "the shared-table decode
+    attention". The walk with the write is `paged_decode_fused`.
 
     q: [S, H, D] (the new token's queries)
     k_cache/v_cache: [num_blocks, block_size, KV, D]
@@ -365,54 +369,62 @@ def paged_decode_attention(q, k_cache, v_cache, block_table, ctx_lens,
       sliced by the caller either way)
     window > 0: token-exact sliding window (Mistral-class serving) —
       only the ~window/block_size slots inside it are visited
-    allowed_slots: optional [S, NB] int32/bool — block-sparse serving:
-      slot j of sequence s participates only when nonzero (the layout
-      row at cache-block granularity; requires the sparse block size to
-      be a multiple of the cache block size so each cache block falls in
-      ONE layout block). Skipped slots cost no compute; the walk never
-      issues their load, the grid clamps their DMA to a resident tile.
     k_new/v_new [S, KV, D] + slots [S]: FUSED write+attend — the new
-      token's KV is folded into its target block in VMEM (attention sees
-      it) and the block is RMW'd back to the arena, replacing the
-      separate paged_kv_write call (which cost a second kernel launch
-      per layer; decode at small batch is launch-bound). Returns
-      (out, new_k_cache, new_v_cache) with the caches aliased in place.
-      REQUIRES: distinct sequences per row (no chunked-continuation
-      rows sharing a table — their writes would race across grid steps)
-      and pad rows (ctx 0 / slot -1) pointing at a reserved scratch
-      block, since each row's target block is written back even when
-      nothing changed. The write slot must be ctx-1's flat slot.
+      token's KV enters the cache inside the call (attention sees it),
+      replacing the separate paged_kv_write call (which cost a second
+      kernel launch per layer; decode at small batch is launch-bound).
+      Returns (out, new_k_cache, new_v_cache) with the caches aliased
+      in place. REQUIRES: distinct sequences per row (no
+      chunked-continuation rows sharing a table — their writes would
+      race across grid steps) and pad rows (ctx 0 / slot -1) pointing
+      at a reserved scratch block, since the grid writes each row's
+      target block back even when nothing changed. The write slot must
+      be ctx-1's flat slot.
     returns: [S, H, D] (fused: (out, k_cache, v_cache))
     """
     S, H, D = q.shape
-    NBLK, bs, KV, _ = k_cache.shape
-    NB = block_table.shape[1]
-    scale = 1.0 / (D**0.5)
-    sparse = allowed_slots is not None
+    KV = k_cache.shape[2]
     fused = k_new is not None
-    alibi = alibi_slopes is not None
     quant = k_scale is not None
+    if fused and not quant and supports_fused_v2(D):
+        return paged_decode_fused(q, k_cache, v_cache, block_table, ctx_lens,
+                                  k_new, v_new, slots, window=window,
+                                  alibi_slopes=alibi_slopes)
     qg, ab, G, Gp = _group_queries(q, KV, alibi_slopes)
+    scale = 1.0 / (D**0.5)
     if not fused and not quant and _walks_live_blocks(qg, k_cache):
         out = _attend_live_blocks(qg, ab, k_cache, v_cache, block_table,
-                                  ctx_lens, window, allowed_slots, scale)
+                                  ctx_lens, window, scale)
         return out[:, :, :G, :].reshape(S, H, D)
-    allow = (allowed_slots.astype(jnp.int32) if sparse
-             else jnp.ones((S, NB), jnp.int32))
+    out, *pools = _attend_grid(qg, ab, k_cache, v_cache, block_table,
+                               ctx_lens, window, scale, k_new, v_new, slots,
+                               k_scale, v_scale)
+    out = out[:, :, :G, :].reshape(S, H, D)
+    return (out, *pools) if fused else out
+
+
+def _attend_grid(qg, ab, k_cache, v_cache, block_table, ctx_lens,
+                 window: int, scale: float, k_new, v_new, slots,
+                 k_scale, v_scale):
+    """paged_decode_attention on the (S, NB) BlockSpec grid
+    (_decode_kernel): qg [S, KV, Gp, D] grouped queries, ab the
+    [KV, Gp] ALiBi table or None -> [out [S, KV, Gp, D], then the
+    updated pools when k_new is given (k, v, and the scale pools when
+    quantised)]."""
+    S, KV, Gp, D = qg.shape
+    NBLK, bs = k_cache.shape[:2]
+    NB = block_table.shape[1]
+    fused = k_new is not None
+    alibi = ab is not None
+    quant = k_scale is not None
     slots_arr = (slots.astype(jnp.int32) if fused
                  else jnp.full((S,), -1, jnp.int32))
 
-    def kv_block_of(s, j, tbl_ref, ctx_ref, allow_ref, slot_ref):
+    def kv_block_of(s, j, tbl_ref, ctx_ref, slot_ref):
         last = jnp.maximum(ctx_ref[s] - 1, 0) // bs
         if window > 0:
             j = _win_jbase_decode(ctx_ref[s], window, bs) + j
         j = jnp.minimum(j, last)
-        if sparse:
-            # layout-skipped slots revisit the last block instead of
-            # streaming their own — like the causal clamp, repeat visits
-            # to a resident tile cost no DMA, so sparse decode saves
-            # bandwidth as well as compute
-            j = jnp.where(allow_ref[s, j] != 0, j, last)
         # clip to the arena: a violated table contract must stay
         # contained (a wild block index can wedge the TPU runtime)
         return _arena_block(tbl_ref[s, j], NBLK)
@@ -424,13 +436,13 @@ def paged_decode_attention(q, k_cache, v_cache, block_table, ctx_lens,
         # a block's scale tile rides the SAME paging as its codes
         return (kv_block_of(s, j, *refs), 0, 0)
 
-    def row_index(s, j, tbl_ref, ctx_ref, allow_ref, slot_ref):
+    def row_index(s, j, *refs):
         return (s, 0, 0)
 
-    def q_index(s, j, tbl_ref, ctx_ref, allow_ref, slot_ref):
+    def q_index(s, j, *refs):
         return (s, 0, 0, 0)
 
-    def tgt_block_of(s, j, tbl_ref, ctx_ref, allow_ref, slot_ref):
+    def tgt_block_of(s, j, tbl_ref, ctx_ref, slot_ref):
         # constant in j: the sequence's NEWEST block — flushed once
         last = jnp.maximum(ctx_ref[s] - 1, 0) // bs
         return _arena_block(tbl_ref[s, last], NBLK)
@@ -452,34 +464,28 @@ def paged_decode_attention(q, k_cache, v_cache, block_table, ctx_lens,
     if quant:
         in_specs += [sc_spec, sc_spec]
     if alibi:  # whole [KV, Gp] slope table resident in VMEM
-        in_specs.append(pl.BlockSpec(
-            (KV, Gp), lambda s, j, tbl_ref, ctx_ref, allow_ref, slot_ref:
-            (0, 0)))
-    o_spec = pl.BlockSpec((1, KV, Gp, D), q_index)
-    o_shape = jax.ShapeDtypeStruct((S, KV, Gp, D), q.dtype)
+        in_specs.append(pl.BlockSpec((KV, Gp), lambda s, j, *refs: (0, 0)))
+    out_specs = [pl.BlockSpec((1, KV, Gp, D), q_index)]
+    out_shape = [jax.ShapeDtypeStruct(qg.shape, qg.dtype)]
+    aliases = {}
     if fused:
         tgt_spec = pl.BlockSpec((1, bs, KV, D), tgt_index)
-        out_specs = [o_spec, tgt_spec, tgt_spec]
-        out_shape = [o_shape,
-                     jax.ShapeDtypeStruct(k_cache.shape, k_cache.dtype),
-                     jax.ShapeDtypeStruct(v_cache.shape, v_cache.dtype)]
-        # args: (4 scalar-prefetch), q, kn, vn, k_cache, v_cache
+        out_specs += [tgt_spec, tgt_spec]
+        out_shape += [jax.ShapeDtypeStruct(k_cache.shape, k_cache.dtype),
+                      jax.ShapeDtypeStruct(v_cache.shape, v_cache.dtype)]
+        # args: (3 scalar-prefetch), q, kn, vn, k_cache, v_cache
         # [, k_scale, v_scale] — code pools and scale tiles alias
         # through so the arena updates in place
-        aliases = {7: 1, 8: 2}
+        aliases = {6: 1, 7: 2}
         if quant:
             tgt_sc_spec = pl.BlockSpec((1, bs, KV), tgt_sc_index)
             out_specs += [tgt_sc_spec, tgt_sc_spec]
             out_shape += [
                 jax.ShapeDtypeStruct(k_scale.shape, k_scale.dtype),
                 jax.ShapeDtypeStruct(v_scale.shape, v_scale.dtype)]
-            aliases = {7: 1, 8: 2, 9: 3, 10: 4}
-    else:
-        out_specs = o_spec
-        out_shape = o_shape
-        aliases = {}
+            aliases = {6: 1, 7: 2, 8: 3, 9: 4}
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
+        num_scalar_prefetch=3,
         grid=(S, NBw),
         in_specs=in_specs,
         out_specs=out_specs,
@@ -489,47 +495,32 @@ def paged_decode_attention(q, k_cache, v_cache, block_table, ctx_lens,
             pltpu.VMEM((KV * Gp, 1), jnp.float32),
         ],
     )
-    call = pl.pallas_call(
+    return pl.pallas_call(
         functools.partial(
             _decode_kernel, block_size=bs, scale=scale, n_kv=KV, gp=Gp,
-            window=window, sparse=sparse, fused=fused, alibi=alibi,
-            quant=quant,
+            window=window, fused=fused, alibi=alibi, quant=quant,
         ),
         grid_spec=grid_spec,
         out_shape=out_shape,
         input_output_aliases=aliases,
         interpret=interpret(),
         name="paged_decode_grid",
-    )
-    sc = (k_scale, v_scale) if quant else ()
-    tail = (ab,) if alibi else ()
-    if fused:
-        res = call(block_table, ctx_lens, allow, slots_arr, qg,
-                   k_new, v_new, k_cache, v_cache, *sc, *tail)
-        if quant:
-            out, ck, cv, cks, cvs = res
-            return out[:, :, :G, :].reshape(S, H, D), ck, cv, cks, cvs
-        out, ck, cv = res
-        return out[:, :, :G, :].reshape(S, H, D), ck, cv
-    out = call(block_table, ctx_lens, allow, slots_arr, qg, k_cache, v_cache,
-               *sc, *tail)
-    return out[:, :, :G, :].reshape(S, H, D)
+    )(block_table, ctx_lens, slots_arr, qg,
+      *((k_new, v_new) if fused else ()), k_cache, v_cache,
+      *((k_scale, v_scale) if quant else ()), *((ab,) if alibi else ()))
 
 
 def paged_decode_attention_xla(q, k_cache, v_cache, block_table, ctx_lens,
-                               allowed=None, window: int = 0,
-                               alibi_slopes=None, k_scale=None,
-                               v_scale=None):
-    """jnp oracle for the kernel (tests; also a CPU fallback, and the
-    block-sparse serving path via `allowed`).
+                               window: int = 0, alibi_slopes=None,
+                               k_scale=None, v_scale=None):
+    """jnp oracle for the kernels (tests; the engine's
+    decode_impl='xla'; TP meshes whose heads do not divide).
 
     Gathers each sequence's paged KV into a dense [S, NB*bs, KV, D]
     context — O(S·max_ctx) memory, fine at test scale. THIS is the
     per-step block-table gather materialization the fused kernel
     exists to avoid; it stays as the reference/oracle path only.
 
-    allowed: optional [S, NB*bs] bool — extra per-position mask (the
-    block-sparse layout row of each query's position).
     window > 0: token-exact sliding window per row.
     alibi_slopes: optional [H] — score bias slope_h * key_pos (the
     single query row makes the absolute form exact under softmax).
@@ -559,8 +550,6 @@ def paged_decode_attention_xla(q, k_cache, v_cache, block_table, ctx_lens,
     mask = pos[None, :] < ctx_lens[:, None]  # [S, NB*bs]
     if window > 0:
         mask = mask & (pos[None, :] >= ctx_lens[:, None] - window)
-    if allowed is not None:
-        mask = mask & allowed
     logits = jnp.where(mask[:, None, :], logits, NEG_INF)
     probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
     return jnp.einsum("shk,skhd->shd", probs, v)
@@ -579,10 +568,10 @@ _RING = 3
 
 
 def _walk_live_blocks(
-    s, tbl_ref, ctx_ref, allow_ref, q_ref, k_any, v_any, ab_ref,
+    s, tbl_ref, ctx_ref, q_ref, k_any, v_any, ab_ref,
     bufk, bufv, lsem, *,
     n_seqs: int, block_size: int, scale: float, n_kv: int, gp: int,
-    window: int, sparse: bool, new_col: bool,
+    window: int, new_col: bool,
 ):
     """Row `s`'s online softmax over the LIVE blocks of its table and
     nothing else: a fori_loop from the sliding window's first slot to
@@ -600,11 +589,6 @@ def _walk_live_blocks(
     write+attend) — so only columns < ctx-1 are live. Otherwise the
     row was written before the call and columns < ctx are.
 
-    sparse: block-sparse layouts ride in as the allow_ref bitmap — a
-    disallowed slot's load is never ISSUED (its iteration neither waits
-    nor computes; later blocks' loads are issued regardless of the gap,
-    so pipelining is preserved across it).
-
     Returns the per-head (running max, sum, accumulator) tuples of
     (Gp, 1), (Gp, 1), (Gp, D) f32; a row with no live block (ctx 0:
     batch padding) returns the initial carry and issues no load."""
@@ -616,7 +600,6 @@ def _walk_live_blocks(
     # never a wild DMA — an out-of-bounds manual DMA doesn't just crash
     # the program, it can wedge the TPU runtime for every later client
     n_blk = k_any.shape[0]
-    n_slots = tbl_ref.shape[1]
 
     def cached_of(ctx):
         return jnp.maximum(ctx - 1, 0) if new_col else ctx
@@ -626,15 +609,6 @@ def _walk_live_blocks(
 
     def nblk_of(ctx):
         return pl.cdiv(cached_of(ctx), bs)
-
-    def wanted(sq, j, nblk):
-        # block j of row sq is read: inside the live range and, under a
-        # layout, allowed (the bitmap is read inside its bounds only)
-        ok = j < nblk
-        if sparse:
-            ok = jnp.logical_and(
-                ok, allow_ref[sq, jnp.minimum(j, n_slots - 1)] != 0)
-        return ok
 
     def load(sq, j):
         blk = _arena_block(tbl_ref[sq, j], n_blk)
@@ -647,8 +621,7 @@ def _walk_live_blocks(
         ctx = ctx_ref[sq]
         jb, nb = jbase_of(ctx), nblk_of(ctx)
         for j in range(ring - 1):
-            pl.when(wanted(sq, jb + j, nb))(
-                functools.partial(load, sq, jb + j))
+            pl.when(jb + j < nb)(functools.partial(load, sq, jb + j))
 
     @pl.when(s == 0)
     def _prefetch_self():
@@ -667,28 +640,19 @@ def _walk_live_blocks(
         ms, ls, accs = carry  # per-head tuples: (Gp,1),(Gp,1),(Gp,D)
         bslot = j % ring
 
-        @pl.when(wanted(s, j + ring - 1, nblk))
+        @pl.when(j + ring - 1 < nblk)
         def _prefetch_ahead():
             load(s, j + ring - 1)
 
-        def wait():
-            pltpu.make_async_copy(k_any.at[0], bufk.at[bufset, bslot],
-                                  lsem.at[bufset, bslot, 0]).wait()
-            pltpu.make_async_copy(v_any.at[0], bufv.at[bufset, bslot],
-                                  lsem.at[bufset, bslot, 1]).wait()
+        pltpu.make_async_copy(k_any.at[0], bufk.at[bufset, bslot],
+                              lsem.at[bufset, bslot, 0]).wait()
+        pltpu.make_async_copy(v_any.at[0], bufv.at[bufset, bslot],
+                              lsem.at[bufset, bslot, 1]).wait()
 
         cols = j * bs + jax.lax.broadcasted_iota(jnp.int32, (gp, bs), 1)
         live = cols < cached
         if window > 0:
             live = jnp.logical_and(live, cols >= ctx - window)
-        if sparse:
-            # a disallowed block has no in-flight DMA: don't wait, and
-            # mask every column so the accumulators pass through
-            ok = allow_ref[s, j] != 0
-            live = jnp.logical_and(live, ok)
-            pl.when(ok)(wait)
-        else:
-            wait()
         kb = bufk[bufset, bslot]  # (bs, KV, D)
         vb = bufv[bufset, bslot]
         ms2, ls2, accs2 = [], [], []
@@ -703,17 +667,9 @@ def _walk_live_blocks(
             m_new = jnp.maximum(ms[h], jnp.max(st, axis=1, keepdims=True))
             p = jnp.exp(st - m_new)
             corr = jnp.exp(ms[h] - m_new)
-            l_new = ls[h] * corr + jnp.sum(p, axis=1, keepdims=True)
-            a_new = accs[h] * corr + _dot(p.astype(vb.dtype), vb[:, h, :])
-            if sparse:
-                # disallowed block: carry passes through untouched (the
-                # stale buffer's garbage and the all--inf exp NaNs are in
-                # the UNSELECTED where branch — never propagated)
-                m_new = jnp.where(ok, m_new, ms[h])
-                l_new = jnp.where(ok, l_new, ls[h])
-                a_new = jnp.where(ok, a_new, accs[h])
-            ls2.append(l_new)
-            accs2.append(a_new)
+            ls2.append(ls[h] * corr + jnp.sum(p, axis=1, keepdims=True))
+            accs2.append(accs[h] * corr
+                         + _dot(p.astype(vb.dtype), vb[:, h, :]))
             ms2.append(m_new)
         return tuple(ms2), tuple(ls2), tuple(accs2)
 
@@ -735,7 +691,7 @@ def _store_row(o_ref, s, ls, accs):
 
 
 def _decode_rows_kernel(
-    tbl_ref, ctx_ref, allow_ref,                    # scalar prefetch
+    tbl_ref, ctx_ref,                               # scalar prefetch
     q_ref, k_any, v_any,                            # inputs (caches in HBM)
     *rest,                                          # [ab], out, scratch
     alibi: bool, **walk,
@@ -751,13 +707,13 @@ def _decode_rows_kernel(
         ab_ref = None
     s = pl.program_id(0)
     _, ls, accs = _walk_live_blocks(
-        s, tbl_ref, ctx_ref, allow_ref, q_ref, k_any, v_any, ab_ref,
+        s, tbl_ref, ctx_ref, q_ref, k_any, v_any, ab_ref,
         bufk, bufv, lsem, new_col=False, **walk)
     _store_row(o_ref, s, ls, accs)
 
 
 def _decode_fused_kernel(
-    tbl_ref, ctx_ref, slot_ref, allow_ref,          # scalar prefetch
+    tbl_ref, ctx_ref, slot_ref,                     # scalar prefetch
     q_ref, kn_ref, vn_ref, k_any, v_any,            # inputs (caches in HBM)
     *rest,                                          # [ab], outs, scratch
     alibi: bool, **walk,
@@ -783,7 +739,7 @@ def _decode_fused_kernel(
     slot = slot_ref[s]
 
     ms, ls, accs = _walk_live_blocks(
-        s, tbl_ref, ctx_ref, allow_ref, q_ref, k_any, v_any, ab_ref,
+        s, tbl_ref, ctx_ref, q_ref, k_any, v_any, ab_ref,
         bufk, bufv, lsem, new_col=True, **walk)
 
     if alibi:
@@ -872,20 +828,16 @@ def _walks_live_blocks(qg, k_cache) -> bool:
 
 
 def _attend_live_blocks(qg, ab, k_cache, v_cache, block_table, ctx_lens,
-                        window: int, allowed_slots, scale: float):
+                        window: int, scale: float):
     """paged_decode_attention's unfused, unquantised case on the per-row
     walk: qg [S, KV, Gp, D] grouped queries, ab the [KV, Gp] ALiBi table
     or None -> [S, KV, Gp, D]."""
     S, KV, Gp, D = qg.shape
     bs = k_cache.shape[1]
-    sparse = allowed_slots is not None
-    # dense: the bitmap is never read; a (1, 1) stand-in keeps SMEM free
-    allow = (allowed_slots.astype(jnp.int32) if sparse
-             else jnp.zeros((1, 1), jnp.int32))
     vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
     hbm = pl.BlockSpec(memory_space=pl.ANY)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=2,
         grid=(S,),
         in_specs=[vmem, hbm, hbm] + ([vmem] if ab is not None else []),
         out_specs=vmem,
@@ -898,8 +850,7 @@ def _attend_live_blocks(qg, ab, k_cache, v_cache, block_table, ctx_lens,
     return pl.pallas_call(
         functools.partial(
             _decode_rows_kernel, n_seqs=S, block_size=bs, scale=scale,
-            n_kv=KV, gp=Gp, window=window, sparse=sparse,
-            alibi=ab is not None,
+            n_kv=KV, gp=Gp, window=window, alibi=ab is not None,
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(qg.shape, qg.dtype),
@@ -911,7 +862,7 @@ def _attend_live_blocks(qg, ab, k_cache, v_cache, block_table, ctx_lens,
         # tests find the program by it (the int8 and fused-write cases,
         # still on the (S, NB) grid, carry the same name)
         name="paged_decode_grid",
-    )(block_table, ctx_lens, allow, qg, k_cache, v_cache,
+    )(block_table, ctx_lens, qg, k_cache, v_cache,
       *(() if ab is None else (ab,)))
 
 
@@ -923,12 +874,12 @@ def supports_fused_v2(head_dim: int) -> bool:
 
 def paged_decode_fused(q, k_cache, v_cache, block_table, ctx_lens,
                        k_new, v_new, slots, window: int = 0,
-                       allowed_slots=None, alibi_slopes=None):
+                       alibi_slopes=None):
     """Fused single-token decode: write the batch's new KV rows into the
-    paged arenas AND attend over them, one kernel launch. The serving
-    engine's hot path for dense AND (via allowed_slots) block-sparse
-    layouts; only D % 128 != 0 models fall back to _decode_kernel's
-    bitmap grid.
+    paged arenas AND attend over them, one kernel launch (what
+    paged_decode_attention runs for k_new on unquantised pools at
+    D % 128 == 0; other head dims and int8 pools take _decode_kernel's
+    fused mode).
 
     Same contract as paged_decode_attention's fused mode: rows are
     DISTINCT sequences; ctx INCLUDES the new token; slots [S] are the
@@ -936,27 +887,18 @@ def paged_decode_fused(q, k_cache, v_cache, block_table, ctx_lens,
     Returns (out [S, H, D], k_cache, v_cache) with the arenas updated in
     place (donate them).
 
-    allowed_slots: optional [S, NB] block-sparse bitmap — disallowed
-    slots are never DMA'd at all (the (S, NB)-grid kernel could only
-    clamp them to a resident tile).
-
     Requires head_dim % 128 == 0: the per-row (KV, D) write DMA must be
-    lane-aligned (D=64 models route to paged_decode_attention's fused
-    mode instead — see supports_fused_v2)."""
+    lane-aligned (supports_fused_v2)."""
     S, H, D = q.shape
-    NBLK, bs, KV, _ = k_cache.shape
-    NB = block_table.shape[1]
+    bs, KV = k_cache.shape[1:3]
     scale = 1.0 / (D**0.5)
-    sparse = allowed_slots is not None
     alibi = alibi_slopes is not None
-    allow = (allowed_slots.astype(jnp.int32) if sparse
-             else jnp.zeros((S, NB), jnp.int32))
     qg, ab, G, Gp = _group_queries(q, KV, alibi_slopes)
     ab = (ab,) if alibi else ()
 
     vmem = lambda: pl.BlockSpec(memory_space=pltpu.VMEM)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
+        num_scalar_prefetch=3,
         grid=(S,),
         in_specs=[
             vmem(), vmem(), vmem(),
@@ -978,7 +920,7 @@ def paged_decode_fused(q, k_cache, v_cache, block_table, ctx_lens,
     out, ck, cv = pl.pallas_call(
         functools.partial(
             _decode_fused_kernel, n_seqs=S, block_size=bs, scale=scale,
-            n_kv=KV, gp=Gp, window=window, sparse=sparse, alibi=alibi,
+            n_kv=KV, gp=Gp, window=window, alibi=alibi,
         ),
         grid_spec=grid_spec,
         out_shape=[
@@ -986,13 +928,13 @@ def paged_decode_fused(q, k_cache, v_cache, block_table, ctx_lens,
             jax.ShapeDtypeStruct(k_cache.shape, k_cache.dtype),
             jax.ShapeDtypeStruct(v_cache.shape, v_cache.dtype),
         ],
-        # args: 4 scalar prefetch, q, kn, vn, k_cache, v_cache [, ab]
-        input_output_aliases={7: 1, 8: 2},
+        # args: 3 scalar prefetch, q, kn, vn, k_cache, v_cache [, ab]
+        input_output_aliases={6: 1, 7: 2},
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_WALK_VMEM_LIMIT),
         interpret=interpret(),
         name="paged_decode_fused",
-    )(block_table, ctx_lens, slots.astype(jnp.int32), allow, qg,
+    )(block_table, ctx_lens, slots.astype(jnp.int32), qg,
       k_new, v_new, k_cache, v_cache, *ab)
     return out[:, :, :G, :].reshape(S, H, D), ck, cv
 

@@ -9,8 +9,6 @@ from deepspeed_tpu.models import transformer as T
 # interpreter-/compile-heavy: excluded from the fast lane (-m 'not slow')
 import pytest  # noqa: E402
 
-pytestmark = pytest.mark.slow
-
 VOCAB = 64
 
 

@@ -1,4 +1,4 @@
-"""Curriculum learning + random-LTD tests.
+"""Curriculum learning, random-LTD and Megatron indexed-dataset tests.
 
 Ref model: tests/unit/runtime (curriculum scheduler math) and the
 random-LTD invariant: dropped tokens bypass the LTD layers unchanged.
@@ -15,6 +15,10 @@ from deepspeed_tpu.runtime.data_pipeline import (
     CurriculumScheduler,
     RandomLTDScheduler,
     truncate_to_seqlen,
+)
+from deepspeed_tpu.runtime.indexed_dataset import (
+    MMapIndexedDataset,
+    MMapIndexedDatasetBuilder,
 )
 
 # interpreter-/compile-heavy: excluded from the fast lane (-m 'not slow')
@@ -237,3 +241,42 @@ class TestProgressiveLayerDrop:
             self._build(progressive_layer_drop={"enabled": True},
                         optimizer={"type": "OneBitAdam",
                                    "params": {"lr": 1e-3, "freeze_step": 5}})
+
+
+class TestIndexedDataset:
+    def test_build_read_roundtrip(self, tmp_path):
+        prefix = str(tmp_path / "corpus")
+        b = MMapIndexedDatasetBuilder(prefix, dtype=np.int32)
+        docs = [np.arange(10), np.arange(5) + 100, np.arange(17) * 3]
+        for d in docs:
+            b.add_item(d)
+            b.end_document()
+        b.finalize()
+
+        ds = MMapIndexedDataset(prefix)
+        assert len(ds) == 3
+        for i, d in enumerate(docs):
+            np.testing.assert_array_equal(ds[i], d.astype(np.int32))
+        np.testing.assert_array_equal(ds.sizes, [10, 5, 17])
+        np.testing.assert_array_equal(ds.doc_idx, [0, 1, 2, 3])
+        # partial reads (the sampler's window access pattern)
+        np.testing.assert_array_equal(ds.get(2, offset=4, length=3),
+                                      (np.arange(17) * 3)[4:7].astype(np.int32))
+
+    def test_uint16_tokens(self, tmp_path):
+        """GPT-2-vocab datasets use uint16 (the Megatron convention)."""
+        prefix = str(tmp_path / "u16")
+        b = MMapIndexedDatasetBuilder(prefix, dtype=np.uint16)
+        b.add_item(np.array([1, 2, 50000], np.uint16))
+        b.end_document()
+        b.finalize()
+        ds = MMapIndexedDataset(prefix)
+        assert ds.dtype == np.uint16
+        np.testing.assert_array_equal(ds[0], [1, 2, 50000])
+
+    def test_bad_magic_raises(self, tmp_path):
+        p = tmp_path / "bad.idx"
+        p.write_bytes(b"NOTMAGIC0" + b"\x00" * 64)
+        (tmp_path / "bad.bin").write_bytes(b"")
+        with pytest.raises(ValueError, match="magic"):
+            MMapIndexedDataset(str(tmp_path / "bad"))

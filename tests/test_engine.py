@@ -13,9 +13,6 @@ import pytest
 import deepspeed_tpu as ds
 from deepspeed_tpu.models import transformer as T
 
-# interpreter-/compile-heavy: excluded from the fast lane (-m 'not slow')
-pytestmark = pytest.mark.slow
-
 VOCAB = 128
 
 

@@ -23,9 +23,7 @@ from deepspeed_tpu.ops.pallas.flash_attention import (
     flash_attention,
 )
 
-# interpreter-/compile-heavy: excluded from the fast lane (-m 'not slow')
-pytestmark = [pytest.mark.slow,
-              pytest.mark.usefixtures("pallas_interpret_module")]
+pytestmark = pytest.mark.usefixtures("pallas_interpret_module")
 
 
 def make_qkv(rng, B=2, S=128, H=2, KV=None, D=64, dtype=jnp.float32):

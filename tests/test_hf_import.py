@@ -26,9 +26,6 @@ from deepspeed_tpu.utils.hf_checkpoint import (
     import_external,
 )
 
-pytestmark = pytest.mark.slow  # torch model construction dominates
-
-
 def _torch_logits(model, tokens):
     with torch.no_grad():
         return model(torch.tensor([tokens])).logits[0].float().numpy()

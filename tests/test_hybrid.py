@@ -15,8 +15,6 @@ from deepspeed_tpu.runtime.hybrid_engine import HybridEngine
 # interpreter-/compile-heavy: excluded from the fast lane (-m 'not slow')
 import pytest  # noqa: E402
 
-pytestmark = pytest.mark.slow
-
 VOCAB = 128
 
 

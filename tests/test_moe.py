@@ -15,9 +15,6 @@ import deepspeed_tpu as ds
 from deepspeed_tpu.models import transformer as T
 from deepspeed_tpu.moe import compute_capacity, top1_gating, top2_gating
 
-# interpreter-/compile-heavy: excluded from the fast lane (-m 'not slow')
-pytestmark = pytest.mark.slow
-
 VOCAB = 128
 
 

@@ -5,8 +5,8 @@ the LIVE blocks of that row's table and nothing else.
 - the kernel against the gather oracle, interpreted, as cases of one
   test: a prefill chunk's rows on one table among distinct rows, the
   context edges around a block boundary, both serving cells' head
-  layouts, a window shorter than the context, ALiBi, a layout with
-  gaps, head dim 64 (which the (S, NB) grid keeps);
+  layouts, a window shorter than the context, ALiBi, head dim 64
+  (which the (S, NB) grid keeps);
 - dead table slots are not read, not merely masked;
 - which case takes which kernel, read from the traced program;
 - AOT compiles for a DESCRIBED v5e at both serving cells' shapes (no
@@ -36,9 +36,9 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _case(H=4, KV=2, D=128, bs=16, NB=4, ctx=(5, 33, 64), chunk=None,
-          window=0, alibi=False, gaps=False, dtype=jnp.float32):
+          window=0, alibi=False, dtype=jnp.float32):
     return dict(H=H, KV=KV, D=D, bs=bs, NB=NB, ctx=ctx, chunk=chunk,
-                window=window, alibi=alibi, gaps=gaps, dtype=dtype)
+                window=window, alibi=alibi, dtype=dtype)
 
 
 CASES = {
@@ -57,9 +57,6 @@ CASES = {
         ctx=(5, 33, 50, 64, 64), chunk=(3, 2), window=20),
     "window_not_a_block_multiple": _case(ctx=(7, 41, 64), window=37),
     "alibi": _case(H=8, KV=2, ctx=(2, 30, 64), alibi=True),
-    "layout_with_gaps": _case(NB=6, ctx=(5, 50, 81, 96), gaps=True),
-    "layout_with_gaps_and_chunk": _case(
-        NB=6, ctx=(70, 71, 72, 20), chunk=(0, 3), gaps=True),
     # Mosaic takes no manual DMA of a 64-wide block: the grid keeps it
     "head_dim_64": _case(D=64, ctx=(0, 5, 33, 64)),
     "head_dim_64_window": _case(D=64, ctx=(5, 33, 64), window=20),
@@ -85,25 +82,16 @@ def _inputs(rng, c):
 def test_shared_table_attention_matches_oracle(rng, name):
     c = CASES[name]
     q, kc, vc, tbl, ctx = _inputs(rng, c)
-    S, NB, bs = len(ctx), c["NB"], c["bs"]
-    kw, okw = {}, {}
+    kw = {}
     if c["alibi"]:
-        kw["alibi_slopes"] = okw["alibi_slopes"] = jnp.asarray(
-            alibi_slopes(c["H"]), jnp.float32)
-    if c["gaps"]:
-        lay = np.asarray(rng.integers(0, 2, (S, NB)), np.int32)
-        for s in range(S):  # the row's own slot stays: no empty softmax
-            lay[s, max(int(ctx[s]) - 1, 0) // bs] = 1
-        kw["allowed_slots"] = jnp.asarray(lay)
-        okw["allowed"] = jnp.repeat(jnp.asarray(lay).astype(bool), bs,
-                                    axis=1)
+        kw["alibi_slopes"] = jnp.asarray(alibi_slopes(c["H"]), jnp.float32)
     with jax.default_matmul_precision("highest"):
         out = paged_decode_attention(q, kc, vc, jnp.asarray(tbl),
                                      jnp.asarray(ctx), window=c["window"],
                                      **kw)
         ref = paged_decode_attention_xla(q, kc, vc, jnp.asarray(tbl),
                                          jnp.asarray(ctx),
-                                         window=c["window"], **okw)
+                                         window=c["window"], **kw)
     tol = 3e-2 if c["dtype"] == jnp.bfloat16 else 2e-3
     real = ctx > 0
     np.testing.assert_allclose(np.asarray(out, np.float32)[real],

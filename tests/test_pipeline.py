@@ -20,9 +20,6 @@ from deepspeed_tpu.runtime.pipe import (
     unpartition_layers,
 )
 
-# interpreter-/compile-heavy: excluded from the fast lane (-m 'not slow')
-pytestmark = pytest.mark.slow
-
 VOCAB = 128
 
 

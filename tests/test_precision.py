@@ -17,8 +17,6 @@ from deepspeed_tpu.runtime.precision import (
 # interpreter-/compile-heavy: excluded from the fast lane (-m 'not slow')
 import pytest  # noqa: E402
 
-pytestmark = pytest.mark.slow
-
 
 def cfg(**kw):
     return FP16Config(enabled=True, **kw)

@@ -14,9 +14,6 @@ import deepspeed_tpu as ds
 from deepspeed_tpu.models import transformer as T
 from deepspeed_tpu.ops import quantization as Q
 
-# interpreter-/compile-heavy: excluded from the fast lane (-m 'not slow')
-pytestmark = pytest.mark.slow
-
 VOCAB = 128
 
 

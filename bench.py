@@ -334,7 +334,7 @@ def _serving_sim():
             "completion_ms": {"p50": pct(sched_completion, 50),
                               "p95": pct(sched_completion, 95)},
             "preemptions": sched.counters["preemptions"],
-            "chained_steps": sched.counters["chained_steps"],
+            "lookahead_steps": sched.counters["lookahead_steps"],
             "fused_steps": sched.counters["fused_steps"],
             "recompile_findings": len(eng.recompile_tracker.findings),
             "new_signatures_after_warmup": int(new_sigs),

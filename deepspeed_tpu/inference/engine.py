@@ -785,6 +785,19 @@ class InferenceEngine:
             self._sample_fns[key] = jax.jit(fn)
         return self._sample_fns[key]
 
+    def _next_tokens_fn(self):
+        """Compiled composition of a decode step's token input ON the
+        device (the serving scheduler's look-ahead): row i takes row
+        src[i] of the previous step's sampled tokens where src[i] >= 0
+        and the host's toks[i] elsewhere, so a sampled token feeds the
+        next step without a host round trip. One tiny program per
+        (previous width, width) pair; warmup() compiles the pairs."""
+        if not hasattr(self, "_next_tokens"):
+            self._next_tokens = jax.jit(
+                lambda prev, toks, src: jnp.where(
+                    src >= 0, prev[jnp.maximum(src, 0)], toks))
+        return self._next_tokens
+
     def _dev(self, x):
         """Host array → device, replicated over the serving mesh (so the
         compiled step's non-weight operands carry a committed sharding)."""
@@ -1421,7 +1434,10 @@ class InferenceEngine:
         bucket(max_batch_size)). chunked=True additionally compiles the
         shared-table variant mixed prefill chunks need. decode_chunks:
         fused multi-step depths (model.decode_multi) to warm per width.
-        sampling/presence select the sampling epilogue variant.
+        sampling/presence select the sampling epilogue variant. Off a
+        mesh and without presence the scheduler's look-ahead composes a
+        step's tokens on the device (`_next_tokens_fn`): its program is
+        warmed for every (previous width, width) pair of `widths`.
         footprint=True additionally AOT-compiles the per-width decode
         program once more for its static cost report (the jit call
         cache and the AOT artifact are separate compilations), filling
@@ -1507,6 +1523,16 @@ class InferenceEngine:
                 rt.record(f"serving_sample[w{w}]", (steps,))
                 warm("sample", w, lambda: self._sample_fn(scfg, False)(
                     logits, keys, self._dev(steps)))
+            if self.mesh is None and not with_pres:
+                # the look-ahead's token composition (never engaged on
+                # a mesh or with the presence bitmap): the previous
+                # step may have had any warmed width
+                src = self._dev(np.full((w,), -1, np.int32))
+                for wp in widths:
+                    rt.record(f"serving_tokens[w{wp},w{w}]", (toks,))
+                    warm("tokens", w, lambda: self._next_tokens_fn()(
+                        self._dev(np.zeros((wp,), np.int32)),
+                        self._dev(toks), src), source=wp)
             for C in decode_chunks:
                 C = int(C)
                 if C < 1:

@@ -197,8 +197,9 @@ class SequenceDescriptor:
     seen_tokens: int = 0  # tokens whose KV lives in the cache
     # prefix-cache bookkeeping: token ids for positions [0, len(tokens))
     # when known, and the chain key per registered/matched full block.
-    # tokens_valid flips off the first time tokens are committed that the
-    # host never saw (fused-decode sampling) — no further index commits.
+    # tokens_valid is off while tokens are committed that the host has
+    # not seen (fused-decode sampling: for good; the scheduler's
+    # look-ahead: until supply_tokens) — no index commits meanwhile.
     tokens: List[int] = dataclasses.field(default_factory=list)
     tokens_valid: bool = True
     block_keys: List[bytes] = dataclasses.field(default_factory=list)
@@ -528,9 +529,12 @@ class StateManager:
         """Bump seen_tokens after the forward wrote the KV; with
         token_ids (or a token record from admission) also registers
         newly-full blocks in the prefix index. Committing tokens the
-        host never saw (fused-decode sampling) permanently stops index
-        registration for the sequence — already-registered blocks stay
-        valid (their contents are final)."""
+        host has not seen stops index registration for the sequence —
+        already-registered blocks stay valid (their contents are
+        final) — until `supply_tokens` hands in exactly the missing
+        ids (the scheduler's look-ahead does, one iteration later); a
+        fused-decode chunk's tokens are never supplied, so there the
+        stop is for good."""
         seq = self._seqs[uid]
         start = seq.seen_tokens
         seq.seen_tokens += new_tokens
@@ -547,6 +551,23 @@ class StateManager:
         if seq.seen_tokens > len(seq.tokens):
             seq.tokens_valid = False
             return
+        self._register_full_blocks(seq)
+
+    def supply_tokens(self, uid: int, token_ids) -> None:
+        """The ids of the NEWEST committed tokens, which `commit` was
+        given without them: ServingScheduler.run() launches step n+1 on
+        step n's sampled tokens while they are still on the device and
+        reads them afterwards. When they close the gap exactly (every
+        committed token known again) registration resumes where it
+        stopped, so a one-iteration delay costs a session no prefix
+        hit; ids that do not close it are dropped and the sequence
+        stays unregistered, as before."""
+        seq = self._seqs[uid]
+        if not self.enable_prefix_cache \
+                or len(seq.tokens) + len(token_ids) != seq.seen_tokens:
+            return
+        seq.tokens.extend(int(t) for t in token_ids)
+        seq.tokens_valid = True
         self._register_full_blocks(seq)
 
     def flush(self, uid: int) -> None:

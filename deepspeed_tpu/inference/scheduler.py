@@ -37,15 +37,21 @@ Performance comes from two pipelining layers:
   (bucket width x chunk) decode/sample grid at init, so steady-state
   serving triggers zero S003 recompiles (tracked by the engine's
   always-on RecompileTracker; asserted in tests/test_scheduler.py).
-- **async double-buffered dispatch**: a dispatch is issued (JAX async),
-  then ALL host bookkeeping for the next iteration — commits, block
-  tables, token buffers, sampling streams — happens while the device
-  runs. In the steady pure-decode state the sampled-token array stays
-  DEVICE-RESIDENT: it feeds the next dispatch directly, and the host
-  readback of step N (token ids only, via utils.sync.serving_readback)
-  lands after step N+1 is already in flight. With `decode_chunk > 1`
-  the steady state additionally fuses decode_chunk steps into one
-  compiled program (model.decode_multi), amortizing dispatch entirely.
+- **look-ahead dispatch**: run() launches step N+1 BEFORE it reads
+  step N back, whenever N+1 can be composed without the VALUES of N's
+  tokens. Which rows exist, their contexts, block tables, draw counters
+  and prefill chunks are host state, and a finish by length is known
+  from counts; only the sampled token ids are missing, and those stay
+  DEVICE-RESIDENT: row i of step N+1 gathers its token from row src[i]
+  of N's sampled array (engine._next_tokens_fn). The readback of N
+  (token ids only, utils.sync.serving_readback) then lands while N+1
+  runs, so the device never waits for the host's admit / select /
+  build. Steps the host cannot compose that way (speculation, the
+  presence bitmap, a mesh, wave or fused parts, a reservation that
+  must preempt, a handoff's first token) keep the order readback,
+  then dispatch. With `decode_chunk > 1` the steady state fuses
+  decode_chunk steps into one compiled program (model.decode_multi),
+  amortizing dispatch entirely.
 
 `generate()` and `generate_speculative()` are thin wrappers over this
 scheduler (prefill_mode='wave', warmup off) — one control plane serves
@@ -160,6 +166,13 @@ class _Step:
     def __init__(self, parts: List[_Part], n_tokens: int):
         self.parts = parts
         self.n_tokens = n_tokens      # batched tokens this iteration
+        # what a look-ahead dispatch of the NEXT step settled about this
+        # step's sampled requests before their tokens were read, by rid:
+        # True = ends with this token whatever its value (blocks already
+        # released), False = its token was fed on from the device (the
+        # next step's commit is waiting for the id). Absent: nothing
+        # was settled, _accept decides from the counts as it reads
+        self.settled: Dict[int, bool] = {}
 
 
 class ServingScheduler:
@@ -201,11 +214,11 @@ class ServingScheduler:
         self._next_rid = 0
         self.counters: Dict[str, float] = {
             "steps": 0, "admitted": 0, "finished": 0, "preemptions": 0,
-            "batched_tokens": 0, "fused_steps": 0, "chained_steps": 0,
+            "batched_tokens": 0, "fused_steps": 0, "lookahead_steps": 0,
             "wave_prefills": 0, "handoffs": 0, "adopted": 0,
             "spills": 0, "spill_resumes": 0, "spill_fallbacks": 0,
             "spill_rejects": 0, "spill_integrity_failures": 0,
-            "spill_releases": 0, "chain_fallbacks": 0,
+            "spill_releases": 0, "lookahead_fallbacks": 0,
             "deadline_rejections": 0, "starvation_protected": 0,
             # seconds of step()/run() spent in each phase (PHASES):
             # together they are the loop's wall time
@@ -613,22 +626,30 @@ class ServingScheduler:
                     return False
                 self._preempt(victim)
 
+    def _release(self, req: Request) -> None:
+        """Give req's KV blocks back to the allocator and its place in
+        the batch to admission. _finish does it as it retires a
+        request; the look-ahead does it one step EARLIER for a request
+        whose token in flight is its last by the counts, so that the
+        place refills in the iteration it would have."""
+        if req.uid is not None and self.engine.state.get(req.uid) is not None:
+            self.engine.flush(req.uid)
+        req.uid = None
+        if req in self.active:
+            self.active.remove(req)
+
     def _finish(self, req: Request, reason: str) -> None:
         """Retire NOW: blocks go back to the allocator at the iteration
         the sequence finishes, not when the batch drains."""
-        if req.uid is not None and self.engine.state.get(req.uid) is not None:
-            self.engine.flush(req.uid)
+        self._release(req)
         if req.spill_key is not None and self.spill_store is not None:
             # a spilled payload whose request retires another way
             # (shed, length while queued) must not strand host bytes
             self.spill_store.discard(req.spill_key)
             req.spill_key = None
-        req.uid = None
         req.state = FINISHED
         req.finish_reason = reason
         req.finish_t = time.perf_counter()
-        if req in self.active:
-            self.active.remove(req)
         self.finished[req.rid] = req
         self.counters["finished"] += 1
         if req.first_token_t is not None:
@@ -914,10 +935,15 @@ class ServingScheduler:
             bs = self.engine.config.kv_block_size
             self.counters["kv_live_blocks"] += int(np.sum(-(-live // bs)))
 
-    def _dispatch_mixed(self, rows) -> Optional[_Part]:
+    def _dispatch_mixed(self, rows, ahead_of: Optional[_Step] = None,
+                        src: Optional[Dict[int, int]] = None
+                        ) -> Optional[_Part]:
         """One compiled decode program over the iteration's ragged rows:
         1-token decode rows + multi-token prefill chunk rows (the
-        Sarathi piggyback). rows: [(req, chunk, sample)]."""
+        Sarathi piggyback). rows: [(req, chunk, sample)]. A chunk of
+        [None] is a decode row whose token the host does not hold yet:
+        it is row src[req.rid] of the sampled tokens of `ahead_of`, the
+        step still in flight, and is gathered on the device."""
         eng = self.engine
         ph = self._phases
         ph.mark("build")
@@ -929,6 +955,7 @@ class ServingScheduler:
         self._it_rows += n_rows
         sp = _bucket(n_rows, 8)
         toks = np.zeros((sp,), np.int32)
+        srcs = np.full((sp,), -1, np.int32)  # >= 0: a row of ahead_of
         ctx = np.zeros((sp,), np.int32)  # pad rows: ctx 0 = inert
         tables = np.full((sp, eng.config.blocks_per_seq),
                          eng.pad_block, np.int32)
@@ -940,7 +967,10 @@ class ServingScheduler:
             table = eng.state.block_table(
                 [req.uid], eng.config.blocks_per_seq, eng.pad_block)[0]
             for j, tok in enumerate(chunk):
-                toks[row] = int(tok)
+                if tok is None:
+                    srcs[row] = src[req.rid]
+                else:
+                    toks[row] = int(tok)
                 ctx[row] = base_seen + j + 1
                 tables[row] = table
                 row += 1
@@ -949,13 +979,28 @@ class ServingScheduler:
         unique = all(len(c) == 1 for _, c, _ in rows)
         eng.recompile_tracker.record(
             f"serving_decode[w{sp},u{int(unique)}]", (toks, tables, ctx))
-        ph.mark("launch", kind="mixed", rows=n_rows)
+        prev_toks = None  # the sampled tokens some row is gathered from
+        if (srcs >= 0).any():
+            prev_toks = ahead_of.parts[0].tok_dev
+            eng.recompile_tracker.record(
+                f"serving_tokens[w{prev_toks.shape[0]},w{sp}]", (srcs,))
+        ph.mark("launch", kind="mixed", rows=n_rows,
+                ahead=int(ahead_of is not None))
+        toks_dev = eng._dev(toks)
+        if prev_toks is not None:
+            toks_dev = eng._next_tokens_fn()(prev_toks, toks_dev,
+                                             eng._dev(srcs))
         logits, eng.cache = eng._decode_fn(sp, unique)(
-            eng.params, eng.cache, eng._dev(toks), eng._dev(tables),
+            eng.params, eng.cache, toks_dev, eng._dev(tables),
             eng._dev(ctx))
         # host bookkeeping overlaps the in-flight device program
         ph.mark("commit")
         for req, chunk, sample in rows:
+            if chunk[0] is None:
+                # the id follows when ahead_of is read back (_accept)
+                eng.state.commit(req.uid, 1)
+                ahead_of.settled[req.rid] = False
+                continue
             eng.state.commit(req.uid, len(chunk),
                              token_ids=[int(t) for t in chunk])
             if req.state == PREFILL:
@@ -1051,21 +1096,39 @@ class ServingScheduler:
         return (self.governor is not None
                 and self.governor.level >= BROWNOUT)
 
-    def _dispatch(self) -> Optional[_Step]:
+    def _dispatch(self, ahead_of: Optional[_Step] = None,
+                  governed: bool = False) -> Optional[_Step]:
         """Build and launch one iteration; returns None when idle.
         Host-side state (commits, next tables) is updated after the
         async launch, overlapping the device program. The pressure
         governor (when enabled) updates FIRST — its level steers this
         iteration's admission cap, victim policy, and brownout
-        degradations."""
+        degradations.
+
+        ahead_of is run()'s look-ahead: the previous step, launched and
+        not read back. The iteration is then composed from host state
+        and the COUNTS of that step (`_in_flight`), its decode rows
+        taking their tokens from the step's device-resident array; None
+        is also returned, and counted in `lookahead_fallbacks`, when
+        this iteration turns out to need the step's VALUES first (a
+        wave or fused program, a reservation that has to preempt).
+        Nothing it did until then has to be undone: the early releases
+        and the admissions are the ones the readback would have led
+        to, and a reservation is taken once however often it is asked
+        for. The governor's update is not one of those: it moves its
+        level one step and trims parked blocks per call, so the
+        dispatch that follows a handed-back look-ahead says `governed`
+        and leaves it at the one update of this iteration."""
         ph = self._phases
         ph.mark("admit")
-        if self.governor is not None:
+        src = self._in_flight(ahead_of) if ahead_of is not None else {}
+        if self.governor is not None and not governed:
             self.governor.update()
         self._admit()
         if not self.active:
             return None
-        self.counters["steps"] += 1
+        if ahead_of is None:  # a look-ahead step counts once launched
+            self.counters["steps"] += 1
         ph.mark("select")
         if self._spec and not self._brownout():
             # BROWNOUT degrades speculation to plain decode: draft rows
@@ -1077,12 +1140,16 @@ class ServingScheduler:
         prefill = [r for r in self.active if r.state == PREFILL]
         C = self._fused_depth(running)
         if C:
+            if ahead_of is not None:
+                return self._needs_readback()
             return _Step([self._dispatch_fused(running, C)],
                          len(running) * C)
         parts: List[_Part] = []
         if prefill and self.cfg.prefill_mode == "wave":
             wave = [r for r in prefill if r.fed == 0]
             if wave:
+                if ahead_of is not None:
+                    return self._needs_readback()
                 parts.extend(self._dispatch_wave(wave))
                 ph.mark("select")
                 prefill = [r for r in prefill if r.state == PREFILL]
@@ -1093,15 +1160,29 @@ class ServingScheduler:
             # shrink the prefill chunk: under brownout every reserved
             # prefill token is pool pressure the decode rows pay for
             pchunk = max(1, pchunk // self.cfg.pressure.brownout_chunk_div)
-        rows: List[Tuple[Request, List[int], bool]] = []
+        rows: List[Tuple[Request, List[Optional[int]], bool]] = []
         for req in list(running):  # oldest first; preemption takes youngest
             if budget < 1 or row_budget < 1:
                 break
             if req.state != RUNNING:
                 continue  # preempted/finished while reserving earlier rows
-            if not self._reserve(req, 1):
-                continue
-            rows.append((req, [req.pending], True))
+            if ahead_of is None:
+                if not self._reserve(req, 1):
+                    continue
+            else:
+                # a reservation that does not fit has to preempt, and
+                # preemption needs every token read: the normal order
+                # resolves it (pressure, KVCacheExhaustedError, or a
+                # row whose KV died under it), counted so a hot
+                # fall-back loop shows in the metrics (L004)
+                try:
+                    self.engine.state.extend(req.uid, 1)
+                except RuntimeError:
+                    self.counters["lookahead_fallbacks"] += 1
+                    return None
+            # a token still in flight stays on the device: None
+            rows.append(
+                (req, [None if req.rid in src else req.pending], True))
             budget -= 1
             row_budget -= 1
         for req in prefill:
@@ -1117,17 +1198,80 @@ class ServingScheduler:
             rows.append((req, chunk, req.fed + c == len(req.base)))
             budget -= c
             row_budget -= c
-        part = self._dispatch_mixed(rows)
+        part = self._dispatch_mixed(rows, ahead_of, src)
         if part is not None:
             parts.append(part)
+        if ahead_of is not None:
+            if part is None:
+                return self._needs_readback()
+            self.counters["steps"] += 1
+            self.counters["lookahead_steps"] += 1
         if not parts:
             return None
         return _Step(parts, sum(len(c) for _, c, _ in rows))
 
+    # -- look-ahead: compose step n+1 while step n is in flight ----------
+    def _ends_by_count(self, req: Request, n_out: int) -> bool:
+        """Does req end by length once it holds n_out output tokens
+        (output budget or context capacity)? Counts only: whatever the
+        tokens are, so it is known before they are read."""
+        return (n_out >= req.max_new_tokens
+                or self.engine.state.get(req.uid).seen_tokens + 1
+                >= self.engine.config.max_seq_len)
+
+    def _can_look_ahead(self, prev: _Step) -> bool:
+        """May the next iteration be composed and launched before prev
+        is read back? Read from what the scheduler and prev ARE, not
+        from anything a user sets. Not with speculation (verification
+        is a host decision over the values), not with the presence
+        bitmap (it needs the host token before the next draw), not on a
+        mesh engine (a committed device array would re-specialise the
+        mesh program), not over a wave or fused part (their tokens are
+        laid out otherwise), and not while a handoff request's first
+        token is in flight (it parks for the router instead of decoding
+        here)."""
+        if self._spec or self.scfg.needs_presence \
+                or self.engine.mesh is not None:
+            return False
+        if len(prev.parts) != 1 or prev.parts[0].kind != "mixed":
+            return False
+        return not any(req.handoff for req, _ in prev.parts[0].sample_rows)
+
+    def _in_flight(self, prev: _Step) -> Dict[int, int]:
+        """What prev, launched and unread, settles by counts alone.
+        Each request it samples for either ends with that token
+        whatever its value (output budget, context capacity): its
+        blocks and its place go back NOW, in the order the readback
+        would have freed them, so admission refills the place in the
+        iteration it would have. Or it carries on, unless the token
+        turns out to be its EOS: its next input is row `src` of prev's
+        token array. Returns {rid: src} for those."""
+        src: Dict[int, int] = {}
+        for req, row in prev.parts[0].sample_rows:
+            if req.done:
+                continue  # ended on EOS while this row was in flight
+            if self._ends_by_count(req, len(req.output) + 1):
+                self._release(req)
+                prev.settled[req.rid] = True
+            else:
+                src[req.rid] = row
+        return src
+
+    def _needs_readback(self) -> None:
+        """The look-ahead met an iteration it cannot compose without
+        the values of the step in flight: nothing is launched, run()
+        reads back and dispatches in the normal order."""
+        self.counters["lookahead_fallbacks"] += 1
+        return None
+
     # -- finalize: readback + accept + retire ----------------------------
-    def _accept(self, req: Request, tok: int, now: float) -> None:
+    def _accept(self, req: Request, tok: int, now: float,
+                ends: Optional[bool] = None) -> None:
         """Mirror generate()'s accept: append, then finish on EOS /
-        output budget / context capacity — retiring immediately."""
+        output budget / context capacity — retiring immediately.
+        `ends` is what a look-ahead dispatch settled about the length
+        before this token was read (_Step.settled); None = decide here.
+        Either way it is decided ONCE, from the same counts."""
         if req.first_token_t is None:
             req.first_token_t = now
         req.output.append(tok)
@@ -1136,11 +1280,14 @@ class ServingScheduler:
         if req.eos_token_id is not None and tok == req.eos_token_id:
             self._finish(req, "eos")
             return
-        if len(req.output) >= req.max_new_tokens:
-            self._finish(req, "length")
-            return
-        seq = self.engine.state.get(req.uid)
-        if seq.seen_tokens + 1 >= self.engine.config.max_seq_len:
+        if ends is None:
+            ends = self._ends_by_count(req, len(req.output))
+        elif not ends:
+            # fed on from the device a step ago: the id its commit
+            # went without, so the prefix index registers what the
+            # normal order would have
+            self.engine.state.supply_tokens(req.uid, (tok,))
+        if ends:
             self._finish(req, "length")
             return
         req.pending = tok
@@ -1158,6 +1305,10 @@ class ServingScheduler:
         req.state = RUNNING
 
     def _finalize(self, step: _Step) -> None:
+        """Read the step's sampled tokens back and accept them: the
+        host's first sight of each, so `first_token_t`, `finish_t` and
+        `len(output)` are stamped and grown here whenever the step was
+        launched."""
         for part in step.parts:
             if part.tok_dev is None:
                 continue  # mid-prompt prefill chunks: nothing sampled
@@ -1179,8 +1330,11 @@ class ServingScheduler:
             else:
                 for req, row in part.sample_rows:
                     if req.done:
-                        continue  # chained lookahead of a retired row
-                    self._accept(req, int(toks[row]), now)
+                        # the look-ahead row of a request that ended on
+                        # EOS a step ago: nothing past EOS is kept
+                        continue
+                    self._accept(req, int(toks[row]), now,
+                                 step.settled.get(req.rid))
 
     # -- speculative iteration (generate_speculative control plane) ------
     def _dispatch_spec(self) -> Optional[_Step]:
@@ -1339,103 +1493,17 @@ class ServingScheduler:
                 kind=self._it_kind, fault_delay_s=delay,
                 **{f"{p}_ms": ns * 1e-6 for p, ns in ph.ns.items()})
 
-    def _can_chain(self, step: _Step) -> bool:
-        """May the NEXT iteration consume this step's device-resident
-        sampled tokens directly (no host round trip between them)?
-        Steady pure-decode only: one mixed part whose rows all keep
-        decoding with >= 2 tokens of budget, no queue/prefill activity,
-        no presence coupling (the bitmap update needs the host token),
-        and a single-device engine (a committed device array would
-        re-specialize the mesh program)."""
-        if self._spec or self.scfg.needs_presence:
-            return False
-        if self.engine.mesh is not None:
-            return False
-        if self.waiting or len(step.parts) != 1:
-            return False
-        part = step.parts[0]
-        if part.kind != "mixed":
-            return False
-        if len(part.sample_rows) != len(self.active):
-            return False
-        # the token array feeds the next dispatch POSITIONALLY: row i of
-        # the chained step reads tok_dev[i], so the previous step must
-        # have sampled row i at index i (pure decode steps do; the step
-        # that finished a prefill chunk samples at the chunk-end row)
-        if any(row != i for i, (_, row) in enumerate(part.sample_rows)):
-            return False
-        if part.tok_dev.shape[0] != _bucket(max(len(part.sample_rows), 1), 8):
-            return False
-        eng = self.engine
-        for req, _ in part.sample_rows:
-            if req.state != RUNNING or req.eos_token_id is not None:
-                return False
-            if len(req.output) + 2 > req.max_new_tokens:
-                return False
-            seq = eng.state.get(req.uid)
-            if seq is None or seq.seen_tokens + 2 >= eng.config.max_seq_len:
-                return False
-        return True
-
-    def _dispatch_chained(self, prev: _Step) -> Optional[_Step]:
-        """Launch the next pure-decode iteration feeding prev's sampled
-        tokens DEVICE-RESIDENT (the [bucket] array is the next token
-        input; prev's host readback lands after this launch). Commits
-        carry no token ids (the host has not seen them yet). Returns
-        None when a row's block reservation forced a composition change
-        (caller falls back to finalize-then-dispatch)."""
-        eng = self.engine
-        ph = self._phases
-        part = prev.parts[0]
-        rows = [req for req, _ in part.sample_rows]
-        sp = part.tok_dev.shape[0]
-        for req in rows:
-            try:
-                eng.state.extend(req.uid, 1)
-            except RuntimeError:
-                # pressure (KVCacheExhaustedError) or a row whose KV
-                # died under it mid-chain: resolve via the normal
-                # path, which can preempt/spill/requeue; counted so a
-                # hot chain-break loop is visible in metrics instead
-                # of silently absorbed (L004)
-                self.counters["chain_fallbacks"] += 1
-                return None
-        ph.mark("build")
-        self._it_kind = "chained"
-        self._it_rows += len(rows)
-        ctx = np.zeros((sp,), np.int32)
-        tables = np.full((sp, eng.config.blocks_per_seq),
-                         eng.pad_block, np.int32)
-        sample_rows = []
-        for r, req in enumerate(rows):
-            seq = eng.state.get(req.uid)
-            ctx[r] = seq.seen_tokens + 1
-            tables[r] = eng.state.block_table(
-                [req.uid], eng.config.blocks_per_seq, eng.pad_block)[0]
-            sample_rows.append((req, r))
-        eng.recompile_tracker.record(
-            f"serving_decode[w{sp},u1]",
-            (np.zeros((sp,), np.int32), tables, ctx))
-        ph.mark("launch", kind="chained", rows=len(rows))
-        logits, eng.cache = eng._decode_fn(sp, True)(
-            eng.params, eng.cache, part.tok_dev, eng._dev(tables),
-            eng._dev(ctx))
-        ph.mark("commit")
-        for req in rows:
-            eng.state.commit(req.uid, 1)  # token device-resident: no ids
-        tok_dev = self._sample_part(logits, sample_rows, sp)
-        ph.mark("commit")
-        self.counters["steps"] += 1
-        self._count_tokens(len(rows), ctx)
-        self.counters["chained_steps"] += 1
-        return _Step([_Part("mixed", sample_rows, tok_dev)], len(rows))
-
     def run(self, tick=None) -> None:
         """Drive until idle. tick(scheduler), when given, runs once per
         iteration before admission — the arrival-injection hook the
-        serving simulator uses. The loop is double-buffered: in the
-        steady pure-decode state iteration N+1 is dispatched on N's
-        device-resident tokens BEFORE N's readback."""
+        serving simulator uses. The loop keeps one step in flight and
+        LOOKS AHEAD: iteration n+1 is composed from host state and the
+        counts of step n, launched on n's device-resident tokens, and
+        only then is n read back (`_dispatch(ahead_of=)`), in every
+        iteration whose composition allows it (`_can_look_ahead`, and
+        the fall-backs `_dispatch` finds on its way); the others read
+        back first, then dispatch, as step() always does. Either order
+        gives the same tokens, finish reasons and prefix index."""
         prev: Optional[_Step] = None
         stalls = 0
         while True:
@@ -1446,16 +1514,16 @@ class ServingScheduler:
                 if tick is not None:
                     tick(self)
                 self._phases.mark("select")
-                if prev is not None and not self.waiting \
-                        and self._can_chain(prev):
-                    nxt = self._dispatch_chained(prev)
-                    self._finalize(prev)  # readback overlaps nxt's compute
-                    prev = nxt
-                    continue
+                st, looked = None, False
                 if prev is not None:
+                    looked = self._can_look_ahead(prev)
+                    if looked:
+                        st = self._dispatch(ahead_of=prev)
+                    # with st launched, the readback overlaps its compute
                     self._finalize(prev)
                     prev = None
-                st = self._dispatch()
+                if st is None:
+                    st = self._dispatch(governed=looked)
                 if st is None:
                     if not self.has_work:
                         break
@@ -1474,8 +1542,6 @@ class ServingScheduler:
                     prev = st
             finally:
                 self._end_iteration()
-        if prev is not None:
-            self._finalize(prev)
 
     # -- observability ---------------------------------------------------
     def metrics(self) -> Dict[str, float]:

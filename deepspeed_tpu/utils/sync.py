@@ -27,8 +27,12 @@ def serving_readback(x: Any) -> np.ndarray:
     """The serving scheduler's ONE per-iteration host readback: sampled
     token ids ([bucket] or [chunk, bucket] int32) of an in-flight
     dispatch (inference/scheduler.py). R002-allowlisted because the
-    loop is double-buffered: the readback of step N is issued AFTER
-    step N+1's dispatch whenever composition allows, so the device
-    pipeline never idles on it — and what crosses the link is token
-    ids, never [batch, vocab] logits."""
+    loop looks ahead: ServingScheduler.run() issues the readback of
+    step N AFTER it has launched step N+1 on N's device-resident
+    tokens, in every iteration whose composition allows it (not under
+    speculation, the presence bitmap, a mesh, wave or fused parts, a
+    reservation that must preempt; step() always reads back first), so
+    the device does not idle on it — and what crosses the link is
+    token ids, never [batch, vocab] logits. The scheduler's
+    `readback_wait_s` is the time spent in here."""
     return np.asarray(jax.device_get(x))  # ds-lint: ok R002 the serving choke point

@@ -2,7 +2,7 @@
 leak-family regression tests for the fixes the analyzer drove: spill
 payloads released on every router re-route path (shed / failover /
 drain / rebalance), host-tier drain at replica retirement, counted
-chain-dispatch fallbacks, and the quiesce-residual audit the bench
+look-ahead fallbacks, and the quiesce-residual audit the bench
 serving/chaos/overload lanes gate on (docs/lifecycle.md)."""
 
 import json
@@ -632,32 +632,46 @@ class TestHostStoreDrain:
         assert store.drain() == 0
 
 
-class TestChainFallbackCounted:
-    def test_kv_exhaustion_falls_back_and_counts(self, model):
-        from deepspeed_tpu.inference import (KVCacheExhaustedError,
-                                             ServingScheduler,
+class TestLookaheadFallbackCounted:
+    """A look-ahead dispatch whose decode row cannot reserve its KV
+    room (pool pressure, or a row whose KV died under it) is not
+    absorbed silently: the step is handed back to the normal order
+    (readback, then dispatch, which may preempt) and counted (L004)."""
+
+    def _sched_with_a_step_in_flight(self, model):
+        from deepspeed_tpu.inference import (ServingScheduler,
                                              ServingSchedulerConfig)
 
         sched = ServingScheduler(
             _engine(model), ServingSchedulerConfig(warmup=False))
-        req = types.SimpleNamespace(uid=0)
-        prev = types.SimpleNamespace(parts=[types.SimpleNamespace(
-            sample_rows=[(req, 0)],
-            tok_dev=np.zeros((4,), np.int32))])
+        sched.submit([1, 2, 3, 4, 5], 8)
+        prev = sched._dispatch()
+        assert sched._can_look_ahead(prev)
+        return sched, prev
 
-        def boom(uid, n):
-            raise KVCacheExhaustedError("full")
+    @pytest.mark.parametrize("error", ["exhausted", "runtime"])
+    def test_failed_reservation_falls_back_and_counts(self, model, error):
+        from deepspeed_tpu.inference import KVCacheExhaustedError
+
+        sched, prev = self._sched_with_a_step_in_flight(model)
+        steps, extend = sched.counters["steps"], sched.engine.state.extend
+
+        def boom(uid, n, **kw):
+            if error == "exhausted":
+                raise KVCacheExhaustedError("full")
+            raise RuntimeError("row died under the look-ahead")
 
         sched.engine.state.extend = boom
-        assert sched._dispatch_chained(prev) is None
-        assert sched.counters["chain_fallbacks"] == 1
-
-        def boom2(uid, n):
-            raise RuntimeError("row died under the chain")
-
-        sched.engine.state.extend = boom2
-        assert sched._dispatch_chained(prev) is None
-        assert sched.counters["chain_fallbacks"] == 2
+        assert sched._dispatch(ahead_of=prev) is None
+        assert sched.counters["lookahead_fallbacks"] == 1
+        assert sched.counters["lookahead_steps"] == 0
+        assert sched.counters["steps"] == steps  # the step was not run
+        assert prev.settled == {}                # nothing fed ahead
+        # the normal order picks it up: readback, then dispatch
+        sched.engine.state.extend = extend
+        sched._finalize(prev)
+        assert sched._dispatch() is not None
+        assert sched.counters["steps"] == steps + 1
 
 
 class TestQuiesceResiduals:

@@ -278,6 +278,47 @@ class TestAccounting:
         assert not seq.tokens_valid
         assert m.indexed_blocks == 2  # prompt blocks stay addressable
 
+    def test_supplied_ids_resume_registration(self):
+        """The scheduler's look-ahead: a token is committed before the
+        host has read it and its id follows one iteration later. The
+        index ends up holding what id-carrying commits would have."""
+        want = mgr()
+        want.extend(0, 6, token_ids=list(range(6)))
+        want.commit(0, 6)
+        for t in range(6, 13):
+            want.extend(0, 1)
+            want.commit(0, 1, token_ids=[t])
+        m = mgr()
+        seq, _ = m.extend(0, 6, token_ids=list(range(6)))
+        m.commit(0, 6)
+        for t in range(6, 13):
+            m.extend(0, 1)
+            m.commit(0, 1)              # the id is still on the device
+            assert not seq.tokens_valid
+            m.supply_tokens(0, [t])     # the readback landed
+            assert seq.tokens_valid
+        assert m.indexed_blocks == want.indexed_blocks == 3
+        assert set(m._index) == set(want._index)
+        assert seq.tokens == list(range(13))
+
+    def test_ids_that_do_not_close_the_gap_are_dropped(self):
+        m = mgr()
+        seq, _ = m.extend(0, 8, token_ids=list(range(8)))
+        m.commit(0, 8)
+        m.extend(0, 4)
+        m.commit(0, 4)                  # a fused chunk: never supplied
+        m.extend(0, 1)
+        m.commit(0, 1)
+        m.supply_tokens(0, [99])        # one id for a gap of five
+        assert not seq.tokens_valid and seq.tokens == list(range(8))
+        assert m.indexed_blocks == 2
+        off = StateManager(num_blocks=8, block_size=4,
+                           enable_prefix_cache=False)
+        off.extend(0, 2)
+        off.commit(0, 2)
+        off.supply_tokens(0, [1, 2])    # no index: nothing to keep
+        assert off.get(0).tokens == []
+
     def test_disabled_cache_is_legacy_behavior(self):
         m = StateManager(num_blocks=8, block_size=4,
                          enable_prefix_cache=False)

@@ -1,7 +1,8 @@
 """ServingScheduler tests: token-identity against one-shot generate()
 (staggered arrivals, chunked prefill, forced preemption), immediate
 block reclamation, admission policies, AOT-warmup zero-recompile
-steady state (S003), double-buffered chaining, and monitor counters.
+steady state (S003), the look-ahead's pure-decode case, and monitor
+counters (the look-ahead's own cases: tests/test_lookahead.py).
 
 Fast lane: tiny model, f32, CPU — the control plane is host-side and
 the compiled programs are seconds-cheap at this size."""
@@ -311,9 +312,12 @@ class TestWarmupZeroRecompile:
 
 class TestDoubleBuffering:
     def test_chained_steps_fire_and_match(self, model, rng):
-        """run()'s steady pure-decode state chains dispatches on the
-        device-resident token array (readback lands after the next
-        launch); tokens equal the unchained step() drive."""
+        """run() launches each iteration on the previous one's
+        device-resident token array (its readback lands after the next
+        launch): the pure-decode stretch that used to chain is the
+        look-ahead's case "every row carried, source map = identity",
+        and so are the chunked-prefill iterations before it; tokens
+        equal the step() drive, which always reads back first."""
         prompts = _prompts(rng, (6, 9))
         cfg = ServingSchedulerConfig(prefill_chunk=8,
                                      max_num_batched_tokens=16,
@@ -321,13 +325,14 @@ class TestDoubleBuffering:
         a = ServingScheduler(engine_for(model), cfg)
         ra = [a.submit(p, 10) for p in prompts]
         got = _drain(a, ra)
-        assert a.counters["chained_steps"] > 0
+        assert a.counters["lookahead_steps"] == a.counters["steps"] - 1
+        assert a.counters["lookahead_fallbacks"] == 0
 
         b = ServingScheduler(engine_for(model), cfg)
         rb = [b.submit(p, 10) for p in prompts]
         while b.has_work:
             b.step()
-        assert b.counters["chained_steps"] == 0
+        assert b.counters["lookahead_steps"] == 0
         assert got == [b.finished[r].output for r in rb]
 
     def test_fused_steady_state(self, model, rng):

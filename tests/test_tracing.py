@@ -266,7 +266,7 @@ class TestServingLoop:
         recs = profiler.spans()
         its = [r for r in recs if r.name == "sched.iteration"]
         assert [r.ids["iteration"] for r in its] == list(range(1, len(its) + 1))
-        assert {r.ids["kind"] for r in its} <= {"mixed", "chained", "idle"}
+        assert {r.ids["kind"] for r in its} <= {"mixed", "idle"}
         assert its[0].ids["rows"] > 0
         by_parent = {}
         for r in recs:
@@ -278,6 +278,11 @@ class TestServingLoop:
         assert profiler.self_ns(recs)[its[0].sid] == 0
         launch = [k for k in kids if k.name == "sched.launch"][0]
         assert launch.ids["kind"] == "mixed" and launch.ids["rows"] > 0
+        # the first iteration has no step in flight; later ones say so
+        assert launch.ids["ahead"] == 0
+        ahead = [r.ids["ahead"] for r in recs if r.name == "sched.launch"
+                 and r.ids.get("kind") == "mixed"]
+        assert set(ahead[1:]) == {1}
         for rid in rids:
             mine = [r for r in recs if r.ids.get("rid") == rid]
             assert sorted(names(mine)) == [
@@ -316,7 +321,7 @@ class TestServingLoop:
 class TestWarmupAndInit:
     def test_warmup_split_sums_to_its_seconds(self, engine):
         rep = engine.warmup_report
-        assert rep["programs"] == len(rep["per_program"]) == 3
+        assert rep["programs"] == len(rep["per_program"]) == 4
         assert set(rep["split"]) == {"trace_s", "lower_s", "compile_s",
                                      "execute_s", "other_s"}
         assert sum(rep["split"].values()) == pytest.approx(rep["seconds"])
@@ -326,7 +331,8 @@ class TestWarmupAndInit:
                         ("trace", "lower", "compile", "execute", "other"))
             assert parts == pytest.approx(pp["seconds"])
         kinds = [(pp["kind"], pp.get("unique")) for pp in rep["per_program"]]
-        assert kinds == [("decode", 1), ("decode", 0), ("sample", None)]
+        assert kinds == [("decode", 1), ("decode", 0), ("sample", None),
+                         ("tokens", None)]
 
     def test_setup_spans_are_kept_with_tracing_off(self):
         params = T.init(MCFG, jax.random.PRNGKey(1))
@@ -343,7 +349,7 @@ class TestWarmupAndInit:
         assert by_name["init.pool"][0].parent == root.sid
         progs = by_name["warmup.program"]
         assert [(p.ids["kind"], p.ids["width"]) for p in progs] == [
-            ("decode", 8), ("sample", 8)]
+            ("decode", 8), ("sample", 8), ("tokens", 8)]
         for p in progs:
             kids = {r.name for r in recs if r.parent == p.sid}
             assert "warmup.execute" in kids

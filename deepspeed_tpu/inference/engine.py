@@ -42,6 +42,7 @@ from ..resilience.integrity import (
 )
 from ..models import transformer as T
 from ..ops.pallas import kernels_runnable
+from ..ops.pallas.paged_attention import latent_lanes, latent_walk_fits
 from ..utils import profiler
 from ..utils.logging import log_dist
 from ..utils.sync import host_sync, serving_readback
@@ -254,6 +255,13 @@ class InferenceEngine:
                     f"tp_size {tp} (ref AutoTP requires head divisibility, "
                     "module_inject/auto_tp.py)"
                 )
+        if model_config.is_latent and (
+                self.mesh is not None or quantization or offload is not None
+                or self.config.kv_cache_dtype != "auto"):
+            raise NotImplementedError(
+                "a latent-attention model is served on one device from "
+                "resident bf16/f32 weights and a bf16/f32 latent cache: no "
+                "mesh, weight quantization, offload or int8 KV yet")
         if self.config.decode_impl not in ("auto", "pallas", "xla"):
             raise ValueError(
                 f"decode_impl must be 'auto', 'pallas' or 'xla' "
@@ -271,6 +279,20 @@ class InferenceEngine:
         self._use_kernel = kernel_layout and (
             self.config.decode_impl == "pallas"
             or (self.config.decode_impl == "auto" and kernels_runnable()))
+        if model_config.is_latent and self._use_kernel:
+            # the latent walk holds a table's live blocks in VMEM twice
+            # over: a context it cannot take is refused here, not handed
+            # to the XLA oracle call by call
+            row = jax.ShapeDtypeStruct(
+                (1, self.config.kv_block_size,
+                 latent_lanes(model_config.latent_dim)), dtype)
+            if not latent_walk_fits(self.config.blocks_per_seq, row):
+                raise ValueError(
+                    f"max_seq_len {self.config.max_seq_len} = "
+                    f"{self.config.blocks_per_seq} blocks of "
+                    f"{self.config.kv_block_size} tokens is more than the "
+                    "latent walk's VMEM buffers hold; lower max_seq_len or "
+                    "use decode_impl='xla'")
         if model_config.use_learned_pos:
             # prefill pads prompts up to a power-of-two bucket, and every
             # padded position indexes the learned position table — so the
@@ -834,9 +856,10 @@ class InferenceEngine:
         (+ per-block scale tiles when quantized). The capacity number
         the ds_budget gate pins the int8/bf16 ratio on (>= 1.8x)."""
         per_tok = 0
-        for l in range(self.cfg.n_layers):
+        n_pools = 2 if self.cache.v else 1  # a latent cache has no V
+        for l in range(len(self.cache.k)):
             # one token slot of one block: [KV, D] in the pool dtype
-            per_tok += 2 * self.cache.k[l][0, 0].nbytes
+            per_tok += n_pools * self.cache.k[l][0, 0].nbytes
             if self.cache.quantized:
                 per_tok += 2 * self.cache.k_scale[l][0, 0].nbytes
         return per_tok
@@ -859,11 +882,17 @@ class InferenceEngine:
         return s
 
     # -- paged-KV block transfer (prefill/decode disaggregation) ---------
+    def _pages_travel(self) -> None:
+        if self.cfg.is_latent:
+            raise NotImplementedError(
+                "a latent cache's pages do not travel yet (handoff, spill)")
+
     def _kv_gather_fn(self):
         """Compiled gather of [blocks_per_seq] cache pages across every
         layer: (cache, idx) -> ([L, B, bs, KV, D] k, same v). Pad slots
         index the reserved scratch block, so one program serves every
         sequence length."""
+        self._pages_travel()
         if self._kv_gather is None:
             def gather(cache, idx):
                 out = (jnp.stack([ck[idx] for ck in cache.k]),
@@ -881,6 +910,7 @@ class InferenceEngine:
         """Compiled scatter of transferred pages into this cache:
         (cache, idx, k, v) -> cache with rows idx overwritten. Pad rows
         land on the reserved scratch block (never a live page)."""
+        self._pages_travel()
         if self._kv_scatter is None:
             if self.kv_quant:
                 def scatter(cache, idx, k, v, ks, vs):
@@ -925,7 +955,8 @@ class InferenceEngine:
         per_page = int(self.cache.k[0][0].nbytes)
         if self.cache.quantized:
             per_page += int(self.cache.k_scale[0][0].nbytes)
-        return 2 * self.cfg.n_layers * n_blocks * per_page
+        return ((2 if self.cache.v else 1) * len(self.cache.k) * n_blocks
+                * per_page)
 
     def export_kv(self, uid: int) -> Dict[str, Any]:
         """Serialize one sequence's paged KV for a cross-engine handoff

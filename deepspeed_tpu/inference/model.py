@@ -42,11 +42,16 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..models import transformer as T
-from ..ops.attention import causal_attention
+from ..ops.attention import causal_attention, uses_flash
 from ..ops.pallas.paged_attention import (
+    latent_lanes,
     paged_decode_attention,
     paged_decode_attention_xla,
     paged_kv_write,
+    paged_latent_attention,
+    paged_latent_attention_xla,
+    paged_latent_write,
+    paged_latent_write_xla,
     paged_scale_write,
     quantize_kv_rows,
 )
@@ -86,6 +91,14 @@ def prepare(params: Dict[str, Any], cfg: T.TransformerConfig,
         prepare_layer({name: w[l] for name, w in st.items()}, cfg, fuse)
         for l in range(L)
     ]
+    # leading dense layers: their top-level `dense_<name>` stacks become
+    # a list of per-layer dicts beside `layers`
+    pre = T.DENSE_PREFIX
+    dense = {k[len(pre):]: out.pop(k) for k in list(out) if k.startswith(pre)}
+    if dense:
+        out["dense_layers"] = [
+            prepare_layer({name: w[l] for name, w in dense.items()}, cfg, fuse)
+            for l in range(cfg.n_dense_layers)]
     return out
 
 
@@ -95,13 +108,20 @@ def prepare_layer(lp: Dict[str, Any], cfg: T.TransformerConfig,
     body of prepare(); offload serving stages layers through this one at
     a time so a bigger-than-HBM model never materializes whole)."""
     lp = dict(lp)
+    if "wkv_b" in lp:
+        # latent attention: the up-projection splits into the halves the
+        # two forms read apart (absorbed decode multiplies q by w_uk and
+        # the attended latent by w_uv), once, not per compiled step
+        Dn = cfg.qk_nope_head_dim
+        wkv_b = lp.pop("wkv_b")
+        lp["w_uk"], lp["w_uv"] = wkv_b[..., :Dn], wkv_b[..., Dn:]
     if fuse and "wq" in lp:
         lp["w_qkv"] = jnp.concatenate(
             [lp.pop("wq"), lp.pop("wk"), lp.pop("wv")], axis=1)
         if "bq" in lp:
             lp["b_qkv"] = jnp.concatenate(
                 [lp.pop("bq"), lp.pop("bk"), lp.pop("bv")], axis=0)
-        if cfg.n_experts == 0 and cfg.is_gated and "w_gate" in lp:
+        if "w_router" not in lp and cfg.is_gated and "w_gate" in lp:
             lp["w_gi"] = jnp.concatenate(
                 [lp.pop("w_gate"), lp.pop("w_in")], axis=1)
     return lp
@@ -299,13 +319,18 @@ def _shard_map_kernel(fn, mesh: Mesh, in_specs, out_specs):
 class PagedCache(NamedTuple):
     """Per-layer lists (length n_layers) of [NBLK, bs, KV, D] arrays.
 
+    A latent-attention model (cfg.is_latent) caches ONE row a token a
+    layer, [normed latent; rotary key]: `k` holds its pools
+    [NBLK, bs, C] (C the row padded to whole lanes, latent_lanes) and
+    `v` is empty.
+
     int8-quantized caches (kv_quant) additionally carry per-layer
     [NBLK, bs, KV] f32 scale-tile pools: block i's codes dequantize by
     k_scale[i] — the scales are part of the page, so every path that
     moves pages (COW, export/import, spill) moves them together."""
 
     k: List[jnp.ndarray]
-    v: List[jnp.ndarray]
+    v: List[jnp.ndarray] = ()
     k_scale: Optional[List[jnp.ndarray]] = None
     v_scale: Optional[List[jnp.ndarray]] = None
 
@@ -329,7 +354,15 @@ def init_cache(
     """kv_quant=True allocates int8 code pools + f32 per-block scale
     tiles instead of `dtype` pools — half (vs bf16) or a quarter (vs
     f32) the resident KV bytes plus KV*8 scale bytes per token."""
-    KV, D, L = cfg.kv_heads, cfg.head_dim, cfg.n_layers
+    KV, D, L = cfg.kv_heads, cfg.head_dim, cfg.depth
+    if cfg.is_latent:
+        if kv_quant or mesh is not None:
+            raise NotImplementedError(
+                "a latent cache is bf16/f32 on one device: no int8 pool "
+                "and no mesh")
+        shape = (num_blocks, block_size, latent_lanes(cfg.latent_dim))
+        return PagedCache(k=[jnp.zeros(shape, dtype) for _ in range(L)],
+                          v=[])
     shape = (num_blocks, block_size, KV, D)
     if kv_quant:
         dtype = jnp.int8
@@ -469,7 +502,7 @@ def _layer_pools(cache: PagedCache, li: int) -> tuple:
     """One layer's pools in PagedCache's field order: (k, v), and
     (k, v, k_scale, v_scale) of a quantised cache. What `attend` hands
     back per layer and _forward zips into the new PagedCache."""
-    return tuple(pool[li] for pool in cache if pool is not None)
+    return tuple(pool[li] for pool in cache if pool)
 
 
 def _write_pools(pools: tuple, k_new, v_new, flat_idx, mesh=None,
@@ -477,7 +510,11 @@ def _write_pools(pools: tuple, k_new, v_new, flat_idx, mesh=None,
     """One layer's pools (_layer_pools) with [T, KV, D] new rows written
     at flat slots [T], every pool constrained to its KV-head sharding.
     Both serving sites write through here (decode unless it fuses the
-    write into the attention call)."""
+    write into the attention call). A latent cache is one pool: k_new
+    holds its [T, C] rows and v_new nothing."""
+    if len(pools) == 1:
+        write = paged_latent_write if use_kernel else paged_latent_write_xla
+        return (write(pools[0], k_new, flat_idx),)
     if len(pools) == 4:
         ck, cv, *scales = _write_kv_quant(*pools, k_new, v_new, flat_idx,
                                           mesh, use_kernel)
@@ -507,7 +544,17 @@ _SCAN_ROWS_PER_EXPERT = (2, 128)
 def expert_path(n_tokens: int, cfg: T.TransformerConfig) -> str:
     """Which expert path a compiled serving program over `n_tokens`
     rows takes: 'scan' or 'ragged'. A function of the call's static
-    shape alone (no flag selects it)."""
+    shape alone (no flag selects it).
+
+    A chip that holds a SHARE of the experts (cfg.experts_held) scans
+    its held experts whatever the rows: the rows a held expert sees are
+    the same T x k / X an expert, but how many pairs reach the held
+    ones varies by iteration and is known on the device alone, so the
+    ragged wire would have to gather and sort every pair, of which
+    held / X stay. The scan streams each held expert once and drops
+    the pairs routed elsewhere in its weight matrix."""
+    if cfg.experts_held is not None:
+        return "scan"
     rows = n_tokens * cfg.moe_top_k / cfg.n_experts
     lo, hi = _SCAN_ROWS_PER_EXPERT
     return "scan" if lo < rows < hi else "ragged"
@@ -563,7 +610,7 @@ def _mlp(h, lp, cfg: T.TransformerConfig, census_cb=None):
     application stream out via jax.debug.callback — the scheduler's
     expert-utilization/imbalance counters (scheduler.metrics())."""
     act = T._act_fn(cfg)  # one dispatch table for train + serve
-    if cfg.n_experts == 0:
+    if "w_router" not in lp:  # a dense model, or a leading dense layer
         if cfg.is_gated:
             if "w_gi" in lp:
                 gi = _wmm("te,ef->tf", h, lp["w_gi"])
@@ -599,12 +646,25 @@ def _mlp(h, lp, cfg: T.TransformerConfig, census_cb=None):
     path = expert_path(T_, cfg)
     with jax.named_scope("moe_route"):
         logits = h.astype(jnp.float32) @ lp["w_router"].astype(jnp.float32)
-        # eval gate: no noise; one authority with the training paths
-        idx, wts, _, _ = dropless_topk_gating(
-            logits, cfg.moe_top_k, renormalize=cfg.moe_norm_topk_prob)
+        if cfg.moe_scoring == "sigmoid":
+            idx, wts = _sigmoid_topk_gating(logits, cfg)
+        else:
+            # eval gate: no noise; one authority with the training paths
+            idx, wts, _, _ = dropless_topk_gating(
+                logits, cfg.moe_top_k, renormalize=cfg.moe_norm_topk_prob)
         if census_cb is not None:
             jax.debug.callback(census_cb, expert_counts(idx, X))
-        if path == "scan":
+        if cfg.experts_held is not None:
+            # this chip's share: the router chose among all X; a pair
+            # routed to an expert held elsewhere lands on column Xh,
+            # which the weight matrix does not have, and is dropped
+            start, Xh = cfg.experts_held
+            held = (idx >= start) & (idx < start + Xh)
+            weights = jnp.zeros((T_, Xh), jnp.float32).at[
+                jnp.arange(T_)[:, None], jnp.where(held, idx - start, Xh)
+            ].add(wts, mode="drop")
+            wcols = weights.T.astype(h.dtype)
+        elif path == "scan":
             # combine-weight matrix [T, X] from the top-k decisions
             weights = jnp.zeros((T_, X), jnp.float32).at[
                 jnp.arange(T_)[:, None], idx].add(wts)
@@ -649,7 +709,27 @@ def _mlp(h, lp, cfg: T.TransformerConfig, census_cb=None):
 
     with jax.named_scope("moe_experts"):
         out, _ = jax.lax.scan(expert, jnp.zeros_like(h), tuple(xs))
+    if cfg.n_shared_experts:
+        with jax.named_scope("moe_shared"):
+            # every token, unweighted, on every chip alike
+            out = out + _wmm(
+                "tf,fe->te", act(_wmm("te,ef->tf", h, lp["ws_gate"]))
+                * _wmm("te,ef->tf", h, lp["ws_in"]), lp["ws_out"])
     return _moe_residual(out, h, lp, cfg, act)
+
+
+def _sigmoid_topk_gating(logits, cfg: T.TransformerConfig):
+    """Sigmoid-scored top-k (DeepSeek-V3 class routers): each expert's
+    score is the sigmoid of its own logit, in float32; the k largest
+    are chosen (ties to the lowest index), their scores divided by
+    their sum when cfg.moe_norm_topk_prob, then multiplied by
+    cfg.routed_scaling_factor. No groups, no correction bias.
+    logits [T, X] f32 -> (idx [T, k] int32, weights [T, k] f32)."""
+    scores = jax.nn.sigmoid(logits)
+    wts, idx = jax.lax.top_k(scores, cfg.moe_top_k)
+    if cfg.moe_norm_topk_prob:
+        wts = wts / (jnp.sum(wts, axis=-1, keepdims=True) + 1e-20)
+    return idx, wts * cfg.routed_scaling_factor
 
 
 def _ffn_residual(x, attn_out, h1, lp, cfg: T.TransformerConfig,
@@ -670,6 +750,8 @@ def _ffn_residual(x, attn_out, h1, lp, cfg: T.TransformerConfig,
     with jax.named_scope("mlp"):
         y = _mlp(h2.reshape(-1, h2.shape[-1]), lp, cfg,
                  census_cb=census_cb).reshape(x.shape)
+        if cfg.sandwich_norm:
+            y = T._norm(y, lp["ln2_post_scale"], None, cfg)
     return x + attn_out + y if cfg.parallel_residual else x + y
 
 
@@ -757,7 +839,7 @@ def _layer(x, lp, li: int, positions, cfg: T.TransformerConfig, mesh, attend,
     prefill prompts [B, Tp, E]): norm1, the QKV projection (fused w_qkv
     or split, bias or none), QK-norm, rope at `positions` (the
     second-to-last axis of q/k: [S] or [Tp]), the head constraints,
-    `attend(q, k, v, li, alibi) -> (att, layer_cache)` handed in by the
+    `attend(q, k, v, li, alibi, lp) -> (att, layer_cache)` handed in by the
     caller (the ONE thing the two sites differ in: what attention runs
     and how the new rows reach the cache), the output projection and
     the FFN tail. Returns (x, layer_cache)."""
@@ -765,6 +847,17 @@ def _layer(x, lp, li: int, positions, cfg: T.TransformerConfig, mesh, attend,
     with jax.named_scope("norm1"):
         h1 = T._act_quant(
             T._norm(x, lp["ln1_scale"], lp.get("ln1_bias"), cfg), cfg)
+    if cfg.is_latent:
+        with jax.named_scope("attention"):
+            with jax.named_scope("mla_project"):
+                q, row = _latent_project(h1, lp, positions, cfg)
+            # k: the row the cache holds for each token; no v
+            att, layer_cache = attend(q, row, None, li, None, lp)
+            with jax.named_scope("mla_out"):
+                out = _wmm("...hd,hde->...e", att, lp["wo"])
+                if cfg.sandwich_norm:
+                    out = T._norm(out, lp["ln1_post_scale"], None, cfg)
+        return _ffn_residual(x, out, h1, lp, cfg, census_cb), layer_cache
     with jax.named_scope("attention"):
         if "w_qkv" in lp:
             qkv = _wmm("...e,ehd->...hd", h1, lp["w_qkv"])
@@ -787,11 +880,120 @@ def _layer(x, lp, li: int, positions, cfg: T.TransformerConfig, mesh, attend,
         q = _cons(q, mesh, *heads)
         k = _cons(k, mesh, *heads)
         v = _cons(v, mesh, *heads)
-        att, layer_cache = attend(q, k, v, li, alibi)
+        att, layer_cache = attend(q, k, v, li, alibi, lp)
         out = _wmm("...hd,hde->...e", att, lp["wo"])
         if "bo" in lp:
             out = out + lp["bo"].astype(x.dtype)
+        if cfg.sandwich_norm:
+            out = T._norm(out, lp["ln1_post_scale"], None, cfg)
     return _ffn_residual(x, out, h1, lp, cfg, census_cb), layer_cache
+
+
+# ---------------------------------------------------------------------------
+# latent attention (MLA): the projections, and the two forms
+# ---------------------------------------------------------------------------
+
+def _latent_project(h1, lp, positions, cfg: T.TransformerConfig):
+    """normed activations h1 [..., E] -> (q [..., H, Dn + Dr] with its
+    last Dr rotated, row [..., Rkv + Dr]: what the cache holds for the
+    token, the RMSNorm'd latent and the rotated shared key)."""
+    Dn, Rkv = cfg.qk_nope_head_dim, cfg.kv_lora_rank
+    cq = T._norm(_wmm("...e,er->...r", h1, lp["wq_a"]),
+                 lp["q_a_scale"], None, cfg)
+    q = _wmm("...r,rhd->...hd", cq, lp["wq_b"])
+    q = jnp.concatenate(
+        [q[..., :Dn], _rope_at(q[..., Dn:], positions, cfg)], axis=-1)
+    ckv = _wmm("...e,ec->...c", h1, lp["wkv_a"])
+    c = T._norm(ckv[..., :Rkv], lp["kv_a_scale"], None, cfg)
+    # one rotary key for all heads: a head axis of 1 for _rope_at
+    kr = _rope_at(ckv[..., None, Rkv:], positions, cfg)[..., 0, :]
+    return q, jnp.concatenate([c, kr], axis=-1)
+
+
+def _pad_lanes(x, width: int):
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, width - x.shape[-1])])
+
+
+def _latent_absorbed(q, row, lp, pool, tables, ctx_lens, flat_idx,
+                     cfg: T.TransformerConfig, use_kernel: bool):
+    """Decode and chunk rows, the ABSORBED form: rows [S] written to
+    the latent pool, then every head's query moved into the latent
+    space (q~ = W_uk^T q_nope, so score = q~ . latent + q_rope . k_rope)
+    and attended as multi-query attention over the ONE cached row a
+    token; the attended latent goes through W_uv. No key or value of
+    any head is ever materialised. -> (att [S, H, Dv], (pool,))"""
+    Dn, Rkv = cfg.qk_nope_head_dim, cfg.kv_lora_rank
+    C = pool.shape[-1]
+    with jax.named_scope("mla_cache_write"):
+        (pool,) = _write_pools((pool,), _pad_lanes(row, C), None, flat_idx,
+                               None, use_kernel)
+    with jax.named_scope("mla_project"):
+        qt = _wmm("shn,chn->shc", q[..., :Dn], lp["w_uk"])
+        scale = 1.0 / (q.shape[-1] ** 0.5)
+        ql = _pad_lanes(jnp.concatenate([qt, q[..., Dn:]], axis=-1) * scale,
+                        C).astype(q.dtype)
+    with jax.named_scope("mla_attend"):
+        walk = (paged_latent_attention if use_kernel
+                else paged_latent_attention_xla)
+        u = walk(ql, pool, tables, ctx_lens, Rkv)
+    with jax.named_scope("mla_out"):
+        att = _wmm("shc,chv->shv", u, lp["w_uv"])
+    return att, (pool,)
+
+
+# heads a whole-prompt latent prefill up-projects and attends at a time:
+# K and V of every head of every prompt token at once are gigabytes at
+# 128 heads (B 2 x T 4096: 3 x 0.5 GB, twice over inside the flash call)
+_LATENT_PREFILL_HEADS = 32
+
+
+def _latent_naive(q, row, lp, pool, flat_idx, cfg: T.TransformerConfig,
+                  use_kernel: bool):
+    """Whole-prompt prefill, the NAIVE form: rows [B, Tp] written to
+    the latent pool, then every head's key [nope; shared rope] and
+    value up-projected from the latent and attended causally. Chosen
+    for whole prompts from the shapes alone: it multiplies (Dn + Dr +
+    Dv) per score against the absorbed form's (2 Rkv + Dr), fewer at
+    every length, and its rows need no per-row block table. The flash
+    kernel takes one head dim for q, k and v, a lane multiple: all
+    three are zero-padded to it and q pre-scaled so that the kernel's
+    1/sqrt(padded) comes out as 1/sqrt(Dn + Dr).
+    -> (att [B, Tp, H, Dv], (pool,))"""
+    B, Tp, H, Dq = q.shape
+    Rkv, Dv = cfg.kv_lora_rank, cfg.v_head_dim
+    with jax.named_scope("mla_cache_write"):
+        (pool,) = _write_pools(
+            (pool,), _pad_lanes(row, pool.shape[-1]).reshape(B * Tp, -1),
+            None, flat_idx, None, use_kernel)
+    c, kr = row[..., :Rkv], row[..., Rkv:]
+    flash = uses_flash(q, use_kernel and cfg.use_flash)
+    Dp = latent_lanes(max(Dq, Dv)) if flash else Dq
+    hg = min(H, _LATENT_PREFILL_HEADS)
+    assert H % hg == 0, (H, hg)
+
+    def heads(args):
+        qg, w_uk, w_uv = args  # [B, Tp, hg, Dq], [Rkv, hg, Dn], [Rkv, hg, Dv]
+        k = jnp.concatenate(
+            [_wmm("btc,chn->bthn", c, w_uk),
+             jnp.broadcast_to(kr[:, :, None, :], (B, Tp, hg, kr.shape[-1]))],
+            axis=-1)
+        v = _wmm("btc,chv->bthv", c, w_uv)
+        if not flash:
+            return causal_attention(qg, k, v, use_flash=False)
+        qs = (qg * ((Dp / Dq) ** 0.5)).astype(qg.dtype)
+        return causal_attention(
+            _pad_lanes(qs, Dp), _pad_lanes(k, Dp), _pad_lanes(v, Dp),
+            use_flash=True)[..., :Dv]
+
+    def split(w, axis):  # the head axis -> [H / hg, ..., hg, ...] leading
+        w = w.reshape(*w.shape[:axis], H // hg, hg, *w.shape[axis + 1:])
+        return jnp.moveaxis(w, axis, 0)
+
+    with jax.named_scope("mla_attend"):
+        att = jax.lax.map(heads, (split(q, 2), split(lp["w_uk"], 1),
+                                  split(lp["w_uv"], 1)))
+    # [H / hg, B, Tp, hg, Dv] -> [B, Tp, H, Dv]
+    return jnp.moveaxis(att, 0, 2).reshape(B, Tp, H, Dv), (pool,)
 
 
 def _forward(params, tokens, positions, cfg: T.TransformerConfig, mesh,
@@ -817,7 +1019,10 @@ def _forward(params, tokens, positions, cfg: T.TransformerConfig, mesh,
 
     pools = []  # per layer, as _layer_pools
     x_hist = []  # layer outputs; fetch l is barriered on output l-2
-    for li, lp in enumerate(params["layers"]):
+    # leading dense layers first (cache layers 0..n_dense-1), then the
+    # stacked ones
+    for li, lp in enumerate(list(params.get("dense_layers", ()))
+                            + list(params["layers"])):
         if fetch_layer is not None:
             lp = fetch_layer(lp, x_hist[-2] if len(x_hist) >= 2 else None,
                              li)
@@ -832,7 +1037,8 @@ def _forward(params, tokens, positions, cfg: T.TransformerConfig, mesh,
         x = T._norm(x, params["ln_f_scale"], params.get("ln_f_bias"), cfg)
         logits = _lm_logits(x, params, cfg)
         logits = _cons(logits, mesh, None, None)
-    return logits, PagedCache(*(list(pool) for pool in zip(*pools)))
+    new = PagedCache(*(list(pool) for pool in zip(*pools)))
+    return logits, new._replace(v=[]) if cfg.is_latent else new
 
 
 # ---------------------------------------------------------------------------
@@ -881,7 +1087,11 @@ def decode_step(
     # separate write)
     fuse_write = unique_rows and use_kernel and _tp_size(mesh) <= 1
 
-    def attend(q, k, v, li, alibi):
+    def attend(q, k, v, li, alibi, lp):
+        if cfg.is_latent:  # k: the rows the cache holds
+            return _latent_absorbed(q, k, lp, *_layer_pools(cache, li),
+                                    tables, ctx_lens, flat_idx, cfg,
+                                    use_kernel)
         where = (tables, ctx_lens, use_kernel, cfg.window_for_layer(li),
                  mesh, alibi)
         pools = _layer_pools(cache, li)
@@ -1006,7 +1216,10 @@ def prefill_batch(
         jnp.int32(-1),
     ).reshape(B * Tp)
 
-    def attend(q, k, v, li, alibi):
+    def attend(q, k, v, li, alibi, lp):
+        if cfg.is_latent:  # k: the rows the cache holds
+            return _latent_naive(q, k, lp, *_layer_pools(cache, li),
+                                 flat_idx, cfg, use_kernel)
         # the prompt's in-flight attention stays full precision (it
         # never reads the cache); only the RESIDENT copy quantizes —
         # later decode steps read these codes

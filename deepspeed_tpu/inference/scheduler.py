@@ -237,6 +237,11 @@ class ServingScheduler:
             # ceil(ctx / kv_block_size); over steps, what the paged
             # attention kernel's time should follow
             "kv_live_blocks": 0,
+            # cached tokens the rows of the dispatched programs attended
+            # over in ONE latent-attention layer (sum over rows of ctx;
+            # 0 unless the model caches a latent): what the latent walk
+            # multiplies, whatever it reads once a table
+            "mla_cache_tokens": 0,
         }
         self._phases = profiler.Phases("sched", "iteration", PHASES,
                                        sums=self.counters)
@@ -934,6 +939,8 @@ class ServingScheduler:
             live = ctx[ctx > 0][:, None] + np.arange(steps)
             bs = self.engine.config.kv_block_size
             self.counters["kv_live_blocks"] += int(np.sum(-(-live // bs)))
+            if cfg.is_latent:
+                self.counters["mla_cache_tokens"] += int(np.sum(live))
 
     def _dispatch_mixed(self, rows, ahead_of: Optional[_Step] = None,
                         src: Optional[Dict[int, int]] = None

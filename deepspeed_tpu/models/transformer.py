@@ -169,8 +169,65 @@ class TransformerConfig:
     # and k vectors (all heads together, n_heads * head_dim values) with
     # a learned scale of that length, before the head split's rope.
     qk_norm: bool = False
+    # ---- latent attention + shared/held experts (DeepSeek-V2 class;
+    # openPangu-Ultra-MoE). SERVING ONLY: init() makes the tree and
+    # inference/model.py runs it; the training forward refuses it.
+    # kv_lora_rank > 0 turns attention into multi-head latent attention:
+    # queries through a q_lora_rank bottleneck with its own RMSNorm,
+    # keys/values through ONE kv_lora_rank latent (RMSNorm'd) plus ONE
+    # rotary key of qk_rope_head_dim shared by all heads; a head's query
+    # and key are [nope (qk_nope_head_dim); rope (qk_rope_head_dim)],
+    # its value v_head_dim. What serving caches is the latent and the
+    # rotary key: kv_lora_rank + qk_rope_head_dim values a token a layer.
+    kv_lora_rank: int = 0
+    q_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # a second RMSNorm on each sub-layer's OUTPUT, before the residual
+    # add: x + N_post(f(N_pre(x))) (four norms a layer)
+    sandwich_norm: bool = False
+    # shared experts beside the routed ones: one dense gated MLP of
+    # n_shared_experts * d_ff every token passes through, unweighted
+    n_shared_experts: int = 0
+    # router scores: "softmax" over the experts, or "sigmoid" of each
+    # logit (then the chosen weights are divided by their sum when
+    # moe_norm_topk_prob, and multiplied by routed_scaling_factor)
+    moe_scoring: str = "softmax"
+    routed_scaling_factor: float = 1.0
+    # leading dense layers BEFORE the stacked ones: n_layers counts the
+    # stacked (routed) layers alone, the model's depth is
+    # n_dense_layers + n_layers. Their weights are top-level leaves
+    # `dense_<name>` [n_dense_layers, ...] (the tree keeps ONE
+    # homogeneous `layers` stack); their MLP is dense of width dense_d_ff
+    n_dense_layers: int = 0
+    dense_d_ff: Optional[int] = None
+    # (start, count): the slice of the n_experts routed experts THIS
+    # chip holds under expert parallelism. The router keeps all
+    # n_experts outputs; the expert stacks hold `count`; pairs routed
+    # elsewhere add nothing here (their chips add it). None: all held.
+    experts_held: Optional[Tuple[int, int]] = None
 
     def __post_init__(self):
+        if self.moe_scoring not in ("softmax", "sigmoid"):
+            raise ValueError(
+                f"unknown moe_scoring {self.moe_scoring!r} (softmax|sigmoid)")
+        if self.kv_lora_rank > 0 and not (
+                self.q_lora_rank > 0 and self.qk_nope_head_dim > 0
+                and self.qk_rope_head_dim > 0 and self.v_head_dim > 0
+                and self.qk_rope_head_dim % 2 == 0):
+            raise ValueError(
+                "latent attention (kv_lora_rank > 0) needs q_lora_rank, "
+                "qk_nope_head_dim, v_head_dim and an even qk_rope_head_dim")
+        if self.experts_held is not None:
+            start, count = self.experts_held
+            if not (0 <= start and count >= 1
+                    and start + count <= self.n_experts):
+                raise ValueError(
+                    f"experts_held {self.experts_held} is no slice of "
+                    f"{self.n_experts} experts")
+        if self.n_dense_layers and self.dense_d_ff is None:
+            raise ValueError("n_dense_layers needs dense_d_ff")
         if self.rope_scaling_type not in ("none", "linear", "llama3"):
             raise ValueError(
                 f"unsupported rope_scaling_type '{self.rope_scaling_type}' "
@@ -306,6 +363,34 @@ class TransformerConfig:
         return self.sliding_window
 
     @property
+    def is_latent(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def latent_dim(self) -> int:
+        """Values serving caches for one token in one latent layer."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def serving_only(self) -> Tuple[str, ...]:
+        """The fields set here that only inference/model.py computes:
+        the training forward refuses a configuration that has any."""
+        return tuple(k for k in ("kv_lora_rank", "sandwich_norm",
+                                 "n_shared_experts", "n_dense_layers",
+                                 "experts_held") if getattr(self, k)) + (
+            ("moe_scoring",) if self.moe_scoring != "softmax" else ())
+
+    @property
+    def depth(self) -> int:
+        """Layers a token passes: leading dense + stacked."""
+        return self.n_dense_layers + self.n_layers
+
+    @property
+    def n_experts_held(self) -> int:
+        return (self.n_experts if self.experts_held is None
+                else self.experts_held[1])
+
+    @property
     def kv_heads(self) -> int:
         return self.n_kv_heads or self.n_heads
 
@@ -344,16 +429,47 @@ def param_count(cfg: TransformerConfig) -> int:
 # params + logical specs
 # ---------------------------------------------------------------------------
 
-def _layer_shapes(cfg: TransformerConfig) -> Dict[str, Tuple[Tuple[int, ...], Tuple]]:
-    """name -> (shape-without-layer-dim, logical axes-without-layer-dim)"""
-    E, H, KV, D, F = cfg.d_model, cfg.n_heads, cfg.kv_heads, cfg.head_dim, cfg.ff_dim
-    shapes = {
-        "ln1_scale": ((E,), ("embed",)),
-        "wq": ((E, H, D), ("embed", "heads", "head_dim")),
-        "wk": ((E, KV, D), ("embed", "heads", "head_dim")),
-        "wv": ((E, KV, D), ("embed", "heads", "head_dim")),
-        "wo": ((H, D, E), ("heads", "head_dim", "embed")),
+def _latent_attention_shapes(cfg: TransformerConfig):
+    """The latent-attention leaves of one layer (names after the
+    published checkpoints' q_a / q_b / kv_a / kv_b projections): the
+    query bottleneck and its norm, the up-projection to every head's
+    [nope; rope] query, the down-projection to [latent; shared rotary
+    key], the latent's norm, the up-projection to every head's
+    [nope key; value], and the output projection."""
+    E, H = cfg.d_model, cfg.n_heads
+    Rq, Rkv = cfg.q_lora_rank, cfg.kv_lora_rank
+    Dn, Dr, Dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    return {
+        "wq_a": ((E, Rq), ("embed", None)),
+        "q_a_scale": ((Rq,), (None,)),
+        "wq_b": ((Rq, H, Dn + Dr), (None, "heads", "head_dim")),
+        "wkv_a": ((E, Rkv + Dr), ("embed", None)),
+        "kv_a_scale": ((Rkv,), (None,)),
+        "wkv_b": ((Rkv, H, Dn + Dv), (None, "heads", "head_dim")),
+        "wo": ((H, Dv, E), ("heads", "head_dim", "embed")),
     }
+
+
+def _layer_shapes(cfg: TransformerConfig, dense: bool = False
+                  ) -> Dict[str, Tuple[Tuple[int, ...], Tuple]]:
+    """name -> (shape-without-layer-dim, logical axes-without-layer-dim).
+    dense: a LEADING dense layer of a model whose stacked layers are
+    routed (cfg.n_dense_layers): the same attention and norms, a dense
+    MLP of width cfg.dense_d_ff."""
+    E, H, KV, D, F = cfg.d_model, cfg.n_heads, cfg.kv_heads, cfg.head_dim, cfg.ff_dim
+    shapes = {"ln1_scale": ((E,), ("embed",))}
+    if cfg.is_latent:
+        shapes.update(_latent_attention_shapes(cfg))
+    else:
+        shapes.update({
+            "wq": ((E, H, D), ("embed", "heads", "head_dim")),
+            "wk": ((E, KV, D), ("embed", "heads", "head_dim")),
+            "wv": ((E, KV, D), ("embed", "heads", "head_dim")),
+            "wo": ((H, D, E), ("heads", "head_dim", "embed")),
+        })
+    if cfg.sandwich_norm:
+        shapes["ln1_post_scale"] = ((E,), ("embed",))
+        shapes["ln2_post_scale"] = ((E,), ("embed",))
     if not cfg.shared_ln:
         shapes["ln2_scale"] = ((E,), ("embed",))
     if cfg.qk_norm:
@@ -361,19 +477,31 @@ def _layer_shapes(cfg: TransformerConfig) -> Dict[str, Tuple[Tuple[int, ...], Tu
         # head sharding of wq / wk applies to it as it stands
         shapes["q_norm_scale"] = ((H, D), ("heads", "head_dim"))
         shapes["k_norm_scale"] = ((KV, D), ("heads", "head_dim"))
-    X = cfg.n_experts
+    X = 0 if dense else cfg.n_experts
+    if dense:
+        F = cfg.dense_d_ff
     if X > 0:
         # Expert-stacked FFN weights: leading experts dim shards over the
         # 'expert' mesh axis; the expert-hidden dim may additionally shard
         # over 'model' (ref: moe/experts.py local expert bundle — here one
         # stacked array instead of a ModuleList).
+        # the router spans every expert; the stacks hold this chip's
+        # share of them (cfg.experts_held; all of them when None)
+        Xh = cfg.n_experts_held
         shapes.update({
             "w_router": ((E, X), ("embed", None)),
-            "w_in": ((X, E, F), ("expert", "embed", "expert_mlp")),
-            "w_out": ((X, F, E), ("expert", "expert_mlp", "embed")),
+            "w_in": ((Xh, E, F), ("expert", "embed", "expert_mlp")),
+            "w_out": ((Xh, F, E), ("expert", "expert_mlp", "embed")),
         })
         if cfg.is_gated:
-            shapes["w_gate"] = ((X, E, F), ("expert", "embed", "expert_mlp"))
+            shapes["w_gate"] = ((Xh, E, F), ("expert", "embed", "expert_mlp"))
+        if cfg.n_shared_experts:
+            Fs = cfg.n_shared_experts * F
+            shapes.update({
+                "ws_gate": ((E, Fs), ("embed", "mlp")),
+                "ws_in": ((E, Fs), ("embed", "mlp")),
+                "ws_out": ((Fs, E), ("mlp", "embed")),
+            })
         if cfg.moe_use_residual:
             # PR-MoE: dense residual expert + mixing coefficient
             shapes.update({
@@ -399,6 +527,8 @@ def _layer_shapes(cfg: TransformerConfig) -> Dict[str, Tuple[Tuple[int, ...], Tu
         if not cfg.shared_ln:
             shapes["ln2_bias"] = ((E,), ("embed",))
     if cfg.has_mlp_bias:
+        if cfg.experts_held is not None:
+            raise NotImplementedError("mlp biases on a held share of experts")
         shapes["b_in"] = (((X, F) if X > 0 else (F,)),
                           (("expert", "expert_mlp") if X > 0 else ("mlp",)))
         shapes["b_out"] = (((X, E) if X > 0 else (E,)),
@@ -410,6 +540,10 @@ def _layer_shapes(cfg: TransformerConfig) -> Dict[str, Tuple[Tuple[int, ...], Tu
     if cfg.has_attn_out_bias:
         shapes["bo"] = ((E,), ("embed",))
     return shapes
+
+
+# top-level leaves of the leading dense layers (cfg.n_dense_layers)
+DENSE_PREFIX = "dense_"
 
 
 def init(cfg: TransformerConfig, rng) -> Dict[str, Any]:
@@ -437,19 +571,28 @@ def init(cfg: TransformerConfig, rng) -> Dict[str, Any]:
         if cfg.lm_head_bias:
             params["lm_head_b"] = jnp.zeros((V,), jnp.float32)
 
-    layers = {}
-    lkeys = jax.random.split(keys[3], len(_layer_shapes(cfg)))
-    for i, (name, (shape, _)) in enumerate(sorted(_layer_shapes(cfg).items())):
-        full = (L,) + shape
-        if "ln" in name or name.endswith("_norm_scale"):
-            layers[name] = jnp.broadcast_to(norm_init(shape, name), full).copy()
-        elif name.startswith("b"):
-            layers[name] = jnp.zeros(full, jnp.float32)
-        else:
-            scale = std / (2 * L) ** 0.5 if name in ("wo", "w_out",
-                                                     "wr_out") else std
-            layers[name] = jax.random.normal(lkeys[i], full, jnp.float32) * scale
-    params["layers"] = layers
+    def stack(key, depth: int, dense: bool):
+        shapes = _layer_shapes(cfg, dense)
+        out = {}
+        lkeys = jax.random.split(key, len(shapes))
+        for i, (name, (shape, _)) in enumerate(sorted(shapes.items())):
+            full = (depth,) + shape
+            if "ln" in name or name.endswith("_scale"):
+                out[name] = jnp.broadcast_to(norm_init(shape, name), full).copy()
+            elif name.startswith("b"):
+                out[name] = jnp.zeros(full, jnp.float32)
+            else:
+                scale = std / (2 * cfg.depth) ** 0.5 if name in (
+                    "wo", "w_out", "wr_out", "ws_out") else std
+                out[name] = jax.random.normal(lkeys[i], full, jnp.float32) * scale
+        return out
+
+    params["layers"] = stack(keys[3], L, dense=False)
+    # leading dense layers: top-level `dense_<name>` [n_dense, ...], so
+    # `layers` stays one homogeneous stack
+    for name, w in stack(keys[4], cfg.n_dense_layers, dense=True).items() \
+            if cfg.n_dense_layers else ():
+        params[DENSE_PREFIX + name] = w
     if cfg.pipeline_stages > 1:
         from ..runtime.pipe import partition_layers
 
@@ -486,6 +629,9 @@ def logical_specs(cfg: TransformerConfig) -> Dict[str, Any]:
     specs["layers"] = {
         name: lead + logical for name, (_, logical) in _layer_shapes(cfg).items()
     }
+    if cfg.n_dense_layers:
+        for name, (_, logical) in _layer_shapes(cfg, dense=True).items():
+            specs[DENSE_PREFIX + name] = ("layers",) + logical
     return specs
 
 
@@ -546,6 +692,8 @@ def rope_dim(cfg: TransformerConfig) -> int:
     """Rotated dims per head: head_dim, or the partial-rotary slice
     (Phi/NeoX partial_rotary_factor — rope applies to the first
     rotary_pct * head_dim dims, the rest pass through)."""
+    if cfg.is_latent:
+        return cfg.qk_rope_head_dim
     R = int(cfg.rotary_pct * cfg.head_dim)
     return R - (R % 2)
 
@@ -1068,6 +1216,10 @@ def forward_hidden(
     pld_theta: traced scalar keep-floor for Progressive Layer Dropping
     (requires rng; eval passes rng=None, which disables PLD like the
     reference's eval forward)."""
+    if cfg.serving_only:
+        raise NotImplementedError(
+            f"the training forward does not compute {list(cfg.serving_only)}: "
+            "this family is served (inference/model.py) and not trained here")
     with jax.named_scope("embed"):
         x = params["embed"][tokens]
         x = _shard(x, DP, "seq", None)

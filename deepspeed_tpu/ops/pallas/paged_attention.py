@@ -1040,3 +1040,284 @@ def paged_scale_write(k_scale, v_scale, ks_new, vs_new, flat_slots):
         k_scale.reshape(NBLK, bs, 1, KV), v_scale.reshape(NBLK, bs, 1, KV),
         ks_new[:, None, :], vs_new[:, None, :], flat_slots)
     return ck.reshape(NBLK, bs, KV), cv.reshape(NBLK, bs, KV)
+
+
+# ---------------------------------------------------------------------------
+# latent attention (MLA): one shared key of width latent + rope whose
+# first `v_dim` values are also the value
+#
+# The pool is [num_blocks, block_size, C], one row a token and no V
+# pool. C is kv_lora_rank + qk_rope_head_dim PADDED to whole lanes
+# (latent_lanes: 576 -> 640): the TPU's tiled HBM layout pads the
+# minor dim to 128 whatever the array says, and Mosaic takes a manual
+# DMA of a block only if its minor dim fills whole tiles ("Slice shape
+# along dimension 2 must be aligned to tiling (128), but is 576", AOT
+# for v5e). So the pad lanes are in the shape, hold zeros, and cost
+# 64 / 576 = 11% of the pool's bytes and of the score matmul. Decode is the
+# absorbed form: every head's query arrives already multiplied into the
+# latent space, [S, H, C], so a row is multi-query attention of H heads
+# over ONE key, and all H heads are the rows of one MXU matmul.
+# ---------------------------------------------------------------------------
+
+def _latent_rows_kernel(
+    tbl_ref, ctx_ref, grp_ref,                      # scalar prefetch
+    q_ref, pool_any,                                # inputs (pool in HBM)
+    o_ref, buf, lsem,                               # out, scratch
+    *, n_seqs: int, block_size: int, v_dim: int,
+):
+    """Row s's online softmax over the live blocks of its table, all
+    heads at once, with every live block of a TABLE read from HBM once
+    for all of that table's rows: `buf` [2, NB, bs, C] holds the whole
+    table (a buffer set per table, alternating), rows of one table
+    follow each other (grp_ref: the table's index, rising by one where
+    the table changes; _LATENT_GROUP blocks are multiplied a step), and
+    a row that shares its predecessor's table
+    finds that row's blocks resident and loads only the blocks its
+    longer context adds. The row BEFORE a new table starts all of that
+    table's first row's loads into the other set, so they land while it
+    computes. Every load started is waited exactly once, by the first
+    row that needs it. Live blocks only (as _walk_live_blocks): a dead
+    table slot costs no DMA and no step."""
+    bs = block_size
+    n_blk = pool_any.shape[0]
+    s = pl.program_id(0)
+
+    def nblk_of(r):
+        return pl.cdiv(ctx_ref[r], bs)
+
+    def fresh_of(r):
+        return jnp.logical_or(
+            r == 0, grp_ref[r] != grp_ref[jnp.maximum(r - 1, 0)])
+
+    def load(r, j):
+        blk = _arena_block(tbl_ref[r, j], n_blk)
+        pltpu.make_async_copy(pool_any.at[blk], buf.at[grp_ref[r] % 2, j],
+                              lsem.at[grp_ref[r] % 2, j]).start()
+
+    def load_range(r, lo, hi):
+        def one(j, c):
+            load(r, j)
+            return c
+
+        jax.lax.fori_loop(lo, hi, one, 0)
+
+    ctx = ctx_ref[s]
+    nblk = nblk_of(s)
+    fresh = fresh_of(s)
+    bufset = grp_ref[s] % 2
+    # blocks of this table resident before this row (loaded AND waited
+    # by the rows before it); a fresh row's were started, not waited
+    resident = jnp.where(fresh, 0, nblk_of(jnp.maximum(s - 1, 0)))
+
+    @pl.when(s == 0)
+    def _first_row():
+        # a group's last blocks may lie beyond the row's live ones: they
+        # are masked out of the softmax, but 0 x NaN would still poison
+        # the accumulator, so the buffers start as zeros and only ever
+        # hold pool rows after that
+        def zero(i, c):
+            buf[i // buf.shape[1], i % buf.shape[1]] = jnp.zeros(
+                buf.shape[2:], buf.dtype)
+            return c
+
+        jax.lax.fori_loop(0, 2 * buf.shape[1], zero, 0)
+        load_range(0, 0, nblk)
+
+    @pl.when(jnp.logical_not(fresh))
+    def _the_blocks_a_longer_context_adds():
+        load_range(s, resident, nblk)
+
+    @pl.when(s + 1 < n_seqs)
+    def _next_table():
+        nxt = jnp.minimum(s + 1, n_seqs - 1)
+
+        @pl.when(fresh_of(nxt))
+        def _start():
+            load_range(nxt, 0, nblk_of(nxt))
+
+    q = q_ref[0]  # (H, C), the softmax scale folded in by the caller
+    H = q.shape[0]
+    G = _LATENT_GROUP
+
+    def body(g, carry):
+        m, l, acc = carry
+        j0 = g * G
+        for i in range(G):
+            @pl.when(jnp.logical_and(j0 + i < nblk, j0 + i >= resident))
+            def _wait(j=j0 + i):
+                pltpu.make_async_copy(pool_any.at[0], buf.at[bufset, j],
+                                      lsem.at[bufset, j]).wait()
+
+        # G blocks a step: the accumulator is rescaled once for G * bs
+        # columns (at one block a step the (H, v_dim) f32 rescale, not
+        # the MXU, set the pace: chip, PR 33)
+        kb = buf[bufset, pl.ds(j0, G)].reshape(G * bs, buf.shape[-1])
+        st = _dot(q, kb, trans_b=True)  # (H, G * bs)
+
+        def mask(st):
+            cols = j0 * bs + jax.lax.broadcasted_iota(jnp.int32, st.shape, 1)
+            return jnp.where(cols < ctx, st, NEG_INF)
+
+        # only a row's last group holds columns past its context
+        st = jax.lax.cond((j0 + G) * bs > ctx, mask, lambda st: st, st)
+        m_new = jnp.maximum(m, jnp.max(st, axis=1, keepdims=True))
+        p = jnp.exp(st - m_new)
+        corr = jnp.exp(m - m_new)
+        l = l * corr + jnp.sum(p, axis=1, keepdims=True)
+        acc = acc * corr + _dot(p.astype(kb.dtype), kb[:, :v_dim])
+        return m_new, l, acc
+
+    init = (jnp.full((H, 1), NEG_INF, jnp.float32),
+            jnp.zeros((H, 1), jnp.float32),
+            jnp.zeros((H, v_dim), jnp.float32))
+    _, l, acc = jax.lax.fori_loop(0, pl.cdiv(nblk, G), body, init)
+    l_safe = jnp.where(l == 0.0, 1.0, l)  # ctx 0: batch padding, zeros
+    o_ref[0] = (acc / l_safe).astype(o_ref.dtype)
+
+
+# live blocks the latent walk multiplies in one step of its loop
+_LATENT_GROUP = 4
+
+
+def latent_lanes(latent_dim: int) -> int:
+    """The latent pool's minor dim: `latent_dim` padded to whole lanes."""
+    return -(-latent_dim // 128) * 128
+
+
+def latent_walk_fits(n_table_slots: int, pool) -> bool:
+    """Whether paged_latent_attention's kernel can take this pool: its
+    two whole-table buffer sets must fit the walk's VMEM budget beside
+    the double-buffered q and out rows. At 128-token blocks of 640 bf16
+    lanes that is tables of up to 153 blocks (19,584 tokens); the
+    engine refuses a longer context when it is built with the kernel."""
+    _, bs, C = pool.shape
+    slots = -(-n_table_slots // _LATENT_GROUP) * _LATENT_GROUP
+    need = 2 * slots * bs * C * pool.dtype.itemsize
+    return (C % 128 == 0 and pool.dtype.itemsize in (2, 4)
+            and need <= _WALK_VMEM_BUDGET)
+
+
+def table_groups(block_table):
+    """[S] int32: the index of each row's TABLE, rising by one wherever
+    a row's table differs from the row before it (rows of one prefill
+    chunk follow each other and share theirs)."""
+    same = jnp.all(block_table[1:] == block_table[:-1], axis=1)
+    return jnp.concatenate([jnp.zeros((1,), jnp.int32),
+                            jnp.cumsum(~same, dtype=jnp.int32)])
+
+
+def paged_latent_attention(q, pool, block_table, ctx_lens, v_dim: int):
+    """Absorbed-form latent attention of S rows over the paged latent
+    pool, rows already written (paged_latent_write ran first): THE
+    shared-table decode attention of a latent-attention model, so its
+    pallas_call carries that program's trace name, `paged_decode_grid`.
+
+    q: [S, H, C] queries in the latent space ([W_uk^T q_nope; q_rope]),
+       the 1/sqrt(d_qk) softmax scale already folded in
+    pool: [num_blocks, block_size, C]; a token's row is [latent (v_dim,
+       also the value); rotary key]
+    block_table: [S, NB] int32; rows sharing a table must be adjacent
+    ctx_lens: [S] int32, the row included; 0 = batch padding (zeros out)
+    returns [S, H, v_dim]: per head, sum of p * latent (the caller
+    applies W_uv)."""
+    S, H, C = q.shape
+    NB = block_table.shape[1]
+    bs = pool.shape[1]
+    # the buffers hold whole groups: a row's last group may reach past
+    # the table's last slot
+    NBp = -(-NB // _LATENT_GROUP) * _LATENT_GROUP
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(S,),
+        in_specs=[pl.BlockSpec((1, H, C), lambda s, *_: (s, 0, 0)),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((1, H, v_dim), lambda s, *_: (s, 0, 0)),
+        scratch_shapes=[pltpu.VMEM((2, NBp, bs, C), pool.dtype),
+                        pltpu.SemaphoreType.DMA((2, NBp))],
+    )
+    return pl.pallas_call(
+        functools.partial(_latent_rows_kernel, n_seqs=S, block_size=bs,
+                          v_dim=v_dim),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((S, H, v_dim), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_WALK_VMEM_LIMIT),
+        interpret=interpret(),
+        name="paged_decode_grid",
+    )(block_table, ctx_lens, table_groups(block_table), q, pool)
+
+
+def paged_latent_attention_xla(q, pool, block_table, ctx_lens, v_dim: int):
+    """jnp oracle for paged_latent_attention (tests; decode_impl='xla';
+    tables too long for the kernel's buffers): gathers each row's pages
+    into a dense [S, NB*bs, C] context."""
+    S = q.shape[0]
+    kc = pool[block_table].reshape(S, -1, pool.shape[-1])
+    logits = jnp.einsum("shc,skc->shk", q, kc).astype(jnp.float32)
+    live = jnp.arange(kc.shape[1])[None, :] < ctx_lens[:, None]
+    logits = jnp.where(live[:, None, :], logits, NEG_INF)
+    probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
+    out = jnp.einsum("shk,skc->shc", probs, kc[..., :v_dim])
+    return jnp.where((ctx_lens > 0)[:, None, None], out, 0).astype(q.dtype)
+
+
+def _latent_write_kernel(slots_ref, new_ref, pool_in, pool_out,
+                         *, block_size: int, n_blocks: int):
+    """_kv_write_kernel for the one latent pool: RMW one token row into
+    its block, the block copied from the aliased input on first visit
+    only (tokens arrive sorted by slot)."""
+    t = pl.program_id(0)
+    slot = slots_ref[t]
+
+    def cb(i):
+        return _arena_block(slots_ref[i] // block_size, n_blocks)
+
+    first = jnp.logical_or(t == 0, cb(t) != cb(jnp.maximum(t - 1, 0)))
+
+    @pl.when(first)
+    def _copy():
+        pool_out[...] = pool_in[...]
+
+    @pl.when(slot >= 0)
+    def _write():
+        row = jax.lax.broadcasted_iota(jnp.int32, (1, block_size, 1), 1)
+        pool_out[...] = jnp.where(row == slot % block_size,
+                                  new_ref[0][None], pool_out[...])
+
+
+def paged_latent_write(pool, new, flat_slots):
+    """Write [T, C] new latent rows into the [NBLK, bs, C] pool at flat
+    slot ids [T] (block*bs + offset; -1 rows are dropped): paged_kv_write
+    for a cache that is one pool."""
+    NBLK, bs, C = pool.shape
+    T = flat_slots.shape[0]
+    order = jnp.argsort(flat_slots)
+    slots = flat_slots[order].astype(jnp.int32)
+
+    def pool_index(t, slots_ref):
+        return (_arena_block(slots_ref[t] // bs, NBLK), 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(T,),
+        in_specs=[pl.BlockSpec((1, 1, C), lambda t, slots_ref: (t, 0, 0)),
+                  pl.BlockSpec((1, bs, C), pool_index)],
+        out_specs=pl.BlockSpec((1, bs, C), pool_index),
+    )
+    return pl.pallas_call(
+        functools.partial(_latent_write_kernel, block_size=bs,
+                          n_blocks=NBLK),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(pool.shape, pool.dtype),
+        input_output_aliases={2: 0},
+        interpret=interpret(),
+        name="paged_latent_write",
+    )(slots, new[order][:, None, :], pool)
+
+
+def paged_latent_write_xla(pool, new, flat_slots):
+    """jnp scatter oracle for paged_latent_write (-1 slots dropped)."""
+    NBLK, bs, C = pool.shape
+    idx = jnp.where(flat_slots < 0, NBLK * bs, flat_slots)
+    return pool.reshape(NBLK * bs, C).at[idx].set(
+        new, mode="drop").reshape(pool.shape)

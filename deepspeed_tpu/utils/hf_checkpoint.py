@@ -143,8 +143,21 @@ _LLAMA_FAMILY = {"LlamaForCausalLM", "MistralForCausalLM",
                  "MixtralForCausalLM", "Qwen2ForCausalLM",
                  "OlmoeForCausalLM"}
 # a config.json without `architectures` is told by its model_type
-_ARCH_OF_MODEL_TYPE = {"olmoe": "OlmoeForCausalLM"}
+_ARCH_OF_MODEL_TYPE = {"olmoe": "OlmoeForCausalLM",
+                       "pangu_ultra_moe": "PanguUltraMoEForCausalLM"}
+# config.json keys that change what a BLOCK computes (latent attention,
+# shared experts, leading dense layers, a second norm, a scaled or
+# grouped router): an architecture whose mapping below does not read
+# one of them would be served as a plain block under a real model's name
+_BLOCK_KEYS = ("kv_lora_rank", "q_lora_rank", "qk_nope_head_dim",
+               "qk_rope_head_dim", "v_head_dim", "n_shared_experts",
+               "first_k_dense_replace", "sandwich_norm",
+               "routed_scaling_factor", "moe_intermediate_size",
+               "n_routed_experts", "n_group", "topk_group",
+               "num_nextn_predict_layers")
+_READS_BLOCK_KEYS = {"PanguUltraMoEForCausalLM"}
 SUPPORTED_ARCHITECTURES = sorted(_LLAMA_FAMILY | {
+    "PanguUltraMoEForCausalLM",
     "GPT2LMHeadModel", "OPTForCausalLM", "FalconForCausalLM",
     "RWForCausalLM",  # falcon's pre-rename arch string
     "PhiForCausalLM", "QWenLMHeadModel",
@@ -163,7 +176,16 @@ def config_from_hf(hf: Dict[str, Any], **overrides) -> TransformerConfig:
     """HF config.json dict → TransformerConfig. overrides win (e.g.
     use_flash=False for CPU tests, attention_impl for long-context)."""
     arch = _arch_of(hf)
-    if arch in _LLAMA_FAMILY:
+    for key in _BLOCK_KEYS if arch not in _READS_BLOCK_KEYS else ():
+        if hf.get(key):
+            raise ValueError(
+                f"{arch} with {key}={hf[key]!r}: this architecture's mapping "
+                f"does not read {key!r}, and a block key that is not read "
+                "would be served as a plain block; refusing a "
+                "silently-wrong import")
+    if arch == "PanguUltraMoEForCausalLM":
+        kw = _pangu_ultra_moe_config(hf)
+    elif arch in _LLAMA_FAMILY:
         kw = dict(
             vocab_size=hf["vocab_size"],
             n_layers=hf["num_hidden_layers"],
@@ -448,6 +470,62 @@ def config_from_hf(hf: Dict[str, Any], **overrides) -> TransformerConfig:
         )
     kw.update(overrides)
     return TransformerConfig(**kw)
+
+
+def _pangu_ultra_moe_config(hf: Dict[str, Any]) -> Dict[str, Any]:
+    """openPangu-Ultra-MoE (`pangu_ultra_moe`): latent attention,
+    sandwich norm, `first_k_dense_replace` leading dense layers, then
+    layers of `n_routed_experts` routed experts (sigmoid scores, top-k
+    over all of them, renormalised, times `routed_scaling_factor`)
+    beside `n_shared_experts` shared. config.json states neither the
+    scoring function nor groups: sigmoid with plain top-k is the
+    family's convention for a scaling factor with norm_topk_prob.
+
+    A cut that is one chip's share of an expert-parallel deployment
+    states it in the file: `n_routed_experts` is what this chip HOLDS,
+    `reduced.n_routed_experts.published` the router's width, and
+    `experts_held.start` the first held expert (0 if absent). The
+    multi-token-prediction layers are not served (no self-drafting):
+    a file that asks for them is refused."""
+    if hf.get("num_nextn_predict_layers"):
+        raise ValueError(
+            "pangu_ultra_moe with num_nextn_predict_layers="
+            f"{hf['num_nextn_predict_layers']}: the multi-token-prediction "
+            "block is not served (no self-drafting); a configuration says "
+            "so by setting it to 0 under `reduced`")
+    if hf.get("attention_bias"):
+        raise ValueError("pangu_ultra_moe with attention_bias is unsupported")
+    held = int(hf["n_routed_experts"])
+    routed = int((hf.get("reduced") or {}).get("n_routed_experts", {})
+                 .get("published", held))
+    start = int((hf.get("experts_held") or {}).get("start", 0))
+    n_dense = int(hf.get("first_k_dense_replace", 0))
+    return dict(
+        vocab_size=hf["vocab_size"],
+        n_layers=hf["num_hidden_layers"] - n_dense,
+        n_dense_layers=n_dense,
+        dense_d_ff=hf["intermediate_size"],
+        n_heads=hf["num_attention_heads"],
+        d_model=hf["hidden_size"],
+        d_ff=hf["moe_intermediate_size"],
+        max_seq=hf.get("max_position_embeddings", 4096),
+        variant="llama",
+        rope_theta=float(hf.get("rope_theta", 10000.0)),
+        norm_eps=float(hf.get("rms_norm_eps", 1e-5)),
+        tie_embeddings=bool(hf.get("tie_word_embeddings", False)),
+        kv_lora_rank=hf["kv_lora_rank"], q_lora_rank=hf["q_lora_rank"],
+        qk_nope_head_dim=hf["qk_nope_head_dim"],
+        qk_rope_head_dim=hf["qk_rope_head_dim"],
+        v_head_dim=hf["v_head_dim"],
+        sandwich_norm=bool(hf.get("sandwich_norm", False)),
+        n_experts=routed, moe_top_k=hf["num_experts_per_tok"],
+        experts_held=(start, held) if held != routed else None,
+        n_shared_experts=int(hf.get("n_shared_experts", 0)),
+        moe_scoring="sigmoid",
+        moe_norm_topk_prob=bool(hf.get("norm_topk_prob", True)),
+        routed_scaling_factor=float(hf.get("routed_scaling_factor", 1.0)),
+        moe_dropless=True,
+    )
 
 
 # ---------------------------------------------------------------------------

@@ -1,0 +1,412 @@
+"""openPangu-Ultra-MoE (`pangu_ultra_moe`) on the normal serving path at
+a tiny size on the CPU, against the plain float32 reference of
+benchmarks/reference/pangu_ultra_moe.py: latent attention through the
+paged latent cache (whole-prompt prefill in the naive form, chunks and
+single-token steps in the absorbed form, a shared-table iteration of
+mixed rows), sandwich norm, a leading dense layer, a shared expert
+beside a HELD share of the routed experts, the share test, the mutants
+that must fail, the latent kernels against their oracles, and the
+cut's file.
+
+Everything is float32 with seeded weights: 1 dense + 2 routed layers,
+d 64, 4 heads of [nope 16; rope 8], value 16, latent 32, 16 routed
+experts top-4 of which this chip holds 4 (experts 4..7), one shared.
+"""
+
+import dataclasses
+import json
+import pathlib
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmarks.reference import pangu_ultra_moe as ref
+from deepspeed_tpu.inference import (
+    ServingScheduler,
+    ServingSchedulerConfig,
+    init_inference,
+)
+from deepspeed_tpu.inference import model as M
+from deepspeed_tpu.models import transformer as T
+from deepspeed_tpu.ops.pallas import paged_attention as PA
+from deepspeed_tpu.utils.hf_checkpoint import config_from_hf
+
+ROOT = pathlib.Path(__file__).resolve().parents[1]
+BENCH = ROOT / "benchmarks"
+HF = {"attention_bias": False, "first_k_dense_replace": 1,
+      "hidden_act": "silu", "hidden_size": 64, "intermediate_size": 96,
+      "kv_lora_rank": 32, "max_position_embeddings": 256,
+      "model_type": "pangu_ultra_moe", "moe_intermediate_size": 32,
+      "n_routed_experts": 4, "n_shared_experts": 1, "norm_topk_prob": True,
+      "num_attention_heads": 4, "num_experts_per_tok": 4,
+      "num_hidden_layers": 3, "num_key_value_heads": 4,
+      "num_nextn_predict_layers": 0, "q_lora_rank": 24,
+      "qk_nope_head_dim": 16, "qk_rope_head_dim": 8, "rms_norm_eps": 1e-05,
+      "rope_theta": 25600000, "routed_scaling_factor": 2.5,
+      "sandwich_norm": True, "tie_word_embeddings": False, "v_head_dim": 16,
+      "vocab_size": 256,
+      "reduced": {"n_routed_experts": {"published": 16, "here": 4}},
+      "experts_held": {"start": 4, "count": 4, "of": 16}}
+# the same model with every routed expert on one chip
+UNCUT = dict(HF, n_routed_experts=16, reduced={}, experts_held={})
+
+# float32 on both sides, logits up to 2.7. The system reassociates (the
+# absorbed form multiplies W_uk into the query before the key, the
+# expert scan's running sum, the online softmax of the latent walk),
+# which moves a logit by ~1e-6 (measured here: 1.1e-6 at the prefill,
+# 1.3-1.5e-6 at the absorbed steps); a router tie flipped by that noise
+# would move one by ~0.05, and none is at these seeds. The reference on
+# weights rounded to bf16 differs by 1.8e-2, the mutants by 4.4e-2
+# (softmax for sigmoid), 0.12 (one expert fewer), 0.16 (a float8
+# cache), 0.31 (no scaling factor) and 1.8 (no post-sublayer norm): all
+# at least 89 x the limit.
+LOGITS_ATOL = 2e-4
+# the routed block alone, outputs up to 0.2: float32 reassociation
+# (measured 1.5e-8); a dropped scaling factor moves them by 0.097
+BLOCK_ATOL = 1e-6
+ENGINE = dict(max_seq_len=256, kv_block_size=32, num_kv_blocks=32,
+              max_batch_size=16, min_prefill_bucket=32)
+
+
+@pytest.fixture(scope="module")
+def model():
+    mcfg = config_from_hf(HF, use_flash=False)
+    params = T.init(mcfg, jax.random.PRNGKey(1))
+    # spread the logits (the 0.02 init gives nearly flat ones) and make
+    # every norm scale matter (T.init gives ones)
+    params = jax.tree.map(lambda x: x * 4, params)
+
+    def scales(tree, salt):
+        return {k: (1 + 0.3 * jax.random.normal(
+            jax.random.fold_in(jax.random.PRNGKey(salt), i), v.shape)
+            if "scale" in k else v) for i, (k, v) in enumerate(tree.items())}
+
+    top = scales({k: v for k, v in params.items() if k != "layers"}, 2)
+    return mcfg, dict(top, layers=scales(params["layers"], 3))
+
+
+@pytest.fixture(scope="module")
+def tokens():
+    return np.random.default_rng(0).integers(0, HF["vocab_size"], (2, 80))
+
+
+def _top(params):
+    return {k: v for k, v in params.items() if k != "layers"}
+
+
+def _layer_fn(params):
+    return lambda l: jax.tree.map(lambda a: a[l], params["layers"])
+
+
+def _ref_logits(params, toks, mutate=None, hf=HF):
+    return np.asarray(ref.forward_logits(_top(params), _layer_fn(params),
+                                         toks, hf, mutate))
+
+
+# -- the configuration ---------------------------------------------------
+
+def test_the_cut_builds_the_share_at_published_widths():
+    hf = json.loads(
+        (BENCH / "configs/openpangu-ultra-moe-serve-l5-ep32.json").read_text())
+    cfg = config_from_hf(hf, **hf["serve"]["model_overrides"])
+    assert (cfg.n_dense_layers, cfg.n_layers, cfg.depth) == (1, 4, 5)
+    assert (cfg.d_model, cfg.n_heads, cfg.ff_dim, cfg.dense_d_ff) == \
+        (7680, 128, 2048, 18432)
+    assert (cfg.q_lora_rank, cfg.kv_lora_rank, cfg.qk_nope_head_dim,
+            cfg.qk_rope_head_dim, cfg.v_head_dim) == (1536, 512, 128, 64, 128)
+    assert cfg.latent_dim == 576 and PA.latent_lanes(cfg.latent_dim) == 640
+    assert (cfg.n_experts, cfg.moe_top_k, cfg.experts_held) == (256, 8, (0, 8))
+    assert cfg.moe_scoring == "sigmoid" and cfg.routed_scaling_factor == 2.5
+    assert cfg.sandwich_norm and cfg.n_shared_experts == 1
+    assert cfg.vocab_size == 19200 and not cfg.tie_embeddings
+    shapes = jax.eval_shape(lambda k: T.init(cfg, k), jax.random.PRNGKey(0))
+    assert shapes["layers"]["w_router"].shape == (4, 7680, 256)
+    assert shapes["layers"]["w_in"].shape == (4, 8, 7680, 2048)
+    assert shapes["dense_w_in"].shape == (1, 7680, 18432)
+    # the file's own count: matrices alone, norms apart
+    flat = dict(shapes["layers"], **{k: v for k, v in shapes.items()
+                                     if k != "layers"})
+    n = sum(int(np.prod(s.shape)) for k, s in flat.items() if "scale" not in k)
+    assert n == 3_409_018_880
+    # ONE homogeneous stack and top-level ARRAYS: what the benchmark's
+    # weight maker and reference_inputs take
+    assert all(not isinstance(v, dict) for k, v in shapes.items()
+               if k != "layers")
+    assert all(v.shape[0] == cfg.n_layers for v in shapes["layers"].values())
+
+
+def test_the_cuts_file_keeps_the_published_widths():
+    from benchmarks.tests import helpers
+
+    helpers.check_cell(ROOT, "serve-pangu-longchat-saturated")
+    cfg = json.loads(
+        (BENCH / "configs/openpangu-ultra-moe-serve-l5-ep32.json").read_text())
+    helpers.check_published_widths(cfg, BENCH)
+    assert sorted(cfg["reduced"]) == [
+        "first_k_dense_replace", "n_routed_experts", "num_hidden_layers",
+        "num_nextn_predict_layers", "vocab_size"]
+    with pytest.raises(AssertionError):       # a width may never be cut
+        helpers.check_published_widths(dict(cfg, kv_lora_rank=256), BENCH)
+
+
+def test_the_mtp_block_is_refused_not_dropped():
+    with pytest.raises(ValueError, match="num_nextn_predict_layers"):
+        config_from_hf(dict(HF, num_nextn_predict_layers=1))
+
+
+@pytest.mark.parametrize("key,value", [
+    ("kv_lora_rank", 512), ("n_shared_experts", 1),
+    ("first_k_dense_replace", 3), ("sandwich_norm", True),
+    ("routed_scaling_factor", 2.5), ("q_lora_rank", 1536)])
+def test_a_block_key_a_known_architecture_does_not_read_is_refused(key, value):
+    """A Llama-family name carrying a key that changes what a block
+    computes would otherwise be served as a plain dense model."""
+    llama = {"architectures": ["LlamaForCausalLM"], "vocab_size": 128,
+             "hidden_size": 64, "intermediate_size": 96,
+             "num_hidden_layers": 2, "num_attention_heads": 4}
+    config_from_hf(llama)                                   # as it is: fine
+    config_from_hf(dict(llama, **{key: None}))              # absent or null
+    with pytest.raises(ValueError, match=key):
+        config_from_hf(dict(llama, **{key: value}))
+
+
+def test_the_training_forward_refuses_this_family(model, tokens):
+    mcfg, params = model
+    with pytest.raises(NotImplementedError, match="kv_lora_rank"):
+        T.forward(params, jnp.asarray(tokens), mcfg)
+
+
+# -- serving against the reference -----------------------------------------
+
+@pytest.fixture(scope="module")
+def served(model, tokens):
+    """Whole-prompt prefill (naive form), a 3-token chunk and two
+    single-token steps (absorbed form through the latent cache) of both
+    rows; the logits each put() returned."""
+    mcfg, params = model
+    eng = init_inference(params, mcfg, dict(ENGINE), dtype=jnp.float32)
+    f, n, k = tokens.astype(np.int32), 50, 3
+    got = [eng.put([0, 1], [r[:n - k] for r in f]),
+           eng.put([0, 1], [r[n - k:n] for r in f]),
+           eng.put([0, 1], [r[n:n + 1] for r in f]),
+           eng.put([0, 1], [r[n + 1:n + 2] for r in f])]
+    return eng, [np.asarray(g) for g in got], [n - k - 1, n - 1, n, n + 1]
+
+
+def test_serving_through_the_latent_cache_matches_the_reference(
+        model, tokens, served):
+    mcfg, params = model
+    eng, got, pos = served
+    assert eng.cache.v == [] and len(eng.cache.k) == mcfg.depth == 3
+    assert eng.cache.k[0].shape == (33, 32, 128)      # 40 values, one lane tile
+    assert eng.kv_bytes_per_token() == 3 * 128 * 4
+    want = _ref_logits(params, tokens)
+    assert np.abs(want).max() > 0.5                   # the logits are not flat
+    for step, p in enumerate(pos):
+        assert np.abs(got[step] - want[:, p]).max() < LOGITS_ATOL, step
+
+
+def test_the_absorbed_form_agrees_with_the_naive_form(model, tokens, served):
+    """The same positions' logits from a whole-prompt prefill (keys and
+    values up-projected for every head) and from chunk rows over the
+    cache (queries moved into the latent space)."""
+    mcfg, params = model
+    _, got, _ = served
+    eng = init_inference(params, mcfg, dict(ENGINE), dtype=jnp.float32)
+    f = tokens.astype(np.int32)
+    naive = np.asarray(eng.put([7, 8], [r[:50] for r in f]))   # one prefill
+    assert np.abs(naive - got[1]).max() < LOGITS_ATOL          # 47 + chunk of 3
+
+
+@pytest.mark.parametrize("mutant", ref.MUTANTS)
+def test_a_wrong_model_fails_the_written_tolerance(model, tokens, served,
+                                                   mutant):
+    """No scaling factor, softmax for sigmoid, one expert fewer, no
+    post-sublayer norm, a float8 cache: far outside the limit."""
+    _, params = model
+    _, got, pos = served
+    wrong = _ref_logits(params, tokens, mutant)
+    for step, p in enumerate(pos):
+        assert np.abs(got[step] - wrong[:, p]).max() > 80 * LOGITS_ATOL
+
+
+def test_a_bf16_reference_fails_the_written_tolerance(model, tokens, served):
+    _, params = model
+    _, got, pos = served
+    rounded = jax.tree.map(
+        lambda a: a.astype(jnp.bfloat16).astype(jnp.float32), params)
+    wrong = _ref_logits(rounded, tokens)
+    for step, p in enumerate(pos):
+        assert np.abs(got[step] - wrong[:, p]).max() > 50 * LOGITS_ATOL
+
+
+def test_a_shared_table_iteration_of_mixed_rows_matches_the_reference(
+        model, tokens):
+    """The scheduler's own program: decode rows and prefill-chunk rows
+    of several requests in one call, every chunk's rows on one table.
+    Its greedy tokens are the reference's argmax at every position."""
+    mcfg, params = model
+    eng = init_inference(params, mcfg, dict(ENGINE), dtype=jnp.float32)
+    eng.warmup(widths=[8, 16], footprint=False)
+    sched = ServingScheduler(
+        eng, ServingSchedulerConfig(max_num_batched_tokens=16,
+                                    prefill_chunk=6, warmup=False), seed=0)
+    prompts = [tokens[0, :40].astype(np.int32), tokens[1, :23].astype(np.int32),
+               tokens[0, 40:75].astype(np.int32)]
+    rids = [sched.submit(p, max_new_tokens=5) for p in prompts]
+    sched.run()
+    c = sched.counters
+    assert c["moe_token_expert_pairs"] == c["batched_tokens"] * mcfg.moe_top_k
+    assert c["mla_cache_tokens"] > c["batched_tokens"] > 0
+    assert not eng.recompile_tracker.findings
+    for rid, p in zip(rids, prompts):
+        out = sched.finished[rid].output
+        seq = np.concatenate([p, out]).astype(np.int32)
+        want = _ref_logits(params, seq[None])[0]
+        assert out == [int(want[len(p) - 1 + j].argmax()) for j in range(5)]
+
+
+# -- the latent kernels ----------------------------------------------------
+
+def test_the_latent_kernels_match_their_oracles(pallas_interpret):
+    """Rows of one chunk on one table (contexts rising, one crossing a
+    block), decode rows with tables of their own, padding rows between
+    them; the write with dropped slots and two rows into one block."""
+    rng = np.random.default_rng(0)
+    NBLK, bs, C, V, H = 40, 16, 128, 96, 4
+    pool = jnp.asarray(rng.normal(size=(NBLK, bs, C)), jnp.float32)
+    tA, tB, tC, pad = [3, 7, 9, 0, 0, 0], [1, 2, 4, 5, 6, 0], \
+        [11, 12, 0, 0, 0, 0], [39] * 6
+    tables = jnp.asarray([tA] * 5 + [tB] + [pad] + [tC] * 4 + [pad], jnp.int32)
+    ctx = jnp.asarray([30, 31, 32, 33, 34, 70, 0, 14, 15, 16, 17, 0], jnp.int32)
+    np.testing.assert_array_equal(
+        np.asarray(PA.table_groups(tables)),
+        [0, 0, 0, 0, 0, 1, 2, 3, 3, 3, 3, 4])
+    q = jnp.asarray(rng.normal(size=(12, H, C)) * 0.3, jnp.float32)
+    want = PA.paged_latent_attention_xla(q, pool, tables, ctx, V)
+    got = jax.jit(lambda *a: PA.paged_latent_attention(*a, V))(
+        q, pool, tables, ctx)
+    assert np.abs(np.asarray(got) - np.asarray(want)).max() < 1e-5
+    assert np.all(np.asarray(got)[[6, 11]] == 0)        # padding rows: zeros
+    new = jnp.asarray(rng.normal(size=(6, C)), jnp.float32)
+    slots = jnp.asarray([35, -1, 3 * 16 + 2, 3 * 16 + 3, 100, -1], jnp.int32)
+    np.testing.assert_array_equal(
+        np.asarray(jax.jit(PA.paged_latent_write)(pool, new, slots)),
+        np.asarray(PA.paged_latent_write_xla(pool, new, slots)))
+
+
+def test_the_kernel_engine_agrees_with_the_oracle_engine(model, tokens, served,
+                                                         pallas_interpret):
+    mcfg, params = model
+    _, got, _ = served
+    eng = init_inference(params, mcfg, dict(ENGINE), dtype=jnp.float32)
+    assert eng.resolved_impl == "pallas"
+    f, n, k = tokens.astype(np.int32), 50, 3
+    out = [eng.put([0, 1], [r[:n - k] for r in f]),
+           eng.put([0, 1], [r[n - k:n] for r in f]),
+           eng.put([0, 1], [r[n:n + 1] for r in f])]
+    for a, b in zip(out, got):
+        assert np.abs(np.asarray(a) - b).max() < LOGITS_ATOL
+
+
+def test_a_context_longer_than_the_walks_buffers_is_refused(model):
+    pool = jax.ShapeDtypeStruct((8, 128, 640), jnp.bfloat16)
+    assert PA.latent_walk_fits(72, pool) and PA.latent_walk_fits(146, pool)
+    assert not PA.latent_walk_fits(256, pool)
+    assert not PA.latent_walk_fits(
+        8, jax.ShapeDtypeStruct((8, 128, 576), jnp.bfloat16))
+    # the engine says so when it is built, whatever the call would do
+    mcfg, params = model
+    long = dict(ENGINE, kv_block_size=128, max_seq_len=512 * 128,
+                num_kv_blocks=520, decode_impl="pallas")
+    with pytest.raises(ValueError, match="latent walk's VMEM"):
+        init_inference(params, dataclasses.replace(mcfg, max_seq=512 * 128),
+                       long, dtype=jnp.float32)
+    init_inference(params, dataclasses.replace(mcfg, max_seq=512 * 128),
+                   dict(long, decode_impl="xla"), dtype=jnp.float32)
+
+
+# -- the routed block and the share ----------------------------------------
+
+def _block(params, n_tokens):
+    lw = jax.tree.map(lambda a: a[1], params["layers"])
+    h = jnp.asarray(np.random.default_rng(3).normal(size=(n_tokens, 64)),
+                    jnp.float32)
+    return lw, h
+
+
+def test_the_held_share_scans_its_experts_whatever_the_rows(model):
+    mcfg, _ = model
+    assert [M.expert_path(t, mcfg) for t in (1, 8, 128, 8192)] == ["scan"] * 4
+    whole = dataclasses.replace(mcfg, experts_held=None)
+    assert M.expert_path(4, whole) == "ragged"     # every expert held: by rows
+
+
+def test_the_routed_block_alone_matches_the_reference(model):
+    mcfg, params = model
+    lw, h = _block(params, 24)
+    got = np.asarray(M._mlp(h, lw, mcfg))
+    with jax.default_matmul_precision("highest"):
+        routed, shared, _ = ref.moe_parts(h, lw, HF)
+        want = np.asarray(routed + shared)
+        unscaled = np.asarray(ref.moe_parts(h, lw, HF, "no_scaling")[0] + shared)
+    assert np.abs(want).max() > 0.05
+    assert np.abs(got - want).max() < BLOCK_ATOL
+    assert np.abs(got - unscaled).max() > 1000 * BLOCK_ATOL
+
+
+def test_the_shares_add_up_to_the_uncut_layer(model):
+    """THE share test: the four shares' routed parts (experts 0..3,
+    4..7, 8..11, 12..15, each computed by the PROGRAM's expert layer
+    told which it holds), with the shared expert counted once, are what
+    the uncut reference gives for the whole layer."""
+    mcfg, params = model
+    lw, h = _block(params, 24)
+    rng = jax.random.PRNGKey(9)
+    every = {n: jax.random.normal(jax.random.fold_in(rng, i),
+                                  (16,) + lw[n].shape[1:]) * 0.08
+             for i, n in enumerate(("w_gate", "w_in", "w_out"))}
+    with jax.default_matmul_precision("highest"):
+        r, s, _ = ref.moe_parts(h, dict(lw, **every), UNCUT)
+        whole, shared = np.asarray(r + s), np.asarray(s)
+        # the reference's own shares add up too
+        parts = [ref.moe_parts(
+            h, dict(lw, **{n: w[a:a + 4] for n, w in every.items()}),
+            dict(HF, experts_held={"start": a}))[0] for a in (0, 4, 8, 12)]
+        assert np.abs(np.asarray(sum(parts)) + shared - whole).max() < BLOCK_ATOL
+    total = np.zeros_like(whole)
+    for start in (0, 4, 8, 12):
+        cfg = dataclasses.replace(mcfg, experts_held=(start, 4))
+        mine = dict(lw, **{n: w[start:start + 4] for n, w in every.items()})
+        total += np.asarray(M._mlp(h, mine, cfg)) - shared
+    assert np.abs(whole - shared).max() > 0.05       # the routed part matters
+    assert np.abs(total + shared - whole).max() < 4 * BLOCK_ATOL
+    # and one share alone is NOT the layer
+    assert np.abs(np.asarray(M._mlp(h, lw, mcfg)) - whole).max() > 0.01
+
+
+# -- the rehearsal: the cell's runner kind ----------------------------------
+
+def test_the_rehearsal_cell_names_the_real_cells_reference_and_kind():
+    """`tiny-pangu-serve-sat` rehearses the real cell: the same
+    reference through the same runner kind. The run itself, and every
+    test the accepted benchmark holds kind `serve` to, is
+    benchmarks/tests/test_serve_aliases.py's (this family's mix states
+    `serve_longctx`, the `serve` runner under the kind that a context
+    over 4,096 needs: runners/serve_longctx.py)."""
+    from benchmarks import harness
+    from benchmarks.tests import helpers
+
+    rc = next(rc for rc in helpers.rehearsal_cells()
+              if rc["name"] == "tiny-pangu-serve-sat")
+    assert (rc["reference"], rc["runner"]) == ("pangu_ultra_moe", "serve_longctx")
+    real = harness.load_cell("serve-pangu-longchat-saturated")
+    assert real.traffic["runner"] == rc["runner"]
+    assert real.config["reference"] == rc["reference"]
+    assert harness.load_module(
+        BENCH / "runners" / "serve_longctx.py").run.__module__.endswith("serve")
+    # the mix the real cell offers is longer than the older cells' context
+    lens = real.traffic["prompt_len"]["max"] + real.traffic["answer_len"]["max"]
+    assert 4096 < lens <= real.config["serve"]["engine"]["max_seq_len"]

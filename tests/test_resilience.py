@@ -371,11 +371,14 @@ class TestHandoffGuards:
 
     def test_export_timeout_falls_back(self, fleet_bits, rng):
         prompts = [list(rng.integers(0, 64, 10)) for _ in range(2)]
+        # wall-clock limits: a sound export takes milliseconds, and took
+        # 11 ms once under six busy workers, so the limit stands well
+        # above that and the injected delay well above the limit
         router, vc = self._disagg(
-            fleet_bits, {"handoff_timeout_s": 0.01})
+            fleet_bits, {"handoff_timeout_s": 0.25})
         plan = FaultPlan([
             {"point": "engine.export_kv", "kind": "delay",
-             "value": 0.05, "at": 1, "times": 1}])
+             "value": 0.6, "at": 1, "times": 1}])
         with armed(plan):
             gids = [router.submit(p, 6) for p in prompts]
             _drive(router, vc)

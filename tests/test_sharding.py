@@ -25,8 +25,18 @@ from deepspeed_tpu.runtime.zero import (
     zero_sharded_dims,
 )
 
+def _trains(path):
+    from deepspeed_tpu.utils.hf_checkpoint import config_from_hf
+
+    return not config_from_hf(json.loads(path.read_text())).serving_only
+
+
+# the benchmark's configurations that TRAIN here: one whose training
+# forward is refused (`TransformerConfig.serving_only`) has no ZeRO
+# layout to pin
 BENCH_CONFIGS = sorted(
-    (pathlib.Path(__file__).parent.parent / "benchmarks" / "configs").glob("*.json"))
+    p for p in (pathlib.Path(__file__).parent.parent / "benchmarks"
+                / "configs").glob("*.json") if _trains(p))
 
 
 def mesh_dp8():

@@ -367,6 +367,7 @@ class InferenceEngine:
             # step-entry dequant pass
             self._dequant = lambda p: p
         self._prepare_fn = None
+        self._expert_paths: Dict[int, str] = {}  # by program width
         self._layer_xform = None
         self._top_xform = None
         # awaited, so each set-up phase's span carries its own time and
@@ -444,6 +445,25 @@ class InferenceEngine:
         """The RESOLVED serving implementation ('pallas' | 'xla') —
         what config.decode_impl='auto' picked on this backend."""
         return "pallas" if self._use_kernel else "xla"
+
+    def expert_path(self, width: int) -> Optional[str]:
+        """The expert path (M.expert_path: 'stream', 'scan' or 'ragged')
+        of a compiled program over `width` token rows, None for a dense
+        model: asked with what the program's routed layers will see
+        (the leaves' shapes and types after the step's own dequant and
+        fetch, the resolved kernel choice, the mesh). What the
+        scheduler counts engaged steps by and the set-up spans name."""
+        if self.cfg.n_experts == 0:
+            return None
+        if width not in self._expert_paths:
+            fetch = self._fetch_layer() or (lambda lp, dep, li: lp)
+            n_dense = len(self.params.get("dense_layers", ()))
+            lp = jax.eval_shape(
+                lambda p: fetch(self._dequant(p)["layers"][0], None, n_dense),
+                self.params)
+            self._expert_paths[width] = M.expert_path(
+                width, self.cfg, lp, self._use_kernel, self.mesh)
+        return self._expert_paths[width]
 
     def refresh_params(self, params: Any) -> None:
         """(Re)point the served weight tree — the hybrid-engine shared-
@@ -1537,6 +1557,9 @@ class InferenceEngine:
             steps = np.zeros((w,), np.int32)
             keys = self._row_keys(0, np.zeros((w,), np.uint32))
             logits = None
+            # a routed model's programs name their expert path
+            path = self.expert_path(w)
+            moe = {"moe_expert_path": path} if path else {}
             for uniq in ((True, False) if chunked else (True,)):
                 rt.record(f"serving_decode[w{w},u{int(uniq)}]",
                           (toks, tables, ctx))
@@ -1544,7 +1567,7 @@ class InferenceEngine:
                     "decode", w, lambda: self._decode_fn(w, uniq)(
                         self.params, self.cache, self._dev(toks),
                         self._dev(tables), self._dev(ctx)),
-                    unique=int(uniq))
+                    unique=int(uniq), **moe)
             if with_pres:
                 pres = np.zeros((w, V), np.uint8)
                 rt.record(f"serving_sample[w{w}]", (steps, pres))
@@ -1581,7 +1604,7 @@ class InferenceEngine:
                     if with_pres:
                         args.append(self._dev(np.zeros((w, V), np.uint8)))
                 _, _, self.cache, _ = warm("fused", w, lambda: fn(*args),
-                                           chunk=C)
+                                           chunk=C, **moe)
             if footprint:
                 with profiler.span("warmup.footprint", always=True, width=w):
                     rep = build_cost_report(self.compiled_decode(w),
@@ -2033,11 +2056,15 @@ def init_inference(
             raise ValueError("conflicting offload in config and kwarg")
         offload = off
     icfg = InferenceConfig(**cfg)
-    with profiler.span("init.inference", always=True,
-                       **M.moe_span_ids(model_config, icfg.max_batch_size)):
-        return InferenceEngine(model_config, params, icfg, dtype,
-                               quantization=quantization, mesh=mesh,
-                               offload=offload)
+    with profiler.span("init.inference", always=True) as sp:
+        engine = InferenceEngine(model_config, params, icfg, dtype,
+                                 quantization=quantization, mesh=mesh,
+                                 offload=offload)
+        if model_config.n_experts:  # a routed model names its experts
+            sp.set(n_experts=model_config.n_experts,
+                   moe_top_k=model_config.moe_top_k,
+                   moe_expert_path=engine.expert_path(icfg.max_batch_size))
+        return engine
 
 
 def init_inference_from_hf(

@@ -43,6 +43,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..models import transformer as T
 from ..ops.attention import causal_attention, uses_flash
+from ..ops.pallas.expert_stream import expert_stream_mlp, stream_f_tile
 from ..ops.pallas.paged_attention import (
     latent_lanes,
     paged_decode_attention,
@@ -527,50 +528,69 @@ def _write_pools(pools: tuple, k_new, v_new, flat_idx, mesh=None,
 
 
 # Rows an expert sees (T x k / X, static in a compiled program) between
-# which serving scans over ALL experts; at or outside these it takes the
-# ragged wire. Measured on a TPU v5e at OLMoE-1B-7B's widths (64 experts
-# of 2048 x 1024, top-8, bf16, 8 layers; PERF.md section 6, PR 27), ms
-# for the routed block of 8 layers, scan / ragged: T 8: 11.3 / 6.2,
-# 16: 11.4 / 9.3, 64: 12.1 / 20.7, 128: 14.4 / 21.4, 256: 16.4 / 22.9,
-# 512: 24.7 / 25.6, 1024: 40.0 / 32.9. The scan streams every expert's
-# weights once and multiplies every token by every expert (X / k times
-# the needed operations): best while the stream binds. The ragged wire
-# does the needed operations only, at a fixed cost of its sort, gather
-# and `lax.ragged_dot` (flat at ~2.6 ms a layer from 8 to 32 rows an
-# expert), and skips experts no token reached, so it wins at both ends.
+# which serving multiplies every token by EVERY expert so as to stream
+# each expert's weights once; at or outside these it takes the ragged
+# wire. One pair of bounds for each way of streaming: the one pipelined
+# pass over the stack ('stream', ops/pallas/expert_stream.py) and the
+# `lax.scan` over experts that stays for what the pass cannot take.
+# Measured on a TPU v5e at OLMoE-1B-7B's widths (64 experts of
+# 2048 x 1024, top-8, bf16, 8 layers whose weights alone stream in
+# 7.87 ms; PERF.md section 6, PR 34), ms for the routed block of 8
+# layers, stream / scan / ragged: T 8: 8.7 / 11.3 / 6.3,
+# 16: 8.7 / 11.4 / 9.3, 32: 8.8 / 11.6 / 12.8, 64: 9.1 / 12.3 / 20.8,
+# 128: 8.8 / 14.7 / 21.4, 256: 9.1 / 16.5 / 22.8, 512: 17.7 / 24.9 / 25.7,
+# 1024: 35.2 / 39.9 / 32.9. All-expert streaming does X / k times the
+# needed operations: best while the weight stream binds (the pass: to
+# 256 tokens; from 512 on its matmuls bind, at 94% of the chip's peak).
+# The ragged wire does the needed operations only, at a fixed cost of
+# its sort, gather and `lax.ragged_dot` (flat at ~2.6 ms a layer from 8
+# to 32 rows an expert), and skips experts no token reached, so it wins
+# at both ends.
+_STREAM_ROWS_PER_EXPERT = (1, 128)
 _SCAN_ROWS_PER_EXPERT = (2, 128)
 
 
-def expert_path(n_tokens: int, cfg: T.TransformerConfig) -> str:
+def expert_path(n_tokens: int, cfg: T.TransformerConfig, lp=None,
+                use_kernel: bool = False, mesh=None) -> str:
     """Which expert path a compiled serving program over `n_tokens`
-    rows takes: 'scan' or 'ragged'. A function of the call's static
-    shape alone (no flag selects it).
+    rows takes: 'stream', 'scan' or 'ragged'. THE place that chooses,
+    from what the call can observe and nothing else (no flag selects
+    it): the static rows, the layer's leaves `lp` (arrays or shapes:
+    their types, dtypes and sizes), whether kernels run (`use_kernel`:
+    decode_impl resolved 'pallas') and the mesh.
 
-    A chip that holds a SHARE of the experts (cfg.experts_held) scans
-    its held experts whatever the rows: the rows a held expert sees are
-    the same T x k / X an expert, but how many pairs reach the held
-    ones varies by iteration and is known on the device alone, so the
-    ragged wire would have to gather and sort every pair, of which
-    held / X stay. The scan streams each held expert once and drops
-    the pairs routed elsewhere in its weight matrix."""
+    Every expert is streamed ('stream' or 'scan') between the measured
+    bounds of rows an expert, and whatever the rows on a chip that
+    holds a SHARE of the experts (cfg.experts_held): the rows a held
+    expert sees are the same T x k / X an expert, but how many pairs
+    reach the held ones varies by iteration and is known on the device
+    alone, so the ragged wire would have to gather and sort every
+    pair, of which held / X stay. Streaming reads each held expert
+    once and drops the pairs routed elsewhere in its weight matrix.
+
+    Of the two, the one pipelined pass wherever its kernel takes the
+    inputs: kernels on and one device (under a mesh of several, as for
+    attention in _decode_attention, a raw pallas_call cannot consume
+    sharded operands), a gated block without biases, plain 16-bit
+    stacks whose E and F fill whole lanes and tokens whose resident
+    buffers fit VMEM (expert_stream.stream_f_tile). A QuantizedWeight
+    stack dequantises transiently and keeps the scan."""
+    streams = (
+        lp is not None and use_kernel
+        and (mesh is None or mesh.devices.size == 1)
+        and cfg.is_gated and "b_in" not in lp
+        and stream_f_tile(n_tokens, lp["w_gate"], lp["w_in"],
+                          lp["w_out"]) is not None)
+    every = "stream" if streams else "scan"
     if cfg.experts_held is not None:
-        return "scan"
+        return every
     rows = n_tokens * cfg.moe_top_k / cfg.n_experts
-    lo, hi = _SCAN_ROWS_PER_EXPERT
-    return "scan" if lo < rows < hi else "ragged"
+    lo, hi = _STREAM_ROWS_PER_EXPERT if streams else _SCAN_ROWS_PER_EXPERT
+    return every if lo < rows < hi else "ragged"
 
 
-def moe_span_ids(cfg: T.TransformerConfig, width: int) -> Dict[str, Any]:
-    """What `init.inference` says of a routed model: its experts, its
-    top-k, and the expert path of the decode program `width` rows wide
-    (nothing for a dense one)."""
-    if cfg.n_experts == 0:
-        return {}
-    return {"n_experts": cfg.n_experts, "moe_top_k": cfg.moe_top_k,
-            "moe_expert_path": expert_path(width, cfg)}
-
-
-def _mlp(h, lp, cfg: T.TransformerConfig, census_cb=None):
+def _mlp(h, lp, cfg: T.TransformerConfig, census_cb=None,
+         use_kernel: bool = False, mesh=None):
     """FFN over [T, E] tokens — dense or MoE (Mixtral / OLMoE serving).
 
     Dense llama uses the fused [E, 2F] gate|up GEMM when the prepared
@@ -585,22 +605,29 @@ def _mlp(h, lp, cfg: T.TransformerConfig, census_cb=None):
     k>=2 renormalized), so serving matches the training forward
     wherever training dropped nothing.
 
-    Two expert paths share the gating authority
+    Three expert paths share the gating authority
     (moe.dropless.dropless_topk_gating); expert_path() picks one from
-    the static rows an expert sees (T x k / X):
+    the static rows an expert sees (T x k / X) and from what it is
+    handed here (the layer's leaves, use_kernel, mesh: as _layer hands
+    them to `attend`):
     - 'ragged': per-expert token batching — the ragged batch's rows
       stable-sort by expert id and run as ONE grouped (ragged) GEMM per
       projection inside this same compiled program
       (moe/dropless.py dropless_apply), FLOPs proportional to T*k.
-    - 'scan': a `lax.scan` over the stacked expert weights with a
-      per-expert combine column — X/k times the needed FLOPs, no
-      [T,X,C] dispatch tensor, every weight streamed once.
+    - 'stream': ONE pipelined pass over the stacked expert weights with
+      a per-expert combine column (ops/pallas/expert_stream.py) — X/k
+      times the needed FLOPs, no [T,X,C] dispatch tensor, every weight
+      streamed once and the stream never drained between experts.
+    - 'scan': the same sum as a `lax.scan` over the experts, a loop
+      trip and three separately started dots an expert, for what the
+      pass cannot take (int8 stacks, biases, a mesh, decode_impl 'xla').
 
     Device time is told apart by scope: `moe_route` (router matmul,
     softmax, top-k, and the sort or the weight matrix), `moe_experts`
-    (the expert matmuls and activation; on the scan path the combine
-    column too, which XLA fuses into the output matmul), `moe_combine`
-    (the ragged wire's weighting and segment-sum).
+    (the expert matmuls and activation; on the stream and scan paths
+    the combine column too, inside the kernel or fused by XLA into the
+    output matmul), `moe_combine` (the ragged wire's weighting and
+    segment-sum).
 
     Expert stacks may arrive as groupwise-int8 QuantizedWeight (the
     N004 machinery; quantize_layer): codes dequantize transiently here,
@@ -643,7 +670,7 @@ def _mlp(h, lp, cfg: T.TransformerConfig, census_cb=None):
 
     X = cfg.n_experts
     T_ = h.shape[0]
-    path = expert_path(T_, cfg)
+    path = expert_path(T_, cfg, lp, use_kernel, mesh)
     with jax.named_scope("moe_route"):
         logits = h.astype(jnp.float32) @ lp["w_router"].astype(jnp.float32)
         if cfg.moe_scoring == "sigmoid":
@@ -664,7 +691,7 @@ def _mlp(h, lp, cfg: T.TransformerConfig, census_cb=None):
                 jnp.arange(T_)[:, None], jnp.where(held, idx - start, Xh)
             ].add(wts, mode="drop")
             wcols = weights.T.astype(h.dtype)
-        elif path == "scan":
+        elif path != "ragged":
             # combine-weight matrix [T, X] from the top-k decisions
             weights = jnp.zeros((T_, X), jnp.float32).at[
                 jnp.arange(T_)[:, None], idx].add(wts)
@@ -682,6 +709,12 @@ def _mlp(h, lp, cfg: T.TransformerConfig, census_cb=None):
             w_gate=deq(lp["w_gate"]) if has_gate else None,
             b_in=lp.get("b_in"), b_out=lp.get("b_out"), act=act)
         return _moe_residual(out, h, lp, cfg, act)
+
+    if path == "stream":
+        with jax.named_scope("moe_experts"):
+            out = expert_stream_mlp(h, lp["w_gate"], lp["w_in"],
+                                    lp["w_out"], wcols, act)
+        return _moe_shared(out, h, lp, cfg, act)
 
     xs = [deq(lp["w_in"]), deq(lp["w_out"]), wcols]
     if has_gate:
@@ -709,9 +742,15 @@ def _mlp(h, lp, cfg: T.TransformerConfig, census_cb=None):
 
     with jax.named_scope("moe_experts"):
         out, _ = jax.lax.scan(expert, jnp.zeros_like(h), tuple(xs))
+    return _moe_shared(out, h, lp, cfg, act)
+
+
+def _moe_shared(out, h, lp, cfg: T.TransformerConfig, act):
+    """The tail of the all-expert paths: the shared expert (every
+    token, unweighted, on every chip alike) under its own scope, then
+    the PR-MoE residual."""
     if cfg.n_shared_experts:
         with jax.named_scope("moe_shared"):
-            # every token, unweighted, on every chip alike
             out = out + _wmm(
                 "tf,fe->te", act(_wmm("te,ef->tf", h, lp["ws_gate"]))
                 * _wmm("te,ef->tf", h, lp["ws_in"]), lp["ws_out"])
@@ -733,7 +772,7 @@ def _sigmoid_topk_gating(logits, cfg: T.TransformerConfig):
 
 
 def _ffn_residual(x, attn_out, h1, lp, cfg: T.TransformerConfig,
-                  census_cb=None):
+                  census_cb=None, use_kernel: bool = False, mesh=None):
     """The tail of one layer over [..., E] activations: the attention
     residual, norm2 and the FFN (sequential, or
     the Falcon/Phi parallel form where the FFN reads ln2(x) or the
@@ -748,8 +787,8 @@ def _ffn_residual(x, attn_out, h1, lp, cfg: T.TransformerConfig,
             h2 = T._act_quant(
                 T._norm(x, lp["ln2_scale"], lp.get("ln2_bias"), cfg), cfg)
     with jax.named_scope("mlp"):
-        y = _mlp(h2.reshape(-1, h2.shape[-1]), lp, cfg,
-                 census_cb=census_cb).reshape(x.shape)
+        y = _mlp(h2.reshape(-1, h2.shape[-1]), lp, cfg, census_cb,
+                 use_kernel, mesh).reshape(x.shape)
         if cfg.sandwich_norm:
             y = T._norm(y, lp["ln2_post_scale"], None, cfg)
     return x + attn_out + y if cfg.parallel_residual else x + y
@@ -834,7 +873,7 @@ def _decode_attention(q, pools: tuple, table, ctx, use_kernel: bool,
 # ---------------------------------------------------------------------------
 
 def _layer(x, lp, li: int, positions, cfg: T.TransformerConfig, mesh, attend,
-           alibi, census_cb=None):
+           alibi, census_cb=None, use_kernel: bool = False):
     """One serving layer over [..., E] activations (decode rows [S, E],
     prefill prompts [B, Tp, E]): norm1, the QKV projection (fused w_qkv
     or split, bias or none), QK-norm, rope at `positions` (the
@@ -842,7 +881,8 @@ def _layer(x, lp, li: int, positions, cfg: T.TransformerConfig, mesh, attend,
     `attend(q, k, v, li, alibi, lp) -> (att, layer_cache)` handed in by the
     caller (the ONE thing the two sites differ in: what attention runs
     and how the new rows reach the cache), the output projection and
-    the FFN tail. Returns (x, layer_cache)."""
+    the FFN tail, whose routed block asks expert_path with `use_kernel`
+    and the mesh. Returns (x, layer_cache)."""
     H, KV = cfg.n_heads, cfg.kv_heads
     with jax.named_scope("norm1"):
         h1 = T._act_quant(
@@ -857,7 +897,8 @@ def _layer(x, lp, li: int, positions, cfg: T.TransformerConfig, mesh, attend,
                 out = _wmm("...hd,hde->...e", att, lp["wo"])
                 if cfg.sandwich_norm:
                     out = T._norm(out, lp["ln1_post_scale"], None, cfg)
-        return _ffn_residual(x, out, h1, lp, cfg, census_cb), layer_cache
+        return _ffn_residual(x, out, h1, lp, cfg, census_cb, use_kernel,
+                             mesh), layer_cache
     with jax.named_scope("attention"):
         if "w_qkv" in lp:
             qkv = _wmm("...e,ehd->...hd", h1, lp["w_qkv"])
@@ -886,7 +927,8 @@ def _layer(x, lp, li: int, positions, cfg: T.TransformerConfig, mesh, attend,
             out = out + lp["bo"].astype(x.dtype)
         if cfg.sandwich_norm:
             out = T._norm(out, lp["ln1_post_scale"], None, cfg)
-    return _ffn_residual(x, out, h1, lp, cfg, census_cb), layer_cache
+    return _ffn_residual(x, out, h1, lp, cfg, census_cb, use_kernel,
+                         mesh), layer_cache
 
 
 # ---------------------------------------------------------------------------
@@ -997,7 +1039,8 @@ def _latent_naive(q, row, lp, pool, flat_idx, cfg: T.TransformerConfig,
 
 
 def _forward(params, tokens, positions, cfg: T.TransformerConfig, mesh,
-             attend, fetch_layer=None, census_cb=None, head_rows=None):
+             attend, fetch_layer=None, census_cb=None, head_rows=None,
+             use_kernel: bool = False):
     """The serving forward both sites run: tokens [...] int32 at
     `positions` (their last axis) -> (f32 logits, the PagedCache the
     layers' `attend` calls returned). Prologue (embedding, learned
@@ -1027,7 +1070,7 @@ def _forward(params, tokens, positions, cfg: T.TransformerConfig, mesh,
             lp = fetch_layer(lp, x_hist[-2] if len(x_hist) >= 2 else None,
                              li)
         x, layer_cache = _layer(x, lp, li, positions, cfg, mesh, attend,
-                                alibi, census_cb)
+                                alibi, census_cb, use_kernel)
         pools.append(layer_cache)
         x_hist.append(x)
 
@@ -1103,7 +1146,7 @@ def decode_step(
         return _decode_attention(q, pools, *where), pools
 
     return _forward(params, tokens, positions, cfg, mesh, attend,
-                    fetch_layer, census_cb)
+                    fetch_layer, census_cb, use_kernel=use_kernel)
 
 
 def decode_multi(
@@ -1258,4 +1301,5 @@ def prefill_batch(
             axis=1)[:, 0]
 
     return _forward(params, tokens, positions, cfg, mesh, attend,
-                    fetch_layer, census_cb, head_rows=last_real)
+                    fetch_layer, census_cb, head_rows=last_real,
+                    use_kernel=use_kernel)

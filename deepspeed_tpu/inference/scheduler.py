@@ -232,6 +232,11 @@ class ServingScheduler:
             # routed layer: batched tokens x top-k (0 for a dense model);
             # over steps and experts, the rows an expert sees a step
             "moe_token_expert_pairs": 0,
+            # dispatched programs whose routed layers take the streamed
+            # pass over the expert stack (engine.expert_path of the
+            # program's width: one pipelined kernel a layer); over
+            # steps, 1.0 where every step is one such program
+            "moe_stream_steps": 0,
             # KV cache blocks the decode rows of the dispatched programs
             # had to read: sum over rows (and fused steps) of
             # ceil(ctx / kv_block_size); over steps, what the paged
@@ -921,20 +926,25 @@ class ServingScheduler:
             ph.mark("commit")
             parts.append(_Part("wave", sample_rows, tok_dev))
             self.counters["wave_prefills"] += len(wave)
-            self._count_tokens(int(n_real.sum()))
+            self._count_tokens(int(n_real.sum()), bp * tp)
             self._it_rows += int(n_real.sum())
         return parts
 
-    def _count_tokens(self, n: int, ctx=None, steps: int = 1) -> None:
-        """Tokens a dispatched program batched, the token-expert pairs
-        they make in a routed layer and, where the program reads the
-        paged cache, the live KV blocks of its rows: ctx is the host
-        array of context lengths it was launched with (0 = pad row),
-        each row one token longer in every further fused step."""
+    def _count_tokens(self, n: int, width: int, ctx=None,
+                      steps: int = 1) -> None:
+        """Tokens a dispatched program batched out of its `width` token
+        rows, the token-expert pairs they make in a routed layer and
+        whether that layer streams its experts in one pass, and, where
+        the program reads the paged cache, the live KV blocks of its
+        rows: ctx is the host array of context lengths it was launched
+        with (0 = pad row), each row one token longer in every further
+        fused step."""
         self.counters["batched_tokens"] += n
         cfg = self.engine.cfg
         if cfg.n_experts > 0:
             self.counters["moe_token_expert_pairs"] += n * cfg.moe_top_k
+            self.counters["moe_stream_steps"] += (
+                self.engine.expert_path(width) == "stream")
         if ctx is not None:
             live = ctx[ctx > 0][:, None] + np.arange(steps)
             bs = self.engine.config.kv_block_size
@@ -1018,7 +1028,7 @@ class ServingScheduler:
         tok_dev = (self._sample_part(logits, sample_rows, sp)
                    if sample_rows else None)
         ph.mark("commit")
-        self._count_tokens(n_rows, ctx)
+        self._count_tokens(n_rows, sp, ctx)
         return _Part("mixed", sample_rows, tok_dev)
 
     def _dispatch_fused(self, running: List[Request], C: int) -> _Part:
@@ -1073,7 +1083,7 @@ class ServingScheduler:
         ph.mark("commit")
         for req in running:
             eng.state.commit(req.uid, C)
-        self._count_tokens(len(running) * C, ctx, steps=C)
+        self._count_tokens(len(running) * C, width, ctx, steps=C)
         self.counters["fused_steps"] += 1
         return _Part("fused", sample_rows, gen, n_steps=C)
 
@@ -1449,7 +1459,8 @@ class ServingScheduler:
                 self._accept(req, t, now)
                 if req.done:
                     break
-        self._count_tokens(sum(len(c) for _, c in chunks))
+        rows = sum(len(c) for _, c in chunks)
+        self._count_tokens(rows, _bucket(rows, 8))
         return _Step([], 0)  # already finalized (host verification)
 
     # -- public driving --------------------------------------------------

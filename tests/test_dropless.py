@@ -293,17 +293,14 @@ class TestServingUnits:
     """_mlp-level serving units: dropless vs scan parity, groupwise
     quantized expert stacks, the census callback."""
 
-    def _layer(self, cfg, seed=1):
+    def _layer(self, cfg, seed=1, dtype=jnp.float32):
         r = np.random.default_rng(seed)
         E, F, X = cfg.d_model, cfg.ff_dim, cfg.n_experts
         lp = {
             "w_router": jnp.asarray(r.normal(size=(E, X)), jnp.float32),
-            "w_in": jnp.asarray(r.normal(size=(X, E, F)),
-                                jnp.float32) * 0.1,
-            "w_gate": jnp.asarray(r.normal(size=(X, E, F)),
-                                  jnp.float32) * 0.1,
-            "w_out": jnp.asarray(r.normal(size=(X, F, E)),
-                                 jnp.float32) * 0.1,
+            "w_in": jnp.asarray(r.normal(size=(X, E, F)) * 0.1, dtype),
+            "w_gate": jnp.asarray(r.normal(size=(X, E, F)) * 0.1, dtype),
+            "w_out": jnp.asarray(r.normal(size=(X, F, E)) * 0.1, dtype),
         }
         return lp
 
@@ -315,7 +312,9 @@ class TestServingUnits:
         return T.TransformerConfig(**base)
 
     # serving picks its expert path from the call's shape
-    # (inference/model.py expert_path); these force one or the other
+    # (inference/model.py expert_path); these force every expert (the
+    # scan; where kernels run on 16-bit stacks of whole lanes, the
+    # streamed pass) or the ragged wire
     SCAN, RAGGED = (0.0, float("inf")), (float("inf"),) * 2
 
     def test_dropless_mlp_equals_scan_mlp(self, monkeypatch):
@@ -335,17 +334,23 @@ class TestServingUnits:
             np.asarray(M._mlp(h, lp, self._cfg(moe_dropless=True))),
             np.asarray(M._mlp(h, lp, cfg)))
 
-    @pytest.mark.parametrize("rows", [SCAN, RAGGED], ids=["scan", "ragged"])
-    def test_census_counts_assignments(self, monkeypatch, rows):
+    @pytest.mark.parametrize("path", ["scan", "ragged", "stream"])
+    def test_census_counts_assignments(self, monkeypatch, pallas_interpret,
+                                       path):
         from deepspeed_tpu.inference import model as M
 
+        rows = self.RAGGED if path == "ragged" else self.SCAN
         monkeypatch.setattr(M, "_SCAN_ROWS_PER_EXPERT", rows)
-        cfg = self._cfg()
-        lp = self._layer(cfg)
-        h = jnp.asarray(np.random.default_rng(2).normal(size=(16, 32)),
-                        jnp.float32)
+        monkeypatch.setattr(M, "_STREAM_ROWS_PER_EXPERT", rows)
+        wide = path == "stream"  # the pass: bf16 stacks of whole lanes
+        cfg = self._cfg(**(dict(d_model=128, d_ff=128) if wide else {}))
+        dtype = jnp.bfloat16 if wide else jnp.float32
+        lp = self._layer(cfg, dtype=dtype)
+        h = jnp.asarray(np.random.default_rng(2).normal(
+            size=(16, cfg.d_model)), dtype)
+        assert M.expert_path(16, cfg, lp, wide) == path
         seen = []
-        jax.block_until_ready(M._mlp(h, lp, cfg, census_cb=seen.append))  # ds-lint: ok R002 test asserts the callback landed
+        jax.block_until_ready(M._mlp(h, lp, cfg, seen.append, wide))  # ds-lint: ok R002 test asserts the callback landed
         assert len(seen) == 1
         counts = np.asarray(seen[0])
         assert counts.shape == (4,)

@@ -53,8 +53,13 @@ LOSS_ATOL = 2e-5
 # the routed block alone, outputs up to 0.07: float32 reassociation
 # (measured 2e-8); the mutants move them by more than 1e-3
 BLOCK_ATOL = 1e-6
-# always the scan / always the ragged wire, whatever the shape
+# always every expert (the scan; with kernels on, the streamed pass) /
+# always the ragged wire, whatever the shape
 ALWAYS = {"scan": (0.0, float("inf")), "ragged": (float("inf"),) * 2}
+ALWAYS["stream"] = ALWAYS["scan"]
+# the three paths on 16-bit stacks, as a share of the block's largest
+# output (tests/test_expert_stream.py measures 0.3-0.7% against float32)
+BLOCK_RTOL_BF16 = 0.015
 ENGINE = dict(max_seq_len=256, kv_block_size=32, num_kv_blocks=32,
               max_batch_size=8, min_prefill_bucket=32)
 
@@ -288,33 +293,73 @@ def test_the_routed_block_alone_matches_the_reference(model, monkeypatch, path):
         assert np.abs(got - w).max() > 1000 * BLOCK_ATOL, m
 
 
-def test_both_expert_paths_agree_and_the_shape_picks_one(model, monkeypatch):
+def test_both_expert_paths_agree_and_the_shape_picks_one(
+        model, monkeypatch, pallas_interpret):
     mcfg, params = model
     lw, h = _block(params, 24)
     out = {}
-    for path, rows in ALWAYS.items():
-        monkeypatch.setattr(M, "_SCAN_ROWS_PER_EXPERT", rows)
+    for path in ("scan", "ragged"):
+        monkeypatch.setattr(M, "_SCAN_ROWS_PER_EXPERT", ALWAYS[path])
         out[path] = np.asarray(M._mlp(h, lw, mcfg))
     assert np.abs(out["scan"] - out["ragged"]).max() < BLOCK_ATOL
+    # the third path takes 16-bit stacks that fill whole lanes: the same
+    # family two lanes wide, all three paths on the same bf16 leaves
+    wide = dataclasses.replace(mcfg, d_model=128, d_ff=128)
+    r = np.random.default_rng(5)
+    bf = lambda *shape: jnp.asarray(r.normal(size=shape) * 0.1, jnp.bfloat16)
+    lw = {"w_router": jnp.asarray(r.normal(size=(128, 8)), jnp.float32),
+          "w_gate": bf(8, 128, 128), "w_in": bf(8, 128, 128),
+          "w_out": bf(8, 128, 128)}
+    h = bf(24, 128) * 10
+    out = {}
+    for path, rows in ALWAYS.items():
+        monkeypatch.setattr(M, "_SCAN_ROWS_PER_EXPERT", rows)
+        monkeypatch.setattr(M, "_STREAM_ROWS_PER_EXPERT", rows)
+        kernels = path == "stream"
+        assert M.expert_path(24, wide, lw, kernels) == path
+        out[path] = np.asarray(
+            M._mlp(h, lw, wide, None, kernels).astype(jnp.float32))
+    top = np.abs(out["ragged"]).max()
+    assert top > 0.1
+    for path in ("stream", "scan"):
+        assert np.abs(out[path] - out["ragged"]).max() < BLOCK_RTOL_BF16 * top
     monkeypatch.undo()
-    # T x k / X rows an expert: ragged at or under 2 and from 128 on
+    # T x k / X rows an expert at the published widths, from the shapes
+    # alone. Where kernels run: the streamed pass above 1 row an expert
+    # and under 128; where they do not: the scan above 2 and under 128
     pub = config_from_hf({k: v for k, v in json.loads(
         PUBLISHED.read_text()).items() if not k.startswith("_")})
-    assert [M.expert_path(t, pub) for t in (8, 16, 32, 128, 256, 512, 1024)] \
-        == ["ragged", "ragged", "scan", "scan", "scan", "scan", "ragged"]
+    stack = jax.ShapeDtypeStruct((64, 2048, 1024), jnp.bfloat16)
+    lp = {"w_gate": stack, "w_in": stack,
+          "w_out": jax.ShapeDtypeStruct((64, 1024, 2048), jnp.bfloat16)}
+    widths = (8, 16, 32, 128, 256, 512, 1024)
+    assert [M.expert_path(t, pub, lp, True) for t in widths] == [
+        "ragged", "stream", "stream", "stream", "stream", "stream", "ragged"]
+    for by in ([M.expert_path(t, pub, lp, False) for t in widths],
+               [M.expert_path(t, pub) for t in widths]):
+        assert by == ["ragged", "ragged", "scan", "scan", "scan", "scan",
+                      "ragged"]
     # no flag selects it
-    assert M.expert_path(128, dataclasses.replace(pub, moe_dropless=False)) \
-        == M.expert_path(128, pub)
+    assert M.expert_path(128, dataclasses.replace(pub, moe_dropless=False),
+                         lp, True) == M.expert_path(128, pub, lp, True)
 
 
 def test_init_inference_names_the_experts_and_the_path(model):
     mcfg, params = model
     profiler.clear()
-    init_inference(params, mcfg, dict(ENGINE), dtype=jnp.float32)
+    eng = init_inference(params, mcfg, dict(ENGINE), dtype=jnp.float32)
     (span,) = [s for s in profiler.spans() if s.name == "init.inference"]
     assert span.ids["n_experts"] == 8 and span.ids["moe_top_k"] == 3
-    assert span.ids["moe_expert_path"] == M.expert_path(8, mcfg) == "scan"
-    assert M.moe_span_ids(dataclasses.replace(mcfg, n_experts=0), 8) == {}
+    eng_path = span.ids["moe_expert_path"]
+    assert eng_path == M.expert_path(8, mcfg, eng.params["layers"][0],
+                                     eng.resolved_impl == "pallas") == "scan"
+    # a dense model's span names no experts
+    dense = dataclasses.replace(mcfg, n_experts=0)
+    profiler.clear()
+    init_inference(T.init(dense, jax.random.PRNGKey(1)), dense, dict(ENGINE),
+                   dtype=jnp.float32)
+    (span,) = [s for s in profiler.spans() if s.name == "init.inference"]
+    assert not {"n_experts", "moe_top_k", "moe_expert_path"} & set(span.ids)
 
 
 # -- the weight rule -------------------------------------------------------

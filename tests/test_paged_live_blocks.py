@@ -235,9 +235,9 @@ def test_the_scheduler_counts_the_live_blocks_it_dispatches(rng):
     seen = []
     count = sched._count_tokens
 
-    def spy(n, ctx=None, steps=1):
+    def spy(n, width, ctx=None, steps=1):
         before = sched.counters["kv_live_blocks"]
-        count(n, ctx, steps)
+        count(n, width, ctx, steps)
         if ctx is not None:
             seen.append((np.array(ctx), steps,
                          sched.counters["kv_live_blocks"] - before))

@@ -342,6 +342,15 @@ def test_the_held_share_scans_its_experts_whatever_the_rows(model):
     assert [M.expert_path(t, mcfg) for t in (1, 8, 128, 8192)] == ["scan"] * 4
     whole = dataclasses.replace(mcfg, experts_held=None)
     assert M.expert_path(4, whole) == "ragged"     # every expert held: by rows
+    # where kernels run, the cell's held experts (8 of 7680 x 2048) take
+    # the one streamed pass at every width its buffers fit, and the
+    # scan beyond (a whole prompt of the logits check)
+    stack = jax.ShapeDtypeStruct((8, 7680, 2048), jnp.bfloat16)
+    lp = {"w_gate": stack, "w_in": stack,
+          "w_out": jax.ShapeDtypeStruct((8, 2048, 7680), jnp.bfloat16)}
+    assert [M.expert_path(t, mcfg, lp, True) for t in (1, 8, 128, 304, 8192)] \
+        == ["stream"] * 4 + ["scan"]
+    assert M.expert_path(128, mcfg, lp, False) == "scan"
 
 
 def test_the_routed_block_alone_matches_the_reference(model):

@@ -107,6 +107,50 @@ class KvCacheDtypeError(ValueError):
     heterogeneous-fleet geometry rejection."""
 
 
+# What a served model's cache holds, by kind of pool, and what each
+# kind cannot do yet. A paged K/V pool does everything. A latent pool
+# (one row a token, ops/pallas latent kernels) has no int8 form, no
+# sharding and no page transfer. A state pool (model.PagedCache.state:
+# one fixed-size row a tracked sequence) is not paged at all: whatever
+# moves or shares PAGES (a prefix-cache credit, COW, handoff, spill)
+# would move them without the state that goes with their last token,
+# and whatever rolls a sequence back (a rejected speculative draft)
+# would leave the state ahead of it. Until a snapshot of the state per
+# cached block exists these are refused HERE, where the engine or the
+# scheduler is built, never computed wrongly.
+_POOL_CANNOT = {
+    "kv": frozenset(),
+    "latent": frozenset({"mesh", "weight_quantization", "offload",
+                         "int8_kv", "page_transfer"}),
+    "state": frozenset({"mesh", "weight_quantization", "offload",
+                        "int8_kv", "page_transfer", "prefix_credit",
+                        "speculation"}),
+}
+
+
+def pool_kinds(cfg: T.TransformerConfig) -> Tuple[str, ...]:
+    """The kinds of pool this model's cache holds: ('kv',), ('latent',),
+    and 'state' beside either where some layers carry recurrent state."""
+    return (("latent" if cfg.is_latent else "kv",)
+            + (("state",) if cfg.n_state_layers else ()))
+
+
+def pools_can(cfg: T.TransformerConfig, feature: str) -> bool:
+    return not any(feature in _POOL_CANNOT[k] for k in pool_kinds(cfg))
+
+
+def refuse_for_pools(cfg: T.TransformerConfig, feature: str) -> None:
+    """THE refusal of what a model's pools cannot do: raises naming the
+    pool kinds present and which of them cannot do `feature`."""
+    kinds = pool_kinds(cfg)
+    cannot = [k for k in kinds if feature in _POOL_CANNOT[k]]
+    if cannot:
+        raise NotImplementedError(
+            f"{feature} is not served for a model whose cache holds "
+            f"{' + '.join(kinds)} pools: the {' and the '.join(cannot)} "
+            f"pool cannot do it yet (inference/engine.py _POOL_CANNOT)")
+
+
 def _bucket(n: int, lo: int) -> int:
     b = lo
     while b < n:
@@ -255,13 +299,13 @@ class InferenceEngine:
                     f"tp_size {tp} (ref AutoTP requires head divisibility, "
                     "module_inject/auto_tp.py)"
                 )
-        if model_config.is_latent and (
-                self.mesh is not None or quantization or offload is not None
-                or self.config.kv_cache_dtype != "auto"):
-            raise NotImplementedError(
-                "a latent-attention model is served on one device from "
-                "resident bf16/f32 weights and a bf16/f32 latent cache: no "
-                "mesh, weight quantization, offload or int8 KV yet")
+        for feature, asked in (
+                ("mesh", self.mesh is not None),
+                ("weight_quantization", bool(quantization)),
+                ("offload", offload is not None),
+                ("int8_kv", self.config.kv_cache_dtype != "auto")):
+            if asked:
+                refuse_for_pools(model_config, feature)
         if self.config.decode_impl not in ("auto", "pallas", "xla"):
             raise ValueError(
                 f"decode_impl must be 'auto', 'pallas' or 'xla' "
@@ -381,6 +425,10 @@ class InferenceEngine:
             max_tracked=self.config.max_tracked_sequences,
             enable_prefix_cache=self.config.prefix_cache.enabled,
             cache_pool_blocks=self.config.prefix_cache.pool_blocks,
+            # a model with recurrent state is given no prefix credit
+            # (the index still fills: admissions that would have been
+            # credited are counted, PrefixMatch.declined)
+            credit_prefix=pools_can(model_config, "prefix_credit"),
         )
         self._cow_fn = None  # compiled (cache, src, dst) -> cache page copy
         # compiled block-table transfer pair (disaggregated serving):
@@ -398,12 +446,18 @@ class InferenceEngine:
                 f"kv_cache_dtype must be 'auto' or 'int8' "
                 f"(got {self.config.kv_cache_dtype!r})")
         self.kv_quant = self.config.kv_cache_dtype == "int8"
-        with profiler.span("init.pool", always=True):
+        with profiler.span("init.pool", always=True) as sp:
             self.cache = host_sync(M.init_cache(
                 model_config, self.config.num_kv_blocks + 1,
                 self.config.kv_block_size, dtype, mesh=self.mesh,
                 kv_quant=self.kv_quant,
+                state_slots=self.config.max_tracked_sequences,
             ))
+            if self.cache.state:
+                sp.set(kv_layers=len(self.cache.k),
+                       state_layers=len(self.cache.state),
+                       state_slots=self.config.max_tracked_sequences,
+                       state_bytes=sum(x.nbytes for x in self.cache.state))
         self._prefill_batch_fns: Dict[Tuple[int, int], Any] = {}
         # keyed (batch_width, unique_rows)
         self._decode_fns: Dict[Tuple[int, bool], Any] = {}
@@ -481,6 +535,17 @@ class InferenceEngine:
         if self._offload is not None:
             self.params = self._refresh_offload(params)
             return
+        if self._host_tree_too_large_twice(params):
+            # the compiled transform holds its input AND its output on
+            # the device; a host tree of over half the device's memory
+            # is laid out on the host instead (numpy views a layer, the
+            # few fused projections made on the device) and sent once
+            dtype = self._dtype
+            cast = jax.tree.map(
+                lambda x: x.astype(dtype)
+                if jnp.issubdtype(x.dtype, jnp.floating) else x, params)
+            self.params = jax.device_put(M.prepare(cast, self.cfg, fuse=True))
+            return
         if self._prepare_fn is None:
             cfg, dtype = self.cfg, self._dtype
             fuse = self.mesh is None
@@ -507,6 +572,25 @@ class InferenceEngine:
         if self.mesh is not None:
             prepared = _shard_serving_params(prepared, self.cfg, self.mesh)
         self.params = prepared
+
+    def _host_tree_too_large_twice(self, params: Any) -> bool:
+        """Whether `params` is a plain tree of HOST arrays that the
+        device could not hold twice over in the serving dtype (one
+        device, no quantization: the cases refresh_params' compiled
+        transform is not needed for). Read from the arrays and the
+        device's own memory limit; a backend that states none (the
+        CPU) says no."""
+        leaves = jax.tree.leaves(params)
+        if self.mesh is not None or self._quantization or self._per_channel \
+                or not all(isinstance(x, np.ndarray) for x in leaves):
+            return False
+        stats = jax.local_devices()[0].memory_stats() or {}
+        if "bytes_limit" not in stats:
+            return False
+        itemsize = jnp.dtype(self._dtype).itemsize
+        nbytes = sum(x.size * (itemsize if jnp.issubdtype(
+            x.dtype, jnp.floating) else x.dtype.itemsize) for x in leaves)
+        return 2 * nbytes > stats["bytes_limit"] - stats.get("bytes_in_use", 0)
 
     def _layer_pspec_sharding(self, lp: Any, memory_kind: str):
         """Per-leaf NamedShardings for ONE prepared layer under the TP
@@ -709,11 +793,11 @@ class InferenceEngine:
 
             census = self._census_cb()
 
-            def step(params, cache, tokens, n_real, tables):
+            def step(params, cache, tokens, n_real, tables, *slots):
                 return M.prefill_batch(
                     deq(params), cache, tokens, n_real, tables, cfg,
                     use_kernel, mesh=mesh, fetch_layer=fetch,
-                    census_cb=census,
+                    census_cb=census, slots=slots[0] if slots else None,
                 )
 
             # donated: the paged KV cache aliases the returned cache
@@ -750,11 +834,11 @@ class InferenceEngine:
 
             census = self._census_cb()
 
-            def step(params, cache, tokens, tables, ctx):
+            def step(params, cache, tokens, tables, ctx, *slots):
                 return M.decode_step(
                     deq(params), cache, tokens, tables, ctx, cfg, use_kernel,
                     mesh=mesh, unique_rows=unique_rows, fetch_layer=fetch,
-                    census_cb=census,
+                    census_cb=census, slots=slots[0] if slots else None,
                 )
 
             # donated: the KV cache aliases the returned cache in-place
@@ -780,29 +864,32 @@ class InferenceEngine:
             census = self._census_cb()
 
             if sampling is None:
-                def step(params, cache, tokens, tables, ctx):
+                def step(params, cache, tokens, tables, ctx, *slots):
                     return M.decode_multi(
                         deq(params), cache, tokens, tables, ctx, cfg,
                         n_steps=n_steps, use_kernel=use_kernel, mesh=mesh,
                         fetch_layer=fetch, census_cb=census,
+                        slots=slots[0] if slots else None,
                     )
             elif with_presence:
                 def step(params, cache, tokens, tables, ctx, keys, step0,
-                         presence):
+                         presence, *slots):
                     return M.decode_multi(
                         deq(params), cache, tokens, tables, ctx, cfg,
                         n_steps=n_steps, use_kernel=use_kernel, mesh=mesh,
                         sampling=sampling, keys=keys, step0=step0,
                         presence=presence, fetch_layer=fetch,
-                        census_cb=census,
+                        census_cb=census, slots=slots[0] if slots else None,
                     )
             else:
-                def step(params, cache, tokens, tables, ctx, keys, step0):
+                def step(params, cache, tokens, tables, ctx, keys, step0,
+                         *slots):
                     return M.decode_multi(
                         deq(params), cache, tokens, tables, ctx, cfg,
                         n_steps=n_steps, use_kernel=use_kernel, mesh=mesh,
                         sampling=sampling, keys=keys, step0=step0,
                         fetch_layer=fetch, census_cb=census,
+                        slots=slots[0] if slots else None,
                     )
 
             # donated: the KV cache aliases the carried cache output
@@ -847,6 +934,15 @@ class InferenceEngine:
             return jnp.asarray(x)
         return jax.device_put(jnp.asarray(x), NamedSharding(self.mesh, P()))
 
+    def state_args(self, slots: np.ndarray) -> tuple:
+        """The operand a compiled step of a model with recurrent state
+        takes after the others: each row's sequence's state slot (-1: a
+        pad row), on the device. Empty for every other model, whose
+        programs take no such operand."""
+        if not self.cache.state:
+            return ()
+        return (self._dev(np.asarray(slots, np.int32)),)
+
     def _copy_block(self, src: int, dst: int) -> None:
         """Host-issued cache-page copy (the COW half of prefix caching):
         clone block src's K/V rows into block dst across every layer, in
@@ -863,6 +959,7 @@ class InferenceEngine:
                              [ks.at[d].set(ks[s]) for ks in cache.k_scale]),
                     v_scale=(None if cache.v_scale is None else
                              [vs.at[d].set(vs[s]) for vs in cache.v_scale]),
+                    state=cache.state,
                 )
 
             # donated: cache aliases the returned PagedCache (in-place
@@ -903,9 +1000,8 @@ class InferenceEngine:
 
     # -- paged-KV block transfer (prefill/decode disaggregation) ---------
     def _pages_travel(self) -> None:
-        if self.cfg.is_latent:
-            raise NotImplementedError(
-                "a latent cache's pages do not travel yet (handoff, spill)")
+        """Handoff and spill move pages between caches."""
+        refuse_for_pools(self.cfg, "page_transfer")
 
     def _kv_gather_fn(self):
         """Compiled gather of [blocks_per_seq] cache pages across every
@@ -1045,6 +1141,7 @@ class InferenceEngine:
         not on which replica runs them). Raises HandoffIntegrityError
         BEFORE any allocation when the payload's digest envelope does
         not verify (an in-transit/DRAM bit flip) — same fallback."""
+        self._pages_travel()
         fault_point("engine.import_kv", uid=uid)
         # chaos point 'handoff.payload': kind='corrupt' flips one bit
         # in the K/V page stacks of a COPY of the payload (the
@@ -1384,9 +1481,10 @@ class InferenceEngine:
                 toks_b = np.zeros((bp, tp), np.int32)
                 n_real = np.zeros((bp,), np.int32)
                 tables = np.zeros((bp, self.config.blocks_per_seq), np.int32)
+                slots = np.full((bp,), -1, np.int32)
                 for row, (pos, uid, toks) in enumerate(wave):
                     n = len(toks)
-                    self.state.extend(uid, n)
+                    slots[row] = self.state.extend(uid, n).slot
                     toks_b[row, :n] = toks
                     n_real[row] = n
                     tables[row] = self.state.block_table(
@@ -1394,6 +1492,7 @@ class InferenceEngine:
                 logits, self.cache = self._prefill_batch_fn(bp, tp)(
                     self.params, self.cache, self._dev(toks_b),
                     self._dev(n_real), self._dev(tables),
+                    *self.state_args(slots),
                 )
                 for row, (pos, uid, toks) in enumerate(wave):
                     self.state.commit(uid, len(toks), token_ids=toks)
@@ -1416,11 +1515,12 @@ class InferenceEngine:
             ctx = np.zeros((sp,), np.int32)  # pad rows: ctx 0 = inert
             tables = np.full((sp, self.config.blocks_per_seq),
                              self.pad_block, np.int32)
+            slots = np.full((sp,), -1, np.int32)
             last_row: List[int] = []  # each chunk's final row index
             row = 0
             for pos, uid, chunk in decodes:
                 base = self.state.get(uid).seen_tokens
-                self.state.extend(uid, len(chunk))
+                slot = self.state.extend(uid, len(chunk)).slot
                 table = self.state.block_table(
                     [uid], self.config.blocks_per_seq, self.pad_block,
                 )[0]
@@ -1428,6 +1528,7 @@ class InferenceEngine:
                     toks[row] = int(tok)
                     ctx[row] = base + j + 1
                     tables[row] = table
+                    slots[row] = slot
                     row += 1
                 last_row.append(row - 1)
             # single-token rows are all DISTINCT sequences → the fused
@@ -1436,7 +1537,7 @@ class InferenceEngine:
             unique = all(len(c) == 1 for _, _, c in decodes)
             logits, self.cache = self._decode_fn(sp, unique)(
                 self.params, self.cache, self._dev(toks),
-                self._dev(tables), self._dev(ctx),
+                self._dev(tables), self._dev(ctx), *self.state_args(slots),
             )
             for (pos, uid, chunk), lr in zip(decodes, last_row):
                 self.state.commit(uid, len(chunk), token_ids=chunk)
@@ -1557,17 +1658,22 @@ class InferenceEngine:
             steps = np.zeros((w,), np.int32)
             keys = self._row_keys(0, np.zeros((w,), np.uint32))
             logits = None
-            # a routed model's programs name their expert path
+            state = self.state_args(np.full((w,), -1, np.int32))
+            # a routed model's programs name their expert path, a model
+            # of two kinds of layer its counts of each
             path = self.expert_path(w)
-            moe = {"moe_expert_path": path} if path else {}
+            named = {"moe_expert_path": path} if path else {}
+            if state:
+                named.update(kv_layers=len(self.cache.k),
+                             state_layers=len(self.cache.state))
             for uniq in ((True, False) if chunked else (True,)):
                 rt.record(f"serving_decode[w{w},u{int(uniq)}]",
                           (toks, tables, ctx))
                 logits, self.cache = warm(
                     "decode", w, lambda: self._decode_fn(w, uniq)(
                         self.params, self.cache, self._dev(toks),
-                        self._dev(tables), self._dev(ctx)),
-                    unique=int(uniq), **moe)
+                        self._dev(tables), self._dev(ctx), *state),
+                    unique=int(uniq), **named)
             if with_pres:
                 pres = np.zeros((w, V), np.uint8)
                 rt.record(f"serving_sample[w{w}]", (steps, pres))
@@ -1603,8 +1709,9 @@ class InferenceEngine:
                     args.append(self._dev(steps))
                     if with_pres:
                         args.append(self._dev(np.zeros((w, V), np.uint8)))
+                args += state
                 _, _, self.cache, _ = warm("fused", w, lambda: fn(*args),
-                                           chunk=C, **moe)
+                                           chunk=C, **named)
             if footprint:
                 with profiler.span("warmup.footprint", always=True, width=w):
                     rep = build_cost_report(self.compiled_decode(w),
@@ -1660,7 +1767,8 @@ class InferenceEngine:
         tables = np.full((width, self.config.blocks_per_seq),
                          self.pad_block, np.int32)
         return self._aot(self._decode_fn(width, unique_rows),
-                         self._dev(toks), self._dev(tables), self._dev(toks))
+                         self._dev(toks), self._dev(tables), self._dev(toks),
+                         *self.state_args(toks - 1))
 
     def compiled_prefill(self, bp: int, tp: int):
         """AOT-compiled whole-prompt prefill wave for batch bucket bp x
@@ -1669,7 +1777,8 @@ class InferenceEngine:
             self._prefill_batch_fn(bp, tp),
             self._dev(np.zeros((bp, tp), np.int32)),
             self._dev(np.zeros((bp,), np.int32)),
-            self._dev(np.zeros((bp, self.config.blocks_per_seq), np.int32)))
+            self._dev(np.zeros((bp, self.config.blocks_per_seq), np.int32)),
+            *self.state_args(np.full((bp,), -1, np.int32)))
 
     def _aot(self, fn, *operands):
         import warnings
@@ -1717,7 +1826,8 @@ class InferenceEngine:
                 _warnings.simplefilter("ignore")
                 lowered = self._decode_fn(w, True).lower(
                     self.params, self.cache, self._dev(toks),
-                    self._dev(tables), self._dev(ctx))
+                    self._dev(tables), self._dev(ctx),
+                    *self.state_args(toks - 1))
                 compiled = lowered.compile()
             reports.append(check_program_numerics(
                 compiled, policy, lowered=lowered,
@@ -1738,6 +1848,7 @@ class InferenceEngine:
         candidate rows is written, but seen_tokens is NOT committed:
         the caller commits only the accepted prefix (rejected rows'
         slots are simply overwritten by the next real tokens)."""
+        refuse_for_pools(self.cfg, "speculation")
         rows = sum(len(c) for c in chunks)
         if rows > self.config.max_batch_size:
             raise RuntimeError(
@@ -2064,6 +2175,9 @@ def init_inference(
             sp.set(n_experts=model_config.n_experts,
                    moe_top_k=model_config.moe_top_k,
                    moe_expert_path=engine.expert_path(icfg.max_batch_size))
+        if engine.cache.state:  # and a model of two kinds its layers
+            sp.set(kv_layers=len(engine.cache.k),
+                   state_layers=len(engine.cache.state))
         return engine
 
 

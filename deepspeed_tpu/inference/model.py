@@ -31,7 +31,10 @@ Cache: per layer, k and v as [num_blocks, block_size, KV_heads,
 head_dim] — one cache page is a contiguous (block_size, KV, D) tile
 (single large DMA in the kernels); TP shards the KV dim. All cache
 mutation goes through Pallas RMW kernels on donated buffers so the
-arena is updated in place.
+arena is updated in place. A model whose layers are of two kinds
+(cfg.layer_types) has K/V pools for its attention layers ALONE and,
+for each of the others, a STATE pool [slots, width]: one row a tracked
+sequence, of fixed size whatever the sequence's length (PagedCache).
 """
 
 from functools import partial
@@ -45,6 +48,8 @@ from ..models import transformer as T
 from ..ops.attention import causal_attention, uses_flash
 from ..ops.pallas.expert_stream import expert_stream_mlp, stream_f_tile
 from ..ops.pallas.paged_attention import (
+    fused_write_fits,
+    kv_pack,
     latent_lanes,
     paged_decode_attention,
     paged_decode_attention_xla,
@@ -88,18 +93,30 @@ def prepare(params: Dict[str, Any], cfg: T.TransformerConfig,
             f"(got leading dim {lead.shape[0]} != {L}; merge pipeline "
             "partitions before serving)"
         )
-    out["layers"] = [
-        prepare_layer({name: w[l] for name, w in st.items()}, cfg, fuse)
-        for l in range(L)
-    ]
+    # operators of two kinds: each kind's top-level stacks hand layer li
+    # the entry of its place among its kind (conv leaves keep their
+    # prefix, clear of the FFN's w_in / w_out)
+    ops = {kind: {(k if kind == "conv" else k[len(prefix):]): out.pop(k)
+                  for k in list(out) if k.startswith(prefix)}
+           for kind, prefix, _ in T.operator_stacks(cfg)}
+
+    def layer(leaves, li):
+        mine = ops.get(cfg.layer_kind(li), {})
+        return prepare_layer(dict(
+            leaves, **{name: w[cfg.op_index(li)] for name, w in mine.items()}),
+            cfg, fuse)
+
+    nd = cfg.n_dense_layers
+    out["layers"] = [layer({name: w[l] for name, w in st.items()}, nd + l)
+                     for l in range(L)]
     # leading dense layers: their top-level `dense_<name>` stacks become
     # a list of per-layer dicts beside `layers`
     pre = T.DENSE_PREFIX
     dense = {k[len(pre):]: out.pop(k) for k in list(out) if k.startswith(pre)}
     if dense:
         out["dense_layers"] = [
-            prepare_layer({name: w[l] for name, w in dense.items()}, cfg, fuse)
-            for l in range(cfg.n_dense_layers)]
+            layer({name: w[l] for name, w in dense.items()}, l)
+            for l in range(nd)]
     return out
 
 
@@ -318,7 +335,11 @@ def _shard_map_kernel(fn, mesh: Mesh, in_specs, out_specs):
 
 
 class PagedCache(NamedTuple):
-    """Per-layer lists (length n_layers) of [NBLK, bs, KV, D] arrays.
+    """Per-layer lists of [NBLK, bs, KV, D] arrays, one entry for each
+    layer that holds K/V (cfg.n_kv_layers: every layer, or the
+    attention layers of a model of two kinds, in their order). At head
+    dim 64 a pool is PACKED, [NBLK, bs, KV / 2, 128] (kv_pack: two
+    heads a 128-lane row, the same bytes).
 
     A latent-attention model (cfg.is_latent) caches ONE row a token a
     layer, [normed latent; rotary key]: `k` holds its pools
@@ -334,6 +355,16 @@ class PagedCache(NamedTuple):
     v: List[jnp.ndarray] = ()
     k_scale: Optional[List[jnp.ndarray]] = None
     v_scale: Optional[List[jnp.ndarray]] = None
+    # recurrent state: one [slots, width] pool for each layer that
+    # carries fixed-size state from token to token (cfg.n_state_layers,
+    # in their order); row s is the state of the tracked sequence that
+    # holds slot s (ragged.SequenceDescriptor.slot). What a row holds is
+    # the layer's business (a conv layer: its last conv_kernel - 1
+    # inputs, oldest first); a layer kind with another state is another
+    # width here, not another manager. Not paged: pages travel (COW,
+    # handoff, spill) WITHOUT it, which is why the engine refuses those
+    # for a model that has any.
+    state: List[jnp.ndarray] = ()
 
     @property
     def block_size(self) -> int:
@@ -351,20 +382,28 @@ class PagedCache(NamedTuple):
 def init_cache(
     cfg: T.TransformerConfig, num_blocks: int, block_size: int, dtype=jnp.bfloat16,
     mesh: Optional[Mesh] = None, kv_quant: bool = False,
+    state_slots: int = 0,
 ) -> PagedCache:
     """kv_quant=True allocates int8 code pools + f32 per-block scale
     tiles instead of `dtype` pools — half (vs bf16) or a quarter (vs
-    f32) the resident KV bytes plus KV*8 scale bytes per token."""
-    KV, D, L = cfg.kv_heads, cfg.head_dim, cfg.depth
+    f32) the resident KV bytes plus KV*8 scale bytes per token.
+    state_slots: rows of each state pool (one a tracked sequence) of a
+    model with recurrent state."""
+    KV, D, L = cfg.kv_heads, cfg.head_dim, cfg.n_kv_layers
+    if (cfg.is_latent or cfg.n_state_layers) and (
+            kv_quant or mesh is not None):
+        raise NotImplementedError(
+            "a latent cache, and a cache beside recurrent state, is "
+            "bf16/f32 on one device: no int8 pool and no mesh")
+    state = [jnp.zeros((state_slots, cfg.state_width), dtype)
+             for _ in range(cfg.n_state_layers)]
     if cfg.is_latent:
-        if kv_quant or mesh is not None:
-            raise NotImplementedError(
-                "a latent cache is bf16/f32 on one device: no int8 pool "
-                "and no mesh")
         shape = (num_blocks, block_size, latent_lanes(cfg.latent_dim))
         return PagedCache(k=[jnp.zeros(shape, dtype) for _ in range(L)],
                           v=[])
-    shape = (num_blocks, block_size, KV, D)
+    # unquantised pools on one device pack two 64-wide heads a row
+    pack = 1 if kv_quant or mesh is not None else kv_pack(KV, D)
+    shape = (num_blocks, block_size, KV // pack, D * pack)
     if kv_quant:
         dtype = jnp.int8
     if mesh is not None:
@@ -378,8 +417,9 @@ def init_cache(
         mk = lambda: jnp.zeros(shape, dtype)
         mks = lambda: jnp.ones(shape[:3], jnp.float32)
     if not kv_quant:
-        return PagedCache(k=[mk() for _ in range(L)],
-                          v=[mk() for _ in range(L)])
+        cache = PagedCache(k=[mk() for _ in range(L)],
+                           v=[mk() for _ in range(L)])
+        return cache._replace(state=state) if state else cache
     return PagedCache(
         k=[mk() for _ in range(L)], v=[mk() for _ in range(L)],
         k_scale=[mks() for _ in range(L)], v_scale=[mks() for _ in range(L)])
@@ -457,6 +497,8 @@ def _write_kv_xla(cache_k, cache_v, k_new, v_new, flat_idx):
     first — otherwise pad rows would overwrite the last cache slot."""
     NBLK, bs, KV, D = cache_k.shape
     idx = jnp.where(flat_idx < 0, NBLK * bs, flat_idx)
+    # rows in the pool's own row shape (a packed pool's: kv_pack)
+    k_new, v_new = (r.reshape(r.shape[0], KV, D) for r in (k_new, v_new))
     ck = cache_k.reshape(NBLK * bs, KV, D).at[idx].set(k_new, mode="drop")
     cv = cache_v.reshape(NBLK * bs, KV, D).at[idx].set(v_new, mode="drop")
     return ck.reshape(NBLK, bs, KV, D), cv.reshape(NBLK, bs, KV, D)
@@ -503,7 +545,7 @@ def _layer_pools(cache: PagedCache, li: int) -> tuple:
     """One layer's pools in PagedCache's field order: (k, v), and
     (k, v, k_scale, v_scale) of a quantised cache. What `attend` hands
     back per layer and _forward zips into the new PagedCache."""
-    return tuple(pool[li] for pool in cache if pool)
+    return tuple(pool[li] for pool in cache[:4] if pool)
 
 
 def _write_pools(pools: tuple, k_new, v_new, flat_idx, mesh=None,
@@ -674,7 +716,8 @@ def _mlp(h, lp, cfg: T.TransformerConfig, census_cb=None,
     with jax.named_scope("moe_route"):
         logits = h.astype(jnp.float32) @ lp["w_router"].astype(jnp.float32)
         if cfg.moe_scoring == "sigmoid":
-            idx, wts = _sigmoid_topk_gating(logits, cfg)
+            idx, wts = _sigmoid_topk_gating(logits, cfg,
+                                            lp.get("expert_bias"))
         else:
             # eval gate: no noise; one authority with the training paths
             idx, wts, _, _ = dropless_topk_gating(
@@ -757,15 +800,22 @@ def _moe_shared(out, h, lp, cfg: T.TransformerConfig, act):
     return _moe_residual(out, h, lp, cfg, act)
 
 
-def _sigmoid_topk_gating(logits, cfg: T.TransformerConfig):
+def _sigmoid_topk_gating(logits, cfg: T.TransformerConfig, bias=None):
     """Sigmoid-scored top-k (DeepSeek-V3 class routers): each expert's
     score is the sigmoid of its own logit, in float32; the k largest
     are chosen (ties to the lowest index), their scores divided by
     their sum when cfg.moe_norm_topk_prob, then multiplied by
-    cfg.routed_scaling_factor. No groups, no correction bias.
+    cfg.routed_scaling_factor. `bias` [X] (a layer's `expert_bias`,
+    cfg.moe_expert_bias) is added to the scores for the CHOICE alone:
+    the weights are the chosen experts' unbiased scores. No groups.
     logits [T, X] f32 -> (idx [T, k] int32, weights [T, k] f32)."""
     scores = jax.nn.sigmoid(logits)
-    wts, idx = jax.lax.top_k(scores, cfg.moe_top_k)
+    if bias is not None:
+        _, idx = jax.lax.top_k(scores + bias.astype(jnp.float32),
+                               cfg.moe_top_k)
+        wts = jnp.take_along_axis(scores, idx, axis=-1)
+    else:
+        wts, idx = jax.lax.top_k(scores, cfg.moe_top_k)
     if cfg.moe_norm_topk_prob:
         wts = wts / (jnp.sum(wts, axis=-1, keepdims=True) + 1e-20)
     return idx, wts * cfg.routed_scaling_factor
@@ -873,20 +923,31 @@ def _decode_attention(q, pools: tuple, table, ctx, use_kernel: bool,
 # ---------------------------------------------------------------------------
 
 def _layer(x, lp, li: int, positions, cfg: T.TransformerConfig, mesh, attend,
-           alibi, census_cb=None, use_kernel: bool = False):
+           alibi, census_cb=None, use_kernel: bool = False, carry=None):
     """One serving layer over [..., E] activations (decode rows [S, E],
-    prefill prompts [B, Tp, E]): norm1, the QKV projection (fused w_qkv
+    prefill prompts [B, Tp, E]): norm1, then the layer's operator by its
+    kind (cfg.layer_kind(li)) and the FFN tail. A 'conv' layer: the
+    gated short convolution (_short_conv) with `carry(u, li)` handed in
+    by the caller, as `attend` is: where the inputs before this one
+    come from and how the sequence's state row is left. An attention
+    layer: the QKV projection (fused w_qkv
     or split, bias or none), QK-norm, rope at `positions` (the
     second-to-last axis of q/k: [S] or [Tp]), the head constraints,
     `attend(q, k, v, li, alibi, lp) -> (att, layer_cache)` handed in by the
     caller (the ONE thing the two sites differ in: what attention runs
     and how the new rows reach the cache), the output projection and
     the FFN tail, whose routed block asks expert_path with `use_kernel`
-    and the mesh. Returns (x, layer_cache)."""
+    and the mesh. Returns (x, layer_cache): the layer's K/V pools, or
+    its state pool."""
     H, KV = cfg.n_heads, cfg.kv_heads
     with jax.named_scope("norm1"):
         h1 = T._act_quant(
             T._norm(x, lp["ln1_scale"], lp.get("ln1_bias"), cfg), cfg)
+    if cfg.layer_kind(li) == "conv":
+        with jax.named_scope("short_conv"):
+            out, state = _short_conv(h1, lp, partial(carry, li=li))
+        return _ffn_residual(x, out, h1, lp, cfg, census_cb, use_kernel,
+                             mesh), state
     if cfg.is_latent:
         with jax.named_scope("attention"):
             with jax.named_scope("mla_project"):
@@ -929,6 +990,92 @@ def _layer(x, lp, li: int, positions, cfg: T.TransformerConfig, mesh, attend,
             out = T._norm(out, lp["ln1_post_scale"], None, cfg)
     return _ffn_residual(x, out, h1, lp, cfg, census_cb, use_kernel,
                          mesh), layer_cache
+
+
+# ---------------------------------------------------------------------------
+# the gated short convolution, and the state its sequences carry
+# ---------------------------------------------------------------------------
+
+def _short_conv(h1, lp, carry):
+    """Normed activations h1 [..., E] -> (the operator's output
+    [..., E], the layer's state pool). [B; C; X] = conv_in h1;
+    u = B * X; v_t = sum_j taps[:, j] * u_{t-(K-1)+j} (depthwise,
+    causal, no bias, no activation; the sum in float32); out =
+    conv_out (C * v). `carry(u)` gives (the K - 1 inputs before each
+    position, oldest first, each shaped as u; the state pool with each
+    sequence's last K - 1 inputs written): the one thing a step over
+    ragged rows and a whole-prompt prefill differ in."""
+    with jax.named_scope("conv_project"):
+        b, c, xg = jnp.split(_wmm("...e,ef->...f", h1, lp["conv_in"]), 3,
+                             axis=-1)
+        u = b * xg
+    with jax.named_scope("conv_state"):
+        past, state = carry(u)
+    taps = lp["conv_taps"].astype(jnp.float32)  # [E, K], oldest first
+    v = sum(up.astype(jnp.float32) * taps[:, j]
+            for j, up in enumerate([*past, u]))
+    with jax.named_scope("conv_out"):
+        out = _wmm("...e,ef->...f", c * v.astype(u.dtype), lp["conv_out"])
+    return out, state
+
+
+def _state_write(pool, slots, rows, keep):
+    """State pool [slots, W] with rows [N, W] written at slots [N]
+    where `keep` [N]; the others (pad rows, rows that are not their
+    sequence's last of the step) are dropped."""
+    idx = jnp.where(keep & (slots >= 0), slots, pool.shape[0])
+    return pool.at[idx].set(rows.astype(pool.dtype), mode="drop")
+
+
+def _carry_rows(u, pool, slots, positions):
+    """`carry` of a step over ragged rows u [S, E]: row r is the token
+    at positions[r] of the sequence holding state slot slots[r] (-1:
+    batch padding); rows of one sequence are adjacent and in order (a
+    prefill chunk), any other row is another sequence. The k-th input
+    before row r is row r - k where that is the same sequence's token
+    k places back, else it was left in the sequence's slot by an
+    earlier step; before the sequence's first token it is ZERO,
+    whatever the slot holds, so a slot needs no clearing between the
+    sequences that take it in turn, nor after a flush. Each sequence's
+    last row of the step leaves its last K - 1 inputs in the slot."""
+    S, E = u.shape
+    K1 = pool.shape[1] // E  # K - 1 inputs carried, oldest first
+    held = pool[jnp.maximum(slots, 0)].reshape(S, K1, E)
+    row = jnp.arange(S)
+    same = [(jnp.roll(slots, k) == slots) & (jnp.roll(positions, k) + k
+                                            == positions) & (row >= k)
+            for k in range(1, K1 + 1)]
+    # rows of its own sequence before row r in this step, up to K - 1
+    run = jnp.sum(jnp.cumprod(jnp.stack(same).astype(jnp.int32), axis=0),
+                  axis=0)
+    past = []
+    for k in range(K1, 0, -1):  # oldest first
+        here = jnp.roll(u, k, axis=0)
+        there = jnp.take_along_axis(
+            held, jnp.clip(K1 - k + run, 0, K1 - 1)[:, None, None], axis=1
+        )[:, 0].astype(u.dtype)
+        past.append(jnp.where(
+            (positions >= k)[:, None],
+            jnp.where((run >= k)[:, None], here, there), 0))
+    last = jnp.roll(slots, -1) != slots
+    last = last.at[S - 1].set(True)
+    rows = jnp.concatenate([*past[1:], u], axis=-1)
+    return past, _state_write(pool, slots, rows, last)
+
+
+def _carry_prompts(u, pool, slots, n_real):
+    """`carry` of a whole-prompt prefill u [B, Tp, E]: a plain causal
+    shift (zeros before the prompt starts), and each prompt's last
+    K - 1 real inputs written to its sequence's slot."""
+    B, Tp, E = u.shape
+    K1 = pool.shape[1] // E
+    up = jnp.pad(u, ((0, 0), (K1, 0), (0, 0)))  # up[:, t + K1] = u_t
+    past = [up[:, K1 - k:K1 - k + Tp] for k in range(K1, 0, -1)]
+    # inputs n_real - K1 .. n_real - 1: up[:, n_real .. n_real + K1 - 1]
+    tail = jnp.take_along_axis(
+        up, (n_real[:, None] + jnp.arange(K1))[:, :, None], axis=1)
+    return past, _state_write(pool, slots, tail.reshape(B, K1 * E),
+                              n_real > 0)
 
 
 # ---------------------------------------------------------------------------
@@ -1040,7 +1187,7 @@ def _latent_naive(q, row, lp, pool, flat_idx, cfg: T.TransformerConfig,
 
 def _forward(params, tokens, positions, cfg: T.TransformerConfig, mesh,
              attend, fetch_layer=None, census_cb=None, head_rows=None,
-             use_kernel: bool = False):
+             use_kernel: bool = False, carry=None):
     """The serving forward both sites run: tokens [...] int32 at
     `positions` (their last axis) -> (f32 logits, the PagedCache the
     layers' `attend` calls returned). Prologue (embedding, learned
@@ -1060,7 +1207,8 @@ def _forward(params, tokens, positions, cfg: T.TransformerConfig, mesh,
     alibi = (jnp.asarray(T.model_alibi_slopes(cfg)) if cfg.alibi
              else None)
 
-    pools = []  # per layer, as _layer_pools
+    pools = []  # per layer that holds K/V, as _layer_pools
+    states = []  # per layer that holds state, its pool
     x_hist = []  # layer outputs; fetch l is barriered on output l-2
     # leading dense layers first (cache layers 0..n_dense-1), then the
     # stacked ones
@@ -1070,8 +1218,9 @@ def _forward(params, tokens, positions, cfg: T.TransformerConfig, mesh,
             lp = fetch_layer(lp, x_hist[-2] if len(x_hist) >= 2 else None,
                              li)
         x, layer_cache = _layer(x, lp, li, positions, cfg, mesh, attend,
-                                alibi, census_cb, use_kernel)
-        pools.append(layer_cache)
+                                alibi, census_cb, use_kernel, carry)
+        (states if cfg.layer_kind(li) == "conv" else pools).append(
+            layer_cache)
         x_hist.append(x)
 
     with jax.named_scope("lm_head"):
@@ -1081,6 +1230,8 @@ def _forward(params, tokens, positions, cfg: T.TransformerConfig, mesh,
         logits = _lm_logits(x, params, cfg)
         logits = _cons(logits, mesh, None, None)
     new = PagedCache(*(list(pool) for pool in zip(*pools)))
+    if states:
+        new = new._replace(state=states)
     return logits, new._replace(v=[]) if cfg.is_latent else new
 
 
@@ -1092,9 +1243,15 @@ def decode_step(
     params, cache: PagedCache, tokens, tables, ctx_lens, cfg: T.TransformerConfig,
     use_kernel: bool = True, mesh: Optional[Mesh] = None,
     unique_rows: bool = False, fetch_layer=None, census_cb=None,
+    slots=None,
 ):
     """tokens [S] int32, tables [S, NB] int32, ctx_lens [S] int32 (context
     length INCLUDING the new token) → (logits [S, V], new cache).
+
+    slots [S] int32: each row's sequence's state slot (-1: batch
+    padding), for a model with recurrent state (cache.state) and no
+    other; rows of one sequence (a prefill chunk) are adjacent and in
+    order (_carry_rows).
 
     ref: engine_v2.py put→model.forward decode path; one compiled program
     per (S, NB) shape. mesh: TP serving — params/cache arrive sharded
@@ -1127,8 +1284,10 @@ def decode_step(
     flat_idx = jnp.where(valid, flat_idx, jnp.int32(-1))
     # the write fuses into the attention call only on the single-device
     # kernel path (the shard_map TP path and the XLA oracle keep the
-    # separate write)
-    fuse_write = unique_rows and use_kernel and _tp_size(mesh) <= 1
+    # separate write), and only at widths whose rows' write semaphores
+    # the core has
+    fuse_write = (unique_rows and use_kernel and _tp_size(mesh) <= 1
+                  and fused_write_fits(tokens.shape[0]))
 
     def attend(q, k, v, li, alibi, lp):
         if cfg.is_latent:  # k: the rows the cache holds
@@ -1137,7 +1296,7 @@ def decode_step(
                                     use_kernel)
         where = (tables, ctx_lens, use_kernel, cfg.window_for_layer(li),
                  mesh, alibi)
-        pools = _layer_pools(cache, li)
+        pools = _layer_pools(cache, cfg.op_index(li))
         if fuse_write:
             att, *pools = _decode_attention(q, pools, *where, k_new=k,
                                             v_new=v, slots=flat_idx)
@@ -1145,8 +1304,13 @@ def decode_step(
         pools = _write_pools(pools, k, v, flat_idx, mesh, use_kernel)
         return _decode_attention(q, pools, *where), pools
 
+    def carry(u, li):
+        return _carry_rows(u, cache.state[cfg.op_index(li)], slots,
+                           positions)
+
     return _forward(params, tokens, positions, cfg, mesh, attend,
-                    fetch_layer, census_cb, use_kernel=use_kernel)
+                    fetch_layer, census_cb, use_kernel=use_kernel,
+                    carry=carry)
 
 
 def decode_multi(
@@ -1154,7 +1318,7 @@ def decode_multi(
     cfg: T.TransformerConfig, n_steps: int, use_kernel: bool = True,
     mesh: Optional[Mesh] = None, unique_rows: bool = True,
     sampling=None, keys=None, step0=None, presence=None,
-    fetch_layer=None, census_cb=None,
+    fetch_layer=None, census_cb=None, slots=None,
 ):
     """Fused decode: n_steps tokens per compiled program.
 
@@ -1190,7 +1354,7 @@ def decode_multi(
                                     use_kernel, mesh=mesh,
                                     unique_rows=unique_rows,
                                     fetch_layer=fetch_layer,
-                                    census_cb=census_cb)
+                                    census_cb=census_cb, slots=slots)
         if sampling is None:
             nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         else:
@@ -1234,10 +1398,12 @@ def prefill_batch(
     params, cache: PagedCache, tokens, n_real, tables,
     cfg: T.TransformerConfig, use_kernel: bool = True,
     mesh: Optional[Mesh] = None, fetch_layer=None, census_cb=None,
+    slots=None,
 ):
     """Cross-prompt batched prefill: tokens [B, Tp] int32 (padded),
     n_real [B] int32, tables [B, NB] int32 → (last-real-token logits
-    [B, V], new cache).
+    [B, V], new cache). slots [B] int32: each prompt's sequence's state
+    slot, for a model with recurrent state (decode_step).
 
     ONE compiled program runs B concurrent prompts — the ragged-batch
     idea of SplitFuse applied to prefill (ref: inference/v2/kernels/
@@ -1267,7 +1433,8 @@ def prefill_batch(
         # never reads the cache); only the RESIDENT copy quantizes —
         # later decode steps read these codes
         pools = _write_pools(
-            _layer_pools(cache, li), k.reshape(B * Tp, *k.shape[2:]),
+            _layer_pools(cache, cfg.op_index(li)),
+            k.reshape(B * Tp, *k.shape[2:]),
             v.reshape(B * Tp, *v.shape[2:]), flat_idx, mesh, use_kernel)
         flash = partial(causal_attention, window=cfg.window_for_layer(li))
         if _heads_shardable(mesh, cfg):
@@ -1300,6 +1467,10 @@ def prefill_batch(
             x, last[:, None, None].astype(jnp.int32).repeat(x.shape[-1], axis=2),
             axis=1)[:, 0]
 
+    def carry(u, li):
+        return _carry_prompts(u, cache.state[cfg.op_index(li)], slots,
+                              n_real)
+
     return _forward(params, tokens, positions, cfg, mesh, attend,
                     fetch_layer, census_cb, head_rows=last_real,
-                    use_kernel=use_kernel)
+                    use_kernel=use_kernel, carry=carry)

@@ -195,6 +195,10 @@ class SequenceDescriptor:
     uid: int
     blocks: List[int] = dataclasses.field(default_factory=list)
     seen_tokens: int = 0  # tokens whose KV lives in the cache
+    # this sequence's row of every state pool (model.PagedCache.state),
+    # held from tracking to flush. What the row holds is the model's
+    # business; a sequence at position 0 reads none of it.
+    slot: int = -1
     # prefix-cache bookkeeping: token ids for positions [0, len(tokens))
     # when known, and the chain key per registered/matched full block.
     # tokens_valid is off while tokens are committed that the host has
@@ -219,6 +223,9 @@ class PrefixMatch:
     reused_blocks: List[int]       # shared blocks (index hits)
     fresh_blocks: List[int]        # newly allocated blocks
     cow: Optional[Tuple[int, int]] = None  # (src, dst) page copy to issue
+    # prompt tokens the index held and the manager may not credit
+    # (StateManager.credit_prefix): the whole prompt is computed
+    declined: int = 0
 
 
 def _chain_key(parent: Optional[bytes], toks) -> bytes:
@@ -239,14 +246,20 @@ class StateManager:
 
     def __init__(self, num_blocks: int, block_size: int, max_tracked: int = 2048,
                  enable_prefix_cache: bool = False,
-                 cache_pool_blocks: int = -1):
+                 cache_pool_blocks: int = -1, credit_prefix: bool = True):
         self.block_size = block_size
+        # False: the index is kept and walked but no admission is
+        # credited with cached tokens (what a sequence carries beside
+        # its pages, its state slot, is not in the index)
+        self.credit_prefix = credit_prefix
         self.allocator = BlockedAllocator(
             num_blocks, evict_cb=self._on_evict,
             cache_pool_blocks=cache_pool_blocks if enable_prefix_cache else 0)
         self.max_tracked = max_tracked
         self.enable_prefix_cache = enable_prefix_cache
         self._seqs: Dict[int, SequenceDescriptor] = {}
+        # state slots not held by a tracked sequence, lowest first
+        self._free_slots: List[int] = list(range(max_tracked - 1, -1, -1))
         self._index: Dict[bytes, int] = {}      # chain key -> block id
         self._block_key: Dict[int, bytes] = {}  # block id -> chain key
         # chain key -> (parent key, this block's token ids): the token
@@ -276,7 +289,8 @@ class StateManager:
                 raise RuntimeError(
                     f"too many tracked sequences ({self.max_tracked})"
                 )
-            self._seqs[uid] = SequenceDescriptor(uid=uid)
+            self._seqs[uid] = SequenceDescriptor(
+                uid=uid, slot=self._free_slots.pop())
         return self._seqs[uid]
 
     @property
@@ -467,7 +481,7 @@ class StateManager:
                 self.allocator.free([b])
             seq.blocks = [b for b in seq.blocks if b not in acquired]
             if created:
-                del self._seqs[uid]
+                self._free_slots.append(self._seqs.pop(uid).slot)
             raise
         if token_ids is not None:
             return seq, match
@@ -492,6 +506,10 @@ class StateManager:
             self.stats["lookup_misses"] += 1
             self.stats["prompt_tokens"] += n
             return PrefixMatch(0, [], [])
+        if not self.credit_prefix:
+            self.stats["lookup_misses"] += 1
+            self.stats["prompt_tokens"] += n
+            return PrefixMatch(0, [], [], declined=n_cached)
         cow: Optional[Tuple[int, int]] = None
         # acquire every matched block (pins them against eviction)
         for _, block in chain:
@@ -578,6 +596,7 @@ class StateManager:
         seq = self._seqs.pop(uid, None)
         if seq is None:
             raise KeyError(f"unknown sequence uid {uid}")
+        self._free_slots.append(seq.slot)
         self.allocator.free(seq.blocks)
 
     # -- device views ----------------------------------------------------

@@ -71,7 +71,7 @@ from ..resilience.integrity import HandoffIntegrityError
 from ..utils import profiler
 from ..utils.logging import log_dist
 from ..utils.sync import serving_readback
-from .engine import InferenceEngine, _bucket
+from .engine import InferenceEngine, _bucket, refuse_for_pools
 from .pressure import BROWNOUT, RED, PressureGovernor, estimate_ttft
 from .ragged import KVCacheExhaustedError
 
@@ -205,6 +205,8 @@ class ServingScheduler:
         self._spec = dict(speculative) if speculative else None
         if self._spec and not self.scfg.greedy:
             raise ValueError("speculative decoding is greedy-only")
+        if self._spec:
+            refuse_for_pools(engine.cfg, "speculation")
         self.waiting: "deque[Request]" = deque()
         self.active: List[Request] = []   # admission order; PREFILL/RUNNING
         self.finished: Dict[int, Request] = {}
@@ -247,6 +249,16 @@ class ServingScheduler:
             # 0 unless the model caches a latent): what the latent walk
             # multiplies, whatever it reads once a table
             "mla_cache_tokens": 0,
+            # recurrent state (0 unless the model has state layers):
+            # slots held by tracked sequences, summed over dispatched
+            # steps; sequences that (re)started at position 0 in a slot,
+            # at admission or after a flush, whose first row resets it
+            # on the device (model._carry_rows reads nothing of a slot
+            # at position 0); admissions whose prompt the prefix index
+            # held and that were given no credit for it
+            "state_slots_live": 0,
+            "state_slot_resets": 0,
+            "state_prefix_credits_refused": 0,
         }
         self._phases = profiler.Phases("sched", "iteration", PHASES,
                                        sums=self.counters)
@@ -301,6 +313,7 @@ class ServingScheduler:
             self.governor = PressureGovernor(pcfg, engine,
                                              budget_bytes=budget)
             if pcfg.spill_enabled:
+                refuse_for_pools(engine.cfg, "page_transfer")
                 from .offload_store import HostKvSpillStore
 
                 self.spill_store = HostKvSpillStore(
@@ -385,6 +398,8 @@ class ServingScheduler:
         prompt = [int(t) for t in prompt]
         if not prompt:
             raise ValueError("empty prompt")
+        if handoff:
+            refuse_for_pools(self.engine.cfg, "page_transfer")
         if len(prompt) > self.engine.config.max_seq_len:
             raise ValueError(
                 f"prompt of {len(prompt)} > max_seq_len "
@@ -836,6 +851,10 @@ class ServingScheduler:
             req.uid = uid
             req.fed = eng.state.get(uid).seen_tokens  # = match.n_cached
             req.n_cached += match.n_cached
+            if eng.cache.state:
+                self.counters["state_slot_resets"] += 1
+                self.counters["state_prefix_credits_refused"] += (
+                    match.declined > 0)
             req.state = PREFILL
             self.active.append(req)
             self._stamp_admission(req)
@@ -903,18 +922,20 @@ class ServingScheduler:
             toks_b = np.zeros((bp, tp), np.int32)
             n_real = np.zeros((bp,), np.int32)
             tables = np.zeros((bp, eng.config.blocks_per_seq), np.int32)
+            slots = np.full((bp,), -1, np.int32)
             for row, r in enumerate(wave):
                 base = r.base
                 toks_b[row, :len(base)] = base
                 n_real[row] = len(base)
                 tables[row] = eng.state.block_table(
                     [r.uid], eng.config.blocks_per_seq)[0]
+                slots[row] = eng.state.get(r.uid).slot
             eng.recompile_tracker.record(
                 f"serving_prefill[b{bp},t{tp}]", (toks_b, n_real, tables))
             ph.mark("launch", kind="wave", rows=int(n_real.sum()))
             logits, eng.cache = eng._prefill_batch_fn(bp, tp)(
                 eng.params, eng.cache, eng._dev(toks_b),
-                eng._dev(n_real), eng._dev(tables))
+                eng._dev(n_real), eng._dev(tables), *eng.state_args(slots))
             ph.mark("commit")
             sample_rows = []
             for row, r in enumerate(wave):
@@ -951,6 +972,8 @@ class ServingScheduler:
             self.counters["kv_live_blocks"] += int(np.sum(-(-live // bs)))
             if cfg.is_latent:
                 self.counters["mla_cache_tokens"] += int(np.sum(live))
+        if cfg.n_state_layers:
+            self.counters["state_slots_live"] += self.engine.state.n_tracked
 
     def _dispatch_mixed(self, rows, ahead_of: Optional[_Step] = None,
                         src: Optional[Dict[int, int]] = None
@@ -976,6 +999,7 @@ class ServingScheduler:
         ctx = np.zeros((sp,), np.int32)  # pad rows: ctx 0 = inert
         tables = np.full((sp, eng.config.blocks_per_seq),
                          eng.pad_block, np.int32)
+        slots = np.full((sp,), -1, np.int32)  # each row's state slot
         sample_rows: List[Tuple[Request, int]] = []
         row = 0
         for req, chunk, sample in rows:
@@ -983,6 +1007,7 @@ class ServingScheduler:
             base_seen = seq.seen_tokens
             table = eng.state.block_table(
                 [req.uid], eng.config.blocks_per_seq, eng.pad_block)[0]
+            slots[row:row + len(chunk)] = seq.slot
             for j, tok in enumerate(chunk):
                 if tok is None:
                     srcs[row] = src[req.rid]
@@ -1009,7 +1034,7 @@ class ServingScheduler:
                                              eng._dev(srcs))
         logits, eng.cache = eng._decode_fn(sp, unique)(
             eng.params, eng.cache, toks_dev, eng._dev(tables),
-            eng._dev(ctx))
+            eng._dev(ctx), *eng.state_args(slots))
         # host bookkeeping overlaps the in-flight device program
         ph.mark("commit")
         for req, chunk, sample in rows:
@@ -1052,9 +1077,11 @@ class ServingScheduler:
         pres_rows = (np.zeros((width, V), np.uint8)
                      if scfg.needs_presence and use_sampler else None)
         sample_rows = []
+        slots = np.full((width,), -1, np.int32)
         for r, req in enumerate(running):
             seq = eng.state.get(req.uid)
             base = seq.seen_tokens
+            slots[r] = seq.slot
             eng.state.extend(req.uid, C)  # capacity pre-checked by caller
             toks[r] = req.pending
             ctx[r] = base + 1
@@ -1079,6 +1106,7 @@ class ServingScheduler:
             args.append(eng._dev(steps))
             if pres_rows is not None:
                 args.append(eng._dev(pres_rows))
+        args += eng.state_args(slots)
         gen, _, eng.cache, _ = fn(*args)
         ph.mark("commit")
         for req in running:

@@ -207,8 +207,44 @@ class TransformerConfig:
     # n_experts outputs; the expert stacks hold `count`; pairs routed
     # elsewhere add nothing here (their chips add it). None: all held.
     experts_held: Optional[Tuple[int, int]] = None
+    # ---- layers of two kinds (LFM2 class hybrids). SERVING ONLY.
+    # layer_types names the operator of each of the model's `depth`
+    # layers, leading dense ones included: "attention", or "conv", the
+    # gated short convolution: [B; C; X] = W_in h, u = B * X, a causal
+    # depthwise convolution of conv_kernel taps over u, out =
+    # W_out (C * conv). A sequence carries the last conv_kernel - 1
+    # values of u from token to token in a conv layer, whatever its
+    # length, and K/V in the attention layers alone. The operators'
+    # weights are top-level stacks by kind, `conv_<name>` [n conv
+    # layers, ...] and `attn_<name>` [n attention layers, ...]; `layers`
+    # (and `dense_<name>`) keep what every layer has, its norms and FFN.
+    layer_types: Optional[Tuple[str, ...]] = None
+    conv_kernel: int = 0
+    # QK-norm a HEAD at a time: the RMS statistic over each head's
+    # head_dim values, ONE learned scale of head_dim shared by all the
+    # heads of q (another for k). qk_norm must be set too.
+    qk_norm_per_head: bool = False
+    # a learned per-expert bias added to the router's scores for the
+    # CHOICE of the top-k alone (the weights stay the unbiased scores):
+    # leaf `expert_bias` [n_experts] in every routed layer
+    moe_expert_bias: bool = False
 
     def __post_init__(self):
+        if self.layer_types is not None:
+            object.__setattr__(self, "layer_types", tuple(self.layer_types))
+            if len(self.layer_types) != self.depth or not set(
+                    self.layer_types) <= {"attention", "conv"}:
+                raise ValueError(
+                    f"layer_types names 'attention' or 'conv' for each of "
+                    f"the {self.depth} layers (got {self.layer_types})")
+            if "conv" in self.layer_types and self.conv_kernel < 2:
+                raise ValueError("conv layers need conv_kernel >= 2")
+            if self.kv_lora_rank > 0:
+                raise NotImplementedError(
+                    "layers of two kinds with latent attention: a latent "
+                    "pool beside state pools is not served")
+        if self.qk_norm_per_head and not self.qk_norm:
+            raise ValueError("qk_norm_per_head is a form of qk_norm: set both")
         if self.moe_scoring not in ("softmax", "sigmoid"):
             raise ValueError(
                 f"unknown moe_scoring {self.moe_scoring!r} (softmax|sigmoid)")
@@ -377,8 +413,38 @@ class TransformerConfig:
         the training forward refuses a configuration that has any."""
         return tuple(k for k in ("kv_lora_rank", "sandwich_norm",
                                  "n_shared_experts", "n_dense_layers",
-                                 "experts_held") if getattr(self, k)) + (
+                                 "experts_held", "layer_types",
+                                 "moe_expert_bias") if getattr(self, k)) + (
             ("moe_scoring",) if self.moe_scoring != "softmax" else ())
+
+    def layer_kind(self, li: int) -> str:
+        """The operator of layer li of the `depth`: 'attention' | 'conv'."""
+        return "attention" if self.layer_types is None else self.layer_types[li]
+
+    def op_index(self, li: int) -> int:
+        """Layer li's place among the layers of its own kind: which
+        entry of its kind's weight stacks, and which K/V pool or state
+        pool of the cache, is its."""
+        if self.layer_types is None:
+            return li
+        return self.layer_types[:li].count(self.layer_types[li])
+
+    @property
+    def n_kv_layers(self) -> int:
+        """Layers that hold K/V (or a latent row) in the paged cache."""
+        return (self.depth if self.layer_types is None
+                else self.layer_types.count("attention"))
+
+    @property
+    def n_state_layers(self) -> int:
+        """Layers that hold fixed-size per-sequence state in a slot."""
+        return self.depth - self.n_kv_layers
+
+    @property
+    def state_width(self) -> int:
+        """Values one sequence carries in one state layer: the last
+        conv_kernel - 1 inputs of the convolution, oldest first."""
+        return (self.conv_kernel - 1) * self.d_model
 
     @property
     def depth(self) -> int:
@@ -458,25 +524,16 @@ def _layer_shapes(cfg: TransformerConfig, dense: bool = False
     MLP of width cfg.dense_d_ff."""
     E, H, KV, D, F = cfg.d_model, cfg.n_heads, cfg.kv_heads, cfg.head_dim, cfg.ff_dim
     shapes = {"ln1_scale": ((E,), ("embed",))}
-    if cfg.is_latent:
-        shapes.update(_latent_attention_shapes(cfg))
-    else:
-        shapes.update({
-            "wq": ((E, H, D), ("embed", "heads", "head_dim")),
-            "wk": ((E, KV, D), ("embed", "heads", "head_dim")),
-            "wv": ((E, KV, D), ("embed", "heads", "head_dim")),
-            "wo": ((H, D, E), ("heads", "head_dim", "embed")),
-        })
+    if cfg.layer_types is None:
+        # every layer's operator is attention: its leaves are the
+        # layer's own (a model of two kinds keeps them in stacks by
+        # kind beside `layers`, _operator_shapes)
+        shapes.update(_operator_shapes(cfg, "attention"))
     if cfg.sandwich_norm:
         shapes["ln1_post_scale"] = ((E,), ("embed",))
         shapes["ln2_post_scale"] = ((E,), ("embed",))
     if not cfg.shared_ln:
         shapes["ln2_scale"] = ((E,), ("embed",))
-    if cfg.qk_norm:
-        # one scale per projected value, kept [heads, head_dim] so the
-        # head sharding of wq / wk applies to it as it stands
-        shapes["q_norm_scale"] = ((H, D), ("heads", "head_dim"))
-        shapes["k_norm_scale"] = ((KV, D), ("heads", "head_dim"))
     X = 0 if dense else cfg.n_experts
     if dense:
         F = cfg.dense_d_ff
@@ -490,6 +547,8 @@ def _layer_shapes(cfg: TransformerConfig, dense: bool = False
         Xh = cfg.n_experts_held
         shapes.update({
             "w_router": ((E, X), ("embed", None)),
+            **({"expert_bias": ((X,), (None,))}
+               if cfg.moe_expert_bias else {}),
             "w_in": ((Xh, E, F), ("expert", "embed", "expert_mlp")),
             "w_out": ((Xh, F, E), ("expert", "expert_mlp", "embed")),
         })
@@ -533,6 +592,38 @@ def _layer_shapes(cfg: TransformerConfig, dense: bool = False
                           (("expert", "expert_mlp") if X > 0 else ("mlp",)))
         shapes["b_out"] = (((X, E) if X > 0 else (E,)),
                            (("expert", "embed") if X > 0 else ("embed",)))
+    return shapes
+
+
+def _operator_shapes(cfg: TransformerConfig, kind: str):
+    """The leaves of one layer's OPERATOR, by its kind: attention
+    (plain or latent, with its QK-norm scales and biases) or the gated
+    short convolution (`conv_in` to [B; C; X], the depthwise `conv_taps`
+    [channel, tap], oldest tap first, and `conv_out`). Same form as
+    _layer_shapes."""
+    E, H, KV, D = cfg.d_model, cfg.n_heads, cfg.kv_heads, cfg.head_dim
+    if kind == "conv":
+        return {
+            "conv_in": ((E, 3 * E), ("embed", "mlp")),
+            "conv_taps": ((E, cfg.conv_kernel), ("embed", None)),
+            "conv_out": ((E, E), ("mlp", "embed")),
+        }
+    if cfg.is_latent:
+        return _latent_attention_shapes(cfg)
+    shapes = {
+        "wq": ((E, H, D), ("embed", "heads", "head_dim")),
+        "wk": ((E, KV, D), ("embed", "heads", "head_dim")),
+        "wv": ((E, KV, D), ("embed", "heads", "head_dim")),
+        "wo": ((H, D, E), ("heads", "head_dim", "embed")),
+    }
+    if cfg.qk_norm_per_head:
+        shapes["q_norm_scale"] = ((D,), ("head_dim",))
+        shapes["k_norm_scale"] = ((D,), ("head_dim",))
+    elif cfg.qk_norm:
+        # one scale per projected value, kept [heads, head_dim] so the
+        # head sharding of wq / wk applies to it as it stands
+        shapes["q_norm_scale"] = ((H, D), ("heads", "head_dim"))
+        shapes["k_norm_scale"] = ((KV, D), ("heads", "head_dim"))
     if cfg.has_qkv_bias:
         shapes["bq"] = ((H, D), ("heads", "head_dim"))
         shapes["bk"] = ((KV, D), ("heads", "head_dim"))
@@ -544,6 +635,29 @@ def _layer_shapes(cfg: TransformerConfig, dense: bool = False
 
 # top-level leaves of the leading dense layers (cfg.n_dense_layers)
 DENSE_PREFIX = "dense_"
+# top-level stacks of the operators' leaves, by kind, of a model whose
+# layers are of two kinds (cfg.layer_types)
+OPERATOR_PREFIX = {"attention": "attn_", "conv": "conv_"}
+
+
+def operator_stacks(cfg: TransformerConfig):
+    """[(kind, prefix, layers of that kind)] of a model of two kinds;
+    empty where every layer's operator is its own."""
+    if cfg.layer_types is None:
+        return []
+    return [(kind, prefix, cfg.layer_types.count(kind))
+            for kind, prefix in OPERATOR_PREFIX.items()
+            if kind in cfg.layer_types]
+
+
+def _operator_leaves(cfg: TransformerConfig):
+    """(top-level name, kind, leaf name, shape, logical axes) of every
+    operator stack's leaves. The conv leaves carry their prefix already
+    (`conv_in` / `conv_out`, clear of the FFN's `w_in` / `w_out`)."""
+    for kind, prefix, _ in operator_stacks(cfg):
+        for name, (shape, logical) in _operator_shapes(cfg, kind).items():
+            top = name if name.startswith(prefix) else prefix + name
+            yield top, kind, name, shape, logical
 
 
 def init(cfg: TransformerConfig, rng) -> Dict[str, Any]:
@@ -571,8 +685,7 @@ def init(cfg: TransformerConfig, rng) -> Dict[str, Any]:
         if cfg.lm_head_bias:
             params["lm_head_b"] = jnp.zeros((V,), jnp.float32)
 
-    def stack(key, depth: int, dense: bool):
-        shapes = _layer_shapes(cfg, dense)
+    def stack(key, depth: int, shapes):
         out = {}
         lkeys = jax.random.split(key, len(shapes))
         for i, (name, (shape, _)) in enumerate(sorted(shapes.items())):
@@ -587,12 +700,20 @@ def init(cfg: TransformerConfig, rng) -> Dict[str, Any]:
                 out[name] = jax.random.normal(lkeys[i], full, jnp.float32) * scale
         return out
 
-    params["layers"] = stack(keys[3], L, dense=False)
+    params["layers"] = stack(keys[3], L, _layer_shapes(cfg))
     # leading dense layers: top-level `dense_<name>` [n_dense, ...], so
     # `layers` stays one homogeneous stack
-    for name, w in stack(keys[4], cfg.n_dense_layers, dense=True).items() \
+    for name, w in stack(keys[4], cfg.n_dense_layers,
+                         _layer_shapes(cfg, dense=True)).items() \
             if cfg.n_dense_layers else ():
         params[DENSE_PREFIX + name] = w
+    # operators of two kinds: a top-level stack a kind, so that no leaf
+    # of `layers` is one only some layers have. The prefixed names keep
+    # `conv_in` / `conv_out` clear of the FFN's `w_in` / `w_out`
+    for i, (kind, _, n) in enumerate(operator_stacks(cfg)):
+        made = stack(keys[5 + i], n, _operator_shapes(cfg, kind))
+        params.update({top: made[name] for top, k, name, _, _
+                       in _operator_leaves(cfg) if k == kind})
     if cfg.pipeline_stages > 1:
         from ..runtime.pipe import partition_layers
 
@@ -632,6 +753,8 @@ def logical_specs(cfg: TransformerConfig) -> Dict[str, Any]:
     if cfg.n_dense_layers:
         for name, (_, logical) in _layer_shapes(cfg, dense=True).items():
             specs[DENSE_PREFIX + name] = ("layers",) + logical
+    for top, _, _, _, logical in _operator_leaves(cfg):
+        specs[top] = ("layers",) + logical
     return specs
 
 
@@ -665,15 +788,20 @@ def qk_norm(q, k, lp, cfg: TransformerConfig):
     its own heads only: refused."""
     if not cfg.qk_norm:
         return q, k
-    if "model" in jax.sharding.get_abstract_mesh().manual_axes:
+    if not cfg.qk_norm_per_head and \
+            "model" in jax.sharding.get_abstract_mesh().manual_axes:
         raise NotImplementedError(
             "qk_norm inside a shard_map region over 'model': the RMS "
             "statistic spans all heads of a token, a shard holds only "
             "its own; apply it before entering the manual region")
 
+    # a head at a time (cfg.qk_norm_per_head: one [head_dim] scale for
+    # all heads), or over the whole projection
+    axes = -1 if cfg.qk_norm_per_head else (-2, -1)
+
     def norm(x, scale):
         x32 = x.astype(jnp.float32)
-        ms = jnp.mean(jnp.square(x32), axis=(-2, -1), keepdims=True)
+        ms = jnp.mean(jnp.square(x32), axis=axes, keepdims=True)
         return (x32 * jax.lax.rsqrt(ms + cfg.norm_eps)
                 * scale.astype(jnp.float32)).astype(x.dtype)
 

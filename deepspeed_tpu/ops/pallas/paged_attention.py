@@ -24,15 +24,19 @@ the arguments, dtype and shape it is handed, nothing else):
   arenas left in HBM, a fori_loop over the row's live blocks with
   manual DMA a few blocks ahead. Dead table slots cost nothing. Takes
   the fused single-token write+attend (paged_decode_fused) and the
-  unfused, unquantised attention the shared-table program runs;
+  unfused, unquantised attention the shared-table program runs, at
+  head dims that are multiples of 128 and, through PACKED pools
+  (kv_pack: two KV heads of 64 in one 128-lane row), at head dim 64;
 - the (seqs, table_slots) BlockSpec grid (_decode_kernel): the block
   table is a scalar-prefetch argument and index maps do the paging; a
   slot beyond the context clamps to the last needed block, so a pruned
   step revisits a resident tile (no DMA, no compute, but a grid step:
   ~0.34 us each on a v5e). Keeps what the walk cannot take: int8 KV
   (scale tiles ride the index maps), the fused write at head dims that
-  are not a multiple of 128, and block shapes Mosaic refuses as a
-  manual DMA (_walks_live_blocks).
+  are neither a multiple of 128 nor packed, and block shapes Mosaic
+  refuses as a manual DMA (_walks_live_blocks: a minor dim that is not
+  whole lanes, so head dims other than 64 below 128, or 64 with an odd
+  KV count, or under a mesh or int8, where pools are not packed).
 
 int8 per-block KV quantization (docs/paged_attention.md): pools may
 hold int8 codes with a per-block [block_size, KV] f32 scale tile
@@ -308,6 +312,62 @@ def _decode_kernel(
         )
 
 
+def kv_pack(kv_heads: int, head_dim: int) -> int:
+    """KV heads that share one 128-lane row of a PACKED pool: 2 at head
+    dim 64 with an even count of KV heads, else 1 (not packed).
+
+    The TPU's tiled HBM layout pads a 64-wide minor dim to 128 lanes,
+    so a pool [NB, bs, KV, 64] would take, and stream, twice its bytes,
+    and Mosaic refuses a manual DMA of such a block (_walks_live_blocks).
+    A packed pool is [NB, bs, KV / 2, 128]: the same row-major bytes,
+    heads 2p and 2p + 1 side by side in row p. Every kernel here then
+    runs UNCHANGED at (KV / 2, 128): the queries of a pair's two heads
+    become one group whose rows are zero outside their own head's 64
+    lanes (block-diagonal, _pack_queries), so one 128-deep score matmul
+    gives both heads' scores, and of the 128 output lanes each row
+    keeps its own head's 64 (_unpack_out). The MXU multiplies twice the
+    needed values; the walk is bound by its DMAs and its per-(head,
+    block) loop, which halves. Who allocates a pool asks this (and
+    packs only unquantised pools on one device: scale tiles and head
+    sharding are per KV head); everything below reads the packing off
+    the shapes it is handed."""
+    return 2 if head_dim == 64 and kv_heads % 2 == 0 else 1
+
+
+def _packing(q, k_cache) -> int:
+    """Heads a pool row holds, from the shapes: pool lanes over the
+    queries' head dim (1: not packed)."""
+    return k_cache.shape[3] // q.shape[2]
+
+
+def _pack_queries(q, pack: int, G: int):
+    """[S, H, D] -> [S, H, pack * D], head h's values in the lanes of
+    its place (h // G) % pack within its pair's pool row, zeros in the
+    other's (G = queries a KV head)."""
+    S, H, D = q.shape
+    eye = jnp.eye(pack, dtype=q.dtype)
+    return jnp.einsum(
+        "skigd,ij->skigjd", q.reshape(S, H // (pack * G), pack, G, D), eye
+    ).reshape(S, H, pack * D)
+
+
+def _unpack_out(out, pack: int, G: int):
+    """[S, H, pack * D] -> [S, H, D]: each row's own head's lanes."""
+    S, H, PD = out.shape
+    D = PD // pack
+    eye = jnp.eye(pack, dtype=out.dtype)
+    return jnp.einsum(
+        "skigjd,ij->skigd",
+        out.reshape(S, H // (pack * G), pack, G, pack, D), eye
+    ).reshape(S, H, D)
+
+
+def _pack_rows(new, pool):
+    """New rows [T, KV, D] in the shape of the pool's rows
+    ([T, KV / pack, pack * D] of a packed pool: a plain reshape)."""
+    return None if new is None else new.reshape(new.shape[0], *pool.shape[2:])
+
+
 def _group_queries(q, n_kv: int, alibi_slopes=None):
     """[S, H, D] queries -> [S, KV, Gp, D] with each KV head's group
     sublane-padded to Gp = max(G, 8) rows, and the ALiBi slopes (or
@@ -329,7 +389,8 @@ def _group_queries(q, n_kv: int, alibi_slopes=None):
 def paged_decode_attention(q, k_cache, v_cache, block_table, ctx_lens,
                            window: int = 0,
                            k_new=None, v_new=None, slots=None,
-                           alibi_slopes=None, k_scale=None, v_scale=None):
+                           alibi_slopes=None, k_scale=None, v_scale=None,
+                           scale=None):
     """One-token-per-sequence attention over the paged KV cache: THE
     entry for "attend these rows over this paged cache". Which kernel
     runs is read from the arguments here and nowhere else, never from a
@@ -380,18 +441,33 @@ def paged_decode_attention(q, k_cache, v_cache, block_table, ctx_lens,
       at a reserved scratch block, since the grid writes each row's
       target block back even when nothing changed. The write slot must
       be ctx-1's flat slot.
+    A PACKED pool (kv_pack: [num_blocks, block_size, KV / 2, 128] for
+    head dim 64, told from the shapes) is attended at (KV / 2, 128)
+    through this same entry, queries block-diagonal, k_new/v_new
+    reshaped, `scale` (default 1/sqrt(D)) kept the true head dim's.
     returns: [S, H, D] (fused: (out, k_cache, v_cache))
     """
     S, H, D = q.shape
     KV = k_cache.shape[2]
     fused = k_new is not None
     quant = k_scale is not None
+    scale = 1.0 / (D**0.5) if scale is None else scale
+    pack = _packing(q, k_cache)
+    if pack > 1:
+        G = H // (KV * pack)
+        out = paged_decode_attention(
+            _pack_queries(q, pack, G), k_cache, v_cache, block_table,
+            ctx_lens, window, _pack_rows(k_new, k_cache),
+            _pack_rows(v_new, v_cache), slots, alibi_slopes, k_scale,
+            v_scale, scale)
+        if fused:
+            return (_unpack_out(out[0], pack, G), *out[1:])
+        return _unpack_out(out, pack, G)
     if fused and not quant and supports_fused_v2(D):
         return paged_decode_fused(q, k_cache, v_cache, block_table, ctx_lens,
                                   k_new, v_new, slots, window=window,
-                                  alibi_slopes=alibi_slopes)
+                                  alibi_slopes=alibi_slopes, scale=scale)
     qg, ab, G, Gp = _group_queries(q, KV, alibi_slopes)
-    scale = 1.0 / (D**0.5)
     if not fused and not quant and _walks_live_blocks(qg, k_cache):
         out = _attend_live_blocks(qg, ab, k_cache, v_cache, block_table,
                                   ctx_lens, window, scale)
@@ -529,6 +605,7 @@ def paged_decode_attention_xla(q, k_cache, v_cache, block_table, ctx_lens,
     the compute dtype exactly as the kernel's fused dequant does."""
     S, H, D = q.shape
     _, bs, KV, _ = k_cache.shape
+    KV *= _packing(q, k_cache)  # a packed pool: a row-major view of KV x D
     G = H // KV
     k = k_cache[block_table].reshape(S, -1, KV, D)  # [S, NB*bs, KV, D]
     v = v_cache[block_table].reshape(S, -1, KV, D)
@@ -872,9 +949,24 @@ def supports_fused_v2(head_dim: int) -> bool:
     return head_dim % 128 == 0
 
 
+# DMA semaphores a core has (2,048 bytes of them, 4 each: AOT for v5e,
+# libtpu 0.0.34, "Allocation (size=4096) would exceed memory (size=2048)
+# ... space=sflag" at 512 rows)
+_DMA_SEMAPHORES = 512
+
+
+def fused_write_fits(n_rows: int) -> bool:
+    """Whether a fused write+attend call can take this many rows: the
+    walk with the write (paged_decode_fused) holds two DMA semaphores a
+    row until its last grid step, beside its block ring's. A wider step
+    writes first and attends after (paged_kv_write, then the walk), as
+    the shared-table program does; who fuses asks here first."""
+    return 2 * n_rows + 2 * _RING * 2 <= _DMA_SEMAPHORES
+
+
 def paged_decode_fused(q, k_cache, v_cache, block_table, ctx_lens,
                        k_new, v_new, slots, window: int = 0,
-                       alibi_slopes=None):
+                       alibi_slopes=None, scale=None):
     """Fused single-token decode: write the batch's new KV rows into the
     paged arenas AND attend over them, one kernel launch (what
     paged_decode_attention runs for k_new on unquantised pools at
@@ -891,7 +983,7 @@ def paged_decode_fused(q, k_cache, v_cache, block_table, ctx_lens,
     lane-aligned (supports_fused_v2)."""
     S, H, D = q.shape
     bs, KV = k_cache.shape[1:3]
-    scale = 1.0 / (D**0.5)
+    scale = 1.0 / (D**0.5) if scale is None else scale
     alibi = alibi_slopes is not None
     qg, ab, G, Gp = _group_queries(q, KV, alibi_slopes)
     ab = (ab,) if alibi else ()
@@ -987,9 +1079,13 @@ def paged_kv_write(cache_k, cache_v, k_new, v_new, flat_slots):
     """Write [T, KV, D] new KV rows into [NBLK, bs, KV, D] caches at flat
     slot ids [T] (block*bs + offset; -1 rows are dropped). The TPU-native
     fused-cache-store (ref: inference/v2/kernels/ragged_ops/
-    linear_blocked_kv_rotary/ — rotary is applied upstream in XLA)."""
+    linear_blocked_kv_rotary/ — rotary is applied upstream in XLA).
+    Rows of a packed pool (kv_pack) may come as [T, KV, D] of the model's
+    heads: the same bytes as the pool's [T, KV / 2, 128]."""
     NBLK, bs, KV, D = cache_k.shape
     T = flat_slots.shape[0]
+    # a packed pool (kv_pack) takes its rows in its own shape
+    k_new, v_new = _pack_rows(k_new, cache_k), _pack_rows(v_new, cache_v)
     order = jnp.argsort(flat_slots)
     slots = flat_slots[order].astype(jnp.int32)
     kn = k_new[order]

@@ -144,20 +144,41 @@ _LLAMA_FAMILY = {"LlamaForCausalLM", "MistralForCausalLM",
                  "OlmoeForCausalLM"}
 # a config.json without `architectures` is told by its model_type
 _ARCH_OF_MODEL_TYPE = {"olmoe": "OlmoeForCausalLM",
-                       "pangu_ultra_moe": "PanguUltraMoEForCausalLM"}
+                       "pangu_ultra_moe": "PanguUltraMoEForCausalLM",
+                       "lfm2_moe": "Lfm2MoeForCausalLM"}
 # config.json keys that change what a BLOCK computes (latent attention,
-# shared experts, leading dense layers, a second norm, a scaled or
-# grouped router): an architecture whose mapping below does not read
-# one of them would be served as a plain block under a real model's name
-_BLOCK_KEYS = ("kv_lora_rank", "q_lora_rank", "qk_nope_head_dim",
-               "qk_rope_head_dim", "v_head_dim", "n_shared_experts",
-               "first_k_dense_replace", "sandwich_norm",
-               "routed_scaling_factor", "moe_intermediate_size",
-               "n_routed_experts", "n_group", "topk_group",
-               "num_nextn_predict_layers")
-_READS_BLOCK_KEYS = {"PanguUltraMoEForCausalLM"}
+# shared experts, leading dense layers, a second norm, a scaled, grouped
+# or biased router, layers of another kind than attention): an
+# architecture whose mapping below does not read one of them would be
+# served as a plain block under a real model's name
+_LATENT_MOE_KEYS = ("kv_lora_rank", "q_lora_rank", "qk_nope_head_dim",
+                    "qk_rope_head_dim", "v_head_dim", "n_shared_experts",
+                    "first_k_dense_replace", "sandwich_norm",
+                    "routed_scaling_factor", "moe_intermediate_size",
+                    "n_routed_experts", "n_group", "topk_group",
+                    "num_nextn_predict_layers")
+_HYBRID_KEYS = ("layer_types", "conv_L_cache", "conv_bias",
+                "num_dense_layers", "use_expert_bias")
+_BLOCK_KEYS = _LATENT_MOE_KEYS + _HYBRID_KEYS
+# the block keys each architecture's mapping reads; any other stays an
+# error for it too
+_READS_BLOCK_KEYS = {
+    "PanguUltraMoEForCausalLM": frozenset(_LATENT_MOE_KEYS),
+    "Lfm2MoeForCausalLM": frozenset(_HYBRID_KEYS + (
+        "moe_intermediate_size", "routed_scaling_factor")),
+}
+
+
+def _block_key_set(hf: Dict[str, Any], key: str) -> bool:
+    """Whether `key` asks for something of a block. `layer_types` that
+    names full attention for every layer asks for nothing."""
+    if key == "layer_types":
+        return any(t != "full_attention" for t in hf.get(key) or ())
+    return bool(hf.get(key))
+
+
 SUPPORTED_ARCHITECTURES = sorted(_LLAMA_FAMILY | {
-    "PanguUltraMoEForCausalLM",
+    "PanguUltraMoEForCausalLM", "Lfm2MoeForCausalLM",
     "GPT2LMHeadModel", "OPTForCausalLM", "FalconForCausalLM",
     "RWForCausalLM",  # falcon's pre-rename arch string
     "PhiForCausalLM", "QWenLMHeadModel",
@@ -176,8 +197,9 @@ def config_from_hf(hf: Dict[str, Any], **overrides) -> TransformerConfig:
     """HF config.json dict → TransformerConfig. overrides win (e.g.
     use_flash=False for CPU tests, attention_impl for long-context)."""
     arch = _arch_of(hf)
-    for key in _BLOCK_KEYS if arch not in _READS_BLOCK_KEYS else ():
-        if hf.get(key):
+    for key in _BLOCK_KEYS:
+        if key not in _READS_BLOCK_KEYS.get(arch, ()) \
+                and _block_key_set(hf, key):
             raise ValueError(
                 f"{arch} with {key}={hf[key]!r}: this architecture's mapping "
                 f"does not read {key!r}, and a block key that is not read "
@@ -185,6 +207,8 @@ def config_from_hf(hf: Dict[str, Any], **overrides) -> TransformerConfig:
                 "silently-wrong import")
     if arch == "PanguUltraMoEForCausalLM":
         kw = _pangu_ultra_moe_config(hf)
+    elif arch == "Lfm2MoeForCausalLM":
+        kw = _lfm2_moe_config(hf)
     elif arch in _LLAMA_FAMILY:
         kw = dict(
             vocab_size=hf["vocab_size"],
@@ -523,6 +547,55 @@ def _pangu_ultra_moe_config(hf: Dict[str, Any]) -> Dict[str, Any]:
         n_shared_experts=int(hf.get("n_shared_experts", 0)),
         moe_scoring="sigmoid",
         moe_norm_topk_prob=bool(hf.get("norm_topk_prob", True)),
+        routed_scaling_factor=float(hf.get("routed_scaling_factor", 1.0)),
+        moe_dropless=True,
+    )
+
+
+def _lfm2_moe_config(hf: Dict[str, Any]) -> Dict[str, Any]:
+    """LFM2-MoE (`lfm2_moe`): `layer_types` names each layer's operator,
+    `conv` (the gated short convolution of `conv_L_cache` taps, no
+    bias) or `full_attention` (GQA with a per-head QK-norm and rope);
+    `num_dense_layers` leading layers with a dense SwiGLU of
+    `intermediate_size`, then routed layers of `num_experts` experts of
+    `moe_intermediate_size`: sigmoid scores, top-k chosen by score +
+    `expert_bias`, weights the unbiased scores over their sum, times
+    `routed_scaling_factor`. The head is tied to the embedding unless
+    the file says otherwise (the family's published parameter count is
+    of one vocabulary matrix)."""
+    if hf.get("conv_bias"):
+        raise ValueError("lfm2_moe with conv_bias is unsupported")
+    kinds = {"conv": "conv", "full_attention": "attention"}
+    types = hf["layer_types"]
+    unknown = sorted(set(types) - set(kinds))
+    if unknown or len(types) != hf["num_hidden_layers"]:
+        raise ValueError(
+            f"lfm2_moe layer_types names {sorted(kinds)} for each of "
+            f"num_hidden_layers={hf['num_hidden_layers']} layers (got "
+            f"{len(types)} entries, unknown {unknown})")
+    n_dense = int(hf.get("num_dense_layers", 0))
+    rope = hf.get("rope_parameters") or {}
+    return dict(
+        vocab_size=hf["vocab_size"],
+        n_layers=hf["num_hidden_layers"] - n_dense,
+        n_dense_layers=n_dense,
+        dense_d_ff=hf["intermediate_size"],
+        n_heads=hf["num_attention_heads"],
+        n_kv_heads=hf.get("num_key_value_heads") or None,
+        d_model=hf["hidden_size"],
+        d_ff=hf["moe_intermediate_size"],
+        max_seq=hf.get("max_position_embeddings", 4096),
+        variant="llama",
+        rope_theta=float(hf.get("rope_theta", rope.get("rope_theta", 1e6))),
+        norm_eps=float(hf.get("norm_eps", 1e-5)),
+        tie_embeddings=bool(hf.get("tie_word_embeddings", True)),
+        layer_types=tuple(kinds[t] for t in types),
+        conv_kernel=int(hf["conv_L_cache"]),
+        qk_norm=True, qk_norm_per_head=True,
+        n_experts=hf["num_experts"], moe_top_k=hf["num_experts_per_tok"],
+        moe_scoring="sigmoid",
+        moe_norm_topk_prob=bool(hf.get("norm_topk_prob", True)),
+        moe_expert_bias=bool(hf.get("use_expert_bias", False)),
         routed_scaling_factor=float(hf.get("routed_scaling_factor", 1.0)),
         moe_dropless=True,
     )

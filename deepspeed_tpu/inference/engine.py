@@ -42,6 +42,7 @@ from ..resilience.integrity import (
 )
 from ..models import transformer as T
 from ..ops.pallas import kernels_runnable
+from ..ops.pallas.expert_stream import grouped_rows
 from ..ops.pallas.paged_attention import latent_lanes, latent_walk_fits
 from ..utils import profiler
 from ..utils.logging import log_dist
@@ -501,9 +502,9 @@ class InferenceEngine:
         return "pallas" if self._use_kernel else "xla"
 
     def expert_path(self, width: int) -> Optional[str]:
-        """The expert path (M.expert_path: 'stream', 'scan' or 'ragged')
-        of a compiled program over `width` token rows, None for a dense
-        model: asked with what the program's routed layers will see
+        """The expert path (M.expert_path: 'stream', 'grouped', 'scan' or
+        'ragged') of a compiled program over `width` token rows, None for
+        a dense model: asked with what the program's routed layers will see
         (the leaves' shapes and types after the step's own dequant and
         fetch, the resolved kernel choice, the mesh). What the
         scheduler counts engaged steps by and the set-up spans name."""
@@ -1659,10 +1660,14 @@ class InferenceEngine:
             keys = self._row_keys(0, np.zeros((w,), np.uint32))
             logits = None
             state = self.state_args(np.full((w,), -1, np.int32))
-            # a routed model's programs name their expert path, a model
-            # of two kinds of layer its counts of each
+            # a routed model's programs name their expert path (one that
+            # multiplies an expert by its own rows, the static rows of
+            # its buffer), a model of two kinds of layer its counts of each
             path = self.expert_path(w)
             named = {"moe_expert_path": path} if path else {}
+            if path == "grouped":
+                named["moe_grouped_rows"] = grouped_rows(
+                    w, self.cfg.moe_top_k, self.cfg.n_experts)
             if state:
                 named.update(kv_layers=len(self.cache.k),
                              state_layers=len(self.cache.state))

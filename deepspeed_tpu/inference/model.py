@@ -46,7 +46,13 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..models import transformer as T
 from ..ops.attention import causal_attention, uses_flash
-from ..ops.pallas.expert_stream import expert_stream_mlp, stream_f_tile
+from ..ops.pallas.expert_stream import (
+    expert_grouped_mlp,
+    expert_stream_mlp,
+    group_rows,
+    grouped_f_tile,
+    stream_f_tile,
+)
 from ..ops.pallas.paged_attention import (
     fused_write_fits,
     kv_pack,
@@ -583,32 +589,63 @@ def _write_pools(pools: tuple, k_new, v_new, flat_idx, mesh=None,
 # 128: 8.8 / 14.7 / 21.4, 256: 9.1 / 16.5 / 22.8, 512: 17.7 / 24.9 / 25.7,
 # 1024: 35.2 / 39.9 / 32.9. All-expert streaming does X / k times the
 # needed operations: best while the weight stream binds (the pass: to
-# 256 tokens; from 512 on its matmuls bind, at 94% of the chip's peak).
+# 256 tokens; from 512 on its matmuls bind, at 94% of the chip's peak,
+# which is where its grouped entry takes over: _STREAM_RIDGE_TOKENS
+# below, with that column of the table).
 # The ragged wire does the needed operations only, at a fixed cost of
 # its sort, gather and `lax.ragged_dot` (flat at ~2.6 ms a layer from 8
 # to 32 rows an expert), and skips experts no token reached, so it wins
 # at both ends.
 _STREAM_ROWS_PER_EXPERT = (1, 128)
 _SCAN_ROWS_PER_EXPERT = (2, 128)
+# Rows (T, static) past which the one pipelined pass, where it would be
+# taken, multiplies an expert's weight tile by that expert's OWN rows
+# ('grouped'): the chip's ridge. All-expert streaming does T x X x 6EF
+# operations over X x 3EF x 2 bytes of 16-bit stacks, T operations a
+# byte whatever X, k, E and F are, so its matmuls bind from
+# 197e12 / 819e9 = ~240 rows on a v5e; 256 is the table's next column.
+# Same chip, the routed block alone, ms, stream / grouped / ragged
+# (PERF.md section 6, PR 37). LFM2-8B-A1B's widths (32 experts of
+# 2048 x 1792, top-4, 12 layers whose weights alone stream in 10.32 ms):
+# T 128: 11.88 / 11.58 / 31.2, 192: 12.22 / 11.66 / 24.4,
+# 256: 12.17 / 11.74 / 32.7, 288: 13.11 / 11.88 / 24.8,
+# 320: 14.47 / 11.90 / 26.2, 384: 17.24 / 11.99 / 34.1,
+# 512: 22.83 / 12.27 / 35.6, 768: 33.96 / 13.52 / 38.6. OLMoE's (64 of
+# 2048 x 1024, top-8, 8 layers, 7.87 ms): 128: 9.69 / 9.94 / 21.4,
+# 192: 9.78 / 10.22 / 22.2, 256: 9.91 / 10.43 / 22.8,
+# 288: 10.26 / 10.63 / 16.9, 320: 11.21 / 11.42 / 23.5,
+# 384: 13.37 / 11.55 / 24.2, 512: 17.71 / 11.92 / 25.6,
+# 768: 26.44 / 12.95 / 28.4. Under the ridge both forms wait for the
+# same weight stream and are within 5% of each other, the grouped form
+# ahead at one pair of widths and behind at the other (it moves
+# ~12 B x E a buffer row to lay the pairs out and sum them back; the
+# all-expert form reduces a [T, X] combine matrix a grid step). The two
+# widths' first wins, 128 and 384, are three columns apart, so the bound
+# is the computed ridge and not a column read off either: past it the
+# grouped form is 10-150% ahead at the first and from 4% behind (288,
+# 320: inside what OLMoE's own floor moved between two machines, 8.77
+# to 9.69 at 128) to 16-104% ahead at the second.
+_STREAM_RIDGE_TOKENS = 256
 
 
 def expert_path(n_tokens: int, cfg: T.TransformerConfig, lp=None,
                 use_kernel: bool = False, mesh=None) -> str:
     """Which expert path a compiled serving program over `n_tokens`
-    rows takes: 'stream', 'scan' or 'ragged'. THE place that chooses,
-    from what the call can observe and nothing else (no flag selects
-    it): the static rows, the layer's leaves `lp` (arrays or shapes:
-    their types, dtypes and sizes), whether kernels run (`use_kernel`:
-    decode_impl resolved 'pallas') and the mesh.
+    rows takes: 'stream', 'grouped', 'scan' or 'ragged'. THE place that
+    chooses, from what the call can observe and nothing else (no flag
+    selects it): the static rows, the layer's leaves `lp` (arrays or
+    shapes: their types, dtypes and sizes), whether kernels run
+    (`use_kernel`: decode_impl resolved 'pallas') and the mesh.
 
-    Every expert is streamed ('stream' or 'scan') between the measured
-    bounds of rows an expert, and whatever the rows on a chip that
-    holds a SHARE of the experts (cfg.experts_held): the rows a held
-    expert sees are the same T x k / X an expert, but how many pairs
-    reach the held ones varies by iteration and is known on the device
-    alone, so the ragged wire would have to gather and sort every
-    pair, of which held / X stay. Streaming reads each held expert
-    once and drops the pairs routed elsewhere in its weight matrix.
+    Every expert is streamed AND multiplied by every row ('stream' or
+    'scan') between the measured bounds of rows an expert, and whatever
+    the rows on a chip that holds a SHARE of the experts
+    (cfg.experts_held): the rows a held expert sees are the same
+    T x k / X an expert, but how many pairs reach the held ones varies
+    by iteration and is known on the device alone, so a path that sorts
+    or groups would have to lay out every pair, of which held / X stay.
+    Streaming reads each held expert once and drops the pairs routed
+    elsewhere in its weight matrix.
 
     Of the two, the one pipelined pass wherever its kernel takes the
     inputs: kernels on and one device (under a mesh of several, as for
@@ -616,7 +653,16 @@ def expert_path(n_tokens: int, cfg: T.TransformerConfig, lp=None,
     sharded operands), a gated block without biases, plain 16-bit
     stacks whose E and F fill whole lanes and tokens whose resident
     buffers fit VMEM (expert_stream.stream_f_tile). A QuantizedWeight
-    stack dequantises transiently and keeps the scan."""
+    stack dequantises transiently and keeps the scan.
+
+    Where that pass would be taken and the rows are past the chip's
+    ridge (_STREAM_RIDGE_TOKENS: its matmuls would bind, not its
+    stream), the pass takes its second entry ('grouped': the same
+    stacks through the same weight pipeline, each expert against its
+    OWN rows) if the buffer of the T x k pairs fits VMEM
+    (expert_stream.grouped_f_tile); where it does not, 'stream' as
+    before. Nothing else moved: a held share, what the pass cannot take
+    and the bounds of rows an expert answer as they did."""
     streams = (
         lp is not None and use_kernel
         and (mesh is None or mesh.devices.size == 1)
@@ -628,7 +674,13 @@ def expert_path(n_tokens: int, cfg: T.TransformerConfig, lp=None,
         return every
     rows = n_tokens * cfg.moe_top_k / cfg.n_experts
     lo, hi = _STREAM_ROWS_PER_EXPERT if streams else _SCAN_ROWS_PER_EXPERT
-    return every if lo < rows < hi else "ragged"
+    if not lo < rows < hi:
+        return "ragged"
+    if (streams and n_tokens > _STREAM_RIDGE_TOKENS and grouped_f_tile(
+            n_tokens, cfg.moe_top_k, lp["w_gate"], lp["w_in"],
+            lp["w_out"]) is not None):
+        return "grouped"
+    return every
 
 
 def _mlp(h, lp, cfg: T.TransformerConfig, census_cb=None,
@@ -647,11 +699,11 @@ def _mlp(h, lp, cfg: T.TransformerConfig, census_cb=None,
     k>=2 renormalized), so serving matches the training forward
     wherever training dropped nothing.
 
-    Three expert paths share the gating authority
+    Four expert paths share the gating authority
     (moe.dropless.dropless_topk_gating); expert_path() picks one from
-    the static rows an expert sees (T x k / X) and from what it is
-    handed here (the layer's leaves, use_kernel, mesh: as _layer hands
-    them to `attend`):
+    the static rows (T, and the rows an expert sees, T x k / X) and
+    from what it is handed here (the layer's leaves, use_kernel, mesh:
+    as _layer hands them to `attend`):
     - 'ragged': per-expert token batching — the ragged batch's rows
       stable-sort by expert id and run as ONE grouped (ragged) GEMM per
       projection inside this same compiled program
@@ -660,16 +712,24 @@ def _mlp(h, lp, cfg: T.TransformerConfig, census_cb=None,
       a per-expert combine column (ops/pallas/expert_stream.py) — X/k
       times the needed FLOPs, no [T,X,C] dispatch tensor, every weight
       streamed once and the stream never drained between experts.
+    - 'grouped': that pass's second entry, past the chip's ridge
+      (_STREAM_RIDGE_TOKENS rows, where the all-expert matmuls would
+      bind): the same stacks through the same weight pipeline, each
+      expert's tile against its OWN rows, the T x k pairs laid out by
+      expert in one static, capacity-free buffer
+      (expert_stream.group_rows) - the needed FLOPs to a row block,
+      every weight still streamed once, reached or not.
     - 'scan': the same sum as a `lax.scan` over the experts, a loop
       trip and three separately started dots an expert, for what the
       pass cannot take (int8 stacks, biases, a mesh, decode_impl 'xla').
 
     Device time is told apart by scope: `moe_route` (router matmul,
-    softmax, top-k, and the sort or the weight matrix), `moe_experts`
-    (the expert matmuls and activation; on the stream and scan paths
-    the combine column too, inside the kernel or fused by XLA into the
-    output matmul), `moe_combine` (the ragged wire's weighting and
-    segment-sum).
+    softmax, top-k, and the sort, the pairs' layout and gather, or the
+    weight matrix), `moe_experts` (the expert matmuls and activation;
+    on the stream and scan paths the combine column too, inside the
+    kernel or fused by XLA into the output matmul; on the grouped path
+    the weighted float32 sum over a token's k rows), `moe_combine` (the
+    ragged wire's weighting and segment-sum).
 
     Expert stacks may arrive as groupwise-int8 QuantizedWeight (the
     N004 machinery; quantize_layer): codes dequantize transiently here,
@@ -734,7 +794,7 @@ def _mlp(h, lp, cfg: T.TransformerConfig, census_cb=None,
                 jnp.arange(T_)[:, None], jnp.where(held, idx - start, Xh)
             ].add(wts, mode="drop")
             wcols = weights.T.astype(h.dtype)
-        elif path != "ragged":
+        elif path in ("stream", "scan"):
             # combine-weight matrix [T, X] from the top-k decisions
             weights = jnp.zeros((T_, X), jnp.float32).at[
                 jnp.arange(T_)[:, None], idx].add(wts)
@@ -752,6 +812,21 @@ def _mlp(h, lp, cfg: T.TransformerConfig, census_cb=None,
             w_gate=deq(lp["w_gate"]) if has_gate else None,
             b_in=lp.get("b_in"), b_out=lp.get("b_out"), act=act)
         return _moe_residual(out, h, lp, cfg, act)
+
+    if path == "grouped":
+        with jax.named_scope("moe_route"):
+            # the pairs in the order a stable sort by expert gives, each
+            # expert's run from a 16-row boundary of one static buffer
+            row_token, pair_row, starts, counts = group_rows(idx, X)
+            xs = h[row_token]
+        with jax.named_scope("moe_experts"):
+            ys = expert_grouped_mlp(xs, starts, counts, lp["w_gate"],
+                                    lp["w_in"], lp["w_out"], act)
+            # float32 across a token's k experts up to the one cast; a
+            # [T, E] gather a choice, which XLA fuses into the sum
+            out = sum(ys[pair_row[:, j]] * wts[:, j, None]
+                      for j in range(cfg.moe_top_k)).astype(h.dtype)
+        return _moe_shared(out, h, lp, cfg, act)
 
     if path == "stream":
         with jax.named_scope("moe_experts"):

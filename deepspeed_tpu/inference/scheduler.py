@@ -239,6 +239,10 @@ class ServingScheduler:
             # program's width: one pipelined kernel a layer); over
             # steps, 1.0 where every step is one such program
             "moe_stream_steps": 0,
+            # and those whose routed layers take that pass's second
+            # entry (expert_path 'grouped': each expert's weight tile
+            # against its own rows alone)
+            "moe_grouped_steps": 0,
             # KV cache blocks the decode rows of the dispatched programs
             # had to read: sum over rows (and fused steps) of
             # ceil(ctx / kv_block_size); over steps, what the paged
@@ -955,7 +959,8 @@ class ServingScheduler:
                       steps: int = 1) -> None:
         """Tokens a dispatched program batched out of its `width` token
         rows, the token-expert pairs they make in a routed layer and
-        whether that layer streams its experts in one pass, and, where
+        whether that layer streams its experts in one pass (over every
+        row, or over each expert's own), and, where
         the program reads the paged cache, the live KV blocks of its
         rows: ctx is the host array of context lengths it was launched
         with (0 = pad row), each row one token longer in every further
@@ -964,8 +969,9 @@ class ServingScheduler:
         cfg = self.engine.cfg
         if cfg.n_experts > 0:
             self.counters["moe_token_expert_pairs"] += n * cfg.moe_top_k
-            self.counters["moe_stream_steps"] += (
-                self.engine.expert_path(width) == "stream")
+            path = self.engine.expert_path(width)
+            self.counters["moe_stream_steps"] += path == "stream"
+            self.counters["moe_grouped_steps"] += path == "grouped"
         if ctx is not None:
             live = ctx[ctx > 0][:, None] + np.arange(steps)
             bs = self.engine.config.kv_block_size

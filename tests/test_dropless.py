@@ -334,7 +334,7 @@ class TestServingUnits:
             np.asarray(M._mlp(h, lp, self._cfg(moe_dropless=True))),
             np.asarray(M._mlp(h, lp, cfg)))
 
-    @pytest.mark.parametrize("path", ["scan", "ragged", "stream"])
+    @pytest.mark.parametrize("path", ["scan", "ragged", "stream", "grouped"])
     def test_census_counts_assignments(self, monkeypatch, pallas_interpret,
                                        path):
         from deepspeed_tpu.inference import model as M
@@ -342,7 +342,10 @@ class TestServingUnits:
         rows = self.RAGGED if path == "ragged" else self.SCAN
         monkeypatch.setattr(M, "_SCAN_ROWS_PER_EXPERT", rows)
         monkeypatch.setattr(M, "_STREAM_ROWS_PER_EXPERT", rows)
-        wide = path == "stream"  # the pass: bf16 stacks of whole lanes
+        monkeypatch.setattr(M, "_STREAM_RIDGE_TOKENS",
+                            0 if path == "grouped" else float("inf"))
+        # the pass, either entry: bf16 stacks of whole lanes
+        wide = path in ("stream", "grouped")
         cfg = self._cfg(**(dict(d_model=128, d_ff=128) if wide else {}))
         dtype = jnp.bfloat16 if wide else jnp.float32
         lp = self._layer(cfg, dtype=dtype)

@@ -12,6 +12,10 @@ interpret mode:
 - AOT compiles for a DESCRIBED v5e at both routed cells' shapes (no
   chip; the topology is described inside a module-scoped fixture, as
   benchmarks/tests/test_aot_latent.py does).
+
+The pass's second entry ('grouped') and the table of expert_path's
+answers by cell are in tests/test_expert_grouped.py, which takes this
+file's helpers.
 """
 
 import dataclasses
@@ -251,6 +255,7 @@ def test_every_step_of_a_routed_model_streams_and_says_so(monkeypatch):
     eng, sched, spans = _served(_cfg(d_ff=128))
     assert eng.resolved_impl == "pallas" and eng.expert_path(8) == "stream"
     assert sched.counters["moe_stream_steps"] == sched.counters["steps"] > 0
+    assert sched.counters["moe_grouped_steps"] == 0
     (init,) = [s for s in spans if s.name == "init.inference"]
     assert init.ids["moe_expert_path"] == "stream"
     assert init.ids["n_experts"] == 8 and init.ids["moe_top_k"] == 3
@@ -259,6 +264,7 @@ def test_every_step_of_a_routed_model_streams_and_says_so(monkeypatch):
     assert decode and all(p["moe_expert_path"] == "stream" for p in decode)
     assert all("moe_expert_path" not in p for p in programs
                if p["kind"] not in ("decode", "fused"))
+    assert all("moe_grouped_rows" not in p for p in programs)
 
 
 @pytest.mark.usefixtures("pallas_interpret")
@@ -287,6 +293,7 @@ def test_what_does_not_stream_counts_no_stream_step(what):
     assert sched.counters["steps"] > 0
     assert sched.counters["moe_stream_steps"] == (
         sched.counters["steps"] if want == "stream" else 0)
+    assert sched.counters["moe_grouped_steps"] == 0
 
 
 # -- AOT for a described v5e ------------------------------------------------
@@ -324,3 +331,4 @@ def test_the_pass_compiles_at_the_cells_shapes(one_chip, rows, X, e, f):
     # one kernel a layer, and no loop over experts around it
     assert len(calls) == 1 and "expert_stream" in calls[0]
     assert " while(" not in text
+

@@ -188,6 +188,27 @@ def test_the_cut_builds_at_published_widths():
     assert sum(a.size * 2 for a in cache.k + cache.v) / 2049 / 128 == 6144
 
 
+def test_the_published_shapes_group_their_pairs_past_the_ridge():
+    """32 experts of 2048 x 1792, top-4: the cell's 512-row program
+    multiplies an expert by its own rows; 128 rows, under the chip's
+    ridge, every token by all 32; the logits check's whole-prompt
+    prefill (two prompts of 512 rows: 128 rows an expert) keeps the
+    ragged wire."""
+    hf = json.loads((BENCH / "configs/lfm2-8b-a1b-serve-l13.json").read_text())
+    cfg = config_from_hf(hf, **hf["serve"]["model_overrides"])
+    stack = jax.ShapeDtypeStruct((32, 2048, 1792), jnp.bfloat16)
+    lp = {"w_gate": stack, "w_in": stack,
+          "w_out": jax.ShapeDtypeStruct((32, 1792, 2048), jnp.bfloat16)}
+    widths = (8, 16, 128, 256, 257, 512, 768, 1024, 2048)
+    assert [M.expert_path(t, cfg, lp, True) for t in widths] == [
+        "ragged", "stream", "stream", "stream", "grouped", "grouped",
+        "grouped", "ragged", "ragged"]
+    # kernels off (decode_impl "xla"), or a mesh: as before the entry
+    assert [M.expert_path(t, cfg, lp, False) for t in widths] == [
+        "ragged", "ragged", "scan", "scan", "scan", "scan", "scan",
+        "ragged", "ragged"]
+
+
 def test_the_cuts_file_keeps_the_published_widths():
     hf = json.loads((BENCH / "configs/lfm2-8b-a1b-serve-l13.json").read_text())
     helpers.check_published_widths(hf, BENCH)

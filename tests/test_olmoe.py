@@ -57,7 +57,7 @@ BLOCK_ATOL = 1e-6
 # always the ragged wire, whatever the shape
 ALWAYS = {"scan": (0.0, float("inf")), "ragged": (float("inf"),) * 2}
 ALWAYS["stream"] = ALWAYS["scan"]
-# the three paths on 16-bit stacks, as a share of the block's largest
+# the four paths on 16-bit stacks, as a share of the block's largest
 # output (tests/test_expert_stream.py measures 0.3-0.7% against float32)
 BLOCK_RTOL_BF16 = 0.015
 ENGINE = dict(max_seq_len=256, kv_block_size=32, num_kv_blocks=32,
@@ -312,21 +312,24 @@ def test_both_expert_paths_agree_and_the_shape_picks_one(
           "w_out": bf(8, 128, 128)}
     h = bf(24, 128) * 10
     out = {}
-    for path, rows in ALWAYS.items():
+    for path, rows in dict(ALWAYS, grouped=ALWAYS["stream"]).items():
         monkeypatch.setattr(M, "_SCAN_ROWS_PER_EXPERT", rows)
         monkeypatch.setattr(M, "_STREAM_ROWS_PER_EXPERT", rows)
-        kernels = path == "stream"
+        monkeypatch.setattr(M, "_STREAM_RIDGE_TOKENS",
+                            0 if path == "grouped" else float("inf"))
+        kernels = path in ("stream", "grouped")
         assert M.expert_path(24, wide, lw, kernels) == path
         out[path] = np.asarray(
             M._mlp(h, lw, wide, None, kernels).astype(jnp.float32))
     top = np.abs(out["ragged"]).max()
     assert top > 0.1
-    for path in ("stream", "scan"):
+    for path in ("stream", "scan", "grouped"):
         assert np.abs(out[path] - out["ragged"]).max() < BLOCK_RTOL_BF16 * top
     monkeypatch.undo()
     # T x k / X rows an expert at the published widths, from the shapes
     # alone. Where kernels run: the streamed pass above 1 row an expert
-    # and under 128; where they do not: the scan above 2 and under 128
+    # and under 128 (past 256 tokens, the chip's ridge, by its grouped
+    # entry); where they do not: the scan above 2 and under 128
     pub = config_from_hf({k: v for k, v in json.loads(
         PUBLISHED.read_text()).items() if not k.startswith("_")})
     stack = jax.ShapeDtypeStruct((64, 2048, 1024), jnp.bfloat16)
@@ -334,7 +337,9 @@ def test_both_expert_paths_agree_and_the_shape_picks_one(
           "w_out": jax.ShapeDtypeStruct((64, 1024, 2048), jnp.bfloat16)}
     widths = (8, 16, 32, 128, 256, 512, 1024)
     assert [M.expert_path(t, pub, lp, True) for t in widths] == [
-        "ragged", "stream", "stream", "stream", "stream", "stream", "ragged"]
+        "ragged", "stream", "stream", "stream", "stream", "grouped", "ragged"]
+    assert [M.expert_path(t, pub, lp, True) for t in (257, 768)] == [
+        "grouped", "grouped"]
     for by in ([M.expert_path(t, pub, lp, False) for t in widths],
                [M.expert_path(t, pub) for t in widths]):
         assert by == ["ragged", "ragged", "scan", "scan", "scan", "scan",

@@ -29,6 +29,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..ops.attention import causal_attention, uses_flash
+from ..utils.profiler import LAYER_STACK, ZERO_GATHER
 
 DP = ("data", "zero", "expert")
 
@@ -975,6 +976,17 @@ def _layer_prefetch(cfg: TransformerConfig):
             plan.prefetch_depth)
 
 
+def _layer_scan(scan, *args):
+    """`scan(*args)` over a stack of layers (`jax.lax.scan`,
+    runtime/overlap.py's `scan_with_prefetch`, or runtime/pipe.py's
+    loop over microbatch slots around a stage's) under the device scope
+    `layer_stack`: the loop's own slicing of stacked leaves and
+    activations and its control-flow copies, forward, recomputation
+    and backward, around the model's layer scopes (docs/tracing.md)."""
+    with jax.named_scope(LAYER_STACK):
+        return scan(*args)
+
+
 def _act_quant(x, cfg: TransformerConfig):
     """Fake-quantize activations (STE) when activation_quant_bits is set
     (ref: basic_layer.py activation quantization hooks). Applies in train
@@ -1382,7 +1394,7 @@ def forward_hidden(
             xs = (lp, layer_rngs[lo:hi])
         else:
             xs = lp
-        return jax.lax.scan(body, x_in, xs)
+        return _layer_scan(jax.lax.scan, body, x_in, xs)
 
     _prefetch = _layer_prefetch(cfg)
     if _prefetch is not None:
@@ -1405,8 +1417,8 @@ def forward_hidden(
                 rest = ()
             pack = ((lambda w, r: (w,) + tuple(r)) if rest
                     else (lambda w, r: w))
-            return scan_with_prefetch(body, x_in, lp, rest, pack,
-                                      _gather_fn, _depth)
+            return _layer_scan(scan_with_prefetch, body, x_in, lp, rest,
+                               pack, _gather_fn, _depth)
 
     if cfg.attention_window_pattern is not None:
         # GPT-Neo-class per-layer windows: the window is STATIC in each
@@ -1440,7 +1452,7 @@ def forward_hidden(
                 xs = (lp, group(layer_rngs))
             else:
                 xs = lp
-            return jax.lax.scan(period_body, x_in, xs)
+            return _layer_scan(jax.lax.scan, period_body, x_in, xs)
 
     if ltd_idx is not None and cfg.random_ltd_layer_range is not None:
         # Random-LTD: layers in [a, b) see only the kept tokens (at their
@@ -1464,7 +1476,8 @@ def forward_hidden(
     else:
         x, aux = seg(x, 0, cfg.n_layers, layer_body)
         aux_sum = jnp.sum(jnp.reshape(aux, (-1, 2)), axis=0)
-    out = _norm(x, params["ln_f_scale"], params.get("ln_f_bias"), cfg)
+    with jax.named_scope("norm_f"):
+        out = _norm(x, params["ln_f_scale"], params.get("ln_f_bias"), cfg)
     if with_aux:
         return out, {"moe_aux_loss": aux_sum[0], "moe_z_loss": aux_sum[1]}
     return out
@@ -1560,7 +1573,8 @@ def make_loss_fn(cfg: TransformerConfig, loss_chunks: int = 8):
             # the head in its gathered (TP) layout BEFORE the chunk scan:
             # under ZeRO-3 the partitioner otherwise re-gathers it in
             # every chunk, forward and backward (PERF.md §6, PR 24)
-            head = _shard(_lm_head(params, cfg), None, "model")
+            with jax.named_scope(ZERO_GATHER):
+                head = _shard(_lm_head(params, cfg), None, "model")
             loss = _token_mean_ce(x, head, targets,
                                   _shift_mask(batch, targets), n,
                                   head_b=params.get("lm_head_b"))
@@ -1625,13 +1639,14 @@ def make_pipelined_loss_fn(cfg: TransformerConfig, loss_chunks: int = 8):
 
         # Embedding runs replicated over 'pipe' (cheap gather); the heavy
         # layer stack runs stage-sharded.
-        x = params["embed"][inputs]
-        if cfg.use_learned_pos:
-            x = x + params["pos_embed"][:S].astype(x.dtype)
-        if cfg.embedding_layernorm:
-            x = _norm(x, params["embed_ln_scale"],
-                      params.get("embed_ln_bias"), cfg)
-        x = _shard(x, None, DP, "seq", None)
+        with jax.named_scope("embed"):
+            x = params["embed"][inputs]
+            if cfg.use_learned_pos:
+                x = x + params["pos_embed"][:S].astype(x.dtype)
+            if cfg.embedding_layernorm:
+                x = _norm(x, params["embed_ln_scale"],
+                          params.get("embed_ln_bias"), cfg)
+            x = _shard(x, None, DP, "seq", None)
 
         use_rng = rng is not None and _wants_rng(cfg)
         layer_body = _make_layer_body(cfg, use_rng)
@@ -1655,53 +1670,53 @@ def make_pipelined_loss_fn(cfg: TransformerConfig, loss_chunks: int = 8):
                     # split over ALL layers then slice, as the flat model
                     keys = stage_slice_keys(
                         mb_key, cfg.n_layers, r * n_stage + stage_idx, lc)
-                    h, l_aux = jax.lax.scan(layer_body, h, (lp, keys))
+                    h, l_aux = _layer_scan(
+                        jax.lax.scan, layer_body, h, (lp, keys))
                 else:
-                    h, l_aux = jax.lax.scan(layer_body, h, lp)
+                    h, l_aux = _layer_scan(jax.lax.scan, layer_body, h, lp)
                 return h, aux + jnp.sum(l_aux, axis=0)
 
-            hidden, aux = pipeline_apply_circular(
-                chunk_fn,
-                layers,
-                carry_in,
-                rng=rng if use_rng else None,
-                state_spec=state_spec,
-            )
+            hidden, aux = _layer_scan(
+                partial(pipeline_apply_circular,
+                        rng=rng if use_rng else None, state_spec=state_spec),
+                chunk_fn, layers, carry_in)
         else:
             def stage_fn(lp_stage, carry, mb_key, stage_idx):
                 h, aux = carry
                 if use_rng:
                     keys = stage_slice_keys(mb_key, cfg.n_layers, stage_idx, lps)
-                    h, l_aux = jax.lax.scan(layer_body, h, (lp_stage, keys))
+                    h, l_aux = _layer_scan(
+                        jax.lax.scan, layer_body, h, (lp_stage, keys))
                 else:
-                    h, l_aux = jax.lax.scan(layer_body, h, lp_stage)
+                    h, l_aux = _layer_scan(
+                        jax.lax.scan, layer_body, h, lp_stage)
                 return h, aux + jnp.sum(l_aux, axis=0)
 
             if n_stage <= 1:
                 # degenerate single-stage pipeline: layers stay [L, ...] in
                 # storage; add the [1, L, ...] stage dim at trace time
                 layers = jax.tree.map(lambda l: l[None], layers)
-            hidden, aux = pipeline_apply(
-                stage_fn,
-                layers,
-                carry_in,
-                rng=rng if use_rng else None,
-                state_spec=state_spec,
-            )
+            hidden, aux = _layer_scan(
+                partial(pipeline_apply,
+                        rng=rng if use_rng else None, state_spec=state_spec),
+                stage_fn, layers, carry_in)
 
         # Head/loss: shard microbatches over 'pipe' so the CE work (the
         # reference computes loss only on the last stage) splits across
         # stages instead of replicating.
         hidden = _shard(hidden, "pipe", DP, "seq", None)
-        x_out = _norm(hidden, params["ln_f_scale"], params.get("ln_f_bias"), cfg)
-        head = _lm_head(params, cfg)
+        with jax.named_scope("norm_f"):
+            x_out = _norm(hidden, params["ln_f_scale"],
+                          params.get("ln_f_bias"), cfg)
         mask = _shift_mask(batch, targets)
         n = _ce_chunk_count(S, loss_chunks)
-        per_micro = jax.vmap(
-            lambda xc, tc, mc: _token_mean_ce(
-                xc, head, tc, mc, n, head_b=params.get("lm_head_b"))
-        )(x_out, targets, mask)
-        loss = jnp.mean(per_micro)
+        with jax.named_scope("lm_head"):
+            head = _lm_head(params, cfg)
+            per_micro = jax.vmap(
+                lambda xc, tc, mc: _token_mean_ce(
+                    xc, head, tc, mc, n, head_b=params.get("lm_head_b"))
+            )(x_out, targets, mask)
+            loss = jnp.mean(per_micro)
         if cfg.n_experts > 0:
             loss = loss + cfg.moe_aux_loss_coef * jnp.mean(aux[:, 0])
             if cfg.moe_z_loss_coef:

@@ -250,7 +250,8 @@ def parse_hlo_collectives(hlo_text: str) -> List[Dict]:
     Each record additionally carries the operand payload (`operand_bytes`,
     summed over the shapes inside the call parens) and the replica-group
     size (`group_size`, 0 when unstated/flat) — the inputs the costmodel's
-    per-link volume math needs.
+    per-link volume math needs — and the instruction's `name` (what
+    collective_manifest resolves to a site).
 
     Async pairs count ONCE: `-done` ops never match (the op alternation
     requires an opening paren right after the collective kind), and when
@@ -283,8 +284,16 @@ def parse_hlo_collectives(hlo_text: str) -> List[Dict]:
         )
         out.append({"op": op, "bytes": nbytes, "dtypes": dtypes,
                     "operand_bytes": operand_bytes,
-                    "group_size": _group_size(tail)})
+                    "group_size": _group_size(tail),
+                    "name": _name_before(hlo_text, m.start())})
     return out
+
+
+def _name_before(hlo_text: str, eq: int) -> str:
+    """The name of the instruction whose `=` sits at `eq`: the last
+    word of its line before it (`  ROOT %all-reduce.45 = ...`)."""
+    head = hlo_text[hlo_text.rfind("\n", 0, eq) + 1:eq].split()
+    return head[-1].lstrip("%") if head else ""
 
 
 # --- entry-parameter extraction (analysis/sanitizer.py consumer) -------
@@ -657,18 +666,91 @@ def parse_hlo_computations(hlo_text: str,
     return comps, entry
 
 
+def _volumes(records) -> Dict[str, Dict[str, float]]:
+    agg: Dict[str, Dict[str, float]] = defaultdict(lambda: {"count": 0, "bytes": 0})
+    for rec in records:
+        agg[rec["op"]]["count"] += 1
+        agg[rec["op"]]["bytes"] += rec["bytes"]
+    return dict(agg)
+
+
 def collective_volumes(compiled) -> Dict[str, Dict[str, float]]:
     """Per-collective-kind totals for one compiled step.
 
     Returns {op: {count, bytes}} — e.g. how many bytes of all-gather one
     train step moves (the reference's comms summary table, per op kind,
     ref: comms_logging.py log_summary)."""
-    text = compiled.as_text()
-    agg: Dict[str, Dict[str, float]] = defaultdict(lambda: {"count": 0, "bytes": 0})
-    for rec in parse_hlo_collectives(text):
-        agg[rec["op"]]["count"] += 1
-        agg[rec["op"]]["bytes"] += rec["bytes"]
-    return dict(agg)
+    return _volumes(parse_hlo_collectives(compiled.as_text()))
+
+
+# instructions whose called computations run as events of their own on
+# the device (a loop's body, a branch): everything else that calls a
+# computation (a fusion, an async wrapper, a reduction) runs as ONE
+# event under the caller's name
+_CONTROL_FLOW_OPS = ("while", "conditional", "call")
+MANIFEST_KINDS = ("all-gather", "all-reduce", "reduce-scatter",
+                  "collective-permute", "all-to-all")
+
+
+def collective_manifest(hlo_text: str) -> Dict:
+    """What one compiled step moves between devices, by SITE.
+
+    {"kinds": {op: {count, bytes}} (collective_volumes' table: static
+    sites, one execution each), "in_fusion": {count, bytes} (the
+    collectives whose site is not themselves), "sites": [(name, op,
+    bytes)]}. A site is the top-level instruction of the entry
+    computation or of a loop body or branch that IS a collective or
+    CONTAINS one: a collective in a fused computation is booked to the
+    `fusion.<n>` that calls it, which is the name its time carries in
+    a device trace (a trace's own names tell only the collectives that
+    stand alone). Several collectives under one site and kind add up."""
+    records = parse_hlo_collectives(hlo_text)
+    comps, _ = parse_hlo_computations(hlo_text)
+    home = {ins["name"]: c for c, body in comps.items() for ins in body}
+    caller = {}
+    for c, body in comps.items():
+        for ins in body:
+            for callee in ins["called"]:
+                caller.setdefault(callee, (ins, c))
+
+    def site_of(name: str) -> str:
+        comp = home.get(name)
+        while comp in caller:
+            ins, outer = caller[comp]
+            if ins["op"] in _CONTROL_FLOW_OPS:
+                break
+            name, comp = ins["name"], outer
+        return name
+
+    sites: Dict[Tuple[str, str], int] = {}
+    in_fusion = {"count": 0, "bytes": 0}
+    for rec in records:
+        site = site_of(rec["name"])
+        if site != rec["name"]:
+            in_fusion["count"] += 1
+            in_fusion["bytes"] += rec["bytes"]
+        key = (site, rec["op"])
+        sites[key] = sites.get(key, 0) + rec["bytes"]
+    return {"kinds": _volumes(records), "in_fusion": in_fusion,
+            "sites": [(name, op, nbytes)
+                      for (name, op), nbytes in sites.items()]}
+
+
+def manifest_ids(manifest: Dict) -> Dict[str, object]:
+    """The manifest as span ids (`train.compile.collectives`,
+    docs/tracing.md): `<kind>_n` / `<kind>_bytes` per kind with `-` as
+    `_`, `in_fusion_n` / `in_fusion_bytes`, and `sites`, one compact
+    string of `name:kind:bytes` joined by commas."""
+    ids: Dict[str, object] = {}
+    for kind in MANIFEST_KINDS:
+        v = manifest["kinds"].get(kind, {"count": 0, "bytes": 0})
+        key = kind.replace("-", "_")
+        ids[f"{key}_n"] = int(v["count"])
+        ids[f"{key}_bytes"] = int(v["bytes"])
+    ids["in_fusion_n"] = int(manifest["in_fusion"]["count"])
+    ids["in_fusion_bytes"] = int(manifest["in_fusion"]["bytes"])
+    ids["sites"] = ",".join(f"{n}:{k}:{b}" for n, k, b in manifest["sites"])
+    return ids
 
 
 # --- rng extraction (analysis/determinism.py consumer) -----------------

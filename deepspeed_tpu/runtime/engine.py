@@ -70,6 +70,13 @@ class TrainState:
     loss_scale: Any  # LossScaleState or None
 
 
+def master_copy(params):
+    """The float32 view of the stored params a step updates where no
+    master is kept (device scope `param_cast`)."""
+    with jax.named_scope(profiler.PARAM_CAST):
+        return cast_params(params, jnp.float32)
+
+
 class DeepSpeedTPUEngine:
     """Engine over a (loss_fn, params) pair.
 
@@ -404,6 +411,8 @@ class DeepSpeedTPUEngine:
         self._train_step_fn = None
         self._train_compiled = None  # most recent AOT step (profiling source)
         self._train_compiled_cache: Dict[Any, Any] = {}  # per batch-shape key
+        self._manifests: Dict[Any, Dict] = {}  # its collectives, same key
+        self._manifest = None  # of the most recent step
         self._eval_step_fn = None
         self._grad_step_fn = None
         # classifies every AOT-cache miss (weak-type drift, shape churn,
@@ -754,6 +763,14 @@ class DeepSpeedTPUEngine:
             layer_tp_specs=layer_tp,
         )
 
+    def collective_manifest(self) -> Optional[Dict]:
+        """What the most recent compiled train step moves between
+        devices, by site (profiling/hlo.py collective_manifest: `kinds`,
+        `in_fusion`, `sites`): the table the always-kept span
+        `train.compile.collectives` carries as ids, whatever
+        comms_logger.enabled says. None before the first step."""
+        return self._manifest
+
     def overlap_stats(self):
         """Per-step overlap feed for monitor.training_events
         (docs/overlap.md): exposed_comm_us / achieved_overlap_frac /
@@ -804,21 +821,23 @@ class DeepSpeedTPUEngine:
                 from ..comm.compressed import quantized_mean_tree
 
                 wgrads, losses = worker_acc(master, batch, base_rng)
-                grads = quantized_mean_tree(wgrads, mesh)
-                grads = jax.tree.map(
-                    lambda g, s: shd.constraint(g, s, mesh), grads, grad_specs
-                )
+                with jax.named_scope(profiler.GRAD_REDUCE):
+                    grads = quantized_mean_tree(wgrads, mesh)
+                    grads = jax.tree.map(
+                        lambda g, s: shd.constraint(g, s, mesh),
+                        grads, grad_specs)
                 return grads, jnp.mean(losses)
 
             return accumulate_qgz
 
         def accumulate(master, batch, base_rng, scale, step):
             def to_model_params(m):
-                p = cast_params(m, compute_dtype)
-                if qwz_apply is not None:
-                    p = qwz_apply(p)
-                if compression is not None:
-                    p = compression(p, step)
+                with jax.named_scope(profiler.PARAM_CAST):
+                    p = cast_params(m, compute_dtype)
+                    if qwz_apply is not None:
+                        p = qwz_apply(p)
+                    if compression is not None:
+                        p = compression(p, step)
                 return p
 
             if pipelined:
@@ -832,18 +851,19 @@ class DeepSpeedTPUEngine:
                     return l * scale, l
 
                 grads, loss = jax.grad(scaled_loss, has_aux=True)(master)
-                inv = 1.0 / scale
-                if bucket_mb > 0:
-                    # bucketed launches: each bucket's reduce-scatters
-                    # issue under the previous bucket's unscale compute
-                    grads = overlap.bucketed_apply(
-                        grads, grad_specs, mesh, bucket_mb,
-                        lambda j, g: g * inv)
-                else:
-                    grads = jax.tree.map(
-                        lambda g, s: shd.constraint(g, s, mesh),
-                        grads, grad_specs)
-                    grads = jax.tree.map(lambda g: g * inv, grads)
+                with jax.named_scope(profiler.GRAD_REDUCE):
+                    inv = 1.0 / scale
+                    if bucket_mb > 0:
+                        # bucketed launches: each bucket's reduce-scatters
+                        # issue under the previous bucket's unscale compute
+                        grads = overlap.bucketed_apply(
+                            grads, grad_specs, mesh, bucket_mb,
+                            lambda j, g: g * inv)
+                    else:
+                        grads = jax.tree.map(
+                            lambda g, s: shd.constraint(g, s, mesh),
+                            grads, grad_specs)
+                        grads = jax.tree.map(lambda g: g * inv, grads)
                 return grads, loss
 
             def micro(carry, xs):
@@ -861,32 +881,36 @@ class DeepSpeedTPUEngine:
                 # ZeRO>=2: constrain per-micro grads to the sharded layout →
                 # XLA reduce-scatters inside the accumulation loop
                 # (ref: stage_1_and_2.py overlap_comm reduction during bwd).
-                if bucket_mb > 0:
-                    # bucket_mb-sized launch groups, pipelined against
-                    # the accumulate adds (runtime/overlap.py)
-                    acc_leaves = jax.tree.leaves(acc)
-                    acc = overlap.bucketed_apply(
-                        grads, grad_specs, mesh, bucket_mb,
-                        lambda j, g: acc_leaves[j] + g)
-                else:
-                    grads = jax.tree.map(
-                        lambda g, s: shd.constraint(g, s, mesh),
-                        grads, grad_specs,
-                    )
-                    acc = jax.tree.map(jnp.add, acc, grads)
+                with jax.named_scope(profiler.GRAD_REDUCE):
+                    if bucket_mb > 0:
+                        # bucket_mb-sized launch groups, pipelined against
+                        # the accumulate adds (runtime/overlap.py)
+                        acc_leaves = jax.tree.leaves(acc)
+                        acc = overlap.bucketed_apply(
+                            grads, grad_specs, mesh, bucket_mb,
+                            lambda j, g: acc_leaves[j] + g)
+                    else:
+                        grads = jax.tree.map(
+                            lambda g, s: shd.constraint(g, s, mesh),
+                            grads, grad_specs,
+                        )
+                        acc = jax.tree.map(jnp.add, acc, grads)
                 return (acc, loss_sum + loss), None
 
-            zeros = jax.tree.map(
-                lambda m, s: shd.constraint(jnp.zeros(m.shape, jnp.float32), s, mesh),
-                master,
-                grad_specs,
-            )
+            with jax.named_scope(profiler.GRAD_REDUCE):
+                zeros = jax.tree.map(
+                    lambda m, s: shd.constraint(
+                        jnp.zeros(m.shape, jnp.float32), s, mesh),
+                    master,
+                    grad_specs,
+                )
             idxs = jnp.arange(gas)
             (grads, loss_sum), _ = jax.lax.scan(
                 micro, (zeros, jnp.float32(0.0)), (idxs, batch)
             )
-            inv = 1.0 / (gas * scale)
-            grads = jax.tree.map(lambda g: g * inv, grads)
+            with jax.named_scope(profiler.GRAD_REDUCE):
+                inv = 1.0 / (gas * scale)
+                grads = jax.tree.map(lambda g: g * inv, grads)
             return grads, loss_sum / gas
 
         return accumulate
@@ -908,34 +932,40 @@ class DeepSpeedTPUEngine:
         master_shd = getattr(self, "_master_shardings", None)
 
         def finish(new_master, new_opt, new_step, loss_scale, metrics):
-            if opt_shd is not None:
-                new_opt = jax.tree.map(
-                    jax.lax.with_sharding_constraint, new_opt, opt_shd
-                )
-            if use_master and master_shd is not None:
-                new_master = jax.tree.map(
-                    jax.lax.with_sharding_constraint, new_master, master_shd
-                )
-
             def cast_gather(m, store_spec, mshd=None):
-                x = m.astype(compute_dtype)
-                if mshd is not None:
-                    # pin the compute-dtype cast to the SHARDED layout and
-                    # barrier before regathering, so the ZeRO param
-                    # allgather moves bf16, not fp32 (XLA otherwise
-                    # reorders to gather-then-convert)
-                    x = jax.lax.with_sharding_constraint(x, mshd)
-                    x = jax.lax.optimization_barrier(x)
-                return shd.constraint(x, store_spec, mesh)
+                with jax.named_scope(profiler.PARAM_CAST):
+                    x = m.astype(compute_dtype)
+                    if mshd is not None:
+                        # pin the compute-dtype cast to the SHARDED layout
+                        # and barrier before regathering, so the ZeRO
+                        # param allgather moves bf16, not fp32 (XLA
+                        # otherwise reorders to gather-then-convert)
+                        x = jax.lax.with_sharding_constraint(x, mshd)
+                        x = jax.lax.optimization_barrier(x)
+                with jax.named_scope(profiler.ZERO_GATHER):
+                    return shd.constraint(x, store_spec, mesh)
 
-            if master_shd is not None:
-                new_params = jax.tree.map(
-                    cast_gather, new_master, param_specs, master_shd
-                )
-            else:
-                new_params = jax.tree.map(
-                    cast_gather, new_master, param_specs
-                )
+            # XLA fuses the copy into the update's one pass over master
+            # and moments and gives the fusion the COPY's path: entered
+            # inside `optimizer`, that pass reads `optimizer` (a reader
+            # takes the outermost scope), `param_cast` the loss's copies
+            with jax.named_scope(profiler.OPTIMIZER):
+                if opt_shd is not None:
+                    new_opt = jax.tree.map(
+                        jax.lax.with_sharding_constraint, new_opt, opt_shd
+                    )
+                if use_master and master_shd is not None:
+                    new_master = jax.tree.map(
+                        jax.lax.with_sharding_constraint, new_master,
+                        master_shd)
+                if master_shd is not None:
+                    new_params = jax.tree.map(
+                        cast_gather, new_master, param_specs, master_shd
+                    )
+                else:
+                    new_params = jax.tree.map(
+                        cast_gather, new_master, param_specs
+                    )
             state = TrainState(
                 step=new_step,
                 params=new_params,
@@ -970,44 +1000,47 @@ class DeepSpeedTPUEngine:
         nonfinite_guard = (not fp16) and cfg.integrity.enabled
 
         def step_fn(state: TrainState, batch):
-            master = (
-                state.master
-                if use_master
-                else cast_params(fetch_params(state.params), jnp.float32)
-            )
+            master = state.master if use_master else master_copy(
+                fetch_params(state.params))
             scale = state.loss_scale.scale if fp16 else jnp.float32(1.0)
             base_rng = jax.random.fold_in(jax.random.PRNGKey(seed), state.step)
 
             grads, loss = accumulate(master, batch, base_rng, scale, state.step)
 
-            grad_norm = global_grad_norm(grads)
-            if fp16:
-                # any inf/nan leaf makes the sum-of-squares norm non-finite,
-                # so this single check subsumes a per-leaf isfinite pass
-                found_inf = jnp.logical_not(jnp.isfinite(grad_norm))
-            elif nonfinite_guard:
-                found_inf = found_inf_in_grads(grads)
-            else:
-                found_inf = jnp.bool_(False)
-            grads = clip_grads_by_global_norm(grads, clip, grad_norm)
+            with jax.named_scope(profiler.GRAD_CLIP):
+                grad_norm = global_grad_norm(grads)
+                if fp16:
+                    # any inf/nan leaf makes the sum-of-squares norm
+                    # non-finite, so this single check subsumes a
+                    # per-leaf isfinite pass
+                    found_inf = jnp.logical_not(jnp.isfinite(grad_norm))
+                elif nonfinite_guard:
+                    found_inf = found_inf_in_grads(grads)
+                else:
+                    found_inf = jnp.bool_(False)
+                grads = clip_grads_by_global_norm(grads, clip, grad_norm)
 
-            new_step = state.step + 1
-            lr = schedule(state.step)
-            new_master, new_opt = optimizer.update(grads, state.opt, master, lr, new_step)
+            with jax.named_scope(profiler.OPTIMIZER):
+                new_step = state.step + 1
+                lr = schedule(state.step)
+                new_master, new_opt = optimizer.update(
+                    grads, state.opt, master, lr, new_step)
 
-            if fp16 or nonfinite_guard:
-                # skip the update on overflow (ref: fused_optimizer.py step
-                # overflow path) — select is branchless and free on TPU.
-                sel = lambda new, old: jax.tree.map(
-                    lambda n, o: jnp.where(found_inf, o, n), new, old
-                )
-                new_master = sel(new_master, master)
-                new_opt = sel(new_opt, state.opt)
-                new_step = jnp.where(found_inf, state.step, new_step)
-            if fp16:
-                new_ls = update_loss_scale(state.loss_scale, found_inf, cfg.fp16)
-            else:
-                new_ls = state.loss_scale
+                if fp16 or nonfinite_guard:
+                    # skip the update on overflow (ref: fused_optimizer.py
+                    # step overflow path) — select is branchless and free
+                    # on TPU.
+                    sel = lambda new, old: jax.tree.map(
+                        lambda n, o: jnp.where(found_inf, o, n), new, old
+                    )
+                    new_master = sel(new_master, master)
+                    new_opt = sel(new_opt, state.opt)
+                    new_step = jnp.where(found_inf, state.step, new_step)
+                if fp16:
+                    new_ls = update_loss_scale(
+                        state.loss_scale, found_inf, cfg.fp16)
+                else:
+                    new_ls = state.loss_scale
 
             metrics = {
                 "loss": loss,
@@ -1050,7 +1083,9 @@ class DeepSpeedTPUEngine:
 
         def body(master, delta, batch, base_rng):
             if with_delta:
-                local = jax.tree.map(lambda m, d: m + d[0], master, delta)
+                with jax.named_scope(profiler.PARAM_CAST):
+                    local = jax.tree.map(
+                        lambda m, d: m + d[0], master, delta)
             else:
                 local = master
 
@@ -1062,12 +1097,14 @@ class DeepSpeedTPUEngine:
                 # 1-bit/0-1/qgZ compose with pipeline parallelism
                 # (ref: 1-bit Adam under Megatron PP, onebit/adam.py)
                 def local_loss(m):
-                    p = cast_params(m, compute_dtype)
+                    with jax.named_scope(profiler.PARAM_CAST):
+                        p = cast_params(m, compute_dtype)
                     out = loss_fn(p, batch, base_rng)
                     return out[0] if has_aux else out
 
                 loss, grads = jax.value_and_grad(local_loss)(local)
-                grads = jax.tree.map(lambda g: g[None], grads)
+                with jax.named_scope(profiler.GRAD_REDUCE):
+                    grads = jax.tree.map(lambda g: g[None], grads)
                 return grads, loss[None]
 
             def micro(carry, xs):
@@ -1076,19 +1113,24 @@ class DeepSpeedTPUEngine:
                 rng = jax.random.fold_in(base_rng, idx)
 
                 def local_loss(m):
-                    p = cast_params(m, compute_dtype)
+                    with jax.named_scope(profiler.PARAM_CAST):
+                        p = cast_params(m, compute_dtype)
                     out = loss_fn(p, micro_batch, rng)
                     return out[0] if has_aux else out
 
                 loss, grads = jax.value_and_grad(local_loss)(local)
-                acc = jax.tree.map(jnp.add, acc, grads)
+                with jax.named_scope(profiler.GRAD_REDUCE):
+                    acc = jax.tree.map(jnp.add, acc, grads)
                 return (acc, loss_sum + loss), None
 
-            zeros = jax.tree.map(lambda m: jnp.zeros(m.shape, jnp.float32), master)
+            with jax.named_scope(profiler.GRAD_REDUCE):
+                zeros = jax.tree.map(
+                    lambda m: jnp.zeros(m.shape, jnp.float32), master)
             (grads, loss_sum), _ = jax.lax.scan(
                 micro, (zeros, jnp.float32(0.0)), (jnp.arange(gas), batch)
             )
-            grads = jax.tree.map(lambda g: (g / gas)[None], grads)
+            with jax.named_scope(profiler.GRAD_REDUCE):
+                grads = jax.tree.map(lambda g: (g / gas)[None], grads)
             return grads, (loss_sum / gas)[None]
 
         if not manual:
@@ -1145,27 +1187,26 @@ class DeepSpeedTPUEngine:
         finish = self._make_finalizer()
 
         def step_fn(state: TrainState, batch):
-            master = state.master if use_master else cast_params(state.params, jnp.float32)
+            master = (state.master if use_master
+                      else master_copy(state.params))
             # ZeRO-1: grads come from the replicated params (the sharded
             # master would allgather fp32 into the worker shard_map)
-            grad_src = (
-                cast_params(state.params, jnp.float32) if zero1 else master
-            )
+            grad_src = master_copy(state.params) if zero1 else master
             base_rng = jax.random.fold_in(jax.random.PRNGKey(seed), state.step)
             wgrads, losses = worker_acc(grad_src, batch, base_rng)
             loss = jnp.mean(losses)
-            new_step = state.step + 1
-            lr = schedule(state.step)
-            new_master, new_opt = optimizer.compressed_update(
-                wgrads, state.opt, master, lr, new_step, mesh
-            )
-            metrics = {
-                "loss": loss,
-                # post-compression momentum norm (true grad norm would need
-                # the uncompressed reduction this phase exists to avoid)
-                "grad_norm": global_grad_norm(new_opt["mu"]),
-                "lr": lr,
-            }
+            with jax.named_scope(profiler.OPTIMIZER):
+                new_step = state.step + 1
+                lr = schedule(state.step)
+                new_master, new_opt = optimizer.compressed_update(
+                    wgrads, state.opt, master, lr, new_step, mesh
+                )
+            with jax.named_scope(profiler.GRAD_CLIP):
+                # post-compression momentum norm (true grad norm would
+                # need the uncompressed reduction this phase exists to
+                # avoid)
+                norm = global_grad_norm(new_opt["mu"])
+            metrics = {"loss": loss, "grad_norm": norm, "lr": lr}
             return finish(new_master, new_opt, new_step, state.loss_scale,
                           metrics)
 
@@ -1196,7 +1237,8 @@ class DeepSpeedTPUEngine:
         }[kind]
 
         def step_fn(state: TrainState, batch):
-            master = state.master if use_master else cast_params(state.params, jnp.float32)
+            master = (state.master if use_master
+                      else master_copy(state.params))
             base_rng = jax.random.fold_in(jax.random.PRNGKey(seed), state.step)
             if with_delta:
                 wgrads, losses = worker_acc(
@@ -1205,19 +1247,20 @@ class DeepSpeedTPUEngine:
             else:
                 wgrads, losses = worker_acc(master, batch, base_rng)
             loss = jnp.mean(losses)
-            new_step = state.step + 1
-            lr = schedule(state.step)
-            new_master, new_opt = upd(wgrads, state.opt, master, lr, mesh)
-            if kind in ("local", "sync"):
-                # per-replica momentum norm: worker_mu is worker-major, so
-                # normalize by sqrt(dp) to stay comparable with the
-                # replicated-mu norm of the phase-1 programs
-                dp = new_opt["worker_lrs"].shape[0]
-                norm = global_grad_norm(new_opt["worker_mu"]) / jnp.sqrt(
-                    jnp.float32(dp)
-                )
-            else:
-                norm = global_grad_norm(new_opt["mu"])
+            with jax.named_scope(profiler.OPTIMIZER):
+                new_step = state.step + 1
+                lr = schedule(state.step)
+                new_master, new_opt = upd(wgrads, state.opt, master, lr, mesh)
+            with jax.named_scope(profiler.GRAD_CLIP):
+                if kind in ("local", "sync"):
+                    # per-replica momentum norm: worker_mu is worker-major,
+                    # so normalize by sqrt(dp) to stay comparable with the
+                    # replicated-mu norm of the phase-1 programs
+                    dp = new_opt["worker_lrs"].shape[0]
+                    norm = global_grad_norm(
+                        new_opt["worker_mu"]) / jnp.sqrt(jnp.float32(dp))
+                else:
+                    norm = global_grad_norm(new_opt["mu"])
             metrics = {
                 "loss": loss,
                 # momentum norm (the exact mean-grad norm would need the
@@ -1283,10 +1326,11 @@ class DeepSpeedTPUEngine:
         fetch_params = self._make_param_fetch()
 
         def grad_fn(params, step, batch):
-            master = cast_params(fetch_params(params), jnp.float32)
+            master = master_copy(fetch_params(params))
             base_rng = jax.random.fold_in(jax.random.PRNGKey(seed), step)
             grads, loss = accumulate(master, batch, base_rng, jnp.float32(1.0), step)
-            return grads, loss, global_grad_norm(grads)
+            with jax.named_scope(profiler.GRAD_CLIP):
+                return grads, loss, global_grad_norm(grads)
 
         return jax.jit(grad_fn)
 
@@ -1875,15 +1919,24 @@ class DeepSpeedTPUEngine:
                 # AOT compile (per batch-shape signature, matching jit's
                 # retrace-on-new-shape) so the step's HLO is inspectable:
                 # flops/comm accounting reads the program actually executed.
-                from ..profiling.hlo import collective_volumes
+                from ..profiling.hlo import collective_manifest, manifest_ids
 
                 with profiler.span("train.compile", always=True,
-                                   step=self.global_steps + 1), \
-                        profiler.compile_spans("train.compile"):
-                    compiled = step_fn.lower(self.state, batch).compile()
+                                   step=self.global_steps + 1):
+                    with profiler.compile_spans("train.compile"):
+                        compiled = step_fn.lower(self.state, batch).compile()
+                    # what the step moves between devices, by site: the
+                    # one parse of the compiled text, kept as a span
+                    # whatever comms_logger.enabled says
+                    with profiler.span("train.compile.collectives",
+                                       always=True) as sp:
+                        manifest = collective_manifest(compiled.as_text())
+                        sp.set(**manifest_ids(manifest))
                 self._train_compiled_cache[shape_key] = compiled
-                comms_logger.record_compiled(collective_volumes(compiled))
+                self._manifests[shape_key] = manifest
+                comms_logger.record_compiled(manifest["kinds"])
             self._train_compiled = compiled
+            self._manifest = self._manifests[shape_key]
             ph.mark("launch")
             self.state, metrics = compiled(self.state, batch)
         self.state = self._park_params(self.state)
@@ -1957,7 +2010,6 @@ class DeepSpeedTPUEngine:
 
             seqlen = self.curriculum.update_difficulty(self.global_steps + 1)
             batch = truncate_to_seqlen(batch, seqlen)
-        self.tput.start()
         metrics = self._dispatch_step(batch)
         ph.mark("readback")
         # single host transfer for all metrics (device sync point) — per-key
@@ -1966,11 +2018,12 @@ class DeepSpeedTPUEngine:
         metrics = {k: float(v) for k, v in jax.device_get(metrics).items()}  # ds-lint: ok R002 the one deliberate per-step sync
         ph.mark("post")
         # the step's time is the phases' own stamps: prepare + launch +
-        # readback (BATCH_TIMER and the spans share one clock reading)
+        # readback (BATCH_TIMER, the throughput timer and the spans
+        # share one clock reading)
         step_time = (ph.ns["prepare"] + ph.ns["launch"]
                      + ph.ns["readback"]) * 1e-9
         self.timers(BATCH_TIMER).add(step_time)
-        self.tput.stop()
+        self.tput.add(step_time)
         self.global_steps += 1
         if self._heartbeat is not None:
             # metrics were device_get'd above, so this beat certifies a

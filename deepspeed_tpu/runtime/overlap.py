@@ -50,6 +50,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from ..utils.profiler import GRAD_REDUCE, ZERO_GATHER
+
 __all__ = [
     "OverlapPlan",
     "overlap_scope",
@@ -187,15 +189,19 @@ def make_prefetch_gather(store_specs, tp_specs, mesh, n_lead: int = 1):
 
         @jax.custom_vjp
         def gather(w):
-            w = jax.lax.with_sharding_constraint(w, NamedSharding(mesh, s))
-            return jax.lax.with_sharding_constraint(w, NamedSharding(mesh, g))
+            with jax.named_scope(ZERO_GATHER):
+                w = jax.lax.with_sharding_constraint(
+                    w, NamedSharding(mesh, s))
+                return jax.lax.with_sharding_constraint(
+                    w, NamedSharding(mesh, g))
 
         def fwd(w):
             return gather(w), None
 
         def bwd(_, ct):
-            return (jax.lax.with_sharding_constraint(
-                ct, NamedSharding(mesh, s)),)
+            with jax.named_scope(GRAD_REDUCE):
+                return (jax.lax.with_sharding_constraint(
+                    ct, NamedSharding(mesh, s)),)
 
         gather.defvjp(fwd, bwd)
         return gather
@@ -208,8 +214,13 @@ def make_prefetch_gather(store_specs, tp_specs, mesh, n_lead: int = 1):
         g = _drop_lead(tp_spec, n_lead)
         if s == g:
             return lambda w: w
-        return lambda w: jax.lax.with_sharding_constraint(
-            w, NamedSharding(mesh, g))
+
+        def pin_gathered(w):
+            with jax.named_scope(ZERO_GATHER):
+                return jax.lax.with_sharding_constraint(
+                    w, NamedSharding(mesh, g))
+
+        return pin_gathered
 
     is_spec = lambda x: isinstance(x, P)  # noqa: E731
     fns = jax.tree.map(leaf_fn, store_specs, tp_specs, is_leaf=is_spec)

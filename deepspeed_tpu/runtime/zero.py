@@ -42,6 +42,7 @@ import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..config.config import ZeroConfig
+from ..utils.profiler import GRAD_REDUCE, ZERO_GATHER
 
 
 def zero_axes(mesh: Mesh) -> Tuple[str, ...]:
@@ -328,23 +329,25 @@ def make_qwz_gather(store_specs, gathered_specs, shapes, mesh: Mesh):
                 w, jax.sharding.NamedSharding(mesh, store_spec)
             )
             q, s = quantize_per_axis(w, k)
-            q = jax.lax.with_sharding_constraint(
-                q, jax.sharding.NamedSharding(mesh, gathered_spec)
-            )
-            s = jax.lax.with_sharding_constraint(
-                s, jax.sharding.NamedSharding(mesh, scale_spec)
-            )
+            with jax.named_scope(ZERO_GATHER):
+                q = jax.lax.with_sharding_constraint(
+                    q, jax.sharding.NamedSharding(mesh, gathered_spec)
+                )
+                s = jax.lax.with_sharding_constraint(
+                    s, jax.sharding.NamedSharding(mesh, scale_spec)
+                )
             return dequantize_per_axis(q, s, k, w.dtype)
 
         def fwd(w):
             return gather(w), None
 
         def bwd(_, g):
-            return (
-                jax.lax.with_sharding_constraint(
-                    g, jax.sharding.NamedSharding(mesh, store_spec)
-                ),
-            )
+            with jax.named_scope(GRAD_REDUCE):
+                return (
+                    jax.lax.with_sharding_constraint(
+                        g, jax.sharding.NamedSharding(mesh, store_spec)
+                    ),
+                )
 
         gather.defvjp(fwd, bwd)
         return gather

@@ -36,6 +36,19 @@ HOT_SPANS = 65536    # per-iteration spans kept (minutes of serving)
 KEPT_SPANS = 4096    # always=True spans kept
 STAGE_GAP_NS = 1_000_000
 
+# The device scopes of the compiled train step (`jax.named_scope`, so
+# metadata alone: docs/tracing.md "Device scopes of the train step").
+# With the model's own (MODEL_SCOPES, models/transformer.py: the six
+# the benchmark has read since PR 26 and `norm_f`, the final norm) they
+# name every instruction the program itself wrote; a reader takes the
+# OUTERMOST of the names it asks for.
+(PARAM_CAST, GRAD_REDUCE, GRAD_CLIP, OPTIMIZER, ZERO_GATHER,
+ LAYER_STACK) = TRAIN_STEP_SCOPES = (
+    "param_cast", "grad_reduce", "grad_clip", "optimizer", "zero_gather",
+    "layer_stack")
+MODEL_SCOPES = ("embed", "norm1", "attention", "norm2", "mlp", "norm_f",
+                "lm_head")
+
 _is_profiling = jax.profiler.TraceAnnotation.is_enabled
 
 # what jax.monitoring calls the three host stages of building a program

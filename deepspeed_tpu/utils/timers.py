@@ -95,29 +95,24 @@ class SynchronizedWallClockTimer:
 
 
 class ThroughputTimer:
-    """Samples/sec + TFLOPs estimator (ref: deepspeed/utils/timer.py:198)."""
+    """Samples/sec estimator (ref: deepspeed/utils/timer.py:198). It has
+    no clock of its own: the engine books each step's seconds from the
+    same phase stamps BATCH_TIMER and the `train.*` spans are made of
+    (docs/tracing.md), so the log's `samples/s` and `time: step=` lines
+    cannot disagree."""
 
     def __init__(self, batch_size: int, start_step: int = 2):
         self.batch_size = max(batch_size, 1)
         self.start_step = start_step
         self.global_step_count = 0
         self.total_elapsed_time = 0.0
-        self._start_time = 0.0
-        self.started = False
 
-    def start(self):
-        self.started = True
-        self._start_time = time.perf_counter()
-
-    def stop(self, global_step: bool = True, report_speed: bool = False):
-        if not self.started:
-            return
-        self.started = False
-        duration = time.perf_counter() - self._start_time
-        if global_step:
-            self.global_step_count += 1
-            if self.global_step_count > self.start_step:
-                self.total_elapsed_time += duration
+    def add(self, seconds: float) -> None:
+        """Book one global step that took `seconds` (the first
+        `start_step` steps, which compile, are counted but not timed)."""
+        self.global_step_count += 1
+        if self.global_step_count > self.start_step:
+            self.total_elapsed_time += seconds
 
     @property
     def avg_samples_per_sec(self) -> float:

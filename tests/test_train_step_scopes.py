@@ -1,0 +1,443 @@
+"""The device scopes of the compiled train step and its collective
+manifest (docs/tracing.md "Device scopes of the train step").
+
+Every step builder of runtime/engine.py is compiled at a tiny size on
+the CPU lane and its optimized HLO's `op_name`s are read the way an
+operator's profile reads them (profiling/latency.py): each scope of
+utils/profiler.TRAIN_STEP_SCOPES that applies is there, the optimizer
+never sits inside the model, and the share of the program's own
+instructions that some scope names stays at or above a recorded
+figure. The scopes are metadata: with `jax.named_scope` patched to a
+null context (here, in the test: the program has no switch) the
+optimized step is the same program label for label. The manifest is
+held to `collective_volumes`, to the compiled text, and, compiled for a
+described v5e:2x2, to booking a collective inside a fusion to the
+fusion's name.
+"""
+
+import contextlib
+import functools
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P
+
+import deepspeed_tpu as ds
+from deepspeed_tpu.comm.logger import comms_logger
+from deepspeed_tpu.models import transformer as T
+from deepspeed_tpu.platform.mesh import build_mesh
+from deepspeed_tpu.profiling.hlo import (
+    collective_manifest,
+    collective_volumes,
+    manifest_ids,
+)
+from deepspeed_tpu.profiling.latency import hlo_scope_map, scope_path
+from deepspeed_tpu.utils import profiler
+from deepspeed_tpu.utils.profiler import (
+    GRAD_CLIP,
+    GRAD_REDUCE,
+    LAYER_STACK,
+    MODEL_SCOPES,
+    OPTIMIZER,
+    PARAM_CAST,
+    TRAIN_STEP_SCOPES,
+    ZERO_GATHER,
+)
+
+VOCAB = 128
+ALL = MODEL_SCOPES + TRAIN_STEP_SCOPES
+# (the 1-bit optimizers refuse gradient clipping)
+ONEBIT = {"type": "OneBitAdam", "params": {"lr": 1e-3, "freeze_step": 1}}
+ZOADAM = {"type": "ZeroOneAdam",
+          "params": {"lr": 1e-3, "var_freeze_step": 1,
+                     "local_step_scaler": 100, "local_step_clipper": 16}}
+
+# step builder -> (config over the base, mesh, model overrides, the
+# scopes of TRAIN_STEP_SCOPES its step must hold, the least share of
+# its own instructions some scope names: recorded from this tree less
+# two points)
+BUILDERS = {
+    "plain": ({}, {}, {},
+              {PARAM_CAST, GRAD_CLIP, OPTIMIZER, LAYER_STACK}, 0.95),
+    "fp16": ({"bf16": {"enabled": False}, "fp16": {"enabled": True}}, {}, {},
+             {PARAM_CAST, GRAD_REDUCE, GRAD_CLIP, OPTIMIZER, LAYER_STACK},
+             0.95),
+    "gas2": ({"gradient_accumulation_steps": 2}, {}, {},
+             {PARAM_CAST, GRAD_REDUCE, GRAD_CLIP, OPTIMIZER, LAYER_STACK},
+             0.93),
+    "zero1": ({"zero_optimization": {"stage": 1},
+               "gradient_accumulation_steps": 2}, {"data": 4}, {},
+              set(TRAIN_STEP_SCOPES), 0.94),
+    "zero2": ({"zero_optimization": {"stage": 2},
+               "gradient_accumulation_steps": 2}, {"data": 4}, {},
+              set(TRAIN_STEP_SCOPES), 0.94),
+    "zero3": ({"zero_optimization": {"stage": 3},
+               "gradient_accumulation_steps": 2}, {"data": 4}, {},
+              set(TRAIN_STEP_SCOPES), 0.94),
+    "pipelined": ({"gradient_accumulation_steps": 2}, {"pipe": 2, "data": 2},
+                  {"pipeline_stages": 2},
+                  # bf16 unscales by 1.0: the multiply folds away
+                  {PARAM_CAST, GRAD_CLIP, OPTIMIZER, LAYER_STACK}, 0.97),
+    "onebit": ({"optimizer": ONEBIT, "gradient_clipping": 0.0,
+                "gradient_accumulation_steps": 2}, {"data": 4}, {},
+               {PARAM_CAST, GRAD_REDUCE, GRAD_CLIP, OPTIMIZER, LAYER_STACK},
+               0.95),
+    "zoadam-full": ({"optimizer": ZOADAM, "gradient_clipping": 0.0,
+                     "gradient_accumulation_steps": 2},
+                    {"data": 4}, {},
+                    {PARAM_CAST, GRAD_REDUCE, GRAD_CLIP, OPTIMIZER,
+                     LAYER_STACK}, 0.93),
+    "zoadam-local": ({"optimizer": ZOADAM, "gradient_clipping": 0.0,
+                      "gradient_accumulation_steps": 2},
+                     {"data": 4}, {},
+                     {PARAM_CAST, GRAD_REDUCE, GRAD_CLIP, OPTIMIZER,
+                      LAYER_STACK}, 0.93),
+}
+# opcodes that compute nothing
+TRIVIAL = ("parameter", "param_", "constant", "get-tuple-element", "tuple",
+           "bitcast")
+
+
+def build(name, **extra):
+    over, mesh, model, _, _ = BUILDERS[name]
+    mcfg = T.TransformerConfig(**dict(
+        dict(vocab_size=VOCAB, n_layers=2, n_heads=4, d_model=64, max_seq=32,
+             variant="llama", use_flash=False), **model))
+    cfg = {"train_micro_batch_size_per_gpu": 1,
+           "gradient_accumulation_steps": 1,
+           "optimizer": {"type": "adamw", "params": {"lr": 1e-3}},
+           "bf16": {"enabled": True}, "gradient_clipping": 1.0,
+           "seed": 7, "steps_per_print": 10**9}
+    cfg.update(over, **extra)
+    pipelined = "pipeline_stages" in model
+    n = int(np.prod(list(mesh.values()))) if mesh else 1
+    return ds.initialize(
+        cfg,
+        loss_fn=(T.make_pipelined_loss_fn if pipelined
+                 else T.make_loss_fn)(mcfg),
+        param_init_fn=lambda k: T.init(mcfg, k),
+        param_logical_specs=T.logical_specs(mcfg),
+        mesh=build_mesh(mesh, devices=jax.devices()[:n]),
+        pipelined=pipelined)
+
+
+def batch_of(engine):
+    r = np.random.default_rng(0)
+    return {"tokens": r.integers(
+        0, VOCAB, (engine.config.train_batch_size, 33)).astype(np.int32)}
+
+
+def compile_step(name, **extra):
+    """(the engine, the optimized text of the step `name` builds)."""
+    engine = build(name, **extra)
+    batch = batch_of(engine)
+    if name.startswith("zoadam"):
+        sb = engine.shard_batch(engine._reshape_gas(batch),
+                                leading_accum_dim=True)
+        with jax.sharding.set_mesh(engine.mesh):
+            step = engine._build_zoadam_step(name.split("-")[1])
+            return engine, step.lower(engine.state, sb).compile().as_text()
+    # 1-bit Adam compiles its compressed-momentum step once the warm-up
+    # (freeze_step 1) is over
+    for _ in range(2 if name == "onebit" else 1):
+        engine.train_batch(batch)
+    return engine, engine._train_compiled.as_text()
+
+
+@functools.lru_cache(maxsize=None)
+def compiled(name):
+    return compile_step(name)
+
+
+def own_instructions(text):
+    """name -> path of the instructions the program itself wrote that
+    compute something: an `op_name` under `jit(...)` (a parameter's is
+    its key path, a reduction's region has a bare one)."""
+    return {n: p for n, p in hlo_scope_map(text).items()
+            if p.startswith("jit(") and not n.startswith(TRIVIAL)}
+
+
+@pytest.mark.parametrize("name", BUILDERS)
+def test_the_step_holds_its_scopes(name):
+    _, _, _, want, floor = BUILDERS[name]
+    _, text = compiled(name)
+    paths = {n: scope_path(p, ALL) for n, p in own_instructions(text).items()}
+    found = {s for path in paths.values() for s in path}
+    assert want <= found, sorted(want - found)
+    # the optimizer is outside the model, and no new scope is an
+    # ancestor of the model's six but `layer_stack`
+    for n, path in paths.items():
+        if OPTIMIZER in path:
+            assert not set(path) & set(MODEL_SCOPES), (n, path)
+        inside = [s for s in path if s in MODEL_SCOPES]
+        if inside:
+            before = path[:path.index(inside[0])]
+            assert set(before) <= {LAYER_STACK}, (n, path)
+    named = sum(1 for path in paths.values() if path)
+    assert named / len(paths) >= floor, (named, len(paths))
+
+
+def canonical(text):
+    """The optimized text without what labels it: metadata, the table
+    of source locations, and the numbers XLA gives its names (renamed
+    in order of appearance)."""
+    text = re.sub(r",? ?metadata=\{[^}]*\}", "", text)
+    text = "\n".join(
+        line for line in text.splitlines()
+        if not re.match(r"^(FileNames|FunctionNames|FileLocations|StackFrames"
+                        r"|\d+ [\"{])", line))
+    names = {}
+    return re.sub(r"%[\w.\-]+",
+                  lambda m: names.setdefault(m.group(0), f"%{len(names)}"),
+                  text)
+
+
+@pytest.mark.parametrize(
+    "name", ["plain", "fp16", "gas2", "zero3", "pipelined", "onebit",
+             "zoadam-local"])
+def test_the_scopes_are_metadata_alone(name, monkeypatch):
+    """Rule (b): the step with every `jax.named_scope` a null context
+    (the model's six too) is the same optimized program."""
+    _, with_scopes = compiled(name)
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    _, without = compile_step(name)
+    assert not any(scope_path(p, ALL)
+                   for p in hlo_scope_map(without).values())
+    assert "metadata=" in with_scopes
+    assert canonical(with_scopes) == canonical(without)
+
+
+def test_the_program_gained_no_switch():
+    """No flag, config key or environment variable turns the scopes or
+    the manifest off: the names are in one tuple beside the facility."""
+    import inspect
+
+    from deepspeed_tpu.config import config as C
+    from deepspeed_tpu.runtime import engine as E
+
+    assert TRAIN_STEP_SCOPES == (PARAM_CAST, GRAD_REDUCE, GRAD_CLIP,
+                                 OPTIMIZER, ZERO_GATHER, LAYER_STACK)
+    assert not set(TRAIN_STEP_SCOPES) & set(MODEL_SCOPES)
+    src = inspect.getsource(E) + inspect.getsource(C)
+    for word in ("named_scope", "scopes", "manifest"):
+        assert not re.search(rf"(environ|getenv)[^\n]*{word}", src, re.I)
+        assert not re.search(rf"^\s+\w*{word}\w*\s*:\s*bool", src,
+                             re.I | re.M)
+
+
+@pytest.mark.parametrize("name", ["plain", "zero1", "zero3", "pipelined",
+                                  "onebit"])
+def test_the_manifest_is_the_compiled_steps_collectives(name):
+    engine, text = compiled(name)
+    man = engine.collective_manifest()
+    assert man == collective_manifest(text)
+    # the totals collective_volumes reads off the same text
+    assert man["kinds"] == collective_volumes(engine._train_compiled)
+    assert sum(b for _, _, b in man["sites"]) == sum(
+        v["bytes"] for v in man["kinds"].values())
+    for site, kind, nbytes in man["sites"]:
+        assert re.search(rf"%{re.escape(site)} = ", text), site
+        assert kind in man["kinds"] and nbytes > 0
+    ids = manifest_ids(man)
+    assert ids["sites"] == ",".join(f"{n}:{k}:{b}" for n, k, b in man["sites"])
+    if name == "plain":
+        assert man["sites"] == [] and ids["sites"] == ""
+        assert ids["all_gather_n"] == ids["all_reduce_n"] == 0
+    else:
+        assert ids["all_gather_n"] + ids["all_reduce_n"] > 0
+    assert len(ids["sites"]) < 4096
+
+
+def test_the_manifest_is_a_span_whatever_the_comms_logger_says():
+    """`train.compile.collectives`, always kept, a child of
+    `train.compile`, with the engine's table as ids; the logger's flag
+    only decides whether ITS summary holds the step."""
+    assert not comms_logger.enabled
+    profiler.clear()
+    engine, _ = compile_step("zero2")
+    spans = profiler.spans()
+    parent = [s for s in spans if s.name == "train.compile"]
+    got = [s for s in spans if s.name == "train.compile.collectives"]
+    assert len(parent) == len(got) == 1
+    assert got[0].parent == parent[0].sid
+    assert parent[0].t0_ns <= got[0].t0_ns and got[0].t1_ns <= parent[0].t1_ns
+    want = manifest_ids(engine.collective_manifest())
+    assert {k: got[0].ids[k] for k in want} == want
+    assert want["all_gather_n"] > 0
+    assert comms_logger.summary() == {}
+    # the engine sets the logger from its config: with the flag on, the
+    # same table is also in the logger's summary
+    try:
+        engine2, _ = compile_step("zero2", comms_logger={"enabled": True})
+        kinds = engine2.collective_manifest()["kinds"]
+        assert {k: v["count"] for k, v in comms_logger.summary().items()} \
+            == {f"{k}@hlo": v["count"] for k, v in kinds.items()}
+    finally:
+        comms_logger.configure(enabled=False)
+        comms_logger.reset()
+
+
+def test_a_hand_made_fusion_is_booked_to_its_caller():
+    text = """HloModule m, is_scheduled=true
+
+%fused_ar (p: f32[64]) -> f32[16] {
+  %p = f32[64]{0} parameter(0)
+  %all-reduce.9 = f32[64]{0} all-reduce(%p), replica_groups={{0,1,2,3}}, to_apply=%add
+  ROOT %slice.1 = f32[16]{0} slice(%all-reduce.9), slice={[0:16]}
+}
+
+%body (t: (f32[64])) -> (f32[64]) {
+  %t = (f32[64]{0}) parameter(0)
+  %g = f32[64]{0} get-tuple-element(%t), index=0
+  %all-gather.3 = f32[256]{0} all-gather(%g), dimensions={0}
+  %fusion.7 = f32[16]{0} fusion(%g), kind=kCustom, calls=%fused_ar
+  ROOT %r = (f32[64]{0}) tuple(%g)
+}
+
+ENTRY %main (a: f32[64]) -> f32[64] {
+  %a = f32[64]{0} parameter(0)
+  %w = (f32[64]{0}) while(%a), condition=%cond, body=%body
+  ROOT %o = f32[64]{0} get-tuple-element(%w), index=0
+}
+"""
+    man = collective_manifest(text)
+    assert sorted(man["sites"]) == [("all-gather.3", "all-gather", 1024),
+                                    ("fusion.7", "all-reduce", 256)]
+    assert man["in_fusion"] == {"count": 1, "bytes": 256}
+    assert man["kinds"]["all-reduce"] == {"count": 1, "bytes": 256}
+
+
+# -- AOT for a described v5e:2x2 ---------------------------------------------
+
+@pytest.fixture(scope="module")
+def v5e_mesh():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - whatever keeps libtpu away
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    return jax.sharding.Mesh(np.array(topo.devices).reshape(4), ("data",))
+
+
+def test_on_the_chip_a_gradients_all_reduce_sits_in_a_fusion(v5e_mesh):
+    """What no scope can catch: the gradient of a weight gathered from
+    its ZeRO shards is all-reduced whole and sliced, and the TPU
+    compiler fuses the two into one `fusion.<n>` (an
+    `all-reduce-scatter` computation) whose own name is no
+    collective's. The manifest books the bytes to that name."""
+    mesh = v5e_mesh
+    shard, rep = NamedSharding(mesh, P("data")), NamedSharding(mesh, P())
+    w = jax.ShapeDtypeStruct((4096, 1024), jnp.bfloat16, sharding=shard)
+    x = jax.ShapeDtypeStruct((64, 4096), jnp.bfloat16, sharding=shard)
+
+    def step(w, x):
+        def loss(w):
+            with jax.named_scope(ZERO_GATHER):
+                full = jax.lax.with_sharding_constraint(w, rep)
+            return jnp.sum(jnp.square((x @ full).astype(jnp.float32)))
+
+        g = jax.grad(loss)(w)
+        with jax.named_scope(GRAD_REDUCE):
+            return jax.lax.with_sharding_constraint(g, shard)
+
+    text = jax.jit(step).lower(w, x).compile().as_text()
+    man = collective_manifest(text)
+    gathers = [s for s in man["sites"] if s[1] == "all-gather"]
+    assert gathers and all(s[0].startswith("all-gather") for s in gathers)
+    fused = [s for s in man["sites"]
+             if s[1] in ("all-reduce", "reduce-scatter")
+             and not s[0].startswith(s[1])]
+    assert fused and all(s[0].startswith("fusion") for s in fused), man
+    assert man["in_fusion"]["count"] == len(fused)
+    assert man["in_fusion"]["bytes"] == sum(s[2] for s in fused)
+    # the fusion's instruction is in the text under that very name, and
+    # it calls the computation that holds the collective
+    for name, _, _ in fused:
+        assert re.search(rf"%{re.escape(name)} = [^\n]* fusion\([^\n]*calls=",
+                         text)
+
+
+# -- the operator's reader, the one clock, the docs --------------------------
+
+def test_the_measured_profile_reads_the_same_names(tmp_path, capsys):
+    """profiling/latency.py on the CPU lane: the train step's rows are
+    there with time in them, a nested scope is reported under its
+    parent, `coverage` is the share inside ANY scope (above what the
+    model's scopes alone cover on the same trace), and the manifest's
+    kinds follow with their bytes and rate."""
+    from deepspeed_tpu.profiling import latency
+
+    engine = build("zero1")
+    batch = batch_of(engine)
+    trace_dir = str(tmp_path / "tr")
+    m = latency.measure_module_latency(engine, batch, trace_dir, steps=2)
+    rows = {b for b in m["fwd"] if m["fwd"][b] + m["bwd"].get(b, 0.0) > 0}
+    assert {"attention", "mlp", OPTIMIZER, GRAD_CLIP} <= rows, rows
+    assert LAYER_STACK in rows  # the scan's own slicing, nothing inside it
+    assert any(" > " in b for b in rows), rows
+    assert not any(b.startswith(LAYER_STACK + " > ") for b in rows), rows
+    parts = sum(m["fwd"].values()) + sum(m["bwd"].values()) + m["other"]
+    np.testing.assert_allclose(parts, m["total"], rtol=1e-6)
+    old = latency.attribute_trace(
+        trace_dir, engine._train_compiled.as_text(),
+        buckets=("attention", "mlp", "norm1", "norm2", "embed", "lm_head"),
+        steps=2)
+    assert m["coverage"] > old["coverage"] + 0.05, (m["coverage"], old["coverage"])
+    assert m["coverage"] > 0.9
+    man = engine.collective_manifest()
+    assert set(m["collectives"]) == set(man["kinds"])
+    gathers = m["collectives"]["all-gather"]
+    assert gathers["sites"] == man["kinds"]["all-gather"]["count"]
+    assert gathers["bytes"] >= man["kinds"]["all-gather"]["bytes"]
+    latency.print_measured_profile(m)
+    out = capsys.readouterr().out
+    for word in (OPTIMIZER, GRAD_CLIP, "all-gather:", "MB a step", "coverage"):
+        assert word in out, word
+
+
+def test_one_clock_for_a_steps_host_numbers():
+    """The log's `samples/s` (ThroughputTimer) and `time: step=`
+    (BATCH_TIMER) are booked from the same phase stamps."""
+    from deepspeed_tpu.utils.timers import BATCH_TIMER
+
+    engine = build("plain")
+    batch = batch_of(engine)
+    for _ in range(5):
+        engine.train_batch(batch)
+    steps = engine.timers(BATCH_TIMER)._record
+    assert len(steps) == engine.tput.global_step_count == 5
+    skipped = engine.tput.start_step
+    assert engine.tput.total_elapsed_time == pytest.approx(
+        sum(steps[skipped:]), rel=1e-12)
+    assert engine.tput.avg_samples_per_sec == pytest.approx(
+        engine.config.train_batch_size * (5 - skipped) / sum(steps[skipped:]))
+    assert not hasattr(engine.tput, "start")
+
+
+def test_the_docs_name_every_scope_and_id():
+    import pathlib
+
+    doc = (pathlib.Path(__file__).resolve().parents[1]
+           / "docs" / "tracing.md").read_text()
+    assert "## Device scopes of the train step" in doc
+    for scope in TRAIN_STEP_SCOPES + MODEL_SCOPES:
+        assert f"`{scope}`" in doc, scope
+    ids = manifest_ids({"kinds": {}, "in_fusion": {"count": 0, "bytes": 0},
+                        "sites": []})
+    for key in ids:
+        assert f"`{key}`" in doc or f"`{key.rsplit('_', 1)[0]}_*`" in doc, key
+    for name in ("train.compile.collectives", "engine.collective_manifest()",
+                 "ThroughputTimer"):
+        assert name in doc, name

@@ -179,10 +179,10 @@ _HOT_FN_PREFIXES = (
     # boundary guard runs once per stage per dispatch
     "pipeline_apply", "partition_layers", "unpartition_layers",
     "stage_slice_keys", "pipe_permute_tick", "simulate_schedule",
-    # comm/compute overlap layer (runtime/overlap.py): the prefetch
-    # scan, bucket launcher, and barrier pins trace into every
+    # comm/compute overlap layer (runtime/overlap.py): the layer
+    # gather, bucket launcher, and barriers trace into every
     # overlap-on training step
-    "scan_with_prefetch", "make_prefetch_gather", "bucketed_apply",
+    "make_prefetch_gather", "bucketed_apply",
     "bucket_partition", "overlap_stats",
 )
 _SYNC_CALLS = ("block_until_ready", "device_get")

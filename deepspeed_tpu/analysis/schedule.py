@@ -222,10 +222,12 @@ def _window_cost(weights: List[float], prefix: List[float],
 # ops that FORWARD a value without executing on it: a consumer of this
 # kind does not end a collective's slack window — the window runs on to
 # the first consumer that does real work. optimization_barrier is the
-# load-bearing member: the overlap layer (runtime/overlap.py) pins a
-# prefetched gather's issue slot with a barrier, and the barrier must
-# not read as the gather's "consumer" or every pinned collective would
-# measure zero slack
+# load-bearing member: the overlap layer (runtime/overlap.py
+# bucketed_apply, runtime/pipe.py) orders a collective against compute
+# with a barrier, and the barrier must not read as the collective's
+# "consumer" or every such collective would measure zero slack. (A
+# projection: on the chip a barrier's outputs wait for ALL its inputs,
+# so the slack credited across one is not paid out — docs/overlap.md.)
 _TUPLING_OPS = frozenset(("tuple", "opt-barrier", "optimization-barrier"))
 
 # layout/dtype packaging: ops (and all-packaging fusions) that XLA's

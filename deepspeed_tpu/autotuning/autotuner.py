@@ -148,8 +148,9 @@ class Autotuner:
             }
         # comm/compute overlap knobs (runtime/overlap.py, docs/overlap.md):
         # overlap=False builds the serialized twin (collectives scored at
-        # full wire time), prefetch_depth sizes the scan-carried gather
-        # pipeline, bucket_mb the reduce-scatter launch granularity.
+        # full wire time), prefetch_depth 0 / >= 1 turns the ZeRO-3
+        # in-body layer gather off / on (the depth itself is unused),
+        # bucket_mb is the reduce-scatter launch granularity.
         if cand.get("overlap") is not None:
             cfg.setdefault("zero_optimization", {})["overlap_comm"] = \
                 bool(cand["overlap"])
@@ -282,8 +283,8 @@ class Autotuner:
 
         prefetch_depths / bucket_mbs: the comm/compute-overlap knobs
         (runtime/overlap.py, docs/overlap.md) as two more axes —
-        prefetch_depth sizes the ZeRO-3 scan-carried gather pipeline,
-        bucket_mb the reduce-scatter launch granularity. Both change
+        prefetch_depth 0 / >= 1 turns the ZeRO-3 in-body layer gather
+        off / on, bucket_mb is the reduce-scatter launch granularity. Both change
         WHERE collectives land in the compiled schedule, and the S009
         projection's slack-credit model prices exactly that, so the
         overlapped candidate outranks its serialized twin without

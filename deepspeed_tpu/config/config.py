@@ -67,19 +67,21 @@ class ZeroConfig(ConfigModel):
     offload_optimizer: OffloadConfig = Field(default_factory=OffloadConfig)
     offload_param: OffloadConfig = Field(default_factory=OffloadConfig)
     # Comm/compute overlap master switch (runtime/overlap.py,
-    # docs/overlap.md): scan-carried ZeRO-3 parameter prefetch, bucketed
-    # gradient reduce-scatter launches, pipeline permute overlap, and the
-    # schedule analyzer's latency-hiding credit. false = the serialized
-    # twin ds_schedule commits (every collective modeled fully exposed,
-    # no prefetch/bucket restructure) — the reference's overlap_comm
+    # docs/overlap.md): the ZeRO-3 layer gather inside the layer body,
+    # bucketed gradient reduce-scatter launches, pipeline permute
+    # overlap, and the schedule analyzer's latency-hiding credit. false =
+    # the serialized twin ds_schedule commits (every collective modeled
+    # fully exposed, no in-body gather / bucket restructure) — the reference's overlap_comm
     # semantics (ref: stage_1_and_2.py overlap_comm reduction during bwd).
     overlap_comm: bool = True
-    # How many layers ahead the scanned stack's gathered-weights buffer
-    # runs (ref: partitioned_param_coordinator.py fetch_sub_module +
-    # stage3_prefetch_bucket_size's look-ahead role). 0 disables the
-    # prefetch restructure (per-use gathers at the consumer); >=1 carries
-    # that many gathered layer buffers through the scan. tune_aot
-    # searches this axis.
+    # 0 leaves the ZeRO-3 layer gathers to the partitioner (per-use
+    # gathers at the consumer); >= 1 gathers each layer's shards inside
+    # that layer's body (runtime/overlap.py). Nothing reads the depth
+    # itself since the scan stopped carrying gathered buffers ahead
+    # (the barrier that pinned them made every gather synchronous on the
+    # chip: docs/overlap.md); the key keeps parsing (ref:
+    # partitioned_param_coordinator.py fetch_sub_module's look-ahead)
+    # and tune_aot still enumerates it.
     prefetch_depth: int = 1
     # Gradient reduce-scatter launch-group size in MiB (ref:
     # stage_1_and_2.py reduce_bucket_size IPG buckets). 0 = one

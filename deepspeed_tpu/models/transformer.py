@@ -953,13 +953,14 @@ def _causal_attention(q, k, v, use_flash, alibi=None, **kw):
                          axis_names=set(free), check_vma=False)(*args)
 
 
-def _layer_prefetch(cfg: TransformerConfig):
-    """(gather_apply, depth) for the scanned layer stack when the
-    engine's ambient overlap plan carries prefetch specs
-    (runtime/overlap.py — training traces under zero-3 overlap_comm),
-    else None: eval/generation forwards, pipelined stacks (the permute
-    path overlaps instead), and per-period window patterns stay on the
-    plain scan."""
+def _layer_gather(cfg: TransformerConfig):
+    """The ZeRO-3 gather of one layer's store slices
+    (runtime/overlap.py make_prefetch_gather) when the engine's ambient
+    overlap plan carries layer specs (training traces under zero-3
+    overlap_comm), else None: eval/generation forwards, pipelined
+    stacks and per-period window patterns leave the gathers to the
+    partitioner. The layer body applies it to its own slice, inside
+    what jax.checkpoint wraps (_make_layer_body)."""
     if cfg.pipeline_stages > 1 or cfg.attention_window_pattern is not None:
         return None
     from ..runtime.overlap import current_plan, make_prefetch_gather
@@ -971,18 +972,17 @@ def _layer_prefetch(cfg: TransformerConfig):
     mesh = jax.sharding.get_abstract_mesh()
     if mesh.empty or mesh.manual_axes:
         return None  # partial-manual shard_map traces keep per-use gathers
-    return (make_prefetch_gather(plan.layer_store_specs,
-                                 plan.layer_tp_specs, plan.mesh),
-            plan.prefetch_depth)
+    return make_prefetch_gather(plan.layer_store_specs,
+                                plan.layer_tp_specs, plan.mesh)
 
 
 def _layer_scan(scan, *args):
-    """`scan(*args)` over a stack of layers (`jax.lax.scan`,
-    runtime/overlap.py's `scan_with_prefetch`, or runtime/pipe.py's
-    loop over microbatch slots around a stage's) under the device scope
-    `layer_stack`: the loop's own slicing of stacked leaves and
-    activations and its control-flow copies, forward, recomputation
-    and backward, around the model's layer scopes (docs/tracing.md)."""
+    """`scan(*args)` over a stack of layers (`jax.lax.scan`, or
+    runtime/pipe.py's loop over microbatch slots around a stage's)
+    under the device scope `layer_stack`: the loop's own slicing of
+    stacked leaves and activations and its control-flow copies,
+    forward, recomputation and backward, around the model's layer
+    scopes (docs/tracing.md)."""
     with jax.named_scope(LAYER_STACK):
         return scan(*args)
 
@@ -1223,11 +1223,19 @@ def _wants_rng(cfg: TransformerConfig) -> bool:
 
 
 def _make_layer_body(cfg: TransformerConfig, use_rng: bool, positions=None,
-                     pld_theta=None, window: Optional[int] = None):
+                     pld_theta=None, window: Optional[int] = None,
+                     gather=None):
     """One transformer layer as a scan body (shared by the flat
     scan-over-layers path, the pipelined per-stage path, and the
     random-LTD subset segment — which passes the subset's original
     `positions`).
+
+    gather: `_layer_gather`'s ZeRO-3 gather of the layer's own store
+    slices (docs/overlap.md). It runs here, inside what the remat modes
+    below wrap and with no barrier around it: the scan's xs stay store
+    slices, the backward pass gathers again instead of reading a saved
+    gathered stack, and the TPU compiler is free to start each gather
+    under the matmuls ahead of its consumer.
 
     pld_theta: traced scalar — Progressive Layer Dropping (ref:
     runtime/progressive_layer_drop.py, arXiv 2010.13369). Each layer is
@@ -1245,6 +1253,8 @@ def _make_layer_body(cfg: TransformerConfig, use_rng: bool, positions=None,
         else:
             h0, lp = carry, xs
             r1 = r2 = None
+        if gather is not None:
+            lp = gather(lp)
 
         def run(h0):
             # named scopes land in every HLO op's metadata op_name, so
@@ -1372,7 +1382,11 @@ def forward_hidden(
     if rng is None:
         pld_theta = None  # eval: keep every layer
     use_rng = rng is not None and (_wants_rng(cfg) or pld_theta is not None)
-    layer_body = _make_layer_body(cfg, use_rng, pld_theta=pld_theta)
+    # ZeRO-3 under an overlap plan: each layer gathers its own shards
+    # inside its body (runtime/overlap.py, docs/overlap.md)
+    gather = _layer_gather(cfg)
+    layer_body = _make_layer_body(cfg, use_rng, pld_theta=pld_theta,
+                                  gather=gather)
 
     layers = params["layers"]
     if cfg.pipeline_stages > 1:
@@ -1395,30 +1409,6 @@ def forward_hidden(
         else:
             xs = lp
         return _layer_scan(jax.lax.scan, body, x_in, xs)
-
-    _prefetch = _layer_prefetch(cfg)
-    if _prefetch is not None:
-        # ZeRO-3 parameter prefetch (runtime/overlap.py,
-        # docs/overlap.md): the scan carries a gathered-weights buffer
-        # so layer i+depth's shard all-gather issues under layer i's
-        # compute instead of at its own consumer
-        from ..runtime.overlap import scan_with_prefetch
-
-        _gather_fn, _depth = _prefetch
-
-        def seg(x_in, lo, hi, body):  # noqa: F811 — prefetch scan
-            lp = jax.tree.map(lambda t: t[lo:hi], layers)
-            if pld_theta is not None:
-                rest = (layer_rngs[lo:hi],
-                        jnp.arange(lo, hi, dtype=jnp.float32))
-            elif use_rng:
-                rest = (layer_rngs[lo:hi],)
-            else:
-                rest = ()
-            pack = ((lambda w, r: (w,) + tuple(r)) if rest
-                    else (lambda w, r: w))
-            return _layer_scan(scan_with_prefetch, body, x_in, lp, rest,
-                               pack, _gather_fn, _depth)
 
     if cfg.attention_window_pattern is not None:
         # GPT-Neo-class per-layer windows: the window is STATIC in each
@@ -1466,7 +1456,7 @@ def forward_hidden(
         x, aux1 = seg(x, 0, a, layer_body)
         h_sub = jnp.take_along_axis(x, ltd_idx[..., None], axis=1)
         sub_body = _make_layer_body(cfg, use_rng, positions=ltd_idx,
-                                    pld_theta=pld_theta)
+                                    pld_theta=pld_theta, gather=gather)
         h_sub, aux2 = seg(h_sub, a, b, sub_body)
         x = x.at[jnp.arange(B)[:, None], ltd_idx].set(h_sub)
         x, aux3 = seg(x, b, cfg.n_layers, layer_body)

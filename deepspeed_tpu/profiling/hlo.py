@@ -63,6 +63,13 @@ _GROUPS_IOTA_FULL_RE = re.compile(
 _GROUPS_ALL_EXPLICIT_RE = re.compile(
     r"replica_groups=\{(?P<body>\{[\d,]*\}(?:,\{[\d,]*\})*)\}")
 _PAIRS_RE = re.compile(r"source_target_pairs=\{(?P<body>[^}]*(?:\},\{[^}]*)*)\}")
+# the TPU compiler's async collective fusion: ONE collective becomes a
+# chain of instructions of its kind that share a `chain_id`, the first
+# inside the fusion named `async-collective-start.<n>`, the last inside
+# `async-collective-done.<n>`, any between inside the compute fusions
+# it runs beside
+_CHAIN_RE = re.compile(r'chain_id="(?P<id>\d+)"')
+_CHANNEL_RE = re.compile(r"channel_id=(?P<id>\d+)")
 
 
 _ASYNC_CALLS_RE = re.compile(
@@ -250,21 +257,34 @@ def parse_hlo_collectives(hlo_text: str) -> List[Dict]:
     Each record additionally carries the operand payload (`operand_bytes`,
     summed over the shapes inside the call parens) and the replica-group
     size (`group_size`, 0 when unstated/flat) — the inputs the costmodel's
-    per-link volume math needs — and the instruction's `name` (what
-    collective_manifest resolves to a site).
+    per-link volume math needs — the instruction's `name` (what
+    collective_manifest resolves to a site) and `async`: whether the
+    compiler left it asynchronous (a `-start` op, or a member of an
+    async-collective-fusion chain).
 
     Async pairs count ONCE: `-done` ops never match (the op alternation
-    requires an opening paren right after the collective kind), and when
+    requires an opening paren right after the collective kind), when
     a `-start` op carries a `calls=` computation (async sugar printed
     alongside its wrapped body) the body's inner collective is skipped —
-    only the start site contributes bytes. A collective inside a fusion
-    or while-loop body has no start site and IS attributed (once, like
-    every other instruction — trip counts are not statically known)."""
+    only the start site contributes bytes — and of the instructions that
+    share a `chain_id` (one collective the TPU compiler runs as an
+    async-collective-start / -done chain of fusions) the first in the
+    text stands for all. A collective inside a fusion or while-loop
+    body has no start site and IS attributed (once, like every other
+    instruction — trip counts are not statically known)."""
     skip_spans = _async_wrapped_spans(hlo_text)
+    chains = set()
     out = []
     for m in _INSTR_RE.finditer(hlo_text):
         if any(lo <= m.start() < hi for lo, hi in skip_spans):
             continue  # body of an already-counted async -start wrapper
+        chain = _CHAIN_RE.search(m.group("tail"))
+        if chain is not None:
+            channel = _CHANNEL_RE.search(m.group("tail"))
+            key = (chain.group("id"), channel and channel.group("id"))
+            if key in chains:
+                continue  # a later link of a chain already counted
+            chains.add(key)
         is_start = m.group("op").endswith("-start")
         op = m.group("op").replace("-start", "")
         result = m.group("result")
@@ -285,7 +305,8 @@ def parse_hlo_collectives(hlo_text: str) -> List[Dict]:
         out.append({"op": op, "bytes": nbytes, "dtypes": dtypes,
                     "operand_bytes": operand_bytes,
                     "group_size": _group_size(tail),
-                    "name": _name_before(hlo_text, m.start())})
+                    "name": _name_before(hlo_text, m.start()),
+                    "async": is_start or chain is not None})
     return out
 
 
@@ -698,9 +719,12 @@ def collective_manifest(hlo_text: str) -> Dict:
     {"kinds": {op: {count, bytes}} (collective_volumes' table: static
     sites, one execution each), "in_fusion": {count, bytes} (the
     collectives whose site is not themselves), "sites": [(name, op,
-    bytes)]}. A site is the top-level instruction of the entry
-    computation or of a loop body or branch that IS a collective or
-    CONTAINS one: a collective in a fused computation is booked to the
+    bytes)], "async": {op: count} (the collectives the compiler left
+    asynchronous: a `-start` / `-done` pair, or an
+    `async-collective-start` / `-done` chain of fusions, booked once to
+    its start; a kind with none is absent)}. A site is the top-level
+    instruction of the entry computation or of a loop body or branch
+    that IS a collective or CONTAINS one: a collective in a fused computation is booked to the
     `fusion.<n>` that calls it, which is the name its time carries in
     a device trace (a trace's own names tell only the collectives that
     stand alone). Several collectives under one site and kind add up."""
@@ -724,29 +748,34 @@ def collective_manifest(hlo_text: str) -> Dict:
 
     sites: Dict[Tuple[str, str], int] = {}
     in_fusion = {"count": 0, "bytes": 0}
+    n_async: Dict[str, int] = {}
     for rec in records:
         site = site_of(rec["name"])
         if site != rec["name"]:
             in_fusion["count"] += 1
             in_fusion["bytes"] += rec["bytes"]
+        if rec["async"]:
+            n_async[rec["op"]] = n_async.get(rec["op"], 0) + 1
         key = (site, rec["op"])
         sites[key] = sites.get(key, 0) + rec["bytes"]
     return {"kinds": _volumes(records), "in_fusion": in_fusion,
             "sites": [(name, op, nbytes)
-                      for (name, op), nbytes in sites.items()]}
+                      for (name, op), nbytes in sites.items()],
+            "async": n_async}
 
 
 def manifest_ids(manifest: Dict) -> Dict[str, object]:
     """The manifest as span ids (`train.compile.collectives`,
-    docs/tracing.md): `<kind>_n` / `<kind>_bytes` per kind with `-` as
-    `_`, `in_fusion_n` / `in_fusion_bytes`, and `sites`, one compact
-    string of `name:kind:bytes` joined by commas."""
+    docs/tracing.md): `<kind>_n` / `<kind>_bytes` / `<kind>_async_n`
+    per kind with `-` as `_`, `in_fusion_n` / `in_fusion_bytes`, and
+    `sites`, one compact string of `name:kind:bytes` joined by commas."""
     ids: Dict[str, object] = {}
     for kind in MANIFEST_KINDS:
         v = manifest["kinds"].get(kind, {"count": 0, "bytes": 0})
         key = kind.replace("-", "_")
         ids[f"{key}_n"] = int(v["count"])
         ids[f"{key}_bytes"] = int(v["bytes"])
+        ids[f"{key}_async_n"] = int(manifest["async"].get(kind, 0))
     ids["in_fusion_n"] = int(manifest["in_fusion"]["count"])
     ids["in_fusion_bytes"] = int(manifest["in_fusion"]["bytes"])
     ids["sites"] = ",".join(f"{n}:{k}:{b}" for n, k, b in manifest["sites"])

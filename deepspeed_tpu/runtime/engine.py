@@ -737,8 +737,8 @@ class DeepSpeedTPUEngine:
     def _overlap_plan(self):
         """The OverlapPlan this engine traces its loss under, or None
         when zero_optimization.overlap_comm is false (the serialized
-        twin). Prefetch specs (the `layers` subtrees of the storage/TP
-        spec trees) ride along only where the scan-carried gather
+        twin). Layer specs (the `layers` subtrees of the storage/TP
+        spec trees) ride along only where the layer body's own gather
         applies: a flat (non-pipelined) scanned stack under ZeRO-3,
         with the weight tree not already gathered up front by qwZ /
         compression transforms."""
@@ -798,7 +798,7 @@ class DeepSpeedTPUEngine:
         pld = cfg.progressive_layer_drop
         # comm/compute overlap (runtime/overlap.py): the plan rides an
         # ambient scope around the loss trace — forward_hidden picks up
-        # the prefetch specs, runtime/pipe.py the permute reorder
+        # the layer specs, runtime/pipe.py the permute reorder
         plan = self._overlap_plan()
         loss_fn = overlap.scoped_loss(self._remat_wrapped_loss_fn(), plan)
         bucket_mb = plan.bucket_mb if plan is not None else 0.0

@@ -9,36 +9,55 @@ current one computes) and the overlap_comm bucketed gradient reduction
 backward). On TPU both collapse into *where the collective sits on the
 XLA schedule* relative to its first consumer:
 
-  prefetch   — the scanned layer stack carries a gathered-weights
-               double buffer: iteration i issues the all-gather for
-               layer i+prefetch_depth's zero-sharded shards, pinned
-               (optimization_barrier) to the slot UNDER layer i's
-               compute (scan_with_prefetch). The gather's first real
-               consumer is one scan iteration away, so the latency-
-               hiding scheduler spans it with the whole layer body.
+  gather     — under ZeRO-3 the scanned layer stack scans over the
+               STORE slices and gathers layer i's shards INSIDE layer
+               i's body, inside the function jax.checkpoint wraps
+               (make_prefetch_gather, applied by models/transformer's
+               layer body). The scan carries activations only; the
+               backward pass re-gathers (ZeRO-3's own semantics), so no
+               gathered leaf is a scan residual. Nothing pins the
+               gather: the TPU compiler's async collective fusion
+               starts each gather of a body under the matmuls that
+               precede its consumer, all but the first of a pass.
   bucketing  — gradient reduce-scatters launch in bucket_mb-sized
-               groups, software-pipelined: bucket j+1's scatters are
-               barrier-pinned to issue before bucket j's accumulate/
-               scale compute (bucketed_apply), instead of one
-               serialized constraint wall at the accumulation
-               boundary.
+               groups: bucket j+1's scatters are ordered (barrier)
+               before bucket j's accumulate/scale compute
+               (bucketed_apply), instead of one constraint wall at the
+               accumulation boundary. The barrier makes bucket j's
+               compute wait for bucket j+1's scatters to be DONE (see
+               below); no benchmark cell accumulates, so the chip has
+               not priced it (ROADMAP Q4).
   permute    — runtime/pipe.py issues the 1F1B boundary
                collective-permute right after the stage compute and
-               pins it ahead of the exit-collection bookkeeping, so
-               the hop rides under the next microbatch's work.
+               orders it (barrier) ahead of the exit-collection
+               bookkeeping.
 
 All three are LAYOUT/SCHEDULE rewrites only — the gathered values,
 grads, and stage hand-offs are the same arrays, so the canonical fp32
 loss trajectory is bitwise identical overlap-on vs overlap-off
-(tests/test_overlap.py pins this). The measured effect is the S007/
-S009 exposure drop that scripts/ds_schedule.py commits as regression
-pins (`overlap` keys in SCHEDULE.json).
+(tests/test_overlap.py pins this). scripts/ds_schedule.py commits the
+S007/S009 PROJECTION of the CPU-compiled step as regression pins
+(`overlap` keys in SCHEDULE.json); what the chip pays is read off the
+compiled step itself (`<kind>_async_n` of the collective manifest,
+profiling/hlo.py) and off a device trace (PERF.md).
+
+What `optimization_barrier` binds (the `barrier` below): its outputs
+exist once ALL its inputs exist. `a, b = barrier((a, b))` therefore
+makes every consumer of `b` wait until `a` is DONE, not until `a` has
+been issued — a collective on one side and the compute that should
+hide it on the other are serialised, and the TPU compiler turns the
+collective's start/done pair back into a synchronous instruction.
+Until PR 39 the layer gather was carried one iteration ahead through
+the scan and pinned this way; on the chip 35.86 of 36.17 ms of
+collectives a step ran with nothing beside them (PERF.md §6). No
+barrier may stand between a layer gather and that layer's compute.
 
 The engine activates the layer by entering `overlap_scope` around the
-loss trace (`zero_optimization.overlap_comm`, knobs `prefetch_depth` /
-`bucket_mb`); models and the pipeline runtime read the ambient plan at
-trace time — the same ambient-context discipline as
-jax.sharding.set_mesh.
+loss trace (`zero_optimization.overlap_comm`, knob `bucket_mb`;
+`prefetch_depth: 0` leaves the layer gathers to the partitioner, any
+other value means the in-body gather); models and the pipeline runtime
+read the ambient plan at trace time — the same ambient-context
+discipline as jax.sharding.set_mesh.
 """
 
 import contextlib
@@ -47,7 +66,6 @@ import dataclasses
 from typing import Any, Callable, List, Optional, Sequence
 
 import jax
-import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..utils.profiler import GRAD_REDUCE, ZERO_GATHER
@@ -58,7 +76,6 @@ __all__ = [
     "current_plan",
     "scoped_loss",
     "make_prefetch_gather",
-    "scan_with_prefetch",
     "bucket_partition",
     "bucketed_apply",
     "overlap_stats",
@@ -71,8 +88,8 @@ class OverlapPlan:
 
     layer_store_specs / layer_tp_specs are the `layers` subtrees of the
     engine's storage and TP spec trees (None when the model has no
-    scanned stack, the program is pipelined, or prefetch is off) —
-    forward_hidden slices them per layer to build the prefetch gather.
+    scanned stack, the program is pipelined, or prefetch_depth is 0) —
+    forward_hidden builds the layer body's gather from them.
     """
 
     mesh: Any
@@ -117,17 +134,17 @@ def scoped_loss(loss_fn: Callable, plan: Optional[OverlapPlan]) -> Callable:
 
 
 # ----------------------------------------------------------------------
-# differentiable issue-slot barrier
+# differentiable ordering barrier
 # ----------------------------------------------------------------------
 
 @jax.custom_vjp
 def barrier(xs):
     """jax.lax.optimization_barrier with a VJP (the primitive has no
     differentiation rule): backward barriers the cotangents at the
-    mirrored program point, so a forward issue-slot pin (gather before
-    layer compute) transposes to a backward ordering tie (scatter
-    cotangent joined with the activation cotangent). Values pass
-    through untouched in both directions — the pin is schedule-only."""
+    mirrored program point. Every output waits for EVERY input (module
+    docstring): it orders whole values, it cannot say "issued". Its two
+    users are bucketed_apply and runtime/pipe.py's boundary permute.
+    Values pass through untouched in both directions."""
     return jax.lax.optimization_barrier(xs)
 
 
@@ -150,7 +167,7 @@ barrier.defvjp(_barrier_fwd, _barrier_bwd)
 
 
 # ----------------------------------------------------------------------
-# ZeRO-3 parameter prefetch (scan-carried gathered-weights buffer)
+# ZeRO-3 layer gather (inside the layer body)
 # ----------------------------------------------------------------------
 
 def _drop_lead(spec: P, n: int) -> P:
@@ -163,19 +180,24 @@ def _drop_lead(spec: P, n: int) -> P:
 
 
 def make_prefetch_gather(store_specs, tp_specs, mesh, n_lead: int = 1):
-    """Per-leaf prefetch gather for a scanned layer stack.
+    """Per-leaf ZeRO-3 gather for one layer's slice of a scanned stack.
 
     For every zero-sharded stacked leaf (per-layer store slice differs
     from its TP/gathered slice), returns a custom-vjp function whose
     forward constrains the slice store→gathered — XLA emits the
-    all-gather at the constraint, which scan_with_prefetch pins one
-    iteration ahead of the consumer — and whose backward constrains the
+    all-gather at the constraint — and whose backward constrains the
     cotangent straight back to the store slice, so the grad
     reduce-scatter runs per layer INSIDE the backward scan instead of
     at the accumulation boundary (the make_qwz_gather discipline,
     runtime/zero.py, minus quantization). Leaves whose store slice
-    already equals the gathered slice (persistence-threshold params) or
-    whose stacking dim itself carries mesh axes pass through identity.
+    already equals the gathered slice (persistence-threshold params, a
+    one-chip mesh) or whose stacking dim itself carries mesh axes pass
+    through identity.
+
+    The caller applies it to the layer's own slice at the top of the
+    layer body, INSIDE whatever jax.checkpoint wraps: the scan's xs
+    stay store slices, the backward pass gathers again, and a gathered
+    leaf is a scan residual only under remat "none".
     """
 
     def leaf_fn(store_spec, tp_spec):
@@ -206,91 +228,13 @@ def make_prefetch_gather(store_specs, tp_specs, mesh, n_lead: int = 1):
         gather.defvjp(fwd, bwd)
         return gather
 
-    def pin_leaf_fn(store_spec, tp_spec):
-        lead = list(store_spec)[:n_lead]
-        if any(e is not None for e in lead):
-            return lambda w: w
-        s = _drop_lead(store_spec, n_lead)
-        g = _drop_lead(tp_spec, n_lead)
-        if s == g:
-            return lambda w: w
-
-        def pin_gathered(w):
-            with jax.named_scope(ZERO_GATHER):
-                return jax.lax.with_sharding_constraint(
-                    w, NamedSharding(mesh, g))
-
-        return pin_gathered
-
-    is_spec = lambda x: isinstance(x, P)  # noqa: E731
-    fns = jax.tree.map(leaf_fn, store_specs, tp_specs, is_leaf=is_spec)
-    pin_fns = jax.tree.map(pin_leaf_fn, store_specs, tp_specs,
-                           is_leaf=is_spec)
+    fns = jax.tree.map(leaf_fn, store_specs, tp_specs,
+                       is_leaf=lambda x: isinstance(x, P))
 
     def apply(w_slice):
         return jax.tree.map(lambda fn, w: fn(w), fns, w_slice)
 
-    def pin(w_gathered):
-        """Re-assert the gathered layout on a buffer crossing a scan
-        carry boundary. Without this the SPMD partitioner is free to
-        resolve the while-loop carry as the store slice — resharding
-        the gathered value down at the backedge and re-gathering at the
-        consumer, which silently undoes the prefetch (and doubles the
-        collective count)."""
-        return jax.tree.map(lambda fn, w: fn(w), pin_fns, w_gathered)
-
-    apply.pin = pin
     return apply
-
-
-def scan_with_prefetch(body, init, w_stack, rest, pack, gather, depth: int):
-    """jax.lax.scan over a layer stack with a gathered-weights
-    double buffer carried `depth` iterations ahead.
-
-    body(carry, xs) -> (carry, out) is the unmodified layer body;
-    `pack(w, rest_i)` rebuilds its xs from a gathered weight slice and
-    the non-weight xs slice (rngs / layer indices). Iteration i
-    consumes the gathered buffer for layer i from the carry and issues
-    `gather` on layer (i+depth) mod L's store slice; the
-    optimization_barrier ties that issue to the slot BEFORE layer i's
-    compute, so the all-gather sits a full layer body away from its
-    first real consumer — the slack window analysis/schedule.py
-    credits. The wrapped tail re-gathers the head layers into the
-    final carry unconsumed: one wasted gather per segment, the price
-    of a branch-free scan body (XLA dead-values them out of the
-    backward).
-    """
-    leaves = jax.tree.leaves(w_stack)
-    if not leaves:
-        raise ValueError("scan_with_prefetch needs a non-empty stack")
-    L = int(leaves[0].shape[0])
-    depth = max(1, min(int(depth), L))
-
-    def fetch(i):
-        return jax.tree.map(
-            lambda t: jax.lax.dynamic_index_in_dim(t, i, 0, keepdims=False),
-            w_stack)
-
-    pin = getattr(gather, "pin", lambda t: t)
-    bufs = tuple(gather(fetch(i)) for i in range(depth))
-
-    def body2(carry, xs):
-        x, bufs = carry
-        # every carry crossing re-asserts the gathered layout — see
-        # make_prefetch_gather.pin
-        bufs = tuple(pin(b) for b in bufs)
-        i, rest_i = xs
-        g_next = gather(fetch((i + depth) % L))
-        # issue-slot pin: the layer input now depends on the gather
-        # having been ISSUED (not consumed), so the scheduler cannot
-        # sink the collective down to its consumer next iteration
-        g_next, x = barrier((g_next, x))
-        y, out = body(x, pack(bufs[0], rest_i))
-        return (y, tuple(pin(b) for b in bufs[1:]) + (g_next,)), out
-
-    idxs = jnp.arange(L, dtype=jnp.int32)
-    (x_fin, _), outs = jax.lax.scan(body2, (init, bufs), (idxs, rest))
-    return x_fin, outs
 
 
 # ----------------------------------------------------------------------
@@ -325,11 +269,12 @@ def bucketed_apply(grads, grad_specs, mesh, bucket_mb: float,
     launch groups, software-pipelined against `consume`.
 
     Bucket j+1's reduce-scatters (the constraint to the ZeRO grad
-    layout, ref: stage_1_and_2.py:923 IPG buckets) are barrier-pinned
-    to issue BEFORE bucket j's consume compute (the accumulate add /
-    loss-scale multiply), so each launch group's wire time hides under
-    the previous group's arithmetic instead of serializing at the
-    accumulation boundary. consume(leaf_index, scattered_grad) maps
+    layout, ref: stage_1_and_2.py:923 IPG buckets) are ordered by a
+    barrier BEFORE bucket j's consume compute (the accumulate add /
+    loss-scale multiply), meant to hide each launch group's wire time
+    under the previous group's arithmetic instead of serializing at the
+    accumulation boundary (what the barrier really binds: module
+    docstring). consume(leaf_index, scattered_grad) maps
     each scattered leaf to its output (flatten order preserved).
     """
     from ..parallel import sharding as shd
@@ -353,9 +298,11 @@ def bucketed_apply(grads, grad_specs, mesh, bucket_mb: float,
     for b, group in enumerate(buckets):
         nxt = launch(buckets[b + 1]) if b + 1 < len(buckets) else None
         if nxt is not None:
-            # pin: the next bucket's scatters are issued before this
-            # bucket's consume compute runs (the barrier makes the
-            # consumed values depend on the issue, not the payloads)
+            # the next bucket's scatters are ordered before this
+            # bucket's consume compute. The barrier makes the consumed
+            # values wait for those scatters' PAYLOADS, not their issue
+            # (module docstring); left as it is until a cell that
+            # accumulates prices it (ROADMAP Q4)
             nxt, cur = barrier((nxt, cur))
             nxt, cur = list(nxt), list(cur)
         for j, g in zip(group, cur):

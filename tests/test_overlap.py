@@ -3,8 +3,9 @@
 The contract under test, from the ISSUE pins:
   - the restructure is LAYOUT-ONLY — canonical fp32 losses are bitwise
     identical overlap-on vs overlap-off;
-  - scan_with_prefetch computes exactly what a plain scan computes
-    (values and grads), for every prefetch depth;
+  - a scan whose body gathers its own ZeRO-3 slice computes what a
+    plain scan computes (values bitwise, grads to rounding), with and
+    without remat, and under remat no gathered leaf is a residual;
   - bucket_partition is a deterministic exact cover;
   - the analyzer credits the shapes the restructure produces (loop-
     carried wrap-around slack, tuple-index-aware barrier tracing,
@@ -30,19 +31,18 @@ from deepspeed_tpu.runtime.overlap import (
     make_prefetch_gather,
     overlap_scope,
     overlap_stats,
-    scan_with_prefetch,
 )
 
 VOCAB = 128
 
 
-def _flat_engine(overlap, bf16=False, **zero_kw):
+def _flat_engine(overlap, bf16=False, mesh=None, model=None, **zero_kw):
     # bf16=True is the canonical ds_budget train config (where the
     # overlap win is measured and pinned); bf16=False is the noiseless
     # fp32 path for the bitwise-identity invariant.
-    mcfg = T.TransformerConfig(
-        vocab_size=VOCAB, n_layers=2, n_heads=4, d_model=64, max_seq=32,
-        variant="llama", use_flash=False)
+    mcfg = T.TransformerConfig(**dict(
+        dict(vocab_size=VOCAB, n_layers=2, n_heads=4, d_model=64, max_seq=32,
+             variant="llama", use_flash=False), **(model or {})))
     return ds.initialize(
         {"train_micro_batch_size_per_gpu": 1,
          "gradient_accumulation_steps": 2,
@@ -51,7 +51,7 @@ def _flat_engine(overlap, bf16=False, **zero_kw):
                                "param_persistence_threshold": 64,
                                "overlap_comm": overlap, **zero_kw},
          **({"bf16": {"enabled": True}} if bf16 else {}),
-         "mesh": {"data": 4, "model": 2}, "steps_per_print": 10**9},
+         "mesh": mesh or {"data": 4, "model": 2}, "steps_per_print": 10**9},
         loss_fn=T.make_loss_fn(mcfg),
         param_init_fn=lambda k: T.init(mcfg, k),
         param_logical_specs=T.logical_specs(mcfg))
@@ -130,10 +130,14 @@ class TestOverlapScope:
 
 
 # ----------------------------------------------------------------------
-# prefetch scan: values and grads match a plain scan
+# the layer gather inside the body: values and grads are a plain scan's
 # ----------------------------------------------------------------------
 
-class TestScanWithPrefetch:
+class TestLayerGatherInBody:
+    """A plain jax.lax.scan over the STORE slices whose body gathers
+    its own slice (make_prefetch_gather), bare and inside
+    jax.checkpoint."""
+
     def _setup(self):
         devs = np.array(jax.devices()[:4])
         mesh = Mesh(devs, ("data",))
@@ -145,61 +149,57 @@ class TestScanWithPrefetch:
         rest = jnp.arange(L, dtype=jnp.float32)
         init = jnp.ones((D,), jnp.float32)
 
-        def pack(w, r):
-            return (w, r)
-
         def body(x, xs):
             w, r = xs
             y = jnp.tanh(x @ w["w"] + r)
             return y, jnp.sum(y)
 
-        return mesh, w_stack, store, tp, rest, init, pack, body
+        return mesh, w_stack, store, tp, rest, init, body
 
-    def _reference(self, w_stack, rest, init, pack, body):
-        L = rest.shape[0]
+    @staticmethod
+    def _in_body(body, gather, remat):
+        def gathered(x, xs):
+            w, r = xs
+            return body(x, (gather(w), r))
 
-        def body_ref(x, xs):
-            i, r = xs
-            w = jax.tree.map(lambda t: t[i], w_stack)
-            return body(x, pack(w, r))
+        return jax.checkpoint(gathered) if remat else gathered
 
-        idxs = jnp.arange(L, dtype=jnp.int32)
-        return jax.lax.scan(body_ref, init, (idxs, rest))
-
-    @pytest.mark.parametrize("depth", [1, 2])
-    def test_values_match_plain_scan(self, depth):
-        mesh, w_stack, store, tp, rest, init, pack, body = self._setup()
+    @pytest.mark.parametrize("remat", [False, True])
+    def test_values_match_plain_scan(self, remat):
+        mesh, w_stack, store, tp, rest, init, body = self._setup()
         gather = make_prefetch_gather(store, tp, mesh)
+        in_body = self._in_body(body, gather, remat)
 
-        def run(w_stack, init, rest):
-            return scan_with_prefetch(
-                body, init, w_stack, rest, pack, gather, depth)
-
-        x_fin, outs = jax.jit(run)(w_stack, init, rest)
+        x_fin, outs = jax.jit(
+            lambda w, i, r: jax.lax.scan(in_body, i, (w, r))
+        )(w_stack, init, rest)
         x_ref, outs_ref = jax.jit(
-            lambda w, i, r: self._reference(w, r, i, pack, body)
+            lambda w, i, r: jax.lax.scan(body, i, (w, r))
         )(w_stack, init, rest)
         np.testing.assert_array_equal(np.asarray(x_fin), np.asarray(x_ref))
         np.testing.assert_array_equal(np.asarray(outs), np.asarray(outs_ref))
 
-    def test_grads_match_plain_scan(self):
-        mesh, w_stack, store, tp, rest, init, pack, body = self._setup()
+    @pytest.mark.parametrize("remat", [False, True])
+    def test_grads_match_plain_scan(self, remat):
+        mesh, w_stack, store, tp, rest, init, body = self._setup()
         gather = make_prefetch_gather(store, tp, mesh)
 
-        def loss_pf(w_stack):
-            x_fin, outs = scan_with_prefetch(
-                body, init, w_stack, rest, pack, gather, 1)
-            return jnp.sum(x_fin) + jnp.sum(outs)
+        def loss(step):
+            def f(w_stack):
+                x_fin, outs = jax.lax.scan(step, init, (w_stack, rest))
+                return jnp.sum(x_fin) + jnp.sum(outs)
+            return f
 
-        def loss_ref(w_stack):
-            x_fin, outs = self._reference(w_stack, rest, init, pack, body)
-            return jnp.sum(x_fin) + jnp.sum(outs)
-
-        g_pf = jax.jit(jax.grad(loss_pf))(w_stack)
-        g_ref = jax.jit(jax.grad(loss_ref))(w_stack)
-        np.testing.assert_allclose(np.asarray(g_pf["w"]),
+        g_in = jax.jit(jax.grad(loss(self._in_body(body, gather, remat))))(
+            w_stack)
+        g_ref = jax.jit(jax.grad(loss(
+            jax.checkpoint(body) if remat else body)))(w_stack)
+        np.testing.assert_allclose(np.asarray(g_in["w"]),
                                    np.asarray(g_ref["w"]),
                                    rtol=1e-6, atol=1e-6)
+        # the cotangent comes back in the store layout: the reduction
+        # ran inside the backward scan
+        assert g_in["w"].sharding.spec == P(None, "data")
 
     def test_persistent_leaf_passes_identity(self):
         devs = np.array(jax.devices()[:4])
@@ -210,7 +210,6 @@ class TestScanWithPrefetch:
         w = {"b": jnp.ones((3, 8))}
         out = gather(jax.tree.map(lambda t: t[0], w))
         np.testing.assert_array_equal(out["b"], np.ones(8))
-        assert hasattr(gather, "pin")
 
     def test_sharded_stacking_dim_passes_identity(self):
         devs = np.array(jax.devices()[:4])
@@ -378,6 +377,34 @@ class TestEngineOverlap:
         on, off = run(True), run(False)
         for a, b in zip(on, off):
             np.testing.assert_array_equal(a, b)
+
+    @pytest.mark.parametrize("remat", ["save_attn_qkv", "full"])
+    def test_no_gathered_leaf_is_a_scan_residual(self, remat):
+        """(c) The engine's compiled ZeRO-3 step on eight host devices:
+        under remat no stacked [L, ...] of a layer leaf at its GATHERED
+        shape exists in the program (a store stack is an eighth of it a
+        device): the backward pass gathers again. The carried, pinned
+        buffer this replaced wrote every one of them."""
+        import re
+
+        eng = _flat_engine(True, mesh={"data": 8},
+                           model={"n_layers": 3, "d_ff": 160, "remat": remat})
+        eng.train_batch({"tokens": np.zeros(
+            (eng.config.train_batch_size, 33), np.int32)})
+        shapes = set(re.findall(r"= \(?([a-z]+\d+\[[\d,]*\])",
+                                eng._train_compiled.as_text()))
+        is_spec = lambda x: isinstance(x, P)  # noqa: E731
+        leaves = jax.tree_util.tree_leaves_with_path(
+            eng.state.params["layers"])
+        store = jax.tree.leaves(eng.param_specs["layers"], is_leaf=is_spec)
+        tp = jax.tree.leaves(eng.tp_specs["layers"], is_leaf=is_spec)
+        sharded = [(jax.tree_util.keystr(path), leaf.shape)
+                   for (path, leaf), s, t in zip(leaves, store, tp) if s != t]
+        assert len(sharded) == 9, sharded
+        for name, shape in sharded:
+            assert shape[0] == 3
+            stacked = "f32[" + ",".join(map(str, shape)) + "]"
+            assert stacked not in shapes, (name, stacked)
 
     def test_sanitize_stats_and_exposure_drop(self):
         """overlap_stats plumbing + the measured win: the overlap-on

@@ -24,6 +24,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax._src import core as jcore
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 import deepspeed_tpu as ds
@@ -369,6 +370,225 @@ def test_on_the_chip_a_gradients_all_reduce_sits_in_a_fusion(v5e_mesh):
                          text)
 
 
+def test_a_hand_made_async_chain_is_booked_once_and_flagged():
+    """The TPU compiler's async collective fusion: one all-gather as a
+    chain of `chain_id` links inside `async-collective-start`, a
+    compute fusion and `async-collective-done`. The manifest counts it
+    once, at its start, as asynchronous; a `-start` / `-done` pair is
+    asynchronous too; a gather the compiler turned back (it keeps the
+    name it had, `async_collective_name`) and a plain one are not."""
+    text = """HloModule m, is_scheduled=true
+
+%fused_computation.1 (p: bf16[64,16]) -> bf16[64,64] {
+  %p = bf16[64,16]{1,0} parameter(0)
+  ROOT %all-gather.10 = bf16[64,64]{1,0} all-gather(%p), channel_id=14, replica_groups=[1,4]<=[4], dimensions={1}, frontend_attributes={chain_id="0"}
+}
+
+%async_collective_fusion.2 (p: bf16[64,16], x: bf16[8,64]) -> bf16[8,64] {
+  %p = bf16[64,16]{1,0} parameter(0)
+  %x = bf16[8,64]{1,0} parameter(1)
+  %all-gather.11 = bf16[64,64]{1,0} all-gather(%p), channel_id=14, replica_groups=[1,4]<=[4], dimensions={1}, frontend_attributes={chain_id="0"}
+  ROOT %convolution.1 = bf16[8,64]{1,0} convolution(%x, %x), dim_labels=bf_io->bf
+}
+
+%fused_computation.3 (p: bf16[64,16]) -> bf16[64,64] {
+  %p = bf16[64,16]{1,0} parameter(0)
+  ROOT %all-gather.12 = bf16[64,64]{1,0} all-gather(%p), channel_id=14, replica_groups=[1,4]<=[4], dimensions={1}, frontend_attributes={chain_id="0"}
+}
+
+%body (t: (bf16[64,16], bf16[8,64])) -> (bf16[64,16], bf16[8,64]) {
+  %t = (bf16[64,16]{1,0}, bf16[8,64]{1,0}) parameter(0)
+  %w = bf16[64,16]{1,0} get-tuple-element(%t), index=0
+  %x = bf16[8,64]{1,0} get-tuple-element(%t), index=1
+  %all-gather.20 = bf16[64]{0} all-gather(%x), channel_id=3, replica_groups=[1,4]<=[4], dimensions={0}, frontend_attributes={async_collective_name="all-gather-start.1"}
+  %async-collective-start = bf16[64,64]{1,0} fusion(%w), kind=kCustom, calls=%fused_computation.1
+  %fusion.2 = bf16[8,64]{1,0} fusion(%w, %x), kind=kOutput, calls=%async_collective_fusion.2
+  %async-collective-done = bf16[64,64]{1,0} fusion(%w), kind=kCustom, calls=%fused_computation.3
+  %collective-permute-start.4 = (bf16[8,64]{1,0}, bf16[8,64]{1,0}) collective-permute-start(%x), channel_id=5, source_target_pairs={{0,1},{1,0}}
+  %collective-permute-done.4 = bf16[8,64]{1,0} collective-permute-done(%collective-permute-start.4)
+  ROOT %r = (bf16[64,16]{1,0}, bf16[8,64]{1,0}) tuple(%w, %fusion.2)
+}
+
+ENTRY %main (a: (bf16[64,16], bf16[8,64])) -> (bf16[64,16], bf16[8,64]) {
+  %a = (bf16[64,16]{1,0}, bf16[8,64]{1,0}) parameter(0)
+  ROOT %w = (bf16[64,16]{1,0}, bf16[8,64]{1,0}) while(%a), condition=%cond, body=%body
+}
+"""
+    man = collective_manifest(text)
+    assert sorted(man["sites"]) == [
+        ("all-gather.20", "all-gather", 128),
+        ("async-collective-start", "all-gather", 8192),
+        ("collective-permute-start.4", "collective-permute", 1024)]
+    assert man["kinds"]["all-gather"] == {"count": 2, "bytes": 8320}
+    assert man["async"] == {"all-gather": 1, "collective-permute": 1}
+    assert man["in_fusion"] == {"count": 1, "bytes": 8192}
+    ids = manifest_ids(man)
+    assert (ids["all_gather_n"], ids["all_gather_async_n"]) == (2, 1)
+    assert ids["collective_permute_async_n"] == 1
+    assert ids["all_reduce_async_n"] == ids["reduce_scatter_async_n"] == 0
+
+
+# -- ZeRO-3's layer gathers, compiled for the chip (docs/overlap.md) ---------
+
+def zero3_layer_stack(mesh, **model):
+    """(step, params, tokens): the gradient of the model's own loss
+    under a ZeRO-3 overlap plan on `mesh`, with abstract bf16 parameters
+    in their store layout: what `forward_hidden`'s layer scan traces
+    for the engine, without an engine (a described device holds no
+    array)."""
+    from deepspeed_tpu.config.config import ZeroConfig
+    from deepspeed_tpu.runtime import zero
+    from deepspeed_tpu.runtime.overlap import OverlapPlan, overlap_scope
+
+    mcfg = T.TransformerConfig(**dict(
+        dict(vocab_size=VOCAB, n_layers=3, n_heads=4, d_model=64, d_ff=160,
+             max_seq=32, variant="llama", use_flash=False), **model))
+    shapes = jax.eval_shape(lambda k: T.init(mcfg, k), jax.random.PRNGKey(0))
+    tp = jax.tree.map(lambda p: P(), shapes)
+    store = zero.derive_param_storage_specs(
+        tp, jax.tree.map(lambda p: tuple(p.shape), shapes), mesh,
+        ZeroConfig(stage=3, param_persistence_threshold=64))
+    plan = OverlapPlan(mesh=mesh, layer_store_specs=store["layers"],
+                       layer_tp_specs=tp["layers"])
+    loss_fn = T.make_loss_fn(mcfg)
+
+    def step(params, tokens):
+        with overlap_scope(plan):
+            return jax.grad(
+                lambda p: loss_fn(p, {"tokens": tokens}, None))(params)
+
+    params = jax.tree.map(
+        lambda s, spec: jax.ShapeDtypeStruct(
+            s.shape, jnp.bfloat16, sharding=NamedSharding(mesh, spec)),
+        shapes, store)
+    tokens = jax.ShapeDtypeStruct(
+        (mesh.shape["data"], mcfg.max_seq + 1), jnp.int32,
+        sharding=NamedSharding(mesh, P("data")))
+    return step, params, tokens
+
+
+def _holds_zero_gather(jaxpr):
+    return any(
+        (e.primitive.name == "sharding_constraint"
+         and ZERO_GATHER in str(e.source_info.name_stack))
+        or any(_holds_zero_gather(s)
+               for s in jcore.jaxprs_in_params(e.params))
+        for e in jaxpr.eqns)
+
+
+def barriers_over_gathers(jaxpr, gathered_in=()):
+    """(the `optimization_barrier`s of `jaxpr`, nested ones too, that
+    take a GATHERED leaf, how many barriers there are): a gathered leaf
+    is what a custom-vjp call holding a `zero_gather` constraint
+    returns, followed through converts and reshapes and into the
+    jaxprs an equation calls."""
+    forward = {"convert_element_type", "reshape", "squeeze", "transpose",
+               "copy", "sharding_constraint"}
+    gathered = {jaxpr.invars[i] for i in gathered_in}
+    bad, n = [], 0
+    for e in jaxpr.eqns:
+        hit = [i for i, v in enumerate(e.invars)
+               if not isinstance(v, jcore.Literal) and v in gathered]
+        name = e.primitive.name
+        if name == "optimization_barrier":
+            n += 1
+            bad += [e] if hit else []
+        subs = list(jcore.jaxprs_in_params(e.params))
+        if name.startswith("custom_vjp_call") and any(
+                _holds_zero_gather(s) for s in subs):
+            gathered.update(e.outvars)
+            continue
+        for s in subs:
+            off = len(e.invars) - len(s.invars)
+            b, m = barriers_over_gathers(
+                s, [i - off for i in hit if i >= off])
+            bad, n = bad + b, n + m
+        if name in forward and hit:
+            gathered.update(e.outvars)
+    return bad, n
+
+
+@pytest.mark.parametrize("remat", ["none", "save_attn_qkv"])
+def test_no_barrier_stands_over_a_gathered_leaf(remat):
+    """An `optimization_barrier`'s outputs exist once ALL its inputs
+    do: one that takes a gathered leaf beside the layer's input makes
+    the layer wait for the gather to be done (what the carried, pinned
+    prefetch did: runtime/overlap.py). The traced step holds none; and
+    the walker finds one where one is put."""
+    from deepspeed_tpu.runtime.overlap import barrier, make_prefetch_gather
+
+    mesh = build_mesh({"data": 4}, devices=jax.devices()[:4])
+    step, params, tokens = zero3_layer_stack(mesh, remat=remat)
+    with jax.sharding.set_mesh(mesh):
+        jaxpr = jax.make_jaxpr(step)(params, tokens).jaxpr
+    assert _holds_zero_gather(jaxpr)
+    assert barriers_over_gathers(jaxpr) == ([], 0)
+
+    gather = make_prefetch_gather({"w": P(None, "data")}, {"w": P()}, mesh)
+
+    def pinned(w, x):
+        g, x = barrier((gather({"w": w})["w"], x))
+        return x @ g
+
+    with jax.sharding.set_mesh(mesh):
+        seeded = jax.make_jaxpr(pinned)(jnp.ones((8, 8)), jnp.ones((2, 8)))
+    bad, n = barriers_over_gathers(seeded.jaxpr)
+    assert (len(bad), n) == (1, 1)
+
+
+def test_on_the_chip_the_layer_gathers_run_beside_the_matmuls(v5e_mesh):
+    """(b) The model's ZeRO-3 layer stack at Mistral-7B's widths (4096
+    x 14336, 4096 tokens a chip), compiled for a described v5e:2x2:
+    every MLP gather of the two loop bodies is an
+    `async-collective-start` .. `-done` chain with a matmul fusion
+    between the two, and the manifest says so."""
+    from deepspeed_tpu.profiling.hlo import parse_hlo_computations
+
+    mesh = build_mesh({"data": 4}, devices=list(v5e_mesh.devices.flat))
+    step, params, tokens = zero3_layer_stack(
+        mesh, n_layers=4, n_heads=32, n_kv_heads=8, d_model=4096,
+        d_ff=14336, max_seq=4096, remat="save_attn_qkv")
+    with jax.sharding.set_mesh(mesh):
+        text = jax.jit(step).lower(params, tokens).compile().as_text()
+    comps, _ = parse_hlo_computations(text)
+    mlp_bytes = 4096 * 14336 * 2
+
+    def inside(ins, op):
+        """The instructions of kind `op` in what `ins` calls."""
+        return [j for c in ins["called"] for j in comps.get(c, ())
+                if j["op"] == op]
+
+    bodies = {c for body in comps.values() for ins in body
+              if ins["op"] == "while" for c in ins["called"]}
+    chains = 0
+    for body in (comps[c] for c in bodies):
+        # a synchronous MLP gather would stand in the body under its own name
+        assert not [i for i in body if i["op"] == "all-gather"
+                    and i["nbytes"] == mlp_bytes]
+        starts = [(pos, ins) for pos, ins in enumerate(body)
+                  if ins["name"].startswith("async-collective-start")
+                  and any(g["nbytes"] == mlp_bytes
+                          for g in inside(ins, "all-gather"))]
+        for pos, start in starts:
+            suffix = start["name"][len("async-collective-start"):]
+            done = next(p for p, ins in enumerate(body)
+                        if ins["name"] == "async-collective-done" + suffix)
+            assert any(inside(ins, "convolution")
+                       for ins in body[pos + 1:done]), start["name"]
+            chains += 1
+    # w_in, w_gate, w_out: forward, and again in the backward body
+    assert chains == 6, chains
+    man = collective_manifest(text)
+    ids = manifest_ids(man)
+    assert ids["all_gather_async_n"] == man["async"]["all-gather"] >= chains
+    assert ids["all_gather_n"] > ids["all_gather_async_n"]
+    started = [s for s in man["sites"] if s[1] == "all-gather"
+               and s[0].startswith("async-collective-start")]
+    assert len(started) == man["async"]["all-gather"]
+    # a chain's links are one gather: 117.4 MB, not three times that
+    assert sum(1 for s in started if s[2] == mlp_bytes) == chains
+
+
 # -- the operator's reader, the one clock, the docs --------------------------
 
 def test_the_measured_profile_reads_the_same_names(tmp_path, capsys):
@@ -435,7 +655,7 @@ def test_the_docs_name_every_scope_and_id():
     for scope in TRAIN_STEP_SCOPES + MODEL_SCOPES:
         assert f"`{scope}`" in doc, scope
     ids = manifest_ids({"kinds": {}, "in_fusion": {"count": 0, "bytes": 0},
-                        "sites": []})
+                        "sites": [], "async": {}})
     for key in ids:
         assert f"`{key}`" in doc or f"`{key.rsplit('_', 1)[0]}_*`" in doc, key
     for name in ("train.compile.collectives", "engine.collective_manifest()",

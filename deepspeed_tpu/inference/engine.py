@@ -152,6 +152,38 @@ def refuse_for_pools(cfg: T.TransformerConfig, feature: str) -> None:
             f"pool cannot do it yet (inference/engine.py _POOL_CANNOT)")
 
 
+def pool_bytes(cfg: T.TransformerConfig, config, dtype) -> Dict[str, int]:
+    """Bytes of the pools an engine of `config` would allocate for this
+    model, from shapes alone: {'kv': ..., 'state': ...}."""
+    shapes = jax.eval_shape(lambda: M.init_cache(
+        cfg, config.num_kv_blocks + 1, config.kv_block_size, dtype,
+        kv_quant=config.kv_cache_dtype == "int8",
+        state_slots=config.max_tracked_sequences))
+    size = lambda tree: sum(x.size * x.dtype.itemsize
+                            for x in jax.tree.leaves(tree))
+    return {"kv": size(shapes._replace(state=())),
+            "state": size(shapes.state)}
+
+
+def refuse_pools_beyond(limit: Optional[int], weights: int,
+                        pools: Dict[str, int]) -> None:
+    """A model with state pools whose weights + K/V + state exceed the
+    device's memory is refused where the engine is built, with the
+    three numbers: a slot costs megabytes there (a float32 matrix a
+    head), so max_tracked_sequences is not free, and the alternative is
+    an allocation failure in warm-up that names none of them. `limit`
+    None (a backend that states no limit: the CPU) refuses nothing."""
+    total = weights + pools["kv"] + pools["state"]
+    if limit is not None and pools["state"] and total > limit:
+        gb = lambda n: f"{n / 1e9:.2f} GB"
+        raise ValueError(
+            f"this engine does not fit the device: weights {gb(weights)} + "
+            f"K/V pools {gb(pools['kv'])} + state pools "
+            f"{gb(pools['state'])} = {gb(total)} of {gb(limit)}; lower "
+            f"max_tracked_sequences (a state slot costs "
+            f"{gb(pools['state'])} / slots) or num_kv_blocks")
+
+
 def _bucket(n: int, lo: int) -> int:
     b = lo
     while b < n:
@@ -447,6 +479,12 @@ class InferenceEngine:
                 f"kv_cache_dtype must be 'auto' or 'int8' "
                 f"(got {self.config.kv_cache_dtype!r})")
         self.kv_quant = self.config.kv_cache_dtype == "int8"
+        if model_config.n_state_layers:
+            stats = jax.local_devices()[0].memory_stats() or {}
+            refuse_pools_beyond(
+                stats.get("bytes_limit"),
+                sum(x.nbytes for x in jax.tree.leaves(self.params)),
+                pool_bytes(model_config, self.config, dtype))
         with profiler.span("init.pool", always=True) as sp:
             self.cache = host_sync(M.init_cache(
                 model_config, self.config.num_kv_blocks + 1,
@@ -454,11 +492,17 @@ class InferenceEngine:
                 kv_quant=self.kv_quant,
                 state_slots=self.config.max_tracked_sequences,
             ))
+            # bytes one tracked sequence's slot holds over all the state
+            # layers' pools (0 for a model without recurrent state)
+            self.state_slot_bytes = sum(
+                x.nbytes // x.shape[0]
+                for x in jax.tree.leaves(self.cache.state))
             if self.cache.state:
                 sp.set(kv_layers=len(self.cache.k),
                        state_layers=len(self.cache.state),
                        state_slots=self.config.max_tracked_sequences,
-                       state_bytes=sum(x.nbytes for x in self.cache.state))
+                       state_bytes=sum(
+                           x.nbytes for x in jax.tree.leaves(self.cache.state)))
         self._prefill_batch_fns: Dict[Tuple[int, int], Any] = {}
         # keyed (batch_width, unique_rows)
         self._decode_fns: Dict[Tuple[int, bool], Any] = {}

@@ -31,9 +31,9 @@ Cache: per layer, k and v as [num_blocks, block_size, KV_heads,
 head_dim] — one cache page is a contiguous (block_size, KV, D) tile
 (single large DMA in the kernels); TP shards the KV dim. All cache
 mutation goes through Pallas RMW kernels on donated buffers so the
-arena is updated in place. A model whose layers are of two kinds
+arena is updated in place. A model whose layers are of several kinds
 (cfg.layer_types) has K/V pools for its attention layers ALONE and,
-for each of the others, a STATE pool [slots, width]: one row a tracked
+for each of the others, STATE pools [slots, ...]: one entry a tracked
 sequence, of fixed size whatever the sequence's length (PagedCache).
 """
 
@@ -52,6 +52,12 @@ from ..ops.pallas.expert_stream import (
     group_rows,
     grouped_f_tile,
     stream_f_tile,
+)
+from ..ops.pallas.gated_delta import (
+    gated_delta_chunked,
+    gated_delta_step,
+    gated_delta_step_xla,
+    step_fits,
 )
 from ..ops.pallas.paged_attention import (
     fused_write_fits,
@@ -99,10 +105,10 @@ def prepare(params: Dict[str, Any], cfg: T.TransformerConfig,
             f"(got leading dim {lead.shape[0]} != {L}; merge pipeline "
             "partitions before serving)"
         )
-    # operators of two kinds: each kind's top-level stacks hand layer li
-    # the entry of its place among its kind (conv leaves keep their
-    # prefix, clear of the FFN's w_in / w_out)
-    ops = {kind: {(k if kind == "conv" else k[len(prefix):]): out.pop(k)
+    # operators of several kinds: each kind's top-level stacks hand
+    # layer li the entry of its place among its kind (conv and gdn
+    # leaves keep their prefix, clear of the FFN's w_in / w_out)
+    ops = {kind: {(k[len(prefix):] if kind == "attention" else k): out.pop(k)
                   for k in list(out) if k.startswith(prefix)}
            for kind, prefix, _ in T.operator_stacks(cfg)}
 
@@ -140,8 +146,11 @@ def prepare_layer(lp: Dict[str, Any], cfg: T.TransformerConfig,
         wkv_b = lp.pop("wkv_b")
         lp["w_uk"], lp["w_uv"] = wkv_b[..., :Dn], wkv_b[..., Dn:]
     if fuse and "wq" in lp:
+        # the output gate's projection (cfg.attn_output_gate) rides the
+        # same GEMM, after v
         lp["w_qkv"] = jnp.concatenate(
-            [lp.pop("wq"), lp.pop("wk"), lp.pop("wv")], axis=1)
+            [lp.pop("wq"), lp.pop("wk"), lp.pop("wv")]
+            + ([lp.pop("wq_gate")] if "wq_gate" in lp else []), axis=1)
         if "bq" in lp:
             lp["b_qkv"] = jnp.concatenate(
                 [lp.pop("bq"), lp.pop("bk"), lp.pop("bv")], axis=0)
@@ -156,6 +165,7 @@ def prepare_layer(lp: Dict[str, Any], cfg: T.TransformerConfig,
 _SERVING_SPECS = {
     "w_qkv": (1, ("embed", "heads", "head_dim")),
     "wq": (1, ("embed", "heads", "head_dim")),
+    "wq_gate": (1, ("embed", "heads", "head_dim")),
     "wk": (1, ("embed", "heads", "head_dim")),
     "wv": (1, ("embed", "heads", "head_dim")),
     "wo": (2, ("heads", "head_dim", "embed")),
@@ -361,16 +371,20 @@ class PagedCache(NamedTuple):
     v: List[jnp.ndarray] = ()
     k_scale: Optional[List[jnp.ndarray]] = None
     v_scale: Optional[List[jnp.ndarray]] = None
-    # recurrent state: one [slots, width] pool for each layer that
-    # carries fixed-size state from token to token (cfg.n_state_layers,
-    # in their order); row s is the state of the tracked sequence that
-    # holds slot s (ragged.SequenceDescriptor.slot). What a row holds is
-    # the layer's business (a conv layer: its last conv_kernel - 1
-    # inputs, oldest first); a layer kind with another state is another
-    # width here, not another manager. Not paged: pages travel (COW,
-    # handoff, spill) WITHOUT it, which is why the engine refuses those
-    # for a model that has any.
-    state: List[jnp.ndarray] = ()
+    # recurrent state: for each layer that carries fixed-size state
+    # from token to token (cfg.n_state_layers, in their order) a tuple
+    # of pools [slots, ...] (cfg.state_shapes of the layer's kind);
+    # entry s of each is the state of the tracked sequence that holds
+    # slot s (ragged.SequenceDescriptor.slot). What an entry holds is
+    # the layer's business: a conv layer ONE pool [slots, width], its
+    # last conv_kernel - 1 inputs, oldest first; a linear-attention
+    # layer a float32 pool [slots + 1, heads, Dk, Dv] of its heads'
+    # matrices (the last slot is the pad rows', ops/pallas/
+    # gated_delta.py) and such a pool of carried inputs beside it.
+    # Another kind of state is another shape here, not another manager.
+    # Not paged: pages travel (COW, handoff, spill) WITHOUT it, which is
+    # why the engine refuses those for a model that has any.
+    state: List[Tuple[jnp.ndarray, ...]] = ()
 
     @property
     def block_size(self) -> int:
@@ -401,8 +415,15 @@ def init_cache(
         raise NotImplementedError(
             "a latent cache, and a cache beside recurrent state, is "
             "bf16/f32 on one device: no int8 pool and no mesh")
-    state = [jnp.zeros((state_slots, cfg.state_width), dtype)
-             for _ in range(cfg.n_state_layers)]
+    def state_pools(kind):
+        # the carried inputs, and before them the delta rule's matrices,
+        # whose pool holds one slot more: the pad rows' (gated_delta_step)
+        *matrices, (carried, _) = cfg.state_shapes(kind)
+        return (*(jnp.zeros((state_slots + 1, *shape), dt)
+                  for shape, dt in matrices),
+                jnp.zeros((state_slots, *carried), dtype))
+
+    state = [state_pools(kind) for kind in cfg.state_layer_kinds]
     if cfg.is_latent:
         shape = (num_blocks, block_size, latent_lanes(cfg.latent_dim))
         return PagedCache(k=[jnp.zeros(shape, dtype) for _ in range(L)],
@@ -865,13 +886,19 @@ def _mlp(h, lp, cfg: T.TransformerConfig, census_cb=None,
 
 def _moe_shared(out, h, lp, cfg: T.TransformerConfig, act):
     """The tail of the all-expert paths: the shared expert (every
-    token, unweighted, on every chip alike) under its own scope, then
-    the PR-MoE residual."""
+    token, on every chip alike; unweighted, or times a sigmoid gate of
+    its own, one scalar a token: cfg.shared_expert_gate) under its own
+    scope, then the PR-MoE residual."""
     if cfg.n_shared_experts:
         with jax.named_scope("moe_shared"):
-            out = out + _wmm(
+            y = _wmm(
                 "tf,fe->te", act(_wmm("te,ef->tf", h, lp["ws_gate"]))
                 * _wmm("te,ef->tf", h, lp["ws_in"]), lp["ws_out"])
+            if cfg.shared_expert_gate:
+                y = y * jax.nn.sigmoid(
+                    h.astype(jnp.float32)
+                    @ lp["ws_sgate"].astype(jnp.float32)).astype(y.dtype)
+            out = out + y
     return _moe_residual(out, h, lp, cfg, act)
 
 
@@ -998,29 +1025,41 @@ def _decode_attention(q, pools: tuple, table, ctx, use_kernel: bool,
 # ---------------------------------------------------------------------------
 
 def _layer(x, lp, li: int, positions, cfg: T.TransformerConfig, mesh, attend,
-           alibi, census_cb=None, use_kernel: bool = False, carry=None):
+           alibi, census_cb=None, use_kernel: bool = False, carry=None,
+           recur=None):
     """One serving layer over [..., E] activations (decode rows [S, E],
     prefill prompts [B, Tp, E]): norm1, then the layer's operator by its
     kind (cfg.layer_kind(li)) and the FFN tail. A 'conv' layer: the
     gated short convolution (_short_conv) with `carry(u, li)` handed in
     by the caller, as `attend` is: where the inputs before this one
-    come from and how the sequence's state row is left. An attention
+    come from and how the sequence's state row is left. A
+    'linear_attention' layer: the Gated DeltaNet (_gated_delta_net)
+    with the same `carry` for its convolution and `recur(q, k, v, g,
+    beta, li)`, how the heads' matrices advance (a step over ragged
+    rows, or a whole prompt's chunked scan). An attention
     layer: the QKV projection (fused w_qkv
-    or split, bias or none), QK-norm, rope at `positions` (the
+    or split, bias or none; with the output gate's, cfg.attn_output_gate),
+    QK-norm, rope at `positions` (the
     second-to-last axis of q/k: [S] or [Tp]), the head constraints,
     `attend(q, k, v, li, alibi, lp) -> (att, layer_cache)` handed in by the
     caller (the ONE thing the two sites differ in: what attention runs
     and how the new rows reach the cache), the output projection and
     the FFN tail, whose routed block asks expert_path with `use_kernel`
     and the mesh. Returns (x, layer_cache): the layer's K/V pools, or
-    its state pool."""
+    its state pools."""
     H, KV = cfg.n_heads, cfg.kv_heads
     with jax.named_scope("norm1"):
         h1 = T._act_quant(
             T._norm(x, lp["ln1_scale"], lp.get("ln1_bias"), cfg), cfg)
-    if cfg.layer_kind(li) == "conv":
-        with jax.named_scope("short_conv"):
-            out, state = _short_conv(h1, lp, partial(carry, li=li))
+    kind = cfg.layer_kind(li)
+    if kind != "attention":
+        if kind == "conv":
+            with jax.named_scope("short_conv"):
+                out, state = _short_conv(h1, lp, partial(carry, li=li))
+        else:
+            with jax.named_scope("linear_attention"):
+                out, state = _gated_delta_net(
+                    h1, lp, cfg, partial(carry, li=li), partial(recur, li=li))
         return _ffn_residual(x, out, h1, lp, cfg, census_cb, use_kernel,
                              mesh), state
     if cfg.is_latent:
@@ -1040,7 +1079,9 @@ def _layer(x, lp, li: int, positions, cfg: T.TransformerConfig, mesh, attend,
             qkv = _wmm("...e,ehd->...hd", h1, lp["w_qkv"])
             if "b_qkv" in lp:
                 qkv = qkv + lp["b_qkv"].astype(x.dtype)
-            q, k, v = jnp.split(qkv, [H, H + KV], axis=-2)
+            q, k, v, *gate = jnp.split(
+                qkv, [H, H + KV] + [H + 2 * KV] * cfg.attn_output_gate,
+                axis=-2)
         else:
             q = _wmm("...e,ehd->...hd", h1, lp["wq"])
             k = _wmm("...e,ehd->...hd", h1, lp["wk"])
@@ -1049,6 +1090,8 @@ def _layer(x, lp, li: int, positions, cfg: T.TransformerConfig, mesh, attend,
                 q = q + lp["bq"].astype(x.dtype)
                 k = k + lp["bk"].astype(x.dtype)
                 v = v + lp["bv"].astype(x.dtype)
+            gate = ([_wmm("...e,ehd->...hd", h1, lp["wq_gate"])]
+                    if cfg.attn_output_gate else [])
         q, k = T.qk_norm(q, k, lp, cfg)
         if cfg.use_rope:
             q = _rope_at(q, positions, cfg)
@@ -1058,6 +1101,10 @@ def _layer(x, lp, li: int, positions, cfg: T.TransformerConfig, mesh, attend,
         k = _cons(k, mesh, *heads)
         v = _cons(v, mesh, *heads)
         att, layer_cache = attend(q, k, v, li, alibi, lp)
+        if gate:
+            with jax.named_scope("attn_gate"):
+                att = att * jax.nn.sigmoid(
+                    gate[0].astype(jnp.float32)).astype(att.dtype)
         out = _wmm("...hd,hde->...e", att, lp["wo"])
         if "bo" in lp:
             out = out + lp["bo"].astype(x.dtype)
@@ -1085,13 +1132,101 @@ def _short_conv(h1, lp, carry):
                              axis=-1)
         u = b * xg
     with jax.named_scope("conv_state"):
-        past, state = carry(u)
-    taps = lp["conv_taps"].astype(jnp.float32)  # [E, K], oldest first
-    v = sum(up.astype(jnp.float32) * taps[:, j]
-            for j, up in enumerate([*past, u]))
+        past, pool = carry(u)
+    v = _depthwise(past, u, lp["conv_taps"])
     with jax.named_scope("conv_out"):
         out = _wmm("...e,ef->...f", c * v.astype(u.dtype), lp["conv_out"])
-    return out, state
+    return out, (pool,)
+
+
+def _depthwise(past, u, taps):
+    """sum_j taps[:, j] * (the input K - 1 - j places back), in float32:
+    `past` the K - 1 inputs before each position, oldest first, `u` the
+    current one, taps [channels, K], oldest first."""
+    taps = taps.astype(jnp.float32)
+    return sum(up.astype(jnp.float32) * taps[:, j]
+               for j, up in enumerate([*past, u]))
+
+
+def _gated_delta_net(h1, lp, cfg: T.TransformerConfig, carry, recur):
+    """The Gated DeltaNet operator: normed activations h1 [..., E] ->
+    (its output [..., E], the layer's state pools (the heads' matrices,
+    the convolution's carried inputs)).
+
+    [q; k; v; z] = gdn_in h1, [b; a] = gdn_ba h1 (one of each a value
+    head); [q; k; v] <- silu(causal depthwise convolution of
+    conv_kernel taps, no bias, zeros before the sequence starts);
+    beta = sigmoid(b); g = -exp(a_log) softplus(a + dt_bias), float32;
+    q and k of the key heads repeated to the value heads, each head's
+    L2-normalised (x rsqrt(sum x^2 + 1e-6)), q scaled by Dk^-0.5; the
+    delta rule per value head (ops/pallas/gated_delta.py) through
+    `recur(q, k, v, g, beta)` -> (o float32, the matrices' pool): the
+    one thing a step over ragged rows and a whole-prompt prefill differ
+    in, beside `carry` (as _short_conv's); then o <- rms(o) *
+    gdn_norm_scale * silu(z) over each head's Dv values (a PLAIN scale;
+    the norm first, then the gate) and gdn_out."""
+    Hk, Hv = cfg.gdn_key_heads, cfg.gdn_value_heads
+    Dk, Dv = cfg.gdn_key_dim, cfg.gdn_value_dim
+    C, f32 = cfg.gdn_conv_dim, jnp.float32
+    with jax.named_scope("gdn_project"):
+        mixed = _wmm("...e,ef->...f", h1, lp["gdn_in"])
+        u, z = mixed[..., :C], mixed[..., C:]
+        b, a = jnp.split(
+            _wmm("...e,ef->...f", h1, lp["gdn_ba"]).astype(f32), 2, axis=-1)
+        beta = jax.nn.sigmoid(b)
+        g = -jnp.exp(lp["gdn_a_log"].astype(f32)) * jax.nn.softplus(
+            a + lp["gdn_dt_bias"].astype(f32))
+    with jax.named_scope("gdn_conv"):
+        past, conv_pool = carry(u)
+        # the convolution's output in the activations' dtype, as the
+        # publisher's; the heads' norms and the rule in float32
+        c = jax.nn.silu(_depthwise(past, u, lp["gdn_taps"])).astype(u.dtype)
+        q, k, v = jnp.split(c.astype(f32), [Hk * Dk, 2 * Hk * Dk], axis=-1)
+        lead = c.shape[:-1]
+
+        def unit(x):  # [..., Hk * Dk] -> [..., Hv, Dk], each head's norm 1
+            x = x.reshape(*lead, Hk, Dk)
+            x = x * jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) + 1e-6)
+            return jnp.repeat(x, Hv // Hk, axis=-2)
+
+        q, k = unit(q) * Dk ** -0.5, unit(k)
+        v = v.reshape(*lead, Hv, Dv)
+    with jax.named_scope("gdn_state"):
+        o, pool = recur(q, k, v, g, beta)
+    with jax.named_scope("gdn_out"):
+        o = o * jax.lax.rsqrt(
+            jnp.mean(o * o, -1, keepdims=True) + cfg.norm_eps)
+        o = (o * lp["gdn_norm_scale"].astype(f32)
+             * jax.nn.silu(z.reshape(o.shape).astype(f32))).astype(h1.dtype)
+        out = _wmm("...f,fe->...e", o.reshape(*lead, Hv * Dv), lp["gdn_out"])
+    return out, (pool, conv_pool)
+
+
+def _recur_rows(q, k, v, g, beta, pool, slots, positions, use_kernel: bool):
+    """`recur` of a step over ragged rows [S, H, ...] (the rows of
+    _carry_rows): each run advances its sequence's matrices from its
+    slot, from zero where the run starts at position 0."""
+    step = (gated_delta_step if use_kernel and step_fits(q.shape[0], pool)
+            else gated_delta_step_xla)
+    return step(q, k, v, g, beta, pool, slots, positions)
+
+
+def _recur_prompts(q, k, v, g, beta, pool, slots, n_real):
+    """`recur` of a whole-prompt prefill [B, Tp, H, ...]: the chunked
+    scan from a zero state, the padding after each prompt's n_real
+    tokens made to leave the state as it is, and each prompt's last
+    state copied into its sequence's slot (pad prompts' into the pool's
+    last)."""
+    B, Tp = g.shape[:2]
+    real = (jnp.arange(Tp)[None, :] < n_real[:, None])[..., None]
+    o, last = gated_delta_chunked(
+        q, jnp.where(real[..., None], k, 0.0), v, jnp.where(real, g, 0.0),
+        jnp.where(real, beta, 0.0))
+    where = jnp.where((n_real > 0) & (slots >= 0), slots, pool.shape[0] - 1)
+    for i in range(B):  # a 2 MiB entry each: slice updates, no scatter
+        pool = jax.lax.dynamic_update_slice(
+            pool, last[i][None].astype(pool.dtype), (where[i], 0, 0, 0))
+    return o, pool
 
 
 def _state_write(pool, slots, rows, keep):
@@ -1262,7 +1397,7 @@ def _latent_naive(q, row, lp, pool, flat_idx, cfg: T.TransformerConfig,
 
 def _forward(params, tokens, positions, cfg: T.TransformerConfig, mesh,
              attend, fetch_layer=None, census_cb=None, head_rows=None,
-             use_kernel: bool = False, carry=None):
+             use_kernel: bool = False, carry=None, recur=None):
     """The serving forward both sites run: tokens [...] int32 at
     `positions` (their last axis) -> (f32 logits, the PagedCache the
     layers' `attend` calls returned). Prologue (embedding, learned
@@ -1293,8 +1428,8 @@ def _forward(params, tokens, positions, cfg: T.TransformerConfig, mesh,
             lp = fetch_layer(lp, x_hist[-2] if len(x_hist) >= 2 else None,
                              li)
         x, layer_cache = _layer(x, lp, li, positions, cfg, mesh, attend,
-                                alibi, census_cb, use_kernel, carry)
-        (states if cfg.layer_kind(li) == "conv" else pools).append(
+                                alibi, census_cb, use_kernel, carry, recur)
+        (pools if cfg.layer_kind(li) == "attention" else states).append(
             layer_cache)
         x_hist.append(x)
 
@@ -1380,12 +1515,17 @@ def decode_step(
         return _decode_attention(q, pools, *where), pools
 
     def carry(u, li):
-        return _carry_rows(u, cache.state[cfg.op_index(li)], slots,
+        return _carry_rows(u, cache.state[cfg.state_index(li)][-1], slots,
                            positions)
+
+    def recur(q, k, v, g, beta, li):
+        return _recur_rows(q, k, v, g, beta,
+                           cache.state[cfg.state_index(li)][0], slots,
+                           positions, use_kernel)
 
     return _forward(params, tokens, positions, cfg, mesh, attend,
                     fetch_layer, census_cb, use_kernel=use_kernel,
-                    carry=carry)
+                    carry=carry, recur=recur)
 
 
 def decode_multi(
@@ -1543,9 +1683,14 @@ def prefill_batch(
             axis=1)[:, 0]
 
     def carry(u, li):
-        return _carry_prompts(u, cache.state[cfg.op_index(li)], slots,
+        return _carry_prompts(u, cache.state[cfg.state_index(li)][-1], slots,
+                              n_real)
+
+    def recur(q, k, v, g, beta, li):
+        return _recur_prompts(q, k, v, g, beta,
+                              cache.state[cfg.state_index(li)][0], slots,
                               n_real)
 
     return _forward(params, tokens, positions, cfg, mesh, attend,
                     fetch_layer, census_cb, head_rows=last_real,
-                    use_kernel=use_kernel, carry=carry)
+                    use_kernel=use_kernel, carry=carry, recur=recur)

@@ -263,6 +263,15 @@ class ServingScheduler:
             "state_slots_live": 0,
             "state_slot_resets": 0,
             "state_prefix_credits_refused": 0,
+            # bytes of their slots the dispatched programs' sequences
+            # read and wrote, over all state layers (a step over rows
+            # reads and writes each live sequence's slot once a layer,
+            # a whole-prompt prefill writes it); and, where some layers
+            # are linear attention, the tokens of runs longer than one
+            # (prefill chunks, whole prompts): rows whose matrices come
+            # from the row before and not from a slot
+            "state_bytes_moved": 0,
+            "gdn_run_tokens": 0,
         }
         self._phases = profiler.Phases("sched", "iteration", PHASES,
                                        sums=self.counters)
@@ -952,6 +961,7 @@ class ServingScheduler:
             parts.append(_Part("wave", sample_rows, tok_dev))
             self.counters["wave_prefills"] += len(wave)
             self._count_tokens(int(n_real.sum()), bp * tp)
+            self._count_state(n_real[:len(wave)].tolist(), reads=False)
             self._it_rows += int(n_real.sum())
         return parts
 
@@ -980,6 +990,21 @@ class ServingScheduler:
                 self.counters["mla_cache_tokens"] += int(np.sum(live))
         if cfg.n_state_layers:
             self.counters["state_slots_live"] += self.engine.state.n_tracked
+
+    def _count_state(self, runs: Sequence[int], steps: int = 1,
+                     reads: bool = True) -> None:
+        """What a dispatched program of a model with recurrent state
+        moves of its sequences' slots: `runs` holds the tokens of each
+        sequence it advances. A step over rows reads and writes each
+        slot once a layer (`reads`), a whole-prompt prefill writes it."""
+        cfg = self.engine.cfg
+        if not cfg.n_state_layers:
+            return
+        self.counters["state_bytes_moved"] += (
+            len(runs) * steps * (2 if reads else 1)
+            * self.engine.state_slot_bytes)
+        if "linear_attention" in cfg.layer_types:
+            self.counters["gdn_run_tokens"] += sum(r for r in runs if r > 1)
 
     def _dispatch_mixed(self, rows, ahead_of: Optional[_Step] = None,
                         src: Optional[Dict[int, int]] = None
@@ -1060,6 +1085,7 @@ class ServingScheduler:
                    if sample_rows else None)
         ph.mark("commit")
         self._count_tokens(n_rows, sp, ctx)
+        self._count_state([len(c) for _, c, _ in rows])
         return _Part("mixed", sample_rows, tok_dev)
 
     def _dispatch_fused(self, running: List[Request], C: int) -> _Part:
@@ -1118,6 +1144,7 @@ class ServingScheduler:
         for req in running:
             eng.state.commit(req.uid, C)
         self._count_tokens(len(running) * C, width, ctx, steps=C)
+        self._count_state([1] * len(running), steps=C)
         self.counters["fused_steps"] += 1
         return _Part("fused", sample_rows, gen, n_steps=C)
 

@@ -189,8 +189,10 @@ class TransformerConfig:
     # add: x + N_post(f(N_pre(x))) (four norms a layer)
     sandwich_norm: bool = False
     # shared experts beside the routed ones: one dense gated MLP of
-    # n_shared_experts * d_ff every token passes through, unweighted
+    # n_shared_experts * d_ff every token passes through; unweighted, or
+    # (shared_expert_gate) times sigmoid(ws_sgate . h), a scalar a token
     n_shared_experts: int = 0
+    shared_expert_gate: bool = False
     # router scores: "softmax" over the experts, or "sigmoid" of each
     # logit (then the chosen weights are divided by their sum when
     # moe_norm_topk_prob, and multiplied by routed_scaling_factor)
@@ -208,19 +210,33 @@ class TransformerConfig:
     # n_experts outputs; the expert stacks hold `count`; pairs routed
     # elsewhere add nothing here (their chips add it). None: all held.
     experts_held: Optional[Tuple[int, int]] = None
-    # ---- layers of two kinds (LFM2 class hybrids). SERVING ONLY.
-    # layer_types names the operator of each of the model's `depth`
-    # layers, leading dense ones included: "attention", or "conv", the
-    # gated short convolution: [B; C; X] = W_in h, u = B * X, a causal
-    # depthwise convolution of conv_kernel taps over u, out =
-    # W_out (C * conv). A sequence carries the last conv_kernel - 1
-    # values of u from token to token in a conv layer, whatever its
-    # length, and K/V in the attention layers alone. The operators'
-    # weights are top-level stacks by kind, `conv_<name>` [n conv
-    # layers, ...] and `attn_<name>` [n attention layers, ...]; `layers`
-    # (and `dense_<name>`) keep what every layer has, its norms and FFN.
+    # ---- layers of several kinds (LFM2 / Qwen3-Next class hybrids).
+    # SERVING ONLY. layer_types names the operator of each of the
+    # model's `depth` layers, leading dense ones included, one of
+    # LAYER_KINDS: "attention"; "conv", the gated short convolution:
+    # [B; C; X] = W_in h, u = B * X, a causal depthwise convolution of
+    # conv_kernel taps over u, out = W_out (C * conv); or
+    # "linear_attention", the Gated DeltaNet (inference/model.py
+    # _gated_delta_net): gdn_value_heads heads that each carry a
+    # float32 [gdn_key_dim, gdn_value_dim] matrix rewritten by every
+    # token (S <- exp(g) S; S <- S + k (beta (v - S^T k))^T), q and k
+    # of gdn_key_heads heads repeated to the value heads, behind a
+    # causal depthwise convolution of conv_kernel taps (then silu) over
+    # the channels of [q; k; v]. A sequence carries fixed-size state
+    # from token to token in a conv or linear-attention layer, whatever
+    # its length (state_shapes), and K/V in the attention layers alone.
+    # The operators' weights are top-level stacks by kind (`conv_<name>`
+    # [n conv layers, ...], `attn_<name>`, `gdn_<name>`); `layers` (and
+    # `dense_<name>`) keep what every layer has, its norms and FFN.
     layer_types: Optional[Tuple[str, ...]] = None
     conv_kernel: int = 0
+    gdn_key_heads: int = 0
+    gdn_value_heads: int = 0
+    gdn_key_dim: int = 0
+    gdn_value_dim: int = 0
+    # attention's output gate: W_q projects each head to [q; gate]
+    # (leaf `wq_gate` beside `wq`), att <- att * sigmoid(gate) before W_o
+    attn_output_gate: bool = False
     # QK-norm a HEAD at a time: the RMS statistic over each head's
     # head_dim values, ONE learned scale of head_dim shared by all the
     # heads of q (another for k). qk_norm must be set too.
@@ -234,16 +250,33 @@ class TransformerConfig:
         if self.layer_types is not None:
             object.__setattr__(self, "layer_types", tuple(self.layer_types))
             if len(self.layer_types) != self.depth or not set(
-                    self.layer_types) <= {"attention", "conv"}:
+                    self.layer_types) <= set(LAYER_KINDS):
                 raise ValueError(
-                    f"layer_types names 'attention' or 'conv' for each of "
+                    f"layer_types names one of {LAYER_KINDS} for each of "
                     f"the {self.depth} layers (got {self.layer_types})")
-            if "conv" in self.layer_types and self.conv_kernel < 2:
-                raise ValueError("conv layers need conv_kernel >= 2")
+            if set(self.layer_types) & {"conv", "linear_attention"} \
+                    and self.conv_kernel < 2:
+                raise ValueError(
+                    "conv and linear_attention layers need conv_kernel >= 2")
+            if "linear_attention" in self.layer_types and not (
+                    self.gdn_key_heads > 0 and self.gdn_key_dim > 0
+                    and self.gdn_value_dim > 0 and self.gdn_value_heads > 0
+                    and self.gdn_value_heads % self.gdn_key_heads == 0):
+                raise ValueError(
+                    "linear_attention layers need gdn_key_heads, "
+                    "gdn_key_dim, gdn_value_dim and gdn_value_heads, a "
+                    "multiple of gdn_key_heads")
             if self.kv_lora_rank > 0:
                 raise NotImplementedError(
                     "layers of two kinds with latent attention: a latent "
                     "pool beside state pools is not served")
+        if self.shared_expert_gate and not self.n_shared_experts:
+            raise ValueError("shared_expert_gate gates n_shared_experts: "
+                             "set both")
+        if self.attn_output_gate and (self.kv_lora_rank > 0
+                                     or self.has_qkv_bias):
+            raise NotImplementedError(
+                "attn_output_gate with latent attention or q/k/v biases")
         if self.qk_norm_per_head and not self.qk_norm:
             raise ValueError("qk_norm_per_head is a form of qk_norm: set both")
         if self.moe_scoring not in ("softmax", "sigmoid"):
@@ -415,20 +448,26 @@ class TransformerConfig:
         return tuple(k for k in ("kv_lora_rank", "sandwich_norm",
                                  "n_shared_experts", "n_dense_layers",
                                  "experts_held", "layer_types",
-                                 "moe_expert_bias") if getattr(self, k)) + (
+                                 "moe_expert_bias", "attn_output_gate",
+                                 "shared_expert_gate") if getattr(self, k)) + (
             ("moe_scoring",) if self.moe_scoring != "softmax" else ())
 
     def layer_kind(self, li: int) -> str:
-        """The operator of layer li of the `depth`: 'attention' | 'conv'."""
+        """The operator of layer li of the `depth`: one of LAYER_KINDS."""
         return "attention" if self.layer_types is None else self.layer_types[li]
 
     def op_index(self, li: int) -> int:
         """Layer li's place among the layers of its own kind: which
-        entry of its kind's weight stacks, and which K/V pool or state
-        pool of the cache, is its."""
+        entry of its kind's weight stacks, and which K/V pool of the
+        cache, is its."""
         if self.layer_types is None:
             return li
         return self.layer_types[:li].count(self.layer_types[li])
+
+    def state_index(self, li: int) -> int:
+        """State layer li's place among the layers that hold state (of
+        whatever kind): which entry of the cache's state pools is its."""
+        return li - self.layer_types[:li].count("attention")
 
     @property
     def n_kv_layers(self) -> int:
@@ -442,10 +481,37 @@ class TransformerConfig:
         return self.depth - self.n_kv_layers
 
     @property
-    def state_width(self) -> int:
-        """Values one sequence carries in one state layer: the last
-        conv_kernel - 1 inputs of the convolution, oldest first."""
-        return (self.conv_kernel - 1) * self.d_model
+    def gdn_conv_dim(self) -> int:
+        """Channels the linear-attention layer's convolution runs over:
+        [q; k; v] of all heads."""
+        return (2 * self.gdn_key_heads * self.gdn_key_dim
+                + self.gdn_value_heads * self.gdn_value_dim)
+
+    def state_width(self, kind: str) -> int:
+        """Values one sequence carries as the last conv_kernel - 1
+        inputs, oldest first, of a state layer's depthwise convolution:
+        over d_model channels ('conv') or gdn_conv_dim
+        ('linear_attention')."""
+        return (self.conv_kernel - 1) * (
+            self.d_model if kind == "conv" else self.gdn_conv_dim)
+
+    def state_shapes(self, kind: str):
+        """What one sequence carries in one state layer of `kind`, as
+        ((shape, dtype), ...) of its slot in each of the layer's pools
+        (dtype None: the cache's): the convolution's carried inputs,
+        and before them, for 'linear_attention', a float32 matrix a
+        value head."""
+        carried = ((self.state_width(kind),), None)
+        if kind == "conv":
+            return (carried,)
+        return (((self.gdn_value_heads, self.gdn_key_dim,
+                  self.gdn_value_dim), jnp.float32), carried)
+
+    @property
+    def state_layer_kinds(self) -> Tuple[str, ...]:
+        """The kind of each layer that holds state, in their order."""
+        return tuple(k for k in (self.layer_types or ())
+                     if k != "attention")
 
     @property
     def depth(self) -> int:
@@ -561,6 +627,8 @@ def _layer_shapes(cfg: TransformerConfig, dense: bool = False
                 "ws_gate": ((E, Fs), ("embed", "mlp")),
                 "ws_in": ((E, Fs), ("embed", "mlp")),
                 "ws_out": ((Fs, E), ("mlp", "embed")),
+                **({"ws_sgate": ((E, 1), ("embed", None))}
+                   if cfg.shared_expert_gate else {}),
             })
         if cfg.moe_use_residual:
             # PR-MoE: dense residual expert + mixing coefficient
@@ -609,6 +677,24 @@ def _operator_shapes(cfg: TransformerConfig, kind: str):
             "conv_taps": ((E, cfg.conv_kernel), ("embed", None)),
             "conv_out": ((E, E), ("mlp", "embed")),
         }
+    if kind == "linear_attention":
+        # `gdn_in` to [q; k; v; z] (q, k of the key heads, v and the
+        # output gate z of the value heads), `gdn_ba` to [b; a] (one of
+        # each a value head), the depthwise `gdn_taps` [channel, tap]
+        # over [q; k; v], oldest tap first, the decay's `gdn_a_log` and
+        # `gdn_dt_bias` a value head, the output norm's PLAIN scale of
+        # gdn_value_dim, and `gdn_out`
+        Hv, Dv = cfg.gdn_value_heads, cfg.gdn_value_dim
+        C = cfg.gdn_conv_dim
+        return {
+            "gdn_in": ((E, C + Hv * Dv), ("embed", "mlp")),
+            "gdn_ba": ((E, 2 * Hv), ("embed", None)),
+            "gdn_taps": ((C, cfg.conv_kernel), ("mlp", None)),
+            "gdn_a_log": ((Hv,), (None,)),
+            "gdn_dt_bias": ((Hv,), (None,)),
+            "gdn_norm_scale": ((Dv,), (None,)),
+            "gdn_out": ((Hv * Dv, E), ("mlp", "embed")),
+        }
     if cfg.is_latent:
         return _latent_attention_shapes(cfg)
     shapes = {
@@ -617,6 +703,8 @@ def _operator_shapes(cfg: TransformerConfig, kind: str):
         "wv": ((E, KV, D), ("embed", "heads", "head_dim")),
         "wo": ((H, D, E), ("heads", "head_dim", "embed")),
     }
+    if cfg.attn_output_gate:
+        shapes["wq_gate"] = ((E, H, D), ("embed", "heads", "head_dim"))
     if cfg.qk_norm_per_head:
         shapes["q_norm_scale"] = ((D,), ("head_dim",))
         shapes["k_norm_scale"] = ((D,), ("head_dim",))
@@ -637,8 +725,10 @@ def _operator_shapes(cfg: TransformerConfig, kind: str):
 # top-level leaves of the leading dense layers (cfg.n_dense_layers)
 DENSE_PREFIX = "dense_"
 # top-level stacks of the operators' leaves, by kind, of a model whose
-# layers are of two kinds (cfg.layer_types)
-OPERATOR_PREFIX = {"attention": "attn_", "conv": "conv_"}
+# layers are of several kinds (cfg.layer_types); its keys are the kinds
+OPERATOR_PREFIX = {"attention": "attn_", "conv": "conv_",
+                   "linear_attention": "gdn_"}
+LAYER_KINDS = tuple(OPERATOR_PREFIX)
 
 
 def operator_stacks(cfg: TransformerConfig):
@@ -653,8 +743,9 @@ def operator_stacks(cfg: TransformerConfig):
 
 def _operator_leaves(cfg: TransformerConfig):
     """(top-level name, kind, leaf name, shape, logical axes) of every
-    operator stack's leaves. The conv leaves carry their prefix already
-    (`conv_in` / `conv_out`, clear of the FFN's `w_in` / `w_out`)."""
+    operator stack's leaves. The conv and gdn leaves carry their prefix
+    already (`conv_in` / `conv_out`, clear of the FFN's `w_in` /
+    `w_out`)."""
     for kind, prefix, _ in operator_stacks(cfg):
         for name, (shape, logical) in _operator_shapes(cfg, kind).items():
             top = name if name.startswith(prefix) else prefix + name

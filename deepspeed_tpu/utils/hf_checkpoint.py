@@ -145,7 +145,8 @@ _LLAMA_FAMILY = {"LlamaForCausalLM", "MistralForCausalLM",
 # a config.json without `architectures` is told by its model_type
 _ARCH_OF_MODEL_TYPE = {"olmoe": "OlmoeForCausalLM",
                        "pangu_ultra_moe": "PanguUltraMoEForCausalLM",
-                       "lfm2_moe": "Lfm2MoeForCausalLM"}
+                       "lfm2_moe": "Lfm2MoeForCausalLM",
+                       "qwen3_next": "Qwen3NextForCausalLM"}
 # config.json keys that change what a BLOCK computes (latent attention,
 # shared experts, leading dense layers, a second norm, a scaled, grouped
 # or biased router, layers of another kind than attention): an
@@ -159,13 +160,20 @@ _LATENT_MOE_KEYS = ("kv_lora_rank", "q_lora_rank", "qk_nope_head_dim",
                     "num_nextn_predict_layers")
 _HYBRID_KEYS = ("layer_types", "conv_L_cache", "conv_bias",
                 "num_dense_layers", "use_expert_bias")
-_BLOCK_KEYS = _LATENT_MOE_KEYS + _HYBRID_KEYS
+_LINEAR_ATTENTION_KEYS = (
+    "full_attention_interval", "linear_conv_kernel_dim",
+    "linear_key_head_dim", "linear_value_head_dim", "linear_num_key_heads",
+    "linear_num_value_heads", "shared_expert_intermediate_size",
+    "mlp_only_layers")
+_BLOCK_KEYS = _LATENT_MOE_KEYS + _HYBRID_KEYS + _LINEAR_ATTENTION_KEYS
 # the block keys each architecture's mapping reads; any other stays an
 # error for it too
 _READS_BLOCK_KEYS = {
     "PanguUltraMoEForCausalLM": frozenset(_LATENT_MOE_KEYS),
     "Lfm2MoeForCausalLM": frozenset(_HYBRID_KEYS + (
         "moe_intermediate_size", "routed_scaling_factor")),
+    "Qwen3NextForCausalLM": frozenset(_LINEAR_ATTENTION_KEYS + (
+        "layer_types", "moe_intermediate_size")),
 }
 
 
@@ -179,6 +187,7 @@ def _block_key_set(hf: Dict[str, Any], key: str) -> bool:
 
 SUPPORTED_ARCHITECTURES = sorted(_LLAMA_FAMILY | {
     "PanguUltraMoEForCausalLM", "Lfm2MoeForCausalLM",
+    "Qwen3NextForCausalLM",
     "GPT2LMHeadModel", "OPTForCausalLM", "FalconForCausalLM",
     "RWForCausalLM",  # falcon's pre-rename arch string
     "PhiForCausalLM", "QWenLMHeadModel",
@@ -209,6 +218,8 @@ def config_from_hf(hf: Dict[str, Any], **overrides) -> TransformerConfig:
         kw = _pangu_ultra_moe_config(hf)
     elif arch == "Lfm2MoeForCausalLM":
         kw = _lfm2_moe_config(hf)
+    elif arch == "Qwen3NextForCausalLM":
+        kw = _qwen3_next_config(hf)
     elif arch in _LLAMA_FAMILY:
         kw = dict(
             vocab_size=hf["vocab_size"],
@@ -597,6 +608,95 @@ def _lfm2_moe_config(hf: Dict[str, Any]) -> Dict[str, Any]:
         moe_norm_topk_prob=bool(hf.get("norm_topk_prob", True)),
         moe_expert_bias=bool(hf.get("use_expert_bias", False)),
         routed_scaling_factor=float(hf.get("routed_scaling_factor", 1.0)),
+        moe_dropless=True,
+    )
+
+
+def _qwen3_next_config(hf: Dict[str, Any]) -> Dict[str, Any]:
+    """Qwen3-Next (`qwen3_next`): layer i is gated softmax attention
+    where (i + 1) % `full_attention_interval` == 0 (or as `layer_types`
+    names it) and a Gated DeltaNet otherwise: `linear_num_key_heads`
+    key and `linear_num_value_heads` value heads of
+    `linear_key_head_dim` / `linear_value_head_dim` behind a depthwise
+    convolution of `linear_conv_kernel_dim` taps. Attention: heads of
+    `head_dim` whose query projection carries an output gate, a
+    per-head QK-norm before rope on the first `partial_rotary_factor`
+    of each head. Every layer's FFN: `num_experts` experts of
+    `moe_intermediate_size`, top-k of a softmax renormalised, beside
+    one shared expert of `shared_expert_intermediate_size` with a
+    sigmoid gate of its own.
+
+    The family's RMSNorms are zero-centred (x * (1 + w)) except the
+    DeltaNet's output norm: an importer stores 1 + w in `ln1_scale`,
+    `ln2_scale`, `ln_f_scale`, `attn_q_norm_scale`, `attn_k_norm_scale`
+    and w itself in `gdn_norm_scale`, so the program's norm is the one
+    it has. `gdn_in`'s columns are [q; k; v; z] and `gdn_ba`'s [b; a],
+    each block in head order (the publisher interleaves them by key-head
+    group: a permutation of columns, the importer's business).
+
+    A cut that is one chip's share of an expert-parallel deployment
+    states it as openPangu's does: `num_experts` is what this chip
+    HOLDS, `reduced.num_experts.published` the router's width,
+    `experts_held.start` the first held expert (0 if absent). The
+    multi-token-prediction block is not part of the next-token logits
+    and is not served."""
+    if hf.get("mlp_only_layers"):
+        raise ValueError("qwen3_next with mlp_only_layers is unsupported")
+    if hf.get("decoder_sparse_step", 1) != 1:
+        raise ValueError("qwen3_next with decoder_sparse_step != 1 is "
+                         "unsupported")
+    if hf.get("attention_bias") or hf.get("rope_scaling"):
+        raise ValueError(
+            "qwen3_next with attention_bias or rope_scaling is unsupported")
+    L = int(hf["num_hidden_layers"])
+    kinds = {"linear_attention": "linear_attention",
+             "full_attention": "attention"}
+    types = hf.get("layer_types") or [
+        "full_attention" if (i + 1) % hf["full_attention_interval"] == 0
+        else "linear_attention" for i in range(L)]
+    unknown = sorted(set(types) - set(kinds))
+    if unknown or len(types) != L:
+        raise ValueError(
+            f"qwen3_next layer_types names {sorted(kinds)} for each of "
+            f"num_hidden_layers={L} layers (got {len(types)} entries, "
+            f"unknown {unknown})")
+    F = int(hf["moe_intermediate_size"])
+    shared = int(hf.get("shared_expert_intermediate_size", 0))
+    if shared % F:
+        raise ValueError(
+            f"qwen3_next shared_expert_intermediate_size {shared} is no "
+            f"multiple of moe_intermediate_size {F}")
+    held = int(hf["num_experts"])
+    routed = int((hf.get("reduced") or {}).get("num_experts", {})
+                 .get("published", held))
+    start = int((hf.get("experts_held") or {}).get("start", 0))
+    return dict(
+        vocab_size=hf["vocab_size"],
+        n_layers=L,
+        n_heads=hf["num_attention_heads"],
+        n_kv_heads=hf.get("num_key_value_heads") or None,
+        head_dim_override=int(hf["head_dim"]),
+        d_model=hf["hidden_size"],
+        d_ff=F,
+        max_seq=hf.get("max_position_embeddings", 4096),
+        variant="llama",
+        rope_theta=float(hf.get("rope_theta", 1e7)),
+        rotary_pct=float(hf.get("partial_rotary_factor", 1.0)),
+        norm_eps=float(hf.get("rms_norm_eps", 1e-6)),
+        tie_embeddings=bool(hf.get("tie_word_embeddings", False)),
+        layer_types=tuple(kinds[t] for t in types),
+        conv_kernel=int(hf["linear_conv_kernel_dim"]),
+        gdn_key_heads=int(hf["linear_num_key_heads"]),
+        gdn_value_heads=int(hf["linear_num_value_heads"]),
+        gdn_key_dim=int(hf["linear_key_head_dim"]),
+        gdn_value_dim=int(hf["linear_value_head_dim"]),
+        attn_output_gate=True,
+        qk_norm=True, qk_norm_per_head=True,
+        n_experts=routed, moe_top_k=hf["num_experts_per_tok"],
+        experts_held=(start, held) if held != routed else None,
+        n_shared_experts=shared // F,
+        shared_expert_gate=shared > 0,
+        moe_norm_topk_prob=bool(hf.get("norm_topk_prob", True)),
         moe_dropless=True,
     )
 

@@ -159,7 +159,7 @@ def test_the_cut_builds_at_published_widths():
     assert cfg.qk_norm and cfg.qk_norm_per_head and cfg.tie_embeddings
     assert cfg.layer_types == ("conv",) + ("attention", "conv", "conv",
                                            "conv") * 3
-    assert (cfg.n_kv_layers, cfg.n_state_layers, cfg.state_width) == \
+    assert (cfg.n_kv_layers, cfg.n_state_layers, cfg.state_width("conv")) == \
         (3, 10, 4096)
     assert cfg.vocab_size == 65536 and cfg.rope_theta == 1e6
     shapes = jax.eval_shape(lambda k: T.init(cfg, k), jax.random.PRNGKey(0))
@@ -184,7 +184,7 @@ def test_the_cut_builds_at_published_widths():
     cache = jax.eval_shape(lambda: M.init_cache(
         cfg, 2049, 128, jnp.bfloat16, state_slots=1024))
     assert [a.shape for a in cache.k] == [(2049, 128, 4, 128)] * 3
-    assert [a.shape for a in cache.state] == [(1024, 4096)] * 10
+    assert [a.shape for (a,) in cache.state] == [(1024, 4096)] * 10
     assert sum(a.size * 2 for a in cache.k + cache.v) / 2049 / 128 == 6144
 
 
@@ -393,7 +393,8 @@ def test_the_scheduler_serves_unequal_sequences_through_reused_slots(model):
     before the first admission too) never reaches the next."""
     eng = _sched_engine(model)
     eng.cache = eng.cache._replace(
-        state=[jnp.full_like(p, jnp.nan) for p in eng.cache.state])
+        state=jax.tree.map(lambda p: jnp.full_like(p, jnp.nan),
+                           eng.cache.state))
     requests = _requests(12)
     s, outputs = _serve(eng, requests)
     assert all(len(o) == n for o, (_, n) in zip(outputs, requests))

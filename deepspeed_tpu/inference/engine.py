@@ -445,6 +445,7 @@ class InferenceEngine:
             self._dequant = lambda p: p
         self._prepare_fn = None
         self._expert_paths: Dict[int, str] = {}  # by program width
+        self._carry_kernels: Dict[int, bool] = {}  # by program width
         self._layer_xform = None
         self._top_xform = None
         # awaited, so each set-up phase's span carries its own time and
@@ -563,6 +564,21 @@ class InferenceEngine:
             self._expert_paths[width] = M.expert_path(
                 width, self.cfg, lp, self._use_kernel, self.mesh)
         return self._expert_paths[width]
+
+    def carry_kernel(self, width: int) -> bool:
+        """Whether a compiled step over `width` token rows runs every
+        state layer's short convolution as the one-pass kernel
+        (ops/pallas/conv_carry.py; M.decode_step's `carry` asks the same
+        of the same shapes): the resolved kernel choice and carry_fits
+        of each layer's pool of carried inputs. False for a model
+        without recurrent state. What the scheduler counts
+        state_carry_kernel_steps by."""
+        if width not in self._carry_kernels:
+            self._carry_kernels[width] = (
+                bool(self.cache.state) and self._use_kernel and all(
+                    M.carry_fits(width, self._dtype, pools[-1])
+                    for pools in self.cache.state))
+        return self._carry_kernels[width]
 
     def refresh_params(self, params: Any) -> None:
         """(Re)point the served weight tree — the hybrid-engine shared-

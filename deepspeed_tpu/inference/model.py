@@ -53,6 +53,7 @@ from ..ops.pallas.expert_stream import (
     grouped_f_tile,
     stream_f_tile,
 )
+from ..ops.pallas.conv_carry import carry_facts, carry_fits, conv_carry
 from ..ops.pallas.gated_delta import (
     gated_delta_chunked,
     gated_delta_step,
@@ -376,8 +377,9 @@ class PagedCache(NamedTuple):
     # of pools [slots, ...] (cfg.state_shapes of the layer's kind);
     # entry s of each is the state of the tracked sequence that holds
     # slot s (ragged.SequenceDescriptor.slot). What an entry holds is
-    # the layer's business: a conv layer ONE pool [slots, width], its
-    # last conv_kernel - 1 inputs, oldest first; a linear-attention
+    # the layer's business: a conv layer ONE pool [slots, conv_kernel
+    # - 1, channels / lanes, lanes], its last conv_kernel - 1 inputs,
+    # oldest first, a slot whole tiles; a linear-attention
     # layer a float32 pool [slots + 1, heads, Dk, Dv] of its heads'
     # matrices (the last slot is the pad rows', ops/pallas/
     # gated_delta.py) and such a pool of carried inputs beside it.
@@ -1123,17 +1125,16 @@ def _short_conv(h1, lp, carry):
     [..., E], the layer's state pool). [B; C; X] = conv_in h1;
     u = B * X; v_t = sum_j taps[:, j] * u_{t-(K-1)+j} (depthwise,
     causal, no bias, no activation; the sum in float32); out =
-    conv_out (C * v). `carry(u)` gives (the K - 1 inputs before each
-    position, oldest first, each shaped as u; the state pool with each
-    sequence's last K - 1 inputs written): the one thing a step over
-    ragged rows and a whole-prompt prefill differ in."""
+    conv_out (C * v). `carry(u, taps)` gives (v in float32; the state
+    pool with each sequence's last K - 1 inputs written): where the
+    K - 1 inputs before each position come from is the one thing a step
+    over ragged rows and a whole-prompt prefill differ in."""
     with jax.named_scope("conv_project"):
         b, c, xg = jnp.split(_wmm("...e,ef->...f", h1, lp["conv_in"]), 3,
                              axis=-1)
         u = b * xg
     with jax.named_scope("conv_state"):
-        past, pool = carry(u)
-    v = _depthwise(past, u, lp["conv_taps"])
+        v, pool = carry(u, lp["conv_taps"])
     with jax.named_scope("conv_out"):
         out = _wmm("...e,ef->...f", c * v.astype(u.dtype), lp["conv_out"])
     return out, (pool,)
@@ -1177,10 +1178,10 @@ def _gated_delta_net(h1, lp, cfg: T.TransformerConfig, carry, recur):
         g = -jnp.exp(lp["gdn_a_log"].astype(f32)) * jax.nn.softplus(
             a + lp["gdn_dt_bias"].astype(f32))
     with jax.named_scope("gdn_conv"):
-        past, conv_pool = carry(u)
+        conv, conv_pool = carry(u, lp["gdn_taps"])
         # the convolution's output in the activations' dtype, as the
         # publisher's; the heads' norms and the rule in float32
-        c = jax.nn.silu(_depthwise(past, u, lp["gdn_taps"])).astype(u.dtype)
+        c = jax.nn.silu(conv).astype(u.dtype)
         q, k, v = jnp.split(c.astype(f32), [Hk * Dk, 2 * Hk * Dk], axis=-1)
         lead = c.shape[:-1]
 
@@ -1230,34 +1231,33 @@ def _recur_prompts(q, k, v, g, beta, pool, slots, n_real):
 
 
 def _state_write(pool, slots, rows, keep):
-    """State pool [slots, W] with rows [N, W] written at slots [N]
-    where `keep` [N]; the others (pad rows, rows that are not their
-    sequence's last of the step) are dropped."""
+    """State pool [slots, K - 1, ...] with rows [N, (K - 1) E] written
+    at slots [N] where `keep` [N]; the others (pad rows, rows that are
+    not their sequence's last of the step) are dropped."""
     idx = jnp.where(keep & (slots >= 0), slots, pool.shape[0])
-    return pool.at[idx].set(rows.astype(pool.dtype), mode="drop")
+    return pool.at[idx].set(
+        rows.reshape(-1, *pool.shape[1:]).astype(pool.dtype), mode="drop")
 
 
 def _carry_rows(u, pool, slots, positions):
-    """`carry` of a step over ragged rows u [S, E]: row r is the token
-    at positions[r] of the sequence holding state slot slots[r] (-1:
-    batch padding); rows of one sequence are adjacent and in order (a
-    prefill chunk), any other row is another sequence. The k-th input
-    before row r is row r - k where that is the same sequence's token
-    k places back, else it was left in the sequence's slot by an
-    earlier step; before the sequence's first token it is ZERO,
-    whatever the slot holds, so a slot needs no clearing between the
-    sequences that take it in turn, nor after a flush. Each sequence's
-    last row of the step leaves its last K - 1 inputs in the slot."""
+    """The K - 1 inputs before each row of a step over ragged rows
+    u [S, E], oldest first, and the pool those rows leave: row r is
+    the token at positions[r] of the sequence holding state slot
+    slots[r] (-1: batch padding); rows of one sequence are adjacent and
+    in order (a prefill chunk), any other row is another sequence. The
+    k-th input before row r is row r - k where that is the same
+    sequence's token k places back, else it was left in the sequence's
+    slot by an earlier step; before the sequence's first token it is
+    ZERO, whatever the slot holds, so a slot needs no clearing between
+    the sequences that take it in turn, nor after a flush. Each
+    sequence's last row of the step leaves its last K - 1 inputs in the
+    slot. With _depthwise, what ops/pallas/conv_carry.py does in one
+    pass, and its oracle."""
     S, E = u.shape
-    K1 = pool.shape[1] // E  # K - 1 inputs carried, oldest first
+    K1 = pool.shape[1]  # K - 1 inputs carried, oldest first
     held = pool[jnp.maximum(slots, 0)].reshape(S, K1, E)
-    row = jnp.arange(S)
-    same = [(jnp.roll(slots, k) == slots) & (jnp.roll(positions, k) + k
-                                            == positions) & (row >= k)
-            for k in range(1, K1 + 1)]
     # rows of its own sequence before row r in this step, up to K - 1
-    run = jnp.sum(jnp.cumprod(jnp.stack(same).astype(jnp.int32), axis=0),
-                  axis=0)
+    run, last = carry_facts(slots, positions, K1)
     past = []
     for k in range(K1, 0, -1):  # oldest first
         here = jnp.roll(u, k, axis=0)
@@ -1267,18 +1267,16 @@ def _carry_rows(u, pool, slots, positions):
         past.append(jnp.where(
             (positions >= k)[:, None],
             jnp.where((run >= k)[:, None], here, there), 0))
-    last = jnp.roll(slots, -1) != slots
-    last = last.at[S - 1].set(True)
     rows = jnp.concatenate([*past[1:], u], axis=-1)
     return past, _state_write(pool, slots, rows, last)
 
 
 def _carry_prompts(u, pool, slots, n_real):
-    """`carry` of a whole-prompt prefill u [B, Tp, E]: a plain causal
-    shift (zeros before the prompt starts), and each prompt's last
-    K - 1 real inputs written to its sequence's slot."""
+    """_carry_rows of a whole-prompt prefill u [B, Tp, E]: a plain
+    causal shift (zeros before the prompt starts), and each prompt's
+    last K - 1 real inputs written to its sequence's slot."""
     B, Tp, E = u.shape
-    K1 = pool.shape[1] // E
+    K1 = pool.shape[1]
     up = jnp.pad(u, ((0, 0), (K1, 0), (0, 0)))  # up[:, t + K1] = u_t
     past = [up[:, K1 - k:K1 - k + Tp] for k in range(K1, 0, -1)]
     # inputs n_real - K1 .. n_real - 1: up[:, n_real .. n_real + K1 - 1]
@@ -1514,9 +1512,12 @@ def decode_step(
         pools = _write_pools(pools, k, v, flat_idx, mesh, use_kernel)
         return _decode_attention(q, pools, *where), pools
 
-    def carry(u, li):
-        return _carry_rows(u, cache.state[cfg.state_index(li)][-1], slots,
-                           positions)
+    def carry(u, taps, li):
+        pool = cache.state[cfg.state_index(li)][-1]
+        if use_kernel and carry_fits(u.shape[0], u.dtype, pool):
+            return conv_carry(u, taps, pool, slots, positions)
+        past, pool = _carry_rows(u, pool, slots, positions)
+        return _depthwise(past, u, taps), pool
 
     def recur(q, k, v, g, beta, li):
         return _recur_rows(q, k, v, g, beta,
@@ -1682,9 +1683,10 @@ def prefill_batch(
             x, last[:, None, None].astype(jnp.int32).repeat(x.shape[-1], axis=2),
             axis=1)[:, 0]
 
-    def carry(u, li):
-        return _carry_prompts(u, cache.state[cfg.state_index(li)][-1], slots,
-                              n_real)
+    def carry(u, taps, li):
+        past, pool = _carry_prompts(
+            u, cache.state[cfg.state_index(li)][-1], slots, n_real)
+        return _depthwise(past, u, taps), pool
 
     def recur(q, k, v, g, beta, li):
         return _recur_prompts(q, k, v, g, beta,

@@ -272,6 +272,11 @@ class ServingScheduler:
             # from the row before and not from a slot
             "state_bytes_moved": 0,
             "gdn_run_tokens": 0,
+            # dispatched steps over rows whose state layers run their
+            # short convolution as the one-pass kernel
+            # (engine.carry_kernel of the program's width); over steps,
+            # 1.0 where every step is one such program
+            "state_carry_kernel_steps": 0,
         }
         self._phases = profiler.Phases("sched", "iteration", PHASES,
                                        sums=self.counters)
@@ -961,7 +966,8 @@ class ServingScheduler:
             parts.append(_Part("wave", sample_rows, tok_dev))
             self.counters["wave_prefills"] += len(wave)
             self._count_tokens(int(n_real.sum()), bp * tp)
-            self._count_state(n_real[:len(wave)].tolist(), reads=False)
+            self._count_state(n_real[:len(wave)].tolist(), bp * tp,
+                              reads=False)
             self._it_rows += int(n_real.sum())
         return parts
 
@@ -991,15 +997,19 @@ class ServingScheduler:
         if cfg.n_state_layers:
             self.counters["state_slots_live"] += self.engine.state.n_tracked
 
-    def _count_state(self, runs: Sequence[int], steps: int = 1,
+    def _count_state(self, runs: Sequence[int], width: int, steps: int = 1,
                      reads: bool = True) -> None:
         """What a dispatched program of a model with recurrent state
         moves of its sequences' slots: `runs` holds the tokens of each
-        sequence it advances. A step over rows reads and writes each
-        slot once a layer (`reads`), a whole-prompt prefill writes it."""
+        sequence it advances, `width` its token rows. A step over rows
+        reads and writes each slot once a layer (`reads`; through the
+        convolution's kernel or not, by its width), a whole-prompt
+        prefill writes it."""
         cfg = self.engine.cfg
         if not cfg.n_state_layers:
             return
+        self.counters["state_carry_kernel_steps"] += (
+            reads and self.engine.carry_kernel(width))
         self.counters["state_bytes_moved"] += (
             len(runs) * steps * (2 if reads else 1)
             * self.engine.state_slot_bytes)
@@ -1085,7 +1095,7 @@ class ServingScheduler:
                    if sample_rows else None)
         ph.mark("commit")
         self._count_tokens(n_rows, sp, ctx)
-        self._count_state([len(c) for _, c, _ in rows])
+        self._count_state([len(c) for _, c, _ in rows], sp)
         return _Part("mixed", sample_rows, tok_dev)
 
     def _dispatch_fused(self, running: List[Request], C: int) -> _Part:
@@ -1144,7 +1154,7 @@ class ServingScheduler:
         for req in running:
             eng.state.commit(req.uid, C)
         self._count_tokens(len(running) * C, width, ctx, steps=C)
-        self._count_state([1] * len(running), steps=C)
+        self._count_state([1] * len(running), width, steps=C)
         self.counters["fused_steps"] += 1
         return _Part("fused", sample_rows, gen, n_steps=C)
 

@@ -487,21 +487,30 @@ class TransformerConfig:
         return (2 * self.gdn_key_heads * self.gdn_key_dim
                 + self.gdn_value_heads * self.gdn_value_dim)
 
+    def conv_channels(self, kind: str) -> int:
+        """Channels a state layer's depthwise convolution runs over:
+        d_model ('conv') or gdn_conv_dim ('linear_attention')."""
+        return self.d_model if kind == "conv" else self.gdn_conv_dim
+
     def state_width(self, kind: str) -> int:
         """Values one sequence carries as the last conv_kernel - 1
-        inputs, oldest first, of a state layer's depthwise convolution:
-        over d_model channels ('conv') or gdn_conv_dim
-        ('linear_attention')."""
-        return (self.conv_kernel - 1) * (
-            self.d_model if kind == "conv" else self.gdn_conv_dim)
+        inputs, oldest first, of a state layer's depthwise
+        convolution."""
+        return (self.conv_kernel - 1) * self.conv_channels(kind)
 
     def state_shapes(self, kind: str):
         """What one sequence carries in one state layer of `kind`, as
         ((shape, dtype), ...) of its slot in each of the layer's pools
         (dtype None: the cache's): the convolution's carried inputs,
         and before them, for 'linear_attention', a float32 matrix a
-        value head."""
-        carried = ((self.state_width(kind),), None)
+        value head. The carried inputs are [conv_kernel - 1, channels]
+        with the channels folded into whole lanes where they are some
+        (state_width values all the same): a slot is then whole
+        (sublane, lane) tiles on the chip and one copy moves it
+        (ops/pallas/conv_carry.py)."""
+        channels = self.conv_channels(kind)
+        lanes = 128 if channels % 128 == 0 else channels
+        carried = ((self.conv_kernel - 1, channels // lanes, lanes), None)
         if kind == "conv":
             return (carried,)
         return (((self.gdn_value_heads, self.gdn_key_dim,

@@ -184,7 +184,7 @@ def test_the_cut_builds_at_published_widths():
     cache = jax.eval_shape(lambda: M.init_cache(
         cfg, 2049, 128, jnp.bfloat16, state_slots=1024))
     assert [a.shape for a in cache.k] == [(2049, 128, 4, 128)] * 3
-    assert [a.shape for (a,) in cache.state] == [(1024, 4096)] * 10
+    assert [a.shape for (a,) in cache.state] == [(1024, 2, 16, 128)] * 10
     assert sum(a.size * 2 for a in cache.k + cache.v) / 2049 / 128 == 6144
 
 
@@ -533,15 +533,45 @@ def test_the_set_up_spans_name_the_layers_by_kind(model):
     assert programs and all(s.ids["state_layers"] == 7 for s in programs)
 
 
-def test_the_scopes_of_the_operator_are_in_the_program(model):
-    eng = _engine(model)
-    text = eng._decode_fn(8, False).lower(
+def _step_text(eng):
+    return eng._decode_fn(8, False).lower(
         eng.params, eng.cache, *(eng._dev(np.zeros(s, np.int32)) for s in
                                  ((8,), (8, eng.config.blocks_per_seq), (8,))),
         *eng.state_args(np.zeros((8,), np.int32))).as_text(debug_info=True)
+
+
+def test_the_scopes_of_the_operator_are_in_the_program(model):
+    text = _step_text(_engine(model))
     for scope in ("short_conv/conv_project", "short_conv/conv_state",
                   "short_conv/conv_out", "attention"):
         assert scope in text, scope
+
+
+@pytest.mark.usefixtures("pallas_interpret")
+def test_the_convolution_kernel_serves_what_the_xla_path_serves(model):
+    """The step program with its convolutions as the one-pass kernel
+    (ops/pallas/conv_carry.py, under `conv_state`) against decode_impl
+    'xla' (_carry_rows + _depthwise): the same logits over a prefill, a
+    chunk and single steps, the same served tokens through reused
+    slots, and every step of the schedule counted where the kernel ran
+    and none where it did not. (One width of program throughout: the
+    interpreter's kernels are slow to trace.)"""
+    eng, xla = _sched_engine(model), _sched_engine(model, decode_impl="xla")
+    assert eng.resolved_impl == "pallas" and eng.carry_kernel(8)
+    assert xla.resolved_impl == "xla" and not xla.carry_kernel(8)
+    assert "short_conv/conv_state/jit(_conv_carry)" in _step_text(eng)
+    assert "jit(_conv_carry)" not in _step_text(xla)
+    got, want, _, _ = _feeds(model, eng, [21], [5], 2, seed=6)
+    oracle, _, _, _ = _feeds(model, xla, [21], [5], 2, seed=6)
+    assert np.abs(got - oracle).max() < LOGITS_ATOL
+    assert np.abs(got - want).max() < LOGITS_ATOL
+    requests = [(p[:12], n) for p, n in _requests(8, seed=8)]
+    s, served = _serve(eng, requests, max_num_batched_tokens=8)
+    sx, served_xla = _serve(xla, requests, max_num_batched_tokens=8)
+    assert served == served_xla
+    assert s.counters["state_slot_resets"] == 8  # through 6 slots
+    assert s.counters["state_carry_kernel_steps"] == s.counters["steps"] > 0
+    assert sx.counters["state_carry_kernel_steps"] == 0 < sx.counters["steps"]
 
 
 # -- head dim 64: packed pools through the unchanged kernels --------------

@@ -177,7 +177,7 @@ def test_the_cut_builds_at_published_widths():
     assert not cfg.tie_embeddings and cfg.vocab_size == 18992
     assert (cfg.n_kv_layers, cfg.n_state_layers) == (3, 9)
     assert cfg.state_shapes("linear_attention") == (
-        ((32, 128, 128), jnp.float32), ((3 * 8192,), None))
+        ((32, 128, 128), jnp.float32), ((3, 8192 // 128, 128), None))
     shapes = jax.eval_shape(lambda k: T.init(cfg, k), jax.random.PRNGKey(0))
     assert shapes["layers"]["w_in"].shape == (12, 64, 2048, 512)
     assert shapes["layers"]["w_router"].shape == (12, 2048, 512)
@@ -207,7 +207,7 @@ def test_the_cut_builds_at_published_widths():
     assert [tuple((a.shape, a.dtype) for a in pools)
             for pools in cache.state] == [
         (((257, 32, 128, 128), jnp.float32),
-         ((256, 24576), jnp.bfloat16))] * 9
+         ((256, 3, 64, 128), jnp.bfloat16))] * 9
     assert sum(a.size * 2 for a in cache.k + cache.v) / 1025 / 128 == 6144
     # what the engine counts before it allocates
     pools = E.pool_bytes(cfg, E.InferenceConfig(**hf["serve"]["engine"]),
@@ -434,6 +434,39 @@ def test_the_engine_with_kernels_matches_the_reference(model):
     assert np.abs(got - want).max() < LOGITS_ATOL, np.abs(got - want).max(-1)
 
 
+def _step_text(eng):
+    return eng._decode_fn(8, False).lower(
+        eng.params, eng.cache, *(eng._dev(np.zeros(s, np.int32)) for s in
+                                 ((8,), (8, eng.config.blocks_per_seq), (8,))),
+        *eng.state_args(np.zeros((8,), np.int32))).as_text(debug_info=True)
+
+
+@pytest.mark.usefixtures("pallas_interpret")
+def test_the_convolution_kernel_serves_what_the_xla_path_serves(model):
+    """The step program with its convolutions as the one-pass kernel
+    (ops/pallas/conv_carry.py, under `gdn_conv`) against decode_impl
+    'xla' (_carry_rows + _depthwise): the same logits over a prefill, a
+    chunk and single steps, the same served tokens, and every step of
+    the schedule counted where the kernel ran and none where it did
+    not. (One width of program throughout: the interpreter's kernels
+    are slow to trace.)"""
+    eng, xla = _sched_engine(model), _sched_engine(model, decode_impl="xla")
+    assert eng.resolved_impl == "pallas" and eng.carry_kernel(8)
+    assert xla.resolved_impl == "xla" and not xla.carry_kernel(8)
+    assert "linear_attention/gdn_conv/jit(_conv_carry)" in _step_text(eng)
+    assert "jit(_conv_carry)" not in _step_text(xla)
+    got, want, _, _ = _feeds(model, eng, [21], [5], 2, seed=6)
+    oracle, _, _, _ = _feeds(model, xla, [21], [5], 2, seed=6)
+    assert np.abs(got - oracle).max() < LOGITS_ATOL
+    assert np.abs(got - want).max() < LOGITS_ATOL
+    requests = [(p[:12], 3) for p, _ in _requests(2, seed=8)]
+    s, served = _serve(eng, requests, max_num_batched_tokens=8)
+    sx, served_xla = _serve(xla, requests, max_num_batched_tokens=8)
+    assert served == served_xla
+    assert s.counters["state_carry_kernel_steps"] == s.counters["steps"] > 0
+    assert sx.counters["state_carry_kernel_steps"] == 0 < sx.counters["steps"]
+
+
 # -- through the scheduler: slots taken, reused, never cleared -------------
 
 def _requests(n, seed=5):
@@ -605,11 +638,7 @@ def test_pools_that_exceed_the_device_are_refused_with_the_three_numbers(
 
 
 def test_the_scopes_of_the_operator_are_in_the_program(model):
-    eng = _engine(model)
-    text = eng._decode_fn(8, False).lower(
-        eng.params, eng.cache, *(eng._dev(np.zeros(s, np.int32)) for s in
-                                 ((8,), (8, eng.config.blocks_per_seq), (8,))),
-        *eng.state_args(np.zeros((8,), np.int32))).as_text(debug_info=True)
+    text = _step_text(_engine(model))
     for scope in ("linear_attention/gdn_project", "linear_attention/gdn_conv",
                   "linear_attention/gdn_state", "linear_attention/gdn_out",
                   "attention/attn_gate", "mlp/moe_shared"):

@@ -60,6 +60,13 @@ from ..ops.pallas.gated_delta import (
     gated_delta_step_xla,
     step_fits,
 )
+from ..ops.pallas.ssm_state import (
+    pack_state,
+    ssm_chunked,
+    ssm_step,
+    ssm_step_fits,
+    ssm_step_xla,
+)
 from ..ops.pallas.paged_attention import (
     fused_write_fits,
     kv_pack,
@@ -260,12 +267,15 @@ def _wmm(eq: str, x, w):
     return jnp.einsum(eq, x, w.astype(x.dtype))
 
 
-def _embed_rows(embed, tokens):
+def _embed_rows(embed, tokens, multiplier: float = 1.0):
+    """The tokens' rows of the embedding, times cfg.embedding_multiplier
+    where the model has one."""
     if isinstance(embed, ChannelQuantWeight):
         dt = jnp.dtype(embed.dtype_name)
         return (embed.q[tokens].astype(dt)
                 * embed.scale[tokens][..., None].astype(dt))
-    return embed[tokens]
+    rows = embed[tokens]
+    return rows if multiplier == 1.0 else rows * multiplier
 
 
 def _lm_logits(x, params, cfg: T.TransformerConfig):
@@ -277,8 +287,11 @@ def _lm_logits(x, params, cfg: T.TransformerConfig):
         if isinstance(emb, ChannelQuantWeight):
             y = jnp.einsum("...e,ve->...v", x, emb.q.astype(x.dtype))
             return y.astype(jnp.float32) * emb.scale
-        return jnp.einsum("...e,ve->...v", x, emb.astype(x.dtype)
-                          ).astype(jnp.float32)
+        y = jnp.einsum("...e,ve->...v", x, emb.astype(x.dtype)
+                       ).astype(jnp.float32)
+        # Granite divides its logits (a model with that scalar is
+        # tied, 16-bit or float32, on one device)
+        return y if cfg.logits_scaling == 1.0 else y / cfg.logits_scaling
     head = params["lm_head"]
     if isinstance(head, ChannelQuantWeight):
         y = jnp.einsum("...e,ev->...v", x, head.q.astype(x.dtype))
@@ -382,7 +395,10 @@ class PagedCache(NamedTuple):
     # oldest first, a slot whole tiles; a linear-attention
     # layer a float32 pool [slots + 1, heads, Dk, Dv] of its heads'
     # matrices (the last slot is the pad rows', ops/pallas/
-    # gated_delta.py) and such a pool of carried inputs beside it.
+    # gated_delta.py) and such a pool of carried inputs beside it; a
+    # state-space layer the same two, its matrices transposed and
+    # packed [slots + 1, heads / pack, N, pack P] (ops/pallas/
+    # ssm_state.py).
     # Another kind of state is another shape here, not another manager.
     # Not paged: pages travel (COW, handoff, spill) WITHOUT it, which is
     # why the engine refuses those for a model that has any.
@@ -418,8 +434,8 @@ def init_cache(
             "a latent cache, and a cache beside recurrent state, is "
             "bf16/f32 on one device: no int8 pool and no mesh")
     def state_pools(kind):
-        # the carried inputs, and before them the delta rule's matrices,
-        # whose pool holds one slot more: the pad rows' (gated_delta_step)
+        # the carried inputs, and before them the heads' matrices, whose
+        # pool holds one slot more: the pad rows' (state_step_call)
         *matrices, (carried, _) = cfg.state_shapes(kind)
         return (*(jnp.zeros((state_slots + 1, *shape), dt)
                   for shape, dt in matrices),
@@ -932,6 +948,9 @@ def _ffn_residual(x, attn_out, h1, lp, cfg: T.TransformerConfig,
     the Falcon/Phi parallel form where the FFN reads ln2(x) or the
     shared ln1 output h1), under the scopes the training forward
     names (`norm2`, `mlp`)."""
+    m = cfg.residual_multiplier
+    if m != 1.0:  # Granite: both branches of every layer, before the add
+        attn_out = attn_out * m
     if not cfg.parallel_residual:
         x = x + attn_out
     if cfg.parallel_residual and cfg.shared_ln:
@@ -945,6 +964,8 @@ def _ffn_residual(x, attn_out, h1, lp, cfg: T.TransformerConfig,
                  use_kernel, mesh).reshape(x.shape)
         if cfg.sandwich_norm:
             y = T._norm(y, lp["ln2_post_scale"], None, cfg)
+        if m != 1.0:
+            y = y * m
     return x + attn_out + y if cfg.parallel_residual else x + y
 
 
@@ -1031,18 +1052,22 @@ def _layer(x, lp, li: int, positions, cfg: T.TransformerConfig, mesh, attend,
            recur=None):
     """One serving layer over [..., E] activations (decode rows [S, E],
     prefill prompts [B, Tp, E]): norm1, then the layer's operator by its
-    kind (cfg.layer_kind(li)) and the FFN tail. A 'conv' layer: the
-    gated short convolution (_short_conv) with `carry(u, li)` handed in
-    by the caller, as `attend` is: where the inputs before this one
-    come from and how the sequence's state row is left. A
-    'linear_attention' layer: the Gated DeltaNet (_gated_delta_net)
-    with the same `carry` for its convolution and `recur(q, k, v, g,
-    beta, li)`, how the heads' matrices advance (a step over ragged
-    rows, or a whole prompt's chunked scan). An attention
+    kind (cfg.layer_kind(li)) and the FFN tail. A layer that carries
+    state runs its kind's operator (_STATE_OPERATORS) under its kind's
+    scope. A 'conv' layer: the gated short convolution (_short_conv)
+    with `carry(u, li)` handed in by the caller, as `attend` is: where
+    the inputs before this one come from and how the sequence's state
+    row is left. A 'linear_attention' layer: the Gated DeltaNet
+    (_gated_delta_net) with the same `carry` for its convolution and
+    `recur(args, li)`, how the heads' matrices advance (a step over
+    ragged rows, or a whole prompt's chunked scan). A 'state_space'
+    layer: the Mamba-2 mixer (_state_space), with both. An attention
     layer: the QKV projection (fused w_qkv
     or split, bias or none; with the output gate's, cfg.attn_output_gate),
     QK-norm, rope at `positions` (the
-    second-to-last axis of q/k: [S] or [Tp]), the head constraints,
+    second-to-last axis of q/k: [S] or [Tp]; none where the model has
+    no positions), a softmax scale of its own folded into q, the head
+    constraints,
     `attend(q, k, v, li, alibi, lp) -> (att, layer_cache)` handed in by the
     caller (the ONE thing the two sites differ in: what attention runs
     and how the new rows reach the cache), the output projection and
@@ -1055,13 +1080,10 @@ def _layer(x, lp, li: int, positions, cfg: T.TransformerConfig, mesh, attend,
             T._norm(x, lp["ln1_scale"], lp.get("ln1_bias"), cfg), cfg)
     kind = cfg.layer_kind(li)
     if kind != "attention":
-        if kind == "conv":
-            with jax.named_scope("short_conv"):
-                out, state = _short_conv(h1, lp, partial(carry, li=li))
-        else:
-            with jax.named_scope("linear_attention"):
-                out, state = _gated_delta_net(
-                    h1, lp, cfg, partial(carry, li=li), partial(recur, li=li))
+        scope, operator = _STATE_OPERATORS[kind]
+        with jax.named_scope(scope):
+            out, state = operator(h1, lp, cfg, partial(carry, li=li),
+                                  partial(recur, li=li))
         return _ffn_residual(x, out, h1, lp, cfg, census_cb, use_kernel,
                              mesh), state
     if cfg.is_latent:
@@ -1098,6 +1120,14 @@ def _layer(x, lp, li: int, positions, cfg: T.TransformerConfig, mesh, attend,
         if cfg.use_rope:
             q = _rope_at(q, positions, cfg)
             k = _rope_at(k, positions, cfg)
+        if cfg.attention_multiplier is not None:
+            # a softmax scale that is not head_dim^-0.5 (Granite's
+            # 1/128): q is scaled by what the kernels' own head_dim^-0.5
+            # lacks, ONE multiply on the step's [rows, H, D], so that
+            # flash attention (which hard-codes its scale), the paged
+            # walk, both oracles and every other family's program text
+            # stay as they are; the cached K is the unscaled one
+            q = q * (cfg.attention_multiplier * cfg.head_dim ** 0.5)
         heads = (None,) * (q.ndim - 2) + ("model", None)
         q = _cons(q, mesh, *heads)
         k = _cons(k, mesh, *heads)
@@ -1120,7 +1150,7 @@ def _layer(x, lp, li: int, positions, cfg: T.TransformerConfig, mesh, attend,
 # the gated short convolution, and the state its sequences carry
 # ---------------------------------------------------------------------------
 
-def _short_conv(h1, lp, carry):
+def _short_conv(h1, lp, cfg, carry, recur=None):
     """Normed activations h1 [..., E] -> (the operator's output
     [..., E], the layer's state pool). [B; C; X] = conv_in h1;
     u = B * X; v_t = sum_j taps[:, j] * u_{t-(K-1)+j} (depthwise,
@@ -1161,7 +1191,7 @@ def _gated_delta_net(h1, lp, cfg: T.TransformerConfig, carry, recur):
     q and k of the key heads repeated to the value heads, each head's
     L2-normalised (x rsqrt(sum x^2 + 1e-6)), q scaled by Dk^-0.5; the
     delta rule per value head (ops/pallas/gated_delta.py) through
-    `recur(q, k, v, g, beta)` -> (o float32, the matrices' pool): the
+    `recur((q, k, v, g, beta))` -> (o float32, the matrices' pool): the
     one thing a step over ragged rows and a whole-prompt prefill differ
     in, beside `carry` (as _short_conv's); then o <- rms(o) *
     gdn_norm_scale * silu(z) over each head's Dv values (a PLAIN scale;
@@ -1193,7 +1223,7 @@ def _gated_delta_net(h1, lp, cfg: T.TransformerConfig, carry, recur):
         q, k = unit(q) * Dk ** -0.5, unit(k)
         v = v.reshape(*lead, Hv, Dv)
     with jax.named_scope("gdn_state"):
-        o, pool = recur(q, k, v, g, beta)
+        o, pool = recur((q, k, v, g, beta))
     with jax.named_scope("gdn_out"):
         o = o * jax.lax.rsqrt(
             jnp.mean(o * o, -1, keepdims=True) + cfg.norm_eps)
@@ -1203,31 +1233,109 @@ def _gated_delta_net(h1, lp, cfg: T.TransformerConfig, carry, recur):
     return out, (pool, conv_pool)
 
 
-def _recur_rows(q, k, v, g, beta, pool, slots, positions, use_kernel: bool):
+def _state_space(h1, lp, cfg: T.TransformerConfig, carry, recur):
+    """The state-space (Mamba-2) mixer: normed activations h1 [..., E]
+    -> (its output [..., E], the layer's state pools (the heads'
+    matrices, the convolution's carried inputs)).
+
+    [z; x; B; C; dt] = ssm_in h1 (no bias); [x; B; C] <- silu(causal
+    depthwise convolution of conv_kernel taps + ssm_conv_bias, zeros
+    before the sequence starts), in the activations' dtype as the
+    publisher's; dt <- softplus(dt + dt_bias), A = -exp(a_log),
+    float32, one of each a head, no clamp; the recurrence per head
+    (ops/pallas/ssm_state.py: S <- exp(dt A) S + (dt x) B^T, y = S C)
+    through `recur((x, dt, A, B, C))` -> (y float32, the matrices'
+    pool), with `carry` as _short_conv's; y += D x (ssm_d);
+    then y <- rms(y * silu(z)) * ssm_norm_scale over ALL the heads'
+    values together (the gate BEFORE the norm: the DeltaNet's order is
+    the other way round) and ssm_out."""
+    Hs, P, N = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state_dim
+    I, f32 = cfg.ssm_inner, jnp.float32
+    with jax.named_scope("ssm_project"):
+        mixed = _wmm("...e,ef->...f", h1, lp["ssm_in"])
+        z, u, dt = jnp.split(mixed, [I, I + cfg.ssm_conv_dim], axis=-1)
+        dt = jax.nn.softplus(dt.astype(f32) + lp["ssm_dt_bias"].astype(f32))
+        A = -jnp.exp(lp["ssm_a_log"].astype(f32))
+    with jax.named_scope("ssm_conv"):
+        conv, conv_pool = carry(u, lp["ssm_taps"])
+        c = jax.nn.silu(conv + lp["ssm_conv_bias"].astype(f32)
+                        ).astype(u.dtype).astype(f32)
+        x, Bm, Cm = jnp.split(c, [I, I + N], axis=-1)
+        x = x.reshape(*x.shape[:-1], Hs, P)
+    with jax.named_scope("ssm_state"):
+        y, pool = recur((x, dt, A, Bm, Cm))
+        y = y + lp["ssm_d"].astype(f32)[:, None] * x
+    with jax.named_scope("ssm_gate_norm"):
+        y = y.reshape(z.shape) * jax.nn.silu(z.astype(f32))
+        y = (y * jax.lax.rsqrt(jnp.mean(y * y, -1, keepdims=True)
+                               + cfg.norm_eps)
+             * lp["ssm_norm_scale"].astype(f32)).astype(h1.dtype)
+    with jax.named_scope("ssm_out"):
+        out = _wmm("...f,fe->...e", y, lp["ssm_out"])
+    return out, (pool, conv_pool)
+
+
+# a layer that carries state, by its kind: its scope, its operator
+# (h1, lp, cfg, carry, recur) -> (out, the layer's state pools)
+_STATE_OPERATORS = {
+    "conv": ("short_conv", _short_conv),
+    "linear_attention": ("linear_attention", _gated_delta_net),
+    "state_space": ("state_space", _state_space),
+}
+
+
+def _recur_rows(kind: str, args, pool, slots, positions, use_kernel: bool):
     """`recur` of a step over ragged rows [S, H, ...] (the rows of
-    _carry_rows): each run advances its sequence's matrices from its
-    slot, from zero where the run starts at position 0."""
-    step = (gated_delta_step if use_kernel and step_fits(q.shape[0], pool)
-            else gated_delta_step_xla)
-    return step(q, k, v, g, beta, pool, slots, positions)
+    _carry_rows), for a layer of `kind` whose heads carry a matrix:
+    each run advances its sequence's matrices from its slot, from zero
+    where the run starts at position 0; through the kind's kernel
+    where kernels run and it takes the shapes, else its loop in XLA."""
+    fits, kernel, xla = _STEP_OF[kind]
+    step = kernel if use_kernel and fits(args[0].shape[0], pool) else xla
+    return step(*args, pool, slots, positions)
 
 
-def _recur_prompts(q, k, v, g, beta, pool, slots, n_real):
-    """`recur` of a whole-prompt prefill [B, Tp, H, ...]: the chunked
-    scan from a zero state, the padding after each prompt's n_real
-    tokens made to leave the state as it is, and each prompt's last
-    state copied into its sequence's slot (pad prompts' into the pool's
-    last)."""
-    B, Tp = g.shape[:2]
+def _recur_prompts(kind: str, args, pool, slots, n_real, cfg):
+    """`recur` of a whole-prompt prefill [B, Tp, H, ...]: the kind's
+    chunked scan from a zero state, the padding after each prompt's
+    n_real tokens made to leave the state as it is, and each prompt's
+    last state copied into its sequence's slot (pad prompts' into the
+    pool's last)."""
+    B, Tp = args[0].shape[:2]
     real = (jnp.arange(Tp)[None, :] < n_real[:, None])[..., None]
-    o, last = gated_delta_chunked(
-        q, jnp.where(real[..., None], k, 0.0), v, jnp.where(real, g, 0.0),
-        jnp.where(real, beta, 0.0))
+    o, last = _SCAN_OF[kind](args, real, cfg)
     where = jnp.where((n_real > 0) & (slots >= 0), slots, pool.shape[0] - 1)
-    for i in range(B):  # a 2 MiB entry each: slice updates, no scatter
+    for i in range(B):  # megabytes an entry: slice updates, no scatter
         pool = jax.lax.dynamic_update_slice(
             pool, last[i][None].astype(pool.dtype), (where[i], 0, 0, 0))
     return o, pool
+
+
+def _gdn_scan(args, real, cfg):
+    """The delta rule's chunked scan over whole prompts; a pad token
+    (not `real` [B, Tp, 1]) has k = 0, g = 0, beta = 0."""
+    q, k, v, g, beta = args
+    return gated_delta_chunked(
+        q, jnp.where(real[..., None], k, 0.0), v, jnp.where(real, g, 0.0),
+        jnp.where(real, beta, 0.0))
+
+
+def _ssm_scan(args, real, cfg):
+    """The state-space layer's chunked scan over whole prompts, its
+    last states in the pool's layout; a pad token has dt = 0."""
+    x, dt, A, Bm, Cm = args
+    y, last = ssm_chunked(x, jnp.where(real, dt, 0.0), A, Bm, Cm,
+                          chunk=cfg.ssm_chunk)
+    return y, pack_state(last, cfg.ssm_pack)
+
+
+# a kind whose heads carry a matrix: whether its step kernel takes
+# (rows, pool), the kernel, the same step in XLA; its whole-prompt scan
+_STEP_OF = {
+    "linear_attention": (step_fits, gated_delta_step, gated_delta_step_xla),
+    "state_space": (ssm_step_fits, ssm_step, ssm_step_xla),
+}
+_SCAN_OF = {"linear_attention": _gdn_scan, "state_space": _ssm_scan}
 
 
 def _state_write(pool, slots, rows, keep):
@@ -1237,6 +1345,25 @@ def _state_write(pool, slots, rows, keep):
     idx = jnp.where(keep & (slots >= 0), slots, pool.shape[0])
     return pool.at[idx].set(
         rows.reshape(-1, *pool.shape[1:]).astype(pool.dtype), mode="drop")
+
+
+def _slot_wide(carry, cache: "PagedCache", cfg: T.TransformerConfig):
+    """`carry(u, taps, li)` of a serving site from its `carry(u, taps,
+    pool)` over layer li's pool of carried inputs. Where a slot of that
+    pool is wider than the layer's channels (cfg.state_shapes pads it
+    to whole tiles: Granite's 8,448 in 9,216), the inputs and the taps
+    are padded to the slot with zeros and the sum is cut back, so that
+    everything between works at ONE width, the slot's."""
+    def at_layer(u, taps, li):
+        pool = cache.state[cfg.state_index(li)][-1]
+        E, wide = u.shape[-1], pool.shape[-2] * pool.shape[-1]
+        if wide == E:
+            return carry(u, taps, pool)
+        pad = lambda a, axis: jnp.pad(
+            a, [(0, wide - E) if i == axis else (0, 0) for i in range(a.ndim)])
+        conv, pool = carry(pad(u, u.ndim - 1), pad(taps, 0), pool)
+        return conv[..., :E], pool
+    return at_layer
 
 
 def _carry_rows(u, pool, slots, positions):
@@ -1406,7 +1533,7 @@ def _forward(params, tokens, positions, cfg: T.TransformerConfig, mesh,
     if not is_prepared(params):
         params = prepare(params, cfg, fuse=mesh is None)
     with jax.named_scope("embed"):
-        x = _embed_rows(params["embed"], tokens)
+        x = _embed_rows(params["embed"], tokens, cfg.embedding_multiplier)
         if cfg.use_learned_pos:
             x = x + params["pos_embed"][positions].astype(x.dtype)
         if cfg.embedding_layernorm:
@@ -1512,15 +1639,15 @@ def decode_step(
         pools = _write_pools(pools, k, v, flat_idx, mesh, use_kernel)
         return _decode_attention(q, pools, *where), pools
 
-    def carry(u, taps, li):
-        pool = cache.state[cfg.state_index(li)][-1]
+    @partial(_slot_wide, cache=cache, cfg=cfg)
+    def carry(u, taps, pool):
         if use_kernel and carry_fits(u.shape[0], u.dtype, pool):
             return conv_carry(u, taps, pool, slots, positions)
         past, pool = _carry_rows(u, pool, slots, positions)
         return _depthwise(past, u, taps), pool
 
-    def recur(q, k, v, g, beta, li):
-        return _recur_rows(q, k, v, g, beta,
+    def recur(args, li):
+        return _recur_rows(cfg.layer_kind(li), args,
                            cache.state[cfg.state_index(li)][0], slots,
                            positions, use_kernel)
 
@@ -1683,15 +1810,15 @@ def prefill_batch(
             x, last[:, None, None].astype(jnp.int32).repeat(x.shape[-1], axis=2),
             axis=1)[:, 0]
 
-    def carry(u, taps, li):
-        past, pool = _carry_prompts(
-            u, cache.state[cfg.state_index(li)][-1], slots, n_real)
+    @partial(_slot_wide, cache=cache, cfg=cfg)
+    def carry(u, taps, pool):
+        past, pool = _carry_prompts(u, pool, slots, n_real)
         return _depthwise(past, u, taps), pool
 
-    def recur(q, k, v, g, beta, li):
-        return _recur_prompts(q, k, v, g, beta,
+    def recur(args, li):
+        return _recur_prompts(cfg.layer_kind(li), args,
                               cache.state[cfg.state_index(li)][0], slots,
-                              n_real)
+                              n_real, cfg)
 
     return _forward(params, tokens, positions, cfg, mesh, attend,
                     fetch_layer, census_cb, head_rows=last_real,

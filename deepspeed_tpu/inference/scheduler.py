@@ -100,6 +100,10 @@ PHASES = {"tick": "tick_s", "admit": "admit_s", "select": "select_s",
 SLOW_ITERATION_NS = 50_000_000
 # finished requests whose TTFT/TPOT metrics() percentiles are taken over
 LATENCY_WINDOW = 4096
+# the counter of run tokens (ServingScheduler._count_state), by the
+# kind of layer whose heads carry a matrix from row to row of a run
+_RUN_TOKENS = {"linear_attention": "gdn_run_tokens",
+               "state_space": "ssm_run_tokens"}
 
 
 @dataclasses.dataclass
@@ -266,12 +270,14 @@ class ServingScheduler:
             # bytes of their slots the dispatched programs' sequences
             # read and wrote, over all state layers (a step over rows
             # reads and writes each live sequence's slot once a layer,
-            # a whole-prompt prefill writes it); and, where some layers
-            # are linear attention, the tokens of runs longer than one
-            # (prefill chunks, whole prompts): rows whose matrices come
-            # from the row before and not from a slot
+            # a whole-prompt prefill writes it); and, where some layers'
+            # heads carry a matrix (_RUN_TOKENS: by their kind), the
+            # tokens of runs longer than one (prefill chunks, whole
+            # prompts): rows whose matrices come from the row before
+            # and not from a slot
             "state_bytes_moved": 0,
             "gdn_run_tokens": 0,
+            "ssm_run_tokens": 0,
             # dispatched steps over rows whose state layers run their
             # short convolution as the one-pass kernel
             # (engine.carry_kernel of the program's width); over steps,
@@ -1013,8 +1019,9 @@ class ServingScheduler:
         self.counters["state_bytes_moved"] += (
             len(runs) * steps * (2 if reads else 1)
             * self.engine.state_slot_bytes)
-        if "linear_attention" in cfg.layer_types:
-            self.counters["gdn_run_tokens"] += sum(r for r in runs if r > 1)
+        for counter in {_RUN_TOKENS[k] for k in cfg.layer_types
+                        if k in _RUN_TOKENS}:
+            self.counters[counter] += sum(r for r in runs if r > 1)
 
     def _dispatch_mixed(self, rows, ahead_of: Optional[_Step] = None,
                         src: Optional[Dict[int, int]] = None

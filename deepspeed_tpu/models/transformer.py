@@ -222,18 +222,31 @@ class TransformerConfig:
     # token (S <- exp(g) S; S <- S + k (beta (v - S^T k))^T), q and k
     # of gdn_key_heads heads repeated to the value heads, behind a
     # causal depthwise convolution of conv_kernel taps (then silu) over
-    # the channels of [q; k; v]. A sequence carries fixed-size state
-    # from token to token in a conv or linear-attention layer, whatever
-    # its length (state_shapes), and K/V in the attention layers alone.
+    # the channels of [q; k; v]; or "state_space", the Mamba-2 mixer
+    # (inference/model.py _state_space): ssm_heads heads of ssm_head_dim
+    # that each carry a float32 [ssm_head_dim, ssm_state_dim] matrix
+    # (S <- exp(dt A) S + (dt x) B^T, y = S C + D x: ONE scalar decay a
+    # head a token, B and C one vector a token for all the heads),
+    # behind a causal depthwise convolution of conv_kernel taps WITH a
+    # bias (then silu) over the channels of [x; B; C]. A sequence carries
+    # fixed-size state from token to token in a conv, linear-attention
+    # or state-space layer, whatever its length (state_shapes), and K/V
+    # in the attention layers alone.
     # The operators' weights are top-level stacks by kind (`conv_<name>`
-    # [n conv layers, ...], `attn_<name>`, `gdn_<name>`); `layers` (and
-    # `dense_<name>`) keep what every layer has, its norms and FFN.
+    # [n conv layers, ...], `attn_<name>`, `gdn_<name>`, `ssm_<name>`);
+    # `layers` (and `dense_<name>`) keep what every layer has, its norms
+    # and FFN.
     layer_types: Optional[Tuple[str, ...]] = None
     conv_kernel: int = 0
     gdn_key_heads: int = 0
     gdn_value_heads: int = 0
     gdn_key_dim: int = 0
     gdn_value_dim: int = 0
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state_dim: int = 0
+    # tokens the whole-prompt scan of a state-space layer takes at a time
+    ssm_chunk: int = 256
     # attention's output gate: W_q projects each head to [q; gate]
     # (leaf `wq_gate` beside `wq`), att <- att * sigmoid(gate) before W_o
     attn_output_gate: bool = False
@@ -245,6 +258,18 @@ class TransformerConfig:
     # CHOICE of the top-k alone (the weights stay the unbiased scores):
     # leaf `expert_bias` [n_experts] in every routed layer
     moe_expert_bias: bool = False
+    # ---- Granite's scalars and its attention without positions.
+    # SERVING ONLY. position_embedding "none": no rotary and no learned
+    # positions, nothing (None: the variant's). The embedding's rows
+    # times embedding_multiplier; both branches of every layer times
+    # residual_multiplier before they are added (x + m op(norm1 x),
+    # then x + m ffn(norm2 x)); the logits DIVIDED by logits_scaling;
+    # attention's softmax scale (None: head_dim^-0.5).
+    position_embedding: Optional[str] = None
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
+    attention_multiplier: Optional[float] = None
 
     def __post_init__(self):
         if self.layer_types is not None:
@@ -254,10 +279,16 @@ class TransformerConfig:
                 raise ValueError(
                     f"layer_types names one of {LAYER_KINDS} for each of "
                     f"the {self.depth} layers (got {self.layer_types})")
-            if set(self.layer_types) & {"conv", "linear_attention"} \
-                    and self.conv_kernel < 2:
+            if self.state_layer_kinds and self.conv_kernel < 2:
                 raise ValueError(
-                    "conv and linear_attention layers need conv_kernel >= 2")
+                    "layers that carry state (every kind but attention) "
+                    "need conv_kernel >= 2")
+            if "state_space" in self.layer_types and not (
+                    self.ssm_heads > 0 and self.ssm_head_dim > 0
+                    and self.ssm_state_dim > 0 and self.ssm_chunk > 0):
+                raise ValueError(
+                    "state_space layers need ssm_heads, ssm_head_dim, "
+                    "ssm_state_dim and ssm_chunk")
             if "linear_attention" in self.layer_types and not (
                     self.gdn_key_heads > 0 and self.gdn_key_dim > 0
                     and self.gdn_value_dim > 0 and self.gdn_value_heads > 0
@@ -279,6 +310,10 @@ class TransformerConfig:
                 "attn_output_gate with latent attention or q/k/v biases")
         if self.qk_norm_per_head and not self.qk_norm:
             raise ValueError("qk_norm_per_head is a form of qk_norm: set both")
+        if self.position_embedding not in (None, "none"):
+            raise ValueError(
+                f"unknown position_embedding {self.position_embedding!r} "
+                "(None: the variant's; 'none')")
         if self.moe_scoring not in ("softmax", "sigmoid"):
             raise ValueError(
                 f"unknown moe_scoring {self.moe_scoring!r} (softmax|sigmoid)")
@@ -381,11 +416,13 @@ class TransformerConfig:
     # -- family-knob resolution (None -> variant preset) ---------------
     @property
     def use_rope(self) -> bool:
-        return self.variant != "gpt2" and not self.alibi
+        return (self.variant != "gpt2" and not self.alibi
+                and self.position_embedding != "none")
 
     @property
     def use_learned_pos(self) -> bool:
-        return self.variant == "gpt2" and not self.alibi
+        return (self.variant == "gpt2" and not self.alibi
+                and self.position_embedding != "none")
 
     @property
     def norm_kind(self) -> str:
@@ -449,8 +486,14 @@ class TransformerConfig:
                                  "n_shared_experts", "n_dense_layers",
                                  "experts_held", "layer_types",
                                  "moe_expert_bias", "attn_output_gate",
-                                 "shared_expert_gate") if getattr(self, k)) + (
-            ("moe_scoring",) if self.moe_scoring != "softmax" else ())
+                                 "shared_expert_gate", "position_embedding",
+                                 "attention_multiplier")
+                     if getattr(self, k)) + tuple(
+            k for k, plain in (("moe_scoring", "softmax"),
+                               ("embedding_multiplier", 1.0),
+                               ("residual_multiplier", 1.0),
+                               ("logits_scaling", 1.0))
+            if getattr(self, k) != plain)
 
     def layer_kind(self, li: int) -> str:
         """The operator of layer li of the `depth`: one of LAYER_KINDS."""
@@ -487,10 +530,46 @@ class TransformerConfig:
         return (2 * self.gdn_key_heads * self.gdn_key_dim
                 + self.gdn_value_heads * self.gdn_value_dim)
 
+    @property
+    def ssm_inner(self) -> int:
+        """Values all the state-space heads hold a token: x, z and y."""
+        return self.ssm_heads * self.ssm_head_dim
+
+    @property
+    def ssm_conv_dim(self) -> int:
+        """Channels the state-space layer's convolution runs over:
+        [x; B; C], one B and one C for all the heads."""
+        return self.ssm_inner + 2 * self.ssm_state_dim
+
+    @property
+    def gdn_state_shape(self) -> Tuple[int, ...]:
+        """A sequence's matrices in a linear-attention layer: [Dk
+        sublanes, Dv lanes] a value head (ops/pallas/gated_delta.py)."""
+        return (self.gdn_value_heads, self.gdn_key_dim, self.gdn_value_dim)
+
+    @property
+    def ssm_state_shape(self) -> Tuple[int, ...]:
+        """A sequence's matrices in a state-space layer, as
+        ops/pallas/ssm_state.py lays them out: TRANSPOSED, [ssm_state_dim
+        sublanes, ssm_head_dim lanes] a head, and where a head is under
+        a lane tile as many heads side by side as fill one (ssm_pack:
+        two of 64)."""
+        pack = self.ssm_pack
+        return (self.ssm_heads // pack, self.ssm_state_dim,
+                pack * self.ssm_head_dim)
+
+    @property
+    def ssm_pack(self) -> int:
+        """Heads of a state-space layer that share a 128-lane row of
+        their matrices' pool."""
+        pack = max(1, 128 // self.ssm_head_dim)
+        fills = pack * self.ssm_head_dim == 128 and self.ssm_heads % pack == 0
+        return pack if fills else 1
+
     def conv_channels(self, kind: str) -> int:
-        """Channels a state layer's depthwise convolution runs over:
-        d_model ('conv') or gdn_conv_dim ('linear_attention')."""
-        return self.d_model if kind == "conv" else self.gdn_conv_dim
+        """Channels a state layer's depthwise convolution runs over, by
+        its kind (_STATE_LAYERS)."""
+        return getattr(self, _STATE_LAYERS[kind][0])
 
     def state_width(self, kind: str) -> int:
         """Values one sequence carries as the last conv_kernel - 1
@@ -502,19 +581,27 @@ class TransformerConfig:
         """What one sequence carries in one state layer of `kind`, as
         ((shape, dtype), ...) of its slot in each of the layer's pools
         (dtype None: the cache's): the convolution's carried inputs,
-        and before them, for 'linear_attention', a float32 matrix a
-        value head. The carried inputs are [conv_kernel - 1, channels]
-        with the channels folded into whole lanes where they are some
-        (state_width values all the same): a slot is then whole
+        and before them, for a kind whose heads carry a matrix
+        (_STATE_LAYERS: 'linear_attention', 'state_space'), the float32
+        matrices. The carried inputs are [conv_kernel - 1, channels]
+        with the channels folded into whole lanes where they are some,
+        and MORE than a tile's 8 lane rows padded to whole (8, 128)
+        tiles (zeros that nothing reads: 8,448 channels are 66 lane
+        rows in a slot of 72; up to 8 rows are a block of the whole
+        dimension and stay as they are): a slot is then whole
         (sublane, lane) tiles on the chip and one copy moves it
-        (ops/pallas/conv_carry.py)."""
+        (ops/pallas/conv_carry.py; Mosaic refuses to slice 66 of 72,
+        and `carry_fits` such a pool)."""
         channels = self.conv_channels(kind)
         lanes = 128 if channels % 128 == 0 else channels
-        carried = ((self.conv_kernel - 1, channels // lanes, lanes), None)
-        if kind == "conv":
+        rows = channels // lanes
+        if lanes == 128 and rows > 8:
+            rows = -(-rows // 8) * 8
+        carried = ((self.conv_kernel - 1, rows, lanes), None)
+        matrices = _STATE_LAYERS[kind][1]
+        if matrices is None:
             return (carried,)
-        return (((self.gdn_value_heads, self.gdn_key_dim,
-                  self.gdn_value_dim), jnp.float32), carried)
+        return ((getattr(self, matrices), jnp.float32), carried)
 
     @property
     def state_layer_kinds(self) -> Tuple[str, ...]:
@@ -675,10 +762,10 @@ def _layer_shapes(cfg: TransformerConfig, dense: bool = False
 
 def _operator_shapes(cfg: TransformerConfig, kind: str):
     """The leaves of one layer's OPERATOR, by its kind: attention
-    (plain or latent, with its QK-norm scales and biases) or the gated
+    (plain or latent, with its QK-norm scales and biases), the gated
     short convolution (`conv_in` to [B; C; X], the depthwise `conv_taps`
-    [channel, tap], oldest tap first, and `conv_out`). Same form as
-    _layer_shapes."""
+    [channel, tap], oldest tap first, and `conv_out`), the Gated
+    DeltaNet or the state-space mixer. Same form as _layer_shapes."""
     E, H, KV, D = cfg.d_model, cfg.n_heads, cfg.kv_heads, cfg.head_dim
     if kind == "conv":
         return {
@@ -703,6 +790,27 @@ def _operator_shapes(cfg: TransformerConfig, kind: str):
             "gdn_dt_bias": ((Hv,), (None,)),
             "gdn_norm_scale": ((Dv,), (None,)),
             "gdn_out": ((Hv * Dv, E), ("mlp", "embed")),
+        }
+    if kind == "state_space":
+        # `ssm_in` to [z; x; B; C; dt] (the gate z and x of all heads, ONE
+        # B and C of ssm_state_dim, a step dt a head), the depthwise
+        # `ssm_taps` [channel, tap] over [x; B; C], oldest tap first, and
+        # their `ssm_conv_bias`, the decay's `ssm_a_log` and the step's
+        # `ssm_dt_bias` a head, the skip's `ssm_d` a head (the
+        # publisher's D; a plain leaf to every recipe here, NOT a
+        # `scale`: drawn 1 beside taps of 0.02 the skip D x is a hundred
+        # times the state's read S C, and no check sees the state),
+        # the gated norm's scale over all heads, and `ssm_out`
+        Hs, I, C = cfg.ssm_heads, cfg.ssm_inner, cfg.ssm_conv_dim
+        return {
+            "ssm_in": ((E, I + C + Hs), ("embed", "mlp")),
+            "ssm_taps": ((C, cfg.conv_kernel), ("mlp", None)),
+            "ssm_conv_bias": ((C,), ("mlp",)),
+            "ssm_a_log": ((Hs,), (None,)),
+            "ssm_dt_bias": ((Hs,), (None,)),
+            "ssm_d": ((Hs,), (None,)),
+            "ssm_norm_scale": ((I,), ("mlp",)),
+            "ssm_out": ((I, E), ("mlp", "embed")),
         }
     if cfg.is_latent:
         return _latent_attention_shapes(cfg)
@@ -736,8 +844,14 @@ DENSE_PREFIX = "dense_"
 # top-level stacks of the operators' leaves, by kind, of a model whose
 # layers are of several kinds (cfg.layer_types); its keys are the kinds
 OPERATOR_PREFIX = {"attention": "attn_", "conv": "conv_",
-                   "linear_attention": "gdn_"}
+                   "linear_attention": "gdn_", "state_space": "ssm_"}
 LAYER_KINDS = tuple(OPERATOR_PREFIX)
+# what a layer of each kind that carries state holds a sequence: the
+# property that counts its convolution's channels, and the one that
+# gives its heads' float32 matrices (None: it has none)
+_STATE_LAYERS = {"conv": ("d_model", None),
+                 "linear_attention": ("gdn_conv_dim", "gdn_state_shape"),
+                 "state_space": ("ssm_conv_dim", "ssm_state_shape")}
 
 
 def operator_stacks(cfg: TransformerConfig):

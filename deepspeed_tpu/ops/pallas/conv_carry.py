@@ -77,13 +77,17 @@ def _tile_rows(n_rows: int, width: int, k1: int, itemsize: int) -> int:
 
 def carry_fits(n_rows: int, dtype, pool) -> bool:
     """Whether the kernel takes a step of n_rows rows of `dtype` inputs
-    over this pool: whole lanes a slot, slots in the inputs' own dtype
-    (a slot's rows are copied, never converted), the step's inputs and
-    a tile's buffers inside the kernel's VMEM, the rows' facts in
-    scalar memory."""
+    over this pool: whole lanes a slot, its lane rows whole (8, 128)
+    tiles where they are more than one (Mosaic refuses the slot's copy
+    otherwise: `TransformerConfig.state_shapes` pads them), slots in
+    the inputs' own dtype (a slot's rows are copied, never converted),
+    the step's inputs and a tile's buffers inside the kernel's VMEM,
+    the rows' facts in scalar memory."""
     if pool.ndim != 4 or pool.shape[-1] != LANES or pool.dtype != dtype:
         return False
     _, k1, C, _ = pool.shape
+    if C > 8 and C % 8:
+        return False
     width, itemsize = C * LANES, jnp.dtype(dtype).itemsize
     # the inputs and the taps, double-buffered though fetched once
     resident = 2 * n_rows * width * itemsize + 2 * (k1 + 1) * width * 4

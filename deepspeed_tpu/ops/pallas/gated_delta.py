@@ -166,6 +166,70 @@ def gated_delta_step_xla(q, k, v, g, beta, pool, slots, positions):
     return out, pool
 
 
+def run_state(flag, pool_in, pool_out, heads):
+    """`heads(before)` of one row of a matrix-state kernel, with
+    `before(h)` the run's state so far: the slot's (flag 1: its first
+    row), zeros (3: a sequence's first token, a pad row), or what the
+    row before left in the output block (0). Three bodies, so that a
+    row loads its state from one place."""
+    pl.when(flag == 1)(lambda: heads(lambda h: pool_in[0, h]))
+    pl.when(flag == 3)(lambda: heads(
+        lambda h: jnp.zeros(pool_out.shape[2:], F32)))
+    pl.when(flag == 0)(lambda: heads(lambda h: pool_out[0, h]))
+
+
+def run_flags(slots, positions, pool):
+    """(where, flags) [S] int32 of a step's ragged rows over a pool
+    [slots + 1, ...]: the slot each row's state lives in (the LAST for
+    a pad row), and where its state comes from (run_state: 1 the first
+    row of its run, 3 that and a zero state, 0 the row before)."""
+    first, fresh = run_starts(slots, positions)
+    where = jnp.where(slots < 0, pool.shape[0] - 1, slots).astype(jnp.int32)
+    return where, first.astype(jnp.int32) + 2 * fresh.astype(jnp.int32)
+
+
+def state_step_call(kernel, name: str, out_row, pool, walk, scalars=(),
+                    rows=()):
+    """The walk every matrix-state step kernel shares (this file's
+    `gdn_state`, ops/pallas/ssm_state.py's `ssm_state`): the grid is
+    the step's ragged rows; row t's block of each of `rows` [S, ...]
+    comes in, its block of the output [S, *out_row] goes out; the pool
+    [slots + 1, ...] float32 is aliased in and out and a row's block of
+    it is its sequence's slot (`walk`: run_flags of the step's rows),
+    so a run's state stays in VMEM from row to row and is written once.
+    The kernel is handed (slot_ref, flag_ref, *scalars' refs, *rows'
+    refs, pool_in, o_ref, pool_out): flag_ref[t] says where row t's
+    state comes from (run_state), `scalars` are flat arrays for scalar
+    memory. -> (out [S, *out_row] float32, the pool)."""
+    where, flags = walk
+    S_rows = where.shape[0]
+    row = lambda *block: pl.BlockSpec(
+        block, lambda t, *_: (t,) + (0,) * (len(block) - 1))
+    slot = pl.BlockSpec(
+        (1, *pool.shape[1:]),
+        lambda t, where, *_: (where[t],) + (0,) * (pool.ndim - 1))
+    n_scalars = 2 + len(scalars)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=n_scalars,
+        grid=(S_rows,),
+        in_specs=[*(row(1, *a.shape[1:]) for a in rows), slot],
+        out_specs=[row(1, *out_row), slot],
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct((S_rows, *out_row), F32),
+                   jax.ShapeDtypeStruct(pool.shape, pool.dtype)],
+        # the pool: the operand after the scalars and the rows' inputs
+        input_output_aliases={n_scalars + len(rows): 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_STEP_VMEM_LIMIT),
+        interpret=interpret(),
+        name=name,
+    )(where, flags, *scalars, *rows, pool)
+
+
 def _step_kernel(slot_ref, flag_ref, dec_ref, beta_ref, qT_ref, kT_ref,
                  v_ref, pool_in, o_ref, pool_out, *, n_heads: int):
     """One row: every head's state decayed, read against k, written
@@ -187,12 +251,7 @@ def _step_kernel(slot_ref, flag_ref, dec_ref, beta_ref, qT_ref, kT_ref,
             pool_out[0, h] = S
             o_ref[0, h:h + 1, :] = jnp.sum(S * qc, axis=0, keepdims=True)
 
-    # the run's state so far: the slot's (its first row), zeros (a
-    # sequence's first token, a pad row), or what the row before left
-    pl.when(flag == 1)(lambda: heads(lambda h: pool_in[0, h]))
-    pl.when(flag == 3)(lambda: heads(
-        lambda h: jnp.zeros(pool_out.shape[2:], F32)))
-    pl.when(flag == 0)(lambda: heads(lambda h: pool_out[0, h]))
+    run_state(flag, pool_in, pool_out, heads)
 
 
 def gated_delta_step(q, k, v, g, beta, pool, slots, positions):
@@ -202,36 +261,13 @@ def gated_delta_step(q, k, v, g, beta, pool, slots, positions):
     (-1: a pad row); positions [S], each row's token's position.
     -> (o [S, H, Dv] float32, the pool with every run's last state in
     its sequence's slot)."""
-    S_rows, H, Dk = q.shape
-    Dv = v.shape[-1]
-    first, fresh = run_starts(slots, positions)
-    where = jnp.where(slots < 0, pool.shape[0] - 1, slots).astype(jnp.int32)
-    flags = first.astype(jnp.int32) + 2 * fresh.astype(jnp.int32)
-    rows = lambda *block: pl.BlockSpec(
-        block, lambda t, *_: (t,) + (0,) * (len(block) - 1))
-    slot = pl.BlockSpec((1, H, Dk, Dv), lambda t, where, *_: (where[t], 0, 0, 0))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
-        grid=(S_rows,),
-        in_specs=[rows(1, Dk, H), rows(1, Dk, H), rows(1, H, Dv), slot],
-        out_specs=[rows(1, H, Dv), slot],
-    )
+    walk = run_flags(slots, positions, pool)
     f32 = lambda a: a.astype(F32)
-    o, pool = pl.pallas_call(
-        functools.partial(_step_kernel, n_heads=H),
-        grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((S_rows, H, Dv), F32),
-                   jax.ShapeDtypeStruct(pool.shape, pool.dtype)],
-        # operand 7 (after the four prefetched scalars): the pool
-        input_output_aliases={7: 1},
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",),
-            vmem_limit_bytes=_STEP_VMEM_LIMIT),
-        interpret=interpret(),
-        name="gdn_state",
-    )(where, flags, jnp.exp(f32(g)).reshape(-1), f32(beta).reshape(-1),
-      f32(q).transpose(0, 2, 1), f32(k).transpose(0, 2, 1), f32(v), pool)
-    return o, pool
+    return state_step_call(
+        functools.partial(_step_kernel, n_heads=q.shape[1]), "gdn_state",
+        v.shape[1:], pool, walk,
+        scalars=(jnp.exp(f32(g)).reshape(-1), f32(beta).reshape(-1)),
+        rows=(f32(q).transpose(0, 2, 1), f32(k).transpose(0, 2, 1), f32(v)))
 
 
 def step_fits(n_rows: int, pool) -> bool:

@@ -146,7 +146,8 @@ _LLAMA_FAMILY = {"LlamaForCausalLM", "MistralForCausalLM",
 _ARCH_OF_MODEL_TYPE = {"olmoe": "OlmoeForCausalLM",
                        "pangu_ultra_moe": "PanguUltraMoEForCausalLM",
                        "lfm2_moe": "Lfm2MoeForCausalLM",
-                       "qwen3_next": "Qwen3NextForCausalLM"}
+                       "qwen3_next": "Qwen3NextForCausalLM",
+                       "granitemoehybrid": "GraniteMoeHybridForCausalLM"}
 # config.json keys that change what a BLOCK computes (latent attention,
 # shared experts, leading dense layers, a second norm, a scaled, grouped
 # or biased router, layers of another kind than attention): an
@@ -165,7 +166,14 @@ _LINEAR_ATTENTION_KEYS = (
     "linear_key_head_dim", "linear_value_head_dim", "linear_num_key_heads",
     "linear_num_value_heads", "shared_expert_intermediate_size",
     "mlp_only_layers")
-_BLOCK_KEYS = _LATENT_MOE_KEYS + _HYBRID_KEYS + _LINEAR_ATTENTION_KEYS
+_STATE_SPACE_KEYS = (
+    "mamba_n_heads", "mamba_d_head", "mamba_d_state", "mamba_d_conv",
+    "mamba_n_groups", "mamba_expand", "mamba_chunk_size", "mamba_conv_bias",
+    "mamba_proj_bias", "shared_intermediate_size", "embedding_multiplier",
+    "residual_multiplier", "logits_scaling", "attention_multiplier",
+    "position_embedding_type")
+_BLOCK_KEYS = (_LATENT_MOE_KEYS + _HYBRID_KEYS + _LINEAR_ATTENTION_KEYS
+               + _STATE_SPACE_KEYS)
 # the block keys each architecture's mapping reads; any other stays an
 # error for it too
 _READS_BLOCK_KEYS = {
@@ -174,6 +182,8 @@ _READS_BLOCK_KEYS = {
         "moe_intermediate_size", "routed_scaling_factor")),
     "Qwen3NextForCausalLM": frozenset(_LINEAR_ATTENTION_KEYS + (
         "layer_types", "moe_intermediate_size")),
+    "GraniteMoeHybridForCausalLM": frozenset(_STATE_SPACE_KEYS + (
+        "layer_types",)),
 }
 
 
@@ -182,12 +192,14 @@ def _block_key_set(hf: Dict[str, Any], key: str) -> bool:
     names full attention for every layer asks for nothing."""
     if key == "layer_types":
         return any(t != "full_attention" for t in hf.get(key) or ())
+    if key == "position_embedding_type":  # rope is what a block has
+        return hf.get(key) not in (None, "rope")
     return bool(hf.get(key))
 
 
 SUPPORTED_ARCHITECTURES = sorted(_LLAMA_FAMILY | {
     "PanguUltraMoEForCausalLM", "Lfm2MoeForCausalLM",
-    "Qwen3NextForCausalLM",
+    "Qwen3NextForCausalLM", "GraniteMoeHybridForCausalLM",
     "GPT2LMHeadModel", "OPTForCausalLM", "FalconForCausalLM",
     "RWForCausalLM",  # falcon's pre-rename arch string
     "PhiForCausalLM", "QWenLMHeadModel",
@@ -220,6 +232,8 @@ def config_from_hf(hf: Dict[str, Any], **overrides) -> TransformerConfig:
         kw = _lfm2_moe_config(hf)
     elif arch == "Qwen3NextForCausalLM":
         kw = _qwen3_next_config(hf)
+    elif arch == "GraniteMoeHybridForCausalLM":
+        kw = _granite_moe_hybrid_config(hf)
     elif arch in _LLAMA_FAMILY:
         kw = dict(
             vocab_size=hf["vocab_size"],
@@ -507,6 +521,19 @@ def config_from_hf(hf: Dict[str, Any], **overrides) -> TransformerConfig:
     return TransformerConfig(**kw)
 
 
+def _held_share(hf: Dict[str, Any], key: str):
+    """(the router's width, `experts_held`) of a file whose `key`
+    counts the routed experts: a cut that is one chip's share of an
+    expert-parallel deployment gives under `key` what this chip HOLDS,
+    under `reduced.<key>.published` the router's width and under
+    `experts_held.start` the first held expert (0 if absent); a file
+    that holds them all gives (its count, None)."""
+    held = int(hf[key])
+    routed = int((hf.get("reduced") or {}).get(key, {}).get("published", held))
+    start = int((hf.get("experts_held") or {}).get("start", 0))
+    return routed, (start, held) if held != routed else None
+
+
 def _pangu_ultra_moe_config(hf: Dict[str, Any]) -> Dict[str, Any]:
     """openPangu-Ultra-MoE (`pangu_ultra_moe`): latent attention,
     sandwich norm, `first_k_dense_replace` leading dense layers, then
@@ -530,10 +557,7 @@ def _pangu_ultra_moe_config(hf: Dict[str, Any]) -> Dict[str, Any]:
             "so by setting it to 0 under `reduced`")
     if hf.get("attention_bias"):
         raise ValueError("pangu_ultra_moe with attention_bias is unsupported")
-    held = int(hf["n_routed_experts"])
-    routed = int((hf.get("reduced") or {}).get("n_routed_experts", {})
-                 .get("published", held))
-    start = int((hf.get("experts_held") or {}).get("start", 0))
+    routed, experts_held = _held_share(hf, "n_routed_experts")
     n_dense = int(hf.get("first_k_dense_replace", 0))
     return dict(
         vocab_size=hf["vocab_size"],
@@ -554,7 +578,7 @@ def _pangu_ultra_moe_config(hf: Dict[str, Any]) -> Dict[str, Any]:
         v_head_dim=hf["v_head_dim"],
         sandwich_norm=bool(hf.get("sandwich_norm", False)),
         n_experts=routed, moe_top_k=hf["num_experts_per_tok"],
-        experts_held=(start, held) if held != routed else None,
+        experts_held=experts_held,
         n_shared_experts=int(hf.get("n_shared_experts", 0)),
         moe_scoring="sigmoid",
         moe_norm_topk_prob=bool(hf.get("norm_topk_prob", True)),
@@ -666,10 +690,7 @@ def _qwen3_next_config(hf: Dict[str, Any]) -> Dict[str, Any]:
         raise ValueError(
             f"qwen3_next shared_expert_intermediate_size {shared} is no "
             f"multiple of moe_intermediate_size {F}")
-    held = int(hf["num_experts"])
-    routed = int((hf.get("reduced") or {}).get("num_experts", {})
-                 .get("published", held))
-    start = int((hf.get("experts_held") or {}).get("start", 0))
+    routed, experts_held = _held_share(hf, "num_experts")
     return dict(
         vocab_size=hf["vocab_size"],
         n_layers=L,
@@ -693,10 +714,94 @@ def _qwen3_next_config(hf: Dict[str, Any]) -> Dict[str, Any]:
         attn_output_gate=True,
         qk_norm=True, qk_norm_per_head=True,
         n_experts=routed, moe_top_k=hf["num_experts_per_tok"],
-        experts_held=(start, held) if held != routed else None,
+        experts_held=experts_held,
         n_shared_experts=shared // F,
         shared_expert_gate=shared > 0,
         moe_norm_topk_prob=bool(hf.get("norm_topk_prob", True)),
+        moe_dropless=True,
+    )
+
+
+def _granite_moe_hybrid_config(hf: Dict[str, Any]) -> Dict[str, Any]:
+    """Granite 4.0-H (`granitemoehybrid`): `layer_types` names each
+    layer `mamba` (the Mamba-2 mixer: `mamba_n_heads` heads of
+    `mamba_d_head` with a state of `mamba_d_state`, ONE group, behind a
+    depthwise convolution of `mamba_d_conv` taps with a bias; the
+    scan's chunk `mamba_chunk_size`) or `attention` (GQA, no bias, no
+    QK-norm, `position_embedding_type` "nope": no positional operation
+    at all, softmax scale `attention_multiplier`). Every layer's FFN:
+    `num_local_experts` experts of `intermediate_size`, top-k of the
+    router's logits with a softmax over the chosen (= the full softmax
+    renormalised), beside one ungated shared SwiGLU of
+    `shared_intermediate_size`. Four scalars: the embedding times
+    `embedding_multiplier`, both branches of every layer times
+    `residual_multiplier`, the logits over `logits_scaling`, and the
+    softmax scale.
+
+    An importer's business, not this mapping's: the publisher fuses an
+    expert's gate and up into one `input_linear` (split in halves into
+    `w_gate`, `w_in`) and the mixer's `in_proj` is [z; x; B; C; dt]
+    (`ssm_in` as it stands); `ssm_d` holds the publisher's `D`.
+
+    A cut that is one chip's share of an expert-parallel deployment
+    states it as Qwen3-Next's does: `num_local_experts` is what this
+    chip HOLDS, `reduced.num_local_experts.published` the router's
+    width, `experts_held.start` the first held expert (0 if absent)."""
+    kinds = {"mamba": "state_space", "attention": "attention"}
+    L = int(hf["num_hidden_layers"])
+    types = hf["layer_types"]
+    unknown = sorted(set(types) - set(kinds))
+    if unknown or len(types) != L:
+        raise ValueError(
+            f"granitemoehybrid layer_types names {sorted(kinds)} for each "
+            f"of num_hidden_layers={L} layers (got {len(types)} entries, "
+            f"unknown {unknown})")
+    for key, only in (("mamba_n_groups", 1), ("mamba_proj_bias", False),
+                      ("mamba_conv_bias", True), ("attention_bias", False),
+                      ("position_embedding_type", "nope"),
+                      ("normalization_function", "rmsnorm"),
+                      ("hidden_act", "silu"), ("rope_scaling", None)):
+        if hf.get(key, only) != only:
+            raise ValueError(
+                f"granitemoehybrid with {key}={hf[key]!r} is unsupported "
+                f"(served: {only!r})")
+    Hs, P = int(hf["mamba_n_heads"]), int(hf["mamba_d_head"])
+    if Hs * P != int(hf["mamba_expand"]) * hf["hidden_size"]:
+        raise ValueError(
+            f"granitemoehybrid mamba_n_heads x mamba_d_head = {Hs * P} is "
+            f"not mamba_expand x hidden_size")
+    F = int(hf["intermediate_size"])
+    shared = int(hf.get("shared_intermediate_size", 0))
+    if shared % F:
+        raise ValueError(
+            f"granitemoehybrid shared_intermediate_size {shared} is no "
+            f"multiple of intermediate_size {F}")
+    routed, experts_held = _held_share(hf, "num_local_experts")
+    return dict(
+        vocab_size=hf["vocab_size"],
+        n_layers=L,
+        n_heads=hf["num_attention_heads"],
+        n_kv_heads=hf.get("num_key_value_heads") or None,
+        d_model=hf["hidden_size"],
+        d_ff=F,
+        max_seq=hf.get("max_position_embeddings", 4096),
+        variant="llama",
+        norm_eps=float(hf.get("rms_norm_eps", 1e-5)),
+        tie_embeddings=bool(hf.get("tie_word_embeddings", True)),
+        layer_types=tuple(kinds[t] for t in types),
+        conv_kernel=int(hf["mamba_d_conv"]),
+        ssm_heads=Hs, ssm_head_dim=P,
+        ssm_state_dim=int(hf["mamba_d_state"]),
+        ssm_chunk=int(hf.get("mamba_chunk_size", 256)),
+        position_embedding="none",
+        embedding_multiplier=float(hf.get("embedding_multiplier", 1.0)),
+        residual_multiplier=float(hf.get("residual_multiplier", 1.0)),
+        logits_scaling=float(hf.get("logits_scaling", 1.0)),
+        attention_multiplier=float(hf["attention_multiplier"]),
+        n_experts=routed, moe_top_k=hf["num_experts_per_tok"],
+        experts_held=experts_held,
+        n_shared_experts=shared // F,
+        moe_norm_topk_prob=True,
         moe_dropless=True,
     )
 

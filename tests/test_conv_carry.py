@@ -152,8 +152,14 @@ def _pool(k1, E, dtype=jnp.bfloat16, n_slots=256):
 @pytest.mark.parametrize("what,n_rows,dtype,pool,fits", [
     ("qwen3_next_cell", 256, jnp.bfloat16, _pool(3, 8192), True),
     ("lfm2_cell", 512, jnp.bfloat16, _pool(2, 2048, n_slots=1024), True),
-    ("a_tiny_float32_model", 8, jnp.float32, _pool(3, 1280, jnp.float32),
+    ("granite4h_cell", 128, jnp.bfloat16, _pool(3, 9216), True),
+    # its 1,280 channels in the slot of 2,048 that state_shapes gives it
+    ("a_tiny_float32_model", 8, jnp.float32, _pool(3, 2048, jnp.float32),
      True),
+    ("lane_rows_under_one_tile", 8, jnp.float32, _pool(2, 256, jnp.float32),
+     True),
+    ("lane_rows_that_are_not_whole_tiles", 8, jnp.float32,
+     _pool(3, 1280, jnp.float32), False),
     ("channels_that_are_not_whole_lanes", 8, jnp.float32,
      _pool(2, 192, jnp.float32), False),
     ("a_pool_in_another_dtype_than_the_inputs", 256, jnp.bfloat16,

@@ -1,0 +1,782 @@
+"""Granite 4.0-H (`granitemoehybrid`) on the normal serving path at a
+tiny size on the CPU, against the plain float32 reference of
+benchmarks/reference/granite_moe_hybrid.py: Mamba-2 state-space layers
+whose sequences carry a float32 matrix a head (and the last three
+inputs of a convolution with a bias) in a state slot, attention layers
+WITHOUT positions that alone hold K/V, a held share of routed experts
+beside an ungated shared expert, the four scalars; through whole-prompt
+prefill (the chunked scan), chunks and single steps (the segmented
+recurrence), through the scheduler with slots reused and never cleared;
+the chunked form against the recurrence, the step kernel against its
+oracle, the four shares that add up to the uncut layer, the mutants
+that must fail, the refusals, and the cut's file.
+
+Everything is float32 with seeded weights: two periods of (mamba,
+mamba, attention, mamba), d 64, 8 state-space heads of 16 with a state
+of 32 (all eight side by side in ONE 128-lane row of the pool), 4 query
+/ 2 KV heads of 16 with a softmax scale of 1/16 (not 16^-0.5), 8
+experts of 32 top-3 of which experts 4..7 are held, a shared expert of
+64.
+"""
+
+import json
+import os
+import pathlib
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from benchmarks.reference import granite_moe_hybrid as ref
+from benchmarks.tests import helpers
+from deepspeed_tpu.inference import (
+    ServingScheduler,
+    ServingSchedulerConfig,
+    init_inference,
+)
+from deepspeed_tpu.inference import engine as E
+from deepspeed_tpu.inference import model as M
+from deepspeed_tpu.models import transformer as T
+from deepspeed_tpu.ops.pallas import conv_carry as CC
+from deepspeed_tpu.ops.pallas import ssm_state as SS
+from deepspeed_tpu.utils.hf_checkpoint import config_from_hf
+
+ROOT = pathlib.Path(__file__).resolve().parents[1]
+BENCH = ROOT / "benchmarks"
+CUT = BENCH / "configs/granite-4.0-h-small-serve-l10-ep4.json"
+PATTERN = ["mamba", "mamba", "attention", "mamba"]
+HF = {"attention_bias": False, "attention_multiplier": 0.0625,
+      "embedding_multiplier": 12, "hidden_act": "silu", "hidden_size": 64,
+      "intermediate_size": 32, "layer_types": PATTERN * 2,
+      "logits_scaling": 16, "mamba_chunk_size": 16, "mamba_conv_bias": True,
+      "mamba_d_conv": 4, "mamba_d_head": 16, "mamba_d_state": 32,
+      "mamba_expand": 2, "mamba_n_groups": 1, "mamba_n_heads": 8,
+      "mamba_proj_bias": False, "max_position_embeddings": 512,
+      "model_type": "granitemoehybrid", "normalization_function": "rmsnorm",
+      "num_attention_heads": 4, "num_experts_per_tok": 3,
+      "num_hidden_layers": 8, "num_key_value_heads": 2,
+      "num_local_experts": 4, "position_embedding_type": "nope",
+      "residual_multiplier": 0.22, "rms_norm_eps": 1e-05,
+      "rope_scaling": None, "rope_theta": 10000,
+      "shared_intermediate_size": 64, "tie_word_embeddings": True,
+      "vocab_size": 256,
+      "reduced": {"num_local_experts": {"published": 8, "here": 4}},
+      "experts_held": {"start": 4, "count": 4, "of": 8}}
+
+# float32 on both sides, logits up to 0.32 (they are divided by 16).
+# The system reassociates (the fused QKV matmul, the chunked scan's
+# matmuls against the recurrence, the expert scan's running sum, the
+# taps' sum in another order, q scaled before the scores and not
+# after), which moves a logit by under 1e-7 (measured here: 6e-8 over
+# prefill, a chunk and single steps; 7e-8 over the chunk offsets). The
+# mutants differ by MUTANT_DISTANCES below, the smallest 260 x the
+# limit, which is 30 x the noise.
+LOGITS_ATOL = 2e-6
+ENGINE = dict(max_seq_len=256, kv_block_size=32, num_kv_blocks=48,
+              max_batch_size=32, max_tracked_sequences=6,
+              min_prefill_bucket=32)
+
+
+@pytest.fixture(scope="module")
+def model():
+    mcfg = config_from_hf(HF, use_flash=False)
+    params = T.init(mcfg, jax.random.PRNGKey(1))
+    # spread the logits (the 0.02 init gives nearly flat ones) and make
+    # every norm scale, tap, bias, decay and skip matter
+    params = jax.tree.map(lambda x: x * 4, params)
+
+    def shaped(tree, salt):
+        out = {}
+        for i, (k, v) in enumerate(tree.items()):
+            key = jax.random.fold_in(jax.random.PRNGKey(salt), i)
+            if k == "ssm_d":
+                # away from 0 (no skip) and from 1 (the publisher's start)
+                v = jax.random.uniform(key, v.shape, minval=0.4, maxval=0.7)
+            elif "scale" in k:
+                v = 1 + 0.3 * jax.random.normal(key, v.shape)
+            elif k == "ssm_taps":
+                v = 0.6 * jax.random.normal(key, v.shape)
+            elif k == "ssm_conv_bias":
+                v = 0.5 * jax.random.normal(key, v.shape)
+            elif k in ("attn_wq", "attn_wk"):
+                v = v * 6  # scores sharp enough for positions to matter
+            elif k in ("ssm_a_log", "ssm_dt_bias"):
+                # decays from 0.3 to 0.97 a token: long and short memory
+                v = jax.random.uniform(key, v.shape, minval=-3.0, maxval=0.5)
+            out[k] = v
+        return out
+
+    top = shaped({k: v for k, v in params.items() if k != "layers"}, 2)
+    return mcfg, dict(top, layers=shaped(params["layers"], 3))
+
+
+def _top(params):
+    return {k: v for k, v in params.items() if k != "layers"}
+
+
+def _layer_fn(params):
+    return lambda l: jax.tree.map(lambda a: a[l], params["layers"])
+
+
+def _ref_logits(params, toks, mutate=None, hf=HF):
+    return np.asarray(ref.forward_logits(_top(params), _layer_fn(params),
+                                         toks, hf, mutate))
+
+
+def _engine(model, **over):
+    mcfg, params = model
+    return init_inference(params, mcfg, dict(ENGINE, **over),
+                          dtype=jnp.float32)
+
+
+@pytest.fixture(scope="module")
+def shared_engine(model):
+    """One engine for the teacher-forced tests: they flush what they
+    put, and share its compiled programs."""
+    return _engine(model)
+
+
+def _feeds(model, eng, lens, splits, n_dec, seed=0):
+    """Teacher-forced put() logits of prompts of `lens`, each fed as
+    len - sum(splits) tokens whole, then chunks of `splits`, then n_dec
+    single tokens: (engine logits [prompts, feeds, V], the reference's
+    at the same positions)."""
+    rng = np.random.default_rng(seed)
+    full = [rng.integers(0, HF["vocab_size"], n + n_dec).astype(np.int32)
+            for n in lens]
+    uids = list(range(100, 100 + len(lens)))
+    cuts = [[n - sum(splits[j:]) for j in range(len(splits) + 1)]
+            + [n + j + 1 for j in range(n_dec)] for n in lens]
+    got = []
+    for j in range(len(cuts[0])):
+        toks = [f[(c[j - 1] if j else 0):c[j]] for f, c in zip(full, cuts)]
+        got.append(np.asarray(eng.put(uids, toks)))
+    for u in uids:
+        eng.flush(u)
+    padded = np.zeros((len(full), max(map(len, full))), np.int32)
+    for i, f in enumerate(full):
+        padded[i, :len(f)] = f
+    want = _ref_logits(model[1], padded)
+    want = np.stack([want[i, np.asarray(c) - 1] for i, c in enumerate(cuts)])
+    return np.stack(got, axis=1), want, padded, cuts
+
+
+@pytest.fixture(scope="module")
+def served(model, shared_engine):
+    return _feeds(model, shared_engine, [70, 83], [5], 6)
+
+
+def test_prefill_chunks_and_single_steps_match_the_reference(served):
+    got, want, _, _ = served
+    assert np.isfinite(got).all()
+    assert np.abs(want).max() > 0.2
+    assert np.abs(got - want).max() < LOGITS_ATOL, np.abs(got - want).max(-1)
+
+
+@pytest.mark.parametrize("chunk", [1, 2, 3, 4, 7])
+def test_a_chunk_boundary_at_every_offset(model, shared_engine, chunk):
+    """The first chunk starts 1..7 tokens before the prompt's end (a
+    run of one, runs shorter and longer than the convolution's three
+    carried inputs), a second chunk of 4 follows (its first rows read
+    what the first left in the slot: the matrices and the inputs), then
+    single steps."""
+    got, want, _, _ = _feeds(model, shared_engine, [41, 56], [chunk, 4], 3,
+                             seed=chunk)
+    assert np.abs(got - want).max() < LOGITS_ATOL, np.abs(got - want).max(-1)
+
+
+def _float8(x):
+    return x.astype(jnp.float8_e4m3fn).astype(x.dtype)
+
+
+@pytest.mark.parametrize("control", ref.MUTANTS + ("float8_weights",))
+def test_a_wrong_model_fails_the_written_tolerance(model, served, control):
+    """Each of the logits audit's controls, put in the reference's
+    place: the engine must NOT agree with it. `state_bf16` (the
+    matrices rounded to bf16 after every token) is judged HERE: the
+    chip's bf16 engine cannot tell it from its own rounding."""
+    got, _, padded, cuts = served
+    params = model[1]
+    if control == "float8_weights":
+        wrong = _ref_logits(jax.tree.map(_float8, params), padded)
+    else:
+        wrong = _ref_logits(params, padded, control)
+    wrong = np.stack([wrong[i, np.asarray(c) - 1] for i, c in enumerate(cuts)])
+    # the nearest of them, `softmax_all_no_renorm`, reads 316 x
+    assert np.abs(got - wrong).max() > 300 * LOGITS_ATOL, control
+
+
+# -- the configuration ---------------------------------------------------
+
+def _cut():
+    hf = json.loads(CUT.read_text())
+    return hf, config_from_hf(hf, **hf["serve"]["model_overrides"])
+
+
+def test_the_cut_builds_at_published_widths():
+    hf, cfg = _cut()
+    row = json.loads((BENCH / "configs/published/granite-4.0-h-small.json"
+                      ).read_text())
+    # the six widths benchmarks/tests/helpers.WIDTH_KEY does not know
+    for key, mine in (("mamba_d_state", cfg.ssm_state_dim),
+                      ("mamba_d_head", cfg.ssm_head_dim),
+                      ("mamba_d_conv", cfg.conv_kernel),
+                      ("mamba_n_heads", cfg.ssm_heads),
+                      ("mamba_n_groups", 1),
+                      ("mamba_chunk_size", cfg.ssm_chunk)):
+        assert hf[key] == row[key] == mine, key
+    assert (cfg.n_layers, cfg.depth, cfg.d_model) == (10, 10, 4096)
+    assert (cfg.n_heads, cfg.kv_heads, cfg.head_dim) == (32, 8, 128)
+    assert not cfg.use_rope and not cfg.use_learned_pos
+    assert (cfg.embedding_multiplier, cfg.residual_multiplier,
+            cfg.logits_scaling, cfg.attention_multiplier) == \
+        (12.0, 0.22, 16.0, 0.0078125)
+    assert cfg.layer_types == ("state_space",) * 5 + ("attention",) \
+        + ("state_space",) * 4
+    assert (cfg.n_experts, cfg.moe_top_k, cfg.experts_held, cfg.ff_dim) == \
+        (72, 10, (0, 18), 768)
+    assert cfg.moe_scoring == "softmax" and cfg.moe_norm_topk_prob
+    assert cfg.n_shared_experts == 2 and not cfg.shared_expert_gate
+    assert cfg.tie_embeddings and cfg.vocab_size == 25088 == 196 * 128
+    assert (cfg.n_kv_layers, cfg.n_state_layers) == (1, 9)
+    assert (cfg.ssm_inner, cfg.ssm_conv_dim, cfg.ssm_pack) == (8192, 8448, 2)
+    assert cfg.conv_channels("state_space") == 8448
+    # a head's matrix transposed, two heads a lane row; 66 lane rows of
+    # carried inputs in a slot of 72
+    assert cfg.state_shapes("state_space") == (
+        ((64, 128, 128), jnp.float32), ((3, 72, 128), None))
+    assert set(cfg.serving_only) >= {
+        "layer_types", "position_embedding", "embedding_multiplier",
+        "residual_multiplier", "logits_scaling", "attention_multiplier"}
+    shapes = jax.eval_shape(lambda k: T.init(cfg, k), jax.random.PRNGKey(0))
+    assert shapes["layers"]["w_in"].shape == (10, 18, 4096, 768)
+    assert shapes["layers"]["w_router"].shape == (10, 4096, 72)
+    assert shapes["layers"]["ws_in"].shape == (10, 4096, 1536)
+    assert "ws_sgate" not in shapes["layers"] and "lm_head" not in shapes
+    assert shapes["ssm_in"].shape == (9, 4096, 16768)
+    assert shapes["ssm_taps"].shape == (9, 8448, 4)
+    assert shapes["ssm_conv_bias"].shape == (9, 8448)
+    assert shapes["ssm_a_log"].shape == shapes["ssm_dt_bias"].shape == \
+        shapes["ssm_d"].shape == (9, 128)
+    assert shapes["ssm_norm_scale"].shape == (9, 8192)
+    assert shapes["ssm_out"].shape == (9, 8192, 4096)
+    assert shapes["attn_wq"].shape == (1, 4096, 32, 128)
+    assert shapes["attn_wk"].shape == (1, 4096, 8, 128)
+    flat = dict(shapes["layers"], **{k: v for k, v in shapes.items()
+                                     if k != "layers"})
+    # the file's own count, every leaf
+    assert sum(int(np.prod(s.shape)) for s in flat.values()) == 2_955_758_208
+    assert all(not isinstance(v, dict) for k, v in shapes.items()
+               if k != "layers")
+    assert all(v.shape[0] == cfg.n_layers for v in shapes["layers"].values())
+    # the cache: K/V for the ONE attention layer; two pools a Mamba-2
+    # layer, the matrices' with the pad rows' slot
+    cache = jax.eval_shape(lambda: M.init_cache(
+        cfg, 1025, 128, jnp.bfloat16, state_slots=128))
+    assert [a.shape for a in cache.k] == [(1025, 128, 8, 128)]
+    assert [tuple((a.shape, a.dtype) for a in pools)
+            for pools in cache.state] == [
+        (((129, 64, 128, 128), jnp.float32),
+         ((128, 3, 72, 128), jnp.bfloat16))] * 9
+    assert sum(a.size * 2 for a in cache.k + cache.v) / 1025 / 128 == 4096
+    pools = E.pool_bytes(cfg, E.InferenceConfig(**hf["serve"]["engine"]),
+                         jnp.bfloat16)
+    assert pools == {"kv": 1025 * 128 * 4096,
+                     "state": 9 * (129 * 4_194_304 + 128 * 55_296)}
+
+
+def test_the_seeded_recipes_draw_a_skip_the_size_of_the_states_read():
+    """`ssm_d` is a plain leaf to benchmarks/weights.py and to
+    models/transformer.init: drawn 0.02 x normal like the taps, so that
+    the skip D x and the state's read S C are of one size and `correct`
+    sees both (drawn 1, as the publisher initialises it, the read is
+    under a hundredth of the skip: benchmarks/traffic/
+    chat-saturated-granite4h.json says what each reads)."""
+    from benchmarks import weights
+
+    mcfg = config_from_hf(HF)
+    for params in (weights.make_params(mcfg, 3, jnp.float32),
+                   T.init(mcfg, jax.random.PRNGKey(3))):
+        assert 0 < np.abs(np.asarray(params["ssm_d"])).max() < 0.2
+        assert np.abs(np.asarray(params["ssm_d"])).min() > 0
+        assert (np.asarray(params["ssm_norm_scale"]) == 1).all()
+        assert np.abs(np.asarray(params["ssm_conv_bias"])).min() > 0
+        assert 0 < np.abs(np.asarray(params["ssm_a_log"])).max() < 0.2
+
+
+def test_the_published_shapes_stream_the_held_experts():
+    """18 held experts of 4096 x 768 (three F tiles of 256 an expert): a
+    held share streams at every width (expert_path), through the one
+    pipelined pass."""
+    from deepspeed_tpu.ops.pallas.expert_stream import stream_f_tile
+
+    _, cfg = _cut()
+    stack = jax.ShapeDtypeStruct((18, 4096, 768), jnp.bfloat16)
+    lp = {"w_gate": stack, "w_in": stack,
+          "w_out": jax.ShapeDtypeStruct((18, 768, 4096), jnp.bfloat16)}
+    assert {M.expert_path(t, cfg, lp, True) for t in (8, 128, 256)} == \
+        {"stream"}
+    assert M.expert_path(128, cfg, lp, False) == "scan"
+    assert stream_f_tile(128, lp["w_gate"], lp["w_in"], lp["w_out"]) == 256
+
+
+def test_the_cuts_file_keeps_the_published_widths():
+    hf = json.loads(CUT.read_text())
+    helpers.check_published_widths(hf, BENCH)
+    assert sorted(hf["reduced"]) == ["layer_types", "num_hidden_layers",
+                                     "num_local_experts", "vocab_size"]
+    assert hf["share_of"] and hf["stands_for"]
+    assert hf["experts_held"] == {"start": 0, "count": 18, "of": 72}
+    assert hf["vocab_size"] * 4 == hf["reduced"]["vocab_size"]["published"]
+    assert hf["layer_types"] == hf["reduced"]["layer_types"]["published"][:10]
+    for key in ("state_dtype", "state_layout", "column_order", "no_dt_clamp",
+                "decay", "skip_d", "weights", "state_slots", "kv_pool",
+                "max_tracked_sequences", "max_seq_len"):
+        assert hf["assumed"][key]
+
+
+_MISTRAL = {"architectures": ["MistralForCausalLM"], "hidden_size": 64,
+            "intermediate_size": 128, "num_attention_heads": 4,
+            "num_key_value_heads": 2, "num_hidden_layers": 2,
+            "vocab_size": 64}
+
+
+@pytest.mark.parametrize("what,hf,match", [
+    ("a latent key the mapping does not read", dict(HF, kv_lora_rank=32),
+     "does not read"),
+    ("state-space keys under another architecture",
+     dict(_MISTRAL, mamba_n_heads=8), "does not read"),
+    ("a multiplier under another architecture",
+     dict(_MISTRAL, residual_multiplier=0.22), "does not read"),
+    ("no positions under another architecture",
+     dict(_MISTRAL, position_embedding_type="nope"), "does not read"),
+    ("B and C of several groups", dict(HF, mamba_n_groups=2),
+     "mamba_n_groups"),
+    ("rotary positions", dict(HF, position_embedding_type="rope"),
+     "position_embedding_type"),
+    ("a convolution without its bias", dict(HF, mamba_conv_bias=False),
+     "mamba_conv_bias"),
+    ("a kind the family does not have",
+     dict(HF, layer_types=["conv"] * 8), "layer_types names"),
+    ("heads that are not the expansion", dict(HF, mamba_n_heads=6),
+     "mamba_expand"),
+    ("a shared expert that is no multiple of an expert",
+     dict(HF, shared_intermediate_size=100), "no multiple"),
+])
+def test_what_the_mapping_cannot_serve_is_an_error(what, hf, match):
+    with pytest.raises(ValueError, match=match):
+        config_from_hf(hf)
+
+
+def test_the_kinds_and_their_tables_are_one():
+    """The fourth kind is an entry of each table, and the layer_types
+    error names the kinds from the one tuple."""
+    from deepspeed_tpu.inference import scheduler as S
+
+    assert T.LAYER_KINDS == tuple(T.OPERATOR_PREFIX)
+    state_kinds = set(T.LAYER_KINDS) - {"attention"}
+    assert set(T._STATE_LAYERS) == set(M._STATE_OPERATORS) == state_kinds
+    matrix_kinds = {k for k, (_, m) in T._STATE_LAYERS.items() if m}
+    assert set(M._STEP_OF) == set(M._SCAN_OF) == set(S._RUN_TOKENS) == \
+        matrix_kinds == {"linear_attention", "state_space"}
+    with pytest.raises(ValueError, match="ssm_heads"):
+        T.TransformerConfig(n_layers=2, conv_kernel=4, layer_types=(
+            "attention", "state_space"))
+    with pytest.raises(ValueError, match="position_embedding"):
+        T.TransformerConfig(position_embedding="sinusoidal")
+
+
+def test_the_training_forward_refuses_the_family(model):
+    mcfg, params = model
+    with pytest.raises(NotImplementedError, match="layer_types"):
+        T.forward_hidden(params, jnp.zeros((1, 8), jnp.int32), mcfg)
+    # and each scalar on its own, with no layers of several kinds
+    for field, value in (("embedding_multiplier", 12.0),
+                         ("residual_multiplier", 0.22),
+                         ("logits_scaling", 16.0),
+                         ("attention_multiplier", 0.0625),
+                         ("position_embedding", "none")):
+        cfg = T.TransformerConfig(vocab_size=64, n_layers=1, n_heads=2,
+                                  d_model=32, max_seq=16, use_flash=False,
+                                  **{field: value})
+        with pytest.raises(NotImplementedError, match=field):
+            T.forward_hidden(T.init(cfg, jax.random.PRNGKey(0)),
+                             jnp.zeros((1, 8), jnp.int32), cfg)
+
+
+# -- the recurrence: chunked = recurrent, the step over runs ---------------
+
+def _ssm_inputs(rng, *lead, H=8, P=16, N=32):
+    def normal(*shape):
+        return jnp.asarray(rng.normal(size=shape), jnp.float32)
+
+    # decays exp(dt A) from ~0.2 to ~0.99 a token
+    return (normal(*lead, H, P), jax.nn.softplus(normal(*lead, H) - 1.0),
+            -jnp.exp(normal(H) * 0.5), normal(*lead, N), normal(*lead, N))
+
+
+@pytest.mark.parametrize("tokens,chunk", [(1, 16), (5, 16), (16, 16),
+                                          (23, 16), (70, 16), (37, 8),
+                                          (23, 7), (300, 256)])
+def test_the_chunked_form_is_the_recurrence(rng, tokens, chunk):
+    """Lengths that are and are not multiples of the chunk, from a
+    state that is not zero: outputs and the state left behind."""
+    x, dt, A, Bm, Cm = _ssm_inputs(rng, 2, tokens)
+    state = jnp.asarray(rng.normal(size=(2, 8, 16, 32)), jnp.float32)
+    y1, s1 = SS.ssm_recurrent(x, dt, A, Bm, Cm, state)
+    y2, s2 = SS.ssm_chunked(x, dt, A, Bm, Cm, state, chunk=chunk)
+    # float32 sums of up to 256 terms in another order: relative
+    np.testing.assert_allclose(y2, y1, rtol=5e-5, atol=1e-4)
+    np.testing.assert_allclose(s2, s1, rtol=5e-5, atol=1e-4)
+
+
+def test_the_pools_layout_is_a_view_of_the_heads_matrices(rng):
+    s = jnp.asarray(rng.normal(size=(3, 8, 16, 32)), jnp.float32)
+    packed = SS.pack_state(s, 8)
+    assert packed.shape == (3, 1, 32, 128)
+    # head h's [P, N] transposed, in lanes h P .. (h + 1) P of the row
+    np.testing.assert_array_equal(packed[1, 0, :, 5 * 16:6 * 16], s[1, 5].T)
+    np.testing.assert_array_equal(SS.unpack_state(packed, 8), s)
+    np.testing.assert_array_equal(
+        SS.unpack_state(SS.pack_state(s, 2), 2), s)
+
+
+def test_a_padded_prompt_leaves_the_state_of_its_last_real_token(rng, model):
+    """_recur_prompts: prompts of 9 and 30 tokens padded to 32, a pad
+    prompt beside them; each slot gets the state after its prompt's own
+    last token (in the pool's layout), the others are not touched."""
+    mcfg = model[0]
+    x, dt, A, Bm, Cm = _ssm_inputs(rng, 3, 32)
+    pool = jnp.full((5, 1, 32, 128), 7.0)
+    n_real = jnp.asarray([9, 30, 0], jnp.int32)
+    slots = jnp.asarray([2, 0, -1], jnp.int32)
+    y, new = M._recur_prompts("state_space", (x, dt, A, Bm, Cm), pool, slots,
+                              n_real, mcfg)
+    for i, (n, slot) in enumerate([(9, 2), (30, 0)]):
+        want_y, want_s = SS.ssm_recurrent(
+            x[i:i + 1, :n], dt[i:i + 1, :n], A, Bm[i:i + 1, :n],
+            Cm[i:i + 1, :n])
+        np.testing.assert_allclose(y[i, :n], want_y[0], atol=1e-4)
+        np.testing.assert_allclose(SS.unpack_state(new[slot], 8), want_s[0],
+                                   atol=1e-4)
+    assert (np.asarray(new[1]) == 7).all() and (np.asarray(new[3]) == 7).all()
+
+
+def _check_step(step, rng):
+    """A step's rows: a run of five from a slot's state (positions
+    5..9), a decode row, a pad row, a run of three from position 0 (the
+    slot's NaN must not be read), another pad row."""
+    slots = jnp.asarray([3, 3, 3, 3, 3, 1, -1, 0, 0, 0, -1], jnp.int32)
+    pos = jnp.asarray([5, 6, 7, 8, 9, 12, 0, 0, 1, 2, 0], jnp.int32)
+    pool = jnp.asarray(rng.normal(size=(6, 1, 32, 128)), jnp.float32)
+    pool = pool.at[0].set(jnp.nan)
+    x, dt, A, Bm, Cm = _ssm_inputs(rng, 11)
+    y, new = step(x, dt, A, Bm, Cm, pool, slots, pos)
+    for rows, slot, start in ((slice(0, 5), 3, pool[3]),
+                              (slice(5, 6), 1, pool[1]),
+                              (slice(7, 10), 0, None)):
+        want_y, want_s = SS.ssm_recurrent(
+            x[None, rows], dt[None, rows], A, Bm[None, rows], Cm[None, rows],
+            None if start is None else SS.unpack_state(start, 8)[None])
+        np.testing.assert_allclose(y[rows], want_y[0], atol=2e-5)
+        np.testing.assert_allclose(SS.unpack_state(new[slot], 8), want_s[0],
+                                   atol=2e-5)
+    # the slots of no row of this step are as they were
+    np.testing.assert_array_equal(new[2], pool[2])
+    np.testing.assert_array_equal(new[4], pool[4])
+
+
+def test_the_step_over_runs_is_a_segmented_recurrence(rng):
+    _check_step(SS.ssm_step_xla, rng)
+
+
+@pytest.mark.usefixtures("pallas_interpret")
+def test_the_step_kernel_matches_the_recurrence(rng):
+    f32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32)
+    assert SS.ssm_step_fits(11, f32(6, 1, 32, 128))
+    # a head's width that fills no lane row; a state of half sublanes;
+    # a slot whose four buffers pass the kernel's VMEM
+    assert not SS.ssm_step_fits(11, f32(6, 8, 32, 48))
+    assert not SS.ssm_step_fits(11, f32(6, 1, 12, 128))
+    assert not SS.ssm_step_fits(11, f32(6, 128, 128, 128))
+    assert not SS.ssm_step_fits(
+        11, jax.ShapeDtypeStruct((6, 1, 32, 128), jnp.bfloat16))
+    _check_step(SS.ssm_step, rng)
+
+
+@pytest.mark.usefixtures("pallas_interpret")
+def test_the_engine_with_kernels_matches_the_reference(model):
+    """decode_impl 'auto' under the interpreter resolves the kernels:
+    the step kernel on the aliased pool, the walk and write in the
+    attention layers (192 channels are no whole lanes: the convolution
+    stays XLA's here, and the next test is its kernel's)."""
+    eng = _engine(model)
+    assert eng.resolved_impl == "pallas" and not eng.carry_kernel(8)
+    assert "state_space/ssm_state/ssm_state" in _step_text(eng)
+    got, want, _, _ = _feeds(model, eng, [37, 45], [5], 3, seed=4)
+    assert np.abs(got - want).max() < LOGITS_ATOL, np.abs(got - want).max(-1)
+
+
+def test_a_slot_wider_than_the_channels_serves_the_same(model,
+                                                         pallas_interpret):
+    """A state of 576 makes the convolution's channels 1,280: ten lane
+    rows in a slot of sixteen (cfg.state_shapes pads more than one
+    tile's rows to whole tiles, as the published 66 rows are padded to
+    72). The inputs and taps are padded to the slot (model._slot_wide)
+    on both paths: the one-pass kernel and decode_impl 'xla' give the
+    reference's logits over a prefill, a chunk and single steps."""
+    hf = dict(HF, mamba_d_state=576)
+    mcfg = config_from_hf(hf, use_flash=False)
+    assert mcfg.state_shapes("state_space")[-1] == ((3, 16, 128), None)
+    # up to a tile's rows stay a block of the whole dimension
+    assert config_from_hf(dict(HF, mamba_d_state=64)).state_shapes(
+        "state_space")[-1] == ((3, 2, 128), None)
+    shapes = jax.eval_shape(lambda k: T.init(mcfg, k), jax.random.PRNGKey(0))
+    fresh = T.init(mcfg, jax.random.PRNGKey(4))
+    # the fixture's values wherever the shapes agree
+    flat = lambda p: dict(p["layers"], **_top(p))
+    old = flat(model[1])
+    take = lambda k, v: old[k] if old[k].shape == v.shape else v * 4
+    params = {k: take(k, v) for k, v in _top(fresh).items()}
+    params["layers"] = {k: take(k, v) for k, v in fresh["layers"].items()}
+    assert shapes["ssm_taps"].shape == (6, 1280, 4)
+    full = np.random.default_rng(8).integers(0, 256, (1, 30)).astype(np.int32)
+    want = _ref_logits(params, full, hf=hf)[0]
+    for impl, kernel in (("auto", True), ("xla", False)):
+        eng = init_inference(params, mcfg, dict(ENGINE, decode_impl=impl),
+                             dtype=jnp.float32)
+        assert eng.carry_kernel(8) is kernel
+        got = [np.asarray(eng.put([7], [full[0, a:b]]))[0]
+               for a, b in ((0, 20), (20, 25), (25, 26), (26, 27))]
+        eng.flush(7)
+        err = np.abs(np.stack(got) - want[[19, 24, 25, 26]]).max()
+        assert err < LOGITS_ATOL, (impl, err)
+
+
+def _step_text(eng):
+    return eng._decode_fn(8, False).lower(
+        eng.params, eng.cache, *(eng._dev(np.zeros(s, np.int32)) for s in
+                                 ((8,), (8, eng.config.blocks_per_seq), (8,))),
+        *eng.state_args(np.zeros((8,), np.int32))).as_text(debug_info=True)
+
+
+def test_the_scopes_of_the_operator_are_in_the_program(model):
+    text = _step_text(_engine(model))
+    for scope in ("state_space/ssm_project", "state_space/ssm_conv",
+                  "state_space/ssm_state", "state_space/ssm_gate_norm",
+                  "state_space/ssm_out", "mlp/moe_shared", "mlp/moe_route"):
+        assert scope in text, scope
+    assert "rope" not in text and "cos" not in text
+
+
+# -- through the scheduler: slots taken, reused, never cleared -------------
+
+def _requests(n, seed=5):
+    rng = np.random.default_rng(seed)
+    return [(rng.integers(0, HF["vocab_size"], int(rng.integers(9, 60))
+                          ).tolist(), int(rng.integers(3, 12)))
+            for _ in range(n)]
+
+
+def _sched_engine(model, **over):
+    return _engine(model, max_batch_size=ENGINE["max_tracked_sequences"],
+                   **over)
+
+
+def _serve(eng, requests, **sched):
+    s = ServingScheduler(eng, ServingSchedulerConfig(
+        **dict(dict(max_num_batched_tokens=48, prefill_chunk=8,
+                    prefill_mode="chunked", decode_chunk=1, warmup=False),
+               **sched)))
+    rids = [s.submit(p, max_new_tokens=n) for p, n in requests]
+    s.run()
+    return s, [s.finished[r].output for r in rids]
+
+
+def _greedy_by_the_reference(model, requests, outputs):
+    for (prompt, _), out in zip(requests, outputs):
+        toks = np.zeros((1, 96), np.int32)
+        toks[0, :len(prompt) + len(out)] = prompt + out
+        logits = _ref_logits(model[1], toks)[0]
+        for j, t in enumerate(out):
+            row = logits[len(prompt) + j - 1]
+            assert row[t] >= row.max() - LOGITS_ATOL, (j, t, row.argmax())
+
+
+def test_a_slot_is_handed_on_with_no_clearing(model):
+    """12 requests of unequal lengths through 6 slots: every slot is
+    handed on to a later sequence, and what the last one left in it
+    (here: NaN, put there before the first admission too, in the
+    matrices AND the carried inputs) never reaches the next."""
+    eng = _sched_engine(model)
+    eng.cache = eng.cache._replace(state=jax.tree.map(
+        lambda p: jnp.full_like(p, jnp.nan), eng.cache.state))
+    requests = _requests(12)
+    s, outputs = _serve(eng, requests)
+    assert all(len(o) == n for o, (_, n) in zip(outputs, requests))
+    _greedy_by_the_reference(model, requests, outputs)
+    d = s.counters
+    assert d["state_slot_resets"] == 12 > ENGINE["max_tracked_sequences"]
+    assert d["state_slots_live"] >= d["steps"] > 0
+    assert eng.state.n_tracked == 0 and len(eng.state._free_slots) == 6
+    assert d["lookahead_steps"] > 0  # the slot is updated in program order
+    # a slot: 6 Mamba-2 layers x (8 matrices of 16 x 32 + 3 inputs of
+    # 8 x 16 + 2 x 32 = 192 channels: no whole lanes, so as they are),
+    # float32
+    assert eng.state_slot_bytes == 6 * 4 * (8 * 16 * 32 + 3 * 192)
+    assert d["state_bytes_moved"] % (2 * eng.state_slot_bytes) == 0
+    assert d["state_bytes_moved"] >= 2 * eng.state_slot_bytes * d["steps"]
+    # every prompt went in as chunks of up to 8: all its tokens but a
+    # last chunk of one are rows of runs
+    prompts = sum(len(p) for p, _ in requests)
+    assert prompts - 12 <= d["ssm_run_tokens"] <= prompts
+    assert d["gdn_run_tokens"] == 0
+
+
+def test_whole_prompt_waves_and_fused_decode_carry_the_state(model):
+    """prefill_mode 'wave' runs the chunked scan and writes the slot at
+    the prompt's end; decode_chunk 4 carries it through a fused scan."""
+    requests = _requests(6, seed=3)
+    s, outputs = _serve(_sched_engine(model), requests, prefill_mode="wave",
+                        decode_chunk=4)
+    _greedy_by_the_reference(model, requests, outputs)
+    assert s.counters["ssm_run_tokens"] == sum(len(p) for p, _ in requests)
+
+
+# -- the share of an expert-parallel deployment ----------------------------
+
+def test_four_shares_and_the_shared_expert_once_are_the_uncut_layer(model):
+    """Guide section 4: the routed parts that four shares of two
+    experts give, with what every chip computes alike (the shared
+    expert) counted ONCE, add up to what the uncut reference gives for
+    the whole layer (before the 0.22)."""
+    _, params = model
+    rng = np.random.default_rng(0)
+    n = jnp.asarray(rng.normal(size=(24, 64)), jnp.float32)
+    key = jax.random.PRNGKey(9)
+    lw = {k: params["layers"][k][0]
+          for k in ("w_router", "ws_gate", "ws_in", "ws_out")}
+    full = {k: 0.1 * jax.random.normal(jax.random.fold_in(key, i), shape)
+            for i, (k, shape) in enumerate(
+                {"w_gate": (8, 64, 32), "w_in": (8, 64, 32),
+                 "w_out": (8, 32, 64)}.items())}
+    uncut_hf = {k: v for k, v in HF.items()
+                if k not in ("reduced", "experts_held")}
+    with jax.default_matmul_precision("highest"):
+        whole, _ = ref.moe(n, dict(lw, **full),
+                           dict(uncut_hf, num_local_experts=8))
+        shared = ref._swiglu(n, lw["ws_gate"], lw["ws_in"], lw["ws_out"])
+        parts = []
+        for share in range(4):
+            cfg = config_from_hf(dict(
+                HF, num_local_experts=2, experts_held={"start": 2 * share},
+                reduced={"num_local_experts": {"published": 8, "here": 2}}))
+            assert cfg.experts_held == (2 * share, 2)
+            lp = dict(lw, **{k: w[2 * share:2 * share + 2]
+                             for k, w in full.items()})
+            parts.append(M._mlp(n, lp, cfg) - shared)
+    np.testing.assert_allclose(sum(parts) + shared, whole, atol=2e-5)
+    assert float(jnp.abs(whole - shared).max()) > 0.01  # the routed part counts
+
+
+# -- what cannot be right yet is refused where it is built ----------------
+
+@pytest.mark.parametrize("what,kwargs,config", [
+    ("int8_kv", {}, {"kv_cache_dtype": "int8"}),
+    ("mesh", {}, {"tp_size": 2}),
+    ("weight_quantization", {"quantization": {"bits": 8}}, {}),
+    ("offload", {"offload": {"device": "cpu"}}, {}),
+])
+def test_the_engine_refuses_at_build(model, what, kwargs, config):
+    mcfg, params = model
+    assert E.pool_kinds(mcfg) == ("kv", "state")
+    with pytest.raises(NotImplementedError, match=what):
+        init_inference(params, mcfg, dict(ENGINE, **config),
+                       dtype=jnp.float32, **kwargs)
+
+
+def test_prefix_credit_and_speculation_are_refused(model):
+    assert not E.pools_can(model[0], "prefix_credit")
+    with pytest.raises(NotImplementedError, match="speculation"):
+        ServingScheduler(_engine(model), ServingSchedulerConfig(warmup=False),
+                         speculative={"ngram": 2, "draft_len": 3})
+
+
+def test_pools_beyond_the_device_are_refused_with_the_three_numbers():
+    """128 slots of 38.2 MB beside 5.91 GB of weights fit 16 GB; 256 do
+    not, and the refusal names the three numbers."""
+    hf, cfg = _cut()
+    weights = 2 * 2_955_758_208
+    conf = E.InferenceConfig(**hf["serve"]["engine"])
+    pools = E.pool_bytes(cfg, conf, jnp.bfloat16)
+    assert round((weights + sum(pools.values())) / 1e9, 1) == 11.4
+    E.refuse_pools_beyond(16 * 10 ** 9, weights, pools)
+    twice = E.pool_bytes(cfg, E.InferenceConfig(**dict(
+        hf["serve"]["engine"], max_tracked_sequences=256)), jnp.bfloat16)
+    with pytest.raises(ValueError, match=r"weights 5.91 GB \+ K/V pools "
+                       r"0.54 GB \+ state pools 9.83 GB = 16.28 GB of "
+                       r"16.00 GB"):
+        E.refuse_pools_beyond(16 * 10 ** 9, weights, twice)
+
+
+# -- the kernels at the cell's shapes --------------------------------------
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - whatever keeps libtpu away
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _kernels(text):
+    return [line for line in text.splitlines()
+            if 'custom_call_target="tpu_custom_call"' in line]
+
+
+def test_the_step_kernel_compiles_for_v5e_at_the_cells_shapes(one_chip):
+    """128 rows of 128 heads of 64 x 128 over a pool of 129 slots of
+    4 MiB, aliased in and out (no second 541 MB pool among the
+    temporaries)."""
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    rows, pool = 128, sds((129, 64, 128, 128))
+    assert SS.ssm_step_fits(rows, pool)
+    compiled = jax.jit(SS.ssm_step, donate_argnums=(5,)).lower(
+        sds((rows, 128, 64)), sds((rows, 128)), sds((128,)), sds((rows, 128)),
+        sds((rows, 128)), pool, sds((rows,), jnp.int32),
+        sds((rows,), jnp.int32)).compile()
+    calls = _kernels(compiled.as_text())
+    assert len(calls) == 1 and "ssm_state" in calls[0]
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 129 * 64 * 128 * 128 * 4
+    assert mem.temp_size_in_bytes < 64 << 20
+
+
+def test_the_convolution_compiles_for_v5e_at_8448_channels(one_chip):
+    """8,448 channels are 66 lane rows: a slot of 66 is refused by
+    Mosaic ("Slice shape along dimension 2 must be aligned to tiling
+    (8)"), which is why cfg.state_shapes pads it to 72 and the step's
+    inputs with it (model._slot_wide)."""
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    rows, pool = 128, sds((128, 3, 72, 128))
+    assert CC.carry_fits(rows, jnp.bfloat16, pool)
+    compiled = jax.jit(CC.conv_carry, donate_argnums=(2,)).lower(
+        sds((rows, 9216)), sds((9216, 4)), pool, sds((rows,), jnp.int32),
+        sds((rows,), jnp.int32)).compile()
+    calls = _kernels(compiled.as_text())
+    assert len(calls) == 1 and "conv_carry" in calls[0]

@@ -279,9 +279,10 @@ def test_layer_types_are_the_interval_or_as_named():
 
 
 def test_the_kinds_a_layer_can_be_come_from_one_tuple():
-    assert T.LAYER_KINDS == ("attention", "conv", "linear_attention")
+    assert T.LAYER_KINDS == ("attention", "conv", "linear_attention",
+                             "state_space")
     with pytest.raises(ValueError, match=r"one of \('attention', 'conv', "
-                       r"'linear_attention'\)"):
+                       r"'linear_attention', 'state_space'\)"):
         T.TransformerConfig(n_layers=2, layer_types=("attention", "mamba"))
     with pytest.raises(ValueError, match="gdn_key_heads"):
         T.TransformerConfig(n_layers=2, conv_kernel=4, layer_types=(
@@ -373,7 +374,8 @@ def test_a_padded_prompt_leaves_the_state_of_its_last_real_token(rng):
     pool = jnp.full((5, 8, 32, 128), 7.0)
     n_real = jnp.asarray([9, 30, 0], jnp.int32)
     slots = jnp.asarray([2, 0, -1], jnp.int32)
-    o, new = M._recur_prompts(q, k, v, g, beta, pool, slots, n_real)
+    o, new = M._recur_prompts("linear_attention", (q, k, v, g, beta), pool,
+                              slots, n_real, None)
     for i, (n, slot) in enumerate([(9, 2), (30, 0)]):
         want_o, want_s = GD.gated_delta_recurrent(
             q[i:i + 1, :n], k[i:i + 1, :n], v[i:i + 1, :n], g[i:i + 1, :n],
@@ -519,8 +521,9 @@ def test_a_slot_is_handed_on_with_no_clearing(model):
     assert eng.state.n_tracked == 0 and len(eng.state._free_slots) == 6
     assert d["lookahead_steps"] > 0  # the slot is updated in program order
     # a slot: 6 DeltaNet layers x (8 matrices of 32 x 128 + 3 inputs of
-    # 2 x 4 x 32 + 8 x 128 channels), float32
-    assert eng.state_slot_bytes == 6 * 4 * (8 * 32 * 128 + 3 * 1280)
+    # 2 x 4 x 32 + 8 x 128 = 1,280 channels in a slot of 2,048: whole
+    # (8, 128) tiles), float32
+    assert eng.state_slot_bytes == 6 * 4 * (8 * 32 * 128 + 3 * 2048)
     assert d["state_bytes_moved"] % (2 * eng.state_slot_bytes) == 0
     assert d["state_bytes_moved"] >= 2 * eng.state_slot_bytes * d["steps"]
     # every prompt went in as chunks of up to 8: all its tokens but a

@@ -446,6 +446,7 @@ class InferenceEngine:
         self._prepare_fn = None
         self._expert_paths: Dict[int, str] = {}  # by program width
         self._carry_kernels: Dict[int, bool] = {}  # by program width
+        self._step_kernels: Dict[int, bool] = {}  # by program width
         self._layer_xform = None
         self._top_xform = None
         # awaited, so each set-up phase's span carries its own time and
@@ -579,6 +580,24 @@ class InferenceEngine:
                     M.carry_fits(width, self._dtype, pools[-1])
                     for pools in self.cache.state))
         return self._carry_kernels[width]
+
+    def step_kernel(self, width: int) -> bool:
+        """Whether a compiled step over `width` token rows advances
+        every matrix a head carries (linear attention, state space)
+        through its kind's step kernel and not the loop over rows in
+        XLA (M._recur_rows asks the same of the same shapes): the
+        resolved kernel choice and the kind's fit of each such layer's
+        pool. False for a model without such layers. What the scheduler
+        counts state_step_kernel_steps by."""
+        if width not in self._step_kernels:
+            cfg = self.cfg
+            fits = [M._STEP_OF[kind][0](
+                width, self.cache.state[cfg.state_index(li)][0])
+                for li, kind in enumerate(cfg.layer_types or ())
+                if kind in M._STEP_OF]
+            self._step_kernels[width] = (
+                bool(fits) and self._use_kernel and all(fits))
+        return self._step_kernels[width]
 
     def refresh_params(self, params: Any) -> None:
         """(Re)point the served weight tree — the hybrid-engine shared-

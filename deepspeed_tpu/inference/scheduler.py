@@ -283,6 +283,10 @@ class ServingScheduler:
             # (engine.carry_kernel of the program's width); over steps,
             # 1.0 where every step is one such program
             "state_carry_kernel_steps": 0,
+            # and those whose layers with a matrix a head advance it
+            # through their step kernel (engine.step_kernel), not the
+            # loop over rows in XLA
+            "state_step_kernel_steps": 0,
         }
         self._phases = profiler.Phases("sched", "iteration", PHASES,
                                        sums=self.counters)
@@ -1009,13 +1013,15 @@ class ServingScheduler:
         moves of its sequences' slots: `runs` holds the tokens of each
         sequence it advances, `width` its token rows. A step over rows
         reads and writes each slot once a layer (`reads`; through the
-        convolution's kernel or not, by its width), a whole-prompt
-        prefill writes it."""
+        convolution's and the matrices' kernels or not, by its width),
+        a whole-prompt prefill writes it."""
         cfg = self.engine.cfg
         if not cfg.n_state_layers:
             return
         self.counters["state_carry_kernel_steps"] += (
             reads and self.engine.carry_kernel(width))
+        self.counters["state_step_kernel_steps"] += (
+            reads and self.engine.step_kernel(width))
         self.counters["state_bytes_moved"] += (
             len(runs) * steps * (2 if reads else 1)
             * self.engine.state_slot_bytes)

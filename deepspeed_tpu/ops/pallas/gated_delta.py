@@ -11,16 +11,16 @@ decay g <= 0 and the write strength beta in (0, 1):
 - `gated_delta_step`: one serving step over ragged rows, the Pallas
   kernel `gdn_state`. Row t is one token; rows of one sequence are
   adjacent and in order (a RUN: a decode row is a run of one, a prefill
-  chunk a run of several), so a step is a segmented recurrence. The
-  grid walks the rows; a run's state is fetched from its sequence's
-  slot of the pool [slots + 1, H, Dk, Dv] on its first row (zeros at
-  position 0, whatever the slot holds), stays in VMEM from row to row
-  of the run (an output block whose index does not change is not
-  written back between grid steps) and is written to the slot once,
-  after its last row. The pool is aliased in and out: what moves is
-  each live sequence's 4 x H x Dk x Dv bytes in and out, never the
-  pool. The pool's LAST slot belongs to no sequence: pad rows write
-  there.
+  chunk a run of several), so a step is a segmented recurrence. A
+  run's state is fetched from its sequence's slot of the pool
+  [slots + 1, H, Dk, Dv] (zeros at position 0, whatever the slot
+  holds), updated in place in ONE VMEM buffer by the run's rows, and
+  written to the slot once, after its last row. The pool stays in HBM,
+  aliased in and out, and the kernel issues the copies itself
+  (`state_step_call`, the walk this kernel shares with ssm_state.py's):
+  what moves is each live sequence's 4 x H x Dk x Dv bytes in and out,
+  never the pool. The pool's LAST slot belongs to no sequence: it is
+  the pad rows', which neither read nor write it.
 - `gated_delta_chunked`: a whole prompt, the chunked form: algebra on
   the recurrence (the paper's WY representation), C tokens at a time as
   matmuls. With G_i = g_1 + ... + g_i inside the chunk, D_ij =
@@ -51,7 +51,6 @@ from . import interpret
 F32 = jnp.float32
 # the published chunk of the family's kernels
 CHUNK = 64
-_STEP_VMEM_LIMIT = 48 << 20
 
 
 def gated_delta_recurrent(q, k, v, g, beta, state=None):
@@ -166,92 +165,334 @@ def gated_delta_step_xla(q, k, v, g, beta, pool, slots, positions):
     return out, pool
 
 
-def run_state(flag, pool_in, pool_out, heads):
-    """`heads(before)` of one row of a matrix-state kernel, with
-    `before(h)` the run's state so far: the slot's (flag 1: its first
-    row), zeros (3: a sequence's first token, a pad row), or what the
-    row before left in the output block (0). Three bodies, so that a
-    row loads its state from one place."""
-    pl.when(flag == 1)(lambda: heads(lambda h: pool_in[0, h]))
-    pl.when(flag == 3)(lambda: heads(
-        lambda h: jnp.zeros(pool_out.shape[2:], F32)))
-    pl.when(flag == 0)(lambda: heads(lambda h: pool_out[0, h]))
-
-
 def run_flags(slots, positions, pool):
     """(where, flags) [S] int32 of a step's ragged rows over a pool
     [slots + 1, ...]: the slot each row's state lives in (the LAST for
-    a pad row), and where its state comes from (run_state: 1 the first
-    row of its run, 3 that and a zero state, 0 the row before)."""
+    a pad row), and where its state comes from (1: the first row of
+    its run, from its slot; 3: that and a zero state; 0: the row
+    before)."""
     first, fresh = run_starts(slots, positions)
     where = jnp.where(slots < 0, pool.shape[0] - 1, slots).astype(jnp.int32)
     return where, first.astype(jnp.int32) + 2 * fresh.astype(jnp.int32)
 
 
-def state_step_call(kernel, name: str, out_row, pool, walk, scalars=(),
-                    rows=()):
+# VMEM the walk's slots may take (the rest of the limit: the rows'
+# blocks, double-buffered, and the compiler's own temporaries), the
+# most runs a batch, the runs of several rows that can be held aside,
+# the rows of a grid step
+_STEP_VMEM_LIMIT = 64 << 20
+_SLOTS_VMEM = 48 << 20
+_MAX_BATCH = 8
+_MAX_ASIDE = 4
+_TILE_ROWS = 8
+# bits of a row's flag beside run_flags' two (1: the first row of its
+# run, 2: from a zero state): the step holds two runs of one slot, the
+# row is the first of its batch, the one after which the batch's copies
+# change hands, a row of a run held aside; of a run's: it reads its
+# slot, it writes it
+_SAFE, _BATCH, _HAND, _ASIDE = 4, 8, 16, 32
+_READS, _WRITES = 1, 2
+
+
+def walk_shape(pool_shape):
+    """(batch, aside) of the walk over a pool [slots + 1, ...] float32:
+    the runs a batch and the runs of several rows it can hold aside,
+    two batches' and those runs' whole slots inside _SLOTS_VMEM; a
+    batch of 0 where two slots do not fit."""
+    slot = 4
+    for d in pool_shape[1:]:
+        slot *= d
+    fit = _SLOTS_VMEM // slot
+    aside = min(_MAX_ASIDE, max(0, fit - 2))
+    return min(_MAX_BATCH, (fit - aside) // 2), aside
+
+
+def walk_plan(slots, positions, pool, batch: int, aside: int, tile: int):
+    """The scalars of the walk over a step's ragged rows (int32). By
+    row [S]: its flag (run_flags' two bits, _SAFE, _BATCH, _HAND,
+    _ASIDE), the VMEM buffer its run's state is in, its run's
+    number among the batched runs. By batched run [S] and by run held
+    aside [aside]: its slot (the LAST for a pad row's) and whether it
+    reads and writes it (_READS, _WRITES). By grid step [S / tile]:
+    the tile whose rows held aside it computes. And (the tiles that
+    hold such rows, the last batch's number).
+
+    Runs of ONE row (decode rows, pad rows) are batched `batch` at a
+    time in their order, and a batch's copies change hands (its
+    predecessor's write-backs awaited, its successor's fetches issued)
+    after half its rows. Runs of SEVERAL rows (prefill chunks), where
+    the step has `aside` of them at most and some run of one row
+    beside them, are held aside: each has a buffer of its own from the
+    walk's first copy to its last, and their rows are computed a tile
+    a grid step FROM THE FIRST GRID STEP ON, whatever their place in
+    the step, under the batches' copies, which is the only time the
+    chip has to spare: computed in their place (the scheduler puts
+    them last) nothing is left to hide them under. With more such
+    runs, or none of one row, every run is batched. Where two runs
+    live in ONE slot (the scheduler gives a sequence one run a step,
+    so in no served step) every row is _SAFE, nothing is held aside
+    and a batch is one run."""
+    S = slots.shape[0]
+    i32 = lambda a: a.astype(jnp.int32)
+    rows = jnp.arange(S)
+    where, flags = run_flags(slots, positions, pool)
+    first, live = (flags & 1) == 1, slots >= 0
+    starts = first & live
+    safe = jnp.any((where[:, None] == where[None, :]) & starts[:, None]
+                   & starts[None, :] & (rows[:, None] != rows[None, :]))
+    several = ~(first & jnp.roll(first, -1).at[S - 1].set(True))
+    held = several & ~safe & jnp.any(~several) & (
+        jnp.sum(i32(first & several)) <= aside)
+    # the batched runs, and their batches' edges among the rows not
+    # held aside
+    per = jnp.where(safe, 1, batch)
+    brun = jnp.cumsum(i32(first & ~held)) - 1
+    opens = first & ~held & (brun % per == 0)
+    among = jnp.cumsum(i32(~held)) - 1
+    nth = among - jax.lax.cummax(jnp.where(opens, among, 0))
+    after = jax.lax.cummin(jnp.concatenate(
+        [jnp.where(held, S, rows)[1:], jnp.full((1,), S)]), reverse=True)
+    closes = ~held & ((after == S) | opens[jnp.minimum(after, S - 1)])
+    hand = max(1, batch // 2)
+    hands = ~safe & ~held & ((nth == hand - 1) | (closes & (nth < hand - 1)))
+    arun = jnp.cumsum(i32(first & held)) - 1
+    buf = jnp.where(held, 2 * batch + arun,
+                    (brun // per % 2) * batch + brun % per)
+    flags = (flags + _SAFE * i32(safe) + _BATCH * i32(opens)
+             + _HAND * i32(hands) + _ASIDE * i32(held))
+    # by run: what its first row says, picked by a one-hot [runs, rows]
+    pick = lambda firsts, number, n: lambda a: jnp.sum(jnp.where(
+        firsts[None, :] & (number[None, :] == jnp.arange(n)[:, None]),
+        i32(a)[None, :], 0), axis=1)
+    bits = lambda of: _READS * of((flags & 3) == 1) + _WRITES * of(live)
+    of_brun = pick(first & ~held, brun, S)
+    of_arun = pick(first & held, arun, max(aside, 1))
+    # grid step g computes the rows held aside of the g-th tile that
+    # has some (the last such tile again where there are no more: an
+    # output block is never come back to)
+    G = S // tile
+    has = jnp.any(held.reshape(G, tile), axis=1)
+    nth_tile = jnp.cumsum(i32(has)) - 1
+    tiles = jnp.sum(jnp.where(
+        has[None, :] & (nth_tile[None, :] == jnp.minimum(
+            jnp.arange(G), nth_tile[G - 1])[:, None]),
+        jnp.arange(G)[None, :], 0), axis=1)
+    return (flags, i32(buf), i32(brun), of_brun(where), bits(of_brun),
+            of_arun(where), bits(of_arun), i32(tiles),
+            jnp.stack([nth_tile[G - 1] + 1, brun[S - 1] // per]))
+
+
+def _walk_kernel(body, batch: int, aside: int, tile: int, n_scalars: int,
+                 n_rows: int):
+    """The kernel of state_step_call around a row's `body`: a grid
+    step is `tile` rows."""
+    def kernel(flag_ref, buf_ref, brun_ref, bslot_ref, bbits_ref, aslot_ref,
+               abits_ref, tiles_ref, ends_ref, *refs):
+        scalars, refs = refs[:n_scalars], refs[n_scalars:]
+        ins, ins_aside = refs[:n_rows], refs[n_rows:2 * n_rows]
+        _, o_ref, o_aside, pool_out, held, rsem, wsem = refs[2 * n_rows:]
+        n = flag_ref.shape[0]
+        g, steps = pl.program_id(0), pl.num_programs(0)
+        safe = (flag_ref[0] & _SAFE) != 0
+        per = jnp.where(safe, 1, batch)
+
+        # (the aliased output IS the pool: a slot an earlier run of
+        # this step wrote is read as that run left it)
+        fetch = lambda slot, buf: pltpu.make_async_copy(
+            pool_out.at[slot], held.at[buf], rsem.at[buf])
+        leave = lambda slot, buf: pltpu.make_async_copy(
+            held.at[buf], pool_out.at[slot], wsem.at[buf])
+        start, wait = lambda c: c.start(), lambda c: c.wait()
+
+        def copies(n_runs, run_of, bit, copy, act):
+            """Start or wait for (`act`) the fetches or write-backs
+            (`copy`) of those of `n_runs` runs that have `bit`;
+            run_of(k) -> (the run's bits, its slot, its buffer)."""
+            def one(k, c):
+                bits, slot, buf = run_of(k)
+                pl.when((bits & bit) != 0)(lambda: act(copy(slot, buf)))
+                return c
+            jax.lax.fori_loop(0, n_runs, one, 0)
+
+        def of_batch(of):
+            def run_of(k):
+                run = jnp.clip(of * per + k, 0, n - 1)
+                there = (of >= 0) & (of * per + k < n)
+                return (jnp.where(there, bbits_ref[run], 0), bslot_ref[run],
+                        (jnp.maximum(of, 0) % 2) * batch + k)
+            return per, run_of
+
+        held_aside = aside, lambda a: (abits_ref[a], aslot_ref[a],
+                                       2 * batch + a)
+
+        def compute(i, t, ins, o_ref):
+            flag, b = flag_ref[t], buf_ref[t]
+
+            def after(h, S):
+                held[b, h] = S
+
+            zero = lambda h: jnp.zeros(held.shape[2:], F32)
+            state = lambda heads: (
+                pl.when((flag & 2) != 0)(lambda: heads(zero, after)),
+                pl.when((flag & 2) == 0)(lambda: heads(
+                    lambda h: held[b, h], after)))
+            body(t, i, *scalars, *ins, o_ref, state)
+
+        def own_row(i, t):
+            flag = flag_ref[t]
+            j = brun_ref[t] // per
+
+            @pl.when((flag & _BATCH) != 0)
+            def _():
+                @pl.when(safe)
+                def _():
+                    copies(*of_batch(j - 1), _WRITES, leave, start)
+                    copies(*of_batch(j - 1), _WRITES, leave, wait)
+                    copies(*of_batch(j), _READS, fetch, start)
+                copies(*of_batch(j), _READS, fetch, wait)
+                # reads and writes apart: in flight together they move
+                # at the writes' rate, the lesser
+                pl.when(~safe)(lambda: copies(
+                    *of_batch(j - 1), _WRITES, leave, start))
+
+            compute(i, t, ins, o_ref)
+
+            @pl.when((flag & _HAND) != 0)
+            def _():
+                copies(*of_batch(j - 1), _WRITES, leave, wait)
+                copies(*of_batch(j + 1), _READS, fetch, start)
+
+        def aside_row(i, t):
+            pl.when((flag_ref[t] & 3) == 1)(
+                lambda: fetch(0, buf_ref[t]).wait())
+            compute(i, t, ins_aside, o_aside)
+
+        def row(i, c):
+            # a row of this step's tile, then one held aside: the
+            # second kind spread evenly between the batches' edges
+            own, other = g * tile + i, tiles_ref[g] * tile + i
+            pl.when((flag_ref[own] & _ASIDE) == 0)(lambda: own_row(i, own))
+            pl.when((g < ends_ref[0]) & ((flag_ref[other] & _ASIDE) != 0))(
+                lambda: aside_row(i, other))
+            return c
+
+        @pl.when(g == 0)
+        def _():
+            pl.when(~safe)(lambda: copies(*of_batch(0), _READS, fetch, start))
+            copies(*held_aside, _READS, fetch, start)
+
+        jax.lax.fori_loop(0, tile, row, 0)
+
+        @pl.when(g == steps - 1)
+        def _():
+            last = of_batch(ends_ref[1])
+            copies(*last, _WRITES, leave, start)
+            copies(*held_aside, _WRITES, leave, start)
+            copies(*last, _WRITES, leave, wait)
+            copies(*held_aside, _WRITES, leave, wait)
+
+    return kernel
+
+
+def state_step_call(body, name: str, out_row, pool, slots, positions,
+                    shape, interpreted: bool, scalars=(), rows=()):
     """The walk every matrix-state step kernel shares (this file's
-    `gdn_state`, ops/pallas/ssm_state.py's `ssm_state`): the grid is
-    the step's ragged rows; row t's block of each of `rows` [S, ...]
-    comes in, its block of the output [S, *out_row] goes out; the pool
-    [slots + 1, ...] float32 is aliased in and out and a row's block of
-    it is its sequence's slot (`walk`: run_flags of the step's rows),
-    so a run's state stays in VMEM from row to row and is written once.
-    The kernel is handed (slot_ref, flag_ref, *scalars' refs, *rows'
-    refs, pool_in, o_ref, pool_out): flag_ref[t] says where row t's
-    state comes from (run_state), `scalars` are flat arrays for scalar
-    memory. -> (out [S, *out_row] float32, the pool)."""
-    where, flags = walk
-    S_rows = where.shape[0]
-    row = lambda *block: pl.BlockSpec(
-        block, lambda t, *_: (t,) + (0,) * (len(block) - 1))
-    slot = pl.BlockSpec(
-        (1, *pool.shape[1:]),
-        lambda t, where, *_: (where[t],) + (0,) * (pool.ndim - 1))
-    n_scalars = 2 + len(scalars)
+    `gdn_state`, ops/pallas/ssm_state.py's `ssm_state`). A grid step is
+    a tile of the step's ragged rows; its block of each of `rows`
+    [S, ...] comes in, its block of the output [S, *out_row] goes out,
+    both by Pallas's pipeline (a row at a time the few KB of a row
+    queue behind the slots' megabytes and arrive microseconds late, a
+    wait a row). The pool [slots + 1, ...] float32 stays in HBM,
+    aliased in and out, and the kernel moves live slots alone, by its
+    own copies, a batch of runs (walk_shape) at a time into one of two
+    sets of VMEM buffers, READS AND WRITES APART: batch j's fetches
+    are awaited, then batch j - 1's write-backs issued; batch j's rows
+    update their runs' states in place in their buffers meanwhile;
+    after half of them those write-backs are awaited and batch
+    j + 1's fetches issued into the buffers they left (walk_plan).
+    The chip moves reads alone at ~700 GB/s and writes alone at ~630,
+    and both in flight together at ~640 in all, however many: apart
+    they reach ~680 (PERF.md section 6, PR 45). The rows of a few runs
+    of several rows are computed under those copies, from blocks of
+    their own (the second set of `rows`' blocks and of the output's).
+    A run that starts at position 0 and a pad row fetch nothing; a pad
+    row writes nothing. `body(t, i, *scalars' refs, *rows' refs,
+    o_ref, state)` computes row t, row i of its blocks:
+    `state(heads)` runs `heads(before, after)`, before(h) matrix h of
+    the run's state so far, after(h, S) what the row leaves of it.
+    `scalars` are flat arrays for scalar memory, `shape` is
+    walk_shape's of the pool. -> (out [S, *out_row] float32, the
+    pool)."""
+    batch, aside = shape
+    S_rows = slots.shape[0]
+    tile = next(r for r in range(min(_TILE_ROWS, S_rows), 0, -1)
+                if S_rows % r == 0)
+    walk = walk_plan(slots, positions, pool, batch, aside, tile)
+    n_walk = len(walk)
+    # a tile of an array [S, *shape]: grid step g's own, and the one whose
+    # rows held aside it computes (the walk's `tiles`, prefetched)
+    own = lambda *shape: pl.BlockSpec(
+        (tile, *shape), lambda g, *refs: (g,) + (0,) * len(shape))
+    aside_of = lambda *shape: pl.BlockSpec(
+        (tile, *shape),
+        lambda g, *refs: (refs[n_walk - 2][g],) + (0,) * len(shape))
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    n_scalars = n_walk + len(scalars)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=n_scalars,
-        grid=(S_rows,),
-        in_specs=[*(row(1, *a.shape[1:]) for a in rows), slot],
-        out_specs=[row(1, *out_row), slot],
+        grid=(S_rows // tile,),
+        in_specs=[*(own(*a.shape[1:]) for a in rows),
+                  *(aside_of(*a.shape[1:]) for a in rows), hbm],
+        out_specs=[own(*out_row), aside_of(*out_row), hbm],
+        scratch_shapes=[
+            pltpu.VMEM((2 * batch + aside, *pool.shape[1:]), F32),
+            pltpu.SemaphoreType.DMA((2 * batch + aside,)),
+            pltpu.SemaphoreType.DMA((2 * batch + aside,))],
     )
-    return pl.pallas_call(
-        kernel,
+    out = jax.ShapeDtypeStruct((S_rows, *out_row), F32)
+    o, o_aside, pool = pl.pallas_call(
+        _walk_kernel(body, batch, aside, tile, len(scalars), len(rows)),
         grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((S_rows, *out_row), F32),
-                   jax.ShapeDtypeStruct(pool.shape, pool.dtype)],
+        out_shape=[out, out, jax.ShapeDtypeStruct(pool.shape, pool.dtype)],
         # the pool: the operand after the scalars and the rows' inputs
-        input_output_aliases={n_scalars + len(rows): 1},
+        input_output_aliases={n_scalars + 2 * len(rows): 2},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=_STEP_VMEM_LIMIT),
-        interpret=interpret(),
+        interpret=interpreted,
         name=name,
-    )(where, flags, *scalars, *rows, pool)
+    )(*walk, *scalars, *rows, *rows, pool)
+    is_aside = ((walk[0] & _ASIDE) != 0).reshape((S_rows,) + (1,) * len(
+        out_row))
+    return jnp.where(is_aside, o_aside, o), pool
 
 
-def _step_kernel(slot_ref, flag_ref, dec_ref, beta_ref, qT_ref, kT_ref,
-                 v_ref, pool_in, o_ref, pool_out, *, n_heads: int):
+def walk_fits(pool) -> bool:
+    """Whether the walk takes this pool: float32, and two slots (a
+    batch of one run, twice) inside _SLOTS_VMEM."""
+    return pool.dtype == F32 and walk_shape(pool.shape)[0] > 0
+
+
+def _step_kernel(t, i, dec_ref, beta_ref, qT_ref, kT_ref, v_ref, o_ref,
+                 state, *, n_heads: int):
     """One row: every head's state decayed, read against k, written
     with the token's correction, read against q. The state is
     [Dk sublanes, Dv lanes] a head: k and q arrive as columns
     [Dk, H] (one lane a head) and broadcast along the lanes; v, the
     correction and the output are rows."""
-    t = pl.program_id(0)
-    flag = flag_ref[t]
-    qT, kT = qT_ref[0], kT_ref[0]  # [Dk, H]
+    qT, kT = qT_ref[i], kT_ref[i]  # [Dk, H]
 
-    def heads(before):
+    def heads(before, after):
         for h in range(n_heads):
             kc, qc = kT[:, h:h + 1], qT[:, h:h + 1]  # [Dk, 1]
             S = before(h) * dec_ref[t * n_heads + h]
             m = jnp.sum(S * kc, axis=0, keepdims=True)  # [1, Dv]
-            d = beta_ref[t * n_heads + h] * (v_ref[0, h:h + 1, :] - m)
+            d = beta_ref[t * n_heads + h] * (v_ref[i, h:h + 1, :] - m)
             S = S + kc * d
-            pool_out[0, h] = S
-            o_ref[0, h:h + 1, :] = jnp.sum(S * qc, axis=0, keepdims=True)
+            after(h, S)
+            o_ref[i, h:h + 1, :] = jnp.sum(S * qc, axis=0, keepdims=True)
 
-    run_state(flag, pool_in, pool_out, heads)
+    state(heads)
 
 
 def gated_delta_step(q, k, v, g, beta, pool, slots, positions):
@@ -261,21 +502,27 @@ def gated_delta_step(q, k, v, g, beta, pool, slots, positions):
     (-1: a pad row); positions [S], each row's token's position.
     -> (o [S, H, Dv] float32, the pool with every run's last state in
     its sequence's slot)."""
-    walk = run_flags(slots, positions, pool)
+    return _gated_delta_step(q, k, v, g, beta, pool, slots, positions,
+                             walk_shape(pool.shape), interpret())
+
+
+# jitted on its own, as conv_carry is: a step program's layers trace and
+# lower the kernel once
+@functools.partial(jax.jit, static_argnums=(8, 9))
+def _gated_delta_step(q, k, v, g, beta, pool, slots, positions, shape,
+                      interpreted: bool):
     f32 = lambda a: a.astype(F32)
     return state_step_call(
         functools.partial(_step_kernel, n_heads=q.shape[1]), "gdn_state",
-        v.shape[1:], pool, walk,
+        v.shape[1:], pool, slots, positions, shape, interpreted,
         scalars=(jnp.exp(f32(g)).reshape(-1), f32(beta).reshape(-1)),
         rows=(f32(q).transpose(0, 2, 1), f32(k).transpose(0, 2, 1), f32(v)))
 
 
 def step_fits(n_rows: int, pool) -> bool:
     """Whether the step kernel takes these shapes: whole lanes and
-    sublanes a head's matrix, the slot's four buffers (in and out, each
-    double-buffered) inside the kernel's VMEM, the rows' decays and
-    strengths in scalar memory."""
+    sublanes a head's matrix, the walk's slots inside the kernel's VMEM
+    (walk_fits), the rows' decays and strengths in scalar memory."""
     _, H, Dk, Dv = pool.shape
-    return (Dk % 8 == 0 and Dv % 128 == 0 and pool.dtype == F32
-            and 4 * H * Dk * Dv * 4 <= _STEP_VMEM_LIMIT // 2
+    return (Dk % 8 == 0 and Dv % 128 == 0 and walk_fits(pool)
             and 2 * n_rows * H * 4 <= 256 << 10)

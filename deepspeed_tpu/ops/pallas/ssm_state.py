@@ -15,9 +15,10 @@ and B and C are shared by the heads.
 
 - `ssm_step`: one serving step over ragged rows, the Pallas kernel
   `ssm_state`. The walk is gated_delta.py's (`state_step_call`: a run's
-  state fetched from its sequence's slot on its first row, kept in VMEM
-  from row to row, written once; the pool aliased in and out, its last
-  slot the pad rows'); the body is this file's.
+  state fetched from its sequence's slot by the kernel's own copies,
+  updated in place in one VMEM buffer from row to row, written once;
+  the pool in HBM, aliased in and out, its last slot the pad rows'); the
+  body is this file's.
 
   THE LAYOUT. A head's matrix is [P, N] = [64, 128] at the published
   widths, and the lane tile is 128 wide. Two layouts were compiled for
@@ -36,9 +37,10 @@ and B and C are shared by the heads.
   activations already have ([H P] = [H / pack, pack P]), a sublane
   broadcast each; and the read against C is a sum over sublanes. Nothing
   is transposed around the kernel, no scalar is read a head. Both wait
-  for the slots' copies most of the time (8 MiB a row in and out), which
-  is why the cross-lane work of the first costs 6% and not a multiple;
-  6% of nine layers is 0.9 ms of a 21.8 ms iteration.
+  for the slots' copies most of the time (8 MiB a row in and out; the
+  arithmetic alone is 2.5 us a row, my chip run, PR 45), which is why
+  the cross-lane work of the first costs 6% and not a multiple; 6% of
+  nine layers is 0.9 ms of a 21.8 ms iteration.
 - `ssm_chunked`: a whole prompt, the chunked form (the paper's SSD):
   algebra on the recurrence, Q tokens at a time as matmuls. With
   G_i = sum_{j<=i} dt_j A inside the chunk, L_ij = exp(G_i - G_j) for
@@ -60,14 +62,14 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
+from . import interpret
 from .gated_delta import (
-    _STEP_VMEM_LIMIT,
     F32,
     run_flags,
-    run_state,
     state_step_call,
+    walk_fits,
+    walk_shape,
 )
 
 LANES = 128
@@ -168,24 +170,23 @@ def ssm_step_xla(x, dt, A, Bm, Cm, pool, slots, positions):
     return out, pool
 
 
-def _step_kernel(slot_ref, flag_ref, dec_ref, dx_ref, bc_ref, pool_in, o_ref,
-                 pool_out, *, n_rows: int):
-    """One row: every lane row of heads (`pack` heads side by side)
-    decayed, written with dt x against B, read against C. dec_ref and
-    dx_ref [1, H / pack, pack P]: each head's decay repeated over its P
-    lanes, and dt x; bc_ref [1, N, 2]: B and C as columns."""
-    t = pl.program_id(0)
-    shape = pool_out.shape[2:]  # [N, pack P]
-    Bb = jnp.broadcast_to(bc_ref[0, :, 0:1], shape)
-    Cb = jnp.broadcast_to(bc_ref[0, :, 1:2], shape)
+def _step_kernel(t, i, dec_ref, dx_ref, bc_ref, o_ref, state, *,
+                 n_rows: int, shape):
+    """One row: every lane row of heads (`pack` heads side by side,
+    `shape` [N, pack P]) decayed, written with dt x against B, read
+    against C. Row i of the blocks dec_ref and dx_ref
+    [tile, H / pack, pack P]: each head's decay repeated over its P
+    lanes, and dt x; of bc_ref [tile, N, 2]: B and C as columns."""
+    Bb = jnp.broadcast_to(bc_ref[i, :, 0:1], shape)
+    Cb = jnp.broadcast_to(bc_ref[i, :, 1:2], shape)
 
-    def heads(before):
+    def heads(before, after):
         for j in range(n_rows):
-            S = before(j) * dec_ref[0, j:j + 1, :] + Bb * dx_ref[0, j:j + 1, :]
-            pool_out[0, j] = S
-            o_ref[0, j:j + 1, :] = jnp.sum(S * Cb, axis=0, keepdims=True)
+            S = before(j) * dec_ref[i, j:j + 1, :] + Bb * dx_ref[i, j:j + 1, :]
+            after(j, S)
+            o_ref[i, j:j + 1, :] = jnp.sum(S * Cb, axis=0, keepdims=True)
 
-    run_state(flag_ref[t], pool_in, pool_out, heads)
+    state(heads)
 
 
 def ssm_step(x, dt, A, Bm, Cm, pool, slots, positions):
@@ -195,14 +196,23 @@ def ssm_step(x, dt, A, Bm, Cm, pool, slots, positions):
     slot (-1: a pad row); positions [S], each row's token's position.
     -> (y [S, H, P] float32, the pool with every run's last state in
     its sequence's slot)."""
+    return _ssm_step(x, dt, A, Bm, Cm, pool, slots, positions,
+                     walk_shape(pool.shape), interpret())
+
+
+# jitted on its own, as conv_carry is: a step program's layers trace and
+# lower the kernel once
+@functools.partial(jax.jit, static_argnums=(8, 9))
+def _ssm_step(x, dt, A, Bm, Cm, pool, slots, positions, shape,
+              interpreted: bool):
     S_rows, H, P = x.shape
     _, Hp, N, PP = pool.shape
     f32 = lambda a: a.astype(F32)
     dt = f32(dt)
     dec = jnp.repeat(jnp.exp(dt * f32(A)), P, axis=-1)
     y, pool = state_step_call(
-        functools.partial(_step_kernel, n_rows=Hp), "ssm_state", (Hp, PP),
-        pool, run_flags(slots, positions, pool),
+        functools.partial(_step_kernel, n_rows=Hp, shape=(N, PP)),
+        "ssm_state", (Hp, PP), pool, slots, positions, shape, interpreted,
         rows=(dec.reshape(S_rows, Hp, PP),
               (f32(x) * dt[..., None]).reshape(S_rows, Hp, PP),
               jnp.stack([f32(Bm), f32(Cm)], axis=-1)))
@@ -211,9 +221,8 @@ def ssm_step(x, dt, A, Bm, Cm, pool, slots, positions):
 
 def ssm_step_fits(n_rows: int, pool) -> bool:
     """Whether the step kernel takes this pool: whole lanes and
-    sublanes a lane row of heads, and the slot's four buffers (in and
-    out, each double-buffered) inside the kernel's VMEM. (No row count
-    refuses: the rows' decays and steps are vectors, not scalars.)"""
+    sublanes a lane row of heads, and the walk's slots inside the
+    kernel's VMEM (gated_delta.walk_fits). (No row count refuses: the
+    rows' decays and steps are vectors, not scalars.)"""
     _, Hp, N, PP = pool.shape
-    return (PP == LANES and N % 8 == 0 and pool.dtype == F32
-            and 4 * Hp * N * PP * 4 <= _STEP_VMEM_LIMIT // 2)
+    return PP == LANES and N % 8 == 0 and walk_fits(pool)

@@ -213,12 +213,14 @@ def test_a_model_without_state_counts_nothing():
 
     eng = engine_for(*small_model())
     assert eng.resolved_impl == "pallas" and not eng.carry_kernel(8)
+    assert not eng.step_kernel(8)
     s = ServingScheduler(eng, ServingSchedulerConfig(
         max_num_batched_tokens=16, prefill_chunk=8, warmup=False))
     s.submit(list(range(11)), max_new_tokens=3)
     s.run()
     assert s.counters["steps"] > 0
     assert s.counters["state_carry_kernel_steps"] == 0
+    assert s.counters["state_step_kernel_steps"] == 0
 
 
 @pytest.fixture(scope="module")

@@ -26,6 +26,7 @@ import pathlib
 import jax
 import jax.numpy as jnp
 import numpy as np
+import _state_walk as W
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -496,14 +497,36 @@ def test_the_step_over_runs_is_a_segmented_recurrence(rng):
 def test_the_step_kernel_matches_the_recurrence(rng):
     f32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32)
     assert SS.ssm_step_fits(11, f32(6, 1, 32, 128))
-    # a head's width that fills no lane row; a state of half sublanes;
-    # a slot whose four buffers pass the kernel's VMEM
+    # a head's width that fills no lane row; a state of half sublanes
     assert not SS.ssm_step_fits(11, f32(6, 8, 32, 48))
     assert not SS.ssm_step_fits(11, f32(6, 1, 12, 128))
-    assert not SS.ssm_step_fits(11, f32(6, 128, 128, 128))
     assert not SS.ssm_step_fits(
         11, jax.ShapeDtypeStruct((6, 1, 32, 128), jnp.bfloat16))
     _check_step(SS.ssm_step, rng)
+
+
+@pytest.mark.usefixtures("pallas_interpret")
+@pytest.mark.parametrize("pattern,walk", W.CASES)
+def test_the_walk_over_a_steps_rows(rng, monkeypatch, pattern, walk):
+    """The kernel's own copies (tests/_state_walk.py: the rows'
+    patterns, the walk's batches) against the loop over rows in XLA:
+    three lane rows of eight heads of 16, a state of 8."""
+    shape = (W.SLOTS + 1, 3, 8, 128)
+    W.set_walk(monkeypatch, walk, shape)
+    W.check_walk(SS.ssm_step, SS.ssm_step_xla,
+                 lambda rng, n: _ssm_inputs(rng, n, H=24, P=16, N=8), shape,
+                 pattern, rng)
+
+
+@pytest.mark.parametrize("what,shape,fits", [
+    # two slots in VMEM is the least: a batch of one run, twice
+    ("the cell's", (129, 64, 128, 128), True),
+    ("slots of 24 MiB", (5, 384, 128, 128), True),
+    ("slots of 26 MiB", (5, 416, 128, 128), False),
+])
+def test_ssm_step_fits_the_walks_slots(what, shape, fits):
+    assert SS.ssm_step_fits(128, jax.ShapeDtypeStruct(shape, jnp.float32)) \
+        is fits
 
 
 @pytest.mark.usefixtures("pallas_interpret")
@@ -514,7 +537,7 @@ def test_the_engine_with_kernels_matches_the_reference(model):
     stays XLA's here, and the next test is its kernel's)."""
     eng = _engine(model)
     assert eng.resolved_impl == "pallas" and not eng.carry_kernel(8)
-    assert "state_space/ssm_state/ssm_state" in _step_text(eng)
+    assert "state_space/ssm_state/jit(_ssm_step)" in _step_text(eng)
     got, want, _, _ = _feeds(model, eng, [37, 45], [5], 3, seed=4)
     assert np.abs(got - want).max() < LOGITS_ATOL, np.abs(got - want).max(-1)
 
@@ -633,6 +656,37 @@ def test_a_slot_is_handed_on_with_no_clearing(model):
     prompts = sum(len(p) for p, _ in requests)
     assert prompts - 12 <= d["ssm_run_tokens"] <= prompts
     assert d["gdn_run_tokens"] == 0
+
+
+@pytest.mark.usefixtures("pallas_interpret")
+def test_the_steps_through_the_step_kernel_are_counted(model, monkeypatch):
+    """`state_step_kernel_steps`: every step over rows of an engine
+    whose kernels run and whose pools fit the walk, each once; none
+    under decode_impl 'xla'; none where a pool does not fit (the step
+    is then the loop in XLA). One width of program: the interpreter's
+    kernels are slow to trace."""
+    eng, xla = _sched_engine(model), _sched_engine(model, decode_impl="xla")
+    assert eng.step_kernel(8) and not xla.step_kernel(8)
+    requests = [(p[:12], 3) for p, _ in _requests(2, seed=8)]
+    s, served = _serve(eng, requests, max_num_batched_tokens=8)
+    assert s.counters["state_step_kernel_steps"] == s.counters["steps"] > 0
+    assert s.counters["state_carry_kernel_steps"] == 0  # 192 channels
+    sx = ServingScheduler(xla, ServingSchedulerConfig(warmup=False))
+    sx._count_state([1, 1], 8)
+    assert sx.counters["state_step_kernel_steps"] == 0
+    _greedy_by_the_reference(model, requests, served)
+    # slots past the walk's VMEM: another width asks again, and answers no
+    monkeypatch.setattr(W.GD, "_SLOTS_VMEM", 16 << 10)
+    pool = eng.cache.state[0][0]
+    assert not SS.ssm_step_fits(16, pool) and not eng.step_kernel(16)
+    before = s.counters["state_step_kernel_steps"]
+    s._count_state([1, 1], 16)
+    assert s.counters["state_step_kernel_steps"] == before
+    args = _ssm_inputs(np.random.default_rng(0), 16)
+    text = str(jax.make_jaxpr(lambda *a: M._recur_rows(
+        "state_space", a, pool, jnp.zeros((16,), jnp.int32),
+        jnp.ones((16,), jnp.int32), True))(*args))
+    assert "pallas_call" not in text
 
 
 def test_whole_prompt_waves_and_fused_decode_carry_the_state(model):

@@ -559,6 +559,8 @@ def test_the_convolution_kernel_serves_what_the_xla_path_serves(model):
     eng, xla = _sched_engine(model), _sched_engine(model, decode_impl="xla")
     assert eng.resolved_impl == "pallas" and eng.carry_kernel(8)
     assert xla.resolved_impl == "xla" and not xla.carry_kernel(8)
+    # (no head carries a matrix here: the step kernels' counter stays 0)
+    assert not eng.step_kernel(8) and not xla.step_kernel(8)
     assert "short_conv/conv_state/jit(_conv_carry)" in _step_text(eng)
     assert "jit(_conv_carry)" not in _step_text(xla)
     got, want, _, _ = _feeds(model, eng, [21], [5], 2, seed=6)
@@ -572,6 +574,8 @@ def test_the_convolution_kernel_serves_what_the_xla_path_serves(model):
     assert s.counters["state_slot_resets"] == 8  # through 6 slots
     assert s.counters["state_carry_kernel_steps"] == s.counters["steps"] > 0
     assert sx.counters["state_carry_kernel_steps"] == 0 < sx.counters["steps"]
+    assert s.counters["state_step_kernel_steps"] == 0
+    assert sx.counters["state_step_kernel_steps"] == 0
 
 
 # -- head dim 64: packed pools through the unchanged kernels --------------

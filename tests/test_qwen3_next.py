@@ -24,6 +24,7 @@ import pathlib
 import jax
 import jax.numpy as jnp
 import numpy as np
+import _state_walk as W
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -426,6 +427,32 @@ def test_the_step_kernel_matches_the_recurrence(rng):
 
 
 @pytest.mark.usefixtures("pallas_interpret")
+@pytest.mark.parametrize("pattern,walk", W.CASES)
+def test_the_walk_over_a_steps_rows(rng, monkeypatch, pattern, walk):
+    """The kernel's own copies (tests/_state_walk.py: the rows'
+    patterns, the walk's batches) against the loop over rows in XLA."""
+    shape = (W.SLOTS + 1, 3, 8, 128)
+    W.set_walk(monkeypatch, walk, shape)
+    W.check_walk(GD.gated_delta_step, GD.gated_delta_step_xla,
+                 lambda rng, n: _delta_inputs(rng, n, H=3, Dk=8), shape,
+                 pattern, rng)
+
+
+@pytest.mark.parametrize("what,n_rows,shape,dtype,fits", [
+    # two slots in VMEM is the least: a batch of one run, twice
+    ("the cell's", 256, (257, 32, 128, 128), jnp.float32, True),
+    ("slots of 24 MiB", 8, (5, 96, 128, 512), jnp.float32, True),
+    ("slots of 26 MiB", 8, (5, 104, 128, 512), jnp.float32, False),
+    ("bfloat16 state", 8, (5, 8, 32, 128), jnp.bfloat16, False),
+    ("half a lane row", 8, (5, 8, 32, 64), jnp.float32, False),
+    ("the rows' scalars past scalar memory", 2048, (5, 32, 128, 128),
+     jnp.float32, False),
+])
+def test_step_fits(what, n_rows, shape, dtype, fits):
+    assert GD.step_fits(n_rows, jax.ShapeDtypeStruct(shape, dtype)) is fits
+
+
+@pytest.mark.usefixtures("pallas_interpret")
 def test_the_engine_with_kernels_matches_the_reference(model):
     """decode_impl 'auto' under the interpreter resolves the kernels:
     the step kernel on the aliased pool, the packed D = 64 walk and
@@ -455,6 +482,7 @@ def test_the_convolution_kernel_serves_what_the_xla_path_serves(model):
     eng, xla = _sched_engine(model), _sched_engine(model, decode_impl="xla")
     assert eng.resolved_impl == "pallas" and eng.carry_kernel(8)
     assert xla.resolved_impl == "xla" and not xla.carry_kernel(8)
+    assert eng.step_kernel(8) and not xla.step_kernel(8)
     assert "linear_attention/gdn_conv/jit(_conv_carry)" in _step_text(eng)
     assert "jit(_conv_carry)" not in _step_text(xla)
     got, want, _, _ = _feeds(model, eng, [21], [5], 2, seed=6)
@@ -467,6 +495,9 @@ def test_the_convolution_kernel_serves_what_the_xla_path_serves(model):
     assert served == served_xla
     assert s.counters["state_carry_kernel_steps"] == s.counters["steps"] > 0
     assert sx.counters["state_carry_kernel_steps"] == 0 < sx.counters["steps"]
+    # and the matrices through `gdn_state`, each step counted once
+    assert s.counters["state_step_kernel_steps"] == s.counters["steps"]
+    assert sx.counters["state_step_kernel_steps"] == 0
 
 
 # -- through the scheduler: slots taken, reused, never cleared -------------

@@ -66,6 +66,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple, Union
 import numpy as np
 
 from ..config.config import ServingSchedulerConfig
+from ..ops.pallas.paged_attention import kv_pack, walk_reads
 from ..resilience.faults import fault_point
 from ..resilience.integrity import HandoffIntegrityError
 from ..utils import profiler
@@ -252,6 +253,16 @@ class ServingScheduler:
             # ceil(ctx / kv_block_size); over steps, what the paged
             # attention kernel's time should follow
             "kv_live_blocks": 0,
+            # of those, the blocks the shared-table walk FETCHES: rows
+            # of one prefill chunk follow each other on one table and
+            # walk as a group whose blocks are read once, by its longest
+            # row (paged_attention.walk_reads: the kernel's own
+            # grouping); equal to kv_live_blocks where every row is a
+            # sequence of its own. And the rows that rode on another
+            # row's walk. 1 - kv_block_reads / kv_live_blocks is the
+            # share of block visits the groups saved
+            "kv_block_reads": 0,
+            "kv_grouped_rows": 0,
             # cached tokens the rows of the dispatched programs attended
             # over in ONE latent-attention layer (sum over rows of ctx;
             # 0 unless the model caches a latent): what the latent walk
@@ -982,7 +993,7 @@ class ServingScheduler:
         return parts
 
     def _count_tokens(self, n: int, width: int, ctx=None,
-                      steps: int = 1) -> None:
+                      steps: int = 1, tables=None) -> None:
         """Tokens a dispatched program batched out of its `width` token
         rows, the token-expert pairs they make in a routed layer and
         whether that layer streams its experts in one pass (over every
@@ -990,7 +1001,8 @@ class ServingScheduler:
         the program reads the paged cache, the live KV blocks of its
         rows: ctx is the host array of context lengths it was launched
         with (0 = pad row), each row one token longer in every further
-        fused step."""
+        fused step. tables: the rows' block tables where rows may share
+        one (the shared-table program), for what its walk fetches."""
         self.counters["batched_tokens"] += n
         cfg = self.engine.cfg
         if cfg.n_experts > 0:
@@ -1001,7 +1013,14 @@ class ServingScheduler:
         if ctx is not None:
             live = ctx[ctx > 0][:, None] + np.arange(steps)
             bs = self.engine.config.kv_block_size
-            self.counters["kv_live_blocks"] += int(np.sum(-(-live // bs)))
+            blocks = int(np.sum(-(-live // bs)))
+            self.counters["kv_live_blocks"] += blocks
+            if tables is not None:
+                blocks, rode = walk_reads(
+                    tables, ctx, bs, cfg.n_heads // cfg.kv_heads
+                    * kv_pack(cfg.kv_heads, cfg.head_dim))
+                self.counters["kv_grouped_rows"] += rode
+            self.counters["kv_block_reads"] += blocks
             if cfg.is_latent:
                 self.counters["mla_cache_tokens"] += int(np.sum(live))
         if cfg.n_state_layers:
@@ -1107,7 +1126,7 @@ class ServingScheduler:
         tok_dev = (self._sample_part(logits, sample_rows, sp)
                    if sample_rows else None)
         ph.mark("commit")
-        self._count_tokens(n_rows, sp, ctx)
+        self._count_tokens(n_rows, sp, ctx, tables=tables)
         self._count_state([len(c) for _, c, _ in rows], sp)
         return _Part("mixed", sample_rows, tok_dev)
 

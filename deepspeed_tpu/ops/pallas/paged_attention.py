@@ -20,13 +20,25 @@ paged_decode_attention is the one entry for "attend these rows over
 this paged cache". Two ways to page, one per case (the entry picks from
 the arguments, dtype and shape it is handed, nothing else):
 
-- the per-row live-block walk (_walk_live_blocks): grid (seqs,), the
-  arenas left in HBM, a fori_loop over the row's live blocks with
-  manual DMA a few blocks ahead. Dead table slots cost nothing. Takes
-  the fused single-token write+attend (paged_decode_fused) and the
-  unfused, unquantised attention the shared-table program runs, at
-  head dims that are multiples of 128 and, through PACKED pools
-  (kv_pack: two KV heads of 64 in one 128-lane row), at head dim 64;
+- the live-block walk (_block_ring): grid (seqs,), the arenas left in
+  HBM, a fori_loop over a row's live blocks with manual DMA a few
+  blocks ahead. Dead table slots cost nothing. Takes the fused
+  single-token write+attend (paged_decode_fused: every row a sequence
+  of its own, walked alone, _walk_live_blocks) and the unfused,
+  unquantised attention the shared-table program runs, at head dims
+  that are multiples of 128 and, through PACKED pools (kv_pack: two KV
+  heads of 64 in one 128-lane row), at head dim 64. In the
+  shared-table attention ADJACENT rows with equal tables (a prefill
+  chunk's rows: walk_groups) walk as one GROUP of up to 256 / Gp rows
+  (32 where a KV head serves up to 8 query heads): the group's first
+  row reads the blocks of the group's longest context ONCE and
+  multiplies each by all the group's queries in one pair of matmuls a
+  KV head (_group_softmax), every row masked to its own context and
+  window; the group's other rows' grid steps walk nothing. What stays
+  per row: a row whose neighbours have other tables (every decode
+  row) walks alone exactly as before, and rows that share a table
+  without being adjacent each read it for themselves. So who builds a
+  step puts a chunk's rows next to each other (the scheduler does);
 - the (seqs, table_slots) BlockSpec grid (_decode_kernel): the block
   table is a scalar-prefetch argument and index maps do the paging; a
   slot beyond the context clamps to the last needed block, so a pruned
@@ -48,6 +60,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -400,11 +413,19 @@ def paged_decode_attention(q, k_cache, v_cache, block_table, ctx_lens,
       (supports_fused_v2): paged_decode_fused, the per-row live-block
       walk with the new row DMA'd into its slot;
     - attend only, unquantised, a block shape Mosaic takes as a manual
-      DMA (_walks_live_blocks): the per-row live-block walk, grid (S,)
-      — each row reads the live blocks of its table and nothing else,
-      so the time follows the contexts and not the table's width. This
-      is what the shared-table program calls after paged_kv_write, rows
-      of one prefill chunk sharing a table;
+      DMA (_walks_live_blocks): the live-block walk, grid (S,) — each
+      row reads the live blocks of its table and nothing else, so the
+      time follows the contexts and not the table's width. This is
+      what the shared-table program calls after paged_kv_write, rows
+      of one prefill chunk sharing a table: ADJACENT rows with equal
+      tables are walked as one GROUP (up to 256 / max(G, 8) rows, a
+      longer run as several), whose blocks are read once, by its
+      longest context, and multiplied by all its rows' queries
+      together, each row masked to its own ctx_lens and window. Rows
+      that share a table should therefore be adjacent (the contract
+      paged_latent_attention states too); apart they stay correct and
+      each reads the table for itself, as every row of a table of its
+      own does. The choice is read from the tables, per call;
     - everything else on the (S, NB) BlockSpec grid: int8 KV (k_scale
       given: the scale tiles ride the index maps), the fused write at
       other head dims, and block shapes the walk cannot take
@@ -424,7 +445,8 @@ def paged_decode_attention(q, k_cache, v_cache, block_table, ctx_lens,
       quantizes the new rows in-kernel (codes + scales RMW'd back
       through aliased outputs, so fused mode returns
       (out, k_cache, v_cache, k_scale, v_scale)).
-    block_table: [S, NB] int32 — cache block ids per sequence
+    block_table: [S, NB] int32 — cache block ids per sequence; rows of
+      one sequence (a prefill chunk) adjacent, to share one read
     ctx_lens: [S] int32 — context length INCLUDING the new token; rows
       with 0 are batch padding (the walk stores zeros, the grid garbage;
       sliced by the caller either way)
@@ -470,7 +492,7 @@ def paged_decode_attention(q, k_cache, v_cache, block_table, ctx_lens,
     qg, ab, G, Gp = _group_queries(q, KV, alibi_slopes)
     if not fused and not quant and _walks_live_blocks(qg, k_cache):
         out = _attend_live_blocks(qg, ab, k_cache, v_cache, block_table,
-                                  ctx_lens, window, scale)
+                                  ctx_lens, window, scale, interpret())
         return out[:, :, :G, :].reshape(S, H, D)
     out, *pools = _attend_grid(qg, ab, k_cache, v_cache, block_table,
                                ctx_lens, window, scale, k_new, v_new, slots,
@@ -644,48 +666,37 @@ def paged_decode_attention_xla(q, k_cache, v_cache, block_table, ctx_lens,
 _RING = 3
 
 
-def _walk_live_blocks(
-    s, tbl_ref, ctx_ref, q_ref, k_any, v_any, ab_ref,
-    bufk, bufv, lsem, *,
-    n_seqs: int, block_size: int, scale: float, n_kv: int, gp: int,
-    window: int, new_col: bool,
-):
-    """Row `s`'s online softmax over the LIVE blocks of its table and
-    nothing else: a fori_loop from the sliding window's first slot to
-    the last block that holds a cached column, each block DMA'd from
-    the HBM arenas into a ring of VMEM buffers `bufk`/`bufv`
-    [2, ring, bs, KV, D] ring - 1 iterations ahead of its use. Scratch
-    persists across grid steps, so the step of row s also issues the
-    first ring - 1 blocks of row s+1 (buffer sets alternate by row
-    parity) —
-    the common short-context case never stalls. Dead table slots cost
-    nothing: no grid step, no DMA, no compare.
+def _live_span(ctx, block_size: int, window: int, new_col: bool = False):
+    """[first, end) table slots of a row's LIVE blocks, from its context
+    length (a scalar in a kernel, an array outside one): the sliding
+    window's first slot to the last block that holds a cached column
+    (new_col: the newest token, position ctx-1, is not in the cache)."""
+    cached = jnp.maximum(ctx - 1, 0) if new_col else ctx
+    first = _win_jbase_decode(ctx, window, block_size) if window > 0 else 0
+    return first, (cached + block_size - 1) // block_size
 
-    new_col: the row's newest token (position ctx-1) is NOT in the
-    cache — the caller folds it in as its own column (fused
-    write+attend) — so only columns < ctx-1 are live. Otherwise the
-    row was written before the call and columns < ctx are.
 
-    Returns the per-head (running max, sum, accumulator) tuples of
-    (Gp, 1), (Gp, 1), (Gp, D) f32; a row with no live block (ctx 0:
-    batch padding) returns the initial carry and issues no load."""
-    bs = block_size
-    D = q_ref.shape[-1]
+def _block_ring(s, span_of, tbl_ref, k_any, v_any, bufk, bufv, lsem,
+                n_seqs: int):
+    """The walk's DMA side: blocks of a row's table move from the HBM
+    arenas into a ring of VMEM buffers `bufk`/`bufv`
+    [2, ring, bs, KV, D] ring - 1 iterations ahead of their use.
+    `span_of(row)` gives the [first, end) table slots grid step `row`
+    walks (empty: that step walks nothing and no load is issued for it).
+    Scratch persists across grid steps, so the step of row s also issues
+    the first ring - 1 blocks of row s+1 (buffer sets alternate by row
+    parity): the common short-context case never stalls.
+
+    Returns walk(first, end, visit, init): a fori_loop over step s's
+    slots, carry = visit(j, k_block, v_block, carry) with the (bs, KV,
+    D) blocks of slot j. Every load issued is waited by the step it was
+    issued for, so `first`/`end` must be span_of(s)."""
     ring = bufk.shape[1]
     # every HBM index is CLAMPED to the arena: a violated block-table
     # contract (caller bug) must produce wrong-but-contained results,
     # never a wild DMA — an out-of-bounds manual DMA doesn't just crash
     # the program, it can wedge the TPU runtime for every later client
     n_blk = k_any.shape[0]
-
-    def cached_of(ctx):
-        return jnp.maximum(ctx - 1, 0) if new_col else ctx
-
-    def jbase_of(ctx):
-        return _win_jbase_decode(ctx, window, bs) if window > 0 else 0
-
-    def nblk_of(ctx):
-        return pl.cdiv(cached_of(ctx), bs)
 
     def load(sq, j):
         blk = _arena_block(tbl_ref[sq, j], n_blk)
@@ -695,8 +706,7 @@ def _walk_live_blocks(
                               lsem.at[sq % 2, j % ring, 1]).start()
 
     def prefetch_first(sq):
-        ctx = ctx_ref[sq]
-        jb, nb = jbase_of(ctx), nblk_of(ctx)
+        jb, nb = span_of(sq)
         for j in range(ring - 1):
             pl.when(jb + j < nb)(functools.partial(load, sq, jb + j))
 
@@ -708,30 +718,45 @@ def _walk_live_blocks(
     def _prefetch_next_row():
         prefetch_first(s + 1)
 
-    ctx = ctx_ref[s]
-    cached = cached_of(ctx)
-    nblk = nblk_of(ctx)
     bufset = s % 2
 
-    def body(j, carry):
+    def walk(first, end, visit, init):
+        def body(j, carry):
+            bslot = j % ring
+
+            @pl.when(j + ring - 1 < end)
+            def _prefetch_ahead():
+                load(s, j + ring - 1)
+
+            pltpu.make_async_copy(k_any.at[0], bufk.at[bufset, bslot],
+                                  lsem.at[bufset, bslot, 0]).wait()
+            pltpu.make_async_copy(v_any.at[0], bufv.at[bufset, bslot],
+                                  lsem.at[bufset, bslot, 1]).wait()
+            return visit(j, bufk[bufset, bslot], bufv[bufset, bslot], carry)
+
+        return jax.lax.fori_loop(first, end, body, init)
+
+    return walk
+
+
+def _row_softmax(walk, first, end, s, ctx, q_ref, ab_ref, *,
+                 block_size: int, scale: float, n_kv: int, gp: int,
+                 window: int, new_col: bool = False):
+    """Row `s`'s online softmax over table slots [first, end) of `walk`,
+    the cached columns of a row of context `ctx` (inside its window)
+    live; new_col as _live_span's. Returns the per-head (running max,
+    sum, accumulator) tuples of (Gp, 1), (Gp, 1), (Gp, D) f32; an empty
+    span (ctx 0: batch padding) returns the initial carry."""
+    bs = block_size
+    D = q_ref.shape[-1]
+    cached = jnp.maximum(ctx - 1, 0) if new_col else ctx
+
+    def visit(j, kb, vb, carry):
         ms, ls, accs = carry  # per-head tuples: (Gp,1),(Gp,1),(Gp,D)
-        bslot = j % ring
-
-        @pl.when(j + ring - 1 < nblk)
-        def _prefetch_ahead():
-            load(s, j + ring - 1)
-
-        pltpu.make_async_copy(k_any.at[0], bufk.at[bufset, bslot],
-                              lsem.at[bufset, bslot, 0]).wait()
-        pltpu.make_async_copy(v_any.at[0], bufv.at[bufset, bslot],
-                              lsem.at[bufset, bslot, 1]).wait()
-
         cols = j * bs + jax.lax.broadcasted_iota(jnp.int32, (gp, bs), 1)
         live = cols < cached
         if window > 0:
             live = jnp.logical_and(live, cols >= ctx - window)
-        kb = bufk[bufset, bslot]  # (bs, KV, D)
-        vb = bufv[bufset, bslot]
         ms2, ls2, accs2 = [], [], []
         for h in range(n_kv):
             q = q_ref[s, h]  # (Gp, D)
@@ -756,7 +781,36 @@ def _walk_live_blocks(
         tuple(jnp.zeros((gp, 1), jnp.float32) for _ in range(n_kv)),
         tuple(jnp.zeros((gp, D), jnp.float32) for _ in range(n_kv)),
     )
-    return jax.lax.fori_loop(jbase_of(ctx), nblk, body, init)
+    return walk(first, end, visit, init)
+
+
+def _walk_live_blocks(
+    s, tbl_ref, ctx_ref, q_ref, k_any, v_any, ab_ref,
+    bufk, bufv, lsem, *,
+    n_seqs: int, block_size: int, scale: float, n_kv: int, gp: int,
+    window: int, new_col: bool,
+):
+    """Row `s`'s online softmax over the LIVE blocks of its table and
+    nothing else (_live_span of its context, through _block_ring). Dead
+    table slots cost nothing: no grid step, no DMA, no compare.
+
+    new_col: the row's newest token (position ctx-1) is NOT in the
+    cache — the caller folds it in as its own column (fused
+    write+attend) — so only columns < ctx-1 are live. Otherwise the
+    row was written before the call and columns < ctx are.
+
+    Returns _row_softmax's carry; a row with no live block (ctx 0:
+    batch padding) returns the initial one and issues no load."""
+
+    def span_of(sq):
+        return _live_span(ctx_ref[sq], block_size, window, new_col)
+
+    walk = _block_ring(s, span_of, tbl_ref, k_any, v_any, bufk, bufv, lsem,
+                       n_seqs)
+    return _row_softmax(
+        walk, *span_of(s), s, ctx_ref[s], q_ref, ab_ref,
+        block_size=block_size, scale=scale, n_kv=n_kv, gp=gp, window=window,
+        new_col=new_col)
 
 
 def _store_row(o_ref, s, ls, accs):
@@ -767,26 +821,164 @@ def _store_row(o_ref, s, ls, accs):
         o_ref[s, h] = (accs[h] / l_safe).astype(o_ref.dtype)
 
 
+# query rows of a GROUP's matmuls (rows x Gp): adjacent rows that share
+# a table are walked together up to 256 / Gp of them (32 at Gp 8, a
+# serving cell's prefill chunk), a longer run as several groups
+_GROUP_QUERY_ROWS = 256
+
+
+def _group_rows(gp: int, n_rows: int) -> int:
+    """Rows the shared-table walk takes as one group at most; 1 (no
+    grouping) where a row's Gp query rows are not whole sublane tiles,
+    so that a group's rows could not be stacked by a free reshape."""
+    return 1 if gp % 8 else max(1, min(_GROUP_QUERY_ROWS // gp, n_rows))
+
+
+def walk_groups(block_table, max_rows: int, xp=jnp):
+    """[S] int32: for each row, the row that WALKS for it in the
+    shared-table attention: itself, or the first row of its group.
+    Adjacent rows with equal tables form a run (rows of one prefill
+    chunk follow each other and share theirs); a run is cut into groups
+    of `max_rows`. Equal tables that are not adjacent do not group.
+    Written over `xp` (jnp in the kernel's entry, numpy in walk_reads)
+    so that the two cannot disagree."""
+    idx = xp.arange(block_table.shape[0], dtype=xp.int32)
+    first = xp.concatenate([
+        xp.ones((1,), bool),
+        xp.any(block_table[1:] != block_table[:-1], axis=1)])
+    marks = xp.where(first, idx, 0)
+    if xp is np:
+        run = np.maximum.accumulate(marks)  # the run's first row
+    else:
+        # the same running maximum as ONE masked [S, S] reduction: XLA
+        # lowers a cumulative maximum on the TPU to a loop of S trips
+        # (0.2 ms at 128 rows, 1.1 ms at 512: chip, PR 46)
+        run = jnp.max(jnp.where(idx[None, :] <= idx[:, None],
+                                marks[None, :], 0), axis=1)
+    return idx - (idx - run) % max_rows
+
+
+def walk_reads(block_table, ctx_lens, block_size: int, queries_per_kv: int):
+    """(blocks fetched, rows that rode) of one shared-table call over
+    these host arrays, by the walk's own grouping: a group's blocks
+    count once, by its longest row; a row rides when another row walks
+    for it (rows of context 0, batch padding, left out).
+    queries_per_kv: query heads a pool row's KV heads serve (H / KV,
+    times kv_pack). The scheduler's kv_block_reads / kv_grouped_rows."""
+    rows = np.arange(len(ctx_lens))
+    lead = walk_groups(block_table, _group_rows(max(queries_per_kv, 8),
+                                                len(ctx_lens)), np)
+    reads = np.zeros(len(ctx_lens), np.int64)
+    np.maximum.at(reads, lead, -(-ctx_lens // block_size))
+    return int(reads.sum()), int(np.sum((ctx_lens > 0) & (lead != rows)))
+
+
 def _decode_rows_kernel(
-    tbl_ref, ctx_ref,                               # scalar prefetch
-    q_ref, k_any, v_any,                            # inputs (caches in HBM)
-    *rest,                                          # [ab], out, scratch
-    alibi: bool, **walk,
+    tbl_ref, ctx_ref, first_ref, end_ref, n_ref,    # scalar prefetch
+    q_ref, ctxv_ref, k_any, v_any,                  # inputs (caches in HBM)
+    *rest,                                          # [ab, abv], out, scratch
+    alibi: bool, group: int, n_seqs: int, **opts,
 ):
     """The shared-table decode attention: attend only. Every row is
     already in the cache (paged_kv_write ran first), so rows that share
     one table — a prefill chunk's rows, ctx rising by one — read the
-    same blocks without racing a write."""
-    if alibi:  # [KV, Gp] ALiBi slope table rides as the LAST input
-        ab_ref, o_ref, bufk, bufv, lsem = rest
+    same blocks without racing a write.
+
+    A step's part is in n_ref: 1, a row walked alone (_row_softmax, as
+    the fused kernel walks its rows); n > 1, the first row of a GROUP of
+    n adjacent rows of one table, which walks the blocks of the group's
+    longest context ONCE ([first_ref, end_ref): the group's span) and
+    multiplies each by the group's stacked queries, every row masked to
+    its own context (_group_softmax), then stores all n rows; 0, a row
+    its group's first row has stored already: the step only keeps the
+    next row's prefetch chain going."""
+    if alibi:  # [KV, Gp] slopes and their [KV, group * Gp, 1] tiling
+        ab_ref, abv_ref, o_ref, bufk, bufv, lsem, *acc = rest
     else:
-        o_ref, bufk, bufv, lsem = rest
-        ab_ref = None
+        o_ref, bufk, bufv, lsem, *acc = rest
+        ab_ref = abv_ref = None
     s = pl.program_id(0)
-    _, ls, accs = _walk_live_blocks(
-        s, tbl_ref, ctx_ref, q_ref, k_any, v_any, ab_ref,
-        bufk, bufv, lsem, new_col=False, **walk)
-    _store_row(o_ref, s, ls, accs)
+    walk = _block_ring(s, lambda sq: (first_ref[sq], end_ref[sq]), tbl_ref,
+                       k_any, v_any, bufk, bufv, lsem, n_seqs)
+    n = n_ref[s]
+    first, end = first_ref[s], end_ref[s]
+
+    def alone():
+        _, ls, accs = _row_softmax(walk, first, end, s, ctx_ref[s], q_ref,
+                                   ab_ref, **opts)
+        _store_row(o_ref, s, ls, accs)
+
+    if group == 1:  # no row can ride: every step is a row of its own
+        alone()
+        return
+    pl.when(n == 1)(alone)
+
+    @pl.when(n > 1)
+    def _group():
+        _group_softmax(walk, first, end, s, n, q_ref, ctxv_ref, abv_ref,
+                       o_ref, *acc, group=group, **opts)
+
+
+def _group_softmax(walk, first, end, s, n, q_ref, ctxv_ref, abv_ref, o_ref,
+                   m_sc, l_sc, acc_sc, *, group: int, block_size: int,
+                   scale: float, n_kv: int, gp: int, window: int):
+    """Rows s .. s+n-1 (one table, n <= group) over table slots
+    [first, end) of `walk`, each block multiplied by ALL their queries
+    in one pair of matmuls a KV head: `group` rows x Gp query rows,
+    stacked by a reshape (Gp is whole sublane tiles), every query row
+    masked to its OWN context (ctxv_ref [S * Gp, 1]: a row's context
+    once a query row) and window. The tile of `group` rows is static
+    and starts at s or, near the end of the batch, before it: rows of
+    the tile outside s .. s+n-1 are computed and not stored. The
+    accumulators live in VMEM scratch m_sc / l_sc [KV, group * Gp, 1]
+    and acc_sc [KV, group * Gp, D] (f32; 128 KB a head at D 128: not a
+    loop carry)."""
+    bs = block_size
+    S, _, _, D = q_ref.shape
+    rg = group * gp
+    start = jnp.minimum(s, S - group)
+    rows = pl.ds(start, group)
+    ctx_col = ctxv_ref[pl.ds(pl.multiple_of(start * gp, 8), rg), :]  # (rg, 1)
+
+    m_sc[...] = jnp.full(m_sc.shape, NEG_INF, jnp.float32)
+    l_sc[...] = jnp.zeros(l_sc.shape, jnp.float32)
+    acc_sc[...] = jnp.zeros(acc_sc.shape, jnp.float32)
+
+    def visit(j, kb, vb, carry):
+        cols = j * bs + jax.lax.broadcasted_iota(jnp.int32, (rg, bs), 1)
+        live = cols < ctx_col
+        if window > 0:
+            live = jnp.logical_and(live, cols >= ctx_col - window)
+        for h in range(n_kv):
+            q = q_ref[rows, h].reshape(rg, D)
+            st = _dot(q, kb[:, h, :], trans_b=True) * scale  # (rg, bs)
+            if abv_ref is not None:
+                st = st + abv_ref[h] * cols.astype(jnp.float32)
+            # a block with no live column for a row (past a shorter
+            # context, before a later window) leaves that row's sums as
+            # they were, or, before its first live block, finite values
+            # its first live block's correction (exp(-1e30 - m)) zeroes
+            st = jnp.where(live, st, NEG_INF)
+            m_prev = m_sc[h]
+            m_new = jnp.maximum(m_prev, jnp.max(st, axis=1, keepdims=True))
+            p = jnp.exp(st - m_new)
+            corr = jnp.exp(m_prev - m_new)
+            l_sc[h] = l_sc[h] * corr + jnp.sum(p, axis=1, keepdims=True)
+            acc_sc[h] = acc_sc[h] * corr + _dot(p.astype(vb.dtype),
+                                                vb[:, h, :])
+            m_sc[h] = m_new
+        return carry
+
+    walk(first, end, visit, 0)
+
+    ridx = start + jax.lax.broadcasted_iota(jnp.int32, (group, 1, 1), 0)
+    mine = jnp.logical_and(ridx >= s, ridx < s + n)
+    for h in range(n_kv):
+        l = l_sc[h]
+        out = acc_sc[h] / jnp.where(l == 0.0, 1.0, l)
+        out = jnp.where(ctx_col > 0, out, 0.0)  # batch padding: zeros
+        out = out.reshape(group, gp, D).astype(o_ref.dtype)
+        o_ref[rows, h] = jnp.where(mine, out, o_ref[rows, h])
 
 
 def _decode_fused_kernel(
@@ -899,48 +1091,84 @@ def _walks_live_blocks(qg, k_cache) -> bool:
         return False
     if itemsize == 2 and KV not in (2, 4) and KV % 8:
         return False
+    S, _, Gp, _ = qg.shape
+    rg = _group_rows(Gp, S) * Gp
     need = (4 * _RING * bs * KV * D * itemsize      # k, v x row parity
-            + 2 * qg.size * qg.dtype.itemsize)      # whole-array q, out
+            + 2 * qg.size * qg.dtype.itemsize       # whole-array q, out
+            # a group's accumulators and every row's context a query
+            # row, both f32 / int32 columns padded to whole lanes
+            + 4 * (KV * rg * (D + 256) + S * Gp * 128))
     return need <= _WALK_VMEM_BUDGET
 
 
+# jitted on its own so that a step program's calls, one a layer, trace
+# and lower ONCE (the grouped body doubles what a call takes to lower:
+# +1.9 s over the dense cell's 16 layers otherwise, AOT for v5e);
+# `interpreted` is interpret() at the caller's trace time
+@functools.partial(jax.jit, static_argnums=(6, 7, 8))
 def _attend_live_blocks(qg, ab, k_cache, v_cache, block_table, ctx_lens,
-                        window: int, scale: float):
-    """paged_decode_attention's unfused, unquantised case on the per-row
-    walk: qg [S, KV, Gp, D] grouped queries, ab the [KV, Gp] ALiBi table
-    or None -> [S, KV, Gp, D]."""
+                        window: int, scale: float, interpreted: bool):
+    """paged_decode_attention's unfused, unquantised case on the live-
+    block walk: qg [S, KV, Gp, D] grouped queries, ab the [KV, Gp] ALiBi
+    table or None -> [S, KV, Gp, D]. Which rows walk alone and which as
+    a group is read HERE from the tables (walk_groups) and handed to the
+    kernel as each grid step's part: its span of table slots and the
+    rows it stores."""
     S, KV, Gp, D = qg.shape
     bs = k_cache.shape[1]
+    group = _group_rows(Gp, S)
+    ctx_lens = ctx_lens.astype(jnp.int32)
+    first, end = _live_span(ctx_lens, bs, window)
+    first = jnp.broadcast_to(first, end.shape).astype(jnp.int32)
+    n = jnp.ones((S,), jnp.int32)
+    if group > 1:
+        # [walking row, row]: a group's span runs from its rows' first
+        # live slot to its longest row's end; a row that rides walks
+        # nothing
+        mine = (walk_groups(block_table, group)[None, :]
+                == jnp.arange(S, dtype=jnp.int32)[:, None])
+        n = jnp.sum(mine, axis=1, dtype=jnp.int32)
+        end = jnp.max(jnp.where(mine, end[None, :], 0), axis=1)
+        first = jnp.minimum(end, jnp.min(
+            jnp.where(mine & (ctx_lens > 0)[None, :], first[None, :],
+                      block_table.shape[1]), axis=1))
+    alibi = () if ab is None else (
+        ab, jnp.tile(ab, (1, group))[:, :, None])
     vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
     hbm = pl.BlockSpec(memory_space=pl.ANY)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=5,
         grid=(S,),
-        in_specs=[vmem, hbm, hbm] + ([vmem] if ab is not None else []),
+        in_specs=[vmem, vmem, hbm, hbm] + [vmem] * len(alibi),
         out_specs=vmem,
         scratch_shapes=[
             pltpu.VMEM((2, _RING, bs, KV, D), k_cache.dtype),
             pltpu.VMEM((2, _RING, bs, KV, D), v_cache.dtype),
             pltpu.SemaphoreType.DMA((2, _RING, 2)),
-        ],
+        ] + ([
+            pltpu.VMEM((KV, group * Gp, 1), jnp.float32),
+            pltpu.VMEM((KV, group * Gp, 1), jnp.float32),
+            pltpu.VMEM((KV, group * Gp, D), jnp.float32),
+        ] if group > 1 else []),
     )
     return pl.pallas_call(
         functools.partial(
             _decode_rows_kernel, n_seqs=S, block_size=bs, scale=scale,
             n_kv=KV, gp=Gp, window=window, alibi=ab is not None,
+            group=group,
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(qg.shape, qg.dtype),
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_WALK_VMEM_LIMIT),
-        interpret=interpret(),
+        interpret=interpreted,
         # the trace name of THE SHARED-TABLE DECODE ATTENTION, whatever
         # its grid: the benchmark's readers, chip_smoke.py and the AOT
         # tests find the program by it (the int8 and fused-write cases,
         # still on the (S, NB) grid, carry the same name)
         name="paged_decode_grid",
-    )(block_table, ctx_lens, qg, k_cache, v_cache,
-      *(() if ab is None else (ab,)))
+    )(block_table, ctx_lens, first, end, n, qg,
+      jnp.repeat(ctx_lens, Gp)[:, None], k_cache, v_cache, *alibi)
 
 
 def supports_fused_v2(head_dim: int) -> bool:

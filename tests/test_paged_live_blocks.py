@@ -6,8 +6,13 @@ the LIVE blocks of that row's table and nothing else.
   test: a prefill chunk's rows on one table among distinct rows, the
   context edges around a block boundary, both serving cells' head
   layouts, a window shorter than the context, ALiBi, head dim 64
-  (which the (S, NB) grid keeps);
-- dead table slots are not read, not merely masked;
+  (which the (S, NB) grid keeps); and the GROUPS the walk makes of
+  adjacent rows of one table (PR 46): every size around the static
+  bound, several groups between decode and pad rows, rows whose live
+  blocks differ inside a group, equal tables that are not adjacent;
+- dead table slots are not read, not merely masked; a group's blocks
+  are fetched once, and rows walked alone are bit for bit what they are
+  without a group beside them;
 - which case takes which kernel, read from the traced program;
 - AOT compiles for a DESCRIBED v5e at both serving cells' shapes (no
   chip; the topology is described inside a module-scoped fixture and
@@ -29,6 +34,7 @@ import deepspeed_tpu.models.transformer as T
 from deepspeed_tpu.inference import (
     ServingScheduler, ServingSchedulerConfig, init_inference)
 from deepspeed_tpu.ops.attention import alibi_slopes
+import deepspeed_tpu.ops.pallas.paged_attention as PA
 from deepspeed_tpu.ops.pallas.paged_attention import (
     paged_decode_attention, paged_decode_attention_xla)
 
@@ -36,9 +42,20 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _case(H=4, KV=2, D=128, bs=16, NB=4, ctx=(5, 33, 64), chunk=None,
-          window=0, alibi=False, dtype=jnp.float32):
-    return dict(H=H, KV=KV, D=D, bs=bs, NB=NB, ctx=ctx, chunk=chunk,
-                window=window, alibi=alibi, dtype=dtype)
+          window=0, alibi=False, dtype=jnp.float32, chunks=(), same=(),
+          packed=False):
+    """chunk / chunks: (first row, rows) runs that share the first row's
+    table; same: (row, other) pairs of NON-adjacent rows on one table;
+    packed: the pool is [blocks, bs, KV / 2, 2 * D] (kv_pack)."""
+    chunks = tuple(chunks) + ((chunk,) if chunk else ())
+    return dict(H=H, KV=KV, D=D, bs=bs, NB=NB, ctx=tuple(ctx), chunks=chunks,
+                window=window, alibi=alibi, dtype=dtype, same=same,
+                packed=packed)
+
+
+def _rising(first, n):
+    """Contexts of a chunk's rows: first, first + 1, ..."""
+    return tuple(range(first, first + n))
 
 
 CASES = {
@@ -60,20 +77,81 @@ CASES = {
     # Mosaic takes no manual DMA of a 64-wide block: the grid keeps it
     "head_dim_64": _case(D=64, ctx=(0, 5, 33, 64)),
     "head_dim_64_window": _case(D=64, ctx=(5, 33, 64), window=20),
+    # groups (PR 46): a run of n adjacent rows on one table between two
+    # decode rows; the walk's static bound at Gp 8 is 32 rows, so 33
+    # walk as 32 + 1 and 70 as 32 + 32 + 6
+    **{f"group_of_{n}": _case(ctx=(40, *_rising(3, n), 64), chunk=(1, n),
+                              NB=6)
+       for n in (1, 2, 5, 31, 32, 33, 70)},
+    # the chunk's tail: 5 rows where the chunk has 8, the batch padded
+    # with rows of context 0 on the pad table (a group of their own)
+    "chunk_tail_then_pad_rows": _case(
+        ctx=(30, 50, *_rising(20, 5), 0, 0, 0, 0, 0),
+        chunks=((2, 5), (7, 5))),
+    "two_groups_between_decode_and_pad_rows": _case(
+        ctx=(9, *_rising(1, 6), 40, 0, *_rising(30, 4), 64, 0, 0),
+        chunks=((1, 6), (9, 4), (14, 2))),
+    "three_groups_back_to_back": _case(
+        ctx=(*_rising(14, 4), *_rising(1, 3), *_rising(60, 5), 33),
+        chunks=((0, 4), (4, 3), (7, 5))),
+    # the last group reaches the batch's end: its tile of rows starts
+    # before its first row
+    "group_at_the_end_of_the_batch": _case(
+        ctx=(64, 12, 31, 7, 48, *_rising(15, 3)), chunk=(5, 3)),
+    # rows of one group with one, two and three live blocks
+    "group_rows_differ_in_live_blocks": _case(
+        ctx=(5, *_rising(15, 20), 64), chunk=(1, 20)),
+    "group_block_128": _case(
+        bs=128, NB=3, ctx=(300, *_rising(120, 12), 1), chunk=(1, 12)),
+    # every row its own window; the group's span starts at its
+    # shortest row's window and ends at its longest row's context
+    "group_window": _case(
+        ctx=(5, *_rising(30, 25), 64), chunk=(1, 25), window=20),
+    "group_window_not_a_block_multiple": _case(
+        ctx=(*_rising(44, 9), 50), chunk=(0, 9), window=37, NB=5),
+    "group_alibi": _case(H=8, KV=2, ctx=(2, *_rising(9, 12), 64),
+                         chunk=(1, 12), alibi=True),
+    "group_g1_mha_16kv": _case(H=16, KV=16, ctx=(31, *_rising(10, 10), 3),
+                               chunk=(1, 10)),
+    "group_g4_32q_8kv_bf16": _case(
+        H=32, KV=8, ctx=(49, *_rising(12, 9), 64), chunk=(1, 9),
+        dtype=jnp.bfloat16),
+    "group_g8_16q_2kv_d256_bf16": _case(
+        H=16, KV=2, D=256, ctx=(17, *_rising(28, 7), 1), chunk=(1, 7),
+        dtype=jnp.bfloat16),
+    # Gp 16: the bound is 256 / 16 = 16 rows, 20 walk as 16 + 4
+    "group_g16_bound_16_rows": _case(
+        H=32, KV=2, ctx=(9, *_rising(5, 20), 40), chunk=(1, 20)),
+    # G 12 is not whole sublane tiles: no groups, every row alone
+    "g12_rows_walk_alone": _case(
+        H=24, KV=2, ctx=(9, *_rising(14, 5), 40), chunk=(1, 5)),
+    # the packed head-dim-64 pool: 8 KV heads as 4 rows of 128 lanes
+    "group_packed_d64": _case(
+        H=32, KV=8, D=64, ctx=(33, *_rising(10, 11), 64), chunk=(1, 11),
+        packed=True, dtype=jnp.bfloat16),
+    "packed_d64_rows_alone": _case(
+        H=8, KV=4, D=64, ctx=(0, 5, 33, 64), packed=True),
+    # equal tables that are NOT adjacent do not group and stay correct
+    "equal_tables_not_adjacent": _case(
+        ctx=(20, 9, 21, 40, 22, *_rising(30, 3), 23),
+        chunk=(5, 3), same=((0, 2), (0, 4), (0, 8))),
 }
 
 
 def _inputs(rng, c):
     S, NBLK = len(c["ctx"]), len(c["ctx"]) * c["NB"] + 1
     shape = (NBLK, c["bs"], c["KV"], c["D"])
+    if c["packed"]:
+        shape = (NBLK, c["bs"], c["KV"] // 2, 2 * c["D"])
     q = jnp.asarray(rng.normal(size=(S, c["H"], c["D"])), c["dtype"])
     kc = jnp.asarray(rng.normal(size=shape), c["dtype"])
     vc = jnp.asarray(rng.normal(size=shape), c["dtype"])
     tbl = rng.permutation(NBLK - 1)[: S * c["NB"]].reshape(S, c["NB"])
     tbl = tbl.astype(np.int32)
-    if c["chunk"]:
-        first, n = c["chunk"]
+    for first, n in c["chunks"]:
         tbl[first:first + n] = tbl[first]
+    for row, other in c["same"]:
+        tbl[other] = tbl[row]
     return q, kc, vc, tbl, np.asarray(c["ctx"], np.int32)
 
 
@@ -97,7 +175,7 @@ def test_shared_table_attention_matches_oracle(rng, name):
     np.testing.assert_allclose(np.asarray(out, np.float32)[real],
                                np.asarray(ref, np.float32)[real],
                                rtol=tol, atol=tol)
-    if c["D"] % 128 == 0:  # the walk: a pad row stores zeros
+    if c["D"] % 128 == 0 or c["packed"]:  # the walk: a pad row stores zeros
         assert not np.asarray(out, np.float32)[~real].any()
 
 
@@ -121,6 +199,93 @@ def test_dead_slots_are_not_read(rng):
                                       jnp.asarray(ctx))
     assert np.isfinite(np.asarray(out)).all()
     np.testing.assert_array_equal(np.asarray(out), np.asarray(want))
+
+
+def _count_block_loads(monkeypatch):
+    """Every block the walk fetches passes _arena_block once (K and V
+    move together): count the calls the interpreted kernel makes."""
+    loads = []
+    clip = PA._arena_block
+
+    def counting(idx, n_blocks):
+        jax.debug.callback(lambda i: loads.append(int(i)), idx)
+        return clip(idx, n_blocks)
+
+    monkeypatch.setattr(PA, "_arena_block", counting)
+    PA._attend_live_blocks.clear_cache()  # traced with the plain clamp before
+    return loads
+
+
+@pytest.mark.usefixtures("pallas_interpret")
+@pytest.mark.parametrize("name,reads", [
+    # 40 -> 3 blocks, the group 3..7 -> 1, 64 -> 4
+    ("group_of_5", 3 + 1 + 4),
+    # 33 rows of contexts 3..35: 32 rows (3..34: 3 blocks) + 1 (35: 3)
+    ("group_of_33", 3 + 3 + 3 + 4),
+    # rows of 15..34: one, two and three live blocks, fetched as three
+    ("group_rows_differ_in_live_blocks", 1 + 3 + 4),
+    # windows of 20 over contexts 30..54: slots 0 (30 - 20 = 10) to 3
+    ("group_window", 1 + 4 + 2),
+    # no groups where the tables are equal and apart: every row's own
+    ("equal_tables_not_adjacent", 2 + 1 + 2 + 3 + 2 + 2 + 2),
+    ("g12_rows_walk_alone", 1 + 5 * 1 + 2 + 3),
+])
+def test_a_groups_blocks_are_fetched_once(rng, monkeypatch, name, reads):
+    c = CASES[name]
+    q, kc, vc, tbl, ctx = _inputs(rng, c)
+    loads = _count_block_loads(monkeypatch)
+    try:
+        out = paged_decode_attention(q, kc, vc, jnp.asarray(tbl),
+                                     jnp.asarray(ctx), window=c["window"])
+        out.block_until_ready()
+        jax.effects_barrier()
+    finally:
+        PA._attend_live_blocks.clear_cache()
+    assert len(loads) == reads
+    # and each fetched block is one the fetching row's table names
+    assert set(loads) <= set(tbl.ravel().tolist())
+    lead = PA.walk_groups(tbl, PA._group_rows(max(c["H"] // c["KV"], 8),
+                                              len(ctx)), np)
+    grouped = {first + i for first, n in c["chunks"] for i in range(1, n)}
+    if name == "g12_rows_walk_alone":  # G 12: not whole sublane tiles
+        grouped = set()
+    assert set(np.flatnonzero(lead != np.arange(len(ctx)))) <= grouped
+    assert (lead != np.arange(len(ctx))).sum() >= len(grouped) - 2
+
+
+@pytest.mark.usefixtures("pallas_interpret")
+def test_rows_walked_alone_are_what_they_are_without_a_group(rng):
+    """A group of one takes the per-row walk: the decode rows of a call
+    with a chunk between them are bit for bit what they are in a call
+    of their own, and a chunk of ONE row is such a row too."""
+    c = CASES["group_of_31"]
+    q, kc, vc, tbl, ctx = _inputs(rng, c)
+    # a decode row, the chunk's first, the last
+    alone = np.array([0, 1, len(ctx) - 1])
+    tbl[2:-1] = tbl[2]  # the group is rows 2.., the chunk's first row apart
+    with jax.default_matmul_precision("highest"):
+        out = paged_decode_attention(q, kc, vc, jnp.asarray(tbl),
+                                     jnp.asarray(ctx))
+        want = paged_decode_attention(q[alone], kc, vc,
+                                      jnp.asarray(tbl[alone]),
+                                      jnp.asarray(ctx[alone]))
+    np.testing.assert_array_equal(np.asarray(out)[alone], np.asarray(want))
+
+
+@pytest.mark.parametrize("tables,bound,lead", [
+    ([1, 2, 2, 2, 3, 3, 4], 32, [0, 1, 1, 1, 4, 4, 6]),
+    ([5, 5, 5, 5, 5, 5, 5], 3, [0, 0, 0, 3, 3, 3, 6]),
+    ([7, 8, 7, 8, 7, 7, 9], 32, [0, 1, 2, 3, 4, 4, 6]),  # apart: alone
+    ([1, 2, 3, 4, 5, 6, 7], 32, [0, 1, 2, 3, 4, 5, 6]),
+    ([4, 4, 4, 4, 4, 4, 4], 1, [0, 1, 2, 3, 4, 5, 6]),
+])
+def test_which_row_walks_for_which(tables, bound, lead):
+    """walk_groups: adjacent rows of one table, cut at the bound; the
+    same answer over numpy (the scheduler's counters) and jnp (the
+    kernel's entry)."""
+    tbl = np.asarray(tables, np.int32)[:, None] * np.ones((1, 4), np.int32)
+    assert PA.walk_groups(tbl, bound, np).tolist() == lead
+    assert np.asarray(PA.walk_groups(jnp.asarray(tbl), bound)).tolist() == lead
 
 
 def _kernel_grids(fn, *args):
@@ -172,7 +337,22 @@ def test_which_case_walks_and_which_keeps_the_grid(what, KV, D, dtype,
 # AOT compiles for a described v5e at the serving cells' shapes
 # ---------------------------------------------------------------------------
 
-ROWS, BLOCK, BLOCKS_PER_SEQ, POOL, D = 128, 128, 32, 704, 128
+BLOCK, BLOCKS_PER_SEQ = 128, 32
+
+# the five serving cells that run the walk, at their engines' shapes:
+# rows of a step, query / KV heads, head dim, pool blocks; lfm2's pool
+# is packed (kv_pack: 8 heads of 64 as 4 rows of 128 lanes)
+CELLS = {
+    "serve-chat-saturated": dict(rows=128, H=32, KV=8, D=128, pool=704),
+    "serve-olmoe-chat-saturated": dict(rows=128, H=16, KV=16, D=128,
+                                       pool=704),
+    "serve-lfm2-chat-saturated-r512": dict(rows=512, H=32, KV=8, D=64,
+                                           pool=2048),
+    "serve-qwen3next-chat-saturated-r256": dict(rows=256, H=16, KV=2, D=256,
+                                                pool=1024),
+    "serve-granite4h-chat-saturated-r128": dict(rows=128, H=32, KV=8, D=128,
+                                                pool=1024),
+}
 
 
 @pytest.fixture(scope="module")
@@ -194,24 +374,32 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.mark.parametrize("cell,H,KV", [
-    ("serve-chat-saturated", 32, 8),
-    ("serve-olmoe-chat-saturated", 16, 16),
+@pytest.mark.parametrize("cell,window", [
+    ("serve-chat-saturated", 4096), ("serve-olmoe-chat-saturated", 4096),
+    ("serve-chat-saturated", 0), ("serve-olmoe-chat-saturated", 0),
+    ("serve-lfm2-chat-saturated-r512", 0),
+    ("serve-qwen3next-chat-saturated-r256", 0),
+    ("serve-granite4h-chat-saturated-r128", 0),
 ])
-@pytest.mark.parametrize("window", [4096, 0])
-def test_shared_table_attention_compiles_for_v5e(one_chip, cell, H, KV,
-                                                 window):
+def test_shared_table_attention_compiles_for_v5e(one_chip, cell, window):
+    """The walk WITH its grouped body (groups of 32 rows at every cell's
+    Gp of 8) is what Mosaic is handed at each cell's shapes."""
+    c = CELLS[cell]
+    rows, H, KV, D = c["rows"], c["H"], c["KV"], c["D"]
+    pack = PA.kv_pack(KV, D)
+
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    cache = sds((POOL + 1, BLOCK, KV, D), jnp.bfloat16)
-    args = (sds((ROWS, H, D), jnp.bfloat16), cache, cache,
-            sds((ROWS, BLOCKS_PER_SEQ), jnp.int32), sds((ROWS,), jnp.int32))
+    cache = sds((c["pool"] + 1, BLOCK, KV // pack, D * pack), jnp.bfloat16)
+    args = (sds((rows, H, D), jnp.bfloat16), cache, cache,
+            sds((rows, BLOCKS_PER_SEQ), jnp.int32), sds((rows,), jnp.int32))
 
     def fn(q, kc, vc, table, ctx):
         return paged_decode_attention(q, kc, vc, table, ctx, window=window)
 
-    assert _kernel_grids(fn, *args) == [(ROWS,)]
+    assert PA._group_rows(max(H // KV, 8), rows) == 32
+    assert _kernel_grids(fn, *args) == [(rows,)]
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert any('custom_call_target="tpu_custom_call"' in line
                and "paged_decode_grid" in line for line in text.splitlines())
@@ -221,43 +409,101 @@ def test_shared_table_attention_compiles_for_v5e(one_chip, cell, H, KV,
 # the counter the kernel's time should follow, and its reader
 # ---------------------------------------------------------------------------
 
-def test_the_scheduler_counts_the_live_blocks_it_dispatches(rng):
+def _tiny_scheduler(prefill_chunk, batched_tokens=16, **engine):
     cfg = T.TransformerConfig(vocab_size=128, n_layers=2, n_heads=4,
                               d_model=64, max_seq=128, variant="llama",
                               use_flash=False)
     eng = init_inference(
         T.init(cfg, jax.random.PRNGKey(0)), cfg,
-        dict(max_seq_len=64, kv_block_size=8, num_kv_blocks=32,
-             min_prefill_bucket=8, max_batch_size=8), dtype=jnp.float32)
-    sched = ServingScheduler(
-        eng, ServingSchedulerConfig(max_num_batched_tokens=16,
-                                    prefill_chunk=4, warmup=False), seed=0)
+        dict(dict(max_seq_len=64, kv_block_size=8, num_kv_blocks=32,
+                  min_prefill_bucket=8, max_batch_size=8), **engine),
+        dtype=jnp.float32)
+    return ServingScheduler(
+        eng, ServingSchedulerConfig(max_num_batched_tokens=batched_tokens,
+                                    prefill_chunk=prefill_chunk,
+                                    warmup=False), seed=0)
+
+
+def _spy_on_counts(sched):
+    """Every _count_tokens call that handed over a ctx array: (ctx,
+    tables, steps, what it added to each KV counter)."""
     seen = []
     count = sched._count_tokens
+    keys = ("kv_live_blocks", "kv_block_reads", "kv_grouped_rows")
 
-    def spy(n, width, ctx=None, steps=1):
-        before = sched.counters["kv_live_blocks"]
-        count(n, width, ctx, steps)
+    def spy(n, width, ctx=None, steps=1, tables=None):
+        before = [sched.counters[k] for k in keys]
+        count(n, width, ctx, steps, tables)
         if ctx is not None:
-            seen.append((np.array(ctx), steps,
-                         sched.counters["kv_live_blocks"] - before))
+            seen.append((np.array(ctx), tables, steps,
+                         *(sched.counters[k] - b
+                           for k, b in zip(keys, before))))
 
     sched._count_tokens = spy
+    return seen
+
+
+def test_the_scheduler_counts_the_live_blocks_it_dispatches(rng):
+    sched = _tiny_scheduler(prefill_chunk=4)
+    seen = _spy_on_counts(sched)
     for n in (11, 5):
         sched.submit(rng.integers(0, 128, n).astype(np.int32),
                      max_new_tokens=6)
     sched.run()
     assert seen, "no dispatcher handed over its ctx array"
-    for ctx, steps, added in seen:
+    for ctx, _, steps, added, _, _ in seen:
         assert added == sum(-(-(int(c) + k) // 8)
                             for c in ctx[ctx > 0] for k in range(steps))
-    assert sched.counters["kv_live_blocks"] == sum(a for _, _, a in seen)
+    assert sched.counters["kv_live_blocks"] == sum(s[3] for s in seen)
     # the first chunks' rows sit inside their first block: one each;
     # later rows (contexts of 9 to 17 tokens) read two or three
-    ctx, _, added = seen[0]
+    ctx, _, _, added, _, _ = seen[0]
     assert ctx.max() <= 8 and added == (ctx > 0).sum() > 0
-    ctx, _, added = seen[-1]
+    ctx, _, _, added, _, _ = seen[-1]
     assert ctx.max() > 8 and added > (ctx > 0).sum()
+    # what the walk fetches: a chunk's rows ride on its first row's walk
+    # and the chunk's blocks count once, by its longest row
+    chunked = [s for s in seen if s[5]]
+    assert chunked, "no step held a chunk"
+    for ctx, tables, _, live, reads, rode in chunked:
+        assert reads < live and rode <= (ctx > 0).sum() - 1
+    # a decode-only step: every row a sequence of its own
+    ctx, tables, _, live, reads, rode = seen[-1]
+    assert len({t.tobytes() for t in tables[ctx > 0]}) == (ctx > 0).sum()
+    assert reads == live and rode == 0
+    assert (sched.counters["kv_block_reads"]
+            == sum(s[4] for s in seen) < sched.counters["kv_live_blocks"])
+    assert sched.counters["kv_grouped_rows"] == sum(s[5] for s in seen)
+
+
+def test_a_chunk_of_32_over_two_blocks_among_decode_rows(rng):
+    """One step of the shared-table program as the chat cells run it:
+    decode rows, then a chunk of 32 rows whose contexts cross from the
+    table's first block into its second."""
+    sched = _tiny_scheduler(prefill_chunk=32, batched_tokens=40,
+                            kv_block_size=16, max_seq_len=128,
+                            max_batch_size=40)
+    seen = _spy_on_counts(sched)
+    for n in (5, 9, 13):  # three sequences that will be decoding
+        sched.submit(rng.integers(0, 128, n).astype(np.int32),
+                     max_new_tokens=12)
+    for _ in range(3):
+        sched.step()
+    del seen[:]
+    sched.submit(rng.integers(0, 128, 40).astype(np.int32),
+                 max_new_tokens=2)
+    while not any(s[5] for s in seen):
+        sched.step()
+    ctx, tables, steps, live, reads, rode = next(s for s in seen if s[5])
+    last = tables[np.flatnonzero(ctx > 0)[-1]]  # the chunk's rows come last
+    chunk = np.flatnonzero((tables == last).all(axis=1) & (ctx > 0))
+    decode = np.setdiff1d(np.flatnonzero(ctx > 0), chunk)
+    assert len(chunk) == 32 and len(decode) == 3
+    assert ctx[chunk].tolist() == list(range(1, 33))
+    blocks = -(-ctx // 16)
+    assert live == blocks.sum() == blocks[decode].sum() + 16 * 1 + 16 * 2
+    assert reads == blocks[decode].sum() + 2  # the chunk's two, once
+    assert rode == 31
 
 
 def test_the_live_block_reader():

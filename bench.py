@@ -1,375 +1,47 @@
 #!/usr/bin/env python
 """CPU gate lanes: correctness and count checks on the virtual CPU mesh.
 
-Every lane here runs on the CPU backend under a deterministic virtual
-clock or an iteration count; none of them touches the accelerator and
-none of their numbers is a device metric. `python bench.py` with no
-lane flag is a usage error. What runs on the chip is `chip_smoke.py`
-(the device benchmark grid is ROADMAP A1's work).
+Every lane runs on the CPU backend under a deterministic virtual clock
+or an iteration count; none touches the accelerator and none of their
+numbers is a device metric or a rate. `python bench.py` with no lane
+flag is a usage error. The chip is `chip_smoke.py`'s and
+`benchmarks/run.py`'s. Each lane is one gate of `scripts/ds_gate.py`
+(named in brackets), which lists the conditions it holds; PLAN is
+'default' (the lane's committed JSON) or a path.
 
-`python bench.py --prefix-microbench` runs the HOST-SIDE prefix
-cache microbench (JAX_PLATFORMS=cpu): a synthetic shared-prefix serving
-workload through the real engine, reporting cached-token ratio and
-prefill-tokens-avoided — a device-independent signal for the perf
-trajectory of the ragged control plane's prefix cache.
-
-`python bench.py --serving-sim` runs the CPU-runnable serving
-simulation: one Poisson arrival trace served twice on identical
-engines — (a) the continuous-batching ServingScheduler (chunked
-prefill interleaved with decode, AOT-warmed buckets, double-buffered
-dispatch) and (b) back-to-back run-to-completion generate() batches
-(the pre-scheduler control plane). Reports host-timed TTFT/TPOT/
-completion percentiles and request goodput for both; vs_baseline is
-the scheduler/static goodput ratio.
-
-`python bench.py --serving-sim --replicas N` (N > 1) runs the FLEET
-simulation instead: a shared-prefix Poisson trace served across N
-simulated router replicas under a deterministic virtual clock,
-comparing round-robin vs prefix-aware routing vs prefill/decode
-disaggregation, plus a cache-neutral drain trace on 1 vs N replicas
-for capacity scaling. vs_baseline is the prefix-aware/round-robin
-goodput ratio; exit is non-zero unless prefix-aware wins, the fleet
-scales >= 0.8 per replica, steady state compiles nothing after warmup
-on every replica, and every lane's outputs are token-identical.
-
-`python bench.py --serving-sim --chaos <plan>` (plan = 'default' or a
-FaultPlan JSON path) runs the CHAOS lane: the same virtual-clock
-fleet sim served clean and then under the injected fault plan
-(replica death mid-decode, KV-handoff failures, a straggler window).
-Exit is non-zero unless the chaos pass loses zero tokens with
-token-identical outputs, failover is triggered by the health monitor
-(the lane never calls fail_replica), the straggler is restored via a
-half-open probe, and goodput degradation / orphan-drain recovery stay
-within the plan's budget. scripts/ds_chaos.py gates this in CI
-(docs/fault_tolerance.md).
-
-`python bench.py --train-chaos [plan]` (plan = 'default' =
-TRAINCHAOS.json, or a path) runs the TRAINING chaos lane on the
-virtual 8-device CPU mesh: one elastic training run executed
-uninterrupted and then under the injected plan — a mid-run rank
-preemption answered from peer-redundant ZeRO shards (world shrink +
-regrow, zero disk restores), transient dataloader/collective faults
-healed by bounded retries, and a straggler window that must flag.
-Exit is non-zero unless the data-order ledger is byte-exact, the loss
-trajectory matches the uninterrupted run (bitwise before the
-preemption, within the plan's reassociation budget after), and
-rollback/reconstruction stay within budget. scripts/ds_elastic.py
-gates this in CI (docs/fault_tolerance.md, docs/elasticity.md).
-
-`python bench.py --pipe-sim [plan]` (plan = 'default' = PIPE.json,
-or a path) runs the INTERLEAVED-PIPELINE lane on the virtual
-8-device CPU mesh (docs/pipeline.md): bitwise loss identity across
-pipeline layouts (P=1 == P=2 == P=2 interleaved V=2 on the noiseless
-fp32 path), measured bubble fraction equal to the (P-1)/(V*M+P-1)
-closed form and beating the non-interleaved bound, the zero-3 +
-{data,pipe,model} + bf16 V=2 step projecting faster than V=1 on the
-S009 schedule analysis AND the v5p roofline, and a stage-host
-preemption chaos sub-lane (peer-mirrored stage slices, zero disk
-restores, byte-exact ledger, 'pipe.permute' boundary faults healed
-and charged to the per-stage skew feed). Exit is non-zero unless
-every gate holds, steady state compiles one program per layout, a
-rerun is byte-identical, and the ledger matches the committed
-PIPE.json. scripts/ds_pipe.py gates this in CI.
-
-`python bench.py --sdc-chaos [plan]` (plan = 'default' =
-SDCCHAOS.json, or a path) runs the SILENT-DATA-CORRUPTION lane:
-elastic training and the disaggregated serving fleet, clean and then
-under injected in-memory bit flips (a gradient-path flip the anomaly
-guardian must veto before commit, a peer-mirror flip the digest
-envelope must catch with holder fallover, KV handoff flips discarded
-at import). Exit is non-zero unless every injected flip is detected
-before any state commit, zero poisoned optimizer updates or served
-tokens land (ledger byte-exact, outputs token-identical to clean),
-recovery needs no disk, and a rerun is byte-identical.
-scripts/ds_sdc.py gates this in CI (docs/fault_tolerance.md SDC
-section).
-
-`python bench.py --moe-sim [plan]` (plan = 'default' = MOE.json)
-runs the DROPLESS-MoE lane (docs/moe.md): dropless vs capacity-factor
-routing trained on identical seeds/batches on the virtual 8-device
-mesh (zero3+EP+TP), plus dropless MoE decode through the
-ServingScheduler. Exit is non-zero unless dropless routes every
-assignment (zero drops, pinned), the capacity reference measurably
-drops on the skew workload, dropless trains at least as well, EP=1 ==
-EP=N training math and serving decode tokens, steady-state serving
-compiles nothing after warmup, the expert-utilization census reaches
-scheduler.metrics(), and a rerun is byte-identical.
-scripts/ds_moe.py gates this in CI.
-
-`python bench.py --overlap-probe` runs the COMM/COMPUTE-OVERLAP probe
-(docs/overlap.md) on the virtual 8-device CPU mesh: the two canonical
-training programs (flat zero-3+TP train_step, interleaved-pipeline
-3D train_step_pipe3d) each compiled overlap_comm on vs off, printing
-the S009 step-time projections, exposed-comm fractions, the projected
-on/off delta, and a wall-clock CPU probe per pair (CPU schedules all
-collectives synchronously, so wall time bounds restructure overhead
-while the projection pair carries the hiding win).
-scripts/ds_schedule.py gates the committed exposure pin in CI.
-
-`python bench.py --autoscale-sim [plan]` (plan = 'default' =
-AUTOSCALE.json, or a path) runs the ELASTIC-AUTOSCALING lane
-(docs/autoscaling.md), two tiers sharing ONE Autoscaler policy code
-path: (a) the MACRO diurnal lane — a multi-hour virtual-clock
-diurnal/burst trace (millions of fluid-modeled sessions, premium +
-standard SLO tenants, a 4x burst shoulder) served by the real
-Autoscaler over a deterministic fluid fleet model, gating premium-
-class p95 TTFT within its SLO with zero premium sheds at materially
-lower replica-hours than static peak provisioning (and a valley-
-static reference that must VIOLATE the SLO — the lane has teeth);
-(b) the MICRO fleet lane — a compressed diurnal/burst trace through
-REAL router replicas (engine factory, cache-warm spin-up, graceful
-drain with page-move migration) under the virtual clock, gating
-token-identical outputs vs a static max-fleet reference, zero-token
-drains, and a chaos sub-lane where a replica dies mid-scale-up
-('replica.spinup') and the autoscaler retries with backoff. Exit is
-non-zero unless every gate holds and a rerun is byte-identical.
-scripts/ds_autoscale.py gates this in CI.
+  --serving-sim --replicas N   [fleet] N > 1 router replicas on one
+      shared-prefix Poisson trace: round-robin vs prefix-aware routing
+      vs prefill/decode disaggregation, a cache-neutral drain on 1 vs
+      N replicas; zero recompiles after warmup, token-identical
+      outputs in every lane (docs/serving_router.md)
+  --serving-sim --chaos PLAN   [chaos] the same fleet served clean and
+      under a FaultPlan: replica death mid-decode, KV-handoff
+      failures, a straggler window (docs/fault_tolerance.md)
+  --train-chaos [PLAN]         [elastic] TRAINCHAOS.json: one elastic
+      training run uninterrupted and under a rank preemption answered
+      from peer-redundant ZeRO shards (docs/elasticity.md)
+  --pipe-sim [PLAN]            [pipe] PIPE.json: bitwise loss identity
+      across pipeline layouts, the bubble's closed form, a stage-host
+      preemption (docs/pipeline.md)
+  --sdc-chaos [PLAN]           [sdc] SDCCHAOS.json: injected bit flips
+      in gradients, peer mirrors and KV handoffs, each detected before
+      any commit (docs/fault_tolerance.md)
+  --overload-sim [PLAN]        [overload] OVERLOAD.json: a 4x-capacity
+      burst under the pressure governor and the host spill tier
+  --moe-sim [PLAN]             [moe] MOE.json: dropless against
+      capacity-factor routing, EP=1 == EP=N, dropless serving decode
+      (docs/moe.md)
+  --autoscale-sim [PLAN]       [autoscale] AUTOSCALE.json: the
+      Autoscaler's policy loop over a fluid diurnal trace and over real
+      replicas joining cache-warm and draining (docs/autoscaling.md)
 """
 
+import argparse
 import json
 import os
 import sys
-import time
 
 import numpy as np
-
-
-def _prefix_cache_microbench():
-    """Synthetic shared-prefix workload (chat system-prompt shape): R
-    requests share a long common prefix and differ in a short tail.
-    Host-side by construction — the control plane is pure Python and
-    the tiny model compiles on CPU — so CI gets a stable perf signal
-    for the cache without touching an accelerator."""
-    os.environ["JAX_PLATFORMS"] = "cpu"
-    import jax
-    import jax.numpy as jnp
-
-    from deepspeed_tpu.inference import init_inference
-    from deepspeed_tpu.models import transformer as T
-
-    mcfg = T.TransformerConfig(
-        vocab_size=512, n_layers=2, n_heads=4, d_model=128,
-        max_seq=512, variant="llama", use_flash=False)
-    params = T.init(mcfg, jax.random.PRNGKey(0))
-    eng = init_inference(
-        params, mcfg,
-        dict(max_seq_len=256, kv_block_size=16, num_kv_blocks=64,
-             min_prefill_bucket=16, max_batch_size=32),
-        dtype=jnp.float32)
-    rng = np.random.default_rng(0)
-    system_prefix = list(rng.integers(0, 512, 96))  # 6 full blocks
-    n_requests = 8
-    tail_len = 12
-    t0 = time.perf_counter()
-    for uid in range(n_requests):
-        tail = list(rng.integers(0, 512, tail_len))
-        eng.put([uid], [np.asarray(system_prefix + tail, np.int32)])
-        if uid % 2 == 1:
-            # half the requests retire: their prefix blocks PARK and
-            # later arrivals resurrect them from the LRU pool
-            eng.flush(uid)
-    wall = time.perf_counter() - t0
-    st = eng.prefix_cache_stats()
-    out = {
-        "metric": "prefix_cache_microbench",
-        "workload": {
-            "requests": n_requests,
-            "shared_prefix_tokens": len(system_prefix),
-            "tail_tokens": tail_len,
-            "kv_block_size": eng.config.kv_block_size,
-        },
-        "cached_token_ratio": round(st["cached_token_ratio"], 4),
-        "prefill_tokens_avoided": int(st["cached_tokens"]),
-        "prompt_tokens_total": int(st["prompt_tokens"]),
-        "lookup_hits": int(st["lookup_hits"]),
-        "lookup_misses": int(st["lookup_misses"]),
-        "evictions": int(st["evictions"]),
-        "cow_copies": int(st["cow_copies"]),
-        "parked_blocks": int(st["parked_blocks"]),
-        "wall_s": round(wall, 3),
-        "platform": jax.default_backend(),
-    }
-    print(json.dumps(out))
-    # every request after the first shared the whole system prefix
-    return 0 if st["lookup_hits"] == n_requests - 1 else 1
-
-
-def _serving_sim():
-    """Continuous batching vs static batching on ONE arrival trace.
-
-    Host-side by construction (tiny model, JAX_PLATFORMS=cpu): the
-    signal is the CONTROL-PLANE difference — admission while decoding,
-    chunked prefill piggybacking, immediate retirement — not kernel
-    speed, so CI gets a stable goodput ratio without an accelerator.
-    The static lane models the pre-scheduler serving story exactly:
-    arrivals queue until the current generate() batch fully drains
-    (run-to-completion), and a batch must decode to its longest
-    member's budget."""
-    os.environ["JAX_PLATFORMS"] = "cpu"
-    import jax
-    import jax.numpy as jnp
-
-    from deepspeed_tpu.inference import (
-        ServingScheduler,
-        ServingSchedulerConfig,
-        init_inference,
-    )
-    from deepspeed_tpu.models import transformer as T
-
-    mcfg = T.TransformerConfig(
-        vocab_size=512, n_layers=2, n_heads=4, d_model=128,
-        max_seq=512, variant="llama", use_flash=False)
-    params = T.init(mcfg, jax.random.PRNGKey(0))
-
-    def build_engine():
-        return init_inference(
-            params, mcfg,
-            dict(max_seq_len=256, kv_block_size=16, num_kv_blocks=128,
-                 min_prefill_bucket=16, max_batch_size=16),
-            dtype=jnp.float32)
-
-    # one fixed workload for both lanes: Poisson arrivals, varied
-    # prompt/output lengths (the run-to-completion tax needs variance)
-    rng = np.random.default_rng(0)
-    n_requests = 24
-    arrivals = np.cumsum(rng.exponential(0.05, n_requests))
-    prompts = [list(rng.integers(0, 512, int(rng.integers(16, 64))))
-               for _ in range(n_requests)]
-    max_new = [int(rng.integers(2, 24)) for _ in range(n_requests)]
-
-    def pct(xs, q):
-        return round(float(np.percentile(np.asarray(xs), q)) * 1e3, 2)
-
-    # -- lane A: continuous batching (ServingScheduler) -----------------
-    eng = build_engine()
-    sched = ServingScheduler(
-        eng,
-        ServingSchedulerConfig(max_num_batched_tokens=48,
-                               prefill_chunk=16, decode_chunk=4),
-        seed=0)  # warmup on: AOT grid compiles before the clock starts
-    baseline_sigs = {n: eng.recompile_tracker.n_signatures(n)
-                     for n in eng.recompile_tracker._sigs}
-    t0 = time.perf_counter()
-    submitted = 0
-    finish_wall = {}
-
-    def tick(s):
-        nonlocal submitted
-        now = time.perf_counter() - t0
-        while submitted < n_requests and arrivals[submitted] <= now:
-            s.submit(prompts[submitted], max_new[submitted])
-            submitted += 1
-
-    while submitted < n_requests or sched.has_work:
-        tick(sched)
-        if not sched.step() and submitted < n_requests:
-            time.sleep(max(0.0, arrivals[submitted]
-                           - (time.perf_counter() - t0)))
-    for rid, req in sched.finished.items():
-        finish_wall[rid] = req.finish_t - t0
-    sched_wall = max(finish_wall.values())
-    sched_ttft = [req.first_token_t - req.arrival
-                  for req in sched.finished.values()
-                  if req.first_token_t is not None]
-    sched_tpot = sched._tpot
-    sched_completion = [finish_wall[r] - arrivals[r]
-                       for r in range(n_requests)]
-    new_sigs = sum(
-        eng.recompile_tracker.n_signatures(n) - baseline_sigs.get(n, 0)
-        for n in eng.recompile_tracker._sigs)
-    # lane-end quiesce audit (lifecycle L002 runtime half): every
-    # request finished, so the pool must be whole again — leaked
-    # blocks, tracked sequences, spill bytes, or backlog here mean a
-    # release path was skipped somewhere in the lane
-    from deepspeed_tpu.analysis.lifecycle import quiesce_residuals
-    residuals = quiesce_residuals(sched)
-
-    # -- lane B: static back-to-back generate() batches ------------------
-    eng_b = build_engine()
-    # same compile warmth as lane A: one throwaway batch outside the clock
-    eng_b.generate([prompts[0]], max_new_tokens=2)
-    t0b = time.perf_counter()
-    done = 0
-    static_completion, static_ttft_l = [], []
-    last_finish_b = 0.0
-    while done < n_requests:
-        now = time.perf_counter() - t0b
-        if arrivals[done] > now:
-            time.sleep(arrivals[done] - now)
-            continue
-        now = time.perf_counter() - t0b
-        batch = [i for i in range(done, n_requests) if arrivals[i] <= now]
-        batch = batch[:eng_b.config.max_batch_size]
-        # run-to-completion: the whole batch decodes to its longest
-        # member's budget; tokens reach callers when generate returns
-        eng_b.generate([prompts[i] for i in batch],
-                       max_new_tokens=max(max_new[i] for i in batch))
-        end = time.perf_counter() - t0b
-        for i in batch:
-            static_completion.append(end - arrivals[i])
-            static_ttft_l.append(end - arrivals[i])
-        last_finish_b = end
-        done += len(batch)
-    static_wall = last_finish_b
-
-    goodput_sched = n_requests / sched_wall
-    goodput_static = n_requests / static_wall
-    out = {
-        "metric": "serving_sim_goodput",
-        "value": round(goodput_sched, 2),
-        "unit": "req/s",
-        "vs_baseline": round(goodput_sched / goodput_static, 3),
-        "workload": {
-            "requests": n_requests,
-            "poisson_mean_interarrival_s": 0.05,
-            "prompt_tokens": [16, 64],
-            "max_new_tokens": [2, 24],
-        },
-        "scheduler": {
-            "goodput_rps": round(goodput_sched, 2),
-            "ttft_ms": {"p50": pct(sched_ttft, 50),
-                        "p95": pct(sched_ttft, 95)},
-            "tpot_ms": {"p50": pct(sched_tpot, 50),
-                        "p95": pct(sched_tpot, 95)},
-            "completion_ms": {"p50": pct(sched_completion, 50),
-                              "p95": pct(sched_completion, 95)},
-            "preemptions": sched.counters["preemptions"],
-            "lookahead_steps": sched.counters["lookahead_steps"],
-            "fused_steps": sched.counters["fused_steps"],
-            "recompile_findings": len(eng.recompile_tracker.findings),
-            "new_signatures_after_warmup": int(new_sigs),
-            "prefix_cache_hits": int(
-                eng.prefix_cache_stats()["lookup_hits"]),
-            # KV-pool residency (engine.prefix_cache_stats): resident
-            # bytes/token + quantized-vs-bf16 pool flag per lane
-            "kv_bytes_per_token": int(
-                eng.prefix_cache_stats()["kv_bytes_per_token"]),
-            "kv_pool_quantized": bool(eng.cache.quantized),
-            # warmup-time static footprint per decode bucket (analysis/
-            # costmodel via engine.warmup) — the S004 admission inputs
-            "hbm_per_bucket_mb": {
-                str(w): round(fp["peak_hbm_bytes"] / 2**20, 2)
-                for w, fp in sorted(eng.warmup_footprints.items())},
-            # schedule-aware S009 step-time projection per bucket
-            # (analysis/schedule.py via engine.warmup footprints)
-            "step_time_us_per_bucket": {
-                str(w): round(fp.get("step_time_us", 0.0), 2)
-                for w, fp in sorted(eng.warmup_footprints.items())},
-            "budget_findings": len(sched.budget_report.findings),
-            # empty dict == fully quiesced (gates the exit code)
-            "quiesce_residuals": residuals,
-        },
-        "static": {
-            "goodput_rps": round(goodput_static, 2),
-            "ttft_ms": {"p50": pct(static_ttft_l, 50),
-                        "p95": pct(static_ttft_l, 95)},
-            "completion_ms": {"p50": pct(static_completion, 50),
-                              "p95": pct(static_completion, 95)},
-        },
-        "platform": jax.default_backend(),
-    }
-    print(json.dumps(out))
-    return 0 if goodput_sched > goodput_static and not residuals else 1
 
 
 # deterministic per-step cost model for the fleet simulator: one
@@ -658,7 +330,7 @@ def _router_sim(n_replicas: int):
 # ---------------------------------------------------------------------------
 
 def _default_chaos_plan(n_replicas: int) -> dict:
-    """The CI chaos plan (scripts/ds_chaos.py gates on it): one decode
+    """The CI chaos plan (scripts/ds_gate.py chaos gates on it): one decode
     replica dies permanently mid-decode, two KV handoffs fail, and a
     second decode replica straggles through a window long enough to
     trip the dispatch deadline. Budgets are virtual-clock seconds —
@@ -840,7 +512,7 @@ def _chaos_lane(build_engine, n_replicas, router_cfg, trace, plan=None,
 
 
 def _chaos_sim(n_replicas: int, plan_arg: str):
-    """Chaos gate (scripts/ds_chaos.py; docs/fault_tolerance.md): the
+    """Chaos gate (scripts/ds_gate.py chaos; docs/fault_tolerance.md): the
     deterministic virtual-clock fleet sim served twice — clean, then
     under the injected FaultPlan — asserting ZERO token loss and
     token-identical outputs, health-monitor-triggered failover (the
@@ -969,7 +641,7 @@ def _chaos_sim(n_replicas: int, plan_arg: str):
 # ---------------------------------------------------------------------------
 
 def _default_train_chaos_plan() -> dict:
-    """The CI training chaos plan (scripts/ds_elastic.py gates on it;
+    """The CI training chaos plan (scripts/ds_gate.py elastic gates on it;
     the committed TRAINCHAOS.json is this dict). One rank is preempted
     mid-run (peer-redundant shards must recover it with NO disk
     restore), a transient dataloader I/O error and a transient
@@ -1020,7 +692,7 @@ def _default_train_chaos_plan() -> dict:
 
 
 def _train_chaos(plan_arg: str):
-    """Training chaos gate (scripts/ds_elastic.py;
+    """Training chaos gate (scripts/ds_gate.py elastic;
     docs/fault_tolerance.md): the same elastic training run executed
     twice on the virtual 8-device CPU mesh — uninterrupted, then under
     the injected FaultPlan (a mid-run rank preemption + world shrink +
@@ -1197,11 +869,11 @@ def _train_chaos(plan_arg: str):
 
 # ---------------------------------------------------------------------------
 # pipeline lane: interleaved 3D parallelism — identity, bubble, projection,
-# stage-host chaos (scripts/ds_pipe.py gates this; docs/pipeline.md)
+# stage-host chaos (scripts/ds_gate.py pipe gates this; docs/pipeline.md)
 # ---------------------------------------------------------------------------
 
 def _default_pipe_plan() -> dict:
-    """The CI pipeline plan (scripts/ds_pipe.py gates on it; the
+    """The CI pipeline plan (scripts/ds_gate.py pipe gates on it; the
     committed PIPE.json carries this dict plus the expected ledger).
     Four lanes on the virtual 8-device CPU mesh:
 
@@ -1266,7 +938,7 @@ def _default_pipe_plan() -> dict:
 
 
 def _pipe_sim(plan_arg: str, capture=None):
-    """Pipeline gate (scripts/ds_pipe.py; docs/pipeline.md): identity,
+    """Pipeline gate (scripts/ds_gate.py pipe; docs/pipeline.md): identity,
     bubble, pod projection, and stage-host chaos lanes for the
     interleaved virtual-stage pipeline composed with ZeRO-3/TP."""
     os.environ["JAX_PLATFORMS"] = "cpu"
@@ -1587,7 +1259,7 @@ def _pipe_sim(plan_arg: str, capture=None):
 # ---------------------------------------------------------------------------
 
 def _default_sdc_chaos_plan() -> dict:
-    """The CI silent-data-corruption plan (scripts/ds_sdc.py gates on
+    """The CI silent-data-corruption plan (scripts/ds_gate.py sdc gates on
     it; the committed SDCCHAOS.json carries this dict plus the
     expected detection ledger). Three in-memory flip classes, one per
     registered corrupt point:
@@ -1768,7 +1440,7 @@ def _sdc_serving_lane(plan, wk, jax):
 
 
 def _sdc_chaos(plan_arg: str, capture=None):
-    """SDC chaos gate (scripts/ds_sdc.py; docs/fault_tolerance.md SDC
+    """SDC chaos gate (scripts/ds_gate.py sdc; docs/fault_tolerance.md SDC
     section): the elastic-training and disaggregated-serving lanes run
     clean and then under the injected bit-flip plan, and the gate
     asserts 100% detection of every injected flip (gradient, mirror,
@@ -1933,7 +1605,7 @@ def _sdc_chaos(plan_arg: str, capture=None):
 # ---------------------------------------------------------------------------
 
 def _default_overload_plan() -> dict:
-    """The CI overload plan (scripts/ds_overload.py gates on it; the
+    """The CI overload plan (scripts/ds_gate.py overload gates on it; the
     committed OVERLOAD.json carries this dict plus the expected
     pressure/spill ledger). The workload is a BURST: every request
     arrives inside a window ~4x shorter than one replica can serve it
@@ -2036,7 +1708,7 @@ def _overload_lane(build_engine, sched_cfg, trace, plan=None):
 
 
 def _overload_sim(plan_arg: str, capture=None):
-    """Overload chaos gate (scripts/ds_overload.py;
+    """Overload chaos gate (scripts/ds_gate.py overload;
     docs/fault_tolerance.md pressure section): a 4x-capacity burst
     with the pressure governor + spill tier on, served four times —
     an UNPRESSURED reference (huge pool, no deadlines), the overload
@@ -2247,7 +1919,7 @@ def _overload_sim(plan_arg: str, capture=None):
 
 
 def _default_moe_plan() -> dict:
-    """The CI MoE plan (scripts/ds_moe.py gates on it; the committed
+    """The CI MoE plan (scripts/ds_gate.py moe gates on it; the committed
     MOE.json carries this dict plus the expected quality/routing
     ledger). Two halves: (a) TRAINING — dropless vs capacity-factor
     routing trained on identical seeds/batches on the virtual 8-dev
@@ -2279,7 +1951,7 @@ def _default_moe_plan() -> dict:
 
 
 def _moe_sim(plan_arg: str = "default", capture=None):
-    """Dropless-MoE gate (scripts/ds_moe.py; docs/moe.md): dropless vs
+    """Dropless-MoE gate (scripts/ds_gate.py moe; docs/moe.md): dropless vs
     capacity-factor training ledger + EP layout invariance + dropless
     serving decode through the scheduler, all deterministic on the
     virtual 8-device CPU mesh. With `capture`, writes the committed
@@ -2480,7 +2152,7 @@ def _moe_sim(plan_arg: str = "default", capture=None):
 
 
 def _default_autoscale_plan() -> dict:
-    """The CI autoscaling plan (scripts/ds_autoscale.py gates on it;
+    """The CI autoscaling plan (scripts/ds_gate.py autoscale gates on it;
     the committed AUTOSCALE.json carries this dict plus the expected
     macro/micro ledgers). Two tiers, one Autoscaler policy path:
 
@@ -3031,7 +2703,7 @@ def _autoscale_micro_trace(wk: dict, seed: int):
 
 
 def _autoscale_sim(plan_arg: str, capture=None):
-    """Elastic-autoscaling gate (scripts/ds_autoscale.py;
+    """Elastic-autoscaling gate (scripts/ds_gate.py autoscale;
     docs/autoscaling.md): the macro diurnal lane (three fleet modes)
     plus the micro fleet lane (static reference, autoscaled clean,
     autoscaled + armed spin-up chaos, chaos rerun). With `capture`,
@@ -3208,166 +2880,46 @@ def _autoscale_sim(plan_arg: str, capture=None):
     return 0 if all(gates.values()) else 1
 
 
-def _overlap_probe():
-    """Comm/compute-overlap probe (docs/overlap.md): the two canonical
-    training programs — the flat zero-3+TP train_step and the
-    interleaved-pipeline 3D train_step_pipe3d (V=2) — each compiled
-    twice, overlap_comm on vs off, on the virtual 8-device CPU mesh.
-    Prints ONE JSON line with the S009 step-time projection and
-    exposed-comm fraction for every (program, mode) pair, the
-    projected on/off delta, and a short wall-clock CPU probe (real
-    train_batch steps; CPU compiles every collective synchronously,
-    so the wall numbers bound the restructure's OVERHEAD — the
-    projection pair carries the hiding win). Exit 0 unless the
-    backend yields no schedule artifacts."""
-    os.environ["JAX_PLATFORMS"] = "cpu"
-    flags = os.environ.get("XLA_FLAGS", "")
-    if "xla_force_host_platform_device_count" not in flags:
-        os.environ["XLA_FLAGS"] = (
-            flags + " --xla_force_host_platform_device_count=8")
-    import deepspeed_tpu as ds
-    from deepspeed_tpu.models import transformer as T
+# lane flag -> lane; each takes its plan ("default" = the lane's
+# committed baseline JSON, or a path) and returns the exit code.
+# scripts/ds_gate.py calls the same functions
+LANES = {
+    "train-chaos": _train_chaos,
+    "sdc-chaos": _sdc_chaos,
+    "autoscale-sim": _autoscale_sim,
+    "moe-sim": _moe_sim,
+    "pipe-sim": _pipe_sim,
+    "overload-sim": _overload_sim,
+}
 
-    def flat_engine(overlap):
-        mcfg = T.TransformerConfig(
-            vocab_size=128, n_layers=2, n_heads=4, d_model=64,
-            max_seq=32, variant="llama", use_flash=False)
-        eng = ds.initialize(
-            {"train_micro_batch_size_per_gpu": 1,
-             "gradient_accumulation_steps": 2,
-             "optimizer": {"type": "adamw", "params": {"lr": 1e-3}},
-             "zero_optimization": {"stage": 3,
-                                   "param_persistence_threshold": 64,
-                                   "overlap_comm": overlap},
-             "bf16": {"enabled": True},
-             "mesh": {"data": 4, "model": 2},
-             "steps_per_print": 10**9},
-            loss_fn=T.make_loss_fn(mcfg),
-            param_init_fn=lambda k: T.init(mcfg, k),
-            param_logical_specs=T.logical_specs(mcfg))
-        batch = {"tokens": np.zeros(
-            (eng.config.train_batch_size, 33), np.int32)}
-        return eng, batch
 
-    def pipe_engine(overlap):
-        pcfg = T.TransformerConfig(
-            vocab_size=128, n_layers=4, n_heads=4, d_model=64,
-            max_seq=128, variant="llama", use_flash=False,
-            pipeline_stages=2, pipeline_virtual_stages=2)
-        eng = ds.initialize(
-            {"train_micro_batch_size_per_gpu": 2,
-             "gradient_accumulation_steps": 8,
-             "optimizer": {"type": "adamw", "params": {"lr": 1e-3}},
-             "zero_optimization": {"stage": 3,
-                                   "param_persistence_threshold": 64,
-                                   "overlap_comm": overlap},
-             "bf16": {"enabled": True},
-             "mesh": {"pipe": 2, "data": 2, "model": 2},
-             "steps_per_print": 10**9},
-            loss_fn=T.make_pipelined_loss_fn(pcfg),
-            param_init_fn=lambda k: T.init(pcfg, k),
-            param_logical_specs=T.logical_specs(pcfg),
-            pipelined=True, pipeline_virtual_stages=2)
-        batch = {"tokens": np.zeros(
-            (eng.config.train_batch_size, 129), np.int32)}
-        return eng, batch
-
-    out = {"programs": {}}
-    ok = False
-    for name, build, steps in (("train_step", flat_engine, 3),
-                               ("train_step_pipe3d", pipe_engine, 2)):
-        entry = {}
-        for mode, overlap in (("on", True), ("off", False)):
-            eng, batch = build(overlap)
-            san = eng.sanitize(batch)
-            sched = getattr(san.cost, "_schedule", None) \
-                if san.cost is not None else None
-            eng.train_batch(batch)  # compile + warmup
-            t0 = time.perf_counter()
-            for _ in range(steps):
-                eng.train_batch(batch)
-            wall_ms = (time.perf_counter() - t0) / steps * 1e3
-            rec = {"wall_ms_cpu": round(wall_ms, 2)}
-            if sched is not None:
-                ok = True
-                rec.update({
-                    "s009_step_time_us": round(sched.step_time_s * 1e6, 3),
-                    "exposed_comm_us": round(sched.exposed_s * 1e6, 3),
-                    "exposed_comm_fraction": round(
-                        sched.exposed_comm_fraction, 4),
-                    "n_hidden_sync": sched.n_hidden_sync,
-                })
-            entry[mode] = rec
-        on, off = entry["on"], entry["off"]
-        if "s009_step_time_us" in on and "s009_step_time_us" in off:
-            entry["projected_speedup"] = round(
-                off["s009_step_time_us"] / max(1e-9,
-                                               on["s009_step_time_us"]), 4)
-            entry["exposed_us_hidden"] = round(
-                off["exposed_comm_us"] - on["exposed_comm_us"], 3)
-        out["programs"][name] = entry
-    deltas = [e.get("projected_speedup", 1.0)
-              for e in out["programs"].values()]
-    print(json.dumps({
-        "metric": "overlap_probe_step_time_delta",
-        "value": round(min(deltas), 4) if ok else 0.0,
-        "unit": "x_projected_off_over_on",
-        **out,
-        **({} if ok else {"error": "no schedule artifacts on this "
-                                   "backend; probe inconclusive"}),
-    }))
-    return 0 if ok else 1
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(
+        description=__doc__.split("\n")[0],
+        epilog="the lanes are this file's docstring; the chip is "
+               "chip_smoke.py's and benchmarks/run.py's")
+    lane = ap.add_mutually_exclusive_group(required=True)
+    for name in LANES:
+        lane.add_argument(f"--{name}", nargs="?", const="default",
+                          metavar="PLAN")
+    lane.add_argument("--serving-sim", action="store_true",
+                      help="the fleet lane (--replicas N > 1), or the "
+                           "chaos lane with --chaos PLAN")
+    ap.add_argument("--replicas", type=int, default=0)
+    ap.add_argument("--chaos", metavar="PLAN")
+    args = ap.parse_args(argv)
+    if args.serving_sim:
+        if args.chaos is not None:
+            return _chaos_sim(args.replicas if args.replicas > 1 else 4,
+                              args.chaos)
+        if args.replicas < 2:
+            ap.error("--serving-sim is the fleet lane: --replicas N > 1")
+        return _router_sim(args.replicas)
+    # the group is required and exclusive: exactly one lane has a plan
+    return next(fn(plan) for name, fn in LANES.items()
+                if (plan := getattr(args, name.replace("-", "_")))
+                is not None)
 
 
 if __name__ == "__main__":
-    if "--prefix-microbench" in sys.argv[1:]:
-        sys.exit(_prefix_cache_microbench())
-    if "--overlap-probe" in sys.argv[1:]:
-        sys.exit(_overlap_probe())
-    if "--train-chaos" in sys.argv[1:]:
-        argv = sys.argv[1:]
-        i = argv.index("--train-chaos")
-        plan = (argv[i + 1] if i + 1 < len(argv)
-                and not argv[i + 1].startswith("-") else "default")
-        sys.exit(_train_chaos(plan))
-    if "--sdc-chaos" in sys.argv[1:]:
-        argv = sys.argv[1:]
-        i = argv.index("--sdc-chaos")
-        plan = (argv[i + 1] if i + 1 < len(argv)
-                and not argv[i + 1].startswith("-") else "default")
-        sys.exit(_sdc_chaos(plan))
-    if "--autoscale-sim" in sys.argv[1:]:
-        argv = sys.argv[1:]
-        i = argv.index("--autoscale-sim")
-        plan = (argv[i + 1] if i + 1 < len(argv)
-                and not argv[i + 1].startswith("-") else "default")
-        sys.exit(_autoscale_sim(plan))
-    if "--moe-sim" in sys.argv[1:]:
-        argv = sys.argv[1:]
-        i = argv.index("--moe-sim")
-        plan = (argv[i + 1] if i + 1 < len(argv)
-                and not argv[i + 1].startswith("-") else "default")
-        sys.exit(_moe_sim(plan))
-    if "--pipe-sim" in sys.argv[1:]:
-        argv = sys.argv[1:]
-        i = argv.index("--pipe-sim")
-        plan = (argv[i + 1] if i + 1 < len(argv)
-                and not argv[i + 1].startswith("-") else "default")
-        sys.exit(_pipe_sim(plan))
-    if "--overload-sim" in sys.argv[1:]:
-        argv = sys.argv[1:]
-        i = argv.index("--overload-sim")
-        plan = (argv[i + 1] if i + 1 < len(argv)
-                and not argv[i + 1].startswith("-") else "default")
-        sys.exit(_overload_sim(plan))
-    if "--serving-sim" in sys.argv[1:]:
-        argv = sys.argv[1:]
-        n = int(argv[argv.index("--replicas") + 1]) \
-            if "--replicas" in argv else 1
-        if "--chaos" in argv:
-            plan = argv[argv.index("--chaos") + 1]
-            sys.exit(_chaos_sim(n if n > 1 else 4, plan))
-        sys.exit(_router_sim(n) if n > 1 else _serving_sim())
-    print("usage: python bench.py --<lane> [...]  (the CPU gate lanes in this "
-          "file's docstring; the chip is chip_smoke.py's)", file=sys.stderr)
-    sys.exit(2)
+    sys.exit(main())

@@ -10,26 +10,26 @@ Seven prongs (see docs/static_analysis.md):
               per-device HBM budget (S004), collective-volume blowups
               and baseline regressions (S005), roofline balance (S006).
               Baselines persist to MEMBUDGET.json
-              (`python scripts/ds_budget.py --capture / --check`).
+              (`python scripts/ds_gate.py budget --capture / --check`).
   schedule  — schedule-aware analysis over the same artifacts:
               exposed-collective time (S007), hierarchy-aware replica-
               group placement (S008), critical-path step-time
               projection (S009) — the autotuner's AOT score. Baselines
               persist to SCHEDULE.json
-              (`python scripts/ds_schedule.py --capture / --check`).
+              (`python scripts/ds_gate.py schedule --capture / --check`).
   numerics  — precision-flow analysis over the same artifacts: low-
               precision accumulation (N001), fp32 master-weight
               integrity (N002), loss-scale coverage (N003),
               quantized-collective sanity (N004). Dtype ledgers
               persist to NUMERICS.json
-              (`python scripts/ds_numerics.py --capture / --check`).
+              (`python scripts/ds_gate.py numerics --capture / --check`).
   lint      — `ds-lint`, an AST pass with project rules R001-R008
-              (`python scripts/ds_lint.py --strict`).
+              (`python scripts/ds_gate.py lint --strict`).
   concurrency — interprocedural lockset race detection (C001),
               lock-order deadlock cycles (C002), and callback-thread
               escape analysis (C003) over the whole tree at once; the
               lock ledger persists to CONCURRENCY.json
-              (`python scripts/ds_race.py --capture / --check`). R003
+              (`python scripts/ds_gate.py race --capture / --check`). R003
               is a per-file shim over C001.
   determinism — RNG-discipline and bitwise-reproducibility analysis:
               layout-dependent PRNG draws (D001), reassociation hazards
@@ -37,7 +37,7 @@ Seven prongs (see docs/static_analysis.md):
               nondeterminism (D003), serving draw-key discipline
               (D004); the rng-op/reduce-class ledger persists to
               DETERMINISM.json
-              (`python scripts/ds_determinism.py --capture / --check`).
+              (`python scripts/ds_gate.py determinism --capture / --check`).
               R008 is the per-file lint shim over D001.
 """
 
@@ -51,14 +51,13 @@ from .sanitizer import (
 from .costmodel import (
     ICI_GBPS,
     CostReport,
+    baseline_doc,
     build_cost_report,
     check_against_baseline,
     check_collective_volume,
     check_hbm_budget,
     check_roofline,
-    load_baseline,
     roofline,
-    save_baseline,
 )
 from .schedule import (
     PodTopology,
@@ -114,9 +113,8 @@ __all__ = [
     "check_collective_volume",
     "check_hbm_budget",
     "check_roofline",
-    "load_baseline",
+    "baseline_doc",
     "roofline",
-    "save_baseline",
     "PodTopology",
     "ScheduleAnalysis",
     "analyze_compiled",

@@ -54,7 +54,7 @@ old R003 rule: any unlocked write of a shared container fires.
 
 Pragmas: `# ds-lint: ok C001 <reason>` on the finding line (or the line
 above); `R003` suppresses C001 too — existing suppressions keep
-working. `scripts/ds_race.py` gates the tree (CONCURRENCY.json ledger);
+working. `scripts/ds_gate.py race` gates the tree (CONCURRENCY.json ledger);
 `resilience/interleave.py` is the dynamic twin that proves a finding
 real or a suppression safe.
 """
@@ -65,7 +65,7 @@ import os
 import re
 from typing import Dict, Iterable, List, Optional, Sequence, Set, Tuple
 
-from .report import Finding
+from .report import Finding, site_keys
 
 __all__ = ["C_RULES", "ConcurrencyReport", "analyze_paths",
            "analyze_sources", "r003_findings"]
@@ -228,6 +228,8 @@ class _Mod:
 class ConcurrencyReport:
     findings: List[Finding] = dataclasses.field(default_factory=list)
     suppressed: List[Finding] = dataclasses.field(default_factory=list)
+    #: report.site_keys of `suppressed` (what CONCURRENCY.json holds)
+    suppressed_sites: List[str] = dataclasses.field(default_factory=list)
     files_checked: int = 0
     ledger: Dict[str, dict] = dataclasses.field(default_factory=dict)
 
@@ -586,8 +588,15 @@ def _scan_scope(fnode: ast.FunctionDef, cls: Optional[_Class],
     target = cls.methods if cls is not None else mod.functions
     target[name] = m
     outer_env = _local_types(fnode, cls, mod, known) if pseudo else {}
-    for nid, (kind, pnode) in pseudo.items():
-        pname = f"{name}.<{kind}@{getattr(pnode, 'lineno', 0)}>"
+    # an inline body is named by its order among the bodies of its
+    # kind in this def, never by its line: a root's name is a key of
+    # CONCURRENCY.json, and a moved line is not a finding
+    nth: Dict[str, int] = {}
+    for nid, (kind, pnode) in sorted(
+            pseudo.items(),
+            key=lambda kv: getattr(kv[1][1], "lineno", 0)):
+        nth[kind] = nth.get(kind, 0) + 1
+        pname = f"{name}.<{kind}#{nth[kind]}>"
         pm = _scan_fn(pnode, cls, mod, known, pname, kind, {},
                       extra_env=outer_env)
         target[pname] = pm
@@ -975,6 +984,7 @@ def analyze_sources(sources: Sequence[Tuple[str, str]]
     lines_by_path = {rel: src.splitlines() for rel, src in sources}
     report.findings, report.suppressed = _split_suppressed(
         findings, lines_by_path)
+    report.suppressed_sites = site_keys(report.suppressed, dict(sources))
     sup_by_key: Dict[str, int] = {}
     for f in report.suppressed:
         for key in report.ledger:

@@ -25,13 +25,11 @@ Three checks (findings ride the sanitizer report machinery):
         compute-bound compiles comm- or memory-bound (flops vs
         bytes-accessed vs ICI bytes against the chip's peak rates).
 
-Baselines persist to MEMBUDGET.json (scripts/ds_budget.py --capture /
+Baselines persist to MEMBUDGET.json (scripts/ds_gate.py budget --capture /
 --check, the tier-1 pre-test gate next to ds-lint).
 """
 
 import dataclasses
-import json
-import os
 import re
 from typing import Any, Dict, Optional
 
@@ -52,8 +50,7 @@ __all__ = [
     "check_roofline",
     "check_against_baseline",
     "roofline",
-    "load_baseline",
-    "save_baseline",
+    "baseline_doc",
 ]
 
 # Effective per-chip ICI bandwidth (bytes/s) for the ring-collective
@@ -343,7 +340,7 @@ def check_collective_volume(
                     f"{100 * tolerance:.0f}% tolerance)"),
                 fix_hint=(
                     "inspect collective_volumes() per op kind; re-capture "
-                    "the baseline (scripts/ds_budget.py --capture) only if "
+                    "the baseline (scripts/ds_gate.py budget --capture) only if "
                     "the growth is intended"),
             ))
     return out
@@ -419,29 +416,18 @@ def check_roofline(
 
 
 # ----------------------------------------------------------------------
-# baseline persistence (MEMBUDGET.json / scripts/ds_budget.py)
+# the baseline document (MEMBUDGET.json; scripts/ds_gate.py budget
+# reads and writes the file)
 # ----------------------------------------------------------------------
 
-def load_baseline(path: str) -> Optional[Dict[str, Any]]:
-    """The MEMBUDGET.json document, or None when absent/unreadable."""
-    if not os.path.exists(path):
-        return None
-    try:
-        with open(path, "r", encoding="utf-8") as fh:
-            return json.load(fh)
-    except (OSError, ValueError):
-        return None
-
-
-def save_baseline(
-    path: str,
+def baseline_doc(
     programs: Dict[str, CostReport],
     budgets: Optional[Dict[str, Any]] = None,
     meta: Optional[Dict[str, Any]] = None,
 ) -> Dict[str, Any]:
-    """Write a MEMBUDGET.json baseline: one entry per program with the
+    """The MEMBUDGET.json document: one entry per program with the
     regression-gated scalars, plus the budget block --check enforces."""
-    doc = {
+    return {
         "schema": 1,
         **(meta or {}),
         "budgets": {"hbm_regression_tolerance": 0.10, **(budgets or {})},
@@ -459,10 +445,6 @@ def save_baseline(
             for name, rep in programs.items()
         },
     }
-    with open(path, "w", encoding="utf-8") as fh:
-        json.dump(doc, fh, indent=1, sort_keys=True)
-        fh.write("\n")
-    return doc
 
 
 def check_against_baseline(
@@ -472,7 +454,7 @@ def check_against_baseline(
     label: Optional[str] = None,
 ) -> SanitizerReport:
     """S004 regression form: peak HBM grew more than `tolerance` over
-    the captured baseline entry (the ds_budget.py --check gate — a PR
+    the captured baseline entry (the scripts/ds_gate.py budget --check gate — a PR
     that quietly inflates a step's footprint fails like a lint
     finding). Comm regressions ride check_collective_volume."""
     label = label or report.label
@@ -488,7 +470,7 @@ def check_against_baseline(
                 f"{100 * tolerance:.0f}% tolerance)"),
             fix_hint=(
                 "find the new residency (args/out/temp breakdown in the "
-                "cost report); re-capture with scripts/ds_budget.py "
+                "cost report); re-capture with scripts/ds_gate.py budget "
                 "--capture only if the growth is intended"),
         ))
     return out

@@ -35,7 +35,7 @@ Rules
         keyed on mtime alone (ties fall back to enumeration order),
         `json.dump` without `sort_keys=True` (committed-artifact
         byte stability), iteration over a set, and — in
-        `scripts/ds_*.py` capture paths — `time.time()`/unseeded
+        `scripts/` capture paths — `time.time()`/unseeded
         `random`/`np.random.default_rng()`.
   D004  serving draw-key discipline (AST): a sampled draw in the
         scheduler/router/sampling/engine serving paths must key on
@@ -49,7 +49,7 @@ D003/D004 honor the ds-lint pragma syntax (`# ds-lint: ok D003 <why>`
 on the offending line or the line above); D001/D002 have no source
 anchor, so their override story is the registry: `allow_manual` for
 deliberate per-shard draws, `waived` reduce classes for accepted
-reassociation. Gate: `scripts/ds_determinism.py` against the committed
+reassociation. Gate: `scripts/ds_gate.py determinism` against the committed
 DETERMINISM.json — D findings have NO baseline (any active finding is
 red in every mode); only the per-program rng-op/reduce-class ledger is
 pinned.
@@ -62,7 +62,7 @@ import os
 import re
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
-from .report import Finding, LintReport, SanitizerReport
+from .report import Finding, LintReport, SanitizerReport, site_keys
 
 __all__ = [
     "D_RULES",
@@ -176,7 +176,7 @@ BITWISE_PINS: Dict[str, BitwisePin] = {
              "the installed XLA fuses the shared-param expert reduce "
              "with the TP partial-sum reduce into one all-reduce — "
              "same class as axes=expert, same dynamic pin (also "
-             "scripts/ds_moe.py ep_layout_training_invariant)"),
+             "scripts/ds_gate.py moe ep_layout_training_invariant)"),
         ),
     ),
     "train_step_pipe3d": BitwisePin(
@@ -718,7 +718,10 @@ def _scan_sources(sources: Iterable[Tuple[str, str]],
         active, suppressed = _split_suppressed(found, src.splitlines())
         report.findings.extend(active)
         report.suppressed.extend(suppressed)
+        report.suppressed_sites.extend(
+            site_keys(suppressed, {relpath: src}))
         report.files_checked += 1
+    report.suppressed_sites.sort()
     return report
 
 

@@ -2,7 +2,7 @@
 economy (paged-KV blocks, the host spill tier, handoff payloads, pool
 tallies) plus the meta-audit of the chaos machinery's own coverage.
 Eighth prong of the static-analysis suite (docs/static_analysis.md;
-gate: scripts/ds_lifecycle.py, the 15th tier-1 gate).
+gate: scripts/ds_gate.py lifecycle, the 15th tier-1 gate).
 
 The serving stack acquires and releases resources across deep call
 chains (`scheduler._admit` -> `export_kv` -> `import_kv` -> `adopt`):
@@ -80,7 +80,7 @@ import re
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Sequence, Set, Tuple
 
-from .report import Finding
+from .report import Finding, site_keys
 
 __all__ = [
     "L_RULES", "LIFECYCLE_ROOTS", "LifecycleReport",
@@ -401,10 +401,9 @@ def l001_findings(sources: Sequence[Tuple[str, str]]
     findings: List[Finding] = []
     tallies: Dict[str, Dict[str, int]] = {}
     for rel, tree in trees:
-        t = {"functions": 0, "acquires": 0, "releases": 0}
+        t = {"acquires": 0, "releases": 0}
         for n in ast.walk(tree):
             if isinstance(n, (ast.FunctionDef, ast.AsyncFunctionDef)):
-                t["functions"] += 1
                 s = _scan_l001_fn(n, rel, summaries, findings)
                 t["acquires"] += s["acquires"]
                 t["releases"] += s["releases"]
@@ -1018,7 +1017,7 @@ def analyze_sources(
     rep.findings, rep.suppressed = _split_suppressed(findings, lines)
     rep.findings.sort(key=lambda f: (f.path, f.line, f.rule))
     rep.coverage = coverage
-    rep.ledger = {"roots": tallies, "authorities": authorities}
+    rep.ledger = {"roots": tallies, "authorities": sorted(authorities)}
     return rep
 
 
@@ -1044,21 +1043,23 @@ def analyze_tree(repo_root: str) -> LifecycleReport:
     f3, coverage = l003_findings(registry, lanes, call_sites, reg_lines)
     findings += f3
 
-    lines: Dict[str, List[str]] = {
-        rel: src.splitlines() for rel, src in sources}
+    texts: Dict[str, str] = dict(sources)
     faults_path = os.path.join(repo_root, _FAULTS_REL)
     if os.path.isfile(faults_path):
         with open(faults_path, "r", encoding="utf-8") as fh:
-            lines[_FAULTS_REL] = fh.read().splitlines()
-    rep.findings, rep.suppressed = _split_suppressed(findings, lines)
+            texts[_FAULTS_REL] = fh.read()
+    rep.findings, rep.suppressed = _split_suppressed(
+        findings, {rel: src.splitlines() for rel, src in texts.items()})
     rep.findings.sort(key=lambda f: (f.path, f.line, f.rule))
     rep.coverage = coverage
+    # what LIFECYCLE.json pins is what a rule's verdict rests on, never
+    # an inventory or a position: L001's acquire/release tallies a
+    # root, the classes L002 holds to a counters literal (the keys are
+    # the source's), the waived sites by function. A change that alters
+    # no finding alters no byte of it (docs/static_analysis.md)
     rep.ledger = {
         "roots": tallies,
-        "authorities": authorities,
-        "registry_points": len(registry),
-        "lanes": sorted(lanes),
-        "suppressions": sorted(
-            f"{f.path}:{f.line}:{f.rule}" for f in rep.suppressed),
+        "authorities": sorted(authorities),
+        "suppressions": site_keys(rep.suppressed, texts),
     }
     return rep

@@ -56,7 +56,7 @@ Rules
         per mesh layout (the PR-14 EP=1 != EP=N router-noise bug; the
         static companion to the determinism analyzer's D001). Also:
         unseeded `random.Random()` / `time.time()` in the
-        `scripts/ds_*.py` capture paths — process entropy in a
+        `scripts/` capture paths — process entropy in a
         committed ledger
 
 Pragma: `# ds-lint: ok` suppresses every rule on that line (or the line
@@ -675,7 +675,7 @@ def _check_r002(ctx: _Ctx, tree: ast.Module) -> None:
 # whose roots live elsewhere fall back to the conservative
 # every-method-is-concurrent mode (the old behavior). The cross-file
 # picture — roots registered in ANOTHER module — is the ds_race gate's
-# job (scripts/ds_race.py, the 13th tier-1 gate).
+# job (scripts/ds_gate.py race, the 13th tier-1 gate).
 # ----------------------------------------------------------------------
 
 def _check_r003(ctx: _Ctx, tree: ast.Module) -> None:

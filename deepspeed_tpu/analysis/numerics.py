@@ -41,7 +41,7 @@ Four checks (findings ride the sanitizer report machinery):
 `engine.sanitize()` runs N001-N003 on every train-step flavor (fused,
 fp16-loss-scaled, 1-bit/0-1-Adam, offload-grad) and N004 on the
 compressed programs; `InferenceEngine.sanitize_numerics()` covers the
-serving decode buckets. `scripts/ds_numerics.py` persists per-program
+serving decode buckets. `scripts/ds_gate.py numerics` persists per-program
 dtype ledgers to NUMERICS.json as a tier-1 pre-test gate.
 """
 
@@ -526,7 +526,7 @@ def dtype_ledger(compiled: Any = None, lowered: Any = None) -> Dict:
     text (declared precision — deterministic for a fixed trace),
     collective payload dtypes from the compiled text. A dtype KEY
     appearing here that is absent from the committed baseline is a
-    precision regression (`scripts/ds_numerics.py --check`)."""
+    precision regression (`scripts/ds_gate.py numerics --check`)."""
     ledger: Dict[str, Dict] = {"reduce": {}, "dot": {}, "convert": {},
                                "collectives": {}}
     pre = preopt_hlo_text(lowered) if lowered is not None else None
@@ -578,7 +578,7 @@ def diff_ledgers(
                         f"(x{val}) is not in the committed "
                         "NUMERICS.json baseline"),
                     fix_hint="inspect the new op's precision; "
-                             "re-capture (scripts/ds_numerics.py "
+                             "re-capture (scripts/ds_gate.py numerics "
                              "--capture) only if intended",
                 ))
             elif base[key] != val:

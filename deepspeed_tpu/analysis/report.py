@@ -1,14 +1,15 @@
 """Structured findings shared by the graph sanitizer and ds-lint.
 
 Plain dataclasses, not log lines: tests and CI consume them directly
-(`SanitizerReport.ok` gates a pipeline; `LintReport.by_rule()` feeds the
-baseline count in COVERAGE.md). Rendering is a method, never the storage
-format.
+(`SanitizerReport.ok` gates a pipeline; `LintReport.by_rule()` is the
+count `scripts/ds_gate.py lint --json` prints). Rendering is a method,
+never the storage format.
 """
 
+import ast
 import dataclasses
 from collections import Counter
-from typing import Dict, List
+from typing import Dict, Iterable, List, Mapping
 
 
 @dataclasses.dataclass(frozen=True)
@@ -37,6 +38,48 @@ class Finding:
         if self.fix_hint:
             s += f"\n    hint: {self.fix_hint}"
         return s
+
+
+def _qualname_at(tree: ast.AST, line: int) -> str:
+    """Dotted name of the innermost def/class whose span holds `line`."""
+    best = "<module>"
+
+    def walk(node: ast.AST, prefix: str) -> None:
+        nonlocal best
+        for child in ast.iter_child_nodes(node):
+            if isinstance(child, (ast.FunctionDef, ast.AsyncFunctionDef,
+                                  ast.ClassDef)):
+                if child.lineno <= line <= (child.end_lineno or
+                                            child.lineno):
+                    best = prefix + child.name
+                    walk(child, best + ".")
+            else:
+                walk(child, prefix)
+
+    walk(tree, "")
+    return best
+
+
+def site_keys(findings: Iterable[Finding],
+              sources: Mapping[str, str]) -> List[str]:
+    """The keys a baseline holds for pragma-suppressed sites:
+    `path::qualname RULE`, the n-th such site of one function `#n`
+    from the second on. Never a line number: an edit that moves a site
+    without adding or removing one changes no baseline byte.
+    `sources` maps a finding's path to that file's text."""
+    trees: Dict[str, ast.AST] = {}
+    seen: Counter = Counter()
+    keys = []
+    for f in sorted(findings, key=lambda f: (f.path, f.line, f.rule)):
+        if f.path not in trees:
+            try:
+                trees[f.path] = ast.parse(sources.get(f.path, ""))
+            except SyntaxError:
+                trees[f.path] = ast.Module(body=[], type_ignores=[])
+        key = f"{f.path}::{_qualname_at(trees[f.path], f.line)} {f.rule}"
+        seen[key] += 1
+        keys.append(key if seen[key] == 1 else f"{key}#{seen[key]}")
+    return sorted(keys)
 
 
 @dataclasses.dataclass
@@ -79,9 +122,12 @@ class SanitizerReport(_Report):
 
 @dataclasses.dataclass
 class LintReport(_Report):
-    """ds-lint findings over a file set, plus the suppressed tail."""
+    """ds-lint findings over a file set, plus the suppressed tail
+    (`suppressed_sites`: its `site_keys`, where the producer kept the
+    sources to name them by)."""
 
     suppressed: List[Finding] = dataclasses.field(default_factory=list)
+    suppressed_sites: List[str] = dataclasses.field(default_factory=list)
     files_checked: int = 0
 
     def summary(self) -> str:

@@ -33,7 +33,7 @@ findings-ride-the-sanitizer-report discipline as the rest of
         itself is the AOT score autotuning/autotuner.py ranks candidate
         configs with before any trial execution.
 
-Baselines persist to SCHEDULE.json (scripts/ds_schedule.py --capture /
+Baselines persist to SCHEDULE.json (scripts/ds_gate.py schedule --capture /
 --check, the tier-1 pre-test gate next to ds_lint/ds_budget/
 ds_numerics). All bandwidth constants come from the single authority
 platform/accelerator.LINKS.
@@ -541,7 +541,7 @@ def check_exposed_comm(
                 fix_hint=(
                     "inspect the per-collective exposure ledger "
                     "(ScheduleAnalysis.collectives); re-capture with "
-                    "scripts/ds_schedule.py --capture only if the new "
+                    "scripts/ds_gate.py schedule --capture only if the new "
                     "exposure is intended"),
             ))
     return out
@@ -738,7 +738,7 @@ def check_step_time(
                 fix_hint=(
                     "diff the schedule ledger (exposed/compute legs) "
                     "against the baseline; re-capture with "
-                    "scripts/ds_schedule.py --capture only if the new "
+                    "scripts/ds_gate.py schedule --capture only if the new "
                     "projection is intended"),
             ))
     return out

@@ -26,7 +26,7 @@ Model RNG needs no carrying — the engine derives every step's stream
 from fold_in(seed, step).
 
 The same trainer drives the deterministic training chaos lane
-(`bench.py --train-chaos`, gated by scripts/ds_elastic.py): a FaultPlan
+(`bench.py --train-chaos`, gated by scripts/ds_gate.py elastic): a FaultPlan
 preempts a rank mid-run via the 'engine.step' fault point and the gate
 asserts peer recovery with zero disk restores and a loss trajectory
 matching the uninterrupted run.

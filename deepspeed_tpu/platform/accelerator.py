@@ -37,7 +37,7 @@ LINKS = {
 # Accelerator.peak_flops / hbm_per_device / hbm_bandwidth match the
 # RUNNING device against these — a TPU whose device_kind is not in the
 # tables is an ERROR, never a default; chip_roofline(kind) looks a NAMED
-# chip up directly — how the CPU-hosted gates (scripts/ds_budget.py S006
+# chip up directly — how the CPU-hosted gates (scripts/ds_gate.py budget S006
 # verdict on the fused decode program) project a real serving chip's
 # balance point instead of the host's degenerate 1:1 profile.
 PEAK_FLOPS = {

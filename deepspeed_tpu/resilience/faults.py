@@ -8,7 +8,7 @@ driven by a seeded, REPLAYABLE `FaultPlan` injected at named **fault
 points** compiled into the real code paths (router dispatch, KV
 handoff, checkpoint commit, offload I/O, heartbeats), so CI exercises
 replica death, handoff failure, stragglers, and crash-consistent
-checkpoint recovery deterministically (scripts/ds_chaos.py; the
+checkpoint recovery deterministically (scripts/ds_gate.py chaos; the
 Varuna/Bamboo-class preemption-tolerance posture, PAPERS).
 
 Design constraints:

@@ -5,7 +5,7 @@ program (exactly one finding per seeded violation) and stay silent on
 the real training/inference step functions — a check that never fires is
 dead weight, one that fires on healthy code is noise. Lint rules are
 driven over synthetic sources plus the live tree (which must be clean —
-the `scripts/ds_lint.py --strict` gate).
+the `scripts/ds_gate.py lint --strict` gate).
 """
 
 import textwrap
@@ -974,7 +974,7 @@ class TestLintPragma:
 class TestTreeIsClean:
     def test_package_lints_clean(self):
         """The merged tree must stay lint-clean — the same gate as
-        `python scripts/ds_lint.py --strict`."""
+        `python scripts/ds_gate.py lint --strict`."""
         import os
 
         pkg = os.path.dirname(os.path.abspath(ds.__file__))

@@ -474,15 +474,15 @@ class TestDsBudgetScript:
     """Slow lane: each subprocess rebuilds EVERY canonical program
     (two engine compiles + two inference compiles since the MoE
     program joined) — and the pre-test gate lane already runs
-    `ds_budget.py --check --strict` on every PR, so the fast lane
+    `ds_gate.py budget --check --strict` on every PR, so the fast lane
     carries no coverage gap."""
 
     def _run(self, *args):
         env = dict(os.environ)
         env.pop("XLA_FLAGS", None)  # the script sets its own device count
         return subprocess.run(
-            [sys.executable, os.path.join(REPO, "scripts", "ds_budget.py"),
-             *args],
+            [sys.executable, os.path.join(REPO, "scripts", "ds_gate.py"),
+             "budget", *args],
             capture_output=True, text=True, env=env, cwd=REPO, timeout=600)
 
     def test_check_passes_on_committed_tree(self):

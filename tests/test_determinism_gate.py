@@ -1,4 +1,4 @@
-"""ds_determinism gate roundtrip (scripts/ds_determinism.py): the CLI
+"""determinism gate roundtrip (scripts/ds_gate.py determinism): the CLI
 against the committed DETERMINISM.json ledger.
 
 Fast lane: subset checks (--programs serving_sample_w8 — no engine
@@ -24,8 +24,8 @@ def _run(*args, timeout=600):
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)  # the script sets its own device count
     return subprocess.run(
-        [sys.executable,
-         os.path.join(REPO, "scripts", "ds_determinism.py"), *args],
+        [sys.executable, os.path.join(REPO, "scripts", "ds_gate.py"),
+         "determinism", *args],
         capture_output=True, text=True, env=env, cwd=REPO,
         timeout=timeout)
 
@@ -81,7 +81,7 @@ class TestDsDeterminismScript:
     def test_suppression_drift_warns_then_strict_fails(self, tmp_path):
         base = json.load(open(LEDGER))
         base["host"]["draw_keys"]["suppressed"].append(
-            "deepspeed_tpu/inference/x.py:1 D004")
+            "deepspeed_tpu/inference/x.py::sample D004")
         injected = tmp_path / "determinism.json"
         injected.write_text(json.dumps(base))
         r = _run("--check", "--baseline", str(injected),

@@ -5,7 +5,7 @@ docs/elasticity.md).
 
 The full journey — injected mid-run rank kill + world shrink + regrow
 with a byte-exact data-order ledger — is additionally gated end-to-end
-by `bench.py --train-chaos` / scripts/ds_elastic.py (tier-1 pre-test
+by `bench.py --train-chaos` / scripts/ds_gate.py elastic (tier-1 pre-test
 gate); here the pieces are proven fast and in isolation, plus one
 compact in-process journey.
 """

@@ -4,7 +4,7 @@ blake2b integrity envelopes, the EMA z-score anomaly detector, the
 digest-verified peer-mirror reconstruct, handoff payload verification,
 and the ElasticTrainer guardian journey (veto -> verified-mirror
 rollback -> bitwise-clean replay). The full multi-fault lane is gated
-end-to-end by `bench.py --sdc-chaos` / scripts/ds_sdc.py (tier-1
+end-to-end by `bench.py --sdc-chaos` / scripts/ds_gate.py sdc (tier-1
 pre-test gate); here the pieces are proven fast and in isolation.
 """
 
@@ -575,7 +575,7 @@ class TestSdcGate:
 
         root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
         r = subprocess.run(
-            [sys.executable, os.path.join(root, "scripts", "ds_sdc.py"),
-             "--help"], capture_output=True, text=True, timeout=120)
+            [sys.executable, os.path.join(root, "scripts", "ds_gate.py"),
+             "sdc", "--help"], capture_output=True, text=True, timeout=120)
         assert r.returncode == 0
         assert "--capture" in r.stdout and "--strict" in r.stdout

@@ -397,13 +397,13 @@ class TestRealTree:
 # gate CLI roundtrip
 # ---------------------------------------------------------------------------
 
-GATE = os.path.join(_REPO, "scripts", "ds_lifecycle.py")
+GATE = os.path.join(_REPO, "scripts", "ds_gate.py")
 
 
 def _gate(*args):
     return subprocess.run(
-        [sys.executable, GATE, *args], capture_output=True, text=True,
-        env={**os.environ, "JAX_PLATFORMS": "cpu"})
+        [sys.executable, GATE, "lifecycle", *args], capture_output=True,
+        text=True, env={**os.environ, "JAX_PLATFORMS": "cpu"})
 
 
 @pytest.mark.slow
@@ -434,7 +434,7 @@ class TestGateCLI:
         committed = json.load(open(os.path.join(_REPO,
                                                 "LIFECYCLE.json")))
         committed["ledger"]["suppressions"].append(
-            "deepspeed_tpu/inference/scheduler.py:1:L001")
+            "deepspeed_tpu/inference/scheduler.py::ServingScheduler.step L001")
         b = tmp_path / "drift.json"
         b.write_text(json.dumps(committed))
         r = _gate("--check", "--baseline", str(b))
@@ -446,7 +446,7 @@ class TestGateCLI:
     def test_ledger_drift_fails_even_non_strict(self, tmp_path):
         committed = json.load(open(os.path.join(_REPO,
                                                 "LIFECYCLE.json")))
-        committed["ledger"]["registry_points"] += 1
+        committed["ledger"]["authorities"].append("x.py::Nobody")
         b = tmp_path / "drift.json"
         b.write_text(json.dumps(committed))
         r = _gate("--check", "--baseline", str(b))

@@ -618,8 +618,8 @@ class TestDsNumericsScript:
         env = dict(os.environ)
         env.pop("XLA_FLAGS", None)  # the script sets its own device count
         return subprocess.run(
-            [sys.executable,
-             os.path.join(REPO, "scripts", "ds_numerics.py"), *args],
+            [sys.executable, os.path.join(REPO, "scripts", "ds_gate.py"),
+             "numerics", *args],
             capture_output=True, text=True, env=env, cwd=REPO,
             timeout=600)
 

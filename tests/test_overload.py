@@ -587,7 +587,7 @@ class TestObservability:
 
 class TestOverloadBaseline:
     """The committed OVERLOAD.json must stay consistent with the lane
-    (scripts/ds_overload.py gates the full run; this keeps the cheap
+    (scripts/ds_gate.py overload gates the full run; this keeps the cheap
     structural contract in the fast lane)."""
 
     def test_committed_baseline_shape(self):

@@ -781,16 +781,16 @@ class TestLinkAuthority:
 @pytest.mark.slow
 class TestDsScheduleScript:
     """Slow lane: each subprocess rebuilds EVERY canonical program via
-    ds_budget's builder (the MoE zero3+EP+TP engine included) — and
-    the pre-test gate lane already runs `ds_schedule.py --check
+    the budget gate's builder (the MoE zero3+EP+TP engine included) — and
+    the pre-test gate lane already runs `ds_gate.py schedule --check
     --strict` on every PR, so the fast lane carries no coverage gap."""
 
     def _run(self, *args):
         env = dict(os.environ)
         env.pop("XLA_FLAGS", None)  # the script sets its own device count
         return subprocess.run(
-            [sys.executable,
-             os.path.join(REPO, "scripts", "ds_schedule.py"), *args],
+            [sys.executable, os.path.join(REPO, "scripts", "ds_gate.py"),
+             "schedule", *args],
             capture_output=True, text=True, env=env, cwd=REPO,
             timeout=600)
 

@@ -65,6 +65,11 @@ class InferenceConfig(ConfigModel):
     max_seq_len: int = 4096           # per-sequence context cap
     kv_block_size: int = 128
     num_kv_blocks: int = 512          # total paged-cache blocks
+    # rings of the windowed layers' pool of a model of mixed windows
+    # (model.ring_blocks blocks each, one a tracked sequence from
+    # admission to flush); 0 = one for every tracked sequence. No other
+    # model reads it.
+    num_kv_rings: int = 0
     min_prefill_bucket: int = 64
     tp_size: int = 1                  # tensor-parallel degree
     # KV-cache residency dtype: 'auto' = the engine compute dtype;
@@ -118,7 +123,17 @@ class KvCacheDtypeError(ValueError):
 # and whatever rolls a sequence back (a rejected speculative draft)
 # would leave the state ahead of it. Until a snapshot of the state per
 # cached block exists these are refused HERE, where the engine or the
-# scheduler is built, never computed wrongly.
+# scheduler is built, never computed wrongly. A ring pool (a windowed
+# layer's, in a model of mixed windows: model.ring_blocks blocks a
+# tracked sequence, turned over as the window passes them) is walked by
+# the paged kernels and is not paged either: a cached or exported PAGE
+# of the full layers has no counterpart in it once the ring has turned
+# (prefix_credit, page_transfer: credit, COW, handoff, spill wait for a
+# ring's contents to travel with the last pages), a rejected draft may
+# already have overwritten the block it would roll back to
+# (speculation), and its pools have no int8 form and no sharding yet
+# (int8_kv, mesh: init_cache builds neither). Weight quantization and
+# offload touch no cache and stay served.
 _POOL_CANNOT = {
     "kv": frozenset(),
     "latent": frozenset({"mesh", "weight_quantization", "offload",
@@ -126,13 +141,17 @@ _POOL_CANNOT = {
     "state": frozenset({"mesh", "weight_quantization", "offload",
                         "int8_kv", "page_transfer", "prefix_credit",
                         "speculation"}),
+    "ring": frozenset({"mesh", "int8_kv", "page_transfer", "prefix_credit",
+                       "speculation"}),
 }
 
 
 def pool_kinds(cfg: T.TransformerConfig) -> Tuple[str, ...]:
     """The kinds of pool this model's cache holds: ('kv',), ('latent',),
-    and 'state' beside either where some layers carry recurrent state."""
+    'ring' beside 'kv' where layers of two windows are mixed, and
+    'state' beside any where some layers carry recurrent state."""
     return (("latent" if cfg.is_latent else "kv",)
+            + (("ring",) if cfg.mixed_windows else ())
             + (("state",) if cfg.n_state_layers else ()))
 
 
@@ -152,17 +171,31 @@ def refuse_for_pools(cfg: T.TransformerConfig, feature: str) -> None:
             f"pool cannot do it yet (inference/engine.py _POOL_CANNOT)")
 
 
+def ring_geometry(cfg: T.TransformerConfig, config) -> Tuple[int, int]:
+    """(rings, blocks a ring) of the windowed layers' pool an engine of
+    `config` holds for this model; (0, 0) for a model without rings."""
+    R = M.ring_blocks(cfg, config.kv_block_size, config.blocks_per_seq)
+    return (config.num_kv_rings or config.max_tracked_sequences if R else 0,
+            R)
+
+
 def pool_bytes(cfg: T.TransformerConfig, config, dtype) -> Dict[str, int]:
     """Bytes of the pools an engine of `config` would allocate for this
-    model, from shapes alone: {'kv': ..., 'state': ...}."""
+    model, from shapes alone: {'kv': ..., 'state': ...}, and for a
+    model of mixed windows 'ring', its windowed layers' pools, which
+    'kv' then leaves out."""
+    rings, R = ring_geometry(cfg, config)
     shapes = jax.eval_shape(lambda: M.init_cache(
         cfg, config.num_kv_blocks + 1, config.kv_block_size, dtype,
         kv_quant=config.kv_cache_dtype == "int8",
-        state_slots=config.max_tracked_sequences))
+        state_slots=config.max_tracked_sequences,
+        ring_pool_blocks=rings * R + 1))
     size = lambda tree: sum(x.size * x.dtype.itemsize
                             for x in jax.tree.leaves(tree))
-    return {"kv": size(shapes._replace(state=())),
-            "state": size(shapes.state)}
+    ring = size([pool for pools in (shapes.k, shapes.v)
+                 for pool, held in zip(pools, cfg.ring_layers) if held])
+    return {"kv": size(shapes._replace(state=())) - ring,
+            "state": size(shapes.state), **({"ring": ring} if R else {})}
 
 
 def refuse_pools_beyond(limit: Optional[int], weights: int,
@@ -173,12 +206,13 @@ def refuse_pools_beyond(limit: Optional[int], weights: int,
     head), so max_tracked_sequences is not free, and the alternative is
     an allocation failure in warm-up that names none of them. `limit`
     None (a backend that states no limit: the CPU) refuses nothing."""
-    total = weights + pools["kv"] + pools["state"]
+    kv = pools["kv"] + pools.get("ring", 0)
+    total = weights + kv + pools["state"]
     if limit is not None and pools["state"] and total > limit:
         gb = lambda n: f"{n / 1e9:.2f} GB"
         raise ValueError(
             f"this engine does not fit the device: weights {gb(weights)} + "
-            f"K/V pools {gb(pools['kv'])} + state pools "
+            f"K/V pools {gb(kv)} + state pools "
             f"{gb(pools['state'])} = {gb(total)} of {gb(limit)}; lower "
             f"max_tracked_sequences (a state slot costs "
             f"{gb(pools['state'])} / slots) or num_kv_blocks")
@@ -454,6 +488,7 @@ class InferenceEngine:
         with profiler.span("init.transform", always=True):
             self.refresh_params(params)
             host_sync(self.params)
+        rings, ring_blocks = ring_geometry(model_config, self.config)
         self.state = StateManager(
             num_blocks=self.config.num_kv_blocks,
             block_size=self.config.kv_block_size,
@@ -464,6 +499,7 @@ class InferenceEngine:
             # (the index still fills: admissions that would have been
             # credited are counted, PrefixMatch.declined)
             credit_prefix=pools_can(model_config, "prefix_credit"),
+            num_rings=rings, ring_blocks=ring_blocks,
         )
         self._cow_fn = None  # compiled (cache, src, dst) -> cache page copy
         # compiled block-table transfer pair (disaggregated serving):
@@ -493,7 +529,16 @@ class InferenceEngine:
                 self.config.kv_block_size, dtype, mesh=self.mesh,
                 kv_quant=self.kv_quant,
                 state_slots=self.config.max_tracked_sequences,
+                ring_pool_blocks=rings * ring_blocks + 1,
             ))
+            if rings:
+                pools = pool_bytes(model_config, self.config, dtype)
+                held = model_config.ring_layers
+                sp.set(full_layers=len(held) - sum(held),
+                       full_blocks=self.config.num_kv_blocks,
+                       full_bytes=pools["kv"], window_layers=sum(held),
+                       rings=rings, ring_blocks=ring_blocks,
+                       ring_bytes=pools["ring"])
             # bytes one tracked sequence's slot holds over all the state
             # layers' pools (0 for a model without recurrent state)
             self.state_slot_bytes = sum(
@@ -873,11 +918,11 @@ class InferenceEngine:
 
             census = self._census_cb()
 
-            def step(params, cache, tokens, n_real, tables, *slots):
+            def step(params, cache, tokens, n_real, tables, *rows):
                 return M.prefill_batch(
                     deq(params), cache, tokens, n_real, tables, cfg,
                     use_kernel, mesh=mesh, fetch_layer=fetch,
-                    census_cb=census, slots=slots[0] if slots else None,
+                    census_cb=census, **self._row_kwargs(rows),
                 )
 
             # donated: the paged KV cache aliases the returned cache
@@ -914,11 +959,11 @@ class InferenceEngine:
 
             census = self._census_cb()
 
-            def step(params, cache, tokens, tables, ctx, *slots):
+            def step(params, cache, tokens, tables, ctx, *rows):
                 return M.decode_step(
                     deq(params), cache, tokens, tables, ctx, cfg, use_kernel,
                     mesh=mesh, unique_rows=unique_rows, fetch_layer=fetch,
-                    census_cb=census, slots=slots[0] if slots else None,
+                    census_cb=census, **self._row_kwargs(rows),
                 )
 
             # donated: the KV cache aliases the returned cache in-place
@@ -944,32 +989,32 @@ class InferenceEngine:
             census = self._census_cb()
 
             if sampling is None:
-                def step(params, cache, tokens, tables, ctx, *slots):
+                def step(params, cache, tokens, tables, ctx, *rows):
                     return M.decode_multi(
                         deq(params), cache, tokens, tables, ctx, cfg,
                         n_steps=n_steps, use_kernel=use_kernel, mesh=mesh,
                         fetch_layer=fetch, census_cb=census,
-                        slots=slots[0] if slots else None,
+                        **self._row_kwargs(rows),
                     )
             elif with_presence:
                 def step(params, cache, tokens, tables, ctx, keys, step0,
-                         presence, *slots):
+                         presence, *rows):
                     return M.decode_multi(
                         deq(params), cache, tokens, tables, ctx, cfg,
                         n_steps=n_steps, use_kernel=use_kernel, mesh=mesh,
                         sampling=sampling, keys=keys, step0=step0,
                         presence=presence, fetch_layer=fetch,
-                        census_cb=census, slots=slots[0] if slots else None,
+                        census_cb=census, **self._row_kwargs(rows),
                     )
             else:
                 def step(params, cache, tokens, tables, ctx, keys, step0,
-                         *slots):
+                         *rows):
                     return M.decode_multi(
                         deq(params), cache, tokens, tables, ctx, cfg,
                         n_steps=n_steps, use_kernel=use_kernel, mesh=mesh,
                         sampling=sampling, keys=keys, step0=step0,
                         fetch_layer=fetch, census_cb=census,
-                        slots=slots[0] if slots else None,
+                        **self._row_kwargs(rows),
                     )
 
             # donated: the KV cache aliases the carried cache output
@@ -1014,14 +1059,28 @@ class InferenceEngine:
             return jnp.asarray(x)
         return jax.device_put(jnp.asarray(x), NamedSharding(self.mesh, P()))
 
-    def state_args(self, slots: np.ndarray) -> tuple:
-        """The operand a compiled step of a model with recurrent state
-        takes after the others: each row's sequence's state slot (-1: a
-        pad row), on the device. Empty for every other model, whose
-        programs take no such operand."""
-        if not self.cache.state:
-            return ()
-        return (self._dev(np.asarray(slots, np.int32)),)
+    def state_args(self, slots: np.ndarray,
+                   rings: Optional[np.ndarray] = None) -> tuple:
+        """The operands a compiled step takes after the others for what
+        its rows' sequences hold beside their pages, on the device: each
+        row's state slot (a model with recurrent state), then each row's
+        ring (a model of mixed windows); -1: a pad row, and `rings`
+        None: all pad rows. Empty for every other model, whose programs
+        take no such operand."""
+        args = ()
+        if self.cache.state:
+            args += (self._dev(np.asarray(slots, np.int32)),)
+        if self.state.num_rings:
+            args += (self._dev(np.full(len(slots), -1, np.int32)
+                               if rings is None
+                               else np.asarray(rings, np.int32)),)
+        return args
+
+    def _row_kwargs(self, extra: tuple) -> Dict[str, Any]:
+        """state_args' operands as a step function's keywords."""
+        names = (("slots",) if self.cache.state else ()) + (
+            ("rings",) if self.state.num_rings else ())
+        return dict(zip(names, extra, strict=True))
 
     def _copy_block(self, src: int, dst: int) -> None:
         """Host-issued cache-page copy (the COW half of prefix caching):
@@ -1347,7 +1406,7 @@ class InferenceEngine:
         }
 
     def can_schedule(self, uids: Iterable[int], lengths: Iterable[int]) -> bool:
-        need = 0
+        need = new = 0
         for uid, n in zip(uids, lengths):
             seq = self.state.get(uid)
             seen = seq.seen_tokens if seq else 0
@@ -1355,7 +1414,10 @@ class InferenceEngine:
                 return False
             have = len(seq.blocks) if seq else 0
             need += max(0, -(-(seen + n) // self.state.block_size) - have)
-        return need <= self.state.free_blocks
+            new += seq is None
+        # both pools: the paged blocks, and a ring a sequence not tracked yet
+        return need <= self.state.free_blocks and (
+            not self.state.num_rings or new <= self.state.free_rings)
 
     # -- per-row PRNG streams: key = fold_in(base(seed), uid), draw
     # -- counter = the sampled token's POSITION (seen_tokens at draw
@@ -1432,6 +1494,14 @@ class InferenceEngine:
                         f"uid {uid}: {seq.seen_tokens}+{len(toks)} tokens "
                         "> max_seq_len"
                     )
+                if self.state.num_rings and len(toks) > self.config.kv_block_size:
+                    # a ring is sized for a chunk of one block of rows
+                    # (model.ring_blocks): more would write over
+                    # positions its own first rows still see
+                    raise ValueError(
+                        f"uid {uid}: a chunk of {len(toks)} rows is more "
+                        f"than a ring takes in one step (kv_block_size "
+                        f"{self.config.kv_block_size}); split the put()")
                 decodes.append((i, uid, toks))
                 n_rows += len(toks)
             else:
@@ -1562,9 +1632,11 @@ class InferenceEngine:
                 n_real = np.zeros((bp,), np.int32)
                 tables = np.zeros((bp, self.config.blocks_per_seq), np.int32)
                 slots = np.full((bp,), -1, np.int32)
+                rings = np.full((bp,), -1, np.int32)
                 for row, (pos, uid, toks) in enumerate(wave):
                     n = len(toks)
-                    slots[row] = self.state.extend(uid, n).slot
+                    seq = self.state.extend(uid, n)
+                    slots[row], rings[row] = seq.slot, seq.ring
                     toks_b[row, :n] = toks
                     n_real[row] = n
                     tables[row] = self.state.block_table(
@@ -1572,7 +1644,7 @@ class InferenceEngine:
                 logits, self.cache = self._prefill_batch_fn(bp, tp)(
                     self.params, self.cache, self._dev(toks_b),
                     self._dev(n_real), self._dev(tables),
-                    *self.state_args(slots),
+                    *self.state_args(slots, rings),
                 )
                 for row, (pos, uid, toks) in enumerate(wave):
                     self.state.commit(uid, len(toks), token_ids=toks)
@@ -1596,11 +1668,12 @@ class InferenceEngine:
             tables = np.full((sp, self.config.blocks_per_seq),
                              self.pad_block, np.int32)
             slots = np.full((sp,), -1, np.int32)
+            rings = np.full((sp,), -1, np.int32)
             last_row: List[int] = []  # each chunk's final row index
             row = 0
             for pos, uid, chunk in decodes:
                 base = self.state.get(uid).seen_tokens
-                slot = self.state.extend(uid, len(chunk)).slot
+                seq = self.state.extend(uid, len(chunk))
                 table = self.state.block_table(
                     [uid], self.config.blocks_per_seq, self.pad_block,
                 )[0]
@@ -1608,7 +1681,7 @@ class InferenceEngine:
                     toks[row] = int(tok)
                     ctx[row] = base + j + 1
                     tables[row] = table
-                    slots[row] = slot
+                    slots[row], rings[row] = seq.slot, seq.ring
                     row += 1
                 last_row.append(row - 1)
             # single-token rows are all DISTINCT sequences → the fused
@@ -1617,7 +1690,8 @@ class InferenceEngine:
             unique = all(len(c) == 1 for _, _, c in decodes)
             logits, self.cache = self._decode_fn(sp, unique)(
                 self.params, self.cache, self._dev(toks),
-                self._dev(tables), self._dev(ctx), *self.state_args(slots),
+                self._dev(tables), self._dev(ctx),
+                *self.state_args(slots, rings),
             )
             for (pos, uid, chunk), lr in zip(decodes, last_row):
                 self.state.commit(uid, len(chunk), token_ids=chunk)

@@ -37,6 +37,7 @@ for each of the others, STATE pools [slots, ...]: one entry a tracked
 sequence, of fixed size whatever the sequence's length (PagedCache).
 """
 
+import contextlib
 from functools import partial
 from typing import Any, Dict, List, NamedTuple, Optional, Tuple
 
@@ -371,6 +372,17 @@ class PagedCache(NamedTuple):
     dim 64 a pool is PACKED, [NBLK, bs, KV / 2, 128] (kv_pack: two
     heads a 128-lane row, the same bytes).
 
+    A model of mixed windows (cfg.mixed_windows) holds pools of TWO
+    sizes in these lists: a full layer's [NBLK, ...] paged by a
+    sequence's block table as every other model's, a windowed layer's
+    [rings * R + 1, ...] (ring_blocks) where the sequence that holds
+    ring r (ragged.SequenceDescriptor.ring) keeps its last R blocks of
+    tokens in blocks r * R .. r * R + R - 1, position p in block
+    (p // bs) % R of them: bounded per-sequence state that happens to
+    be walked by the paged kernels, through a table the step makes from
+    the ring's number (_ring_tables). Not paged either: what moves or
+    shares pages is refused for it (engine._POOL_CANNOT 'ring').
+
     A latent-attention model (cfg.is_latent) caches ONE row a token a
     layer, [normed latent; rotary key]: `k` holds its pools
     [NBLK, bs, C] (C the row padded to whole lanes, latent_lanes) and
@@ -410,29 +422,50 @@ class PagedCache(NamedTuple):
 
     @property
     def num_blocks(self) -> int:
-        return self.k[0].shape[0]
+        """Blocks of a PAGED layer's pool (the largest: a windowed
+        layer's ring pool is sized apart)."""
+        return max(pool.shape[0] for pool in self.k)
 
     @property
     def quantized(self) -> bool:
         return self.k_scale is not None
 
 
+def ring_blocks(cfg: T.TransformerConfig, block_size: int,
+                blocks_per_seq: int) -> int:
+    """R, the blocks a sequence's ring holds in a windowed layer of a
+    model of mixed windows (0: the model has no rings): the widest
+    window plus the rows of one sequence a step may write before it
+    attends (a chunk: at most block_size, engine.put refuses more),
+    in whole blocks, plus one because neither end is aligned:
+    ceil((window + block_size - 1) / block_size) + 1, so that no
+    position a row of the chunk still sees shares a block with one the
+    chunk writes. Never more than a table's blocks_per_seq, where the
+    ring is the whole context."""
+    if not cfg.mixed_windows:
+        return 0
+    return min(-(-(cfg.widest_window + block_size - 1) // block_size) + 1,
+               blocks_per_seq)
+
+
 def init_cache(
     cfg: T.TransformerConfig, num_blocks: int, block_size: int, dtype=jnp.bfloat16,
     mesh: Optional[Mesh] = None, kv_quant: bool = False,
-    state_slots: int = 0,
+    state_slots: int = 0, ring_pool_blocks: int = 0,
 ) -> PagedCache:
     """kv_quant=True allocates int8 code pools + f32 per-block scale
     tiles instead of `dtype` pools — half (vs bf16) or a quarter (vs
     f32) the resident KV bytes plus KV*8 scale bytes per token.
     state_slots: rows of each state pool (one a tracked sequence) of a
-    model with recurrent state."""
+    model with recurrent state. ring_pool_blocks: blocks of a WINDOWED
+    layer's pool in a model of mixed windows (rings x ring_blocks + the
+    pad rows' one); its full layers' pools hold num_blocks."""
     KV, D, L = cfg.kv_heads, cfg.head_dim, cfg.n_kv_layers
-    if (cfg.is_latent or cfg.n_state_layers) and (
+    if (cfg.is_latent or cfg.n_state_layers or cfg.mixed_windows) and (
             kv_quant or mesh is not None):
         raise NotImplementedError(
-            "a latent cache, and a cache beside recurrent state, is "
-            "bf16/f32 on one device: no int8 pool and no mesh")
+            "a latent cache, a cache beside recurrent state and a cache "
+            "with rings is bf16/f32 on one device: no int8 pool and no mesh")
     def state_pools(kind):
         # the carried inputs, and before them the heads' matrices, whose
         # pool holds one slot more: the pad rows' (state_step_call)
@@ -461,6 +494,12 @@ def init_cache(
     else:
         mk = lambda: jnp.zeros(shape, dtype)
         mks = lambda: jnp.ones(shape[:3], jnp.float32)
+    if cfg.mixed_windows:
+        mk = lambda ring: jnp.zeros(
+            (ring_pool_blocks if ring else num_blocks, *shape[1:]), dtype)
+        cache = PagedCache(k=[mk(ring) for ring in cfg.ring_layers],
+                           v=[mk(ring) for ring in cfg.ring_layers])
+        return cache._replace(state=state) if state else cache
     if not kv_quant:
         cache = PagedCache(k=[mk() for _ in range(L)],
                            v=[mk() for _ in range(L)])
@@ -470,17 +509,21 @@ def init_cache(
         k_scale=[mks() for _ in range(L)], v_scale=[mks() for _ in range(L)])
 
 
-def _rope_at(x, positions, cfg: T.TransformerConfig):
+def _rope_at(x, positions, cfg: T.TransformerConfig, scaled: bool = True):
     """Rotary embedding of x [..., T, H, D] at per-token positions [T]
     (decode needs a different position per row, unlike training's
     contiguous offset; prefill's prompts share one [Tp]).
     Frequencies come from T.rope_inv_freq so long-context scaling
     (linear / llama3) and partial rotary (Phi) match the training
-    forward exactly."""
-    freqs = T.rope_inv_freq(cfg)
+    forward exactly. scaled: the layer's table is the scaled one
+    (cfg.rope_scaled_at), whose cos and sin YaRN also multiplies by
+    cfg.rope_attention_factor."""
+    freqs = T.rope_inv_freq(cfg, scaled)
     R = T.rope_dim(cfg)
     angles = positions.astype(jnp.float32)[:, None] * freqs[None, :]  # [T, R/2]
     cos, sin = jnp.cos(angles), jnp.sin(angles)
+    if scaled and cfg.rope_attention_factor != 1.0:
+        cos, sin = (t * cfg.rope_attention_factor for t in (cos, sin))
     xr, xp = x[..., :R], x[..., R:]
     c, s = cos[:, None, :], sin[:, None, :]
     if cfg.rope_interleaved:
@@ -1118,8 +1161,9 @@ def _layer(x, lp, li: int, positions, cfg: T.TransformerConfig, mesh, attend,
                     if cfg.attn_output_gate else [])
         q, k = T.qk_norm(q, k, lp, cfg)
         if cfg.use_rope:
-            q = _rope_at(q, positions, cfg)
-            k = _rope_at(k, positions, cfg)
+            scaled = cfg.rope_scaled_at(li)
+            q = _rope_at(q, positions, cfg, scaled)
+            k = _rope_at(k, positions, cfg, scaled)
         if cfg.attention_multiplier is not None:
             # a softmax scale that is not head_dim^-0.5 (Granite's
             # 1/128): q is scaled by what the kernels' own head_dim^-0.5
@@ -1132,7 +1176,12 @@ def _layer(x, lp, li: int, positions, cfg: T.TransformerConfig, mesh, attend,
         q = _cons(q, mesh, *heads)
         k = _cons(k, mesh, *heads)
         v = _cons(v, mesh, *heads)
-        att, layer_cache = attend(q, k, v, li, alibi, lp)
+        # a model of mixed windows: device time by the layer's window
+        # (metadata alone; no other model's program carries the scope)
+        with (jax.named_scope("attn_window" if cfg.window_for_layer(li)
+                              else "attn_full")
+              if cfg.mixed_windows else contextlib.nullcontext()):
+            att, layer_cache = attend(q, k, v, li, alibi, lp)
         if gate:
             with jax.named_scope("attn_gate"):
                 att = att * jax.nn.sigmoid(
@@ -1578,10 +1627,15 @@ def decode_step(
     params, cache: PagedCache, tokens, tables, ctx_lens, cfg: T.TransformerConfig,
     use_kernel: bool = True, mesh: Optional[Mesh] = None,
     unique_rows: bool = False, fetch_layer=None, census_cb=None,
-    slots=None,
+    slots=None, rings=None,
 ):
     """tokens [S] int32, tables [S, NB] int32, ctx_lens [S] int32 (context
     length INCLUDING the new token) → (logits [S, V], new cache).
+
+    rings [S] int32: each row's sequence's ring of the windowed layers'
+    pools (-1: batch padding), for a model of mixed windows and no
+    other: its windowed layers write and walk through _ring_tables,
+    its full layers through `tables`.
 
     slots [S] int32: each row's sequence's state slot (-1: batch
     padding), for a model with recurrent state (cache.state) and no
@@ -1617,6 +1671,13 @@ def decode_step(
         * bs + positions % bs
     )
     flat_idx = jnp.where(valid, flat_idx, jnp.int32(-1))
+    by_window = {False: (tables, flat_idx)}  # does a ring hold the layer
+    if cfg.mixed_windows:
+        _need_rings(rings)
+        ring_tables = _ring_tables(rings, cache, cfg, tables.shape[1])
+        by_window[True] = (ring_tables, jnp.where(valid, jnp.take_along_axis(
+            ring_tables, (positions // bs)[:, None], axis=1)[:, 0] * bs
+            + positions % bs, jnp.int32(-1)))
     # the write fuses into the attention call only on the single-device
     # kernel path (the shard_map TP path and the XLA oracle keep the
     # separate write), and only at widths whose rows' write semaphores
@@ -1629,14 +1690,15 @@ def decode_step(
             return _latent_absorbed(q, k, lp, *_layer_pools(cache, li),
                                     tables, ctx_lens, flat_idx, cfg,
                                     use_kernel)
-        where = (tables, ctx_lens, use_kernel, cfg.window_for_layer(li),
-                 mesh, alibi)
+        window = cfg.window_for_layer(li)
+        table, flat = by_window[cfg.ring_layers[cfg.op_index(li)]]
+        where = (table, ctx_lens, use_kernel, window, mesh, alibi)
         pools = _layer_pools(cache, cfg.op_index(li))
         if fuse_write:
             att, *pools = _decode_attention(q, pools, *where, k_new=k,
-                                            v_new=v, slots=flat_idx)
+                                            v_new=v, slots=flat)
             return att, pools
-        pools = _write_pools(pools, k, v, flat_idx, mesh, use_kernel)
+        pools = _write_pools(pools, k, v, flat, mesh, use_kernel)
         return _decode_attention(q, pools, *where), pools
 
     @partial(_slot_wide, cache=cache, cfg=cfg)
@@ -1656,12 +1718,37 @@ def decode_step(
                     carry=carry, recur=recur)
 
 
+def _need_rings(rings):
+    if rings is None:
+        raise ValueError(
+            "a model of mixed windows holds its windowed layers' K/V in "
+            "rings: the step takes `rings`, each row's sequence's ring "
+            "(ragged.SequenceDescriptor.ring; the engine passes it)")
+
+
+def _ring_tables(rings, cache: PagedCache, cfg: T.TransformerConfig,
+                 n_slots: int):
+    """[S, n_slots] int32: the block table of a WINDOWED layer, made
+    from each row's ring number: table slot j (token positions
+    j * bs ..) is block (j % R) of the row's ring, R = ring_blocks, so
+    the paged kernels write and walk it as they do any table and never
+    learn that slots R apart name one block: of two such slots the
+    window keeps at most one live. Pad rows (ring -1) get the pool's
+    last block, which no ring holds."""
+    pool = cache.k[cfg.ring_layers.index(True)]
+    R = ring_blocks(cfg, cache.block_size, n_slots)
+    rings = rings.astype(jnp.int32)[:, None]
+    return jnp.where(
+        rings >= 0, rings * R + jnp.arange(n_slots, dtype=jnp.int32)[None] % R,
+        jnp.int32(pool.shape[0] - 1))
+
+
 def decode_multi(
     params, cache: PagedCache, tokens, tables, ctx_lens,
     cfg: T.TransformerConfig, n_steps: int, use_kernel: bool = True,
     mesh: Optional[Mesh] = None, unique_rows: bool = True,
     sampling=None, keys=None, step0=None, presence=None,
-    fetch_layer=None, census_cb=None, slots=None,
+    fetch_layer=None, census_cb=None, slots=None, rings=None,
 ):
     """Fused decode: n_steps tokens per compiled program.
 
@@ -1697,7 +1784,8 @@ def decode_multi(
                                     use_kernel, mesh=mesh,
                                     unique_rows=unique_rows,
                                     fetch_layer=fetch_layer,
-                                    census_cb=census_cb, slots=slots)
+                                    census_cb=census_cb, slots=slots,
+                                    rings=rings)
         if sampling is None:
             nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         else:
@@ -1741,12 +1829,15 @@ def prefill_batch(
     params, cache: PagedCache, tokens, n_real, tables,
     cfg: T.TransformerConfig, use_kernel: bool = True,
     mesh: Optional[Mesh] = None, fetch_layer=None, census_cb=None,
-    slots=None,
+    slots=None, rings=None,
 ):
     """Cross-prompt batched prefill: tokens [B, Tp] int32 (padded),
     n_real [B] int32, tables [B, NB] int32 → (last-real-token logits
     [B, V], new cache). slots [B] int32: each prompt's sequence's state
-    slot, for a model with recurrent state (decode_step).
+    slot, for a model with recurrent state (decode_step). rings [B]
+    int32: each prompt's sequence's ring, for a model of mixed windows:
+    a windowed layer keeps the prompt's last ring_blocks blocks alone
+    (older positions are outside every later row's window).
 
     ONE compiled program runs B concurrent prompts — the ragged-batch
     idea of SplitFuse applied to prefill (ref: inference/v2/kernels/
@@ -1767,6 +1858,21 @@ def prefill_batch(
         ) * bs + positions[None, :] % bs,
         jnp.int32(-1),
     ).reshape(B * Tp)
+    flat_by_window = {False: flat_idx}  # does a ring hold the layer
+    if cfg.mixed_windows:
+        _need_rings(rings)
+        ring_tables = _ring_tables(rings, cache, cfg, tables.shape[1])
+        R = ring_blocks(cfg, bs, tables.shape[1])
+        kept = ((positions[None, :] < n_real[:, None])
+                & (positions[None, :] // bs
+                   > ((n_real - 1) // bs)[:, None] - R))
+        flat_by_window[True] = jnp.where(
+            kept,
+            jnp.take_along_axis(ring_tables, jnp.minimum(
+                positions[None, :] // bs, tables.shape[1] - 1), axis=1) * bs
+            + positions[None, :] % bs,
+            jnp.int32(-1),
+        ).reshape(B * Tp)
 
     def attend(q, k, v, li, alibi, lp):
         if cfg.is_latent:  # k: the rows the cache holds
@@ -1778,7 +1884,9 @@ def prefill_batch(
         pools = _write_pools(
             _layer_pools(cache, cfg.op_index(li)),
             k.reshape(B * Tp, *k.shape[2:]),
-            v.reshape(B * Tp, *v.shape[2:]), flat_idx, mesh, use_kernel)
+            v.reshape(B * Tp, *v.shape[2:]),
+            flat_by_window[cfg.ring_layers[cfg.op_index(li)]],
+            mesh, use_kernel)
         flash = partial(causal_attention, window=cfg.window_for_layer(li))
         if _heads_shardable(mesh, cfg):
             # flash kernel per head-shard; GQA grouping stays

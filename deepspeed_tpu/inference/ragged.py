@@ -39,7 +39,18 @@ class KVCacheExhaustedError(RuntimeError):
     RuntimeError subclass, so legacy callers keep working) because the
     serving scheduler's reserve loop must distinguish "pool pressure —
     preempt and retry" from any other RuntimeError (e.g. the tracked-
-    sequence cap), which it must surface, not answer with preemption."""
+    sequence cap), which it must surface, not answer with preemption.
+    `pool` names which pool was short: 'full' (the paged blocks) or
+    'window' (the rings of a model whose windowed layers hold one)."""
+
+    pool = "full"
+
+
+class KVRingsExhaustedError(KVCacheExhaustedError):
+    """Every ring of the windowed layers' pool is held by a tracked
+    sequence: a new sequence waits for one to finish."""
+
+    pool = "window"
 
 
 class BlockedAllocator:
@@ -199,6 +210,13 @@ class SequenceDescriptor:
     # held from tracking to flush. What the row holds is the model's
     # business; a sequence at position 0 reads none of it.
     slot: int = -1
+    # this sequence's ring of the windowed layers' pool (a model of
+    # mixed windows: model.ring_blocks), held from tracking to flush:
+    # ring r is blocks r * R .. r * R + R - 1 of every windowed layer's
+    # pool, token position p in block (p // block_size) % R of them, so
+    # a windowed layer costs a sequence R blocks at any length. -1: the
+    # model has no rings.
+    ring: int = -1
     # prefix-cache bookkeeping: token ids for positions [0, len(tokens))
     # when known, and the chain key per registered/matched full block.
     # tokens_valid is off while tokens are committed that the host has
@@ -246,8 +264,14 @@ class StateManager:
 
     def __init__(self, num_blocks: int, block_size: int, max_tracked: int = 2048,
                  enable_prefix_cache: bool = False,
-                 cache_pool_blocks: int = -1, credit_prefix: bool = True):
+                 cache_pool_blocks: int = -1, credit_prefix: bool = True,
+                 num_rings: int = 0, ring_blocks: int = 0):
         self.block_size = block_size
+        # rings of the windowed layers' pool not held by a tracked
+        # sequence, lowest first; ring_blocks is R, for the accounts
+        self.num_rings, self.ring_blocks = num_rings, ring_blocks
+        self._free_rings: List[int] = list(range(num_rings - 1, -1, -1))
+        self.rings_recycled = 0  # ring blocks a write has turned over
         # False: the index is kept and walked but no admission is
         # credited with cached tokens (what a sequence carries beside
         # its pages, its state slot, is not in the index)
@@ -289,8 +313,13 @@ class StateManager:
                 raise RuntimeError(
                     f"too many tracked sequences ({self.max_tracked})"
                 )
+            if self.num_rings and not self._free_rings:
+                raise KVRingsExhaustedError(
+                    f"every ring of the windowed layers' pool is held "
+                    f"({self.num_rings} rings of {self.ring_blocks} blocks)")
             self._seqs[uid] = SequenceDescriptor(
-                uid=uid, slot=self._free_slots.pop())
+                uid=uid, slot=self._free_slots.pop(),
+                ring=self._free_rings.pop() if self.num_rings else -1)
         return self._seqs[uid]
 
     @property
@@ -317,8 +346,23 @@ class StateManager:
         evict callback drops their index keys first)."""
         return self.allocator.trim_parked(max_blocks)
 
+    @property
+    def free_rings(self) -> int:
+        return len(self._free_rings)
+
+    @property
+    def rings_live(self) -> int:
+        return self.num_rings - len(self._free_rings)
+
     def can_fit(self, uid: int, new_tokens: int) -> bool:
-        seq = self._seqs.get(uid) or SequenceDescriptor(uid=uid)
+        """Whether BOTH pools can take `new_tokens` more of `uid`: the
+        paged blocks they need, and a ring for a sequence that holds
+        none yet."""
+        seq = self._seqs.get(uid)
+        if seq is None:
+            if self.num_rings and not self._free_rings:
+                return False
+            seq = SequenceDescriptor(uid=uid)
         return seq.blocks_needed(new_tokens, self.block_size) <= self.free_blocks
 
     def cache_stats(self) -> Dict[str, float]:
@@ -481,7 +525,7 @@ class StateManager:
                 self.allocator.free([b])
             seq.blocks = [b for b in seq.blocks if b not in acquired]
             if created:
-                self._free_slots.append(self._seqs.pop(uid).slot)
+                self._untrack(uid)
             raise
         if token_ids is not None:
             return seq, match
@@ -556,6 +600,11 @@ class StateManager:
         seq = self._seqs[uid]
         start = seq.seen_tokens
         seq.seen_tokens += new_tokens
+        if self.num_rings:
+            # blocks the write entered whose ring slot held an older one
+            bs, R = self.block_size, self.ring_blocks
+            self.rings_recycled += max(
+                0, (seq.seen_tokens - 1) // bs - max((start - 1) // bs, R - 1))
         if not self.enable_prefix_cache or not seq.tokens_valid:
             return
         if token_ids is not None:
@@ -593,11 +642,17 @@ class StateManager:
         blocks. Refcounted: shared blocks survive for their other
         owners; index-addressed blocks whose count hits zero park in
         the LRU pool for future prefix hits."""
-        seq = self._seqs.pop(uid, None)
-        if seq is None:
+        if uid not in self._seqs:
             raise KeyError(f"unknown sequence uid {uid}")
+        self.allocator.free(self._untrack(uid).blocks)
+
+    def _untrack(self, uid: int) -> SequenceDescriptor:
+        """Stop tracking `uid`: its slot and its ring go back."""
+        seq = self._seqs.pop(uid)
         self._free_slots.append(seq.slot)
-        self.allocator.free(seq.blocks)
+        if seq.ring >= 0:
+            self._free_rings.append(seq.ring)
+        return seq
 
     # -- device views ----------------------------------------------------
     def block_table(self, uids: List[int], max_blocks: int,

@@ -212,6 +212,9 @@ class ServingScheduler:
             raise ValueError("speculative decoding is greedy-only")
         if self._spec:
             refuse_for_pools(engine.cfg, "speculation")
+        # of the engine's count of ring blocks turned over, what this
+        # scheduler's counter holds already (_count_rings)
+        self._rings_recycled_seen = engine.state.rings_recycled
         self.waiting: "deque[Request]" = deque()
         self.active: List[Request] = []   # admission order; PREFILL/RUNNING
         self.finished: Dict[int, Request] = {}
@@ -278,6 +281,22 @@ class ServingScheduler:
             "state_slots_live": 0,
             "state_slot_resets": 0,
             "state_prefix_credits_refused": 0,
+            # a model of mixed windows (0 for every other): counted once
+            # a dispatched SEQUENCE a step (a chunk's rows are one read,
+            # by its longest row). Cached tokens a FULL layer's walk
+            # reads for it (its context) and those a WINDOWED layer's
+            # does (its context, at most the window); ring blocks its
+            # writes have turned over (a block entered whose ring slot
+            # held an older one: StateManager.rings_recycled); rings
+            # held by tracked sequences, summed over dispatched steps;
+            # and admission passes that left a request waiting because
+            # the paged blocks, or the rings, were short
+            "kv_full_tokens": 0,
+            "kv_window_tokens": 0,
+            "kv_ring_blocks_recycled": 0,
+            "kv_rings_live": 0,
+            "admit_waits_full_pool": 0,
+            "admit_waits_window_pool": 0,
             # bytes of their slots the dispatched programs' sequences
             # read and wrote, over all state layers (a step over rows
             # reads and writes each live sequence's slot once a layer,
@@ -871,7 +890,9 @@ class ServingScheduler:
             uid = self._alloc_uid()
             try:
                 _, match = eng.state.extend(uid, len(base), token_ids=base)
-            except KVCacheExhaustedError:
+            except KVCacheExhaustedError as short:
+                if eng.state.num_rings:
+                    self.counters[f"admit_waits_{short.pool}_pool"] += 1
                 if not self.active:
                     # alone against an empty pool and still no fit: the
                     # prompt needs more blocks than the cache holds —
@@ -962,19 +983,22 @@ class ServingScheduler:
             n_real = np.zeros((bp,), np.int32)
             tables = np.zeros((bp, eng.config.blocks_per_seq), np.int32)
             slots = np.full((bp,), -1, np.int32)
+            rings = np.full((bp,), -1, np.int32)
             for row, r in enumerate(wave):
                 base = r.base
                 toks_b[row, :len(base)] = base
                 n_real[row] = len(base)
                 tables[row] = eng.state.block_table(
                     [r.uid], eng.config.blocks_per_seq)[0]
-                slots[row] = eng.state.get(r.uid).slot
+                seq = eng.state.get(r.uid)
+                slots[row], rings[row] = seq.slot, seq.ring
             eng.recompile_tracker.record(
                 f"serving_prefill[b{bp},t{tp}]", (toks_b, n_real, tables))
             ph.mark("launch", kind="wave", rows=int(n_real.sum()))
             logits, eng.cache = eng._prefill_batch_fn(bp, tp)(
                 eng.params, eng.cache, eng._dev(toks_b),
-                eng._dev(n_real), eng._dev(tables), *eng.state_args(slots))
+                eng._dev(n_real), eng._dev(tables),
+                *eng.state_args(slots, rings))
             ph.mark("commit")
             sample_rows = []
             for row, r in enumerate(wave):
@@ -987,6 +1011,7 @@ class ServingScheduler:
             parts.append(_Part("wave", sample_rows, tok_dev))
             self.counters["wave_prefills"] += len(wave)
             self._count_tokens(int(n_real.sum()), bp * tp)
+            self._count_rings(n_real[:len(wave)])
             self._count_state(n_real[:len(wave)].tolist(), bp * tp,
                               reads=False)
             self._it_rows += int(n_real.sum())
@@ -1025,6 +1050,23 @@ class ServingScheduler:
                 self.counters["mla_cache_tokens"] += int(np.sum(live))
         if cfg.n_state_layers:
             self.counters["state_slots_live"] += self.engine.state.n_tracked
+
+    def _count_rings(self, contexts) -> None:
+        """What a dispatched step's SEQUENCES read of the two kinds of
+        K/V a model of mixed windows holds: `contexts` is each one's
+        context after the step (a chunk's rows are one read, by its
+        longest row)."""
+        state = self.engine.state
+        if not state.num_rings:
+            return
+        ctx = np.asarray(contexts, np.int64)
+        self.counters["kv_full_tokens"] += int(ctx.sum())
+        self.counters["kv_window_tokens"] += int(
+            np.minimum(ctx, self.engine.cfg.widest_window).sum())
+        self.counters["kv_rings_live"] += state.rings_live
+        self.counters["kv_ring_blocks_recycled"] += (
+            state.rings_recycled - self._rings_recycled_seen)
+        self._rings_recycled_seen = state.rings_recycled
 
     def _count_state(self, runs: Sequence[int], width: int, steps: int = 1,
                      reads: bool = True) -> None:
@@ -1073,6 +1115,9 @@ class ServingScheduler:
         tables = np.full((sp, eng.config.blocks_per_seq),
                          eng.pad_block, np.int32)
         slots = np.full((sp,), -1, np.int32)  # each row's state slot
+        # and its ring (a model of mixed windows alone)
+        rings = (np.full((sp,), -1, np.int32) if eng.state.num_rings
+                 else None)
         sample_rows: List[Tuple[Request, int]] = []
         row = 0
         for req, chunk, sample in rows:
@@ -1081,6 +1126,8 @@ class ServingScheduler:
             table = eng.state.block_table(
                 [req.uid], eng.config.blocks_per_seq, eng.pad_block)[0]
             slots[row:row + len(chunk)] = seq.slot
+            if rings is not None:
+                rings[row:row + len(chunk)] = seq.ring
             for j, tok in enumerate(chunk):
                 if tok is None:
                     srcs[row] = src[req.rid]
@@ -1107,7 +1154,7 @@ class ServingScheduler:
                                              eng._dev(srcs))
         logits, eng.cache = eng._decode_fn(sp, unique)(
             eng.params, eng.cache, toks_dev, eng._dev(tables),
-            eng._dev(ctx), *eng.state_args(slots))
+            eng._dev(ctx), *eng.state_args(slots, rings))
         # host bookkeeping overlaps the in-flight device program
         ph.mark("commit")
         for req, chunk, sample in rows:
@@ -1127,6 +1174,9 @@ class ServingScheduler:
                    if sample_rows else None)
         ph.mark("commit")
         self._count_tokens(n_rows, sp, ctx, tables=tables)
+        if rings is not None:
+            self._count_rings([eng.state.get(req.uid).seen_tokens
+                               for req, _, _ in rows])
         self._count_state([len(c) for _, c, _ in rows], sp)
         return _Part("mixed", sample_rows, tok_dev)
 
@@ -1152,10 +1202,11 @@ class ServingScheduler:
                      if scfg.needs_presence and use_sampler else None)
         sample_rows = []
         slots = np.full((width,), -1, np.int32)
+        rings = np.full((width,), -1, np.int32)
         for r, req in enumerate(running):
             seq = eng.state.get(req.uid)
             base = seq.seen_tokens
-            slots[r] = seq.slot
+            slots[r], rings[r] = seq.slot, seq.ring
             eng.state.extend(req.uid, C)  # capacity pre-checked by caller
             toks[r] = req.pending
             ctx[r] = base + 1
@@ -1180,12 +1231,14 @@ class ServingScheduler:
             args.append(eng._dev(steps))
             if pres_rows is not None:
                 args.append(eng._dev(pres_rows))
-        args += eng.state_args(slots)
+        args += eng.state_args(slots, rings)
         gen, _, eng.cache, _ = fn(*args)
         ph.mark("commit")
         for req in running:
             eng.state.commit(req.uid, C)
         self._count_tokens(len(running) * C, width, ctx, steps=C)
+        for i in range(C):
+            self._count_rings(ctx[:len(running)] + i)
         self._count_state([1] * len(running), width, steps=C)
         self.counters["fused_steps"] += 1
         return _Part("fused", sample_rows, gen, n_steps=C)
@@ -1276,6 +1329,8 @@ class ServingScheduler:
         budget = self.cfg.max_num_batched_tokens
         row_budget = self.engine.config.max_batch_size
         pchunk = self.cfg.prefill_chunk
+        if self.engine.state.num_rings:  # what a ring takes in one step
+            pchunk = min(pchunk, self.engine.config.kv_block_size)
         if self._brownout():
             # shrink the prefill chunk: under brownout every reserved
             # prefill token is pool pressure the decode rows pay for

@@ -21,6 +21,7 @@ model_implementations zoo). TPU-first design decisions:
 """
 
 import dataclasses
+import math
 from functools import partial
 from typing import Any, Dict, Optional, Tuple
 
@@ -125,12 +126,23 @@ class TransformerConfig:
     random_ltd_layer_range: Optional[Tuple[int, int]] = None
     # RoPE frequency scaling for long-context checkpoints (HF
     # rope_scaling): "none" | "linear" (positions / factor) | "llama3"
-    # (NTK-style per-band wavelength remap, the Llama-3.x rule).
+    # (NTK-style per-band wavelength remap, the Llama-3.x rule) | "yarn"
+    # (SERVING ONLY: bands that turn more than rope_yarn_beta_fast times
+    # over rope_original_max_seq keep their frequency, those that turn
+    # fewer than rope_yarn_beta_slow times divide it by the factor, a
+    # linear ramp between; cos and sin times rope_attention_factor).
     rope_scaling_type: str = "none"
     rope_scaling_factor: float = 1.0
     rope_low_freq_factor: float = 1.0
     rope_high_freq_factor: float = 4.0
     rope_original_max_seq: int = 8192
+    rope_yarn_beta_fast: float = 32.0
+    rope_yarn_beta_slow: float = 1.0
+    rope_attention_factor: float = 1.0
+    # SERVING ONLY. The scaling above is the FULL-attention layers'
+    # alone: a layer with a window (window_for_layer > 0) rotates by the
+    # plain rope_theta table (Mellum-class rope_parameters by layer type)
+    rope_scaling_full_only: bool = False
     # Explicit head dim for families where head_dim != d_model / n_heads
     # (Mistral-Nemo / Gemma-class); None derives it.
     head_dim_override: Optional[int] = None
@@ -333,10 +345,11 @@ class TransformerConfig:
                     f"{self.n_experts} experts")
         if self.n_dense_layers and self.dense_d_ff is None:
             raise ValueError("n_dense_layers needs dense_d_ff")
-        if self.rope_scaling_type not in ("none", "linear", "llama3"):
+        if self.rope_scaling_type not in ("none", "linear", "llama3",
+                                          "yarn"):
             raise ValueError(
                 f"unsupported rope_scaling_type '{self.rope_scaling_type}' "
-                "(supported: none|linear|llama3)"
+                "(supported: none|linear|llama3|yarn)"
             )
         if self.pipeline_virtual_stages > 1 and self.pipeline_stages <= 1:
             raise ValueError(
@@ -383,10 +396,10 @@ class TransformerConfig:
                 raise ValueError(
                     f"bad attention_window_pattern {p} (non-empty, "
                     "entries >= 0; 0 = global)")
-            if self.n_layers % len(p):
+            if self.depth % len(p):
                 raise ValueError(
                     f"attention_window_pattern length {len(p)} must "
-                    f"divide n_layers {self.n_layers}")
+                    f"divide n_layers {self.depth}")
             if self.pipeline_stages > 1 or self.random_ltd_layer_range:
                 raise NotImplementedError(
                     "attention_window_pattern with pipeline/random-LTD "
@@ -470,6 +483,34 @@ class TransformerConfig:
         return self.sliding_window
 
     @property
+    def mixed_windows(self) -> bool:
+        """Whether the attention layers see windows of more than one
+        length (windowed and full layers mixed): serving then holds the
+        windowed layers' K/V in a bounded ring a sequence
+        (inference/model.py ring_blocks), the full layers' in pages."""
+        return len({self.window_for_layer(li) for li in range(self.depth)
+                    if self.layer_kind(li) == "attention"}) > 1
+
+    @property
+    def ring_layers(self) -> Tuple[bool, ...]:
+        """For each layer that holds K/V, in the cache's order: whether
+        serving holds it in rings (a windowed layer of a model of mixed
+        windows) and not in pages."""
+        return tuple(self.mixed_windows and self.window_for_layer(li) > 0
+                     for li in range(self.depth)
+                     if self.layer_kind(li) == "attention")
+
+    @property
+    def widest_window(self) -> int:
+        return max(self.window_for_layer(li) for li in range(self.depth))
+
+    def rope_scaled_at(self, li: int) -> bool:
+        """Whether layer li rotates by the SCALED table (rope_inv_freq):
+        every layer, or the full-attention layers alone."""
+        return not (self.rope_scaling_full_only
+                    and self.window_for_layer(li) > 0)
+
+    @property
     def is_latent(self) -> bool:
         return self.kv_lora_rank > 0
 
@@ -487,13 +528,16 @@ class TransformerConfig:
                                  "experts_held", "layer_types",
                                  "moe_expert_bias", "attn_output_gate",
                                  "shared_expert_gate", "position_embedding",
-                                 "attention_multiplier")
+                                 "attention_multiplier",
+                                 "rope_scaling_full_only")
                      if getattr(self, k)) + tuple(
             k for k, plain in (("moe_scoring", "softmax"),
                                ("embedding_multiplier", 1.0),
                                ("residual_multiplier", 1.0),
                                ("logits_scaling", 1.0))
-            if getattr(self, k) != plain)
+            if getattr(self, k) != plain) + (
+            ("rope_scaling_type",) if self.rope_scaling_type == "yarn"
+            else ())
 
     def layer_kind(self, li: int) -> str:
         """The operator of layer li of the `depth`: one of LAYER_KINDS."""
@@ -1041,16 +1085,42 @@ def rope_dim(cfg: TransformerConfig) -> int:
     return R - (R % 2)
 
 
-def rope_inv_freq(cfg: TransformerConfig) -> jnp.ndarray:
+def yarn_band_range(cfg: TransformerConfig) -> Tuple[int, int]:
+    """(low, high) band indices of YaRN's ramp (rope_inv_freq)."""
+    D, L = rope_dim(cfg), cfg.rope_original_max_seq
+
+    def band(turns):
+        return (D * math.log(L / (2 * math.pi * turns))
+                / (2 * math.log(cfg.rope_theta)))
+
+    return (max(math.floor(band(cfg.rope_yarn_beta_fast)), 0),
+            min(math.ceil(band(cfg.rope_yarn_beta_slow)), D // 2 - 1))
+
+
+def rope_inv_freq(cfg: TransformerConfig, scaled: bool = True) -> jnp.ndarray:
     """Per-band rotary frequencies [rope_dim/2], with long-context
-    scaling.
+    scaling (scaled False: the plain rope_theta table, a windowed
+    layer's where cfg.rope_scaling_full_only).
 
     "linear" divides every frequency by the factor (position
     interpolation); "llama3" is the Llama-3.x NTK-by-parts rule — long
     wavelengths compress by the factor, short ones keep full resolution,
-    the middle band interpolates (HF rope_scaling 'llama3' semantics)."""
+    the middle band interpolates (HF rope_scaling 'llama3' semantics);
+    "yarn" is NTK-by-parts over band INDEX: c(n) = D ln(L / (2 pi n)) /
+    (2 ln theta) is the band that turns n times over the original
+    length L, bands below low = floor(c(beta_fast)) keep their
+    frequency, bands above high = ceil(c(beta_slow)) divide it by the
+    factor, a linear ramp between (HF 'yarn' with truncate; static in
+    the served length)."""
     D = rope_dim(cfg)
     inv = cfg.rope_theta ** (-jnp.arange(0, D // 2, dtype=jnp.float32) / (D // 2))
+    if not scaled:
+        return inv
+    if cfg.rope_scaling_type == "yarn":
+        low, high = yarn_band_range(cfg)
+        ramp = jnp.clip((jnp.arange(D // 2, dtype=jnp.float32) - low)
+                        / max(high - low, 1e-3), 0.0, 1.0)
+        return inv / cfg.rope_scaling_factor * ramp + inv * (1.0 - ramp)
     if cfg.rope_scaling_type == "linear":
         return inv / cfg.rope_scaling_factor
     if cfg.rope_scaling_type == "llama3":

@@ -39,6 +39,7 @@ supported as a fallback (torch.load per shard).
 """
 
 import json
+import math
 import os
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
@@ -147,7 +148,8 @@ _ARCH_OF_MODEL_TYPE = {"olmoe": "OlmoeForCausalLM",
                        "pangu_ultra_moe": "PanguUltraMoEForCausalLM",
                        "lfm2_moe": "Lfm2MoeForCausalLM",
                        "qwen3_next": "Qwen3NextForCausalLM",
-                       "granitemoehybrid": "GraniteMoeHybridForCausalLM"}
+                       "granitemoehybrid": "GraniteMoeHybridForCausalLM",
+                       "mellum": "MellumForCausalLM"}
 # config.json keys that change what a BLOCK computes (latent attention,
 # shared experts, leading dense layers, a second norm, a scaled, grouped
 # or biased router, layers of another kind than attention): an
@@ -172,18 +174,30 @@ _STATE_SPACE_KEYS = (
     "mamba_proj_bias", "shared_intermediate_size", "embedding_multiplier",
     "residual_multiplier", "logits_scaling", "attention_multiplier",
     "position_embedding_type")
-_BLOCK_KEYS = (_LATENT_MOE_KEYS + _HYBRID_KEYS + _LINEAR_ATTENTION_KEYS
-               + _STATE_SPACE_KEYS)
+# attention of two windows with a rotary table by layer type, dense and
+# routed MLPs named layer by layer, and what a block of that class may
+# carry that no mapping here reads yet
+_MIXED_WINDOW_KEYS = (
+    "rope_parameters", "mlp_layer_types", "use_qk_norm", "qk_norm",
+    "attn_logit_softcapping", "final_logit_softcapping", "attention_sinks",
+    "shared_expert_intermediate_size", "num_shared_experts")
+_BLOCK_KEYS = tuple(dict.fromkeys(
+    _LATENT_MOE_KEYS + _HYBRID_KEYS + _LINEAR_ATTENTION_KEYS
+    + _STATE_SPACE_KEYS + _MIXED_WINDOW_KEYS))
 # the block keys each architecture's mapping reads; any other stays an
 # error for it too
 _READS_BLOCK_KEYS = {
     "PanguUltraMoEForCausalLM": frozenset(_LATENT_MOE_KEYS),
     "Lfm2MoeForCausalLM": frozenset(_HYBRID_KEYS + (
-        "moe_intermediate_size", "routed_scaling_factor")),
+        "moe_intermediate_size", "routed_scaling_factor",
+        "rope_parameters")),
     "Qwen3NextForCausalLM": frozenset(_LINEAR_ATTENTION_KEYS + (
         "layer_types", "moe_intermediate_size")),
     "GraniteMoeHybridForCausalLM": frozenset(_STATE_SPACE_KEYS + (
         "layer_types",)),
+    "MellumForCausalLM": frozenset((
+        "layer_types", "rope_parameters", "mlp_layer_types",
+        "moe_intermediate_size")),
 }
 
 
@@ -200,6 +214,7 @@ def _block_key_set(hf: Dict[str, Any], key: str) -> bool:
 SUPPORTED_ARCHITECTURES = sorted(_LLAMA_FAMILY | {
     "PanguUltraMoEForCausalLM", "Lfm2MoeForCausalLM",
     "Qwen3NextForCausalLM", "GraniteMoeHybridForCausalLM",
+    "MellumForCausalLM",
     "GPT2LMHeadModel", "OPTForCausalLM", "FalconForCausalLM",
     "RWForCausalLM",  # falcon's pre-rename arch string
     "PhiForCausalLM", "QWenLMHeadModel",
@@ -234,6 +249,8 @@ def config_from_hf(hf: Dict[str, Any], **overrides) -> TransformerConfig:
         kw = _qwen3_next_config(hf)
     elif arch == "GraniteMoeHybridForCausalLM":
         kw = _granite_moe_hybrid_config(hf)
+    elif arch == "MellumForCausalLM":
+        kw = _mellum_config(hf)
     elif arch in _LLAMA_FAMILY:
         kw = dict(
             vocab_size=hf["vocab_size"],
@@ -587,6 +604,102 @@ def _pangu_ultra_moe_config(hf: Dict[str, Any]) -> Dict[str, Any]:
     )
 
 
+def _mellum_config(hf: Dict[str, Any]) -> Dict[str, Any]:
+    """Mellum 2 (`mellum`): GQA without bias or QK-norm whose layers are
+    `sliding_attention` (the last `sliding_window` tokens) or
+    `full_attention` as `layer_types` names them, each type with its own
+    rotary table under `rope_parameters` (YaRN with an
+    `attention_factor` on the full layers, the plain table on the
+    windowed ones); the MLP of layer i is `sparse` (`num_experts`
+    experts of `moe_intermediate_size`, softmax scores, top-k, weights
+    over their sum where `norm_topk_prob`) or `dense` (a SwiGLU of
+    `intermediate_size`) as `mlp_layer_types` names it, dense layers
+    leading. `max_window_layers` / `use_sliding_window` decide nothing
+    where `layer_types` is explicit."""
+    L = hf["num_hidden_layers"]
+    types = hf.get("layer_types") or ["full_attention"] * L
+    mlps = hf.get("mlp_layer_types") or ["sparse"] * L
+    unknown = sorted((set(types) - {"sliding_attention", "full_attention"})
+                     | (set(mlps) - {"sparse", "dense"}))
+    if unknown or len(types) != L or len(mlps) != L:
+        raise ValueError(
+            "mellum layer_types names sliding_attention / full_attention "
+            "and mlp_layer_types sparse / dense for each of "
+            f"num_hidden_layers={L} layers (got {len(types)} and "
+            f"{len(mlps)} entries, unknown {unknown})")
+    n_dense = mlps.index("sparse") if "sparse" in mlps else L
+    if "dense" in mlps[n_dense:] or n_dense == L:
+        raise ValueError(
+            "mellum mlp_layer_types with a dense layer after a sparse one, "
+            f"or no sparse layer, is unsupported (got {mlps})")
+    if hf.get("attention_bias") or hf.get("hidden_act", "silu") != "silu":
+        raise ValueError(
+            "mellum with attention_bias or another hidden_act than silu is "
+            "unsupported")
+    window = int(hf.get("sliding_window") or 0)
+    if "sliding_attention" in types and window <= 0:
+        raise ValueError("mellum sliding_attention layers need sliding_window")
+    ropes = hf.get("rope_parameters") or {}
+    by_type = {t: ropes.get(t) or {} for t in set(types)}
+    if set(ropes) - set(types) - {"sliding_attention", "full_attention"}:
+        raise ValueError(
+            "mellum rope_parameters is keyed by layer type (got "
+            f"{sorted(ropes)})")
+    thetas = {float(r.get("rope_theta", hf.get("rope_theta", 10000.0)))
+              for r in by_type.values()}
+    sliding = by_type.get("sliding_attention", {})
+    if len(thetas) != 1 or sliding.get("rope_type", "default") != "default":
+        raise ValueError(
+            "mellum with a rope_theta by layer type, or a scaled table on "
+            f"the sliding layers, is unsupported (got {ropes})")
+    kw = dict(
+        vocab_size=hf["vocab_size"],
+        n_layers=L - n_dense,
+        n_heads=hf["num_attention_heads"],
+        n_kv_heads=hf.get("num_key_value_heads") or None,
+        d_model=hf["hidden_size"],
+        d_ff=hf["moe_intermediate_size"],
+        head_dim_override=int(hf["head_dim"]) if hf.get("head_dim") else None,
+        max_seq=hf.get("max_position_embeddings", 4096),
+        variant="llama",
+        rope_theta=thetas.pop(),
+        norm_eps=float(hf.get("rms_norm_eps", 1e-6)),
+        tie_embeddings=bool(hf.get("tie_word_embeddings", False)),
+        n_experts=hf["num_experts"], moe_top_k=hf["num_experts_per_tok"],
+        moe_norm_topk_prob=bool(hf.get("norm_topk_prob", False)),
+        moe_dropless=True,
+    )
+    if n_dense:
+        kw.update(n_dense_layers=n_dense, dense_d_ff=hf["intermediate_size"])
+    if len(set(types)) > 1:
+        kw["attention_window_pattern"] = tuple(
+            window if t == "sliding_attention" else 0 for t in types)
+    elif types[0] == "sliding_attention":
+        kw["sliding_window"] = window
+    full = by_type.get("full_attention", {})
+    rtype = full.get("rope_type", "default")
+    if rtype == "yarn":
+        if not full.get("truncate", True):
+            raise ValueError("mellum YaRN without truncate is unsupported")
+        kw.update(
+            rope_scaling_type="yarn",
+            rope_scaling_factor=float(full["factor"]),
+            rope_original_max_seq=int(
+                full["original_max_position_embeddings"]),
+            rope_yarn_beta_fast=float(full.get("beta_fast", 32)),
+            rope_yarn_beta_slow=float(full.get("beta_slow", 1)),
+            # the published default where the file gives none
+            rope_attention_factor=float(
+                full.get("attention_factor")
+                or 0.1 * math.log(float(full["factor"])) + 1.0),
+            rope_scaling_full_only="sliding_attention" in types)
+    elif rtype != "default":
+        raise ValueError(
+            f"mellum full_attention rope_type {rtype!r} is unsupported "
+            "(supported: default, yarn); refusing a silently-wrong import")
+    return kw
+
+
 def _lfm2_moe_config(hf: Dict[str, Any]) -> Dict[str, Any]:
     """LFM2-MoE (`lfm2_moe`): `layer_types` names each layer's operator,
     `conv` (the gated short convolution of `conv_L_cache` taps, no
@@ -610,6 +723,10 @@ def _lfm2_moe_config(hf: Dict[str, Any]) -> Dict[str, Any]:
             f"{len(types)} entries, unknown {unknown})")
     n_dense = int(hf.get("num_dense_layers", 0))
     rope = hf.get("rope_parameters") or {}
+    if rope.get("rope_type", "default") != "default" or any(
+            isinstance(v, dict) for v in rope.values()):
+        raise ValueError(
+            f"lfm2_moe reads rope_parameters' rope_theta alone (got {rope})")
     return dict(
         vocab_size=hf["vocab_size"],
         n_layers=hf["num_hidden_layers"] - n_dense,

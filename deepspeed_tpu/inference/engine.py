@@ -41,10 +41,11 @@ from ..resilience.integrity import (
     payload_digest,
 )
 from ..models import transformer as T
-from ..ops.pallas import kernels_runnable
+from ..ops.pallas import kernel_traces, kernels_runnable
 from ..ops.pallas.expert_stream import grouped_rows
 from ..ops.pallas.paged_attention import latent_lanes, latent_walk_fits
 from ..utils import profiler
+from ..utils.frames import on_one_chunk
 from ..utils.logging import log_dist
 from ..utils.sync import host_sync, serving_readback
 from . import model as M
@@ -1753,7 +1754,9 @@ class InferenceEngine:
 
         Every program is AWAITED here (so its execution is charged to
         it, not to whatever runs next) and leaves an always-kept span
-        `warmup.program` (ids kind, width, unique) with children
+        `warmup.program` (ids kind, width, unique, and kernel_traces:
+        the kernel bodies it traced, one a distinct signature whatever
+        the layers, ops/pallas kernel_jit) with children
         `warmup.trace` / `warmup.lower` / `warmup.compile` (the stages
         jax.monitoring reports) and `warmup.execute` (docs/tracing.md).
 
@@ -1784,7 +1787,12 @@ class InferenceEngine:
             with profiler.span("warmup.program", always=True, kind=kind,
                                width=w, **ids) as sp, \
                     profiler.compile_spans("warmup") as stages:
-                out = call()
+                before = kernel_traces()
+                # tracing and lowering are millions of Python calls: on
+                # one chunk of the frame stack (utils/frames.py)
+                out = on_one_chunk(call)
+                traced = kernel_traces() - before
+                sp.set(kernel_traces=traced)
                 t_run = _time.perf_counter_ns()
                 host_sync(out)
                 t_done = _time.perf_counter_ns()
@@ -1796,6 +1804,7 @@ class InferenceEngine:
                 for st in stage_names] + [("execute", [(t_run, t_done)])])
             per_program.append(dict(
                 {"kind": kind, "width": w, **ids,
+                 "kernel_traces": traced,
                  "seconds": (sp.t1_ns - sp.t0_ns) * 1e-9},
                 **{f"{k}_s": v * 1e-9 for k, v in split.items()}))
             return out

@@ -1359,10 +1359,15 @@ def _attention_delta(h, lp, cfg: TransformerConfig, rng=None, positions=None,
     return _dropout(out, cfg.dropout, rng)
 
 
-def _act_fn(cfg: TransformerConfig):
-    return {"silu": jax.nn.silu, "gelu": jax.nn.gelu,
+# one object a name: a kernel's jit boundary takes the activation as a
+# static argument, and a fresh partial a call would be a new signature
+_ACT_FNS = {"silu": jax.nn.silu, "gelu": jax.nn.gelu,
             "gelu_exact": partial(jax.nn.gelu, approximate=False),
-            "relu": jax.nn.relu}[cfg.act_name]
+            "relu": jax.nn.relu}
+
+
+def _act_fn(cfg: TransformerConfig):
+    return _ACT_FNS[cfg.act_name]
 
 
 def _mlp_delta(h, lp, cfg: TransformerConfig, rng=None):

@@ -35,7 +35,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import interpret
+from . import interpret, kernel_jit
 
 F32 = jnp.float32
 LANES = 128
@@ -167,11 +167,8 @@ def conv_carry(u, taps, pool, slots, positions):
     return _conv_carry(u, taps, pool, slots, positions, rows, interpret())
 
 
-# jitted on its own: a step program calls it once a state layer with
-# the same shapes, and traces and lowers the kernel once for all of
-# them (what nine or ten kernels would add to a warm-up's trace and
-# lower stages, which no compile cache saves)
-@functools.partial(jax.jit, static_argnums=(5, 6))
+# nine or ten state layers call it with the same shapes
+@kernel_jit(5, 6)
 def _conv_carry(u, taps, pool, slots, positions, rows: int, interpreted: bool):
     S, E = u.shape
     _, k1, C, L = pool.shape

@@ -39,7 +39,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import interpret
+from . import interpret, kernel_jit
 
 # scoped VMEM the pass may ask Mosaic for, what its buffers may take of
 # that, and what the double-buffered weight tiles may take of those:
@@ -145,6 +145,9 @@ def _grouped_tile(R: int, E: int, F: int):
                           _GROUPED_VMEM_BUDGET)
 
 
+# jitted beside the grouped pass: the routed layers' sorts are one
+# lowered function, as their kernels are (no kernel: not counted)
+@functools.partial(jax.jit, static_argnums=(1,))
 def group_rows(idx, n_experts: int):
     """Where the grouped pass's buffer holds each (token, expert) pair
     of idx [T, k]: the pairs in the order a stable sort by expert gives
@@ -163,7 +166,8 @@ def group_rows(idx, n_experts: int):
     T, k = idx.shape
     A, C = T * k, 128
     flat = jnp.pad(idx.reshape(-1), (0, -A % C), constant_values=n_experts)
-    onehot = (flat[:, None] == jnp.arange(n_experts)).astype(jnp.bfloat16)
+    experts = jnp.arange(n_experts, dtype=flat.dtype)
+    onehot = (flat[:, None] == experts).astype(jnp.bfloat16)
     chunks = onehot.reshape(-1, C, n_experts)
     lower = jnp.tril(jnp.ones((C, C), jnp.bfloat16), -1)
     within = jnp.einsum("ij,cjx->cix", lower, chunks,
@@ -214,10 +218,16 @@ def expert_stream_mlp(h, w_gate, w_in, w_out, wcols, act=jax.nn.silu):
     type, float32 inside every dot and across experts. Every expert is
     streamed and multiplied whatever the columns hold. The caller asks
     stream_f_tile first."""
+    tf = stream_f_tile(h.shape[0], w_gate, w_in, w_out)
+    assert tf is not None, (h.shape, w_gate.shape, w_gate.dtype)
+    return _stream_mlp(h, w_gate, w_in, w_out, wcols, act, tf, interpret())
+
+
+@kernel_jit(5, 6, 7)
+def _stream_mlp(h, w_gate, w_in, w_out, wcols, act, tf: int,
+                interpreted: bool):
     T, E = h.shape
     X, _, F = w_gate.shape
-    tf = stream_f_tile(T, w_gate, w_in, w_out)
-    assert tf is not None, (h.shape, w_gate.shape, w_gate.dtype)
     Tp = _pad_rows(T)
     hp = jnp.pad(h.astype(w_gate.dtype), ((0, Tp - T), (0, 0)))
     cols = jnp.pad(wcols.astype(jnp.float32).T, ((0, Tp - T), (0, 0)))
@@ -238,7 +248,7 @@ def expert_stream_mlp(h, w_gate, w_in, w_out, wcols, act=jax.nn.silu):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=_STREAM_VMEM_LIMIT),
-        interpret=interpret(),
+        interpret=interpreted,
         name="expert_stream",
     )(cols, hp, w_gate, w_in, w_out)
     return out[:T]
@@ -286,9 +296,16 @@ def expert_grouped_mlp(xs, starts, counts, w_gate, w_in, w_out,
     first."""
     dims = _stack_dims(w_gate, w_in, w_out)
     assert dims is not None, (xs.shape, w_gate.shape, w_gate.dtype)
-    (R, E), (X, _, F) = xs.shape, dims
-    tf = _grouped_tile(R, E, F)
+    tf = _grouped_tile(xs.shape[0], *dims[1:])
     assert tf is not None, (xs.shape, w_gate.shape)
+    return _grouped_mlp(xs, starts, counts, w_gate, w_in, w_out, act, tf,
+                        interpret())
+
+
+@kernel_jit(6, 7, 8)
+def _grouped_mlp(xs, starts, counts, w_gate, w_in, w_out, act, tf: int,
+                 interpreted: bool):
+    (R, E), (X, _, F) = xs.shape, w_gate.shape
     whole = pl.BlockSpec(memory_space=pltpu.VMEM)
     return pl.pallas_call(
         functools.partial(_grouped_kernel, act=act, rows=_GROUP_ROW_TILE),
@@ -306,6 +323,6 @@ def expert_grouped_mlp(xs, starts, counts, w_gate, w_in, w_out,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=_GROUPED_VMEM_LIMIT),
-        interpret=interpret(),
+        interpret=interpreted,
         name="expert_stream_grouped",
     )(starts, counts, xs.astype(w_gate.dtype), w_gate, w_in, w_out)

@@ -46,7 +46,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import interpret
+from . import interpret, kernel_jit
 
 F32 = jnp.float32
 # the published chunk of the family's kernels
@@ -506,9 +506,7 @@ def gated_delta_step(q, k, v, g, beta, pool, slots, positions):
                              walk_shape(pool.shape), interpret())
 
 
-# jitted on its own, as conv_carry is: a step program's layers trace and
-# lower the kernel once
-@functools.partial(jax.jit, static_argnums=(8, 9))
+@kernel_jit(8, 9)
 def _gated_delta_step(q, k, v, g, beta, pool, slots, positions, shape,
                       interpreted: bool):
     f32 = lambda a: a.astype(F32)

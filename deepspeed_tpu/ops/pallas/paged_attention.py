@@ -64,7 +64,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import interpret
+from . import interpret, kernel_jit
 from .flash_attention import NEG_INF, _dot
 
 
@@ -496,14 +496,15 @@ def paged_decode_attention(q, k_cache, v_cache, block_table, ctx_lens,
         return out[:, :, :G, :].reshape(S, H, D)
     out, *pools = _attend_grid(qg, ab, k_cache, v_cache, block_table,
                                ctx_lens, window, scale, k_new, v_new, slots,
-                               k_scale, v_scale)
+                               k_scale, v_scale, interpret())
     out = out[:, :, :G, :].reshape(S, H, D)
     return (out, *pools) if fused else out
 
 
+@kernel_jit(6, 7, 13)
 def _attend_grid(qg, ab, k_cache, v_cache, block_table, ctx_lens,
                  window: int, scale: float, k_new, v_new, slots,
-                 k_scale, v_scale):
+                 k_scale, v_scale, interpreted: bool):
     """paged_decode_attention on the (S, NB) BlockSpec grid
     (_decode_kernel): qg [S, KV, Gp, D] grouped queries, ab the
     [KV, Gp] ALiBi table or None -> [out [S, KV, Gp, D], then the
@@ -601,7 +602,7 @@ def _attend_grid(qg, ab, k_cache, v_cache, block_table, ctx_lens,
         grid_spec=grid_spec,
         out_shape=out_shape,
         input_output_aliases=aliases,
-        interpret=interpret(),
+        interpret=interpreted,
         name="paged_decode_grid",
     )(block_table, ctx_lens, slots_arr, qg,
       *((k_new, v_new) if fused else ()), k_cache, v_cache,
@@ -1101,11 +1102,9 @@ def _walks_live_blocks(qg, k_cache) -> bool:
     return need <= _WALK_VMEM_BUDGET
 
 
-# jitted on its own so that a step program's calls, one a layer, trace
-# and lower ONCE (the grouped body doubles what a call takes to lower:
-# +1.9 s over the dense cell's 16 layers otherwise, AOT for v5e);
-# `interpreted` is interpret() at the caller's trace time
-@functools.partial(jax.jit, static_argnums=(6, 7, 8))
+# the grouped body doubles what a call takes to lower: +1.9 s over the
+# dense cell's 16 layers without the boundary, AOT for v5e
+@kernel_jit(6, 7, 8)
 def _attend_live_blocks(qg, ab, k_cache, v_cache, block_table, ctx_lens,
                         window: int, scale: float, interpreted: bool):
     """paged_decode_attention's unfused, unquantised case on the live-
@@ -1209,9 +1208,18 @@ def paged_decode_fused(q, k_cache, v_cache, block_table, ctx_lens,
 
     Requires head_dim % 128 == 0: the per-row (KV, D) write DMA must be
     lane-aligned (supports_fused_v2)."""
+    scale = 1.0 / (q.shape[-1]**0.5) if scale is None else scale
+    return _decode_fused(q, k_cache, v_cache, block_table, ctx_lens,
+                         k_new, v_new, slots, alibi_slopes, window, scale,
+                         interpret())
+
+
+@kernel_jit(9, 10, 11)
+def _decode_fused(q, k_cache, v_cache, block_table, ctx_lens, k_new, v_new,
+                  slots, alibi_slopes, window: int, scale: float,
+                  interpreted: bool):
     S, H, D = q.shape
     bs, KV = k_cache.shape[1:3]
-    scale = 1.0 / (D**0.5) if scale is None else scale
     alibi = alibi_slopes is not None
     qg, ab, G, Gp = _group_queries(q, KV, alibi_slopes)
     ab = (ab,) if alibi else ()
@@ -1252,7 +1260,7 @@ def paged_decode_fused(q, k_cache, v_cache, block_table, ctx_lens,
         input_output_aliases={6: 1, 7: 2},
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_WALK_VMEM_LIMIT),
-        interpret=interpret(),
+        interpret=interpreted,
         name="paged_decode_fused",
     )(block_table, ctx_lens, slots.astype(jnp.int32), qg,
       k_new, v_new, k_cache, v_cache, *ab)
@@ -1310,6 +1318,11 @@ def paged_kv_write(cache_k, cache_v, k_new, v_new, flat_slots):
     linear_blocked_kv_rotary/ — rotary is applied upstream in XLA).
     Rows of a packed pool (kv_pack) may come as [T, KV, D] of the model's
     heads: the same bytes as the pool's [T, KV / 2, 128]."""
+    return _kv_write(cache_k, cache_v, k_new, v_new, flat_slots, interpret())
+
+
+@kernel_jit(5)
+def _kv_write(cache_k, cache_v, k_new, v_new, flat_slots, interpreted: bool):
     NBLK, bs, KV, D = cache_k.shape
     T = flat_slots.shape[0]
     # a packed pool (kv_pack) takes its rows in its own shape
@@ -1348,7 +1361,7 @@ def paged_kv_write(cache_k, cache_v, k_new, v_new, flat_slots):
         ],
         # alias caches through: in-place RMW, no copy of the arena
         input_output_aliases={3: 0, 4: 1},
-        interpret=interpret(),
+        interpret=interpreted,
         name="paged_kv_write",
     )(slots, kn, vn, cache_k, cache_v)
 
@@ -1358,7 +1371,8 @@ def paged_scale_write(k_scale, v_scale, ks_new, vs_new, flat_slots):
     pools at flat slot ids [T] — the scale half of a quantized
     paged_kv_write. Rides the SAME RMW kernel through a
     [NBLK, bs, 1, KV] view (the KV axis lands on the lane dim, so the
-    block tile stays lane-aligned and dtype-generic)."""
+    block tile stays lane-aligned and dtype-generic), and so behind the
+    same jit boundary: the layers' scale writes are one lowering."""
     NBLK, bs, KV = k_scale.shape
     ck, cv = paged_kv_write(
         k_scale.reshape(NBLK, bs, 1, KV), v_scale.reshape(NBLK, bs, 1, KV),
@@ -1544,6 +1558,13 @@ def paged_latent_attention(q, pool, block_table, ctx_lens, v_dim: int):
     ctx_lens: [S] int32, the row included; 0 = batch padding (zeros out)
     returns [S, H, v_dim]: per head, sum of p * latent (the caller
     applies W_uv)."""
+    return _latent_attention(q, pool, block_table, ctx_lens, v_dim,
+                             interpret())
+
+
+@kernel_jit(4, 5)
+def _latent_attention(q, pool, block_table, ctx_lens, v_dim: int,
+                      interpreted: bool):
     S, H, C = q.shape
     NB = block_table.shape[1]
     bs = pool.shape[1]
@@ -1566,7 +1587,7 @@ def paged_latent_attention(q, pool, block_table, ctx_lens, v_dim: int):
         out_shape=jax.ShapeDtypeStruct((S, H, v_dim), q.dtype),
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_WALK_VMEM_LIMIT),
-        interpret=interpret(),
+        interpret=interpreted,
         name="paged_decode_grid",
     )(block_table, ctx_lens, table_groups(block_table), q, pool)
 
@@ -1613,6 +1634,11 @@ def paged_latent_write(pool, new, flat_slots):
     """Write [T, C] new latent rows into the [NBLK, bs, C] pool at flat
     slot ids [T] (block*bs + offset; -1 rows are dropped): paged_kv_write
     for a cache that is one pool."""
+    return _latent_write(pool, new, flat_slots, interpret())
+
+
+@kernel_jit(3)
+def _latent_write(pool, new, flat_slots, interpreted: bool):
     NBLK, bs, C = pool.shape
     T = flat_slots.shape[0]
     order = jnp.argsort(flat_slots)
@@ -1634,7 +1660,7 @@ def paged_latent_write(pool, new, flat_slots):
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(pool.shape, pool.dtype),
         input_output_aliases={2: 0},
-        interpret=interpret(),
+        interpret=interpreted,
         name="paged_latent_write",
     )(slots, new[order][:, None, :], pool)
 

@@ -63,7 +63,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from . import interpret
+from . import interpret, kernel_jit
 from .gated_delta import (
     F32,
     run_flags,
@@ -200,9 +200,7 @@ def ssm_step(x, dt, A, Bm, Cm, pool, slots, positions):
                      walk_shape(pool.shape), interpret())
 
 
-# jitted on its own, as conv_carry is: a step program's layers trace and
-# lower the kernel once
-@functools.partial(jax.jit, static_argnums=(8, 9))
+@kernel_jit(8, 9)
 def _ssm_step(x, dt, A, Bm, Cm, pool, slots, positions, shape,
               interpreted: bool):
     S_rows, H, P = x.shape

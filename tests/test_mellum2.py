@@ -23,6 +23,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from _step_program import step_program, tiny as _tiny
 from benchmarks.reference import mellum2 as ref
 from deepspeed_tpu.inference import (
     ServingScheduler,
@@ -497,11 +498,6 @@ def test_what_the_mapping_cannot_serve_is_an_error(what, hf, match):
         config_from_hf(hf)
 
 
-def _tiny(name):
-    return json.loads(
-        (BENCH / "tests/data/configs" / f"{name}.json").read_text())
-
-
 OTHERS = ["tiny-mistral", "tiny-olmoe", "tiny-pangu", "tiny-lfm2",
           "tiny-qwen3next", "tiny-granite4h"]
 
@@ -516,45 +512,18 @@ def test_the_keys_stay_an_error_for_every_other_architecture(name, key):
 
 # -- the older families' programs -------------------------------------------
 
-def step_program_text(name, kernels):
-    """The StableHLO of a tiny configuration's 8-row decode step,
-    lowered for the TPU with the Pallas kernels (their Mosaic modules
-    are in the text) or as the XLA oracle, without source locations."""
-    hf = _tiny(name)
-    cfg = config_from_hf(hf, max_seq=512)
-    params = jax.eval_shape(lambda: M.prepare(jax.tree.map(
-        lambda a: a.astype(jnp.bfloat16),
-        T.init(cfg, jax.random.PRNGKey(0))), cfg))
-    cache = jax.eval_shape(lambda: M.init_cache(
-        cfg, 17, 128, jnp.bfloat16, state_slots=8))
-    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)
-
-    def step(p, c, tok, tab, ctx, *slots):
-        return M.decode_step(p, c, tok, tab, ctx, cfg, use_kernel=kernels,
-                             slots=slots[0] if slots else None)
-
-    limit = jax.config.jax_traceback_in_locations_limit
-    jax.config.update("jax_traceback_in_locations_limit", 0)
-    try:
-        traced = jax.jit(step, donate_argnums=(1,)).trace(
-            params, cache, i32(8), i32(8, 4), i32(8),
-            *((i32(8),) if cache.state else ()))
-        return traced.lower(
-            lowering_platforms=("tpu",) if kernels else None).as_text()
-    finally:
-        jax.config.update("jax_traceback_in_locations_limit", limit)
-
-
 @pytest.mark.parametrize("kernels", [True, False], ids=["pallas", "xla"])
 @pytest.mark.parametrize("name", OTHERS)
 def test_an_older_familys_step_program_is_the_parents(name, kernels):
     """The six families the benchmark held before this one take the path
     they took: their step programs' text hashes as it did at the parent
-    commit (a921fbd: the file holds what `step_program_text` gave with
-    that tree on the path). A PR that changes a family's program ON
-    PURPOSE re-captures the file (`python tests/test_mellum2.py`) and
-    says so; JAX's version changes it too."""
-    text = step_program_text(name, kernels)
+    commit (a921fbd: the file holds what `_step_program.step_program`
+    gave with that tree on the path; the six `pallas` entries are PR
+    49's, whose kernels went behind a jit of their own, the `xla` ones
+    came out as they were). A PR that changes a family's program ON
+    PURPOSE re-captures the file (`PYTHONPATH=. python
+    tests/test_mellum2.py`) and says so; JAX's version changes it too."""
+    text = step_program(name, kernels)[1]
     pinned = json.loads(HASHES.read_text())
     assert hashlib.sha256(text.encode()).hexdigest()[:16] == \
         pinned[f"{name}/{'pallas' if kernels else 'xla'}"]
@@ -563,5 +532,5 @@ def test_an_older_familys_step_program_is_the_parents(name, kernels):
 if __name__ == "__main__":
     HASHES.write_text(json.dumps({
         f"{name}/{'pallas' if k else 'xla'}": hashlib.sha256(
-            step_program_text(name, k).encode()).hexdigest()[:16]
+            step_program(name, k)[1].encode()).hexdigest()[:16]
         for name in OTHERS for k in (True, False)}, indent=1) + "\n")

@@ -874,9 +874,45 @@ def walk_reads(block_table, ctx_lens, block_size: int, queries_per_kv: int):
     return int(reads.sum()), int(np.sum((ctx_lens > 0) & (lead != rows)))
 
 
+def _wholly_live(j, shortest, longest, block_size: int, window: int):
+    """Whether table slot j holds a live column in EVERY place for every
+    row of a group whose contexts run from `shortest` to `longest`: the
+    slot ends inside the shortest context and, under a window, starts
+    inside the longest row's window. A group's visit masks the other
+    slots of its span and no others (scalars in the kernel, arrays in
+    walk_masks)."""
+    whole = (j + 1) * block_size <= shortest
+    if window > 0:
+        whole = whole & (j * block_size >= longest - window)
+    return whole
+
+
+def walk_masks(block_table, ctx_lens, block_size: int, queries_per_kv: int,
+               window: int = 0):
+    """(blocks masked, blocks visited) by the GROUPS of one shared-table
+    call over these host arrays, by the walk's own grouping and the
+    kernel's own predicate (_wholly_live): of a group's span only the
+    slots that can hold a dead column for some row take the visit with
+    its compares and select. Rows walked alone are in neither count."""
+    lead = walk_groups(block_table, _group_rows(max(queries_per_kv, 8),
+                                                len(ctx_lens)), np)
+    masked = visited = 0
+    for g in np.flatnonzero(np.bincount(lead) > 1):
+        ctx = ctx_lens[lead == g]
+        end = -(-ctx.max() // block_size)
+        first = 0
+        if window > 0 and (ctx > 0).any():
+            first = (np.maximum(ctx[ctx > 0] - window, 0) // block_size).min()
+        slots = np.arange(min(first, end), end)
+        visited += len(slots)
+        masked += int(np.sum(~_wholly_live(slots, ctx.min(), ctx.max(),
+                                           block_size, window)))
+    return masked, visited
+
+
 def _decode_rows_kernel(
     tbl_ref, ctx_ref, first_ref, end_ref, n_ref,    # scalar prefetch
-    q_ref, ctxv_ref, k_any, v_any,                  # inputs (caches in HBM)
+    q_ref, k_any, v_any,                            # inputs (caches in HBM)
     *rest,                                          # [ab, abv], out, scratch
     alibi: bool, group: int, n_seqs: int, **opts,
 ):
@@ -893,7 +929,7 @@ def _decode_rows_kernel(
     its own context (_group_softmax), then stores all n rows; 0, a row
     its group's first row has stored already: the step only keeps the
     next row's prefetch chain going."""
-    if alibi:  # [KV, Gp] slopes and their [KV, group * Gp, 1] tiling
+    if alibi:  # [KV, Gp] slopes and their [KV, 1, group * Gp] tiling
         ab_ref, abv_ref, o_ref, bufk, bufv, lsem, *acc = rest
     else:
         o_ref, bufk, bufv, lsem, *acc = rest
@@ -916,58 +952,129 @@ def _decode_rows_kernel(
 
     @pl.when(n > 1)
     def _group():
-        _group_softmax(walk, first, end, s, n, q_ref, ctxv_ref, abv_ref,
+        _group_softmax(walk, first, end, s, n, ctx_ref, q_ref, abv_ref,
                        o_ref, *acc, group=group, **opts)
 
 
-def _group_softmax(walk, first, end, s, n, q_ref, ctxv_ref, abv_ref, o_ref,
-                   m_sc, l_sc, acc_sc, *, group: int, block_size: int,
+def _fold8(x, op, identity: float):
+    """(n, w) -> (8, w): rows i, i + 8, ... combined by `op`, whole
+    sublane tiles against each other, so that nothing crosses sublanes
+    (n that is not whole tiles is filled up with `identity`)."""
+    n, w = x.shape
+    if n % 8:
+        x = jnp.concatenate([x, jnp.full((-n % 8, w), identity, x.dtype)])
+    return functools.reduce(op, [x[i:i + 8] for i in range(0, len(x), 8)])
+
+
+def _rows8(x, n: int):
+    """A sublane-replicated (8, w) value as (n, w): the same tile
+    again and again."""
+    return jnp.tile(x, (-(-n // 8), 1))[:n]
+
+
+# KV heads whose matmuls a group's visit issues back to back: a matmul
+# of one 128 x 128 operand costs ~0.15 us whatever its rows (chip, PR
+# 50), most of it latency that the next head's matmul hides when
+# nothing else stands between them; four heads' score tiles are what
+# the visit then keeps (128 KB each at 256 query rows)
+_HEADS_A_PHASE = 4
+
+
+def _group_softmax(walk, first, end, s, n, ctx_ref, q_ref, abv_ref, o_ref,
+                   q_sc, m_sc, l_sc, acc_sc, *, group: int, block_size: int,
                    scale: float, n_kv: int, gp: int, window: int):
     """Rows s .. s+n-1 (one table, n <= group) over table slots
     [first, end) of `walk`, each block multiplied by ALL their queries
     in one pair of matmuls a KV head: `group` rows x Gp query rows,
-    stacked by a reshape (Gp is whole sublane tiles), every query row
-    masked to its OWN context (ctxv_ref [S * Gp, 1]: a row's context
-    once a query row) and window. The tile of `group` rows is static
-    and starts at s or, near the end of the batch, before it: rows of
-    the tile outside s .. s+n-1 are computed and not stored. The
-    accumulators live in VMEM scratch m_sc / l_sc [KV, group * Gp, 1]
-    and acc_sc [KV, group * Gp, D] (f32; 128 KB a head at D 128: not a
-    loop carry)."""
+    stacked by a reshape (Gp is whole sublane tiles) ONCE a group into
+    q_sc [KV, group * Gp, D]. The tile of `group` rows is static and
+    starts at s or, near the end of the batch, before it: rows of the
+    tile outside s .. s+n-1 are computed and not stored.
+
+    The visit is TRANSPOSED: a head's scores are (bs, group * Gp), the
+    block's columns down the sublanes and the query rows along the
+    lanes (K as the streamed operand, the queries as the held one), so
+    a query row's max and sum run DOWN a lane: whole tiles against each
+    other (_fold8) and one 8-sublane reduction of the max; nothing
+    crosses lanes. The running max m_sc and sum l_sc are [KV, 8,
+    group * Gp] (the max in every sublane, the sum split over the 8 and
+    added up once, at the store), the accumulator acc_sc [KV, D,
+    group * Gp] is the output transposed (V^T p, transposed back once a
+    group); all f32 VMEM scratch, not a loop carry (128 KB a head at D
+    128).
+
+    A block is MASKED (every query row to its own context, read from
+    ctx_ref along the lanes, and window) only where it can hold a dead
+    column for some row of the group (_wholly_live): past the group's
+    shortest context or, under a window, before its longest row's
+    window start. Every other block of the span takes the same visit
+    without the compares and the select."""
     bs = block_size
     S, _, _, D = q_ref.shape
     rg = group * gp
     start = jnp.minimum(s, S - group)
     rows = pl.ds(start, group)
-    ctx_col = ctxv_ref[pl.ds(pl.multiple_of(start * gp, 8), rg), :]  # (rg, 1)
 
+    def shortest_longest(i, c):
+        return (jnp.minimum(c[0], ctx_ref[s + i]),
+                jnp.maximum(c[1], ctx_ref[s + i]))
+
+    shortest, longest = jax.lax.fori_loop(1, n, shortest_longest,
+                                          (ctx_ref[s], ctx_ref[s]))
+    # every query row's context, along the lanes
+    lane_row = jax.lax.broadcasted_iota(jnp.int32, (8, rg), 1) // gp
+    ctx_row = jax.lax.fori_loop(
+        0, group,
+        lambda i, c: jnp.where(lane_row == i, ctx_ref[start + i], c),
+        jnp.zeros((8, rg), jnp.int32))
+
+    for h in range(n_kv):
+        q_sc[h] = q_ref[rows, h].reshape(rg, D)
     m_sc[...] = jnp.full(m_sc.shape, NEG_INF, jnp.float32)
     l_sc[...] = jnp.zeros(l_sc.shape, jnp.float32)
     acc_sc[...] = jnp.zeros(acc_sc.shape, jnp.float32)
 
+    def update(j, kb, vb, masked: bool):
+        if masked or abv_ref is not None:
+            cols = j * bs + jax.lax.broadcasted_iota(jnp.int32, (bs, rg), 0)
+        if masked:
+            live = cols < _rows8(ctx_row, bs)
+            if window > 0:
+                live = jnp.logical_and(live,
+                                       cols >= _rows8(ctx_row - window, bs))
+        for h0 in range(0, n_kv, _HEADS_A_PHASE):
+            heads = range(h0, min(h0 + _HEADS_A_PHASE, n_kv))
+            sts = [_dot(kb[:, h, :], q_sc[h], trans_b=True) for h in heads]
+            ps = []
+            for h, st in zip(heads, sts):
+                st = st * scale  # (bs, rg)
+                if abv_ref is not None:
+                    st = st + abv_ref[h] * cols.astype(jnp.float32)
+                if masked:
+                    # a block with no live column for a row (past a
+                    # shorter context, before a later window) leaves
+                    # that row's sums as they were, or, before its first
+                    # live block, finite values its first live block's
+                    # correction (exp(-1e30 - m)) zeroes
+                    st = jnp.where(live, st, NEG_INF)
+                m_prev = m_sc[h]  # (8, rg)
+                m_new = jnp.maximum(m_prev, jnp.max(
+                    _fold8(st, jnp.maximum, NEG_INF), axis=0, keepdims=True))
+                p = jnp.exp(st - _rows8(m_new, bs))
+                corr = jnp.exp(m_prev - m_new)
+                l_sc[h] = l_sc[h] * corr + _fold8(p, jnp.add, 0.0)
+                acc_sc[h] = acc_sc[h] * _rows8(corr, D)
+                m_sc[h] = m_new
+                ps.append(p.astype(vb.dtype))
+            pvs = [_dot(vb[:, h, :], p, trans_a=True)  # (D, rg)
+                   for h, p in zip(heads, ps)]
+            for h, pv in zip(heads, pvs):
+                acc_sc[h] = acc_sc[h] + pv
+
     def visit(j, kb, vb, carry):
-        cols = j * bs + jax.lax.broadcasted_iota(jnp.int32, (rg, bs), 1)
-        live = cols < ctx_col
-        if window > 0:
-            live = jnp.logical_and(live, cols >= ctx_col - window)
-        for h in range(n_kv):
-            q = q_ref[rows, h].reshape(rg, D)
-            st = _dot(q, kb[:, h, :], trans_b=True) * scale  # (rg, bs)
-            if abv_ref is not None:
-                st = st + abv_ref[h] * cols.astype(jnp.float32)
-            # a block with no live column for a row (past a shorter
-            # context, before a later window) leaves that row's sums as
-            # they were, or, before its first live block, finite values
-            # its first live block's correction (exp(-1e30 - m)) zeroes
-            st = jnp.where(live, st, NEG_INF)
-            m_prev = m_sc[h]
-            m_new = jnp.maximum(m_prev, jnp.max(st, axis=1, keepdims=True))
-            p = jnp.exp(st - m_new)
-            corr = jnp.exp(m_prev - m_new)
-            l_sc[h] = l_sc[h] * corr + jnp.sum(p, axis=1, keepdims=True)
-            acc_sc[h] = acc_sc[h] * corr + _dot(p.astype(vb.dtype),
-                                                vb[:, h, :])
-            m_sc[h] = m_new
+        whole = _wholly_live(j, shortest, longest, bs, window)
+        pl.when(whole)(lambda: update(j, kb, vb, False))
+        pl.when(jnp.logical_not(whole))(lambda: update(j, kb, vb, True))
         return carry
 
     walk(first, end, visit, 0)
@@ -975,10 +1082,10 @@ def _group_softmax(walk, first, end, s, n, q_ref, ctxv_ref, abv_ref, o_ref,
     ridx = start + jax.lax.broadcasted_iota(jnp.int32, (group, 1, 1), 0)
     mine = jnp.logical_and(ridx >= s, ridx < s + n)
     for h in range(n_kv):
-        l = l_sc[h]
+        l = jnp.sum(l_sc[h], axis=0, keepdims=True)  # (1, rg)
         out = acc_sc[h] / jnp.where(l == 0.0, 1.0, l)
-        out = jnp.where(ctx_col > 0, out, 0.0)  # batch padding: zeros
-        out = out.reshape(group, gp, D).astype(o_ref.dtype)
+        out = jnp.where(ctx_row[:1] > 0, out, 0.0)  # batch padding: zeros
+        out = out.T.reshape(group, gp, D).astype(o_ref.dtype)
         o_ref[rows, h] = jnp.where(mine, out, o_ref[rows, h])
 
 
@@ -1096,9 +1203,9 @@ def _walks_live_blocks(qg, k_cache) -> bool:
     rg = _group_rows(Gp, S) * Gp
     need = (4 * _RING * bs * KV * D * itemsize      # k, v x row parity
             + 2 * qg.size * qg.dtype.itemsize       # whole-array q, out
-            # a group's accumulators and every row's context a query
-            # row, both f32 / int32 columns padded to whole lanes
-            + 4 * (KV * rg * (D + 256) + S * Gp * 128))
+            # a group's stacked queries, its f32 accumulator and its
+            # running max and sum, 8 sublanes each
+            + KV * rg * (D * (qg.dtype.itemsize + 4) + 2 * 8 * 4))
     return need <= _WALK_VMEM_BUDGET
 
 
@@ -1132,22 +1239,23 @@ def _attend_live_blocks(qg, ab, k_cache, v_cache, block_table, ctx_lens,
             jnp.where(mine & (ctx_lens > 0)[None, :], first[None, :],
                       block_table.shape[1]), axis=1))
     alibi = () if ab is None else (
-        ab, jnp.tile(ab, (1, group))[:, :, None])
+        ab, jnp.tile(ab, (1, group))[:, None, :])
     vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
     hbm = pl.BlockSpec(memory_space=pl.ANY)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,
         grid=(S,),
-        in_specs=[vmem, vmem, hbm, hbm] + [vmem] * len(alibi),
+        in_specs=[vmem, hbm, hbm] + [vmem] * len(alibi),
         out_specs=vmem,
         scratch_shapes=[
             pltpu.VMEM((2, _RING, bs, KV, D), k_cache.dtype),
             pltpu.VMEM((2, _RING, bs, KV, D), v_cache.dtype),
             pltpu.SemaphoreType.DMA((2, _RING, 2)),
         ] + ([
-            pltpu.VMEM((KV, group * Gp, 1), jnp.float32),
-            pltpu.VMEM((KV, group * Gp, 1), jnp.float32),
-            pltpu.VMEM((KV, group * Gp, D), jnp.float32),
+            pltpu.VMEM((KV, group * Gp, D), qg.dtype),
+            pltpu.VMEM((KV, 8, group * Gp), jnp.float32),
+            pltpu.VMEM((KV, 8, group * Gp), jnp.float32),
+            pltpu.VMEM((KV, D, group * Gp), jnp.float32),
         ] if group > 1 else []),
     )
     return pl.pallas_call(
@@ -1166,8 +1274,7 @@ def _attend_live_blocks(qg, ab, k_cache, v_cache, block_table, ctx_lens,
         # tests find the program by it (the int8 and fused-write cases,
         # still on the (S, NB) grid, carry the same name)
         name="paged_decode_grid",
-    )(block_table, ctx_lens, first, end, n, qg,
-      jnp.repeat(ctx_lens, Gp)[:, None], k_cache, v_cache, *alibi)
+    )(block_table, ctx_lens, first, end, n, qg, k_cache, v_cache, *alibi)
 
 
 def supports_fused_v2(head_dim: int) -> bool:

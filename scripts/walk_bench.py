@@ -1,0 +1,177 @@
+"""Standalone chip timing of the shared-table K/V walk (paged_decode_grid,
+its entry included) at the serving cells' shapes: the table of PERF.md
+section 6, PR 50, kept so that the next change to the walk can re-run it.
+
+  chiprun -- python scripts/walk_bench.py                 # every shape
+  chiprun -- python scripts/walk_bench.py mellum_full dense --kinds mixed,groups
+  ... --tree .scratch/parent --tag parent   another checkout's kernel (the
+                                            parent's, unpacked with git archive)
+
+A call's rows by --kinds: `mixed` (decode rows beside chunks of 32 rows on
+one table, as the cell's steps are), `groups` (chunks alone), `decode` (rows
+alone), `empty` (every context 0: the entry and the grid steps, no visit).
+--chain calls run in ONE program, each waiting for the last, so the host's
+~200 us a dispatch is paid once and not a call; best of 5 x 40 programs. A
+(KV head, block) cost is (a kind's time - `empty`) / (its visits x KV). One
+JSON line a measurement, appended to chiprun_out/walk_bench.jsonl.
+"""
+import argparse
+import json
+import os
+import sys
+import time
+
+BLOCK = 128
+# rows of a step, query / KV heads, head dim, pool blocks, table slots,
+# window (mellum_win: a ring of 10 blocks named again and again)
+SHAPES = {
+    "mellum_full": dict(rows=256, H=32, KV=4, D=128, pool=3072, NB=80,
+                        window=0),
+    "mellum_win": dict(rows=256, H=32, KV=4, D=128, pool=960, NB=80,
+                       window=1024, ring=10),
+    "dense": dict(rows=128, H=32, KV=8, D=128, pool=704, NB=32, window=4096),
+    "olmoe": dict(rows=128, H=16, KV=16, D=128, pool=704, NB=32, window=0),
+    "lfm2": dict(rows=512, H=32, KV=8, D=64, pool=2048, NB=32, window=0),
+    "qwen3next": dict(rows=256, H=16, KV=2, D=256, pool=1024, NB=32,
+                      window=0),
+    "granite": dict(rows=128, H=32, KV=8, D=128, pool=1024, NB=32, window=0),
+}
+
+
+def mix(kind, c, rng):
+    """ctx [rows] and the runs [(first row, rows)] that share a table."""
+    import numpy as np
+
+    rows, cap = c["rows"], c["NB"] * BLOCK
+    long_ctx = cap > 4096
+    ctx = np.zeros(rows, np.int64)
+    if kind == "empty":
+        return ctx, []
+    top = cap * 5 // 8 if long_ctx else cap // 3
+    if kind == "decode":
+        ctx[:] = rng.integers(cap // 16, top, rows)
+        return ctx, []
+    if kind == "groups":
+        n_groups = rows // 32
+    else:
+        n_groups = 6 if long_ctx else max(1, rows // 96)
+    n_dec = rows - 32 * n_groups
+    if kind == "mixed" and long_ctx:
+        n_dec -= 2  # two pad rows at the end
+    ctx[:n_dec] = rng.integers(cap // 16, top, n_dec)
+    if long_ctx:  # chunks of prompts up to 8k
+        firsts = [256, 1024, 2048, 3072, 5000, 8000, 1500, 4000]
+    else:  # chat: the chunks of prompts of a few hundred tokens
+        firsts = [1, 33, 65, 97, 129, 193, 257, 385,
+                  1, 33, 65, 97, 129, 161, 225, 449]
+    runs = []
+    for g in range(n_groups):
+        f = n_dec + 32 * g
+        ctx[f:f + 32] = firsts[g % len(firsts)] + np.arange(32)
+        runs.append((f, 32))
+    return ctx, runs
+
+
+def visits(c, ctx, runs):
+    """(blocks rows alone visit, blocks the groups visit)."""
+    import numpy as np
+
+    w = c["window"]
+    first = np.maximum(ctx - w, 0) // BLOCK if w else np.zeros_like(ctx)
+    end = -(-ctx // BLOCK)
+    riding = np.zeros(len(ctx), bool)
+    grouped = 0
+    for f, n in runs:
+        riding[f:f + n] = True
+        grouped += end[f:f + n].max() - first[f:f + n].min()
+    return int((end - first)[~riding & (ctx > 0)].sum()), int(grouped)
+
+
+def main():
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("shapes", nargs="*", default=list(SHAPES))
+    ap.add_argument("--kinds", default="mixed,groups,decode,empty")
+    ap.add_argument("--tree", default=os.path.dirname(
+        os.path.dirname(os.path.abspath(__file__))))
+    ap.add_argument("--tag", default="tree")
+    ap.add_argument("--chain", type=int, default=8)
+    args = ap.parse_args()
+
+    sys.path.insert(0, os.path.abspath(args.tree))
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    import deepspeed_tpu.ops.pallas.paged_attention as PA
+
+    assert os.path.abspath(PA.__file__).startswith(
+        os.path.abspath(args.tree)), PA.__file__
+    os.makedirs("chiprun_out", exist_ok=True)
+    for name in args.shapes:
+        c = SHAPES[name]
+        rows, H, KV, D = c["rows"], c["H"], c["KV"], c["D"]
+        pack = PA.kv_pack(KV, D)
+        shape = (c["pool"] + 1, BLOCK, KV // pack, D * pack)
+        key = jax.random.PRNGKey(0)
+        kc = jax.random.normal(key, shape, jnp.bfloat16)
+        vc = jax.random.normal(jax.random.fold_in(key, 1), shape,
+                               jnp.bfloat16)
+        q = jax.random.normal(jax.random.fold_in(key, 2), (rows, H, D),
+                              jnp.bfloat16)
+        for kind in args.kinds.split(","):
+            rng = np.random.default_rng(0)
+            ctx, runs = mix(kind, c, rng)
+            if c.get("ring"):
+                base = rng.integers(0, c["pool"] // c["ring"], rows)
+                tbl = (base[:, None] * c["ring"]
+                       + np.arange(c["NB"])[None, :] % c["ring"])
+            else:
+                tbl = np.stack([rng.permutation(c["pool"])[:c["NB"]]
+                                for _ in range(rows)])
+            for f, n in runs:
+                tbl[f:f + n] = tbl[f]
+            tbl = jnp.asarray(tbl, jnp.int32)
+            ctxd = jnp.asarray(ctx, jnp.int32)
+
+            def chain(q, kc, vc, tbl, ctxd):
+                qq = q
+                for _ in range(args.chain):
+                    out = PA.paged_decode_attention(qq, kc, vc, tbl, ctxd,
+                                                    window=c["window"])
+                    qq = q + (out * 0).astype(q.dtype)
+                return out
+
+            fn = jax.jit(chain)
+            for _ in range(3):
+                out = fn(q, kc, vc, tbl, ctxd)
+            out.block_until_ready()
+            best = float("inf")
+            for _ in range(5):
+                t0 = time.perf_counter()
+                for _ in range(40):
+                    out = fn(q, kc, vc, tbl, ctxd)
+                out.block_until_ready()
+                best = min(best, (time.perf_counter() - t0) / 40 / args.chain)
+            # a few rows against the float32 gather oracle
+            pick = sorted({0, 1, 2, *[f + i for f, n in runs
+                                      for i in (0, n - 1)]})
+            pick = np.asarray([i for i in pick if ctx[i] > 0][:8] or [0])
+            with jax.default_matmul_precision("highest"):
+                ref = PA.paged_decode_attention_xla(
+                    q[pick].astype(jnp.float32), kc.astype(jnp.float32),
+                    vc.astype(jnp.float32), tbl[pick], ctxd[pick],
+                    window=c["window"])
+            err = float(jnp.max(jnp.abs(out[pick].astype(jnp.float32) - ref)))
+            alone, grouped = visits(c, ctx, runs)
+            line = dict(tag=args.tag, shape=name, kind=kind,
+                        us=round(best * 1e6, 1), decode_visits=alone,
+                        group_visits=grouped, kv=KV // pack,
+                        max_err=round(err, 4),
+                        device=jax.devices()[0].device_kind)
+            print(json.dumps(line), flush=True)
+            with open("chiprun_out/walk_bench.jsonl", "a") as f:
+                f.write(json.dumps(line) + "\n")
+
+
+if __name__ == "__main__":
+    main()

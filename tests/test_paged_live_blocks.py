@@ -131,6 +131,54 @@ CASES = {
         packed=True, dtype=jnp.bfloat16),
     "packed_d64_rows_alone": _case(
         H=8, KV=4, D=64, ctx=(0, 5, 33, 64), packed=True),
+    # what a group's visit decides (PR 50): a block is masked only where
+    # it can hold a dead column for some row of the group.
+    # contexts 70..78 all end in block 4: blocks 0-3 wholly live
+    "group_whole_blocks_but_the_last": _case(
+        ctx=(40, *_rising(70, 9), 64), chunk=(1, 9), NB=6),
+    # contexts 60..68 cross the edge at 64 inside the chunk: the rows
+    # end in different blocks, two masked blocks after three whole ones
+    "group_context_crosses_a_block_edge": _case(
+        ctx=(40, *_rising(60, 9), 64), chunk=(1, 9), NB=6),
+    # window 48 = three blocks of 16 over contexts 60..68: the window
+    # starts at 12..20, on a block's edge for the row of context 64 and
+    # inside a block for the rest; block 2 alone is wholly live
+    "group_window_start_on_and_off_a_block_edge": _case(
+        ctx=(*_rising(60, 9), 50), chunk=(0, 9), window=48, NB=6),
+    # whole blocks in the middle of a windowed span: window 64 over
+    # contexts 90..95, blocks 2-4 wholly inside every row's window
+    "group_window_whole_blocks_between_its_edges": _case(
+        ctx=(17, *_rising(90, 6)), chunk=(1, 6), window=64, NB=7),
+    # 5 rows at the end of a batch of 40: the static tile of 32 rows
+    # starts 27 rows BEFORE the group, over decode rows of other
+    # contexts that the unmasked visit computes and never stores
+    "group_of_5_after_35_decode_rows": _case(
+        ctx=(*[(7 * i) % 90 + 1 for i in range(35)], *_rising(70, 5)),
+        chunk=(35, 5), NB=6),
+    # a span of ONE block (a trip takes one): masked, at block 128
+    "group_span_of_one_block": _case(
+        bs=128, NB=2, ctx=(200, *_rising(5, 12), 1), chunk=(1, 12)),
+    # a span that starts whole and a row of context 0 inside the group
+    # (its table the chunk's): every block of that group is masked
+    "group_with_a_row_of_context_0": _case(
+        ctx=(33, 70, 71, 0, 73, 74, 64), chunk=(1, 5), NB=6),
+    "group_alibi_whole_blocks": _case(
+        H=8, KV=2, ctx=(2, *_rising(70, 9), 64), chunk=(1, 9), alibi=True,
+        NB=6),
+    "group_window_alibi": _case(
+        H=8, KV=2, ctx=(2, *_rising(60, 9), 64), chunk=(1, 9), alibi=True,
+        window=32, NB=6),
+    # Gp 16 at packed head dim 64: 4 KV heads as 2 rows of 128 lanes, 8
+    # query heads a KV head, groups of 16 rows (20 walk as 16 + 4)
+    "group_packed_d64_g16": _case(
+        H=32, KV=4, D=64, ctx=(33, *_rising(70, 20), 64), chunk=(1, 20),
+        packed=True, dtype=jnp.bfloat16, NB=6),
+    # a block that is not whole sublane tiles (4 tokens)
+    "group_block_of_4_tokens": _case(
+        bs=4, NB=8, ctx=(9, *_rising(10, 6), 30), chunk=(1, 6)),
+    "group_d256_whole_blocks": _case(
+        H=16, KV=2, D=256, ctx=(17, *_rising(70, 7), 1), chunk=(1, 7),
+        dtype=jnp.bfloat16, NB=6),
     # equal tables that are NOT adjacent do not group and stay correct
     "equal_tables_not_adjacent": _case(
         ctx=(20, 9, 21, 40, 22, *_rising(30, 3), 23),
@@ -253,6 +301,88 @@ def test_a_groups_blocks_are_fetched_once(rng, monkeypatch, name, reads):
     assert (lead != np.arange(len(ctx))).sum() >= len(grouped) - 2
 
 
+def _chunk_call(first_ctx, bs, NB, window=0, decode=(), ring=0):
+    """Host arrays of one shared-table call: decode rows, then a chunk
+    of 32 rows of contexts first_ctx .. first_ctx + 31 on one table (a
+    ring's table names its R blocks again and again)."""
+    ctx = np.asarray([*decode, *_rising(first_ctx, 32)], np.int64)
+    tbl = np.arange(len(ctx) * NB, dtype=np.int32).reshape(len(ctx), NB)
+    if ring:
+        tbl = tbl[:, :1] + np.arange(NB, dtype=np.int32)[None, :] % ring
+    tbl[len(decode):] = tbl[len(decode)]
+    return tbl, ctx
+
+
+@pytest.mark.parametrize("what,call,window,masked,visited", [
+    # the last chunk of a 8k prompt in a full layer: 64 blocks, of which
+    # the last alone can hold a dead column (8160 = 63.75 blocks)
+    ("8k_chunk_full_layer", _chunk_call(8160, 128, 80, decode=(3000, 17)),
+     0, 1, 64),
+    # contexts 8180..8211 cross into block 64: two masked of 65
+    ("8k_chunk_across_an_edge", _chunk_call(8180, 128, 80), 0, 2, 65),
+    # the same chunk in a windowed layer, a ring of 10 blocks: window
+    # starts 7156..7187 lie inside blocks 55 and 56, the contexts end in
+    # 63 and 64: four masked of ten
+    ("8k_chunk_ring_of_10", _chunk_call(8180, 128, 80, ring=10),
+     1024, 4, 10),
+    ("8k_chunk_ring_of_10_aligned", _chunk_call(8160, 128, 80, ring=10),
+     1024, 2, 9),
+    # a prompt's first chunk: one block, masked
+    ("first_chunk", _chunk_call(1, 128, 32, decode=(300,)), 0, 1, 1),
+    # the chat cells' second chunk under Mistral's window of 4096
+    ("chat_chunk_wide_window", _chunk_call(129, 128, 32), 4096, 1, 2),
+    # rows alone are in neither count
+    ("decode_rows_alone", (np.arange(12, dtype=np.int32).reshape(3, 4),
+                           np.asarray([5, 200, 300])), 0, 0, 0),
+])
+def test_the_unmasked_visit_is_taken_where_it_should_be(what, call, window,
+                                                        masked, visited):
+    """walk_masks counts, by the kernel's own predicate, the blocks a
+    call's groups visit WITH the compares and the select: the edges of
+    a span, never its middle."""
+    tbl, ctx = call
+    assert PA.walk_masks(tbl, ctx, 128, 8, window) == (masked, visited)
+    if visited > 4:
+        assert masked <= (4 if window else 2)
+
+
+@pytest.mark.usefixtures("pallas_interpret")
+@pytest.mark.parametrize("name,masked,visited", [
+    ("group_whole_blocks_but_the_last", 1, 5),
+    ("group_context_crosses_a_block_edge", 2, 5),
+    ("group_window_start_on_and_off_a_block_edge", 4, 5),
+    ("group_window_whole_blocks_between_its_edges", 2, 5),
+    ("group_with_a_row_of_context_0", 5, 5),
+])
+def test_the_kernel_masks_the_blocks_the_count_names(rng, monkeypatch, name,
+                                                     masked, visited):
+    """The interpreted kernel asks _wholly_live once a visit of a group:
+    its answers are walk_masks' counts, block for block."""
+    c = CASES[name]
+    q, kc, vc, tbl, ctx = _inputs(rng, c)
+    answers = []
+    wholly_live = PA._wholly_live
+
+    def spying(j, *args):
+        whole = wholly_live(j, *args)
+        if not isinstance(j, np.ndarray):  # the kernel's, not walk_masks'
+            jax.debug.callback(lambda w: answers.append(bool(w)), whole)
+        return whole
+
+    monkeypatch.setattr(PA, "_wholly_live", spying)
+    PA._attend_live_blocks.clear_cache()  # traced with the plain predicate
+    try:
+        out = paged_decode_attention(q, kc, vc, jnp.asarray(tbl),
+                                     jnp.asarray(ctx), window=c["window"])
+        out.block_until_ready()
+        jax.effects_barrier()
+    finally:
+        PA._attend_live_blocks.clear_cache()
+    assert (answers.count(False), len(answers)) == (masked, visited)
+    assert PA.walk_masks(tbl, ctx, c["bs"], c["H"] // c["KV"],
+                         c["window"]) == (masked, visited)
+
+
 @pytest.mark.usefixtures("pallas_interpret")
 def test_rows_walked_alone_are_what_they_are_without_a_group(rng):
     """A group of one takes the per-row walk: the decode rows of a call
@@ -262,7 +392,10 @@ def test_rows_walked_alone_are_what_they_are_without_a_group(rng):
     q, kc, vc, tbl, ctx = _inputs(rng, c)
     # a decode row, the chunk's first, the last
     alone = np.array([0, 1, len(ctx) - 1])
-    tbl[2:-1] = tbl[2]  # the group is rows 2.., the chunk's first row apart
+    # the group is rows 2.., the chunk's first row apart on a table of
+    # its own (row 0's, backwards: blocks that exist, no run with row 0)
+    tbl[1] = tbl[0][::-1]
+    tbl[2:-1] = tbl[2]
     with jax.default_matmul_precision("highest"):
         out = paged_decode_attention(q, kc, vc, jnp.asarray(tbl),
                                      jnp.asarray(ctx))
@@ -339,7 +472,7 @@ def test_which_case_walks_and_which_keeps_the_grid(what, KV, D, dtype,
 
 BLOCK, BLOCKS_PER_SEQ = 128, 32
 
-# the five serving cells that run the walk, at their engines' shapes:
+# the six serving cells that run the walk, at their engines' shapes:
 # rows of a step, query / KV heads, head dim, pool blocks; lfm2's pool
 # is packed (kv_pack: 8 heads of 64 as 4 rows of 128 lanes)
 CELLS = {
@@ -352,6 +485,8 @@ CELLS = {
                                                 pool=1024),
     "serve-granite4h-chat-saturated-r128": dict(rows=128, H=32, KV=8, D=128,
                                                 pool=1024),
+    "serve-mellum2-mixedlen-saturated-r256": dict(rows=256, H=32, KV=4,
+                                                  D=128, pool=3072),
 }
 
 
@@ -380,6 +515,9 @@ def one_chip():
     ("serve-lfm2-chat-saturated-r512", 0),
     ("serve-qwen3next-chat-saturated-r256", 0),
     ("serve-granite4h-chat-saturated-r128", 0),
+    # its full layers and its windowed ones
+    ("serve-mellum2-mixedlen-saturated-r256", 0),
+    ("serve-mellum2-mixedlen-saturated-r256", 1024),
 ])
 def test_shared_table_attention_compiles_for_v5e(one_chip, cell, window):
     """The walk WITH its grouped body (groups of 32 rows at every cell's

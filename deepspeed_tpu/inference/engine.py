@@ -605,8 +605,13 @@ class InferenceEngine:
         if width not in self._expert_paths:
             fetch = self._fetch_layer() or (lambda lp, dep, li: lp)
             n_dense = len(self.params.get("dense_layers", ()))
+            # the first routed layer (the first of `layers`, but where a
+            # layer is one mixer: the first 'experts' layer)
+            li = next(i for i, lp in enumerate(self.params["layers"])
+                      if "w_router" in lp)
             lp = jax.eval_shape(
-                lambda p: fetch(self._dequant(p)["layers"][0], None, n_dense),
+                lambda p: fetch(self._dequant(p)["layers"][li], None,
+                                n_dense + li),
                 self.params)
             self._expert_paths[width] = M.expert_path(
                 width, self.cfg, lp, self._use_kernel, self.mesh)

@@ -50,6 +50,7 @@ from ..ops.attention import causal_attention, uses_flash
 from ..ops.pallas.expert_stream import (
     expert_grouped_mlp,
     expert_stream_mlp,
+    expert_stream_ungated_mlp,
     group_rows,
     grouped_f_tile,
     stream_f_tile,
@@ -115,11 +116,12 @@ def prepare(params: Dict[str, Any], cfg: T.TransformerConfig,
             "partitions before serving)"
         )
     # operators of several kinds: each kind's top-level stacks hand
-    # layer li the entry of its place among its kind (conv and gdn
-    # leaves keep their prefix, clear of the FFN's w_in / w_out)
-    ops = {kind: {(k[len(prefix):] if kind == "attention" else k): out.pop(k)
-                  for k in list(out) if k.startswith(prefix)}
-           for kind, prefix, _ in T.operator_stacks(cfg)}
+    # layer li the entry of its place among its kind, under the leaf's
+    # own name (conv, gdn and ssm leaves carry their prefix, clear of
+    # the FFN's w_in / w_out)
+    ops = {kind: {} for kind, _, _ in T.operator_stacks(cfg)}
+    for top, kind, name, _, _ in T._operator_leaves(cfg):
+        ops[kind][name] = out.pop(top)
 
     def layer(leaves, li):
         mine = ops.get(cfg.layer_kind(li), {})
@@ -166,7 +168,26 @@ def prepare_layer(lp: Dict[str, Any], cfg: T.TransformerConfig,
         if "w_router" not in lp and cfg.is_gated and "w_gate" in lp:
             lp["w_gi"] = jnp.concatenate(
                 [lp.pop("w_gate"), lp.pop("w_in")], axis=1)
+    if "w_router" in lp and "w_gate" not in lp and "b_in" not in lp:
+        lp["w_in"], lp["w_out"] = _whole_lane_experts(lp["w_in"], lp["w_out"])
     return lp
+
+
+def _whole_lane_experts(w_in, w_out):
+    """The stacks of a routed block WITHOUT a gate, [X, E, F] and
+    [X, F, E], with F padded to whole 128-lane tiles where E fills them
+    and F does not (1,856 = 14.5 tiles to 1,920): zero columns of w_in
+    against zero rows of w_out, which add nothing whatever the
+    activation makes of 0, so that the one pipelined pass takes the
+    stacks (expert_stream's tiles are whole lanes of F). 3.4% more
+    bytes at those widths; a block that fills its lanes, or whose E
+    fills none (the pass would refuse it anyway), stays as it is."""
+    X, E, F = w_in.shape
+    pad = -F % 128
+    if E % 128 or not pad:
+        return w_in, w_out
+    return (jnp.pad(w_in, ((0, 0), (0, 0), (0, pad))),
+            jnp.pad(w_out, ((0, 0), (0, pad), (0, 0))))
 
 
 # per-layer serving weight name -> (contract_ndim, logical axes) for
@@ -732,10 +753,12 @@ def expert_path(n_tokens: int, cfg: T.TransformerConfig, lp=None,
     Of the two, the one pipelined pass wherever its kernel takes the
     inputs: kernels on and one device (under a mesh of several, as for
     attention in _decode_attention, a raw pallas_call cannot consume
-    sharded operands), a gated block without biases, plain 16-bit
-    stacks whose E and F fill whole lanes and tokens whose resident
-    buffers fit VMEM (expert_stream.stream_f_tile). A QuantizedWeight
-    stack dequantises transiently and keeps the scan.
+    sharded operands), a block without biases, gated (three stacks) or
+    not (two: act(h W_in) W_out, the pass's ungated entry), plain
+    16-bit stacks whose E and F fill whole lanes (prepare pads an
+    ungated block's F to them) and tokens whose resident buffers fit
+    VMEM (expert_stream.stream_f_tile). A QuantizedWeight stack
+    dequantises transiently and keeps the scan.
 
     Where that pass would be taken and the rows are past the chip's
     ridge (_STREAM_RIDGE_TOKENS: its matmuls would bind, not its
@@ -748,8 +771,8 @@ def expert_path(n_tokens: int, cfg: T.TransformerConfig, lp=None,
     streams = (
         lp is not None and use_kernel
         and (mesh is None or mesh.devices.size == 1)
-        and cfg.is_gated and "b_in" not in lp
-        and stream_f_tile(n_tokens, lp["w_gate"], lp["w_in"],
+        and "b_in" not in lp
+        and stream_f_tile(n_tokens, lp.get("w_gate"), lp["w_in"],
                           lp["w_out"]) is not None)
     every = "stream" if streams else "scan"
     if cfg.experts_held is not None:
@@ -758,9 +781,10 @@ def expert_path(n_tokens: int, cfg: T.TransformerConfig, lp=None,
     lo, hi = _STREAM_ROWS_PER_EXPERT if streams else _SCAN_ROWS_PER_EXPERT
     if not lo < rows < hi:
         return "ragged"
-    if (streams and n_tokens > _STREAM_RIDGE_TOKENS and grouped_f_tile(
-            n_tokens, cfg.moe_top_k, lp["w_gate"], lp["w_in"],
-            lp["w_out"]) is not None):
+    # (the grouped entry is the gated block's alone)
+    if (streams and cfg.is_gated and n_tokens > _STREAM_RIDGE_TOKENS
+            and grouped_f_tile(n_tokens, cfg.moe_top_k, lp["w_gate"],
+                               lp["w_in"], lp["w_out"]) is not None):
         return "grouped"
     return every
 
@@ -912,8 +936,10 @@ def _mlp(h, lp, cfg: T.TransformerConfig, census_cb=None,
 
     if path == "stream":
         with jax.named_scope("moe_experts"):
-            out = expert_stream_mlp(h, lp["w_gate"], lp["w_in"],
-                                    lp["w_out"], wcols, act)
+            out = (expert_stream_mlp(h, lp["w_gate"], lp["w_in"],
+                                     lp["w_out"], wcols, act) if has_gate
+                   else expert_stream_ungated_mlp(h, lp["w_in"], lp["w_out"],
+                                                  wcols, act))
         return _moe_shared(out, h, lp, cfg, act)
 
     xs = [deq(lp["w_in"]), deq(lp["w_out"]), wcols]
@@ -947,14 +973,17 @@ def _mlp(h, lp, cfg: T.TransformerConfig, census_cb=None,
 
 def _moe_shared(out, h, lp, cfg: T.TransformerConfig, act):
     """The tail of the all-expert paths: the shared expert (every
-    token, on every chip alike; unweighted, or times a sigmoid gate of
-    its own, one scalar a token: cfg.shared_expert_gate) under its own
-    scope, then the PR-MoE residual."""
+    token, on every chip alike; gated as the routed experts are, or
+    act(h ws_in) ws_out where they have no gate; unweighted, or times a
+    sigmoid gate of its own, one scalar a token:
+    cfg.shared_expert_gate) under its own scope, then the PR-MoE
+    residual."""
     if cfg.n_shared_experts:
         with jax.named_scope("moe_shared"):
-            y = _wmm(
-                "tf,fe->te", act(_wmm("te,ef->tf", h, lp["ws_gate"]))
-                * _wmm("te,ef->tf", h, lp["ws_in"]), lp["ws_out"])
+            up = lambda: _wmm("te,ef->tf", h, lp["ws_in"])
+            inner = (act(_wmm("te,ef->tf", h, lp["ws_gate"])) * up()
+                     if "ws_gate" in lp else act(up()))
+            y = _wmm("tf,fe->te", inner, lp["ws_out"])
             if cfg.shared_expert_gate:
                 y = y * jax.nn.sigmoid(
                     h.astype(jnp.float32)
@@ -986,14 +1015,17 @@ def _sigmoid_topk_gating(logits, cfg: T.TransformerConfig, bias=None):
 
 def _ffn_residual(x, attn_out, h1, lp, cfg: T.TransformerConfig,
                   census_cb=None, use_kernel: bool = False, mesh=None):
-    """The tail of one layer over [..., E] activations: the attention
-    residual, norm2 and the FFN (sequential, or
+    """The tail of one layer over [..., E] activations: the operator's
+    residual (and no more in a model whose layers are one sublayer
+    each, cfg.mixer_only), norm2 and the FFN (sequential, or
     the Falcon/Phi parallel form where the FFN reads ln2(x) or the
     shared ln1 output h1), under the scopes the training forward
     names (`norm2`, `mlp`)."""
     m = cfg.residual_multiplier
     if m != 1.0:  # Granite: both branches of every layer, before the add
         attn_out = attn_out * m
+    if cfg.mixer_only:  # the layer is its operator: no FFN behind it
+        return x + attn_out
     if not cfg.parallel_residual:
         x = x + attn_out
     if cfg.parallel_residual and cfg.shared_ln:
@@ -1097,7 +1129,9 @@ def _layer(x, lp, li: int, positions, cfg: T.TransformerConfig, mesh, attend,
     prefill prompts [B, Tp, E]): norm1, then the layer's operator by its
     kind (cfg.layer_kind(li)) and the FFN tail. A layer that carries
     state runs its kind's operator (_STATE_OPERATORS) under its kind's
-    scope. A 'conv' layer: the gated short convolution (_short_conv)
+    scope. An 'experts' layer (cfg.mixer_only) runs the routed block on
+    norm1's output under the scope `mlp` and holds nothing. A 'conv'
+    layer: the gated short convolution (_short_conv)
     with `carry(u, li)` handed in by the caller, as `attend` is: where
     the inputs before this one come from and how the sequence's state
     row is left. A 'linear_attention' layer: the Gated DeltaNet
@@ -1122,6 +1156,11 @@ def _layer(x, lp, li: int, positions, cfg: T.TransformerConfig, mesh, attend,
         h1 = T._act_quant(
             T._norm(x, lp["ln1_scale"], lp.get("ln1_bias"), cfg), cfg)
     kind = cfg.layer_kind(li)
+    if kind == "experts":  # the routed block as the layer's operator
+        with jax.named_scope("mlp"):
+            out = _mlp(h1.reshape(-1, h1.shape[-1]), lp, cfg, census_cb,
+                       use_kernel, mesh).reshape(x.shape)
+        return _ffn_residual(x, out, h1, lp, cfg), None
     if kind != "attention":
         scope, operator = _STATE_OPERATORS[kind]
         with jax.named_scope(scope):
@@ -1287,19 +1326,22 @@ def _state_space(h1, lp, cfg: T.TransformerConfig, carry, recur):
     -> (its output [..., E], the layer's state pools (the heads'
     matrices, the convolution's carried inputs)).
 
-    [z; x; B; C; dt] = ssm_in h1 (no bias); [x; B; C] <- silu(causal
+    [z; x; B; C; dt] = ssm_in h1 (no bias), B and C one vector of
+    ssm_state_dim a GROUP of heads (cfg.ssm_groups, side by side);
+    [x; B; C] <- silu(causal
     depthwise convolution of conv_kernel taps + ssm_conv_bias, zeros
     before the sequence starts), in the activations' dtype as the
     publisher's; dt <- softplus(dt + dt_bias), A = -exp(a_log),
     float32, one of each a head, no clamp; the recurrence per head
-    (ops/pallas/ssm_state.py: S <- exp(dt A) S + (dt x) B^T, y = S C)
-    through `recur((x, dt, A, B, C))` -> (y float32, the matrices'
-    pool), with `carry` as _short_conv's; y += D x (ssm_d);
-    then y <- rms(y * silu(z)) * ssm_norm_scale over ALL the heads'
-    values together (the gate BEFORE the norm: the DeltaNet's order is
-    the other way round) and ssm_out."""
+    (ops/pallas/ssm_state.py: S <- exp(dt A) S + (dt x) B^T, y = S C,
+    B and C its group's) through `recur((x, dt, A, B, C))` -> (y
+    float32, the matrices' pool), with `carry` as _short_conv's;
+    y += D x (ssm_d); then y <- rms(y * silu(z)) * ssm_norm_scale, the
+    statistic over each GROUP's values apart (all the heads' together
+    where there is one group; the gate BEFORE the norm: the DeltaNet's
+    order is the other way round) and ssm_out."""
     Hs, P, N = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state_dim
-    I, f32 = cfg.ssm_inner, jnp.float32
+    I, G, f32 = cfg.ssm_inner, cfg.ssm_groups, jnp.float32
     with jax.named_scope("ssm_project"):
         mixed = _wmm("...e,ef->...f", h1, lp["ssm_in"])
         z, u, dt = jnp.split(mixed, [I, I + cfg.ssm_conv_dim], axis=-1)
@@ -1309,16 +1351,19 @@ def _state_space(h1, lp, cfg: T.TransformerConfig, carry, recur):
         conv, conv_pool = carry(u, lp["ssm_taps"])
         c = jax.nn.silu(conv + lp["ssm_conv_bias"].astype(f32)
                         ).astype(u.dtype).astype(f32)
-        x, Bm, Cm = jnp.split(c, [I, I + N], axis=-1)
+        x, Bm, Cm = jnp.split(c, [I, I + G * N], axis=-1)
         x = x.reshape(*x.shape[:-1], Hs, P)
     with jax.named_scope("ssm_state"):
         y, pool = recur((x, dt, A, Bm, Cm))
         y = y + lp["ssm_d"].astype(f32)[:, None] * x
     with jax.named_scope("ssm_gate_norm"):
         y = y.reshape(z.shape) * jax.nn.silu(z.astype(f32))
-        y = (y * jax.lax.rsqrt(jnp.mean(y * y, -1, keepdims=True)
-                               + cfg.norm_eps)
-             * lp["ssm_norm_scale"].astype(f32)).astype(h1.dtype)
+        if G > 1:  # each group's statistic its own
+            y = y.reshape(*z.shape[:-1], G, I // G)
+        y = y * jax.lax.rsqrt(jnp.mean(y * y, -1, keepdims=True)
+                              + cfg.norm_eps)
+        y = (y.reshape(z.shape) * lp["ssm_norm_scale"].astype(f32)
+             ).astype(h1.dtype)
     with jax.named_scope("ssm_out"):
         out = _wmm("...f,fe->...e", y, lp["ssm_out"])
     return out, (pool, conv_pool)
@@ -1374,7 +1419,7 @@ def _ssm_scan(args, real, cfg):
     last states in the pool's layout; a pad token has dt = 0."""
     x, dt, A, Bm, Cm = args
     y, last = ssm_chunked(x, jnp.where(real, dt, 0.0), A, Bm, Cm,
-                          chunk=cfg.ssm_chunk)
+                          chunk=cfg.ssm_chunk, groups=cfg.ssm_groups)
     return y, pack_state(last, cfg.ssm_pack)
 
 
@@ -1603,8 +1648,11 @@ def _forward(params, tokens, positions, cfg: T.TransformerConfig, mesh,
                              li)
         x, layer_cache = _layer(x, lp, li, positions, cfg, mesh, attend,
                                 alibi, census_cb, use_kernel, carry, recur)
-        (pools if cfg.layer_kind(li) == "attention" else states).append(
-            layer_cache)
+        # a layer holds K/V, state or nothing, by its kind
+        if cfg.layer_kind(li) == "attention":
+            pools.append(layer_cache)
+        elif layer_cache is not None:
+            states.append(layer_cache)
         x_hist.append(x)
 
     with jax.named_scope("lm_head"):

@@ -1782,6 +1782,17 @@ class ServingScheduler:
                     m[f"moe_expert_{i}_share"] = float(c) / total
                 m["moe_imbalance"] = float(
                     census.max() / max(float(census.mean()), 1e-9))
+            held = self.engine.cfg.experts_held
+            if held is not None:
+                # a chip that holds a share of the experts: the pairs
+                # that REACHED the held ones (the census counts every
+                # routed layer), beside what a router that spreads its
+                # pairs evenly would send them: count / of a pair
+                start, count = held
+                m["moe_census_held_pairs"] = float(
+                    census[start:start + count].sum())
+                m["moe_census_held_pairs_expected"] = (
+                    total * count / len(census))
         for k, v in self.counters.items():
             m[k] = float(v)
         for cls, v in sorted(self.slo_rejections.items()):

@@ -155,7 +155,7 @@ class TransformerConfig:
     qkv_bias: Optional[bool] = None       # Qwen/Qwen2/Phi: q/k/v biases
     attn_out_bias: Optional[bool] = None  # bo (OPT/Phi yes, Qwen no)
     mlp_bias: Optional[bool] = None       # b_in/b_out
-    activation: Optional[str] = None      # silu | gelu | relu (OPT)
+    activation: Optional[str] = None      # silu | gelu | relu (OPT) | relu2
     norm_type: Optional[str] = None       # rms | layer (Falcon/Phi: layer)
     gated_mlp: Optional[bool] = None      # SwiGLU pair vs single w_in
     # Falcon/Phi parallel form: x + attn(ln1 x) + mlp(ln2 x); shared_ln
@@ -200,9 +200,11 @@ class TransformerConfig:
     # a second RMSNorm on each sub-layer's OUTPUT, before the residual
     # add: x + N_post(f(N_pre(x))) (four norms a layer)
     sandwich_norm: bool = False
-    # shared experts beside the routed ones: one dense gated MLP of
-    # n_shared_experts * d_ff every token passes through; unweighted, or
-    # (shared_expert_gate) times sigmoid(ws_sgate . h), a scalar a token
+    # shared experts beside the routed ones: one dense MLP of
+    # n_shared_experts * d_ff every token passes through, gated or not
+    # as the routed experts are (is_gated: an ungated block has no
+    # `ws_gate`); unweighted, or (shared_expert_gate) times
+    # sigmoid(ws_sgate . h), a scalar a token
     n_shared_experts: int = 0
     shared_expert_gate: bool = False
     # router scores: "softmax" over the experts, or "sigmoid" of each
@@ -238,12 +240,14 @@ class TransformerConfig:
     # (inference/model.py _state_space): ssm_heads heads of ssm_head_dim
     # that each carry a float32 [ssm_head_dim, ssm_state_dim] matrix
     # (S <- exp(dt A) S + (dt x) B^T, y = S C + D x: ONE scalar decay a
-    # head a token, B and C one vector a token for all the heads),
+    # head a token, B and C one vector a token for all the heads of a
+    # group, ssm_groups),
     # behind a causal depthwise convolution of conv_kernel taps WITH a
-    # bias (then silu) over the channels of [x; B; C]. A sequence carries
+    # bias (then silu) over the channels of [x; B; C]; or "experts", the
+    # routed block as a layer of its own (mixer_only). A sequence carries
     # fixed-size state from token to token in a conv, linear-attention
-    # or state-space layer, whatever its length (state_shapes), and K/V
-    # in the attention layers alone.
+    # or state-space layer, whatever its length (state_shapes), K/V
+    # in the attention layers alone, and nothing in an experts layer.
     # The operators' weights are top-level stacks by kind (`conv_<name>`
     # [n conv layers, ...], `attn_<name>`, `gdn_<name>`, `ssm_<name>`);
     # `layers` (and `dense_<name>`) keep what every layer has, its norms
@@ -257,8 +261,18 @@ class TransformerConfig:
     ssm_heads: int = 0
     ssm_head_dim: int = 0
     ssm_state_dim: int = 0
+    # groups of a state-space layer's heads: a token has ONE B and ONE C
+    # vector a group (ssm_heads / ssm_groups heads read each), and the
+    # gated norm's statistic is a group's own
+    ssm_groups: int = 1
     # tokens the whole-prompt scan of a state-space layer takes at a time
     ssm_chunk: int = 256
+    # SERVING ONLY. Every layer is ONE sublayer, x + op(norm1 x), with
+    # one norm: no FFN tail behind a mixer, and the routed block is a
+    # layer of its own, the kind "experts" of LAYER_KINDS (its leaves a
+    # top-level stack `moe_<name>` like any operator's; such a layer
+    # holds neither K/V nor state). `layers` then holds the norm alone.
+    mixer_only: bool = False
     # attention's output gate: W_q projects each head to [q; gate]
     # (leaf `wq_gate` beside `wq`), att <- att * sigmoid(gate) before W_o
     attn_output_gate: bool = False
@@ -293,14 +307,27 @@ class TransformerConfig:
                     f"the {self.depth} layers (got {self.layer_types})")
             if self.state_layer_kinds and self.conv_kernel < 2:
                 raise ValueError(
-                    "layers that carry state (every kind but attention) "
-                    "need conv_kernel >= 2")
+                    "layers that carry state (conv, linear_attention, "
+                    "state_space) need conv_kernel >= 2")
             if "state_space" in self.layer_types and not (
                     self.ssm_heads > 0 and self.ssm_head_dim > 0
                     and self.ssm_state_dim > 0 and self.ssm_chunk > 0):
                 raise ValueError(
                     "state_space layers need ssm_heads, ssm_head_dim, "
                     "ssm_state_dim and ssm_chunk")
+            if "state_space" in self.layer_types and (
+                    self.ssm_groups < 1 or self.ssm_heads % self.ssm_groups
+                    or self.ssm_heads // self.ssm_groups % self.ssm_pack):
+                raise ValueError(
+                    f"ssm_groups {self.ssm_groups} must divide ssm_heads "
+                    f"{self.ssm_heads} into groups of whole lane rows of "
+                    f"the state pool ({self.ssm_pack} heads a row)")
+            if ("experts" in self.layer_types) != (
+                    self.mixer_only and self.n_experts > 0):
+                raise ValueError(
+                    "an 'experts' layer is the routed block of a model "
+                    "whose layers are one sublayer each: it needs "
+                    "mixer_only and n_experts, and they need it")
             if "linear_attention" in self.layer_types and not (
                     self.gdn_key_heads > 0 and self.gdn_key_dim > 0
                     and self.gdn_value_dim > 0 and self.gdn_value_heads > 0
@@ -313,6 +340,14 @@ class TransformerConfig:
                 raise NotImplementedError(
                     "layers of two kinds with latent attention: a latent "
                     "pool beside state pools is not served")
+        if self.mixer_only and (
+                self.layer_types is None or self.n_dense_layers
+                or self.parallel_residual or self.sandwich_norm
+                or self.moe_use_residual):
+            raise ValueError(
+                "mixer_only layers are named by layer_types, and have no "
+                "leading dense layers, parallel or sandwich form, nor a "
+                "PR-MoE residual")
         if self.shared_expert_gate and not self.n_shared_experts:
             raise ValueError("shared_expert_gate gates n_shared_experts: "
                              "set both")
@@ -372,9 +407,10 @@ class TransformerConfig:
         if self.variant not in ("llama", "gpt2"):
             raise ValueError(f"unknown variant '{self.variant}'")
         if self.activation not in (None, "silu", "gelu", "gelu_exact",
-                                   "relu"):
+                                   "relu", "relu2"):
             # "gelu" is the tanh approximation (HF gelu_new — GPT-2/Phi);
-            # "gelu_exact" is erf GELU (Falcon's nn.GELU())
+            # "gelu_exact" is erf GELU (Falcon's nn.GELU()); "relu2" the
+            # squared relu, relu(x)^2 (an ungated MLP's)
             raise ValueError(f"unknown activation '{self.activation}'")
         if self.norm_type not in (None, "rms", "layer"):
             raise ValueError(f"unknown norm_type '{self.norm_type}'")
@@ -529,9 +565,10 @@ class TransformerConfig:
                                  "moe_expert_bias", "attn_output_gate",
                                  "shared_expert_gate", "position_embedding",
                                  "attention_multiplier",
-                                 "rope_scaling_full_only")
+                                 "rope_scaling_full_only", "mixer_only")
                      if getattr(self, k)) + tuple(
             k for k, plain in (("moe_scoring", "softmax"),
+                               ("ssm_groups", 1),
                                ("embedding_multiplier", 1.0),
                                ("residual_multiplier", 1.0),
                                ("logits_scaling", 1.0))
@@ -554,7 +591,7 @@ class TransformerConfig:
     def state_index(self, li: int) -> int:
         """State layer li's place among the layers that hold state (of
         whatever kind): which entry of the cache's state pools is its."""
-        return li - self.layer_types[:li].count("attention")
+        return sum(k in _STATE_LAYERS for k in self.layer_types[:li])
 
     @property
     def n_kv_layers(self) -> int:
@@ -564,8 +601,9 @@ class TransformerConfig:
 
     @property
     def n_state_layers(self) -> int:
-        """Layers that hold fixed-size per-sequence state in a slot."""
-        return self.depth - self.n_kv_layers
+        """Layers that hold fixed-size per-sequence state in a slot (a
+        layer holds K/V, state or nothing, by its kind: not by depth)."""
+        return len(self.state_layer_kinds)
 
     @property
     def gdn_conv_dim(self) -> int:
@@ -582,8 +620,8 @@ class TransformerConfig:
     @property
     def ssm_conv_dim(self) -> int:
         """Channels the state-space layer's convolution runs over:
-        [x; B; C], one B and one C for all the heads."""
-        return self.ssm_inner + 2 * self.ssm_state_dim
+        [x; B; C], one B and one C a group of heads."""
+        return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state_dim
 
     @property
     def gdn_state_shape(self) -> Tuple[int, ...]:
@@ -651,7 +689,7 @@ class TransformerConfig:
     def state_layer_kinds(self) -> Tuple[str, ...]:
         """The kind of each layer that holds state, in their order."""
         return tuple(k for k in (self.layer_types or ())
-                     if k != "attention")
+                     if k in _STATE_LAYERS)
 
     @property
     def depth(self) -> int:
@@ -729,8 +767,13 @@ def _layer_shapes(cfg: TransformerConfig, dense: bool = False
     dense: a LEADING dense layer of a model whose stacked layers are
     routed (cfg.n_dense_layers): the same attention and norms, a dense
     MLP of width cfg.dense_d_ff."""
-    E, H, KV, D, F = cfg.d_model, cfg.n_heads, cfg.kv_heads, cfg.head_dim, cfg.ff_dim
+    E, F = cfg.d_model, cfg.ff_dim
     shapes = {"ln1_scale": ((E,), ("embed",))}
+    if cfg.mixer_only:
+        # one sublayer a layer: its one norm, and nothing else here
+        if cfg.norm_has_bias:
+            shapes["ln1_bias"] = ((E,), ("embed",))
+        return shapes
     if cfg.layer_types is None:
         # every layer's operator is attention: its leaves are the
         # layer's own (a model of two kinds keeps them in stacks by
@@ -745,44 +788,7 @@ def _layer_shapes(cfg: TransformerConfig, dense: bool = False
     if dense:
         F = cfg.dense_d_ff
     if X > 0:
-        # Expert-stacked FFN weights: leading experts dim shards over the
-        # 'expert' mesh axis; the expert-hidden dim may additionally shard
-        # over 'model' (ref: moe/experts.py local expert bundle — here one
-        # stacked array instead of a ModuleList).
-        # the router spans every expert; the stacks hold this chip's
-        # share of them (cfg.experts_held; all of them when None)
-        Xh = cfg.n_experts_held
-        shapes.update({
-            "w_router": ((E, X), ("embed", None)),
-            **({"expert_bias": ((X,), (None,))}
-               if cfg.moe_expert_bias else {}),
-            "w_in": ((Xh, E, F), ("expert", "embed", "expert_mlp")),
-            "w_out": ((Xh, F, E), ("expert", "expert_mlp", "embed")),
-        })
-        if cfg.is_gated:
-            shapes["w_gate"] = ((Xh, E, F), ("expert", "embed", "expert_mlp"))
-        if cfg.n_shared_experts:
-            Fs = cfg.n_shared_experts * F
-            shapes.update({
-                "ws_gate": ((E, Fs), ("embed", "mlp")),
-                "ws_in": ((E, Fs), ("embed", "mlp")),
-                "ws_out": ((Fs, E), ("mlp", "embed")),
-                **({"ws_sgate": ((E, 1), ("embed", None))}
-                   if cfg.shared_expert_gate else {}),
-            })
-        if cfg.moe_use_residual:
-            # PR-MoE: dense residual expert + mixing coefficient
-            shapes.update({
-                "wr_in": ((E, F), ("embed", "mlp")),
-                "wr_out": ((F, E), ("mlp", "embed")),
-                "w_coef": ((E, 2), ("embed", None)),
-                "b_coef": ((2,), (None,)),
-            })
-            if cfg.is_gated:
-                shapes["wr_gate"] = ((E, F), ("embed", "mlp"))
-            if cfg.has_mlp_bias:
-                shapes["br_in"] = ((F,), ("mlp",))
-                shapes["br_out"] = ((E,), ("embed",))
+        shapes.update(_routed_shapes(cfg))
     else:
         shapes.update({
             "w_in": ((E, F), ("embed", "mlp")),
@@ -804,13 +810,65 @@ def _layer_shapes(cfg: TransformerConfig, dense: bool = False
     return shapes
 
 
+def _routed_shapes(cfg: TransformerConfig):
+    """The leaves of one routed block: the FFN of a routed layer, or
+    the operator of an 'experts' layer (cfg.mixer_only). Same form as
+    _layer_shapes."""
+    E, F, X = cfg.d_model, cfg.ff_dim, cfg.n_experts
+    # Expert-stacked FFN weights: leading experts dim shards over the
+    # 'expert' mesh axis; the expert-hidden dim may additionally shard
+    # over 'model' (ref: moe/experts.py local expert bundle — here one
+    # stacked array instead of a ModuleList).
+    # the router spans every expert; the stacks hold this chip's
+    # share of them (cfg.experts_held; all of them when None)
+    Xh = cfg.n_experts_held
+    shapes = {
+        "w_router": ((E, X), ("embed", None)),
+        **({"expert_bias": ((X,), (None,))}
+           if cfg.moe_expert_bias else {}),
+        "w_in": ((Xh, E, F), ("expert", "embed", "expert_mlp")),
+        "w_out": ((Xh, F, E), ("expert", "expert_mlp", "embed")),
+    }
+    if cfg.is_gated:
+        shapes["w_gate"] = ((Xh, E, F), ("expert", "embed", "expert_mlp"))
+    if cfg.n_shared_experts:
+        Fs = cfg.n_shared_experts * F
+        shapes.update({
+            **({"ws_gate": ((E, Fs), ("embed", "mlp"))}
+               if cfg.is_gated else {}),
+            "ws_in": ((E, Fs), ("embed", "mlp")),
+            "ws_out": ((Fs, E), ("mlp", "embed")),
+            **({"ws_sgate": ((E, 1), ("embed", None))}
+               if cfg.shared_expert_gate else {}),
+        })
+    if cfg.moe_use_residual:
+        # PR-MoE: dense residual expert + mixing coefficient
+        shapes.update({
+            "wr_in": ((E, F), ("embed", "mlp")),
+            "wr_out": ((F, E), ("mlp", "embed")),
+            "w_coef": ((E, 2), ("embed", None)),
+            "b_coef": ((2,), (None,)),
+        })
+        if cfg.is_gated:
+            shapes["wr_gate"] = ((E, F), ("embed", "mlp"))
+        if cfg.has_mlp_bias:
+            shapes["br_in"] = ((F,), ("mlp",))
+            shapes["br_out"] = ((E,), ("embed",))
+    return shapes
+
+
 def _operator_shapes(cfg: TransformerConfig, kind: str):
     """The leaves of one layer's OPERATOR, by its kind: attention
     (plain or latent, with its QK-norm scales and biases), the gated
     short convolution (`conv_in` to [B; C; X], the depthwise `conv_taps`
     [channel, tap], oldest tap first, and `conv_out`), the Gated
-    DeltaNet or the state-space mixer. Same form as _layer_shapes."""
+    DeltaNet, the state-space mixer, or the routed block of a model
+    whose layers are one sublayer each. Same form as _layer_shapes."""
     E, H, KV, D = cfg.d_model, cfg.n_heads, cfg.kv_heads, cfg.head_dim
+    if kind == "experts":
+        if cfg.has_mlp_bias:
+            raise NotImplementedError("mlp biases in an 'experts' layer")
+        return _routed_shapes(cfg)
     if kind == "conv":
         return {
             "conv_in": ((E, 3 * E), ("embed", "mlp")),
@@ -836,15 +894,16 @@ def _operator_shapes(cfg: TransformerConfig, kind: str):
             "gdn_out": ((Hv * Dv, E), ("mlp", "embed")),
         }
     if kind == "state_space":
-        # `ssm_in` to [z; x; B; C; dt] (the gate z and x of all heads, ONE
-        # B and C of ssm_state_dim, a step dt a head), the depthwise
+        # `ssm_in` to [z; x; B; C; dt] (the gate z and x of all heads, a B
+        # and a C of ssm_state_dim a group, a step dt a head), the depthwise
         # `ssm_taps` [channel, tap] over [x; B; C], oldest tap first, and
         # their `ssm_conv_bias`, the decay's `ssm_a_log` and the step's
         # `ssm_dt_bias` a head, the skip's `ssm_d` a head (the
         # publisher's D; a plain leaf to every recipe here, NOT a
         # `scale`: drawn 1 beside taps of 0.02 the skip D x is a hundred
         # times the state's read S C, and no check sees the state),
-        # the gated norm's scale over all heads, and `ssm_out`
+        # the gated norm's scale over all heads (its statistic a
+        # group's), and `ssm_out`
         Hs, I, C = cfg.ssm_heads, cfg.ssm_inner, cfg.ssm_conv_dim
         return {
             "ssm_in": ((E, I + C + Hs), ("embed", "mlp")),
@@ -888,11 +947,13 @@ DENSE_PREFIX = "dense_"
 # top-level stacks of the operators' leaves, by kind, of a model whose
 # layers are of several kinds (cfg.layer_types); its keys are the kinds
 OPERATOR_PREFIX = {"attention": "attn_", "conv": "conv_",
-                   "linear_attention": "gdn_", "state_space": "ssm_"}
+                   "linear_attention": "gdn_", "state_space": "ssm_",
+                   "experts": "moe_"}
 LAYER_KINDS = tuple(OPERATOR_PREFIX)
 # what a layer of each kind that carries state holds a sequence: the
 # property that counts its convolution's channels, and the one that
-# gives its heads' float32 matrices (None: it has none)
+# gives its heads' float32 matrices (None: it has none). An attention
+# layer holds K/V; a kind in neither place ('experts') holds nothing
 _STATE_LAYERS = {"conv": ("d_model", None),
                  "linear_attention": ("gdn_conv_dim", "gdn_state_shape"),
                  "state_space": ("ssm_conv_dim", "ssm_state_shape")}
@@ -1363,7 +1424,8 @@ def _attention_delta(h, lp, cfg: TransformerConfig, rng=None, positions=None,
 # static argument, and a fresh partial a call would be a new signature
 _ACT_FNS = {"silu": jax.nn.silu, "gelu": jax.nn.gelu,
             "gelu_exact": partial(jax.nn.gelu, approximate=False),
-            "relu": jax.nn.relu}
+            "relu": jax.nn.relu,
+            "relu2": lambda x: jnp.square(jax.nn.relu(x))}
 
 
 def _act_fn(cfg: TransformerConfig):

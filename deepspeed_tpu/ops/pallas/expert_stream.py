@@ -28,6 +28,12 @@ resident buffer handed to the kernel as scalar-prefetched offsets, and
 a grid step's matmuls walk that window alone. Which is taken is
 inference/model.py expert_path's to say.
 
+A block WITHOUT a gate (act(h W_in) W_out: two stacks an expert, not
+three) takes the all-expert entry's twin, expert_stream_ungated_mlp:
+the same grid, pipeline and combine column over two weight tiles, under
+a kernel name of its own (`expert_stream_ungated`), so that a trace
+tells the two apart.
+
 The stacks are read as serving's prepare() lays them out ([X, E, F],
 [X, E, F], [X, F, E]): no re-layout, no second copy.
 """
@@ -69,31 +75,35 @@ def _pad_rows(n: int) -> int:
 
 def _stack_dims(w_gate, w_in, w_out):
     """(X, E, F) of stacks ([X, E, F], [X, E, F], [X, F, E], arrays or
-    shapes) both passes can take, or None: stacks that are not plain
-    arrays of ONE 16-bit float type, or E or F off the 128-lane tile."""
-    stacks = (w_gate, w_in, w_out)
+    shapes; w_gate None: a block without a gate) the passes can take,
+    or None: stacks that are not plain arrays of ONE 16-bit float type,
+    or E or F off the 128-lane tile."""
+    stacks = tuple(w for w in (w_gate, w_in, w_out) if w is not None)
     if not all(hasattr(w, "dtype") and hasattr(w, "shape") for w in stacks):
         return None  # a QuantizedWeight stack: codes + scales
-    dtype = jnp.dtype(w_gate.dtype)
+    dtype = jnp.dtype(w_in.dtype)
     if (any(jnp.dtype(w.dtype) != dtype for w in stacks) or dtype.itemsize != 2
             or not jnp.issubdtype(dtype, jnp.floating)):
         return None
-    X, E, F = w_gate.shape
-    if w_in.shape != (X, E, F) or w_out.shape != (X, F, E) or E % 128 or F % 128:
+    X, E, F = w_in.shape
+    if (w_gate is not None and w_gate.shape != (X, E, F)
+            or w_out.shape != (X, F, E) or E % 128 or F % 128):
         return None
     return X, E, F
 
 
-def _widest_f_tile(E: int, F: int, resident: int, rows: int, budget: int):
-    """The widest F tile whose double-buffered weights fit their share
-    (the narrowest, one lane tile, may exceed it) and, beside `resident`
-    bytes and the float32 gate, up and their product of `rows` rows,
+def _widest_f_tile(E: int, F: int, resident: int, rows: int, budget: int,
+                   stacks: int = 3):
+    """The widest F tile whose double-buffered weights (`stacks` tiles
+    an expert) fit their share (the narrowest, one lane tile, may
+    exceed it) and, beside `resident` bytes and the float32 products of
+    `rows` rows (gate, up and theirs; or up and its activation),
     `budget`; None where none does."""
     for tf in range(F, 0, -128):
-        weights = 2 * 3 * E * tf * 2
+        weights = 2 * stacks * E * tf * 2
         if F % tf or (weights > _STREAM_WEIGHT_BYTES and tf > 128):
             continue
-        if weights + resident + 3 * rows * tf * 4 <= budget:
+        if weights + resident + stacks * rows * tf * 4 <= budget:
             return tf
     return None
 
@@ -101,9 +111,9 @@ def _widest_f_tile(E: int, F: int, resident: int, rows: int, budget: int):
 def stream_f_tile(n_tokens: int, w_gate, w_in, w_out):
     """The F tile the all-expert pass would take for `n_tokens` rows
     over these stacks (arrays or shapes: [X, E, F], [X, E, F],
-    [X, F, E]), or None where it cannot take them: stacks _stack_dims
-    refuses, or tokens whose resident buffers do not fit beside the
-    weight tiles."""
+    [X, F, E]; w_gate None: the ungated entry's two), or None where it
+    cannot take them: stacks _stack_dims refuses, or tokens whose
+    resident buffers do not fit beside the weight tiles."""
     dims = _stack_dims(w_gate, w_in, w_out)
     if dims is None:
         return None
@@ -112,7 +122,8 @@ def stream_f_tile(n_tokens: int, w_gate, w_in, w_out):
     resident = (4 * Tp * E * 2                    # tokens, result: x 2 buffers
                 + 2 * Tp * E * 4                  # accumulator, a dot's result
                 + 2 * Tp * -(-X // 128) * 128 * 4)  # the combine weights
-    return _widest_f_tile(E, F, resident, Tp, _STREAM_VMEM_BUDGET)
+    return _widest_f_tile(E, F, resident, Tp, _STREAM_VMEM_BUDGET,
+                          stacks=2 if w_gate is None else 3)
 
 
 def grouped_rows(n_tokens: int, top_k: int, n_experts: int) -> int:
@@ -184,8 +195,10 @@ def group_rows(idx, n_experts: int):
     return row_token, pos.reshape(T, k), starts, counts
 
 
-def _stream_kernel(c_ref, h_ref, wg_ref, wi_ref, wo_ref, o_ref, acc_ref, *,
-                   act):
+def _stream_kernel(c_ref, h_ref, *refs, act):
+    """refs: the weight tiles (gate, up, down; or up, down of a block
+    without a gate), the output and the accumulator."""
+    *w_refs, wo_ref, o_ref, acc_ref = refs
     x, f = pl.program_id(0), pl.program_id(1)
 
     @pl.when(jnp.logical_and(x == 0, f == 0))
@@ -193,14 +206,15 @@ def _stream_kernel(c_ref, h_ref, wg_ref, wi_ref, wo_ref, o_ref, acc_ref, *,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     h = h_ref[...]
-    gate = jnp.dot(h, wg_ref[...], preferred_element_type=jnp.float32)
-    up = jnp.dot(h, wi_ref[...], preferred_element_type=jnp.float32)
+    # [gate, up] of a gated block, [up] of one without a gate
+    first, *up = (jnp.dot(h, w[...], preferred_element_type=jnp.float32)
+                  for w in w_refs)
     # this expert's combine column out of the resident [T, X] matrix: a
     # masked lane reduction (a [1, T] row would have to be transposed)
     c = c_ref[...]
     lane = jax.lax.broadcasted_iota(jnp.int32, c.shape, 1)
     col = jnp.sum(jnp.where(lane == x, c, 0.0), axis=1, keepdims=True)
-    inner = (act(gate) * up * col).astype(h.dtype)
+    inner = ((act(first) * up[0] if up else act(first)) * col).astype(h.dtype)
     acc_ref[...] += jnp.dot(inner, wo_ref[...],
                             preferred_element_type=jnp.float32)
 
@@ -226,10 +240,18 @@ def expert_stream_mlp(h, w_gate, w_in, w_out, wcols, act=jax.nn.silu):
 @kernel_jit(5, 6, 7)
 def _stream_mlp(h, w_gate, w_in, w_out, wcols, act, tf: int,
                 interpreted: bool):
+    return _stream_call("expert_stream", h, (w_gate, w_in), w_out, wcols,
+                        act, tf, interpreted)
+
+
+def _stream_call(name: str, h, w_ins, w_out, wcols, act, tf: int,
+                 interpreted: bool):
+    """The all-expert pass over the stacks `w_ins` ([X, E, F] each: gate
+    and up, or up alone) and w_out [X, F, E], as the kernel `name`."""
     T, E = h.shape
-    X, _, F = w_gate.shape
+    X, F, _ = w_out.shape
     Tp = _pad_rows(T)
-    hp = jnp.pad(h.astype(w_gate.dtype), ((0, Tp - T), (0, 0)))
+    hp = jnp.pad(h.astype(w_out.dtype), ((0, Tp - T), (0, 0)))
     cols = jnp.pad(wcols.astype(jnp.float32).T, ((0, Tp - T), (0, 0)))
     whole = lambda x, f: (0, 0)
     out = pl.pallas_call(
@@ -238,8 +260,8 @@ def _stream_mlp(h, w_gate, w_in, w_out, wcols, act, tf: int,
         in_specs=[
             pl.BlockSpec((Tp, X), whole),
             pl.BlockSpec((Tp, E), whole),
-            pl.BlockSpec((None, E, tf), lambda x, f: (x, 0, f)),
-            pl.BlockSpec((None, E, tf), lambda x, f: (x, 0, f)),
+            *(pl.BlockSpec((None, E, tf), lambda x, f: (x, 0, f))
+              for _ in w_ins),
             pl.BlockSpec((None, tf, E), lambda x, f: (x, f, 0)),
         ],
         out_specs=pl.BlockSpec((Tp, E), whole),
@@ -249,9 +271,26 @@ def _stream_mlp(h, w_gate, w_in, w_out, wcols, act, tf: int,
             dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=_STREAM_VMEM_LIMIT),
         interpret=interpreted,
-        name="expert_stream",
-    )(cols, hp, w_gate, w_in, w_out)
+        name=name,
+    )(cols, hp, *w_ins, w_out)
     return out[:T]
+
+
+def expert_stream_ungated_mlp(h, w_in, w_out, wcols, act):
+    """expert_stream_mlp for a block without a gate: sum_x
+    wcols[x][:, None] * (act(h @ w_in[x]) @ w_out[x]), two stacks
+    streamed an expert. The caller asks stream_f_tile (w_gate None)
+    first."""
+    tf = stream_f_tile(h.shape[0], None, w_in, w_out)
+    assert tf is not None, (h.shape, w_in.shape, w_in.dtype)
+    return _stream_ungated_mlp(h, w_in, w_out, wcols, act, tf, interpret())
+
+
+@kernel_jit(4, 5, 6)
+def _stream_ungated_mlp(h, w_in, w_out, wcols, act, tf: int,
+                        interpreted: bool):
+    return _stream_call("expert_stream_ungated", h, (w_in,), w_out, wcols,
+                        act, tf, interpreted)
 
 
 def _grouped_kernel(start_ref, count_ref, xs_ref, wg_ref, wi_ref, wo_ref,

@@ -149,7 +149,8 @@ _ARCH_OF_MODEL_TYPE = {"olmoe": "OlmoeForCausalLM",
                        "lfm2_moe": "Lfm2MoeForCausalLM",
                        "qwen3_next": "Qwen3NextForCausalLM",
                        "granitemoehybrid": "GraniteMoeHybridForCausalLM",
-                       "mellum": "MellumForCausalLM"}
+                       "mellum": "MellumForCausalLM",
+                       "nemotron_h": "NemotronHForCausalLM"}
 # config.json keys that change what a BLOCK computes (latent attention,
 # shared experts, leading dense layers, a second norm, a scaled, grouped
 # or biased router, layers of another kind than attention): an
@@ -181,9 +182,15 @@ _MIXED_WINDOW_KEYS = (
     "rope_parameters", "mlp_layer_types", "use_qk_norm", "qk_norm",
     "attn_logit_softcapping", "final_logit_softcapping", "attention_sinks",
     "shared_expert_intermediate_size", "num_shared_experts")
+# layers that are ONE mixer each, named by a pattern string; a
+# state-space mixer in groups; ungated experts of a squared relu
+_MIXER_ONLY_KEYS = (
+    "hybrid_override_pattern", "mamba_num_heads", "mamba_head_dim",
+    "ssm_state_size", "n_groups", "mlp_hidden_act", "mamba_hidden_act",
+    "moe_shared_expert_intermediate_size", "use_conv_bias")
 _BLOCK_KEYS = tuple(dict.fromkeys(
     _LATENT_MOE_KEYS + _HYBRID_KEYS + _LINEAR_ATTENTION_KEYS
-    + _STATE_SPACE_KEYS + _MIXED_WINDOW_KEYS))
+    + _STATE_SPACE_KEYS + _MIXED_WINDOW_KEYS + _MIXER_ONLY_KEYS))
 # the block keys each architecture's mapping reads; any other stays an
 # error for it too
 _READS_BLOCK_KEYS = {
@@ -198,6 +205,10 @@ _READS_BLOCK_KEYS = {
     "MellumForCausalLM": frozenset((
         "layer_types", "rope_parameters", "mlp_layer_types",
         "moe_intermediate_size")),
+    "NemotronHForCausalLM": frozenset(_MIXER_ONLY_KEYS + (
+        "mamba_proj_bias", "n_routed_experts", "n_shared_experts",
+        "moe_intermediate_size", "routed_scaling_factor", "n_group",
+        "topk_group")),
 }
 
 
@@ -214,7 +225,7 @@ def _block_key_set(hf: Dict[str, Any], key: str) -> bool:
 SUPPORTED_ARCHITECTURES = sorted(_LLAMA_FAMILY | {
     "PanguUltraMoEForCausalLM", "Lfm2MoeForCausalLM",
     "Qwen3NextForCausalLM", "GraniteMoeHybridForCausalLM",
-    "MellumForCausalLM",
+    "MellumForCausalLM", "NemotronHForCausalLM",
     "GPT2LMHeadModel", "OPTForCausalLM", "FalconForCausalLM",
     "RWForCausalLM",  # falcon's pre-rename arch string
     "PhiForCausalLM", "QWenLMHeadModel",
@@ -251,6 +262,8 @@ def config_from_hf(hf: Dict[str, Any], **overrides) -> TransformerConfig:
         kw = _granite_moe_hybrid_config(hf)
     elif arch == "MellumForCausalLM":
         kw = _mellum_config(hf)
+    elif arch == "NemotronHForCausalLM":
+        kw = _nemotron_h_config(hf)
     elif arch in _LLAMA_FAMILY:
         kw = dict(
             vocab_size=hf["vocab_size"],
@@ -842,7 +855,8 @@ def _qwen3_next_config(hf: Dict[str, Any]) -> Dict[str, Any]:
 def _granite_moe_hybrid_config(hf: Dict[str, Any]) -> Dict[str, Any]:
     """Granite 4.0-H (`granitemoehybrid`): `layer_types` names each
     layer `mamba` (the Mamba-2 mixer: `mamba_n_heads` heads of
-    `mamba_d_head` with a state of `mamba_d_state`, ONE group, behind a
+    `mamba_d_head` with a state of `mamba_d_state`, in `mamba_n_groups`
+    groups (the published model: one), behind a
     depthwise convolution of `mamba_d_conv` taps with a bias; the
     scan's chunk `mamba_chunk_size`) or `attention` (GQA, no bias, no
     QK-norm, `position_embedding_type` "nope": no positional operation
@@ -873,7 +887,7 @@ def _granite_moe_hybrid_config(hf: Dict[str, Any]) -> Dict[str, Any]:
             f"granitemoehybrid layer_types names {sorted(kinds)} for each "
             f"of num_hidden_layers={L} layers (got {len(types)} entries, "
             f"unknown {unknown})")
-    for key, only in (("mamba_n_groups", 1), ("mamba_proj_bias", False),
+    for key, only in (("mamba_proj_bias", False),
                       ("mamba_conv_bias", True), ("attention_bias", False),
                       ("position_embedding_type", "nope"),
                       ("normalization_function", "rmsnorm"),
@@ -909,6 +923,7 @@ def _granite_moe_hybrid_config(hf: Dict[str, Any]) -> Dict[str, Any]:
         conv_kernel=int(hf["mamba_d_conv"]),
         ssm_heads=Hs, ssm_head_dim=P,
         ssm_state_dim=int(hf["mamba_d_state"]),
+        ssm_groups=int(hf.get("mamba_n_groups", 1)),
         ssm_chunk=int(hf.get("mamba_chunk_size", 256)),
         position_embedding="none",
         embedding_multiplier=float(hf.get("embedding_multiplier", 1.0)),
@@ -919,6 +934,92 @@ def _granite_moe_hybrid_config(hf: Dict[str, Any]) -> Dict[str, Any]:
         experts_held=experts_held,
         n_shared_experts=shared // F,
         moe_norm_topk_prob=True,
+        moe_dropless=True,
+    )
+
+
+def _nemotron_h_config(hf: Dict[str, Any]) -> Dict[str, Any]:
+    """Nemotron-H (`nemotron_h`): `hybrid_override_pattern` names each
+    layer by a character, and a layer is ONE sublayer, x + mixer(N(x)):
+    `M` the Mamba-2 mixer (`mamba_num_heads` heads of `mamba_head_dim`:
+    the inner width is their product, `expand` is never read; a state
+    of `ssm_state_size`; B and C in `n_groups` groups, the gated norm a
+    group; a depthwise convolution of `conv_kernel` taps with a bias;
+    the scan's chunk `chunk_size`), `E` the routed block
+    (`n_routed_experts` experts of `moe_intermediate_size`, each TWO
+    matrices, down(relu(up h)^2): `mlp_hidden_act` relu2; sigmoid
+    scores, the `num_experts_per_tok` largest of score +
+    e_score_correction_bias, weights the unbiased scores over their sum
+    times `routed_scaling_factor`; one shared expert of the same form,
+    `moe_shared_expert_intermediate_size` wide), `*` attention (GQA of
+    `head_dim`, no bias, NO positional operation: the publisher's
+    attention applies neither rotary nor learned positions and never
+    reads `rope_theta`). `-`, the family's dense MLP layer, is in no
+    pattern served here and is refused by name.
+
+    A cut that is one chip's share of an expert-parallel deployment
+    states it as Granite's does: `n_routed_experts` is what this chip
+    HOLDS, `reduced.n_routed_experts.published` the router's width,
+    `experts_held.start` the first held expert (0 if absent)."""
+    kinds = {"M": "state_space", "E": "experts", "*": "attention"}
+    L, pattern = int(hf["num_hidden_layers"]), hf["hybrid_override_pattern"]
+    if "-" in pattern:
+        raise ValueError(
+            "nemotron_h with a dense MLP layer ('-' in "
+            f"hybrid_override_pattern={pattern!r}) is unsupported: the "
+            "family's dense layer is not served")
+    unknown = sorted(set(pattern) - set(kinds))
+    if unknown or len(pattern) != L:
+        raise ValueError(
+            f"nemotron_h hybrid_override_pattern names {sorted(kinds)} for "
+            f"each of num_hidden_layers={L} layers (got {len(pattern)} "
+            f"characters, unknown {unknown})")
+    for key, only in (("n_group", 1), ("topk_group", 1),
+                      ("mamba_proj_bias", False), ("use_conv_bias", True),
+                      ("use_bias", False), ("mlp_bias", False),
+                      ("attention_bias", False),
+                      ("mamba_hidden_act", "silu"),
+                      ("mlp_hidden_act", "relu2"), ("sliding_window", None)):
+        if hf.get(key, only) != only:
+            raise ValueError(
+                f"nemotron_h with {key}={hf[key]!r} is unsupported "
+                f"(served: {only!r})")
+    F = int(hf["moe_intermediate_size"])
+    shared = (int(hf.get("moe_shared_expert_intermediate_size", 0))
+              if hf.get("n_shared_experts") else 0)
+    if shared % F:
+        raise ValueError(
+            f"nemotron_h moe_shared_expert_intermediate_size {shared} is "
+            f"no multiple of moe_intermediate_size {F}")
+    routed, experts_held = _held_share(hf, "n_routed_experts")
+    return dict(
+        vocab_size=hf["vocab_size"],
+        n_layers=L,
+        n_heads=hf["num_attention_heads"],
+        n_kv_heads=hf.get("num_key_value_heads") or None,
+        head_dim_override=hf.get("head_dim"),
+        d_model=hf["hidden_size"],
+        d_ff=F,
+        max_seq=hf.get("max_position_embeddings", 4096),
+        variant="llama",
+        gated_mlp=False, activation="relu2",
+        norm_eps=float(hf.get("norm_eps", hf.get("layer_norm_epsilon", 1e-5))),
+        tie_embeddings=bool(hf.get("tie_word_embeddings", False)),
+        mixer_only=True,
+        layer_types=tuple(kinds[c] for c in pattern),
+        conv_kernel=int(hf["conv_kernel"]),
+        ssm_heads=int(hf["mamba_num_heads"]),
+        ssm_head_dim=int(hf["mamba_head_dim"]),
+        ssm_state_dim=int(hf["ssm_state_size"]),
+        ssm_groups=int(hf["n_groups"]),
+        ssm_chunk=int(hf.get("chunk_size", 256)),
+        position_embedding="none",
+        n_experts=routed, moe_top_k=hf["num_experts_per_tok"],
+        experts_held=experts_held,
+        n_shared_experts=shared // F,
+        moe_scoring="sigmoid", moe_expert_bias=True,
+        routed_scaling_factor=float(hf.get("routed_scaling_factor", 1.0)),
+        moe_norm_topk_prob=bool(hf.get("norm_topk_prob", True)),
         moe_dropless=True,
     )
 

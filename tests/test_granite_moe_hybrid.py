@@ -353,8 +353,8 @@ _MISTRAL = {"architectures": ["MistralForCausalLM"], "hidden_size": 64,
      dict(_MISTRAL, residual_multiplier=0.22), "does not read"),
     ("no positions under another architecture",
      dict(_MISTRAL, position_embedding_type="nope"), "does not read"),
-    ("B and C of several groups", dict(HF, mamba_n_groups=2),
-     "mamba_n_groups"),
+    ("groups that cut a lane row of the pool's heads",
+     dict(HF, mamba_n_groups=2), "ssm_groups"),
     ("rotary positions", dict(HF, position_embedding_type="rope"),
      "position_embedding_type"),
     ("a convolution without its bias", dict(HF, mamba_conv_bias=False),
@@ -371,14 +371,52 @@ def test_what_the_mapping_cannot_serve_is_an_error(what, hf, match):
         config_from_hf(hf)
 
 
+def test_b_and_c_in_two_groups_are_served():
+    """`mamba_n_groups` is read, not refused: 16 heads in two lane rows
+    of the pool, a row a group. The whole-prompt scan (chunked, a
+    group) and the same tokens as a prefill, a chunk and single steps
+    (the recurrence from the slot) give one answer, and it is not the
+    one-group model's."""
+    hf = dict(HF, mamba_n_groups=2, mamba_n_heads=16, mamba_expand=4)
+    mcfg = config_from_hf(hf, use_flash=False)
+    assert (mcfg.ssm_groups, mcfg.ssm_inner, mcfg.ssm_conv_dim) == \
+        (2, 256, 256 + 2 * 2 * 32)
+    params = jax.tree.map(lambda x: x * 4, T.init(mcfg, jax.random.PRNGKey(2)))
+    eng = init_inference(params, mcfg, ENGINE, dtype=jnp.float32)
+    toks = np.random.default_rng(3).integers(0, 256, 40).astype(np.int32)
+    whole = np.asarray(eng.put([1], [toks]))[0]
+    eng.put([2], [toks[:30]])
+    eng.put([2], [toks[30:37]])
+    for t in toks[37:]:
+        stepped = np.asarray(eng.put([2], [np.asarray([t])]))[0]
+    assert np.abs(whole).max() > 0.1
+    assert np.abs(whole - stepped).max() < LOGITS_ATOL
+    # every head reading group 0's B and C is another model
+    one = dict(params, ssm_in=params["ssm_in"].at[:, :, 576:608].set(
+        params["ssm_in"][:, :, 544:576]))
+    other = init_inference(one, mcfg, ENGINE, dtype=jnp.float32)
+    assert np.abs(np.asarray(other.put([1], [toks]))[0] - whole).max() > \
+        300 * LOGITS_ATOL
+
+
 def test_the_kinds_and_their_tables_are_one():
-    """The fourth kind is an entry of each table, and the layer_types
-    error names the kinds from the one tuple."""
+    """A kind is an entry of each table, and the layer_types error names
+    the kinds from the one tuple. A layer holds K/V ('attention'),
+    state (the kinds of _STATE_LAYERS) or nothing ('experts', the routed
+    block as a layer of its own), by its kind."""
     from deepspeed_tpu.inference import scheduler as S
 
     assert T.LAYER_KINDS == tuple(T.OPERATOR_PREFIX)
-    state_kinds = set(T.LAYER_KINDS) - {"attention"}
+    state_kinds = set(T.LAYER_KINDS) - {"attention", "experts"}
     assert set(T._STATE_LAYERS) == set(M._STATE_OPERATORS) == state_kinds
+    cfg = T.TransformerConfig(
+        n_layers=4, conv_kernel=4, ssm_heads=8, ssm_head_dim=16,
+        ssm_state_dim=32, mixer_only=True, n_experts=4, layer_types=(
+            "state_space", "experts", "attention", "state_space"))
+    assert (cfg.n_kv_layers, cfg.n_state_layers) == (1, 2)
+    assert cfg.state_layer_kinds == ("state_space",) * 2
+    assert [cfg.state_index(li) for li in (0, 3)] == [0, 1]
+    assert {k for _, k, *_ in T._operator_leaves(cfg)} == set(cfg.layer_types)
     matrix_kinds = {k for k, (_, m) in T._STATE_LAYERS.items() if m}
     assert set(M._STEP_OF) == set(M._SCAN_OF) == set(S._RUN_TOKENS) == \
         matrix_kinds == {"linear_attention", "state_space"}

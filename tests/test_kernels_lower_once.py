@@ -39,6 +39,11 @@ KERNELS = {
                        WRITE_WALK | {"conv_carry", "ssm_state"}),
     "tiny-mellum2": ({"paged_decode_fused", "expert_stream"},
                      WRITE_WALK | {"expert_stream"}),
+    # layers of one mixer each: 4 hold a slot, 2 K/V, 2 experts (ungated)
+    "tiny-nemotron3": (
+        {"paged_decode_grid", "conv_carry", "ssm_state",
+         "expert_stream_ungated"},
+        WRITE_WALK | {"conv_carry", "ssm_state", "expert_stream_ungated"}),
 }
 KV_KERNELS = {"paged_decode_fused", "paged_kv_write", "paged_decode_grid",
               "paged_latent_write"}
@@ -67,8 +72,10 @@ def test_a_step_holds_each_kernel_once_and_calls_it_a_layer(name, unique):
     assert set(modules) == KERNELS[name][not unique]
     # a model of mixed windows has two signatures of each K/V kernel:
     # the full layers' pool and window 0, the rings' pool and the window
+    routed = (cfg.layer_types.count("experts") if cfg.mixer_only
+              else cfg.n_layers)
     layers = {k: cfg.n_kv_layers if k in KV_KERNELS
-              else cfg.n_layers if k == "expert_stream"
+              else routed if k.startswith("expert_stream")
               else cfg.n_state_layers for k in modules}
     assert modules == {k: 1 + (cfg.mixed_windows and k in KV_KERNELS)
                        for k in modules}

@@ -500,6 +500,10 @@ def test_what_the_mapping_cannot_serve_is_an_error(what, hf, match):
 
 OTHERS = ["tiny-mistral", "tiny-olmoe", "tiny-pangu", "tiny-lfm2",
           "tiny-qwen3next", "tiny-granite4h"]
+# the families whose step programs are pinned: the six above, this
+# file's own (its hashes are what the tree gave at 518e737, PR 50) and
+# the newest, pinned as PR 51 left it
+PINNED = OTHERS + ["tiny-mellum2", "tiny-nemotron3"]
 
 
 @pytest.mark.parametrize("key", ["rope_parameters", "mlp_layer_types"])
@@ -513,16 +517,20 @@ def test_the_keys_stay_an_error_for_every_other_architecture(name, key):
 # -- the older families' programs -------------------------------------------
 
 @pytest.mark.parametrize("kernels", [True, False], ids=["pallas", "xla"])
-@pytest.mark.parametrize("name", OTHERS)
+@pytest.mark.parametrize("name", PINNED)
 def test_an_older_familys_step_program_is_the_parents(name, kernels):
-    """The six families the benchmark held before this one take the path
-    they took: their step programs' text hashes as it did at the parent
-    commit (a921fbd: the file holds what `_step_program.step_program`
-    gave with that tree on the path; the six `pallas` entries are PR
-    49's, whose kernels went behind a jit of their own, the `xla` ones
-    came out as they were). A PR that changes a family's program ON
-    PURPOSE re-captures the file (`PYTHONPATH=. python
-    tests/test_mellum2.py`) and says so; JAX's version changes it too."""
+    """The families the benchmark holds take the path they took: their
+    step programs' text hashes as it did at the parent commit (the file
+    holds what `_step_program.step_program` gave with that tree on the
+    path; the `pallas` entries are PR 49's, whose kernels went behind a
+    jit of their own). PR 51 threaded groups of B and C through
+    ops/pallas/ssm_state.py: every `pallas` entry, Granite's too, came
+    out as it was (what the chip runs); `tiny-granite4h/xla` alone was
+    re-captured (the loop over rows in XLA, the CPU's oracle, reads B
+    and C a head now), and `tiny-nemotron3` is pinned as that PR left
+    it. A PR that changes a family's program ON PURPOSE re-captures the
+    file (`PYTHONPATH=. python tests/test_mellum2.py`) and says so;
+    JAX's version changes it too."""
     text = step_program(name, kernels)[1]
     pinned = json.loads(HASHES.read_text())
     assert hashlib.sha256(text.encode()).hexdigest()[:16] == \
@@ -533,4 +541,4 @@ if __name__ == "__main__":
     HASHES.write_text(json.dumps({
         f"{name}/{'pallas' if k else 'xla'}": hashlib.sha256(
             step_program(name, k)[1].encode()).hexdigest()[:16]
-        for name in OTHERS for k in (True, False)}, indent=1) + "\n")
+        for name in PINNED for k in (True, False)}, indent=1) + "\n")

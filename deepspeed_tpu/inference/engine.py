@@ -532,6 +532,8 @@ class InferenceEngine:
                 state_slots=self.config.max_tracked_sequences,
                 ring_pool_blocks=rings * ring_blocks + 1,
             ))
+            sp.set(**M.kv_write_ids(self.cache, model_config, self.mesh,
+                                    self._use_kernel))
             if rings:
                 pools = pool_bytes(model_config, self.config, dtype)
                 held = model_config.ring_layers

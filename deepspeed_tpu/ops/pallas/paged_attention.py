@@ -50,6 +50,13 @@ the arguments, dtype and shape it is handed, nothing else):
   whole lanes, so head dims other than 64 below 128, or 64 with an odd
   KV count, or under a mesh or int8, where pools are not packed).
 
+paged_kv_write is the shared-table program's cache store, once a K/V
+layer before the walk: a row goes to cache[blk, off] by ONE DMA of its
+own bytes, HBM to HBM ([blk, off] are the pool's two untiled dims); no
+block passes through VMEM. A pool whose slot is not whole tiles
+(kv_write_path: the same tile rule as the walk's, _whole_tiles) keeps
+the read-modify-write of the slot's block.
+
 int8 per-block KV quantization (docs/paged_attention.md): pools may
 hold int8 codes with a per-block [block_size, KV] f32 scale tile
 riding the same index maps — dequant fuses into the attention inner
@@ -345,6 +352,40 @@ def kv_pack(kv_heads: int, head_dim: int) -> int:
     sharding are per KV head); everything below reads the packing off
     the shapes it is handed."""
     return 2 if head_dim == 64 and kv_heads % 2 == 0 else 1
+
+
+def _whole_tiles(kv_heads: int, head_dim: int, itemsize: int) -> bool:
+    """Whether the trailing (KV, D) dims of a pool are whole tiles of
+    its HBM layout, so that Mosaic takes a manual DMA of one block
+    (.at[blk], the walks) or of one slot's row (.at[blk, off],
+    paged_kv_write). THE tile rule, from AOT compiles for v5e, libtpu
+    0.0.34, KV 1-48 at D 128 and 384 in int8, bf16 and float32 (PR 52):
+    D fills whole lanes ("Slice shape along dimension 3 must be aligned
+    to tiling (128), but is 64"; a scale pool's [.., 1, KV] view fails
+    here), and KV is a multiple of the layout's sublane tile ("dimension
+    2 ... tiling (2) / (4) / (8)"), which is the power of two covering
+    KV, at most 8 and at least 4 / itemsize (int8 4, bf16 2), or 1 for
+    every KV of a 32-bit pool of exactly 128 lanes."""
+    if head_dim % 128:
+        return False
+    if itemsize == 4 and head_dim == 128:
+        return True
+    tile = max(min(8, pl.next_power_of_2(kv_heads)), 4 // itemsize)
+    return kv_heads % tile == 0
+
+
+def kv_write_path(pool_shape, dtype) -> str:
+    """How paged_kv_write reaches a pool [NBLK, bs, KV, D] of this shape
+    and dtype: "rows" (a row's own bytes DMA'd to its slot,
+    _kv_write_rows_kernel) where a slot's (KV, D) is whole tiles
+    (_whole_tiles), else "blocks" (the slot's whole block read, patched
+    in VMEM and written back, _kv_write_blocks_kernel: what Mosaic
+    refuses as a row copy the BlockSpec pipeline pads). Static, from
+    what the call can see and nothing else; the engine's init.pool span
+    reports it a pool (`kv_write`)."""
+    _, _, KV, D = pool_shape
+    rows = _whole_tiles(KV, D, jnp.dtype(dtype).itemsize)
+    return "rows" if rows else "blocks"
 
 
 def _packing(q, k_cache) -> int:
@@ -1187,17 +1228,12 @@ def _walks_live_blocks(qg, k_cache) -> bool:
     it cannot stays on the (S, NB) BlockSpec grid. The walk DMAs one
     whole cache block (bs, KV, D) out of the HBM arena by hand, and
     Mosaic takes such a slice only if its trailing dims fill whole
-    tiles (AOT compiles for v5e, libtpu 0.0.34: "Slice shape along
-    dimension 3 must be aligned to tiling (128)" at D = 64; "dimension
-    2 ... tiling (2) / (4) / (8)" for 16-bit pools of 1, 3, 5-7 or 12
-    KV heads, whose sublane tile is the power of two covering KV, at
-    most 8; 32-bit pools pass at any KV). BlockSpec tiles are padded by
-    the pipeline instead, so the grid takes every shape."""
+    tiles (_whole_tiles: not D = 64, not 16-bit pools of 1, 3, 5-7 or
+    12 KV heads). BlockSpec tiles are padded by the pipeline instead,
+    so the grid takes every shape."""
     _, bs, KV, D = k_cache.shape
     itemsize = k_cache.dtype.itemsize
-    if D % 128 or itemsize not in (2, 4):
-        return False
-    if itemsize == 2 and KV not in (2, 4) and KV % 8:
+    if itemsize not in (2, 4) or not _whole_tiles(KV, D, itemsize):
         return False
     S, _, Gp, _ = qg.shape
     rg = _group_rows(Gp, S) * Gp
@@ -1378,20 +1414,57 @@ def _decode_fused(q, k_cache, v_cache, block_table, ctx_lens, k_new, v_new,
 # paged KV write
 # ---------------------------------------------------------------------------
 
-def _kv_write_kernel(
+def _kv_write_rows_kernel(
+    slots_ref, kn_any, vn_any, ck_in, cv_in, ck_out, cv_out, sem,
+    *, block_size: int, n_blocks: int,
+):
+    """kv_write_path "rows": every live row of the call goes to its
+    cache slot by ONE DMA a pool, HBM to HBM. Rows and pools stay where
+    they are (memory_space=ANY); nothing passes through VMEM. The pool
+    is [NBLK, bs, KV, D], so [blk, off] indexes two UNTILED dims and a
+    copy is the slot's whole (KV, D) tile set, 1-4 KB. One grid step: a
+    loop starts the copies, a second waits them, all on ONE DMA
+    semaphore (the copies of a call are of one size, so a wait takes
+    whichever has landed, and when every started copy has been waited
+    every row has). The aliased inputs are the outputs' buffers and are
+    not read."""
+    del ck_in, cv_in
+
+    def copies(t):
+        slot = slots_ref[t]
+        blk = _arena_block(slot // block_size, n_blocks)
+        off = slot % block_size
+        return (pltpu.make_async_copy(kn_any.at[t], ck_out.at[blk, off], sem),
+                pltpu.make_async_copy(vn_any.at[t], cv_out.at[blk, off], sem))
+
+    def each_live_row(do):
+        def row(t, carry):
+            @pl.when(slots_ref[t] >= 0)
+            def _():
+                for copy in copies(t):
+                    do(copy)
+            return carry
+
+        jax.lax.fori_loop(0, slots_ref.shape[0], row, 0)
+
+    each_live_row(lambda copy: copy.start())
+    each_live_row(lambda copy: copy.wait())
+
+
+def _kv_write_blocks_kernel(
     slots_ref, kn_ref, vn_ref, ck_in, cv_in, ck_out, cv_out,
     *, block_size: int, n_blocks: int,
 ):
-    """Read-modify-write one token row into its cache block.
-
-    XLA's scatter lowering cost ~3ms per call on TPU regardless of size
-    (measured on an earlier setup; not re-measured); at 2 scatters x
-    n_layers per decode step that dominated the engine. This kernel
-    instead RMWs whole cache blocks through VMEM: tokens are pre-sorted
-    by slot so consecutive grid steps hitting the same block keep it
-    resident, and the block is copied from the aliased input only on
-    first visit (a later copy would erase rows written by earlier
-    same-block steps)."""
+    """kv_write_path "blocks": read-modify-write one token row into its
+    cache block, for the pools whose rows Mosaic refuses as a DMA
+    (_whole_tiles). Whole cache blocks pass through VMEM, one grid step
+    a token: tokens are pre-sorted by slot so consecutive grid steps
+    hitting the same block keep it resident, and the block is copied
+    from the aliased input only on first visit (a later copy would
+    erase rows written by earlier same-block steps). A call moves its
+    DISTINCT blocks' bytes in and out, ~200 x its rows' at 128 tokens a
+    block (0.16 ms a dense layer's call on a v5e, PERF.md section 6,
+    PR 52)."""
     t = pl.program_id(0)
     slot = slots_ref[t]
 
@@ -1420,9 +1493,20 @@ def _kv_write_kernel(
 
 def paged_kv_write(cache_k, cache_v, k_new, v_new, flat_slots):
     """Write [T, KV, D] new KV rows into [NBLK, bs, KV, D] caches at flat
-    slot ids [T] (block*bs + offset; -1 rows are dropped). The TPU-native
+    slot ids [T] (block*bs + offset; -1 rows are dropped; a slot past
+    the arena lands in its last block, _arena_block). The TPU-native
     fused-cache-store (ref: inference/v2/kernels/ragged_ops/
     linear_blocked_kv_rotary/ — rotary is applied upstream in XLA).
+    A row moves by one DMA of its own bytes and no block is read or
+    written back, so a call costs its rows and not the blocks they land
+    in; a pool shape Mosaic refuses that copy of keeps the block
+    read-modify-write (kv_write_path picks, from the pool's shape and
+    dtype alone). Either way bytes are copied: the pools after a call
+    are bit-identical to the jnp scatter's (inference/model.
+    _write_kv_xla) in every dtype. The live slots of ONE call are
+    distinct (a sequence's positions are, and sequences share no block
+    they write); two rows with one slot land in no promised order, as
+    in the scatter.
     Rows of a packed pool (kv_pack) may come as [T, KV, D] of the model's
     heads: the same bytes as the pool's [T, KV / 2, 128]."""
     return _kv_write(cache_k, cache_v, k_new, v_new, flat_slots, interpret())
@@ -1434,52 +1518,65 @@ def _kv_write(cache_k, cache_v, k_new, v_new, flat_slots, interpreted: bool):
     T = flat_slots.shape[0]
     # a packed pool (kv_pack) takes its rows in its own shape
     k_new, v_new = _pack_rows(k_new, cache_k), _pack_rows(v_new, cache_v)
-    order = jnp.argsort(flat_slots)
-    slots = flat_slots[order].astype(jnp.int32)
-    kn = k_new[order]
-    vn = v_new[order]
+    slots = flat_slots.astype(jnp.int32)
+    if kv_write_path(cache_k.shape, cache_k.dtype) == "rows":
+        kernel = _kv_write_rows_kernel
+        hbm = pl.BlockSpec(memory_space=pl.ANY)
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(1,),
+            in_specs=[hbm] * 4,
+            out_specs=[hbm] * 2,
+            scratch_shapes=[pltpu.SemaphoreType.DMA(())],
+        )
+    else:
+        kernel = _kv_write_blocks_kernel
+        order = jnp.argsort(slots)
+        slots, k_new, v_new = slots[order], k_new[order], v_new[order]
 
-    def cache_index(t, slots_ref):
-        # clip both ends: negatives are pad rows, and an over-range slot
-        # (caller contract bug) must stay inside the arena
-        return (_arena_block(slots_ref[t] // bs, NBLK), 0, 0, 0)
+        def cache_index(t, slots_ref):
+            # clip both ends: negatives are pad rows, and an over-range
+            # slot (caller contract bug) must stay inside the arena
+            return (_arena_block(slots_ref[t] // bs, NBLK), 0, 0, 0)
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(T,),
-        in_specs=[
-            pl.BlockSpec((1, KV, D), lambda t, slots_ref: (t, 0, 0)),
-            pl.BlockSpec((1, KV, D), lambda t, slots_ref: (t, 0, 0)),
-            pl.BlockSpec((1, bs, KV, D), cache_index),
-            pl.BlockSpec((1, bs, KV, D), cache_index),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, bs, KV, D), cache_index),
-            pl.BlockSpec((1, bs, KV, D), cache_index),
-        ],
-        scratch_shapes=[],
-    )
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(T,),
+            in_specs=[
+                pl.BlockSpec((1, KV, D), lambda t, slots_ref: (t, 0, 0)),
+                pl.BlockSpec((1, KV, D), lambda t, slots_ref: (t, 0, 0)),
+                pl.BlockSpec((1, bs, KV, D), cache_index),
+                pl.BlockSpec((1, bs, KV, D), cache_index),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, bs, KV, D), cache_index),
+                pl.BlockSpec((1, bs, KV, D), cache_index),
+            ],
+            scratch_shapes=[],
+        )
     return pl.pallas_call(
-        functools.partial(_kv_write_kernel, block_size=bs, n_blocks=NBLK),
+        functools.partial(kernel, block_size=bs, n_blocks=NBLK),
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct(cache_k.shape, cache_k.dtype),
             jax.ShapeDtypeStruct(cache_v.shape, cache_v.dtype),
         ],
-        # alias caches through: in-place RMW, no copy of the arena
+        # alias caches through: rows land in place, no copy of the arena
         input_output_aliases={3: 0, 4: 1},
         interpret=interpreted,
         name="paged_kv_write",
-    )(slots, kn, vn, cache_k, cache_v)
+    )(slots, k_new, v_new, cache_k, cache_v)
 
 
 def paged_scale_write(k_scale, v_scale, ks_new, vs_new, flat_slots):
     """Write [T, KV] per-row quant scales into the [NBLK, bs, KV] scale
     pools at flat slot ids [T] — the scale half of a quantized
-    paged_kv_write. Rides the SAME RMW kernel through a
-    [NBLK, bs, 1, KV] view (the KV axis lands on the lane dim, so the
-    block tile stays lane-aligned and dtype-generic), and so behind the
-    same jit boundary: the layers' scale writes are one lowering."""
+    paged_kv_write. Rides the SAME entry through a [NBLK, bs, 1, KV]
+    view (the KV axis lands on the lane dim, so the block tile stays
+    lane-aligned and dtype-generic), and so behind the same jit
+    boundary: the layers' scale writes are one lowering. A slot's KV
+    scales are a part of one lane tile, which no DMA addresses, so
+    this view takes kv_write_path "blocks" at every KV under 128."""
     NBLK, bs, KV = k_scale.shape
     ck, cv = paged_kv_write(
         k_scale.reshape(NBLK, bs, 1, KV), v_scale.reshape(NBLK, bs, 1, KV),
@@ -1715,7 +1812,7 @@ def paged_latent_attention_xla(q, pool, block_table, ctx_lens, v_dim: int):
 
 def _latent_write_kernel(slots_ref, new_ref, pool_in, pool_out,
                          *, block_size: int, n_blocks: int):
-    """_kv_write_kernel for the one latent pool: RMW one token row into
+    """_kv_write_blocks_kernel for the one latent pool: RMW one token row into
     its block, the block copied from the aliased input on first visit
     only (tokens arrive sorted by slot)."""
     t = pl.program_id(0)

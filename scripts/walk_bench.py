@@ -1,15 +1,25 @@
 """Standalone chip timing of the shared-table K/V walk (paged_decode_grid,
 its entry included) at the serving cells' shapes: the table of PERF.md
 section 6, PR 50, kept so that the next change to the walk can re-run it.
+--kernel kv_write times the write that goes before it (paged_kv_write, PR 52).
 
   chiprun -- python scripts/walk_bench.py                 # every shape
   chiprun -- python scripts/walk_bench.py mellum_full dense --kinds mixed,groups
   ... --tree .scratch/parent --tag parent   another checkout's kernel (the
                                             parent's, unpacked with git archive)
+  chiprun -- python scripts/walk_bench.py dense --kernel kv_write \
+      --distinct-blocks 128,1               # 128 rows in 128 blocks, then in ONE
+  ... --kernel kv_write --rows 256 --kv-heads 4 --head-dim 128   a shape by hand
 
 A call's rows by --kinds: `mixed` (decode rows beside chunks of 32 rows on
 one table, as the cell's steps are), `groups` (chunks alone), `decode` (rows
 alone), `empty` (every context 0: the entry and the grid steps, no visit).
+A write's rows by --distinct-blocks: `n` puts the call's rows in n blocks of
+the pool, consecutive slots in each (rows: every row a block of its own, a
+step of decode rows; 1: one chunk's rows); `chat` is a chat step, 95 decode
+rows and a 33-row chunk over two blocks. Each chained write lands in other
+blocks than the last, and the last call's pools are compared with the jnp
+scatter's, whole.
 --chain calls run in ONE program, each waiting for the last, so the host's
 ~200 us a dispatch is paid once and not a call; best of 5 x 40 programs. A
 (KV head, block) cost is (a kind's time - `empty`) / (its visits x KV). One
@@ -35,6 +45,7 @@ SHAPES = {
     "qwen3next": dict(rows=256, H=16, KV=2, D=256, pool=1024, NB=32,
                       window=0),
     "granite": dict(rows=128, H=32, KV=8, D=128, pool=1024, NB=32, window=0),
+    "nemotron": dict(rows=256, H=32, KV=2, D=128, pool=1024, NB=32, window=0),
 }
 
 
@@ -87,9 +98,92 @@ def visits(c, ctx, runs):
     return int((end - first)[~riding & (ctx > 0)].sum()), int(grouped)
 
 
+def write_slots(spread, rows, pool, rng):
+    """[rows] flat slots of one write over `pool` blocks, and the blocks
+    it touches: `spread` blocks, consecutive slots in each."""
+    import numpy as np
+
+    if spread == "chat":
+        runs = [1] * 95 + [20, 13]
+        runs += [1] * (rows - sum(runs))
+    else:
+        n = max(int(spread), -(-rows // BLOCK))  # a block holds BLOCK rows
+        runs = [rows // n + (i < rows % n) for i in range(n)]
+    blocks = rng.permutation(pool)[:len(runs)]
+    slots = np.concatenate([
+        b * BLOCK + rng.integers(0, BLOCK - n + 1) + np.arange(n)
+        for b, n in zip(blocks, runs)])
+    return slots.astype(np.int32), len(runs)
+
+
+def bench_kv_write(args, PA, name, c):
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    rows, KV, D = c["rows"], c["KV"], c["D"]
+    pack = PA.kv_pack(KV, D)
+    shape = (c["pool"] + 1, BLOCK, KV // pack, D * pack)
+    key = jax.random.PRNGKey(0)
+    kc = jax.random.normal(key, shape, jnp.bfloat16)
+    vc = jax.random.normal(jax.random.fold_in(key, 1), shape, jnp.bfloat16)
+    kn = jax.random.normal(jax.random.fold_in(key, 2), (rows, KV, D),
+                           jnp.bfloat16)
+    vn = jax.random.normal(jax.random.fold_in(key, 3), (rows, KV, D),
+                           jnp.bfloat16)
+    for spread in args.distinct_blocks.replace("rows", str(rows)).split(","):
+        rng = np.random.default_rng(0)
+        calls = [write_slots(spread, rows, c["pool"], rng)
+                 for _ in range(args.chain)]
+        slots = jnp.asarray(np.stack([s for s, _ in calls]))
+
+        def chain(kc, vc, kn, vn, slots):
+            for i in range(args.chain):
+                kc, vc = PA.paged_kv_write(kc, vc, kn, vn, slots[i])
+            return kc, vc
+
+        # the pools are donated, as the engine's are: a write in place
+        fn = jax.jit(chain, donate_argnums=(0, 1))
+        ref_k, ref_v = kc, vc
+        for i in range(args.chain):  # the scatter, before kc is donated
+            flat = ref_k.reshape(-1, *shape[2:]), ref_v.reshape(-1, *shape[2:])
+            ref_k, ref_v = (
+                f.at[slots[i]].set(n.reshape(rows, *shape[2:])).reshape(shape)
+                for f, n in zip(flat, (kn, vn)))
+        kc, vc = fn(kc + 0, vc + 0, kn, vn, slots)
+        same = bool(jnp.array_equal(kc, ref_k) & jnp.array_equal(vc, ref_v))
+        for _ in range(2):
+            kc, vc = fn(kc, vc, kn, vn, slots)
+        kc.block_until_ready()
+        best = float("inf")
+        for _ in range(5):
+            t0 = time.perf_counter()
+            for _ in range(40):
+                kc, vc = fn(kc, vc, kn, vn, slots)
+            kc.block_until_ready()
+            best = min(best, (time.perf_counter() - t0) / 40 / args.chain)
+        path = getattr(PA, "kv_write_path", lambda *_: "blocks")(
+            shape, jnp.bfloat16)
+        line = dict(tag=args.tag, kernel="kv_write", shape=name, rows=rows,
+                    kv=KV // pack, head_dim=D * pack, distinct_blocks=spread,
+                    blocks=calls[0][1], path=path, us=round(best * 1e6, 1),
+                    same_as_scatter=same,
+                    device=jax.devices()[0].device_kind)
+        print(json.dumps(line), flush=True)
+        with open("chiprun_out/walk_bench.jsonl", "a") as f:
+            f.write(json.dumps(line) + "\n")
+
+
 def main():
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("shapes", nargs="*", default=list(SHAPES))
+    ap.add_argument("--kernel", choices=("walk", "kv_write"), default="walk")
+    ap.add_argument("--rows", type=int, help="kv_write: a shape by hand, "
+                    "with --kv-heads and --head-dim, in place of a named one")
+    ap.add_argument("--kv-heads", type=int, default=8)
+    ap.add_argument("--head-dim", type=int, default=128)
+    ap.add_argument("--distinct-blocks", default="rows,1,chat",
+                    help="kv_write: blocks a call's rows land in, a list")
     ap.add_argument("--kinds", default="mixed,groups,decode,empty")
     ap.add_argument("--tree", default=os.path.dirname(
         os.path.dirname(os.path.abspath(__file__))))
@@ -107,6 +201,14 @@ def main():
     assert os.path.abspath(PA.__file__).startswith(
         os.path.abspath(args.tree)), PA.__file__
     os.makedirs("chiprun_out", exist_ok=True)
+    if args.kernel == "kv_write":
+        shapes = {n: SHAPES[n] for n in args.shapes}
+        if args.rows:
+            shapes = {"by_hand": dict(rows=args.rows, KV=args.kv_heads,
+                                      D=args.head_dim, pool=704)}
+        for name, c in shapes.items():
+            bench_kv_write(args, PA, name, c)
+        return
     for name in args.shapes:
         c = SHAPES[name]
         rows, H, KV, D = c["rows"], c["H"], c["KV"], c["D"]

@@ -528,7 +528,12 @@ def test_an_older_familys_step_program_is_the_parents(name, kernels):
     out as it was (what the chip runs); `tiny-granite4h/xla` alone was
     re-captured (the loop over rows in XLA, the CPU's oracle, reads B
     and C a head now), and `tiny-nemotron3` is pinned as that PR left
-    it. A PR that changes a family's program ON PURPOSE re-captures the
+    it. PR 52 re-captured the four `pallas` entries whose K/V pools take
+    paged_kv_write's row copies (Mistral, OLMoE, Qwen3-Next, Mellum 2:
+    the write is another kernel body; the tiny LFM2, Granite and
+    Nemotron-H pools are not whole tiles and keep the block path, and no
+    `xla` entry moved). A PR that changes a family's program ON PURPOSE
+    re-captures the
     file (`PYTHONPATH=. python tests/test_mellum2.py`) and says so;
     JAX's version changes it too."""
     text = step_program(name, kernels)[1]

@@ -97,7 +97,11 @@ PHASES = {"tick": "tick_s", "admit": "admit_s", "select": "select_s",
           "readback": "readback_wait_s", "accept": "accept_s"}
 # an iteration whose time outside `readback` exceeds this is counted in
 # counters["slow_iterations"] and leaves an always-kept span: a stalled
-# host loop names itself even with tracing off
+# host loop names itself even with tracing off. The seconds a run loses
+# in one piece are INSIDE `readback`, and a 90 ms pause of a 20 ms
+# iteration is under this mark: those fall to the stall rule of
+# `profiler.Phases.end()` (STALL_FACTOR, STALL_FLOOR_NS beside it),
+# which counts in counters["stall_iterations"] and keeps the same span
 SLOW_ITERATION_NS = 50_000_000
 # finished requests whose TTFT/TPOT metrics() percentiles are taken over
 LATENCY_WINDOW = 4096
@@ -238,6 +242,15 @@ class ServingScheduler:
             # sum over first admissions of admit_t - arrival
             "queue_wait_s": 0.0,
             "slow_iterations": 0,
+            # the stall rule (profiler.Phases.end: an iteration of over
+            # three times the loop's typical one): how many, the seconds
+            # they took beyond a typical iteration (what a window lost
+            # to them), and the part of that inside `readback` (beyond
+            # a typical readback wait: the rest is the host's own)
+            "stall_iterations": 0, "stall_s": 0.0, "stall_readback_s": 0.0,
+            # Python's collector inside iterations, on the phases' clock
+            # (profiler's gc.callbacks hook): seconds and collections
+            "gc_s": 0.0, "gc_collections": 0,
             # (token, expert) assignments the batched tokens made in ONE
             # routed layer: batched tokens x top-k (0 for a dense model);
             # over steps and experts, the rows an expert sees a step
@@ -319,9 +332,11 @@ class ServingScheduler:
             "state_step_kernel_steps": 0,
         }
         self._phases = profiler.Phases("sched", "iteration", PHASES,
-                                       sums=self.counters)
+                                       sums=self.counters, wait="readback")
         self._iteration = 0
         self._it_rows, self._it_kind, self._it_delay0 = 0, "idle", 0.0
+        # the step run() launched ahead of this iteration's readback
+        self._it_ahead: Optional[_Step] = None
         self.spec_stats: Dict[str, float] = {
             "steps": 0, "verified_chunks": 0, "draft_tokens": 0,
             "accepted_tokens": 0, "draft_collapsed_steps": 0,
@@ -1651,23 +1666,56 @@ class ServingScheduler:
         self._iteration += 1
         self._it_rows, self._it_kind = 0, "idle"
         self._it_delay0 = self.fault_delay_s
+        self._it_ahead = None
         self._phases.begin(phase, iteration=self._iteration)
 
     def _end_iteration(self) -> None:
-        """Close the iteration's phases; count and keep it when the
-        host held the loop (everything but the readback wait, plus any
-        injected straggler time) for over SLOW_ITERATION_NS."""
-        ph = self._phases
-        total = ph.end(rows=self._it_rows, kind=self._it_kind)
+        """Close the iteration's phases and book the collector's time.
+        Two rules count an iteration and keep it as ONE always-kept
+        span, `sched.slow_iteration`, tracing on or off: `host`, when
+        the host held the loop (everything but the readback wait, plus
+        any injected straggler time) for over SLOW_ITERATION_NS, and
+        `stall`, when the whole iteration took over three times what
+        an iteration that launches a step typically takes
+        (profiler.Phases.end). The span says what a later reader needs
+        to tell whose the time was (docs/tracing.md); a stall also
+        logs one line."""
+        ph, c = self._phases, self.counters
+        total = ph.end(feed=self._it_kind != "idle",
+                       rows=self._it_rows, kind=self._it_kind)
+        if ph.gc_n:
+            c["gc_s"] += ph.gc_ns * 1e-9
+            c["gc_collections"] += ph.gc_n
         delay = self.fault_delay_s - self._it_delay0
-        if total - ph.ns["readback"] + int(delay * 1e9) > SLOW_ITERATION_NS:
-            self.counters["slow_iterations"] += 1
-            t1 = time.perf_counter_ns()
-            profiler.record(
-                "sched.slow_iteration", t1 - total, t1, always=True,
-                iteration=self._iteration, rows=self._it_rows,
-                kind=self._it_kind, fault_delay_s=delay,
-                **{f"{p}_ms": ns * 1e-6 for p, ns in ph.ns.items()})
+        slow = total - ph.ns["readback"] + int(delay * 1e9) > SLOW_ITERATION_NS
+        if not (slow or ph.excess_ns):
+            return
+        # did the device run through it? The step launched ahead of
+        # this iteration's readback is done by now if it did: asked
+        # once, of an array the loop already holds
+        ahead_ready = None
+        if self._it_ahead is not None:
+            toks = [p.tok_dev for p in self._it_ahead.parts
+                    if p.tok_dev is not None]
+            if toks:
+                ahead_ready = bool(toks[-1].is_ready())
+        if slow:
+            c["slow_iterations"] += 1
+        if ph.excess_ns:
+            c["stall_iterations"] += 1
+            c["stall_s"] += ph.excess_ns * 1e-9
+            c["stall_readback_s"] += ph.excess_wait_ns * 1e-9
+            ph.log_stall(
+                f"iteration {self._iteration}",
+                f"step ahead ready={ahead_ready}; {self._it_rows} rows, "
+                f"{len(self.active)} active, {len(self.waiting)} waiting")
+        ph.keep(
+            "sched.slow_iteration", iteration=self._iteration,
+            rows=self._it_rows, kind=self._it_kind, fault_delay_s=delay,
+            rule="+".join(r for r, on in (("host", slow),
+                                          ("stall", ph.excess_ns)) if on),
+            ahead_ready=ahead_ready, waiting=len(self.waiting),
+            active=len(self.active))
 
     def run(self, tick=None) -> None:
         """Drive until idle. tick(scheduler), when given, runs once per
@@ -1694,7 +1742,7 @@ class ServingScheduler:
                 if prev is not None:
                     looked = self._can_look_ahead(prev)
                     if looked:
-                        st = self._dispatch(ahead_of=prev)
+                        st = self._it_ahead = self._dispatch(ahead_of=prev)
                     # with st launched, the readback overlaps its compute
                     self._finalize(prev)
                     prev = None

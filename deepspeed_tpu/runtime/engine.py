@@ -439,7 +439,8 @@ class DeepSpeedTPUEngine:
         self.timers = SynchronizedWallClockTimer()
         self.tput = ThroughputTimer(batch_size=config.train_batch_size)
         # one train_batch call, tiled: train.batch > train.prepare /
-        # launch / readback / post (docs/tracing.md)
+        # launch / readback / post; a stalled one also leaves
+        # train.slow_batch (docs/tracing.md)
         self._phases = profiler.Phases(
             "train", "batch", ("prepare", "launch", "readback", "post"))
         self.monitor = MonitorMaster(config.monitor)
@@ -1993,11 +1994,17 @@ class DeepSpeedTPUEngine:
         [gas, train_batch_size/gas, ...]; returns host metrics (synced).
         """
         ph = self._phases
-        ph.begin("prepare", step=self.global_steps + 1)
+        step = self.global_steps + 1
+        ph.begin("prepare", step=step)
         try:
             return self._train_batch(batch)
         finally:
             ph.end()
+            if ph.excess_ns:
+                # over three times a typical step (profiler.Phases.end):
+                # kept and logged, tracing on or off
+                ph.keep("train.slow_batch", step=step)
+                ph.log_stall(f"step {step}")
 
     def _train_batch(self, batch) -> Dict[str, float]:
         ph = self._phases

@@ -17,10 +17,15 @@ means `enable()` was called or a profiler session is open; inactive,
 `span()` is one flag read. `always=True` spans (set-up phases and rare
 events: dozens per process) record to the buffer whatever the state,
 in a buffer of their own so per-iteration spans never evict them.
+
+`Phases` also holds the one stall rule of the loops that use it (an
+iteration that takes three times what its loop typically takes), and
+the evidence a kept span of such an iteration carries: the collector's
+time on the spans' clock (one `gc.callbacks` hook, process-wide).
 """
 
 import contextlib
-import functools
+import gc
 import itertools
 import json
 import os
@@ -31,10 +36,37 @@ from typing import Any, Dict, Iterable, Iterator, List, NamedTuple, Optional, Se
 
 import jax
 
+from .logging import logger
+
 PREFIX = "ds."
 HOT_SPANS = 65536    # per-iteration spans kept (minutes of serving)
 KEPT_SPANS = 4096    # always=True spans kept
 STAGE_GAP_NS = 1_000_000
+
+# The stall rule of `Phases.end()` (docs/tracing.md "The serving loop").
+# An iteration is a stall when it takes over STALL_FACTOR x what its
+# loop typically takes (PERF.md section 7 asked for this mark: the
+# iterations of a loop whose program IS the iteration lie within a few
+# percent of each other, and the pauses looked for are of 4 x and up) ...
+STALL_FACTOR = 3
+# ... AND exceeds it by more than this: a loop of microseconds (the
+# CPU lane, an idle pass) trebles at every hiccup of the OS, and a
+# pause under a few milliseconds is under a third of the shortest
+# serving program (13.8 ms). Also what a full collection has to take
+# to leave a `host.gc` span.
+STALL_FLOOR_NS = 5_000_000
+# iterations a loop's pace is learned from before any is judged: their
+# mean less the largest of them (a loop's first pass compiles, or
+# faults its pages in); after them the pace follows at 1 / 2**STALL_EMA_SHIFT
+# an iteration (16 iterations: a third of a second of serving)
+STALL_MIN_SEEN = 8
+STALL_EMA_SHIFT = 4
+# this many stalls in a row are no exception but the loop's new pace
+# (longer prompts, a wider batch): it is learned anew from there
+STALL_RESEED = 8
+# a loop logs a line for each of its first stalls, then their count at
+# every power of two: a loop that stalls for good must not fill a log
+STALL_LOG_LINES = 8
 
 # The device scopes of the compiled train step (`jax.named_scope`, so
 # metadata alone: docs/tracing.md "Device scopes of the train step").
@@ -180,20 +212,80 @@ class span:
                            st[-1] if st else 0, self.ids), self.always)
 
 
-def annotate(name: Optional[str] = None, **ids):
-    """Decorator form of `span` (ref: utils/nvtx.py instrument_w_nvtx)."""
+# -- the collector's clock ---------------------------------------------------
 
-    def deco(fn):
-        label = name or fn.__qualname__
+_gc_ns = 0             # nanoseconds inside collections, process-wide
+_gc_n = 0              # collections
+_gc_t0 = 0             # start stamp of the collection in progress
+_gc_last = [0, 0, 0]   # by generation: the stop stamp of its last collection
 
-        @functools.wraps(fn)
-        def wrapped(*a, **kw):
-            with span(label, **ids):
-                return fn(*a, **kw)
 
-        return wrapped
+def _on_gc(phase: str, info: Dict[str, int]) -> None:
+    """The one `gc.callbacks` hook (two calls a collection), stamped
+    with the spans' clock. It runs on whichever thread tripped the
+    collector, between two bytecodes of whatever that thread was doing,
+    so it takes no lock and calls nothing of JAX: the totals are plain
+    ints, and a full collection that takes over STALL_FLOOR_NS goes
+    into the kept buffer as `host.gc` by the deque's own atomic append,
+    whichever loop is running or none (a warm-up, a reference pass)."""
+    global _gc_ns, _gc_n, _gc_t0
+    if phase == "start":
+        _gc_t0 = time.perf_counter_ns()
+        return
+    if not _gc_t0:
+        return  # hooked while this collection ran
+    now = time.perf_counter_ns()
+    d, _gc_t0 = now - _gc_t0, 0
+    _gc_ns += d
+    _gc_n += 1
+    gen = info["generation"]
+    _gc_last[gen] = now
+    if gen == 2 and d > STALL_FLOOR_NS:
+        _kept.append(SpanRecord(
+            "host.gc", now - d, now, next(_next_sid), 0,
+            {"generation": gen, "collected": info["collected"]}))
 
-    return deco
+
+def gc_clock() -> Tuple[int, int]:
+    """(nanoseconds, collections) of Python's collector in this process
+    since the hook went in (the first `Phases` installs it)."""
+    return _gc_ns, _gc_n
+
+
+def gc_generation_since(t0_ns: int) -> Optional[int]:
+    """The highest generation a collection that ended after `t0_ns`
+    (`perf_counter_ns`) collected; None when none did."""
+    for gen in (2, 1, 0):
+        if _gc_last[gen] > t0_ns:
+            return gen
+    return None
+
+
+class _Pace:
+    """What one pass of a loop typically takes: a running estimate in
+    O(1) a pass, with no list and no sort. Nothing until STALL_MIN_SEEN
+    passes were fed; then their mean less the largest; from there an
+    exponential average."""
+
+    __slots__ = ("ns", "seen", "_sum", "_max")
+
+    def __init__(self):
+        self.ns = self.seen = self._sum = self._max = 0
+
+    def feed(self, ns: int) -> None:
+        n = self.seen = self.seen + 1
+        if n > STALL_MIN_SEEN:
+            self.ns += (ns - self.ns) >> STALL_EMA_SHIFT
+            return
+        self._sum += ns
+        if ns > self._max:
+            self._max = ns
+        if n == STALL_MIN_SEEN:
+            self.ns = (self._sum - self._max) // (n - 1)
+
+    def forget(self) -> None:
+        """Learn the pace anew (`ns` stands until it is)."""
+        self.seen = self._sum = self._max = 0
 
 
 class Phases:
@@ -204,12 +296,24 @@ class Phases:
     e.g. ServingScheduler.counters); when tracing is active at
     `begin()` the same stamps become a parent span `<prefix>.<what>`
     with one child `<prefix>.<phase>` per visit. One instance per loop;
-    an iteration begins and ends on one thread."""
+    an iteration begins and ends on one thread.
+
+    `end()` also judges the iteration by the stall rule (STALL_FACTOR,
+    STALL_FLOOR_NS), tracing on or off, and leaves what its owner
+    writes on the span it keeps of a stall: `typical_ns`, `excess_ns`
+    (0 when it was none), `excess_wait_ns` and the collector's `gc_ns`
+    / `gc_n` inside the iteration (`keep()` writes them on a span,
+    `log_stall()` in a line of the log). No `getrusage` beside them:
+    one read an iteration cost 8 us on the benchmark's host, more than
+    everything else here (PERF.md section 6, PR 53)."""
 
     def __init__(self, prefix: str, what: str, phases: Sequence[str],
-                 sums: Optional[Dict[str, float]] = None):
+                 sums: Optional[Dict[str, float]] = None,
+                 wait: Optional[str] = None):
         """phases: their names, or with `sums` a mapping from each
-        name to its key in `sums`."""
+        name to its key in `sums`. wait: the phase in which the host
+        waits for the device; its own pace is kept, so that
+        `excess_wait_ns` is the part of a stall that lies in it."""
         self.sums = sums
         self._key = dict(phases) if sums is not None else {}
         self._what = f"{prefix}.{what}"
@@ -222,11 +326,26 @@ class Phases:
         self._ids: Dict[str, Any] = {}
         self._child_ids: Dict[str, Any] = {}
         self._ann = self._child_ann = None
+        self._wait = wait
+        self._pace, self._wait_pace = _Pace(), _Pace()
+        self._streak = 0
+        self.stalls = 0  # iterations the rule has fired on
+        self.excess_ns = self.excess_wait_ns = 0
+        self.gc_ns = self.gc_n = self._gc_ns0 = self._gc_n0 = 0
+        if _on_gc not in gc.callbacks:
+            gc.callbacks.append(_on_gc)
+
+    @property
+    def typical_ns(self) -> int:
+        """What an iteration of this loop typically takes (0 until
+        STALL_MIN_SEEN were seen); a stall does not move it."""
+        return self._pace.ns
 
     def begin(self, phase: str, **ids) -> None:
         for p in self.ns:
             self.ns[p] = 0
         self._phase = phase
+        self._gc_ns0, self._gc_n0 = _gc_ns, _gc_n
         self._live = _enabled or _is_profiling()
         if self._live:
             self._sid = next(_next_sid)
@@ -249,9 +368,11 @@ class Phases:
         if self._live:
             self._open_child(phase, ids)
 
-    def end(self, **ids) -> int:
+    def end(self, feed: bool = True, **ids) -> int:
         """Close the iteration; returns its nanoseconds (`self.ns` holds
-        the split until the next begin)."""
+        the split until the next begin). feed=False keeps an iteration
+        that is no sample of the loop's pace (it launched nothing) out
+        of the estimate; it is judged all the same."""
         if self._phase is None:
             return 0
         now = time.perf_counter_ns()
@@ -265,7 +386,61 @@ class Phases:
             self._ids.update(ids)
             _append(SpanRecord(self._what, self._t0, now, self._sid,
                                st[-1] if st else 0, self._ids), False)
-        return now - self._t0
+        total = now - self._t0
+        self.gc_ns, self.gc_n = _gc_ns - self._gc_ns0, _gc_n - self._gc_n0
+        pace, typical = self._pace, self._pace.ns
+        if (pace.seen >= STALL_MIN_SEEN and total > STALL_FACTOR * typical
+                and total - typical > STALL_FLOOR_NS):
+            self.excess_ns = excess = total - typical
+            self.excess_wait_ns = 0 if self._wait is None else min(
+                excess, max(0, self.ns[self._wait] - self._wait_pace.ns))
+            self.stalls += 1
+            self._streak += 1
+            if self._streak >= STALL_RESEED:
+                self._streak = 0
+                pace.forget()
+                self._wait_pace.forget()
+        else:
+            self.excess_ns = self.excess_wait_ns = 0
+            if feed:
+                self._streak = 0
+                pace.feed(total)
+                if self._wait is not None:
+                    self._wait_pace.feed(self.ns[self._wait])
+        return total
+
+    def keep(self, name: str, **ids) -> int:
+        """The iteration just ended as an always-kept span on its own
+        stamps, tracing on or off: the owner's ids, every phase's
+        `_ms`, the pace it was held to, and what the collector did
+        inside it."""
+        return record(
+            name, self._t0, self._t, always=True, **ids,
+            **{f"{p}_ms": ns * 1e-6 for p, ns in self.ns.items()},
+            typical_ms=self.typical_ns * 1e-6,
+            excess_ms=self.excess_ns * 1e-6, gc_ms=self.gc_ns * 1e-6,
+            gc_gen=gc_generation_since(self._t0) if self.gc_n else None)
+
+    def log_stall(self, what: str, tail: str = "") -> None:
+        """One warning in the process's own log for the stall just
+        ended, for the first STALL_LOG_LINES of this loop: `sched:
+        <what> took 2134.0 ms (typical 20.1): readback 2113.2, tick
+        1.9, ...; gc 0.0 ms; <tail>`, the phases longest first. Then only their count, at the next
+        and at every power of two."""
+        n, loop = self.stalls, self._what.split(".")[0]
+        if n <= STALL_LOG_LINES:
+            split = ", ".join(
+                f"{p} {ns * 1e-6:.1f}" for p, ns in
+                sorted(self.ns.items(), key=lambda kv: -kv[1]) if ns)
+            logger.warning(
+                f"{loop}: {what} took {sum(self.ns.values()) * 1e-6:.1f} ms "
+                f"(typical {self.typical_ns * 1e-6:.1f}): {split}; gc "
+                f"{self.gc_ns * 1e-6:.1f} ms{tail and '; ' + tail}")
+        elif n == STALL_LOG_LINES + 1 or n & (n - 1) == 0:
+            logger.warning(
+                f"{loop}: {n} stalls so far; past the first "
+                f"{STALL_LOG_LINES} each is kept as a span and counted, "
+                f"not logged")
 
     def _close(self, now: int) -> None:
         d = now - self._t
@@ -335,11 +510,13 @@ def spans(clear: bool = False) -> List[SpanRecord]:
     """Every recorded span, kept and hot, by start time (a parent
     before the child that starts with it)."""
     with _lock:
-        out = sorted(itertools.chain(_kept, _hot),
-                     key=lambda r: (r.t0_ns, -r.t1_ns))
+        # copied in C before any Python runs over them: the collector's
+        # hook appends to _kept between two bytecodes of any thread
+        out = list(_kept) + list(_hot)
         if clear:
             _kept.clear()
             _hot.clear()
+    out.sort(key=lambda r: (r.t0_ns, -r.t1_ns))
     return out
 
 
@@ -421,15 +598,3 @@ def trace(output_dir: str) -> Iterator[None]:
         yield
     finally:
         jax.profiler.stop_trace()
-
-
-def capture_step_trace(engine, batch, output_dir: str, steps: int = 3) -> str:
-    """Profile `steps` engine steps (first call compiles OUTSIDE the
-    trace so the capture shows steady-state execution). Returns the
-    trace directory for `tensorboard --logdir`."""
-    engine.train_batch(batch)  # compile + warmup outside the trace
-    with trace(output_dir):
-        for i in range(steps):
-            with jax.profiler.StepTraceAnnotation("train", step_num=i):
-                engine.train_batch(batch)
-    return output_dir

@@ -14,7 +14,7 @@ from deepspeed_tpu.runtime.debug import (
     check_cross_host_divergence,
     params_fingerprint,
 )
-from deepspeed_tpu.utils.profiler import annotate, capture_step_trace, trace
+from deepspeed_tpu.utils.profiler import trace
 
 # interpreter-/compile-heavy: excluded from the fast lane (-m 'not slow')
 pytestmark = pytest.mark.slow
@@ -43,19 +43,6 @@ def data(batch=16, seq=33, seed=0):
 
 
 class TestProfilerTrace:
-    def test_capture_step_trace_writes_xplane(self, tmp_path):
-        engine = build_engine()
-        out = capture_step_trace(engine, data(), str(tmp_path / "trace"), steps=2)
-        planes = glob.glob(os.path.join(out, "**", "*.xplane.pb"), recursive=True)
-        assert planes, os.listdir(out)
-
-    def test_annotate_runs(self):
-        @annotate("my_region")
-        def f(x):
-            return x + 1
-
-        assert f(1) == 2
-
     def test_trace_ctx(self, tmp_path):
         with trace(str(tmp_path / "t")):
             jnp.ones((8,)).sum().block_until_ready()

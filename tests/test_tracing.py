@@ -2,10 +2,14 @@
 spans, phase clocks and the always-on time sums they feed in the
 serving loop, the warm-up and the train step."""
 
+import contextlib
+import gc
 import glob
+import logging
 import os
 import threading
 import time
+import tracemalloc
 
 import jax
 import numpy as np
@@ -29,6 +33,39 @@ def clean_buffer():
 
 def names(records):
     return [r.name for r in records]
+
+
+def kept(name):
+    return [r for r in profiler.spans() if r.name == name]
+
+
+def churn(cycles=300_000):
+    """Make that many reference cycles and have the collector find
+    them: a full collection of tens of milliseconds."""
+    junk = []
+    for _ in range(cycles):
+        cell = []
+        cell.append(cell)
+        junk.append(cell)
+    del junk, cell
+    return gc.collect()
+
+
+@contextlib.contextmanager
+def logged():
+    """The messages the package's logger is handed while open (it
+    writes to the standard output it was created with and does not
+    propagate, so pytest's own capture sees nothing of it)."""
+    from deepspeed_tpu.utils.logging import logger
+
+    got = []
+    handler = logging.Handler()
+    handler.emit = lambda rec: got.append(rec.getMessage())
+    logger.addHandler(handler)
+    try:
+        yield got
+    finally:
+        logger.removeHandler(handler)
 
 
 class TestSpans:
@@ -101,18 +138,6 @@ class TestSpans:
         for tag in ("a", "b"):
             assert recs[f"{tag}.inner"].parent == recs[f"{tag}.outer"].sid
             assert recs[f"{tag}.outer"].parent == 0
-
-    def test_annotate_is_the_decorator_form(self):
-        @profiler.annotate("my_region", rid=3)
-        def f(x):
-            return x + 1
-
-        assert f(1) == 2 and profiler.spans() == []
-        profiler.enable()
-        assert f(2) == 3
-        profiler.disable()
-        (rec,) = profiler.spans()
-        assert rec.name == "my_region" and rec.ids == {"rid": 3}
 
     def test_phases_tile_the_iteration_and_feed_the_sums(self):
         sums = {"a_s": 0.0, "b_wait_s": 0.0}
@@ -207,6 +232,179 @@ class TestSpans:
         assert found[0].duration_ns >= 1e6
 
 
+class Clock:
+    """`profiler.time` for a test of the rule: a clock that moves only
+    when the test says so, so no pause of a shared machine is in it."""
+
+    def __init__(self):
+        self.t = 1_000
+
+    def perf_counter_ns(self):
+        return self.t
+
+    def ms(self, ms):
+        self.t += int(ms * 1e6)
+
+
+@pytest.fixture
+def clock(monkeypatch):
+    c = Clock()
+    monkeypatch.setattr(profiler, "time", c)
+    return c
+
+
+def one_pass(ph, clock, a_ms, wait_ms=0.0, **kw):
+    ph.begin("a")
+    clock.ms(a_ms)
+    ph.mark("wait")
+    clock.ms(wait_ms)
+    return ph.end(**kw)
+
+
+class TestStallRule:
+    """`Phases.end()` judges an iteration against what its loop
+    typically takes (docs/tracing.md "The serving loop")."""
+
+    def make(self):
+        return profiler.Phases("loop", "pass", ("a", "wait"), wait="wait")
+
+    def test_the_first_iterations_never_fire(self, clock):
+        ph = self.make()
+        for i in range(profiler.STALL_MIN_SEEN):
+            # the third takes a hundred times the others
+            one_pass(ph, clock, 2000.0 if i == 2 else 20.0)
+            assert ph.excess_ns == 0 and ph.stalls == 0
+        # the pace they leave is theirs without the largest
+        assert ph.typical_ns == 20_000_000
+
+    def test_a_stall_is_over_three_times_the_pace_and_does_not_move_it(
+            self, clock):
+        ph = self.make()
+        for _ in range(20):
+            one_pass(ph, clock, 18.0, 2.0)
+        typical = ph.typical_ns
+        assert typical == 20_000_000
+        one_pass(ph, clock, 18.0, 41.9)       # 59.9 ms: under 3 x
+        assert ph.excess_ns == 0
+        assert 0 < ph.typical_ns - typical < 3_000_000  # it was fed
+        typical = ph.typical_ns
+        total = one_pass(ph, clock, 108.0, 2.0)
+        assert ph.excess_ns == total - typical == 110_000_000 - typical
+        assert ph.excess_wait_ns == 0         # the wait was a typical one
+        assert ph.typical_ns == typical and ph.stalls == 1
+        one_pass(ph, clock, 18.0, 2002.0)     # the same inside the wait
+        assert ph.excess_ns == 2_020_000_000 - typical
+        assert 0 <= ph.excess_ns - ph.excess_wait_ns < 3_000_000
+        assert ph.typical_ns == typical and ph.stalls == 2
+        one_pass(ph, clock, 18.0, 2.0)
+        assert ph.excess_ns == ph.excess_wait_ns == 0
+
+    def test_a_loop_of_microseconds_needs_the_floor_too(self, clock):
+        ph = self.make()
+        for _ in range(20):
+            one_pass(ph, clock, 0.05)
+        one_pass(ph, clock, 4.9)              # 98 x, under the floor
+        assert ph.excess_ns == 0
+        one_pass(ph, clock, 6.0)
+        assert ph.excess_ns > profiler.STALL_FLOOR_NS and ph.stalls == 1
+
+    def test_a_steady_loop_of_200_fires_nothing(self, clock):
+        ph = self.make()
+        rng = np.random.default_rng(0)
+        for ms in rng.uniform(14.0, 30.0, 200):   # up to 2.1 x apart
+            one_pass(ph, clock, float(ms), 1.0)
+            assert ph.excess_ns == 0
+        assert ph.stalls == 0 and 18e6 < ph.typical_ns < 28e6
+
+    def test_an_unfed_iteration_is_judged_and_leaves_the_pace(self, clock):
+        ph = self.make()
+        for _ in range(10):
+            one_pass(ph, clock, 20.0)
+        typical = ph.typical_ns
+        for _ in range(10):                   # idle passes: no sample
+            one_pass(ph, clock, 0.01, feed=False)
+        assert ph.typical_ns == typical and ph.excess_ns == 0
+        one_pass(ph, clock, 90.0, feed=False)
+        assert ph.excess_ns == 90_000_000 - typical
+
+    def test_stalls_in_a_row_are_the_new_pace(self, clock):
+        ph = self.make()
+        for _ in range(10):
+            one_pass(ph, clock, 5.0)
+        for _ in range(profiler.STALL_RESEED):    # the prompts got longer
+            one_pass(ph, clock, 40.0)
+            assert ph.excess_ns == 35_000_000
+        for _ in range(profiler.STALL_MIN_SEEN):  # learned anew, unjudged
+            one_pass(ph, clock, 40.0)
+            assert ph.excess_ns == 0
+        assert ph.typical_ns == 40_000_000
+        assert ph.stalls == profiler.STALL_RESEED
+
+    def test_the_ninth_stall_logs_a_count_not_a_line(self, clock):
+        ph = self.make()
+        for _ in range(10):
+            one_pass(ph, clock, 20.0, 1.0)
+        with logged() as lines:
+            for i in range(16):
+                one_pass(ph, clock, 20.0, 1.0)    # between two stalls
+                one_pass(ph, clock, 20.0, 201.0)
+                ph.log_stall(f"pass {i}", "7 rows")
+        assert ph.stalls == 16
+        assert len(lines) == profiler.STALL_LOG_LINES + 2
+        assert lines[0].startswith(
+            "loop: pass 0 took 221.0 ms (typical 21.0): wait 201.0, a 20.0; "
+            "gc 0.0 ms; 7 rows")
+        assert all(" took " in m for m in lines[:8])
+        assert lines[8].startswith("loop: 9 stalls so far")
+        assert lines[9].startswith("loop: 16 stalls so far")
+
+    def test_end_allocates_nothing_with_tracing_off(self):
+        sums = {"a_s": 0.0, "b_s": 0.0}
+        ph = profiler.Phases("loop", "pass", {"a": "a_s", "b": "b_s"},
+                             sums=sums, wait="b")
+
+        def cycles(n):
+            for _ in range(n):
+                ph.begin("a")
+                ph.mark("b")
+                ph.end()
+
+        cycles(100)
+        gc.collect()
+        profiler.clear()  # a full collection may have left `host.gc`
+        tracemalloc.start()
+        try:
+            before = tracemalloc.take_snapshot()
+            cycles(1000)
+            after = tracemalloc.take_snapshot()
+        finally:
+            tracemalloc.stop()
+        here = [tracemalloc.Filter(True, profiler.__file__)]
+        grown = sum(s.size_diff for s in after.filter_traces(here).compare_to(
+            before.filter_traces(here), "filename"))
+        # what lives on is the last value of each int and float it
+        # holds, not a list or a record an iteration
+        assert grown < 1024, grown
+        assert profiler.spans() == []
+
+    def test_the_collectors_clock_and_a_long_full_collection(self):
+        ph = self.make()
+        ns0, n0 = profiler.gc_clock()
+        ph.begin("a")
+        t0 = time.perf_counter_ns()
+        assert churn() >= 300_000
+        ph.end()
+        ns1, n1 = profiler.gc_clock()
+        assert n1 > n0 and ph.gc_n >= 1
+        assert 0 < ph.gc_ns <= ns1 - ns0 < time.perf_counter_ns() - t0
+        assert profiler.gc_generation_since(t0) == 2
+        assert profiler.gc_generation_since(time.perf_counter_ns()) is None
+        full = [r for r in kept("host.gc") if r.t1_ns > t0]
+        assert full and full[-1].ids["generation"] == 2
+        assert full[-1].ids["collected"] >= 300_000
+        assert full[-1].t1_ns - full[-1].t0_ns > profiler.STALL_FLOOR_NS
+
+
 # -- the hot paths ---------------------------------------------------------------
 
 MCFG = T.TransformerConfig(vocab_size=256, n_layers=2, n_heads=4, d_model=64,
@@ -254,8 +452,10 @@ class TestServingLoop:
         m = sched.metrics()
         for k in list(PHASES.values()) + ["queue_wait_s", "slow_iterations"]:
             assert m[k] == float(sched.counters[k])
-        # tracing was off all along: at most a stall left its name
-        assert {r.name for r in profiler.spans()} <= {"sched.slow_iteration"}
+        # tracing was off all along: at most a stall, or a full
+        # collection of over 5 ms, left its name
+        assert {r.name for r in profiler.spans()} <= {"sched.slow_iteration",
+                                                      "host.gc"}
 
     def test_spans_of_an_iteration_and_of_a_request(self, engine):
         sched = make_sched(engine)
@@ -307,6 +507,144 @@ class TestServingLoop:
                 and r.ids["fault_delay_s"] == pytest.approx(0.2)]
         assert len(slow) == 1
         assert {f"{p}_ms" for p in PHASES} <= set(slow[0].ids)
+
+    # -- the stall rule in the loop (tracing off throughout) ------------------
+
+    NEW_COUNTERS = ("stall_iterations", "stall_s", "stall_readback_s",
+                    "gc_s", "gc_collections")
+
+    def test_every_new_counter_is_there_from_construction(self, engine):
+        sched = make_sched(engine)
+        m = sched.metrics()
+        for k in self.NEW_COUNTERS:
+            assert sched.counters[k] == 0 and m[k] == 0.0
+        # a runner takes snap1 - snap0 over every key: none may appear later
+        before = set(sched.counters)
+        submit_some(sched, n=2, new=3)
+        sched.run()
+        assert set(sched.counters) == before
+
+    def test_a_steady_loop_of_200_iterations_leaves_nothing(
+            self, engine, monkeypatch):
+        # the factor is held by TestStallRule on a clock of its own;
+        # here the loop is real and so are the pauses of a machine
+        # shared with five other workers (a 10 ms sleep that takes 40),
+        # which only marks far above them keep out
+        from deepspeed_tpu.inference import scheduler as S
+
+        monkeypatch.setattr(profiler, "STALL_FLOOR_NS", 400_000_000)
+        monkeypatch.setattr(S, "SLOW_ITERATION_NS", 400_000_000)
+        warm = make_sched(engine)
+        submit_some(warm, new=100)
+        warm.run()  # every width the tail of a run narrows to, compiled
+        profiler.clear()
+        sched = make_sched(engine)
+        with logged() as lines:
+            for _ in range(2):
+                submit_some(sched, new=100)
+                sched.run(tick=lambda s: time.sleep(0.002))
+        assert sched._iteration >= 200
+        assert sched._phases.typical_ns > 2_000_000
+        assert sched.counters["stall_iterations"] == 0
+        assert sched.counters["stall_s"] == 0.0
+        assert sched.counters["stall_readback_s"] == 0.0
+        assert kept("sched.slow_iteration") == [] and lines == []
+
+    def stalled_run(self, engine, where, work=None):
+        """A loop whose `where`-th tick runs `work` (default: sleeps
+        ten typical iterations); returns (scheduler, the span of that
+        iteration, the log, the seconds asked for)."""
+        sched = make_sched(engine)
+        submit_some(sched, new=40)
+        at = {}
+
+        def tick(s):
+            if s._iteration == where:
+                at["typical"] = s._phases.typical_ns
+                at["asked"] = 10 * at["typical"] * 1e-9 + 0.02
+                (work or (lambda: time.sleep(at["asked"])))()
+
+        with logged() as lines:
+            sched.run(tick=tick)
+        (span,) = [r for r in kept("sched.slow_iteration")
+                   if r.ids["iteration"] == where]
+        mine = [m for m in lines
+                if m.startswith(f"sched: iteration {where} took ")]
+        assert at["typical"] > 0
+        return sched, span, mine, at
+
+    def test_a_stalled_tick_is_counted_kept_and_logged(self, engine):
+        sched, span, lines, at = self.stalled_run(engine, where=20)
+        c, ids = sched.counters, span.ids
+        assert c["stall_iterations"] >= 1
+        assert c["stall_s"] >= at["asked"] * 0.9
+        # the readback wait of that iteration was no longer than usual
+        assert c["stall_readback_s"] < 0.1 * c["stall_s"]
+        assert "stall" in ids["rule"].split("+")
+        assert max(PHASES, key=lambda p: ids[f"{p}_ms"]) == "tick"
+        assert ids["tick_ms"] >= at["asked"] * 1e3
+        assert ids["typical_ms"] == pytest.approx(at["typical"] * 1e-6)
+        assert ids["excess_ms"] == pytest.approx(
+            (span.t1_ns - span.t0_ns - at["typical"]) * 1e-6)
+        assert ids["rows"] > 0 and ids["kind"] == "mixed"
+        assert ids["active"] == 6 and ids["waiting"] == 0
+        assert ids["gc_ms"] >= 0 and "majflt" not in ids  # PERF.md §6
+        # the span is the iteration's own stamps: it holds the tick
+        assert (span.t1_ns - span.t0_ns) * 1e-6 == pytest.approx(
+            sum(ids[f"{p}_ms"] for p in PHASES))
+        (line,) = lines
+        assert f"(typical {ids['typical_ms']:.1f}): tick " in line
+        assert "step ahead ready=" in line and "6 active, 0 waiting" in line
+
+    def test_a_stalled_readback_says_whether_the_device_ran_through(
+            self, engine, monkeypatch):
+        from deepspeed_tpu.inference import scheduler as S
+
+        calls = {"n": 0, "at": None}
+
+        def late(x):
+            calls["n"] += 1
+            if calls["n"] == calls["at"]:
+                time.sleep(0.25)   # the host's sight of the tokens is late
+            return serving_readback(x)
+
+        serving_readback = S.serving_readback
+        monkeypatch.setattr(S, "serving_readback", late)
+        # under the look-ahead the next step is launched, and done, by
+        # the time the late tokens are seen
+        calls["at"] = 20
+        sched = make_sched(engine)
+        submit_some(sched, new=40)
+        with logged() as lines:
+            sched.run()
+        (span,) = [r for r in kept("sched.slow_iteration")
+                   if r.ids["readback_ms"] >= 250]
+        assert span.ids["rule"] == "stall"        # the host held nothing
+        assert span.ids["ahead_ready"] is True
+        assert sched.counters["stall_readback_s"] >= 0.2
+        assert sched.counters["stall_s"] >= sched.counters["stall_readback_s"]
+        assert any("step ahead ready=True" in m for m in lines)
+        # step() reads back before it launches again: nothing is ahead
+        profiler.clear()
+        calls.update(n=0, at=20)
+        sched = make_sched(engine)
+        submit_some(sched, new=40)
+        while sched.has_work:
+            sched.step()
+        (span,) = [r for r in kept("sched.slow_iteration")
+                   if r.ids["readback_ms"] >= 250]
+        assert span.ids["ahead_ready"] is None
+        assert sched.counters["stall_readback_s"] >= 0.2
+
+    def test_the_collector_inside_a_tick_is_timed_and_named(self, engine):
+        sched, span, lines, _ = self.stalled_run(engine, where=20, work=churn)
+        assert sched.counters["gc_s"] > 0
+        assert sched.counters["gc_collections"] >= 1
+        assert span.ids["gc_ms"] > 0 and span.ids["gc_gen"] == 2
+        assert span.ids["gc_ms"] <= span.ids["tick_ms"]
+        assert [r for r in kept("host.gc")
+                if span.t0_ns <= r.t0_ns and r.t1_ns <= span.t1_ns]
+        assert len(lines) == 1 and "; gc " in lines[0]
 
     def test_latency_lists_are_bounded(self, engine):
         from deepspeed_tpu.inference.scheduler import LATENCY_WINDOW
@@ -394,3 +732,44 @@ class TestTrainStep:
         booked = engine.timers.timers["train_batch"]._record[-1]
         assert booked == pytest.approx(
             sum(r.t1_ns - r.t0_ns for r in recs[1:4]) * 1e-9)
+
+    def test_a_stalled_batch_is_kept_and_logged(self, monkeypatch):
+        import deepspeed_tpu as ds
+        from deepspeed_tpu.runtime import engine as E
+
+        mcfg = T.TransformerConfig(vocab_size=128, n_layers=1, n_heads=2,
+                                   d_model=32, max_seq=16, variant="llama",
+                                   use_flash=False)
+        engine = ds.initialize(
+            {"train_micro_batch_size_per_gpu": 2,
+             "optimizer": {"type": "adamw", "params": {"lr": 1e-3}},
+             "steps_per_print": 10**9},
+            loss_fn=T.make_loss_fn(mcfg),
+            param_init_fn=lambda k: T.init(mcfg, k),
+            param_logical_specs=T.logical_specs(mcfg))
+        batch = {"tokens": np.random.default_rng(0).integers(
+            0, 128, (engine.config.train_batch_size, 17)).astype(np.int32)}
+        device_get = jax.device_get
+
+        def late(x):
+            if engine.global_steps == 11:   # the twelfth step's loss
+                time.sleep(0.4)
+            return device_get(x)
+
+        monkeypatch.setattr(E.jax, "device_get", late)
+        with logged() as lines:
+            for _ in range(14):
+                engine.train_batch(batch)
+        # the first batch compiled: the pace is the others', and no
+        # batch but the late one is over three times it
+        assert 0 < engine._phases.typical_ns < 130_000_000
+        (span,) = [r for r in kept("train.slow_batch")
+                   if r.ids["readback_ms"] >= 400]
+        ids = span.ids
+        assert ids["step"] == 12
+        assert {"prepare_ms", "launch_ms", "readback_ms", "post_ms",
+                "typical_ms", "excess_ms", "gc_ms", "gc_gen"} <= set(ids)
+        assert ids["excess_ms"] == pytest.approx(
+            (span.t1_ns - span.t0_ns) * 1e-6 - ids["typical_ms"])
+        (line,) = [m for m in lines if m.startswith("train: step 12 took ")]
+        assert "): readback 4" in line

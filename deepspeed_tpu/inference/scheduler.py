@@ -66,7 +66,11 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple, Union
 import numpy as np
 
 from ..config.config import ServingSchedulerConfig
-from ..ops.pallas.paged_attention import kv_pack, walk_reads
+from ..ops.pallas.paged_attention import (
+    kv_pack,
+    latent_walk_reads,
+    walk_reads,
+)
 from ..resilience.faults import fault_point
 from ..resilience.integrity import HandoffIntegrityError
 from ..utils import profiler
@@ -284,6 +288,12 @@ class ServingScheduler:
             # 0 unless the model caches a latent): what the latent walk
             # multiplies, whatever it reads once a table
             "mla_cache_tokens": 0,
+            # of a latent model's rows, those whose visit was a TILE's:
+            # adjacent rows of one table that the latent walk multiplies
+            # a block by together (paged_attention.latent_walk_reads, by
+            # the kernel's own latent_tiles; kv_grouped_rows is the K/V
+            # walk's rule and stays 0 for a latent model)
+            "mla_grouped_rows": 0,
             # recurrent state (0 unless the model has state layers):
             # slots held by tracked sequences, summed over dispatched
             # steps; sequences that (re)started at position 0 in a slot,
@@ -1055,7 +1065,13 @@ class ServingScheduler:
             bs = self.engine.config.kv_block_size
             blocks = int(np.sum(-(-live // bs)))
             self.counters["kv_live_blocks"] += blocks
-            if tables is not None:
+            if tables is not None and cfg.is_latent:
+                # the latent walk's own rule: a table's blocks once a
+                # table, a uniform tile's rows in one visit
+                blocks, tiled = latent_walk_reads(tables, ctx, bs,
+                                                  cfg.n_heads)
+                self.counters["mla_grouped_rows"] += tiled
+            elif tables is not None:
                 blocks, rode = walk_reads(
                     tables, ctx, bs, cfg.n_heads // cfg.kv_heads
                     * kv_pack(cfg.kv_heads, cfg.head_dim))

@@ -1602,27 +1602,36 @@ def paged_scale_write(k_scale, v_scale, ks_new, vs_new, flat_slots):
 # ---------------------------------------------------------------------------
 
 def _latent_rows_kernel(
-    tbl_ref, ctx_ref, grp_ref,                      # scalar prefetch
+    tbl_ref, ctx_ref, grp_ref, uni_ref,             # scalar prefetch
     q_ref, pool_any,                                # inputs (pool in HBM)
-    o_ref, buf, lsem,                               # out, scratch
-    *, n_seqs: int, block_size: int, v_dim: int,
+    o_ref, buf, lsem, *acc,                         # out, scratch
+    n_seqs: int, block_size: int, v_dim: int, tile: int,
 ):
-    """Row s's online softmax over the live blocks of its table, all
-    heads at once, with every live block of a TABLE read from HBM once
-    for all of that table's rows: `buf` [2, NB, bs, C] holds the whole
-    table (a buffer set per table, alternating), rows of one table
-    follow each other (grp_ref: the table's index, rising by one where
-    the table changes; _LATENT_GROUP blocks are multiplied a step), and
-    a row that shares its predecessor's table
-    finds that row's blocks resident and loads only the blocks its
-    longer context adds. The row BEFORE a new table starts all of that
-    table's first row's loads into the other set, so they land while it
-    computes. Every load started is waited exactly once, by the first
-    row that needs it. Live blocks only (as _walk_live_blocks): a dead
-    table slot costs no DMA and no step."""
+    """A grid step owns a TILE of `tile` adjacent rows. Each row's
+    online softmax runs over the live blocks of its table, all heads at
+    once, with every live block of a TABLE read from HBM once for all
+    of that table's rows: `buf` [2, NB, bs, C] holds the whole table (a
+    buffer set per table, alternating), rows of one table follow each
+    other (grp_ref: the table's index, rising by one where the table
+    changes; _LATENT_GROUP blocks are multiplied a loop trip), and a
+    row that shares its predecessor's table finds that row's blocks
+    resident and loads only the blocks its longer context adds. The row
+    BEFORE a new table starts all of that table's first row's loads
+    into the other set, so they land while it computes. Every load
+    started is waited exactly once, by the first row that needs it.
+    Live blocks only (as _walk_live_blocks): a dead table slot costs no
+    DMA and no step.
+
+    What a step does is in uni_ref (latent_tiles): where the tile's rows
+    all name one table (a prefill chunk's inner rows, padding), the
+    tile takes ONE visit (_latent_tile_visit: a trip multiplies its
+    blocks by the tile's stacked queries); anywhere else (decode rows, a
+    chunk's edge) each row of the tile walks alone, by the same loop
+    body as a tile of one."""
     bs = block_size
     n_blk = pool_any.shape[0]
-    s = pl.program_id(0)
+    G = _LATENT_GROUP
+    base = pl.program_id(0) * tile
 
     def nblk_of(r):
         return pl.cdiv(ctx_ref[r], bs)
@@ -1631,27 +1640,39 @@ def _latent_rows_kernel(
         return jnp.logical_or(
             r == 0, grp_ref[r] != grp_ref[jnp.maximum(r - 1, 0)])
 
-    def load(r, j):
-        blk = _arena_block(tbl_ref[r, j], n_blk)
-        pltpu.make_async_copy(pool_any.at[blk], buf.at[grp_ref[r] % 2, j],
-                              lsem.at[grp_ref[r] % 2, j]).start()
-
     def load_range(r, lo, hi):
         def one(j, c):
-            load(r, j)
+            blk = _arena_block(tbl_ref[r, j], n_blk)
+            pltpu.make_async_copy(pool_any.at[blk], buf.at[grp_ref[r] % 2, j],
+                                  lsem.at[grp_ref[r] % 2, j]).start()
             return c
 
         jax.lax.fori_loop(lo, hi, one, 0)
 
-    ctx = ctx_ref[s]
-    nblk = nblk_of(s)
-    fresh = fresh_of(s)
-    bufset = grp_ref[s] % 2
-    # blocks of this table resident before this row (loaded AND waited
-    # by the rows before it); a fresh row's were started, not waited
-    resident = jnp.where(fresh, 0, nblk_of(jnp.maximum(s - 1, 0)))
+    def start_table_after(r):
+        """The row before a new table starts that table's first row's
+        loads, into the other set (r: a row walking alone, or the last
+        row of a tile's visit, at the visit's start)."""
+        @pl.when(r + 1 < n_seqs)
+        def _next_table():
+            nxt = jnp.minimum(r + 1, n_seqs - 1)
 
-    @pl.when(s == 0)
+            @pl.when(fresh_of(nxt))
+            def _start():
+                load_range(nxt, 0, nblk_of(nxt))
+
+    def wait_group(bufset, j0, n_blocks: int, resident, nblk):
+        for i in range(n_blocks):
+            @pl.when(jnp.logical_and(j0 + i < nblk, j0 + i >= resident))
+            def _wait(j=j0 + i):
+                pltpu.make_async_copy(pool_any.at[0], buf.at[bufset, j],
+                                      lsem.at[bufset, j]).wait()
+
+    def group_of(bufset, j0, n_blocks: int):
+        return buf[bufset, pl.ds(j0, n_blocks)].reshape(
+            n_blocks * bs, buf.shape[-1])
+
+    @pl.when(base == 0)
     def _first_row():
         # a group's last blocks may lie beyond the row's live ones: they
         # are masked out of the softmax, but 0 x NaN would still poison
@@ -1663,62 +1684,201 @@ def _latent_rows_kernel(
             return c
 
         jax.lax.fori_loop(0, 2 * buf.shape[1], zero, 0)
-        load_range(0, 0, nblk)
+        load_range(0, 0, nblk_of(0))
 
-    @pl.when(jnp.logical_not(fresh))
-    def _the_blocks_a_longer_context_adds():
-        load_range(s, resident, nblk)
+    def row_alone(i):
+        """Row base + i, the tile's i-th, walks its own blocks."""
+        s = base + i
+        ctx = ctx_ref[s]
+        nblk = nblk_of(s)
+        fresh = fresh_of(s)
+        bufset = grp_ref[s] % 2
+        # blocks of this table resident before this row (loaded AND
+        # waited by the rows before it); a fresh row's were started,
+        # not waited
+        resident = jnp.where(fresh, 0, nblk_of(jnp.maximum(s - 1, 0)))
 
-    @pl.when(s + 1 < n_seqs)
-    def _next_table():
-        nxt = jnp.minimum(s + 1, n_seqs - 1)
+        @pl.when(jnp.logical_not(fresh))
+        def _the_blocks_a_longer_context_adds():
+            load_range(s, resident, nblk)
 
-        @pl.when(fresh_of(nxt))
-        def _start():
-            load_range(nxt, 0, nblk_of(nxt))
+        start_table_after(s)
+        q = q_ref[i]  # (H, C), the softmax scale folded in by the caller
+        H = q.shape[0]
 
-    q = q_ref[0]  # (H, C), the softmax scale folded in by the caller
-    H = q.shape[0]
+        def body(g, carry):
+            m, l, acc = carry
+            j0 = g * G
+            wait_group(bufset, j0, G, resident, nblk)
+            # G blocks a step: the accumulator is rescaled once for
+            # G * bs columns (at one block a step the (H, v_dim) f32
+            # rescale, not the MXU, set the pace: chip, PR 33)
+            kb = group_of(bufset, j0, G)
+            st = _dot(q, kb, trans_b=True)  # (H, G * bs)
+
+            def mask(st):
+                cols = j0 * bs + jax.lax.broadcasted_iota(
+                    jnp.int32, st.shape, 1)
+                return jnp.where(cols < ctx, st, NEG_INF)
+
+            # only a row's last group holds columns past its context
+            st = jax.lax.cond((j0 + G) * bs > ctx, mask, lambda st: st, st)
+            m_new = jnp.maximum(m, jnp.max(st, axis=1, keepdims=True))
+            p = jnp.exp(st - m_new)
+            corr = jnp.exp(m - m_new)
+            l = l * corr + jnp.sum(p, axis=1, keepdims=True)
+            acc = acc * corr + _dot(p.astype(kb.dtype), kb[:, :v_dim])
+            return m_new, l, acc
+
+        init = (jnp.full((H, 1), NEG_INF, jnp.float32),
+                jnp.zeros((H, 1), jnp.float32),
+                jnp.zeros((H, v_dim), jnp.float32))
+        _, l, acc = jax.lax.fori_loop(0, pl.cdiv(nblk, G), body, init)
+        l_safe = jnp.where(l == 0.0, 1.0, l)  # ctx 0: batch padding, zeros
+        o_ref[i] = (acc / l_safe).astype(o_ref.dtype)
+
+    if tile == 1:
+        row_alone(0)
+        return
+
+    one_table = uni_ref[pl.program_id(0)] != 0
+
+    @pl.when(jnp.logical_not(one_table))
+    def _each_row_alone():
+        def one(i, c):
+            row_alone(i)
+            return c
+
+        jax.lax.fori_loop(0, tile, one, 0)
+
+    @pl.when(one_table)
+    def _one_visit():
+        def shortest_longest(i, c):
+            return (jnp.minimum(c[0], ctx_ref[base + i]),
+                    jnp.maximum(c[1], ctx_ref[base + i]))
+
+        shortest, longest = jax.lax.fori_loop(
+            1, tile, shortest_longest, (ctx_ref[base], ctx_ref[base]))
+        nblk = pl.cdiv(longest, bs)
+        fresh = fresh_of(base)
+        bufset = grp_ref[base] % 2
+        before = nblk_of(jnp.maximum(base - 1, 0))
+        resident = jnp.where(fresh, 0, before)
+        # a fresh tile's first row's blocks were started by the row
+        # before it; the tile starts what its longer rows add
+        load_range(base, jnp.where(fresh, nblk_of(base), before), nblk)
+        start_table_after(base + tile - 1)
+
+        def blocks(j0, n_blocks: int):
+            wait_group(bufset, j0, n_blocks, resident, nblk)
+            return group_of(bufset, j0, n_blocks)
+
+        _latent_tile_visit(blocks, nblk, base, shortest, ctx_ref, q_ref,
+                           o_ref, *acc, block_size=bs, v_dim=v_dim)
+
+
+def _latent_tile_visit(blocks, nblk, base, shortest, ctx_ref, q_ref, o_ref,
+                       m_sc, l_sc, acc_sc, *, block_size: int, v_dim: int):
+    """Rows base .. base + R - 1 (q_ref and o_ref are the tile's [R, H,
+    .] blocks; one table) over table slots [0, nblk): a loop trip
+    multiplies _LATENT_GROUP blocks (`blocks(j0)`, waited) by ALL the
+    tile's queries, R x H query rows stacked by a reshape (H is whole
+    sublane tiles), where R rows alone stream the same operand tiles R
+    times by H rows each. The running max m_sc and sum l_sc [R x H, 1]
+    and the accumulator acc_sc [R x H, v_dim] are f32 VMEM scratch, not
+    a loop carry (1 MB at 512 query rows).
+
+    A trip works in SUB-TILES of _LATENT_SUB query rows (one row at 128
+    heads), ordered as PR 50 learned in the K/V walk: every sub-tile's
+    score matmul back to back, then their softmaxes, then their value
+    matmuls, so that one sub-tile's vector work has another's matmul to
+    run under (0.245 us a (block, row) for 0.27-0.29 with sub-tiles of
+    256 or 512 rows, and 0.356 a row alone: chip, PR 54). Not
+    transposed (_group_softmax is): the value matmul would want the
+    trip's (G x bs, v_dim) values transposed, 16 tiles a trip where the
+    K/V visit transposes one, and the MXU is at nine tenths of its
+    time as it is.
+
+    The span's last blocks, fewer than a trip's, are multiplied AS THEY
+    ARE (a trip of 1 .. G - 1 blocks, one of each in the kernel's
+    text): filled up to a whole trip they cost a span of 12 blocks an
+    eighth more (0.245 -> 0.218 us). A whole trip is MASKED, every
+    query row to its own context, only where it can hold a dead column
+    for some row of the tile (_wholly_live of its last slot, by the
+    tile's shortest context); the short trip at the end always is."""
+    bs = block_size
     G = _LATENT_GROUP
+    R, H, _ = q_ref.shape
+    sub = max(1, min(R, _LATENT_SUB // H))  # rows of a sub-tile
+    subs = [(r0, min(sub, R - r0)) for r0 in range(0, R, sub)]
 
-    def body(g, carry):
-        m, l, acc = carry
+    def ctx_of(r0, n):
+        """[n * H, 1] int32: each query row's context."""
+        if n == 1:
+            return ctx_ref[base + r0]
+        row = jax.lax.broadcasted_iota(jnp.int32, (n * H, 1), 0) // H
+        return jax.lax.fori_loop(
+            0, n, lambda i, c: jnp.where(row == i, ctx_ref[base + r0 + i], c),
+            jnp.zeros((n * H, 1), jnp.int32))
+
+    m_sc[...] = jnp.full(m_sc.shape, NEG_INF, jnp.float32)
+    l_sc[...] = jnp.zeros(l_sc.shape, jnp.float32)
+    acc_sc[...] = jnp.zeros(acc_sc.shape, jnp.float32)
+
+    def update(j0, n_blocks: int, masked: bool):
+        kb = blocks(j0, n_blocks)  # (n_blocks * bs, C)
+        sts = [_dot(q_ref[r0:r0 + n].reshape(n * H, q_ref.shape[-1]), kb,
+                    trans_b=True) for r0, n in subs]  # (n * H, n_blocks * bs)
+        ps, corrs = [], []
+        for (r0, n), st in zip(subs, sts):
+            rows = slice(r0 * H, (r0 + n) * H)
+            if masked:
+                cols = j0 * bs + jax.lax.broadcasted_iota(
+                    jnp.int32, st.shape, 1)
+                # a trip with no live column for a row (past a shorter
+                # context) leaves that row's sums as they were
+                st = jnp.where(cols < ctx_of(r0, n), st, NEG_INF)
+            m_prev = m_sc[rows]
+            m_new = jnp.maximum(m_prev, jnp.max(st, axis=1, keepdims=True))
+            p = jnp.exp(st - m_new)
+            corrs.append(jnp.exp(m_prev - m_new))
+            l_sc[rows] = (l_sc[rows] * corrs[-1]
+                          + jnp.sum(p, axis=1, keepdims=True))
+            m_sc[rows] = m_new
+            ps.append(p.astype(kb.dtype))
+        pvs = [_dot(p, kb[:, :v_dim]) for p in ps]
+        for (r0, n), corr, pv in zip(subs, corrs, pvs):
+            rows = slice(r0 * H, (r0 + n) * H)
+            acc_sc[rows] = acc_sc[rows] * corr + pv
+
+    def trip(g, carry):
         j0 = g * G
-        for i in range(G):
-            @pl.when(jnp.logical_and(j0 + i < nblk, j0 + i >= resident))
-            def _wait(j=j0 + i):
-                pltpu.make_async_copy(pool_any.at[0], buf.at[bufset, j],
-                                      lsem.at[bufset, j]).wait()
+        whole = _wholly_live(j0 + G - 1, shortest, shortest, bs, 0)
+        pl.when(whole)(lambda: update(j0, G, False))
+        pl.when(jnp.logical_not(whole))(lambda: update(j0, G, True))
+        return carry
 
-        # G blocks a step: the accumulator is rescaled once for G * bs
-        # columns (at one block a step the (H, v_dim) f32 rescale, not
-        # the MXU, set the pace: chip, PR 33)
-        kb = buf[bufset, pl.ds(j0, G)].reshape(G * bs, buf.shape[-1])
-        st = _dot(q, kb, trans_b=True)  # (H, G * bs)
+    jax.lax.fori_loop(0, nblk // G, trip, 0)
+    # the span's last blocks, fewer than a trip's: multiplied as they
+    # are, not filled up to a trip with blocks that hold nothing live
+    for n_blocks in range(1, G):
+        pl.when(nblk % G == n_blocks)(
+            lambda n_blocks=n_blocks: update(nblk // G * G, n_blocks, True))
 
-        def mask(st):
-            cols = j0 * bs + jax.lax.broadcasted_iota(jnp.int32, st.shape, 1)
-            return jnp.where(cols < ctx, st, NEG_INF)
-
-        # only a row's last group holds columns past its context
-        st = jax.lax.cond((j0 + G) * bs > ctx, mask, lambda st: st, st)
-        m_new = jnp.maximum(m, jnp.max(st, axis=1, keepdims=True))
-        p = jnp.exp(st - m_new)
-        corr = jnp.exp(m - m_new)
-        l = l * corr + jnp.sum(p, axis=1, keepdims=True)
-        acc = acc * corr + _dot(p.astype(kb.dtype), kb[:, :v_dim])
-        return m_new, l, acc
-
-    init = (jnp.full((H, 1), NEG_INF, jnp.float32),
-            jnp.zeros((H, 1), jnp.float32),
-            jnp.zeros((H, v_dim), jnp.float32))
-    _, l, acc = jax.lax.fori_loop(0, pl.cdiv(nblk, G), body, init)
-    l_safe = jnp.where(l == 0.0, 1.0, l)  # ctx 0: batch padding, zeros
-    o_ref[0] = (acc / l_safe).astype(o_ref.dtype)
+    for r0, n in subs:
+        rows = slice(r0 * H, (r0 + n) * H)
+        l = l_sc[rows]
+        out = acc_sc[rows] / jnp.where(l == 0.0, 1.0, l)
+        out = jnp.where(ctx_of(r0, n) > 0, out, 0.0)  # batch padding: zeros
+        o_ref[r0:r0 + n] = out.reshape(n, H, v_dim).astype(o_ref.dtype)
 
 
-# live blocks the latent walk multiplies in one step of its loop
+# live blocks the latent walk multiplies in one trip of its loop
 _LATENT_GROUP = 4
+# query rows (rows x heads) a tile's visit stacks in its matmuls at most,
+# and of the sub-tiles a trip orders its work by
+_LATENT_QUERY_ROWS = 512
+_LATENT_SUB = 128
 
 
 def latent_lanes(latent_dim: int) -> int:
@@ -1726,26 +1886,75 @@ def latent_lanes(latent_dim: int) -> int:
     return -(-latent_dim // 128) * 128
 
 
+def latent_tile(n_rows: int, n_heads: int) -> int:
+    """Adjacent rows R a grid step of the latent walk owns: as many as
+    stack _LATENT_QUERY_ROWS query rows (4 at 128 heads, 8 at most),
+    from the shapes alone; 1, the walk of a row a step, where the heads
+    are not whole sublane tiles (no free reshape stacks them) or the
+    rows are not whole tiles (tier-1's small batches; the engine's
+    widths are)."""
+    tile = min(8, _LATENT_QUERY_ROWS // n_heads)
+    if n_heads % 8 or tile < 2 or n_rows % tile:
+        return 1
+    return tile
+
+
 def latent_walk_fits(n_table_slots: int, pool) -> bool:
     """Whether paged_latent_attention's kernel can take this pool: its
     two whole-table buffer sets must fit the walk's VMEM budget beside
-    the double-buffered q and out rows. At 128-token blocks of 640 bf16
-    lanes that is tables of up to 153 blocks (19,584 tokens); the
-    engine refuses a longer context when it is built with the kernel."""
+    the widest tile's scratch, _LATENT_QUERY_ROWS query rows (a model's
+    heads are fewer): the tile's queries and outputs double buffered
+    (the values counted as wide as the keys), a trip's float32 scores
+    and their 16-bit probabilities, the float32 accumulator and the
+    running max and sum (a lane tile a row); 6.0 MB at 640 bf16 lanes.
+    At 128-token blocks of 640 bf16 lanes that is tables of up to 132
+    blocks (16,896 tokens); the engine refuses a longer context when it
+    is built with the kernel."""
     _, bs, C = pool.shape
+    itemsize = pool.dtype.itemsize
     slots = -(-n_table_slots // _LATENT_GROUP) * _LATENT_GROUP
-    need = 2 * slots * bs * C * pool.dtype.itemsize
-    return (C % 128 == 0 and pool.dtype.itemsize in (2, 4)
+    tile = _LATENT_QUERY_ROWS * (
+        4 * C * itemsize + _LATENT_GROUP * bs * (4 + itemsize)
+        + C * 4 + 2 * 128 * 4)
+    need = 2 * slots * bs * C * itemsize + tile
+    return (C % 128 == 0 and itemsize in (2, 4)
             and need <= _WALK_VMEM_BUDGET)
 
 
-def table_groups(block_table):
+def table_groups(block_table, xp=jnp):
     """[S] int32: the index of each row's TABLE, rising by one wherever
     a row's table differs from the row before it (rows of one prefill
     chunk follow each other and share theirs)."""
-    same = jnp.all(block_table[1:] == block_table[:-1], axis=1)
-    return jnp.concatenate([jnp.zeros((1,), jnp.int32),
-                            jnp.cumsum(~same, dtype=jnp.int32)])
+    same = xp.all(block_table[1:] == block_table[:-1], axis=1)
+    return xp.concatenate([xp.zeros((1,), xp.int32),
+                           xp.cumsum(~same, dtype=xp.int32)])
+
+
+def latent_tiles(groups, tile: int):
+    """[S // tile] bool: the tiles of `tile` adjacent rows whose rows all
+    name ONE table (groups: table_groups of the call), which the latent
+    walk visits together: a prefill chunk's rows but for its edges,
+    padding rows. The kernel's entry (jnp) and latent_walk_reads (numpy)
+    both ask it, so that the two cannot disagree."""
+    groups = groups.reshape(-1, tile)
+    return groups[:, 0] == groups[:, -1]
+
+
+def latent_walk_reads(block_table, ctx_lens, block_size: int, n_heads: int):
+    """(blocks fetched, rows whose visit was a tile's) of one latent
+    call over these host arrays, by the kernel's own rules: a table's
+    blocks are fetched ONCE a run of adjacent rows that name it, by the
+    run's longest row; a row is grouped where latent_tiles says its
+    tile is one table's (rows of context 0, batch padding, left out).
+    The scheduler's kv_block_reads / mla_grouped_rows."""
+    groups = table_groups(block_table, np)
+    reads = np.zeros(len(ctx_lens), np.int64)
+    np.maximum.at(reads, groups, -(-ctx_lens // block_size))
+    tile = latent_tile(len(ctx_lens), n_heads)
+    if tile == 1:  # a row a step: no visit is a tile's
+        return int(reads.sum()), 0
+    tiled = np.repeat(latent_tiles(groups, tile), tile)
+    return int(reads.sum()), int(np.sum(tiled & (ctx_lens > 0)))
 
 
 def paged_latent_attention(q, pool, block_table, ctx_lens, v_dim: int):
@@ -1772,28 +1981,37 @@ def _latent_attention(q, pool, block_table, ctx_lens, v_dim: int,
     S, H, C = q.shape
     NB = block_table.shape[1]
     bs = pool.shape[1]
+    R = latent_tile(S, H)
     # the buffers hold whole groups: a row's last group may reach past
     # the table's last slot
     NBp = -(-NB // _LATENT_GROUP) * _LATENT_GROUP
+    scratch = [pltpu.VMEM((2, NBp, bs, C), pool.dtype),
+               pltpu.SemaphoreType.DMA((2, NBp))]
+    if R > 1:  # a tile's running max, sum and accumulator
+        scratch += [pltpu.VMEM((R * H, 1), jnp.float32),
+                    pltpu.VMEM((R * H, 1), jnp.float32),
+                    pltpu.VMEM((R * H, v_dim), jnp.float32)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(S,),
-        in_specs=[pl.BlockSpec((1, H, C), lambda s, *_: (s, 0, 0)),
+        num_scalar_prefetch=4,
+        grid=(S // R,),
+        in_specs=[pl.BlockSpec((R, H, C), lambda t, *_: (t, 0, 0)),
                   pl.BlockSpec(memory_space=pl.ANY)],
-        out_specs=pl.BlockSpec((1, H, v_dim), lambda s, *_: (s, 0, 0)),
-        scratch_shapes=[pltpu.VMEM((2, NBp, bs, C), pool.dtype),
-                        pltpu.SemaphoreType.DMA((2, NBp))],
+        out_specs=pl.BlockSpec((R, H, v_dim), lambda t, *_: (t, 0, 0)),
+        scratch_shapes=scratch,
     )
-    return pl.pallas_call(
+    call = pl.pallas_call(
         functools.partial(_latent_rows_kernel, n_seqs=S, block_size=bs,
-                          v_dim=v_dim),
+                          v_dim=v_dim, tile=R),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, H, v_dim), q.dtype),
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_WALK_VMEM_LIMIT),
         interpret=interpreted,
         name="paged_decode_grid",
-    )(block_table, ctx_lens, table_groups(block_table), q, pool)
+    )
+    groups = table_groups(block_table)
+    return call(block_table, ctx_lens, groups,
+                latent_tiles(groups, R).astype(jnp.int32), q, pool)
 
 
 def paged_latent_attention_xla(q, pool, block_table, ctx_lens, v_dim: int):

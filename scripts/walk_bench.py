@@ -1,7 +1,9 @@
 """Standalone chip timing of the shared-table K/V walk (paged_decode_grid,
 its entry included) at the serving cells' shapes: the table of PERF.md
 section 6, PR 50, kept so that the next change to the walk can re-run it.
---kernel kv_write times the write that goes before it (paged_kv_write, PR 52).
+--kernel kv_write times the write that goes before it (paged_kv_write, PR 52);
+--kernel latent times the latent walk (paged_latent_attention, the same trace
+name) at the latent cell's shape (PERF.md section 6, PR 54).
 
   chiprun -- python scripts/walk_bench.py                 # every shape
   chiprun -- python scripts/walk_bench.py mellum_full dense --kinds mixed,groups
@@ -10,6 +12,7 @@ section 6, PR 50, kept so that the next change to the walk can re-run it.
   chiprun -- python scripts/walk_bench.py dense --kernel kv_write \
       --distinct-blocks 128,1               # 128 rows in 128 blocks, then in ONE
   ... --kernel kv_write --rows 256 --kv-heads 4 --head-dim 128   a shape by hand
+  chiprun -- python scripts/walk_bench.py --kernel latent  # us a (block, row)
 
 A call's rows by --kinds: `mixed` (decode rows beside chunks of 32 rows on
 one table, as the cell's steps are), `groups` (chunks alone), `decode` (rows
@@ -23,7 +26,10 @@ scatter's, whole.
 --chain calls run in ONE program, each waiting for the last, so the host's
 ~200 us a dispatch is paid once and not a call; best of 5 x 40 programs. A
 (KV head, block) cost is (a kind's time - `empty`) / (its visits x KV). One
-JSON line a measurement, appended to chiprun_out/walk_bench.jsonl.
+JSON line a measurement, appended to chiprun_out/walk_bench.jsonl. The latent
+walk has no KV heads: a visit there is a (block, row), every head at once, and a
+line gives `us_a_visit` = (a kind's time - `empty`) / (its rows' live blocks),
+`empty` run first.
 """
 import argparse
 import json
@@ -47,6 +53,13 @@ SHAPES = {
     "granite": dict(rows=128, H=32, KV=8, D=128, pool=1024, NB=32, window=0),
     "nemotron": dict(rows=256, H=32, KV=2, D=128, pool=1024, NB=32, window=0),
 }
+# --kernel latent: the pool is [blocks, BLOCK, C] and a row's H heads share it;
+# a `mixed` call is the cell's step (PERF.md section 5): ~42 decode rows, then
+# ~86 prompt tokens in chunks of 32 that start where the decode rows end
+LATENT_SHAPES = {
+    "latent": dict(rows=128, H=128, C=640, v_dim=512, pool=5632, NB=72,
+                   window=0, mixed_runs=(32, 32, 22)),
+}
 
 
 def mix(kind, c, rng):
@@ -63,10 +76,11 @@ def mix(kind, c, rng):
         ctx[:] = rng.integers(cap // 16, top, rows)
         return ctx, []
     if kind == "groups":
-        n_groups = rows // 32
+        lens = [32] * (rows // 32)
     else:
-        n_groups = 6 if long_ctx else max(1, rows // 96)
-    n_dec = rows - 32 * n_groups
+        lens = list(c.get("mixed_runs") or
+                    [32] * (6 if long_ctx else max(1, rows // 96)))
+    n_dec = rows - sum(lens)
     if kind == "mixed" and long_ctx:
         n_dec -= 2  # two pad rows at the end
     ctx[:n_dec] = rng.integers(cap // 16, top, n_dec)
@@ -76,10 +90,11 @@ def mix(kind, c, rng):
         firsts = [1, 33, 65, 97, 129, 193, 257, 385,
                   1, 33, 65, 97, 129, 161, 225, 449]
     runs = []
-    for g in range(n_groups):
-        f = n_dec + 32 * g
-        ctx[f:f + 32] = firsts[g % len(firsts)] + np.arange(32)
-        runs.append((f, 32))
+    f = n_dec
+    for g, n in enumerate(lens):
+        ctx[f:f + n] = firsts[g % len(firsts)] + np.arange(n)
+        runs.append((f, n))
+        f += n
     return ctx, runs
 
 
@@ -114,6 +129,80 @@ def write_slots(spread, rows, pool, rng):
         b * BLOCK + rng.integers(0, BLOCK - n + 1) + np.arange(n)
         for b, n in zip(blocks, runs)])
     return slots.astype(np.int32), len(runs)
+
+
+def best_of(fn, chain, *operands):
+    """(the last output, seconds a chained call): best of 5 x 40 programs."""
+    for _ in range(3):
+        out = fn(*operands)
+    out.block_until_ready()
+    best = float("inf")
+    for _ in range(5):
+        t0 = time.perf_counter()
+        for _ in range(40):
+            out = fn(*operands)
+        out.block_until_ready()
+        best = min(best, (time.perf_counter() - t0) / 40 / chain)
+    return out, best
+
+
+def bench_latent(args, PA, name, c):
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    rows, H, C, v_dim = c["rows"], c["H"], c["C"], c["v_dim"]
+    key = jax.random.PRNGKey(0)
+    pool = jax.random.normal(key, (c["pool"] + 1, BLOCK, C), jnp.bfloat16)
+    q = (jax.random.normal(jax.random.fold_in(key, 2), (rows, H, C),
+                           jnp.bfloat16) * C ** -0.5).astype(jnp.bfloat16)
+    empty_us = None
+    # `empty` first: every other kind's visits are sized less it
+    kinds = sorted(args.kinds.split(","), key=lambda k: k != "empty")
+    for kind in kinds:
+        rng = np.random.default_rng(0)
+        ctx, runs = mix(kind, c, rng)
+        tbl = np.stack([rng.permutation(c["pool"])[:c["NB"]]
+                        for _ in range(rows)])
+        for f, n in runs:
+            tbl[f:f + n] = tbl[f]
+        tbl = jnp.asarray(tbl, jnp.int32)
+        ctxd = jnp.asarray(ctx, jnp.int32)
+
+        def chain(q, pool, tbl, ctxd):
+            qq = q
+            for _ in range(args.chain):
+                out = PA.paged_latent_attention(qq, pool, tbl, ctxd, v_dim)
+                qq = q + (out[..., :1] * 0).astype(q.dtype)
+            return out
+
+        out, best = best_of(jax.jit(chain), args.chain, q, pool, tbl, ctxd)
+        pick = sorted({0, 1, 2, *[f + i for f, n in runs
+                                  for i in (0, 1, n - 1)]})
+        pick = np.asarray([i for i in pick if ctx[i] > 0][:12] or [0])
+        with jax.default_matmul_precision("highest"):
+            ref = PA.paged_latent_attention_xla(
+                q[pick].astype(jnp.float32), pool.astype(jnp.float32),
+                tbl[pick], ctxd[pick], v_dim)
+        err = float(jnp.max(jnp.abs(out[pick].astype(jnp.float32) - ref)))
+        blocks = -(-ctx // BLOCK)
+        chunk = np.zeros(rows, bool)
+        for f, n in runs:
+            chunk[f:f + n] = True
+        us = best * 1e6
+        if kind == "empty":
+            empty_us = us
+        line = dict(tag=args.tag, kernel="latent", shape=name, kind=kind,
+                    us=round(us, 1), decode_visits=int(blocks[~chunk].sum()),
+                    chunk_visits=int(blocks[chunk].sum()),
+                    max_err=round(err, 4),
+                    device=jax.devices()[0].device_kind)
+        if empty_us is not None and kind != "empty":
+            line["us_a_visit"] = round(
+                (us - empty_us) / max(1, int(blocks.sum())), 4)
+        print(json.dumps(line), flush=True)
+        with open("chiprun_out/walk_bench.jsonl", "a") as f:
+            f.write(json.dumps(line) + "\n")
 
 
 def bench_kv_write(args, PA, name, c):
@@ -176,8 +265,9 @@ def bench_kv_write(args, PA, name, c):
 
 def main():
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
-    ap.add_argument("shapes", nargs="*", default=list(SHAPES))
-    ap.add_argument("--kernel", choices=("walk", "kv_write"), default="walk")
+    ap.add_argument("shapes", nargs="*")
+    ap.add_argument("--kernel", choices=("walk", "kv_write", "latent"),
+                    default="walk")
     ap.add_argument("--rows", type=int, help="kv_write: a shape by hand, "
                     "with --kv-heads and --head-dim, in place of a named one")
     ap.add_argument("--kv-heads", type=int, default=8)
@@ -201,6 +291,11 @@ def main():
     assert os.path.abspath(PA.__file__).startswith(
         os.path.abspath(args.tree)), PA.__file__
     os.makedirs("chiprun_out", exist_ok=True)
+    if args.kernel == "latent":
+        for name in args.shapes or LATENT_SHAPES:
+            bench_latent(args, PA, name, LATENT_SHAPES[name])
+        return
+    args.shapes = args.shapes or list(SHAPES)
     if args.kernel == "kv_write":
         shapes = {n: SHAPES[n] for n in args.shapes}
         if args.rows:
@@ -243,17 +338,8 @@ def main():
                     qq = q + (out * 0).astype(q.dtype)
                 return out
 
-            fn = jax.jit(chain)
-            for _ in range(3):
-                out = fn(q, kc, vc, tbl, ctxd)
-            out.block_until_ready()
-            best = float("inf")
-            for _ in range(5):
-                t0 = time.perf_counter()
-                for _ in range(40):
-                    out = fn(q, kc, vc, tbl, ctxd)
-                out.block_until_ready()
-                best = min(best, (time.perf_counter() - t0) / 40 / args.chain)
+            out, best = best_of(jax.jit(chain), args.chain,
+                                q, kc, vc, tbl, ctxd)
             # a few rows against the float32 gather oracle
             pick = sorted({0, 1, 2, *[f + i for f, n in runs
                                       for i in (0, n - 1)]})

@@ -260,12 +260,37 @@ def test_a_shared_table_iteration_of_mixed_rows_matches_the_reference(
     c = sched.counters
     assert c["moe_token_expert_pairs"] == c["batched_tokens"] * mcfg.moe_top_k
     assert c["mla_cache_tokens"] > c["batched_tokens"] > 0
+    # the K/V walk's rule groups nothing in a latent model, and four
+    # heads stack no tile; a table's blocks are fetched once a table
+    assert c["kv_grouped_rows"] == c["mla_grouped_rows"] == 0
+    assert 0 < c["kv_block_reads"] < c["kv_live_blocks"]
     assert not eng.recompile_tracker.findings
     for rid, p in zip(rids, prompts):
         out = sched.finished[rid].output
         seq = np.concatenate([p, out]).astype(np.int32)
         want = _ref_logits(params, seq[None])[0]
         assert out == [int(want[len(p) - 1 + j].argmax()) for j in range(5)]
+
+
+def test_the_scheduler_counts_a_latent_step_by_the_latent_walks_rule(model):
+    """mla_grouped_rows / kv_block_reads of a dispatched step are
+    latent_walk_reads of its host arrays (at sixteen heads a tile is
+    eight rows), and the K/V rule's kv_grouped_rows stays 0."""
+    mcfg, params = model
+    eng = init_inference(params, mcfg, dict(ENGINE), dtype=jnp.float32)
+    sched = ServingScheduler(eng, ServingSchedulerConfig(warmup=False), seed=0)
+    eng.cfg = dataclasses.replace(eng.cfg, n_heads=16)
+    NB = eng.config.blocks_per_seq
+    tables = np.arange(16 * NB, dtype=np.int32).reshape(16, NB) % 31
+    tables[3:] = tables[3]                # a chunk of 13 rows on one table
+    ctx = np.asarray([40, 7, 99, *range(50, 63)], np.int32)
+    sched._count_tokens(16, 16, ctx, tables=tables)
+    reads, tiled = PA.latent_walk_reads(tables, ctx, 32, 16)
+    assert tiled == 8                     # rows 8..15: the one whole tile
+    c = sched.counters
+    assert (c["mla_grouped_rows"], c["kv_block_reads"]) == (tiled, reads)
+    assert c["kv_grouped_rows"] == 0
+    assert c["kv_live_blocks"] - reads == 12 * 2   # twelve rows rode
 
 
 # -- the latent kernels ----------------------------------------------------
@@ -297,6 +322,99 @@ def test_the_latent_kernels_match_their_oracles(pallas_interpret):
         np.asarray(PA.paged_latent_write_xla(pool, new, slots)))
 
 
+def _chunk(table, first_ctx, n):
+    return [(table, first_ctx + i) for i in range(n)]
+
+
+_PAD = ("pad", 0)
+# name: (heads, query rows a tile stacks, query rows a sub-tile, the rows
+# as (table, context), rows the walk visits as tiles). Blocks of 16
+# tokens, trips of 4 blocks, tiles of 4 rows but where the case says
+_TILE_CASES = {
+    # rows 2..12 on one table: tiles 1 and 2 are its inner rows, tiles 0
+    # and 3 hold its edges beside decode rows and padding
+    "a_chunk_off_the_tiles_boundary": (
+        8, 32, 128,
+        [("d0", 70), ("d1", 5), *_chunk("A", 20, 11), _PAD, _PAD, _PAD], 8),
+    # one row a block longer than its neighbours (32 | 33) and, in the
+    # second tile, a trip longer (64 | 65); sub-tiles of one row
+    "a_tile_across_a_block_boundary": (
+        8, 32, 8, [*_chunk("A", 30, 4), *_chunk("B", 62, 4)], 8),
+    # the longest row fills its last block, and its last trip, exactly;
+    # sub-tiles of two rows
+    "a_tile_that_ends_on_a_block_boundary": (
+        8, 32, 16, [*_chunk("A", 29, 4), *_chunk("B", 61, 4)], 8),
+    # a tile of padding is one table's and visits nothing: zeros; a row
+    # of context 0 on a live table beside it
+    "a_tile_of_padding": (
+        8, 32, 128, [_PAD] * 4 + [("A", 0), *_chunk("A", 17, 3)], 3),
+    "decode_rows_alone": (
+        8, 32, 128, [(f"d{i}", c) for i, c in
+                     enumerate([70, 1, 16, 33, 64, 65, 90, 17])], 0),
+    # six rows are not whole tiles of four: a row a step, from the shape
+    "rows_that_are_not_whole_tiles": (8, 32, 128, _chunk("A", 30, 6), 0),
+    # fewer heads stack more rows: 8 at the constants as they are
+    "sixteen_heads_and_tiles_of_eight": (
+        16, None, None, [*_chunk("A", 30, 8), ("d0", 40), *_chunk("B", 1, 7)],
+        8),
+}
+
+
+@pytest.mark.parametrize("case", _TILE_CASES)
+def test_a_tile_of_one_tables_rows_is_visited_together(
+        case, monkeypatch, pallas_interpret):
+    """The tiled latent walk against its oracle, against the walk of a
+    row a step (bit for bit where no tile is one table's), and the
+    host's count of tiled rows against the tiles the kernel's entry
+    marks."""
+    H, query_rows, sub_rows, rows, tiled = _TILE_CASES[case]
+    rng = np.random.default_rng(5)
+    NBLK, bs, NB, C, V = 64, 16, 6, 128, 64
+    pool = jnp.asarray(rng.normal(size=(NBLK, bs, C)), jnp.float32)
+    names = dict.fromkeys(t for t, _ in rows if t != "pad")
+    blocks = iter(rng.permutation(NBLK - 1))
+    table_of = {t: [int(next(blocks)) for _ in range(NB)] for t in names}
+    table_of["pad"] = [NBLK - 1] * NB
+    tables = np.asarray([table_of[t] for t, _ in rows], np.int32)
+    ctx = np.asarray([c for _, c in rows], np.int32)
+    q = jnp.asarray(rng.normal(size=(len(rows), H, C)) * 0.3, jnp.float32)
+
+    def walk(query_rows):
+        if query_rows is not None:
+            monkeypatch.setattr(PA, "_LATENT_QUERY_ROWS", query_rows)
+        if sub_rows is not None:
+            monkeypatch.setattr(PA, "_LATENT_SUB", sub_rows)
+        PA._latent_attention.clear_cache()
+        try:
+            return np.asarray(PA.paged_latent_attention(
+                q, pool, jnp.asarray(tables), jnp.asarray(ctx), V))
+        finally:
+            PA._latent_attention.clear_cache()
+
+    got = walk(query_rows)
+    want = np.asarray(PA.paged_latent_attention_xla(
+        q, pool, jnp.asarray(tables), jnp.asarray(ctx), V))
+    assert np.abs(got - want).max() < 1e-5
+    assert np.all(got[ctx == 0] == 0)
+    R = PA.latent_tile(len(rows), H)
+    assert R == {"rows_that_are_not_whole_tiles": 1,
+                 "sixteen_heads_and_tiles_of_eight": 8}.get(case, 4)
+    # the host's counter and the kernel's entry mark the same rows
+    marked = np.repeat(np.asarray(PA.latent_tiles(
+        PA.table_groups(jnp.asarray(tables)), R)), R)
+    reads, counted = PA.latent_walk_reads(tables, ctx, bs, H)
+    assert counted == int(np.sum(marked & (ctx > 0)) * (R > 1)) == tiled
+    longest = {}
+    for (t, c), g in zip(rows, np.asarray(PA.table_groups(tables, np))):
+        longest[g] = max(longest.get(g, 0), -(-c // bs))
+    assert reads == sum(longest.values())
+    alone = walk(H)  # a tile of one row: the walk of a row a step
+    assert PA.latent_tile(len(rows), H) == 1
+    assert np.abs(alone - want).max() < 1e-5
+    if tiled == 0:
+        np.testing.assert_array_equal(got, alone)
+
+
 def test_the_kernel_engine_agrees_with_the_oracle_engine(model, tokens, served,
                                                          pallas_interpret):
     mcfg, params = model
@@ -313,7 +431,9 @@ def test_the_kernel_engine_agrees_with_the_oracle_engine(model, tokens, served,
 
 def test_a_context_longer_than_the_walks_buffers_is_refused(model):
     pool = jax.ShapeDtypeStruct((8, 128, 640), jnp.bfloat16)
-    assert PA.latent_walk_fits(72, pool) and PA.latent_walk_fits(146, pool)
+    # the two buffer sets beside a tile's scratch: 132 blocks at the most
+    assert PA.latent_walk_fits(72, pool) and PA.latent_walk_fits(132, pool)
+    assert not PA.latent_walk_fits(133, pool)
     assert not PA.latent_walk_fits(256, pool)
     assert not PA.latent_walk_fits(
         8, jax.ShapeDtypeStruct((8, 128, 576), jnp.bfloat16))

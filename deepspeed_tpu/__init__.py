@@ -33,6 +33,7 @@ def initialize(
     init_rng=None,
     pipelined: bool = False,
     pipeline_virtual_stages: Optional[int] = None,
+    state_rule=None,
 ) -> DeepSpeedTPUEngine:
     """Build a training engine (ref: deepspeed/__init__.py:69 initialize).
 
@@ -40,6 +41,12 @@ def initialize(
     takes a pure `loss_fn(params, batch, rng) -> loss` plus either a
     concrete params pytree or (`param_init_fn`, abstract shapes) so
     parameters can be materialized directly sharded.
+
+    `state_rule` (runtime.engine.StepStateRule, with `has_aux`): leaves
+    of the tree that are the step's own state, written after the
+    optimizer's update from the loss's aux and kept out of the
+    optimizer (models.transformer.step_state_rule makes a routed
+    model's).
 
     Returns the engine; optimizer and lr scheduler are owned by the
     engine and built from the config's optimizer/scheduler blocks.
@@ -66,6 +73,7 @@ def initialize(
             init_rng=init_rng,
             pipelined=pipelined,
             pipeline_virtual_stages=pipeline_virtual_stages,
+            state_rule=state_rule,
         )
 
 

@@ -950,7 +950,10 @@ def _mlp(h, lp, cfg: T.TransformerConfig, census_cb=None,
             deq(lp["w_in"]), deq(lp["w_out"]),
             w_gate=deq(lp["w_gate"]) if has_gate else None,
             b_in=lp.get("b_in"), b_out=lp.get("b_out"), act=act)
-        return _moe_residual(out, h, lp, cfg, act)
+        # (the shared expert too: a model that holds every expert AND a
+        # shared one reaches this path at few rows an expert; until PR 55
+        # no served family did, and the path left the shared expert out)
+        return _moe_shared(out, h, lp, cfg, act)
 
     if path == "grouped":
         with jax.named_scope("moe_route"):
@@ -1026,24 +1029,16 @@ def _moe_shared(out, h, lp, cfg: T.TransformerConfig, act):
 
 
 def _sigmoid_topk_gating(logits, cfg: T.TransformerConfig, bias=None):
-    """Sigmoid-scored top-k (DeepSeek-V3 class routers): each expert's
-    score is the sigmoid of its own logit, in float32; the k largest
-    are chosen (ties to the lowest index), their scores divided by
-    their sum when cfg.moe_norm_topk_prob, then multiplied by
-    cfg.routed_scaling_factor. `bias` [X] (a layer's `expert_bias`,
-    cfg.moe_expert_bias) is added to the scores for the CHOICE alone:
-    the weights are the chosen experts' unbiased scores. No groups.
+    """The sigmoid-scored router by cfg's settings (top-k, whether the
+    chosen scores are divided by their sum, the routed scale; `bias`
+    a layer's `expert_bias`, for the choice alone): ONE authority with
+    the training gate, moe/dropless.py sigmoid_topk_gating.
     logits [T, X] f32 -> (idx [T, k] int32, weights [T, k] f32)."""
-    scores = jax.nn.sigmoid(logits)
-    if bias is not None:
-        _, idx = jax.lax.top_k(scores + bias.astype(jnp.float32),
-                               cfg.moe_top_k)
-        wts = jnp.take_along_axis(scores, idx, axis=-1)
-    else:
-        wts, idx = jax.lax.top_k(scores, cfg.moe_top_k)
-    if cfg.moe_norm_topk_prob:
-        wts = wts / (jnp.sum(wts, axis=-1, keepdims=True) + 1e-20)
-    return idx, wts * cfg.routed_scaling_factor
+    from ..moe.dropless import sigmoid_topk_gating
+
+    return sigmoid_topk_gating(logits, cfg.moe_top_k, bias,
+                               cfg.moe_norm_topk_prob,
+                               cfg.routed_scaling_factor)
 
 
 def _ffn_residual(x, attn_out, h1, lp, cfg: T.TransformerConfig,
@@ -1232,7 +1227,7 @@ def _layer(x, lp, li: int, positions, cfg: T.TransformerConfig, mesh, attend,
             gate = ([_wmm("...e,ehd->...hd", h1, lp["wq_gate"])]
                     if cfg.attn_output_gate else [])
         q, k = T.qk_norm(q, k, lp, cfg)
-        if cfg.use_rope:
+        if cfg.rope_at(li):
             scaled = cfg.rope_scaled_at(li)
             q = _rope_at(q, positions, cfg, scaled)
             k = _rope_at(k, positions, cfg, scaled)

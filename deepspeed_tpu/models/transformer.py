@@ -20,6 +20,7 @@ model_implementations zoo). TPU-first design decisions:
   Llama variant; learned positions, LayerNorm, gelu for GPT-2.
 """
 
+import contextlib
 import dataclasses
 import math
 from functools import partial
@@ -30,7 +31,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..ops.attention import causal_attention, uses_flash
-from ..utils.profiler import LAYER_STACK, ZERO_GATHER
+from ..utils.profiler import EXPERT_BIAS_UPDATE, LAYER_STACK, ZERO_GATHER
 
 DP = ("data", "zero", "expert")
 
@@ -284,6 +285,12 @@ class TransformerConfig:
     # CHOICE of the top-k alone (the weights stay the unbiased scores):
     # leaf `expert_bias` [n_experts] in every routed layer
     moe_expert_bias: bool = False
+    # TRAINING. What the step moves `expert_bias` by (loss-free
+    # balancing, arXiv 2408.15664, as torchtitan writes it): after each
+    # optimizer step, with c the step's count of tokens that chose each
+    # expert, d = rate * sign(mean(c) - c), b <- b + d - mean(d). No
+    # gradient, no moment, no decay (step_state_rule). 0: b stays.
+    expert_bias_update_rate: float = 0.0
     # ---- Granite's scalars and its attention without positions.
     # SERVING ONLY. position_embedding "none": no rotary and no learned
     # positions, nothing (None: the variant's). The embedding's rows
@@ -292,6 +299,11 @@ class TransformerConfig:
     # then x + m ffn(norm2 x)); the logits DIVIDED by logits_scaling;
     # attention's softmax scale (None: head_dim^-0.5).
     position_embedding: Optional[str] = None
+    # rotary on the WINDOWED layers alone (window_for_layer > 0): a
+    # full-attention layer of a model of mixed windows has no positions
+    # at all (Trinity-class `afmoe`). Training and serving both honour
+    # it (rope_at); it needs the rotary family and a window pattern.
+    rope_windowed_only: bool = False
     embedding_multiplier: float = 1.0
     residual_multiplier: float = 1.0
     logits_scaling: float = 1.0
@@ -361,6 +373,14 @@ class TransformerConfig:
             raise ValueError(
                 f"unknown position_embedding {self.position_embedding!r} "
                 "(None: the variant's; 'none')")
+        if self.rope_windowed_only and not (
+                self.use_rope and self.attention_window_pattern is not None):
+            raise ValueError(
+                "rope_windowed_only rotates the windowed layers of a model "
+                "that has rotary positions and an attention_window_pattern: "
+                f"it contradicts position_embedding "
+                f"{self.position_embedding!r}, variant {self.variant!r}, "
+                f"alibi {self.alibi} or a model with no pattern")
         if self.moe_scoring not in ("softmax", "sigmoid"):
             raise ValueError(
                 f"unknown moe_scoring {self.moe_scoring!r} (softmax|sigmoid)")
@@ -432,10 +452,14 @@ class TransformerConfig:
                 raise ValueError(
                     f"bad attention_window_pattern {p} (non-empty, "
                     "entries >= 0; 0 = global)")
-            if self.depth % len(p):
+            if self.n_layers % len(p) and not self.n_dense_layers:
                 raise ValueError(
                     f"attention_window_pattern length {len(p)} must "
-                    f"divide n_layers {self.depth}")
+                    f"divide n_layers {self.n_layers}")
+            # (behind leading dense layers the pattern is indexed by the
+            # MODEL's layer and the stacked ones need not be whole
+            # periods: the training scan steps over the whole ones and
+            # unrolls the rest, forward_hidden)
             if self.pipeline_stages > 1 or self.random_ltd_layer_range:
                 raise NotImplementedError(
                     "attention_window_pattern with pipeline/random-LTD "
@@ -546,6 +570,13 @@ class TransformerConfig:
         return not (self.rope_scaling_full_only
                     and self.window_for_layer(li) > 0)
 
+    def rope_at(self, li: int) -> bool:
+        """Whether layer li rotates q and k at all: every layer of the
+        rotary family, or its windowed layers alone
+        (rope_windowed_only: a full layer then has no positions)."""
+        return self.use_rope and not (
+            self.rope_windowed_only and self.window_for_layer(li) == 0)
+
     @property
     def is_latent(self) -> bool:
         return self.kv_lora_rank > 0
@@ -558,18 +589,17 @@ class TransformerConfig:
     @property
     def serving_only(self) -> Tuple[str, ...]:
         """The fields set here that only inference/model.py computes:
-        the training forward refuses a configuration that has any."""
-        return tuple(k for k in ("kv_lora_rank", "sandwich_norm",
-                                 "n_shared_experts", "n_dense_layers",
-                                 "experts_held", "layer_types",
-                                 "moe_expert_bias", "attn_output_gate",
+        the training forward refuses a configuration that has any.
+        (What it computes of the serving families' settings since PR 55,
+        on the paths _training_refusal names: sandwich_norm,
+        n_shared_experts, n_dense_layers, experts_held, moe_expert_bias,
+        moe_scoring "sigmoid", attn_output_gate, embedding_multiplier.)"""
+        return tuple(k for k in ("kv_lora_rank", "layer_types",
                                  "shared_expert_gate", "position_embedding",
                                  "attention_multiplier",
                                  "rope_scaling_full_only", "mixer_only")
                      if getattr(self, k)) + tuple(
-            k for k, plain in (("moe_scoring", "softmax"),
-                               ("ssm_groups", 1),
-                               ("embedding_multiplier", 1.0),
+            k for k, plain in (("ssm_groups", 1),
                                ("residual_multiplier", 1.0),
                                ("logits_scaling", 1.0))
             if getattr(self, k) != plain) + (
@@ -695,6 +725,15 @@ class TransformerConfig:
     def depth(self) -> int:
         """Layers a token passes: leading dense + stacked."""
         return self.n_dense_layers + self.n_layers
+
+    @property
+    def carries_census(self) -> bool:
+        """Whether each routed layer hands its census out of the training
+        forward (aux_width): where the step keeps state that reads it
+        (`expert_bias`, moe_expert_bias) or a held share whose pairs a
+        counter tells (experts_held)."""
+        return self.n_experts > 0 and (
+            self.moe_expert_bias or self.experts_held is not None)
 
     @property
     def n_experts_held(self) -> int:
@@ -1012,7 +1051,11 @@ def init(cfg: TransformerConfig, rng) -> Dict[str, Any]:
             full = (depth,) + shape
             if "ln" in name or name.endswith("_scale"):
                 out[name] = jnp.broadcast_to(norm_init(shape, name), full).copy()
-            elif name.startswith("b"):
+            elif name.startswith("b") or (
+                    name == "expert_bias" and cfg.expert_bias_update_rate):
+                # a bias the step itself moves starts at 0, as its
+                # published training does (a served tree keeps its
+                # seeded one: nothing would move it off 0)
                 out[name] = jnp.zeros(full, jnp.float32)
             else:
                 scale = std / (2 * cfg.depth) ** 0.5 if name in (
@@ -1363,12 +1406,15 @@ def _dropout(x, rate: float, rng):
 
 
 def _attention_delta(h, lp, cfg: TransformerConfig, rng=None, positions=None,
-                     window: Optional[int] = None):
+                     window: Optional[int] = None, rope: bool = True):
     """Attention branch over the NORMED input h; returns the residual
     DELTA (the layer body composes sequential vs parallel residuals).
 
     window: per-layer sliding window override (attention_window_pattern
-    layers); None = cfg.sliding_window."""
+    layers); None = cfg.sliding_window. rope: whether THIS layer rotates
+    (cfg.rope_at: a full layer of a rope_windowed_only model does not)."""
+    from jax.ad_checkpoint import checkpoint_name
+
     if window is None:
         window = cfg.sliding_window
     x = h
@@ -1379,10 +1425,18 @@ def _attention_delta(h, lp, cfg: TransformerConfig, rng=None, positions=None,
         q = q + lp["bq"].astype(x.dtype)
         k = k + lp["bk"].astype(x.dtype)
         v = v + lp["bv"].astype(x.dtype)
+    gate = None
+    if cfg.attn_output_gate:
+        # named, and in NO recomputation mode's list: save_attn_qkv
+        # recomputes this one product of the layer's (recomputed) norm1
+        # in the backward, 2 T E H D operations a layer, where keeping
+        # it would hold a fourth [B, S, H, D] a layer (PERF.md §6, PR 55)
+        gate = checkpoint_name(
+            jnp.einsum("bse,ehd->bshd", h, lp["wq_gate"].astype(x.dtype)),
+            "attn_gate")
     q, k = qk_norm(q, k, lp, cfg)
-    if cfg.use_rope:
+    if cfg.use_rope and rope:
         q, k = _rope(q, k, cfg, positions=positions)
-    from jax.ad_checkpoint import checkpoint_name
 
     # named for remat="save_attn_qkv": saved q/k/v are exactly the flash
     # custom-vjp residuals, so the attention block's backward needs NO
@@ -1408,11 +1462,20 @@ def _attention_delta(h, lp, cfg: TransformerConfig, rng=None, positions=None,
         slopes = None
         if cfg.alibi:
             slopes = jnp.asarray(model_alibi_slopes(cfg))
-        out = _causal_attention(q, k, v, cfg.use_flash, alibi=slopes,
-                                window=window,
-                                block_q=cfg.flash_block_q,
-                                block_k=cfg.flash_block_k)  # [B,S,H,D]
+        # a model of mixed windows: device time by the layer's window,
+        # around the attention call alone (serving's names; metadata
+        # only, and no other model's program carries the scope)
+        with (jax.named_scope("attn_window" if window else "attn_full")
+              if cfg.mixed_windows else contextlib.nullcontext()):
+            out = _causal_attention(q, k, v, cfg.use_flash, alibi=slopes,
+                                    window=window,
+                                    block_q=cfg.flash_block_q,
+                                    block_k=cfg.flash_block_k)  # [B,S,H,D]
 
+    if gate is not None:
+        with jax.named_scope("attn_gate"):
+            out = out * jax.nn.sigmoid(
+                gate.astype(jnp.float32)).astype(out.dtype)
     out = _shard(out, DP, "seq", "model", None)
     out = jnp.einsum("bshd,hde->bse", out, lp["wo"].astype(x.dtype))
     if cfg.has_attn_out_bias:
@@ -1432,10 +1495,21 @@ def _act_fn(cfg: TransformerConfig):
     return _ACT_FNS[cfg.act_name]
 
 
-def _mlp_delta(h, lp, cfg: TransformerConfig, rng=None):
+def aux_width(cfg: TransformerConfig) -> int:
+    """Values a layer hands out of the scan beside its activations:
+    (load-balance l_aux, router z-loss), and behind them, where the
+    step's state reads it (cfg.carries_census), the layer's census:
+    the tokens that CHOSE each of the n_experts experts, held here or
+    not, and the held pairs the wire dropped (0), as float32 (exact to
+    2**24 tokens a layer a micro-batch)."""
+    return 2 + (cfg.n_experts + 1 if cfg.carries_census else 0)
+
+
+def _mlp_delta(h, lp, cfg: TransformerConfig, rng=None, dense: bool = False):
     """FFN branch over the NORMED input h; returns (residual delta,
-    moe aux losses [2] = (load-balance l_aux, router z-loss))."""
-    if cfg.n_experts > 0:
+    the layer's aux [aux_width]). dense: a leading dense layer of a
+    routed model (cfg.n_dense_layers), whose `lp` is its own leaves."""
+    if cfg.n_experts > 0 and not dense:
         return _moe_mlp_delta(h, lp, cfg, rng)
     x = h
     act = _act_fn(cfg)
@@ -1460,7 +1534,32 @@ def _mlp_delta(h, lp, cfg: TransformerConfig, rng=None):
     out = jnp.einsum("bsf,fe->bse", inner, lp["w_out"].astype(x.dtype))
     if cfg.has_mlp_bias:
         out = out + lp["b_out"].astype(x.dtype)
-    return _dropout(out, cfg.dropout, rng), jnp.zeros((2,), jnp.float32)
+    return _dropout(out, cfg.dropout, rng), jnp.zeros((aux_width(cfg),),
+                                                      jnp.float32)
+
+
+def _router_settings(cfg: TransformerConfig, lp) -> Dict[str, Any]:
+    """What the dropless router of a routed layer is called with."""
+    bias = lp.get("expert_bias")
+    return dict(
+        top_k=cfg.moe_top_k, renormalize=cfg.moe_norm_topk_prob,
+        scoring=cfg.moe_scoring,
+        # state of the step, never a parameter: the choice has no
+        # gradient, and nothing else reads the bias
+        choice_bias=None if bias is None else jax.lax.stop_gradient(bias),
+        scale=cfg.routed_scaling_factor)
+
+
+def route_tokens(cfg: TransformerConfig, lp, tokens):
+    """(idx [T, K], weights [T, K]) the dropless routed block of layer
+    weights `lp` gives tokens [T, E] with no noise: the training step's
+    own router (moe/dropless.route under `_router_settings`), alone, so
+    that it can be held to a reference on that reference's inputs."""
+    from ..moe.dropless import route
+
+    idx, weights, _, _ = route(tokens, lp["w_router"],
+                               **_router_settings(cfg, lp))
+    return idx, weights
 
 
 def _moe_mlp_delta(h, lp, cfg: TransformerConfig, rng=None):
@@ -1502,6 +1601,11 @@ def _moe_mlp_delta(h, lp, cfg: TransformerConfig, rng=None):
         from ..moe.dropless import dropless_moe_ffn
 
         ep = int(jax.sharding.get_abstract_mesh().shape.get("expert", 1))
+        if cfg.experts_held is not None and ep > 1:
+            raise NotImplementedError(
+                "experts_held under an 'expert' mesh axis: a held share "
+                "is ONE chip's slice of an expert-parallel job, the axis "
+                "is the job itself")
         res = dropless_moe_ffn(
             tokens,
             lp["w_router"],
@@ -1511,12 +1615,12 @@ def _moe_mlp_delta(h, lp, cfg: TransformerConfig, rng=None):
             b_in=lp.get("b_in"),
             b_out=lp.get("b_out"),
             act=act,
-            top_k=cfg.moe_top_k,
-            renormalize=cfg.moe_norm_topk_prob,
             rng=gate_rng,
             noisy_gate_policy=cfg.moe_noisy_gate_policy,
             shard=shard,
             ep_size=ep,
+            held=cfg.experts_held,
+            **_router_settings(cfg, lp),
         )
         out, l_aux, z_loss = res.out, res.l_aux, res.z_loss
     else:
@@ -1534,6 +1638,18 @@ def _moe_mlp_delta(h, lp, cfg: TransformerConfig, rng=None):
         )
         z_loss = jnp.float32(0.0)
     out = out.reshape(B, S, E)
+    if cfg.n_shared_experts:
+        # the shared expert: a dense MLP of n_shared_experts * d_ff every
+        # token passes, on every chip alike, unweighted (serving's
+        # _moe_shared; its sigmoid gate stays serving's alone)
+        with jax.named_scope("moe_shared"):
+            up = jnp.einsum("bse,ef->bsf", h, lp["ws_in"].astype(x.dtype))
+            inner = (act(jnp.einsum("bse,ef->bsf", h,
+                                    lp["ws_gate"].astype(x.dtype))) * up
+                     if cfg.is_gated else act(up))
+            inner = _shard(inner, DP, "seq", "model")
+            out = out + jnp.einsum("bsf,fe->bse", inner,
+                                   lp["ws_out"].astype(x.dtype))
     if cfg.moe_use_residual:
         # PR-MoE (ref: moe/layer.py use_residual — moe and a dense
         # residual expert mixed by a learned softmax coefficient)
@@ -1557,6 +1673,10 @@ def _moe_mlp_delta(h, lp, cfg: TransformerConfig, rng=None):
     out = _shard(out, DP, "seq", None)
     aux = jnp.stack([l_aux.astype(jnp.float32),
                      z_loss.astype(jnp.float32)])
+    if cfg.carries_census:
+        aux = jnp.concatenate([
+            aux, res.counts.astype(jnp.float32),
+            jnp.asarray(res.dropped, jnp.float32)[None]])
     return _dropout(out, cfg.dropout, rng), aux
 
 
@@ -1575,7 +1695,7 @@ def _wants_rng(cfg: TransformerConfig) -> bool:
 
 def _make_layer_body(cfg: TransformerConfig, use_rng: bool, positions=None,
                      pld_theta=None, window: Optional[int] = None,
-                     gather=None):
+                     gather=None, rope: bool = True, dense: bool = False):
     """One transformer layer as a scan body (shared by the flat
     scan-over-layers path, the pipelined per-stage path, and the
     random-LTD subset segment — which passes the subset's original
@@ -1592,7 +1712,11 @@ def _make_layer_body(cfg: TransformerConfig, use_rng: bool, positions=None,
     runtime/progressive_layer_drop.py, arXiv 2010.13369). Each layer is
     skipped with prob (l+1)/L * (1 - theta) (the paper's depth-increasing
     schedule); the skip is a `lax.cond`, so a dropped layer's compute is
-    actually skipped at runtime, not masked."""
+    actually skipped at runtime, not masked.
+
+    rope: whether the layer rotates q and k (cfg.rope_at). dense: a
+    LEADING dense layer of a routed model: xs holds its `dense_<name>`
+    leaves under their plain names, its FFN is dense."""
 
     def layer_body(carry, xs):
         if pld_theta is not None:
@@ -1607,6 +1731,11 @@ def _make_layer_body(cfg: TransformerConfig, use_rng: bool, positions=None,
         if gather is not None:
             lp = gather(lp)
 
+        def post(y, name):
+            # sandwich form: a second norm on the sub-layer's OUTPUT,
+            # inside its scope, before the residual add
+            return _norm(y, lp[name], None, cfg) if cfg.sandwich_norm else y
+
         def run(h0):
             # named scopes land in every HLO op's metadata op_name, so
             # the xplane/chrome trace attributes MEASURED device time to
@@ -1616,8 +1745,9 @@ def _make_layer_body(cfg: TransformerConfig, use_rng: bool, positions=None,
                 h1 = _act_quant(
                     _norm(h0, lp["ln1_scale"], lp.get("ln1_bias"), cfg), cfg)
             with jax.named_scope("attention"):
-                attn = _attention_delta(h1, lp, cfg, r1, positions=positions,
-                                        window=window)
+                attn = post(_attention_delta(
+                    h1, lp, cfg, r1, positions=positions, window=window,
+                    rope=rope), "ln1_post_scale")
             if cfg.parallel_residual:
                 # Falcon/Phi form: both branches read the SAME residual
                 # stream (shared_ln additionally shares the norm)
@@ -1626,7 +1756,8 @@ def _make_layer_body(cfg: TransformerConfig, use_rng: bool, positions=None,
                         _norm(h0, lp["ln2_scale"], lp.get("ln2_bias"), cfg),
                         cfg)
                 with jax.named_scope("mlp"):
-                    mlp, l_aux = _mlp_delta(h2, lp, cfg, r2)
+                    mlp, l_aux = _mlp_delta(h2, lp, cfg, r2, dense)
+                    mlp = post(mlp, "ln2_post_scale")
                 h = h0 + attn + mlp
             else:
                 hmid = h0 + attn
@@ -1635,7 +1766,8 @@ def _make_layer_body(cfg: TransformerConfig, use_rng: bool, positions=None,
                         _norm(hmid, lp["ln2_scale"], lp.get("ln2_bias"), cfg),
                         cfg)
                 with jax.named_scope("mlp"):
-                    mlp, l_aux = _mlp_delta(h2, lp, cfg, r2)
+                    mlp, l_aux = _mlp_delta(h2, lp, cfg, r2, dense)
+                    mlp = post(mlp, "ln2_post_scale")
                 h = hmid + mlp
             h = _shard(h, DP, "seq", None)
             return h, l_aux
@@ -1645,7 +1777,8 @@ def _make_layer_body(cfg: TransformerConfig, use_rng: bool, positions=None,
         p_keep = 1.0 - (idx + 1.0) / cfg.n_layers * (1.0 - pld_theta)
         keep = jax.random.bernoulli(r_pld, p_keep)
         return jax.lax.cond(
-            keep, run, lambda h: (h, jnp.zeros((2,), jnp.float32)), h0
+            keep, run,
+            lambda h: (h, jnp.zeros((aux_width(cfg),), jnp.float32)), h0
         )
 
     if cfg.remat == "full":
@@ -1703,6 +1836,39 @@ def _make_layer_body(cfg: TransformerConfig, use_rng: bool, positions=None,
     return layer_body
 
 
+def _training_refusal(cfg: TransformerConfig, ltd: bool = False,
+                      pipelined: bool = False) -> None:
+    """Refuse, by name, what the training forward does not compute: the
+    settings only serving does (cfg.serving_only), and the settings it
+    computes on SOME of its paths asked for on another."""
+    if cfg.serving_only:
+        raise NotImplementedError(
+            f"the training forward does not compute {list(cfg.serving_only)}: "
+            "this family is served (inference/model.py) and not trained here")
+    routed = [k for k, on in (
+        ("experts_held", cfg.experts_held is not None),
+        ("moe_expert_bias", cfg.moe_expert_bias),
+        ("moe_scoring", cfg.moe_scoring != "softmax"),
+        ("routed_scaling_factor", cfg.routed_scaling_factor != 1.0)) if on]
+    if routed and not cfg.moe_dropless:
+        raise NotImplementedError(
+            f"{routed} are computed by the dropless wire alone "
+            "(moe/dropless.py): set moe_dropless; the capacity-factor "
+            "gates (moe/sharded_moe.py) do not know them")
+    if routed and cfg.moe_noisy_gate_policy is not None:
+        raise NotImplementedError(f"{routed} with a noisy gate")
+    stack = [k for k, on in (
+        ("n_dense_layers", cfg.n_dense_layers > 0),
+        ("embedding_multiplier", cfg.embedding_multiplier != 1.0),
+        ("experts_held", cfg.experts_held is not None),
+        ("moe_expert_bias", cfg.moe_expert_bias)) if on]
+    if stack and (pipelined or cfg.pipeline_stages > 1 or ltd
+                  or cfg.random_ltd_layer_range):
+        raise NotImplementedError(
+            f"{stack} with pipeline stages or random-LTD: the flat "
+            "scan-over-layers forward alone computes them")
+
+
 def forward_hidden(
     params: Dict[str, Any], tokens, cfg: TransformerConfig, rng=None,
     with_aux: bool = False, ltd_idx=None, pld_theta=None,
@@ -1717,12 +1883,11 @@ def forward_hidden(
     pld_theta: traced scalar keep-floor for Progressive Layer Dropping
     (requires rng; eval passes rng=None, which disables PLD like the
     reference's eval forward)."""
-    if cfg.serving_only:
-        raise NotImplementedError(
-            f"the training forward does not compute {list(cfg.serving_only)}: "
-            "this family is served (inference/model.py) and not trained here")
+    _training_refusal(cfg, ltd=ltd_idx is not None)
     with jax.named_scope("embed"):
         x = params["embed"][tokens]
+        if cfg.embedding_multiplier != 1.0:
+            x = x * cfg.embedding_multiplier
         x = _shard(x, DP, "seq", None)
         if cfg.use_learned_pos:
             x = x + params["pos_embed"][: tokens.shape[1]].astype(x.dtype)
@@ -1748,7 +1913,22 @@ def forward_hidden(
 
         layers = unpartition_layers(layers, virtual=cfg.pipeline_virtual_stages)
 
-    layer_rngs = jax.random.split(rng, cfg.n_layers) if use_rng else None
+    # a key a layer of the model's depth: the leading dense layers take
+    # the first, the stacked ones the rest
+    nd = cfg.n_dense_layers
+    layer_rngs = jax.random.split(rng, cfg.depth) if use_rng else None
+    W = aux_width(cfg)
+    for d in range(nd):
+        # the leading dense layers, before the scanned stack, from their
+        # `dense_<name>` leaves (one unrolled body each: they are few)
+        lp = {k[len(DENSE_PREFIX):]: v[d] for k, v in params.items()
+              if k.startswith(DENSE_PREFIX)}
+        body = _make_layer_body(
+            cfg, use_rng, window=cfg.window_for_layer(d),
+            rope=cfg.rope_at(d), dense=True)
+        x, _ = body(x, (lp, layer_rngs[d]) if use_rng else lp)
+    if use_rng:
+        layer_rngs = layer_rngs[nd:]
 
     def seg(x_in, lo, hi, body):
         lp = jax.tree.map(lambda t: t[lo:hi], layers)
@@ -1767,33 +1947,46 @@ def forward_hidden(
         # PERIODS — the body runs len(pattern) sublayers, each with its
         # own window, and xs leaves carry a [n_periods, p, ...] leading
         # shape (the length-divides check lives in __post_init__)
+        # (the pattern is indexed by the MODEL's layer: stacked layer j
+        # is layer nd + j, and so is whether it rotates, cfg.rope_at)
         p = len(cfg.attention_window_pattern)
         bodies = [
             _make_layer_body(cfg, use_rng, pld_theta=pld_theta,
-                             window=cfg.window_for_layer(j))
+                             window=cfg.window_for_layer(nd + j),
+                             rope=cfg.rope_at(nd + j))
             for j in range(p)
         ]
 
         def period_body(carry, xs):
-            h, aux = carry, jnp.zeros((2,), jnp.float32)
+            h, aux = carry, []
             for j in range(p):
                 sub = jax.tree.map(lambda t: t[j], xs)
                 h, l_aux = bodies[j](h, sub)
-                aux = aux + l_aux
-            return h, aux
+                aux.append(l_aux)
+            return h, jnp.stack(aux)  # [p, W]: a row a layer
 
         def seg(x_in, lo, hi, body):  # noqa: F811 — pattern grouping
             assert lo == 0 and hi == cfg.n_layers
-            group = lambda t: t.reshape(t.shape[0] // p, p, *t.shape[1:])
-            lp = jax.tree.map(group, layers)
+            whole = hi // p * p  # the layers of whole periods
+            group = lambda t: t[:whole].reshape(whole // p, p, *t.shape[1:])
+            idxs = jnp.arange(hi, dtype=jnp.float32)
             if pld_theta is not None:
-                xs = (lp, group(layer_rngs),
-                      group(jnp.arange(cfg.n_layers, dtype=jnp.float32)))
+                xs = (layers, layer_rngs, idxs)
             elif use_rng:
-                xs = (lp, group(layer_rngs))
+                xs = (layers, layer_rngs)
             else:
-                xs = lp
-            return _layer_scan(jax.lax.scan, period_body, x_in, xs)
+                xs = layers
+            h, aux = _layer_scan(jax.lax.scan, period_body, x_in,
+                                 jax.tree.map(group, xs))
+            aux = [jnp.reshape(aux, (-1, W))]
+            for j in range(hi - whole):
+                # behind leading dense layers the stacked ones need not
+                # be whole periods: the rest, unrolled (layer whole + j
+                # has body j's window)
+                h, l_aux = bodies[j](
+                    h, jax.tree.map(lambda t: t[whole + j], xs))
+                aux.append(l_aux[None])
+            return h, jnp.concatenate(aux)
 
     if ltd_idx is not None and cfg.random_ltd_layer_range is not None:
         # Random-LTD: layers in [a, b) see only the kept tokens (at their
@@ -1811,16 +2004,23 @@ def forward_hidden(
         h_sub, aux2 = seg(h_sub, a, b, sub_body)
         x = x.at[jnp.arange(B)[:, None], ltd_idx].set(h_sub)
         x, aux3 = seg(x, b, cfg.n_layers, layer_body)
-        aux_sum = (jnp.sum(jnp.reshape(aux1, (-1, 2)), axis=0)
-                   + jnp.sum(jnp.reshape(aux2, (-1, 2)), axis=0)
-                   + jnp.sum(jnp.reshape(aux3, (-1, 2)), axis=0))
+        aux = jnp.concatenate([jnp.reshape(a, (-1, W))
+                               for a in (aux1, aux2, aux3)])
     else:
         x, aux = seg(x, 0, cfg.n_layers, layer_body)
-        aux_sum = jnp.sum(jnp.reshape(aux, (-1, 2)), axis=0)
+    aux = jnp.reshape(aux, (-1, W))  # a row a stacked layer, in order
+    aux_sum = jnp.sum(aux[:, :2], axis=0)
     with jax.named_scope("norm_f"):
         out = _norm(x, params["ln_f_scale"], params.get("ln_f_bias"), cfg)
     if with_aux:
-        return out, {"moe_aux_loss": aux_sum[0], "moe_z_loss": aux_sum[1]}
+        losses = {"moe_aux_loss": aux_sum[0], "moe_z_loss": aux_sum[1]}
+        if cfg.carries_census:
+            # [n_layers, n_experts]: the tokens that chose each expert
+            # in each routed layer (every chosen pair, held here or not)
+            losses["moe_census"] = jnp.round(aux[:, 2:-1]).astype(jnp.int32)
+            losses["moe_pairs_dropped"] = jnp.round(
+                jnp.sum(aux[:, -1])).astype(jnp.int32)
+        return out, losses
     return out
 
 
@@ -1895,11 +2095,16 @@ def _token_mean_ce(x, head, targets, mask, n_chunks: int, head_b=None):
     return tot / jnp.maximum(cnt, 1.0)
 
 
-def make_loss_fn(cfg: TransformerConfig, loss_chunks: int = 8):
+def make_loss_fn(cfg: TransformerConfig, loss_chunks: int = 8,
+                 has_aux: bool = False):
     """Next-token cross-entropy over batch {"tokens": [B, S(+1)]}.
 
     loss_chunks: sequence-chunked CE (memory: [B, S/chunks, V] instead of
-    [B, S, V]); 1 disables chunking."""
+    [B, S, V]); 1 disables chunking. has_aux: return (loss, aux) for an
+    engine built with has_aux, aux the flat dict of what the step reads
+    beside the loss: `moe_census` [n_layers, n_experts] int32 where the
+    model hands it out (cfg.carries_census; step_state_rule reads it)
+    and `moe_pairs_dropped`, the held pairs its wire did not compute."""
 
     def loss_fn(params, batch, rng):
         tokens = batch["tokens"]
@@ -1923,12 +2128,72 @@ def make_loss_fn(cfg: TransformerConfig, loss_chunks: int = 8):
             # Load-balancing aux loss, coefficient per the reference's
             # Megatron-DeepSpeed recipe (ref: sharded_moe.py l_aux
             # usage), plus the ST-MoE router z-loss (dropless routing).
+            # A sigmoid router has neither (dropless_moe_ffn hands 0).
             loss = loss + cfg.moe_aux_loss_coef * aux["moe_aux_loss"]
             if cfg.moe_z_loss_coef:
                 loss = loss + cfg.moe_z_loss_coef * aux["moe_z_loss"]
+        if has_aux:
+            return loss, {k: v for k, v in aux.items()
+                          if k in ("moe_census", "moe_pairs_dropped")}
         return loss
 
     return loss_fn
+
+
+def step_state_rule(cfg: TransformerConfig):
+    """The state of a routed model's train step that is not the
+    optimizer's (runtime/engine.py StepStateRule; pass it to
+    ds.initialize with has_aux and make_loss_fn(..., has_aux=True)):
+    every routed layer's `expert_bias`, moved after the optimizer's
+    update by the census of the step (cfg.expert_bias_update_rate), and
+    the counters of a step's routing, read back with the loss:
+    `moe_pairs_routed` (tokens x k x routed layers), `moe_pairs_held`
+    (of them, on the experts held here), `moe_rows_per_expert_max` /
+    `_min` (over the held experts of every layer), `moe_pairs_dropped`
+    (held pairs the wire did not compute: 0) and `expert_bias_abs_max`.
+    None for a model that hands out no census."""
+    if not cfg.carries_census:
+        return None
+    from ..runtime.engine import StepStateRule
+
+    rate = cfg.expert_bias_update_rate
+    start, count = cfg.experts_held or (0, cfg.n_experts)
+
+    def update(state, aux):
+        c = aux["moe_census"].astype(jnp.float32)  # [n_layers, X]
+
+        def move(b):  # the one state leaf: layers.expert_bias [n_layers, X]
+            d = rate * jnp.sign(jnp.mean(c, -1, keepdims=True) - c)
+            return b + d - jnp.mean(d, -1, keepdims=True)
+
+        new = jax.tree.map(move, state)
+        held = c[:, start:start + count]
+        metrics = {
+            "moe_pairs_routed": jnp.sum(c),
+            "moe_pairs_held": jnp.sum(held),
+            "moe_rows_per_expert_max": jnp.max(held),
+            "moe_rows_per_expert_min": jnp.min(held),
+            "expert_bias_abs_max": jnp.max(jnp.stack(
+                [jnp.max(jnp.abs(b)) for b in jax.tree.leaves(new)]
+                or [jnp.float32(0.0)])),
+        }
+        return new, metrics
+
+    return StepStateRule(
+        is_state=lambda path: path.endswith("['expert_bias']"),
+        update=update, scope=EXPERT_BIAS_UPDATE,
+        counters={"moe_pairs_routed": "sum", "moe_pairs_held": "sum",
+                  "moe_pairs_dropped": "sum",
+                  "moe_rows_per_expert_max": "max",
+                  "moe_rows_per_expert_min": "min",
+                  "expert_bias_abs_max": "last"},
+        ids={"experts_held": count, "experts_total": cfg.n_experts,
+             # rows of the held wire's list a TOKEN (dropless.
+             # held_rows_bound / T): what no skew can pass
+             "pair_rows_bound": min(cfg.moe_top_k, count),
+             "dense_layers": cfg.n_dense_layers,
+             "window_pattern": ",".join(
+                 map(str, cfg.attention_window_pattern or ()))})
 
 
 # ---------------------------------------------------------------------------
@@ -1958,6 +2223,7 @@ def make_pipelined_loss_fn(cfg: TransformerConfig, loss_chunks: int = 8):
         stage_slice_keys,
     )
 
+    _training_refusal(cfg, pipelined=True)
     n_stage = cfg.pipeline_stages
     v = cfg.pipeline_virtual_stages
     if cfg.n_layers % (max(n_stage, 1) * v) != 0:

@@ -26,6 +26,20 @@ Two dispatch wires share one gating authority:
   _AllToAll:95) with 'expert'-axis replica groups, which the schedule
   analyzer (S005/S007) attributes per step.
 
+A chip that holds a SHARE of the experts under expert parallelism
+(`held=(start, count)`, TransformerConfig.experts_held) routes over the
+full width and takes the ragged wire over its own pairs alone
+(_held_wire): the pairs that landed on a held expert are sorted to the
+front of a static list of T x min(k, count) rows, the trivial bound no
+skew can pass, so no held pair is ever dropped, walked in chunks of
+2 T rows: the first always, a later one only where the held pairs
+reach it; pairs routed elsewhere add nothing (their chips add it). On a
+v5e the grouped product's time follows the rows INSIDE its groups, with
+a smaller charge a buffer row, and it leaves the rows OUTSIDE them
+unwritten, forward and backward (PERF.md §6, PR 55: 5.4 ms forward +
+backward for 16,384 live rows of E 2048 x F 1024 in a buffer of as
+many, 10.8 ms in one of 131,072, 37.7 ms with all of those live).
+
 Gate math runs in fp32 regardless of compute dtype (the reference
 casts at TopKGate.forward) and generalizes to any top_k <= n_experts:
 selection by `lax.top_k` over the (optionally noised) logits, combine
@@ -55,6 +69,9 @@ class DroplessOut:
     l_aux: Any    # scalar fp32 load-balance loss (1.0 at uniform)
     z_loss: Any   # scalar fp32 router z-loss (ST-MoE logsumexp^2)
     counts: Any   # [X] int32 tokens routed per expert (the census)
+    # held pairs the wire did NOT compute (scalar int32): 0 by the
+    # buffer's bound, counted all the same (the held wire alone)
+    dropped: Any = 0
 
 
 def router_z_loss(logits) -> jnp.ndarray:
@@ -63,6 +80,30 @@ def router_z_loss(logits) -> jnp.ndarray:
     without saturating (arXiv 2202.08906 eq. 5)."""
     lse = jax.scipy.special.logsumexp(logits.astype(jnp.float32), axis=-1)
     return jnp.mean(jnp.square(lse))
+
+
+def sigmoid_topk_gating(logits, top_k: int, bias=None,
+                        renormalize: Optional[bool] = None,
+                        scale: float = 1.0):
+    """Sigmoid-scored top-k (DeepSeek-V3 class routers): each expert's
+    score is the sigmoid of its own logit, in float32; the k largest
+    are chosen (ties to the lowest index), their scores divided by
+    their sum when `renormalize`, then multiplied by `scale`. `bias`
+    [X] (a layer's `expert_bias`) is added to the scores for the CHOICE
+    alone: the weights are the chosen experts' unbiased scores. No
+    groups. The one place it is written: serving
+    (inference/model.py _sigmoid_topk_gating) and the training gate
+    below call it. logits [T, X] f32 -> (idx [T, k] int32, weights
+    [T, k] f32)."""
+    scores = jax.nn.sigmoid(logits)
+    if bias is not None:
+        _, idx = jax.lax.top_k(scores + bias.astype(jnp.float32), top_k)
+        wts = jnp.take_along_axis(scores, idx, axis=-1)
+    else:
+        wts, idx = jax.lax.top_k(scores, top_k)
+    if renormalize:
+        wts = wts / (jnp.sum(wts, axis=-1, keepdims=True) + 1e-20)
+    return idx, wts * scale
 
 
 def dropless_topk_gating(
@@ -191,6 +232,116 @@ def _ragged_wire(tokens, idx, weights, counts, w_in, w_out, w_gate,
         return jax.ops.segment_sum(ys * wf[:, None], src, num_segments=T)
 
 
+def held_rows_bound(n_tokens: int, top_k: int, held_count: int) -> int:
+    """Rows of the held wire's buffer: a token's k choices are distinct
+    experts, so at most min(k, count) of them are held. No skew passes
+    it: nothing is ever dropped."""
+    return n_tokens * min(top_k, held_count)
+
+
+def held_chunk_rows(n_tokens: int, top_k: int, held_count: int) -> int:
+    """Rows of one chunk of the held wire's list: 2 T, or T where the
+    bound is an odd multiple of T."""
+    return (2 if min(top_k, held_count) % 2 == 0 else 1) * n_tokens
+
+
+def _held_wire(tokens, idx, weights, counts, held, w_in, w_out, w_gate,
+               act, impl):
+    """The ragged wire of a chip that holds experts [start, start +
+    count) of a full-width router: the pairs that landed on a held
+    expert sort to the front of a list of held_rows_bound rows by held
+    expert (stable: a pure function of the routing); the list is walked
+    in CHUNKS of 2 T rows (T where min(k, count) is odd), each one
+    gather, one grouped product a projection over the part of every
+    expert's run that lies in the chunk, one weighted segment sum back
+    to the tokens. The FIRST chunk always runs; a later one that starts
+    past the last held pair is skipped at run time (`lax.cond`): an
+    even router's T pairs a layer, and up to twice as many, take ONE
+    chunk, the worst skew all of them, and none is ever dropped. A
+    chunk costs its rows whether they are live or not (the gather, the
+    selects, the segment sum: 15.4 ms of a 650 ms step), so a layer
+    whose load crosses 2 T pays a second chunk (with chunks of T rows,
+    the even load itself, a step took one chunk or two a layer by the
+    batch's luck), and a layer that holds NO pair pays the first all
+    the same: a chip's step takes the time of its shape at any load up
+    to 2 T a layer, as the steps of the job's other chips do. A job
+    that trains has no such layer; a run of one chip's cut alone has,
+    and a first chunk skipped there turns three stray tokens into 15 ms
+    a layer, on or off by the seed (PERF.md §6, PR 55, third round).
+    Finer chunks would smooth the second chunk's step
+    and were not taken: 32 chunks of T / 4 rows compiled to 13.7 GB of
+    temporaries for a described v5e where these take 5.5 (PERF.md §6,
+    PR 55). Each chunk is recomputed in the backward
+    (`jax.checkpoint`), so the wire holds one chunk's rows at a time
+    whatever the bound. idx, weights [T, K] over all X experts; counts
+    [X] the full census. Returns (out [T, E], held pairs NOT computed,
+    by the groups of the products that ran: 0)."""
+    start, count = held
+    T, K = idx.shape
+    bound = held_rows_bound(T, K, count)
+    C = held_chunk_rows(T, K, count)
+    n_chunks = bound // C
+    with jax.named_scope("moe_route"):
+        local = idx.reshape(-1) - start
+        is_held = (local >= 0) & (local < count)
+        # held pairs first, by held expert; the rest behind them
+        order = jnp.argsort(jnp.where(is_held, local, count),
+                            stable=True)[:bound]
+        src = (order // K).reshape(n_chunks, C)
+        live = is_held[order]
+        wf = weights.reshape(-1)[order].astype(tokens.dtype).reshape(
+            n_chunks, C)
+        held_counts = jax.lax.dynamic_slice_in_dim(
+            counts.astype(jnp.int32), start, count)
+        ends = jnp.cumsum(held_counts)
+        n_held = ends[-1]
+
+    @jax.checkpoint
+    def chunk(c, src_c, wf_c, live_c):
+        """What the pairs of rows [c C, (c + 1) C) of the list add to
+        the tokens (its inputs are all the backward keeps of it). A row
+        past the last held pair lies in no group: what a grouped product
+        leaves there, forward or backward, is whatever the buffer held
+        (`ragged_dot` skips it: that is why its time follows the live
+        rows), so such a row is SELECTED away at both ends, where it
+        comes in and where it goes out: a weight of 0 would turn an Inf
+        there into a NaN of every token's."""
+        lo = c * C
+
+        def run():
+            with jax.named_scope("moe_route"):
+                # the part of each held expert's run inside [lo, lo + C)
+                part = jnp.clip(jnp.minimum(ends, lo + C)
+                                - jnp.maximum(ends - held_counts, lo), 0, C)
+                rows = jnp.where(live_c[:, None], tokens[src_c], 0)
+            with jax.named_scope("moe_experts"):
+                ys = _expert_mlp_sorted(rows, None, part, w_in, w_out,
+                                        w_gate, None, None, act, impl)
+            with jax.named_scope("moe_combine"):
+                return jax.ops.segment_sum(
+                    jnp.where(live_c[:, None], ys * wf_c[:, None], 0),
+                    src_c, num_segments=T), jnp.sum(part)
+
+        return jax.lax.cond(
+            (c == 0) | (lo < n_held), run,
+            lambda: (jnp.zeros_like(tokens), jnp.int32(0)))
+
+    # (the sum rides AROUND the checkpointed chunk: inside it, every
+    # chunk's incoming sum would be kept for the backward)
+    def add(carry, xs):
+        y, rows_run = chunk(*xs)
+        return (carry[0] + y, carry[1] + rows_run), None
+
+    (out, computed), _ = jax.lax.scan(
+        add, (jnp.zeros_like(tokens), jnp.int32(0)),
+        (jnp.arange(n_chunks, dtype=jnp.int32), src, wf,
+         live.reshape(n_chunks, C)))
+    # held pairs no chunk multiplied: a wrong gradient if ever above 0,
+    # so it is counted from the groups of the products that RAN (a
+    # chunk skipped wrongly, or a run cut at a chunk's edge, shows here)
+    return out, n_held - computed
+
+
 def _a2a_wire(tokens, idx, weights, ep_size, w_in, w_out, w_gate,
               b_in, b_out, act, shard):
     """EP=N wire: group-local dispatch into the [G, X, C, E] frame with
@@ -254,6 +405,30 @@ def dropless_apply(
                         w_out, w_gate, b_in, b_out, act, impl)
 
 
+def route(tokens, router_w, *, top_k: int = 1,
+          renormalize: Optional[bool] = None, rng=None,
+          noisy_gate_policy: Optional[str] = None,
+          scoring: str = "softmax", choice_bias=None, scale: float = 1.0):
+    """The router of dropless_moe_ffn, alone: float32 logits of the
+    compute-dtype tokens [T, E] over ALL of router_w's [E, X] experts,
+    then the gate `scoring` names. The one place the training router's
+    precision is written, so what holds it to a reference holds the
+    step's (benchmarks/runners/train_routed.py feeds it the reference's
+    own layer inputs). Returns (idx [T, K] int32, weights [T, K] f32,
+    l_aux, z_loss)."""
+    logits = tokens.astype(jnp.float32) @ router_w.astype(jnp.float32)
+    if scoring == "sigmoid":
+        idx, weights = sigmoid_topk_gating(
+            logits, top_k, choice_bias, renormalize, scale)
+        return idx, weights, jnp.float32(0.0), jnp.float32(0.0)
+    if choice_bias is not None or scale != 1.0:
+        raise NotImplementedError(
+            "a choice bias or a scale on a softmax router")
+    return dropless_topk_gating(
+        logits, top_k, rng=rng, noisy_gate_policy=noisy_gate_policy,
+        renormalize=renormalize)
+
+
 def dropless_moe_ffn(
     tokens,          # [T, E] flattened tokens, compute dtype
     router_w,        # [E, X]
@@ -271,6 +446,10 @@ def dropless_moe_ffn(
     shard=None,      # fn(x, *mesh axis names) sharding constraint
     ep_size: int = 1,
     impl: str = "ragged",
+    scoring: str = "softmax",
+    choice_bias=None,  # [X]: added to the scores for the CHOICE alone
+    scale: float = 1.0,
+    held: Optional[Tuple[int, int]] = None,
 ) -> DroplessOut:
     """Dropless dispatch -> grouped expert MLP -> combine.
 
@@ -280,13 +459,28 @@ def dropless_moe_ffn(
     padding — the serving path and the EP=1 training path). Both wires
     share the gating authority, so the routed math is identical and
     EP=1 == EP=N up to float reassociation (test-pinned).
+
+    scoring "sigmoid" (with `choice_bias` and `scale`): the
+    sigmoid_topk_gating router, which has neither auxiliary loss (both
+    are handed back 0). held (start, count): router_w spans all X
+    experts, the stacks hold `count`; the held wire computes this
+    chip's pairs alone. `counts` is the FULL census either way.
     """
-    logits = tokens.astype(jnp.float32) @ router_w.astype(jnp.float32)
-    idx, weights, l_aux, z_loss = dropless_topk_gating(
-        logits, top_k, rng=rng, noisy_gate_policy=noisy_gate_policy,
-        renormalize=renormalize)
-    counts = expert_counts(idx, w_in.shape[0])
-    if ep_size > 1 and tokens.shape[0] % ep_size == 0:
+    with jax.named_scope("moe_route"):
+        idx, weights, l_aux, z_loss = route(
+            tokens, router_w, top_k=top_k, renormalize=renormalize, rng=rng,
+            noisy_gate_policy=noisy_gate_policy, scoring=scoring,
+            choice_bias=choice_bias, scale=scale)
+        counts = expert_counts(idx, router_w.shape[-1])
+    if held is not None:
+        if b_in is not None or b_out is not None or ep_size > 1:
+            raise NotImplementedError(
+                "a held share of experts with biases or an expert axis")
+        out, dropped = _held_wire(tokens, idx, weights, counts, held, w_in,
+                                  w_out, w_gate, act, impl)
+        return DroplessOut(out=out, l_aux=l_aux, z_loss=z_loss,
+                           counts=counts, dropped=dropped)
+    elif ep_size > 1 and tokens.shape[0] % ep_size == 0:
         out = _a2a_wire(tokens, idx, weights, ep_size, w_in, w_out,
                         w_gate, b_in, b_out, act, shard)
     else:

@@ -70,6 +70,43 @@ class TrainState:
     loss_scale: Any  # LossScaleState or None
 
 
+@dataclasses.dataclass(frozen=True)
+class StepStateRule:
+    """Leaves of the parameter tree that are STATE of the train step and
+    no parameters of the optimizer (a router's `expert_bias`, moved by
+    the step's census and by no gradient: models/transformer.py
+    step_state_rule). The engine keeps them in the tree (master, the
+    compute copy, checkpoints) and out of everything that is the
+    optimizer's: the differentiation, the gradient reduction, the
+    clipping norm, the moments, weight decay. After the optimizer's
+    update, inside the same program and under the device scope `scope`,
+    `update(state leaves, aux) -> (new state leaves, scalar metrics)`
+    writes them from the loss's aux (has_aux), summed over the step's
+    micro-batches; the metrics ride the step's one readback.
+
+    is_state: a leaf's `jax.tree_util.keystr` path -> whether it is
+    state. update sees the float32 master's leaves in the tree's own
+    structure with None where a leaf is the optimizer's. counters: a
+    metric's name -> how `engine.counters` keeps it over steps ("sum",
+    "max", "min" or "last"). ids: what the span `train.init.shapes`
+    says of the model whose state this is."""
+
+    is_state: Callable[[str], bool]
+    update: Callable[[Any, Any], Any]
+    scope: str
+    counters: Dict[str, str] = dataclasses.field(default_factory=dict)
+    ids: Dict[str, Any] = dataclasses.field(default_factory=dict)
+
+
+def _is_none(x) -> bool:
+    return x is None
+
+
+# how `engine.counters` keeps a metric over steps (StepStateRule.counters)
+_KEEP = {"sum": lambda a, b: a + b, "max": max, "min": min,
+         "last": lambda a, b: b}
+
+
 def master_copy(params):
     """The float32 view of the stored params a step updates where no
     master is kept (device scope `param_cast`)."""
@@ -97,6 +134,7 @@ class DeepSpeedTPUEngine:
         init_rng: Optional[Any] = None,
         pipelined: bool = False,
         pipeline_virtual_stages: Optional[int] = None,
+        state_rule: Optional[StepStateRule] = None,
     ):
         """`params` is either a concrete pytree, or (with `param_init_fn`)
         a pytree of ShapeDtypeStructs or None (then `eval_shape` of
@@ -119,6 +157,9 @@ class DeepSpeedTPUEngine:
         self.config = config
         self.loss_fn = loss_fn
         self.has_aux = has_aux
+        self.state_rule = state_rule
+        # always-on sums of the step's scalar metrics the rule names
+        self.counters: Dict[str, float] = {}
         self.pipelined = pipelined
         self._pipe_virtual = (int(pipeline_virtual_stages)
                               if pipeline_virtual_stages else None)
@@ -262,6 +303,8 @@ class DeepSpeedTPUEngine:
                 combined["tp"], combined["opt"], shapes, self.mesh,
                 jnp.dtype(self.compute_dtype).itemsize)
             shapes_span.set(**self.zero_layout)
+            if state_rule is not None:
+                shapes_span.set(**state_rule.ids)
         log_dist(f"engine: zero layout {self.zero_layout}", ranks=[0])
         self.tp_specs = combined["tp"]
         self.param_specs = combined["storage"]
@@ -363,6 +406,14 @@ class DeepSpeedTPUEngine:
             opt_params["dp"] = int(
                 self.mesh.shape["data"] * self.mesh.shape["zero"]
             )
+        if state_rule is not None and (
+                not has_aux or pipelined or self._offload or self._onebit
+                or self._zoadam or self._qgz):
+            raise NotImplementedError(
+                "state_rule rides the fused train step and reads the "
+                "loss's aux: it needs has_aux and composes with neither a "
+                "pipelined loss, offload_optimizer, 1-bit / 0-1 Adam nor "
+                "zero_quantized_gradients")
         self.optimizer: Optimizer = build_optimizer(opt_block.type, opt_params)
         if self._zoadam:
             # host-side replica of the deterministic 0/1 Adam schedule
@@ -588,6 +639,32 @@ class DeepSpeedTPUEngine:
     # ref: partition_parameters.py Init:780 — here params are placed
     # sharded by jit out_shardings instead of patched __init__s)
     # ------------------------------------------------------------------
+    def _leaves_where(self, tree, state: bool):
+        """`tree` with None where a leaf's being the step's state
+        (state_rule.is_state of its path) is not `state`."""
+        is_state = self.state_rule.is_state
+        return jax.tree_util.tree_map_with_path(
+            lambda path, x: x if is_state(
+                jax.tree_util.keystr(path)) == state else None, tree)
+
+    def _optimizers(self, tree):
+        """`tree` (parameters, their gradients, specs or shardings) with
+        None where a leaf is the step's state and not the optimizer's
+        (state_rule): what the optimizer, the clipping and the gradient
+        path see. The tree itself where there is no rule."""
+        return (tree if self.state_rule is None
+                else self._leaves_where(tree, False))
+
+    def _step_state(self, tree):
+        """The complement of `_optimizers`: the state leaves alone."""
+        return self._leaves_where(tree, True)
+
+    @staticmethod
+    def _rejoin(a, b):
+        """One tree of two that hold None where the other holds a leaf."""
+        return jax.tree.map(lambda x, y: y if x is None else x, a, b,
+                            is_leaf=_is_none)
+
     def _init_state(self, params, param_init_fn=None, init_rng=None) -> TrainState:
         if self._offload:
             return self._init_state_offload(params, param_init_fn, init_rng)
@@ -600,7 +677,7 @@ class DeepSpeedTPUEngine:
             params_f32 = cast_params(params, jnp.float32)
             master = cast_params(params_f32, jnp.float32) if self._use_master else None
             stored = cast_params(params_f32, self.compute_dtype)
-            opt = self.optimizer.init(params_f32)
+            opt = self.optimizer.init(self._optimizers(params_f32))
             ls = init_loss_scale(self.config.fp16) if self.config.fp16.enabled else None
             return TrainState(
                 step=jnp.zeros((), jnp.int32),
@@ -615,7 +692,8 @@ class DeepSpeedTPUEngine:
         abstract_params = jax.tree.map(
             lambda p: jax.ShapeDtypeStruct(p.shape, p.dtype), params
         )
-        opt_struct = jax.eval_shape(lambda p: self.optimizer.init(p), abstract_params)
+        opt_struct = jax.eval_shape(
+            lambda p: self.optimizer.init(self._optimizers(p)), abstract_params)
         opt_shardings = {}
         for k in opt_struct.keys():
             if k.startswith(("error_", "worker_")):
@@ -629,9 +707,10 @@ class DeepSpeedTPUEngine:
                 # accumulation b1*mu + (1-b1)*g_w needs the full tree on
                 # every worker, and sharding it would re-introduce an
                 # fp32 allgather per step (master + nu still shard)
-                opt_shardings[k] = shd.tree_shardings(self.param_specs, mesh)
+                opt_shardings[k] = self._optimizers(
+                    shd.tree_shardings(self.param_specs, mesh))
             else:
-                opt_shardings[k] = o_shd
+                opt_shardings[k] = self._optimizers(o_shd)
         # every step program constrains its opt/master outputs to this
         # layout, so (a) phase-switching optimizers (1-bit warmup →
         # compressed) never see a layout drift XLA chose for one program
@@ -782,18 +861,26 @@ class DeepSpeedTPUEngine:
             getattr(self, "_overlap_schedule", None))
 
     def _make_accumulator(self):
-        """(master_f32, batch, base_rng, scale, step) -> (mean grads, loss).
+        """(master_f32, batch, base_rng, scale, step) -> (mean grads, loss,
+        aux).
 
         The shared gradient path: GAS micro-scan with ZeRO grad-layout
         constraints (or one pipelined whole-batch call). Used by the
-        fused train step and by the offload grad step."""
+        fused train step and by the offload grad step. aux: the loss's
+        own (has_aux), summed over the micro-batches, else None. Under
+        a state_rule the gradients are the optimizer's leaves' alone
+        (None at the step's state: nothing differentiates, reduces or
+        accumulates there)."""
         cfg = self.config
         gas = cfg.gradient_accumulation_steps
         mesh = self.mesh
-        grad_specs = self.grad_specs
+        grad_specs = self._optimizers(self.grad_specs)
         compute_dtype = self.compute_dtype
         has_aux = self.has_aux
         pipelined = self.pipelined
+        optimizers, step_state = self._optimizers, self._step_state
+        rejoin = self._rejoin
+        ruled = self.state_rule is not None
         qwz_apply = self._qwz_apply
         compression = self._compression
         pld = cfg.progressive_layer_drop
@@ -827,7 +914,7 @@ class DeepSpeedTPUEngine:
                     grads = jax.tree.map(
                         lambda g, s: shd.constraint(g, s, mesh),
                         grads, grad_specs)
-                return grads, jnp.mean(losses)
+                return grads, jnp.mean(losses), None
 
             return accumulate_qgz
 
@@ -848,10 +935,11 @@ class DeepSpeedTPUEngine:
                 def scaled_loss(m):
                     p = to_model_params(m)
                     out = loss_fn(p, with_pld(batch, step), base_rng)
-                    l, _aux = out if has_aux else (out, None)
-                    return l * scale, l
+                    l, aux = out if has_aux else (out, None)
+                    return l * scale, (l, aux)
 
-                grads, loss = jax.grad(scaled_loss, has_aux=True)(master)
+                grads, (loss, aux) = jax.grad(
+                    scaled_loss, has_aux=True)(master)
                 with jax.named_scope(profiler.GRAD_REDUCE):
                     inv = 1.0 / scale
                     if bucket_mb > 0:
@@ -865,7 +953,12 @@ class DeepSpeedTPUEngine:
                             lambda g, s: shd.constraint(g, s, mesh),
                             grads, grad_specs)
                         grads = jax.tree.map(lambda g: g * inv, grads)
-                return grads, loss
+                return grads, loss, aux
+
+            # the optimizer's leaves are differentiated; the step's
+            # state (state_rule) rides into the loss beside them
+            held = step_state(master) if ruled else None
+            master = optimizers(master)
 
             def micro(carry, xs):
                 acc, loss_sum = carry
@@ -873,12 +966,13 @@ class DeepSpeedTPUEngine:
                 rng = jax.random.fold_in(base_rng, idx)
 
                 def scaled_loss(m):
-                    p = to_model_params(m)
+                    p = to_model_params(rejoin(m, held) if ruled else m)
                     out = loss_fn(p, with_pld(micro_batch, step), rng)
                     loss, aux = out if has_aux else (out, None)
-                    return loss * scale, loss
+                    return loss * scale, (loss, aux)
 
-                grads, loss = jax.grad(scaled_loss, has_aux=True)(master)
+                grads, (loss, aux) = jax.grad(
+                    scaled_loss, has_aux=True)(master)
                 # ZeRO>=2: constrain per-micro grads to the sharded layout →
                 # XLA reduce-scatters inside the accumulation loop
                 # (ref: stage_1_and_2.py overlap_comm reduction during bwd).
@@ -896,7 +990,7 @@ class DeepSpeedTPUEngine:
                             grads, grad_specs,
                         )
                         acc = jax.tree.map(jnp.add, acc, grads)
-                return (acc, loss_sum + loss), None
+                return (acc, loss_sum + loss), aux
 
             with jax.named_scope(profiler.GRAD_REDUCE):
                 zeros = jax.tree.map(
@@ -906,13 +1000,15 @@ class DeepSpeedTPUEngine:
                     grad_specs,
                 )
             idxs = jnp.arange(gas)
-            (grads, loss_sum), _ = jax.lax.scan(
+            (grads, loss_sum), auxs = jax.lax.scan(
                 micro, (zeros, jnp.float32(0.0)), (idxs, batch)
             )
             with jax.named_scope(profiler.GRAD_REDUCE):
                 inv = 1.0 / (gas * scale)
                 grads = jax.tree.map(lambda g: g * inv, grads)
-            return grads, loss_sum / gas
+            # the loss's aux, summed over the step's micro-batches
+            aux = jax.tree.map(lambda a: jnp.sum(a, axis=0), auxs)
+            return grads, loss_sum / gas, aux
 
         return accumulate
 
@@ -990,6 +1086,9 @@ class DeepSpeedTPUEngine:
         accumulate = self._make_accumulator()
         fetch_params = self._make_param_fetch()
         finish = self._make_finalizer()
+        rule = self.state_rule
+        optimizers, step_state = self._optimizers, self._step_state
+        rejoin = self._rejoin
 
         # runtime non-finite gradient guard (integrity block,
         # docs/fault_tolerance.md SDC section): outside fp16 a NaN/Inf
@@ -1006,7 +1105,11 @@ class DeepSpeedTPUEngine:
             scale = state.loss_scale.scale if fp16 else jnp.float32(1.0)
             base_rng = jax.random.fold_in(jax.random.PRNGKey(seed), state.step)
 
-            grads, loss = accumulate(master, batch, base_rng, scale, state.step)
+            grads, loss, aux = accumulate(
+                master, batch, base_rng, scale, state.step)
+            # what the optimizer sees of the master: all of it, or all
+            # but the step's own state (state_rule)
+            full_master, master = master, optimizers(master)
 
             with jax.named_scope(profiler.GRAD_CLIP):
                 grad_norm = global_grad_norm(grads)
@@ -1051,6 +1154,21 @@ class DeepSpeedTPUEngine:
             }
             if fp16:
                 metrics["loss_scale"] = new_ls.scale
+            if aux is not None:
+                # the loss's aux leaves the program beside the loss
+                metrics.update(aux)
+            if rule is not None:
+                # state of the step: written from the aux AFTER the
+                # optimizer's update, by no gradient (a skipped step
+                # leaves it as it leaves the master)
+                with jax.named_scope(rule.scope):
+                    old = step_state(full_master)
+                    new, more = rule.update(old, aux)
+                    if fp16 or nonfinite_guard:
+                        new = jax.tree.map(
+                            lambda n, o: jnp.where(found_inf, o, n), new, old)
+                metrics.update(more)
+                new_master = rejoin(new_master, new)
             return finish(new_master, new_opt, new_step, new_ls, metrics)
 
         # donated: every TrainState leaf aliases the returned TrainState
@@ -1329,7 +1447,8 @@ class DeepSpeedTPUEngine:
         def grad_fn(params, step, batch):
             master = master_copy(fetch_params(params))
             base_rng = jax.random.fold_in(jax.random.PRNGKey(seed), step)
-            grads, loss = accumulate(master, batch, base_rng, jnp.float32(1.0), step)
+            grads, loss, _ = accumulate(
+                master, batch, base_rng, jnp.float32(1.0), step)
             with jax.named_scope(profiler.GRAD_CLIP):
                 return grads, loss, global_grad_norm(grads)
 
@@ -2022,8 +2141,13 @@ class DeepSpeedTPUEngine:
         # single host transfer for all metrics (device sync point) — per-key
         # float() would pay one device round trip per metric; the sync-free
         # path is train_batch_async
-        metrics = {k: float(v) for k, v in jax.device_get(metrics).items()}  # ds-lint: ok R002 the one deliberate per-step sync
+        # (an aux leaf of the loss that is no scalar, as a census, stays
+        # the host array it arrives as)
+        metrics = {k: float(v) if np.ndim(v) == 0 else v
+                   for k, v in jax.device_get(metrics).items()}  # ds-lint: ok R002 the one deliberate per-step sync
         ph.mark("post")
+        if self.state_rule is not None:
+            self._count(metrics)
         # the step's time is the phases' own stamps: prepare + launch +
         # readback (BATCH_TIMER, the throughput timer and the spans
         # share one clock reading)
@@ -2063,9 +2187,18 @@ class DeepSpeedTPUEngine:
             )
             self.flops_profiler.print_profile()
         self.monitor.write_events(
-            [(f"Train/{k}", v, self.global_steps) for k, v in metrics.items()]
+            [(f"Train/{k}", v, self.global_steps) for k, v in metrics.items()
+             if np.ndim(v) == 0]
         )
         return metrics
+
+    def _count(self, metrics: Dict[str, Any]) -> None:
+        """Keep the step's metrics the state rule names in `counters`,
+        each as the rule says: summed, or the extreme or the last seen."""
+        for name, how in self.state_rule.counters.items():
+            v = metrics[name]
+            self.counters[name] = (_KEEP[how](self.counters[name], v)
+                                   if name in self.counters else v)
 
     def eval_batch(self, batch) -> float:
         """Loss-only forward (ref: pipe engine eval_batch)."""

@@ -150,7 +150,8 @@ _ARCH_OF_MODEL_TYPE = {"olmoe": "OlmoeForCausalLM",
                        "qwen3_next": "Qwen3NextForCausalLM",
                        "granitemoehybrid": "GraniteMoeHybridForCausalLM",
                        "mellum": "MellumForCausalLM",
-                       "nemotron_h": "NemotronHForCausalLM"}
+                       "nemotron_h": "NemotronHForCausalLM",
+                       "afmoe": "AfmoeForCausalLM"}
 # config.json keys that change what a BLOCK computes (latent attention,
 # shared experts, leading dense layers, a second norm, a scaled, grouped
 # or biased router, layers of another kind than attention): an
@@ -188,9 +189,17 @@ _MIXER_ONLY_KEYS = (
     "hybrid_override_pattern", "mamba_num_heads", "mamba_head_dim",
     "ssm_state_size", "n_groups", "mlp_hidden_act", "mamba_hidden_act",
     "moe_shared_expert_intermediate_size", "use_conv_bias")
+# a sigmoid router with a scale, a bias kept in balance by the step and
+# groups of one; the embedding times sqrt(E); full attention every n-th
+# layer, the others windowed (Trinity-class `afmoe`)
+_AFMOE_KEYS = (
+    "global_attn_every_n_layers", "load_balance_coeff", "mup_enabled",
+    "num_expert_groups", "num_limited_groups", "route_norm", "route_scale",
+    "score_func", "use_grouped_mm")
 _BLOCK_KEYS = tuple(dict.fromkeys(
     _LATENT_MOE_KEYS + _HYBRID_KEYS + _LINEAR_ATTENTION_KEYS
-    + _STATE_SPACE_KEYS + _MIXED_WINDOW_KEYS + _MIXER_ONLY_KEYS))
+    + _STATE_SPACE_KEYS + _MIXED_WINDOW_KEYS + _MIXER_ONLY_KEYS
+    + _AFMOE_KEYS))
 # the block keys each architecture's mapping reads; any other stays an
 # error for it too
 _READS_BLOCK_KEYS = {
@@ -209,6 +218,9 @@ _READS_BLOCK_KEYS = {
         "mamba_proj_bias", "n_routed_experts", "n_shared_experts",
         "moe_intermediate_size", "routed_scaling_factor", "n_group",
         "topk_group")),
+    "AfmoeForCausalLM": frozenset(_AFMOE_KEYS + (
+        "layer_types", "num_dense_layers", "moe_intermediate_size",
+        "n_group", "topk_group", "num_shared_experts")),
 }
 
 
@@ -225,7 +237,7 @@ def _block_key_set(hf: Dict[str, Any], key: str) -> bool:
 SUPPORTED_ARCHITECTURES = sorted(_LLAMA_FAMILY | {
     "PanguUltraMoEForCausalLM", "Lfm2MoeForCausalLM",
     "Qwen3NextForCausalLM", "GraniteMoeHybridForCausalLM",
-    "MellumForCausalLM", "NemotronHForCausalLM",
+    "MellumForCausalLM", "NemotronHForCausalLM", "AfmoeForCausalLM",
     "GPT2LMHeadModel", "OPTForCausalLM", "FalconForCausalLM",
     "RWForCausalLM",  # falcon's pre-rename arch string
     "PhiForCausalLM", "QWenLMHeadModel",
@@ -264,6 +276,8 @@ def config_from_hf(hf: Dict[str, Any], **overrides) -> TransformerConfig:
         kw = _mellum_config(hf)
     elif arch == "NemotronHForCausalLM":
         kw = _nemotron_h_config(hf)
+    elif arch == "AfmoeForCausalLM":
+        kw = _afmoe_config(hf)
     elif arch in _LLAMA_FAMILY:
         kw = dict(
             vocab_size=hf["vocab_size"],
@@ -562,6 +576,99 @@ def _held_share(hf: Dict[str, Any], key: str):
     routed = int((hf.get("reduced") or {}).get(key, {}).get("published", held))
     start = int((hf.get("experts_held") or {}).get("start", 0))
     return routed, (start, held) if held != routed else None
+
+
+def _afmoe_config(hf: Dict[str, Any]) -> Dict[str, Any]:
+    """Arcee Trinity (`afmoe`): GQA with an RMSNorm over each head of q
+    and k and a sigmoid output gate (`gate_proj`, leaf `wq_gate`), FOUR
+    RMSNorms a layer (a second one on each sub-layer's output), layers
+    `sliding_attention` (the last `sliding_window` tokens, rotary) or
+    `full_attention` (every `global_attn_every_n_layers`-th: NO
+    positions at all) as `layer_types` names them, `num_dense_layers`
+    leading dense SwiGLUs of `intermediate_size`, then routed layers:
+    `num_experts` experts of `moe_intermediate_size` beside
+    `num_shared_experts` shared, `score_func` sigmoid scores in
+    float32, the top `num_experts_per_tok` of score + `expert_bias`
+    chosen, their unbiased scores over their sum (`route_norm`) times
+    `route_scale`; the embedding times sqrt(hidden_size)
+    (`mup_enabled`). `load_balance_coeff` is the step by which training
+    moves `expert_bias` (TransformerConfig.expert_bias_update_rate;
+    there is no auxiliary loss). `use_grouped_mm` chooses between two
+    implementations of the same expert products in the publisher's
+    code and decides nothing here. Groups (`n_group`, `topk_group`,
+    `num_expert_groups`, `num_limited_groups`) are read and must be 1.
+
+    A cut that is one chip's share of an expert-parallel job gives under
+    `num_experts` what the chip HOLDS, under
+    `reduced.num_experts.published` the router's width,
+    `experts_held.start` the first held expert (0 if absent)."""
+    L, every = hf["num_hidden_layers"], hf.get("global_attn_every_n_layers")
+    window = int(hf.get("sliding_window") or 0)
+    types = list(hf.get("layer_types") or ())
+    for key in ("n_group", "topk_group", "num_expert_groups",
+                "num_limited_groups"):
+        if hf.get(key, 1) != 1:
+            raise ValueError(
+                f"afmoe with {key}={hf[key]!r}: a router that chooses "
+                "within groups of experts is unsupported (1 alone)")
+    if hf.get("rope_scaling") is not None \
+            or hf.get("score_func", "sigmoid") != "sigmoid" \
+            or hf.get("hidden_act", "silu") != "silu" \
+            or hf.get("attention_bias"):
+        raise ValueError(
+            "afmoe with a rope_scaling, another score_func than sigmoid, "
+            "another hidden_act than silu or attention_bias is unsupported "
+            f"(got rope_scaling {hf.get('rope_scaling')!r}, score_func "
+            f"{hf.get('score_func')!r}, hidden_act {hf.get('hidden_act')!r})")
+    if not every or every < 2 or window <= 0:
+        raise ValueError(
+            "afmoe needs global_attn_every_n_layers >= 2 and a "
+            "sliding_window: its layers are windowed but every n-th")
+    want = ["full_attention" if (i + 1) % every == 0 else "sliding_attention"
+            for i in range(L)]
+    if types != want:
+        raise ValueError(
+            f"afmoe layer_types must name full_attention for every "
+            f"{every}-th of the {L} layers (global_attn_every_n_layers) "
+            f"and sliding_attention for the others (got {types})")
+    n_dense = int(hf.get("num_dense_layers") or 0)
+    if not 0 <= n_dense < L:
+        raise ValueError(
+            f"afmoe with {L} layers of which {n_dense} dense: at least "
+            "one layer is routed")
+    routed, experts_held = _held_share(hf, "num_experts")
+    kw = dict(
+        vocab_size=hf["vocab_size"],
+        n_layers=L - n_dense,
+        n_heads=hf["num_attention_heads"],
+        n_kv_heads=hf.get("num_key_value_heads") or None,
+        d_model=hf["hidden_size"],
+        d_ff=hf["moe_intermediate_size"],
+        head_dim_override=int(hf["head_dim"]) if hf.get("head_dim") else None,
+        max_seq=hf.get("max_position_embeddings", 4096),
+        variant="llama",
+        rope_theta=float(hf.get("rope_theta", 10000.0)),
+        norm_eps=float(hf.get("rms_norm_eps", 1e-5)),
+        tie_embeddings=bool(hf.get("tie_word_embeddings", False)),
+        # the pattern by the model's layer, dense layers first
+        attention_window_pattern=(window,) * (every - 1) + (0,),
+        rope_windowed_only=True,
+        qk_norm=True, qk_norm_per_head=True, attn_output_gate=True,
+        sandwich_norm=True,
+        n_experts=routed, experts_held=experts_held,
+        moe_top_k=hf["num_experts_per_tok"],
+        moe_scoring="sigmoid", moe_expert_bias=True,
+        moe_norm_topk_prob=bool(hf.get("route_norm", False)),
+        routed_scaling_factor=float(hf.get("route_scale", 1.0)),
+        expert_bias_update_rate=float(hf.get("load_balance_coeff") or 0.0),
+        n_shared_experts=int(hf.get("num_shared_experts") or 0),
+        moe_dropless=True, moe_aux_loss_coef=0.0,
+        embedding_multiplier=(float(hf["hidden_size"]) ** 0.5
+                              if hf.get("mup_enabled") else 1.0),
+    )
+    if n_dense:
+        kw.update(n_dense_layers=n_dense, dense_d_ff=hf["intermediate_size"])
+    return kw
 
 
 def _pangu_ultra_moe_config(hf: Dict[str, Any]) -> Dict[str, Any]:

@@ -75,9 +75,12 @@ STALL_LOG_LINES = 8
 # name every instruction the program itself wrote; a reader takes the
 # OUTERMOST of the names it asks for.
 (PARAM_CAST, GRAD_REDUCE, GRAD_CLIP, OPTIMIZER, ZERO_GATHER,
- LAYER_STACK) = TRAIN_STEP_SCOPES = (
+ LAYER_STACK, EXPERT_BIAS_UPDATE) = TRAIN_STEP_SCOPES = (
     "param_cast", "grad_reduce", "grad_clip", "optimizer", "zero_gather",
-    "layer_stack")
+    "layer_stack",
+    # a routed model's step state, written after the optimizer's update
+    # (models/transformer.py step_state_rule)
+    "expert_bias_update")
 MODEL_SCOPES = ("embed", "norm1", "attention", "norm2", "mlp", "norm_f",
                 "lm_head")
 

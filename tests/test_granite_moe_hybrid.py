@@ -248,8 +248,9 @@ def test_the_cut_builds_at_published_widths():
     # carried inputs in a slot of 72
     assert cfg.state_shapes("state_space") == (
         ((64, 128, 128), jnp.float32), ((3, 72, 128), None))
-    assert set(cfg.serving_only) >= {
-        "layer_types", "position_embedding", "embedding_multiplier",
+    # (embedding_multiplier trains since PR 55: tests/test_trinity.py)
+    assert set(cfg.serving_only) == {
+        "layer_types", "position_embedding",
         "residual_multiplier", "logits_scaling", "attention_multiplier"}
     shapes = jax.eval_shape(lambda k: T.init(cfg, k), jax.random.PRNGKey(0))
     assert shapes["layers"]["w_in"].shape == (10, 18, 4096, 768)
@@ -432,8 +433,7 @@ def test_the_training_forward_refuses_the_family(model):
     with pytest.raises(NotImplementedError, match="layer_types"):
         T.forward_hidden(params, jnp.zeros((1, 8), jnp.int32), mcfg)
     # and each scalar on its own, with no layers of several kinds
-    for field, value in (("embedding_multiplier", 12.0),
-                         ("residual_multiplier", 0.22),
+    for field, value in (("residual_multiplier", 0.22),
                          ("logits_scaling", 16.0),
                          ("attention_multiplier", 0.0625),
                          ("position_embedding", "none")):

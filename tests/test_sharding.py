@@ -193,6 +193,17 @@ class TestBenchmarkLayouts:
     # (128 lanes cannot be split: they shard on the layer dim)
     MOVED = {"mistral": (1, 4096 * 32000 * 2),
              "olmoe": (3, 2048 * 50304 * 2 + 2 * 8 * 16 * 128 * 2)}
+    # Trinity's cut (PR 55; its own cell trains under ZeRO-1 on ONE chip,
+    # where no spec moves): a head of an eighth of the vocabulary,
+    # 25,024 / 4 = 6,256 lanes, breaks the tile and moves; over 8 ways
+    # (3,128 rows) the embedding moves too. By the data axis's size.
+    MOVED_BY_DATA = {"afmoe": {4: (1, 2048 * 25024 * 2),
+                               8: (2, 2 * 2048 * 25024 * 2)}}
+    # leaves ZeRO shards OFF the tile: Trinity's five [layers, 128]
+    # leaves under 1 KiB each (the per-head QK-norm scales of the 4
+    # stacked and the 1 dense layer, the 4 layers' expert_bias), whose
+    # 128 lanes cannot be split and whose layer dim no axis divides
+    OFF_TILE = {"afmoe": (5, (3 * 4 + 2) * 128 * 2)}
 
     def test_storage_dim_is_optimizer_dim(self, path):
         from deepspeed_tpu.parallel.sharding import pipe3d_specs
@@ -210,10 +221,15 @@ class TestBenchmarkLayouts:
             assert s["storage"]["lm_head"] == s["opt"]["lm_head"] == (
                 P("data", "model") if "model" in axes else P("data"))
             rep = zero_layout_report(s["tp"], s["opt"], shapes, mesh, 2)
-            moved, nbytes = self.MOVED[json.loads(path.read_text())["model_type"]]
+            family = json.loads(path.read_text())["model_type"]
+            moved, nbytes = (self.MOVED[family] if family in self.MOVED
+                             else self.MOVED_BY_DATA[family][
+                                 axes["data"] * axes.get("model", 1)])
             assert rep["zero_leaves_moved"] == moved
             assert rep["zero_bytes_moved"] == nbytes
-            assert rep["zero_leaves_off_tile"] == 0
+            assert (rep["zero_leaves_off_tile"],
+                    rep["zero_bytes_off_tile"]) == self.OFF_TILE.get(
+                        family, (0, 0))
 
     def test_data1_leaves_every_spec_alone(self, path):
         # with no live ZeRO axis the function returns before any choice,

@@ -78,7 +78,7 @@ class TransformerConfig:
     # interpret request — ops/pallas.kernels_runnable) and S >= 256;
     # the jnp reference otherwise
     use_flash: bool = True
-    # flash tiling (1024x1024 fastest at S=2048/D=128; 512x1024 at S=16k)
+    # flash tiling (ops/attention.causal_attention says what was measured)
     flash_block_q: int = 512
     flash_block_k: int = 1024
     # MoE (ref: deepspeed/moe/layer.py MoE:17 knobs). n_experts > 0 turns
@@ -2137,7 +2137,39 @@ def make_loss_fn(cfg: TransformerConfig, loss_chunks: int = 8,
                           if k in ("moe_census", "moe_pairs_dropped")}
         return loss
 
+    # what the engine's span `train.init.shapes` says of this model
+    loss_fn.shape_ids = flash_census_ids(cfg)
     return loss_fn
+
+
+def flash_census_ids(cfg: TransformerConfig) -> Dict[str, str]:
+    """The tiles the flash kernels visit at cfg.max_seq, a head, by kind
+    (ops/pallas/flash_attention.tile_census: the kernels' own predicate,
+    no chip): `flash_windows`, the model's distinct windows in its
+    layers' order (0: a full layer), and under `flash_tiles_interior` /
+    `_edge` / `_general` / `flash_work_over_needed` a number a window in
+    that order. {} for a model whose attention is not these kernels'."""
+    at_max_seq = jax.ShapeDtypeStruct((1, cfg.max_seq), jnp.bfloat16)
+    windows = list(dict.fromkeys(
+        cfg.window_for_layer(li) for li in range(cfg.depth)
+        if cfg.layer_kind(li) == "attention"))
+    if (cfg.attention_impl == "ring" or not windows
+            or not uses_flash(at_max_seq, cfg.use_flash)):
+        return {}
+    from ..ops.pallas.flash_attention import tile_census
+
+    by_window = [tile_census(cfg.max_seq, w, cfg.flash_block_q,
+                             cfg.flash_block_k, alibi=cfg.alibi)
+                 for w in windows]
+
+    def a_window(key, fmt="{}"):
+        return ",".join(fmt.format(c[key]) for c in by_window)
+
+    return {"flash_windows": ",".join(map(str, windows)),
+            "flash_tiles_interior": a_window("interior"),
+            "flash_tiles_edge": a_window("edge"),
+            "flash_tiles_general": a_window("general"),
+            "flash_work_over_needed": a_window("work_over_needed", "{:.3f}")}
 
 
 def step_state_rule(cfg: TransformerConfig):

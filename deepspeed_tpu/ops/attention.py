@@ -102,8 +102,15 @@ def causal_attention(q, k, v, use_flash: bool = True, window: int = 0,
     softmax in-tile and the XLA fallback's logits identically.
 
     block_q/block_k tune the flash tiling (TransformerConfig
-    flash_block_q/k — 1024x1024 measured fastest at S=2048/D=128,
-    512x1024 at S=16384 on an earlier setup; not re-measured)."""
+    flash_block_q/k). The training cells run 1024x1024 (measured on a
+    v5e, PERF.md section 6, PR 56, the three kernels of one layer: 18.9
+    ms at B 2 / H 32 / KV 4 / S 8,192 / D 128 under a window of 2,048,
+    33.3 under none, 19.2 at B 4 / KV 8 / S 4,096). Square tiles that
+    divide the sequence and the window let the kernels skip the mask on
+    interior tiles and walk edge tiles by their triangle
+    (flash_attention.tile_census counts them); any other tiling runs
+    the mask over every tile it visits. Other tile sizes were not
+    measured on this installation."""
     if uses_flash(q, use_flash):
         # imported where it runs: jax.experimental.pallas costs ~1.5 s
         # that a training-only or CPU process should not pay at import

@@ -14,8 +14,15 @@ training). Flash-attention-2-style online softmax, with:
 - **Pallas backward**: two kernels (dq; dk/dv) recomputing probabilities
   from the saved logsumexp — replaces round 1's XLA lax.scan backward
   that materialized [BH, S, block_k] probability tiles.
-- causal masking prunes fully-masked blocks with @pl.when; the diagonal
-  band applies an iota mask.
+- **a tile does the work its kind holds** (`_tile_kinds`, one predicate
+  for the three kernels and for `tile_census`): tiles the mask empties
+  are pruned with @pl.when; an `interior` tile (every element passes)
+  runs with no iota, compare or select; a `diagonal` / `window_edge`
+  tile (a triangle passes) is walked in static slabs that multiply only
+  the run the triangle reaches (the backward kernels mask only the
+  sub-tile the edge crosses; the forward, which its mask costs nothing,
+  its slab's whole run); every tile the shapes cannot prove to be one
+  of those runs the `general` body, the iota mask over the whole tile.
 
 grid layout: the innermost grid dims are sequential on TPU, so running
 accumulators live in VMEM scratch across those steps and outputs are
@@ -28,9 +35,11 @@ kernels against torch (ref: tests/unit/ops).
 """
 
 import functools
+from typing import Any, Dict, NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -53,6 +62,165 @@ def _dot(a, b, trans_a=False, trans_b=False):
         precision=(jax.lax.Precision.DEFAULT if a.dtype == jnp.bfloat16
                    else None),
     )
+
+
+# ---------------------------------------------------------------------------
+# what a tile holds
+# ---------------------------------------------------------------------------
+
+# An edge tile is walked in slabs of its sequential axis (q rows in the
+# forward and dq, k columns in dk/dv), SLAB_* long; a tile they do not
+# divide is one slab. A slab multiplies the run of the other axis that
+# its triangle reaches, so a triangle costs (n + 1) / 2n of its tile, n
+# slabs a tile: 12/16 of a 1,024 tile at 512, 10/16 at 256. What the chip
+# measured (PERF.md section 6, PR 56): the backward kernels take the
+# time of their work down to 256 (128 ties); the forward's slabs of 256
+# run no faster than the whole masked tile and its slabs of 512 take
+# exactly 12/16 of it.
+SLAB_FWD = 512
+SLAB_BWD = 256
+
+
+def _slab(block: int, slab: int) -> int:
+    return slab if block % slab == 0 else block
+
+
+def _edge_share(block: int) -> float:
+    """Share of a tile an edge tile multiplies, over the three kernels
+    by the products a tile costs each: 2 forward, 3 dq, 4 dk/dv (the
+    split backward recomputes QK^T and dP)."""
+    def share(slab):
+        n = block // _slab(block, slab)
+        return (n + 1) / (2 * n)
+
+    return (2 * share(SLAB_FWD) + 7 * share(SLAB_BWD)) / 9
+
+
+class TileKinds(NamedTuple):
+    """What the mask leaves of the tile at (q_start, k_start): `live`,
+    any element at all; of a live tile exactly one of `interior` (every
+    element), `diagonal` (col <= row in the tile's own coordinates: the
+    lower triangle), `window_edge` (col > row: the strict upper
+    triangle) and `general` (anything else, or not proven). A flag is a
+    Python bool where the shapes alone decide it."""
+
+    live: Any
+    interior: Any
+    diagonal: Any
+    window_edge: Any
+    general: Any
+
+
+def _tile_kinds(q_start, k_start, block_q: int, block_k: int, seq_len: int,
+                causal: bool, window: int, alibi: bool) -> TileKinds:
+    """THE classification of a tile, for the kernels (traced starts) and
+    for tile_census (ints). The three special kinds exist only where
+    they are exact: square tiles that divide the sequence (no padded
+    column, and a tile's corner lies on the diagonal), a window of a
+    whole number of tiles (its edge then runs corner to corner too) and
+    no ALiBi (which biases every score). The window is judged tile by
+    tile: one that reaches past every tile of the sequence binds
+    nowhere, and leaves interior and diagonal tiles alone."""
+    live = True
+    if causal:
+        live = k_start < q_start + block_q
+    if window > 0:
+        live = live & (k_start + block_k - 1 > q_start - window)
+    if (block_q != block_k or seq_len % block_q or window % block_k
+            or alibi):
+        return TileKinds(live, False, False, False, live)
+    if not causal:
+        return TileKinds(True, True, False, False, False)
+    behind = q_start - k_start  # a multiple of the tile
+    interior = behind > 0
+    edge = False
+    if 0 < window < seq_len:
+        interior = interior & (behind < window)
+        edge = behind == window
+    return TileKinds(live, interior, behind == 0, edge, False)
+
+
+def tile_census(seq_len: int, window: int = 0, block_q: int = 512,
+                block_k: int = 1024, causal: bool = True,
+                alibi: bool = False) -> Dict[str, float]:
+    """The tiles one head's forward visits, by kind, by the kernels' own
+    predicate (each backward kernel visits the same tiles): `interior`,
+    `edge` (diagonal + window_edge), `general`; `work`, the tile units
+    multiplied (an edge tile _edge_share, every other tile 1); `needed`,
+    the tile units the mask keeps; and `work_over_needed`.
+    No chip, no trace: shapes alone, as flash_attention clamps them."""
+    bq, bk = min(block_q, seq_len), min(block_k, seq_len)
+    edge = _edge_share(bq)
+    out = {"interior": 0, "edge": 0, "general": 0, "work": 0.0}
+    for q_start in range(0, seq_len, bq):
+        for k_start in range(0, seq_len, bk):
+            kinds = _tile_kinds(q_start, k_start, bq, bk, seq_len, causal,
+                                window, alibi)
+            if not kinds.live:
+                continue
+            if kinds.diagonal or kinds.window_edge:
+                out["edge"] += 1
+                out["work"] += edge
+            else:
+                out["interior" if kinds.interior else "general"] += 1
+                out["work"] += 1.0
+    rows = np.arange(seq_len)
+    span = rows + 1 if causal else np.full(seq_len, seq_len)
+    if window > 0:
+        span = np.minimum(span, window)
+    out["needed"] = float(span.sum()) / (bq * bk)
+    out["work_over_needed"] = out["work"] / out["needed"]
+    return out
+
+
+_ALL = slice(None)
+
+
+def _when_kind(needed, kinds: TileKinds, **bodies) -> None:
+    """Run, of a needed tile, the body of its kind; a kind the shapes
+    rule out (a flag that is False before any tracing) is not built."""
+    for kind, body in bodies.items():
+        flag = getattr(kinds, kind)
+        if flag is not False:
+            pl.when(needed & flag)(body)
+
+
+def _general_mask(q_start, k_start, shape, q_axis: int, seq_len: int,
+                  causal: bool, window: int, slope):
+    """(keep, bias) of a whole tile of `shape` whose axis `q_axis` runs
+    over the q rows: the general body's iota mask (padded columns, the
+    diagonal, the window) and its ALiBi bias (None without a slope)."""
+    rows = q_start + jax.lax.broadcasted_iota(jnp.int32, shape, q_axis)
+    cols = k_start + jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis)
+    keep = cols < seq_len  # k padding
+    if causal:
+        keep = jnp.logical_and(keep, cols <= rows)
+    if window > 0:
+        keep = jnp.logical_and(keep, cols > rows - window)
+    bias = None if slope is None else slope * (cols - rows).astype(jnp.float32)
+    return keep, bias
+
+
+def _bwd_edge(part, block: int, lower: bool, q_axis: int) -> None:
+    """A backward kernel's walk of an edge tile in slabs of its
+    sequential axis (q rows where `q_axis` is 0: dq; k columns where it
+    is 1: dk/dv): `part(slab, run, keep)` for the run of the other axis
+    that lies wholly inside the triangle (no mask; where the slab has
+    one), then for the sub-tile the edge crosses. `lower`: col <= row
+    is kept (diagonal), else col > row (window_edge). A q slab of the
+    lower triangle reaches the columns BEFORE it, a k slab the rows
+    after it; the strict upper triangle the other way round."""
+    t = _slab(block, SLAB_BWD)
+    row = jax.lax.broadcasted_iota(jnp.int32, (t, t), q_axis)
+    col = jax.lax.broadcasted_iota(jnp.int32, (t, t), 1 - q_axis)
+    keep = col <= row if lower else col > row
+    before = lower == (q_axis == 0)
+    for a in range(0, block, t):
+        slab = slice(a, a + t)
+        inside = slice(0, a) if before else slice(a + t, block)
+        if inside.stop > inside.start:
+            part(slab, inside)
+        part(slab, slab, keep)
 
 
 # ---------------------------------------------------------------------------
@@ -100,40 +268,60 @@ def _fwd_kernel(
         j_abs = _win_j(i, j, block_q, block_k, window, nk_total)
         k_start = j_abs * block_k
         needed = _win_jbase(i, block_q, block_k, window, nk_total) + j < nk_total
-        if causal:
-            needed = jnp.logical_and(needed, k_start < q_start + block_q)
     else:
         k_start = j * block_k
         needed = True
-        if causal:
-            needed = k_start < q_start + block_q
+    kinds = _tile_kinds(q_start, k_start, block_q, block_k, seq_len, causal,
+                        window, alibi)
+    needed = needed & kinds.live
 
-    @pl.when(needed)
-    def _compute():
-        q = q_ref[0]
-        k = k_ref[0]
-        s = _dot(q, k, trans_b=True) * scale  # (bq, bk) f32
+    def update(rows, cols, keep=None, bias=None):
+        """One online-softmax step of the q rows `rows` over the k
+        columns `cols`: rows are independent in m / l / acc, so a slab
+        updates its own."""
+        q = q_ref[0, rows, :]
+        s = _dot(q, k_ref[0, cols, :], trans_b=True) * scale  # f32
+        if bias is not None:
+            s = s + bias
+        if keep is not None:
+            s = jnp.where(keep, s, NEG_INF)
 
-        rows = q_start + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-        cols = k_start + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-        if alibi:
-            s = s + slope * (cols - rows).astype(jnp.float32)
-        mask = cols < seq_len  # k padding
-        if causal:
-            mask = jnp.logical_and(mask, cols <= rows)
-        if window > 0:
-            mask = jnp.logical_and(mask, cols > rows - window)
-        s = jnp.where(mask, s, NEG_INF)
-
-        m_prev = m_sc[:]  # (bq, 1)
+        m_prev = m_sc[rows]  # (n, 1)
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)  # (bq, bk) f32
-        corr = jnp.exp(m_prev - m_new)  # (bq, 1)
-        l_sc[:] = l_sc[:] * corr + jnp.sum(p, axis=1, keepdims=True)
-        v = v_ref[0]
-        pv = _dot(p.astype(v.dtype), v)
-        acc_sc[:] = acc_sc[:] * corr + pv
-        m_sc[:] = m_new
+        # the old sums rescaled BEFORE the exponentials: the same
+        # operations, and the windowed layer's forward 5% faster on a
+        # v5e than with the rescale after them (PERF.md section 6, PR 56)
+        corr = jnp.exp(m_prev - m_new)  # (n, 1)
+        l = l_sc[rows] * corr
+        acc = acc_sc[rows] * corr
+        p = jnp.exp(s - m_new)  # f32
+        l = l + jnp.sum(p, axis=1, keepdims=True)
+        v = v_ref[0, cols, :]
+        acc = acc + _dot(p.astype(v.dtype), v)
+        l_sc[rows] = l
+        acc_sc[rows] = acc
+        m_sc[rows] = m_new
+
+    def edge(lower):
+        # a slab's ONE product over the columns its triangle reaches,
+        # the mask over all of them: the forward is not bound by its
+        # mask, and a second product a slab cost it more than the mask
+        t = _slab(block_q, SLAB_FWD)
+        for a in range(0, block_q, t):
+            cols = slice(0, a + t) if lower else slice(a, block_k)
+            shape = (t, cols.stop - cols.start)
+            row = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+            col = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+            update(slice(a, a + t), cols,
+                   col <= row + a if lower else col > row)
+
+    _when_kind(needed, kinds,
+               interior=lambda: update(_ALL, _ALL),
+               diagonal=lambda: edge(True),
+               window_edge=lambda: edge(False),
+               general=lambda: update(_ALL, _ALL, *_general_mask(
+                   q_start, k_start, (block_q, block_k), 0, seq_len, causal,
+                   window, slope)))
 
     @pl.when(j == nk - 1)
     def _finalize():
@@ -284,37 +472,36 @@ def _bwd_dq_kernel(
     if window > 0:
         k_start = _win_j(i, j, block_q, block_k, window, nk_total) * block_k
         needed = _win_jbase(i, block_q, block_k, window, nk_total) + j < nk_total
-        if causal:
-            needed = jnp.logical_and(needed, k_start < q_start + block_q)
     else:
         k_start = j * block_k
         needed = True
-        if causal:
-            needed = k_start < q_start + block_q
+    kinds = _tile_kinds(q_start, k_start, block_q, block_k, seq_len, causal,
+                        window, alibi)
+    needed = needed & kinds.live
 
-    @pl.when(needed)
-    def _compute():
-        q = q_ref[0]
-        k = k_ref[0]
-        s = _dot(q, k, trans_b=True) * scale  # (bq, bk) f32
+    def part(rows, cols, keep=None, bias=None):
+        """dq of the q rows `rows` gains what the k columns `cols` give."""
+        q = q_ref[0, rows, :]
+        k = k_ref[0, cols, :]
+        s = _dot(q, k, trans_b=True) * scale  # f32
+        if bias is not None:
+            s = s + bias
+        n = s.shape[0]
+        lse = lse_ref[0, :, rows].reshape(n, 1)
+        p = jnp.exp(s - lse)  # f32
+        if keep is not None:
+            p = jnp.where(keep, p, 0.0)
+        dp = _dot(do_ref[0, rows, :], v_ref[0, cols, :], trans_b=True)  # f32
+        delta = delta_ref[0, :, rows].reshape(n, 1)
+        ds = p * (dp - delta) * scale  # f32
+        dq_sc[rows] = dq_sc[rows] + _dot(ds.astype(k.dtype), k)
 
-        rows = q_start + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-        cols = k_start + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-        if alibi:
-            s = s + slope * (cols - rows).astype(jnp.float32)
-        mask = cols < seq_len
-        if causal:
-            mask = jnp.logical_and(mask, cols <= rows)
-        if window > 0:
-            mask = jnp.logical_and(mask, cols > rows - window)
-
-        lse = lse_ref[0].reshape(block_q, 1)  # (bq, 1)
-        p = jnp.where(mask, jnp.exp(s - lse), 0.0)  # (bq, bk) f32
-        do = do_ref[0]
-        dp = _dot(do, v_ref[0], trans_b=True)  # (bq, bk) f32
-        delta = delta_ref[0].reshape(block_q, 1)
-        ds = p * (dp - delta) * scale  # (bq, bk) f32
-        dq_sc[:] = dq_sc[:] + _dot(ds.astype(k.dtype), k)
+    _when_kind(needed, kinds, interior=lambda: part(_ALL, _ALL),
+               diagonal=lambda: _bwd_edge(part, block_q, True, 0),
+               window_edge=lambda: _bwd_edge(part, block_q, False, 0),
+               general=lambda: part(_ALL, _ALL, *_general_mask(
+                   q_start, k_start, (block_q, block_k), 0, seq_len, causal,
+                   window, slope)))
 
     @pl.when(j == nk - 1)
     def _finalize():
@@ -348,46 +535,40 @@ def _bwd_dkv_kernel(
 
     k_start = j * block_k
     if window > 0:
-        i_abs = _win_i(j, i, block_k, block_q, nq_total)
-        q_start = i_abs * block_q
+        q_start = _win_i(j, i, block_k, block_q, nq_total) * block_q
+        # rows beyond the window never see this k block (kinds.live)
         needed = _win_ibase(j, block_k, block_q) + i < nq_total
-        # rows beyond the window never see this k block
-        needed = jnp.logical_and(
-            needed, q_start <= k_start + block_k - 1 + window - 1
-        )
-        if causal:
-            needed = jnp.logical_and(needed, k_start < q_start + block_q)
     else:
         q_start = i * block_q
         needed = True
-        if causal:
-            needed = k_start < q_start + block_q
+    kinds = _tile_kinds(q_start, k_start, block_q, block_k, seq_len, causal,
+                        window, alibi)
+    needed = needed & kinds.live
 
-    @pl.when(needed)
-    def _compute():
-        q = q_ref[0]
-        k = k_ref[0]
-        # transposed orientation (bk, bq): no in-kernel transposes needed
-        s_t = _dot(k, q, trans_b=True) * scale
-
-        cols = k_start + jax.lax.broadcasted_iota(jnp.int32, (block_k, block_q), 0)
-        rows = q_start + jax.lax.broadcasted_iota(jnp.int32, (block_k, block_q), 1)
-        if alibi:
-            s_t = s_t + slope * (cols - rows).astype(jnp.float32)
-        mask = cols < seq_len
-        if causal:
-            mask = jnp.logical_and(mask, cols <= rows)
-        if window > 0:
-            mask = jnp.logical_and(mask, cols > rows - window)
-
-        lse = lse_ref[0]  # (1, bq) broadcasts over bk rows
-        p_t = jnp.where(mask, jnp.exp(s_t - lse), 0.0)  # (bk, bq) f32
-        do = do_ref[0]
-        dv_sc[:] = dv_sc[:] + _dot(p_t.astype(do.dtype), do)
-        dp_t = _dot(v_ref[0], do, trans_b=True)  # (bk, bq) f32
-        delta = delta_ref[0]  # (1, bq)
+    def part(cols, rows, keep=None, bias=None):
+        """dk / dv of the k columns `cols` gain what the q rows `rows`
+        give. Transposed orientation (k, q): no in-kernel transposes."""
+        q = q_ref[0, rows, :]
+        s_t = _dot(k_ref[0, cols, :], q, trans_b=True) * scale  # f32
+        if bias is not None:
+            s_t = s_t + bias
+        lse = lse_ref[0, :, rows]  # (1, n) broadcasts over the k rows
+        p_t = jnp.exp(s_t - lse)  # f32
+        if keep is not None:
+            p_t = jnp.where(keep, p_t, 0.0)
+        do = do_ref[0, rows, :]
+        dv_sc[cols] = dv_sc[cols] + _dot(p_t.astype(do.dtype), do)
+        dp_t = _dot(v_ref[0, cols, :], do, trans_b=True)  # f32
+        delta = delta_ref[0, :, rows]  # (1, n)
         ds_t = p_t * (dp_t - delta) * scale
-        dk_sc[:] = dk_sc[:] + _dot(ds_t.astype(q.dtype), q)
+        dk_sc[cols] = dk_sc[cols] + _dot(ds_t.astype(q.dtype), q)
+
+    _when_kind(needed, kinds, interior=lambda: part(_ALL, _ALL),
+               diagonal=lambda: _bwd_edge(part, block_k, True, 1),
+               window_edge=lambda: _bwd_edge(part, block_k, False, 1),
+               general=lambda: part(_ALL, _ALL, *_general_mask(
+                   q_start, k_start, (block_k, block_q), 1, seq_len, causal,
+                   window, slope)))
 
     @pl.when(jnp.logical_and(g == n_group - 1, i == nq - 1))
     def _finalize():

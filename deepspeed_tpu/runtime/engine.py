@@ -303,6 +303,9 @@ class DeepSpeedTPUEngine:
                 combined["tp"], combined["opt"], shapes, self.mesh,
                 jnp.dtype(self.compute_dtype).itemsize)
             shapes_span.set(**self.zero_layout)
+            # what the model says of itself: a loss function may carry
+            # `shape_ids` (models/transformer.make_loss_fn's does)
+            shapes_span.set(**getattr(loss_fn, "shape_ids", {}))
             if state_rule is not None:
                 shapes_span.set(**state_rule.ids)
         log_dist(f"engine: zero layout {self.zero_layout}", ranks=[0])

@@ -5,10 +5,14 @@ real kernel math — fwd, the Pallas dq and dk/dv backward kernels, GQA
 index maps, and the padding path. The same tests compile to Mosaic when
 run on TPU hardware."""
 
+import dataclasses
+import os
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import SingleDeviceSharding
 
 from deepspeed_tpu.ops.attention import _xla_attention, causal_attention
 # Mosaic requires the lse tile (1, block_q) to satisfy the (8,128)
@@ -16,11 +20,14 @@ from deepspeed_tpu.ops.attention import _xla_attention, causal_attention
 # lane keeps 64 for speed. Same kernels either way.
 BLK = 128 if jax.default_backend() == "tpu" else 64
 
+from deepspeed_tpu.ops.pallas import flash_attention as FA
+from deepspeed_tpu.ops.pallas import interpret_kernels
 from deepspeed_tpu.ops.pallas.flash_attention import (
 
     _flash_bwd,
     _flash_fwd,
     flash_attention,
+    tile_census,
 )
 
 pytestmark = pytest.mark.usefixtures("pallas_interpret_module")
@@ -243,3 +250,193 @@ class TestAlibi:
                                   block_k=BLK, window=40, alibi=ab)
             ref = _xla_attention(q, k, v, causal=True, window=40, alibi=ab)
         np.testing.assert_allclose(out, ref, rtol=2e-4, atol=2e-4)
+
+
+# --- a tile does the work its kind holds (_tile_kinds) -----------------
+
+# S 1,024 in tiles of 512 with slabs of 256 in all three kernels (128
+# in `slabs_128`): an edge tile is walked in 2 (4) slabs, so the static
+# slices and the masked sub-tile are real. window 0: interior + diagonal; 512: diagonal + window_edge,
+# no interior tile; 1,024 = S: a window that binds on no tile.
+KIND_CASES = {
+    "causal": dict(window=0),
+    "window_512": dict(window=512),
+    "window_is_S": dict(window=1024),
+    "slabs_128": dict(window=512, slab=128),
+    "gqa_8_2": dict(window=512, H=8, KV=2),
+    # ... and calls that must take today's body for every tile
+    "window_100": dict(window=100, general=True),
+    "window_256_of_512": dict(window=256, general=True),
+    "padded_S": dict(window=512, S=1000, general=True),
+    "alibi": dict(window=0, alibi=True, general=True),
+    "unequal_tiles": dict(window=512, block_q=256, general=True),
+}
+
+
+class TestTileKinds:
+    @pytest.mark.parametrize("case", KIND_CASES)
+    def test_fwd_and_grads_match_oracle(self, rng, case, monkeypatch):
+        c = dict(dict(S=1024, H=2, KV=None, block_q=512, alibi=False,
+                      slab=256, general=False), **KIND_CASES[case])
+        S, H, w = c["S"], c["H"], c["window"]
+        KV = c["KV"] or H
+        monkeypatch.setattr(FA, "SLAB_FWD", c["slab"])
+        monkeypatch.setattr(FA, "SLAB_BWD", c["slab"])
+        census = tile_census(S, w, c["block_q"], 512, alibi=c["alibi"])
+        assert (census["general"] > 0) == c["general"], census
+        assert (census["interior"] + census["edge"] > 0) != c["general"]
+
+        q, k, v = make_qkv(rng, B=2, S=S, H=H, KV=KV, D=64)
+        ab = (jnp.asarray(TestAlibi()._slopes(H)) if c["alibi"] else None)
+
+        def flash_fn(q, k, v):
+            return flash_attention(q, k, v, causal=True, window=w, alibi=ab,
+                                   block_q=c["block_q"], block_k=512)
+
+        def ref_fn(q, k, v):
+            n_rep = H // KV
+            return _xla_attention(q, jnp.repeat(k, n_rep, axis=2),
+                                  jnp.repeat(v, n_rep, axis=2), causal=True,
+                                  window=w, alibi=ab)
+
+        with jax.default_matmul_precision("highest"):
+            o = flash_fn(q, k, v)
+            np.testing.assert_allclose(o, ref_fn(q, k, v), rtol=2e-3,
+                                       atol=2e-3)
+            cot = jnp.asarray(rng.normal(size=o.shape), o.dtype)
+            g = jax.grad(lambda *a: jnp.vdot(flash_fn(*a), cot),
+                         argnums=(0, 1, 2))(q, k, v)
+            gr = jax.grad(lambda *a: jnp.vdot(ref_fn(*a), cot),
+                          argnums=(0, 1, 2))(q, k, v)
+        for a, b, name in zip(g, gr, "qkv"):
+            np.testing.assert_allclose(a, b, rtol=5e-3, atol=5e-3,
+                                       err_msg=f"d{name}")
+
+    @pytest.mark.parametrize("S,window,bq,bk,causal,alibi", [
+        (1024, 0, 256, 256, True, False), (1024, 512, 256, 256, True, False),
+        (1024, 1024, 256, 256, True, False), (768, 256, 256, 256, True, False),
+        (1024, 0, 256, 256, False, False), (1024, 100, 256, 256, True, False),
+        (1024, 384, 256, 256, True, False), (1000, 512, 256, 256, True, False),
+        (1024, 512, 128, 256, True, False), (1024, 0, 256, 256, True, True),
+        (96, 0, 96, 96, True, False),
+    ])
+    def test_the_predicate_is_exact_tile_by_tile(self, S, window, bq, bk,
+                                                 causal, alibi):
+        """Every tile of the padded square against the mask itself, and
+        the census against the count of what the predicate said."""
+        rows, cols = np.arange(-(-S // bq) * bq), np.arange(-(-S // bk) * bk)
+        mask = np.broadcast_to(cols[None] < S, (len(rows), len(cols)))
+        if causal:
+            mask = mask & (cols[None] <= rows[:, None])
+        if window:
+            mask = mask & (cols[None] > rows[:, None] - window)
+        seen = dict(interior=0, edge=0, general=0)
+        tri = np.tril(np.ones((bq, bk), bool))
+        for qs in rows[::bq]:
+            for ks in cols[::bk]:
+                tile = mask[qs:qs + bq, ks:ks + bk]
+                kinds = FA._tile_kinds(int(qs), int(ks), bq, bk, S, causal,
+                                       window, alibi)
+                if not tile.any():
+                    # the kernels never build a tile the mask empties
+                    assert not kinds.live or kinds.general
+                    continue
+                assert kinds.live
+                assert sum(map(bool, kinds[1:])) == 1, kinds
+                if kinds.interior:
+                    assert tile.all()
+                if kinds.diagonal:
+                    assert (tile == tri).all()
+                if kinds.window_edge:
+                    assert (tile == ~tri).all()
+                if alibi:
+                    assert kinds.general
+                if qs < S and ks < S:
+                    seen["general" if kinds.general else "interior"
+                         if kinds.interior else "edge"] += 1
+        census = tile_census(S, window, bq, bk, causal, alibi)
+        assert {k: census[k] for k in seen} == seen
+        assert census["needed"] == pytest.approx(
+            mask[:S, :S].sum() / (bq * bk))
+
+    @pytest.mark.parametrize("cell,S,window,tiles", [
+        # (interior, edge, general): ISSUE 56's table
+        ("trinity_windowed", 8192, 2048, (7, 14, 0)),
+        ("trinity_full", 8192, 0, (28, 8, 0)),
+        ("mistral", 4096, 4096, (6, 4, 0)),
+    ])
+    def test_the_census_of_the_training_cells(self, cell, S, window, tiles):
+        c = tile_census(S, window, 1024, 1024)
+        assert (c["interior"], c["edge"], c["general"]) == tiles
+        # an edge tile of 1,024: 12/16 in the forward's slabs of 512 (2
+        # products a tile), 10/16 in the backward's of 256 (3 + 4)
+        edge = (2 * 12 / 16 + 7 * 10 / 16) / 9
+        assert c["work"] == pytest.approx(tiles[0] + tiles[1] * edge)
+        assert c["work_over_needed"] == pytest.approx(
+            c["work"] / (sum(tiles) - tiles[1] / 2), rel=1e-3)
+        # today's body (one unit a live tile) ran 1.5 / 1.125 / 1.25 x
+        assert sum(tiles) / c["needed"] == pytest.approx(
+            {"trinity_windowed": 1.5, "trinity_full": 1.125,
+             "mistral": 1.25}[cell], rel=1e-3)
+
+
+def test_a_models_loss_function_carries_the_census():
+    """`flash_*` ids, a number a distinct window in the layers' order
+    (tests/test_train_step_scopes.py holds the engine to putting a loss
+    function's `shape_ids` on `train.init.shapes`); none where the
+    model runs no flash kernel."""
+    from deepspeed_tpu.models import transformer as T
+
+    mcfg = T.TransformerConfig(
+        vocab_size=256, n_layers=4, n_heads=4, d_model=64, max_seq=512,
+        variant="llama", flash_block_q=128, flash_block_k=128,
+        attention_window_pattern=(256, 0))
+    assert T.make_loss_fn(mcfg).shape_ids == {
+        "flash_windows": "256,0", "flash_tiles_interior": "3,6",
+        "flash_tiles_edge": "6,4", "flash_tiles_general": "0,0",
+        "flash_work_over_needed": "1.498,1.248"}
+    with interpret_kernels(False):  # the CPU: the jnp reference runs
+        assert T.flash_census_ids(mcfg) == {}
+    assert T.flash_census_ids(dataclasses.replace(mcfg, use_flash=False)) == {}
+
+
+# --- the cells' shapes, compiled for a described v5e (no chip) ---------
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - whatever keeps libtpu away
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("B,H,KV,S,window", [
+    (2, 32, 4, 8192, 2048), (2, 32, 4, 8192, 0), (4, 32, 8, 4096, 4096)])
+def test_a_cells_three_kernels_compile_for_v5e(one_chip, B, H, KV, S, window):
+    """Mosaic takes the slabs' static slices at the cells' tiles (the
+    interpreter takes any)."""
+    static = (True, 1024, 1024, H, KV, window, False)
+    qs, kvs = ((B * h, S, 128) for h in (H, KV))
+    sd = lambda shape, dt=jnp.bfloat16: jax.ShapeDtypeStruct(
+        shape, dt, sharding=one_chip)
+
+    def step(q, k, v, do):
+        o, lse = _flash_fwd(q, k, v, None, *static)
+        return _flash_bwd(q, k, v, None, o, lse, do, *static)
+
+    with interpret_kernels(False):
+        text = jax.jit(step).lower(sd(qs), sd(kvs), sd(kvs),
+                                   sd(qs)).compile().as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+        assert any(name in line for line in calls), name

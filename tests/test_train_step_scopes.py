@@ -651,6 +651,30 @@ def test_one_clock_for_a_steps_host_numbers():
     assert not hasattr(engine.tput, "start")
 
 
+def test_a_loss_functions_shape_ids_ride_train_init_shapes():
+    """What `models/transformer.make_loss_fn` says of its model
+    (`shape_ids`: the flash kernels' tile census) is on the engine's
+    always-kept span, beside the ZeRO layout's."""
+    from deepspeed_tpu.utils import profiler
+
+    mcfg = T.TransformerConfig(vocab_size=VOCAB, n_layers=2, n_heads=4,
+                               d_model=64, max_seq=32, variant="llama",
+                               use_flash=False)
+    loss_fn = T.make_loss_fn(mcfg)
+    assert loss_fn.shape_ids == {}  # no flash kernel: nothing to say
+    loss_fn.shape_ids = {"flash_windows": "0", "flash_tiles_interior": "6"}
+    ds.initialize(
+        {"train_micro_batch_size_per_gpu": 1, "bf16": {"enabled": True},
+         "optimizer": {"type": "adamw", "params": {"lr": 1e-3}}},
+        loss_fn=loss_fn, param_init_fn=lambda k: T.init(mcfg, k),
+        param_logical_specs=T.logical_specs(mcfg),
+        mesh=build_mesh({}, devices=jax.devices()[:1]))
+    ids = [s for s in profiler.spans()
+           if s.name == "train.init.shapes"][-1].ids
+    assert ids["flash_windows"] == "0" and ids["flash_tiles_interior"] == "6"
+    assert "zero_leaves_moved" in ids
+
+
 def test_the_docs_name_every_scope_and_id():
     import pathlib
 

@@ -1840,6 +1840,11 @@ class InferenceEngine:
             if state:
                 named.update(kv_layers=len(self.cache.k),
                              state_layers=len(self.cache.state))
+                # which implementation advances the heads' matrices: a
+                # silent fall-back to the loop over rows is seen here
+                if set(self.cfg.layer_types or ()) & set(M._STEP_OF):
+                    named["state_step"] = ("kernel" if self.step_kernel(w)
+                                           else "xla")
             for uniq in ((True, False) if chunked else (True,)):
                 rt.record(f"serving_decode[w{w},u{int(uniq)}]",
                           (toks, tables, ctx))

@@ -60,6 +60,7 @@ from ..ops.pallas.gated_delta import (
     gated_delta_chunked,
     gated_delta_step,
     gated_delta_step_xla,
+    pack_heads,
     step_fits,
 )
 from ..ops.pallas.ssm_state import (
@@ -71,6 +72,7 @@ from ..ops.pallas.ssm_state import (
 )
 from ..ops.pallas.paged_attention import (
     fused_write_fits,
+    kv_heads_held,
     kv_pack,
     kv_write_path,
     latent_lanes,
@@ -501,8 +503,12 @@ def init_cache(
         shape = (num_blocks, block_size, latent_lanes(cfg.latent_dim))
         return PagedCache(k=[jnp.zeros(shape, dtype) for _ in range(L)],
                           v=[])
-    # unquantised pools on one device pack two 64-wide heads a row
-    pack = 1 if kv_quant or mesh is not None else kv_pack(KV, D)
+    # unquantised pools on one device pack two 64-wide heads a row, and
+    # hold whole tiles of heads (30 of 128 in 32: kv_heads_held)
+    pack = 1
+    if not kv_quant and mesh is None:
+        pack = kv_pack(KV, D)
+        KV = kv_heads_held(KV, D, jnp.dtype(dtype).itemsize)
     shape = (num_blocks, block_size, KV // pack, D * pack)
     if kv_quant:
         dtype = jnp.int8
@@ -700,6 +706,8 @@ def _write_pools(pools: tuple, k_new, v_new, flat_idx, mesh=None,
     if len(pools) == 1:
         write = paged_latent_write if use_kernel else paged_latent_write_xla
         return (write(pools[0], k_new, flat_idx),)
+    k_new, v_new = (_to_heads(r, _pool_heads(pools[0], r))
+                    for r in (k_new, v_new))
     if len(pools) == 4:
         ck, cv, *scales = _write_kv_quant(*pools, k_new, v_new, flat_idx,
                                           mesh, use_kernel)
@@ -1050,6 +1058,9 @@ def _ffn_residual(x, attn_out, h1, lp, cfg: T.TransformerConfig,
     shared ln1 output h1), under the scopes the training forward
     names (`norm2`, `mlp`)."""
     m = cfg.residual_multiplier
+    if cfg.output_norm:  # the operator's norm stands on its output
+        with jax.named_scope("norm1_post"):
+            attn_out = T._norm(attn_out, lp["ln1_post_scale"], None, cfg)
     if m != 1.0:  # Granite: both branches of every layer, before the add
         attn_out = attn_out * m
     if cfg.mixer_only:  # the layer is its operator: no FFN behind it
@@ -1058,6 +1069,8 @@ def _ffn_residual(x, attn_out, h1, lp, cfg: T.TransformerConfig,
         x = x + attn_out
     if cfg.parallel_residual and cfg.shared_ln:
         h2 = h1
+    elif cfg.output_norm:  # the FFN reads the stream as it is
+        h2 = T._act_quant(x, cfg)
     else:
         with jax.named_scope("norm2"):
             h2 = T._act_quant(
@@ -1069,6 +1082,9 @@ def _ffn_residual(x, attn_out, h1, lp, cfg: T.TransformerConfig,
             y = T._norm(y, lp["ln2_post_scale"], None, cfg)
         if m != 1.0:
             y = y * m
+    if cfg.output_norm:
+        with jax.named_scope("norm2_post"):
+            y = T._norm(y, lp["ln2_post_scale"], None, cfg)
     return x + attn_out + y if cfg.parallel_residual else x + y
 
 
@@ -1096,16 +1112,33 @@ def _moe_residual(out, h, lp, cfg: T.TransformerConfig, act):
             + dense * coef[:, 1:2].astype(h.dtype))
 
 
+def _pool_heads(pool, rows) -> int:
+    """KV heads a pool [NBLK, bs, KV, D] holds, for rows [T, heads, D']:
+    0 for a PACKED pool (D' under the pool's lanes: kv_pack), whose
+    heads are never padded."""
+    return pool.shape[2] if pool.shape[3] == rows.shape[-1] else 0
+
+
+def _to_heads(x, heads: int):
+    """x [T, h, D] with zero heads appended up to `heads` (the heads a
+    pool holds, kv_heads_held: 30 in 32); as it is where it has as many
+    (or `heads` is a packed pool's 0), and None for None."""
+    if x is None or x.shape[1] >= heads:
+        return x
+    return jnp.pad(x, [(0, 0), (0, heads - x.shape[1]), (0, 0)])
+
+
 def _decode_attention(q, pools: tuple, table, ctx, use_kernel: bool,
                       window: int = 0, mesh=None, alibi=None,
-                      k_new=None, v_new=None, slots=None):
+                      k_new=None, v_new=None, slots=None, kv_heads: int = 0):
     """WHERE one decode attention call over a layer's pools
     (_layer_pools) runs. Which Pallas kernel serves it is
     paged_decode_attention's business, read there from the same
     arguments (k_new/v_new/slots: the write fused into the call, the
     pools PRE-write and the result (att, *updated pools); scale pools:
-    int8 KV; window; alibi, the [H] per-head slopes). One of three
-    places:
+    int8 KV; window; alibi, the [H] per-head slopes; kv_heads: the
+    model's own count of KV heads, where the pool may hold more,
+    kv_heads_held). One of three places:
 
     - one device (use_kernel, no 'model' axis): the kernel entry as it
       is, the only place a fused write can run;
@@ -1117,6 +1150,21 @@ def _decode_attention(q, pools: tuple, table, ctx, use_kernel: bool,
       raw pallas_call cannot consume sharded operands; SPMD partitions
       the gather freely)."""
     ck, cv, *scales = pools
+    held = _pool_heads(ck, q)
+    if kv_heads and held > kv_heads:
+        # a pool that holds whole tiles of heads (kv_heads_held): the
+        # padding heads' queries are zeros, as their rows are, and what
+        # they attend is cut off
+        H = q.shape[1]
+        wide = held * (H // kv_heads)
+        out = _decode_attention(
+            _to_heads(q, wide), pools, table, ctx, use_kernel, window, mesh,
+            None if alibi is None else jnp.pad(
+                jnp.asarray(alibi, jnp.float32), (0, wide - H)),
+            _to_heads(k_new, held), _to_heads(v_new, held), slots)
+        if k_new is None:
+            return out[:, :H]
+        return (out[0][:, :H], *out[1:])
     opt = dict(zip(("k_scale", "v_scale"), scales))  # what is present
     if alibi is not None:
         opt["alibi_slopes"] = jnp.asarray(alibi, jnp.float32)
@@ -1180,9 +1228,12 @@ def _layer(x, lp, li: int, positions, cfg: T.TransformerConfig, mesh, attend,
     and the mesh. Returns (x, layer_cache): the layer's K/V pools, or
     its state pools."""
     H, KV = cfg.n_heads, cfg.kv_heads
-    with jax.named_scope("norm1"):
-        h1 = T._act_quant(
-            T._norm(x, lp["ln1_scale"], lp.get("ln1_bias"), cfg), cfg)
+    if cfg.output_norm:  # no norm before the operator: _ffn_residual's
+        h1 = T._act_quant(x, cfg)
+    else:
+        with jax.named_scope("norm1"):
+            h1 = T._act_quant(
+                T._norm(x, lp["ln1_scale"], lp.get("ln1_bias"), cfg), cfg)
     kind = cfg.layer_kind(li)
     if kind == "experts":  # the routed block as the layer's operator
         with jax.named_scope("mlp"):
@@ -1303,7 +1354,8 @@ def _gated_delta_net(h1, lp, cfg: T.TransformerConfig, carry, recur):
     [q; k; v; z] = gdn_in h1, [b; a] = gdn_ba h1 (one of each a value
     head); [q; k; v] <- silu(causal depthwise convolution of
     conv_kernel taps, no bias, zeros before the sequence starts);
-    beta = sigmoid(b); g = -exp(a_log) softplus(a + dt_bias), float32;
+    beta = sigmoid(b) (twice that where cfg.gdn_neg_eigval);
+    g = -exp(a_log) softplus(a + dt_bias), float32;
     q and k of the key heads repeated to the value heads, each head's
     L2-normalised (x rsqrt(sum x^2 + 1e-6)), q scaled by Dk^-0.5; the
     delta rule per value head (ops/pallas/gated_delta.py) through
@@ -1321,6 +1373,8 @@ def _gated_delta_net(h1, lp, cfg: T.TransformerConfig, carry, recur):
         b, a = jnp.split(
             _wmm("...e,ef->...f", h1, lp["gdn_ba"]).astype(f32), 2, axis=-1)
         beta = jax.nn.sigmoid(b)
+        if cfg.gdn_neg_eigval:  # in (0, 2): the state may reflect along k
+            beta = 2.0 * beta
         g = -jnp.exp(lp["gdn_a_log"].astype(f32)) * jax.nn.softplus(
             a + lp["gdn_dt_bias"].astype(f32))
     with jax.named_scope("gdn_conv"):
@@ -1425,7 +1479,7 @@ def _recur_prompts(kind: str, args, pool, slots, n_real, cfg):
     pool's last)."""
     B, Tp = args[0].shape[:2]
     real = (jnp.arange(Tp)[None, :] < n_real[:, None])[..., None]
-    o, last = _SCAN_OF[kind](args, real, cfg)
+    o, last = _SCAN_OF[kind](args, real, cfg, pool)
     where = jnp.where((n_real > 0) & (slots >= 0), slots, pool.shape[0] - 1)
     for i in range(B):  # megabytes an entry: slice updates, no scatter
         pool = jax.lax.dynamic_update_slice(
@@ -1433,16 +1487,19 @@ def _recur_prompts(kind: str, args, pool, slots, n_real, cfg):
     return o, pool
 
 
-def _gdn_scan(args, real, cfg):
-    """The delta rule's chunked scan over whole prompts; a pad token
-    (not `real` [B, Tp, 1]) has k = 0, g = 0, beta = 0."""
+def _gdn_scan(args, real, cfg, pool):
+    """The delta rule's chunked scan over whole prompts, its last
+    states in the pool's layout (the heads side by side that a lane row
+    of `pool` holds); a pad token (not `real` [B, Tp, 1]) has k = 0,
+    g = 0, beta = 0."""
     q, k, v, g, beta = args
-    return gated_delta_chunked(
+    o, last = gated_delta_chunked(
         q, jnp.where(real[..., None], k, 0.0), v, jnp.where(real, g, 0.0),
         jnp.where(real, beta, 0.0))
+    return o, pack_heads(last, last.shape[1] // pool.shape[1])
 
 
-def _ssm_scan(args, real, cfg):
+def _ssm_scan(args, real, cfg, pool):
     """The state-space layer's chunked scan over whole prompts, its
     last states in the pool's layout; a pad token has dt = 0."""
     x, dt, A, Bm, Cm = args
@@ -1772,10 +1829,12 @@ def decode_step(
         pools = _layer_pools(cache, cfg.op_index(li))
         if fuse_write:
             att, *pools = _decode_attention(q, pools, *where, k_new=k,
-                                            v_new=v, slots=flat)
+                                            v_new=v, slots=flat,
+                                            kv_heads=cfg.kv_heads)
             return att, pools
         pools = _write_pools(pools, k, v, flat, mesh, use_kernel)
-        return _decode_attention(q, pools, *where), pools
+        return _decode_attention(q, pools, *where,
+                                 kv_heads=cfg.kv_heads), pools
 
     @partial(_slot_wide, cache=cache, cfg=cfg)
     def carry(u, taps, pool):

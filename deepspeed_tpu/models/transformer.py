@@ -201,6 +201,11 @@ class TransformerConfig:
     # a second RMSNorm on each sub-layer's OUTPUT, before the residual
     # add: x + N_post(f(N_pre(x))) (four norms a layer)
     sandwich_norm: bool = False
+    # SERVING ONLY. The RMSNorm on each sub-layer's OUTPUT alone, none
+    # before it: x + N(f(x)) (the OLMo 2 placement; two norms a layer,
+    # under the names sandwich_norm gives its second pair:
+    # `ln1_post_scale`, `ln2_post_scale`; no `ln1_scale` / `ln2_scale`)
+    output_norm: bool = False
     # shared experts beside the routed ones: one dense MLP of
     # n_shared_experts * d_ff every token passes through, gated or not
     # as the routed experts are (is_gated: an ungated block has no
@@ -259,6 +264,11 @@ class TransformerConfig:
     gdn_value_heads: int = 0
     gdn_key_dim: int = 0
     gdn_value_dim: int = 0
+    # SERVING ONLY. The delta rule's write strength beta = 2 sigmoid(b),
+    # in (0, 2), where it is sigmoid(b): I - beta k k^T then has the
+    # eigenvalue 1 - beta in (-1, 1) along k (the publishers'
+    # `allow_neg_eigval`)
+    gdn_neg_eigval: bool = False
     ssm_heads: int = 0
     ssm_head_dim: int = 0
     ssm_state_dim: int = 0
@@ -355,11 +365,20 @@ class TransformerConfig:
         if self.mixer_only and (
                 self.layer_types is None or self.n_dense_layers
                 or self.parallel_residual or self.sandwich_norm
-                or self.moe_use_residual):
+                or self.output_norm or self.moe_use_residual):
             raise ValueError(
                 "mixer_only layers are named by layer_types, and have no "
-                "leading dense layers, parallel or sandwich form, nor a "
-                "PR-MoE residual")
+                "leading dense layers, parallel or sandwich form, no norm "
+                "on the output alone, nor a PR-MoE residual")
+        if self.output_norm and (
+                self.sandwich_norm or self.parallel_residual
+                or self.norm_has_bias or self.kv_lora_rank > 0
+                or self.residual_multiplier != 1.0):
+            raise ValueError(
+                "output_norm is the RMSNorm on each sub-layer's output "
+                "ALONE: it excludes sandwich_norm (which has it beside the "
+                "norm before), the parallel form, a norm with a bias, "
+                "latent attention and a residual multiplier")
         if self.shared_expert_gate and not self.n_shared_experts:
             raise ValueError("shared_expert_gate gates n_shared_experts: "
                              "set both")
@@ -595,6 +614,7 @@ class TransformerConfig:
         n_shared_experts, n_dense_layers, experts_held, moe_expert_bias,
         moe_scoring "sigmoid", attn_output_gate, embedding_multiplier.)"""
         return tuple(k for k in ("kv_lora_rank", "layer_types",
+                                 "output_norm", "gdn_neg_eigval",
                                  "shared_expert_gate", "position_embedding",
                                  "attention_multiplier",
                                  "rope_scaling_full_only", "mixer_only")
@@ -656,8 +676,23 @@ class TransformerConfig:
     @property
     def gdn_state_shape(self) -> Tuple[int, ...]:
         """A sequence's matrices in a linear-attention layer: [Dk
-        sublanes, Dv lanes] a value head (ops/pallas/gated_delta.py)."""
-        return (self.gdn_value_heads, self.gdn_key_dim, self.gdn_value_dim)
+        sublanes, Dv lanes] a value head, and where a head's values are
+        no whole lane tiles as many heads side by side as make them
+        whole (gdn_pack: two of 192 are 384 lanes, where a head alone
+        would lie in 256 and move a third more), as
+        ops/pallas/gated_delta.py lays them out."""
+        pack = self.gdn_pack
+        return (self.gdn_value_heads // pack, self.gdn_key_dim,
+                pack * self.gdn_value_dim)
+
+    @property
+    def gdn_pack(self) -> int:
+        """Value heads of a linear-attention layer that share a lane
+        row of their matrices' pool: the fewest whose values together
+        are whole 128-lane tiles, where the heads divide into such
+        rows; else one."""
+        pack = math.lcm(self.gdn_value_dim, 128) // self.gdn_value_dim
+        return pack if self.gdn_value_heads % pack == 0 else 1
 
     @property
     def ssm_state_shape(self) -> Tuple[int, ...]:
@@ -807,7 +842,9 @@ def _layer_shapes(cfg: TransformerConfig, dense: bool = False
     routed (cfg.n_dense_layers): the same attention and norms, a dense
     MLP of width cfg.dense_d_ff."""
     E, F = cfg.d_model, cfg.ff_dim
-    shapes = {"ln1_scale": ((E,), ("embed",))}
+    # (a model whose norms stand on the sublayers' outputs alone,
+    # cfg.output_norm, has none before them)
+    shapes = {} if cfg.output_norm else {"ln1_scale": ((E,), ("embed",))}
     if cfg.mixer_only:
         # one sublayer a layer: its one norm, and nothing else here
         if cfg.norm_has_bias:
@@ -818,10 +855,10 @@ def _layer_shapes(cfg: TransformerConfig, dense: bool = False
         # layer's own (a model of two kinds keeps them in stacks by
         # kind beside `layers`, _operator_shapes)
         shapes.update(_operator_shapes(cfg, "attention"))
-    if cfg.sandwich_norm:
+    if cfg.sandwich_norm or cfg.output_norm:
         shapes["ln1_post_scale"] = ((E,), ("embed",))
         shapes["ln2_post_scale"] = ((E,), ("embed",))
-    if not cfg.shared_ln:
+    if not cfg.shared_ln and not cfg.output_norm:
         shapes["ln2_scale"] = ((E,), ("embed",))
     X = 0 if dense else cfg.n_experts
     if dense:

@@ -4,7 +4,9 @@ advances it.
 
 A value head carries S in R^{Dk x Dv}, float32. For each token, with
 its head's q and k (L2-normalised, q scaled by Dk^-0.5), v, the log
-decay g <= 0 and the write strength beta in (0, 1):
+decay g <= 0 and the write strength beta in (0, 1), or in (0, 2) where
+the model lets the state's eigenvalues go negative (beta = 2 sigmoid:
+I - beta k k^T then reflects along k):
 
     S <- exp(g) S;  d = beta (v - S^T k);  S <- S + k d^T;  o = S^T q
 
@@ -13,8 +15,10 @@ decay g <= 0 and the write strength beta in (0, 1):
   adjacent and in order (a RUN: a decode row is a run of one, a prefill
   chunk a run of several), so a step is a segmented recurrence. A
   run's state is fetched from its sequence's slot of the pool
-  [slots + 1, H, Dk, Dv] (zeros at position 0, whatever the slot
-  holds), updated in place in ONE VMEM buffer by the run's rows, and
+  [slots + 1, H, Dk, Dv] (heads of Dv that are no whole lane tiles
+  stand side by side, `pack_heads`: [slots + 1, H / 2, 96, 384] for
+  heads of 96 x 192, so that a slot is whole tiles and moves its own
+  bytes and no padding; zeros at position 0, whatever the slot holds), updated in place in ONE VMEM buffer by the run's rows, and
   written to the slot once, after its last row. The pool stays in HBM,
   aliased in and out, and the kernel issues the copies itself
   (`state_step_call`, the walk this kernel shares with ssm_state.py's):
@@ -29,8 +33,10 @@ decay g <= 0 and the write strength beta in (0, 1):
   U = T diag(beta) V; W = T diag(beta) (K . exp(G)); V' = U - W S_0;
   O = (Q . exp(G)) S_0 + tril((Q K^T) . D) V';
   S_C = exp(G_C) S_0 + (K . exp(G_C - G))^T V'. A is strictly lower
-  triangular, hence nilpotent: T = (I + A)(I + A^2)(I + A^4)... with
-  log2 C factors, matmuls and no substitution loop.
+  triangular, so U and W are ONE triangular solve (forward
+  substitution) of I - A: stable whatever the keys, where the product
+  (I + A)(I + A^2)(I + A^4)... passes through powers of A that
+  near-parallel keys at beta near 2 take to 1e27 before they cancel.
 - `gated_delta_recurrent`: the recurrence itself as a `lax.scan`, the
   oracle both are tested against; `gated_delta_step_xla` is the step
   over rows without a kernel (decode_impl 'xla', the CPU).
@@ -106,15 +112,16 @@ def gated_delta_chunked(q, k, v, g, beta, state=None, chunk: int = CHUNK):
             lower, G[..., :, None] - G[..., None, :], 0.0)), 0.0)
         Kb = K * b_[..., None]
         A = -jnp.where(strict, mm("bhik,bhjk->bhij", Kb, K) * D, 0.0)
-        # (I - A)^-1 of a nilpotent A: (I + A)(I + A^2)(I + A^4)...
-        Tm, P, n = eye + A, A, 1
-        while 2 * n < C:
-            P = mm("bhij,bhjk->bhik", P, P)
-            Tm = mm("bhij,bhjk->bhik", Tm, eye + P)
-            n *= 2
+        # T [diag(beta) V, diag(beta) (K . exp(G))] by forward
+        # substitution (I - A is unit lower triangular): the product
+        # (I + A)(I + A^2)(I + A^4)... is the same matrix but passes
+        # through powers of A, which near-parallel keys at beta up to 2
+        # (A's entries up to 2) take to 1e27 before they cancel
         eG = jnp.exp(G)[..., None]
-        U = mm("bhij,bhjv->bhiv", Tm, V * b_[..., None])
-        W = mm("bhij,bhjk->bhik", Tm, Kb * eG)
+        UW = jax.lax.linalg.triangular_solve(
+            eye - A, jnp.concatenate([V * b_[..., None], Kb * eG], axis=-1),
+            left_side=True, lower=True, unit_diagonal=True)
+        U, W = UW[..., :Dv], UW[..., Dv:]
         Vp = U - mm("bhik,bhkv->bhiv", W, S)
         O = mm("bhik,bhkv->bhiv", Q * eG, S) + mm(
             "bhij,bhjv->bhiv", jnp.where(
@@ -130,6 +137,28 @@ def gated_delta_chunked(q, k, v, g, beta, state=None, chunk: int = CHUNK):
     o = jnp.moveaxis(o, 0, 1).transpose(0, 1, 3, 2, 4).reshape(
         B, N * C, H, Dv)
     return o[:, :T], state
+
+
+def pack_heads(state, pack: int):
+    """[..., H, Dk, Dv] -> the pool's layout [..., H / pack, Dk, pack Dv]:
+    `pack` heads side by side in a lane row (TransformerConfig.gdn_pack:
+    heads of 192 values in pairs, whole lane tiles; one head a row where
+    its values are whole tiles already)."""
+    if pack == 1:
+        return state
+    *lead, H, Dk, Dv = state.shape
+    s = state.reshape(*lead, H // pack, pack, Dk, Dv)
+    return jnp.moveaxis(s, -3, -2).reshape(*lead, H // pack, Dk, pack * Dv)
+
+
+def unpack_heads(packed, pack: int):
+    """pack_heads' inverse: [..., H / pack, Dk, pack Dv] -> [..., H, Dk,
+    Dv]."""
+    if pack == 1:
+        return packed
+    *lead, Hp, Dk, PP = packed.shape
+    s = packed.reshape(*lead, Hp, Dk, pack, PP // pack)
+    return jnp.moveaxis(s, -2, -3).reshape(*lead, Hp * pack, Dk, PP // pack)
 
 
 def run_starts(slots, positions):
@@ -149,16 +178,18 @@ def gated_delta_step_xla(q, k, v, g, beta, pool, slots, positions):
     it) and writing it back."""
     S_rows = q.shape[0]
     pad = pool.shape[0] - 1
+    pack = q.shape[1] // pool.shape[1]
     _, fresh = run_starts(slots, positions)
     where = jnp.where(slots < 0, pad, slots)
 
     def row(t, carry):
         pool, out = carry
-        S = jnp.where(fresh[t], 0.0, pool[where[t]])
+        S = jnp.where(fresh[t], 0.0, unpack_heads(pool[where[t]], pack))
         o, S = gated_delta_recurrent(
             q[t][None, None], k[t][None, None], v[t][None, None],
             g[t][None, None], beta[t][None, None], S[None])
-        return (pool.at[where[t]].set(S[0]), out.at[t].set(o[0, 0]))
+        return (pool.at[where[t]].set(pack_heads(S[0], pack)),
+                out.at[t].set(o[0, 0]))
 
     pool, out = jax.lax.fori_loop(
         0, S_rows, row, (pool, jnp.zeros(v.shape, F32)))
@@ -474,20 +505,38 @@ def walk_fits(pool) -> bool:
 
 
 def _step_kernel(t, i, dec_ref, beta_ref, qT_ref, kT_ref, v_ref, o_ref,
-                 state, *, n_heads: int):
+                 state, *, n_heads: int, pack: int = 1):
     """One row: every head's state decayed, read against k, written
     with the token's correction, read against q. The state is
     [Dk sublanes, Dv lanes] a head: k and q arrive as columns
     [Dk, H] (one lane a head) and broadcast along the lanes; v, the
-    correction and the output are rows."""
+    correction and the output are rows. Where `pack` heads stand side
+    by side in a lane row [Dk, pack Dv] (pack_heads), the row is
+    advanced as ONE matrix: each head's column, decay and strength over
+    its own Dv lanes (`spread`), and the sums over the sublanes are
+    each head's own, lane by lane."""
     qT, kT = qT_ref[i], kT_ref[i]  # [Dk, H]
+    Dk, lanes = qT.shape[0], v_ref.shape[-1]
+    lane_of = lambda rows: jax.lax.broadcasted_iota(
+        jnp.int32, (rows, lanes), 1)
+
+    def spread(j, pick, rows):
+        """pick(h) of each head h of lane row j, over that head's own
+        lanes (the one head's as it is where a row is one head)."""
+        out = pick(j * pack)
+        for p in range(1, pack):
+            out = jnp.where(lane_of(rows) >= p * (lanes // pack),
+                            pick(j * pack + p), out)
+        return out
 
     def heads(before, after):
-        for h in range(n_heads):
-            kc, qc = kT[:, h:h + 1], qT[:, h:h + 1]  # [Dk, 1]
-            S = before(h) * dec_ref[t * n_heads + h]
-            m = jnp.sum(S * kc, axis=0, keepdims=True)  # [1, Dv]
-            d = beta_ref[t * n_heads + h] * (v_ref[i, h:h + 1, :] - m)
+        for h in range(n_heads // pack):
+            kc = spread(h, lambda n: kT[:, n:n + 1], Dk)  # [Dk, 1 | lanes]
+            qc = spread(h, lambda n: qT[:, n:n + 1], Dk)
+            S = before(h) * spread(h, lambda n: dec_ref[t * n_heads + n], 1)
+            m = jnp.sum(S * kc, axis=0, keepdims=True)  # [1, lanes]
+            d = spread(h, lambda n: beta_ref[t * n_heads + n], 1) * (
+                v_ref[i, h:h + 1, :] - m)
             S = S + kc * d
             after(h, S)
             o_ref[i, h:h + 1, :] = jnp.sum(S * qc, axis=0, keepdims=True)
@@ -497,11 +546,11 @@ def _step_kernel(t, i, dec_ref, beta_ref, qT_ref, kT_ref, v_ref, o_ref,
 
 def gated_delta_step(q, k, v, g, beta, pool, slots, positions):
     """One step over ragged rows. q, k [S, H, Dk], v [S, H, Dv], g,
-    beta [S, H] float32; pool [slots + 1, H, Dk, Dv] float32 (its last
-    slot is the pad rows'); slots [S] int32, each row's sequence's slot
-    (-1: a pad row); positions [S], each row's token's position.
-    -> (o [S, H, Dv] float32, the pool with every run's last state in
-    its sequence's slot)."""
+    beta [S, H] float32; pool [slots + 1, H / pack, Dk, pack Dv] float32
+    (pack_heads' layout; its last slot is the pad rows'); slots [S]
+    int32, each row's sequence's slot (-1: a pad row); positions [S],
+    each row's token's position. -> (o [S, H, Dv] float32, the pool with
+    every run's last state in its sequence's slot)."""
     return _gated_delta_step(q, k, v, g, beta, pool, slots, positions,
                              walk_shape(pool.shape), interpret())
 
@@ -510,17 +559,25 @@ def gated_delta_step(q, k, v, g, beta, pool, slots, positions):
 def _gated_delta_step(q, k, v, g, beta, pool, slots, positions, shape,
                       interpreted: bool):
     f32 = lambda a: a.astype(F32)
-    return state_step_call(
-        functools.partial(_step_kernel, n_heads=q.shape[1]), "gdn_state",
-        v.shape[1:], pool, slots, positions, shape, interpreted,
+    H, rows = q.shape[1], pool.shape[1]
+    # v's heads side by side as the pool's are: [S, H Dv] is both
+    o, pool = state_step_call(
+        functools.partial(_step_kernel, n_heads=H, pack=H // rows),
+        "gdn_state", (rows, pool.shape[-1]), pool, slots, positions, shape,
+        interpreted,
         scalars=(jnp.exp(f32(g)).reshape(-1), f32(beta).reshape(-1)),
-        rows=(f32(q).transpose(0, 2, 1), f32(k).transpose(0, 2, 1), f32(v)))
+        rows=(f32(q).transpose(0, 2, 1), f32(k).transpose(0, 2, 1),
+              f32(v).reshape(v.shape[0], rows, -1)))
+    return o.reshape(v.shape), pool
 
 
 def step_fits(n_rows: int, pool) -> bool:
     """Whether the step kernel takes these shapes: whole lanes and
-    sublanes a head's matrix, the walk's slots inside the kernel's VMEM
-    (walk_fits), the rows' decays and strengths in scalar memory."""
+    sublanes a lane row of heads (a head's matrix, or pack_heads' few
+    side by side: heads of 96 x 192 in pairs), the walk's slots inside
+    the kernel's VMEM (walk_fits), the rows' decays and strengths in
+    scalar memory (counted a lane row: heads side by side are so few
+    that the bound's room holds them)."""
     _, H, Dk, Dv = pool.shape
     return (Dk % 8 == 0 and Dv % 128 == 0 and walk_fits(pool)
             and 2 * n_rows * H * 4 <= 256 << 10)

@@ -374,6 +374,28 @@ def _whole_tiles(kv_heads: int, head_dim: int, itemsize: int) -> bool:
     return kv_heads % tile == 0
 
 
+def kv_heads_held(kv_heads: int, head_dim: int, itemsize: int) -> int:
+    """KV heads a pool HOLDS: `kv_heads`, or, for more than the layout's
+    8-row tile of them that are not whole tiles (_whole_tiles: 30 heads
+    of 128 in 16 bits), the next count that is (32). The tiled HBM
+    layout pads such a pool to that count anyway (the memref Mosaic is
+    handed for [.., 30, 128] is [.., 32, 128]: "Slice shape along
+    dimension 2 must be aligned to tiling (8), but is 30"), so the
+    padding heads take no memory that was not taken, and WITH them the
+    live-block walk, the fused write and the row write take the pool,
+    where 30 heads stay on the (S, NB) grid and write whole blocks.
+    Counts under the tile stay as they are (what their layouts pad was
+    not read here). Who allocates a pool asks this (unquantised pools
+    on one device, as kv_pack); the serving model pads the new rows'
+    and the queries' heads with zeros to the pool's (inference/model.py
+    _write_pools, _decode_attention: a padding head attends zeros with
+    a zero query and is cut from the output)."""
+    if (head_dim % 128 or kv_heads <= 8
+            or _whole_tiles(kv_heads, head_dim, itemsize)):
+        return kv_heads
+    return -(-kv_heads // 8) * 8
+
+
 def kv_write_path(pool_shape, dtype) -> str:
     """How paged_kv_write reaches a pool [NBLK, bs, KV, D] of this shape
     and dtype: "rows" (a row's own bytes DMA'd to its slot,
@@ -451,8 +473,10 @@ def paged_decode_attention(q, k_cache, v_cache, block_table, ctx_lens,
     flag:
 
     - k_new/v_new/slots given, unquantised, head dim a multiple of 128
-      (supports_fused_v2): paged_decode_fused, the per-row live-block
-      walk with the new row DMA'd into its slot;
+      (supports_fused_v2) and a pool that holds what kv_heads_held
+      would make it hold (30 heads not held in 32 are no whole tiles,
+      and Mosaic refuses the row's DMA): paged_decode_fused, the
+      per-row live-block walk with the new row DMA'd into its slot;
     - attend only, unquantised, a block shape Mosaic takes as a manual
       DMA (_walks_live_blocks): the live-block walk, grid (S,) — each
       row reads the live blocks of its table and nothing else, so the
@@ -526,7 +550,8 @@ def paged_decode_attention(q, k_cache, v_cache, block_table, ctx_lens,
         if fused:
             return (_unpack_out(out[0], pack, G), *out[1:])
         return _unpack_out(out, pack, G)
-    if fused and not quant and supports_fused_v2(D):
+    if fused and not quant and supports_fused_v2(D) and kv_heads_held(
+            KV, D, k_cache.dtype.itemsize) == KV:
         return paged_decode_fused(q, k_cache, v_cache, block_table, ctx_lens,
                                   k_new, v_new, slots, window=window,
                                   alibi_slopes=alibi_slopes, scale=scale)

@@ -151,7 +151,8 @@ _ARCH_OF_MODEL_TYPE = {"olmoe": "OlmoeForCausalLM",
                        "granitemoehybrid": "GraniteMoeHybridForCausalLM",
                        "mellum": "MellumForCausalLM",
                        "nemotron_h": "NemotronHForCausalLM",
-                       "afmoe": "AfmoeForCausalLM"}
+                       "afmoe": "AfmoeForCausalLM",
+                       "olmo_hybrid": "OlmoHybridForCausalLM"}
 # config.json keys that change what a BLOCK computes (latent attention,
 # shared experts, leading dense layers, a second norm, a scaled, grouped
 # or biased router, layers of another kind than attention): an
@@ -196,10 +197,12 @@ _AFMOE_KEYS = (
     "global_attn_every_n_layers", "load_balance_coeff", "mup_enabled",
     "num_expert_groups", "num_limited_groups", "route_norm", "route_scale",
     "score_func", "use_grouped_mm")
+# a delta rule whose write strength reaches 2 (OLMo-class hybrids)
+_NEG_EIGVAL_KEYS = ("linear_allow_neg_eigval",)
 _BLOCK_KEYS = tuple(dict.fromkeys(
     _LATENT_MOE_KEYS + _HYBRID_KEYS + _LINEAR_ATTENTION_KEYS
     + _STATE_SPACE_KEYS + _MIXED_WINDOW_KEYS + _MIXER_ONLY_KEYS
-    + _AFMOE_KEYS))
+    + _AFMOE_KEYS + _NEG_EIGVAL_KEYS))
 # the block keys each architecture's mapping reads; any other stays an
 # error for it too
 _READS_BLOCK_KEYS = {
@@ -221,6 +224,10 @@ _READS_BLOCK_KEYS = {
     "AfmoeForCausalLM": frozenset(_AFMOE_KEYS + (
         "layer_types", "num_dense_layers", "moe_intermediate_size",
         "n_group", "topk_group", "num_shared_experts")),
+    "OlmoHybridForCausalLM": frozenset(_NEG_EIGVAL_KEYS + (
+        "layer_types", "linear_conv_kernel_dim", "linear_key_head_dim",
+        "linear_value_head_dim", "linear_num_key_heads",
+        "linear_num_value_heads", "rope_parameters")),
 }
 
 
@@ -238,6 +245,7 @@ SUPPORTED_ARCHITECTURES = sorted(_LLAMA_FAMILY | {
     "PanguUltraMoEForCausalLM", "Lfm2MoeForCausalLM",
     "Qwen3NextForCausalLM", "GraniteMoeHybridForCausalLM",
     "MellumForCausalLM", "NemotronHForCausalLM", "AfmoeForCausalLM",
+    "OlmoHybridForCausalLM",
     "GPT2LMHeadModel", "OPTForCausalLM", "FalconForCausalLM",
     "RWForCausalLM",  # falcon's pre-rename arch string
     "PhiForCausalLM", "QWenLMHeadModel",
@@ -278,6 +286,8 @@ def config_from_hf(hf: Dict[str, Any], **overrides) -> TransformerConfig:
         kw = _nemotron_h_config(hf)
     elif arch == "AfmoeForCausalLM":
         kw = _afmoe_config(hf)
+    elif arch == "OlmoHybridForCausalLM":
+        kw = _olmo_hybrid_config(hf)
     elif arch in _LLAMA_FAMILY:
         kw = dict(
             vocab_size=hf["vocab_size"],
@@ -956,6 +966,85 @@ def _qwen3_next_config(hf: Dict[str, Any]) -> Dict[str, Any]:
         shared_expert_gate=shared > 0,
         moe_norm_topk_prob=bool(hf.get("norm_topk_prob", True)),
         moe_dropless=True,
+    )
+
+
+def _olmo_hybrid_config(hf: Dict[str, Any]) -> Dict[str, Any]:
+    """Olmo-Hybrid (`olmo_hybrid`): `layer_types` names each layer
+    `linear_attention` (a Gated DeltaNet of `linear_num_value_heads`
+    heads of `linear_key_head_dim` x `linear_value_head_dim` behind a
+    depthwise convolution of `linear_conv_kernel_dim` taps, its write
+    strength 2 sigmoid where `linear_allow_neg_eigval`) or
+    `full_attention` (multi-head attention with a QK-norm over the
+    WHOLE projected q and k, and NO positions: the published
+    `rope_parameters.rope_theta` is null). Every layer ends in a dense
+    SwiGLU of `intermediate_size`, and the RMSNorms stand on the
+    sublayers' OUTPUTS alone (the OLMo 2 placement): x + N(f(x)).
+
+    The publisher projects q, k, v, the gate z, b and a apart; the
+    tree holds them as Qwen3-Next's does, `gdn_in`'s columns
+    [q; k; v; z] and `gdn_ba`'s [b; a] in head order, and the three
+    convolutions as ONE over the channels [q; k; v]: a concatenation,
+    an importer's business. Every norm's scale is plain (x * w).
+
+    Refused by name, because nothing here computes it: a rotation on
+    the full layers (a `rope_theta` that is not null, a `rope_scaling`),
+    `attention_bias`, `clip_qkv`, an activation other than silu, a
+    `layer_types` entry of another kind, and key heads that do not
+    divide the value heads (the repeat of the key heads IS computed)."""
+    rope = hf.get("rope_parameters") or {}
+    theta = hf.get("rope_theta", rope.get("rope_theta"))
+    if theta is not None or hf.get("rope_scaling") or rope.get(
+            "rope_type", "default") != "default":
+        raise ValueError(
+            f"olmo_hybrid with rope_theta={theta!r} (rope_parameters="
+            f"{rope!r}, rope_scaling={hf.get('rope_scaling')!r}): its full "
+            "layers are served with no positions, as the published "
+            "rope_theta null has them; a rotation is unsupported")
+    for key in ("attention_bias", "clip_qkv"):
+        if hf.get(key):
+            raise ValueError(
+                f"olmo_hybrid with {key}={hf[key]!r} is unsupported")
+    if hf.get("hidden_act", "silu") != "silu":
+        raise ValueError(
+            f"olmo_hybrid with hidden_act={hf['hidden_act']!r} is "
+            "unsupported (silu)")
+    L = int(hf["num_hidden_layers"])
+    kinds = {"linear_attention": "linear_attention",
+             "full_attention": "attention"}
+    types = list(hf.get("layer_types") or ())
+    unknown = sorted(set(types) - set(kinds))
+    if unknown or len(types) != L:
+        raise ValueError(
+            f"olmo_hybrid layer_types names {sorted(kinds)} for each of "
+            f"num_hidden_layers={L} layers (got {len(types)} entries, "
+            f"unknown {unknown})")
+    Hk, Hv = int(hf["linear_num_key_heads"]), int(hf["linear_num_value_heads"])
+    if Hv % Hk:
+        raise ValueError(
+            f"olmo_hybrid linear_num_key_heads {Hk} does not divide "
+            f"linear_num_value_heads {Hv}: the key heads are repeated to "
+            "the value heads, a whole number of times")
+    return dict(
+        vocab_size=hf["vocab_size"],
+        n_layers=L,
+        n_heads=hf["num_attention_heads"],
+        n_kv_heads=hf.get("num_key_value_heads") or None,
+        d_model=hf["hidden_size"],
+        d_ff=hf["intermediate_size"],
+        max_seq=hf.get("max_position_embeddings", 4096),
+        variant="llama",
+        position_embedding="none",
+        norm_eps=float(hf.get("rms_norm_eps", 1e-6)),
+        tie_embeddings=bool(hf.get("tie_word_embeddings", False)),
+        output_norm=True,
+        qk_norm=True,
+        layer_types=tuple(kinds[t] for t in types),
+        conv_kernel=int(hf["linear_conv_kernel_dim"]),
+        gdn_key_heads=Hk, gdn_value_heads=Hv,
+        gdn_key_dim=int(hf["linear_key_head_dim"]),
+        gdn_value_dim=int(hf["linear_value_head_dim"]),
+        gdn_neg_eigval=bool(hf.get("linear_allow_neg_eigval", False)),
     )
 
 

@@ -70,6 +70,17 @@ _OUTGROWN_BENCHMARK_PINS = {
         "the program's tuple (ISSUE 55 item 10) and may not edit the "
         "reader; a `benchmark` PR adds the name there and takes this "
         "entry away",
+    # the four cases of one pin: the stall readers' entries list EVERY
+    # serving cell, and the test counts them
+    **{"test_stall_readers.py::test_the_entry_a_benchmark_pr_appends"
+       f"[{reader}]":
+       "pins 8 serving cells on `with_cells(entry, doc)` (PR 53); PR 58 "
+       "added the ninth, `serve-olmohybrid-chat-saturated-r128`; the rest "
+       "of the case (the entry's form, BENCHMARK.json lacking it) stays "
+       "held by test_olmohybrid_readers.py's twin for its own readers; a "
+       "`benchmark` PR relaxes the count and takes these away"
+       for reader in ("sched_stall_iterations", "sched_stall_share",
+                      "host_gc_ms_per_step", "serve_idle_steady_share")},
 }
 
 

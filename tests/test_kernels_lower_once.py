@@ -39,6 +39,10 @@ KERNELS = {
                        WRITE_WALK | {"conv_carry", "ssm_state"}),
     "tiny-mellum2": ({"paged_decode_fused", "expert_stream"},
                      WRITE_WALK | {"expert_stream"}),
+    # a dense FFN: the delta rule over heads in pairs in 4 of 6 layers
+    "tiny-olmohybrid": (
+        {"paged_decode_fused", "conv_carry", "gdn_state"},
+        WRITE_WALK | {"conv_carry", "gdn_state"}),
     # layers of one mixer each: 4 hold a slot, 2 K/V, 2 experts (ungated)
     "tiny-nemotron3": (
         {"paged_decode_grid", "conv_carry", "ssm_state",

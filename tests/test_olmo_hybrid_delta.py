@@ -1,0 +1,354 @@
+"""The delta rule of an Olmo-Hybrid model (tests/test_olmo_hybrid.py
+holds the model): heads of 96 x 192, two side by side in a lane row of
+the state pool (ops/pallas/gated_delta.py pack_heads), a write strength
+drawn up to 2. The chunked form against the recurrence where
+near-parallel keys make its triangular system as bad as it gets, the
+step over ragged rows in XLA and (interpreted) the `gdn_state` kernel
+against the recurrence (runs of one and of several, a pad row, a first
+token), the shared walk's row patterns on paired heads, what the kernel
+takes, the walk at one query head a KV head, and the two kernels
+compiled for a described v5e at the cell's shapes."""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import _state_walk as W
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from deepspeed_tpu.inference import model as M
+from deepspeed_tpu.models import transformer as T
+from deepspeed_tpu.ops.pallas import gated_delta as GD
+from deepspeed_tpu.ops.pallas import paged_attention as PA
+
+
+def _delta_inputs(rng, *lead, H=2, Dk=96, Dv=192, parallel=0.0):
+    """q, k, v, g, beta of a head of Dk x Dv with beta drawn in (0, 2).
+    `parallel` > 0: every key of a head is one direction plus that much
+    noise (cosines near 1), beta in (1.6, 2) and a slow decay: the
+    worst case of the chunked form's triangular system."""
+    def normal(*shape):
+        return jnp.asarray(rng.normal(size=shape), jnp.float32)
+
+    unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+    k = normal(*lead, H, Dk)
+    if parallel:
+        k = normal(H, Dk) + parallel * k
+    beta = 2 * jax.nn.sigmoid(normal(*lead, H))
+    g = -jnp.exp(normal(*lead, H)) * 0.3
+    if parallel:
+        beta = 1.6 + 0.4 * jax.nn.sigmoid(normal(*lead, H))
+        g = g * 0.1
+    return (unit(normal(*lead, H, Dk)) * Dk ** -0.5, unit(k),
+            normal(*lead, H, Dv), g, beta)
+
+
+@pytest.mark.parametrize("tokens,chunk,parallel", [
+    (1, 64, 0.0), (70, 64, 0.0), (131, 64, 0.0), (37, 16, 0.0),
+    (64, 64, 0.05), (150, 64, 0.05), (150, 64, 0.01), (23, 7, 0.05)])
+def test_the_chunked_form_is_the_recurrence_up_to_beta_two(rng, tokens, chunk,
+                                                           parallel):
+    """Heads of 96 x 192 from a state that is not zero, beta up to 2;
+    with near-parallel keys (cosines of 0.99 and more) the powers of
+    the chunk's strictly lower matrix reach 1e20 and more: the solve
+    by substitution holds where their product gave NaN."""
+    args = _delta_inputs(rng, 2, tokens, parallel=parallel)
+    state = jnp.asarray(rng.normal(size=(2, 2, 96, 192)), jnp.float32)
+    o1, s1 = GD.gated_delta_recurrent(*args, state)
+    o2, s2 = GD.gated_delta_chunked(*args, state, chunk=chunk)
+    assert np.isfinite(o2).all() and np.isfinite(s2).all()
+    tol = 2e-4 if parallel else 2e-5
+    np.testing.assert_allclose(o2, o1, atol=tol)
+    np.testing.assert_allclose(s2, s1, atol=tol)
+
+
+def test_a_write_strength_of_two_reflects_the_state_along_the_key(rng):
+    """beta = 2, no decay: S <- (I - 2 k k^T) S + 2 k v^T, an eigenvalue
+    of -1 along k. Written twice with the same key and value the state
+    is back where it was plus nothing: the second write undoes the
+    first's reflection of the old state and keeps k v^T's own."""
+    q, k, v, _, _ = _delta_inputs(rng, 1, 1)
+    two = lambda a: jnp.concatenate([a, a], axis=1)
+    S0 = jnp.asarray(rng.normal(size=(1, 2, 96, 192)), jnp.float32)
+    zero, beta = jnp.zeros((1, 2, 2)), jnp.full((1, 2, 2), 2.0)
+    for form in (GD.gated_delta_recurrent, GD.gated_delta_chunked):
+        _, S1 = form(q, k, v, zero[:, :1], beta[:, :1], S0)
+        _, S2 = form(two(q), two(k), two(v), zero, beta, S0)
+        kk = jnp.einsum("bhk,bhj->bhkj", k[:, 0], k[:, 0])
+        reflected = S0 - 2 * jnp.einsum("bhkj,bhjv->bhkv", kk, S0)
+        np.testing.assert_allclose(
+            S1, reflected + 2 * k[:, 0, :, :, None] * v[:, 0, :, None, :],
+            atol=2e-5)
+        np.testing.assert_allclose(S2, S0, atol=2e-5)
+
+
+def test_a_padded_prompt_leaves_the_state_in_the_pools_layout(rng):
+    """_recur_prompts: prompts of 9 and 30 tokens padded to 32, a pad
+    prompt beside them; each slot gets the state after its prompt's own
+    last token, two heads side by side, the others are not touched."""
+    mcfg = T.TransformerConfig(
+        n_layers=1, layer_types=("linear_attention",), conv_kernel=4,
+        gdn_key_heads=4, gdn_value_heads=4, gdn_key_dim=96, gdn_value_dim=192)
+    q, k, v, g, beta = _delta_inputs(rng, 3, 32, H=4)
+    pool = jnp.full((5, 2, 96, 384), 7.0)
+    n_real = jnp.asarray([9, 30, 0], jnp.int32)
+    slots = jnp.asarray([2, 0, -1], jnp.int32)
+    o, new = M._recur_prompts("linear_attention", (q, k, v, g, beta), pool,
+                              slots, n_real, mcfg)
+    for i, (n, slot) in enumerate([(9, 2), (30, 0)]):
+        want_o, want_s = GD.gated_delta_recurrent(
+            q[i:i + 1, :n], k[i:i + 1, :n], v[i:i + 1, :n], g[i:i + 1, :n],
+            beta[i:i + 1, :n])
+        np.testing.assert_allclose(o[i, :n], want_o[0], atol=2e-5)
+        np.testing.assert_allclose(GD.unpack_heads(new[slot], 2), want_s[0],
+                                   atol=2e-5)
+    assert (np.asarray(new[1]) == 7).all() and (np.asarray(new[3]) == 7).all()
+
+
+def _ragged_rows(rng, H, pack, parallel=0.0):
+    """A step's rows: a run of five from a slot's state (positions
+    5..9), a decode row, a pad row, a run of three from position 0 (the
+    slot's NaN must not be read), another pad row."""
+    slots = jnp.asarray([3, 3, 3, 3, 3, 1, -1, 0, 0, 0, -1], jnp.int32)
+    pos = jnp.asarray([5, 6, 7, 8, 9, 12, 0, 0, 1, 2, 0], jnp.int32)
+    pool = jnp.asarray(rng.normal(size=(6, H // pack, 96, pack * 192)),
+                       jnp.float32)
+    pool = pool.at[0].set(jnp.nan)
+    return _delta_inputs(rng, 11, H=H, parallel=parallel), pool, slots, pos
+
+
+def _check_step(step, rng, H, pack, parallel=0.0):
+    (q, k, v, g, beta), pool, slots, pos = _ragged_rows(rng, H, pack,
+                                                        parallel)
+    o, new = step(q, k, v, g, beta, pool, slots, pos)
+    assert o.shape == v.shape and new.shape == pool.shape
+    for rows, slot, start in ((slice(0, 5), 3, pool[3]), (slice(5, 6), 1,
+                                                          pool[1]),
+                              (slice(7, 10), 0, None)):
+        want_o, want_s = GD.gated_delta_recurrent(
+            q[None, rows], k[None, rows], v[None, rows], g[None, rows],
+            beta[None, rows],
+            None if start is None else GD.unpack_heads(start, pack)[None])
+        np.testing.assert_allclose(o[rows], want_o[0], atol=2e-5)
+        np.testing.assert_allclose(GD.unpack_heads(new[slot], pack),
+                                   want_s[0], atol=2e-5)
+    # the slots of no row of this step are as they were
+    np.testing.assert_array_equal(new[2], pool[2])
+    np.testing.assert_array_equal(new[4], pool[4])
+
+
+@pytest.mark.parametrize("H,pack", [(2, 2), (3, 1), (4, 2)])
+@pytest.mark.parametrize("parallel", [0.0, 0.05], ids=["spread", "parallel"])
+def test_the_step_over_runs_is_a_segmented_recurrence(rng, H, pack, parallel):
+    """The loop over rows in XLA at heads of 96 x 192: in pairs, and
+    (three heads: no pairs) one a row."""
+    _check_step(GD.gated_delta_step_xla, rng, H, pack, parallel)
+
+
+@pytest.mark.usefixtures("pallas_interpret")
+@pytest.mark.parametrize("H", [2, 4])
+@pytest.mark.parametrize("parallel", [0.0, 0.05], ids=["spread", "parallel"])
+def test_the_step_kernel_matches_the_recurrence_at_96_by_192(rng, H, parallel):
+    """Runs of one and of several, a pad row, a first token, beta up to
+    2 (and near-parallel keys): two heads side by side in a lane row,
+    each head's column, decay and strength over its own 192 lanes."""
+    pool = jax.ShapeDtypeStruct((6, H // 2, 96, 384), jnp.float32)
+    assert GD.step_fits(11, pool)
+    _check_step(GD.gated_delta_step, rng, H, 2, parallel)
+
+
+@pytest.mark.usefixtures("pallas_interpret")
+@pytest.mark.parametrize("pattern,walk", W.CASES)
+def test_the_walk_over_a_steps_rows_with_heads_in_pairs(rng, monkeypatch,
+                                                        pattern, walk):
+    """The kernel's own copies (tests/_state_walk.py: the rows'
+    patterns, the walk's batches) against the loop over rows in XLA, on
+    a pool of three lane rows of two heads of 8 x 192."""
+    shape = (W.SLOTS + 1, 3, 8, 384)
+    W.set_walk(monkeypatch, walk, shape)
+    W.check_walk(GD.gated_delta_step, GD.gated_delta_step_xla,
+                 lambda rng, n: _delta_inputs(rng, n, H=6, Dk=8), shape,
+                 pattern, rng)
+
+
+@pytest.mark.parametrize("what,n_rows,shape,dtype,fits", [
+    ("the cell's", 128, (129, 15, 96, 384), jnp.float32, True),
+    ("the other DeltaNet cell's", 256, (257, 32, 128, 128), jnp.float32, True),
+    ("a head of 192 alone: one and a half lane tiles", 128,
+     (129, 30, 96, 192), jnp.float32, False),
+    ("keys that fill no sublane tile", 8, (5, 2, 92, 384), jnp.float32,
+     False),
+    ("bfloat16 state", 8, (5, 2, 96, 384), jnp.bfloat16, False),
+])
+def test_step_fits(what, n_rows, shape, dtype, fits):
+    assert GD.step_fits(n_rows, jax.ShapeDtypeStruct(shape, dtype)) is fits
+    if fits:
+        assert GD.walk_shape(shape) == (8, 4)
+
+
+@pytest.mark.usefixtures("pallas_interpret")
+def test_the_walk_with_one_query_head_a_kv_head_matches_the_oracle(rng):
+    """30 query and 30 KV heads of 128, contexts that end inside a
+    block, at a block's edge and nowhere (a pad row)."""
+    S, H, D, bs, NB = 5, 30, 128, 16, 4
+    ctx = np.asarray([1, 17, 40, 64, 0], np.int32)
+    NBLK = S * NB + 1
+    q = jnp.asarray(rng.normal(size=(S, H, D)), jnp.float32)
+    kc = jnp.asarray(rng.normal(size=(NBLK, bs, H, D)), jnp.float32)
+    vc = jnp.asarray(rng.normal(size=(NBLK, bs, H, D)), jnp.float32)
+    tbl = jnp.asarray(rng.permutation(NBLK - 1)[:S * NB].reshape(S, NB),
+                      jnp.int32)
+    got = PA.paged_decode_attention(q, kc, vc, tbl, jnp.asarray(ctx))
+    want = PA.paged_decode_attention_xla(q, kc, vc, tbl, jnp.asarray(ctx))
+    np.testing.assert_allclose(got[:4], want[:4], atol=2e-5)
+
+
+@pytest.mark.parametrize("kv,d,itemsize,held", [
+    (30, 128, 2, 32), (12, 128, 2, 16), (30, 256, 2, 32), (30, 128, 1, 32),
+    (16, 128, 2, 16), (32, 128, 2, 32), (8, 128, 2, 8), (30, 128, 4, 30),
+    (30, 64, 2, 30), (6, 128, 2, 6), (1, 128, 2, 1), (30, 384, 4, 32)])
+def test_a_pool_holds_whole_tiles_of_heads(kv, d, itemsize, held):
+    """More than a tile of KV heads that are no whole tiles are held in
+    the next count that is; whole tiles, counts under the tile, a
+    32-bit pool of exactly 128 lanes and a head dim that fills no lane
+    tile stay as they are."""
+    assert PA.kv_heads_held(kv, d, itemsize) == held
+    assert held == kv or PA._whole_tiles(held, d, itemsize)
+
+
+@pytest.mark.usefixtures("pallas_interpret")
+@pytest.mark.parametrize("G", [1, 2], ids=["mha", "gqa"])
+@pytest.mark.parametrize("fused", [False, True], ids=["write_walk", "fused"])
+def test_padding_heads_change_nothing_the_model_sees(rng, G, fused):
+    """_write_pools + _decode_attention on a pool of 16 heads for a
+    model of 12 (rows of 12 heads, queries of 12 G): the same
+    attention as on a pool of 12, the padding heads' rows zeros."""
+    S, KV, D, bs, NB = 4, 12, 128, 16, 3
+    H = KV * G
+    ctx = jnp.asarray([5, 17, 33, 0], jnp.int32)
+    tbl = jnp.asarray(rng.permutation(S * NB).reshape(S, NB), jnp.int32)
+    flat = jnp.where(ctx > 0, jnp.take_along_axis(
+        tbl, ((ctx - 1) // bs)[:, None], 1)[:, 0] * bs + (ctx - 1) % bs, -1)
+    normal = lambda *shape: jnp.asarray(rng.normal(size=shape), jnp.float32)
+    q, kn, vn = normal(S, H, D), normal(S, KV, D), normal(S, KV, D)
+    own = (normal(S * NB + 1, bs, KV, D), normal(S * NB + 1, bs, KV, D))
+    held = tuple(jnp.pad(p, [(0, 0), (0, 0), (0, 4), (0, 0)]) for p in own)
+    outs = []
+    for pools in (own, held):
+        if fused:
+            att, *pools = M._decode_attention(
+                q, pools, tbl, ctx, True, k_new=kn, v_new=vn, slots=flat,
+                kv_heads=KV)
+        else:
+            pools = M._write_pools(pools, kn, vn, flat)
+            att = M._decode_attention(q, pools, tbl, ctx, True, kv_heads=KV)
+        outs.append((att, pools))
+    (a0, p0), (a1, p1) = outs
+    assert a1.shape == (S, H, D)
+    np.testing.assert_allclose(a1[:3], a0[:3], atol=2e-5)
+    for x, y in zip(p0, p1):
+        np.testing.assert_array_equal(y[:, :, :KV], x)
+        assert float(jnp.abs(y[:, :, KV:]).max()) == 0
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - whatever keeps libtpu away
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _kernels(text):
+    return [line for line in text.splitlines()
+            if 'custom_call_target="tpu_custom_call"' in line]
+
+
+def test_the_step_kernel_compiles_for_v5e_at_the_cells_shapes(one_chip):
+    """128 rows of 30 heads of 96 x 192 over a pool of 129 slots of 15
+    lane rows of two heads, aliased in and out (no second 285 MB pool
+    among the temporaries): Mosaic takes the pairs where it would
+    refuse a row of 192 lanes."""
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    rows, pool = 128, sds((129, 15, 96, 384))
+    assert GD.step_fits(rows, pool)
+    compiled = jax.jit(GD.gated_delta_step, donate_argnums=(5,)).lower(
+        sds((rows, 30, 96)), sds((rows, 30, 96)), sds((rows, 30, 192)),
+        sds((rows, 30)), sds((rows, 30)), pool, sds((rows,), jnp.int32),
+        sds((rows,), jnp.int32)).compile()
+    calls = _kernels(compiled.as_text())
+    assert len(calls) == 1 and "gdn_state" in calls[0]
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 129 * 30 * 96 * 192 * 4
+    assert mem.temp_size_in_bytes < 64 << 20
+
+
+def _walk_args(one_chip, held):
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    rows, pool = 128, sds((705, 128, held, 128), jnp.bfloat16)
+    q = new = sds((rows, 30, 128), jnp.bfloat16)
+    table, ints = sds((rows, 32), jnp.int32), sds((rows,), jnp.int32)
+    return q, pool, pool, new, new, table, ints, ints
+
+
+def test_the_walk_and_both_writes_compile_for_v5e_at_30_heads_in_32(one_chip):
+    """30 query / 30 KV heads of 128 over pools [705, 128, 32, 128], a
+    table of 32 slots a row, 128 rows, through the serving model's own
+    two calls: the row write and the live-block walk of the
+    shared-table step, and the fused walk of the single-token step."""
+    def shared(q, kc, vc, kn, vn, table, ctx, slots):
+        pools = M._write_pools((kc, vc), kn, vn, slots)
+        return M._decode_attention(q, pools, table, ctx, True,
+                                   kv_heads=30), pools
+
+    def single(q, kc, vc, kn, vn, table, ctx, slots):
+        return M._decode_attention(q, (kc, vc), table, ctx, True, k_new=kn,
+                                   v_new=vn, slots=slots, kv_heads=30)
+
+    args = _walk_args(one_chip, PA.kv_heads_held(30, 128, 2))
+    text = jax.jit(shared, donate_argnums=(1, 2)).lower(
+        *args).compile().as_text()
+    for name in ("paged_decode_grid", "paged_kv_write"):
+        assert any(name in line for line in _kernels(text)), name
+    assert PA.kv_write_path(args[1].shape, jnp.bfloat16) == "rows"
+    text = jax.jit(single, donate_argnums=(1, 2)).lower(
+        *args).compile().as_text()
+    assert any("paged_decode_fused" in line for line in _kernels(text))
+
+
+def test_a_pool_of_30_heads_stays_on_the_grid_and_is_not_refused(one_chip):
+    """What the padding is for: [705, 128, 30, 128] in 16 bits is no
+    whole tiles, its walk is the (S, NB) grid's and its write a whole
+    block's; the fused walk is not tried on it (Mosaic refuses its
+    row's DMA: "Slice shape along dimension 2 must be aligned to tiling
+    (8), but is 30")."""
+    q, pool, _, new, _, table, ints, _ = _walk_args(one_chip, 30)
+    assert not PA._whole_tiles(30, 128, 2)
+    assert PA.kv_write_path(pool.shape, jnp.bfloat16) == "blocks"
+    qg = jax.eval_shape(lambda q: PA._group_queries(q, 30, None)[0], q)
+    assert not PA._walks_live_blocks(qg, pool)
+    assert PA._walks_live_blocks(qg, _walk_args(one_chip, 32)[1])
+
+    def single(q, kc, vc, kn, vn, table, ctx, slots):
+        return PA.paged_decode_attention(q, kc, vc, table, ctx, k_new=kn,
+                                         v_new=vn, slots=slots)
+
+    text = jax.jit(single, donate_argnums=(1, 2)).lower(
+        q, pool, pool, new, new, table, ints, ints).compile().as_text()
+    assert not any("paged_decode_fused" in line for line in _kernels(text))
+    assert any("paged_decode_grid" in line for line in _kernels(text))

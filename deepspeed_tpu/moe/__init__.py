@@ -1,12 +1,15 @@
 from .dropless import (  # noqa: F401
     DroplessOut,
+    chosen_scores,
     dropless_apply,
     dropless_moe_ffn,
     dropless_topk_gating,
     expert_counts,
     grouped_mm,
     router_z_loss,
+    sigmoid_topk_gating,
     sort_by_expert,
+    sort_pairs,
 )
 from .sharded_moe import (  # noqa: F401
     compute_capacity,

@@ -82,6 +82,22 @@ def router_z_loss(logits) -> jnp.ndarray:
     return jnp.mean(jnp.square(lse))
 
 
+def chosen_scores(scores, idx) -> jnp.ndarray:
+    """scores [T, X] at idx [T, K] -> [T, K]: `take_along_axis` by a
+    comparison against an iota. One term of each sum is nonzero, so the
+    value is the chosen score bit for bit, and its transpose is a select
+    and a sum where a gather's is a scatter-add: an element-indexed
+    gather or scatter costs a v5e ~8.7 ns an element whatever it moves
+    (1.04 ms for the 131,072 pairs of a routed training layer, beside a
+    row gather of 134 MB in 0.21: PERF.md §5). The tokens stand LAST in
+    the comparison, so the sum over the experts adds whole registers:
+    0.016 ms at those pairs where the sum along the lanes of a
+    [T, K, X] comparison took 0.417 (PERF.md §6, PR 59)."""
+    hit = idx.T[:, None, :] == jnp.arange(
+        scores.shape[-1], dtype=idx.dtype)[:, None]  # [K, X, T]
+    return jnp.where(hit, scores.T, 0).sum(1).T
+
+
 def sigmoid_topk_gating(logits, top_k: int, bias=None,
                         renormalize: Optional[bool] = None,
                         scale: float = 1.0):
@@ -96,11 +112,11 @@ def sigmoid_topk_gating(logits, top_k: int, bias=None,
     below call it. logits [T, X] f32 -> (idx [T, k] int32, weights
     [T, k] f32)."""
     scores = jax.nn.sigmoid(logits)
-    if bias is not None:
-        _, idx = jax.lax.top_k(scores + bias.astype(jnp.float32), top_k)
-        wts = jnp.take_along_axis(scores, idx, axis=-1)
-    else:
-        wts, idx = jax.lax.top_k(scores, top_k)
+    _, idx = jax.lax.top_k(
+        scores if bias is None else scores + bias.astype(jnp.float32), top_k)
+    # (not top_k's own values where there is no bias: the same floats,
+    # but their gradient is a scatter-add over the pairs)
+    wts = chosen_scores(scores, idx)
     if renormalize:
         wts = wts / (jnp.sum(wts, axis=-1, keepdims=True) + 1e-20)
     return idx, wts * scale
@@ -138,7 +154,7 @@ def dropless_topk_gating(
 
     noisy = _apply_noise(logits, rng, noisy_gate_policy)
     _, idx = jax.lax.top_k(noisy, top_k)  # [T, K], ties -> lowest index
-    weights = jnp.take_along_axis(gates, idx, axis=-1)  # [T, K] fp32
+    weights = chosen_scores(gates, idx)  # [T, K] fp32
     if renormalize:
         weights = weights / jnp.maximum(
             jnp.sum(weights, axis=-1, keepdims=True),
@@ -151,9 +167,40 @@ def dropless_topk_gating(
 
 
 def expert_counts(expert_idx, n_experts: int) -> jnp.ndarray:
-    """[X] int32 assignment census from [T, K] (or flat) expert ids."""
+    """[X] int32 assignment census from [T, K] (or flat) expert ids, by
+    a comparison (chosen_scores says why not a scatter-add of ones)."""
     flat = expert_idx.reshape(-1)
-    return jnp.zeros((n_experts,), jnp.int32).at[flat].add(1)
+    return (flat[:, None] == jnp.arange(n_experts, dtype=flat.dtype)).sum(
+        0, dtype=jnp.int32)
+
+
+@jax.custom_vjp
+def sort_pairs(key, vals):
+    """ONE stable sort of the flat pairs by `key` [A] int32 that carries
+    each pair's slot and its value `vals` [A] along: returns (sorted
+    key, order, sorted vals), order the stable argsort of key bit for
+    bit and the other two `key[order]`, `vals[order]`, with no gather.
+    The file's one way to permute per-pair values: what jax derives for
+    a sort's payload is a gather forward and a scatter backward over
+    the A elements (chosen_scores has their price), so the cotangent of
+    `vals` goes back by a sort too, on `order`."""
+    return jax.lax.sort(
+        (key, jax.lax.iota(jnp.int32, key.shape[0]), vals),
+        num_keys=1, is_stable=True)
+
+
+def _sort_pairs_fwd(key, vals):
+    out = sort_pairs(key, vals)
+    return out, out[1]
+
+
+def _sort_pairs_bwd(order, cts):
+    # the inverse of a permutation is the sort of its indices
+    _, d_vals = jax.lax.sort((order, cts[2]), num_keys=1)
+    return None, d_vals
+
+
+sort_pairs.defvjp(_sort_pairs_fwd, _sort_pairs_bwd)
 
 
 def sort_by_expert(expert_idx) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
@@ -166,10 +213,12 @@ def sort_by_expert(expert_idx) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     decision — identical across EP layouts, so the grouped GEMM sees
     the same row order no matter how the mesh is carved.
     """
-    T, K = expert_idx.shape
+    K = expert_idx.shape[1]
     flat = expert_idx.reshape(-1)
-    order = jnp.argsort(flat, stable=True)
-    return order, order // K, flat[order]
+    sorted_experts, order = jax.lax.sort(
+        (flat, jax.lax.iota(jnp.int32, flat.shape[0])),
+        num_keys=1, is_stable=True)
+    return order, order // K, sorted_experts
 
 
 def grouped_mm(xs, w, counts, impl: str = "ragged"):
@@ -218,18 +267,20 @@ def _expert_mlp_sorted(xs, sorted_experts, counts, w_in, w_out, w_gate,
 def _ragged_wire(tokens, idx, weights, counts, w_in, w_out, w_gate,
                  b_in, b_out, act, impl):
     """EP=1 / serving wire: sort -> grouped GEMM -> segment-sum."""
-    T = tokens.shape[0]
+    T, K = idx.shape
     # the scopes a trace tells the wire's three parts by (serving reads
     # them; under training they nest inside `mlp`)
     with jax.named_scope("moe_route"):
-        order, src, sorted_experts = sort_by_expert(idx)
+        sorted_experts, order, wf = sort_pairs(
+            idx.reshape(-1), weights.reshape(-1))
+        src = order // K
         xs = tokens[src]  # [A, E] expert-contiguous
     with jax.named_scope("moe_experts"):
         ys = _expert_mlp_sorted(xs, sorted_experts, counts, w_in, w_out,
                                 w_gate, b_in, b_out, act, impl)
     with jax.named_scope("moe_combine"):
-        wf = weights.reshape(-1)[order].astype(tokens.dtype)
-        return jax.ops.segment_sum(ys * wf[:, None], src, num_segments=T)
+        return jax.ops.segment_sum(
+            ys * wf.astype(tokens.dtype)[:, None], src, num_segments=T)
 
 
 def held_rows_bound(n_tokens: int, top_k: int, held_count: int) -> int:
@@ -258,11 +309,16 @@ def _held_wire(tokens, idx, weights, counts, held, w_in, w_out, w_gate,
     past the last held pair is skipped at run time (`lax.cond`): an
     even router's T pairs a layer, and up to twice as many, take ONE
     chunk, the worst skew all of them, and none is ever dropped. A
-    chunk costs its rows whether they are live or not (the gather, the
-    selects, the segment sum: 15.4 ms of a 650 ms step), so a layer
-    whose load crosses 2 T pays a second chunk (with chunks of T rows,
-    the even load itself, a step took one chunk or two a layer by the
-    batch's luck), and a layer that holds NO pair pays the first all
+    chunk costs its rows whether they are live or not, ~15 ms a layer
+    of a 551 ms step (PERF.md §5, PR 59): three row scatter-adds of 2 T
+    rows of E, 2.6-2.9 ms each (the segment sum, forward and recomputed,
+    and the transpose of the row gather), the selects of dead rows 2.6,
+    the products' elementwise and the weighted sum 2.7, the row gathers
+    0.21 each (1.1 where the compiler leaves the tokens out of its fast
+    memory), so a layer whose load crosses 2 T pays a second chunk (with
+    chunks of T rows, the even load itself, a step took one chunk or two
+    a layer by the batch's luck), and a layer that holds NO pair pays the
+    first all
     the same: a chip's step takes the time of its shape at any load up
     to 2 T a layer, as the steps of the job's other chips do. A job
     that trains has no such layer; a run of one chip's cut alone has,
@@ -274,8 +330,10 @@ def _held_wire(tokens, idx, weights, counts, held, w_in, w_out, w_gate,
     PR 55). Each chunk is recomputed in the backward
     (`jax.checkpoint`), so the wire holds one chunk's rows at a time
     whatever the bound. idx, weights [T, K] over all X experts; counts
-    [X] the full census. Returns (out [T, E], held pairs NOT computed,
-    by the groups of the products that ran: 0)."""
+    [X] the full census. Outside the chunks nothing is indexed by pair:
+    a pair's slot and weight ride the sort (sort_pairs), its held flag is
+    the sorted key under `count`. Returns (out [T, E], held pairs NOT
+    computed, by the groups of the products that ran: 0)."""
     start, count = held
     T, K = idx.shape
     bound = held_rows_bound(T, K, count)
@@ -284,13 +342,14 @@ def _held_wire(tokens, idx, weights, counts, held, w_in, w_out, w_gate,
     with jax.named_scope("moe_route"):
         local = idx.reshape(-1) - start
         is_held = (local >= 0) & (local < count)
-        # held pairs first, by held expert; the rest behind them
-        order = jnp.argsort(jnp.where(is_held, local, count),
-                            stable=True)[:bound]
-        src = (order // K).reshape(n_chunks, C)
-        live = is_held[order]
-        wf = weights.reshape(-1)[order].astype(tokens.dtype).reshape(
-            n_chunks, C)
+        # held pairs first, by held expert; the rest behind them (the
+        # list is the sorted arrays' prefix: where count < K it is
+        # shorter than the T K pairs)
+        key, order, wf = sort_pairs(
+            jnp.where(is_held, local, count), weights.reshape(-1))
+        src = (order[:bound] // K).reshape(n_chunks, C)
+        live = key[:bound] < count
+        wf = wf[:bound].astype(tokens.dtype).reshape(n_chunks, C)
         held_counts = jax.lax.dynamic_slice_in_dim(
             counts.astype(jnp.int32), start, count)
         ends = jnp.cumsum(held_counts)

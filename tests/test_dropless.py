@@ -22,6 +22,7 @@ import pytest
 import deepspeed_tpu as ds
 from deepspeed_tpu.models import transformer as T
 from deepspeed_tpu.moe import (
+    chosen_scores,
     compute_capacity,
     dropless_apply,
     dropless_moe_ffn,
@@ -29,7 +30,9 @@ from deepspeed_tpu.moe import (
     expert_counts,
     grouped_mm,
     router_z_loss,
+    sigmoid_topk_gating,
     sort_by_expert,
+    sort_pairs,
     topk_gating,
 )
 
@@ -235,6 +238,149 @@ class TestDroplessWires:
                                top_k=2)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref.out),
                                    atol=1e-6)
+
+
+def _element_indexed(jaxpr, n_pairs):
+    """The gathers and scatters of `jaxpr` (bodies of cond / scan /
+    checkpoint / custom rules included) that move ONE element an index
+    over n_pairs indices or more: (primitive, indices, scope)."""
+    found = []
+    for eqn in jaxpr.eqns:
+        name = eqn.primitive.name
+        if name == "gather" or name.startswith("scatter"):
+            n_idx = int(np.prod(eqn.invars[1].aval.shape[:-1]))
+            moved = eqn.outvars[0].aval if name == "gather" \
+                else eqn.invars[2].aval
+            if moved.size == n_idx and n_idx >= n_pairs:
+                found.append((name, n_idx, str(eqn.source_info.name_stack)))
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (tuple, list)) else (v,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    found += _element_indexed(sub, n_pairs)
+    return found
+
+
+class TestPairsRideTheSort:
+    """A per-pair value is never fetched or put back by an element-
+    indexed gather or scatter over the T x K pairs (~8.7 ns an element
+    on a v5e whatever it moves, PERF.md §6, PR 59): it rides the sort,
+    or comes from a comparison. Every replaced value bit for bit."""
+
+    T_, K, X = 64, 4, 8
+
+    def _routing(self, seed=0):
+        r = np.random.default_rng(seed)
+        idx = jnp.asarray(np.argsort(r.normal(size=(self.T_, self.X)),
+                                     axis=-1)[:, :self.K], jnp.int32)
+        w = jnp.asarray(r.uniform(size=(self.T_, self.K)), jnp.float32)
+        return idx, w
+
+    # count < K: the list is a PREFIX of the sorted pairs; count >= K:
+    # all of them
+    @pytest.mark.parametrize("held", [(2, 2), (5, 3), (0, 6), (0, 8)])
+    def test_held_list_equals_argsort_and_three_gathers(self, held):
+        start, count = held
+        idx, w = self._routing()
+        bound = self.T_ * min(self.K, count)
+        local = idx.reshape(-1) - start
+        is_held = (local >= 0) & (local < count)
+        key = jnp.where(is_held, local, count)
+        order = jnp.argsort(key, stable=True)
+        sk, so, sw = sort_pairs(key, w.reshape(-1))
+        np.testing.assert_array_equal(so, order)
+        np.testing.assert_array_equal(sk, key[order])
+        np.testing.assert_array_equal(sw, w.reshape(-1)[order])
+        np.testing.assert_array_equal(sk[:bound] < count,
+                                      is_held[order[:bound]])
+        # every held pair is inside the list, whatever the bound cut
+        assert int(jnp.sum(sk[:bound] < count)) == int(jnp.sum(is_held))
+
+        cot = jnp.asarray(np.random.default_rng(1).normal(size=bound),
+                          jnp.float32)
+        by_sort = jax.grad(lambda v: jnp.sum(
+            sort_pairs(key, v.reshape(-1))[2][:bound] * cot))(w)
+        by_gather = jax.grad(lambda v: jnp.sum(
+            v.reshape(-1)[order[:bound]] * cot))(w)
+        np.testing.assert_array_equal(by_sort, by_gather)
+
+    def test_sort_by_expert_is_the_same_sort(self):
+        idx, w = self._routing(2)
+        order, src, sorted_e = sort_by_expert(idx)
+        sk, so, _ = sort_pairs(idx.reshape(-1), w.reshape(-1))
+        np.testing.assert_array_equal(order, so)
+        np.testing.assert_array_equal(sorted_e, sk)
+        np.testing.assert_array_equal(src, so // self.K)
+
+    @pytest.mark.parametrize("shape", ["pairs", "flat"])
+    def test_census_equals_scatter_add(self, shape):
+        idx, _ = self._routing(3)
+        idx = idx.at[:, 0].set(1)  # a crowded expert
+        ids = idx if shape == "pairs" else idx.reshape(-1)
+        want = jnp.zeros((self.X,), jnp.int32).at[idx.reshape(-1)].add(1)
+        got = expert_counts(ids, self.X)
+        assert got.dtype == jnp.int32
+        np.testing.assert_array_equal(got, want)
+
+    @pytest.mark.parametrize("bias", [False, True])
+    def test_chosen_scores_equal_take_along_axis(self, bias):
+        r = np.random.default_rng(4)
+        logits = r.normal(size=(self.T_, self.X)).astype(np.float32)
+        logits[:, 5] = logits[:, 2]  # ties: the lowest index is chosen
+        logits[::3] = 0.25           # a whole row of ties
+        logits = jnp.asarray(logits)
+        b = jnp.asarray(r.normal(size=self.X), jnp.float32) if bias else None
+        scores = jax.nn.sigmoid(logits)
+        _, want_idx = jax.lax.top_k(scores + b if bias else scores, self.K)
+        want = jnp.take_along_axis(scores, want_idx, axis=-1)
+        np.testing.assert_array_equal(chosen_scores(scores, want_idx), want)
+        idx, wts = sigmoid_topk_gating(logits, self.K, b, renormalize=True,
+                                       scale=2.5)
+        np.testing.assert_array_equal(idx, want_idx)
+        np.testing.assert_array_equal(
+            wts, want / (jnp.sum(want, -1, keepdims=True) + 1e-20) * 2.5)
+        # the softmax gate's weights, and both gradients
+        sidx, sw, _, _ = dropless_topk_gating(logits, self.K,
+                                              renormalize=False)
+        np.testing.assert_array_equal(sw, jnp.take_along_axis(
+            jax.nn.softmax(logits, axis=-1), sidx, axis=-1))
+        cot = jnp.asarray(r.normal(size=(self.T_, self.K)), jnp.float32)
+        np.testing.assert_array_equal(
+            jax.grad(lambda s: jnp.sum(chosen_scores(s, want_idx) * cot))(
+                scores),
+            jax.grad(lambda s: jnp.sum(jnp.take_along_axis(
+                s, want_idx, axis=-1) * cot))(scores))
+
+    @pytest.mark.parametrize("scoring,bias", [
+        ("softmax", False), ("sigmoid", True), ("sigmoid", False)])
+    @pytest.mark.parametrize("held", [None, (2, 2), (0, 6)])
+    def test_no_element_indexed_op_over_the_pairs(self, held, scoring, bias):
+        """What stands for the counter a static mechanism cannot have:
+        the jaxpr of a routed block's value and gradient holds no
+        gather or scatter of one element an index over T x K indices
+        (the row gathers and row scatter-adds of E elements an index
+        stay). Whole through _ragged_wire, held through _held_wire."""
+        Xh = self.X if held is None else held[1]
+        w = _weights(X=Xh)
+        router = _weights(X=self.X)["router"]
+        toks = jnp.asarray(
+            np.random.default_rng(5).normal(size=(self.T_, 16)), jnp.float32)
+
+        def loss(toks, router, w_in, w_out, w_gate):
+            res = dropless_moe_ffn(
+                toks, router, w_in, w_out, w_gate, act=jax.nn.silu,
+                top_k=self.K, held=held, scoring=scoring, renormalize=True,
+                choice_bias=jnp.zeros(self.X) if bias else None)
+            return jnp.sum(res.out ** 2) + res.l_aux + res.z_loss, res.counts
+
+        jaxpr = jax.make_jaxpr(jax.value_and_grad(
+            loss, argnums=(0, 1, 2, 3, 4), has_aux=True))(
+                toks, router, w["w_in"], w["w_out"], w["w_gate"])
+        assert _element_indexed(jaxpr.jaxpr, self.T_ * self.K) == []
+        # (the walk does see such an op where there is one)
+        probe = jax.make_jaxpr(jax.grad(lambda v: jnp.sum(
+            v[jnp.arange(self.T_ * self.K) % self.T_])))(toks[:, 0])
+        assert _element_indexed(probe.jaxpr, self.T_ * self.K)
 
 
 class TestGatingRngDeterminism:

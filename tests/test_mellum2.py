@@ -532,7 +532,11 @@ def test_an_older_familys_step_program_is_the_parents(name, kernels):
     paged_kv_write's row copies (Mistral, OLMoE, Qwen3-Next, Mellum 2:
     the write is another kernel body; the tiny LFM2, Granite and
     Nemotron-H pools are not whole tiles and keep the block path, and no
-    `xla` entry moved). A PR that changes a family's program ON PURPOSE
+    `xla` entry moved). PR 59 re-captured every routed family's two
+    entries (all but Mistral's): the gates' chosen scores come from a
+    comparison (moe/dropless.py chosen_scores) where the programs held
+    `take_along_axis` or top_k's own values; the same floats. A PR that
+    changes a family's program ON PURPOSE
     re-captures the
     file (`PYTHONPATH=. python tests/test_mellum2.py`) and says so;
     JAX's version changes it too."""

@@ -1,6 +1,8 @@
 """Tiny models and engines the serving tests share
 (tests/test_inference*.py)."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -14,8 +16,13 @@ def small_model(variant="llama", **kw):
                 variant=variant, use_flash=False)
     base.update(kw)
     cfg = T.TransformerConfig(**base)
-    params = T.init(cfg, jax.random.PRNGKey(0))
-    return cfg, params
+    return cfg, _init(cfg)(jax.random.PRNGKey(0))
+
+
+@functools.lru_cache(maxsize=None)
+def _init(cfg):
+    # ONE program a configuration (leaf by leaf, forty small compiles)
+    return jax.jit(lambda key: T.init(cfg, key))
 
 
 def engine_for(cfg, params, **ckw):
@@ -25,7 +32,18 @@ def engine_for(cfg, params, **ckw):
     return init_inference(params, cfg, base, dtype=jnp.float32)
 
 
+@functools.lru_cache(maxsize=None)
+def _forward(cfg):
+    return jax.jit(lambda params, toks: T.forward(params, toks, cfg))
+
+
 def oracle_next_logits(params, cfg, context):
-    """Training-model full-context forward → last-token logits."""
-    logits = T.forward(params, jnp.asarray([context], jnp.int32), cfg)
-    return np.asarray(logits[0, -1], np.float32)
+    """Training-model full-context forward → last-token logits. ONE
+    program a configuration: the context padded to max_seq (causal: what
+    follows the last token cannot reach it). Op by op at every length a
+    decode loop passes through, the forward was sixty small compiles a
+    length."""
+    toks = np.zeros((1, cfg.max_seq), np.int32)
+    toks[0, :len(context)] = context
+    logits = _forward(cfg)(params, toks)
+    return np.asarray(logits[0, len(context) - 1], np.float32)

@@ -1,11 +1,13 @@
-"""The row patterns and the check that tests/test_qwen3_next.py and
-tests/test_granite_moe_hybrid.py hold the matrix-state step kernels'
+"""The row patterns and the check that tests/test_qwen3_next.py,
+tests/test_granite_moe_hybrid.py, tests/test_nemotron_h.py and
+tests/test_olmo_hybrid_delta.py hold the matrix-state step kernels'
 shared walk to (ops/pallas/gated_delta.py state_step_call), each with
 its own kernel and oracle.
 
 A pool here has 12 slots and the pad rows' 13th, three matrices a slot.
 """
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -62,6 +64,34 @@ CASES += [pytest.param(p, w, id=f"{p}-{w}") for p, w in (
     ("a_chunk_longer_than_a_batch", "none_aside"))]
 
 
+def ssm_inputs(rng, *lead, G=1, H=8, P=16, N=32):
+    """x, dt, A, B, C of a Mamba-2 step over `lead` rows: H heads of P
+    with a state of N, B and C in G groups; decays exp(dt A) from ~0.2
+    to ~0.99 a token."""
+    def normal(*shape):
+        return jnp.asarray(rng.normal(size=shape), jnp.float32)
+
+    return (normal(*lead, H, P), jax.nn.softplus(normal(*lead, H) - 1.0),
+            -jnp.exp(normal(H) * 0.5), normal(*lead, G * N),
+            normal(*lead, G * N))
+
+
+def ragged(rng, pool_shape):
+    """A step's rows over a seeded pool of six slots: a run of five from
+    a slot's state (positions 5..9), a decode row, a pad row, a run of
+    three from position 0 (the slot's NaN must not be read), another pad
+    row: (pool, each row's slot, each row's position, the runs as (rows,
+    slot, the state the run starts from or None)). Slots 2 and 4 are in
+    no row: a step leaves them as they were."""
+    slots = jnp.asarray([3, 3, 3, 3, 3, 1, -1, 0, 0, 0, -1], jnp.int32)
+    pos = jnp.asarray([5, 6, 7, 8, 9, 12, 0, 0, 1, 2, 0], jnp.int32)
+    pool = jnp.asarray(rng.normal(size=pool_shape), jnp.float32)
+    pool = pool.at[0].set(jnp.nan)
+    return pool, slots, pos, ((slice(0, 5), 3, pool[3]),
+                              (slice(5, 6), 1, pool[1]),
+                              (slice(7, 10), 0, None))
+
+
 def set_walk(monkeypatch, walk: str, pool_shape):
     patches, shape = WALKS[walk]
     for name, value in patches.items():
@@ -82,8 +112,10 @@ def check_walk(step, xla, args_of, pool_shape, pattern: str, rng):
         if at[rows.index(s)] == 0:
             pool = pool.at[s].set(jnp.nan)
     args = args_of(rng, slots.shape[0])
-    out, new = step(*args, pool, slots, pos)
-    want_out, want = xla(*args, pool, slots, pos)
+    # each side ONE program, as a step calls it (op by op, the oracle's
+    # loop over rows is a dozen small compiles a case)
+    out, new = jax.jit(step)(*args, pool, slots, pos)
+    want_out, want = jax.jit(xla)(*args, pool, slots, pos)
     np.testing.assert_allclose(out, want_out, atol=2e-5)
     for s in range(SLOTS + 1):
         if s in rows:

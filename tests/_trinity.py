@@ -5,6 +5,7 @@ import pathlib
 
 import jax
 import numpy as np
+import pytest
 
 from benchmarks import harness
 from deepspeed_tpu.models import transformer as T
@@ -18,6 +19,13 @@ PUBLISHED = {k: v for k, v in harness.load_json(
     BENCH / "configs/published/trinity-mini.json").items()
     if not k.startswith("_")}
 PERIOD = ["sliding_attention"] * 3 + ["full_attention"]
+
+
+@pytest.fixture(scope="module")
+def highest():
+    """float32 matmuls at full precision, for a module's comparisons."""
+    with jax.default_matmul_precision("highest"):
+        yield
 
 
 def tiny(**over):
@@ -57,8 +65,9 @@ def seeded(mcfg, seed=1):
             return 0.02 * jax.random.normal(k, x.shape)
         return x * 20 if "w_router" in name else x
 
-    return jax.tree_util.tree_map_with_path(
-        jig, T.init(mcfg, jax.random.PRNGKey(seed)))
+    # ONE program: leaf by leaf it is a hundred small compiles a tree
+    return jax.jit(lambda: jax.tree_util.tree_map_with_path(
+        jig, T.init(mcfg, jax.random.PRNGKey(seed))))()
 
 
 def tokens_of(hf, shape, seed=0):

@@ -17,6 +17,14 @@ there:
   JAX_PLATFORMS nor the host-device flag, and refuses to start unless
   the backend is "tpu"; `pallas_interpret` is then a no-op, so the same
   tests compile their kernels with Mosaic.
+
+What is here is what every module needs. A served family's helpers,
+fixtures (one engine a configuration a module) and recurring cases are
+tests/_family.py's, imported by the family's module; what a family's
+module may cost is README.md "A served family's tests". The two hooks
+below stand in for edits only a `benchmark` PR may make to
+benchmarks/tests (collected through tests/benchmark_suite) and go with
+them.
 """
 
 import contextlib
@@ -100,8 +108,9 @@ def pytest_collection_modifyitems(config, items):
 # `tpot_p50_ms` and the harness raises (the driver's run of PR 55 and the
 # builder's, 1,440 and 1,333 s; the runs of 1,228-1,279 s passed). A case
 # of that file that fails with THIS message is run once more; any other
-# failure stands. A `benchmark` PR that gives the cell a longer window
-# takes this away (ROADMAP.md Q-bench).
+# failure stands. PR 60 took load off that worker's neighbours, not the
+# window off the wall clock: a `benchmark` PR that gives the cell a longer
+# window takes this away (ROADMAP.md Q-bench).
 _TOO_LOADED_FOR_THE_WINDOW = "did not produce end-to-end metric"
 
 

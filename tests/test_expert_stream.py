@@ -111,8 +111,10 @@ def _both(h, lp, cfg, monkeypatch):
     assert M.expert_path(h.shape[0], cfg, lp, True) == "stream"
     assert M.expert_path(h.shape[0], cfg, lp, False) == "scan"
     f = lambda a: np.asarray(a.astype(jnp.float32), np.float64)
-    return (f(M._mlp(h, lp, cfg, None, True, None)),
-            f(M._mlp(h, lp, cfg, None, False, None)))
+    # (each path ONE program, as a step holds it; op by op the layer is
+    # fifteen small compiles a case)
+    return tuple(f(jax.jit(lambda h, lp, kernels=kernels: M._mlp(
+        h, lp, cfg, None, kernels, None))(h, lp)) for kernels in (True, False))
 
 
 # 13: off the 16-row sublane tile of a 16-bit type

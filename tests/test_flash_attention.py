@@ -91,7 +91,9 @@ class TestBackwardKernels:
             out = oracle(q[:, :, None], k[:, :, None], v[:, :, None], causal)[:, :, 0]
             return jnp.sum(out * do)
 
-        dq_ref, dk_ref, dv_ref = jax.grad(f, argnums=(0, 1, 2))(q, k, v)
+        # (the oracle's gradient ONE program: op by op it is dozens of
+        # small compiles a case, here and below)
+        dq_ref, dk_ref, dv_ref = jax.jit(jax.grad(f, argnums=(0, 1, 2)))(q, k, v)
 
         o, lse = _flash_fwd(q, k, v, None, causal, BLK, BLK, H=1, KV=1)
         dq, dk, dv = _flash_bwd(q, k, v, None, o, lse, do, causal, BLK, BLK,
@@ -116,9 +118,9 @@ class TestFlashGQA:
 
         with jax.default_matmul_precision("highest"):
             out = flash_attention(q, k, v, block_q=BLK, block_k=BLK)
-            ref = oracle(q, k, v)
-            g1 = jax.grad(f_flash, argnums=(0, 1, 2))(q, k, v)
-            g2 = jax.grad(f_ref, argnums=(0, 1, 2))(q, k, v)
+            ref = jax.jit(oracle)(q, k, v)
+            g1 = jax.jit(jax.grad(f_flash, argnums=(0, 1, 2)))(q, k, v)
+            g2 = jax.jit(jax.grad(f_ref, argnums=(0, 1, 2)))(q, k, v)
         np.testing.assert_allclose(out, ref, rtol=2e-3, atol=2e-3)
         for a, b, name in zip(g1, g2, "qkv"):
             np.testing.assert_allclose(a, b, rtol=2e-3, atol=2e-3, err_msg=f"d{name}")
@@ -135,8 +137,8 @@ class TestBF16:
         def loss_ref(q, k, v):
             return jnp.sum(oracle(q, k, v).astype(jnp.float32) ** 2)
 
-        g1 = jax.grad(loss_flash)(q, k, v)
-        g2 = jax.grad(loss_ref)(q, k, v)
+        g1 = jax.jit(jax.grad(loss_flash))(q, k, v)
+        g2 = jax.jit(jax.grad(loss_ref))(q, k, v)
         np.testing.assert_allclose(
             np.asarray(g1, np.float32), np.asarray(g2, np.float32), rtol=5e-2, atol=5e-2
         )
@@ -182,12 +184,14 @@ class TestSlidingWindowKernel:
 
         with jax.default_matmul_precision("highest"):
             o = flash_fn(q, k, v)
-            ref = win_oracle(q, k, v)
+            ref = jax.jit(win_oracle)(q, k, v)
             np.testing.assert_allclose(o, ref, rtol=2e-3, atol=2e-3)
 
             cot = jnp.asarray(rng.normal(size=o.shape), o.dtype)
-            g = jax.grad(lambda *a: jnp.vdot(flash_fn(*a), cot), argnums=(0, 1, 2))(q, k, v)
-            gr = jax.grad(lambda *a: jnp.vdot(win_oracle(*a), cot), argnums=(0, 1, 2))(q, k, v)
+            g = jax.jit(jax.grad(lambda *a: jnp.vdot(flash_fn(*a), cot),
+                                 argnums=(0, 1, 2)))(q, k, v)
+            gr = jax.jit(jax.grad(lambda *a: jnp.vdot(win_oracle(*a), cot),
+                                  argnums=(0, 1, 2)))(q, k, v)
         for a, b in zip(g, gr):
             np.testing.assert_allclose(a, b, rtol=5e-3, atol=5e-3)
 
@@ -228,15 +232,15 @@ class TestAlibi:
         with jax.default_matmul_precision("highest"):
             out = flash_attention(q, k, v, causal=True, block_q=BLK,
                                   block_k=BLK, alibi=ab)
-            ref = orc(q, k, v)
+            ref = jax.jit(orc)(q, k, v)
             np.testing.assert_allclose(out, ref, rtol=2e-4, atol=2e-4)
 
             do = jnp.asarray(rng.normal(size=out.shape), out.dtype)
-            gk = jax.grad(lambda *a: jnp.sum(flash_attention(
+            gk = jax.jit(jax.grad(lambda *a: jnp.sum(flash_attention(
                 *a, causal=True, block_q=BLK, block_k=BLK, alibi=ab) * do),
-                argnums=(0, 1, 2))(q, k, v)
-            go = jax.grad(lambda *a: jnp.sum(orc(*a) * do),
-                          argnums=(0, 1, 2))(q, k, v)
+                argnums=(0, 1, 2)))(q, k, v)
+            go = jax.jit(jax.grad(lambda *a: jnp.sum(orc(*a) * do),
+                                  argnums=(0, 1, 2)))(q, k, v)
         for a, b in zip(gk, go):
             np.testing.assert_allclose(a, b, rtol=2e-3, atol=2e-3)
 
@@ -301,13 +305,13 @@ class TestTileKinds:
 
         with jax.default_matmul_precision("highest"):
             o = flash_fn(q, k, v)
-            np.testing.assert_allclose(o, ref_fn(q, k, v), rtol=2e-3,
+            np.testing.assert_allclose(o, jax.jit(ref_fn)(q, k, v), rtol=2e-3,
                                        atol=2e-3)
             cot = jnp.asarray(rng.normal(size=o.shape), o.dtype)
-            g = jax.grad(lambda *a: jnp.vdot(flash_fn(*a), cot),
-                         argnums=(0, 1, 2))(q, k, v)
-            gr = jax.grad(lambda *a: jnp.vdot(ref_fn(*a), cot),
-                          argnums=(0, 1, 2))(q, k, v)
+            g = jax.jit(jax.grad(lambda *a: jnp.vdot(flash_fn(*a), cot),
+                                 argnums=(0, 1, 2)))(q, k, v)
+            gr = jax.jit(jax.grad(lambda *a: jnp.vdot(ref_fn(*a), cot),
+                                  argnums=(0, 1, 2)))(q, k, v)
         for a, b, name in zip(g, gr, "qkv"):
             np.testing.assert_allclose(a, b, rtol=5e-3, atol=5e-3,
                                        err_msg=f"d{name}")

@@ -7,9 +7,11 @@ WITHOUT positions that alone hold K/V, a held share of routed experts
 beside an ungated shared expert, the four scalars; through whole-prompt
 prefill (the chunked scan), chunks and single steps (the segmented
 recurrence), through the scheduler with slots reused and never cleared;
-the chunked form against the recurrence, the step kernel against its
-oracle, the four shares that add up to the uncut layer, the mutants
-that must fail, the refusals, and the cut's file.
+the four shares that add up to the uncut layer, the mutants that must
+fail, the refusals, and the cut's file; then the recurrence alone (the
+chunked form, the step kernel, its walk, the kernels at the cell's
+shapes). The contract every served family is held to is
+tests/_family.py's.
 
 Everything is float32 with seeded weights: two periods of (mamba,
 mamba, attention, mamba), d 64, 8 state-space heads of 16 with a state
@@ -19,24 +21,36 @@ experts of 32 top-3 of which experts 4..7 are held, a shared expert of
 64.
 """
 
+import functools
 import json
-import os
-import pathlib
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+import _family as F
 import _state_walk as W
 import pytest
-from jax.sharding import SingleDeviceSharding
+from _family import (  # noqa: F401 - the contract's fixtures and cases, collected here
+    engines,
+    family,
+    model,
+    one_chip,
+    pytest_generate_tests,
+    served,
+    test_a_chunk_boundary_at_every_offset,
+    test_a_wrong_model_fails_the_written_tolerance,
+    test_prefill_chunks_and_single_steps_match_the_reference,
+    test_prefix_credit_and_speculation_are_refused,
+    test_the_cuts_file_keeps_the_published_widths,
+    test_the_engine_refuses_at_build,
+    test_the_engine_with_kernels_matches_the_reference,
+    test_the_training_forward_refuses_the_family,
+    test_what_the_mapping_cannot_serve_is_an_error,
+    test_whole_prompt_waves_and_fused_decode_carry_the_state,
+)
 
 from benchmarks.reference import granite_moe_hybrid as ref
-from benchmarks.tests import helpers
-from deepspeed_tpu.inference import (
-    ServingScheduler,
-    ServingSchedulerConfig,
-    init_inference,
-)
+from deepspeed_tpu.inference import ServingScheduler, ServingSchedulerConfig
 from deepspeed_tpu.inference import engine as E
 from deepspeed_tpu.inference import model as M
 from deepspeed_tpu.models import transformer as T
@@ -44,8 +58,7 @@ from deepspeed_tpu.ops.pallas import conv_carry as CC
 from deepspeed_tpu.ops.pallas import ssm_state as SS
 from deepspeed_tpu.utils.hf_checkpoint import config_from_hf
 
-ROOT = pathlib.Path(__file__).resolve().parents[1]
-BENCH = ROOT / "benchmarks"
+BENCH = F.BENCH
 CUT = BENCH / "configs/granite-4.0-h-small-serve-l10-ep4.json"
 PATTERN = ["mamba", "mamba", "attention", "mamba"]
 HF = {"attention_bias": False, "attention_multiplier": 0.0625,
@@ -72,152 +85,77 @@ HF = {"attention_bias": False, "attention_multiplier": 0.0625,
 # taps' sum in another order, q scaled before the scores and not
 # after), which moves a logit by under 1e-7 (measured here: 6e-8 over
 # prefill, a chunk and single steps; 7e-8 over the chunk offsets). The
-# mutants differ by MUTANT_DISTANCES below, the smallest 260 x the
-# limit, which is 30 x the noise.
+# mutants differ by at least 316 x the limit (the nearest of them,
+# `softmax_all_no_renorm`), which is 30 x the noise.
 LOGITS_ATOL = 2e-6
 ENGINE = dict(max_seq_len=256, kv_block_size=32, num_kv_blocks=48,
               max_batch_size=32, max_tracked_sequences=6,
               min_prefill_bucket=32)
 
 
-@pytest.fixture(scope="module")
-def model():
-    mcfg = config_from_hf(HF, use_flash=False)
-    params = T.init(mcfg, jax.random.PRNGKey(1))
-    # spread the logits (the 0.02 init gives nearly flat ones) and make
-    # every norm scale, tap, bias, decay and skip matter
-    params = jax.tree.map(lambda x: x * 4, params)
-
-    def shaped(tree, salt):
-        out = {}
-        for i, (k, v) in enumerate(tree.items()):
-            key = jax.random.fold_in(jax.random.PRNGKey(salt), i)
-            if k == "ssm_d":
-                # away from 0 (no skip) and from 1 (the publisher's start)
-                v = jax.random.uniform(key, v.shape, minval=0.4, maxval=0.7)
-            elif "scale" in k:
-                v = 1 + 0.3 * jax.random.normal(key, v.shape)
-            elif k == "ssm_taps":
-                v = 0.6 * jax.random.normal(key, v.shape)
-            elif k == "ssm_conv_bias":
-                v = 0.5 * jax.random.normal(key, v.shape)
-            elif k in ("attn_wq", "attn_wk"):
-                v = v * 6  # scores sharp enough for positions to matter
-            elif k in ("ssm_a_log", "ssm_dt_bias"):
-                # decays from 0.3 to 0.97 a token: long and short memory
-                v = jax.random.uniform(key, v.shape, minval=-3.0, maxval=0.5)
-            out[k] = v
-        return out
-
-    top = shaped({k: v for k, v in params.items() if k != "layers"}, 2)
-    return mcfg, dict(top, layers=shaped(params["layers"], 3))
+def _jig(k, v, key):
+    """Every norm scale, tap, bias, decay and skip matters."""
+    if k == "ssm_d":
+        # away from 0 (no skip) and from 1 (the publisher's start)
+        return jax.random.uniform(key, v.shape, minval=0.4, maxval=0.7)
+    if "scale" in k:
+        return 1 + 0.3 * jax.random.normal(key, v.shape)
+    if k == "ssm_taps":
+        return 0.6 * jax.random.normal(key, v.shape)
+    if k == "ssm_conv_bias":
+        return 0.5 * jax.random.normal(key, v.shape)
+    if k in ("attn_wq", "attn_wk"):
+        return v * 6  # scores sharp enough for positions to matter
+    if k in ("ssm_a_log", "ssm_dt_bias"):
+        # decays from 0.3 to 0.97 a token: long and short memory
+        return jax.random.uniform(key, v.shape, minval=-3.0, maxval=0.5)
+    return v
 
 
-def _top(params):
-    return {k: v for k, v in params.items() if k != "layers"}
+def test_what_only_this_cut_states():
+    hf = json.loads(CUT.read_text())
+    assert hf["vocab_size"] * 4 == hf["reduced"]["vocab_size"]["published"]
+    assert hf["layer_types"] == hf["reduced"]["layer_types"]["published"][:10]
 
 
-def _layer_fn(params):
-    return lambda l: jax.tree.map(lambda a: a[l], params["layers"])
-
-
-def _ref_logits(params, toks, mutate=None, hf=HF):
-    return np.asarray(ref.forward_logits(_top(params), _layer_fn(params),
-                                         toks, hf, mutate))
-
-
-def _engine(model, **over):
-    mcfg, params = model
-    return init_inference(params, mcfg, dict(ENGINE, **over),
-                          dtype=jnp.float32)
-
-
-@pytest.fixture(scope="module")
-def shared_engine(model):
-    """One engine for the teacher-forced tests: they flush what they
-    put, and share its compiled programs."""
-    return _engine(model)
-
-
-def _feeds(model, eng, lens, splits, n_dec, seed=0):
-    """Teacher-forced put() logits of prompts of `lens`, each fed as
-    len - sum(splits) tokens whole, then chunks of `splits`, then n_dec
-    single tokens: (engine logits [prompts, feeds, V], the reference's
-    at the same positions)."""
-    rng = np.random.default_rng(seed)
-    full = [rng.integers(0, HF["vocab_size"], n + n_dec).astype(np.int32)
-            for n in lens]
-    uids = list(range(100, 100 + len(lens)))
-    cuts = [[n - sum(splits[j:]) for j in range(len(splits) + 1)]
-            + [n + j + 1 for j in range(n_dec)] for n in lens]
-    got = []
-    for j in range(len(cuts[0])):
-        toks = [f[(c[j - 1] if j else 0):c[j]] for f, c in zip(full, cuts)]
-        got.append(np.asarray(eng.put(uids, toks)))
-    for u in uids:
-        eng.flush(u)
-    padded = np.zeros((len(full), max(map(len, full))), np.int32)
-    for i, f in enumerate(full):
-        padded[i, :len(f)] = f
-    want = _ref_logits(model[1], padded)
-    want = np.stack([want[i, np.asarray(c) - 1] for i, c in enumerate(cuts)])
-    return np.stack(got, axis=1), want, padded, cuts
-
-
-@pytest.fixture(scope="module")
-def served(model, shared_engine):
-    return _feeds(model, shared_engine, [70, 83], [5], 6)
-
-
-def test_prefill_chunks_and_single_steps_match_the_reference(served):
-    got, want, _, _ = served
-    assert np.isfinite(got).all()
-    assert np.abs(want).max() > 0.2
-    assert np.abs(got - want).max() < LOGITS_ATOL, np.abs(got - want).max(-1)
-
-
-@pytest.mark.parametrize("chunk", [1, 2, 3, 4, 7])
-def test_a_chunk_boundary_at_every_offset(model, shared_engine, chunk):
-    """The first chunk starts 1..7 tokens before the prompt's end (a
-    run of one, runs shorter and longer than the convolution's three
-    carried inputs), a second chunk of 4 follows (its first rows read
-    what the first left in the slot: the matrices and the inputs), then
-    single steps."""
-    got, want, _, _ = _feeds(model, shared_engine, [41, 56], [chunk, 4], 3,
-                             seed=chunk)
-    assert np.abs(got - want).max() < LOGITS_ATOL, np.abs(got - want).max(-1)
-
-
-def _float8(x):
-    return x.astype(jnp.float8_e4m3fn).astype(x.dtype)
-
-
-@pytest.mark.parametrize("control", ref.MUTANTS + ("float8_weights",))
-def test_a_wrong_model_fails_the_written_tolerance(model, served, control):
-    """Each of the logits audit's controls, put in the reference's
-    place: the engine must NOT agree with it. `state_bf16` (the
-    matrices rounded to bf16 after every token) is judged HERE: the
-    chip's bf16 engine cannot tell it from its own rounding."""
-    got, _, padded, cuts = served
-    params = model[1]
-    if control == "float8_weights":
-        wrong = _ref_logits(jax.tree.map(_float8, params), padded)
-    else:
-        wrong = _ref_logits(params, padded, control)
-    wrong = np.stack([wrong[i, np.asarray(c) - 1] for i, c in enumerate(cuts)])
-    # the nearest of them, `softmax_all_no_renorm`, reads 316 x
-    assert np.abs(got - wrong).max() > 300 * LOGITS_ATOL, control
+FAMILY = F.Family(
+    hf=HF, ref=ref, atol=LOGITS_ATOL, engine=ENGINE, jig=_jig, spread=0.2,
+    far=300, run_tokens="ssm_run_tokens", cut=CUT,
+    with_kernels=(True, False, "state_space/ssm_state/jit(_ssm_step)"),
+    reduced=("layer_types", "num_hidden_layers", "num_local_experts",
+             "vocab_size"),
+    held={"start": 0, "count": 18, "of": 72},
+    assumed=("state_dtype", "state_layout", "column_order", "no_dt_clamp",
+             "decay", "skip_d", "weights", "state_slots", "kv_pool",
+             "max_tracked_sequences", "max_seq_len"),
+    unservable=(
+        ("a latent key the mapping does not read", dict(HF, kv_lora_rank=32),
+         "does not read"),
+        ("state-space keys under another architecture",
+         dict(F.MISTRAL, mamba_n_heads=8), "does not read"),
+        ("a multiplier under another architecture",
+         dict(F.MISTRAL, residual_multiplier=0.22), "does not read"),
+        ("no positions under another architecture",
+         dict(F.MISTRAL, position_embedding_type="nope"), "does not read"),
+        ("groups that cut a lane row of the pool's heads",
+         dict(HF, mamba_n_groups=2), "ssm_groups"),
+        ("rotary positions", dict(HF, position_embedding_type="rope"),
+         "position_embedding_type"),
+        ("a convolution without its bias", dict(HF, mamba_conv_bias=False),
+         "mamba_conv_bias"),
+        ("a kind the family does not have",
+         dict(HF, layer_types=["conv"] * 8), "layer_types names"),
+        ("heads that are not the expansion", dict(HF, mamba_n_heads=6),
+         "mamba_expand"),
+        ("a shared expert that is no multiple of an expert",
+         dict(HF, shared_intermediate_size=100), "no multiple"),
+    ))
 
 
 # -- the configuration ---------------------------------------------------
 
-def _cut():
-    hf = json.loads(CUT.read_text())
-    return hf, config_from_hf(hf, **hf["serve"]["model_overrides"])
-
-
 def test_the_cut_builds_at_published_widths():
-    hf, cfg = _cut()
+    hf, cfg = F.cut_of(FAMILY)
     row = json.loads((BENCH / "configs/published/granite-4.0-h-small.json"
                       ).read_text())
     # the six widths benchmarks/tests/helpers.WIDTH_KEY does not know
@@ -266,13 +204,9 @@ def test_the_cut_builds_at_published_widths():
     assert shapes["ssm_out"].shape == (9, 8192, 4096)
     assert shapes["attn_wq"].shape == (1, 4096, 32, 128)
     assert shapes["attn_wk"].shape == (1, 4096, 8, 128)
-    flat = dict(shapes["layers"], **{k: v for k, v in shapes.items()
-                                     if k != "layers"})
+    flat = F.one_stack(cfg, shapes)
     # the file's own count, every leaf
     assert sum(int(np.prod(s.shape)) for s in flat.values()) == 2_955_758_208
-    assert all(not isinstance(v, dict) for k, v in shapes.items()
-               if k != "layers")
-    assert all(v.shape[0] == cfg.n_layers for v in shapes["layers"].values())
     # the cache: K/V for the ONE attention layer; two pools a Mamba-2
     # layer, the matrices' with the pad rows' slot
     cache = jax.eval_shape(lambda: M.init_cache(
@@ -314,65 +248,15 @@ def test_the_published_shapes_stream_the_held_experts():
     pipelined pass."""
     from deepspeed_tpu.ops.pallas.expert_stream import stream_f_tile
 
-    _, cfg = _cut()
-    stack = jax.ShapeDtypeStruct((18, 4096, 768), jnp.bfloat16)
-    lp = {"w_gate": stack, "w_in": stack,
-          "w_out": jax.ShapeDtypeStruct((18, 768, 4096), jnp.bfloat16)}
+    _, cfg = F.cut_of(FAMILY)
+    lp = F.expert_stacks(18, 4096, 768)
     assert {M.expert_path(t, cfg, lp, True) for t in (8, 128, 256)} == \
         {"stream"}
     assert M.expert_path(128, cfg, lp, False) == "scan"
     assert stream_f_tile(128, lp["w_gate"], lp["w_in"], lp["w_out"]) == 256
 
 
-def test_the_cuts_file_keeps_the_published_widths():
-    hf = json.loads(CUT.read_text())
-    helpers.check_published_widths(hf, BENCH)
-    assert sorted(hf["reduced"]) == ["layer_types", "num_hidden_layers",
-                                     "num_local_experts", "vocab_size"]
-    assert hf["share_of"] and hf["stands_for"]
-    assert hf["experts_held"] == {"start": 0, "count": 18, "of": 72}
-    assert hf["vocab_size"] * 4 == hf["reduced"]["vocab_size"]["published"]
-    assert hf["layer_types"] == hf["reduced"]["layer_types"]["published"][:10]
-    for key in ("state_dtype", "state_layout", "column_order", "no_dt_clamp",
-                "decay", "skip_d", "weights", "state_slots", "kv_pool",
-                "max_tracked_sequences", "max_seq_len"):
-        assert hf["assumed"][key]
-
-
-_MISTRAL = {"architectures": ["MistralForCausalLM"], "hidden_size": 64,
-            "intermediate_size": 128, "num_attention_heads": 4,
-            "num_key_value_heads": 2, "num_hidden_layers": 2,
-            "vocab_size": 64}
-
-
-@pytest.mark.parametrize("what,hf,match", [
-    ("a latent key the mapping does not read", dict(HF, kv_lora_rank=32),
-     "does not read"),
-    ("state-space keys under another architecture",
-     dict(_MISTRAL, mamba_n_heads=8), "does not read"),
-    ("a multiplier under another architecture",
-     dict(_MISTRAL, residual_multiplier=0.22), "does not read"),
-    ("no positions under another architecture",
-     dict(_MISTRAL, position_embedding_type="nope"), "does not read"),
-    ("groups that cut a lane row of the pool's heads",
-     dict(HF, mamba_n_groups=2), "ssm_groups"),
-    ("rotary positions", dict(HF, position_embedding_type="rope"),
-     "position_embedding_type"),
-    ("a convolution without its bias", dict(HF, mamba_conv_bias=False),
-     "mamba_conv_bias"),
-    ("a kind the family does not have",
-     dict(HF, layer_types=["conv"] * 8), "layer_types names"),
-    ("heads that are not the expansion", dict(HF, mamba_n_heads=6),
-     "mamba_expand"),
-    ("a shared expert that is no multiple of an expert",
-     dict(HF, shared_intermediate_size=100), "no multiple"),
-])
-def test_what_the_mapping_cannot_serve_is_an_error(what, hf, match):
-    with pytest.raises(ValueError, match=match):
-        config_from_hf(hf)
-
-
-def test_b_and_c_in_two_groups_are_served():
+def test_b_and_c_in_two_groups_are_served(engines):
     """`mamba_n_groups` is read, not refused: 16 heads in two lane rows
     of the pool, a row a group. The whole-prompt scan (chunked, a
     group) and the same tokens as a prefill, a chunk and single steps
@@ -383,7 +267,7 @@ def test_b_and_c_in_two_groups_are_served():
     assert (mcfg.ssm_groups, mcfg.ssm_inner, mcfg.ssm_conv_dim) == \
         (2, 256, 256 + 2 * 2 * 32)
     params = jax.tree.map(lambda x: x * 4, T.init(mcfg, jax.random.PRNGKey(2)))
-    eng = init_inference(params, mcfg, ENGINE, dtype=jnp.float32)
+    eng = engines.fresh(model=(mcfg, params))  # two other models: an engine each
     toks = np.random.default_rng(3).integers(0, 256, 40).astype(np.int32)
     whole = np.asarray(eng.put([1], [toks]))[0]
     eng.put([2], [toks[:30]])
@@ -395,7 +279,7 @@ def test_b_and_c_in_two_groups_are_served():
     # every head reading group 0's B and C is another model
     one = dict(params, ssm_in=params["ssm_in"].at[:, :, 576:608].set(
         params["ssm_in"][:, :, 544:576]))
-    other = init_inference(one, mcfg, ENGINE, dtype=jnp.float32)
+    other = engines.fresh(model=(mcfg, one))
     assert np.abs(np.asarray(other.put([1], [toks]))[0] - whole).max() > \
         300 * LOGITS_ATOL
 
@@ -428,11 +312,9 @@ def test_the_kinds_and_their_tables_are_one():
         T.TransformerConfig(position_embedding="sinusoidal")
 
 
-def test_the_training_forward_refuses_the_family(model):
-    mcfg, params = model
-    with pytest.raises(NotImplementedError, match="layer_types"):
-        T.forward_hidden(params, jnp.zeros((1, 8), jnp.int32), mcfg)
-    # and each scalar on its own, with no layers of several kinds
+def test_the_training_forward_refuses_each_scalar_on_its_own():
+    """With no layers of several kinds (the family whole: the contract's
+    test_the_training_forward_refuses_the_family)."""
     for field, value in (("residual_multiplier", 0.22),
                          ("logits_scaling", 16.0),
                          ("attention_multiplier", 0.0625),
@@ -445,16 +327,175 @@ def test_the_training_forward_refuses_the_family(model):
                              jnp.zeros((1, 8), jnp.int32), cfg)
 
 
-# -- the recurrence: chunked = recurrent, the step over runs ---------------
+# -- the recurrence from the model's slots ---------------------------------
 
-def _ssm_inputs(rng, *lead, H=8, P=16, N=32):
-    def normal(*shape):
-        return jnp.asarray(rng.normal(size=shape), jnp.float32)
+def test_a_padded_prompt_leaves_the_state_of_its_last_real_token(rng, model):
+    """_recur_prompts: prompts of 9 and 30 tokens padded to 32, a pad
+    prompt beside them; each slot gets the state after its prompt's own
+    last token (in the pool's layout), the others are not touched."""
+    mcfg = model[0]
+    x, dt, A, Bm, Cm = W.ssm_inputs(rng, 3, 32)
+    pool = jnp.full((5, 1, 32, 128), 7.0)
+    n_real = jnp.asarray([9, 30, 0], jnp.int32)
+    slots = jnp.asarray([2, 0, -1], jnp.int32)
+    y, new = M._recur_prompts("state_space", (x, dt, A, Bm, Cm), pool, slots,
+                              n_real, mcfg)
+    for i, (n, slot) in enumerate([(9, 2), (30, 0)]):
+        want_y, want_s = SS.ssm_recurrent(
+            x[i:i + 1, :n], dt[i:i + 1, :n], A, Bm[i:i + 1, :n],
+            Cm[i:i + 1, :n])
+        np.testing.assert_allclose(y[i, :n], want_y[0], atol=1e-4)
+        np.testing.assert_allclose(SS.unpack_state(new[slot], 8), want_s[0],
+                                   atol=1e-4)
+    assert (np.asarray(new[1]) == 7).all() and (np.asarray(new[3]) == 7).all()
 
-    # decays exp(dt A) from ~0.2 to ~0.99 a token
-    return (normal(*lead, H, P), jax.nn.softplus(normal(*lead, H) - 1.0),
-            -jnp.exp(normal(H) * 0.5), normal(*lead, N), normal(*lead, N))
 
+def test_a_slot_wider_than_the_channels_serves_the_same(model, engines,
+                                                         pallas_interpret):
+    """A state of 576 makes the convolution's channels 1,280: ten lane
+    rows in a slot of sixteen (cfg.state_shapes pads more than one
+    tile's rows to whole tiles, as the published 66 rows are padded to
+    72). The inputs and taps are padded to the slot (model._slot_wide)
+    on both paths: the one-pass kernel and decode_impl 'xla' give the
+    reference's logits over a prefill, a chunk and single steps."""
+    hf = dict(HF, mamba_d_state=576)
+    mcfg = config_from_hf(hf, use_flash=False)
+    assert mcfg.state_shapes("state_space")[-1] == ((3, 16, 128), None)
+    # up to a tile's rows stay a block of the whole dimension
+    assert config_from_hf(dict(HF, mamba_d_state=64)).state_shapes(
+        "state_space")[-1] == ((3, 2, 128), None)
+    shapes = jax.eval_shape(lambda k: T.init(mcfg, k), jax.random.PRNGKey(0))
+    fresh = T.init(mcfg, jax.random.PRNGKey(4))
+    # the fixture's values wherever the shapes agree
+    flat = lambda p: dict(p["layers"], **F.top(p))
+    old = flat(model[1])
+    take = lambda k, v: old[k] if old[k].shape == v.shape else v * 4
+    params = {k: take(k, v) for k, v in F.top(fresh).items()}
+    params["layers"] = {k: take(k, v) for k, v in fresh["layers"].items()}
+    assert shapes["ssm_taps"].shape == (6, 1280, 4)
+    full = np.random.default_rng(8).integers(0, 256, (1, 30)).astype(np.int32)
+    want = F.ref_logits(FAMILY, params, full, hf=hf)[0]
+    for impl, kernel in (("auto", True), ("xla", False)):
+        # another model: an engine each
+        eng = engines.fresh(model=(mcfg, params), decode_impl=impl)
+        assert eng.carry_kernel(8) is kernel
+        got = [np.asarray(eng.put([7], [full[0, a:b]]))[0]
+               for a, b in ((0, 20), (20, 25), (25, 26), (26, 27))]
+        eng.flush(7)
+        err = np.abs(np.stack(got) - want[[19, 24, 25, 26]]).max()
+        assert err < LOGITS_ATOL, (impl, err)
+
+
+def test_the_scopes_of_the_operator_are_in_the_program(engines):
+    text = F.step_text(engines())
+    for scope in ("state_space/ssm_project", "state_space/ssm_conv",
+                  "state_space/ssm_state", "state_space/ssm_gate_norm",
+                  "state_space/ssm_out", "mlp/moe_shared", "mlp/moe_route"):
+        assert scope in text, scope
+    assert "rope" not in text and "cos" not in text
+
+
+# -- through the scheduler: slots taken, reused, never cleared -------------
+
+def test_a_slot_is_handed_on_with_no_clearing(model, engines):
+    """12 requests of unequal lengths through 6 slots: every slot is
+    handed on to a later sequence, and what the last one left in it
+    (here: NaN, put there before the first admission too, in the
+    matrices AND the carried inputs) never reaches the next."""
+    eng = engines.sched()
+    d, requests = F.through_reused_slots(FAMILY, model, eng)
+    # a slot: 6 Mamba-2 layers x (8 matrices of 16 x 32 + 3 inputs of
+    # 8 x 16 + 2 x 32 = 192 channels: no whole lanes, so as they are),
+    # float32
+    assert eng.state_slot_bytes == 6 * 4 * (8 * 16 * 32 + 3 * 192)
+    assert d["state_bytes_moved"] % (2 * eng.state_slot_bytes) == 0
+    assert d["state_bytes_moved"] >= 2 * eng.state_slot_bytes * d["steps"]
+    # every prompt went in as chunks of up to 8: all its tokens but a
+    # last chunk of one are rows of runs
+    prompts = sum(len(p) for p, _ in requests)
+    assert prompts - 12 <= d["ssm_run_tokens"] <= prompts
+    assert d["gdn_run_tokens"] == 0
+
+
+@pytest.mark.usefixtures("pallas_interpret")
+def test_the_steps_through_the_step_kernel_are_counted(model, engines,
+                                                       monkeypatch):
+    """`state_step_kernel_steps`: every step over rows of an engine
+    whose kernels run and whose pools fit the walk, each once; none
+    under decode_impl 'xla'; none where a pool does not fit (the step
+    is then the loop in XLA). One width of program: the interpreter's
+    kernels are slow to trace."""
+    eng, xla = engines(), engines(decode_impl="xla")
+    assert eng.step_kernel(8) and not xla.step_kernel(8)
+    requests = [(p[:12], 3) for p, _ in F.requests(FAMILY, 2, seed=8)]
+    s, served = F.serve(eng, requests, max_num_batched_tokens=8)
+    assert s.counters["state_step_kernel_steps"] == s.counters["steps"] > 0
+    assert s.counters["state_carry_kernel_steps"] == 0  # 192 channels
+    sx = ServingScheduler(xla, ServingSchedulerConfig(warmup=False))
+    sx._count_state([1, 1], 8)
+    assert sx.counters["state_step_kernel_steps"] == 0
+    F.greedy_by_the_reference(FAMILY, model, requests, served)
+    # slots past the walk's VMEM: another width asks again, and answers no
+    monkeypatch.setattr(W.GD, "_SLOTS_VMEM", 16 << 10)
+    pool = eng.cache.state[0][0]
+    assert not SS.ssm_step_fits(16, pool) and not eng.step_kernel(16)
+    before = s.counters["state_step_kernel_steps"]
+    s._count_state([1, 1], 16)
+    assert s.counters["state_step_kernel_steps"] == before
+    args = W.ssm_inputs(np.random.default_rng(0), 16)
+    text = str(jax.make_jaxpr(lambda *a: M._recur_rows(
+        "state_space", a, pool, jnp.zeros((16,), jnp.int32),
+        jnp.ones((16,), jnp.int32), True))(*args))
+    assert "pallas_call" not in text
+
+
+# -- the share of an expert-parallel deployment ----------------------------
+
+def test_four_shares_and_the_shared_expert_once_are_the_uncut_layer(model):
+    """Guide section 4: the routed parts that four shares of two
+    experts give, with what every chip computes alike (the shared
+    expert) counted ONCE, add up to what the uncut reference gives for
+    the whole layer (before the 0.22)."""
+    _, params = model
+    rng = np.random.default_rng(0)
+    n = jnp.asarray(rng.normal(size=(24, 64)), jnp.float32)
+    key = jax.random.PRNGKey(9)
+    lw = {k: params["layers"][k][0]
+          for k in ("w_router", "ws_gate", "ws_in", "ws_out")}
+    full = {k: 0.1 * jax.random.normal(jax.random.fold_in(key, i), shape)
+            for i, (k, shape) in enumerate(
+                {"w_gate": (8, 64, 32), "w_in": (8, 64, 32),
+                 "w_out": (8, 32, 64)}.items())}
+    uncut_hf = {k: v for k, v in HF.items()
+                if k not in ("reduced", "experts_held")}
+    with jax.default_matmul_precision("highest"):
+        whole, _ = ref.moe(n, dict(lw, **full),
+                           dict(uncut_hf, num_local_experts=8))
+        shared = ref._swiglu(n, lw["ws_gate"], lw["ws_in"], lw["ws_out"])
+    F.shares_of_two_add_up(FAMILY, "num_local_experts", n, lw, full, whole,
+                           shared)
+
+
+# -- what cannot be right yet is refused where it is built ----------------
+
+def test_pools_beyond_the_device_are_refused_with_the_three_numbers():
+    """128 slots of 38.2 MB beside 5.91 GB of weights fit 16 GB; 256 do
+    not, and the refusal names the three numbers."""
+    hf, cfg = F.cut_of(FAMILY)
+    weights = 2 * 2_955_758_208
+    conf = E.InferenceConfig(**hf["serve"]["engine"])
+    pools = E.pool_bytes(cfg, conf, jnp.bfloat16)
+    assert round((weights + sum(pools.values())) / 1e9, 1) == 11.4
+    E.refuse_pools_beyond(16 * 10 ** 9, weights, pools)
+    twice = E.pool_bytes(cfg, E.InferenceConfig(**dict(
+        hf["serve"]["engine"], max_tracked_sequences=256)), jnp.bfloat16)
+    with pytest.raises(ValueError, match=r"weights 5.91 GB \+ K/V pools "
+                       r"0.54 GB \+ state pools 9.83 GB = 16.28 GB of "
+                       r"16.00 GB"):
+        E.refuse_pools_beyond(16 * 10 ** 9, weights, twice)
+
+
+# -- the recurrence alone --------------------------------------------------
 
 @pytest.mark.parametrize("tokens,chunk", [(1, 16), (5, 16), (16, 16),
                                           (23, 16), (70, 16), (37, 8),
@@ -462,10 +503,13 @@ def _ssm_inputs(rng, *lead, H=8, P=16, N=32):
 def test_the_chunked_form_is_the_recurrence(rng, tokens, chunk):
     """Lengths that are and are not multiples of the chunk, from a
     state that is not zero: outputs and the state left behind."""
-    x, dt, A, Bm, Cm = _ssm_inputs(rng, 2, tokens)
+    x, dt, A, Bm, Cm = W.ssm_inputs(rng, 2, tokens)
     state = jnp.asarray(rng.normal(size=(2, 8, 16, 32)), jnp.float32)
-    y1, s1 = SS.ssm_recurrent(x, dt, A, Bm, Cm, state)
-    y2, s2 = SS.ssm_chunked(x, dt, A, Bm, Cm, state, chunk=chunk)
+    # (each form ONE program: op by op the chunked form is dozens of
+    # small compiles a case)
+    y1, s1 = jax.jit(SS.ssm_recurrent)(x, dt, A, Bm, Cm, state)
+    y2, s2 = jax.jit(functools.partial(SS.ssm_chunked, chunk=chunk))(
+        x, dt, A, Bm, Cm, state)
     # float32 sums of up to 256 terms in another order: relative
     np.testing.assert_allclose(y2, y1, rtol=5e-5, atol=1e-4)
     np.testing.assert_allclose(s2, s1, rtol=5e-5, atol=1e-4)
@@ -482,41 +526,12 @@ def test_the_pools_layout_is_a_view_of_the_heads_matrices(rng):
         SS.unpack_state(SS.pack_state(s, 2), 2), s)
 
 
-def test_a_padded_prompt_leaves_the_state_of_its_last_real_token(rng, model):
-    """_recur_prompts: prompts of 9 and 30 tokens padded to 32, a pad
-    prompt beside them; each slot gets the state after its prompt's own
-    last token (in the pool's layout), the others are not touched."""
-    mcfg = model[0]
-    x, dt, A, Bm, Cm = _ssm_inputs(rng, 3, 32)
-    pool = jnp.full((5, 1, 32, 128), 7.0)
-    n_real = jnp.asarray([9, 30, 0], jnp.int32)
-    slots = jnp.asarray([2, 0, -1], jnp.int32)
-    y, new = M._recur_prompts("state_space", (x, dt, A, Bm, Cm), pool, slots,
-                              n_real, mcfg)
-    for i, (n, slot) in enumerate([(9, 2), (30, 0)]):
-        want_y, want_s = SS.ssm_recurrent(
-            x[i:i + 1, :n], dt[i:i + 1, :n], A, Bm[i:i + 1, :n],
-            Cm[i:i + 1, :n])
-        np.testing.assert_allclose(y[i, :n], want_y[0], atol=1e-4)
-        np.testing.assert_allclose(SS.unpack_state(new[slot], 8), want_s[0],
-                                   atol=1e-4)
-    assert (np.asarray(new[1]) == 7).all() and (np.asarray(new[3]) == 7).all()
-
-
 def _check_step(step, rng):
-    """A step's rows: a run of five from a slot's state (positions
-    5..9), a decode row, a pad row, a run of three from position 0 (the
-    slot's NaN must not be read), another pad row."""
-    slots = jnp.asarray([3, 3, 3, 3, 3, 1, -1, 0, 0, 0, -1], jnp.int32)
-    pos = jnp.asarray([5, 6, 7, 8, 9, 12, 0, 0, 1, 2, 0], jnp.int32)
-    pool = jnp.asarray(rng.normal(size=(6, 1, 32, 128)), jnp.float32)
-    pool = pool.at[0].set(jnp.nan)
-    x, dt, A, Bm, Cm = _ssm_inputs(rng, 11)
-    y, new = step(x, dt, A, Bm, Cm, pool, slots, pos)
-    for rows, slot, start in ((slice(0, 5), 3, pool[3]),
-                              (slice(5, 6), 1, pool[1]),
-                              (slice(7, 10), 0, None)):
-        want_y, want_s = SS.ssm_recurrent(
+    pool, slots, pos, runs = W.ragged(rng, (6, 1, 32, 128))
+    x, dt, A, Bm, Cm = W.ssm_inputs(rng, 11)
+    y, new = jax.jit(step)(x, dt, A, Bm, Cm, pool, slots, pos)
+    for rows, slot, start in runs:
+        want_y, want_s = jax.jit(SS.ssm_recurrent)(
             x[None, rows], dt[None, rows], A, Bm[None, rows], Cm[None, rows],
             None if start is None else SS.unpack_state(start, 8)[None])
         np.testing.assert_allclose(y[rows], want_y[0], atol=2e-5)
@@ -552,7 +567,7 @@ def test_the_walk_over_a_steps_rows(rng, monkeypatch, pattern, walk):
     shape = (W.SLOTS + 1, 3, 8, 128)
     W.set_walk(monkeypatch, walk, shape)
     W.check_walk(SS.ssm_step, SS.ssm_step_xla,
-                 lambda rng, n: _ssm_inputs(rng, n, H=24, P=16, N=8), shape,
+                 lambda rng, n: W.ssm_inputs(rng, n, H=24, P=16, N=8), shape,
                  pattern, rng)
 
 
@@ -567,294 +582,19 @@ def test_ssm_step_fits_the_walks_slots(what, shape, fits):
         is fits
 
 
-@pytest.mark.usefixtures("pallas_interpret")
-def test_the_engine_with_kernels_matches_the_reference(model):
-    """decode_impl 'auto' under the interpreter resolves the kernels:
-    the step kernel on the aliased pool, the walk and write in the
-    attention layers (192 channels are no whole lanes: the convolution
-    stays XLA's here, and the next test is its kernel's)."""
-    eng = _engine(model)
-    assert eng.resolved_impl == "pallas" and not eng.carry_kernel(8)
-    assert "state_space/ssm_state/jit(_ssm_step)" in _step_text(eng)
-    got, want, _, _ = _feeds(model, eng, [37, 45], [5], 3, seed=4)
-    assert np.abs(got - want).max() < LOGITS_ATOL, np.abs(got - want).max(-1)
-
-
-def test_a_slot_wider_than_the_channels_serves_the_same(model,
-                                                         pallas_interpret):
-    """A state of 576 makes the convolution's channels 1,280: ten lane
-    rows in a slot of sixteen (cfg.state_shapes pads more than one
-    tile's rows to whole tiles, as the published 66 rows are padded to
-    72). The inputs and taps are padded to the slot (model._slot_wide)
-    on both paths: the one-pass kernel and decode_impl 'xla' give the
-    reference's logits over a prefill, a chunk and single steps."""
-    hf = dict(HF, mamba_d_state=576)
-    mcfg = config_from_hf(hf, use_flash=False)
-    assert mcfg.state_shapes("state_space")[-1] == ((3, 16, 128), None)
-    # up to a tile's rows stay a block of the whole dimension
-    assert config_from_hf(dict(HF, mamba_d_state=64)).state_shapes(
-        "state_space")[-1] == ((3, 2, 128), None)
-    shapes = jax.eval_shape(lambda k: T.init(mcfg, k), jax.random.PRNGKey(0))
-    fresh = T.init(mcfg, jax.random.PRNGKey(4))
-    # the fixture's values wherever the shapes agree
-    flat = lambda p: dict(p["layers"], **_top(p))
-    old = flat(model[1])
-    take = lambda k, v: old[k] if old[k].shape == v.shape else v * 4
-    params = {k: take(k, v) for k, v in _top(fresh).items()}
-    params["layers"] = {k: take(k, v) for k, v in fresh["layers"].items()}
-    assert shapes["ssm_taps"].shape == (6, 1280, 4)
-    full = np.random.default_rng(8).integers(0, 256, (1, 30)).astype(np.int32)
-    want = _ref_logits(params, full, hf=hf)[0]
-    for impl, kernel in (("auto", True), ("xla", False)):
-        eng = init_inference(params, mcfg, dict(ENGINE, decode_impl=impl),
-                             dtype=jnp.float32)
-        assert eng.carry_kernel(8) is kernel
-        got = [np.asarray(eng.put([7], [full[0, a:b]]))[0]
-               for a, b in ((0, 20), (20, 25), (25, 26), (26, 27))]
-        eng.flush(7)
-        err = np.abs(np.stack(got) - want[[19, 24, 25, 26]]).max()
-        assert err < LOGITS_ATOL, (impl, err)
-
-
-def _step_text(eng):
-    return eng._decode_fn(8, False).lower(
-        eng.params, eng.cache, *(eng._dev(np.zeros(s, np.int32)) for s in
-                                 ((8,), (8, eng.config.blocks_per_seq), (8,))),
-        *eng.state_args(np.zeros((8,), np.int32))).as_text(debug_info=True)
-
-
-def test_the_scopes_of_the_operator_are_in_the_program(model):
-    text = _step_text(_engine(model))
-    for scope in ("state_space/ssm_project", "state_space/ssm_conv",
-                  "state_space/ssm_state", "state_space/ssm_gate_norm",
-                  "state_space/ssm_out", "mlp/moe_shared", "mlp/moe_route"):
-        assert scope in text, scope
-    assert "rope" not in text and "cos" not in text
-
-
-# -- through the scheduler: slots taken, reused, never cleared -------------
-
-def _requests(n, seed=5):
-    rng = np.random.default_rng(seed)
-    return [(rng.integers(0, HF["vocab_size"], int(rng.integers(9, 60))
-                          ).tolist(), int(rng.integers(3, 12)))
-            for _ in range(n)]
-
-
-def _sched_engine(model, **over):
-    return _engine(model, max_batch_size=ENGINE["max_tracked_sequences"],
-                   **over)
-
-
-def _serve(eng, requests, **sched):
-    s = ServingScheduler(eng, ServingSchedulerConfig(
-        **dict(dict(max_num_batched_tokens=48, prefill_chunk=8,
-                    prefill_mode="chunked", decode_chunk=1, warmup=False),
-               **sched)))
-    rids = [s.submit(p, max_new_tokens=n) for p, n in requests]
-    s.run()
-    return s, [s.finished[r].output for r in rids]
-
-
-def _greedy_by_the_reference(model, requests, outputs):
-    for (prompt, _), out in zip(requests, outputs):
-        toks = np.zeros((1, 96), np.int32)
-        toks[0, :len(prompt) + len(out)] = prompt + out
-        logits = _ref_logits(model[1], toks)[0]
-        for j, t in enumerate(out):
-            row = logits[len(prompt) + j - 1]
-            assert row[t] >= row.max() - LOGITS_ATOL, (j, t, row.argmax())
-
-
-def test_a_slot_is_handed_on_with_no_clearing(model):
-    """12 requests of unequal lengths through 6 slots: every slot is
-    handed on to a later sequence, and what the last one left in it
-    (here: NaN, put there before the first admission too, in the
-    matrices AND the carried inputs) never reaches the next."""
-    eng = _sched_engine(model)
-    eng.cache = eng.cache._replace(state=jax.tree.map(
-        lambda p: jnp.full_like(p, jnp.nan), eng.cache.state))
-    requests = _requests(12)
-    s, outputs = _serve(eng, requests)
-    assert all(len(o) == n for o, (_, n) in zip(outputs, requests))
-    _greedy_by_the_reference(model, requests, outputs)
-    d = s.counters
-    assert d["state_slot_resets"] == 12 > ENGINE["max_tracked_sequences"]
-    assert d["state_slots_live"] >= d["steps"] > 0
-    assert eng.state.n_tracked == 0 and len(eng.state._free_slots) == 6
-    assert d["lookahead_steps"] > 0  # the slot is updated in program order
-    # a slot: 6 Mamba-2 layers x (8 matrices of 16 x 32 + 3 inputs of
-    # 8 x 16 + 2 x 32 = 192 channels: no whole lanes, so as they are),
-    # float32
-    assert eng.state_slot_bytes == 6 * 4 * (8 * 16 * 32 + 3 * 192)
-    assert d["state_bytes_moved"] % (2 * eng.state_slot_bytes) == 0
-    assert d["state_bytes_moved"] >= 2 * eng.state_slot_bytes * d["steps"]
-    # every prompt went in as chunks of up to 8: all its tokens but a
-    # last chunk of one are rows of runs
-    prompts = sum(len(p) for p, _ in requests)
-    assert prompts - 12 <= d["ssm_run_tokens"] <= prompts
-    assert d["gdn_run_tokens"] == 0
-
-
-@pytest.mark.usefixtures("pallas_interpret")
-def test_the_steps_through_the_step_kernel_are_counted(model, monkeypatch):
-    """`state_step_kernel_steps`: every step over rows of an engine
-    whose kernels run and whose pools fit the walk, each once; none
-    under decode_impl 'xla'; none where a pool does not fit (the step
-    is then the loop in XLA). One width of program: the interpreter's
-    kernels are slow to trace."""
-    eng, xla = _sched_engine(model), _sched_engine(model, decode_impl="xla")
-    assert eng.step_kernel(8) and not xla.step_kernel(8)
-    requests = [(p[:12], 3) for p, _ in _requests(2, seed=8)]
-    s, served = _serve(eng, requests, max_num_batched_tokens=8)
-    assert s.counters["state_step_kernel_steps"] == s.counters["steps"] > 0
-    assert s.counters["state_carry_kernel_steps"] == 0  # 192 channels
-    sx = ServingScheduler(xla, ServingSchedulerConfig(warmup=False))
-    sx._count_state([1, 1], 8)
-    assert sx.counters["state_step_kernel_steps"] == 0
-    _greedy_by_the_reference(model, requests, served)
-    # slots past the walk's VMEM: another width asks again, and answers no
-    monkeypatch.setattr(W.GD, "_SLOTS_VMEM", 16 << 10)
-    pool = eng.cache.state[0][0]
-    assert not SS.ssm_step_fits(16, pool) and not eng.step_kernel(16)
-    before = s.counters["state_step_kernel_steps"]
-    s._count_state([1, 1], 16)
-    assert s.counters["state_step_kernel_steps"] == before
-    args = _ssm_inputs(np.random.default_rng(0), 16)
-    text = str(jax.make_jaxpr(lambda *a: M._recur_rows(
-        "state_space", a, pool, jnp.zeros((16,), jnp.int32),
-        jnp.ones((16,), jnp.int32), True))(*args))
-    assert "pallas_call" not in text
-
-
-def test_whole_prompt_waves_and_fused_decode_carry_the_state(model):
-    """prefill_mode 'wave' runs the chunked scan and writes the slot at
-    the prompt's end; decode_chunk 4 carries it through a fused scan."""
-    requests = _requests(6, seed=3)
-    s, outputs = _serve(_sched_engine(model), requests, prefill_mode="wave",
-                        decode_chunk=4)
-    _greedy_by_the_reference(model, requests, outputs)
-    assert s.counters["ssm_run_tokens"] == sum(len(p) for p, _ in requests)
-
-
-# -- the share of an expert-parallel deployment ----------------------------
-
-def test_four_shares_and_the_shared_expert_once_are_the_uncut_layer(model):
-    """Guide section 4: the routed parts that four shares of two
-    experts give, with what every chip computes alike (the shared
-    expert) counted ONCE, add up to what the uncut reference gives for
-    the whole layer (before the 0.22)."""
-    _, params = model
-    rng = np.random.default_rng(0)
-    n = jnp.asarray(rng.normal(size=(24, 64)), jnp.float32)
-    key = jax.random.PRNGKey(9)
-    lw = {k: params["layers"][k][0]
-          for k in ("w_router", "ws_gate", "ws_in", "ws_out")}
-    full = {k: 0.1 * jax.random.normal(jax.random.fold_in(key, i), shape)
-            for i, (k, shape) in enumerate(
-                {"w_gate": (8, 64, 32), "w_in": (8, 64, 32),
-                 "w_out": (8, 32, 64)}.items())}
-    uncut_hf = {k: v for k, v in HF.items()
-                if k not in ("reduced", "experts_held")}
-    with jax.default_matmul_precision("highest"):
-        whole, _ = ref.moe(n, dict(lw, **full),
-                           dict(uncut_hf, num_local_experts=8))
-        shared = ref._swiglu(n, lw["ws_gate"], lw["ws_in"], lw["ws_out"])
-        parts = []
-        for share in range(4):
-            cfg = config_from_hf(dict(
-                HF, num_local_experts=2, experts_held={"start": 2 * share},
-                reduced={"num_local_experts": {"published": 8, "here": 2}}))
-            assert cfg.experts_held == (2 * share, 2)
-            lp = dict(lw, **{k: w[2 * share:2 * share + 2]
-                             for k, w in full.items()})
-            parts.append(M._mlp(n, lp, cfg) - shared)
-    np.testing.assert_allclose(sum(parts) + shared, whole, atol=2e-5)
-    assert float(jnp.abs(whole - shared).max()) > 0.01  # the routed part counts
-
-
-# -- what cannot be right yet is refused where it is built ----------------
-
-@pytest.mark.parametrize("what,kwargs,config", [
-    ("int8_kv", {}, {"kv_cache_dtype": "int8"}),
-    ("mesh", {}, {"tp_size": 2}),
-    ("weight_quantization", {"quantization": {"bits": 8}}, {}),
-    ("offload", {"offload": {"device": "cpu"}}, {}),
-])
-def test_the_engine_refuses_at_build(model, what, kwargs, config):
-    mcfg, params = model
-    assert E.pool_kinds(mcfg) == ("kv", "state")
-    with pytest.raises(NotImplementedError, match=what):
-        init_inference(params, mcfg, dict(ENGINE, **config),
-                       dtype=jnp.float32, **kwargs)
-
-
-def test_prefix_credit_and_speculation_are_refused(model):
-    assert not E.pools_can(model[0], "prefix_credit")
-    with pytest.raises(NotImplementedError, match="speculation"):
-        ServingScheduler(_engine(model), ServingSchedulerConfig(warmup=False),
-                         speculative={"ngram": 2, "draft_len": 3})
-
-
-def test_pools_beyond_the_device_are_refused_with_the_three_numbers():
-    """128 slots of 38.2 MB beside 5.91 GB of weights fit 16 GB; 256 do
-    not, and the refusal names the three numbers."""
-    hf, cfg = _cut()
-    weights = 2 * 2_955_758_208
-    conf = E.InferenceConfig(**hf["serve"]["engine"])
-    pools = E.pool_bytes(cfg, conf, jnp.bfloat16)
-    assert round((weights + sum(pools.values())) / 1e9, 1) == 11.4
-    E.refuse_pools_beyond(16 * 10 ** 9, weights, pools)
-    twice = E.pool_bytes(cfg, E.InferenceConfig(**dict(
-        hf["serve"]["engine"], max_tracked_sequences=256)), jnp.bfloat16)
-    with pytest.raises(ValueError, match=r"weights 5.91 GB \+ K/V pools "
-                       r"0.54 GB \+ state pools 9.83 GB = 16.28 GB of "
-                       r"16.00 GB"):
-        E.refuse_pools_beyond(16 * 10 ** 9, weights, twice)
-
-
 # -- the kernels at the cell's shapes --------------------------------------
-
-@pytest.fixture(scope="module")
-def one_chip():
-    os.environ.setdefault("TPU_LOG_DIR", "disabled")
-    from jax.experimental import topologies
-
-    try:
-        topo = topologies.get_topology_desc(platform="tpu",
-                                            topology_name="v5e:2x2")
-    except Exception as e:  # noqa: BLE001 - whatever keeps libtpu away
-        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    from jax.experimental.compilation_cache import compilation_cache as cc
-
-    jax.config.update("jax_enable_compilation_cache", False)
-    cc.reset_cache()
-    return SingleDeviceSharding(topo.devices[0])
-
-
-def _kernels(text):
-    return [line for line in text.splitlines()
-            if 'custom_call_target="tpu_custom_call"' in line]
-
 
 def test_the_step_kernel_compiles_for_v5e_at_the_cells_shapes(one_chip):
     """128 rows of 128 heads of 64 x 128 over a pool of 129 slots of
     4 MiB, aliased in and out (no second 541 MB pool among the
     temporaries)."""
-    def sds(shape, dtype=jnp.float32):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-
+    sds = F.on_chip(one_chip, jnp.float32)
     rows, pool = 128, sds((129, 64, 128, 128))
     assert SS.ssm_step_fits(rows, pool)
-    compiled = jax.jit(SS.ssm_step, donate_argnums=(5,)).lower(
+    F.compiles_one_aliased_kernel(SS.ssm_step, (
         sds((rows, 128, 64)), sds((rows, 128)), sds((128,)), sds((rows, 128)),
         sds((rows, 128)), pool, sds((rows,), jnp.int32),
-        sds((rows,), jnp.int32)).compile()
-    calls = _kernels(compiled.as_text())
-    assert len(calls) == 1 and "ssm_state" in calls[0]
-    mem = compiled.memory_analysis()
-    assert mem.alias_size_in_bytes >= 129 * 64 * 128 * 128 * 4
-    assert mem.temp_size_in_bytes < 64 << 20
+        sds((rows,), jnp.int32)), 5, "ssm_state")
 
 
 def test_the_convolution_compiles_for_v5e_at_8448_channels(one_chip):
@@ -862,13 +602,11 @@ def test_the_convolution_compiles_for_v5e_at_8448_channels(one_chip):
     Mosaic ("Slice shape along dimension 2 must be aligned to tiling
     (8)"), which is why cfg.state_shapes pads it to 72 and the step's
     inputs with it (model._slot_wide)."""
-    def sds(shape, dtype=jnp.bfloat16):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-
+    sds = F.on_chip(one_chip, jnp.bfloat16)
     rows, pool = 128, sds((128, 3, 72, 128))
     assert CC.carry_fits(rows, jnp.bfloat16, pool)
     compiled = jax.jit(CC.conv_carry, donate_argnums=(2,)).lower(
         sds((rows, 9216)), sds((9216, 4)), pool, sds((rows,), jnp.int32),
         sds((rows,), jnp.int32)).compile()
-    calls = _kernels(compiled.as_text())
+    calls = F.kernels(compiled.as_text())
     assert len(calls) == 1 and "conv_carry" in calls[0]

@@ -21,15 +21,20 @@ import pathlib
 import jax
 import jax.numpy as jnp
 import numpy as np
+import _family as F
 import pytest
-
-from _step_program import step_program, tiny as _tiny
-from benchmarks.reference import mellum2 as ref
-from deepspeed_tpu.inference import (
-    ServingScheduler,
-    ServingSchedulerConfig,
-    init_inference,
+from _family import (  # noqa: F401 - the contract's fixtures and cases, collected here
+    engines,
+    family,
+    pytest_generate_tests,
+    test_a_wrong_model_fails_the_written_tolerance,
+    test_the_training_forward_refuses_the_family,
+    test_what_the_mapping_cannot_serve_is_an_error,
 )
+from _step_program import step_program, tiny as _tiny
+
+from benchmarks.reference import mellum2 as ref
+from deepspeed_tpu.inference import ServingScheduler, ServingSchedulerConfig
 from deepspeed_tpu.inference import engine as E
 from deepspeed_tpu.inference import model as M
 from deepspeed_tpu.inference.ragged import (
@@ -40,8 +45,7 @@ from deepspeed_tpu.inference.ragged import (
 from deepspeed_tpu.models import transformer as T
 from deepspeed_tpu.utils.hf_checkpoint import config_from_hf
 
-ROOT = pathlib.Path(__file__).resolve().parents[1]
-BENCH = ROOT / "benchmarks"
+BENCH = F.BENCH
 CUT = BENCH / "configs/mellum2-12b-a2.5b-serve-l8.json"
 PUBLISHED = BENCH / "configs/published/mellum2-12b-a2.5b-instruct.json"
 HASHES = pathlib.Path(__file__).with_name("data") / "step_program_hashes.json"
@@ -71,14 +75,42 @@ RING = 4                  # ceil((16 + 7) / 8) + 1 blocks of 8 tokens
 # is 8e-5 on logits of up to 2.7 (summation order); the nearest wrong
 # model, YaRN without its factor, reads 1.2
 LOGITS_ATOL = 5e-4
+FAMILY = F.Family(
+    hf=HF, ref=ref, atol=LOGITS_ATOL, engine=ENGINE, far=100,
+    training_refuses="rope_scaling",
+    unservable=(
+        ("a dense layer after a sparse one",
+         dict(HF, mlp_layer_types=["sparse", "dense"] + ["sparse"] * 6),
+         "dense layer after"),
+        ("an unknown layer type",
+         dict(HF, layer_types=["chunked_attention"] * 8), "unknown"),
+        ("a scaled table on the sliding layers",
+         dict(HF, rope_parameters=dict(HF["rope_parameters"],
+                                       sliding_attention=YARN)),
+         "scaled table on"),
+        ("another scaling than YaRN",
+         dict(HF, rope_parameters=dict(
+             HF["rope_parameters"],
+             full_attention=dict(YARN, rope_type="longrope"))), "longrope"),
+        ("YaRN without truncate",
+         dict(HF, rope_parameters=dict(
+             HF["rope_parameters"],
+             full_attention=dict(YARN, truncate=False))), "truncate"),
+        ("a bias on attention", dict(HF, attention_bias=True),
+         "attention_bias"),
+        ("sliding layers without a window", dict(HF, sliding_window=None),
+         "need sliding_window"),
+    ))
 
 
 def _model(hf=HF, seed=0):
     mcfg = config_from_hf(hf, max_seq=512, use_flash=False)
-    params = T.init(mcfg, jax.random.PRNGKey(seed))
     # four times the recipe's spread: scores sharp enough for the window,
-    # the positions and the rotary table to matter
-    return mcfg, jax.tree.map(lambda a: a * 4 if a.ndim > 1 else a, params)
+    # the positions and the rotary table to matter (ONE program: leaf by
+    # leaf the tree is dozens of small compiles)
+    return mcfg, jax.jit(lambda: jax.tree.map(
+        lambda a: a * 4 if a.ndim > 1 else a,
+        T.init(mcfg, jax.random.PRNGKey(seed))))()
 
 
 @pytest.fixture(scope="module")
@@ -86,61 +118,31 @@ def model():
     return _model()
 
 
-def _ref_logits(params, toks, mutate=None, hf=HF):
-    top = {k: v for k, v in params.items() if k != "layers"}
-    layer = lambda l: jax.tree.map(lambda a: a[l], params["layers"])
-    return np.asarray(ref.forward_logits(top, layer, toks, hf, mutate))
-
-
-def _engine(model, **over):
-    mcfg, params = model
-    return init_inference(params, mcfg, dict(ENGINE, **over),
-                          dtype=jnp.float32)
-
-
-@pytest.fixture(scope="module")
-def shared_engine(model):
-    """One engine for the teacher-forced tests: they flush what they
-    put, and share its compiled programs."""
-    return _engine(model)
-
-
-def _feeds(model, eng, lens, tail, chunk, n_dec, seed=0, hf=HF):
-    """Teacher-forced put() logits of prompts of `lens`: all but the
-    last `tail` tokens whole (a whole-prompt prefill), those in chunks
-    of `chunk`, then n_dec single tokens: (engine
-    logits [prompts, feeds, V], the reference's at the same positions,
-    the tokens, the cuts)."""
+def _ring_feeds(model, eng, lens, tail, chunk, n_dec, seed=0, hf=HF):
+    """F.feed of prompts of `lens`: all but the last `tail` tokens whole
+    (a whole-prompt prefill), those in chunks of `chunk` (a ring takes
+    no more rows a step than it has blocks to turn over), then n_dec
+    single tokens."""
     rng = np.random.default_rng(seed)
     full = [rng.integers(0, hf["vocab_size"], n + n_dec).astype(np.int32)
             for n in lens]
-    uids = list(range(100, 100 + len(lens)))
     cuts = [[min(c, n) for c in range(n - tail, n + chunk, chunk)]
             + [n + j + 1 for j in range(n_dec)] for n in lens]
-    got = []
-    for j in range(len(cuts[0])):
-        toks = [f[(c[j - 1] if j else 0):c[j]] for f, c in zip(full, cuts)]
-        got.append(np.asarray(eng.put(uids, toks)))
-    for u in uids:
-        eng.flush(u)
-    padded = np.zeros((len(full), max(map(len, full))), np.int32)
-    for i, f in enumerate(full):
-        padded[i, :len(f)] = f
-    want = _ref_logits(model[1], padded, hf=hf)
-    want = np.stack([want[i, np.asarray(c) - 1] for i, c in enumerate(cuts)])
-    return np.stack(got, axis=1), want, padded, cuts
+    return F.feed(FAMILY, model, eng, full, cuts, hf=hf)
 
 
 @pytest.fixture(scope="module")
-def served(model, shared_engine):
+def served(model, engines):
     """Prompts of 100 and 107 tokens (over three turns of a 32-token
     ring): 95 / 102 whole, a 5-token chunk, 12 single steps."""
-    return _feeds(model, shared_engine, [100, 107], 5, 5, 12)
+    return _ring_feeds(model, engines(), [100, 107], 5, 5, 12)
 
 
-def test_the_ring_engages_and_is_sized_as_derived(model, shared_engine):
-    mcfg, _ = model
+def test_the_ring_engages_and_is_sized_as_derived(model, engines):
+    mcfg, shared_engine = model[0], engines()
     assert mcfg.mixed_windows
+    assert set(mcfg.serving_only) == {"rope_scaling_full_only",
+                                      "rope_scaling_type"}
     assert mcfg.attention_window_pattern == (16, 16, 16, 0)
     assert M.ring_blocks(mcfg, 8, 32) == RING
     assert E.pool_kinds(mcfg) == ("kv", "ring")
@@ -162,15 +164,15 @@ def test_prefill_a_chunk_and_single_steps_match_the_reference(served):
 
 
 @pytest.mark.parametrize("tail,chunk", [(72, 7), (74, 8), (45, 5), (11, 1)])
-def test_chunks_through_the_ring_at_every_offset(model, shared_engine, tail,
+def test_chunks_through_the_ring_at_every_offset(model, engines, tail,
                                                  chunk):
     """The whole of a 75-token sequence through the decode rows, in
     chunks whose ends fall at every offset of a block (7, 5), on its
     boundaries (8: the most a ring of this size takes) and one token at
     a time: every windowed layer writes into blocks it has turned over
     (two turns and more) and reads across the turn."""
-    got, want, _, _ = _feeds(model, shared_engine, [75, 75], tail, chunk, 2,
-                             seed=chunk)
+    got, want, _, _ = _ring_feeds(model, engines(), [75, 75], tail, chunk, 2,
+                                  seed=chunk)
     assert np.abs(got - want).max() < LOGITS_ATOL, np.abs(got - want).max(-1)
 
 
@@ -180,40 +182,20 @@ def test_the_paged_kernels_write_and_walk_the_ring(pallas_interpret):
     ring's number, a chunk's rows riding as ONE group though each row's
     window starts a token later. The rehearsal configuration (heads of
     128, window 16 over blocks of 16: a ring of 3 blocks), a sequence
-    of 70 tokens in chunks of 16 (the most a ring of this size takes)
-    and single steps."""
+    of 70 tokens: 3 whole, four chunks of 16 (the most a ring of this
+    size takes), a ragged last chunk of 3 and single steps."""
     hf = _tiny("tiny-mellum2")
-    model = _model(hf)
-    eng = init_inference(model[1], model[0], dict(
-        hf["serve"]["engine"], max_seq_len=128, max_batch_size=16,
-        decode_impl="pallas"), dtype=jnp.float32)
+    model = _model(hf)  # another model: its own engine
+    eng = F.Engines(model, hf["serve"]["engine"]).fresh(
+        max_seq_len=128, max_batch_size=16, decode_impl="pallas")
     assert eng.resolved_impl == "pallas" and eng.state.ring_blocks == 3
-    got, want, _, _ = _feeds(model, eng, [70], 67, 16, 3, hf=hf)
+    got, want, _, _ = _ring_feeds(model, eng, [70], 67, 16, 3, hf=hf)
     # interpreted kernels multiply at the CPU's default precision
     assert np.abs(want).max() > 3.0
     assert np.abs(got - want).max() < 1e-2, np.abs(got - want).max(-1)
 
 
-def _float8(x):
-    return x.astype(jnp.float8_e4m3fn).astype(x.dtype)
-
-
-@pytest.mark.parametrize("control", ref.MUTANTS + ("float8_weights",))
-def test_a_wrong_model_fails_the_written_tolerance(model, served, control):
-    """Each of the logits audit's controls, put in the reference's
-    place: the engine must NOT agree with it. The controls the chip's
-    bf16 engine cannot tell from its own rounding are judged HERE."""
-    got, _, padded, cuts = served
-    params = model[1]
-    if control == "float8_weights":
-        wrong = _ref_logits(jax.tree.map(_float8, params), padded)
-    else:
-        wrong = _ref_logits(params, padded, control)
-    wrong = np.stack([wrong[i, np.asarray(c) - 1] for i, c in enumerate(cuts)])
-    assert np.abs(got - wrong).max() > 100 * LOGITS_ATOL, control
-
-
-def test_a_dense_entry_is_a_swiglu_of_intermediate_size():
+def test_a_dense_entry_is_a_swiglu_of_intermediate_size(engines):
     """`mlp_layer_types` `dense` (none in the published file): a leading
     SwiGLU of `intermediate_size`, through the same path."""
     hf = dict(HF, mlp_layer_types=["dense"] + ["sparse"] * 7)
@@ -222,7 +204,9 @@ def test_a_dense_entry_is_a_swiglu_of_intermediate_size():
     assert (mcfg.n_dense_layers, mcfg.dense_d_ff, mcfg.n_layers) == (1, 96, 7)
     assert mcfg.attention_window_pattern == (16, 16, 16, 0)
     assert params["dense_w_in"].shape == (1, 64, 96)
-    got, want, _, _ = _feeds(model, _engine(model), [60], 19, 5, 3, hf=hf)
+    # another model: its own engine
+    got, want, _, _ = _ring_feeds(model, engines.fresh(model=model), [60], 19,
+                                  5, 3, hf=hf)
     assert np.abs(got - want).max() < LOGITS_ATOL
 
 
@@ -330,18 +314,18 @@ def _scheduler(eng, **over):
 
 
 @pytest.mark.parametrize("short,engine", [
-    ("window", dict(num_kv_rings=2)),
-    ("full", dict(num_kv_blocks=12)),
+    ("window", dict()),  # the module's engine as it is: four rings
+    ("full", dict(num_kv_rings=8, num_kv_blocks=12)),
 ])
-def test_a_request_that_a_pool_cannot_take_waits_and_none_fails(model, short,
+def test_a_request_that_a_pool_cannot_take_waits_and_none_fails(engines, short,
                                                                 engine):
-    """Six requests of 20 + 4 tokens (3 blocks each) against two rings,
+    """Six requests of 20 + 4 tokens (3 blocks each) against four rings,
     or against 12 paged blocks: admission takes a request only where
     BOTH pools can, the others wait in the queue, the counter names the
     pool, and every one finishes at its asked length with the tokens a
     scheduler with room gives."""
     def run(**cfg):
-        eng = _engine(model, **dict(dict(num_kv_rings=8), **cfg))
+        eng = engines(**cfg)
         sched = _scheduler(eng)
         rng = np.random.default_rng(3)
         rids = [sched.submit(rng.integers(0, 128, 20).tolist(),
@@ -352,7 +336,7 @@ def test_a_request_that_a_pool_cannot_take_waits_and_none_fails(model, short,
         return sched, [sched.finished[r] for r in rids]
 
     sched, reqs = run(**engine)
-    roomy, want = run()
+    roomy, want = run(num_kv_rings=8)
     assert all(r.finish_reason == "length" and len(r.output) == 4
                for r in reqs)
     assert [r.output for r in reqs] == [r.output for r in want]
@@ -364,10 +348,10 @@ def test_a_request_that_a_pool_cannot_take_waits_and_none_fails(model, short,
         == roomy.counters["admit_waits_full_pool"] == 0
 
 
-def test_the_counters_count_a_sequence_once_a_step(model):
+def test_the_counters_count_a_sequence_once_a_step(engines):
     """A prompt of 41 tokens in chunks of 8 and 3 answers: a chunk's
     rows are one read; a windowed layer's read stops at the window."""
-    eng = _engine(model)
+    eng = engines()
     sched = _scheduler(eng, prefill_chunk=8)
     sched.submit(list(range(41)), max_new_tokens=3)
     sched.run()
@@ -383,7 +367,8 @@ def test_the_counters_count_a_sequence_once_a_step(model):
     assert c["kv_live_blocks"] > 0 and c["state_slots_live"] == 0
 
 
-def test_what_a_ring_cannot_do_is_refused_where_the_engine_is_built(model):
+def test_what_a_ring_cannot_do_is_refused_where_the_engine_is_built(model,
+                                                                     engines):
     mcfg, params = model
     assert E._POOL_CANNOT["ring"] == {"mesh", "int8_kv", "page_transfer",
                                       "prefix_credit", "speculation"}
@@ -393,8 +378,8 @@ def test_what_a_ring_cannot_do_is_refused_where_the_engine_is_built(model):
             E.refuse_for_pools(mcfg, feature)
     assert E.pools_can(mcfg, "weight_quantization")
     with pytest.raises(NotImplementedError, match="int8_kv"):
-        _engine(model, kv_cache_dtype="int8")
-    eng = _engine(model, prefix_cache={"enabled": True})
+        engines.fresh(kv_cache_dtype="int8")  # the build raises
+    eng = engines(prefix_cache={"enabled": True})
     assert not eng.state.credit_prefix
     eng.put([1], [np.arange(20, dtype=np.int32)])
     with pytest.raises(NotImplementedError, match="page_transfer"):
@@ -405,6 +390,7 @@ def test_what_a_ring_cannot_do_is_refused_where_the_engine_is_built(model):
     # a chunk of more rows than a ring takes in one step
     with pytest.raises(ValueError, match="more than a ring takes"):
         eng.put([1], [np.arange(9, dtype=np.int32)])
+    eng.flush(1)
     # a model of ONE window, or none, has no ring and takes its old path
     for hf in (dict(HF, layer_types=["full_attention"] * 8),
                dict(HF, layer_types=["sliding_attention"] * 8)):
@@ -413,14 +399,6 @@ def test_what_a_ring_cannot_do_is_refused_where_the_engine_is_built(model):
         assert M.ring_blocks(one, 8, 32) == 0
         assert one.attention_window_pattern is None
     assert one.sliding_window == 16 and one.rope_scaling_type == "none"
-
-
-def test_the_training_forward_refuses_the_family(model):
-    mcfg, params = model
-    assert set(mcfg.serving_only) == {"rope_scaling_full_only",
-                                      "rope_scaling_type"}
-    with pytest.raises(NotImplementedError, match="rope_scaling"):
-        T.forward(params, jnp.zeros((1, 8), jnp.int32), mcfg)
 
 
 # -- the configuration and the import --------------------------------------
@@ -469,33 +447,6 @@ UNREAD = [("use_qk_norm", True), ("attn_logit_softcapping", 50.0),
 def test_the_import_refuses_a_block_key_it_does_not_read(key, value):
     with pytest.raises(ValueError, match=f"does not read '{key}'"):
         config_from_hf(dict(HF, **{key: value}))
-
-
-@pytest.mark.parametrize("what,hf,match", [
-    ("a dense layer after a sparse one",
-     dict(HF, mlp_layer_types=["sparse", "dense"] + ["sparse"] * 6),
-     "dense layer after"),
-    ("an unknown layer type",
-     dict(HF, layer_types=["chunked_attention"] * 8), "unknown"),
-    ("a scaled table on the sliding layers",
-     dict(HF, rope_parameters=dict(HF["rope_parameters"],
-                                   sliding_attention=YARN)),
-     "scaled table on"),
-    ("another scaling than YaRN",
-     dict(HF, rope_parameters=dict(
-         HF["rope_parameters"],
-         full_attention=dict(YARN, rope_type="longrope"))), "longrope"),
-    ("YaRN without truncate",
-     dict(HF, rope_parameters=dict(
-         HF["rope_parameters"],
-         full_attention=dict(YARN, truncate=False))), "truncate"),
-    ("a bias on attention", dict(HF, attention_bias=True), "attention_bias"),
-    ("sliding layers without a window", dict(HF, sliding_window=None),
-     "need sliding_window"),
-])
-def test_what_the_mapping_cannot_serve_is_an_error(what, hf, match):
-    with pytest.raises(ValueError, match=match):
-        config_from_hf(hf)
 
 
 OTHERS = ["tiny-mistral", "tiny-olmoe", "tiny-pangu", "tiny-lfm2",

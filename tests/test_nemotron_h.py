@@ -20,22 +20,33 @@ which experts 4..7 are held, a shared expert of 48.
 """
 
 import json
-import os
-import pathlib
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+import _family as F
+import _state_walk as W
 import pytest
-from jax.sharding import SingleDeviceSharding
+from _family import (  # noqa: F401 - the contract's fixtures and cases, collected here
+    engines,
+    family,
+    model,
+    one_chip,
+    pytest_generate_tests,
+    served,
+    test_a_chunk_boundary_at_every_offset,
+    test_a_wrong_model_fails_the_written_tolerance,
+    test_prefill_chunks_and_single_steps_match_the_reference,
+    test_prefix_credit_and_speculation_are_refused,
+    test_the_cuts_file_keeps_the_published_widths,
+    test_the_engine_refuses_at_build,
+    test_the_engine_with_kernels_matches_the_reference,
+    test_the_training_forward_refuses_the_family,
+    test_what_the_mapping_cannot_serve_is_an_error,
+    test_whole_prompt_waves_and_fused_decode_carry_the_state,
+)
 
 from benchmarks.reference import nemotron_h as ref
-from benchmarks.tests import helpers
-from deepspeed_tpu.inference import (
-    ServingScheduler,
-    ServingSchedulerConfig,
-    init_inference,
-)
 from deepspeed_tpu.inference import engine as E
 from deepspeed_tpu.inference import model as M
 from deepspeed_tpu.models import transformer as T
@@ -43,8 +54,7 @@ from deepspeed_tpu.ops.pallas import expert_stream as ES
 from deepspeed_tpu.ops.pallas import ssm_state as SS
 from deepspeed_tpu.utils.hf_checkpoint import config_from_hf
 
-ROOT = pathlib.Path(__file__).resolve().parents[1]
-BENCH = ROOT / "benchmarks"
+BENCH = F.BENCH
 CUT = BENCH / "configs/nemotron-3-nano-30b-a3b-serve-l13-ep4.json"
 PUBLISHED = BENCH / "configs/published/nemotron-3-nano-30b-a3b-bf16.json"
 HF = {"attention_bias": False, "chunk_size": 16, "conv_kernel": 4,
@@ -78,142 +88,79 @@ ENGINE = dict(max_seq_len=256, kv_block_size=32, num_kv_blocks=48,
               min_prefill_bucket=32)
 
 
-@pytest.fixture
-def rng():
-    return np.random.default_rng(0)
+def _jig(k, v, key):
+    """Every norm scale, tap, bias, decay, skip and router bias
+    matters."""
+    if k == "ssm_d":
+        return jax.random.uniform(key, v.shape, minval=0.4, maxval=0.7)
+    if "scale" in k:
+        return 1 + 0.3 * jax.random.normal(key, v.shape)
+    if k == "ssm_taps":
+        return 0.6 * jax.random.normal(key, v.shape)
+    if k == "ssm_conv_bias":
+        return 0.5 * jax.random.normal(key, v.shape)
+    if k in ("attn_wq", "attn_wk"):
+        return v * 6  # scores sharp enough for positions to matter
+    if k in ("ssm_a_log", "ssm_dt_bias"):
+        # decays from 0.3 to 0.97 a token: long and short memory
+        return jax.random.uniform(key, v.shape, minval=-3.0, maxval=0.5)
+    if k == "moe_expert_bias":
+        return 0.3 * jax.random.normal(key, v.shape)  # moves choices
+    if k in ("moe_w_in", "moe_ws_in"):
+        return v * 3  # relu^2 of a small input is smaller still
+    return v
 
 
-@pytest.fixture(scope="module")
-def model():
-    mcfg = config_from_hf(HF, use_flash=False)
-    params = T.init(mcfg, jax.random.PRNGKey(1))
-    # spread the logits (the 0.02 init gives nearly flat ones) and make
-    # every norm scale, tap, bias, decay, skip and router bias matter
-    params = jax.tree.map(lambda x: x * 4, params)
-
-    def shaped(tree, salt):
-        out = {}
-        for i, (k, v) in enumerate(tree.items()):
-            key = jax.random.fold_in(jax.random.PRNGKey(salt), i)
-            if k == "ssm_d":
-                v = jax.random.uniform(key, v.shape, minval=0.4, maxval=0.7)
-            elif "scale" in k:
-                v = 1 + 0.3 * jax.random.normal(key, v.shape)
-            elif k == "ssm_taps":
-                v = 0.6 * jax.random.normal(key, v.shape)
-            elif k == "ssm_conv_bias":
-                v = 0.5 * jax.random.normal(key, v.shape)
-            elif k in ("attn_wq", "attn_wk"):
-                v = v * 6  # scores sharp enough for positions to matter
-            elif k in ("ssm_a_log", "ssm_dt_bias"):
-                # decays from 0.3 to 0.97 a token: long and short memory
-                v = jax.random.uniform(key, v.shape, minval=-3.0, maxval=0.5)
-            elif k == "moe_expert_bias":
-                v = 0.3 * jax.random.normal(key, v.shape)  # moves choices
-            elif k in ("moe_w_in", "moe_ws_in"):
-                v = v * 3  # relu^2 of a small input is smaller still
-            out[k] = v
-        return out
-
-    top = shaped({k: v for k, v in params.items() if k != "layers"}, 2)
-    return mcfg, dict(top, layers=shaped(params["layers"], 3))
+def test_what_only_this_cut_states():
+    hf = json.loads(CUT.read_text())
+    assert hf["vocab_size"] * 4 == hf["reduced"]["vocab_size"]["published"]
+    assert hf["hybrid_override_pattern"] == \
+        hf["reduced"]["hybrid_override_pattern"]["published"][:13]
+    sv = hf["serve"]
+    assert sv["engine"]["max_batch_size"] == \
+        sv["engine"]["max_tracked_sequences"] == 256
+    assert sv["scheduler"] == {
+        "max_num_batched_tokens": 512, "prefill_chunk": 32,
+        "prefill_mode": "chunked", "decode_chunk": 1}
 
 
-def _top(params):
-    return {k: v for k, v in params.items() if k != "layers"}
-
-
-def _ref_logits(params, toks, mutate=None, hf=HF):
-    return np.asarray(ref.forward_logits(
-        _top(params), lambda l: jax.tree.map(lambda a: a[l], params["layers"]),
-        toks, hf, mutate))
-
-
-def _engine(model, **over):
-    mcfg, params = model
-    return init_inference(params, mcfg, dict(ENGINE, **over),
-                          dtype=jnp.float32)
-
-
-@pytest.fixture(scope="module")
-def shared_engine(model):
-    return _engine(model)
-
-
-def _feeds(model, eng, lens, splits, n_dec, seed=0):
-    """Teacher-forced put() logits of prompts of `lens`, each fed as
-    len - sum(splits) tokens whole, then chunks of `splits`, then n_dec
-    single tokens: (engine logits [prompts, feeds, V], the reference's
-    at the same positions)."""
-    rng = np.random.default_rng(seed)
-    full = [rng.integers(0, HF["vocab_size"], n + n_dec).astype(np.int32)
-            for n in lens]
-    uids = list(range(100, 100 + len(lens)))
-    cuts = [[n - sum(splits[j:]) for j in range(len(splits) + 1)]
-            + [n + j + 1 for j in range(n_dec)] for n in lens]
-    got = []
-    for j in range(len(cuts[0])):
-        toks = [f[(c[j - 1] if j else 0):c[j]] for f, c in zip(full, cuts)]
-        got.append(np.asarray(eng.put(uids, toks)))
-    for u in uids:
-        eng.flush(u)
-    padded = np.zeros((len(full), max(map(len, full))), np.int32)
-    for i, f in enumerate(full):
-        padded[i, :len(f)] = f
-    want = _ref_logits(model[1], padded)
-    want = np.stack([want[i, np.asarray(c) - 1] for i, c in enumerate(cuts)])
-    return np.stack(got, axis=1), want, padded, cuts
-
-
-@pytest.fixture(scope="module")
-def served(model, shared_engine):
-    return _feeds(model, shared_engine, [70, 83], [5], 6)
-
-
-def test_prefill_chunks_and_single_steps_match_the_reference(served):
-    got, want, _, _ = served
-    assert np.isfinite(got).all()
-    assert np.abs(want).max() > 2
-    assert np.abs(got - want).max() < LOGITS_ATOL, np.abs(got - want).max(-1)
-
-
-@pytest.mark.parametrize("chunk", [1, 3, 7])
-def test_a_chunk_boundary_at_every_offset(model, shared_engine, chunk):
-    """Ragged rows: the first chunk starts 1, 3 or 7 tokens before the
-    prompt's end, a second chunk of 4 follows (its first rows read what
-    the first left in the slot), then single steps, two prompts of
-    unequal lengths side by side."""
-    got, want, _, _ = _feeds(model, shared_engine, [41, 56], [chunk, 4], 3,
-                             seed=chunk)
-    assert np.abs(got - want).max() < LOGITS_ATOL, np.abs(got - want).max(-1)
-
-
-def _float8(x):
-    return x.astype(jnp.float8_e4m3fn).astype(x.dtype)
-
-
-@pytest.mark.parametrize("control", ref.MUTANTS + ("float8_weights",))
-def test_a_wrong_model_fails_the_written_tolerance(model, served, control):
-    """Each of the logits audit's controls, put in the reference's
-    place: the engine must NOT agree with it. The judge of record for
-    whatever the chip's bf16 engine cannot tell from its own rounding
-    (the traffic file names those)."""
-    got, _, padded, cuts = served
-    params = model[1]
-    if control == "float8_weights":
-        wrong = _ref_logits(jax.tree.map(_float8, params), padded)
-    else:
-        wrong = _ref_logits(params, padded, control)
-    wrong = np.stack([wrong[i, np.asarray(c) - 1] for i, c in enumerate(cuts)])
-    assert np.abs(got - wrong).max() > 300 * LOGITS_ATOL, control
+# ragged rows: the first chunk starts 1, 3 or 7 tokens before the
+# prompt's end
+FAMILY = F.Family(
+    hf=HF, ref=ref, atol=LOGITS_ATOL, engine=ENGINE, jig=_jig, spread=2.0,
+    far=300, chunks=(1, 3, 7), training_refuses="mixer_only",
+    run_tokens="ssm_run_tokens", cut=CUT,
+    with_kernels=(True, True, "state_space/ssm_state/jit(_ssm_step)"),
+    reduced=("hybrid_override_pattern", "n_routed_experts",
+             "num_hidden_layers", "vocab_size"),
+    held={"start": 0, "count": 32, "of": 128},
+    assumed=("expand_unused", "no_positions", "time_step_keys",
+             "state_dtype", "state_layout", "weights", "state_slots",
+             "kv_pool", "max_tracked_sequences", "max_seq_len"),
+    unservable=(
+        ("the family's dense layer",
+         dict(HF, hybrid_override_pattern="MEM*-MEM"), "dense MLP layer"),
+        ("group-limited routing", dict(HF, n_group=2), "n_group"),
+        ("groups kept of several", dict(HF, topk_group=2), "topk_group"),
+        ("an unknown character", dict(HF, hybrid_override_pattern="MEM*EMEX"),
+         "hybrid_override_pattern names"),
+        ("a pattern of another length", dict(HF, num_hidden_layers=9),
+         "hybrid_override_pattern names"),
+        ("gated experts", dict(HF, mlp_hidden_act="silu"), "mlp_hidden_act"),
+        ("a convolution without its bias", dict(HF, use_conv_bias=False),
+         "use_conv_bias"),
+        ("a latent key the mapping does not read", dict(HF, kv_lora_rank=32),
+         "does not read"),
+        ("the pattern under another architecture",
+         dict(F.MISTRAL, hybrid_override_pattern="M*"), "does not read"),
+        ("groups under another architecture", dict(F.MISTRAL, n_groups=8),
+         "does not read"),
+        ("a shared expert that is no multiple of an expert",
+         dict(HF, moe_shared_expert_intermediate_size=50), "no multiple"),
+    ))
 
 
 # -- the configuration ---------------------------------------------------
-
-def _cut():
-    hf = json.loads(CUT.read_text())
-    return hf, config_from_hf(hf, **hf["serve"]["model_overrides"])
-
 
 def _published():
     return {k: v for k, v in json.loads(PUBLISHED.read_text()).items()
@@ -253,7 +200,7 @@ def test_the_importer_reads_the_published_file():
 
 
 def test_the_cut_builds_at_published_widths():
-    hf, cfg = _cut()
+    hf, cfg = F.cut_of(FAMILY)
     full = config_from_hf(_published(), max_seq=4096)
     # the cut differs from the published model in the four reduced keys
     import dataclasses
@@ -322,61 +269,6 @@ def test_the_cut_builds_at_published_widths():
     assert ES.stream_f_tile(256, None, lp["w_in"], lp["w_out"]) == 640
 
 
-def test_the_cuts_file_keeps_the_published_widths():
-    hf = json.loads(CUT.read_text())
-    helpers.check_published_widths(hf, BENCH)
-    assert sorted(hf["reduced"]) == ["hybrid_override_pattern",
-                                     "n_routed_experts", "num_hidden_layers",
-                                     "vocab_size"]
-    assert hf["share_of"] and hf["stands_for"]
-    assert hf["experts_held"] == {"start": 0, "count": 32, "of": 128}
-    assert hf["vocab_size"] * 4 == hf["reduced"]["vocab_size"]["published"]
-    assert hf["hybrid_override_pattern"] == \
-        hf["reduced"]["hybrid_override_pattern"]["published"][:13]
-    for key in ("expand_unused", "no_positions", "time_step_keys",
-                "state_dtype", "state_layout", "weights", "state_slots",
-                "kv_pool", "max_tracked_sequences", "max_seq_len"):
-        assert hf["assumed"][key]
-    sv = hf["serve"]
-    assert sv["engine"]["max_batch_size"] == \
-        sv["engine"]["max_tracked_sequences"] == 256
-    assert sv["scheduler"] == {
-        "max_num_batched_tokens": 512, "prefill_chunk": 32,
-        "prefill_mode": "chunked", "decode_chunk": 1}
-
-
-_MISTRAL = {"architectures": ["MistralForCausalLM"], "hidden_size": 64,
-            "intermediate_size": 128, "num_attention_heads": 4,
-            "num_key_value_heads": 2, "num_hidden_layers": 2,
-            "vocab_size": 64}
-
-
-@pytest.mark.parametrize("what,hf,match", [
-    ("the family's dense layer",
-     dict(HF, hybrid_override_pattern="MEM*-MEM"), "dense MLP layer"),
-    ("group-limited routing", dict(HF, n_group=2), "n_group"),
-    ("groups kept of several", dict(HF, topk_group=2), "topk_group"),
-    ("an unknown character", dict(HF, hybrid_override_pattern="MEM*EMEX"),
-     "hybrid_override_pattern names"),
-    ("a pattern of another length", dict(HF, num_hidden_layers=9),
-     "hybrid_override_pattern names"),
-    ("gated experts", dict(HF, mlp_hidden_act="silu"), "mlp_hidden_act"),
-    ("a convolution without its bias", dict(HF, use_conv_bias=False),
-     "use_conv_bias"),
-    ("a latent key the mapping does not read", dict(HF, kv_lora_rank=32),
-     "does not read"),
-    ("the pattern under another architecture",
-     dict(_MISTRAL, hybrid_override_pattern="M*"), "does not read"),
-    ("groups under another architecture", dict(_MISTRAL, n_groups=8),
-     "does not read"),
-    ("a shared expert that is no multiple of an expert",
-     dict(HF, moe_shared_expert_intermediate_size=50), "no multiple"),
-])
-def test_what_the_mapping_cannot_serve_is_an_error(what, hf, match):
-    with pytest.raises(ValueError, match=match):
-        config_from_hf(hf)
-
-
 def test_the_kinds_and_what_they_hold():
     """The fifth kind is an entry of OPERATOR_PREFIX and of no state
     table: a layer holds K/V, state or nothing by its kind."""
@@ -396,12 +288,6 @@ def test_the_kinds_and_what_they_hold():
         T.TransformerConfig(n_layers=2, mixer_only=True)
 
 
-def test_the_training_forward_refuses_the_family(model):
-    mcfg, params = model
-    with pytest.raises(NotImplementedError, match="mixer_only"):
-        T.forward_hidden(params, jnp.zeros((1, 8), jnp.int32), mcfg)
-
-
 def test_a_dense_squared_relu_mlp_trains():
     """relu2 is one entry of the activation table train and serve
     share."""
@@ -416,15 +302,6 @@ def test_a_dense_squared_relu_mlp_trains():
 
 
 # -- the recurrence in groups ------------------------------------------------
-
-def _ssm_inputs(rng, *lead, G, H=8, P=16, N=32):
-    def normal(*shape):
-        return jnp.asarray(rng.normal(size=shape), jnp.float32)
-
-    return (normal(*lead, H, P), jax.nn.softplus(normal(*lead, H) - 1.0),
-            -jnp.exp(normal(H) * 0.5), normal(*lead, G * N),
-            normal(*lead, G * N))
-
 
 def _loop(x, dt, A, Bm, Cm, state, G):
     """The recurrence head by head in numpy float64: head h reads the B
@@ -446,7 +323,7 @@ def _loop(x, dt, A, Bm, Cm, state, G):
 
 @pytest.mark.parametrize("G", [1, 2, 8])
 def test_the_recurrence_and_the_chunked_form_read_their_groups(rng, G):
-    x, dt, A, Bm, Cm = _ssm_inputs(rng, 2, 23, G=G)
+    x, dt, A, Bm, Cm = W.ssm_inputs(rng, 2, 23, G=G)
     state = jnp.asarray(rng.normal(size=(2, 8, 16, 32)), jnp.float32)
     want_y, want_s = _loop(x, dt, A, Bm, Cm, state, G)
     y1, s1 = SS.ssm_recurrent(x, dt, A, Bm, Cm, state, groups=G)
@@ -461,19 +338,11 @@ def test_the_recurrence_and_the_chunked_form_read_their_groups(rng, G):
 
 
 def _check_step(step, rng, G):
-    """A step's rows: a run of five from a slot's state, a decode row, a
-    pad row, a run of three from position 0 (the slot's NaN must not be
-    read), another pad row. 16 heads of 16 in two lane rows of the
-    pool."""
-    slots = jnp.asarray([3, 3, 3, 3, 3, 1, -1, 0, 0, 0, -1], jnp.int32)
-    pos = jnp.asarray([5, 6, 7, 8, 9, 12, 0, 0, 1, 2, 0], jnp.int32)
-    pool = jnp.asarray(rng.normal(size=(6, 2, 32, 128)), jnp.float32)
-    pool = pool.at[0].set(jnp.nan)
-    x, dt, A, Bm, Cm = _ssm_inputs(rng, 11, G=G, H=16)
-    y, new = step(x, dt, A, Bm, Cm, pool, slots, pos)
-    for rows, slot, start in ((slice(0, 5), 3, pool[3]),
-                              (slice(5, 6), 1, pool[1]),
-                              (slice(7, 10), 0, None)):
+    """16 heads of 16 in two lane rows of the pool."""
+    pool, slots, pos, runs = W.ragged(rng, (6, 2, 32, 128))
+    x, dt, A, Bm, Cm = W.ssm_inputs(rng, 11, G=G, H=16)
+    y, new = jax.jit(step)(x, dt, A, Bm, Cm, pool, slots, pos)
+    for rows, slot, start in runs:
         first = (np.zeros((1, 16, 16, 32)) if start is None
                  else SS.unpack_state(start, 8)[None])
         want_y, want_s = _loop(x[None, rows], dt[None, rows], A,
@@ -505,7 +374,7 @@ def test_the_step_kernel_with_eight_groups(rng):
     slots = jnp.asarray([2, 2, 2, 0, -1, 1, 3, 3], jnp.int32)
     pos = jnp.asarray([0, 1, 2, 9, 0, 4, 7, 8], jnp.int32)
     pool = jnp.asarray(rng.normal(size=(5, 8, 8, 128)), jnp.float32)
-    args = _ssm_inputs(rng, 8, G=8, H=64, N=8)
+    args = W.ssm_inputs(rng, 8, G=8, H=64, N=8)
     y1, p1 = SS.ssm_step(*args, pool, slots, pos)
     y2, p2 = SS.ssm_step_xla(*args, pool, slots, pos)
     np.testing.assert_allclose(y1, y2, atol=2e-5)
@@ -550,32 +419,11 @@ def test_stacks_that_fill_their_lanes_stay_as_they_are():
 
 # -- the engine with kernels, the program's text ---------------------------
 
-def _step_text(eng):
-    return eng._decode_fn(8, False).lower(
-        eng.params, eng.cache, *(eng._dev(np.zeros(s, np.int32)) for s in
-                                 ((8,), (8, eng.config.blocks_per_seq), (8,))),
-        *eng.state_args(np.zeros((8,), np.int32))).as_text(debug_info=True)
-
-
-@pytest.mark.usefixtures("pallas_interpret")
-def test_the_engine_with_kernels_matches_the_reference(model):
-    """decode_impl 'auto' under the interpreter resolves the kernels:
-    the step kernel with two groups on the aliased pool, the walk and
-    the write in the attention layers (d 64 fills no lanes: the
-    convolution and the experts stay XLA's here; their kernels' own
-    tests are above and in test_conv_carry.py)."""
-    eng = _engine(model)
-    assert eng.resolved_impl == "pallas" and eng.step_kernel(8)
-    assert "state_space/ssm_state/jit(_ssm_step)" in _step_text(eng)
-    got, want, _, _ = _feeds(model, eng, [37, 45], [5], 3, seed=4)
-    assert np.abs(got - want).max() < LOGITS_ATOL, np.abs(got - want).max(-1)
-
-
-def test_the_scopes_of_a_layer_that_is_one_mixer(model):
+def test_the_scopes_of_a_layer_that_is_one_mixer(engines):
     """The scopes a kind already has keep their names, the one norm is
     `norm1`, and there is no `norm2`: span-only readers work
     unchanged."""
-    text = _step_text(_engine(model))
+    text = F.step_text(engines())
     for scope in ("norm1", "state_space/ssm_project", "state_space/ssm_conv",
                   "state_space/ssm_state", "state_space/ssm_gate_norm",
                   "state_space/ssm_out", "attention", "mlp/moe_route",
@@ -608,51 +456,16 @@ def test_the_ungated_pass_has_a_kernel_name_of_its_own():
 
 # -- through the scheduler: slots taken, reused, counted by what holds them --
 
-def _requests(n, seed=5):
-    rng = np.random.default_rng(seed)
-    return [(rng.integers(0, HF["vocab_size"], int(rng.integers(9, 60))
-                          ).tolist(), int(rng.integers(3, 12)))
-            for _ in range(n)]
-
-
-def _serve(eng, requests, **sched):
-    s = ServingScheduler(eng, ServingSchedulerConfig(
-        **dict(dict(max_num_batched_tokens=48, prefill_chunk=8,
-                    prefill_mode="chunked", decode_chunk=1, warmup=False),
-               **sched)))
-    rids = [s.submit(p, max_new_tokens=n) for p, n in requests]
-    s.run()
-    return s, [s.finished[r].output for r in rids]
-
-
-def _greedy_by_the_reference(model, requests, outputs):
-    for (prompt, _), out in zip(requests, outputs):
-        toks = np.zeros((1, 96), np.int32)
-        toks[0, :len(prompt) + len(out)] = prompt + out
-        logits = _ref_logits(model[1], toks)[0]
-        for j, t in enumerate(out):
-            row = logits[len(prompt) + j - 1]
-            assert row[t] >= row.max() - LOGITS_ATOL, (j, t, row.argmax())
-
-
-def test_a_slot_is_handed_on_and_counted_by_the_layers_that_hold_one(model):
+def test_a_slot_is_handed_on_and_counted_by_the_layers_that_hold_one(
+        model, engines):
     """12 requests through 6 slots: every slot is handed on to a later
     sequence and what the last one left in it (here: NaN) never reaches
     the next; the counters of state count the FOUR mixers of eight
     layers, not the depth."""
-    eng = _engine(model, max_batch_size=ENGINE["max_tracked_sequences"])
+    eng = engines.sched()
     assert E.pool_kinds(model[0]) == ("kv", "state")
     assert len(eng.cache.state) == 4 and len(eng.cache.k) == 1
-    eng.cache = eng.cache._replace(state=jax.tree.map(
-        lambda p: jnp.full_like(p, jnp.nan), eng.cache.state))
-    requests = _requests(12)
-    s, outputs = _serve(eng, requests)
-    assert all(len(o) == n for o, (_, n) in zip(outputs, requests))
-    _greedy_by_the_reference(model, requests, outputs)
-    d = s.counters
-    assert d["state_slot_resets"] == 12 > ENGINE["max_tracked_sequences"]
-    assert d["state_slots_live"] >= d["steps"] > 0
-    assert eng.state.n_tracked == 0 and len(eng.state._free_slots) == 6
+    d, requests = F.through_reused_slots(FAMILY, model, eng)
     # a slot: 4 mixers x (16 matrices of 16 x 32 + 3 inputs of 16 x 16 +
     # 2 x 2 x 32 = 384 channels: three whole lane rows), float32
     assert eng.state_slot_bytes == 4 * 4 * (16 * 16 * 32 + 3 * 384)
@@ -664,25 +477,16 @@ def test_a_slot_is_handed_on_and_counted_by_the_layers_that_hold_one(model):
         d["batched_tokens"] * HF["num_experts_per_tok"]
 
 
-def test_the_census_says_how_many_pairs_reached_the_held_experts(model):
+def test_the_census_says_how_many_pairs_reached_the_held_experts(engines):
     """With the census on, `metrics()` sets the pairs that reached this
     chip's experts beside the even router's expectation."""
-    eng = _engine(model, max_batch_size=ENGINE["max_tracked_sequences"],
-                  moe_census=True)
-    s, _ = _serve(eng, _requests(4, seed=2))
+    eng = engines.sched(moe_census=True)
+    s, _ = F.serve(eng, F.requests(FAMILY, 4, seed=2))
     m = s.metrics()
     census = eng.moe_expert_census()
     assert m["moe_census_held_pairs"] == float(census[4:8].sum()) > 0
     assert m["moe_census_held_pairs_expected"] == \
         pytest.approx(census.sum() * 4 / 8)
-
-
-def test_whole_prompt_waves_and_fused_decode_carry_the_state(model):
-    requests = _requests(6, seed=3)
-    eng = _engine(model, max_batch_size=ENGINE["max_tracked_sequences"])
-    s, outputs = _serve(eng, requests, prefill_mode="wave", decode_chunk=4)
-    _greedy_by_the_reference(model, requests, outputs)
-    assert s.counters["ssm_run_tokens"] == sum(len(p) for p, _ in requests)
 
 
 # -- the share of an expert-parallel deployment ----------------------------
@@ -708,93 +512,31 @@ def test_four_shares_and_the_shared_expert_once_are_the_uncut_layer(model):
     with jax.default_matmul_precision("highest"):
         whole, _ = ref.moe(n[None], ow, uncut_hf)
         alike = ref._relu2(n, shared["ws_in"], shared["ws_out"])
-        parts = []
-        for share in range(4):
-            cfg = config_from_hf(dict(
-                HF, n_routed_experts=2, experts_held={"start": 2 * share},
-                reduced={"n_routed_experts": {"published": 8, "here": 2}}))
-            assert cfg.experts_held == (2 * share, 2)
-            lp = dict(shared, **{k: w[2 * share:2 * share + 2]
-                                 for k, w in full.items()})
-            parts.append(M._mlp(n, lp, cfg) - alike)
-    np.testing.assert_allclose(sum(parts) + alike, whole[0], atol=2e-5)
-    assert float(jnp.abs(whole[0] - alike).max()) > 0.01
-
-
-# -- what cannot be right yet is refused where it is built ----------------
-
-@pytest.mark.parametrize("what,kwargs,config", [
-    ("int8_kv", {}, {"kv_cache_dtype": "int8"}),
-    ("mesh", {}, {"tp_size": 2}),
-    ("weight_quantization", {"quantization": {"bits": 8}}, {}),
-    ("offload", {"offload": {"device": "cpu"}}, {}),
-])
-def test_the_engine_refuses_at_build(model, what, kwargs, config):
-    mcfg, params = model
-    with pytest.raises(NotImplementedError, match=what):
-        init_inference(params, mcfg, dict(ENGINE, **config),
-                       dtype=jnp.float32, **kwargs)
-
-
-def test_prefix_credit_and_speculation_are_refused(model):
-    assert not E.pools_can(model[0], "prefix_credit")
-    with pytest.raises(NotImplementedError, match="speculation"):
-        ServingScheduler(_engine(model), ServingSchedulerConfig(warmup=False),
-                         speculative={"ngram": 2, "draft_len": 3})
+    F.shares_of_two_add_up(FAMILY, "n_routed_experts", n, shared, full,
+                           whole[0], alike)
 
 
 # -- the kernels at the cell's shapes --------------------------------------
 
-@pytest.fixture(scope="module")
-def one_chip():
-    os.environ.setdefault("TPU_LOG_DIR", "disabled")
-    from jax.experimental import topologies
-
-    try:
-        topo = topologies.get_topology_desc(platform="tpu",
-                                            topology_name="v5e:2x2")
-    except Exception as e:  # noqa: BLE001 - whatever keeps libtpu away
-        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    from jax.experimental.compilation_cache import compilation_cache as cc
-
-    jax.config.update("jax_enable_compilation_cache", False)
-    cc.reset_cache()
-    return SingleDeviceSharding(topo.devices[0])
-
-
-def _kernels(text):
-    return [line for line in text.splitlines()
-            if 'custom_call_target="tpu_custom_call"' in line]
-
-
 def test_the_step_kernel_compiles_for_v5e_with_eight_groups(one_chip):
     """256 rows of 64 heads of 64 x 128 in 8 groups over a pool of 257
     slots of 2 MiB, aliased in and out."""
-    def sds(shape, dtype=jnp.float32):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-
+    sds = F.on_chip(one_chip, jnp.float32)
     rows, pool = 256, sds((257, 32, 128, 128))
     assert SS.ssm_step_fits(rows, pool)
-    compiled = jax.jit(SS.ssm_step, donate_argnums=(5,)).lower(
+    F.compiles_one_aliased_kernel(SS.ssm_step, (
         sds((rows, 64, 64)), sds((rows, 64)), sds((64,)), sds((rows, 1024)),
         sds((rows, 1024)), pool, sds((rows,), jnp.int32),
-        sds((rows,), jnp.int32)).compile()
-    calls = _kernels(compiled.as_text())
-    assert len(calls) == 1 and "ssm_state" in calls[0]
-    mem = compiled.memory_analysis()
-    assert mem.alias_size_in_bytes >= 257 * 32 * 128 * 128 * 4
-    assert mem.temp_size_in_bytes < 64 << 20
+        sds((rows,), jnp.int32)), 5, "ssm_state")
 
 
 def test_the_ungated_pass_compiles_for_v5e_at_the_cells_shapes(one_chip):
     """32 held experts of 2688 x 1,856 padded to 1,920: three F tiles of
     640 an expert, 256 rows resident."""
-    def sds(shape, dtype=jnp.bfloat16):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-
+    sds = F.on_chip(one_chip, jnp.bfloat16)
     w_in, w_out = sds((32, 2688, 1920)), sds((32, 1920, 2688))
     compiled = jax.jit(lambda h, wi, wo, c: ES.expert_stream_ungated_mlp(
         h, wi, wo, c, T._ACT_FNS["relu2"])).lower(
         sds((256, 2688)), w_in, w_out, sds((32, 256), jnp.float32)).compile()
-    calls = _kernels(compiled.as_text())
+    calls = F.kernels(compiled.as_text())
     assert len(calls) == 1 and "expert_stream_ungated" in calls[0]

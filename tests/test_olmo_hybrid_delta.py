@@ -9,14 +9,15 @@ token), the shared walk's row patterns on paired heads, what the kernel
 takes, the walk at one query head a KV head, and the two kernels
 compiled for a described v5e at the cell's shapes."""
 
-import os
+import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+import _family as F
 import _state_walk as W
 import pytest
-from jax.sharding import SingleDeviceSharding
+from _family import one_chip  # noqa: F401 - the described v5e chip
 
 from deepspeed_tpu.inference import model as M
 from deepspeed_tpu.models import transformer as T
@@ -56,8 +57,11 @@ def test_the_chunked_form_is_the_recurrence_up_to_beta_two(rng, tokens, chunk,
     by substitution holds where their product gave NaN."""
     args = _delta_inputs(rng, 2, tokens, parallel=parallel)
     state = jnp.asarray(rng.normal(size=(2, 2, 96, 192)), jnp.float32)
-    o1, s1 = GD.gated_delta_recurrent(*args, state)
-    o2, s2 = GD.gated_delta_chunked(*args, state, chunk=chunk)
+    # (each form ONE program: op by op the chunked form is some forty
+    # small compiles a case)
+    o1, s1 = jax.jit(GD.gated_delta_recurrent)(*args, state)
+    o2, s2 = jax.jit(functools.partial(GD.gated_delta_chunked, chunk=chunk))(
+        *args, state)
     assert np.isfinite(o2).all() and np.isfinite(s2).all()
     tol = 2e-4 if parallel else 2e-5
     np.testing.assert_allclose(o2, o1, atol=tol)
@@ -107,27 +111,13 @@ def test_a_padded_prompt_leaves_the_state_in_the_pools_layout(rng):
     assert (np.asarray(new[1]) == 7).all() and (np.asarray(new[3]) == 7).all()
 
 
-def _ragged_rows(rng, H, pack, parallel=0.0):
-    """A step's rows: a run of five from a slot's state (positions
-    5..9), a decode row, a pad row, a run of three from position 0 (the
-    slot's NaN must not be read), another pad row."""
-    slots = jnp.asarray([3, 3, 3, 3, 3, 1, -1, 0, 0, 0, -1], jnp.int32)
-    pos = jnp.asarray([5, 6, 7, 8, 9, 12, 0, 0, 1, 2, 0], jnp.int32)
-    pool = jnp.asarray(rng.normal(size=(6, H // pack, 96, pack * 192)),
-                       jnp.float32)
-    pool = pool.at[0].set(jnp.nan)
-    return _delta_inputs(rng, 11, H=H, parallel=parallel), pool, slots, pos
-
-
 def _check_step(step, rng, H, pack, parallel=0.0):
-    (q, k, v, g, beta), pool, slots, pos = _ragged_rows(rng, H, pack,
-                                                        parallel)
-    o, new = step(q, k, v, g, beta, pool, slots, pos)
+    pool, slots, pos, runs = W.ragged(rng, (6, H // pack, 96, pack * 192))
+    q, k, v, g, beta = _delta_inputs(rng, 11, H=H, parallel=parallel)
+    o, new = jax.jit(step)(q, k, v, g, beta, pool, slots, pos)
     assert o.shape == v.shape and new.shape == pool.shape
-    for rows, slot, start in ((slice(0, 5), 3, pool[3]), (slice(5, 6), 1,
-                                                          pool[1]),
-                              (slice(7, 10), 0, None)):
-        want_o, want_s = GD.gated_delta_recurrent(
+    for rows, slot, start in runs:
+        want_o, want_s = jax.jit(GD.gated_delta_recurrent)(
             q[None, rows], k[None, rows], v[None, rows], g[None, rows],
             beta[None, rows],
             None if start is None else GD.unpack_heads(start, pack)[None])
@@ -192,17 +182,7 @@ def test_step_fits(what, n_rows, shape, dtype, fits):
 def test_the_walk_with_one_query_head_a_kv_head_matches_the_oracle(rng):
     """30 query and 30 KV heads of 128, contexts that end inside a
     block, at a block's edge and nowhere (a pad row)."""
-    S, H, D, bs, NB = 5, 30, 128, 16, 4
-    ctx = np.asarray([1, 17, 40, 64, 0], np.int32)
-    NBLK = S * NB + 1
-    q = jnp.asarray(rng.normal(size=(S, H, D)), jnp.float32)
-    kc = jnp.asarray(rng.normal(size=(NBLK, bs, H, D)), jnp.float32)
-    vc = jnp.asarray(rng.normal(size=(NBLK, bs, H, D)), jnp.float32)
-    tbl = jnp.asarray(rng.permutation(NBLK - 1)[:S * NB].reshape(S, NB),
-                      jnp.int32)
-    got = PA.paged_decode_attention(q, kc, vc, tbl, jnp.asarray(ctx))
-    want = PA.paged_decode_attention_xla(q, kc, vc, tbl, jnp.asarray(ctx))
-    np.testing.assert_allclose(got[:4], want[:4], atol=2e-5)
+    F.walk_matches_the_oracle(rng, H=30, KV=30, D=128)
 
 
 @pytest.mark.parametrize("kv,d,itemsize,held", [
@@ -253,55 +233,24 @@ def test_padding_heads_change_nothing_the_model_sees(rng, G, fused):
         assert float(jnp.abs(y[:, :, KV:]).max()) == 0
 
 
-@pytest.fixture(scope="module")
-def one_chip():
-    os.environ.setdefault("TPU_LOG_DIR", "disabled")
-    from jax.experimental import topologies
-
-    try:
-        topo = topologies.get_topology_desc(platform="tpu",
-                                            topology_name="v5e:2x2")
-    except Exception as e:  # noqa: BLE001 - whatever keeps libtpu away
-        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    from jax.experimental.compilation_cache import compilation_cache as cc
-
-    jax.config.update("jax_enable_compilation_cache", False)
-    cc.reset_cache()
-    return SingleDeviceSharding(topo.devices[0])
-
-
-def _kernels(text):
-    return [line for line in text.splitlines()
-            if 'custom_call_target="tpu_custom_call"' in line]
-
-
 def test_the_step_kernel_compiles_for_v5e_at_the_cells_shapes(one_chip):
     """128 rows of 30 heads of 96 x 192 over a pool of 129 slots of 15
     lane rows of two heads, aliased in and out (no second 285 MB pool
     among the temporaries): Mosaic takes the pairs where it would
     refuse a row of 192 lanes."""
-    def sds(shape, dtype=jnp.float32):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-
+    sds = F.on_chip(one_chip, jnp.float32)
     rows, pool = 128, sds((129, 15, 96, 384))
     assert GD.step_fits(rows, pool)
-    compiled = jax.jit(GD.gated_delta_step, donate_argnums=(5,)).lower(
+    F.compiles_one_aliased_kernel(GD.gated_delta_step, (
         sds((rows, 30, 96)), sds((rows, 30, 96)), sds((rows, 30, 192)),
         sds((rows, 30)), sds((rows, 30)), pool, sds((rows,), jnp.int32),
-        sds((rows,), jnp.int32)).compile()
-    calls = _kernels(compiled.as_text())
-    assert len(calls) == 1 and "gdn_state" in calls[0]
-    mem = compiled.memory_analysis()
-    assert mem.alias_size_in_bytes >= 129 * 30 * 96 * 192 * 4
-    assert mem.temp_size_in_bytes < 64 << 20
+        sds((rows,), jnp.int32)), 5, "gdn_state")
 
 
 def _walk_args(one_chip, held):
-    def sds(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-
-    rows, pool = 128, sds((705, 128, held, 128), jnp.bfloat16)
-    q = new = sds((rows, 30, 128), jnp.bfloat16)
+    sds = F.on_chip(one_chip, jnp.bfloat16)
+    rows, pool = 128, sds((705, 128, held, 128))
+    q = new = sds((rows, 30, 128))
     table, ints = sds((rows, 32), jnp.int32), sds((rows,), jnp.int32)
     return q, pool, pool, new, new, table, ints, ints
 
@@ -324,11 +273,11 @@ def test_the_walk_and_both_writes_compile_for_v5e_at_30_heads_in_32(one_chip):
     text = jax.jit(shared, donate_argnums=(1, 2)).lower(
         *args).compile().as_text()
     for name in ("paged_decode_grid", "paged_kv_write"):
-        assert any(name in line for line in _kernels(text)), name
+        assert any(name in line for line in F.kernels(text)), name
     assert PA.kv_write_path(args[1].shape, jnp.bfloat16) == "rows"
     text = jax.jit(single, donate_argnums=(1, 2)).lower(
         *args).compile().as_text()
-    assert any("paged_decode_fused" in line for line in _kernels(text))
+    assert any("paged_decode_fused" in line for line in F.kernels(text))
 
 
 def test_a_pool_of_30_heads_stays_on_the_grid_and_is_not_refused(one_chip):
@@ -350,5 +299,5 @@ def test_a_pool_of_30_heads_stays_on_the_grid_and_is_not_refused(one_chip):
 
     text = jax.jit(single, donate_argnums=(1, 2)).lower(
         q, pool, pool, new, new, table, ints, ints).compile().as_text()
-    assert not any("paged_decode_fused" in line for line in _kernels(text))
-    assert any("paged_decode_grid" in line for line in _kernels(text))
+    assert not any("paged_decode_fused" in line for line in F.kernels(text))
+    assert any("paged_decode_grid" in line for line in F.kernels(text))

@@ -11,12 +11,13 @@ Everything is float32 with seeded weights: 4 layers, d 64, 4 heads,
 
 import dataclasses
 import json
-import pathlib
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+import _family as F
 import pytest
+from _family import engines, family  # noqa: F401 - the contract's fixtures
 
 from benchmarks.reference import olmoe as ref
 from deepspeed_tpu.inference import (
@@ -31,8 +32,7 @@ from deepspeed_tpu.moe.sharded_moe import topk_gating
 from deepspeed_tpu.utils import profiler
 from deepspeed_tpu.utils.hf_checkpoint import config_from_hf, import_external
 
-ROOT = pathlib.Path(__file__).resolve().parents[1]
-PUBLISHED = ROOT / "benchmarks/configs/published/olmoe-1b-7b-0125-instruct.json"
+PUBLISHED = F.ROOT / "benchmarks/configs/published/olmoe-1b-7b-0125-instruct.json"
 HF = {"attention_bias": False, "clip_qkv": None, "hidden_act": "silu",
       "hidden_size": 64, "intermediate_size": 32,
       "max_position_embeddings": 256, "model_type": "olmoe",
@@ -62,6 +62,7 @@ ALWAYS["stream"] = ALWAYS["scan"]
 BLOCK_RTOL_BF16 = 0.015
 ENGINE = dict(max_seq_len=256, kv_block_size=32, num_kv_blocks=32,
               max_batch_size=8, min_prefill_bucket=32)
+FAMILY = F.Family(hf=HF, ref=ref, atol=LOGITS_ATOL, engine=ENGINE)
 
 
 @pytest.fixture(scope="module")
@@ -81,19 +82,6 @@ def model():
 @pytest.fixture(scope="module")
 def tokens():
     return np.random.default_rng(0).integers(0, HF["vocab_size"], (2, 65))
-
-
-def _top(params):
-    return {k: v for k, v in params.items() if k != "layers"}
-
-
-def _layer_fn(params):
-    return lambda l: jax.tree.map(lambda a: a[l], params["layers"])
-
-
-def _ref_logits(params, toks, mutate=None):
-    return np.asarray(ref.forward_logits(_top(params), _layer_fn(params),
-                                         toks, HF, mutate))
 
 
 # -- the configuration ---------------------------------------------------
@@ -142,7 +130,7 @@ def test_qk_scales_are_ones_at_init_and_take_the_head_sharding(model):
 def test_training_forward_matches_the_reference(model, tokens):
     mcfg, params = model
     got = np.asarray(T.forward(params, jnp.asarray(tokens[:, :-1]), mcfg))
-    want = _ref_logits(params, tokens[:, :-1])
+    want = F.ref_logits(FAMILY, params, tokens[:, :-1])
     assert np.abs(want).max() > 0.5          # the logits are not flat
     assert np.abs(got - want).max() < LOGITS_ATOL
 
@@ -163,7 +151,7 @@ def test_eval_loss_through_ds_initialize_matches_the_reference(model, tokens):
         param_logical_specs=T.logical_specs(mcfg))
     # one row a device over the conftest's eight: the two rows four times
     batch = np.tile(tokens.astype(np.int32), (4, 1))
-    want = ref.loss(_top(params), _layer_fn(params), tokens, HF)
+    want = ref.loss(F.top(params), F.layer_fn(params), tokens, HF)
     assert abs(float(eng.eval_batch({"tokens": batch})) - want) < LOSS_ATOL
     assert abs(float(loss_fn(params, {"tokens": jnp.asarray(tokens)}, None))
                - want) < LOSS_ATOL
@@ -172,16 +160,16 @@ def test_eval_loss_through_ds_initialize_matches_the_reference(model, tokens):
 # -- serving -------------------------------------------------------------
 
 @pytest.fixture(scope="module")
-def served(model, tokens):
+def served(engines, tokens):
     """Prefill, a 3-token chunk and two decode steps of both rows
     through the paged cache; the logits each put() returned."""
-    mcfg, params = model
-    eng = init_inference(params, mcfg, dict(ENGINE), dtype=jnp.float32)
+    eng = engines()
     f, n, k = tokens.astype(np.int32), 50, 3
     got = [eng.put([0, 1], [r[:n - k] for r in f]),
            eng.put([0, 1], [r[n - k:n] for r in f]),
            eng.put([0, 1], [r[n:n + 1] for r in f]),
            eng.put([0, 1], [r[n + 1:n + 2] for r in f])]
+    eng.flush(0), eng.flush(1)
     return eng, got, [n - k - 1, n - 1, n, n + 1]
 
 
@@ -189,7 +177,7 @@ def test_serving_through_the_paged_cache_matches_the_reference(
         model, tokens, served):
     _, params = model
     _, got, pos = served
-    want = _ref_logits(params, tokens)
+    want = F.ref_logits(FAMILY, params, tokens)
     for step, p in enumerate(pos):
         assert np.abs(got[step] - want[:, p]).max() < LOGITS_ATOL, step
 
@@ -201,7 +189,7 @@ def test_a_wrong_model_fails_the_written_tolerance(model, tokens, served,
     training and serving logits are far outside the limit of each."""
     mcfg, params = model
     _, got, pos = served
-    wrong = _ref_logits(params, tokens, mutant)
+    wrong = F.ref_logits(FAMILY, params, tokens, mutant)
     for step, p in enumerate(pos):
         assert np.abs(got[step] - wrong[:, p]).max() > 100 * LOGITS_ATOL
     trained = np.asarray(T.forward(params, jnp.asarray(tokens), mcfg))
@@ -209,9 +197,9 @@ def test_a_wrong_model_fails_the_written_tolerance(model, tokens, served,
 
 
 def test_warmup_and_the_scheduler_decode_the_references_greedy_tokens(
-        model, tokens):
+        model, engines, tokens):
     mcfg, params = model
-    eng = init_inference(params, mcfg, dict(ENGINE), dtype=jnp.float32)
+    eng = engines()
     eng.warmup(widths=[8], footprint=False)
     sched = ServingScheduler(
         eng, ServingSchedulerConfig(max_num_batched_tokens=16,
@@ -224,21 +212,19 @@ def test_warmup_and_the_scheduler_decode_the_references_greedy_tokens(
     for rid, p in zip(rids, prompts):
         out = sched.finished[rid].output
         seq = np.concatenate([p, out]).astype(np.int32)
-        want = _ref_logits(params, seq[None])[0]
+        want = F.ref_logits(FAMILY, params, seq[None])[0]
         assert out == [int(want[len(p) - 1 + j].argmax()) for j in range(4)]
 
 
-def test_tensor_parallel_serving_norms_over_all_heads(model, tokens, served):
+def test_tensor_parallel_serving_norms_over_all_heads(engines, tokens, served):
     """tp=2 splits the heads; the QK-norm statistic still spans all of
     them (a per-shard norm would move the logits by order 1)."""
-    mcfg, params = model
     _, got, _ = served
-    eng = init_inference(
-        params, mcfg, {**ENGINE, "tensor_parallel": {"tp_size": 2}},
-        dtype=jnp.float32)
+    eng = engines(tensor_parallel={"tp_size": 2})
     assert "model" in tuple(
         eng.params["layers"][0]["q_norm_scale"].sharding.spec)
     out = eng.put([0, 1], [r[:47] for r in tokens.astype(np.int32)])
+    eng.flush(0), eng.flush(1)
     assert np.abs(out - got[0]).max() < LOGITS_ATOL
 
 
@@ -262,19 +248,12 @@ def test_qk_norm_inside_a_manual_region_is_refused(model):
 
 # -- the routed block alone ------------------------------------------------
 
-def _block(params, n_tokens):
-    lw = jax.tree.map(lambda a: a[1], params["layers"])
-    h = jnp.asarray(np.random.default_rng(3).normal(size=(n_tokens, 64)),
-                    jnp.float32)
-    return lw, h
-
-
 @pytest.mark.parametrize("path", ["scan", "ragged"])
 def test_the_routed_block_alone_matches_the_reference(model, monkeypatch, path):
     """So that a wrong weight rule fails by a factor, not by a hair."""
     mcfg, params = model
     monkeypatch.setattr(M, "_SCAN_ROWS_PER_EXPERT", ALWAYS[path])
-    lw, h = _block(params, 24)
+    lw, h = F.block(params, 24)
     assert M.expert_path(24, mcfg) == path
     got = np.asarray(M._mlp(h, lw, mcfg))
     with jax.default_matmul_precision("highest"):
@@ -296,7 +275,7 @@ def test_the_routed_block_alone_matches_the_reference(model, monkeypatch, path):
 def test_both_expert_paths_agree_and_the_shape_picks_one(
         model, monkeypatch, pallas_interpret):
     mcfg, params = model
-    lw, h = _block(params, 24)
+    lw, h = F.block(params, 24)
     out = {}
     for path in ("scan", "ragged"):
         monkeypatch.setattr(M, "_SCAN_ROWS_PER_EXPERT", ALWAYS[path])
@@ -332,9 +311,7 @@ def test_both_expert_paths_agree_and_the_shape_picks_one(
     # entry); where they do not: the scan above 2 and under 128
     pub = config_from_hf({k: v for k, v in json.loads(
         PUBLISHED.read_text()).items() if not k.startswith("_")})
-    stack = jax.ShapeDtypeStruct((64, 2048, 1024), jnp.bfloat16)
-    lp = {"w_gate": stack, "w_in": stack,
-          "w_out": jax.ShapeDtypeStruct((64, 1024, 2048), jnp.bfloat16)}
+    lp = F.expert_stacks(64, 2048, 1024)
     widths = (8, 16, 32, 128, 256, 512, 1024)
     assert [M.expert_path(t, pub, lp, True) for t in widths] == [
         "ragged", "stream", "stream", "stream", "stream", "grouped", "ragged"]
@@ -352,6 +329,7 @@ def test_both_expert_paths_agree_and_the_shape_picks_one(
 def test_init_inference_names_the_experts_and_the_path(model):
     mcfg, params = model
     profiler.clear()
+    # its own two: the span of a build (and a dense model's)
     eng = init_inference(params, mcfg, dict(ENGINE), dtype=jnp.float32)
     (span,) = [s for s in profiler.spans() if s.name == "init.inference"]
     assert span.ids["n_experts"] == 8 and span.ids["moe_top_k"] == 3
@@ -407,7 +385,7 @@ def test_the_capacity_training_path_honours_the_weight_rule(model, tokens):
     cap = dataclasses.replace(mcfg, moe_dropless=False,
                               moe_capacity_factor=8.0)
     got = np.asarray(T.forward(params, jnp.asarray(tokens[:, :32]), cap))
-    assert np.abs(got - _ref_logits(params, tokens[:, :32])).max() < LOGITS_ATOL
+    assert np.abs(got - F.ref_logits(FAMILY, params, tokens[:, :32])).max() < LOGITS_ATOL
 
 
 # -- the import --------------------------------------------------------------

@@ -15,26 +15,22 @@ experts top-4 of which this chip holds 4 (experts 4..7), one shared.
 
 import dataclasses
 import json
-import pathlib
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+import _family as F
 import pytest
+from _family import engines, family, model  # noqa: F401 - the contract's fixtures
 
 from benchmarks.reference import pangu_ultra_moe as ref
-from deepspeed_tpu.inference import (
-    ServingScheduler,
-    ServingSchedulerConfig,
-    init_inference,
-)
+from deepspeed_tpu.inference import ServingScheduler, ServingSchedulerConfig
 from deepspeed_tpu.inference import model as M
 from deepspeed_tpu.models import transformer as T
 from deepspeed_tpu.ops.pallas import paged_attention as PA
 from deepspeed_tpu.utils.hf_checkpoint import config_from_hf
 
-ROOT = pathlib.Path(__file__).resolve().parents[1]
-BENCH = ROOT / "benchmarks"
+ROOT, BENCH = F.ROOT, F.BENCH
 HF = {"attention_bias": False, "first_k_dense_replace": 1,
       "hidden_act": "silu", "hidden_size": 64, "intermediate_size": 96,
       "kv_lora_rank": 32, "max_position_embeddings": 256,
@@ -70,39 +66,16 @@ ENGINE = dict(max_seq_len=256, kv_block_size=32, num_kv_blocks=32,
               max_batch_size=16, min_prefill_bucket=32)
 
 
-@pytest.fixture(scope="module")
-def model():
-    mcfg = config_from_hf(HF, use_flash=False)
-    params = T.init(mcfg, jax.random.PRNGKey(1))
-    # spread the logits (the 0.02 init gives nearly flat ones) and make
-    # every norm scale matter (T.init gives ones)
-    params = jax.tree.map(lambda x: x * 4, params)
-
-    def scales(tree, salt):
-        return {k: (1 + 0.3 * jax.random.normal(
-            jax.random.fold_in(jax.random.PRNGKey(salt), i), v.shape)
-            if "scale" in k else v) for i, (k, v) in enumerate(tree.items())}
-
-    top = scales({k: v for k, v in params.items() if k != "layers"}, 2)
-    return mcfg, dict(top, layers=scales(params["layers"], 3))
+# every norm scale matters (T.init gives ones)
+FAMILY = F.Family(
+    hf=HF, ref=ref, atol=LOGITS_ATOL, engine=ENGINE,
+    jig=lambda k, v, key: (1 + 0.3 * jax.random.normal(key, v.shape)
+                           if "scale" in k else v))
 
 
 @pytest.fixture(scope="module")
 def tokens():
     return np.random.default_rng(0).integers(0, HF["vocab_size"], (2, 80))
-
-
-def _top(params):
-    return {k: v for k, v in params.items() if k != "layers"}
-
-
-def _layer_fn(params):
-    return lambda l: jax.tree.map(lambda a: a[l], params["layers"])
-
-
-def _ref_logits(params, toks, mutate=None, hf=HF):
-    return np.asarray(ref.forward_logits(_top(params), _layer_fn(params),
-                                         toks, hf, mutate))
 
 
 # -- the configuration ---------------------------------------------------
@@ -126,15 +99,9 @@ def test_the_cut_builds_the_share_at_published_widths():
     assert shapes["layers"]["w_in"].shape == (4, 8, 7680, 2048)
     assert shapes["dense_w_in"].shape == (1, 7680, 18432)
     # the file's own count: matrices alone, norms apart
-    flat = dict(shapes["layers"], **{k: v for k, v in shapes.items()
-                                     if k != "layers"})
+    flat = F.one_stack(cfg, shapes)
     n = sum(int(np.prod(s.shape)) for k, s in flat.items() if "scale" not in k)
     assert n == 3_409_018_880
-    # ONE homogeneous stack and top-level ARRAYS: what the benchmark's
-    # weight maker and reference_inputs take
-    assert all(not isinstance(v, dict) for k, v in shapes.items()
-               if k != "layers")
-    assert all(v.shape[0] == cfg.n_layers for v in shapes["layers"].values())
 
 
 def test_the_cuts_file_keeps_the_published_widths():
@@ -181,17 +148,17 @@ def test_the_training_forward_refuses_this_family(model, tokens):
 # -- serving against the reference -----------------------------------------
 
 @pytest.fixture(scope="module")
-def served(model, tokens):
+def served(engines, tokens):
     """Whole-prompt prefill (naive form), a 3-token chunk and two
     single-token steps (absorbed form through the latent cache) of both
     rows; the logits each put() returned."""
-    mcfg, params = model
-    eng = init_inference(params, mcfg, dict(ENGINE), dtype=jnp.float32)
+    eng = engines()
     f, n, k = tokens.astype(np.int32), 50, 3
     got = [eng.put([0, 1], [r[:n - k] for r in f]),
            eng.put([0, 1], [r[n - k:n] for r in f]),
            eng.put([0, 1], [r[n:n + 1] for r in f]),
            eng.put([0, 1], [r[n + 1:n + 2] for r in f])]
+    eng.flush(0), eng.flush(1)
     return eng, [np.asarray(g) for g in got], [n - k - 1, n - 1, n, n + 1]
 
 
@@ -202,21 +169,20 @@ def test_serving_through_the_latent_cache_matches_the_reference(
     assert eng.cache.v == [] and len(eng.cache.k) == mcfg.depth == 3
     assert eng.cache.k[0].shape == (33, 32, 128)      # 40 values, one lane tile
     assert eng.kv_bytes_per_token() == 3 * 128 * 4
-    want = _ref_logits(params, tokens)
+    want = F.ref_logits(FAMILY, params, tokens)
     assert np.abs(want).max() > 0.5                   # the logits are not flat
     for step, p in enumerate(pos):
         assert np.abs(got[step] - want[:, p]).max() < LOGITS_ATOL, step
 
 
-def test_the_absorbed_form_agrees_with_the_naive_form(model, tokens, served):
+def test_the_absorbed_form_agrees_with_the_naive_form(engines, tokens, served):
     """The same positions' logits from a whole-prompt prefill (keys and
     values up-projected for every head) and from chunk rows over the
     cache (queries moved into the latent space)."""
-    mcfg, params = model
-    _, got, _ = served
-    eng = init_inference(params, mcfg, dict(ENGINE), dtype=jnp.float32)
+    eng, got, _ = served
     f = tokens.astype(np.int32)
     naive = np.asarray(eng.put([7, 8], [r[:50] for r in f]))   # one prefill
+    eng.flush(7), eng.flush(8)
     assert np.abs(naive - got[1]).max() < LOGITS_ATOL          # 47 + chunk of 3
 
 
@@ -227,7 +193,7 @@ def test_a_wrong_model_fails_the_written_tolerance(model, tokens, served,
     post-sublayer norm, a float8 cache: far outside the limit."""
     _, params = model
     _, got, pos = served
-    wrong = _ref_logits(params, tokens, mutant)
+    wrong = F.ref_logits(FAMILY, params, tokens, mutant)
     for step, p in enumerate(pos):
         assert np.abs(got[step] - wrong[:, p]).max() > 80 * LOGITS_ATOL
 
@@ -237,18 +203,18 @@ def test_a_bf16_reference_fails_the_written_tolerance(model, tokens, served):
     _, got, pos = served
     rounded = jax.tree.map(
         lambda a: a.astype(jnp.bfloat16).astype(jnp.float32), params)
-    wrong = _ref_logits(rounded, tokens)
+    wrong = F.ref_logits(FAMILY, rounded, tokens)
     for step, p in enumerate(pos):
         assert np.abs(got[step] - wrong[:, p]).max() > 50 * LOGITS_ATOL
 
 
 def test_a_shared_table_iteration_of_mixed_rows_matches_the_reference(
-        model, tokens):
+        model, engines, tokens):
     """The scheduler's own program: decode rows and prefill-chunk rows
     of several requests in one call, every chunk's rows on one table.
     Its greedy tokens are the reference's argmax at every position."""
     mcfg, params = model
-    eng = init_inference(params, mcfg, dict(ENGINE), dtype=jnp.float32)
+    eng = engines.fresh()  # its own: what ITS tracker saw after ITS warm-up
     eng.warmup(widths=[8, 16], footprint=False)
     sched = ServingScheduler(
         eng, ServingSchedulerConfig(max_num_batched_tokens=16,
@@ -268,16 +234,15 @@ def test_a_shared_table_iteration_of_mixed_rows_matches_the_reference(
     for rid, p in zip(rids, prompts):
         out = sched.finished[rid].output
         seq = np.concatenate([p, out]).astype(np.int32)
-        want = _ref_logits(params, seq[None])[0]
+        want = F.ref_logits(FAMILY, params, seq[None])[0]
         assert out == [int(want[len(p) - 1 + j].argmax()) for j in range(5)]
 
 
-def test_the_scheduler_counts_a_latent_step_by_the_latent_walks_rule(model):
+def test_the_scheduler_counts_a_latent_step_by_the_latent_walks_rule(engines):
     """mla_grouped_rows / kv_block_reads of a dispatched step are
     latent_walk_reads of its host arrays (at sixteen heads a tile is
     eight rows), and the K/V rule's kv_grouped_rows stays 0."""
-    mcfg, params = model
-    eng = init_inference(params, mcfg, dict(ENGINE), dtype=jnp.float32)
+    eng = engines.fresh()  # its own: the test gives it sixteen heads
     sched = ServingScheduler(eng, ServingSchedulerConfig(warmup=False), seed=0)
     eng.cfg = dataclasses.replace(eng.cfg, n_heads=16)
     NB = eng.config.blocks_per_seq
@@ -392,7 +357,8 @@ def test_a_tile_of_one_tables_rows_is_visited_together(
             PA._latent_attention.clear_cache()
 
     got = walk(query_rows)
-    want = np.asarray(PA.paged_latent_attention_xla(
+    # (the oracle ONE program: op by op it is twenty small compiles)
+    want = np.asarray(jax.jit(PA.paged_latent_attention_xla, static_argnums=4)(
         q, pool, jnp.asarray(tables), jnp.asarray(ctx), V))
     assert np.abs(got - want).max() < 1e-5
     assert np.all(got[ctx == 0] == 0)
@@ -415,21 +381,21 @@ def test_a_tile_of_one_tables_rows_is_visited_together(
         np.testing.assert_array_equal(got, alone)
 
 
-def test_the_kernel_engine_agrees_with_the_oracle_engine(model, tokens, served,
-                                                         pallas_interpret):
-    mcfg, params = model
+def test_the_kernel_engine_agrees_with_the_oracle_engine(
+        engines, tokens, served, pallas_interpret):
     _, got, _ = served
-    eng = init_inference(params, mcfg, dict(ENGINE), dtype=jnp.float32)
+    eng = engines()
     assert eng.resolved_impl == "pallas"
     f, n, k = tokens.astype(np.int32), 50, 3
     out = [eng.put([0, 1], [r[:n - k] for r in f]),
            eng.put([0, 1], [r[n - k:n] for r in f]),
            eng.put([0, 1], [r[n:n + 1] for r in f])]
+    eng.flush(0), eng.flush(1)
     for a, b in zip(out, got):
         assert np.abs(np.asarray(a) - b).max() < LOGITS_ATOL
 
 
-def test_a_context_longer_than_the_walks_buffers_is_refused(model):
+def test_a_context_longer_than_the_walks_buffers_is_refused(model, engines):
     pool = jax.ShapeDtypeStruct((8, 128, 640), jnp.bfloat16)
     # the two buffer sets beside a tile's scratch: 132 blocks at the most
     assert PA.latent_walk_fits(72, pool) and PA.latent_walk_fits(132, pool)
@@ -439,23 +405,16 @@ def test_a_context_longer_than_the_walks_buffers_is_refused(model):
         8, jax.ShapeDtypeStruct((8, 128, 576), jnp.bfloat16))
     # the engine says so when it is built, whatever the call would do
     mcfg, params = model
-    long = dict(ENGINE, kv_block_size=128, max_seq_len=512 * 128,
+    long = dict(kv_block_size=128, max_seq_len=512 * 128,
                 num_kv_blocks=520, decode_impl="pallas")
+    longer = dataclasses.replace(mcfg, max_seq=512 * 128), params
     with pytest.raises(ValueError, match="latent walk's VMEM"):
-        init_inference(params, dataclasses.replace(mcfg, max_seq=512 * 128),
-                       long, dtype=jnp.float32)
-    init_inference(params, dataclasses.replace(mcfg, max_seq=512 * 128),
-                   dict(long, decode_impl="xla"), dtype=jnp.float32)
+        engines.fresh(model=longer, **long)
+    # another configuration
+    engines.fresh(model=longer, **dict(long, decode_impl="xla"))
 
 
 # -- the routed block and the share ----------------------------------------
-
-def _block(params, n_tokens):
-    lw = jax.tree.map(lambda a: a[1], params["layers"])
-    h = jnp.asarray(np.random.default_rng(3).normal(size=(n_tokens, 64)),
-                    jnp.float32)
-    return lw, h
-
 
 def test_the_held_share_scans_its_experts_whatever_the_rows(model):
     mcfg, _ = model
@@ -465,9 +424,7 @@ def test_the_held_share_scans_its_experts_whatever_the_rows(model):
     # where kernels run, the cell's held experts (8 of 7680 x 2048) take
     # the one streamed pass at every width its buffers fit, and the
     # scan beyond (a whole prompt of the logits check)
-    stack = jax.ShapeDtypeStruct((8, 7680, 2048), jnp.bfloat16)
-    lp = {"w_gate": stack, "w_in": stack,
-          "w_out": jax.ShapeDtypeStruct((8, 2048, 7680), jnp.bfloat16)}
+    lp = F.expert_stacks(8, 7680, 2048)
     # (never the grouped entry, whatever the rows: how many pairs reach
     # the held experts is known on the device alone)
     assert [M.expert_path(t, mcfg, lp, True)
@@ -477,7 +434,7 @@ def test_the_held_share_scans_its_experts_whatever_the_rows(model):
 
 def test_the_routed_block_alone_matches_the_reference(model):
     mcfg, params = model
-    lw, h = _block(params, 24)
+    lw, h = F.block(params, 24)
     got = np.asarray(M._mlp(h, lw, mcfg))
     with jax.default_matmul_precision("highest"):
         routed, shared, _ = ref.moe_parts(h, lw, HF)
@@ -494,7 +451,7 @@ def test_the_shares_add_up_to_the_uncut_layer(model):
     told which it holds), with the shared expert counted once, are what
     the uncut reference gives for the whole layer."""
     mcfg, params = model
-    lw, h = _block(params, 24)
+    lw, h = F.block(params, 24)
     rng = jax.random.PRNGKey(9)
     every = {n: jax.random.normal(jax.random.fold_in(rng, i),
                                   (16,) + lw[n].shape[1:]) * 0.08

@@ -98,7 +98,9 @@ class TestSpans:
         for i in range(profiler.HOT_SPANS + 10):
             profiler.record("hot", i, i + 1)
         profiler.disable()
-        recs = profiler.spans()
+        # (a full collection of over 5 ms inside the loop is a kept span of
+        # its own once any test of this process has hooked the collector)
+        recs = [r for r in profiler.spans() if r.name != "host.gc"]
         # the buffer bound holds, and hot spans never evict a kept one
         assert len(recs) == profiler.HOT_SPANS + 1
         kept = [r for r in recs if r.name == "setup.phase"]
@@ -578,8 +580,20 @@ class TestServingLoop:
         c, ids = sched.counters, span.ids
         assert c["stall_iterations"] >= 1
         assert c["stall_s"] >= at["asked"] * 0.9
-        # the readback wait of that iteration was no longer than usual
-        assert c["stall_readback_s"] < 0.1 * c["stall_s"]
+        # the readback wait of that iteration was no longer than usual.
+        # The counters sum EVERY stalled iteration, and on a loaded
+        # machine another one stalls too: each is a kept span, so the
+        # counters are held to the spans, and another iteration's share
+        # to no more than its own readback and its own excess
+        stalls = [r.ids for r in kept("sched.slow_iteration")
+                  if "stall" in r.ids["rule"].split("+")]
+        assert c["stall_iterations"] == len(stalls)
+        assert c["stall_s"] == pytest.approx(
+            sum(i["excess_ms"] for i in stalls) * 1e-3)
+        others = sum(min(i["excess_ms"], i["readback_ms"]) for i in stalls
+                     if i["iteration"] != 20) * 1e-3
+        assert c["stall_readback_s"] - others < 0.1 * ids["excess_ms"] * 1e-3
+        assert ids["readback_ms"] < 0.1 * ids["tick_ms"]
         assert "stall" in ids["rule"].split("+")
         assert max(PHASES, key=lambda p: ids[f"{p}_ms"]) == "tick"
         assert ids["tick_ms"] >= at["asked"] * 1e3

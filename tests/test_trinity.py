@@ -22,16 +22,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from _trinity import (GRAD_RTOL, LOSS_ATOL, ref, seeded, tiny, tiny3,
-                      tokens_of)
+from _trinity import (GRAD_RTOL, LOSS_ATOL, highest, ref,  # noqa: F401
+                      seeded, tiny, tiny3, tokens_of)
 from benchmarks import afmoe_audit
 from deepspeed_tpu.models import transformer as T
 from deepspeed_tpu.utils.hf_checkpoint import config_from_hf
-
-@pytest.fixture(scope="module")
-def highest():
-    with jax.default_matmul_precision("highest"):
-        yield
 
 
 @pytest.fixture(scope="module")
@@ -213,7 +208,9 @@ def test_bf16_compute_stays_in_a_band_of_the_float32_loss(small3):
     REF_LOSS_ATOL), a fresh model's loss being ln 128 = 4.85."""
     hf, mcfg, params, toks, (loss32, _) = small3
     p16 = jax.tree.map(lambda a: a.astype(jnp.bfloat16), params)
-    got = T.make_loss_fn(mcfg, loss_chunks=1)(p16, {"tokens": toks}, None)
+    # (the program's side ONE program: op by op it is ninety small compiles)
+    got = jax.jit(T.make_loss_fn(mcfg, loss_chunks=1))(
+        p16, {"tokens": toks}, None)
     top = {k: v for k, v in p16.items() if k != "layers"}
     want = ref.loss(top, lambda l: jax.tree.map(lambda a: a[l], p16["layers"]),
                     toks, hf)

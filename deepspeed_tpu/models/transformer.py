@@ -1537,9 +1537,9 @@ def aux_width(cfg: TransformerConfig) -> int:
     (load-balance l_aux, router z-loss), and behind them, where the
     step's state reads it (cfg.carries_census), the layer's census:
     the tokens that CHOSE each of the n_experts experts, held here or
-    not, and the held pairs the wire dropped (0), as float32 (exact to
-    2**24 tokens a layer a micro-batch)."""
-    return 2 + (cfg.n_experts + 1 if cfg.carries_census else 0)
+    not, the held pairs the wire dropped (0) and the chunks of its list
+    that ran, as float32 (exact to 2**24 tokens a layer a micro-batch)."""
+    return 2 + (cfg.n_experts + 2 if cfg.carries_census else 0)
 
 
 def _mlp_delta(h, lp, cfg: TransformerConfig, rng=None, dense: bool = False):
@@ -1713,7 +1713,8 @@ def _moe_mlp_delta(h, lp, cfg: TransformerConfig, rng=None):
     if cfg.carries_census:
         aux = jnp.concatenate([
             aux, res.counts.astype(jnp.float32),
-            jnp.asarray(res.dropped, jnp.float32)[None]])
+            jnp.asarray(res.dropped, jnp.float32)[None],
+            jnp.asarray(res.chunks_run, jnp.float32)[None]])
     return _dropout(out, cfg.dropout, rng), aux
 
 
@@ -2054,9 +2055,9 @@ def forward_hidden(
         if cfg.carries_census:
             # [n_layers, n_experts]: the tokens that chose each expert
             # in each routed layer (every chosen pair, held here or not)
-            losses["moe_census"] = jnp.round(aux[:, 2:-1]).astype(jnp.int32)
-            losses["moe_pairs_dropped"] = jnp.round(
-                jnp.sum(aux[:, -1])).astype(jnp.int32)
+            losses["moe_census"] = jnp.round(aux[:, 2:-2]).astype(jnp.int32)
+            losses["moe_pairs_dropped"], losses["moe_chunks_run"] = (
+                jnp.round(jnp.sum(aux[:, -2:], axis=0)).astype(jnp.int32))
         return out, losses
     return out
 
@@ -2141,7 +2142,8 @@ def make_loss_fn(cfg: TransformerConfig, loss_chunks: int = 8,
     engine built with has_aux, aux the flat dict of what the step reads
     beside the loss: `moe_census` [n_layers, n_experts] int32 where the
     model hands it out (cfg.carries_census; step_state_rule reads it)
-    and `moe_pairs_dropped`, the held pairs its wire did not compute."""
+    and `moe_pairs_dropped`, the held pairs its wire did not compute,
+    beside `moe_chunks_run`, the chunks of its list that ran."""
 
     def loss_fn(params, batch, rng):
         tokens = batch["tokens"]
@@ -2171,7 +2173,8 @@ def make_loss_fn(cfg: TransformerConfig, loss_chunks: int = 8,
                 loss = loss + cfg.moe_z_loss_coef * aux["moe_z_loss"]
         if has_aux:
             return loss, {k: v for k, v in aux.items()
-                          if k in ("moe_census", "moe_pairs_dropped")}
+                          if k in ("moe_census", "moe_pairs_dropped",
+                                   "moe_chunks_run")}
         return loss
 
     # what the engine's span `train.init.shapes` says of this model
@@ -2219,7 +2222,10 @@ def step_state_rule(cfg: TransformerConfig):
     `moe_pairs_routed` (tokens x k x routed layers), `moe_pairs_held`
     (of them, on the experts held here), `moe_rows_per_expert_max` /
     `_min` (over the held experts of every layer), `moe_pairs_dropped`
-    (held pairs the wire did not compute: 0) and `expert_bias_abs_max`.
+    (held pairs the wire did not compute: 0), `moe_chunks_run` (chunks of
+    the held wire's list that ran: one a routed layer while its held
+    pairs fit the first, each three sums of a trace) and
+    `expert_bias_abs_max`.
     None for a model that hands out no census."""
     if not cfg.carries_census:
         return None
@@ -2252,7 +2258,7 @@ def step_state_rule(cfg: TransformerConfig):
         is_state=lambda path: path.endswith("['expert_bias']"),
         update=update, scope=EXPERT_BIAS_UPDATE,
         counters={"moe_pairs_routed": "sum", "moe_pairs_held": "sum",
-                  "moe_pairs_dropped": "sum",
+                  "moe_pairs_dropped": "sum", "moe_chunks_run": "sum",
                   "moe_rows_per_expert_max": "max",
                   "moe_rows_per_expert_min": "min",
                   "expert_bias_abs_max": "last"},

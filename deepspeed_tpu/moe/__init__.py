@@ -7,9 +7,12 @@ from .dropless import (  # noqa: F401
     expert_counts,
     grouped_mm,
     router_z_loss,
+    rows_of,
     sigmoid_topk_gating,
     sort_by_expert,
     sort_pairs,
+    sum_to_tokens,
+    token_order,
 )
 from .sharded_moe import (  # noqa: F401
     compute_capacity,

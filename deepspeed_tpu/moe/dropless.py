@@ -38,7 +38,10 @@ v5e the grouped product's time follows the rows INSIDE its groups, with
 a smaller charge a buffer row, and it leaves the rows OUTSIDE them
 unwritten, forward and backward (PERF.md §6, PR 55: 5.4 ms forward +
 backward for 16,384 live rows of E 2048 x F 1024 in a buffer of as
-many, 10.8 ms in one of 131,072, 37.7 ms with all of those live).
+many, 10.8 ms in one of 131,072, 37.7 ms with all of those live), and
+its weights' gradient does not read them (PR 61: the same bits with
+zeros, data or 100s there). Inside a chunk no row is moved by a
+scatter: rows_of and sum_to_tokens, each the other's transpose.
 
 Gate math runs in fp32 regardless of compute dtype (the reference
 casts at TopKGate.forward) and generalizes to any top_k <= n_experts:
@@ -72,6 +75,9 @@ class DroplessOut:
     # held pairs the wire did NOT compute (scalar int32): 0 by the
     # buffer's bound, counted all the same (the held wire alone)
     dropped: Any = 0
+    # chunks of the held wire's list that ran (scalar int32): 1 while
+    # the held pairs fit the first, which runs at any load
+    chunks_run: Any = 0
 
 
 def router_z_loss(logits) -> jnp.ndarray:
@@ -203,6 +209,65 @@ def _sort_pairs_bwd(order, cts):
 sort_pairs.defvjp(_sort_pairs_fwd, _sort_pairs_bwd)
 
 
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass(frozen=True)
+class TokenOrder:
+    """The rows of one chunk of the held wire's list, and the same rows
+    in TOKEN order: what rows_of and sum_to_tokens move rows by."""
+
+    src: Any    # [C] int32: the token of each row
+    live: Any   # [C] bool: the row is a held pair's
+    ids: Any    # [C] int32: src ascending, the dead rows (n_tokens) last
+    perm: Any   # [C] int32: the row each of ids came from
+    n_tokens: int = dataclasses.field(metadata=dict(static=True))
+
+
+def token_order(src, live, n_tokens: int) -> TokenOrder:
+    """ONE stable sort of a chunk's token ids with the row's position as
+    payload, a dead row keyed n_tokens so that it sorts behind every
+    token (sort_pairs' `lax.sort`; 0.03 ms for 32,768 ids)."""
+    ids, perm = jax.lax.sort(
+        (jnp.where(live, src, n_tokens),
+         jax.lax.iota(jnp.int32, src.shape[0])), num_keys=1, is_stable=True)
+    return TokenOrder(src, live, ids, perm, n_tokens)
+
+
+@jax.custom_vjp
+def rows_of(tokens, order: TokenOrder):
+    """tokens [T, E] -> the chunk's rows [C, E], a row gather: a live
+    row is its token's; a dead row is nobody's (it lies in no group of
+    the products, which neither read it nor write its cotangent), so it
+    holds whatever the gather put there, as a constant. The transpose
+    is therefore sum_to_tokens, which leaves the dead rows out, where
+    jax would derive a row scatter-add of every row (twelve times the
+    gather's time on a v5e, PERF.md section 6, PR 59) behind a select
+    of the dead rows at either end."""
+    return tokens[order.src]
+
+
+@jax.custom_vjp
+def sum_to_tokens(rows, order: TokenOrder):
+    """rows [C, E] -> [T, E]: a token's live rows summed, a dead row
+    left out whatever it holds (an Inf there must reach no token);
+    `segment_sum(where(live, rows, 0), src, T)` with no scatter: the
+    rows are gathered into token order, where a tile of tokens owns one
+    contiguous range of them, and summed by a banded one-hot product
+    (ops/pallas/token_sum.py), in float32 with one rounding at the end
+    (the bits a v5e's own bf16 scatter-add gives: PERF.md section 6,
+    PR 61). Its transpose is rows_of with the dead rows zero."""
+    from ..ops.pallas.token_sum import token_tile_sum
+
+    return token_tile_sum(rows[order.perm], order.ids, order.n_tokens)
+
+
+rows_of.defvjp(lambda tokens, order: (rows_of(tokens, order), order),
+               lambda order, ct: (sum_to_tokens(ct, order), None))
+sum_to_tokens.defvjp(
+    lambda rows, order: (sum_to_tokens(rows, order), order),
+    lambda order, ct: (jnp.where(order.live[:, None], rows_of(ct, order), 0),
+                       None))
+
+
 def sort_by_expert(expert_idx) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Stable-sort the flat assignment list by expert id.
 
@@ -304,21 +369,25 @@ def _held_wire(tokens, idx, weights, counts, held, w_in, w_out, w_gate,
     expert (stable: a pure function of the routing); the list is walked
     in CHUNKS of 2 T rows (T where min(k, count) is odd), each one
     gather, one grouped product a projection over the part of every
-    expert's run that lies in the chunk, one weighted segment sum back
-    to the tokens. The FIRST chunk always runs; a later one that starts
-    past the last held pair is skipped at run time (`lax.cond`): an
+    expert's run that lies in the chunk, one weighted sum back to the
+    tokens in token order (sum_to_tokens). The FIRST chunk always runs;
+    a later one that starts past the last held pair is skipped at run
+    time (`lax.cond`): an
     even router's T pairs a layer, and up to twice as many, take ONE
     chunk, the worst skew all of them, and none is ever dropped. A
-    chunk costs its rows whether they are live or not, ~15 ms a layer
-    of a 551 ms step (PERF.md §5, PR 59): three row scatter-adds of 2 T
-    rows of E, 2.6-2.9 ms each (the segment sum, forward and recomputed,
-    and the transpose of the row gather), the selects of dead rows 2.6,
-    the products' elementwise and the weighted sum 2.7, the row gathers
-    0.21 each (1.1 where the compiler leaves the tokens out of its fast
-    memory), so a layer whose load crosses 2 T pays a second chunk (with
-    chunks of T rows, the even load itself, a step took one chunk or two
-    a layer by the batch's luck), and a layer that holds NO pair pays the
-    first all
+    chunk costs its rows whether they are live or not, ~9.5 ms a layer
+    of a 517 ms step (PERF.md §5, PR 61), and moves no row by a scatter:
+    the rows come by a row gather (rows_of) and go back, forward,
+    recomputed and as that gather's transpose, by sum_to_tokens: a sort
+    of the chunk's ids by token 0.02 ms, a row gather into that order
+    1.1 (its 128 MiB operand is out of the compiler's fast memory) and
+    a banded one-hot product 0.12-0.45 by the live rows, where the row
+    scatter-add it replaced took 2.6-2.9 and the selects of dead rows
+    around it 0.4 each (scripts/wire_bench.py); the row gathers are 5
+    of the 9.5, the products' elementwise and the weighted sum ~3. So a
+    layer whose load crosses 2 T pays a second chunk (with chunks of T
+    rows, the even load itself, a step took one chunk or two a layer by
+    the batch's luck), and a layer that holds NO pair pays the first all
     the same: a chip's step takes the time of its shape at any load up
     to 2 T a layer, as the steps of the job's other chips do. A job
     that trains has no such layer; a run of one chip's cut alone has,
@@ -333,7 +402,8 @@ def _held_wire(tokens, idx, weights, counts, held, w_in, w_out, w_gate,
     [X] the full census. Outside the chunks nothing is indexed by pair:
     a pair's slot and weight ride the sort (sort_pairs), its held flag is
     the sorted key under `count`. Returns (out [T, E], held pairs NOT
-    computed, by the groups of the products that ran: 0)."""
+    computed, by the groups of the products that ran: 0; the chunks
+    that ran)."""
     start, count = held
     T, K = idx.shape
     bound = held_rows_bound(T, K, count)
@@ -362,9 +432,10 @@ def _held_wire(tokens, idx, weights, counts, held, w_in, w_out, w_gate,
         past the last held pair lies in no group: what a grouped product
         leaves there, forward or backward, is whatever the buffer held
         (`ragged_dot` skips it: that is why its time follows the live
-        rows), so such a row is SELECTED away at both ends, where it
-        comes in and where it goes out: a weight of 0 would turn an Inf
-        there into a NaN of every token's."""
+        rows), so such a row is LEFT OUT where rows go back to tokens,
+        forward (the combine) and backward (the gather's transpose):
+        sum_to_tokens selects it to zero, since a weight of 0 would turn
+        an Inf there into a NaN of every token's."""
         lo = c * C
 
         def run():
@@ -372,33 +443,32 @@ def _held_wire(tokens, idx, weights, counts, held, w_in, w_out, w_gate,
                 # the part of each held expert's run inside [lo, lo + C)
                 part = jnp.clip(jnp.minimum(ends, lo + C)
                                 - jnp.maximum(ends - held_counts, lo), 0, C)
-                rows = jnp.where(live_c[:, None], tokens[src_c], 0)
+                order = token_order(src_c, live_c, T)
+                rows = rows_of(tokens, order)
             with jax.named_scope("moe_experts"):
                 ys = _expert_mlp_sorted(rows, None, part, w_in, w_out,
                                         w_gate, None, None, act, impl)
             with jax.named_scope("moe_combine"):
-                return jax.ops.segment_sum(
-                    jnp.where(live_c[:, None], ys * wf_c[:, None], 0),
-                    src_c, num_segments=T), jnp.sum(part)
+                return (sum_to_tokens(ys * wf_c[:, None], order),
+                        jnp.sum(part), jnp.int32(1))
 
         return jax.lax.cond(
             (c == 0) | (lo < n_held), run,
-            lambda: (jnp.zeros_like(tokens), jnp.int32(0)))
+            lambda: (jnp.zeros_like(tokens), jnp.int32(0), jnp.int32(0)))
 
     # (the sum rides AROUND the checkpointed chunk: inside it, every
     # chunk's incoming sum would be kept for the backward)
     def add(carry, xs):
-        y, rows_run = chunk(*xs)
-        return (carry[0] + y, carry[1] + rows_run), None
+        return jax.tree.map(jnp.add, carry, chunk(*xs)), None
 
-    (out, computed), _ = jax.lax.scan(
-        add, (jnp.zeros_like(tokens), jnp.int32(0)),
+    (out, computed, chunks_run), _ = jax.lax.scan(
+        add, (jnp.zeros_like(tokens), jnp.int32(0), jnp.int32(0)),
         (jnp.arange(n_chunks, dtype=jnp.int32), src, wf,
          live.reshape(n_chunks, C)))
     # held pairs no chunk multiplied: a wrong gradient if ever above 0,
     # so it is counted from the groups of the products that RAN (a
     # chunk skipped wrongly, or a run cut at a chunk's edge, shows here)
-    return out, n_held - computed
+    return out, n_held - computed, chunks_run
 
 
 def _a2a_wire(tokens, idx, weights, ep_size, w_in, w_out, w_gate,
@@ -535,10 +605,12 @@ def dropless_moe_ffn(
         if b_in is not None or b_out is not None or ep_size > 1:
             raise NotImplementedError(
                 "a held share of experts with biases or an expert axis")
-        out, dropped = _held_wire(tokens, idx, weights, counts, held, w_in,
-                                  w_out, w_gate, act, impl)
+        out, dropped, chunks_run = _held_wire(
+            tokens, idx, weights, counts, held, w_in, w_out, w_gate, act,
+            impl)
         return DroplessOut(out=out, l_aux=l_aux, z_loss=z_loss,
-                           counts=counts, dropped=dropped)
+                           counts=counts, dropped=dropped,
+                           chunks_run=chunks_run)
     elif ep_size > 1 and tokens.shape[0] % ep_size == 0:
         out = _a2a_wire(tokens, idx, weights, ep_size, w_in, w_out,
                         w_gate, b_in, b_out, act, shard)

@@ -21,6 +21,7 @@ import pytest
 
 import deepspeed_tpu as ds
 from deepspeed_tpu.models import transformer as T
+from deepspeed_tpu.moe import dropless
 from deepspeed_tpu.moe import (
     chosen_scores,
     compute_capacity,
@@ -30,11 +31,15 @@ from deepspeed_tpu.moe import (
     expert_counts,
     grouped_mm,
     router_z_loss,
+    rows_of,
     sigmoid_topk_gating,
     sort_by_expert,
     sort_pairs,
+    sum_to_tokens,
+    token_order,
     topk_gating,
 )
+from deepspeed_tpu.ops.pallas import interpret_kernels, token_sum
 
 VOCAB = 128
 
@@ -240,12 +245,23 @@ class TestDroplessWires:
                                    atol=1e-6)
 
 
-def _element_indexed(jaxpr, n_pairs):
-    """The gathers and scatters of `jaxpr` (bodies of cond / scan /
-    checkpoint / custom rules included) that move ONE element an index
-    over n_pairs indices or more: (primitive, indices, scope)."""
-    found = []
+def _eqns(jaxpr):
+    """Every equation of `jaxpr`, the bodies of cond / scan / checkpoint
+    / custom rules included."""
     for eqn in jaxpr.eqns:
+        yield eqn
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (tuple, list)) else (v,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _eqns(sub)
+
+
+def _element_indexed(jaxpr, n_pairs):
+    """The gathers and scatters of `jaxpr` that move ONE element an
+    index over n_pairs indices or more: (primitive, indices, scope)."""
+    found = []
+    for eqn in _eqns(jaxpr):
         name = eqn.primitive.name
         if name == "gather" or name.startswith("scatter"):
             n_idx = int(np.prod(eqn.invars[1].aval.shape[:-1]))
@@ -253,12 +269,18 @@ def _element_indexed(jaxpr, n_pairs):
                 else eqn.invars[2].aval
             if moved.size == n_idx and n_idx >= n_pairs:
                 found.append((name, n_idx, str(eqn.source_info.name_stack)))
-        for v in eqn.params.values():
-            for sub in v if isinstance(v, (tuple, list)) else (v,):
-                sub = getattr(sub, "jaxpr", sub)
-                if hasattr(sub, "eqns"):
-                    found += _element_indexed(sub, n_pairs)
     return found
+
+
+def _row_scatters(jaxpr, n_rows):
+    """The scatters of `jaxpr` whose updates are n_rows ROWS or more:
+    (primitive, updates' shape, scope)."""
+    return [(eqn.primitive.name, eqn.invars[2].aval.shape,
+             str(eqn.source_info.name_stack))
+            for eqn in _eqns(jaxpr)
+            if eqn.primitive.name.startswith("scatter")
+            and eqn.invars[2].aval.ndim > 1
+            and eqn.invars[2].aval.shape[0] >= n_rows]
 
 
 class TestPairsRideTheSort:
@@ -373,14 +395,218 @@ class TestPairsRideTheSort:
                 choice_bias=jnp.zeros(self.X) if bias else None)
             return jnp.sum(res.out ** 2) + res.l_aux + res.z_loss, res.counts
 
-        jaxpr = jax.make_jaxpr(jax.value_and_grad(
-            loss, argnums=(0, 1, 2, 3, 4), has_aux=True))(
-                toks, router, w["w_in"], w["w_out"], w["w_gate"])
+        # (traced as where kernels run: elsewhere sum_to_tokens falls
+        # back to XLA's sorted segment sum)
+        with interpret_kernels():
+            jaxpr = jax.make_jaxpr(jax.value_and_grad(
+                loss, argnums=(0, 1, 2, 3, 4), has_aux=True))(
+                    toks, router, w["w_in"], w["w_out"], w["w_gate"])
         assert _element_indexed(jaxpr.jaxpr, self.T_ * self.K) == []
+        # and inside a held chunk no ROW is moved by a scatter either
+        # (2.6-2.9 ms for 32,768 rows of 2,048 on a v5e, twelve times
+        # their gather: PERF.md §6, PR 61); the whole wire keeps its
+        # segment sum, which the same walk sees
+        assert (_row_scatters(jaxpr.jaxpr, self.T_) == []) == (
+            held is not None)
         # (the walk does see such an op where there is one)
         probe = jax.make_jaxpr(jax.grad(lambda v: jnp.sum(
             v[jnp.arange(self.T_ * self.K) % self.T_])))(toks[:, 0])
         assert _element_indexed(probe.jaxpr, self.T_ * self.K)
+
+
+class TestRowsGoBackInTokenOrder:
+    """Inside a chunk of the held wire no row is moved by a scatter:
+    rows_of and sum_to_tokens are each the other's transpose, and the
+    sum is a sort, a row gather and a banded one-hot product
+    (ops/pallas/token_sum.py) that equals the segment sum it replaced,
+    dead rows left out whatever they hold."""
+
+    @staticmethod
+    def _chunk(name):
+        """(src, live, rows, n_tokens) of a chunk that crosses the
+        edges of 16-row blocks and 8-token tiles."""
+        r = np.random.default_rng(7)
+        T_, C = 37, 100  # neither a multiple of a tile
+        src = r.integers(0, T_, C)
+        live = r.random(C) < 0.6
+        if name == "a_token_holds_many_rows":
+            src[10:18] = 21
+            live[10:18] = True
+        elif name == "a_run_straddles_two_blocks":
+            # tokens 8..15 (one tile) own rows 12..19 of the sorted list:
+            # 12 rows under them, 8 of theirs, across the edge at 16
+            src = np.concatenate([np.repeat(np.arange(6), 2),
+                                  np.arange(8, 16), np.full(C - 20, 30)])
+            live = np.arange(C) < 24
+        elif name == "most_tokens_hold_no_row":
+            live &= src > 30
+        elif name == "every_row_dead":
+            live[:] = False
+        elif name == "every_row_live":
+            live[:] = True
+        elif name == "tiles_divide_it":
+            T_, C = 32, 96
+            src, live = src[:C] % T_, live[:C]
+        rows = r.normal(size=(C, 24)).astype(np.float32)
+        if name == "dead_rows_hold_inf_and_nan":
+            rows[~live] = np.where(r.random((int((~live).sum()), 24)) < 0.5,
+                                   np.inf, np.nan)
+        return (jnp.asarray(src, jnp.int32), jnp.asarray(live),
+                jnp.asarray(rows), T_)
+
+    @pytest.mark.parametrize("lane", ["no_kernel", "kernel"])
+    @pytest.mark.parametrize("name", [
+        "edges", "a_token_holds_many_rows", "a_run_straddles_two_blocks",
+        "most_tokens_hold_no_row", "every_row_dead", "every_row_live",
+        "tiles_divide_it", "dead_rows_hold_inf_and_nan"])
+    def test_the_banded_sum_is_the_segment_sum(self, name, lane):
+        src, live, rows, T_ = self._chunk(name)
+        want = jax.ops.segment_sum(jnp.where(live[:, None], rows, 0), src,
+                                   num_segments=T_)
+        o = token_order(src, live, T_)
+        np.testing.assert_array_equal(o.ids[:-1] <= o.ids[1:], True)
+        np.testing.assert_array_equal(o.ids, jnp.where(live, src, T_)[o.perm])
+        if lane == "no_kernel":  # what a backend without Mosaic runs
+            assert not token_sum.kernels_runnable()
+            got = token_sum.token_tile_sum(rows[o.perm], o.ids, T_)
+        else:  # the Pallas body, interpreted
+            got = token_sum._tile_sum(rows[o.perm], o.ids, T_, 16, 8, True)
+        assert got.shape == want.shape and got.dtype == rows.dtype
+        np.testing.assert_allclose(got, want, rtol=0, atol=2e-6)
+        # at the package's own tiles, through the public entry
+        np.testing.assert_allclose(sum_to_tokens(rows, o), want, rtol=0,
+                                   atol=2e-6)
+
+    def test_bf16_rows_are_summed_in_float32_and_rounded_once(self):
+        r = np.random.default_rng(8)
+        T_, C = 16, 128  # eight rows a token: a bf16 running sum drifts
+        src = jnp.asarray(np.arange(C) % T_, jnp.int32)
+        rows = jnp.asarray(r.normal(size=(C, 128)), jnp.bfloat16)
+        o = token_order(src, jnp.ones(C, bool), T_)
+        want = jax.ops.segment_sum(rows.astype(jnp.float32), src,
+                                   num_segments=T_).astype(jnp.bfloat16)
+        for got in (token_sum.token_tile_sum(rows[o.perm], o.ids, T_),
+                    token_sum._tile_sum(rows[o.perm], o.ids, T_, 32, 8,
+                                        True)):
+            assert got.dtype == jnp.bfloat16
+            np.testing.assert_array_equal(got, want)
+
+    def test_a_width_over_a_slab_is_walked_in_slabs(self, monkeypatch):
+        src, live, rows, T_ = self._chunk("edges")
+        rows = jnp.tile(rows, (1, 16))  # E 384: three slabs of 128
+        monkeypatch.setattr(token_sum, "_E_TILE", 128)
+        o = token_order(src, live, T_)
+        got = token_sum._tile_sum.__wrapped__(rows[o.perm], o.ids, T_, 16, 8,
+                                              True)
+        np.testing.assert_allclose(got, jax.ops.segment_sum(
+            jnp.where(live[:, None], rows, 0), src, num_segments=T_),
+            rtol=0, atol=2e-6)
+
+    @pytest.mark.parametrize("n_rows,n_tokens,want", [
+        (32768, 16384, 191), (100, 37, 1), (257, 256, 2), (16, 8, 1)])
+    def test_pairs_bound(self, n_rows, n_tokens, want):
+        assert token_sum.pairs_bound(n_rows, n_tokens) == want
+
+    @pytest.mark.parametrize("name", ["edges", "every_row_live",
+                                      "a_run_straddles_two_blocks"])
+    def test_the_schedule_visits_every_tile_and_every_live_pair(self, name):
+        src, live, _, T_ = self._chunk(name)
+        o = token_order(src, live, T_)
+        rb, tb = 16, 8
+        nb, nt = -(-src.shape[0] // rb), -(-T_ // tb)
+        ids = np.pad(np.asarray(o.ids), (0, nb * rb - src.shape[0]),
+                     constant_values=T_)
+        blk, tile, flags, n_live = map(np.asarray, token_sum._schedule(
+            jnp.asarray(ids), T_, nb, nt, rb, tb))
+        assert len(blk) == nb + nt - 1 and n_live[0] == int(live.sum())
+        real = flags != 0
+        # every tile once first and once last, in order; a step with
+        # rows for every (block, tile) pair that shares a live row
+        assert list(tile[(flags & 1) != 0]) == list(range(nt))
+        assert list(tile[(flags & 2) != 0]) == list(range(nt))
+        assert (np.diff(tile[real]) >= 0).all()
+        pairs = {(p // rb, t // tb) for p, t in enumerate(ids) if t < T_}
+        assert pairs <= {(b, t) for b, t, f in zip(blk, tile, flags)
+                         if f & 4}
+        # the steps past the last pair repeat it: nothing is fetched
+        assert (blk[~real] == blk[real][-1]).all()
+        assert (tile[~real] == nt - 1).all()
+
+    def test_each_mover_is_the_others_transpose(self):
+        src, live, rows, T_ = self._chunk("dead_rows_hold_inf_and_nan")
+        o = token_order(src, live, T_)
+        tokens = jnp.asarray(
+            np.random.default_rng(9).normal(size=(T_, rows.shape[1])),
+            jnp.float32)
+        got_rows, back = jax.vjp(lambda t: rows_of(t, o), tokens)
+        # a live row is its token's; a dead row is nobody's (a constant)
+        live_rows = jnp.where(live[:, None], tokens[src], 0)
+        np.testing.assert_array_equal(
+            jnp.where(live[:, None], got_rows, 0), live_rows)
+        np.testing.assert_array_equal(back(rows)[0], sum_to_tokens(rows, o))
+        _, back = jax.vjp(lambda r: sum_to_tokens(r, o), rows)
+        np.testing.assert_array_equal(back(tokens)[0], live_rows)
+        # and what jax derives for the plain forms agrees
+        finite = jnp.where(live[:, None], rows, 1.0)
+        np.testing.assert_allclose(
+            jax.vjp(lambda t: jnp.where(live[:, None], t[src], 0),
+                    tokens)[1](finite)[0],
+            sum_to_tokens(finite, o), rtol=0, atol=2e-6)
+
+    @staticmethod
+    def _held_value_and_grads(held, K=4, X=8, T_=64):
+        w = _weights(X=held[1])
+        router = _weights(X=X)["router"]
+        toks = jnp.asarray(
+            np.random.default_rng(5).normal(size=(T_, 16)), jnp.float32)
+
+        def loss(toks, router, w_in, w_out, w_gate):
+            res = dropless_moe_ffn(
+                toks, router, w_in, w_out, w_gate, act=jax.nn.silu,
+                top_k=K, held=held, scoring="sigmoid", renormalize=True)
+            n_held = jnp.sum(res.counts[held[0]:held[0] + held[1]])
+            # a chunk is 2 T rows (T where min(k, count) is odd): the
+            # first always runs, a later one where the held pairs reach
+            C = dropless.held_chunk_rows(T_, K, held[1])
+            return jnp.sum(res.out ** 2), (
+                res.out, res.dropped, res.chunks_run - jnp.maximum(1, (n_held + C - 1) // C))
+
+        return jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4),
+                                  has_aux=True)(
+            toks, router, w["w_in"], w["w_out"], w["w_gate"])
+
+    # count < K: the list is a prefix of the pairs; count >= K: all
+    @pytest.mark.parametrize("held", [(2, 2), (5, 3), (0, 6)])
+    def test_held_gradients_match_the_scatter_form(self, held, monkeypatch):
+        """The oracle is the wire as it stood before PR 61: the same
+        chunk with a plain row gather and the segment sum, and the
+        transposes jax derives for them (two row scatter-adds)."""
+        (_, (out, dropped, chunks_off)), grads = self._held_value_and_grads(
+            held)
+        assert int(dropped) == 0 and int(chunks_off) == 0
+        monkeypatch.setattr(
+            dropless, "rows_of",
+            lambda t, o: jnp.where(o.live[:, None], t[o.src], 0))
+        monkeypatch.setattr(
+            dropless, "sum_to_tokens",
+            lambda r, o: jax.ops.segment_sum(
+                jnp.where(o.live[:, None], r, 0), o.src,
+                num_segments=o.n_tokens))
+        (_, (want_out, _, _)), want = self._held_value_and_grads(held)
+        np.testing.assert_allclose(out, want_out, rtol=0, atol=1e-5)
+        for g, w in zip(grads, want):
+            np.testing.assert_allclose(g, w, rtol=1e-4, atol=1e-5)
+
+    def test_the_kernel_runs_the_held_wire_interpreted(self,
+                                                       pallas_interpret):
+        held = (0, 6)
+        (_, (out, dropped, _)), grads = self._held_value_and_grads(held)
+        assert int(dropped) == 0
+        with interpret_kernels(False):
+            (_, (want_out, _, _)), want = self._held_value_and_grads(held)
+        np.testing.assert_allclose(out, want_out, rtol=0, atol=1e-5)
+        for g, w in zip(grads, want):
+            np.testing.assert_allclose(g, w, rtol=1e-4, atol=1e-5)
 
 
 class TestGatingRngDeterminism:

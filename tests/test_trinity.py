@@ -161,8 +161,9 @@ def test_the_shares_add_up_to_the_uncut_layer(highest):
         out, aux = T._moe_mlp_delta(h, cut, cfg)
         # what only this share adds; its census is the whole router's
         total = total + (out - shared)
-        census = aux[2:-1] if census is None else census
-        assert np.array_equal(aux[2:-1], census) and aux[-1] == 0
+        census = aux[2:-2] if census is None else census
+        assert np.array_equal(aux[2:-2], census) and aux[-2] == 0
+        assert aux[-1] == 1  # a share's one chunk ran
         one = ref.routed_experts(h, cut, dict(
             hf, experts_held={"start": 2 * s}))
         assert jnp.max(jnp.abs(out - shared - one)) < 1e-4 * top
@@ -183,8 +184,8 @@ def test_no_held_pair_is_dropped_at_any_skew(highest, monkeypatch):
     h = jax.random.normal(jax.random.PRNGKey(6), (1, 40, 32))
     out, aux = T._moe_mlp_delta(h, lp, cfg)
     assert dropless.held_rows_bound(40, 2, 2) == 80
-    assert np.array_equal(aux[2:-1], [0, 0, 0, 40, 40, 0, 0, 0])
-    assert aux[-1] == 0
+    assert np.array_equal(aux[2:-2], [0, 0, 0, 40, 40, 0, 0, 0])
+    assert aux[-2] == 0 and aux[-1] == 1  # none dropped, one chunk ran
     want = ref.shared_expert(h, lp) + ref.routed_experts(h, lp, hf)
     assert jnp.max(jnp.abs(out - want)) < 1e-4 * jnp.max(jnp.abs(want))
     # the count of dropped pairs is of the products that RAN: a chunk
@@ -192,13 +193,13 @@ def test_no_held_pair_is_dropped_at_any_skew(highest, monkeypatch):
     with monkeypatch.context() as m:
         m.setattr(jax.lax, "cond", lambda pred, run, skip: skip())
         out, aux = T._moe_mlp_delta(h, lp, cfg)
-    assert aux[-1] == 80
+    assert aux[-2] == 80 and aux[-1] == 0
     assert jnp.max(jnp.abs(out - ref.shared_expert(h, lp))) < 1e-6
     # and one that sends none: the first chunk runs all the same (a
     # step's time is its shape's), over no live row, and adds nothing
     lp["expert_bias"] = jnp.zeros(8).at[3:5].set(-10.0)
     out, aux = T._moe_mlp_delta(h, lp, cfg)
-    assert aux[2:-1][3:5].sum() == 0 and aux[-1] == 0
+    assert aux[2:-2][3:5].sum() == 0 and aux[-2] == 0 and aux[-1] == 1
     assert jnp.max(jnp.abs(out - ref.shared_expert(h, lp))) < 1e-6
 
 

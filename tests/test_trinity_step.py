@@ -74,6 +74,9 @@ def test_the_bias_is_the_steps_state_and_not_the_optimizers(tmp_path):
         assert m["moe_rows_per_expert_max"] == c[:, 2:6].max()
         assert m["moe_rows_per_expert_min"] == c[:, 2:6].min()
         assert m["moe_pairs_dropped"] == 0
+        # a micro-batch's list is T x min(k, 4 held) = 2 T rows, ONE
+        # chunk, which always runs: two routed layers, two micro-batches
+        assert m["moe_chunks_run"] == 2 * 2
         d = 0.001 * np.sign(c.mean(-1, keepdims=True) - c)
         b = b + d - d.mean(-1, keepdims=True)
         got = np.asarray(eng.state.master["layers"]["expert_bias"])
@@ -83,6 +86,7 @@ def test_the_bias_is_the_steps_state_and_not_the_optimizers(tmp_path):
     assert losses[2] < losses[1] < losses[0]
     assert eng.counters["moe_pairs_routed"] == 3 * 8 * 32 * 2 * 2
     assert eng.counters["moe_pairs_dropped"] == 0
+    assert eng.counters["moe_chunks_run"] == 3 * 2 * 2
     # the compute copy follows the master; checkpoints round-trip both
     assert np.allclose(
         np.asarray(eng.state.params["layers"]["expert_bias"], np.float32),
@@ -113,6 +117,7 @@ def test_the_gradient_path_lacks_the_steps_state():
         eng.state.params)) - 1
     assert aux["moe_census"].shape == (2, 8)
     assert aux["moe_pairs_dropped"].shape == ()
+    assert aux["moe_chunks_run"].shape == ()
 
 
 def test_what_the_rule_cannot_ride_is_refused():
@@ -153,7 +158,7 @@ def test_the_training_forward_refuses_by_name(over, match):
 def test_serving_only_no_longer_names_what_training_computes():
     mcfg = config_from_hf(tiny(), use_flash=False, max_seq=64)
     assert mcfg.serving_only == ()
-    assert mcfg.carries_census and T.aux_width(mcfg) == 2 + 8 + 1
+    assert mcfg.carries_census and T.aux_width(mcfg) == 2 + 8 + 2
     lifted = {"sandwich_norm", "n_shared_experts", "n_dense_layers",
               "experts_held", "moe_expert_bias", "attn_output_gate",
               "moe_scoring", "embedding_multiplier"}

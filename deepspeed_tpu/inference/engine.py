@@ -547,6 +547,13 @@ class InferenceEngine:
             self.state_slot_bytes = sum(
                 x.nbytes // x.shape[0]
                 for x in jax.tree.leaves(self.cache.state))
+            if model_config.n_kv_reader_layers:
+                # who owns what: layers with pages of their own, with
+                # rings, and those that walk a pool they do not own
+                held = model_config.ring_layers
+                sp.set(kv_owner_layers=len(held) - sum(held),
+                       kv_ring_layers=sum(held),
+                       kv_reader_layers=model_config.n_kv_reader_layers)
             if self.cache.state:
                 sp.set(kv_layers=len(self.cache.k),
                        state_layers=len(self.cache.state),

@@ -38,6 +38,7 @@ sequence, of fixed size whatever the sequence's length (PagedCache).
 """
 
 import contextlib
+import math
 from functools import partial
 from typing import Any, Dict, List, NamedTuple, Optional, Tuple
 
@@ -63,6 +64,13 @@ from ..ops.pallas.gated_delta import (
     pack_heads,
     step_fits,
 )
+from ..ops.pallas.selective_scan import pool_view as sscan_pool_view
+from ..ops.pallas.selective_scan import (
+    sscan_chunked,
+    sscan_step,
+    sscan_step_fits,
+    sscan_step_xla,
+)
 from ..ops.pallas.ssm_state import (
     pack_state,
     ssm_chunked,
@@ -74,6 +82,7 @@ from ..ops.pallas.paged_attention import (
     fused_write_fits,
     kv_heads_held,
     kv_pack,
+    kv_pair_fold,
     kv_write_path,
     latent_lanes,
     paged_decode_attention,
@@ -130,7 +139,7 @@ def prepare(params: Dict[str, Any], cfg: T.TransformerConfig,
         mine = ops.get(cfg.layer_kind(li), {})
         return prepare_layer(dict(
             leaves, **{name: w[cfg.op_index(li)] for name, w in mine.items()}),
-            cfg, fuse)
+            cfg, fuse, cfg.layer_kind(li))
 
     nd = cfg.n_dense_layers
     out["layers"] = [layer({name: w[l] for name, w in st.items()}, nd + l)
@@ -146,11 +155,22 @@ def prepare(params: Dict[str, Any], cfg: T.TransformerConfig,
     return out
 
 
+# the kinds of layer whose dense gated FFN reads gate and up as ONE
+# matmul (w_gi) whatever its mixer is. An attention layer's rule is
+# older and its own: the pair fuses where its q, k and v did. The older
+# state kinds keep the pair apart, as their cells were measured: a kind
+# joins this table with a measurement (ROADMAP.md B-I 17 (e))
+_GATE_UP_FUSED = frozenset({"selective_scan", "gated_memory",
+                            "cross_attention"})
+
+
 def prepare_layer(lp: Dict[str, Any], cfg: T.TransformerConfig,
-                  fuse: bool = True) -> Dict[str, Any]:
+                  fuse: bool = True, kind: str = "attention"
+                  ) -> Dict[str, Any]:
     """One layer's training-layout dict -> serving layout (the per-layer
     body of prepare(); offload serving stages layers through this one at
-    a time so a bigger-than-HBM model never materializes whole)."""
+    a time so a bigger-than-HBM model never materializes whole). kind:
+    the layer's, one of T.LAYER_KINDS."""
     lp = dict(lp)
     if "wkv_b" in lp:
         # latent attention: the up-projection splits into the halves the
@@ -159,7 +179,7 @@ def prepare_layer(lp: Dict[str, Any], cfg: T.TransformerConfig,
         Dn = cfg.qk_nope_head_dim
         wkv_b = lp.pop("wkv_b")
         lp["w_uk"], lp["w_uv"] = wkv_b[..., :Dn], wkv_b[..., Dn:]
-    if fuse and "wq" in lp:
+    if fuse and "wk" in lp:
         # the output gate's projection (cfg.attn_output_gate) rides the
         # same GEMM, after v
         lp["w_qkv"] = jnp.concatenate(
@@ -168,9 +188,10 @@ def prepare_layer(lp: Dict[str, Any], cfg: T.TransformerConfig,
         if "bq" in lp:
             lp["b_qkv"] = jnp.concatenate(
                 [lp.pop("bq"), lp.pop("bk"), lp.pop("bv")], axis=0)
-        if "w_router" not in lp and cfg.is_gated and "w_gate" in lp:
-            lp["w_gi"] = jnp.concatenate(
-                [lp.pop("w_gate"), lp.pop("w_in")], axis=1)
+    if fuse and ("w_qkv" in lp or kind in _GATE_UP_FUSED) \
+            and "w_router" not in lp and cfg.is_gated and "w_gate" in lp:
+        lp["w_gi"] = jnp.concatenate(
+            [lp.pop("w_gate"), lp.pop("w_in")], axis=1)
     if "w_router" in lp and "w_gate" not in lp and "b_in" not in lp:
         lp["w_in"], lp["w_out"] = _whole_lane_experts(lp["w_in"], lp["w_out"])
     return lp
@@ -472,6 +493,19 @@ def ring_blocks(cfg: T.TransformerConfig, block_size: int,
                blocks_per_seq)
 
 
+def kv_pool_shape(cfg: T.TransformerConfig) -> Tuple[int, int]:
+    """(heads, values a head) of K (and of V) a token has in a layer's
+    cache: the model's kv_heads of head_dim, or, of a model of paired
+    heads (cfg.differential_attention: a pair is one head of 2
+    head_dim), its pairs folded side by side by the kernels' tile rule
+    (paged_attention.kv_pair_fold): the same values in the same order."""
+    if not cfg.differential_attention:
+        return cfg.kv_heads, cfg.head_dim
+    pairs, width = cfg.kv_heads // 2, 2 * cfg.head_dim
+    fold = kv_pair_fold(pairs, width)
+    return pairs // fold, width * fold
+
+
 def init_cache(
     cfg: T.TransformerConfig, num_blocks: int, block_size: int, dtype=jnp.bfloat16,
     mesh: Optional[Mesh] = None, kv_quant: bool = False,
@@ -484,12 +518,14 @@ def init_cache(
     model with recurrent state. ring_pool_blocks: blocks of a WINDOWED
     layer's pool in a model of mixed windows (rings x ring_blocks + the
     pad rows' one); its full layers' pools hold num_blocks."""
-    KV, D, L = cfg.kv_heads, cfg.head_dim, cfg.n_kv_layers
-    if (cfg.is_latent or cfg.n_state_layers or cfg.mixed_windows) and (
+    (KV, D), L = kv_pool_shape(cfg), cfg.n_kv_layers
+    if (cfg.is_latent or cfg.n_state_layers or cfg.mixed_windows
+            or cfg.differential_attention) and (
             kv_quant or mesh is not None):
         raise NotImplementedError(
-            "a latent cache, a cache beside recurrent state and a cache "
-            "with rings is bf16/f32 on one device: no int8 pool and no mesh")
+            "a latent cache, a cache beside recurrent state, a cache "
+            "with rings and one of paired heads is bf16/f32 on one device: "
+            "no int8 pool and no mesh")
     def state_pools(kind):
         # the carried inputs, and before them the heads' matrices, whose
         # pool holds one slot more: the pad rows' (state_step_call)
@@ -1128,6 +1164,75 @@ def _to_heads(x, heads: int):
     return jnp.pad(x, [(0, 0), (0, heads - x.shape[1]), (0, 0)])
 
 
+def _paired_heads(q, k, v, cfg: T.TransformerConfig):
+    """Differential attention's pairs as heads of twice the width, in
+    the order the projections leave them: q [..., H, D] -> [..., H, 2D],
+    an even head (q1 of its pair) over the first D lanes and zeros, an
+    odd one (q2) zeros and the last D, so that against a K/V pair's
+    [k1; k2] each scores with its own key alone; k, v [..., KV, D] ->
+    [..., KV / 2, 2D], a reshape (None stays None: a cross layer's).
+    Every path below then computes a1 and a2 as H query heads over
+    KV / 2 heads of 2D, at twice the needed products and the needed
+    bytes. q is scaled by 2^0.5: what the kernels' own (2D)^-0.5 lacks
+    of D^-0.5 (as cfg.attention_multiplier's multiply)."""
+    D = q.shape[-1]
+    odd = (jnp.arange(q.shape[-2]) % 2 == 1)[:, None]
+    q = q * jnp.asarray(2 ** 0.5, q.dtype)
+    q = jnp.concatenate([jnp.where(odd, 0, q), jnp.where(odd, q, 0)], axis=-1)
+    pair = lambda a: a if a is None else a.reshape(
+        *a.shape[:-2], a.shape[-2] // 2, 2 * D)
+    return q, pair(k), pair(v)
+
+
+def _diff_combine(att, lp, li: int, cfg: T.TransformerConfig):
+    """att [..., H, 2D], the maps of _paired_heads' queries over their
+    pair's V -> [..., H, D], what W_o reads: for each pair p of heads,
+    a1 - lam a2 (a1 = att[2p], a2 = att[2p + 1]) normed over its 2D
+    values (RMS, the layer's learned scale) times 1 - lam0, float32;
+    lam = exp(lq1 . lk1) - exp(lq2 . lk2) + lam0,
+    lam0 = 0.8 - 0.6 exp(-0.3 li) by the layer's index in the stack."""
+    f32 = jnp.float32
+    lam0 = 0.8 - 0.6 * math.exp(-0.3 * li)
+    dot = lambda a, b: jnp.sum(lp[a].astype(f32) * lp[b].astype(f32))
+    lam = (jnp.exp(dot("diff_lq1", "diff_lk1"))
+           - jnp.exp(dot("diff_lq2", "diff_lk2")) + lam0)
+    o = att[..., 0::2, :].astype(f32) - lam * att[..., 1::2, :].astype(f32)
+    o = o * jax.lax.rsqrt(jnp.mean(o * o, -1, keepdims=True) + cfg.norm_eps)
+    o = o * lp["diff_norm_scale"].astype(f32) * (1.0 - lam0)
+    *lead, H, W = att.shape
+    return o.reshape(*lead, H, W // 2).astype(att.dtype)
+
+
+def _fold_pairs(q, pool):
+    """Paired queries q [S, H, W] laid against a pool whose heads hold
+    `f` K/V pairs side by side (kv_pool_shape: [.., pairs / f, f W]):
+    (q [S, H, f W], each head's W values over its own pair's lanes and
+    zeros elsewhere, so that it scores against its pair alone, times
+    f^0.5: what the kernels' own (f W)^-0.5 lacks of W^-0.5;
+    unfold(att [S, H, f W]) -> [S, H, W], the lanes of its pair's V).
+    q as it is where the pool folds nothing."""
+    S, H, W = q.shape
+    f = pool.shape[-1] // W
+    if f == 1:
+        return q, lambda att: att
+    # heads in their order: H / pairs a pair, f pairs a pool head
+    slot = jnp.arange(H) // (H // (pool.shape[-2] * f)) % f
+    mine = (slot[:, None] == jnp.arange(f)[None, :])[None, :, :, None]
+    wide = jnp.where(mine, q[:, :, None, :] * jnp.asarray(f ** 0.5, q.dtype),
+                     0).reshape(S, H, f * W)
+    return wide, lambda att: jnp.sum(
+        jnp.where(mine, att.reshape(S, H, f, W), 0), axis=2)
+
+
+def _pool_rows(rows, cfg: T.TransformerConfig):
+    """New K or V rows [T, heads, W] in the row shape of the cache's
+    pools: a model of paired heads' pairs folded side by side
+    (kv_pool_shape), any other model's as they are."""
+    if not cfg.differential_attention:
+        return rows
+    return rows.reshape(rows.shape[0], *kv_pool_shape(cfg))
+
+
 def _decode_attention(q, pools: tuple, table, ctx, use_kernel: bool,
                       window: int = 0, mesh=None, alibi=None,
                       k_new=None, v_new=None, slots=None, kv_heads: int = 0):
@@ -1200,7 +1305,7 @@ def _decode_attention(q, pools: tuple, table, ctx, use_kernel: bool,
 
 def _layer(x, lp, li: int, positions, cfg: T.TransformerConfig, mesh, attend,
            alibi, census_cb=None, use_kernel: bool = False, carry=None,
-           recur=None):
+           recur=None, handed=None):
     """One serving layer over [..., E] activations (decode rows [S, E],
     prefill prompts [B, Tp, E]): norm1, then the layer's operator by its
     kind (cfg.layer_kind(li)) and the FFN tail. A layer that carries
@@ -1226,7 +1331,17 @@ def _layer(x, lp, li: int, positions, cfg: T.TransformerConfig, mesh, attend,
     and how the new rows reach the cache), the output projection and
     the FFN tail, whose routed block asks expert_path with `use_kernel`
     and the mesh. Returns (x, layer_cache): the layer's K/V pools, or
-    its state pools."""
+    its state pools.
+
+    `handed` is _forward's, what a layer of this pass leaves for later
+    ones: the layer cfg.memory_donor its scan's output ('memory'), which
+    a 'gated_memory' layer gates its own projection with (it holds
+    nothing); the layer cfg.kv_donor its keys, values and pools as its
+    `attend` left them ('kv'), which a 'cross_attention' layer, a query
+    and an output projection alone, hands to `attend` as `donor`. Where
+    cfg.differential_attention, heads are paired before `attend`
+    (_paired_heads) and their two maps subtracted after it
+    (_diff_combine)."""
     H, KV = cfg.n_heads, cfg.kv_heads
     if cfg.output_norm:  # no norm before the operator: _ffn_residual's
         h1 = T._act_quant(x, cfg)
@@ -1240,11 +1355,21 @@ def _layer(x, lp, li: int, positions, cfg: T.TransformerConfig, mesh, attend,
             out = _mlp(h1.reshape(-1, h1.shape[-1]), lp, cfg, census_cb,
                        use_kernel, mesh).reshape(x.shape)
         return _ffn_residual(x, out, h1, lp, cfg), None
-    if kind != "attention":
+    if kind == "gated_memory":  # holds nothing: the donor's scan gates it
+        with jax.named_scope("gated_memory"):
+            gate = jax.nn.silu(_wmm("...e,ef->...f", h1, lp["gmu_in"]))
+            out = _wmm("...f,fe->...e",
+                       gate * handed["memory"].astype(gate.dtype),
+                       lp["gmu_out"])
+        return _ffn_residual(x, out, h1, lp, cfg, census_cb, use_kernel,
+                             mesh), None
+    if kind in _STATE_OPERATORS:
         scope, operator = _STATE_OPERATORS[kind]
         with jax.named_scope(scope):
-            out, state = operator(h1, lp, cfg, partial(carry, li=li),
-                                  partial(recur, li=li))
+            out, state, *gives = operator(h1, lp, cfg, partial(carry, li=li),
+                                          partial(recur, li=li))
+        if li == cfg.memory_donor:
+            handed["memory"], = gives
         return _ffn_residual(x, out, h1, lp, cfg, census_cb, use_kernel,
                              mesh), state
     if cfg.is_latent:
@@ -1259,8 +1384,14 @@ def _layer(x, lp, li: int, positions, cfg: T.TransformerConfig, mesh, attend,
                     out = T._norm(out, lp["ln1_post_scale"], None, cfg)
         return _ffn_residual(x, out, h1, lp, cfg, census_cb, use_kernel,
                              mesh), layer_cache
+    cross = kind == "cross_attention"
     with jax.named_scope("attention"):
-        if "w_qkv" in lp:
+        if cross:  # the donor's keys and values: a query alone
+            q = _wmm("...e,ehd->...hd", h1, lp["wq"])
+            k, v, gate = None, None, []
+            if "bq" in lp:
+                q = q + lp["bq"].astype(x.dtype)
+        elif "w_qkv" in lp:
             qkv = _wmm("...e,ehd->...hd", h1, lp["w_qkv"])
             if "b_qkv" in lp:
                 qkv = qkv + lp["b_qkv"].astype(x.dtype)
@@ -1290,16 +1421,28 @@ def _layer(x, lp, li: int, positions, cfg: T.TransformerConfig, mesh, attend,
             # walk, both oracles and every other family's program text
             # stay as they are; the cached K is the unscaled one
             q = q * (cfg.attention_multiplier * cfg.head_dim ** 0.5)
+        if cfg.differential_attention:
+            q, k, v = _paired_heads(q, k, v, cfg)
         heads = (None,) * (q.ndim - 2) + ("model", None)
         q = _cons(q, mesh, *heads)
         k = _cons(k, mesh, *heads)
         v = _cons(v, mesh, *heads)
-        # a model of mixed windows: device time by the layer's window
+        # a model of mixed windows: device time by the layer's window, or
+        # `attn_cross` for a walk of another layer's pool
         # (metadata alone; no other model's program carries the scope)
-        with (jax.named_scope("attn_window" if cfg.window_for_layer(li)
-                              else "attn_full")
+        with (jax.named_scope("attn_cross" if cross else "attn_window"
+                              if cfg.window_for_layer(li) else "attn_full")
               if cfg.mixed_windows else contextlib.nullcontext()):
-            att, layer_cache = attend(q, k, v, li, alibi, lp)
+            if cross:
+                att, layer_cache = attend(q, k, v, li, alibi, lp,
+                                          donor=handed["kv"])
+            else:
+                att, layer_cache = attend(q, k, v, li, alibi, lp)
+        if li == cfg.kv_donor:
+            handed["kv"] = (k, v, layer_cache)
+        if cfg.differential_attention:
+            with jax.named_scope("diff_combine"):
+                att = _diff_combine(att, lp, li, cfg)
         if gate:
             with jax.named_scope("attn_gate"):
                 att = att * jax.nn.sigmoid(
@@ -1451,12 +1594,58 @@ def _state_space(h1, lp, cfg: T.TransformerConfig, carry, recur):
     return out, (pool, conv_pool)
 
 
+def _selective_scan(h1, lp, cfg: T.TransformerConfig, carry, recur):
+    """The selective-scan (Mamba-1) mixer: normed activations h1
+    [..., E] -> (its output [..., E], the layer's state pools (the
+    state, the convolution's carried inputs), and what a later layer
+    may read of it: the scan's output y, skip included, BEFORE the
+    gate).
+
+    [x; z] = sscan_in h1 (no bias); x <- silu(causal depthwise
+    convolution of conv_kernel taps + sscan_conv_bias, zeros before the
+    sequence starts), in the activations' dtype as the publisher's;
+    [r; B; C] = sscan_x x (r of cfg.ssm_dt_rank, B and C of
+    ssm_state_dim, one of each a token); dt = softplus(sscan_dt r +
+    sscan_dt_bias), A = -exp(sscan_a_log), float32, a rate a (channel,
+    state) pair, no clamp; the recurrence (ops/pallas/selective_scan.py:
+    h <- exp(dt A) h + dt B x, y = h C) through
+    `recur((x, dt, A, B, C))` -> (y float32, the state's pool), with
+    `carry` as _short_conv's; y += D x (sscan_d); out = sscan_out
+    (y * silu(z)): no norm behind the gate."""
+    N, R, f32 = cfg.ssm_state_dim, cfg.ssm_dt_rank, jnp.float32
+    with jax.named_scope("sscan_project"):
+        u, z = jnp.split(_wmm("...e,ef->...f", h1, lp["sscan_in"]), 2,
+                         axis=-1)
+    with jax.named_scope("sscan_conv"):
+        conv, conv_pool = carry(u, lp["sscan_taps"])
+        x = jax.nn.silu(conv + lp["sscan_conv_bias"].astype(f32)
+                        ).astype(u.dtype)
+    with jax.named_scope("sscan_project"):
+        r, Bm, Cm = jnp.split(_wmm("...f,fr->...r", x, lp["sscan_x"]),
+                              [R, R + N], axis=-1)
+        dt = jax.nn.softplus(
+            _wmm("...r,rf->...f", r, lp["sscan_dt"]).astype(f32)
+            + lp["sscan_dt_bias"].astype(f32))
+        A = -jnp.exp(lp["sscan_a_log"].astype(f32))
+    with jax.named_scope("sscan_state"):
+        x = x.astype(f32)
+        y, pool = recur((x, dt, A, Bm.astype(f32), Cm.astype(f32)))
+        y = y + lp["sscan_d"].astype(f32) * x
+    with jax.named_scope("sscan_gate"):
+        gated = (y * jax.nn.silu(z.astype(f32))).astype(h1.dtype)
+    with jax.named_scope("sscan_out"):
+        out = _wmm("...f,fe->...e", gated, lp["sscan_out"])
+    return out, (pool, conv_pool), y.astype(h1.dtype)
+
+
 # a layer that carries state, by its kind: its scope, its operator
-# (h1, lp, cfg, carry, recur) -> (out, the layer's state pools)
+# (h1, lp, cfg, carry, recur) -> (out, the layer's state pools, and
+# where the kind hands something on to later layers, that)
 _STATE_OPERATORS = {
     "conv": ("short_conv", _short_conv),
     "linear_attention": ("linear_attention", _gated_delta_net),
     "state_space": ("state_space", _state_space),
+    "selective_scan": ("selective_scan", _selective_scan),
 }
 
 
@@ -1508,13 +1697,24 @@ def _ssm_scan(args, real, cfg, pool):
     return y, pack_state(last, cfg.ssm_pack)
 
 
+def _sscan_scan(args, real, cfg, pool):
+    """The selective scan's chunked form over whole prompts, its last
+    states in the pool's layout; a pad token has dt = 0."""
+    x, dt, A, Bm, Cm = args
+    y, last = sscan_chunked(x, jnp.where(real, dt, 0.0), A, Bm, Cm,
+                            chunk=cfg.ssm_chunk)
+    return y, sscan_pool_view(last, pool.shape[-1])
+
+
 # a kind whose heads carry a matrix: whether its step kernel takes
 # (rows, pool), the kernel, the same step in XLA; its whole-prompt scan
 _STEP_OF = {
     "linear_attention": (step_fits, gated_delta_step, gated_delta_step_xla),
     "state_space": (ssm_step_fits, ssm_step, ssm_step_xla),
+    "selective_scan": (sscan_step_fits, sscan_step, sscan_step_xla),
 }
-_SCAN_OF = {"linear_attention": _gdn_scan, "state_space": _ssm_scan}
+_SCAN_OF = {"linear_attention": _gdn_scan, "state_space": _ssm_scan,
+            "selective_scan": _sscan_scan}
 
 
 def _state_write(pool, slots, rows, keep):
@@ -1723,6 +1923,7 @@ def _forward(params, tokens, positions, cfg: T.TransformerConfig, mesh,
 
     pools = []  # per layer that holds K/V, as _layer_pools
     states = []  # per layer that holds state, its pool
+    handed = {}  # what a layer leaves for later ones of this pass (_layer)
     x_hist = []  # layer outputs; fetch l is barriered on output l-2
     # leading dense layers first (cache layers 0..n_dense-1), then the
     # stacked ones
@@ -1732,7 +1933,8 @@ def _forward(params, tokens, positions, cfg: T.TransformerConfig, mesh,
             lp = fetch_layer(lp, x_hist[-2] if len(x_hist) >= 2 else None,
                              li)
         x, layer_cache = _layer(x, lp, li, positions, cfg, mesh, attend,
-                                alibi, census_cb, use_kernel, carry, recur)
+                                alibi, census_cb, use_kernel, carry, recur,
+                                handed)
         # a layer holds K/V, state or nothing, by its kind
         if cfg.layer_kind(li) == "attention":
             pools.append(layer_cache)
@@ -1818,23 +2020,36 @@ def decode_step(
     fuse_write = (unique_rows and use_kernel and _tp_size(mesh) <= 1
                   and fused_write_fits(tokens.shape[0]))
 
-    def attend(q, k, v, li, alibi, lp):
+    pool_heads, _ = kv_pool_shape(cfg)
+
+    def attend(q, k, v, li, alibi, lp, donor=None):
         if cfg.is_latent:  # k: the rows the cache holds
             return _latent_absorbed(q, k, lp, *_layer_pools(cache, li),
                                     tables, ctx_lens, flat_idx, cfg,
                                     use_kernel)
+        unfold = lambda att: att
+        if cfg.differential_attention:  # the pairs as the pools hold them
+            q, unfold = _fold_pairs(q, cache.k[0])
+        if donor is not None:
+            # a cross layer: the donor's pools as its own attend left
+            # them, this step's rows in them; a full walk, and no write
+            att = _decode_attention(q, donor[2], tables, ctx_lens,
+                                    use_kernel, 0, mesh, alibi,
+                                    kv_heads=pool_heads)
+            return unfold(att), None
         window = cfg.window_for_layer(li)
         table, flat = by_window[cfg.ring_layers[cfg.op_index(li)]]
         where = (table, ctx_lens, use_kernel, window, mesh, alibi)
         pools = _layer_pools(cache, cfg.op_index(li))
+        k, v = _pool_rows(k, cfg), _pool_rows(v, cfg)
         if fuse_write:
             att, *pools = _decode_attention(q, pools, *where, k_new=k,
                                             v_new=v, slots=flat,
-                                            kv_heads=cfg.kv_heads)
-            return att, pools
+                                            kv_heads=pool_heads)
+            return unfold(att), pools
         pools = _write_pools(pools, k, v, flat, mesh, use_kernel)
-        return _decode_attention(q, pools, *where,
-                                 kv_heads=cfg.kv_heads), pools
+        return unfold(_decode_attention(q, pools, *where,
+                                        kv_heads=pool_heads)), pools
 
     @partial(_slot_wide, cache=cache, cfg=cfg)
     def carry(u, taps, pool):
@@ -2009,17 +2224,23 @@ def prefill_batch(
             jnp.int32(-1),
         ).reshape(B * Tp)
 
-    def attend(q, k, v, li, alibi, lp):
+    def attend(q, k, v, li, alibi, lp, donor=None):
         if cfg.is_latent:  # k: the rows the cache holds
             return _latent_naive(q, k, lp, *_layer_pools(cache, li),
                                  flat_idx, cfg, use_kernel)
+        if donor is not None:
+            # a cross layer: the donor's keys and values of this pass,
+            # causal and full; it writes nothing
+            return causal_attention(
+                q, donor[0], donor[1],
+                use_flash=use_kernel and cfg.use_flash), None
         # the prompt's in-flight attention stays full precision (it
         # never reads the cache); only the RESIDENT copy quantizes —
         # later decode steps read these codes
         pools = _write_pools(
             _layer_pools(cache, cfg.op_index(li)),
-            k.reshape(B * Tp, *k.shape[2:]),
-            v.reshape(B * Tp, *v.shape[2:]),
+            _pool_rows(k.reshape(B * Tp, *k.shape[2:]), cfg),
+            _pool_rows(v.reshape(B * Tp, *v.shape[2:]), cfg),
             flat_by_window[cfg.ring_layers[cfg.op_index(li)]],
             mesh, use_kernel)
         flash = partial(causal_attention, window=cfg.window_for_layer(li))

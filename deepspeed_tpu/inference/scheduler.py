@@ -77,6 +77,7 @@ from ..utils import profiler
 from ..utils.logging import log_dist
 from ..utils.sync import serving_readback
 from .engine import InferenceEngine, _bucket, refuse_for_pools
+from .model import kv_pool_shape
 from .pressure import BROWNOUT, RED, PressureGovernor, estimate_ttft
 from .ragged import KVCacheExhaustedError
 
@@ -112,7 +113,8 @@ LATENCY_WINDOW = 4096
 # the counter of run tokens (ServingScheduler._count_state), by the
 # kind of layer whose heads carry a matrix from row to row of a run
 _RUN_TOKENS = {"linear_attention": "gdn_run_tokens",
-               "state_space": "ssm_run_tokens"}
+               "state_space": "ssm_run_tokens",
+               "selective_scan": "sscan_run_tokens"}
 
 
 @dataclasses.dataclass
@@ -320,6 +322,16 @@ class ServingScheduler:
             "kv_rings_live": 0,
             "admit_waits_full_pool": 0,
             "admit_waits_window_pool": 0,
+            # a model some of whose layers read ANOTHER layer's K/V (0
+            # for every other), a dispatched sequence a step as above:
+            # cached tokens those layers' walks read of the pool they do
+            # not own (its context, once a reader layer); the token rows
+            # that went through the readers' layers, and those of them
+            # whose logits were read (a prompt chunk's other rows need
+            # the layers up to the donor alone)
+            "kv_shared_tokens": 0,
+            "cross_rows_run": 0,
+            "cross_rows_needed": 0,
             # bytes of their slots the dispatched programs' sequences
             # read and wrote, over all state layers (a step over rows
             # reads and writes each live sequence's slot once a layer,
@@ -331,6 +343,7 @@ class ServingScheduler:
             "state_bytes_moved": 0,
             "gdn_run_tokens": 0,
             "ssm_run_tokens": 0,
+            "sscan_run_tokens": 0,
             # dispatched steps over rows whose state layers run their
             # short convolution as the one-pass kernel
             # (engine.carry_kernel of the program's width); over steps,
@@ -1037,6 +1050,7 @@ class ServingScheduler:
             self.counters["wave_prefills"] += len(wave)
             self._count_tokens(int(n_real.sum()), bp * tp)
             self._count_rings(n_real[:len(wave)])
+            self._count_cross(int(n_real.sum()), len(wave))
             self._count_state(n_real[:len(wave)].tolist(), bp * tp,
                               reads=False)
             self._it_rows += int(n_real.sum())
@@ -1072,9 +1086,9 @@ class ServingScheduler:
                                                   cfg.n_heads)
                 self.counters["mla_grouped_rows"] += tiled
             elif tables is not None:
+                KV, D = kv_pool_shape(cfg)
                 blocks, rode = walk_reads(
-                    tables, ctx, bs, cfg.n_heads // cfg.kv_heads
-                    * kv_pack(cfg.kv_heads, cfg.head_dim))
+                    tables, ctx, bs, cfg.n_heads // KV * kv_pack(KV, D))
                 self.counters["kv_grouped_rows"] += rode
             self.counters["kv_block_reads"] += blocks
             if cfg.is_latent:
@@ -1086,11 +1100,16 @@ class ServingScheduler:
         """What a dispatched step's SEQUENCES read of the two kinds of
         K/V a model of mixed windows holds: `contexts` is each one's
         context after the step (a chunk's rows are one read, by its
-        longest row)."""
+        longest row); and what the layers that read ANOTHER layer's
+        pool walk of it."""
         state = self.engine.state
-        if not state.num_rings:
+        readers = self.engine.cfg.n_kv_reader_layers
+        if not (state.num_rings or readers):
             return
         ctx = np.asarray(contexts, np.int64)
+        self.counters["kv_shared_tokens"] += int(ctx.sum()) * readers
+        if not state.num_rings:
+            return
         self.counters["kv_full_tokens"] += int(ctx.sum())
         self.counters["kv_window_tokens"] += int(
             np.minimum(ctx, self.engine.cfg.widest_window).sum())
@@ -1098,6 +1117,15 @@ class ServingScheduler:
         self.counters["kv_ring_blocks_recycled"] += (
             state.rings_recycled - self._rings_recycled_seen)
         self._rings_recycled_seen = state.rings_recycled
+
+    def _count_cross(self, run: int, needed: int) -> None:
+        """The token rows of a dispatched program that went through the
+        layers that read another layer's K/V, and those of them whose
+        logits were read: what skipping those layers for the other rows
+        would save (a model without such layers counts neither)."""
+        if self.engine.cfg.n_kv_reader_layers:
+            self.counters["cross_rows_run"] += run
+            self.counters["cross_rows_needed"] += needed
 
     def _count_state(self, runs: Sequence[int], width: int, steps: int = 1,
                      reads: bool = True) -> None:
@@ -1205,9 +1233,10 @@ class ServingScheduler:
                    if sample_rows else None)
         ph.mark("commit")
         self._count_tokens(n_rows, sp, ctx, tables=tables)
-        if rings is not None:
+        if rings is not None or eng.cfg.n_kv_reader_layers:
             self._count_rings([eng.state.get(req.uid).seen_tokens
                                for req, _, _ in rows])
+        self._count_cross(n_rows, len(sample_rows))
         self._count_state([len(c) for _, c, _ in rows], sp)
         return _Part("mixed", sample_rows, tok_dev)
 
@@ -1270,6 +1299,7 @@ class ServingScheduler:
         self._count_tokens(len(running) * C, width, ctx, steps=C)
         for i in range(C):
             self._count_rings(ctx[:len(running)] + i)
+        self._count_cross(len(running) * C, len(running) * C)
         self._count_state([1] * len(running), width, steps=C)
         self.counters["fused_steps"] += 1
         return _Part("fused", sample_rows, gen, n_steps=C)

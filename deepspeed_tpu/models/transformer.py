@@ -250,7 +250,10 @@ class TransformerConfig:
     # group, ssm_groups),
     # behind a causal depthwise convolution of conv_kernel taps WITH a
     # bias (then silu) over the channels of [x; B; C]; or "experts", the
-    # routed block as a layer of its own (mixer_only). A sequence carries
+    # routed block as a layer of its own (mixer_only); or
+    # "selective_scan", the Mamba-1 mixer (ssm_dt_rank), and the two
+    # kinds that read another layer, "cross_attention" and
+    # "gated_memory" (differential_attention). A sequence carries
     # fixed-size state from token to token in a conv, linear-attention
     # or state-space layer, whatever its length (state_shapes), K/V
     # in the attention layers alone, and nothing in an experts layer.
@@ -278,6 +281,17 @@ class TransformerConfig:
     ssm_groups: int = 1
     # tokens the whole-prompt scan of a state-space layer takes at a time
     ssm_chunk: int = 256
+    # SERVING ONLY. The rank of a "selective_scan" layer's step: the
+    # Mamba-1 mixer (arXiv:2312.00752; inference/model.py
+    # _selective_scan), whose decay is a MATRIX: a sequence carries
+    # h in R^{channels x ssm_state_dim}, float32, every (channel, state)
+    # pair with a rate of its own, h <- exp(dt_c A_{c,n}) h + dt_c B_n x_c,
+    # y_c = sum_n C_n h_{c,n} + D_c x_c, dt = softplus(W_dt r + b_dt)
+    # through a bottleneck r of this rank, and NO norm behind the gate.
+    # Its channels are ssm_heads x ssm_head_dim (ssm_inner), a "head"
+    # here being one 128-lane row of channels of the state pool; its
+    # convolution (conv_kernel taps, a bias, then silu) runs over x alone.
+    ssm_dt_rank: int = 0
     # SERVING ONLY. Every layer is ONE sublayer, x + op(norm1 x), with
     # one norm: no FFN tail behind a mixer, and the routed block is a
     # layer of its own, the kind "experts" of LAYER_KINDS (its leaves a
@@ -318,6 +332,27 @@ class TransformerConfig:
     residual_multiplier: float = 1.0
     logits_scaling: float = 1.0
     attention_multiplier: Optional[float] = None
+    # ---- a decoder that reads another layer's cache (SambaY class,
+    # arXiv:2507.06607). SERVING ONLY. Two more kinds of layer_types that
+    # hold NOTHING: "cross_attention" (W_q and W_o alone: it attends,
+    # causal and full, over the K/V that the layer `kv_donor` cached for
+    # the sequence, this step's rows included) and "gated_memory"
+    # (out = W_out (silu(W_in h) * m), m what the layer `memory_donor`'s
+    # selective scan read for the SAME token, before its gate). Which
+    # layers donate is DERIVED from layer_types, not set beside it.
+    # differential_attention (arXiv:2410.05258), in every layer that
+    # attends: the heads pair up in order, query pair p = heads
+    # (2p, 2p + 1) reads the K/V pair p // (pairs of q a pair of K/V):
+    # a1 = softmax(s q1 k1^T) V, a2 = softmax(s q2 k2^T) V over the
+    # pair's V = [v1; v2] of 2 head_dim, s = head_dim^-0.5;
+    # lam = exp(lq1 . lk1) - exp(lq2 . lk2) + lam0(l),
+    # lam0(l) = 0.8 - 0.6 exp(-0.3 l) by the layer's index in the whole
+    # stack; o = RMSNorm(a1 - lam a2; a learned scale of 2 head_dim a
+    # layer) * (1 - lam0(l)). A pair of K heads IS one K head of twice
+    # the width in the order the projection leaves them: what a cache
+    # holds of them is the serving model's (inference/model.py
+    # kv_pool_shape).
+    differential_attention: bool = False
 
     def __post_init__(self):
         if self.layer_types is not None:
@@ -330,7 +365,7 @@ class TransformerConfig:
             if self.state_layer_kinds and self.conv_kernel < 2:
                 raise ValueError(
                     "layers that carry state (conv, linear_attention, "
-                    "state_space) need conv_kernel >= 2")
+                    "state_space, selective_scan) need conv_kernel >= 2")
             if "state_space" in self.layer_types and not (
                     self.ssm_heads > 0 and self.ssm_head_dim > 0
                     and self.ssm_state_dim > 0 and self.ssm_chunk > 0):
@@ -358,6 +393,30 @@ class TransformerConfig:
                     "linear_attention layers need gdn_key_heads, "
                     "gdn_key_dim, gdn_value_dim and gdn_value_heads, a "
                     "multiple of gdn_key_heads")
+            if "selective_scan" in self.layer_types and not (
+                    self.ssm_heads > 0 and self.ssm_head_dim > 0
+                    and self.ssm_state_dim > 0 and self.ssm_chunk > 0
+                    and self.ssm_dt_rank > 0):
+                raise ValueError(
+                    "selective_scan layers need ssm_heads x ssm_head_dim "
+                    "channels, ssm_state_dim, ssm_chunk and ssm_dt_rank")
+            for reader, donor, what in (
+                    ("cross_attention", self.kv_donor,
+                     "a full-attention layer before the first of them, "
+                     "whose K/V they read"),
+                    ("gated_memory", self.memory_donor,
+                     "a selective_scan layer before the first of them, "
+                     "whose output they gate with")):
+                if reader in self.layer_types and donor is None:
+                    raise ValueError(f"{reader} layers need {what}")
+            if "cross_attention" in self.layer_types and (
+                    self.use_rope or self.use_learned_pos or self.alibi
+                    or self.qk_norm or self.attn_output_gate
+                    or self.mixer_only):
+                raise NotImplementedError(
+                    "cross_attention layers project no key: they are served "
+                    "without positions (position_embedding 'none'), QK-norm, "
+                    "an output gate, or layers of one sublayer each")
             if self.kv_lora_rank > 0:
                 raise NotImplementedError(
                     "layers of two kinds with latent attention: a latent "
@@ -379,6 +438,17 @@ class TransformerConfig:
                 "ALONE: it excludes sandwich_norm (which has it beside the "
                 "norm before), the parallel form, a norm with a bias, "
                 "latent attention and a residual multiplier")
+        if self.differential_attention and (
+                self.kv_lora_rank > 0 or self.mixer_only or self.alibi
+                or self.attn_output_gate or self.qk_norm
+                or self.n_heads % 2 or self.kv_heads % 2
+                or (self.n_heads // 2) % (self.kv_heads // 2)):
+            raise ValueError(
+                "differential_attention subtracts the maps of PAIRS of "
+                f"heads: n_heads {self.n_heads} and kv_heads {self.kv_heads} "
+                "must be even, a whole number of query pairs a pair of K/V; "
+                "and it stands beside no latent attention, mixer_only, "
+                "ALiBi, output gate or QK-norm")
         if self.shared_expert_gate and not self.n_shared_experts:
             raise ValueError("shared_expert_gate gates n_shared_experts: "
                              "set both")
@@ -617,7 +687,8 @@ class TransformerConfig:
                                  "output_norm", "gdn_neg_eigval",
                                  "shared_expert_gate", "position_embedding",
                                  "attention_multiplier",
-                                 "rope_scaling_full_only", "mixer_only")
+                                 "rope_scaling_full_only", "mixer_only",
+                                 "ssm_dt_rank", "differential_attention")
                      if getattr(self, k)) + tuple(
             k for k, plain in (("ssm_groups", 1),
                                ("residual_multiplier", 1.0),
@@ -654,6 +725,35 @@ class TransformerConfig:
         """Layers that hold fixed-size per-sequence state in a slot (a
         layer holds K/V, state or nothing, by its kind: not by depth)."""
         return len(self.state_layer_kinds)
+
+    def _donor(self, reader: str, gives) -> Optional[int]:
+        """The last layer that `gives(li)` before the first layer of
+        kind `reader`; None where there is no such reader or donor."""
+        types = self.layer_types or ()
+        if reader not in types:
+            return None
+        before = [li for li in range(types.index(reader)) if gives(li)]
+        return before[-1] if before else None
+
+    @property
+    def kv_donor(self) -> Optional[int]:
+        """The layer whose K/V the cross_attention layers read: the
+        full-attention layer before the first of them."""
+        return self._donor("cross_attention", lambda li: (
+            self.layer_types[li] == "attention"
+            and self.window_for_layer(li) == 0))
+
+    @property
+    def memory_donor(self) -> Optional[int]:
+        """The layer whose scan output the gated_memory layers gate
+        with: the last selective_scan layer before the first of them."""
+        return self._donor("gated_memory", lambda li: (
+            self.layer_types[li] == "selective_scan"))
+
+    @property
+    def n_kv_reader_layers(self) -> int:
+        """Layers that walk a K/V pool they do not own."""
+        return (self.layer_types or ()).count("cross_attention")
 
     @property
     def gdn_conv_dim(self) -> int:
@@ -729,7 +829,8 @@ class TransformerConfig:
         ((shape, dtype), ...) of its slot in each of the layer's pools
         (dtype None: the cache's): the convolution's carried inputs,
         and before them, for a kind whose heads carry a matrix
-        (_STATE_LAYERS: 'linear_attention', 'state_space'), the float32
+        (_STATE_LAYERS: 'linear_attention', 'state_space',
+        'selective_scan'), the float32
         matrices. The carried inputs are [conv_kernel - 1, channels]
         with the channels folded into whole lanes where they are some,
         and MORE than a tile's 8 lane rows padded to whole (8, 128)
@@ -991,6 +1092,33 @@ def _operator_shapes(cfg: TransformerConfig, kind: str):
             "ssm_norm_scale": ((I,), ("mlp",)),
             "ssm_out": ((I, E), ("mlp", "embed")),
         }
+    if kind == "selective_scan":
+        # `sscan_in` to [x; z] (the scan's input and the gate), the
+        # depthwise `sscan_taps` [channel, tap] over x, oldest tap
+        # first, and their `sscan_conv_bias`, `sscan_x` to [r; B; C]
+        # (the step's bottleneck of ssm_dt_rank, one B and one C of
+        # ssm_state_dim a token), `sscan_dt` and `sscan_dt_bias` from r
+        # to a step a channel, the decay's `sscan_a_log` a (channel,
+        # state) pair, the skip's `sscan_d` a channel (a plain leaf, as
+        # `ssm_d`), and `sscan_out`
+        I, N, R = cfg.ssm_inner, cfg.ssm_state_dim, cfg.ssm_dt_rank
+        return {
+            "sscan_in": ((E, 2 * I), ("embed", "mlp")),
+            "sscan_taps": ((I, cfg.conv_kernel), ("mlp", None)),
+            "sscan_conv_bias": ((I,), ("mlp",)),
+            "sscan_x": ((I, R + 2 * N), ("mlp", None)),
+            "sscan_dt": ((R, I), (None, "mlp")),
+            "sscan_dt_bias": ((I,), ("mlp",)),
+            "sscan_a_log": ((I, N), ("mlp", None)),
+            "sscan_d": ((I,), ("mlp",)),
+            "sscan_out": ((I, E), ("mlp", "embed")),
+        }
+    if kind == "gated_memory":
+        I = cfg.ssm_inner  # the width of what the donor's scan hands on
+        return {
+            "gmu_in": ((E, I), ("embed", "mlp")),
+            "gmu_out": ((I, E), ("mlp", "embed")),
+        }
     if cfg.is_latent:
         return _latent_attention_shapes(cfg)
     shapes = {
@@ -999,6 +1127,14 @@ def _operator_shapes(cfg: TransformerConfig, kind: str):
         "wv": ((E, KV, D), ("embed", "heads", "head_dim")),
         "wo": ((H, D, E), ("heads", "head_dim", "embed")),
     }
+    if kind == "cross_attention":  # the donor's K/V: no key, no value
+        del shapes["wk"], shapes["wv"]
+    if cfg.differential_attention:
+        # the four vectors of the layer's lam and the scale of the norm
+        # over a pair's 2 D values
+        shapes.update({f"diff_l{n}": ((D,), ("head_dim",))
+                       for n in ("q1", "k1", "q2", "k2")})
+        shapes["diff_norm_scale"] = ((2 * D,), (None,))
     if cfg.attn_output_gate:
         shapes["wq_gate"] = ((E, H, D), ("embed", "heads", "head_dim"))
     if cfg.qk_norm_per_head:
@@ -1011,8 +1147,9 @@ def _operator_shapes(cfg: TransformerConfig, kind: str):
         shapes["k_norm_scale"] = ((KV, D), ("heads", "head_dim"))
     if cfg.has_qkv_bias:
         shapes["bq"] = ((H, D), ("heads", "head_dim"))
-        shapes["bk"] = ((KV, D), ("heads", "head_dim"))
-        shapes["bv"] = ((KV, D), ("heads", "head_dim"))
+        if kind != "cross_attention":
+            shapes["bk"] = ((KV, D), ("heads", "head_dim"))
+            shapes["bv"] = ((KV, D), ("heads", "head_dim"))
     if cfg.has_attn_out_bias:
         shapes["bo"] = ((E,), ("embed",))
     return shapes
@@ -1024,15 +1161,18 @@ DENSE_PREFIX = "dense_"
 # layers are of several kinds (cfg.layer_types); its keys are the kinds
 OPERATOR_PREFIX = {"attention": "attn_", "conv": "conv_",
                    "linear_attention": "gdn_", "state_space": "ssm_",
-                   "experts": "moe_"}
+                   "experts": "moe_", "selective_scan": "sscan_",
+                   "gated_memory": "gmu_", "cross_attention": "xattn_"}
 LAYER_KINDS = tuple(OPERATOR_PREFIX)
 # what a layer of each kind that carries state holds a sequence: the
 # property that counts its convolution's channels, and the one that
 # gives its heads' float32 matrices (None: it has none). An attention
-# layer holds K/V; a kind in neither place ('experts') holds nothing
+# layer holds K/V; a kind in neither place ('experts', 'gated_memory',
+# 'cross_attention') holds nothing
 _STATE_LAYERS = {"conv": ("d_model", None),
                  "linear_attention": ("gdn_conv_dim", "gdn_state_shape"),
-                 "state_space": ("ssm_conv_dim", "ssm_state_shape")}
+                 "state_space": ("ssm_conv_dim", "ssm_state_shape"),
+                 "selective_scan": ("ssm_inner", "ssm_state_shape")}
 
 
 def operator_stacks(cfg: TransformerConfig):

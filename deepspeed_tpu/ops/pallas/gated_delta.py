@@ -314,14 +314,16 @@ def walk_plan(slots, positions, pool, batch: int, aside: int, tile: int):
 
 
 def _walk_kernel(body, batch: int, aside: int, tile: int, n_scalars: int,
-                 n_rows: int):
+                 n_rows: int, n_consts: int = 0):
     """The kernel of state_step_call around a row's `body`: a grid
     step is `tile` rows."""
     def kernel(flag_ref, buf_ref, brun_ref, bslot_ref, bbits_ref, aslot_ref,
                abits_ref, tiles_ref, ends_ref, *refs):
         scalars, refs = refs[:n_scalars], refs[n_scalars:]
         ins, ins_aside = refs[:n_rows], refs[n_rows:2 * n_rows]
-        _, o_ref, o_aside, pool_out, held, rsem, wsem = refs[2 * n_rows:]
+        consts = refs[2 * n_rows:2 * n_rows + n_consts]
+        _, o_ref, o_aside, pool_out, held, rsem, wsem = refs[
+            2 * n_rows + n_consts:]
         n = flag_ref.shape[0]
         g, steps = pl.program_id(0), pl.num_programs(0)
         safe = (flag_ref[0] & _SAFE) != 0
@@ -367,7 +369,7 @@ def _walk_kernel(body, batch: int, aside: int, tile: int, n_scalars: int,
                 pl.when((flag & 2) != 0)(lambda: heads(zero, after)),
                 pl.when((flag & 2) == 0)(lambda: heads(
                     lambda h: held[b, h], after)))
-            body(t, i, *scalars, *ins, o_ref, state)
+            body(t, i, *scalars, *ins, *consts, o_ref, state)
 
         def own_row(i, t):
             flag = flag_ref[t]
@@ -426,7 +428,8 @@ def _walk_kernel(body, batch: int, aside: int, tile: int, n_scalars: int,
 
 
 def state_step_call(body, name: str, out_row, pool, slots, positions,
-                    shape, interpreted: bool, scalars=(), rows=()):
+                    shape, interpreted: bool, scalars=(), rows=(),
+                    consts=()):
     """The walk every matrix-state step kernel shares (this file's
     `gdn_state`, ops/pallas/ssm_state.py's `ssm_state`). A grid step is
     a tile of the step's ragged rows; its block of each of `rows`
@@ -447,7 +450,9 @@ def state_step_call(body, name: str, out_row, pool, slots, positions,
     of several rows are computed under those copies, from blocks of
     their own (the second set of `rows`' blocks and of the output's).
     A run that starts at position 0 and a pad row fetch nothing; a pad
-    row writes nothing. `body(t, i, *scalars' refs, *rows' refs,
+    row writes nothing. `consts` are arrays every row reads whole (a
+    layer's rates): one block each, fetched once and kept in VMEM.
+    `body(t, i, *scalars' refs, *rows' refs, *consts' refs,
     o_ref, state)` computes row t, row i of its blocks:
     `state(heads)` runs `heads(before, after)`, before(h) matrix h of
     the run's state so far, after(h, S) what the row leaves of it.
@@ -473,7 +478,9 @@ def state_step_call(body, name: str, out_row, pool, slots, positions,
         num_scalar_prefetch=n_scalars,
         grid=(S_rows // tile,),
         in_specs=[*(own(*a.shape[1:]) for a in rows),
-                  *(aside_of(*a.shape[1:]) for a in rows), hbm],
+                  *(aside_of(*a.shape[1:]) for a in rows),
+                  *(pl.BlockSpec(a.shape, lambda g, *refs, n=a.ndim: (0,) * n)
+                    for a in consts), hbm],
         out_specs=[own(*out_row), aside_of(*out_row), hbm],
         scratch_shapes=[
             pltpu.VMEM((2 * batch + aside, *pool.shape[1:]), F32),
@@ -482,17 +489,19 @@ def state_step_call(body, name: str, out_row, pool, slots, positions,
     )
     out = jax.ShapeDtypeStruct((S_rows, *out_row), F32)
     o, o_aside, pool = pl.pallas_call(
-        _walk_kernel(body, batch, aside, tile, len(scalars), len(rows)),
+        _walk_kernel(body, batch, aside, tile, len(scalars), len(rows),
+                     len(consts)),
         grid_spec=grid_spec,
         out_shape=[out, out, jax.ShapeDtypeStruct(pool.shape, pool.dtype)],
-        # the pool: the operand after the scalars and the rows' inputs
-        input_output_aliases={n_scalars + 2 * len(rows): 2},
+        # the pool: the operand after the scalars, the rows' inputs and
+        # the constants
+        input_output_aliases={n_scalars + 2 * len(rows) + len(consts): 2},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=_STEP_VMEM_LIMIT),
         interpret=interpreted,
         name=name,
-    )(*walk, *scalars, *rows, *rows, pool)
+    )(*walk, *scalars, *rows, *rows, *consts, pool)
     is_aside = ((walk[0] & _ASIDE) != 0).reshape((S_rows,) + (1,) * len(
         out_row))
     return jnp.where(is_aside, o_aside, o), pool

@@ -396,6 +396,21 @@ def kv_heads_held(kv_heads: int, head_dim: int, itemsize: int) -> int:
     return -(-kv_heads // 8) * 8
 
 
+def kv_pair_fold(pairs: int, width: int, itemsize: int = 2) -> int:
+    """Pairs of K/V heads (differential attention: a pair is ONE head
+    of `width` = 2 head_dim values) that lie side by side in ONE head
+    of a pool: the fewest that leave the pool's heads whole tiles of
+    its HBM layout (_whole_tiles: 10 pairs of 128 values in 16 bits
+    would lie in 16 heads' room; 5 a head are 2 heads of 640, the same
+    bytes and no padding); 1 where no fold does. Asked for the served
+    16 bits whatever a test's dtype, so that the layout is the model's.
+    Who allocates a pool asks this (as kv_pack and kv_heads_held); the
+    serving model lays the new rows and the queries against the pool's
+    heads (inference/model.py kv_pool_shape, _pool_rows, _fold_pairs)."""
+    return next((f for f in range(1, pairs + 1) if pairs % f == 0
+                 and _whole_tiles(pairs // f, width * f, itemsize)), 1)
+
+
 def kv_write_path(pool_shape, dtype) -> str:
     """How paged_kv_write reaches a pool [NBLK, bs, KV, D] of this shape
     and dtype: "rows" (a row's own bytes DMA'd to its slot,

@@ -152,7 +152,8 @@ _ARCH_OF_MODEL_TYPE = {"olmoe": "OlmoeForCausalLM",
                        "mellum": "MellumForCausalLM",
                        "nemotron_h": "NemotronHForCausalLM",
                        "afmoe": "AfmoeForCausalLM",
-                       "olmo_hybrid": "OlmoHybridForCausalLM"}
+                       "olmo_hybrid": "OlmoHybridForCausalLM",
+                       "phi4flash": "Phi4FlashForCausalLM"}
 # config.json keys that change what a BLOCK computes (latent attention,
 # shared experts, leading dense layers, a second norm, a scaled, grouped
 # or biased router, layers of another kind than attention): an
@@ -199,10 +200,13 @@ _AFMOE_KEYS = (
     "score_func", "use_grouped_mm")
 # a delta rule whose write strength reaches 2 (OLMo-class hybrids)
 _NEG_EIGVAL_KEYS = ("linear_allow_neg_eigval",)
+# a decoder whose second half reads its first (SambaY-class `phi4flash`):
+# which mixer a layer has by a rule of the depth, a Mamba-1 scan's rank
+_SAMBAY_KEYS = ("mb_per_layer", "mamba_dt_rank")
 _BLOCK_KEYS = tuple(dict.fromkeys(
     _LATENT_MOE_KEYS + _HYBRID_KEYS + _LINEAR_ATTENTION_KEYS
     + _STATE_SPACE_KEYS + _MIXED_WINDOW_KEYS + _MIXER_ONLY_KEYS
-    + _AFMOE_KEYS + _NEG_EIGVAL_KEYS))
+    + _AFMOE_KEYS + _NEG_EIGVAL_KEYS + _SAMBAY_KEYS))
 # the block keys each architecture's mapping reads; any other stays an
 # error for it too
 _READS_BLOCK_KEYS = {
@@ -228,6 +232,8 @@ _READS_BLOCK_KEYS = {
         "layer_types", "linear_conv_kernel_dim", "linear_key_head_dim",
         "linear_value_head_dim", "linear_num_key_heads",
         "linear_num_value_heads", "rope_parameters")),
+    "Phi4FlashForCausalLM": frozenset(_SAMBAY_KEYS + (
+        "mamba_d_state", "mamba_d_conv", "mamba_expand")),
 }
 
 
@@ -245,7 +251,7 @@ SUPPORTED_ARCHITECTURES = sorted(_LLAMA_FAMILY | {
     "PanguUltraMoEForCausalLM", "Lfm2MoeForCausalLM",
     "Qwen3NextForCausalLM", "GraniteMoeHybridForCausalLM",
     "MellumForCausalLM", "NemotronHForCausalLM", "AfmoeForCausalLM",
-    "OlmoHybridForCausalLM",
+    "OlmoHybridForCausalLM", "Phi4FlashForCausalLM",
     "GPT2LMHeadModel", "OPTForCausalLM", "FalconForCausalLM",
     "RWForCausalLM",  # falcon's pre-rename arch string
     "PhiForCausalLM", "QWenLMHeadModel",
@@ -288,6 +294,8 @@ def config_from_hf(hf: Dict[str, Any], **overrides) -> TransformerConfig:
         kw = _afmoe_config(hf)
     elif arch == "OlmoHybridForCausalLM":
         kw = _olmo_hybrid_config(hf)
+    elif arch == "Phi4FlashForCausalLM":
+        kw = _phi4flash_config(hf)
     elif arch in _LLAMA_FAMILY:
         kw = dict(
             vocab_size=hf["vocab_size"],
@@ -1048,6 +1056,148 @@ def _olmo_hybrid_config(hf: Dict[str, Any]) -> Dict[str, Any]:
     )
 
 
+# what _phi4flash_config reads of a phi4flash config.json, and what it
+# checks and computes nothing from
+_PHI4FLASH_READS = frozenset((
+    "vocab_size", "num_hidden_layers", "num_attention_heads",
+    "num_key_value_heads", "hidden_size", "intermediate_size",
+    "max_position_embeddings", "layer_norm_eps", "tie_word_embeddings",
+    "sliding_window", "mb_per_layer", "mamba_d_state", "mamba_d_conv",
+    "mamba_expand", "mamba_dt_rank"))
+_PHI4FLASH_ROTARY = ("rope_theta", "rope_scaling", "partial_rotary_factor",
+                     "rotary_pct", "rotary_emb_base")
+_PHI4FLASH_DROPOUT = ("embd_pdrop", "resid_pdrop", "attention_dropout",
+                      "attn_pdrop")
+_PHI4FLASH_CHECKS = frozenset(
+    ("hidden_act", "mlp_bias", "lm_head_bias")
+    + _PHI4FLASH_ROTARY + _PHI4FLASH_DROPOUT)
+# what every config.json carries that no block reads (a key that starts
+# with `_` is a note)
+_HF_HOUSEKEEPING_KEYS = frozenset((
+    "architectures", "model_type", "auto_map", "torch_dtype", "dtype",
+    "transformers_version", "use_cache", "initializer_range",
+    "bos_token_id", "eos_token_id", "pad_token_id"))
+# what a CONFIGURATION FILE carries beside its config.json keys: the
+# mappings above read such files whole (_held_share reads `reduced` and
+# `experts_held`), because the benchmark's runners hand config_from_hf
+# the file as it is. Only a mapping that refuses what it
+# does not read has to name the rest; the list goes when the runners
+# strip their own keys first (a `benchmark` PR's edit: PERF.md section 7)
+_CONFIGURATION_FILE_KEYS = frozenset((
+    "source", "published", "reference", "reduced", "assumed", "stands_for",
+    "serve", "train"))
+
+
+def _phi4flash_config(hf: Dict[str, Any]) -> Dict[str, Any]:
+    """Phi-4-mini-flash (`phi4flash`; SambaY, arXiv:2507.06607, with
+    differential attention): which mixer a layer has follows from
+    `mb_per_layer` 2 and the depth L alone: a selective scan (Mamba-1)
+    at even l <= L / 2, differential attention in a window of
+    `sliding_window` at odd l < L / 2, ONE full layer at L / 2 + 1,
+    a gated memory unit at even l above it (it gates with the last
+    scan's output) and cross attention at odd l above it (a query alone,
+    over the full layer's K/V). Every layer ends in a SwiGLU of
+    `intermediate_size` behind LayerNorms with a bias; no positions.
+
+    What the published file does not state is the family's: the scan's
+    sizes (`mamba_expand` 2, `mamba_d_state` 16, `mamba_d_conv` 4,
+    `mamba_dt_rank` "auto" = ceil(E / 16); read where present), biases
+    on q/k/v/o as the Phi family has them, none elsewhere.
+    `sliding_window` an int (the windowed layers' alone) or a list of
+    one entry a layer.
+
+    Refused by name, because nothing here computes it: `mb_per_layer`
+    other than 2, an odd depth, a rotary key that is set, dropout that
+    is not 0, `mlp_bias` or `lm_head_bias` true, an activation other
+    than silu, a window on a layer that reads another's K/V, and any
+    key this mapping does not read."""
+    unread = sorted(k for k in set(hf) - _PHI4FLASH_READS - _PHI4FLASH_CHECKS
+                    - _HF_HOUSEKEEPING_KEYS - _CONFIGURATION_FILE_KEYS
+                    if not k.startswith("_"))
+    if unread:
+        raise ValueError(
+            f"phi4flash with the keys {unread}: this mapping does not read "
+            "them, and a key that is not read would be served as if it "
+            "were absent; refusing a silently-wrong import")
+    if hf.get("mb_per_layer", 2) != 2:
+        raise ValueError(
+            f"phi4flash with mb_per_layer={hf['mb_per_layer']!r} is "
+            "unsupported (2: a scan every second layer)")
+    L = int(hf["num_hidden_layers"])
+    if L % 2 or L < 4:
+        raise ValueError(
+            f"phi4flash with num_hidden_layers={L}: the self-decoder and "
+            "the cross-decoder are halves of an even depth of 4 or more")
+    for key in _PHI4FLASH_ROTARY:
+        if hf.get(key):
+            raise ValueError(
+                f"phi4flash with {key}={hf[key]!r}: its layers are served "
+                "with no positions; a rotation is unsupported")
+    for key in _PHI4FLASH_DROPOUT:
+        if hf.get(key):
+            raise ValueError(
+                f"phi4flash with {key}={hf[key]!r} is unsupported (0)")
+    for key in ("mlp_bias", "lm_head_bias"):
+        if hf.get(key):
+            raise ValueError(
+                f"phi4flash with {key}={hf[key]!r} is unsupported")
+    if hf.get("hidden_act", "silu") != "silu":
+        raise ValueError(
+            f"phi4flash with hidden_act={hf['hidden_act']!r} is "
+            "unsupported (silu)")
+    half, E = L // 2, int(hf["hidden_size"])
+    types = tuple(
+        ("selective_scan" if l <= half else "gated_memory") if l % 2 == 0
+        else ("attention" if l <= half + 1 else "cross_attention")
+        for l in range(L))
+    window = hf.get("sliding_window")
+    if isinstance(window, (list, tuple)):
+        if len(window) != L:
+            raise ValueError(
+                f"phi4flash sliding_window lists one entry a layer: "
+                f"{len(window)} entries for num_hidden_layers={L}")
+        windows = tuple(int(w or 0) if t == "attention" else 0
+                        for w, t in zip(window, types))
+        walled = [l for l, (w, t) in enumerate(zip(window, types))
+                  if w and t == "cross_attention"]
+        if walled:
+            raise ValueError(
+                f"phi4flash sliding_window gives layers {walled} a window: "
+                "a layer that reads another's K/V attends causally and in "
+                "full")
+    else:
+        windows = tuple(int(window or 0) if l % 2 and l < half else 0
+                        for l in range(L))
+    inner = int(hf.get("mamba_expand", 2)) * E
+    rank = hf.get("mamba_dt_rank", "auto")
+    lanes = 128 if inner % 128 == 0 else inner  # a lane row of channels
+    return dict(
+        vocab_size=hf["vocab_size"],
+        n_layers=L,
+        n_heads=hf["num_attention_heads"],
+        n_kv_heads=hf.get("num_key_value_heads") or None,
+        d_model=E,
+        d_ff=hf["intermediate_size"],
+        max_seq=hf.get("max_position_embeddings", 4096),
+        variant="llama",
+        position_embedding="none",
+        norm_type="layer",
+        norm_eps=float(hf.get("layer_norm_eps", 1e-5)),
+        tie_embeddings=bool(hf.get("tie_word_embeddings", False)),
+        qkv_bias=True, attn_out_bias=True, mlp_bias=False,
+        differential_attention=True,
+        layer_types=types,
+        attention_window_pattern=windows,
+        conv_kernel=int(hf.get("mamba_d_conv", 4)),
+        ssm_heads=inner // lanes, ssm_head_dim=lanes,
+        ssm_state_dim=int(hf.get("mamba_d_state", 16)),
+        ssm_dt_rank=(math.ceil(E / 16) if rank == "auto" else int(rank)),
+        # tokens a whole-prompt scan holds as [chunk, channels, state]
+        # float32 pairs at a time: 21 MB a prompt at the published widths
+        ssm_chunk=64,
+    )
+
+
 def _granite_moe_hybrid_config(hf: Dict[str, Any]) -> Dict[str, Any]:
     """Granite 4.0-H (`granitemoehybrid`): `layer_types` names each
     layer `mamba` (the Mamba-2 mixer: `mamba_n_heads` heads of
@@ -1558,6 +1708,11 @@ def import_external(
             "import_external returns the flat [L, ...] layer stack; "
             "stage-partition afterwards via runtime.pipe.partition_layers"
         )
+    if _arch_of(hf) == "Phi4FlashForCausalLM":
+        raise NotImplementedError(
+            "phi4flash: the import is of the configuration alone "
+            "(config_from_hf); the mapping of the publisher's weight names "
+            "waits for a checkpoint's files")
     r = _CheckpointReader(path)
 
     cast: Callable[[np.ndarray], np.ndarray]

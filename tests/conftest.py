@@ -63,6 +63,15 @@ def pytest_sessionstart(session):
 # tests among them), and that a later PR's ADDED entries outgrow:
 # node id's end -> why it is expected to fail until that PR
 _OUTGROWN_BENCHMARK_PINS = {
+    "test_olmohybrid_readers.py::"
+    "test_the_cell_reports_what_the_other_delta_net_cell_reports":
+        "pins len(BENCHMARK.json workloads) == 12 and its own cell and "
+        "configuration as the LAST of every list (PR 58); PR 62 appended the "
+        "thirteenth cell, `serve-phi4flash-reasoning-saturated-r128`, after "
+        "it; that the Olmo-Hybrid cell is on the lists the other DeltaNet "
+        "cell is on stays held by test_phi4flash_readers.py::"
+        "test_the_older_cells_lists_are_appended_to_and_nothing_else; a "
+        "`benchmark` PR relaxes the pin and takes this entry away",
     "test_mellum2_readers.py::"
     "test_the_cell_reports_what_the_dense_cell_reports_but_its_rooflines":
         "pins len(BENCHMARK.json workloads) == 9 (PR 48); PR 51 added the "

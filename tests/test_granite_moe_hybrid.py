@@ -288,11 +288,13 @@ def test_the_kinds_and_their_tables_are_one():
     """A kind is an entry of each table, and the layer_types error names
     the kinds from the one tuple. A layer holds K/V ('attention'),
     state (the kinds of _STATE_LAYERS) or nothing ('experts', the routed
-    block as a layer of its own), by its kind."""
+    block as a layer of its own; 'gated_memory' and 'cross_attention',
+    which read another layer), by its kind."""
     from deepspeed_tpu.inference import scheduler as S
 
     assert T.LAYER_KINDS == tuple(T.OPERATOR_PREFIX)
-    state_kinds = set(T.LAYER_KINDS) - {"attention", "experts"}
+    state_kinds = set(T.LAYER_KINDS) - {"attention", "experts",
+                                        "gated_memory", "cross_attention"}
     assert set(T._STATE_LAYERS) == set(M._STATE_OPERATORS) == state_kinds
     cfg = T.TransformerConfig(
         n_layers=4, conv_kernel=4, ssm_heads=8, ssm_head_dim=16,
@@ -304,7 +306,7 @@ def test_the_kinds_and_their_tables_are_one():
     assert {k for _, k, *_ in T._operator_leaves(cfg)} == set(cfg.layer_types)
     matrix_kinds = {k for k, (_, m) in T._STATE_LAYERS.items() if m}
     assert set(M._STEP_OF) == set(M._SCAN_OF) == set(S._RUN_TOKENS) == \
-        matrix_kinds == {"linear_attention", "state_space"}
+        matrix_kinds == {"linear_attention", "state_space", "selective_scan"}
     with pytest.raises(ValueError, match="ssm_heads"):
         T.TransformerConfig(n_layers=2, conv_kernel=4, layer_types=(
             "attention", "state_space"))

@@ -48,6 +48,10 @@ KERNELS = {
         {"paged_decode_grid", "conv_carry", "ssm_state",
          "expert_stream_ungated"},
         WRITE_WALK | {"conv_carry", "ssm_state", "expert_stream_ungated"}),
+    # 3 scans, 2 rings and the paged layer, which a cross layer walks
+    # again (a pair of 64 in one K/V head: the fused write on the grid)
+    "tiny-phi4flash": ({"paged_decode_grid", "conv_carry", "sscan_state"},
+                       WRITE_WALK | {"conv_carry", "sscan_state"}),
 }
 KV_KERNELS = {"paged_decode_fused", "paged_kv_write", "paged_decode_grid",
               "paged_latent_write"}
@@ -81,8 +85,17 @@ def test_a_step_holds_each_kernel_once_and_calls_it_a_layer(name, unique):
     layers = {k: cfg.n_kv_layers if k in KV_KERNELS
               else routed if k.startswith("expert_stream")
               else cfg.n_state_layers for k in modules}
-    assert modules == {k: 1 + (cfg.mixed_windows and k in KV_KERNELS)
-                       for k in modules}
+    signatures = {k: 1 + (cfg.mixed_windows and k in KV_KERNELS)
+                  for k in modules}
+    # a layer that reads ANOTHER layer's pool walks it once more and
+    # writes nothing: one more site of the walk, and one more signature
+    # where the owners' walk carries their write under the same name
+    readers = cfg.n_kv_reader_layers
+    if readers:
+        layers["paged_decode_grid"] += readers
+        signatures["paged_decode_grid"] += (
+            unique and "paged_decode_fused" not in modules)
+    assert modules == signatures
     assert sites == layers
     assert all(n > 1 for n in layers.values())
 

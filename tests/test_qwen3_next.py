@@ -206,9 +206,12 @@ def test_layer_types_are_the_interval_or_as_named():
 
 def test_the_kinds_a_layer_can_be_come_from_one_tuple():
     assert T.LAYER_KINDS == ("attention", "conv", "linear_attention",
-                             "state_space", "experts")
+                             "state_space", "experts", "selective_scan",
+                             "gated_memory", "cross_attention")
     with pytest.raises(ValueError, match=r"one of \('attention', 'conv', "
-                       r"'linear_attention', 'state_space', 'experts'\)"):
+                       r"'linear_attention', 'state_space', 'experts', "
+                       r"'selective_scan', 'gated_memory', "
+                       r"'cross_attention'\)"):
         T.TransformerConfig(n_layers=2, layer_types=("attention", "mamba"))
     with pytest.raises(ValueError, match="gdn_key_heads"):
         T.TransformerConfig(n_layers=2, conv_kernel=4, layer_types=(

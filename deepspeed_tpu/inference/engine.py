@@ -534,6 +534,13 @@ class InferenceEngine:
             ))
             sp.set(**M.kv_write_ids(self.cache, model_config, self.mesh,
                                     self._use_kernel))
+            if not model_config.is_latent:
+                # KV heads a pool's head holds side by side (paged_
+                # attention.kv_pack), and heads held beyond the model's
+                pack = M.kv_pool_pack(self.cache, model_config)
+                sp.set(kv_pack=pack, kv_heads_padded=(
+                    self.cache.k[0].shape[2] * pack
+                    - M.kv_pool_shape(model_config)[0]))
             if rings:
                 pools = pool_bytes(model_config, self.config, dtype)
                 held = model_config.ring_layers

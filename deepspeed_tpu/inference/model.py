@@ -80,7 +80,6 @@ from ..ops.pallas.ssm_state import (
 )
 from ..ops.pallas.paged_attention import (
     fused_write_fits,
-    kv_heads_held,
     kv_pack,
     kv_pair_fold,
     kv_write_path,
@@ -413,9 +412,11 @@ def _shard_map_kernel(fn, mesh: Mesh, in_specs, out_specs):
 class PagedCache(NamedTuple):
     """Per-layer lists of [NBLK, bs, KV, D] arrays, one entry for each
     layer that holds K/V (cfg.n_kv_layers: every layer, or the
-    attention layers of a model of two kinds, in their order). At head
-    dim 64 a pool is PACKED, [NBLK, bs, KV / 2, 128] (kv_pack: two
-    heads a 128-lane row, the same bytes).
+    attention layers of a model of two kinds, in their order). A pool
+    is PACKED, [NBLK, bs, KV / f, f D] (kv_pack: the same bytes, f
+    heads side by side in one), at head dim 64 (two heads a 128-lane
+    row) and where more than 8 heads are no whole tiles of the layout
+    (30 heads of 128 in 16 bits: 2 heads of 1,920).
 
     A model of mixed windows (cfg.mixed_windows) holds pools of TWO
     sizes in these lists: a full layer's [NBLK, ...] paged by a
@@ -506,6 +507,13 @@ def kv_pool_shape(cfg: T.TransformerConfig) -> Tuple[int, int]:
     return pairs // fold, width * fold
 
 
+def kv_pool_pack(cache: "PagedCache", cfg: T.TransformerConfig) -> int:
+    """KV heads a head of this cache's K/V pools holds side by side
+    (paged_attention.kv_pack as init_cache asked it; 1: not packed),
+    read off the allocated pool's lanes as the kernels' entry reads it."""
+    return cache.k[0].shape[3] // kv_pool_shape(cfg)[1]
+
+
 def init_cache(
     cfg: T.TransformerConfig, num_blocks: int, block_size: int, dtype=jnp.bfloat16,
     mesh: Optional[Mesh] = None, kv_quant: bool = False,
@@ -539,12 +547,11 @@ def init_cache(
         shape = (num_blocks, block_size, latent_lanes(cfg.latent_dim))
         return PagedCache(k=[jnp.zeros(shape, dtype) for _ in range(L)],
                           v=[])
-    # unquantised pools on one device pack two 64-wide heads a row, and
-    # hold whole tiles of heads (30 of 128 in 32: kv_heads_held)
+    # unquantised pools on one device lay heads side by side where the
+    # layout would pad them (two of 64 a lane row; 30 of 128 as 2 x 1,920)
     pack = 1
     if not kv_quant and mesh is None:
-        pack = kv_pack(KV, D)
-        KV = kv_heads_held(KV, D, jnp.dtype(dtype).itemsize)
+        pack = kv_pack(KV, D, jnp.dtype(dtype).itemsize)
     shape = (num_blocks, block_size, KV // pack, D * pack)
     if kv_quant:
         dtype = jnp.int8
@@ -742,8 +749,6 @@ def _write_pools(pools: tuple, k_new, v_new, flat_idx, mesh=None,
     if len(pools) == 1:
         write = paged_latent_write if use_kernel else paged_latent_write_xla
         return (write(pools[0], k_new, flat_idx),)
-    k_new, v_new = (_to_heads(r, _pool_heads(pools[0], r))
-                    for r in (k_new, v_new))
     if len(pools) == 4:
         ck, cv, *scales = _write_kv_quant(*pools, k_new, v_new, flat_idx,
                                           mesh, use_kernel)
@@ -1148,22 +1153,6 @@ def _moe_residual(out, h, lp, cfg: T.TransformerConfig, act):
             + dense * coef[:, 1:2].astype(h.dtype))
 
 
-def _pool_heads(pool, rows) -> int:
-    """KV heads a pool [NBLK, bs, KV, D] holds, for rows [T, heads, D']:
-    0 for a PACKED pool (D' under the pool's lanes: kv_pack), whose
-    heads are never padded."""
-    return pool.shape[2] if pool.shape[3] == rows.shape[-1] else 0
-
-
-def _to_heads(x, heads: int):
-    """x [T, h, D] with zero heads appended up to `heads` (the heads a
-    pool holds, kv_heads_held: 30 in 32); as it is where it has as many
-    (or `heads` is a packed pool's 0), and None for None."""
-    if x is None or x.shape[1] >= heads:
-        return x
-    return jnp.pad(x, [(0, 0), (0, heads - x.shape[1]), (0, 0)])
-
-
 def _paired_heads(q, k, v, cfg: T.TransformerConfig):
     """Differential attention's pairs as heads of twice the width, in
     the order the projections leave them: q [..., H, D] -> [..., H, 2D],
@@ -1235,15 +1224,14 @@ def _pool_rows(rows, cfg: T.TransformerConfig):
 
 def _decode_attention(q, pools: tuple, table, ctx, use_kernel: bool,
                       window: int = 0, mesh=None, alibi=None,
-                      k_new=None, v_new=None, slots=None, kv_heads: int = 0):
+                      k_new=None, v_new=None, slots=None):
     """WHERE one decode attention call over a layer's pools
     (_layer_pools) runs. Which Pallas kernel serves it is
     paged_decode_attention's business, read there from the same
     arguments (k_new/v_new/slots: the write fused into the call, the
     pools PRE-write and the result (att, *updated pools); scale pools:
-    int8 KV; window; alibi, the [H] per-head slopes; kv_heads: the
-    model's own count of KV heads, where the pool may hold more,
-    kv_heads_held). One of three places:
+    int8 KV; window; alibi, the [H] per-head slopes). One of three
+    places:
 
     - one device (use_kernel, no 'model' axis): the kernel entry as it
       is, the only place a fused write can run;
@@ -1255,21 +1243,6 @@ def _decode_attention(q, pools: tuple, table, ctx, use_kernel: bool,
       raw pallas_call cannot consume sharded operands; SPMD partitions
       the gather freely)."""
     ck, cv, *scales = pools
-    held = _pool_heads(ck, q)
-    if kv_heads and held > kv_heads:
-        # a pool that holds whole tiles of heads (kv_heads_held): the
-        # padding heads' queries are zeros, as their rows are, and what
-        # they attend is cut off
-        H = q.shape[1]
-        wide = held * (H // kv_heads)
-        out = _decode_attention(
-            _to_heads(q, wide), pools, table, ctx, use_kernel, window, mesh,
-            None if alibi is None else jnp.pad(
-                jnp.asarray(alibi, jnp.float32), (0, wide - H)),
-            _to_heads(k_new, held), _to_heads(v_new, held), slots)
-        if k_new is None:
-            return out[:, :H]
-        return (out[0][:, :H], *out[1:])
     opt = dict(zip(("k_scale", "v_scale"), scales))  # what is present
     if alibi is not None:
         opt["alibi_slopes"] = jnp.asarray(alibi, jnp.float32)
@@ -2020,8 +1993,6 @@ def decode_step(
     fuse_write = (unique_rows and use_kernel and _tp_size(mesh) <= 1
                   and fused_write_fits(tokens.shape[0]))
 
-    pool_heads, _ = kv_pool_shape(cfg)
-
     def attend(q, k, v, li, alibi, lp, donor=None):
         if cfg.is_latent:  # k: the rows the cache holds
             return _latent_absorbed(q, k, lp, *_layer_pools(cache, li),
@@ -2034,8 +2005,7 @@ def decode_step(
             # a cross layer: the donor's pools as its own attend left
             # them, this step's rows in them; a full walk, and no write
             att = _decode_attention(q, donor[2], tables, ctx_lens,
-                                    use_kernel, 0, mesh, alibi,
-                                    kv_heads=pool_heads)
+                                    use_kernel, 0, mesh, alibi)
             return unfold(att), None
         window = cfg.window_for_layer(li)
         table, flat = by_window[cfg.ring_layers[cfg.op_index(li)]]
@@ -2044,12 +2014,10 @@ def decode_step(
         k, v = _pool_rows(k, cfg), _pool_rows(v, cfg)
         if fuse_write:
             att, *pools = _decode_attention(q, pools, *where, k_new=k,
-                                            v_new=v, slots=flat,
-                                            kv_heads=pool_heads)
+                                            v_new=v, slots=flat)
             return unfold(att), pools
         pools = _write_pools(pools, k, v, flat, mesh, use_kernel)
-        return unfold(_decode_attention(q, pools, *where,
-                                        kv_heads=pool_heads)), pools
+        return unfold(_decode_attention(q, pools, *where)), pools
 
     @partial(_slot_wide, cache=cache, cfg=cfg)
     def carry(u, taps, pool):

@@ -67,7 +67,6 @@ import numpy as np
 
 from ..config.config import ServingSchedulerConfig
 from ..ops.pallas.paged_attention import (
-    kv_pack,
     latent_walk_reads,
     walk_reads,
 )
@@ -77,7 +76,7 @@ from ..utils import profiler
 from ..utils.logging import log_dist
 from ..utils.sync import serving_readback
 from .engine import InferenceEngine, _bucket, refuse_for_pools
-from .model import kv_pool_shape
+from .model import kv_pool_pack, kv_pool_shape
 from .pressure import BROWNOUT, RED, PressureGovernor, estimate_ttft
 from .ragged import KVCacheExhaustedError
 
@@ -1086,9 +1085,9 @@ class ServingScheduler:
                                                   cfg.n_heads)
                 self.counters["mla_grouped_rows"] += tiled
             elif tables is not None:
-                KV, D = kv_pool_shape(cfg)
                 blocks, rode = walk_reads(
-                    tables, ctx, bs, cfg.n_heads // KV * kv_pack(KV, D))
+                    tables, ctx, bs, cfg.n_heads // kv_pool_shape(cfg)[0],
+                    kv_pool_pack(self.engine.cache, cfg))
                 self.counters["kv_grouped_rows"] += rode
             self.counters["kv_block_reads"] += blocks
             if cfg.is_latent:

@@ -27,7 +27,9 @@ the arguments, dtype and shape it is handed, nothing else):
   of its own, walked alone, _walk_live_blocks) and the unfused,
   unquantised attention the shared-table program runs, at head dims
   that are multiples of 128 and, through PACKED pools (kv_pack: two KV
-  heads of 64 in one 128-lane row), at head dim 64. In the
+  heads of 64 in one 128-lane row; 30 heads of 128, no whole tiles,
+  as 2 heads of 1,920), at head dim 64 and at head counts the layout
+  would pad. In the
   shared-table attention ADJACENT rows with equal tables (a prefill
   chunk's rows: walk_groups) walk as one GROUP of up to 256 / Gp rows
   (32 where a KV head serves up to 8 query heads): the group's first
@@ -48,7 +50,8 @@ the arguments, dtype and shape it is handed, nothing else):
   are neither a multiple of 128 nor packed, and block shapes Mosaic
   refuses as a manual DMA (_walks_live_blocks: a minor dim that is not
   whole lanes, so head dims other than 64 below 128, or 64 with an odd
-  KV count, or under a mesh or int8, where pools are not packed).
+  KV count, or heads that are no whole tiles under a mesh or int8,
+  where pools are not packed).
 
 paged_kv_write is the shared-table program's cache store, once a K/V
 layer before the walk: a row goes to cache[blk, off] by ONE DMA of its
@@ -332,26 +335,44 @@ def _decode_kernel(
         )
 
 
-def kv_pack(kv_heads: int, head_dim: int) -> int:
-    """KV heads that share one 128-lane row of a PACKED pool: 2 at head
-    dim 64 with an even count of KV heads, else 1 (not packed).
+def kv_pack(kv_heads: int, head_dim: int, itemsize: int) -> int:
+    """KV heads that lie side by side in one head of a PACKED pool
+    [NB, bs, KV / f, f D] (1: not packed), for a pool of `itemsize`
+    bytes a value:
 
-    The TPU's tiled HBM layout pads a 64-wide minor dim to 128 lanes,
-    so a pool [NB, bs, KV, 64] would take, and stream, twice its bytes,
-    and Mosaic refuses a manual DMA of such a block (_walks_live_blocks).
-    A packed pool is [NB, bs, KV / 2, 128]: the same row-major bytes,
-    heads 2p and 2p + 1 side by side in row p. Every kernel here then
-    runs UNCHANGED at (KV / 2, 128): the queries of a pair's two heads
-    become one group whose rows are zero outside their own head's 64
-    lanes (block-diagonal, _pack_queries), so one 128-deep score matmul
-    gives both heads' scores, and of the 128 output lanes each row
-    keeps its own head's 64 (_unpack_out). The MXU multiplies twice the
-    needed values; the walk is bound by its DMAs and its per-(head,
-    block) loop, which halves. Who allocates a pool asks this (and
+    - 2 at head dim 64 with an even count of KV heads. The TPU's tiled
+      HBM layout pads a 64-wide minor dim to 128 lanes, so a pool
+      [NB, bs, KV, 64] would take, and stream, twice its bytes, and
+      Mosaic refuses a manual DMA of such a block (_walks_live_blocks);
+    - at head dims that are multiples of 128, for more than the
+      layout's 8-row tile of heads that are no whole tiles
+      (_whole_tiles: 30 heads of 128 in 16 bits, which the layout
+      would pad to 32 and keep on the (S, NB) grid): the fewest that
+      leave whole tiles with nothing padded (kv_pair_fold's rule: 15,
+      a pool of 2 heads of 1,920). On a v5e the shared-table walk of
+      128 rows over 389 live blocks takes 1.28 ms so against 2.21 ms
+      over 30 heads held in 32 (2.39 as 8 heads of 512, 1.95 as 2 of
+      2,048 on the grid: PERF.md section 6, PR 63). Counts of up to 8
+      stay as they are (what their layouts pad was not read).
+
+    A packed pool holds the same row-major bytes, heads f p .. f p +
+    f - 1 side by side in row p. Every kernel here then runs UNCHANGED
+    at (KV / f, f D): the queries of a row's heads become one group
+    whose rows are zero outside their own head's lanes (block-diagonal,
+    _pack_queries), so one score matmul over the f D lanes gives every
+    head's scores, and of the output lanes each row keeps its own
+    head's (_unpack_out). The MXU multiplies f times the needed
+    values; the walk is bound by its DMAs and its per-(head, block)
+    loop, which shrinks f-fold. Who allocates a pool asks this (and
     packs only unquantised pools on one device: scale tiles and head
     sharding are per KV head); everything below reads the packing off
     the shapes it is handed."""
-    return 2 if head_dim == 64 and kv_heads % 2 == 0 else 1
+    if head_dim == 64 and kv_heads % 2 == 0:
+        return 2
+    if (head_dim % 128 == 0 and kv_heads > 8
+            and not _whole_tiles(kv_heads, head_dim, itemsize)):
+        return kv_pair_fold(kv_heads, head_dim, itemsize)
+    return 1
 
 
 def _whole_tiles(kv_heads: int, head_dim: int, itemsize: int) -> bool:
@@ -374,28 +395,6 @@ def _whole_tiles(kv_heads: int, head_dim: int, itemsize: int) -> bool:
     return kv_heads % tile == 0
 
 
-def kv_heads_held(kv_heads: int, head_dim: int, itemsize: int) -> int:
-    """KV heads a pool HOLDS: `kv_heads`, or, for more than the layout's
-    8-row tile of them that are not whole tiles (_whole_tiles: 30 heads
-    of 128 in 16 bits), the next count that is (32). The tiled HBM
-    layout pads such a pool to that count anyway (the memref Mosaic is
-    handed for [.., 30, 128] is [.., 32, 128]: "Slice shape along
-    dimension 2 must be aligned to tiling (8), but is 30"), so the
-    padding heads take no memory that was not taken, and WITH them the
-    live-block walk, the fused write and the row write take the pool,
-    where 30 heads stay on the (S, NB) grid and write whole blocks.
-    Counts under the tile stay as they are (what their layouts pad was
-    not read here). Who allocates a pool asks this (unquantised pools
-    on one device, as kv_pack); the serving model pads the new rows'
-    and the queries' heads with zeros to the pool's (inference/model.py
-    _write_pools, _decode_attention: a padding head attends zeros with
-    a zero query and is cut from the output)."""
-    if (head_dim % 128 or kv_heads <= 8
-            or _whole_tiles(kv_heads, head_dim, itemsize)):
-        return kv_heads
-    return -(-kv_heads // 8) * 8
-
-
 def kv_pair_fold(pairs: int, width: int, itemsize: int = 2) -> int:
     """Pairs of K/V heads (differential attention: a pair is ONE head
     of `width` = 2 head_dim values) that lie side by side in ONE head
@@ -404,9 +403,10 @@ def kv_pair_fold(pairs: int, width: int, itemsize: int = 2) -> int:
     would lie in 16 heads' room; 5 a head are 2 heads of 640, the same
     bytes and no padding); 1 where no fold does. Asked for the served
     16 bits whatever a test's dtype, so that the layout is the model's.
-    Who allocates a pool asks this (as kv_pack and kv_heads_held); the
-    serving model lays the new rows and the queries against the pool's
-    heads (inference/model.py kv_pool_shape, _pool_rows, _fold_pairs)."""
+    Who allocates a pool asks this (kv_pack asks it for plain heads
+    that are no whole tiles); for the pairs the serving model lays the
+    new rows and the queries against the pool's heads itself
+    (inference/model.py kv_pool_shape, _pool_rows, _fold_pairs)."""
     return next((f for f in range(1, pairs + 1) if pairs % f == 0
                  and _whole_tiles(pairs // f, width * f, itemsize)), 1)
 
@@ -431,26 +431,43 @@ def _packing(q, k_cache) -> int:
     return k_cache.shape[3] // q.shape[2]
 
 
+def _packed_group(n: int) -> int:
+    """Query rows a packed pool head's group is laid out in
+    (_pack_queries): its n = kv_pack x G queries, more than a sublane
+    tile of them filled up to whole tiles (15 -> 16) so that a chunk's
+    rows stack by a free reshape and walk as one group (_group_rows);
+    up to 8 are _group_queries' to pad, as every pool's."""
+    return n if n <= 8 else -(-n // 8) * 8
+
+
 def _pack_queries(q, pack: int, G: int):
-    """[S, H, D] -> [S, H, pack * D], head h's values in the lanes of
-    its place (h // G) % pack within its pair's pool row, zeros in the
-    other's (G = queries a KV head)."""
+    """[S, H, D] -> [S, H / (pack G) * Gq, pack * D], head h's values
+    in the lanes of its place (h // G) % pack within its pool row,
+    zeros in the others' (G = queries a KV head); a pool row's pack * G
+    queries followed by zero rows up to Gq = _packed_group of them."""
     S, H, D = q.shape
+    n, rows = H // (pack * G), _packed_group(pack * G)
     eye = jnp.eye(pack, dtype=q.dtype)
-    return jnp.einsum(
-        "skigd,ij->skigjd", q.reshape(S, H // (pack * G), pack, G, D), eye
-    ).reshape(S, H, pack * D)
+    wide = jnp.einsum(
+        "skigd,ij->skigjd", q.reshape(S, n, pack, G, D), eye)
+    if rows != pack * G:  # (else ONE reshape: the packed cells' pinned text)
+        wide = jnp.pad(wide.reshape(S, n, pack * G, pack * D),
+                       ((0, 0), (0, 0), (0, rows - pack * G), (0, 0)))
+    return wide.reshape(S, n * rows, pack * D)
 
 
 def _unpack_out(out, pack: int, G: int):
-    """[S, H, pack * D] -> [S, H, D]: each row's own head's lanes."""
-    S, H, PD = out.shape
-    D = PD // pack
+    """[S, H / (pack G) * Gq, pack * D] -> [S, H, D]: each query's own
+    head's lanes (the rows _pack_queries filled up are cut)."""
+    S, HP, PD = out.shape
+    D, rows = PD // pack, _packed_group(pack * G)
     eye = jnp.eye(pack, dtype=out.dtype)
+    if rows != pack * G:
+        out = out.reshape(S, HP // rows, rows, PD)[:, :, :pack * G]
     return jnp.einsum(
         "skigjd,ij->skigd",
-        out.reshape(S, H // (pack * G), pack, G, pack, D), eye
-    ).reshape(S, H, D)
+        out.reshape(S, HP // rows, pack, G, pack, D), eye
+    ).reshape(S, HP // rows * pack * G, D)
 
 
 def _pack_rows(new, pool):
@@ -488,10 +505,10 @@ def paged_decode_attention(q, k_cache, v_cache, block_table, ctx_lens,
     flag:
 
     - k_new/v_new/slots given, unquantised, head dim a multiple of 128
-      (supports_fused_v2) and a pool that holds what kv_heads_held
-      would make it hold (30 heads not held in 32 are no whole tiles,
-      and Mosaic refuses the row's DMA): paged_decode_fused, the
-      per-row live-block walk with the new row DMA'd into its slot;
+      (supports_fused_v2) and a pool whose heads are whole tiles
+      (_whole_tiles: Mosaic refuses the row's DMA into any other):
+      paged_decode_fused, the per-row live-block walk with the new
+      row DMA'd into its slot;
     - attend only, unquantised, a block shape Mosaic takes as a manual
       DMA (_walks_live_blocks): the live-block walk, grid (S,) — each
       row reads the live blocks of its table and nothing else, so the
@@ -509,8 +526,9 @@ def paged_decode_attention(q, k_cache, v_cache, block_table, ctx_lens,
     - everything else on the (S, NB) BlockSpec grid: int8 KV (k_scale
       given: the scale tiles ride the index maps), the fused write at
       other head dims, and block shapes the walk cannot take
-      (D % 128 != 0; 16-bit pools whose KV count is not 2, 4 or a
-      multiple of 8).
+      (_whole_tiles: D % 128 != 0; 16-bit pools whose KV count is
+      not 2, 4 or a multiple of 8, which are those kv_pack does not
+      pack: 1, 3, 5-7 heads, or any such count under a mesh).
 
     The attend-only pallas_call is named `paged_decode_grid` whatever
     its grid: in a trace that name means "the shared-table decode
@@ -543,10 +561,11 @@ def paged_decode_attention(q, k_cache, v_cache, block_table, ctx_lens,
       at a reserved scratch block, since the grid writes each row's
       target block back even when nothing changed. The write slot must
       be ctx-1's flat slot.
-    A PACKED pool (kv_pack: [num_blocks, block_size, KV / 2, 128] for
-    head dim 64, told from the shapes) is attended at (KV / 2, 128)
-    through this same entry, queries block-diagonal, k_new/v_new
-    reshaped, `scale` (default 1/sqrt(D)) kept the true head dim's.
+    A PACKED pool (kv_pack: [num_blocks, block_size, KV / f, f D],
+    f = 2 at head dim 64, 15 for 30 heads of 128; told from the
+    shapes) is attended at (KV / f, f D) through this same entry,
+    queries block-diagonal, k_new/v_new reshaped, `scale` (default
+    1/sqrt(D)) kept the true head dim's.
     returns: [S, H, D] (fused: (out, k_cache, v_cache))
     """
     S, H, D = q.shape
@@ -557,6 +576,12 @@ def paged_decode_attention(q, k_cache, v_cache, block_table, ctx_lens,
     pack = _packing(q, k_cache)
     if pack > 1:
         G = H // (KV * pack)
+        rows = _packed_group(pack * G)
+        if alibi_slopes is not None and rows != pack * G:
+            # the rows _pack_queries fills a group up with: slope 0
+            alibi_slopes = jnp.pad(
+                jnp.asarray(alibi_slopes, jnp.float32).reshape(KV, pack * G),
+                ((0, 0), (0, rows - pack * G))).reshape(-1)
         out = paged_decode_attention(
             _pack_queries(q, pack, G), k_cache, v_cache, block_table,
             ctx_lens, window, _pack_rows(k_new, k_cache),
@@ -565,8 +590,8 @@ def paged_decode_attention(q, k_cache, v_cache, block_table, ctx_lens,
         if fused:
             return (_unpack_out(out[0], pack, G), *out[1:])
         return _unpack_out(out, pack, G)
-    if fused and not quant and supports_fused_v2(D) and kv_heads_held(
-            KV, D, k_cache.dtype.itemsize) == KV:
+    if fused and not quant and supports_fused_v2(D) and _whole_tiles(
+            KV, D, k_cache.dtype.itemsize):
         return paged_decode_fused(q, k_cache, v_cache, block_table, ctx_lens,
                                   k_new, v_new, slots, window=window,
                                   alibi_slopes=alibi_slopes, scale=scale)
@@ -940,16 +965,28 @@ def walk_groups(block_table, max_rows: int, xp=jnp):
     return idx - (idx - run) % max_rows
 
 
-def walk_reads(block_table, ctx_lens, block_size: int, queries_per_kv: int):
+def _walk_group_rows(queries_per_kv: int, pack: int, n_rows: int) -> int:
+    """_group_rows of a call of n_rows rows over a pool that packs
+    `pack` KV heads a head, each serving queries_per_kv query heads:
+    by the Gp the entry lays the queries out in (_packed_group of a
+    packed pool's, _group_queries' max(G, 8))."""
+    group = (_packed_group(queries_per_kv * pack) if pack > 1
+             else queries_per_kv)
+    return _group_rows(max(group, 8), n_rows)
+
+
+def walk_reads(block_table, ctx_lens, block_size: int, queries_per_kv: int,
+               pack: int = 1):
     """(blocks fetched, rows that rode) of one shared-table call over
     these host arrays, by the walk's own grouping: a group's blocks
     count once, by its longest row; a row rides when another row walks
     for it (rows of context 0, batch padding, left out).
-    queries_per_kv: query heads a pool row's KV heads serve (H / KV,
-    times kv_pack). The scheduler's kv_block_reads / kv_grouped_rows."""
+    queries_per_kv: query heads a KV head serves (H / KV); pack: KV
+    heads a pool's head holds (kv_pack).
+    The scheduler's kv_block_reads / kv_grouped_rows."""
     rows = np.arange(len(ctx_lens))
-    lead = walk_groups(block_table, _group_rows(max(queries_per_kv, 8),
-                                                len(ctx_lens)), np)
+    lead = walk_groups(block_table, _walk_group_rows(
+        queries_per_kv, pack, len(ctx_lens)), np)
     reads = np.zeros(len(ctx_lens), np.int64)
     np.maximum.at(reads, lead, -(-ctx_lens // block_size))
     return int(reads.sum()), int(np.sum((ctx_lens > 0) & (lead != rows)))
@@ -969,14 +1006,14 @@ def _wholly_live(j, shortest, longest, block_size: int, window: int):
 
 
 def walk_masks(block_table, ctx_lens, block_size: int, queries_per_kv: int,
-               window: int = 0):
+               window: int = 0, pack: int = 1):
     """(blocks masked, blocks visited) by the GROUPS of one shared-table
     call over these host arrays, by the walk's own grouping and the
     kernel's own predicate (_wholly_live): of a group's span only the
     slots that can hold a dead column for some row take the visit with
     its compares and select. Rows walked alone are in neither count."""
-    lead = walk_groups(block_table, _group_rows(max(queries_per_kv, 8),
-                                                len(ctx_lens)), np)
+    lead = walk_groups(block_table, _walk_group_rows(
+        queries_per_kv, pack, len(ctx_lens)), np)
     masked = visited = 0
     for g in np.flatnonzero(np.bincount(lead) > 1):
         ctx = ctx_lens[lead == g]

@@ -13,6 +13,14 @@ name) at the latent cell's shape (PERF.md section 6, PR 54).
       --distinct-blocks 128,1               # 128 rows in 128 blocks, then in ONE
   ... --kernel kv_write --rows 256 --kv-heads 4 --head-dim 128   a shape by hand
   chiprun -- python scripts/walk_bench.py --kernel latent  # us a (block, row)
+  chiprun -- python scripts/walk_bench.py olmohybrid --pack 1:32,15,4:32,16:32
+      the SAME K/V values, tables and contexts laid out f heads side by side in
+      a pool head ([blocks, 128, held / f, f x D]; `f:held` first pads the KV
+      heads with zero heads to `held`), packed and unpacked by the script's own
+      hands, so that a layout the tree's rule (kv_pack) does not choose can be
+      timed at any checkout (PERF.md section 6, PR 63; `1:32` is what a
+      checkout before PR 63 held); without --pack the pool is the tree's own
+      (kv_pack) and the entry lays the queries against it
 
 A call's rows by --kinds: `mixed` (decode rows beside chunks of 32 rows on
 one table, as the cell's steps are), `groups` (chunks alone), `decode` (rows
@@ -52,6 +60,9 @@ SHAPES = {
                       window=0),
     "granite": dict(rows=128, H=32, KV=8, D=128, pool=1024, NB=32, window=0),
     "nemotron": dict(rows=256, H=32, KV=2, D=128, pool=1024, NB=32, window=0),
+    # decode rows of 1-7 blocks as the chat cell's are (402 live blocks a step)
+    "olmohybrid": dict(rows=128, H=30, KV=30, D=128, pool=560, NB=32, window=0,
+                       ctx_range=(96, 800)),
 }
 # --kernel latent: the pool is [blocks, BLOCK, C] and a row's H heads share it;
 # a `mixed` call is the cell's step (PERF.md section 5): ~42 decode rows, then
@@ -71,9 +82,10 @@ def mix(kind, c, rng):
     ctx = np.zeros(rows, np.int64)
     if kind == "empty":
         return ctx, []
-    top = cap * 5 // 8 if long_ctx else cap // 3
+    low, top = c.get("ctx_range") or (
+        cap // 16, cap * 5 // 8 if long_ctx else cap // 3)
     if kind == "decode":
-        ctx[:] = rng.integers(cap // 16, top, rows)
+        ctx[:] = rng.integers(low, top, rows)
         return ctx, []
     if kind == "groups":
         lens = [32] * (rows // 32)
@@ -83,7 +95,7 @@ def mix(kind, c, rng):
     n_dec = rows - sum(lens)
     if kind == "mixed" and long_ctx:
         n_dec -= 2  # two pad rows at the end
-    ctx[:n_dec] = rng.integers(cap // 16, top, n_dec)
+    ctx[:n_dec] = rng.integers(low, top, n_dec)
     if long_ctx:  # chunks of prompts up to 8k
         firsts = [256, 1024, 2048, 3072, 5000, 8000, 1500, 4000]
     else:  # chat: the chunks of prompts of a few hundred tokens
@@ -129,6 +141,13 @@ def write_slots(spread, rows, pool, rng):
         b * BLOCK + rng.integers(0, BLOCK - n + 1) + np.arange(n)
         for b, n in zip(blocks, runs)])
     return slots.astype(np.int32), len(runs)
+
+
+def emit(line):
+    """One measurement: a JSON line printed and kept."""
+    print(json.dumps(line), flush=True)
+    with open("chiprun_out/walk_bench.jsonl", "a") as f:
+        f.write(json.dumps(line) + "\n")
 
 
 def best_of(fn, chain, *operands):
@@ -200,9 +219,7 @@ def bench_latent(args, PA, name, c):
         if empty_us is not None and kind != "empty":
             line["us_a_visit"] = round(
                 (us - empty_us) / max(1, int(blocks.sum())), 4)
-        print(json.dumps(line), flush=True)
-        with open("chiprun_out/walk_bench.jsonl", "a") as f:
-            f.write(json.dumps(line) + "\n")
+        emit(line)
 
 
 def bench_kv_write(args, PA, name, c):
@@ -211,7 +228,7 @@ def bench_kv_write(args, PA, name, c):
     import numpy as np
 
     rows, KV, D = c["rows"], c["KV"], c["D"]
-    pack = PA.kv_pack(KV, D)
+    pack = tree_pack(PA, KV, D)
     shape = (c["pool"] + 1, BLOCK, KV // pack, D * pack)
     key = jax.random.PRNGKey(0)
     kc = jax.random.normal(key, shape, jnp.bfloat16)
@@ -258,9 +275,131 @@ def bench_kv_write(args, PA, name, c):
                     blocks=calls[0][1], path=path, us=round(best * 1e6, 1),
                     same_as_scatter=same,
                     device=jax.devices()[0].device_kind)
-        print(json.dumps(line), flush=True)
-        with open("chiprun_out/walk_bench.jsonl", "a") as f:
-            f.write(json.dumps(line) + "\n")
+        emit(line)
+
+
+def tree_pack(PA, kv_heads: int, head_dim: int) -> int:
+    """The tree's rule for a bf16 pool (it reads the itemsize since PR 63)."""
+    import inspect
+
+    takes = len(inspect.signature(PA.kv_pack).parameters)
+    return PA.kv_pack(kv_heads, head_dim, *([2] * (takes - 2)))
+
+
+def laid_out(x, held: int, f: int):
+    """x [..., KV, D] with zero heads appended to `held`, f heads side by
+    side in one: [..., held / f, f x D], the same row-major values."""
+    import jax.numpy as jnp
+
+    x = jnp.pad(x, [(0, 0)] * (x.ndim - 2) + [(0, held - x.shape[-2]), (0, 0)])
+    return x.reshape(*x.shape[:-2], held // f, f * x.shape[-1])
+
+
+def pack_by_hand(PA, f: int, held: int, kv_heads: int, window: int):
+    """attend(q [S, H, D], pools [.., held / f, f x D], table, ctx): the
+    queries padded with zero heads to the held ones, each over its own
+    head's lanes of its pool head and zeros in the others', a pool head's
+    queries filled up to whole sublane tiles (so that a chunk's rows walk
+    as one group); of the output each head's own lanes."""
+    import jax.numpy as jnp
+
+    def attend(q, kc, vc, tbl, ctx):
+        S, H, D = q.shape
+        G = H // kv_heads
+        n, fg = held // f, f * G
+        wide = jnp.pad(q, ((0, 0), (0, held * G - H), (0, 0)))
+        place = jnp.arange(f)
+        mine = (place[:, None] == place[None, :])[None, None, :, None, :, None]
+        # [S, n, f, G, f, D]: head i of a pool head over lanes i alone
+        wide = jnp.where(mine, wide.reshape(S, n, f, G, 1, D), 0).reshape(
+            S, n, fg, f * D)
+        rows = -(-fg // 8) * 8 if fg > 8 else fg
+        wide = jnp.pad(wide, ((0, 0), (0, 0), (0, rows - fg), (0, 0)))
+        out = PA.paged_decode_attention(
+            wide.reshape(S, n * rows, f * D), kc, vc, tbl, ctx,
+            window=window, scale=D ** -0.5)
+        out = out.reshape(S, n, rows, f, D)[:, :, :fg].reshape(
+            S, n, f, G, f, D)
+        out = jnp.sum(jnp.where(mine, out, 0), axis=4)  # [S, n, f, G, D]
+        return out.reshape(S, held * G, D)[:, :H]
+
+    return attend
+
+
+def bench_walk(args, PA, name, c):
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    rows, H, KV, D = c["rows"], c["H"], c["KV"], c["D"]
+    key = jax.random.PRNGKey(0)
+    plain = (c["pool"] + 1, BLOCK, KV, D)  # the values, a head a head
+    kv = (jax.random.normal(key, plain, jnp.bfloat16),
+          jax.random.normal(jax.random.fold_in(key, 1), plain, jnp.bfloat16))
+    q = jax.random.normal(jax.random.fold_in(key, 2), (rows, H, D),
+                          jnp.bfloat16)
+    layouts = [None] if not args.pack else [
+        tuple(int(n) for n in (lay + f":{KV}").split(":")[:2])
+        for lay in args.pack.split(",")]
+    for lay in layouts:
+        if lay is None:  # the tree's own pool, its entry packing the queries
+            f, held = tree_pack(PA, KV, D), KV
+
+            def attend(q, kc, vc, tbl, ctxd):
+                return PA.paged_decode_attention(q, kc, vc, tbl, ctxd,
+                                                 window=c["window"])
+        else:
+            f, held = lay
+            attend = pack_by_hand(PA, f, held, KV, c["window"])
+        kc, vc = (laid_out(x, held, f) for x in kv)
+        for kind in args.kinds.split(","):
+            rng = np.random.default_rng(0)
+            ctx, runs = mix(kind, c, rng)
+            if c.get("ring"):
+                base = rng.integers(0, c["pool"] // c["ring"], rows)
+                tbl = (base[:, None] * c["ring"]
+                       + np.arange(c["NB"])[None, :] % c["ring"])
+            else:
+                tbl = np.stack([rng.permutation(c["pool"])[:c["NB"]]
+                                for _ in range(rows)])
+            for f0, n in runs:
+                tbl[f0:f0 + n] = tbl[f0]
+            tbl = jnp.asarray(tbl, jnp.int32)
+            ctxd = jnp.asarray(ctx, jnp.int32)
+
+            def chain(q, kc, vc, tbl, ctxd):
+                qq = q
+                for _ in range(args.chain):
+                    out = attend(qq, kc, vc, tbl, ctxd)
+                    qq = q + (out * 0).astype(q.dtype)
+                return out
+
+            try:
+                out, best = best_of(jax.jit(chain), args.chain,
+                                    q, kc, vc, tbl, ctxd)
+            except Exception as e:  # a layout Mosaic refuses: say so, go on
+                line = dict(tag=args.tag, shape=name, kind=kind,
+                            pool=list(kc.shape[2:]),
+                            refused=str(e).strip().splitlines()[-1][:300])
+                emit(line)
+                break
+            # a few rows against the float32 gather oracle
+            pick = sorted({0, 1, 2, *[f0 + i for f0, n in runs
+                                      for i in (0, n - 1)]})
+            pick = np.asarray([i for i in pick if ctx[i] > 0][:8] or [0])
+            with jax.default_matmul_precision("highest"):
+                ref = PA.paged_decode_attention_xla(
+                    q[pick].astype(jnp.float32), kv[0].astype(jnp.float32),
+                    kv[1].astype(jnp.float32), tbl[pick], ctxd[pick],
+                    window=c["window"])
+            err = float(jnp.max(jnp.abs(out[pick].astype(jnp.float32) - ref)))
+            alone, grouped = visits(c, ctx, runs)
+            line = dict(tag=args.tag, shape=name, kind=kind,
+                        us=round(best * 1e6, 1), decode_visits=alone,
+                        group_visits=grouped, kv=held // f,
+                        pool=list(kc.shape[2:]), max_err=round(err, 4),
+                        device=jax.devices()[0].device_kind)
+            emit(line)
 
 
 def main():
@@ -275,6 +414,9 @@ def main():
     ap.add_argument("--distinct-blocks", default="rows,1,chat",
                     help="kv_write: blocks a call's rows land in, a list")
     ap.add_argument("--kinds", default="mixed,groups,decode,empty")
+    ap.add_argument("--pack", help="walk: layouts laid out by hand, a list of "
+                    "f or f:held (KV heads side by side in a pool head; KV "
+                    "heads held, zero heads beyond the shape's)")
     ap.add_argument("--tree", default=os.path.dirname(
         os.path.dirname(os.path.abspath(__file__))))
     ap.add_argument("--tag", default="tree")
@@ -305,60 +447,7 @@ def main():
             bench_kv_write(args, PA, name, c)
         return
     for name in args.shapes:
-        c = SHAPES[name]
-        rows, H, KV, D = c["rows"], c["H"], c["KV"], c["D"]
-        pack = PA.kv_pack(KV, D)
-        shape = (c["pool"] + 1, BLOCK, KV // pack, D * pack)
-        key = jax.random.PRNGKey(0)
-        kc = jax.random.normal(key, shape, jnp.bfloat16)
-        vc = jax.random.normal(jax.random.fold_in(key, 1), shape,
-                               jnp.bfloat16)
-        q = jax.random.normal(jax.random.fold_in(key, 2), (rows, H, D),
-                              jnp.bfloat16)
-        for kind in args.kinds.split(","):
-            rng = np.random.default_rng(0)
-            ctx, runs = mix(kind, c, rng)
-            if c.get("ring"):
-                base = rng.integers(0, c["pool"] // c["ring"], rows)
-                tbl = (base[:, None] * c["ring"]
-                       + np.arange(c["NB"])[None, :] % c["ring"])
-            else:
-                tbl = np.stack([rng.permutation(c["pool"])[:c["NB"]]
-                                for _ in range(rows)])
-            for f, n in runs:
-                tbl[f:f + n] = tbl[f]
-            tbl = jnp.asarray(tbl, jnp.int32)
-            ctxd = jnp.asarray(ctx, jnp.int32)
-
-            def chain(q, kc, vc, tbl, ctxd):
-                qq = q
-                for _ in range(args.chain):
-                    out = PA.paged_decode_attention(qq, kc, vc, tbl, ctxd,
-                                                    window=c["window"])
-                    qq = q + (out * 0).astype(q.dtype)
-                return out
-
-            out, best = best_of(jax.jit(chain), args.chain,
-                                q, kc, vc, tbl, ctxd)
-            # a few rows against the float32 gather oracle
-            pick = sorted({0, 1, 2, *[f + i for f, n in runs
-                                      for i in (0, n - 1)]})
-            pick = np.asarray([i for i in pick if ctx[i] > 0][:8] or [0])
-            with jax.default_matmul_precision("highest"):
-                ref = PA.paged_decode_attention_xla(
-                    q[pick].astype(jnp.float32), kc.astype(jnp.float32),
-                    vc.astype(jnp.float32), tbl[pick], ctxd[pick],
-                    window=c["window"])
-            err = float(jnp.max(jnp.abs(out[pick].astype(jnp.float32) - ref)))
-            alone, grouped = visits(c, ctx, runs)
-            line = dict(tag=args.tag, shape=name, kind=kind,
-                        us=round(best * 1e6, 1), decode_visits=alone,
-                        group_visits=grouped, kv=KV // pack,
-                        max_err=round(err, 4),
-                        device=jax.devices()[0].device_kind)
-            print(json.dumps(line), flush=True)
-            with open("chiprun_out/walk_bench.jsonl", "a") as f:
-                f.write(json.dumps(line) + "\n")
+        bench_walk(args, PA, name, SHAPES[name])
 
 
 if __name__ == "__main__":

@@ -29,7 +29,9 @@ KERNELS = {
     "tiny-olmoe": ({"paged_decode_fused", "expert_stream"},
                    WRITE_WALK | {"expert_stream"}),
     "tiny-pangu": ({"paged_latent_write", "paged_decode_grid"},) * 2,
-    "tiny-lfm2": ({"paged_decode_fused", "expert_stream", "conv_carry"},
+    # two K/V heads of 64 packed in ONE head of 128: no whole tile of a
+    # 16-bit pool (Mosaic refuses its row's DMA), the fused write on the grid
+    "tiny-lfm2": ({"paged_decode_grid", "expert_stream", "conv_carry"},
                   WRITE_WALK | {"expert_stream", "conv_carry"}),
     "tiny-qwen3next": (
         {"paged_decode_fused", "expert_stream", "conv_carry", "gdn_state"},

@@ -148,7 +148,7 @@ def test_a_ring_block_that_holds_another_turns_rows_is_overwritten(rng):
 def test_512_rows_in_one_call(rng):
     """The LFM2 cell's step: a packed pool takes [T, 8, 64] rows as its
     own [T, 4, 128]."""
-    assert PA.kv_pack(8, 64) == 2
+    assert PA.kv_pack(8, 64, 2) == 2
     kc, vc = _pools(rng, 40, 4, 128, jnp.bfloat16)
     kn, vn = _rows(rng, 512, 8, 64, jnp.bfloat16)
     slots = rng.permutation(40 * BS)[:512].astype(np.int32)
@@ -226,13 +226,13 @@ def test_the_tile_rule(kv, d, itemsize, whole):
     assert PA._whole_tiles(kv, d, itemsize) is whole
 
 
-def _pool_span(cfg, **kw):
+def _pool_span(cfg, dtype=jnp.float32, **kw):
     from deepspeed_tpu.inference import init_inference
 
     profiler.spans(clear=True)
     init_inference(T.init(cfg, jax.random.PRNGKey(0)), cfg, dict(
         max_seq_len=64, kv_block_size=8, num_kv_blocks=24, max_batch_size=4,
-        max_tracked_sequences=4, **kw), dtype=jnp.float32)
+        max_tracked_sequences=4, **kw), dtype=dtype)
     return next(s for s in profiler.spans(clear=True)
                 if s.name == "init.pool")
 
@@ -256,6 +256,25 @@ def test_init_pool_says_how_each_pool_is_written():
     assert _pool_span(narrow, decode_impl="pallas").ids["kv_write"] == "rows"
     # float32 pools of 128 lanes take any count of heads; bf16 of 1 not
     assert PA.kv_write_path((25, 8, 1, 128), jnp.bfloat16) == "blocks"
+
+
+@pytest.mark.parametrize("heads,kv,d_model,dtype,kw,pack", [
+    (4, 2, 512, jnp.float32, {}, 1),       # 2 heads of 128: whole tiles
+    (8, 4, 512, jnp.float32, {}, 2),       # heads of 64: two a lane row
+    (12, 12, 1536, jnp.bfloat16, {}, 3),   # 12 of 128 in 16 bits: 4 of 384
+    (12, 12, 1536, jnp.float32, {}, 1),    # in 32 bits any count is whole
+    (12, 12, 1536, jnp.bfloat16, dict(kv_cache_dtype="int8"), 1),
+], ids=["whole_tiles", "head_dim_64", "no_whole_tiles", "float32", "int8"])
+def test_init_pool_says_what_a_pool_head_holds(heads, kv, d_model, dtype, kw,
+                                               pack):
+    """`kv_pack`, the KV heads a pool's head holds side by side, and
+    `kv_heads_padded`, heads held beyond the model's (0: no pool pads
+    its heads since PR 63), off the allocated pool's own shape."""
+    cfg = T.TransformerConfig(
+        vocab_size=64, n_layers=2, n_heads=heads, n_kv_heads=kv,
+        d_model=d_model, max_seq=64, variant="llama", use_flash=False)
+    span = _pool_span(cfg, dtype, decode_impl="xla", **kw)
+    assert (span.ids["kv_pack"], span.ids["kv_heads_padded"]) == (pack, 0)
 
 
 @pytest.mark.usefixtures("pallas_interpret")

@@ -386,9 +386,9 @@ def _packed_case(rng, ctx, H=8, KV=4, D=64, bs=16, NB=4, dtype=jnp.float32,
 
 
 def test_who_packs():
-    assert PA.kv_pack(8, 64) == 2 and PA.kv_pack(2, 64) == 2
-    assert PA.kv_pack(8, 128) == 1 and PA.kv_pack(8, 32) == 1
-    assert PA.kv_pack(3, 64) == 1  # an odd count of heads fills no row
+    assert PA.kv_pack(8, 64, 2) == 2 and PA.kv_pack(2, 64, 4) == 2
+    assert PA.kv_pack(8, 128, 2) == 1 and PA.kv_pack(8, 32, 2) == 1
+    assert PA.kv_pack(3, 64, 2) == 1  # an odd count of heads fills no row
 
 
 @pytest.mark.usefixtures("pallas_interpret")

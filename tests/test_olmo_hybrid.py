@@ -185,24 +185,25 @@ def test_the_cut_builds_at_published_widths():
     # the engine's tree against the benchmark's own count, every leaf
     assert sum(int(np.prod(s.shape)) for s in flat.values()) == \
         shapes.parameters(hf) == 3_268_268_508
-    # the cache: K/V for the attention layers alone, 30 heads held in
-    # 32 (whole tiles: what the layout pads them to anyway); two pools a
-    # DeltaNet layer, the matrices' with the pad rows' slot
+    # the cache: K/V for the attention layers alone, 30 heads of 128 as
+    # 2 heads of 1,920 (kv_pack: whole tiles, nothing padded, the
+    # published 15,360 B a token a layer); two pools a DeltaNet layer,
+    # the matrices' with the pad rows' slot
     cache = jax.eval_shape(lambda: M.init_cache(
         cfg, 561, 128, jnp.bfloat16, state_slots=128))
-    assert [a.shape for a in cache.k] == [(561, 128, 32, 128)] * 3
+    assert [a.shape for a in cache.k] == [(561, 128, 2, 1920)] * 3
     assert [tuple((a.shape, a.dtype) for a in pools)
             for pools in cache.state] == [
         (((129, 15, 96, 384), jnp.float32),
          ((128, 3, 96, 128), jnp.bfloat16))] * 9
     # a token NEEDS 46,080 B of K/V over the three layers and holds
-    # 32 / 30 of that
+    # just that
     assert 3 * shapes.kv_bytes_per_token_per_layer(hf) == 46_080
-    assert sum(a.size * 2 for a in cache.k + cache.v) / 561 / 128 == 49_152
-    # what the engine counts before it allocates: 3.53 + 2.65 GB
+    assert sum(a.size * 2 for a in cache.k + cache.v) / 561 / 128 == 46_080
+    # what the engine counts before it allocates: 3.31 + 2.65 GB
     pools = E.pool_bytes(cfg, E.InferenceConfig(**hf["serve"]["engine"]),
                          jnp.bfloat16)
-    assert pools == {"kv": 561 * 128 * 49_152,
+    assert pools == {"kv": 561 * 128 * 46_080,
                      "state": 9 * (129 * 2_211_840 + 128 * 73_728)}
 
 
@@ -256,23 +257,33 @@ def test_heads_stand_side_by_side_where_that_fills_lane_tiles(heads, dv, pack):
 
 # -- the engine against the reference -------------------------------------
 
-def test_a_pool_that_holds_more_heads_than_the_model_serves_the_same(
+def test_a_packed_pool_serves_the_same_as_the_models_own_shape(
         model, engines, monkeypatch):
-    """The published 30 KV heads are held in 32 (kv_heads_held: whole
-    tiles). Here the 4 heads in 7, in float32 with no kernel: the new
-    rows and the queries are padded with zero heads on their way to the
-    pool and the padding's output is cut, through prefill, chunks and
-    single steps, and through the scheduler."""
-    monkeypatch.setattr(M, "kv_heads_held", lambda kv, d, itemsize: kv + 3)
+    """The published 30 KV heads of 128 lie 15 side by side in 2 heads
+    of 1,920 (kv_pack: the layout would pad them to 32). Here the 4
+    heads of 32 as 2 of 64, in float32 with no kernel: the new rows
+    reach the pool by a reshape and the queries attend it as the same
+    row-major bytes, through prefill, chunks and single steps, and
+    through the scheduler; the pool's bytes are the unpacked pool's."""
+    plain = engines.fresh()
+    assert [a.shape for a in plain.cache.k] == [(49, 32, 4, 32)] * 2
+    monkeypatch.setattr(M, "kv_pack", lambda kv, d, itemsize: 2)
     eng = engines.fresh()  # its own: built under the patch
-    assert [a.shape for a in eng.cache.k] == [(49, 32, 7, 32)] * 2
-    got, want, _, _ = F.feeds(FAMILY, model, eng, [32, 36], [4], 3, seed=4)
+    assert [a.shape for a in eng.cache.k] == [(49, 32, 2, 64)] * 2
+    fed = ([32, 36], [4], 3)
+    got, want, _, _ = F.feeds(FAMILY, model, eng, *fed, seed=4)
     assert np.abs(got - want).max() < LOGITS_ATOL, np.abs(got - want).max(-1)
-    # the padding heads hold zeros
-    assert all(float(jnp.abs(a[:, :, 4:]).max()) == 0 for a in eng.cache.k)
+    own, _, _, _ = F.feeds(FAMILY, model, plain, *fed, seed=4)
+    np.testing.assert_allclose(got, own, atol=1e-5)
+    for packed, unpacked in zip(eng.cache.k + eng.cache.v,
+                                plain.cache.k + plain.cache.v):
+        np.testing.assert_array_equal(packed.reshape(-1),
+                                      unpacked.reshape(-1))
     requests = F.requests(FAMILY, 4, seed=9)  # four: within its six slots
-    _, outputs = F.serve(eng, requests)
+    s, outputs = F.serve(eng, requests)
     F.greedy_by_the_reference(FAMILY, model, requests, outputs)
+    # the scheduler read the packing off the pool: two heads' queries a group
+    assert s.counters["kv_block_reads"] <= s.counters["kv_live_blocks"]
 
 
 def test_the_scopes_of_the_layer_are_in_the_program(engines):

@@ -185,28 +185,80 @@ def test_the_walk_with_one_query_head_a_kv_head_matches_the_oracle(rng):
     F.walk_matches_the_oracle(rng, H=30, KV=30, D=128)
 
 
-@pytest.mark.parametrize("kv,d,itemsize,held", [
-    (30, 128, 2, 32), (12, 128, 2, 16), (30, 256, 2, 32), (30, 128, 1, 32),
-    (16, 128, 2, 16), (32, 128, 2, 32), (8, 128, 2, 8), (30, 128, 4, 30),
-    (30, 64, 2, 30), (6, 128, 2, 6), (1, 128, 2, 1), (30, 384, 4, 32)])
-def test_a_pool_holds_whole_tiles_of_heads(kv, d, itemsize, held):
-    """More than a tile of KV heads that are no whole tiles are held in
-    the next count that is; whole tiles, counts under the tile, a
-    32-bit pool of exactly 128 lanes and a head dim that fills no lane
-    tile stay as they are."""
-    assert PA.kv_heads_held(kv, d, itemsize) == held
-    assert held == kv or PA._whole_tiles(held, d, itemsize)
+@pytest.mark.usefixtures("pallas_interpret")
+def test_the_walk_of_a_packed_pool_matches_the_oracle(rng):
+    """30 query and 30 KV heads of 128 over the pool kv_pack lays them
+    out in, 2 heads of 1,920, blocks of 16: rows of their own whose
+    contexts end inside a block, at a block's edge and nowhere (a pad
+    row), beside a chunk of 32 rows on one table. A head's 15 queries
+    are laid out in 16 rows (_packed_group: whole sublane tiles), so
+    the chunk walks as two groups of 16 that each read its blocks once
+    (at 15 rows a head every row would walk alone)."""
+    S, H, D, bs, NB = 40, 30, 128, 16, 4
+    pack = PA.kv_pack(30, D, 2)
+    assert pack == 15 and PA._packed_group(pack) == 16
+    ctx = np.zeros(S, np.int32)
+    ctx[:5], ctx[5:37], ctx[37:] = [1, 17, 40, 64, 0], 20 + np.arange(32), 33
+    tbl = rng.permutation(S * NB).reshape(S, NB)
+    tbl[5:37] = tbl[5]
+    normal = lambda *shape: jnp.asarray(rng.normal(size=shape), jnp.float32)
+    q = normal(S, H, D)
+    kc, vc = (normal(S * NB + 1, bs, 30 // pack, pack * D) for _ in "kv")
+    qg = jax.eval_shape(lambda q: PA._group_queries(
+        PA._pack_queries(q, pack, 1), 2)[0], q)
+    assert qg.shape == (S, 2, 16, pack * D)
+    assert PA._walks_live_blocks(qg, kc) and PA._group_rows(16, S) == 16
+    # by the walk's own grouping: each half of the chunk reads the blocks
+    # of its longest row (contexts 35 and 51), 30 rows ride
+    assert PA.walk_reads(tbl, ctx, bs, 1, pack) == (
+        int(np.sum(-(-np.delete(ctx, np.s_[5:37]) // bs))) + 3 + 4, 30)
+    assert PA.walk_reads(tbl, ctx, bs, 15)[1] == 0  # 15 rows a head: alone
+    # and of the groups' 3 + 4 visits, those past a group's shortest context
+    assert PA.walk_masks(tbl, ctx, bs, 1, pack=pack) == (4, 7)
+    got, want = (jax.jit(fn)(q, kc, vc, jnp.asarray(tbl, jnp.int32),
+                             jnp.asarray(ctx)) for fn in (
+        PA.paged_decode_attention, PA.paged_decode_attention_xla))
+    live = ctx > 0
+    np.testing.assert_allclose(got[live], want[live], atol=2e-5)
+    # the same values a head a head, the pool the model's own shape
+    plain = jax.jit(PA.paged_decode_attention_xla)(
+        q, kc.reshape(-1, bs, 30, D), vc.reshape(-1, bs, 30, D),
+        jnp.asarray(tbl, jnp.int32), jnp.asarray(ctx))
+    np.testing.assert_allclose(got[live], plain[live], atol=2e-5)
+
+
+@pytest.mark.parametrize("kv,d,itemsize,pack", [
+    (30, 128, 2, 15), (12, 128, 2, 3), (30, 256, 2, 15), (30, 128, 1, 1),
+    (16, 128, 2, 1), (32, 128, 2, 1), (8, 128, 2, 1), (30, 128, 4, 1),
+    (30, 64, 2, 2), (6, 128, 2, 1), (1, 128, 2, 1), (30, 384, 4, 15),
+    (10, 128, 2, 5), (2, 640, 2, 1)])
+def test_a_pool_lays_heads_side_by_side_where_the_layout_would_pad(
+        kv, d, itemsize, pack):
+    """The packing rule's table. More than a tile of KV heads of whole
+    lanes that are no whole tiles lie side by side, the fewest to a
+    head that leave whole tiles with nothing padded (30 of 128 in 16
+    bits: 2 heads of 1,920; ten: 2 of 640, what ten PAIRS of 64 fold
+    to, kv_pair_fold); heads of 64 two a lane row; whole tiles, counts
+    up to the tile, a 32-bit pool of exactly 128 lanes and a count no
+    fold makes whole tiles of (30 heads in 8 bits) stay as they are."""
+    assert PA.kv_pack(kv, d, itemsize) == pack
+    assert pack == 1 or d == 64 or (
+        not PA._whole_tiles(kv, d, itemsize)
+        and PA._whole_tiles(kv // pack, d * pack, itemsize)
+        and pack == PA.kv_pair_fold(kv, d, itemsize))
 
 
 @pytest.mark.usefixtures("pallas_interpret")
 @pytest.mark.parametrize("G", [1, 2], ids=["mha", "gqa"])
 @pytest.mark.parametrize("fused", [False, True], ids=["write_walk", "fused"])
-def test_padding_heads_change_nothing_the_model_sees(rng, G, fused):
-    """_write_pools + _decode_attention on a pool of 16 heads for a
-    model of 12 (rows of 12 heads, queries of 12 G): the same
-    attention as on a pool of 12, the padding heads' rows zeros."""
+def test_packing_changes_nothing_the_model_sees(rng, G, fused):
+    """_write_pools + _decode_attention on a pool of 12 heads of 128
+    laid out as 4 of 384 (what kv_pack answers for 12) for rows of 12
+    heads and queries of 12 G: the same attention as on a pool of 12,
+    and the same row-major bytes in the pools."""
     S, KV, D, bs, NB = 4, 12, 128, 16, 3
-    H = KV * G
+    H, pack = KV * G, PA.kv_pack(KV, D, 2)
+    assert pack == 3
     ctx = jnp.asarray([5, 17, 33, 0], jnp.int32)
     tbl = jnp.asarray(rng.permutation(S * NB).reshape(S, NB), jnp.int32)
     flat = jnp.where(ctx > 0, jnp.take_along_axis(
@@ -214,23 +266,21 @@ def test_padding_heads_change_nothing_the_model_sees(rng, G, fused):
     normal = lambda *shape: jnp.asarray(rng.normal(size=shape), jnp.float32)
     q, kn, vn = normal(S, H, D), normal(S, KV, D), normal(S, KV, D)
     own = (normal(S * NB + 1, bs, KV, D), normal(S * NB + 1, bs, KV, D))
-    held = tuple(jnp.pad(p, [(0, 0), (0, 0), (0, 4), (0, 0)]) for p in own)
+    packed = tuple(p.reshape(-1, bs, KV // pack, pack * D) for p in own)
     outs = []
-    for pools in (own, held):
+    for pools in (own, packed):
         if fused:
             att, *pools = M._decode_attention(
-                q, pools, tbl, ctx, True, k_new=kn, v_new=vn, slots=flat,
-                kv_heads=KV)
+                q, pools, tbl, ctx, True, k_new=kn, v_new=vn, slots=flat)
         else:
             pools = M._write_pools(pools, kn, vn, flat)
-            att = M._decode_attention(q, pools, tbl, ctx, True, kv_heads=KV)
+            att = M._decode_attention(q, pools, tbl, ctx, True)
         outs.append((att, pools))
     (a0, p0), (a1, p1) = outs
     assert a1.shape == (S, H, D)
     np.testing.assert_allclose(a1[:3], a0[:3], atol=2e-5)
     for x, y in zip(p0, p1):
-        np.testing.assert_array_equal(y[:, :, :KV], x)
-        assert float(jnp.abs(y[:, :, KV:]).max()) == 0
+        np.testing.assert_array_equal(y.reshape(x.shape), x)
 
 
 def test_the_step_kernel_compiles_for_v5e_at_the_cells_shapes(one_chip):
@@ -247,51 +297,68 @@ def test_the_step_kernel_compiles_for_v5e_at_the_cells_shapes(one_chip):
         sds((rows,), jnp.int32)), 5, "gdn_state")
 
 
-def _walk_args(one_chip, held):
+def _walk_args(one_chip, pool_heads):
     sds = F.on_chip(one_chip, jnp.bfloat16)
-    rows, pool = 128, sds((705, 128, held, 128))
+    rows, pool = 128, sds((705, 128, *pool_heads))
     q = new = sds((rows, 30, 128))
     table, ints = sds((rows, 32), jnp.int32), sds((rows,), jnp.int32)
     return q, pool, pool, new, new, table, ints, ints
 
 
-def test_the_walk_and_both_writes_compile_for_v5e_at_30_heads_in_32(one_chip):
-    """30 query / 30 KV heads of 128 over pools [705, 128, 32, 128], a
-    table of 32 slots a row, 128 rows, through the serving model's own
-    two calls: the row write and the live-block walk of the
-    shared-table step, and the fused walk of the single-token step."""
+def test_the_walk_and_both_writes_compile_for_v5e_at_30_heads_in_2(one_chip):
+    """30 query / 30 KV heads of 128 over pools [705, 128, 2, 1920]
+    (kv_pack: 15 heads side by side), a table of 32 slots a row, 128
+    rows, through the serving model's own two calls: the row write and
+    the live-block walk of the shared-table step, and the fused walk of
+    the single-token step, at the packed shape."""
     def shared(q, kc, vc, kn, vn, table, ctx, slots):
         pools = M._write_pools((kc, vc), kn, vn, slots)
-        return M._decode_attention(q, pools, table, ctx, True,
-                                   kv_heads=30), pools
+        return M._decode_attention(q, pools, table, ctx, True), pools
 
     def single(q, kc, vc, kn, vn, table, ctx, slots):
         return M._decode_attention(q, (kc, vc), table, ctx, True, k_new=kn,
-                                   v_new=vn, slots=slots, kv_heads=30)
+                                   v_new=vn, slots=slots)
 
-    args = _walk_args(one_chip, PA.kv_heads_held(30, 128, 2))
+    pack = PA.kv_pack(30, 128, 2)
+    args = _walk_args(one_chip, (30 // pack, 128 * pack))
+    assert args[1].shape == (705, 128, 2, 1920)
     text = jax.jit(shared, donate_argnums=(1, 2)).lower(
         *args).compile().as_text()
     for name in ("paged_decode_grid", "paged_kv_write"):
         assert any(name in line for line in F.kernels(text)), name
+    # the walk is the live-block one at 2 heads of 16 query rows
+    assert any("paged_decode_grid" in line and "bf16[128,2,16,1920]" in line
+               for line in F.kernels(text))
     assert PA.kv_write_path(args[1].shape, jnp.bfloat16) == "rows"
     text = jax.jit(single, donate_argnums=(1, 2)).lower(
         *args).compile().as_text()
     assert any("paged_decode_fused" in line for line in F.kernels(text))
 
 
-def test_a_pool_of_30_heads_stays_on_the_grid_and_is_not_refused(one_chip):
-    """What the padding is for: [705, 128, 30, 128] in 16 bits is no
+@pytest.mark.parametrize("heads", [30, 6, 1])
+def test_heads_that_are_no_whole_tiles_stay_on_the_grid_and_are_not_refused(
+        one_chip, heads):
+    """What the packing is for: [705, 128, 30, 128] in 16 bits is no
     whole tiles, its walk is the (S, NB) grid's and its write a whole
     block's; the fused walk is not tried on it (Mosaic refuses its
     row's DMA: "Slice shape along dimension 2 must be aligned to tiling
-    (8), but is 30")."""
-    q, pool, _, new, _, table, ints, _ = _walk_args(one_chip, 30)
-    assert not PA._whole_tiles(30, 128, 2)
+    (8), but is 30"). A pool under a mesh, which is not packed, takes
+    this path, and so do the counts up to the tile that nothing packs
+    (6 heads, 1: the fused walk was tried on them until PR 63, and
+    refused)."""
+    sds = F.on_chip(one_chip, jnp.bfloat16)
+    pool = sds((705, 128, heads, 128))
+    q = new = sds((128, heads, 128))
+    table, ints = sds((128, 32), jnp.int32), sds((128,), jnp.int32)
+    assert not PA._whole_tiles(heads, 128, 2)
     assert PA.kv_write_path(pool.shape, jnp.bfloat16) == "blocks"
-    qg = jax.eval_shape(lambda q: PA._group_queries(q, 30, None)[0], q)
+    qg = jax.eval_shape(lambda q: PA._group_queries(q, heads, None)[0], q)
     assert not PA._walks_live_blocks(qg, pool)
-    assert PA._walks_live_blocks(qg, _walk_args(one_chip, 32)[1])
+    if heads == 30:
+        packed = jax.eval_shape(lambda q: PA._group_queries(
+            PA._pack_queries(q, 15, 1), 2, None)[0], q)
+        assert packed.shape == (128, 2, 16, 1920)
+        assert PA._walks_live_blocks(packed, sds((705, 128, 2, 1920)))
 
     def single(q, kc, vc, kn, vn, table, ctx, slots):
         return PA.paged_decode_attention(q, kc, vc, table, ctx, k_new=kn,

@@ -530,7 +530,7 @@ def test_shared_table_attention_compiles_for_v5e(one_chip, cell, window):
     Gp of 8) is what Mosaic is handed at each cell's shapes."""
     c = CELLS[cell]
     rows, H, KV, D = c["rows"], c["H"], c["KV"], c["D"]
-    pack = PA.kv_pack(KV, D)
+    pack = PA.kv_pack(KV, D, 2)
 
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)

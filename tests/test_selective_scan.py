@@ -133,9 +133,10 @@ def test_the_step_kernel_compiles_for_v5e_at_the_cells_shapes(one_chip):
 def test_the_walk_compiles_for_v5e_at_two_heads_of_640(one_chip):
     """What the fold is for: 10 pairs of 128 are no whole tiles of a
     16-bit pool (it would hold 16), 2 heads of 640 are; the row write
-    and the live-block walk take the pool at 40 query heads."""
-    assert PA.kv_heads_held(10, 128, 2) == 16
-    assert PA.kv_heads_held(2, 640, 2) == 2 and PA.kv_pack(2, 640) == 1
+    and the live-block walk take the pool at 40 query heads (the rule's
+    table: tests/test_olmo_hybrid_delta.py)."""
+    assert not PA._whole_tiles(10, 128, 2) and PA._whole_tiles(2, 640, 2)
+    assert PA.kv_pack(2, 640, 2) == 1
     F.walk_and_write_compile(one_chip, 128, 40, 2, 640, (257, 128, 2, 640))
 
 

@@ -415,8 +415,9 @@ class PagedCache(NamedTuple):
     attention layers of a model of two kinds, in their order). A pool
     is PACKED, [NBLK, bs, KV / f, f D] (kv_pack: the same bytes, f
     heads side by side in one), at head dim 64 (two heads a 128-lane
-    row) and where more than 8 heads are no whole tiles of the layout
-    (30 heads of 128 in 16 bits: 2 heads of 1,920).
+    row), where more than 8 heads are no whole tiles of the layout
+    (30 heads of 128 in 16 bits: 2 heads of 1,920) and where fewer
+    than 8 are (4 heads of 128, 8 of 64: 2 heads of 256).
 
     A model of mixed windows (cfg.mixed_windows) holds pools of TWO
     sizes in these lists: a full layer's [NBLK, ...] paged by a
@@ -549,6 +550,8 @@ def init_cache(
                           v=[])
     # unquantised pools on one device lay heads side by side where the
     # layout would pad them (two of 64 a lane row; 30 of 128 as 2 x 1,920)
+    # and where a block of fewer than 8 heads is cheaper to walk in two
+    # (4 of 128 as 2 x 256)
     pack = 1
     if not kv_quant and mesh is None:
         pack = kv_pack(KV, D, jnp.dtype(dtype).itemsize)

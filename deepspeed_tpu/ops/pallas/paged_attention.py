@@ -28,8 +28,8 @@ the arguments, dtype and shape it is handed, nothing else):
   unquantised attention the shared-table program runs, at head dims
   that are multiples of 128 and, through PACKED pools (kv_pack: two KV
   heads of 64 in one 128-lane row; 30 heads of 128, no whole tiles,
-  as 2 heads of 1,920), at head dim 64 and at head counts the layout
-  would pad. In the
+  as 2 heads of 1,920; 4 heads of 128, or 8 of 64, as 2 heads of 256),
+  at head dim 64 and at head counts the layout would pad. In the
   shared-table attention ADJACENT rows with equal tables (a prefill
   chunk's rows: walk_groups) walk as one GROUP of up to 256 / Gp rows
   (32 where a KV head serves up to 8 query heads): the group's first
@@ -352,8 +352,18 @@ def kv_pack(kv_heads: int, head_dim: int, itemsize: int) -> int:
       a pool of 2 heads of 1,920). On a v5e the shared-table walk of
       128 rows over 389 live blocks takes 1.28 ms so against 2.21 ms
       over 30 heads held in 32 (2.39 as 8 heads of 512, 1.95 as 2 of
-      2,048 on the grid: PERF.md section 6, PR 63). Counts of up to 8
-      stay as they are (what their layouts pad was not read).
+      2,048 on the grid: PERF.md section 6, PR 63);
+    - fewer than the layout's 8-row tile of heads that ARE whole tiles
+      (after the pairing of heads of 64: 4 heads of 128, 8 of 64): the
+      MOST that leave whole tiles, which in 16 bits leaves 2 heads (1
+      is no whole tile there), so 2 for 4 heads of 128 and 4 for 8
+      heads of 64, both a pool of 2 heads of 256. A decode row's visit
+      of a (pool head, block) costs 0.245 us at 4 heads of 128 against
+      0.17 at 8 or more, and a block of 2 x 256 holds the same bytes in
+      half the visits (PERF.md section 6, PR 64). Decided for the
+      served 16 bits where the pool's dtype is wider (as kv_pair_fold),
+      so that a float32 test walks the model's layout. Pools of 2
+      heads, and of 8 or more whole-tile heads, stay as they are.
 
     A packed pool holds the same row-major bytes, heads f p .. f p +
     f - 1 side by side in row p. Every kernel here then runs UNCHANGED
@@ -367,12 +377,16 @@ def kv_pack(kv_heads: int, head_dim: int, itemsize: int) -> int:
     packs only unquantised pools on one device: scale tiles and head
     sharding are per KV head); everything below reads the packing off
     the shapes it is handed."""
-    if head_dim == 64 and kv_heads % 2 == 0:
-        return 2
     if (head_dim % 128 == 0 and kv_heads > 8
             and not _whole_tiles(kv_heads, head_dim, itemsize)):
         return kv_pair_fold(kv_heads, head_dim, itemsize)
-    return 1
+    lanes = 2 if head_dim == 64 and kv_heads % 2 == 0 else 1
+    heads, width = kv_heads // lanes, head_dim * lanes
+    served = min(itemsize, 2)
+    if heads < 8 and _whole_tiles(heads, width, served):
+        return lanes * max(f for f in range(1, heads + 1) if heads % f == 0
+                           and _whole_tiles(heads // f, width * f, served))
+    return lanes
 
 
 def _whole_tiles(kv_heads: int, head_dim: int, itemsize: int) -> bool:
@@ -528,7 +542,9 @@ def paged_decode_attention(q, k_cache, v_cache, block_table, ctx_lens,
       other head dims, and block shapes the walk cannot take
       (_whole_tiles: D % 128 != 0; 16-bit pools whose KV count is
       not 2, 4 or a multiple of 8, which are those kv_pack does not
-      pack: 1, 3, 5-7 heads, or any such count under a mesh).
+      pack: 1, 3, 5-7 heads, or any such count under a mesh; a pool
+      of 4 heads walks as it is under a mesh and, on one device, as
+      the 2 wide heads kv_pack lays it in).
 
     The attend-only pallas_call is named `paged_decode_grid` whatever
     its grid: in a trace that name means "the shared-table decode
@@ -562,8 +578,9 @@ def paged_decode_attention(q, k_cache, v_cache, block_table, ctx_lens,
       target block back even when nothing changed. The write slot must
       be ctx-1's flat slot.
     A PACKED pool (kv_pack: [num_blocks, block_size, KV / f, f D],
-    f = 2 at head dim 64, 15 for 30 heads of 128; told from the
-    shapes) is attended at (KV / f, f D) through this same entry,
+    f = 2 at head dim 64, 15 for 30 heads of 128, 2 for 4 heads of
+    128 and 4 for 8 of 64; told from the shapes) is attended at
+    (KV / f, f D) through this same entry,
     queries block-diagonal, k_new/v_new reshaped, `scale` (default
     1/sqrt(D)) kept the true head dim's.
     returns: [S, H, D] (fused: (out, k_cache, v_cache))

@@ -201,7 +201,7 @@ def feed(family, model, eng, full, cuts, hf=None):
     return np.stack(got, axis=1), want, padded, cuts
 
 
-def feeds(family, model, eng, lens, splits, n_dec, seed=0):
+def feeds(family, model, eng, lens, splits, n_dec, seed=0, hf=None):
     """`feed` of seeded prompts of `lens`, each fed as len - sum(splits)
     tokens whole, then chunks of `splits`, then n_dec single tokens."""
     rng = np.random.default_rng(seed)
@@ -209,7 +209,7 @@ def feeds(family, model, eng, lens, splits, n_dec, seed=0):
                          ).astype(np.int32) for n in lens]
     cuts = [[n - sum(splits[j:]) for j in range(len(splits) + 1)]
             + [n + j + 1 for j in range(n_dec)] for n in lens]
-    return feed(family, model, eng, full, cuts)
+    return feed(family, model, eng, full, cuts, hf=hf)
 
 
 def requests(family, n, seed=5):
@@ -403,12 +403,11 @@ def family(request):
     return request.module.FAMILY
 
 
-@pytest.fixture(scope="module")
-def model(family):
-    """(the tiny configuration, seeded float32 weights): init's values
-    times 4 (the 0.02 init gives nearly flat logits), each leaf then as
-    the family's `jig` says."""
-    mcfg = config_from_hf(family.hf, use_flash=False)
+def model_of(family, hf=None):
+    """(the tiny configuration `hf`, the family's own by default, seeded
+    float32 weights): init's values times 4 (the 0.02 init gives nearly
+    flat logits), each leaf then as the family's `jig` says."""
+    mcfg = config_from_hf(hf or family.hf, use_flash=False)
 
     def jigged(tree, salt):
         return {k: family.jig(k, v, jax.random.fold_in(
@@ -423,6 +422,11 @@ def model(family):
 
     # ONE program: leaf by leaf the tree is a hundred small compiles
     return mcfg, jax.jit(make)()
+
+
+@pytest.fixture(scope="module")
+def model(family):
+    return model_of(family)
 
 
 @pytest.fixture(scope="module")

@@ -27,8 +27,8 @@ BS = 16  # tokens a block here; the cells' 128 in the compiles below
 CELL_POOLS = {
     "dense_granite_8x128": (8, 128),
     "olmoe_16x128": (16, 128),
-    "mellum2_lfm2packed_4x128": (4, 128),
-    "qwen3next_2x256": (2, 256),
+    "four_heads_4x128": (4, 128),  # Mellum 2's and LFM2's until PR 64
+    "qwen3next_mellum2_lfm2_2x256": (2, 256),
     "nemotron_2x128": (2, 128),
 }
 # shapes whose rows Mosaic refuses as a DMA: they keep the block path
@@ -147,13 +147,42 @@ def test_a_ring_block_that_holds_another_turns_rows_is_overwritten(rng):
 @pytest.mark.usefixtures("pallas_interpret")
 def test_512_rows_in_one_call(rng):
     """The LFM2 cell's step: a packed pool takes [T, 8, 64] rows as its
-    own [T, 4, 128]."""
-    assert PA.kv_pack(8, 64, 2) == 2
-    kc, vc = _pools(rng, 40, 4, 128, jnp.bfloat16)
+    own [T, 2, 256]."""
+    assert PA.kv_pack(8, 64, 2) == 4
+    kc, vc = _pools(rng, 40, 2, 256, jnp.bfloat16)
     kn, vn = _rows(rng, 512, 8, 64, jnp.bfloat16)
     slots = rng.permutation(40 * BS)[:512].astype(np.int32)
     slots[-7:] = -1
     _same_as_scatter(kc, vc, kn, vn, slots)
+
+
+@pytest.mark.usefixtures("pallas_interpret")
+@pytest.mark.parametrize("kv,d", [(4, 128), (8, 64)], ids=["4x128", "8x64"])
+@pytest.mark.parametrize("what", ["decode_rows", "chunk_of_32",
+                                  "ring_two_turns"])
+def test_two_wide_heads_take_the_models_rows(rng, kv, d, what):
+    """A whole-tile pool of fewer than 8 KV heads is [.., 2, 256]
+    (kv_pack, PR 64): rows [T, kv, d] of the model land, by the row
+    DMA, where a scatter into the UNPACKED float32 pool puts them:
+    decode rows in slots of their own, a chunk of 32 over a block's
+    edge, and a ring of 10 blocks written two turns deep."""
+    pack = PA.kv_pack(kv, d, 2)
+    assert (kv // pack, d * pack) == (2, 256)
+    plain = _pools(rng, 21, kv, d, jnp.float32)
+    kc, vc = (p.reshape(21, BS, 2, 256) for p in plain)
+    assert PA.kv_write_path(kc.shape, kc.dtype) == "rows"
+    if what == "decode_rows":
+        slots = _scattered_slots(rng, 21 * BS, 40)
+    else:  # positions 8.., or 2 x 10 x BS + 8.. round the ring 1 .. 10
+        pos = 8 + np.arange(32) + (20 * BS if what == "ring_two_turns" else 0)
+        slots = np.concatenate([
+            (1 + (pos // BS) % 10) * BS + pos % BS, [-1] * 4])
+    kn, vn = _rows(rng, len(slots), kv, d, jnp.float32)
+    got = _same_as_scatter(kc, vc, kn, vn, slots)
+    want, _ = M._write_kv_xla(*plain, kn, vn, jnp.asarray(slots, jnp.int32))
+    np.testing.assert_array_equal(got.reshape(want.shape), np.asarray(want))
+    live = np.asarray(slots) >= 0
+    assert (got != np.asarray(kc)).any(axis=(2, 3)).sum() <= live.sum()
 
 
 @pytest.mark.usefixtures("pallas_interpret")
@@ -258,13 +287,56 @@ def test_init_pool_says_how_each_pool_is_written():
     assert PA.kv_write_path((25, 8, 1, 128), jnp.bfloat16) == "blocks"
 
 
+@pytest.mark.parametrize("kv,d,itemsize,pack", [
+    # fewer than a tile of whole-tile heads: two wide heads (PR 64),
+    # for the served 16 bits whatever the pool's dtype
+    (4, 128, 2, 2), (8, 64, 2, 4), (4, 128, 4, 2), (8, 64, 4, 4),
+    (4, 256, 2, 2),
+    # what must NOT move: a tile of heads or more, pools of 2 heads
+    # already, the pools PR 63 packed, heads of 64 that pair to 2 or to
+    # no whole tiles, 8 bits
+    (8, 128, 2, 1), (16, 128, 2, 1), (2, 256, 2, 1), (2, 128, 2, 1),
+    (2, 640, 2, 1), (30, 128, 2, 15), (3, 64, 2, 1), (4, 64, 2, 2),
+    (2, 64, 2, 2), (12, 64, 2, 2), (6, 128, 2, 1), (4, 128, 1, 1),
+])
+def test_who_lies_in_two_wide_heads(kv, d, itemsize, pack):
+    assert PA.kv_pack(kv, d, itemsize) == pack
+    if (kv, d) in ((4, 128), (8, 64), (4, 256)) and itemsize > 1:
+        # the MOST heads side by side that leave whole tiles (one head
+        # of all the lanes is none in 16 bits); the pairs' rule
+        # (kv_pair_fold) answers the FEWEST, 1 for 4 heads of 128
+        assert PA._whole_tiles(kv // pack, d * pack, 2)
+        assert not PA._whole_tiles(1, kv * d, 2)
+        assert PA.kv_pair_fold(4, 128) == 1
+
+
+def test_a_meshed_or_quantised_pool_of_4_heads_is_not_packed():
+    cfg = T.TransformerConfig(
+        vocab_size=64, n_layers=2, n_heads=8, n_kv_heads=4, d_model=1024,
+        max_seq=64, variant="llama", use_flash=False)
+    assert (cfg.kv_heads, cfg.head_dim) == (4, 128)
+    shapes = lambda **kw: {
+        x.shape for x in jax.eval_shape(
+            lambda: M.init_cache(cfg, 5, 8, jnp.bfloat16, **kw)).k}
+    assert shapes() == {(5, 8, 2, 256)}
+    assert shapes(kv_quant=True) == {(5, 8, 4, 128)}
+    mesh = Mesh(np.asarray(jax.devices()[:2]), ("model",))
+    assert {x.shape for x in M.init_cache(
+        cfg, 5, 8, jnp.bfloat16, mesh=mesh).k} == {(5, 8, 4, 128)}
+
+
 @pytest.mark.parametrize("heads,kv,d_model,dtype,kw,pack", [
     (4, 2, 512, jnp.float32, {}, 1),       # 2 heads of 128: whole tiles
     (8, 4, 512, jnp.float32, {}, 2),       # heads of 64: two a lane row
     (12, 12, 1536, jnp.bfloat16, {}, 3),   # 12 of 128 in 16 bits: 4 of 384
     (12, 12, 1536, jnp.float32, {}, 1),    # in 32 bits any count is whole
     (12, 12, 1536, jnp.bfloat16, dict(kv_cache_dtype="int8"), 1),
-], ids=["whole_tiles", "head_dim_64", "no_whole_tiles", "float32", "int8"])
+    (8, 4, 1024, jnp.float32, {}, 2),      # 4 of 128: 2 of 256 (PR 64)
+    (8, 8, 512, jnp.bfloat16, {}, 4),      # 8 of 64: 2 of 256
+    (8, 4, 1024, jnp.bfloat16, dict(kv_cache_dtype="int8"), 1),
+    (8, 8, 1024, jnp.bfloat16, {}, 1),     # a tile of heads stays
+], ids=["whole_tiles", "head_dim_64", "no_whole_tiles", "float32", "int8",
+        "four_heads", "eight_of_64", "four_heads_int8", "eight_heads"])
 def test_init_pool_says_what_a_pool_head_holds(heads, kv, d_model, dtype, kw,
                                                pack):
     """`kv_pack`, the KV heads a pool's head holds side by side, and
@@ -333,9 +405,11 @@ def _compiles(one_chip, fn, *shapes):
 # (rows of a step, pool blocks, KV, D) as each serving cell writes
 CELL_WRITES = {
     "dense": (128, 705, 8, 128), "olmoe": (128, 705, 16, 128),
-    "mellum2_pages": (256, 3073, 4, 128), "mellum2_rings": (256, 961, 4, 128),
-    "lfm2_packed": (512, 2049, 4, 128), "qwen3next": (256, 1025, 2, 256),
+    "mellum2_pages": (256, 3073, 2, 256), "mellum2_rings": (256, 961, 2, 256),
+    "lfm2_packed": (512, 2049, 2, 256), "qwen3next": (256, 1025, 2, 256),
     "granite": (128, 1025, 8, 128), "nemotron": (256, 1025, 2, 128),
+    # what Mellum 2's pools were until PR 64, and a meshed pool's still
+    "four_heads": (256, 3073, 4, 128),
 }
 
 

@@ -140,7 +140,7 @@ def test_the_cut_builds_at_published_widths():
     # tracked sequence a conv layer
     cache = jax.eval_shape(lambda: M.init_cache(
         cfg, 2049, 128, jnp.bfloat16, state_slots=1024))
-    assert [a.shape for a in cache.k] == [(2049, 128, 4, 128)] * 3
+    assert [a.shape for a in cache.k] == [(2049, 128, 2, 256)] * 3
     assert [a.shape for (a,) in cache.state] == [(1024, 2, 16, 128)] * 10
     assert sum(a.size * 2 for a in cache.k + cache.v) / 2049 / 128 == 6144
 
@@ -386,7 +386,9 @@ def _packed_case(rng, ctx, H=8, KV=4, D=64, bs=16, NB=4, dtype=jnp.float32,
 
 
 def test_who_packs():
-    assert PA.kv_pack(8, 64, 2) == 2 and PA.kv_pack(2, 64, 4) == 2
+    # two a lane row, and of LFM2's 4 rows of 128 two a pool head (PR 64)
+    assert PA.kv_pack(8, 64, 2) == 4 and PA.kv_pack(2, 64, 4) == 2
+    assert PA.kv_pack(4, 64, 2) == 2 and PA.kv_pack(12, 64, 2) == 2
     assert PA.kv_pack(8, 128, 2) == 1 and PA.kv_pack(8, 32, 2) == 1
     assert PA.kv_pack(3, 64, 2) == 1  # an odd count of heads fills no row
 
@@ -451,6 +453,31 @@ def test_packed_rows_are_written_and_the_fused_walk_reads_them(rng):
     np.testing.assert_array_equal(np.asarray(fk)[live], np.asarray(xk)[live])
 
 
+@pytest.mark.usefixtures("pallas_interpret")
+def test_eight_kv_heads_of_64_are_served_from_two_wide_heads():
+    """The published 8 KV heads of 64 (under 8 query heads of a model
+    512 wide): the pool holds them four a head, [.., 2, 256] (kv_pack,
+    PR 64), the set-up's span says so, and the interpreted write and
+    walk serve what the reference computes from the heads one by one:
+    whole prompts, a chunk of 2 x 4 rows and single steps."""
+    hf = dict(HF, hidden_size=512, num_attention_heads=8,
+              num_key_value_heads=8)
+    model = F.model_of(FAMILY, hf)
+    assert (model[0].kv_heads, model[0].head_dim) == (8, 64)
+    profiler.spans(clear=True)
+    eng = F.Engines(model, ENGINE).fresh()
+    pool = next(s for s in profiler.spans(clear=True)
+                if s.name == "init.pool")
+    assert (pool.ids["kv_pack"], pool.ids["kv_heads_padded"],
+            pool.ids["kv_write"]) == (4, 0, "rows")
+    assert {k.shape[2:] for k in eng.cache.k} == {(2, 256)}
+    assert eng.resolved_impl == "pallas"
+    got, want, _, _ = F.feeds(FAMILY, model, eng, [32, 36], [4], 3, seed=4,
+                              hf=hf)
+    assert np.abs(want).max() > FAMILY.spread
+    assert np.abs(got - want).max() < LOGITS_ATOL, np.abs(got - want).max(-1)
+
+
 def test_a_wide_step_writes_first_and_attends_after():
     assert PA.fused_write_fits(128) and PA.fused_write_fits(248)
     assert not PA.fused_write_fits(256) and not PA.fused_write_fits(512)
@@ -459,5 +486,7 @@ def test_a_wide_step_writes_first_and_attends_after():
 @pytest.mark.parametrize("rows", [512, 128])
 def test_the_packed_walk_and_write_compile_for_v5e(one_chip, rows):
     """The cell's shapes: 32 query / 8 KV heads of 64 over pools packed
-    to [2049, 128, 4, 128], a table of 32 slots a row."""
-    F.walk_and_write_compile(one_chip, rows, 32, 8, 64, (2049, 128, 4, 128))
+    to [2049, 128, 2, 256], a table of 32 slots a row."""
+    pack = PA.kv_pack(8, 64, 2)
+    F.walk_and_write_compile(one_chip, rows, 32, 8, 64,
+                             (2049, 128, 8 // pack, 64 * pack))

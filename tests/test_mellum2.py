@@ -43,6 +43,7 @@ from deepspeed_tpu.inference.ragged import (
     StateManager,
 )
 from deepspeed_tpu.models import transformer as T
+from deepspeed_tpu.utils import profiler
 from deepspeed_tpu.utils.hf_checkpoint import config_from_hf
 
 BENCH = F.BENCH
@@ -176,18 +177,31 @@ def test_chunks_through_the_ring_at_every_offset(model, engines, tail,
     assert np.abs(got - want).max() < LOGITS_ATOL, np.abs(got - want).max(-1)
 
 
-def test_the_paged_kernels_write_and_walk_the_ring(pallas_interpret):
+@pytest.mark.parametrize("heads,kv,pack", [(4, 2, 1), (8, 4, 2)],
+                         ids=["two_kv_heads", "four_kv_heads_in_two_wide"])
+def test_the_paged_kernels_write_and_walk_the_ring(pallas_interpret, heads,
+                                                   kv, pack):
     """The Pallas path (interpreted): `paged_kv_write` into the ring's
     blocks and the shared-table walk over the table made from the
     ring's number, a chunk's rows riding as ONE group though each row's
     window starts a token later. The rehearsal configuration (heads of
     128, window 16 over blocks of 16: a ring of 3 blocks), a sequence
     of 70 tokens: 3 whole, four chunks of 16 (the most a ring of this
-    size takes), a ragged last chunk of 3 and single steps."""
-    hf = _tiny("tiny-mellum2")
+    size takes), a ragged last chunk of 3 and single steps. With the
+    published 4 KV heads, pages and rings alike hold them two a wide
+    head (kv_pack, PR 64) and the set-up's span says so."""
+    hf = dict(_tiny("tiny-mellum2"), num_attention_heads=heads,
+              num_key_value_heads=kv)
     model = _model(hf)  # another model: its own engine
+    profiler.spans(clear=True)
     eng = F.Engines(model, hf["serve"]["engine"]).fresh(
         max_seq_len=128, max_batch_size=16, decode_impl="pallas")
+    pool = next(s for s in profiler.spans(clear=True)
+                if s.name == "init.pool")
+    assert (pool.ids["kv_pack"], pool.ids["kv_heads_padded"]) == (pack, 0)
+    assert (pool.ids["kv_write"], pool.ids["ring_kv_write"]) == ("rows",) * 2
+    assert {k.shape[2:] for k in eng.cache.k} == {(kv // pack, 128 * pack)}
+    assert len({k.shape[0] for k in eng.cache.k}) == 2  # pages and rings
     assert eng.resolved_impl == "pallas" and eng.state.ring_blocks == 3
     got, want, _, _ = _ring_feeds(model, eng, [70], 67, 16, 3, hf=hf)
     # interpreted kernels multiply at the CPU's default precision

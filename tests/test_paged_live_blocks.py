@@ -13,6 +13,11 @@ the LIVE blocks of that row's table and nothing else.
 - dead table slots are not read, not merely masked; a group's blocks
   are fetched once, and rows walked alone are bit for bit what they are
   without a group beside them;
+- whole-tile pools of fewer than 8 KV heads in two wide heads (PR 64:
+  Mellum 2's 4 x 128 and LFM2's 8 x 64 as [.., 2, 256]): the walk and
+  the fused write against the unpacked float32 attention, over decode
+  rows, a chunk of 32 (two groups of 16), a ring two turns deep and
+  dead slots that name a block of NaN;
 - which case takes which kernel, read from the traced program;
 - AOT compiles for a DESCRIBED v5e at both serving cells' shapes (no
   chip; the topology is described inside a module-scoped fixture and
@@ -34,6 +39,7 @@ from jax.sharding import SingleDeviceSharding
 import deepspeed_tpu.models.transformer as T
 from deepspeed_tpu.inference import (
     ServingScheduler, ServingSchedulerConfig, init_inference)
+from deepspeed_tpu.inference import model as M
 from deepspeed_tpu.ops.attention import alibi_slopes
 import deepspeed_tpu.ops.pallas.paged_attention as PA
 from deepspeed_tpu.ops.pallas.paged_attention import (
@@ -411,6 +417,123 @@ def test_rows_walked_alone_are_what_they_are_without_a_group(rng):
     np.testing.assert_array_equal(np.asarray(out)[alone], np.asarray(want))
 
 
+# ---------------------------------------------------------------------------
+# whole-tile pools of fewer than 8 KV heads lie in two wide heads (PR 64):
+# Mellum 2's 4 heads of 128 and LFM2's 8 of 64 are pools [.., 2, 256]
+# ---------------------------------------------------------------------------
+
+def _wide_call(rng, KV, D, what):
+    """One call's arrays over kv_pack's pool of KV heads of D, and the
+    SAME values a head a head in float32 for the oracle: (q [S, 32, D],
+    the pools as the model holds them, the pools unpacked, the table,
+    the table the oracle gathers by, ctx, window). `what`: decode rows
+    alone; a chunk of 32 rows on one table between decode rows (16 query
+    rows a wide head: two groups of 16); Mellum 2's ring of 10 blocks of
+    128 tokens two turns deep under its window of 1,024, decode rows
+    then a chunk; dead table slots that name a block of NaN (in the
+    model's pools alone: the oracle gathers every slot, from its own)."""
+    bs, NB, window, ring, chunk = 16, 6, 0, 0, None
+    if what == "decode_rows":
+        ctx = (0, 1, 15, 16, 17, 40, 96, 0)
+    elif what == "chunk_of_32":
+        ctx, chunk = (40, *_rising(30, 32), 96), (1, 32)
+    elif what == "ring_two_turns":
+        # 2,560 tokens fill the ring twice; 1,300 is its second turn
+        bs, NB, window, ring = 128, 24, 1024, 10
+        ctx, chunk = (2561, 2688, 2817, 1300, 500, *_rising(2650, 32)), (5, 32)
+    else:
+        ctx = (0, 1, 16, 17, 40, 41, 42, 64)
+    S, H = len(ctx), 32
+    ctx = np.asarray(ctx, np.int32)
+    NBLK = S * (ring or NB) + 1
+    if ring:  # row s holds ring s: its R blocks named again and again
+        tbl = (np.arange(S)[:, None] * ring
+               + np.arange(NB)[None, :] % ring).astype(np.int32)
+    else:
+        tbl = rng.permutation(NBLK - 1)[:S * NB].reshape(S, NB).astype(
+            np.int32)
+    if chunk:
+        tbl[chunk[0]:sum(chunk)] = tbl[chunk[0]]
+    plain = [jnp.asarray(rng.normal(size=(NBLK, bs, KV, D)), jnp.float32)
+             for _ in range(2)]
+    pack = PA.kv_pack(KV, D, 2)
+    pools = [p.reshape(NBLK, bs, KV // pack, pack * D) for p in plain]
+    assert pools[0].shape[2:] == (2, 256)
+    clean = tbl.copy()
+    if what == "dead_slots":
+        for s in range(S):
+            tbl[s, -(-int(ctx[s]) // bs):] = NBLK - 1
+        assert (tbl == NBLK - 1).sum() >= 12
+        pools = [p.at[NBLK - 1].set(jnp.nan) for p in pools]
+    q = jnp.asarray(rng.normal(size=(S, H, D)), jnp.float32)
+    return (q, pools, plain, jnp.asarray(tbl), jnp.asarray(clean), ctx,
+            window)
+
+
+@pytest.mark.usefixtures("pallas_interpret")
+@pytest.mark.parametrize("KV,D", [(4, 128), (8, 64)], ids=["4x128", "8x64"])
+@pytest.mark.parametrize("what", ["decode_rows", "chunk_of_32",
+                                  "ring_two_turns", "dead_slots"])
+def test_the_walk_over_two_wide_heads_is_the_unpacked_attention(rng, KV, D,
+                                                                what):
+    q, pools, plain, tbl, clean, ctx, window = _wide_call(rng, KV, D, what)
+    with jax.default_matmul_precision("highest"):
+        out = _one_program(paged_decode_attention, window)(
+            q, *pools, tbl, jnp.asarray(ctx))
+        want = _one_program(paged_decode_attention_xla, window)(
+            q, *plain, clean, jnp.asarray(ctx))
+    real = ctx > 0
+    np.testing.assert_allclose(np.asarray(out)[real], np.asarray(want)[real],
+                               rtol=2e-3, atol=2e-3)
+    assert not np.asarray(out)[~real].any()
+    # it IS the walk, at 2 heads of 16 query rows: a chunk's rows in
+    # groups of 16, each read once
+    pack = PA._packing(q, pools[0])
+    qg = PA._group_queries(PA._pack_queries(q, pack, 32 // KV), 2)[0]
+    assert qg.shape[1:] == (2, 16, 256) and PA._walks_live_blocks(qg, pools[0])
+    bound = PA._walk_group_rows(32 // KV, pack, len(ctx))
+    assert bound == min(16, len(ctx))
+    if what == "chunk_of_32":  # rows 1-32: rows 1 and 17 walk for them
+        lead = np.asarray(PA.walk_groups(tbl, bound))
+        assert lead.tolist() == [0] + [1] * 16 + [17] * 16 + [33]
+
+
+@pytest.mark.usefixtures("pallas_interpret")
+@pytest.mark.parametrize("KV,D", [(4, 128), (8, 64)], ids=["4x128", "8x64"])
+@pytest.mark.parametrize("what", ["decode_rows", "ring_two_turns",
+                                  "dead_slots"])
+def test_the_fused_write_over_two_wide_heads(rng, KV, D, what):
+    """paged_decode_fused at [.., 2, 256]: every row's new K/V lands in
+    its slot of the pool as a scatter into the UNPACKED pool puts it,
+    and the attention over it is the unpacked one (decode rows: a row a
+    sequence, so the ring's chunk is left out)."""
+    q, pools, plain, tbl, clean, ctx, window = _wide_call(rng, KV, D, what)
+    S = 5 if what == "ring_two_turns" else len(ctx)
+    q, tbl, clean, ctx = q[:S], tbl[:S], clean[:S], ctx[:S]
+    bs, real = pools[0].shape[1], ctx > 0
+    kn, vn = (jnp.asarray(rng.normal(size=(S, KV, D)), jnp.float32)
+              for _ in range(2))
+    pos = np.maximum(ctx - 1, 0)
+    slots = jnp.asarray(np.where(
+        real, np.asarray(tbl)[np.arange(S), pos // bs] * bs + pos % bs,
+        -1).astype(np.int32))
+    with jax.default_matmul_precision("highest"):
+        out, fk, fv = jax.jit(functools.partial(
+            paged_decode_attention, window=window))(
+            q, *pools, tbl, jnp.asarray(ctx), k_new=kn, v_new=vn,
+            slots=slots)
+        xk, xv = M._write_kv_xla(*plain, kn, vn, slots)
+        want = _one_program(paged_decode_attention_xla, window)(
+            q, xk, xv, clean, jnp.asarray(ctx))
+    np.testing.assert_allclose(np.asarray(out)[real], np.asarray(want)[real],
+                               rtol=2e-3, atol=2e-3)
+    live = np.unique(np.asarray(slots)[real] // bs)
+    for got, scattered in ((fk, xk), (fv, xv)):
+        np.testing.assert_array_equal(
+            np.asarray(got).reshape(scattered.shape)[live],
+            np.asarray(scattered)[live])
+
+
 @pytest.mark.parametrize("tables,bound,lead", [
     ([1, 2, 2, 2, 3, 3, 4], 32, [0, 1, 1, 1, 4, 4, 6]),
     ([5, 5, 5, 5, 5, 5, 5], 3, [0, 0, 0, 3, 3, 3, 6]),
@@ -479,8 +602,9 @@ def test_which_case_walks_and_which_keeps_the_grid(what, KV, D, dtype,
 BLOCK, BLOCKS_PER_SEQ = 128, 32
 
 # the six serving cells that run the walk, at their engines' shapes:
-# rows of a step, query / KV heads, head dim, pool blocks; lfm2's pool
-# is packed (kv_pack: 8 heads of 64 as 4 rows of 128 lanes)
+# rows of a step, query / KV heads, head dim, pool blocks; lfm2's and
+# mellum2's pools are packed (kv_pack: 8 heads of 64, 4 of 128, as 2
+# heads of 256)
 CELLS = {
     "serve-chat-saturated": dict(rows=128, H=32, KV=8, D=128, pool=704),
     "serve-olmoe-chat-saturated": dict(rows=128, H=16, KV=16, D=128,
@@ -526,11 +650,15 @@ def one_chip():
     ("serve-mellum2-mixedlen-saturated-r256", 1024),
 ])
 def test_shared_table_attention_compiles_for_v5e(one_chip, cell, window):
-    """The walk WITH its grouped body (groups of 32 rows at every cell's
-    Gp of 8) is what Mosaic is handed at each cell's shapes."""
+    """The walk WITH its grouped body (groups of 32 rows at a Gp of 8,
+    of 16 rows at the 16 query rows of a wide head: Mellum 2's and
+    LFM2's pools [.., 2, 256]) is what Mosaic is handed at each cell's
+    shapes."""
     c = CELLS[cell]
     rows, H, KV, D = c["rows"], c["H"], c["KV"], c["D"]
     pack = PA.kv_pack(KV, D, 2)
+    wide = cell.split("-")[1] in ("lfm2", "mellum2")
+    assert ((KV // pack, D * pack) == (2, 256)) == (wide or KV == 2)
 
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -542,7 +670,7 @@ def test_shared_table_attention_compiles_for_v5e(one_chip, cell, window):
     def fn(q, kc, vc, table, ctx):
         return paged_decode_attention(q, kc, vc, table, ctx, window=window)
 
-    assert PA._group_rows(max(H // KV, 8), rows) == 32
+    assert PA._walk_group_rows(H // KV, pack, rows) == (16 if wide else 32)
     assert _kernel_grids(fn, *args) == [(rows,)]
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert any('custom_call_target="tpu_custom_call"' in line

@@ -404,7 +404,11 @@ class ServingSchedulerConfig(ConfigModel):
     passes one directly. A request whose admission-time TTFT estimate
     exceeds its deadline is rejected with finish_reason='deadline'
     BEFORE any KV block is touched.
-    pressure: the memory-pressure governor block (PressureConfig)."""
+    pressure: the memory-pressure governor block (PressureConfig).
+    denoising_steps: for a model that generates by diffusion over blocks
+    (TransformerConfig.block_length B) and no other: the denoising
+    passes T a block of B masked positions takes, ceil(B / T) positions
+    revealed a pass, the most confident first; 0 = B (one a pass)."""
 
     max_num_batched_tokens: int = 256
     prefill_chunk: int = 32
@@ -416,9 +420,13 @@ class ServingSchedulerConfig(ConfigModel):
     max_preemptions: int = 8
     slo_classes: Dict[str, float] = Field(default_factory=dict)
     pressure: PressureConfig = Field(default_factory=PressureConfig)
+    denoising_steps: int = 0
 
     @model_validator(mode="after")
     def _check(self):
+        if self.denoising_steps < 0:
+            raise ValueError("denoising_steps must be >= 0 (0 = one "
+                             "position a pass)")
         if self.max_preemptions < 0:
             raise ValueError("max_preemptions must be >= 0 (0 = off)")
         for name, dl in self.slo_classes.items():

@@ -172,6 +172,34 @@ def refuse_for_pools(cfg: T.TransformerConfig, feature: str) -> None:
             f"pool cannot do it yet (inference/engine.py _POOL_CANNOT)")
 
 
+# What a model that generates by diffusion over blocks (cfg.block_length)
+# is not served with, and why: each is refused where the engine or the
+# scheduler is built, by refuse_block_diffusion.
+_BLOCK_DIFFUSION_CANNOT = {
+    "speculation": "a draft continues a causal sequence token by token; a "
+                   "block is revealed in the order of its confidences",
+    "decode_multi": "the fused multi-step program feeds ONE sampled token a "
+                    "row from step to step (decode_chunk > 1); a denoising "
+                    "pass feeds a block's rows at the same positions",
+    "wave": "a whole-prompt wave samples one token a prompt; a prompt's "
+            "whole blocks yield none, and its remainder opens the first "
+            "block (prefill_mode='chunked')",
+    "presence": "the repetition penalty needs each token on the host before "
+                "the next draw; a block's tokens stay on the device between "
+                "passes",
+    "handoff": "a request parked after its first TOKEN has no counterpart: "
+               "the first commit yields a block",
+}
+
+
+def refuse_block_diffusion(cfg: T.TransformerConfig, feature: str) -> None:
+    if cfg.block_length:
+        raise NotImplementedError(
+            f"{feature} is not served for a model that generates by "
+            f"diffusion over blocks (block_length {cfg.block_length}): "
+            f"{_BLOCK_DIFFUSION_CANNOT[feature]}")
+
+
 def ring_geometry(cfg: T.TransformerConfig, config) -> Tuple[int, int]:
     """(rings, blocks a ring) of the windowed layers' pool an engine of
     `config` holds for this model; (0, 0) for a model without rings."""
@@ -374,6 +402,21 @@ class InferenceEngine:
                 ("int8_kv", self.config.kv_cache_dtype != "auto")):
             if asked:
                 refuse_for_pools(model_config, feature)
+        B = model_config.block_length
+        if B and self.mesh is not None:
+            raise NotImplementedError(
+                "a model that generates by diffusion over blocks is served "
+                "on one device: its whole-prompt prefill masks in XLA and "
+                "its look-ahead feeds a block's tokens from a committed "
+                "device array, neither of which a mesh program takes yet")
+        if B and (self.config.kv_block_size % B
+                  or self.config.min_prefill_bucket % B):
+            raise ValueError(
+                f"block_length {B} must divide kv_block_size "
+                f"{self.config.kv_block_size} and min_prefill_bucket "
+                f"{self.config.min_prefill_bucket}: a diffusion block never "
+                "straddles two KV blocks, so pages move and are shared in "
+                "whole diffusion blocks")
         if self.config.decode_impl not in ("auto", "pallas", "xla"):
             raise ValueError(
                 f"decode_impl must be 'auto', 'pallas' or 'xla' "
@@ -973,6 +1016,12 @@ class InferenceEngine:
             return self._census.copy()
 
     def _decode_fn(self, s: int, unique_rows: bool = False):
+        """The compiled step over `s` rows: (params, cache, tokens,
+        tables, ctx, *position_args, *state_args) -> (logits, cache). A
+        model that generates by diffusion over blocks has the one
+        program whatever `unique_rows` says: a block's rows share a
+        table and see each other."""
+        unique_rows = unique_rows and not self.cfg.block_length
         key = (s, unique_rows)
         if key not in self._decode_fns:
             cfg, use_kernel, deq = self.cfg, self._use_kernel, self._dequant
@@ -982,10 +1031,13 @@ class InferenceEngine:
             census = self._census_cb()
 
             def step(params, cache, tokens, tables, ctx, *rows):
+                where = {}
+                if cfg.block_length:  # position_args' operand comes first
+                    where["positions"], *rows = rows
                 return M.decode_step(
                     deq(params), cache, tokens, tables, ctx, cfg, use_kernel,
                     mesh=mesh, unique_rows=unique_rows, fetch_layer=fetch,
-                    census_cb=census, **self._row_kwargs(rows),
+                    census_cb=census, **where, **self._row_kwargs(rows),
                 )
 
             # donated: the KV cache aliases the returned cache in-place
@@ -1000,6 +1052,7 @@ class InferenceEngine:
         sampling.SamplingConfig compiled into the program (None =
         greedy); with_presence adds the [s, vocab] repetition-penalty
         bitmap to the carried state."""
+        refuse_block_diffusion(self.cfg, "decode_multi")
         key = (s, n_steps, None if sampling is None else sampling.key(),
                with_presence)
         if not hasattr(self, "_decode_multi_fns"):
@@ -1073,6 +1126,42 @@ class InferenceEngine:
                 lambda prev, toks, src: jnp.where(
                     src >= 0, prev[jnp.maximum(src, 0)], toks))
         return self._next_tokens
+
+    def position_args(self, positions: Optional[np.ndarray],
+                      width: int = 0) -> tuple:
+        """The operand a compiled step over rows takes before
+        state_args' for a model that generates by diffusion over blocks:
+        each row's position, apart from its visible length
+        (model.decode_step; None: `width` pad rows). Empty for every
+        other model, whose programs take no such operand."""
+        if not self.cfg.block_length:
+            return ()
+        if positions is None:
+            positions = np.zeros((width,), np.int32)
+        return (self._dev(np.asarray(positions, np.int32)),)
+
+    def block_ctx(self, positions: np.ndarray) -> np.ndarray:
+        """What rows at `positions` see under the block-causal mask:
+        through the end of their block."""
+        B = self.cfg.block_length
+        return (np.asarray(positions) // B + 1) * B
+
+    def _block_unmask_fn(self, scfg, reveal: int):
+        """Compiled sample-and-reveal epilogue of a denoising pass
+        (sampling.block_unmask) over a [n, V] logits batch: `reveal`
+        positions a block."""
+        from .sampling import block_unmask
+
+        key = (scfg.key(), reveal)
+        if not hasattr(self, "_block_unmask_fns"):
+            self._block_unmask_fns = {}
+        if key not in self._block_unmask_fns:
+            B, mask_id = self.cfg.block_length, self.cfg.mask_token_id
+            self._block_unmask_fns[key] = jax.jit(
+                lambda lg, toks, active, keys, steps: block_unmask(
+                    lg, toks, active, scfg, keys, steps, block_length=B,
+                    mask_id=mask_id, reveal=reveal))
+        return self._block_unmask_fns[key]
 
     def _dev(self, x):
         """Host array → device, replicated over the serving mesh (so the
@@ -1462,8 +1551,20 @@ class InferenceEngine:
         presence: Optional[np.ndarray] = None,
         strict: bool = True,
         sampling_streams: Optional[Sequence[int]] = None,
+        commit: bool = True,
     ) -> Any:
         """Run one engine step over a ragged batch.
+
+        A model that generates by diffusion over blocks
+        (cfg.block_length B): every run is whole blocks and starts on a
+        block boundary (a new uid's prompt, a known uid's next blocks),
+        and the logits of EVERY row of each run's last block come back,
+        [len(uids), B, vocab]: a position's logits are the distribution
+        of the token at that position. commit=False is a DENOISING
+        pass: the rows' K/V are written and seen_tokens stays, so the
+        same positions are fed again; the pass that leaves the block's
+        K/V for good is an ordinary put(). Sampling a block is the
+        scheduler's (return_tokens is refused).
 
         New uids carry their whole prompt; known uids carry exactly one
         continuation token. Returns next-token logits [len(uids), vocab]
@@ -1497,6 +1598,21 @@ class InferenceEngine:
             raise ValueError("duplicate uids in one put()")
         if len(uids) != len(tokens):
             raise ValueError("uids and tokens length mismatch")
+        B = self.cfg.block_length
+        if not commit and not B:
+            raise ValueError(
+                "commit=False is a denoising pass of a model that generates "
+                "by diffusion over blocks (cfg.block_length); this model "
+                "is causal")
+        if B and return_tokens:
+            raise ValueError(
+                "a block's tokens are sampled and revealed by the scheduler "
+                "(ServingScheduler); put() of a block-diffusion model "
+                "returns logits")
+        if B and any(len(t) % B for t in tokens):
+            raise ValueError(
+                f"a run fed to a model of block_length {B} is whole blocks: "
+                f"got runs of {[len(t) for t in tokens]} tokens")
 
         prefills: List[Tuple[int, int, np.ndarray]] = []  # (pos, uid, toks)
         # chunked continuation (SplitFuse/ragged analog): an in-flight
@@ -1529,6 +1645,10 @@ class InferenceEngine:
             else:
                 if len(toks) > self.config.max_seq_len:
                     raise ValueError(f"prompt of {len(toks)} > max_seq_len")
+                if not commit:
+                    raise ValueError(
+                        f"uid {uid}: a denoising pass (commit=False) feeds a "
+                        "block of a sequence already in the cache")
                 prefills.append((i, uid, toks))
         if n_rows > self.config.max_batch_size:
             raise RuntimeError(
@@ -1574,7 +1694,8 @@ class InferenceEngine:
                                                     self._dev(steps))
             tok_out[np.asarray(row_pos)] = np.asarray(toks)[np.asarray(rows)]
 
-        out = np.zeros((len(uids), self.cfg.vocab_size), np.float32)
+        out = np.zeros((len(uids),) + ((B,) if B else ())
+                       + (self.cfg.vocab_size,), np.float32)
 
         rejected: List[int] = []
         if prefills:
@@ -1613,7 +1734,8 @@ class InferenceEngine:
             for pos, uid, toks in prefills:
                 budget = self.config.max_batch_size - n_rows
                 _, match = self.state.extend(
-                    uid, len(toks), token_ids=toks, max_suffix_rows=budget)
+                    uid, len(toks), token_ids=toks, max_suffix_rows=budget,
+                    align=B or 1)
                 if match.n_cached > 0:
                     if match.cow is not None:
                         # shared full-match tail: clone the page before
@@ -1687,6 +1809,7 @@ class InferenceEngine:
             sp = _bucket(n_rows, 8)
             toks = np.zeros((sp,), np.int32)
             ctx = np.zeros((sp,), np.int32)  # pad rows: ctx 0 = inert
+            where = np.zeros((sp,), np.int32)  # a block model's positions
             tables = np.full((sp, self.config.blocks_per_seq),
                              self.pad_block, np.int32)
             slots = np.full((sp,), -1, np.int32)
@@ -1702,10 +1825,13 @@ class InferenceEngine:
                 for j, tok in enumerate(chunk):
                     toks[row] = int(tok)
                     ctx[row] = base + j + 1
+                    where[row] = base + j
                     tables[row] = table
                     slots[row], rings[row] = seq.slot, seq.ring
                     row += 1
                 last_row.append(row - 1)
+            if B:  # a row sees through the end of its block
+                ctx[:n_rows] = self.block_ctx(where[:n_rows])
             # single-token rows are all DISTINCT sequences → the fused
             # write+attend kernel applies; multi-token chunks share a
             # table across rows and keep the separate write kernel
@@ -1713,10 +1839,12 @@ class InferenceEngine:
             logits, self.cache = self._decode_fn(sp, unique)(
                 self.params, self.cache, self._dev(toks),
                 self._dev(tables), self._dev(ctx),
+                *self.position_args(where),
                 *self.state_args(slots, rings),
             )
             for (pos, uid, chunk), lr in zip(decodes, last_row):
-                self.state.commit(uid, len(chunk), token_ids=chunk)
+                if commit:
+                    self.state.commit(uid, len(chunk), token_ids=chunk)
             if return_tokens:
                 sample_rows(
                     logits,
@@ -1729,7 +1857,8 @@ class InferenceEngine:
             else:
                 logits_np = np.asarray(logits[:n_rows])
                 for (pos, uid, chunk), lr in zip(decodes, last_row):
-                    out[pos] = logits_np[lr]
+                    out[pos] = (logits_np[lr + 1 - B:lr + 1] if B
+                                else logits_np[lr])
         result = tok_out if return_tokens else out
         if not strict:
             return result, rejected
@@ -1748,6 +1877,7 @@ class InferenceEngine:
         decode_chunks: Sequence[int] = (),
         presence: bool = False,
         footprint: bool = True,
+        reveals: Sequence[int] = (1,),
     ) -> Dict[str, Any]:
         """Precompile the (bucket width x chunk) decode/sample grid so
         steady-state serving triggers ZERO recompiles (S003): every
@@ -1762,7 +1892,11 @@ class InferenceEngine:
         bucket(max_batch_size)). chunked=True additionally compiles the
         shared-table variant mixed prefill chunks need. decode_chunks:
         fused multi-step depths (model.decode_multi) to warm per width.
-        sampling/presence select the sampling epilogue variant. Off a
+        sampling/presence select the sampling epilogue variant; a model
+        that generates by diffusion over blocks warms its
+        sample-and-reveal epilogue instead, once for each of `reveals`
+        (positions revealed a pass: ceil(block_length /
+        denoising_steps), which the scheduler passes). Off a
         mesh and without presence the scheduler's look-ahead composes a
         step's tokens on the device (`_next_tokens_fn`): its program is
         warmed for every (previous width, width) pair of `widths`.
@@ -1859,15 +1993,29 @@ class InferenceEngine:
                 if set(self.cfg.layer_types or ()) & set(M._STEP_OF):
                     named["state_step"] = ("kernel" if self.step_kernel(w)
                                            else "xla")
-            for uniq in ((True, False) if chunked else (True,)):
+            B = self.cfg.block_length
+            # a block-diffusion model has the shared-table program alone
+            for uniq in ((False,) if B else
+                         (True, False) if chunked else (True,)):
                 rt.record(f"serving_decode[w{w},u{int(uniq)}]",
                           (toks, tables, ctx))
                 logits, self.cache = warm(
                     "decode", w, lambda: self._decode_fn(w, uniq)(
                         self.params, self.cache, self._dev(toks),
-                        self._dev(tables), self._dev(ctx), *state),
+                        self._dev(tables), self._dev(ctx),
+                        *self.position_args(None, w), *state),
                     unique=int(uniq), **named)
-            if with_pres:
+            if B:
+                # the sample-and-reveal epilogue of a denoising pass, at
+                # every count of positions a pass may reveal
+                active = self._dev(np.zeros((w,), bool))
+                for reveal in reveals:
+                    rt.record(f"serving_unmask[w{w},r{reveal}]", (steps,))
+                    warm("unmask", w,
+                         lambda: self._block_unmask_fn(scfg, reveal)(
+                             logits, self._dev(toks), active, keys,
+                             self._dev(steps)), reveal=reveal)
+            elif with_pres:
                 pres = np.zeros((w, V), np.uint8)
                 rt.record(f"serving_sample[w{w}]", (steps, pres))
                 warm("sample", w, lambda: self._sample_fn(scfg, True)(
@@ -1961,6 +2109,7 @@ class InferenceEngine:
                          self.pad_block, np.int32)
         return self._aot(self._decode_fn(width, unique_rows),
                          self._dev(toks), self._dev(tables), self._dev(toks),
+                         *self.position_args(None, width),
                          *self.state_args(toks - 1))
 
     def compiled_prefill(self, bp: int, tp: int):
@@ -2020,6 +2169,7 @@ class InferenceEngine:
                 lowered = self._decode_fn(w, True).lower(
                     self.params, self.cache, self._dev(toks),
                     self._dev(tables), self._dev(ctx),
+                    *self.position_args(None, w),
                     *self.state_args(toks - 1))
                 compiled = lowered.compile()
             reports.append(check_program_numerics(
@@ -2042,6 +2192,7 @@ class InferenceEngine:
         the caller commits only the accepted prefix (rejected rows'
         slots are simply overwritten by the next real tokens)."""
         refuse_for_pools(self.cfg, "speculation")
+        refuse_block_diffusion(self.cfg, "speculation")
         rows = sum(len(c) for c in chunks)
         if rows > self.config.max_batch_size:
             raise RuntimeError(
@@ -2244,11 +2395,12 @@ class InferenceEngine:
         # ds-lint: ok D004 fresh-seed request; replay threads the drawn seed
         seed_val = (int(np.random.default_rng().integers(2**31))
                     if seed is None else int(seed))
+        blocks = bool(self.cfg.block_length)  # neither fused nor waved
         sched = ServingScheduler(
             self,
             ServingSchedulerConfig(
-                decode_chunk=max(1, int(chunk)),
-                prefill_mode="wave",
+                decode_chunk=1 if blocks else max(1, int(chunk)),
+                prefill_mode="chunked" if blocks else "wave",
                 max_num_batched_tokens=max(
                     self.config.max_batch_size,
                     ServingSchedulerConfig().max_num_batched_tokens),

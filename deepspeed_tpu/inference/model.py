@@ -47,7 +47,11 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..models import transformer as T
-from ..ops.attention import causal_attention, uses_flash
+from ..ops.attention import (
+    block_causal_attention,
+    causal_attention,
+    uses_flash,
+)
 from ..ops.pallas.expert_stream import (
     expert_grouped_mlp,
     expert_stream_mlp,
@@ -1938,10 +1942,20 @@ def decode_step(
     params, cache: PagedCache, tokens, tables, ctx_lens, cfg: T.TransformerConfig,
     use_kernel: bool = True, mesh: Optional[Mesh] = None,
     unique_rows: bool = False, fetch_layer=None, census_cb=None,
-    slots=None, rings=None,
+    slots=None, rings=None, positions=None,
 ):
     """tokens [S] int32, tables [S, NB] int32, ctx_lens [S] int32 (context
     length INCLUDING the new token) → (logits [S, V], new cache).
+
+    positions [S] int32: each row's POSITION (rotary, the slot its K/V
+    is written to) where that is not its visible length less one: a
+    model that generates by diffusion over blocks (cfg.block_length B)
+    and no other. A row at position p sees through the END of its block,
+    ctx_lens = (p // B + 1) * B, and a block's rows are fed several
+    times at the SAME positions, each pass writing the block's K/V over
+    the last one's: the write goes before the walk, so the rows of one
+    dispatch see each other (docs/paged_attention.md). Absent:
+    ctx_lens - 1, every other family's program text.
 
     rings [S] int32: each row's sequence's ring of the windowed layers'
     pools (-1: batch padding), for a model of mixed windows and no
@@ -1974,7 +1988,13 @@ def decode_step(
     # rows with ctx_lens == 0 are batch padding: their KV write is dropped
     # and their (garbage) logits are sliced off by the engine
     valid = ctx_lens > 0
-    positions = jnp.maximum(ctx_lens - 1, 0)  # [S] this token's position
+    if positions is None:
+        positions = jnp.maximum(ctx_lens - 1, 0)  # [S] this token's position
+    elif unique_rows:
+        raise ValueError(
+            "rows given `positions` are a block's: they share a table and "
+            "see each other, which the fused write+attend program "
+            "(unique_rows) does not do")
     # per-row flat slot: each row has its own table; padding rows
     # scatter to -1 which mode="drop" discards
     flat_idx = (
@@ -2214,6 +2234,11 @@ def prefill_batch(
             _pool_rows(v.reshape(B * Tp, *v.shape[2:]), cfg),
             flat_by_window[cfg.ring_layers[cfg.op_index(li)]],
             mesh, use_kernel)
+        if cfg.block_length:
+            # the block-causal mask, in XLA (the flash kernel's tiles
+            # know the causal and the windowed mask alone); this family
+            # is refused a mesh by the engine
+            return block_causal_attention(q, k, v, cfg.block_length), pools
         flash = partial(causal_attention, window=cfg.window_for_layer(li))
         if _heads_shardable(mesh, cfg):
             # flash kernel per head-shard; GQA grouping stays
@@ -2241,6 +2266,13 @@ def prefill_batch(
         # gather before the vocab matmul so the head runs on B tokens,
         # not B*Tp
         last = jnp.maximum(n_real - 1, 0)  # [B]; padding rows read pos 0
+        if cfg.block_length:
+            # every position of the last block predicts ITSELF: the
+            # block's rows, [B, block_length, E]
+            rows = (last // cfg.block_length * cfg.block_length)[:, None] \
+                + jnp.arange(cfg.block_length, dtype=jnp.int32)[None]
+            return jnp.take_along_axis(
+                x, jnp.minimum(rows, Tp - 1)[:, :, None], axis=1)
         return jnp.take_along_axis(
             x, last[:, None, None].astype(jnp.int32).repeat(x.shape[-1], axis=2),
             axis=1)[:, 0]

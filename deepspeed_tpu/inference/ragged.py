@@ -481,7 +481,7 @@ class StateManager:
     # -- mutation --------------------------------------------------------
     def extend(
         self, uid: int, new_tokens: int, token_ids=None,
-        max_suffix_rows: Optional[int] = None,
+        max_suffix_rows: Optional[int] = None, align: int = 1,
     ) -> Union[SequenceDescriptor,
                Tuple[SequenceDescriptor, PrefixMatch]]:
         """Reserve cache room for `new_tokens` more tokens of `uid`
@@ -502,7 +502,9 @@ class StateManager:
         carries a (src, dst) page copy the engine must issue before the
         tail is written. max_suffix_rows bounds the non-cached suffix
         (the engine's decode-row budget); a hit whose suffix would not
-        fit degrades to a plain miss."""
+        fit degrades to a plain miss. align: the credit is cut to a
+        multiple of it (a block-diffusion model feeds whole blocks: its
+        suffix starts on a block boundary)."""
         created = uid not in self._seqs
         seq = self.get_or_create(uid)
         match: Optional[PrefixMatch] = None
@@ -510,7 +512,7 @@ class StateManager:
         try:
             if token_ids is not None:
                 match = self._match_prefix(seq, token_ids, max_suffix_rows,
-                                           acquired)
+                                           acquired, align)
                 # a match already advanced seen_tokens to n_cached: the
                 # room still needed is the non-cached remainder
                 new_tokens = len(token_ids) - seq.seen_tokens
@@ -533,7 +535,7 @@ class StateManager:
 
     def _match_prefix(self, seq: SequenceDescriptor, token_ids,
                       max_suffix_rows: Optional[int],
-                      acquired: List[int]) -> PrefixMatch:
+                      acquired: List[int], align: int = 1) -> PrefixMatch:
         """Walk + acquire the prefix chain for a new sequence; fills
         `acquired` so the caller can roll back on allocation failure."""
         n = len(token_ids)
@@ -545,6 +547,7 @@ class StateManager:
             return PrefixMatch(0, [], [])
         chain = self._walk_chain(seq.tokens)
         n_cached = min(len(chain) * self.block_size, n - 1)
+        n_cached -= n_cached % align
         if n_cached <= 0 or (max_suffix_rows is not None
                              and n - n_cached > max_suffix_rows):
             self.stats["lookup_misses"] += 1

@@ -224,3 +224,43 @@ def host_oracle_token(logits, cfg: SamplingConfig, key, t,
     keys = jnp.asarray(key)[None]
     steps = jnp.asarray(t, jnp.int32)[None]
     return int(sample_tokens(row, cfg, keys, steps, pres)[0])
+
+
+def block_unmask(logits, tokens, active, cfg: SamplingConfig, keys, step, *,
+                 block_length: int, mask_id: int, reveal: int):
+    """The epilogue of one DENOISING PASS of a model that generates by
+    diffusion over blocks: [S, V] logits of the pass's rows, `tokens`
+    [S] int32 the rows were fed, `active` [S] bool (the rows that are a
+    position to GENERATE of a block being denoised; every other row, a
+    block's fixed head of prompt tokens, a prompt chunk's, a commit
+    pass's or padding, comes back as it went in, the mask id too if a
+    prompt holds it) -> [S] int32, the tokens the SAME rows are fed
+    next pass. A block is block_length adjacent rows from a multiple of
+    block_length on. Under the device scope `block_unmask`.
+
+    Every active row that held `mask_id` samples a token from the logits AT
+    its own position, the mask id excluded (sample_tokens: greedy, or
+    the chain drawn from `keys` / `step`), and takes as its confidence
+    the probability the softmax of those logits gives that token; of a block's masked rows
+    the `reveal` most confident (ties to the lowest position) keep
+    their token, the others stay masked (the publisher's
+    `low_confidence_static` rule). Nothing crosses to the host: the
+    look-ahead gathers the next pass's input from this array."""
+    with jax.named_scope("block_unmask"):
+        # a generated token is never the mask itself: a position that
+        # drew it would stay masked and the passes, counted on the host,
+        # would not empty the block
+        lg = jnp.where(jnp.arange(logits.shape[-1]) == mask_id, -jnp.inf,
+                       logits.astype(jnp.float32))
+        sampled = sample_tokens(lg, cfg, keys, step)
+        conf = jnp.exp(
+            jnp.take_along_axis(lg, sampled[:, None], axis=-1)[:, 0]
+            - jax.scipy.special.logsumexp(lg, axis=-1))
+        masked = ((tokens == mask_id) & active).reshape(-1, block_length)
+        # lax.top_k is stable: of equal confidences the lower position
+        rank = jax.lax.top_k(
+            jnp.where(masked, conf.reshape(-1, block_length), -1.0),
+            min(reveal, block_length))[1]
+        chosen = jnp.any(
+            rank[:, :, None] == jnp.arange(block_length)[None, None], axis=1)
+        return jnp.where((chosen & masked).reshape(-1), sampled, tokens)

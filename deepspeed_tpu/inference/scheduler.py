@@ -53,6 +53,18 @@ Performance comes from two pipelining layers:
   decode_chunk steps into one compiled program (model.decode_multi),
   amortizing dispatch entirely.
 
+A model that generates by DIFFUSION OVER BLOCKS
+(TransformerConfig.block_length B; docs/serving_scheduler.md "A step
+that yields a block") runs through the same loop and the same compiled
+program, with one difference of kind: a running sequence's step is B
+rows and yields no token. Its block is fed whole, pass after pass, at
+the same positions (`_Block`): denoising passes whose sample epilogue
+reveals the most confident of the masked positions ON THE DEVICE
+(sampling.block_unmask; the look-ahead feeds pass t + 1 from pass t's
+array as it feeds a causal row its token), then one commit pass that
+leaves the block's K/V for good, at which `seen_tokens` advances by B
+and `Request.output` grows by the block.
+
 `generate()` and `generate_speculative()` are thin wrappers over this
 scheduler (prefill_mode='wave', warmup off) — one control plane serves
 batch generation, speculative decoding, and online serving.
@@ -75,7 +87,12 @@ from ..resilience.integrity import HandoffIntegrityError
 from ..utils import profiler
 from ..utils.logging import log_dist
 from ..utils.sync import serving_readback
-from .engine import InferenceEngine, _bucket, refuse_for_pools
+from .engine import (
+    InferenceEngine,
+    _bucket,
+    refuse_block_diffusion,
+    refuse_for_pools,
+)
 from .model import kv_pool_pack, kv_pool_shape
 from .pressure import BROWNOUT, RED, PressureGovernor, estimate_ttft
 from .ragged import KVCacheExhaustedError
@@ -151,6 +168,9 @@ class Request:
     # payload in the scheduler's HostKvSpillStore — resume imports the
     # pages instead of recomputing; None = recompute on re-admission
     spill_key: Optional[int] = None
+    # a block-diffusion model's request while RUNNING: the block it is
+    # generating (it has no `pending` token)
+    block: Optional["_Block"] = None
 
     @property
     def base(self) -> List[int]:
@@ -165,15 +185,36 @@ class Request:
         return self.state == FINISHED
 
 
+@dataclasses.dataclass(eq=False)
+class _Block:
+    """The block a RUNNING request of a block-diffusion model is
+    generating: B positions from `seen_tokens` on, fed whole every pass.
+    Identity matters (a preemption drops the block, and a pass of it
+    still in flight must find it gone), so no equality by value."""
+
+    tokens: List[int]       # the host's newest copy: mask id where masked
+    n_fixed: int            # leading tokens that are the prompt's remainder
+    masked: int             # still masked after every pass dispatched so far
+    # the commit pass went out on the device's copy of the tokens: the
+    # block is accepted when the last denoising pass is read back
+    committing: bool = False
+
+
 class _Part:
     """One dispatched compiled program of an iteration (a step may hold
     several: prefill wave(s) + the mixed decode program)."""
 
-    def __init__(self, kind: str, sample_rows, tok_dev, n_steps: int = 1):
+    def __init__(self, kind: str, sample_rows, tok_dev, n_steps: int = 1,
+                 blocks: Optional[Dict[int, _Block]] = None):
         self.kind = kind              # wave | mixed | fused
-        self.sample_rows = sample_rows  # [(req, row_index)]
-        self.tok_dev = tok_dev        # [bucket] or [n_steps, bucket] int32
+        # [(req, row_index)]; of a block-diffusion model [(req, the first
+        # of its block's rows)], its denoising passes alone
+        self.sample_rows = sample_rows
+        # [bucket] or [n_steps, bucket] int32; of a block-diffusion
+        # model the tokens every row is fed next pass
+        self.tok_dev = tok_dev
         self.n_steps = n_steps
+        self.blocks = blocks          # {rid: the block its pass denoised}
 
 
 class _Step:
@@ -221,6 +262,31 @@ class ServingScheduler:
             raise ValueError("speculative decoding is greedy-only")
         if self._spec:
             refuse_for_pools(engine.cfg, "speculation")
+            refuse_block_diffusion(engine.cfg, "speculation")
+        # a model that generates by diffusion over blocks: its block
+        # length (0: a causal model), the rows a live sequence takes of
+        # an iteration, and the positions a denoising pass reveals
+        self._block = B = engine.cfg.block_length
+        self._rows_per_seq = B or 1
+        self._reveal = -(-B // (self.cfg.denoising_steps or B)) if B else 0
+        if B:
+            for feature, asked in (
+                    ("decode_multi", self.cfg.decode_chunk > 1),
+                    ("wave", self.cfg.prefill_mode == "wave"),
+                    ("presence", self.scfg.needs_presence)):
+                if asked:
+                    refuse_block_diffusion(engine.cfg, feature)
+            if self.cfg.prefill_chunk % B or B > min(
+                    self.cfg.max_num_batched_tokens,
+                    engine.config.max_batch_size):
+                raise ValueError(
+                    f"prefill_chunk {self.cfg.prefill_chunk} is not whole "
+                    f"blocks of block_length {B}, or an iteration's rows "
+                    f"(max_num_batched_tokens "
+                    f"{self.cfg.max_num_batched_tokens}, max_batch_size "
+                    f"{engine.config.max_batch_size}) hold no block: a "
+                    "prompt is fed in whole blocks, each seeing through "
+                    "its own end, and a block is fed whole")
         # of the engine's count of ring blocks turned over, what this
         # scheduler's counter holds already (_count_rings)
         self._rings_recycled_seen = engine.state.rings_recycled
@@ -352,6 +418,22 @@ class ServingScheduler:
             # through their step kernel (engine.step_kernel), not the
             # loop over rows in XLA
             "state_step_kernel_steps": 0,
+            # tokens that entered a request's `output` (`batched_tokens`
+            # counts the ROWS the programs were fed: prompt rows, and a
+            # position once for every pass it is fed in)
+            "output_tokens": 0,
+            # a model that generates by diffusion over blocks (0 for
+            # every other), a sequence a dispatched program: denoising
+            # passes, commit passes, the rows fed in either, of those
+            # the rows that held the mask id when fed (the rows whose
+            # logits were needed), the tokens committed blocks added to
+            # `output`, and blocks a preemption dropped unfinished
+            "block_passes": 0,
+            "block_commits": 0,
+            "block_rows": 0,
+            "block_masked_rows": 0,
+            "block_tokens": 0,
+            "block_restarts": 0,
         }
         self._phases = profiler.Phases("sched", "iteration", PHASES,
                                        sums=self.counters, wait="readback")
@@ -386,7 +468,8 @@ class ServingScheduler:
                       if self.cfg.decode_chunk > 1 and not self._spec
                       else ())
             engine.warmup(sampling=sampling, decode_chunks=chunks,
-                          presence=use_pres)
+                          presence=use_pres,
+                          reveals=(self._reveal,) if B else ())
         # admit-config budget validation: the warmed per-bucket
         # footprints vs the per-device HBM budget (analysis/costmodel
         # S004) — logged once here, surfaced via metrics()/monitor
@@ -495,6 +578,7 @@ class ServingScheduler:
             raise ValueError("empty prompt")
         if handoff:
             refuse_for_pools(self.engine.cfg, "page_transfer")
+            refuse_block_diffusion(self.engine.cfg, "handoff")
         if len(prompt) > self.engine.config.max_seq_len:
             raise ValueError(
                 f"prompt of {len(prompt)} > max_seq_len "
@@ -545,6 +629,7 @@ class ServingScheduler:
         req.uid = None
         req.fed = 0
         req.pending = None
+        req.block = None
         req.state = WAITING
         req.preemptions += 1
         # a spill payload lives in the SOURCE scheduler's host tier —
@@ -580,6 +665,7 @@ class ServingScheduler:
         its base and continues chunking — no recompute either way.
         Raises RuntimeError when the batch or the KV pool cannot take
         it (callers fall back to requeue())."""
+        refuse_block_diffusion(self.engine.cfg, "handoff")
         if len(self.active) >= self.engine.config.max_batch_size:
             raise RuntimeError(
                 f"decode replica at max_batch_size "
@@ -696,6 +782,11 @@ class ServingScheduler:
         victim.uid = None
         victim.fed = 0
         victim.pending = None
+        if victim.block is not None:
+            # the block in flight is dropped: the request resumes at its
+            # last committed block (output holds whole blocks alone)
+            self.counters["block_restarts"] += 1
+            victim.block = None
         victim.state = WAITING
         victim.preemptions += 1
         self.counters["preemptions"] += 1
@@ -845,7 +936,11 @@ class ServingScheduler:
         seen = int(payload["seen_tokens"])
         req.uid = uid
         req.fed = seen
-        if req.output and seen == len(req.base) - 1:
+        if self._block and seen == self._prompt_end(req):
+            # every whole block of its base is in the pages: it opens
+            # the block it had in flight again (_reserve grows the table)
+            self._open_block(req)
+        elif req.output and seen == len(req.base) - 1 and not self._block:
             # mid-decode victim: its next draw's input is the pending
             # (sampled, not-yet-fed) token — exactly where it stopped
             # (per-step _reserve grows the block table from here)
@@ -856,7 +951,7 @@ class ServingScheduler:
             # The payload only carried the WRITTEN blocks; re-reserve
             # room for the rest of the base, as admission would have
             try:
-                self.engine.state.extend(uid, len(req.base) - seen)
+                self.engine.state.extend(uid, self._prompt_end(req) - seen)
             except KVCacheExhaustedError:
                 self.engine.flush(uid)
                 req.spill_key = key
@@ -899,7 +994,9 @@ class ServingScheduler:
                if self.governor is not None
                and self.governor.level >= BROWNOUT else -1)
         while self.waiting:
-            if len(self.active) >= eng.config.max_batch_size:
+            # a live sequence takes _rows_per_seq rows of an iteration
+            if (len(self.active) * self._rows_per_seq
+                    >= eng.config.max_batch_size):
                 break
             if 0 <= cap <= admitted_now:
                 break
@@ -925,8 +1022,13 @@ class ServingScheduler:
                 self._finish(req, "length")
                 continue
             uid = self._alloc_uid()
+            # a block-diffusion model's prompt is fed, and credited by
+            # the prefix index, in whole blocks; the remainder opens the
+            # first block it generates
+            base = base[:self._prompt_end(req)]
             try:
-                _, match = eng.state.extend(uid, len(base), token_ids=base)
+                _, match = eng.state.extend(uid, len(base), token_ids=base,
+                                            align=self._rows_per_seq)
             except KVCacheExhaustedError as short:
                 if eng.state.num_rings:
                     self.counters[f"admit_waits_{short.pool}_pool"] += 1
@@ -953,12 +1055,108 @@ class ServingScheduler:
                 self.counters["state_prefix_credits_refused"] += (
                     match.declined > 0)
             req.state = PREFILL
+            if self._block and req.fed == len(base):
+                self._open_block(req)  # a prompt of less than a block
             self.active.append(req)
             self._stamp_admission(req)
             self.counters["admitted"] += 1
             admitted_now += 1
         for req in reversed(scanned):  # preserve arrival order
             self.waiting.appendleft(req)
+
+    # -- a model that generates by diffusion over blocks -----------------
+    def _prompt_end(self, req: Request) -> int:
+        """How many of req's base tokens are fed as prompt before it
+        runs: all of them, or for a block-diffusion model its whole
+        blocks (the remainder is the fixed head of the first block)."""
+        n = len(req.base)
+        return n - n % self._block if self._block else n
+
+    def _open_block(self, req: Request) -> None:
+        """req, its base's whole blocks fed, starts (or starts again)
+        the block after them: the base's remainder, then the mask id.
+        A block the context has no room for ends the request."""
+        B = self._block
+        fixed = req.base[self._prompt_end(req):]
+        req.block = _Block(
+            tokens=fixed + [self.engine.cfg.mask_token_id] * (B - len(fixed)),
+            n_fixed=len(fixed), masked=B - len(fixed))
+        req.state = RUNNING
+        if self._prompt_end(req) + B > self.engine.config.max_seq_len:
+            self._finish(req, "length")
+
+    def _block_dispatched(self, req: Request, chunk, denoise: bool) -> None:
+        """A pass of req's block went out: what it settles by counts.
+        A denoising pass reveals its share of the masked positions
+        whatever they turn out to be. The commit pass advances the
+        sequence by the block; the block is accepted here if the host
+        holds its tokens (chunk), else when the pass that reveals the
+        last of them is read back (_finalize)."""
+        blk, c, B = req.block, self.counters, self._block
+        c["block_rows"] += B
+        if denoise:
+            c["block_passes"] += 1
+            c["block_masked_rows"] += blk.masked
+            blk.masked -= min(self._reveal, blk.masked)
+            return
+        c["block_commits"] += 1
+        if chunk[0] is None:
+            self.engine.state.commit(req.uid, B)
+            blk.committing = True
+            return
+        self.engine.state.commit(req.uid, B,
+                                 token_ids=[int(t) for t in chunk])
+        self._accept_block(req, time.perf_counter())
+
+    def _accept_block(self, req: Request, now: float) -> None:
+        """req's block is committed and its tokens are on the host:
+        `output` grows by what the block generated (_accept's rules, a
+        block at a time: cut at EOS, cut at the output budget), and the
+        request ends or opens its next block."""
+        blk = req.block
+        new = blk.tokens[blk.n_fixed:][:req.max_new_tokens - len(req.output)]
+        eos = req.eos_token_id is not None and req.eos_token_id in new
+        if eos:
+            new = new[:new.index(req.eos_token_id) + 1]
+        if req.first_token_t is None:
+            req.first_token_t = now
+        req.output.extend(new)
+        req.block = None
+        self.counters["block_tokens"] += len(new)
+        self.counters["output_tokens"] += len(new)
+        if eos:
+            self._finish(req, "eos")
+        elif len(req.output) >= req.max_new_tokens:
+            self._finish(req, "length")
+        else:
+            self._open_block(req)
+
+    def _unmask_part(self, logits_dev, toks_dev, sample_rows, positions,
+                     bucket: int) -> Any:
+        """Device-side epilogue of the denoising passes in one
+        dispatch's [bucket, V] logits (sampling.block_unmask): every
+        masked row of the blocks in `sample_rows` samples a token and
+        its confidence, the most confident of a block are revealed.
+        Returns the device array of the tokens EVERY row is fed next
+        pass, not read back here. A draw's counter is its position and
+        the pass (the masked positions left tell it), so a recomputed
+        block draws what it drew."""
+        eng, B = self.engine, self._block
+        ph = self._phases
+        ph.mark("build")
+        streams = np.zeros((bucket,), np.uint32)
+        active = np.zeros((bucket,), bool)
+        for req, row in sample_rows:
+            streams[row:row + B] = req.stream
+            active[row + req.block.n_fixed:row + B] = True
+            positions[row:row + B] += (
+                eng.config.max_seq_len * (B - req.block.masked))
+        eng.recompile_tracker.record(
+            f"serving_unmask[w{bucket},r{self._reveal}]", (positions,))
+        ph.mark("launch", kind="unmask", rows=bucket)
+        return eng._block_unmask_fn(self.scfg, self._reveal)(
+            logits_dev, toks_dev, eng._dev(active),
+            eng._row_keys(self.seed, streams), eng._dev(positions))
 
     # -- dispatch construction -------------------------------------------
     def _sample_part(self, logits_dev, sample_rows, bucket: int) -> Any:
@@ -1156,8 +1354,16 @@ class ServingScheduler:
         Sarathi piggyback). rows: [(req, chunk, sample)]. A chunk of
         [None] is a decode row whose token the host does not hold yet:
         it is row src[req.rid] of the sampled tokens of `ahead_of`, the
-        step still in flight, and is gathered on the device."""
+        step still in flight, and is gathered on the device.
+
+        A block-diffusion model's RUNNING request gives its block's B
+        rows (sample: a denoising pass; not: the commit pass), [None] *
+        B where the device holds them: rows src[req.rid] .. + B of
+        `ahead_of`. Its rows come before every prompt chunk's, so that
+        a block is B adjacent rows from a multiple of B on
+        (sampling.block_unmask takes them as a group)."""
         eng = self.engine
+        B = self._block
         ph = self._phases
         ph.mark("build")
         n_rows = sum(len(c) for _, c, _ in rows)
@@ -1176,6 +1382,9 @@ class ServingScheduler:
         # and its ring (a model of mixed windows alone)
         rings = (np.full((sp,), -1, np.int32) if eng.state.num_rings
                  else None)
+        # a block-diffusion model's rows: where each stands, apart from
+        # what it sees
+        positions = np.zeros((sp,), np.int32) if B else None
         sample_rows: List[Tuple[Request, int]] = []
         row = 0
         for req, chunk, sample in rows:
@@ -1188,15 +1397,20 @@ class ServingScheduler:
                 rings[row:row + len(chunk)] = seq.ring
             for j, tok in enumerate(chunk):
                 if tok is None:
-                    srcs[row] = src[req.rid]
+                    srcs[row] = src[req.rid] + (j if B else 0)
                 else:
                     toks[row] = int(tok)
                 ctx[row] = base_seen + j + 1
                 tables[row] = table
                 row += 1
-            if sample:
-                sample_rows.append((req, row - 1))
-        unique = all(len(c) == 1 for _, c, _ in rows)
+            if B:
+                positions[row - len(chunk):row] = base_seen + np.arange(
+                    len(chunk))
+            if sample:  # a block's FIRST row, a causal chunk's last
+                sample_rows.append((req, row - (len(chunk) if B else 1)))
+        if B:  # a row sees through the end of its block
+            ctx[:n_rows] = eng.block_ctx(positions[:n_rows])
+        unique = not B and all(len(c) == 1 for _, c, _ in rows)
         eng.recompile_tracker.record(
             f"serving_decode[w{sp},u{int(unique)}]", (toks, tables, ctx))
         prev_toks = None  # the sampled tokens some row is gathered from
@@ -1212,10 +1426,16 @@ class ServingScheduler:
                                              eng._dev(srcs))
         logits, eng.cache = eng._decode_fn(sp, unique)(
             eng.params, eng.cache, toks_dev, eng._dev(tables),
-            eng._dev(ctx), *eng.state_args(slots, rings))
+            eng._dev(ctx), *eng.position_args(positions),
+            *eng.state_args(slots, rings))
         # host bookkeeping overlaps the in-flight device program
         ph.mark("commit")
+        blocks = ({req.rid: req.block for req, _ in sample_rows} if B
+                  else None)
         for req, chunk, sample in rows:
+            if B and req.state == RUNNING:
+                self._block_dispatched(req, chunk, sample)
+                continue
             if chunk[0] is None:
                 # the id follows when ahead_of is read back (_accept)
                 eng.state.commit(req.uid, 1)
@@ -1225,11 +1445,18 @@ class ServingScheduler:
                              token_ids=[int(t) for t in chunk])
             if req.state == PREFILL:
                 req.fed += len(chunk)
-                if req.fed == len(req.base):
+                if B and req.fed == self._prompt_end(req):
+                    self._open_block(req)
+                elif req.fed == len(req.base):
                     req.state = RUNNING
         # mid-prompt chunks produce no token: skip the sample epilogue
-        tok_dev = (self._sample_part(logits, sample_rows, sp)
-                   if sample_rows else None)
+        if not sample_rows:
+            tok_dev = None
+        elif B:
+            tok_dev = self._unmask_part(logits, toks_dev, sample_rows,
+                                        positions.copy(), sp)
+        else:
+            tok_dev = self._sample_part(logits, sample_rows, sp)
         ph.mark("commit")
         self._count_tokens(n_rows, sp, ctx, tables=tables)
         if rings is not None or eng.cfg.n_kv_reader_layers:
@@ -1237,7 +1464,7 @@ class ServingScheduler:
                                for req, _, _ in rows])
         self._count_cross(n_rows, len(sample_rows))
         self._count_state([len(c) for _, c, _ in rows], sp)
-        return _Part("mixed", sample_rows, tok_dev)
+        return _Part("mixed", sample_rows, tok_dev, blocks=blocks)
 
     def _dispatch_fused(self, running: List[Request], C: int) -> _Part:
         """Steady-state fused decode: C steps per compiled program
@@ -1391,18 +1618,20 @@ class ServingScheduler:
         pchunk = self.cfg.prefill_chunk
         if self.engine.state.num_rings:  # what a ring takes in one step
             pchunk = min(pchunk, self.engine.config.kv_block_size)
+        n = self._rows_per_seq  # a block, or a token
         if self._brownout():
             # shrink the prefill chunk: under brownout every reserved
             # prefill token is pool pressure the decode rows pay for
             pchunk = max(1, pchunk // self.cfg.pressure.brownout_chunk_div)
+            pchunk = max(n, pchunk - pchunk % n)  # whole blocks still
         rows: List[Tuple[Request, List[Optional[int]], bool]] = []
         for req in list(running):  # oldest first; preemption takes youngest
-            if budget < 1 or row_budget < 1:
+            if budget < n or row_budget < n:
                 break
             if req.state != RUNNING:
                 continue  # preempted/finished while reserving earlier rows
             if ahead_of is None:
-                if not self._reserve(req, 1):
+                if not self._reserve(req, n):
                     continue
             else:
                 # a reservation that does not fit has to preempt, and
@@ -1411,26 +1640,36 @@ class ServingScheduler:
                 # row whose KV died under it), counted so a hot
                 # fall-back loop shows in the metrics (L004)
                 try:
-                    self.engine.state.extend(req.uid, 1)
+                    self.engine.state.extend(req.uid, n)
                 except RuntimeError:
                     self.counters["lookahead_fallbacks"] += 1
                     return None
             # a token still in flight stays on the device: None
-            rows.append(
-                (req, [None if req.rid in src else req.pending], True))
-            budget -= 1
-            row_budget -= 1
+            if self._block:
+                # the block whole: a denoising pass while a position is
+                # masked, then the commit pass
+                rows.append((req, [None] * n if req.rid in src
+                             else list(req.block.tokens),
+                             req.block.masked > 0))
+            else:
+                rows.append(
+                    (req, [None if req.rid in src else req.pending], True))
+            budget -= n
+            row_budget -= n
         for req in prefill:
             if budget < 1 or row_budget < 1:
                 break
             if req.state != PREFILL:
                 continue  # preempted while reserving decode rows
-            remaining = req.base[req.fed:]
+            remaining = req.base[req.fed:self._prompt_end(req)]
             c = min(pchunk, budget, row_budget, len(remaining))
+            c -= c % n  # whole blocks
             if c < 1:
                 continue
             chunk = remaining[:c]
-            rows.append((req, chunk, req.fed + c == len(req.base)))
+            # a block-diffusion model's prompt yields no token
+            rows.append((req, chunk, not self._block
+                         and req.fed + c == len(req.base)))
             budget -= c
             row_budget -= c
         part = self._dispatch_mixed(rows, ahead_of, src)
@@ -1485,6 +1724,13 @@ class ServingScheduler:
         for req, row in prev.parts[0].sample_rows:
             if req.done:
                 continue  # ended on EOS while this row was in flight
+            if self._block:
+                # a denoising pass ends nothing: the block's next pass
+                # takes its rows from row `row` on (unless the block is
+                # gone: preempted since)
+                if req.block is prev.parts[0].blocks[req.rid]:
+                    src[req.rid] = row
+                continue
             if self._ends_by_count(req, len(req.output) + 1):
                 self._release(req)
                 prev.settled[req.rid] = True
@@ -1510,6 +1756,7 @@ class ServingScheduler:
         if req.first_token_t is None:
             req.first_token_t = now
         req.output.append(tok)
+        self.counters["output_tokens"] += 1
         if req.presence is not None and 0 <= tok < req.presence.size:
             req.presence[tok] = 1
         if req.eos_token_id is not None and tok == req.eos_token_id:
@@ -1551,7 +1798,20 @@ class ServingScheduler:
             toks = serving_readback(part.tok_dev)
             self._phases.mark("accept")
             now = time.perf_counter()
-            if part.kind == "fused":
+            if part.blocks is not None:
+                # denoising passes: the host's copy of each block, and
+                # the block itself where its commit pass went out on
+                # the device's copy
+                B = self._block
+                for req, row in part.sample_rows:
+                    blk = part.blocks[req.rid]
+                    if req.block is not blk:
+                        continue  # dropped by a preemption since
+                    blk.tokens = [int(t) for t in toks[row:row + B]]
+                    if blk.committing:
+                        self.engine.state.supply_tokens(req.uid, blk.tokens)
+                        self._accept_block(req, now)
+            elif part.kind == "fused":
                 # gen [C, width]: distribute each row's chunk in order,
                 # stopping at the first finish (generate()'s mid-chunk
                 # EOS contract — later tokens in the row are discarded)

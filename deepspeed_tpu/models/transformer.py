@@ -353,6 +353,17 @@ class TransformerConfig:
     # holds of them is the serving model's (inference/model.py
     # kv_pool_shape).
     differential_attention: bool = False
+    # ---- generation by diffusion over blocks (block-diffusion language
+    # models, SDAR class). SERVING ONLY. block_length B > 0: position i
+    # sees position j iff j // B <= i // B (bidirectional inside a block,
+    # causal across blocks), the ONE difference of a layer from a causal
+    # one; the logits at a position are the distribution of the token AT
+    # that position, and a position still to be generated is fed as
+    # mask_token_id. How a block is denoised (the passes, the reveal) is
+    # the scheduler's (inference/scheduler.py, docs/serving_scheduler.md).
+    # 0: a causal model, every other family.
+    block_length: int = 0
+    mask_token_id: int = 0
 
     def __post_init__(self):
         if self.layer_types is not None:
@@ -458,6 +469,17 @@ class TransformerConfig:
                 "attn_output_gate with latent attention or q/k/v biases")
         if self.qk_norm_per_head and not self.qk_norm:
             raise ValueError("qk_norm_per_head is a form of qk_norm: set both")
+        if self.block_length and (
+                self.block_length < 0 or self.layer_types is not None
+                or self.kv_lora_rank > 0 or self.sliding_window
+                or self.alibi or self.differential_attention
+                or not 0 <= self.mask_token_id < self.vocab_size):
+            raise ValueError(
+                "block_length is the block of a block-causal mask over "
+                "plain attention layers (no layer_types, latent, windowed, "
+                "ALiBi or differential attention), and mask_token_id an id "
+                f"of the vocabulary (got block_length {self.block_length}, "
+                f"mask_token_id {self.mask_token_id})")
         if self.position_embedding not in (None, "none"):
             raise ValueError(
                 f"unknown position_embedding {self.position_embedding!r} "
@@ -688,7 +710,8 @@ class TransformerConfig:
                                  "shared_expert_gate", "position_embedding",
                                  "attention_multiplier",
                                  "rope_scaling_full_only", "mixer_only",
-                                 "ssm_dt_rank", "differential_attention")
+                                 "ssm_dt_rank", "differential_attention",
+                                 "block_length")
                      if getattr(self, k)) + tuple(
             k for k, plain in (("ssm_groups", 1),
                                ("residual_multiplier", 1.0),

@@ -121,3 +121,38 @@ def causal_attention(q, k, v, use_flash: bool = True, window: int = 0,
     n_rep = q.shape[2] // k.shape[2]
     return _xla_attention(q, _repeat_kv(k, n_rep), _repeat_kv(v, n_rep),
                           causal=True, window=window, alibi=alibi)
+
+
+def block_causal_attention(q, k, v, block_length: int, q_tile: int = 256):
+    """Self-attention under the BLOCK-causal mask of a block-diffusion
+    model, [B,S,H,D] x [B,S,KV,D] -> [B,S,H,D]: position i sees
+    position j iff j // block_length <= i // block_length
+    (bidirectional inside a block, causal across blocks). Plain XLA: a
+    query tile's float32 scores against every key, one tile of q_tile
+    rows at a time (lax.map), so that what is live is
+    [B, H, q_tile, S] and not [B, H, S, S]; GQA heads read their KV
+    head in place. The whole-prompt prefill of such a model runs it
+    (inference/model.py prefill_batch); its steady state is the paged
+    walk."""
+    B, S, H, D = q.shape
+    KV = k.shape[2]
+    tile = min(q_tile, S)
+    pad = -S % tile
+    qg = jnp.pad(q, ((0, 0), (0, pad), (0, 0), (0, 0))).reshape(
+        B, (S + pad) // tile, tile, KV, H // KV, D)
+    see = (jnp.arange(S, dtype=jnp.int32) // block_length)[None, :]
+
+    def one(args):
+        qt, start = args  # [B, tile, KV, G, D]
+        rows = start + jnp.arange(tile, dtype=jnp.int32)
+        scores = jnp.einsum("bqkgd,bskd->bkgqs", qt, k).astype(
+            jnp.float32) * (1.0 / D ** 0.5)
+        keep = see <= (rows // block_length)[:, None]
+        probs = jax.nn.softmax(
+            jnp.where(keep[None, None, None], scores, _NEG_INF),
+            axis=-1).astype(q.dtype)
+        return jnp.einsum("bkgqs,bskd->bqkgd", probs, v)
+
+    starts = jnp.arange(0, S + pad, tile, dtype=jnp.int32)
+    out = jax.lax.map(one, (jnp.moveaxis(qg, 1, 0), starts))
+    return jnp.moveaxis(out, 0, 1).reshape(B, S + pad, H, D)[:, :S]

@@ -153,7 +153,8 @@ _ARCH_OF_MODEL_TYPE = {"olmoe": "OlmoeForCausalLM",
                        "nemotron_h": "NemotronHForCausalLM",
                        "afmoe": "AfmoeForCausalLM",
                        "olmo_hybrid": "OlmoHybridForCausalLM",
-                       "phi4flash": "Phi4FlashForCausalLM"}
+                       "phi4flash": "Phi4FlashForCausalLM",
+                       "sdar_moe": "SDARMoeForCausalLM"}
 # config.json keys that change what a BLOCK computes (latent attention,
 # shared experts, leading dense layers, a second norm, a scaled, grouped
 # or biased router, layers of another kind than attention): an
@@ -234,6 +235,8 @@ _READS_BLOCK_KEYS = {
         "linear_num_value_heads", "rope_parameters")),
     "Phi4FlashForCausalLM": frozenset(_SAMBAY_KEYS + (
         "mamba_d_state", "mamba_d_conv", "mamba_expand")),
+    "SDARMoeForCausalLM": frozenset(("moe_intermediate_size",
+                                     "mlp_only_layers")),
 }
 
 
@@ -251,7 +254,7 @@ SUPPORTED_ARCHITECTURES = sorted(_LLAMA_FAMILY | {
     "PanguUltraMoEForCausalLM", "Lfm2MoeForCausalLM",
     "Qwen3NextForCausalLM", "GraniteMoeHybridForCausalLM",
     "MellumForCausalLM", "NemotronHForCausalLM", "AfmoeForCausalLM",
-    "OlmoHybridForCausalLM", "Phi4FlashForCausalLM",
+    "OlmoHybridForCausalLM", "Phi4FlashForCausalLM", "SDARMoeForCausalLM",
     "GPT2LMHeadModel", "OPTForCausalLM", "FalconForCausalLM",
     "RWForCausalLM",  # falcon's pre-rename arch string
     "PhiForCausalLM", "QWenLMHeadModel",
@@ -296,6 +299,8 @@ def config_from_hf(hf: Dict[str, Any], **overrides) -> TransformerConfig:
         kw = _olmo_hybrid_config(hf)
     elif arch == "Phi4FlashForCausalLM":
         kw = _phi4flash_config(hf)
+    elif arch == "SDARMoeForCausalLM":
+        kw = _sdar_moe_config(hf)
     elif arch in _LLAMA_FAMILY:
         kw = dict(
             vocab_size=hf["vocab_size"],
@@ -1056,6 +1061,70 @@ def _olmo_hybrid_config(hf: Dict[str, Any]) -> Dict[str, Any]:
     )
 
 
+# the block a block-diffusion model of this family generates by where its
+# file states none (the publisher's generation settings for its Chat
+# models; the catalog lists it as not given)
+SDAR_BLOCK_LENGTH = 4
+# ... and the id it feeds for a position still to be generated
+SDAR_MASK_TOKEN_ID = 151669
+
+
+def _sdar_moe_config(hf: Dict[str, Any]) -> Dict[str, Any]:
+    """SDAR-MoE (`sdar_moe`): Qwen3-MoE's layer (GQA without bias, an
+    RMSNorm over each head's `head_dim` values of q and of k before
+    rotary, every MLP `num_experts` gated experts of
+    `moe_intermediate_size` under a softmax router in float32, top-k,
+    weights over their sum where `norm_topk_prob`; no shared expert)
+    under a BLOCK-CAUSAL mask: position i sees j iff j // B <= i // B,
+    B = `block_length` (absent: SDAR_BLOCK_LENGTH), and a position
+    still to be generated is fed as `mask_token_id` (absent:
+    SDAR_MASK_TOKEN_ID). Generation is the scheduler's
+    (docs/serving_scheduler.md, "A step that yields a block").
+
+    Refused by name, because nothing here computes it: a dense MLP in
+    some layer (`mlp_only_layers`, `decoder_sparse_step` other than 1),
+    a sliding window, `attention_bias`, rope scaling, another
+    activation than silu."""
+    if hf.get("mlp_only_layers") or hf.get("decoder_sparse_step", 1) != 1:
+        raise ValueError(
+            "sdar_moe with a dense MLP in some layer (mlp_only_layers="
+            f"{hf.get('mlp_only_layers')!r}, decoder_sparse_step="
+            f"{hf.get('decoder_sparse_step')!r}) is unsupported: every "
+            "layer's MLP is routed here")
+    if hf.get("use_sliding_window") or hf.get("attention_bias") \
+            or hf.get("rope_scaling") \
+            or hf.get("hidden_act", "silu") != "silu":
+        raise ValueError(
+            "sdar_moe with use_sliding_window, attention_bias, rope_scaling "
+            "or another hidden_act than silu is unsupported")
+    if int(hf.get("block_length", SDAR_BLOCK_LENGTH)) < 1:
+        raise ValueError(
+            f"sdar_moe with block_length={hf['block_length']!r}: the family "
+            "generates by blocks of at least one position (a causal model "
+            "is another architecture)")
+    kw = dict(
+        vocab_size=hf["vocab_size"],
+        n_layers=hf["num_hidden_layers"],
+        n_heads=hf["num_attention_heads"],
+        n_kv_heads=hf.get("num_key_value_heads") or None,
+        d_model=hf["hidden_size"],
+        d_ff=hf["moe_intermediate_size"],
+        max_seq=hf.get("max_position_embeddings", 4096),
+        variant="llama",
+        rope_theta=float(hf.get("rope_theta", 10000.0)),
+        norm_eps=float(hf.get("rms_norm_eps", 1e-6)),
+        tie_embeddings=bool(hf.get("tie_word_embeddings", False)),
+        n_experts=hf["num_experts"], moe_top_k=hf["num_experts_per_tok"],
+        moe_norm_topk_prob=bool(hf.get("norm_topk_prob", False)),
+        moe_dropless=True, qk_norm=True, qk_norm_per_head=True,
+        block_length=int(hf.get("block_length", SDAR_BLOCK_LENGTH)),
+        mask_token_id=int(hf.get("mask_token_id", SDAR_MASK_TOKEN_ID)),
+    )
+    if hf.get("head_dim") is not None:
+        kw["head_dim_override"] = int(hf["head_dim"])
+    return kw
+
+
 # what _phi4flash_config reads of a phi4flash config.json, and what it
 # checks and computes nothing from
 _PHI4FLASH_READS = frozenset((
@@ -1708,11 +1777,11 @@ def import_external(
             "import_external returns the flat [L, ...] layer stack; "
             "stage-partition afterwards via runtime.pipe.partition_layers"
         )
-    if _arch_of(hf) == "Phi4FlashForCausalLM":
+    if _arch_of(hf) in ("Phi4FlashForCausalLM", "SDARMoeForCausalLM"):
         raise NotImplementedError(
-            "phi4flash: the import is of the configuration alone "
-            "(config_from_hf); the mapping of the publisher's weight names "
-            "waits for a checkpoint's files")
+            f"{hf.get('model_type')}: the import is of the configuration "
+            "alone (config_from_hf); the mapping of the publisher's weight "
+            "names waits for a checkpoint's files")
     r = _CheckpointReader(path)
 
     cast: Callable[[np.ndarray], np.ndarray]

@@ -63,6 +63,15 @@ def pytest_sessionstart(session):
 # tests among them), and that a later PR's ADDED entries outgrow:
 # node id's end -> why it is expected to fail until that PR
 _OUTGROWN_BENCHMARK_PINS = {
+    "test_phi4flash_readers.py::"
+    "test_the_cell_reports_what_the_other_ring_cell_reports":
+        "pins len(BENCHMARK.json workloads) == 13 and its own cell and "
+        "configuration as the LAST of every list (PR 62); PR 65 appended the "
+        "fourteenth cell, `serve-sdar-chat-saturated-r256`, after it; that "
+        "the Phi-4-flash cell is on the lists the other ring cell is on "
+        "stays held by test_sdar_readers.py::"
+        "test_the_older_cells_stand_where_they_stood; a `benchmark` PR "
+        "relaxes the pin and takes this entry away",
     "test_olmohybrid_readers.py::"
     "test_the_cell_reports_what_the_other_delta_net_cell_reports":
         "pins len(BENCHMARK.json workloads) == 12 and its own cell and "

@@ -2,6 +2,9 @@
 batch triangle assertions in runtime/config.py)."""
 
 import json
+import os
+import subprocess
+import sys
 
 import pytest
 
@@ -178,3 +181,35 @@ def test_gradient_predivide_factor_guard():
     with pytest.raises(NotImplementedError):
         parse_config({"train_micro_batch_size_per_gpu": 1,
                       "gradient_predivide_factor": 2.0})
+
+
+# -- the harness's own configuration (tests/conftest.py) ------------------
+
+_COUNT = "--xla_force_host_platform_device_count"
+_LEVEL = "--xla_backend_optimization_level"
+
+
+@pytest.mark.parametrize("lane, named, want", [
+    ("cpu", "", [f"{_COUNT}=8", f"{_LEVEL}=1"]),
+    ("cpu", f"{_COUNT}=2 {_LEVEL}=3", [f"{_COUNT}=2", f"{_LEVEL}=3"]),
+    ("tpu", "", []),
+], ids=["cpu-bare", "cpu-both-named", "tpu-bare"])
+def test_the_lanes_xla_flags(lane, named, want):
+    """The CPU lane names the device count and the backend's level once
+    each and keeps what the caller named; DS_TPU_TESTS=1 adds neither.
+    Read in a child that takes the lane's decision (importing conftest
+    touches no backend, so the hardware lane's needs no chip)."""
+    env = {k: v for k, v in os.environ.items()
+           if k not in ("DS_TPU_TESTS", "JAX_PLATFORMS")}
+    env["XLA_FLAGS"] = named
+    if lane == "tpu":
+        env["DS_TPU_TESTS"] = "1"
+    out = subprocess.run(
+        [sys.executable, "-c", "import os, conftest; print(os.environ.get("
+         "'JAX_PLATFORMS', '-'), os.environ['XLA_FLAGS'])"],
+        capture_output=True, text=True, timeout=180, env=env,
+        cwd=os.path.dirname(os.path.abspath(__file__)))
+    assert out.returncode == 0, out.stderr
+    platforms, *flags = out.stdout.splitlines()[-1].split()
+    assert sorted(flags) == sorted(want)
+    assert platforms == ("cpu" if lane == "cpu" else "-")

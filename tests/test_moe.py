@@ -104,8 +104,15 @@ class TestGating:
 
 
 class TestMoETraining:
-    def test_loss_decreases(self):
+    @pytest.fixture(scope="class")
+    def default(self):
+        """The class's ONE engine of `model_cfg()` (top-1, the whole mesh
+        as data, a batch of 16) and its trajectory, taken while new."""
         engine = build_engine(model_cfg())
+        return engine, [engine.train_batch(b)["loss"] for b in data()]
+
+    def test_loss_decreases(self, default):
+        engine, _ = default
         batch = data(1)[0]
         ls = [engine.train_batch(batch)["loss"] for _ in range(8)]
         assert ls[-1] < ls[0]
@@ -116,13 +123,16 @@ class TestMoETraining:
         assert "expert" in str(w.sharding.spec)
 
     @pytest.mark.parametrize("top_k", [1, 2])
-    def test_ep_layout_equivalence(self, top_k):
+    def test_ep_layout_equivalence(self, top_k, default):
         """EP=1 vs EP=2 is a layout change only — same trajectory
         (ref: the expert group is carved out of the DP world,
         utils/groups.py:113)."""
         mcfg = model_cfg(moe_top_k=top_k)
-        base = build_engine(mcfg, mesh={"data": -1}, train_batch_size=16)
-        base_losses = [base.train_batch(b)["loss"] for b in data()]
+        if top_k == 1:  # EP=1 at top-1 is the default engine
+            base_losses = default[1]
+        else:
+            base = build_engine(mcfg, mesh={"data": -1}, train_batch_size=16)
+            base_losses = [base.train_batch(b)["loss"] for b in data()]
         ep = build_engine(mcfg, mesh={"data": 4, "expert": 2}, train_batch_size=16)
         ep_losses = [ep.train_batch(b)["loss"] for b in data()]
         np.testing.assert_allclose(ep_losses, base_losses, rtol=2e-4)

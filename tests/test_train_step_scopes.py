@@ -17,6 +17,8 @@ fusion's name.
 
 import contextlib
 import functools
+import gzip
+import json
 import os
 import re
 
@@ -620,7 +622,20 @@ def test_the_measured_profile_reads_the_same_names(tmp_path, capsys):
         buckets=("attention", "mlp", "norm1", "norm2", "embed", "lm_head"),
         steps=2)
     assert m["coverage"] > old["coverage"] + 0.05, (m["coverage"], old["coverage"])
-    assert m["coverage"] > 0.9
+    # How much is inside is held as a COUNT. The share of op TIMES on the
+    # CPU follows what XLA's own unnamed converts and copies cost beside
+    # the dots, which the backend's level and the neighbours move (0.89-
+    # 0.98 at level 3, 0.85-0.92 at level 1, beside five other steps, 30
+    # readings each: CHANGES.md PR 66). The executions do not: 7,384 of
+    # the 7,632 whose instruction carries a name are inside, 0.967, and
+    # without `grad_clip`, `grad_reduce`, `optimizer` 0.948, 0.935, 0.930.
+    scope_of = hlo_scope_map(engine._train_compiled.as_text())
+    with gzip.open(latency._latest_trace_json(trace_dir)) as f:
+        ran = [scope_of.get((e.get("args") or {}).get("hlo_op"))
+               for e in json.load(f)["traceEvents"] if e.get("ph") == "X"]
+    named = [name for name in ran if name]
+    inside = sum(1 for name in named if scope_path(name, ALL))
+    assert inside > 0.95 * len(named), (inside, len(named))
     man = engine.collective_manifest()
     assert set(m["collectives"]) == set(man["kinds"])
     gathers = m["collectives"]["all-gather"]

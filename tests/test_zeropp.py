@@ -56,6 +56,13 @@ def losses(engine, batches):
     return [engine.train_batch(b)["loss"] for b in batches]
 
 
+def trained(n=4, **cfg_kw):
+    """A class's ONE engine of a configuration and its trajectory over
+    `data(n)`, taken while it was new; layout and program are read after."""
+    engine = build_engine(**cfg_kw)
+    return engine, losses(engine, data(n))
+
+
 class TestQuantizationKernels:
     def test_blockwise_roundtrip(self):
         x = jax.random.normal(jax.random.PRNGKey(0), (1000,)) * 3.0
@@ -85,30 +92,28 @@ class TestHpZ:
     """zero_hpz_partition_size=k → data factored into data×zero."""
 
     @pytest.fixture(scope="class")
-    def baseline(self):
-        engine = build_engine(
+    def full(self):
+        return trained(
             zero_optimization={"stage": 3, "param_persistence_threshold": 64})
-        return losses(engine, data())
 
-    def test_hpz_matches_full_sharding_trajectory(self, baseline):
-        engine = build_engine(zero_optimization={
+    @pytest.fixture(scope="class")
+    def hpz(self):
+        return trained(zero_optimization={
             "stage": 3, "param_persistence_threshold": 64,
             "zero_hpz_partition_size": 2,
         })
+
+    def test_hpz_matches_full_sharding_trajectory(self, full, hpz):
+        engine, trajectory = hpz
         assert engine.mesh.shape["zero"] == 2
         assert engine.mesh.shape["data"] == 4
-        np.testing.assert_allclose(losses(engine, data()), baseline, rtol=2e-4)
+        np.testing.assert_allclose(trajectory, full[1], rtol=2e-4)
 
-    def test_hpz_shards_within_subgroup_only(self):
-        engine = build_engine(zero_optimization={
-            "stage": 3, "param_persistence_threshold": 64,
-            "zero_hpz_partition_size": 2,
-        })
+    def test_hpz_shards_within_subgroup_only(self, full, hpz):
+        engine, full = hpz[0], full[0]
         spec = str(engine.state.params["layers"]["w_in"].sharding.spec)
         assert "zero" in spec and "data" not in spec
         # replicated across the 2 groups of 4: each device holds 1/2, not 1/8
-        full = build_engine(zero_optimization={
-            "stage": 3, "param_persistence_threshold": 64})
         w_h = engine.state.params["layers"]["w_in"]
         w_f = full.state.params["layers"]["w_in"]
         assert (w_h.addressable_shards[0].data.size
@@ -132,17 +137,23 @@ class TestHpZ:
 class TestQwZ:
     """zero_quantized_weights: int8 weight gather, convergence parity."""
 
-    def test_qwz_converges_with_parity(self):
-        batches = data(8)
-        base = build_engine(
-            bf16={"enabled": True},
-            zero_optimization={"stage": 3, "param_persistence_threshold": 64})
-        qwz = build_engine(
-            bf16={"enabled": True},
+    @pytest.fixture(scope="class")
+    def qwz(self):
+        return trained(
+            8, bf16={"enabled": True},
             zero_optimization={"stage": 3, "param_persistence_threshold": 64,
                                "zero_quantized_weights": True})
-        lb = losses(base, batches)
-        lq = losses(qwz, batches)
+
+    @pytest.fixture(scope="class")
+    def qgz(self):
+        return trained(8, zero_optimization={
+            "stage": 2, "zero_quantized_gradients": True})
+
+    def test_qwz_converges_with_parity(self, qwz):
+        _, lb = trained(
+            8, bf16={"enabled": True},
+            zero_optimization={"stage": 3, "param_persistence_threshold": 64})
+        lq = qwz[1]
         assert lq[-1] < lq[0]  # training works
         # ≤1% loss delta over the run (the ZeRO++ convergence-parity bar)
         for a, b in zip(lb, lq):
@@ -158,7 +169,7 @@ class TestQwZ:
         ls = losses(engine, batches)
         assert ls[-1] < ls[0]
 
-    def test_qwz_reduces_allgather_bytes(self):
+    def test_qwz_reduces_allgather_bytes(self, qwz):
         """What crosses the gather boundary: with qwZ every zero-sharded
         weight reaches its gathered layout as int8 codes (1 byte an
         element, plus one f32 scale per slice of the sharded dim) where
@@ -173,10 +184,7 @@ class TestQwZ:
 
         from deepspeed_tpu.runtime.zero import zero_sharded_dims
 
-        engine = build_engine(
-            bf16={"enabled": True},
-            zero_optimization={"stage": 3, "param_persistence_threshold": 64,
-                               "zero_quantized_weights": True})
+        engine = qwz[0]
         batch = engine.shard_batch(engine._reshape_gas(data(1)[0]))
         with jax.sharding.set_mesh(engine.mesh):
             text = engine._build_train_step().lower(engine.state, batch).as_text()
@@ -191,30 +199,23 @@ class TestQwZ:
             jax.tree.leaves(shapes, is_leaf=lambda x: isinstance(x, tuple)),
             jax.tree.leaves(dims)) if k >= 0]
         assert sharded and codes == sorted(shp for shp, _ in sharded)
-        qwz = sum(int(np.prod(shp)) + 4 * shp[k] for shp, k in sharded)
+        quantized = sum(int(np.prod(shp)) + 4 * shp[k] for shp, k in sharded)
         base = sum(2 * int(np.prod(shp)) for shp, _ in sharded)
-        assert qwz < 0.6 * base, (qwz, base)
+        assert quantized < 0.6 * base, (quantized, base)
 
-    def test_qgz_converges_with_parity(self):
+    def test_qgz_converges_with_parity(self, qgz):
         """zero_quantized_gradients: int8 two-hop grad reduce, ≤1% loss
         delta vs exact reduction (the ZeRO++ qgZ bar)."""
-        batches = data(8)
-        base = build_engine(zero_optimization={"stage": 2})
-        qgz = build_engine(zero_optimization={"stage": 2,
-                                              "zero_quantized_gradients": True})
-        lb = losses(base, batches)
-        lq = losses(qgz, batches)
+        _, lb = trained(8, zero_optimization={"stage": 2})
+        lq = qgz[1]
         assert lq[-1] < lq[0]
         for a, b in zip(lb, lq):
             assert abs(a - b) / a < 0.01, (lb, lq)
 
-    def test_qgz_int8_on_wire(self):
+    def test_qgz_int8_on_wire(self, qgz):
         from deepspeed_tpu.profiling.hlo import parse_hlo_collectives
 
-        engine = build_engine(zero_optimization={"stage": 2,
-                                                 "zero_quantized_gradients": True})
-        engine.train_batch(data(1)[0])
-        recs = parse_hlo_collectives(engine._train_compiled.as_text())
+        recs = parse_hlo_collectives(qgz[0]._train_compiled.as_text())
         assert any(
             r["op"] in ("all-to-all", "all-gather", "collective-permute")
             and ("s8" in r["dtypes"] or "u8" in r["dtypes"])
